@@ -536,10 +536,8 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, page_size: int,
                 q_ax, kv_ax = "model", None
             else:
                 q_ax, kv_ax = None, None
-            from ..parallel.compat import shard_map
-
             with jax.named_scope("attention"):
-                attn = shard_map(
+                attn = jax.shard_map(
                     _paged, mesh=mesh,
                     in_specs=(P_(d_ax, q_ax, None),
                               P_(d_ax, None, kv_ax, None),

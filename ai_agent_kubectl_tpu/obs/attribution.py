@@ -356,7 +356,7 @@ def validate_attribution(obj: dict) -> None:
 
 
 def render_markdown(obj: dict) -> str:
-    """PROFILE.md-ready table for one attribution artifact."""
+    """Markdown table for one attribution artifact."""
     lines = [
         "| Category | ms/step | % of step | top ops |",
         "|---|---|---|---|",

@@ -105,7 +105,8 @@ def dense_attention_quant(
     dequantized the whole span to bf16 per layer per step
     (models/transformer.py kv_dequantize), which XLA materialized:
     ~13 GB of extra HBM traffic per 7B bs=48 step — the single largest
-    cost in the decode step (device-profiled ablation, PROFILE.md r5).
+    cost in the decode step (device-profiled ablation on an
+    earlier chip run, not re-measured).
 
     GQA is handled by grouping query heads ([b, q, n_kv, g, d]) instead
     of materializing repeated int8 KV.

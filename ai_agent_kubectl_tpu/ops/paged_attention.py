@@ -234,15 +234,13 @@ def paged_decode_attention_pool_sharded(
             f"meshes to the gather path")
     import jax.sharding as jsh
 
-    from ..parallel.compat import shard_map
-
     P_ = jsh.PartitionSpec
 
     def _local(ql, kl, vl, pos, tbl):
         return paged_decode_attention_pool(ql, kl, vl, pos, tbl,
                                            page_size=page_size)
 
-    return shard_map(
+    return jax.shard_map(
         _local, mesh=mesh,
         in_specs=(P_(None, "model", None),
                   P_(None, None, "model", None),

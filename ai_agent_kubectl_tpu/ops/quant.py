@@ -1,8 +1,8 @@
 """Weight-only int8 quantization (SURVEY.md §2.2 optional row, for the
 70B-class configs).
 
-Decode throughput on TPU is weight-read-bound (PROFILE.md: a bs=32 step
-runs at ~78% of the HBM weight-read floor), so halving weight bytes is a
+Decode throughput on TPU is weight-read-bound (a bs=32 step ran at ~78%
+of the HBM weight-read floor — earlier chip run, not re-measured), so halving weight bytes is a
 near-1.9× decode lever for large dense models. TPU-native design:
 
 - **Per-output-channel symmetric int8** for every projection matmul
@@ -14,7 +14,7 @@ near-1.9× decode lever for large dense models. TPU-native design:
   the weight read — only int8 bytes may cross HBM, never a materialized
   bf16 copy. Verified on TPU via the compiled-HLO check in
   tests/test_tpu_kernels.py (the convert lands inside the dot's fusion)
-  and consistent with the measured end-to-end uplift (PROFILE.md).
+  and consistent with the end-to-end uplift of an earlier chip run, not re-measured.
 - **Embeddings and norms stay in the model dtype**: the embedding gather
   is row-wise (per-token), not a matmul, and norm weights are tiny.
 - ``QuantInt8`` is a registered pytree node, so the quantized param tree
@@ -276,7 +276,8 @@ def to_w8a8(params):
     activation-quant noise directly moves the argmax. Rank-4 MoE expert
     stacks also stay weight-only: the MoE einsum epilogues
     (parallel/moe.py::_qeinsum) have no W8A8 path, and the measured
-    verdict on W8A8 (a no-op — PROFILE.md) makes one pointless."""
+    verdict on W8A8 (a no-op — earlier chip run, not re-measured)
+    makes one pointless."""
     out = dict(params)
     out["layers"] = jax.tree_util.tree_map(
         lambda x: (QuantInt8W8A8(q=x.q, scale=x.scale)

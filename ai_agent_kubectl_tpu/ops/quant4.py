@@ -1,7 +1,8 @@
 """Weight-only int4 quantization with a Pallas packed-nibble matmul.
 
 The round-4 profile proved 7B decode sits at the int8 weight-byte floor
-(PROFILE.md: 37.5 ms/step at bs=48 vs a 30.5 ms int8 read floor; W8A8
+(earlier chip run, not re-measured: 37.5 ms/step at bs=48 vs a
+30.5 ms int8 read floor; W8A8
 measured a no-op because the floor is the DMA stream, not the convert).
 The only remaining single-chip lever is fewer bytes — int4 halves them
 again. Replaces: /root/reference/app.py:184 (the remote forward this

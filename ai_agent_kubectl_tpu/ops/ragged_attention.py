@@ -23,16 +23,21 @@ Shape contract:
   entries >= n_blocks are the unmapped-page sentinel.
 
 Same TPU-first design as ops/paged_attention.py (this kernel is that
-one generalized from W=1): grid ``(slot, page)`` with positions +
-query lengths + tables scalar-prefetched, dead pages clamped to the
-slot's LAST LIVE page in the BlockSpec index map (repeat block indices
-elide the HBM→VMEM fetch, ``pl.when`` elides the compute), online
-softmax state persisted in VMEM scratch across the sequential page
-dimension. Causal-in-window masking: query column j attends kv
-positions ``<= positions[n] + j`` — bitwise the same semantics as the
-dense gather path (models/transformer.py::_pool_gather +
-dense_attention with the decode causal mask), which stays as the loud
-fallback for int8 KV and head counts that don't divide tp.
+one generalized from W=1): grid ``(slot, query tile, page)`` with
+positions + query lengths + tables scalar-prefetched, dead pages clamped
+to the tile's LAST LIVE page in the BlockSpec index map (repeat block
+indices elide the HBM→VMEM fetch, ``pl.when`` elides the compute),
+online softmax state persisted in VMEM scratch across the sequential
+page dimension. The window is cut into query tiles of ``_q_tile``
+columns so the VMEM working set depends on the head geometry alone,
+never on W: Mosaic refused every admission width above 64 when a grid
+cell held the whole window (scoped-VMEM limit, v5e). Tiles wholly past
+``q_lens[n]`` cost neither fetches nor compute. Causal-in-window
+masking: query column j attends kv positions ``<= positions[n] + j`` —
+bitwise the same semantics as the dense gather path
+(models/transformer.py::_pool_gather + dense_attention with the decode
+causal mask), which stays as the loud fallback for int8 KV and head
+counts that don't divide tp.
 
 Interpret mode runs the same kernel on CPU for tests and CI.
 """
@@ -59,21 +64,48 @@ def ragged_supported(page_size: int, head_dim: int,
     return head_dim % 128 == 0 and page_size >= 8 and n_pages >= 1
 
 
+#: Query elements (columns x heads x head_dim) one grid cell holds: 64
+#: columns of Llama-3-8B's 32 heads of 128. That tile compiles under the
+#: 16 MiB scoped-VMEM default on v5e; 128 columns is refused (16.19 MiB:
+#: q/out blocks double-buffered, f32 accumulator, lane-padded softmax
+#: state, score tile and spills).
+_Q_TILE_ELEMS = 64 * 32 * 128
+
+
+def _q_tile(w: int, n_heads: int, head_dim: int) -> int:
+    """Query columns per grid cell: the power of two that keeps a tile
+    at ``_Q_TILE_ELEMS`` for this head geometry, or the whole window
+    when that is narrower (decode, spec verify)."""
+    tq = max(8, _Q_TILE_ELEMS // (n_heads * head_dim))
+    return min(w, 1 << (tq.bit_length() - 1))
+
+
+def _last_live_page(pos, q_len, q0, tq: int, page_size: int):
+    """Last KV page any valid column of the tile starting at window
+    column ``q0`` attends: the page of its last valid column's own
+    freshly-written row. Shared by the kernel's compute gate and the
+    index map's fetch clamp so the two never disagree."""
+    hi = jnp.maximum(jnp.minimum(q0 + tq, q_len), 1)
+    return (pos + hi - 1) // page_size
+
+
 def _ragged_pool_kernel(pos_ref, qlen_ref, tbl_ref, q_ref, k_ref, v_ref,
                         o_ref, m_scr, l_scr, acc_scr, *, page_size: int,
                         scale: float, n_pages: int, kv_heads: int,
-                        w: int):
-    """Online-softmax body over one (slot, page) grid cell, W query rows
-    at a time. Rows are laid out [KV, W*G] (row r is query column
-    ``r // G`` of KV group ``r % G``'s block) so one KV-batched
-    ``dot_general`` serves every query column and head of the block —
-    the same working-set shape as the W=1 paged kernel, widened."""
+                        tq: int):
+    """Online-softmax body over one (slot, query tile, page) grid cell,
+    ``tq`` query columns at a time. Rows are laid out [KV, tq*G] (row r
+    is tile column ``r // G`` of KV group ``r % G``'s block) so one
+    KV-batched ``dot_general`` serves every query column and head of
+    the block — the same working-set shape as the W=1 paged kernel,
+    widened."""
     del tbl_ref                       # consumed by the index map
     n = pl.program_id(0)
-    p = pl.program_id(1)
+    q0 = pl.program_id(1) * tq        # window column of tile row 0
+    p = pl.program_id(2)
     pos = pos_ref[n]
     q_len = qlen_ref[n]
-    last_page = (pos + jnp.maximum(q_len, 1) - 1) // page_size
+    last_page = _last_live_page(pos, q_len, q0, tq, page_size)
 
     @pl.when(p == 0)
     def _init():
@@ -81,26 +113,26 @@ def _ragged_pool_kernel(pos_ref, qlen_ref, tbl_ref, q_ref, k_ref, v_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    @pl.when(p <= last_page)
+    @pl.when(jnp.logical_and(p <= last_page, q0 < q_len))
     def _accumulate():
         H, hd = q_ref.shape[2], q_ref.shape[3]
         G = H // kv_heads
-        # [W, H, hd] -> [KV, W*G, hd]: head h of column j lands at row
+        # [tq, H, hd] -> [KV, tq*G, hd]: head h of column j lands at row
         # j*G + h%G of KV group h//G — query column recoverable as
-        # row // G for the causal mask below.
+        # q0 + row // G for the causal mask below.
         qg = jnp.swapaxes(
-            q_ref[0].reshape(w, kv_heads, G, hd), 0, 1
-        ).reshape(kv_heads, w * G, hd)
+            q_ref[0].reshape(tq, kv_heads, G, hd), 0, 1
+        ).reshape(kv_heads, tq * G, hd)
         k = jnp.swapaxes(k_ref[0], 0, 1)                # [KV, page, hd]
         v = jnp.swapaxes(v_ref[0], 0, 1)
         s = jax.lax.dot_general(
             qg, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
-        ) * scale                                       # [KV, W*G, page]
+        ) * scale                                       # [KV, tq*G, page]
         kv_ids = p * page_size + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 2
         )
-        q_ids = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) // G
+        q_ids = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) // G
         # Causal-in-window: column j attends kv <= pos + j; padded
         # columns (j >= q_len) mask everything — their normalizer stays
         # 0 and the finalize writes zeros (outputs are never read).
@@ -117,7 +149,7 @@ def _ragged_pool_kernel(pos_ref, qlen_ref, tbl_ref, q_ref, k_ref, v_ref,
         acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
             pexp.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
-        )                                               # [KV, W*G, hd]
+        )                                               # [KV, tq*G, hd]
 
     @pl.when(p == n_pages - 1)
     def _finalize():
@@ -125,9 +157,9 @@ def _ragged_pool_kernel(pos_ref, qlen_ref, tbl_ref, q_ref, k_ref, v_ref,
         G = H // kv_heads
         l = l_scr[...]
         l = jnp.where(l == 0.0, 1.0, l)
-        out = (acc_scr[...] / l).reshape(kv_heads, w, G, hd)
+        out = (acc_scr[...] / l).reshape(kv_heads, tq, G, hd)
         o_ref[0] = jnp.swapaxes(out, 0, 1).reshape(
-            w, H, hd).astype(o_ref.dtype)
+            tq, H, hd).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -169,50 +201,55 @@ def ragged_attention_pool(
         interpret = jax.default_backend() != "tpu"
 
     G = H // KV
+    tq = _q_tile(W, H, hd)
+    n_qt = pl.cdiv(W, tq)
+    if n_qt * tq != W:
+        # Widths the tile doesn't divide pad up; the extra columns sit
+        # past every q_len, so they are masked and sliced off below.
+        q = jnp.pad(q, ((0, 0), (0, n_qt * tq - W), (0, 0), (0, 0)))
     pos = positions.astype(jnp.int32)
     qln = q_lens.astype(jnp.int32)
     tbl = jnp.clip(block_tables.astype(jnp.int32), 0, n_blocks - 1)
 
     kernel = functools.partial(
         _ragged_pool_kernel, page_size=page_size, scale=scale,
-        n_pages=n_pages, kv_heads=KV, w=W,
+        n_pages=n_pages, kv_heads=KV, tq=tq,
     )
 
-    def q_map(n, p, pos_ref, qlen_ref, tbl_ref):
-        return (n, 0, 0, 0)
+    def q_map(n, t, p, pos_ref, qlen_ref, tbl_ref):
+        return (n, t, 0, 0)
 
-    def kv_map(n, p, pos_ref, qlen_ref, tbl_ref):
-        # Clamp dead pages to the slot's LAST LIVE page (which covers
-        # the window's own freshly-written rows: pos + q_len - 1), then
-        # indirect through the table — repeat block indices elide the
-        # fetch, pl.when elides the compute.
-        last = (pos_ref[n]
-                + jnp.maximum(qlen_ref[n], 1) - 1) // page_size
-        pp = jnp.minimum(p, last)
-        return (tbl_ref[n, pp], 0, 0, 0)
+    def kv_map(n, t, p, pos_ref, qlen_ref, tbl_ref):
+        # Clamp dead pages to the tile's LAST LIVE page (which covers
+        # its own freshly-written rows), then indirect through the
+        # table — repeat block indices elide the fetch, pl.when elides
+        # the compute.
+        last = _last_live_page(pos_ref[n], qlen_ref[n], t * tq, tq,
+                               page_size)
+        return (tbl_ref[n, jnp.minimum(p, last)], 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(N, n_pages),
+        grid=(N, n_qt, n_pages),
         in_specs=[
-            pl.BlockSpec((1, W, H, hd), q_map),
+            pl.BlockSpec((1, tq, H, hd), q_map),
             pl.BlockSpec((1, page_size, KV, hd), kv_map),
             pl.BlockSpec((1, page_size, KV, hd), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, W, H, hd), q_map),
+        out_specs=pl.BlockSpec((1, tq, H, hd), q_map),
         scratch_shapes=[
-            pltpu.VMEM((KV, W * G, 1), jnp.float32),
-            pltpu.VMEM((KV, W * G, 1), jnp.float32),
-            pltpu.VMEM((KV, W * G, hd), jnp.float32),
+            pltpu.VMEM((KV, tq * G, 1), jnp.float32),
+            pltpu.VMEM((KV, tq * G, 1), jnp.float32),
+            pltpu.VMEM((KV, tq * G, hd), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((N, W, H, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((N, n_qt * tq, H, hd), q.dtype),
         interpret=interpret,
     )(pos, qln, tbl, q, k, v)
-    return out
+    return out[:, :W]
 
 
 def ragged_attention_pool_sharded(
@@ -248,15 +285,13 @@ def ragged_attention_pool_sharded(
             f"meshes to the gather path")
     import jax.sharding as jsh
 
-    from ..parallel.compat import shard_map
-
     P_ = jsh.PartitionSpec
 
     def _local(ql, kl, vl, qlen, pos, tbl):
         return ragged_attention_pool(ql, kl, vl, qlen, pos, tbl,
                                      page_size=page_size)
 
-    return shard_map(
+    return jax.shard_map(
         _local, mesh=mesh,
         in_specs=(P_(None, None, "model", None),
                   P_(None, None, "model", None),

@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..models.config import ModelConfig
-from .compat import shard_map
 
 
 def router_weights(cfg: ModelConfig, logits: jnp.ndarray):
@@ -200,7 +199,7 @@ def expert_parallel_moe(
                   qspec[2] if len(qspec) > 2 else None)
         return QuantInt8(q=qspec, scale=sspec)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_ep_shard, cfg=cfg, axis=axis,
                 model_axis=model_axis if use_tp else None, capacity=capacity),
         mesh=mesh,

@@ -41,7 +41,6 @@ from ..models.config import ModelConfig
 from ..models.transformer import KVCache, _layer
 from ..ops.norms import rms_norm
 from ..ops.quant import qmatmul
-from .compat import pvary, shard_map
 
 
 def _pipe_shard(lp, h_mb, pos_mb, k, v, *, cfg: ModelConfig, axis: str,
@@ -58,8 +57,10 @@ def _pipe_shard(lp, h_mb, pos_mb, k, v, *, cfg: ModelConfig, axis: str,
     perm = [(i, (i + 1) % n_stages) for i in range(n_stages)]
     tmap = jax.tree_util.tree_map
 
-    outs0 = pvary(jnp.zeros((M, Bm, S, D), h_mb.dtype), axis)
-    state0 = pvary(jnp.zeros((Bm, S, D), h_mb.dtype), axis)
+    outs0 = jax.lax.pcast(
+        jnp.zeros((M, Bm, S, D), h_mb.dtype), axis, to="varying")
+    state0 = jax.lax.pcast(
+        jnp.zeros((Bm, S, D), h_mb.dtype), axis, to="varying")
 
     def run_local_layers(h, positions, m_lo, k, v):
         """Scan this stage's layers over microbatch rows [m_lo, m_lo+Bm)."""
@@ -172,7 +173,7 @@ def pipeline_layers(
     # stacks layers on axis 0, so one per-leaf P(axis) spec shards both.
     k_specs = jax.tree_util.tree_map(lambda _: P(axis), k)
     v_specs = jax.tree_util.tree_map(lambda _: P(axis), v)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_pipe_shard, cfg=cfg, axis=axis, n_stages=n_stages,
                 n_micro=M, kv_limit=kv_limit, attn_impl=attn_impl),
         mesh=mesh,

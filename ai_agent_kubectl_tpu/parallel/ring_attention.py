@@ -37,7 +37,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .compat import pvary, shard_map
 
 
 def _block_attention(q, k, v, qpos, kpos, scale):
@@ -85,11 +84,14 @@ def _ring_shard(q, k, v, qpos, kpos, *, axis: str, scale: float):
     n = jax.lax.psum(1, axis)
     perm = [(j, (j + 1) % n) for j in range(n)]
 
-    # pvary: the accumulator starts as a constant but becomes device-varying
+    # pcast: the accumulator starts as a constant but becomes device-varying
     # after the first block — mark it so shard_map's carry typing agrees.
-    m0 = pvary(jnp.full((B, Sq, H, 1), -jnp.inf, jnp.float32), axis)
-    l0 = pvary(jnp.zeros((B, Sq, H, 1), jnp.float32), axis)
-    acc0 = pvary(jnp.zeros((B, Sq, H, hd), jnp.float32), axis)
+    m0 = jax.lax.pcast(
+        jnp.full((B, Sq, H, 1), -jnp.inf, jnp.float32), axis, to="varying")
+    l0 = jax.lax.pcast(
+        jnp.zeros((B, Sq, H, 1), jnp.float32), axis, to="varying")
+    acc0 = jax.lax.pcast(
+        jnp.zeros((B, Sq, H, hd), jnp.float32), axis, to="varying")
 
     def step(i, carry):
         m, l, acc, k, v, kpos = carry
@@ -140,7 +142,7 @@ def ring_attention(
         )
     spec4 = P(None, axis, None, None)
     spec2 = P(None, axis)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_ring_shard, axis=axis, scale=scale),
         mesh=mesh,
         in_specs=(spec4, spec4, spec4, spec2, spec2),

@@ -1055,20 +1055,26 @@ async def handle_execute(request: web.Request) -> web.Response:
     return web.json_response(payload)
 
 
-def _device_count(app: web.Application) -> int:
-    """Device count, enumerated once and cached on the app: LBs probe
-    /health several times a second and re-importing jax + listing devices
-    per probe is measurable work for an answer that never changes."""
-    devices = app.get("_device_count")
-    if devices is None:
+def _device_info(app: web.Application) -> dict:
+    """What JAX serves from — platform, device kind and count as
+    ``jax.devices()`` reports them — enumerated once and cached on the
+    app: LBs probe /health several times a second and re-importing jax +
+    listing devices per probe is measurable work for an answer that never
+    changes. The platform is here so a server that came up on the CPU
+    (interpreted kernels) cannot pass for one on the chip."""
+    info = app.get("_device_info")
+    if info is None:
         try:
             import jax
 
-            devices = len(jax.devices())
+            devs = jax.devices()
+            info = {"devices": len(devs), "platform": devs[0].platform,
+                    "device_kind": devs[0].device_kind}
         except Exception:
-            return 0   # transient failure: don't cache; retry next probe
-        app["_device_count"] = devices
-    return devices
+            # transient failure: don't cache; retry next probe
+            return {"devices": 0, "platform": "", "device_kind": ""}
+        app["_device_info"] = info
+    return info
 
 
 async def handle_health(request: web.Request) -> web.Response:
@@ -1157,7 +1163,7 @@ async def handle_health(request: web.Request) -> web.Response:
         engine=getattr(svc.engine, "name", "unknown"),
         engine_ready=ready,
         model=svc.cfg.model_name,
-        devices=_device_count(request.app),
+        **_device_info(request.app),
         breaker=breaker,
         degraded_fallback=svc.fallback is not None,
         last_reset=last_reset,
@@ -1580,14 +1586,14 @@ def create_app(cfg: ServiceConfig, engine: Engine,
 
     async def _start_engine(app: web.Application) -> None:
         await app["service"].engine.start()
-        # Warm the /health device-count cache, but only when the engine
+        # Warm the /health device-info cache, but only when the engine
         # already imported jax — a fake/openai deployment must not pay a
         # multi-second jax import before the socket binds (the first
         # health probe fills the cache lazily there instead).
         import sys
 
         if "jax" in sys.modules:
-            _device_count(app)
+            _device_info(app)
 
     async def _stop_engine(app: web.Application) -> None:
         # The DRAIN_TIMEOUT_SECS drain itself runs at signal time in

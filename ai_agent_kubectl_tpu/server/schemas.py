@@ -79,7 +79,11 @@ class HealthResponse(BaseModel):
     engine: str = ""
     engine_ready: bool = False
     model: str = ""
+    # What JAX serves from, as jax.devices() reports it: device count,
+    # platform ("tpu" | "cpu" | ...) and device kind ("TPU v5 lite").
     devices: int = 0
+    platform: str = ""
+    device_kind: str = ""
     # Failure-containment state (server/breaker.py): closed | half-open |
     # open, and whether an open breaker degrades to rule-based responses
     # instead of 503s.

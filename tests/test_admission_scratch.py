@@ -76,7 +76,6 @@ def _mk(**kw):
         prefill_buckets=(64, 512),
         batch_size=4,
         chunk_len=4,
-        compile_cache_dir="",
         # The dense group-admission scratch is what this suite tests;
         # pool mode retires that machinery (suffixes prefill straight
         # into blocks — ISSUE 10, covered by tests/test_kv_pool.py).

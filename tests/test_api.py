@@ -555,17 +555,24 @@ async def test_metrics_label_cardinality_bounded():
         await client.close()
 
 
-async def test_health_device_count_cached_at_startup():
-    """/health serves the device count enumerated once at startup instead
-    of re-importing jax and listing devices on every LB probe."""
+async def test_health_device_info_cached_at_startup():
+    """/health names what JAX serves from — device count, platform and
+    device kind, here the CPU the tests force — enumerated once at
+    startup instead of re-importing jax and listing devices on every LB
+    probe."""
+    import jax
+
     client, _ = await make_client(make_cfg())
     try:
-        cached = client.app["_device_count"]      # set by the startup hook
+        cached = client.app["_device_info"]       # set by the startup hook
+        dev = jax.devices()[0]
+        assert cached == {"devices": len(jax.devices()),
+                          "platform": "cpu", "device_kind": dev.device_kind}
         body = await (await client.get("/health")).json()
-        assert body["devices"] == cached
+        assert {k: body[k] for k in cached} == cached
         # prove the probe reads the cache, not a fresh enumeration
-        client.app["_device_count"] = cached + 7
+        client.app["_device_info"] = dict(cached, devices=cached["devices"] + 7)
         body = await (await client.get("/health")).json()
-        assert body["devices"] == cached + 7
+        assert body["devices"] == cached["devices"] + 7
     finally:
         await client.close()

@@ -55,7 +55,6 @@ async def test_graceful_drain_finishes_inflight_and_rejects_new():
         prefill_buckets=(64, 128),
         batch_size=2,
         chunk_len=4,
-        compile_cache_dir="",
         prefix_cache=False,
     )
     await eng.start()
@@ -84,7 +83,6 @@ async def test_restart_after_drained_stop():
         prefill_buckets=(64,),
         batch_size=2,
         chunk_len=4,
-        compile_cache_dir="",
         prefix_cache=False,
     )
     await eng.start()
@@ -211,7 +209,7 @@ def test_from_config_round_trips_scheduler_shape(monkeypatch):
     eng = BatchedJaxEngine.from_config(cfg)
     assert eng.chunk_len == 16
     assert eng.chunk_pipe_depth == 3
-    # Defaults: chunk 16 (bench-proven, BENCH_r04) / depth 3 (device-side
+    # Defaults: chunk 16 (earlier chip run, not re-measured) / depth 3 (device-side
     # termination made the deeper pipe free on tails — ISSUE 4), with
     # DEVICE_TERMINATION defaulting on.
     monkeypatch.delenv("CHUNK_LEN")

@@ -40,8 +40,7 @@ def toy_batched(**over):
     from ai_agent_kubectl_tpu.models.config import get_config
 
     kw = dict(dtype="float32", max_seq_len=128, prefill_buckets=(64,),
-              batch_size=2, chunk_len=4, prefix_cache=False,
-              compile_cache_dir="")
+              batch_size=2, chunk_len=4, prefix_cache=False)
     kw.update(over)
     return BatchedJaxEngine(get_config("toy-8m"), **kw)
 

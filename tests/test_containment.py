@@ -389,7 +389,7 @@ async def test_containment_metrics_and_health_exposed():
 #: one prefill bucket (every prompt AND every replay prefix fits 16
 #: tokens) keeps the two bs=48 engine startups inside the tier-1 budget.
 JAX_KW = dict(dtype="float32", max_seq_len=64, prefill_buckets=(16,),
-              prefix_cache=False, compile_cache_dir="",
+              prefix_cache=False,
               batch_size=48, chunk_len=4, chunk_pipe_depth=3)
 N_REQS = 52
 TARGET = "pod q7 "

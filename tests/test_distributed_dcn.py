@@ -20,7 +20,6 @@ import subprocess
 import sys
 from pathlib import Path
 
-import jax
 import pytest
 
 REPO = Path(__file__).resolve().parent.parent
@@ -85,13 +84,6 @@ def _free_port() -> int:
     return port
 
 
-@pytest.mark.xfail(
-    jax.__version__.startswith("0.4."),
-    reason="two-process jax.distributed init does not complete on the jax "
-           "0.4.x container toolchain (fails identically at the seed "
-           "commit); passes on current jax — PROFILE.md r6",
-    strict=False,
-)
 def test_two_process_dcn_mesh_and_sharded_forward(tmp_path):
     coord = f"127.0.0.1:{_free_port()}"
     script = tmp_path / "worker.py"

@@ -669,7 +669,7 @@ from ai_agent_kubectl_tpu.models.config import get_config  # noqa: E402
 #: lean geometry — two engine starts must stay cheap on the tier-1 CPU
 #: gate; the full bs=48 acceptance geometry lives in the slow test below.
 JAX_LEAN_KW = dict(dtype="float32", max_seq_len=64, prefill_buckets=(16,),
-                   prefix_cache=False, compile_cache_dir="",
+                   prefix_cache=False,
                    batch_size=4, chunk_len=4, chunk_pipe_depth=3)
 
 
@@ -749,7 +749,7 @@ async def test_jax_fleet_failover_stream_byte_identical():
 # runs outside the tier-1 CPU budget (same rule as the other
 # engine-start-heavy extras).
 JAX_ACC_KW = dict(dtype="float32", max_seq_len=64, prefill_buckets=(16,),
-                  prefix_cache=False, compile_cache_dir="",
+                  prefix_cache=False,
                   batch_size=48, chunk_len=4, chunk_pipe_depth=3)
 N_ACC = 50
 

@@ -82,7 +82,6 @@ async def test_drain_completes_queued_waiter():
         max_seq_len=256,
         prefill_buckets=(64,),
         seed=0,
-        compile_cache_dir="",
         prefix_cache=False,
     )
     await eng.start()
@@ -288,3 +287,40 @@ def test_stream_decoder_position_dependent_tokenizer():
         pieces.append(tail)
     assert "".join(pieces) == full == "kubectl get pods -n staging"
     assert detok.text == full
+
+
+def test_compile_cache_is_placed_from_outside(monkeypatch, tmp_path):
+    """The persistent compilation cache rule (PR 21): on the CPU no
+    cache is configured; off-CPU with JAX_COMPILATION_CACHE_DIR unset,
+    every construction points at the same git-ignored directory inside
+    the checkout; with it set, JAX already holds the directory and the
+    engine sets none in code — only the compile-time threshold."""
+    from pathlib import Path
+
+    import jax
+
+    from ai_agent_kubectl_tpu.config import DEFAULT_COMPILE_CACHE_DIR
+
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: updates.append((name, value)))
+
+    def setup():
+        updates.clear()
+        JaxEngine(get_config("toy-8m"))._setup_compile_cache()
+        return dict(updates)
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert setup() == {}                                  # CPU backend
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    repo = Path(__file__).resolve().parent.parent
+    assert Path(DEFAULT_COMPILE_CACHE_DIR) == repo / ".jax_cache"
+    assert ".jax_cache/" in (repo / ".gitignore").read_text().split()
+    for _ in range(2):
+        assert setup() == {
+            "jax_compilation_cache_dir": DEFAULT_COMPILE_CACHE_DIR,
+            "jax_persistent_cache_min_compile_time_secs": 0.2}
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert setup() == {"jax_persistent_cache_min_compile_time_secs": 0.2}

@@ -71,7 +71,6 @@ def engines():
             prefill_buckets=(64, 128, 256, 512),
             batch_size=4,
             chunk_len=4,
-            compile_cache_dir="",
         )
         asyncio.run(eng.start())
         made[kvq] = eng
@@ -80,13 +79,6 @@ def engines():
         asyncio.run(eng.stop())
 
 
-@pytest.mark.xfail(
-    jax.__version__.startswith("0.4."),
-    reason="toy greedy argmax flip between full and int8-KV paths on jax "
-           "0.4.x CPU numerics; toolchain drift (fails identically at the "
-           "seed commit), passes on current jax — PROFILE.md r6",
-    strict=False,
-)
 async def test_greedy_parity_full_precision_vs_int8_kv(engines):
     from ai_agent_kubectl_tpu.engine.prompts import render_prompt
 
@@ -115,7 +107,6 @@ async def test_int8_kv_paged_falls_back_to_dense():
         prefill_buckets=(64,),
         batch_size=2,
         chunk_len=4,
-        compile_cache_dir="",
         prefix_cache=False,
     )
     await eng.start()
@@ -143,7 +134,6 @@ async def test_int8_kv_serves_under_mesh_with_parity(engines):
         prefill_buckets=(64, 128, 256, 512),
         batch_size=4,
         chunk_len=4,
-        compile_cache_dir="",
     )
     await eng.start()
     try:
@@ -160,13 +150,6 @@ async def test_int8_kv_serves_under_mesh_with_parity(engines):
         await eng.stop()
 
 
-@pytest.mark.xfail(
-    jax.__version__.startswith("0.4."),
-    reason="jax 0.4.x legacy SPMD partitioner rejects the partial-manual "
-           "pipe×tp shard_map mesh (PartitionId); toolchain drift, passes "
-           "on jax>=0.5 — PROFILE.md r6",
-    strict=False,
-)
 def test_int8_kv_stays_enabled_under_pipe_mesh():
     """Round 5 closed the int8-KV x pipe composition gap (VERDICT r4
     item 2): a pipe mesh now serves a QuantKV cache instead of silently
@@ -181,7 +164,6 @@ def test_int8_kv_stays_enabled_under_pipe_mesh():
         prefill_buckets=(64,),
         batch_size=4,
         chunk_len=4,
-        compile_cache_dir="",
         prefix_cache=False,
     )
     asyncio.run(eng.start())

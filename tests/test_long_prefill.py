@@ -105,7 +105,7 @@ async def test_background_warm_compiles_chunked_prefill_ladder(cls,
     # prompts are exercised by test_kv_pool.py.
     kw = ({"batch_size": 2, "chunk_len": 4, "kv_pool": False}
           if cls is BatchedJaxEngine else {})
-    eng = _mk(cls, (32, 64), compile_cache_dir="", **kw)
+    eng = _mk(cls, (32, 64), **kw)
     await eng.start()
     try:
         deadline = asyncio.get_event_loop().time() + 300

@@ -10,7 +10,6 @@ bespoke test harness.
 
 import asyncio
 
-import jax
 import jax.numpy as jnp
 import pytest
 
@@ -20,21 +19,6 @@ from ai_agent_kubectl_tpu.engine.tokenizer import ByteTokenizer
 from ai_agent_kubectl_tpu.models.config import get_config
 
 PROMPTS = ["list pods", "get nodes -o wide", "describe deployment web"]
-
-#: jax 0.4.x toolchain drift (PROFILE.md r6): the legacy SPMD partitioner
-#: rejects partial-manual shard_map meshes with a >1 ``auto`` axis
-#: ("PartitionId ... not supported for SPMD partitioning" on the stage
-#: body's axis_index). Verified to fail identically at the seed commit on
-#: this toolchain and to pass on current jax — version-keyed xfail so
-#: tier-1 signal stays clean without hiding a real regression elsewhere.
-_PARTIAL_MANUAL_DRIFT = pytest.mark.xfail(
-    jax.__version__.startswith("0.4."),
-    reason="jax 0.4.x legacy SPMD partitioner rejects partial-manual "
-           "pp×tp shard_map meshes (PartitionId); toolchain drift, "
-           "passes on jax>=0.5 — PROFILE.md r6",
-    strict=False,
-)
-
 
 def _batched(mesh_shape: str) -> BatchedJaxEngine:
     return BatchedJaxEngine(
@@ -171,7 +155,6 @@ def _batched_dense(mesh_shape: str, **over) -> BatchedJaxEngine:
     return BatchedJaxEngine(get_config("toy-8m"), **kw)
 
 
-@_PARTIAL_MANUAL_DRIFT
 async def test_batched_serving_pp_tp_mesh_greedy_parity():
     """Pipeline-parallel serving (VERDICT r3 item 4): generate() through
     the real engine over a pp=2,tp=2 mesh matches single-device greedy
@@ -209,7 +192,6 @@ async def test_batched_serving_pp_tp_mesh_greedy_parity():
         await eng.stop()
 
 
-@_PARTIAL_MANUAL_DRIFT
 async def test_batched_serving_pp_tp_int8_kv_parity():
     """int8 KV x pipeline parallelism (VERDICT r4 item 2): the pp=2,tp=2
     serving path reads/writes a QuantKV cache through the pipeline stage

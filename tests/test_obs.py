@@ -562,7 +562,6 @@ async def test_batcher_annotates_trace_from_scheduler_thread():
         prefill_buckets=(64,),
         batch_size=2,
         chunk_len=4,
-        compile_cache_dir="",
         prefix_cache=False,
     )
     await eng.start()

@@ -611,7 +611,7 @@ async def test_http_health_and_metrics_expose_qos():
 # ---------------------------------------------------------------------------
 
 JAX_KW = dict(dtype="float32", max_seq_len=64, prefill_buckets=(16,),
-              prefix_cache=False, compile_cache_dir="",
+              prefix_cache=False,
               batch_size=2, chunk_len=4, chunk_pipe_depth=2)
 
 #: (prompt, temperature, seed) — two greedy + two sampled background

@@ -82,7 +82,8 @@ def test_quantized_forward_close_to_dequantized_reference():
 def test_w8a8_qmatmul_close_to_weight_only():
     """QuantInt8W8A8 (per-token activation quant + s8×s8 MXU dot) stays
     within ~1% of the weight-only dequant reference. Measured a speed
-    no-op on the 7B geometry (PROFILE.md r4) — kept as a library option."""
+    no-op on the 7B geometry (earlier chip run, not re-measured) — kept as a library
+    option."""
     from ai_agent_kubectl_tpu.ops.quant import QuantInt8W8A8, to_w8a8
 
     w = jax.random.normal(jax.random.PRNGKey(5), (64, 32), jnp.float32)
@@ -190,7 +191,7 @@ async def test_int8_embed_serves_under_mesh_with_parity():
             eng = BatchedJaxEngine(
                 cfg, dtype="float32", quant="int8", mesh_shape=mesh_shape,
                 max_seq_len=128, prefill_buckets=(64,), batch_size=2,
-                chunk_len=4, compile_cache_dir="", prefix_cache=False,
+                chunk_len=4, prefix_cache=False,
             )
             await eng.start()
             try:

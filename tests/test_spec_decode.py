@@ -353,7 +353,7 @@ def _mk_jax(**kw):
 
     defaults = dict(dtype="float32", max_seq_len=192,
                     prefill_buckets=(32, 64), prefix_cache=False,
-                    compile_cache_dir="", batch_size=4, chunk_len=4)
+                    batch_size=4, chunk_len=4)
     defaults.update(kw)
     return BatchedJaxEngine(get_config("toy-8m"), **defaults)
 

@@ -46,7 +46,6 @@ def _mk(mesh_shape: str = "", **over) -> BatchedJaxEngine:
         prefill_buckets=(32, 64),
         attn_impl="dense",
         prefix_cache=False,
-        compile_cache_dir="",
         mesh_shape=mesh_shape,
         batch_size=4,
         chunk_len=4,

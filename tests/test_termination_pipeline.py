@@ -226,7 +226,7 @@ async def test_metrics_and_debug_chunks_expose_pipeline():
 # ---------------------------------------------------------------------------
 
 ENGINE_KW = dict(dtype="float32", max_seq_len=128, prefill_buckets=(32,),
-                 prefix_cache=False, compile_cache_dir="",
+                 prefix_cache=False,
                  batch_size=3, chunk_len=4)
 
 

@@ -42,7 +42,6 @@ def _mk(mesh_shape: str, **over) -> BatchedJaxEngine:
         prefill_buckets=(32, 64),
         attn_impl="dense",
         prefix_cache=False,
-        compile_cache_dir="",
         mesh_shape=mesh_shape,
         batch_size=4,
         chunk_len=4,
@@ -84,6 +83,8 @@ async def test_pool_serves_under_tp8_mesh_byte_identical():
         assert sh["devices"] == 8
         assert sh["pool_sharded"] is True
         assert sh["kv_pool_mesh_fallback"] is False
+        # ... and so are the weights: one device holds an eighth of wq.
+        assert sh["weights_shard_fraction"] == 1.0 / 8
         assert eng.stats()["sharding"] == sh
 
         outs = await asyncio.gather(*[

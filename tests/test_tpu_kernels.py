@@ -177,3 +177,127 @@ def test_int8_convert_fuses_into_weight_read():
         "int8 weight convert materialized a full bf16 weight copy:\n"
         + "\n".join(materialized)
     )
+
+
+# ------------------------------------------- block-pool kernels (PR 21)
+#
+# The two kernels that read the shared block pool through per-slot
+# tables — the ragged kernel carries the whole pool serving path on TPU
+# (RAGGED_ATTENTION=auto), the single-query paged kernel serves
+# RAGGED_ATTENTION=off — at the geometry chip_smoke.py serves:
+# Llama-3-8B heads (H 32, KV 8, hd 128), the page DECODE_ATTN=auto picks
+# on TPU (64), the per-slot table of MAX_SEQ_LEN=1024, and the same
+# model's tp=4 shard (H 8, KV 2).
+
+_POOL_GEOMETRIES = ((32, 8), (8, 2))      # (H, KV)
+_POOL_HD, _POOL_PAGE, _POOL_PAGES = 128, 64, 17
+#: every window width the engine warms a ragged program for: decode (1),
+#: a spec verify window (k+1 = 4), and the default PREFILL_BUCKETS.
+_WARMED_WIDTHS = (1, 4, 64, 128, 256, 512, 1024)
+
+
+def _pool_case(H, KV, W, spans, seed):
+    """A block pool plus per-slot tables for ``spans`` = [(position,
+    q_len), ...]: every slot maps exactly the pages its live rows need
+    and the sentinel elsewhere, slot 1 shares slot 0's first block (a
+    radix-shared prefix page), and the block the sentinel clamps to is
+    NaN in the pool the kernel reads — a fetch that escaped the
+    dead-page clamp poisons the output. Returns the kernel operands and
+    a NaN-free copy of the pool for the reference."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    N = len(spans)
+    n_blocks = N * _POOL_PAGES + 1
+    k = _rand((n_blocks, _POOL_PAGE, KV, _POOL_HD), seed, jnp.bfloat16)
+    v = _rand((n_blocks, _POOL_PAGE, KV, _POOL_HD), seed + 1, jnp.bfloat16)
+    q = _rand((N, W, H, _POOL_HD), seed + 2, jnp.bfloat16)
+    tables = np.full((N, _POOL_PAGES), n_blocks + 5, np.int32)
+    for n, (pos, q_len) in enumerate(spans):
+        live = -(-(pos + max(q_len, 1)) // _POOL_PAGE)
+        tables[n, :live] = n * _POOL_PAGES + np.arange(live)
+    tables[1, 0] = tables[0, 0]
+    dead = n_blocks - 1
+    clean = (k.at[dead].set(0), v.at[dead].set(0))
+    poisoned = (k.at[dead].set(jnp.nan), v.at[dead].set(jnp.nan))
+    positions = jnp.asarray([s[0] for s in spans], jnp.int32)
+    q_lens = jnp.asarray([s[1] for s in spans], jnp.int32)
+    return q, poisoned, clean, q_lens, positions, jnp.asarray(tables)
+
+
+def _gather_reference(q, k, v, q_lens, positions, tables):
+    """The dense gather path (models/transformer.py::_pool_gather +
+    dense_attention, causal-in-window mask) in float32."""
+    import jax
+    import jax.numpy as jnp
+
+    from ai_agent_kubectl_tpu.models.transformer import _pool_gather
+    from ai_agent_kubectl_tpu.ops.attention import dense_attention
+
+    W = q.shape[1]
+    kv_len = _POOL_PAGES * _POOL_PAGE
+    cols = jnp.arange(W)[None, :, None]
+    kv_pos = jnp.arange(kv_len)[None, None, :]
+    mask = jnp.logical_and(kv_pos <= positions[:, None, None] + cols,
+                           cols < q_lens[:, None, None])
+    with jax.default_matmul_precision("highest"):
+        return dense_attention(
+            q.astype(jnp.float32),
+            _pool_gather(k, tables, _POOL_PAGES).astype(jnp.float32),
+            _pool_gather(v, tables, _POOL_PAGES).astype(jnp.float32),
+            mask)
+
+
+@pytest.mark.parametrize("H,KV", _POOL_GEOMETRIES)
+@pytest.mark.parametrize("W", _WARMED_WIDTHS)
+def test_compiled_ragged_pool_matches_gather(H, KV, W):
+    """Compiled ragged kernel vs the dense gather reference, one call
+    carrying a decode row, a spec verify window, a fresh full-span
+    prefill, a frozen slot, a partial span behind a shared prefix page
+    (its last query tile half empty) and a full span at an unaligned
+    offset — at every window width the engine warms."""
+    import numpy as np
+
+    from ai_agent_kubectl_tpu.ops.ragged_attention import \
+        ragged_attention_pool
+
+    spans = [(700, 1), (333, min(W, 4)), (0, W), (700, 0),
+             (_POOL_PAGE, W - W // 3), (37, W)]
+    q, (k, v), clean, q_lens, positions, tables = _pool_case(
+        H, KV, W, spans, seed=30)
+    out = np.asarray(ragged_attention_pool(
+        q, k, v, q_lens, positions, tables, page_size=_POOL_PAGE,
+        interpret=False)).astype(np.float32)
+    assert np.isfinite(out).all(), "a dead page leaked into the output"
+    ref = np.asarray(_gather_reference(q, *clean, q_lens, positions,
+                                       tables))
+    for n, (_pos, q_len) in enumerate(spans):
+        np.testing.assert_allclose(
+            out[n, :q_len], ref[n, :q_len], rtol=3e-2, atol=3e-2,
+            err_msg=f"slot {n} (q_len={q_len}, W={W})")
+        assert not out[n, q_len:].any(), f"slot {n}: padding rows not zero"
+
+
+@pytest.mark.parametrize("H,KV", _POOL_GEOMETRIES)
+def test_compiled_paged_pool_matches_gather(H, KV):
+    """Compiled block-table decode kernel vs the dense gather reference
+    over a full batch of ragged positions (first row of a sequence, page
+    edges, the last row the table can hold)."""
+    import numpy as np
+
+    from ai_agent_kubectl_tpu.ops.paged_attention import \
+        paged_decode_attention_pool
+
+    edge = [0, _POOL_PAGE - 1, _POOL_PAGE, _POOL_PAGES * _POOL_PAGE - 1]
+    rng = np.random.RandomState(1)
+    spans = [(int(p), 1) for p in edge + list(
+        rng.randint(0, _POOL_PAGES * _POOL_PAGE, 32 - len(edge)))]
+    q, (k, v), clean, q_lens, positions, tables = _pool_case(
+        H, KV, 1, spans, seed=40)
+    out = np.asarray(paged_decode_attention_pool(
+        q[:, 0], k, v, positions, tables, page_size=_POOL_PAGE,
+        interpret=False)).astype(np.float32)
+    assert np.isfinite(out).all(), "a dead page leaked into the output"
+    ref = np.asarray(_gather_reference(q, *clean, q_lens, positions,
+                                       tables))[:, 0]
+    np.testing.assert_allclose(out, ref, rtol=3e-2, atol=3e-2)

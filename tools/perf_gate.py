@@ -1,13 +1,12 @@
 """Bench-trajectory perf gate: compare a fresh bench artifact against
-the BENCH_r*.json numbers of record.
+a trajectory of earlier ones.
 
-Five BENCH artifacts sat on disk gating nothing: a checkpoint, config,
-or scheduler change that halved throughput would sail through CI and
-only surface when a human next ran ``bench.py`` and happened to compare
-by eye. This tool is the comparison, mechanized:
+A checkpoint, config, or scheduler change that halved throughput would
+sail through CI and only surface when a human next ran ``bench.py`` and
+happened to compare by eye. This tool is the comparison, mechanized:
 
     python tools/perf_gate.py --artifact NEW.json \
-        --trajectory BENCH_r01.json BENCH_r02.json ...
+        --trajectory OLD_1.json OLD_2.json ...
 
 For every known metric the gate derives a **reference** from the
 trajectory — the best value any trajectory artifact recorded (bench
@@ -30,8 +29,8 @@ phase the orchestrator recorded as ``{"status": "timeout"|"error"}``
 dead phase as a pass.
 
 Artifacts are accepted in either form: the raw ``bench.py`` orchestrator
-dict (``{"metric", "value", "extra": {...}}``) or the driver-wrapped
-``BENCH_r*.json`` (``{"parsed": {...}}``).
+dict (``{"metric", "value", "extra": {...}}``) or a driver-wrapped
+one (``{"parsed": {...}}``).
 
 Exit status: 0 = every judged metric passed; 1 = any failure; 2 = no
 judgeable metric (an empty comparison must not read as a pass).
@@ -210,7 +209,7 @@ def main() -> int:
         description="Gate a bench artifact against the BENCH trajectory")
     ap.add_argument("--artifact", required=True,
                     help="fresh bench artifact (orchestrator JSON or "
-                         "driver-wrapped BENCH_r*.json)")
+                         "driver-wrapped one)")
     ap.add_argument("--trajectory", nargs="+", required=True,
                     help="trajectory artifacts, oldest first (the "
                          "newest defines which metrics are required)")

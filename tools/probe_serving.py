@@ -1,4 +1,4 @@
-"""Serving-path attribution probe (the tool behind PROFILE.md round 4).
+"""Serving-path attribution probe.
 
 Two measurements `tools/profile_decode.py` can't make (it builds bf16
 params from scratch; this builds the REAL engine, including QUANT /
@@ -738,22 +738,20 @@ async def main() -> None:
         return jnp.ones((args.bs,), jnp.bool_), jnp.zeros((args.bs,),
                                                           jnp.int32)
 
-    from _bench_sync import force_sync as _sync
-
     for kv_b in eng._kv_buckets:
         fn = eng._batch_chunk_fns[kv_b]
         active, ngen = all_live()
         packed, tokd, posd, cache, _, _ = fn(
             eng.params, tokd, posd, cache, seeds, temps, force, active, ngen,
             budget, no_corrupt)
-        _sync(packed)
+        jax.block_until_ready(packed)
         t0 = time.monotonic()
         for _ in range(args.reps):
             active, ngen = all_live()
             packed, tokd, posd, cache, _, _ = fn(
                 eng.params, tokd, posd, cache, seeds, temps, force, active,
                 ngen, budget, no_corrupt)
-        _sync(packed)
+        jax.block_until_ready(packed)
         dt = (time.monotonic() - t0) / args.reps
         per_step = dt / eng.chunk_len * 1000
         log(f"probe[ceiling]: kv_bucket={kv_b}: chunk={dt*1000:.1f}ms"

@@ -1,11 +1,12 @@
 """TP=8 throughput projection from measured single-chip numbers.
 
-BASELINE.md's v5e-8 row claimed the Megatron shard "lands well past the
-2k/chip clause" with no arithmetic shown; the judge's own arithmetic
-disagreed (VERDICT r5 weak #2). This tool IS the arithmetic: a per-chip
-step model priced from the decode-step attribution table (or the r5
-measured defaults), with every assumption a flag, emitting the markdown
-that BASELINE.md pastes instead of the adjective.
+An earlier v5e-8 claim that the Megatron shard "lands well past the
+2k/chip clause" came with no arithmetic shown, and a reviewer's own
+arithmetic disagreed. This tool IS the arithmetic: a per-chip step model
+priced from the decode-step attribution table (or the defaults of an
+earlier chip run, not re-measured), with every assumption a flag,
+emitting a markdown table in place of the adjective. Its output is a
+projection, never a measurement (ROADMAP D7).
 
 Model (per decode step, Megatron TP over ``--tp`` chips):
 
@@ -208,7 +209,7 @@ def extract_acceptance(bench: dict):
 def render_acceptance(a, acc: dict, rungs: list, out: dict) -> str:
     """The Spec×TP composed section (ISSUE 18): the measured TP step
     price x the measured acceptance ratio, derived in one place so
-    BASELINE.md quotes arithmetic instead of an adjective.
+    the claim is arithmetic instead of an adjective.
 
     Per verify window the mesh pays one (k+1)-wide target step (the
     memory-bound weight stream is read once, same as a decode step)
