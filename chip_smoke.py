@@ -37,9 +37,13 @@ chip_smoke.py`` on a four-chip host); everything else is pinned below.
 with ``JAX_PLATFORMS=cpu`` and skips only the ``tpu``/``ragged``
 assertions, so the command can be debugged where there is no chip.
 
-Last line of stdout on success, one JSON object:
-``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": ...},
-...}`` with the device as the child's ``jax.devices()`` reports it.
+On success stdout is two lines, each one JSON object. First the report:
+model, quant, batch, regime, seconds to ready, compile-cache directory and
+entry counts, requests, tokens, wall per request, downgrade lines seen
+(also written to ``chiprun_out/chip_smoke_report.json``). Then, LAST, the
+result and nothing else — exactly these keys:
+``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": ...}}``
+with the device as the child's ``jax.devices()`` reports it.
 """
 
 from __future__ import annotations
@@ -98,7 +102,7 @@ def long_query(toy: bool) -> str:
         for i in range(16 if toy else 58))
 
 
-#: Server log lines worth repeating in the result: every attention or
+#: Server log lines worth repeating in the report: every attention or
 #: device downgrade the engine takes is logged with one of these.
 DOWNGRADE_RE = re.compile(
     r"fall(?:ing|s)? back|fallback|gather path|using dense|unsupported|"
@@ -234,7 +238,7 @@ def ask_stream(base: str, query: str) -> str:
 
 def drive(base: str, toy: bool):
     """The request phases; any miss raises. Returns ``(commands, stats)``:
-    the answers in a fixed order, and the counts and timings the result
+    the answers in a fixed order, and the counts and timings the report
     line carries."""
     with concurrent.futures.ThreadPoolExecutor(len(SHORT_QUERIES)) as pool:
         futures = [pool.submit(ask, base, q) for q in SHORT_QUERIES]
@@ -370,7 +374,6 @@ def run(toy: bool, ready_timeout: float, log_path: Path) -> dict:
     (log_path.parent / "chip_smoke_commands.json").write_text(
         json.dumps(commands, indent=1))
     return {
-        "ok": True,
         "device": device,
         "model": health["model"],
         "quant": "int8",
@@ -413,16 +416,20 @@ def main() -> int:
     OUT_DIR.mkdir(exist_ok=True)
     log_path = OUT_DIR / "chip_smoke_server.log"
     try:
-        result = run(args.cpu_toy, args.ready_timeout, log_path)
+        report = run(args.cpu_toy, args.ready_timeout, log_path)
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         if log_path.exists():
             tail = log_path.read_text(errors="replace")[-6000:]
             print(f"--- tail of {log_path} ---\n{tail}", file=sys.stderr)
         return 1
-    for line in result["downgrades"]:
+    for line in report["downgrades"]:
         print(f"chip_smoke: server log: {line}", file=sys.stderr)
-    print(json.dumps(result))
+    (OUT_DIR / "chip_smoke_report.json").write_text(json.dumps(report))
+    print(json.dumps(report))
+    # The last line is the result, with exactly these keys; everything
+    # else the run learned is in the report line above it.
+    print(json.dumps({"ok": True, "device": report["device"]}))
     return 0
 
 

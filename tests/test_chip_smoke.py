@@ -49,10 +49,14 @@ def test_chip_smoke_fails_alone_in_a_directory(tmp_path):
 def test_chip_smoke_cpu_toy_end_to_end():
     r = _run(SMOKE, "--cpu-toy", timeout=900)
     assert r.returncode == 0, r.stderr[-4000:]
-    result = json.loads(r.stdout.splitlines()[-1])
-    assert result["ok"] is True
+    report, result = map(json.loads, r.stdout.splitlines())
+    # The last line is the driver's contract: these keys and no others.
+    assert result == {"ok": True, "device": report["device"]}
+    assert set(result["device"]) == {"platform", "kind", "count"}
     assert result["device"]["platform"] == "cpu"
-    assert result["model"] == "toy-8m"
-    assert result["requests"] == {"sent": 11, "succeeded": 11}
-    assert result["tokens_generated"] >= result["engine_served"]
-    assert result["compile_cache"]["dir"] is None
+    assert isinstance(result["device"]["kind"], str)
+    assert isinstance(result["device"]["count"], int)
+    assert report["model"] == "toy-8m"
+    assert report["requests"] == {"sent": 11, "succeeded": 11}
+    assert report["tokens_generated"] >= report["engine_served"]
+    assert report["compile_cache"]["dir"] is None
