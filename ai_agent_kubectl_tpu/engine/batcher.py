@@ -56,7 +56,7 @@ from ..obs.slo import (SLO_QUEUE_WAIT, SLO_SESSION_TTFT, SLO_TTFT,
 from ..obs.steptime import (PHASE_DECODE, PHASE_PREFILL,
                             PHASE_SPEC_VERIFY, StepTimeSentinel,
                             prefill_bucket)
-from ..obs.trace import Trace, current_trace
+from ..obs.trace import EngineSpans, RequestSpans, Trace, current_trace
 from ..ops.quant import (kv_broadcast_rows, kv_set_slots, kv_slot_update,
                          kv_tokens, kv_update_slice)
 from .containment import (CAUSE_SCHEDULER_DEATH, CAUSE_SCHEDULER_ERROR,
@@ -799,6 +799,10 @@ class _Request:
     # the flight-recorder timeline shows admissions/first-token/finish
     # as the scheduler saw them.
     trace: Optional[Trace] = None
+    # The request's engine phases (obs/trace.py RequestSpans), stamped by
+    # the scheduler as each boundary is crossed; made at first use
+    # (EngineSpans.of) so every constructor of a _Request gets one.
+    spans: Optional[RequestSpans] = None
     # Per-request sampling seed (ISSUE 5): every sampled token is drawn
     # from fold_in(PRNGKey(seed), generation_index) — engine/sampling.py
     # slot_keys — so the token stream is a pure function of (seed,
@@ -1253,6 +1257,14 @@ class BatchedJaxEngine(JaxEngine):
         self._fetch_samples: collections.deque = collections.deque(maxlen=4096)
         self._last_n_alive = 0
         self._chunk_log: collections.deque = collections.deque(maxlen=512)
+        # Engine-side spans (obs/trace.py): cumulative per-name totals for
+        # /health.spans; the scheduler thread's own intervals, which go
+        # into the ring above and, as TraceAnnotations, into a
+        # /debug/profile capture on the device ops' clock; and the stamp
+        # the queue_wait span splits on — when the free-slot count last
+        # left 0 (None while no slot is free).
+        self._spans = EngineSpans(self._chunk_log,
+                                  annotate=jax.profiler.TraceAnnotation)
         # Fair-share admission (the ISSUE 7 tentpole): weighted
         # deficit-round-robin over per-tenant sub-queues replaces the
         # FIFO queue.Queue — same put/get/qsize surface, plus per-tenant
@@ -2637,6 +2649,7 @@ class BatchedJaxEngine(JaxEngine):
         wait_ms = (t_adm - req.t_submit) * 1000.0
         self._brownout.note_queue_wait(req.lane, wait_ms, now=t_adm)
         self._slo.note(SLO_QUEUE_WAIT, req.lane, wait_ms, now=t_adm)
+        spans = self._spans.admitted(req, t_adm)
 
         ids = list(req.prompt_ids)
         max_prompt = self.max_seq_len - max(1, req.max_tokens)
@@ -2776,6 +2789,9 @@ class BatchedJaxEngine(JaxEngine):
                 f"tokens, {m} radix-matched, "
                 f"{pages_for(n_prompt, self.kv_pool_page)} pool blocks)")
         self._slots[slot_idx] = slot
+        self._spans.note_slots(self._slots)
+        prefill_meta = dict(prompt_tokens=n_prompt, prefix_hit_tokens=m,
+                            staged_w=len(staged["ids"]) if staged else 0)
         if run:
             t_dk = time.monotonic()
             piece = slot.detok.push(*run)
@@ -2794,6 +2810,8 @@ class BatchedJaxEngine(JaxEngine):
                     f"spliced with the prompt prefill")
         if done_at_admit:
             slot.t_first = time.monotonic()
+            spans.staged(slot.t_first, prefill=prefill_meta,
+                         blocks=len(blocks))
             self._finish(slot_idx,
                          "stop" if ends_eos
                          and len(run) < req.max_tokens else "length")
@@ -2807,10 +2825,14 @@ class BatchedJaxEngine(JaxEngine):
             # the ragged admission width.
             self._pending_adm[slot_idx] = staged
             self._last_admit_t = time.monotonic()
+            spans.staged(self._last_admit_t, prefill=prefill_meta,
+                         blocks=len(blocks))
             return
         self._to_host_async(first_tok_d)
         self._inflight.append(("first", first_tok_d, req, slot_idx))
         self._last_admit_t = time.monotonic()
+        spans.staged(self._last_admit_t, prefill=prefill_meta,
+                     chunks_ahead=self._chunks_in_pipe(), blocks=len(blocks))
 
     def _pool_warmup(self) -> None:
         """Eager startup warm of the pool serving programs: the smallest
@@ -2929,6 +2951,13 @@ class BatchedJaxEngine(JaxEngine):
         logger.info(
             "Radix cache preloaded: %d-token system prompt resident in "
             "%d pool blocks", P, need)
+
+    def _chunks_in_pipe(self) -> int:
+        return sum(1 for e in self._inflight if e[0] == "chunk")
+
+    def spans_health(self) -> dict:
+        """/health.spans (obs/trace.py EngineSpans.health)."""
+        return self._spans.health(self._chunks_consumed)
 
     def sharding_health(self) -> Optional[dict]:
         """Cheap sharding view for /health (ISSUE 14; host attributes
@@ -3738,6 +3767,7 @@ class BatchedJaxEngine(JaxEngine):
                     # _supervise_scheduler's restart is what recovers.
                     self.faults.check_scheduler_die()
                 self._last_progress = time.monotonic()
+                self._spans.note_slots(self._slots)
                 # Bisection probation: the parked half is exonerated when
                 # the probe group fully drains (no slots, no pipeline) —
                 # or earlier, after PROBATION_CLEAN_CHUNKS clean chunks in
@@ -3761,9 +3791,7 @@ class BatchedJaxEngine(JaxEngine):
                 n_active = sum(
                     s is not None and not s.exhausted for s in self._slots
                 )
-                chunks_in_pipe = sum(
-                    1 for e in self._inflight if e[0] == "chunk"
-                )
+                chunks_in_pipe = self._chunks_in_pipe()
                 # Latency mode at low occupancy: deliver a fresh admission's
                 # first token before launching speculative decode chunks —
                 # the transfer otherwise queues behind a full chunk's
@@ -3799,7 +3827,8 @@ class BatchedJaxEngine(JaxEngine):
                             self._ramp_hold_t0 = now
                         if now - self._ramp_hold_t0 < self.ADMIT_RAMP_MAX_SECS:
                             if self._admissions.empty():
-                                time.sleep(0.002)
+                                with self._spans.sched.region("idle"):
+                                    time.sleep(0.002)
                             continue
                     self._ramp_hold_t0 = None
                     self._dispatch_chunk()
@@ -3814,7 +3843,8 @@ class BatchedJaxEngine(JaxEngine):
                 # instead of tripping the scheduler-error path that fails
                 # every active slot.
                 try:
-                    req = self._admissions.get(timeout=0.05)
+                    with self._spans.sched.region("idle"):
+                        req = self._admissions.get(timeout=0.05)
                 except _queue.Empty:
                     continue
                 self._admitting += 1
@@ -3860,12 +3890,15 @@ class BatchedJaxEngine(JaxEngine):
         thread exit so _supervise_scheduler notices the corpse and
         restarts it. Never re-raises: a dead scheduler is a recoverable
         engine event, not a process event."""
+        self._spans.sched.start()
         try:
             self._worker_loop()
         except BaseException:
             logger.critical(
                 "batch scheduler thread died; supervisor will restart it",
                 exc_info=True)
+        finally:
+            self._spans.sched.stop()
 
     # ------------------------------------------- containment (ISSUE 5)
 
@@ -4420,6 +4453,7 @@ class BatchedJaxEngine(JaxEngine):
         req = slot.req
         req.preempt_count += 1
         req.preempt_t0 = time.monotonic()
+        self._spans.of(req).requeued(req.preempt_t0)
         ids = list(slot.detok.ids)
         req.resume_ids = ids or None
         # The client already holds detok.text; the resume emission skips
@@ -4593,6 +4627,12 @@ class BatchedJaxEngine(JaxEngine):
             self._admitting -= len(pending)
 
     def _admit_popped(self, pending: List[_Request]) -> None:
+        with self._spans.sched.region("admit", "admit",
+                                      chunk=self._chunks_dispatched + 1,
+                                      requests=len(pending)):
+            self._admit_popped_in_span(pending)
+
+    def _admit_popped_in_span(self, pending: List[_Request]) -> None:
         # Every request popped off the queue MUST reach either a slot or an
         # error event — an exception mid-burst (e.g. OOM allocating the
         # group scratch) may not silently drop the rest of the burst, or
@@ -4819,6 +4859,7 @@ class BatchedJaxEngine(JaxEngine):
             wait_ms = (t_adm - req.t_submit) * 1000.0
             self._brownout.note_queue_wait(req.lane, wait_ms, now=t_adm)
             self._slo.note(SLO_QUEUE_WAIT, req.lane, wait_ms, now=t_adm)
+            self._spans.admitted(req, t_adm)
 
         # Suffix-depth scratch: kv_limit positions hold everything a
         # suffix admission writes (prefix.n + sbucket, tile-rounded); the
@@ -4892,6 +4933,12 @@ class BatchedJaxEngine(JaxEngine):
         self._inflight.append(("firsts", first_toks_d, pairs))
         self._group_admitted += 1
         self._last_admit_t = time.monotonic()
+        self._spans.note_slots(self._slots)
+        for i, req in enumerate(live):
+            req.spans.staged(
+                self._last_admit_t, chunks_ahead=self._chunks_in_pipe(),
+                prefill=dict(prompt_tokens=int(n_prompts[i]),
+                             prefix_hit_tokens=prefix.n, staged_w=0))
 
     def _admit_one(self, req: _Request) -> None:
         """Dispatch-only admission: prefill → device-side first-token
@@ -4918,6 +4965,7 @@ class BatchedJaxEngine(JaxEngine):
         wait_ms = (t_adm - req.t_submit) * 1000.0
         self._brownout.note_queue_wait(req.lane, wait_ms, now=t_adm)
         self._slo.note(SLO_QUEUE_WAIT, req.lane, wait_ms, now=t_adm)
+        spans = self._spans.admitted(req, t_adm)
 
         last_logits, scratch, n_prompt, prefix_hit = self._prefill_prompt(
             req.prompt_ids, req.max_tokens
@@ -4971,6 +5019,12 @@ class BatchedJaxEngine(JaxEngine):
         self._to_host_async(first_tok_d)
         self._inflight.append(("first", first_tok_d, req, slot_idx))
         self._last_admit_t = time.monotonic()
+        self._spans.note_slots(self._slots)
+        spans.staged(
+            self._last_admit_t, chunks_ahead=self._chunks_in_pipe(),
+            prefill=dict(
+                prompt_tokens=n_prompt, staged_w=0,
+                prefix_hit_tokens=self._prefix.n if prefix_hit else 0))
 
     def _admit_resume(self, req: _Request) -> None:
         """Cross-replica import (fleet migration): seat a request that
@@ -4985,6 +5039,7 @@ class BatchedJaxEngine(JaxEngine):
         without import support (replay-from-scratch) behave identically
         from the fleet's view."""
         t_adm = time.monotonic()
+        spans = self._spans.admitted(req, t_adm)
         detok = StreamDecoder(self.tokenizer)
         piece = detok.push(*req.resume_ids)
         if req.resume_emitted:
@@ -5025,6 +5080,12 @@ class BatchedJaxEngine(JaxEngine):
             self._finish(slot_idx, "length")
             return
         self._replay_slot(slot)
+        # The replay armed the slot from the imported prefix: the next
+        # chunk's first row is this segment's first token.
+        self._spans.note_slots(self._slots)
+        spans.staged(time.monotonic(), chunks_ahead=self._chunks_in_pipe(),
+                     prefill=dict(prompt_tokens=len(req.prompt_ids),
+                                  resumed_tokens=len(req.resume_ids)))
 
     def _consume_first(self, first_tok: int, req: _Request,
                        slot_idx: int) -> None:
@@ -5050,6 +5111,7 @@ class BatchedJaxEngine(JaxEngine):
             now - slot.t_admit, tokens=slot.n_prompt, now=now)
         if req.trace is not None:
             req.trace.event("engine: first token")
+        self._spans.of(req).first_token(now)
         if first_tok in self.model_cfg.eos_ids:
             # The device can't see a first-token EOS (the admission program
             # samples it blind) — speculative chunks already in flight
@@ -5145,6 +5207,15 @@ class BatchedJaxEngine(JaxEngine):
         return packed
 
     def _dispatch_chunk(self) -> None:
+        """One ``sched/dispatch`` interval: array staging + the program
+        call, until the call returns (the device runs on). ``slots`` 0
+        marks a dispatch that found nothing left to run."""
+        with self._spans.sched.region("dispatch", "dispatch",
+                                      chunk=self._chunks_dispatched + 1,
+                                      slots=0) as entry:
+            self._dispatch_chunk_in_span(entry)
+
+    def _dispatch_chunk_in_span(self, entry: dict) -> None:
         if self.faults is not None:
             # A "chunk" hang blocks this (scheduler) thread exactly like a
             # hung device dispatch — the watchdog's target scenario.
@@ -5175,6 +5246,7 @@ class BatchedJaxEngine(JaxEngine):
                       if self._slots[i] is not None
                       and not self._slots[i].exhausted}
             self._pending_adm.clear()
+        t_disp = time.monotonic()
         if staged:
             longest = max(len(e["ids"]) for e in staged.values())
             adm_w = next(b for b in self.prefill_buckets if b >= longest)
@@ -5292,14 +5364,17 @@ class BatchedJaxEngine(JaxEngine):
             s.chunks_inflight += 1
             s.decode_chunks_inflight += 1
         self._to_host_async(packed_d)  # overlap the transfer (see _admit_one)
-        self._inflight.append(("chunk", packed_d, snapshot, ct, spec))
+        chunks_ahead = self._chunks_in_pipe()
         self._chunks_dispatched += 1
-        self._chunk_log.append({
-            "t": time.time(), "event": "dispatch", "kv_bucket": bucket,
-            "slots": len(active_slots),
-            "admissions": len(staged),
-            "pipe": sum(1 for e in self._inflight if e[0] == "chunk"),
-        })
+        self._inflight.append(("chunk", packed_d, snapshot, ct, spec,
+                               self._chunks_dispatched))
+        for i in staged:
+            # This chunk carries slot i's prologue: its stage_wait ends
+            # where this dispatch began, behind the chunks already queued.
+            self._spans.of(self._slots[i].req).dispatched(
+                t_disp, self._chunks_dispatched, chunks_ahead, adm_w=adm_w)
+        entry.update(kv_bucket=bucket, slots=len(active_slots),
+                     admissions=len(staged), pipe=chunks_ahead + 1)
 
     # ----------------------------------------------------------- watchdog
 
@@ -5406,23 +5481,24 @@ class BatchedJaxEngine(JaxEngine):
                     if snap is not None:
                         self._bill_waste(self.chunk_len, snap)
             self._chunks_pruned += 1
-            self._chunk_log.append({"t": time.time(), "event": "prune"})
+            self._spans.sched.mark("prune", chunk=entry[5])
 
     def _consume_oldest(self) -> None:
         self._last_progress = time.monotonic()
         self._first_consumed = True    # cold-start watchdog grace ends
         entry = self._inflight.pop(0)
-        if entry[0] == "first":
-            _, tok_d, req, slot_idx = entry
-            self._consume_first(int(self._fetch(tok_d)[0]), req, slot_idx)
+        if entry[0] in ("first", "firsts"):
+            # An admission program's first token(s): one fetch for the
+            # whole group, then each slot's delivery.
+            with self._spans.sched.region("fetch_wait", "fetch", first=True):
+                vals = self._fetch(entry[1])
+            pairs = ([(entry[2], entry[3])] if entry[0] == "first"
+                     else entry[2])
+            with self._spans.sched.region("consume", "consume", first=True):
+                for (req, slot_idx), v in zip(pairs, vals):
+                    self._consume_first(int(v), req, slot_idx)
             return
-        if entry[0] == "firsts":
-            _, toks_d, pairs = entry
-            vals = self._fetch(toks_d)  # one fetch for the whole group
-            for (req, slot_idx), v in zip(pairs, vals):
-                self._consume_first(int(v), req, slot_idx)
-            return
-        _, packed_d, snapshot, ct, is_spec = entry
+        _, packed_d, snapshot, ct, is_spec, chunk_no = entry
         if self.faults is not None:
             # decode:poison_step — a step-wide fault thrown from the
             # chunk fetch (no slot named): the widened scheduler except
@@ -5435,19 +5511,26 @@ class BatchedJaxEngine(JaxEngine):
         # fetch (protocol.py v3). ``ct`` is the entry's own row width
         # (a draft:die mid-pipe leaves spec-width chunks in flight
         # ahead of plain-width ones).
-        t_fetch = time.monotonic()
-        res = unpack_chunk(self._fetch(packed_d), self.batch_size, ct,
-                           spec=is_spec)
-        fetch_s = time.monotonic() - t_fetch
-        self._fetch_samples.append(fetch_s)
+        with self._spans.sched.region("fetch_wait", "fetch",
+                                      chunk=chunk_no) as fetched:
+            buf = self._fetch(packed_d)
+        # sched/fetch's two stamps, measured once: the same interval is
+        # the chunk_fetch_seconds sample.
+        self._fetch_samples.append(fetched["ms"] / 1000.0)
+        with self._spans.sched.region("consume", "consume", chunk=chunk_no,
+                                      fetch_ms=fetched["ms"],
+                                      pipe=self._chunks_in_pipe()) as consumed:
+            res = unpack_chunk(buf, self.batch_size, ct, spec=is_spec)
+            consumed["n_alive"] = res.n_alive
+            self._consume_chunk(res, snapshot, ct, is_spec)
+
+    def _consume_chunk(self, res, snapshot, ct: int, is_spec: bool) -> None:
+        """The host work after a chunk's fetch (one ``sched/consume``):
+        spec and health accounting, each slot's row → tokens → client,
+        finishes, probation bookkeeping."""
         self._chunks_consumed += 1
         self._steptime_consumed = True   # arms the next dispatch's sample
         self._last_n_alive = res.n_alive
-        self._chunk_log.append({
-            "t": time.time(), "event": "consume", "n_alive": res.n_alive,
-            "fetch_ms": round(fetch_s * 1000.0, 3),
-            "pipe": sum(1 for e in self._inflight if e[0] == "chunk"),
-        })
         # Speculative accounting (ISSUE 12): acceptance counters + the
         # draft_rejected ledger class, billed per snapshot request
         # BEFORE the health-trip early return — the drafting happened
@@ -5485,10 +5568,9 @@ class BatchedJaxEngine(JaxEngine):
         if tripped:
             self.supervisor.note_health_trips(len(tripped))
             for i in tripped:
-                self._chunk_log.append({
-                    "t": time.time(), "event": "health_trip", "slot": i,
-                    "health": describe_health(int(res.health[i])),
-                })
+                self._spans.sched.mark(
+                    "health_trip", slot=i,
+                    health=describe_health(int(res.health[i])))
                 if int(res.health[i]) & HEALTH_GRAMMAR_DEAD:
                     # Grammar dead end (ISSUE 11): the FSM state admits
                     # no legal token — the slot froze before emitting
@@ -5532,11 +5614,15 @@ class BatchedJaxEngine(JaxEngine):
                     res.tokens[i], len(slot.detok.ids), cfg.eos_ids,
                     slot.req.max_tokens)
                 self._bill_waste(wasted, slot.req)
+            self._spans.of(slot.req).chunk_consumed()
             if new_ids:
                 if slot.t_first is None:
+                    # The chunk that carried this slot's prologue (ragged
+                    # admission) or followed its replay: its first token.
                     slot.t_first = time.monotonic()
                     if slot.req.t_first0 is None:
                         slot.req.t_first0 = slot.t_first
+                    self._spans.of(slot.req).first_token(slot.t_first)
                 t_dk = time.monotonic()
                 piece = slot.detok.push(*new_ids)
                 slot.detok_ms += (time.monotonic() - t_dk) * 1000.0
@@ -5651,6 +5737,7 @@ class BatchedJaxEngine(JaxEngine):
         # the drain-rate estimate behind retry_after_hint(); the per-lane
         # deque prices Retry-After for THAT lane's sheds.
         t_fin = time.monotonic()
+        self._spans.note_slots(self._slots)
         self._finish_times.append(t_fin)
         lane = getattr(slot.req, "lane", LANE_INTERACTIVE)
         self._lane_finish.setdefault(
@@ -5681,6 +5768,7 @@ class BatchedJaxEngine(JaxEngine):
             if slot.req.trace is not None:
                 slot.req.trace.event(
                     f"engine: failed ({finish}): {error}")
+            self._spans.of(slot.req).finished(t_fin, finish=finish)
             self._emit(slot.req, "error", error)
             return
         t_dk = time.monotonic()
@@ -5710,6 +5798,8 @@ class BatchedJaxEngine(JaxEngine):
             slot.req.trace.event(
                 f"engine: finished ({finish}, "
                 f"{len(slot.detok.ids)} tokens)")
+        self._spans.of(slot.req).finished(
+            t_end, tokens=len(slot.detok.ids), finish=finish)
         # Starvation truncation is client-visible degradation (ISSUE
         # 20): the transcript stopped short of what decode would have
         # produced, and the result says so rather than passing it off
@@ -5912,6 +6002,12 @@ class BatchedJaxEngine(JaxEngine):
                     event, payload = await req.out_queue.get()
                 if event == "error":
                     raise payload
+                if event == "done" and req.spans is not None:
+                    # Last consume → this coroutine resumed: the event-
+                    # loop handoff; the host detok time rides as meta.
+                    req.spans.resumed(
+                        time.monotonic(),
+                        detok_host_ms=round(payload.detok_ms, 3))
                 yield (event, payload)
                 if event == "done":
                     return

@@ -34,7 +34,7 @@ from ..obs.slo import (SLO_QUEUE_WAIT, SLO_SESSION_TTFT, SLO_TTFT,
 from ..obs.steptime import (PHASE_DECODE, PHASE_PREFILL,
                             PHASE_SPEC_VERIFY, StepTimeSentinel,
                             prefill_bucket)
-from ..obs.trace import current_trace
+from ..obs.trace import EngineSpans, RequestSpans, current_trace
 from .containment import (CAUSE_SCHEDULER_DEATH, CAUSE_SCHEDULER_ERROR,
                           CAUSE_SLOT_HEALTH, PROBATION_CLEAN_CHUNKS,
                           REASON_HEALTH, REASON_ISOLATED, EngineSupervisor)
@@ -97,6 +97,7 @@ class FakeEngine:
     ) -> EngineResult:
         if not self._ready:
             raise EngineUnavailable("FakeEngine not started")
+        t_submit = time.monotonic()
         self.calls += 1
         if self.fail_with is not None:
             exc, self.fail_with = self.fail_with, None
@@ -108,6 +109,8 @@ class FakeEngine:
             await asyncio.sleep(self.delay)
         text = self.scripted.pop(0) if self.scripted else self._answer(prompt)
         n_completion = max(len(text.split()), 1)
+        RequestSpans(current_trace(), None, t_submit).whole_call(
+            time.monotonic(), tokens=n_completion)
         return EngineResult(
             text=text,
             prompt_tokens=len(prompt.split()),
@@ -170,6 +173,10 @@ class _FakeReq:
     # directly. Lets preempt/resume span links land on the stitched
     # /debug/requests timeline in fake-engine tests too.
     trace: Optional[object] = None
+    # The request's engine phases (obs/trace.py RequestSpans) — the same
+    # helper, so the same span names, as the batcher's; made at first
+    # use (EngineSpans.of) so tests that build a _FakeReq by hand get one.
+    spans: Optional[RequestSpans] = None
     # Goodput ledger + SLO (ISSUE 8) — mirrors of the batcher's fields:
     # tokens already billed delivered (fleet imports start at the prefix
     # the donor billed), why the next resume re-splice exists ("preempt"
@@ -369,6 +376,11 @@ class FakeChunkedEngine:
         # the incident bundles read it, so the evidence chain runs in
         # tier-1 on the fake too.
         self._chunk_log: deque = deque(maxlen=512)
+        # Engine-side spans (mirror of the batcher's): /health.spans
+        # totals, the scheduler's own intervals in the ring above (no
+        # TraceAnnotation — this engine stays jax-free), and the stamp
+        # queue_wait splits on.
+        self._spans = EngineSpans(self._chunk_log)
         # Block-paged KV pool mirror (ISSUE 10): the SAME BlockPool /
         # RadixCache objects and the SAME kv_pool.map_prefix admission
         # path the batcher runs — the fake's KV is fictional (scripted
@@ -888,6 +900,10 @@ class FakeChunkedEngine:
             "steptime": self._steptime.snapshot(),
         }
 
+    def spans_health(self) -> dict:
+        """/health.spans (obs/trace.py EngineSpans.health)."""
+        return self._spans.health(self._chunks_consumed)
+
     def steptime_health(self) -> dict:
         """Cheap step-time sentinel view (mirror of the batcher's)."""
         return self._steptime.snapshot()
@@ -917,18 +933,27 @@ class FakeChunkedEngine:
     # ---------------------------------------------------------- scheduler
 
     async def _loop(self) -> None:
-        while True:
-            try:
-                progressed = self._tick()
-            except Exception as e:
-                # A poisoned step, not a dead engine: quarantine/bisect +
-                # reset-and-replay, exactly like the batcher's widened
-                # scheduler except. SchedulerKilled (a BaseException)
-                # deliberately escapes — the task dies and _supervise
-                # restarts it.
-                self._contain_poisoned_step(CAUSE_SCHEDULER_ERROR, error=e)
-                progressed = True
-            await asyncio.sleep(0 if progressed else 0.001)
+        self._spans.sched.start()
+        try:
+            while True:
+                try:
+                    progressed = self._tick()
+                except Exception as e:
+                    # A poisoned step, not a dead engine: quarantine/
+                    # bisect + reset-and-replay, exactly like the
+                    # batcher's widened scheduler except. SchedulerKilled
+                    # (a BaseException) deliberately escapes — the task
+                    # dies and _supervise restarts it.
+                    self._contain_poisoned_step(CAUSE_SCHEDULER_ERROR,
+                                                error=e)
+                    progressed = True
+                if progressed:
+                    await asyncio.sleep(0)
+                else:
+                    with self._spans.sched.region("idle"):
+                        await asyncio.sleep(0.001)
+        finally:
+            self._spans.sched.stop()
 
     async def _supervise(self) -> None:
         """Scheduler-death recovery (the async twin of the batcher's
@@ -967,6 +992,7 @@ class FakeChunkedEngine:
     def _tick(self) -> bool:
         if self.faults is not None:
             self.faults.check_scheduler_die()
+        self._spans.note_slots(self._slots)
         self._sweep()
         if (self._parked and not self._inflight
                 and all(s is None for s in self._slots)):
@@ -1075,6 +1101,7 @@ class FakeChunkedEngine:
         req = slot.req
         req.preempt_count += 1
         req.preempt_t0 = time.monotonic()
+        self._spans.of(req).requeued(req.preempt_t0)
         req.resume_ids = list(slot.emitted)
         req.resume_emitted = True    # fake pieces are always fully emitted
         # Mirror the batcher: no cause marker when nothing was generated
@@ -1146,6 +1173,15 @@ class FakeChunkedEngine:
             # admissions may join a suspect batch; queued requests wait
             # and are never dropped.
             return
+        if None not in self._slots or self._queue.qsize() == 0:
+            self._preempt_for_lane = None     # as a pass that pops nothing
+            return
+        with self._spans.sched.region("admit", "admit",
+                                      chunk=self._chunks_dispatched + 1,
+                                      requests=0) as entry:
+            self._admit_pending_in_span(entry)
+
+    def _admit_pending_in_span(self, entry: dict) -> None:
         counts = self.lane_occupancy()
         prefer, self._preempt_for_lane = self._preempt_for_lane, None
         while None in self._slots:
@@ -1162,7 +1198,9 @@ class FakeChunkedEngine:
             if req.cancel.is_set():
                 continue
             self._credit_preempt_wait(req)
+            entry["requests"] += 1
             t_adm0 = time.monotonic()
+            spans = self._spans.admitted(req, t_adm0)
             lane = req.lane if req.lane in LANES else LANE_INTERACTIVE
             counts[lane] += 1
             if req.t_submit:
@@ -1227,6 +1265,14 @@ class FakeChunkedEngine:
                     if req.trace is not None:
                         req.trace.link("resumed", slot=i, tokens=g)
                 self._slots[i] = slot
+                self._spans.note_slots(self._slots)
+                # Re-seated from the generated prefix: the client already
+                # holds a first token, so this segment's prefill ends here.
+                spans.staged(slot.t_first, chunks_ahead=len(self._inflight),
+                             prefill=dict(prompt_tokens=len(req.prompt_ids),
+                                          resumed_tokens=g),
+                             blocks=len(blocks))
+                spans.first_token(slot.t_first)
                 if g >= req.max_tokens:
                     self._finish(i, "length")
                 continue
@@ -1274,6 +1320,8 @@ class FakeChunkedEngine:
                         continue
                     first = picked
                 if first in self.eos_ids:
+                    spans.finished(time.monotonic(), tokens=0,
+                                   finish="stop")
                     req.out_queue.put_nowait(
                         ("done", self._result(req, [], "stop")))
                     continue
@@ -1303,6 +1351,22 @@ class FakeChunkedEngine:
             if not self.device_termination:
                 slot.dev_active = True
             self._slots[i] = slot
+            self._spans.note_slots(self._slots)
+            # Ragged: the window is staged and the next dispatch carries
+            # it (a forced run already emitted does not end prefill — the
+            # first SAMPLED token does, as on the batcher). Otherwise the
+            # first token was picked right here, the fake's collapsed
+            # admission program.
+            spans.staged(
+                time.monotonic(),
+                chunks_ahead=(None if self._use_ragged
+                              else len(self._inflight)),
+                prefill=dict(prompt_tokens=len(req.prompt_ids),
+                             staged_w=(prefill_bucket(len(req.prompt_ids))
+                                       if self._use_ragged else 0)),
+                blocks=len(blocks))
+            if not self._use_ragged and slot.t_first is not None:
+                spans.first_token(slot.t_first)
             if self._use_ragged:
                 # Ragged admission: the prefill "program" rides the next
                 # chunk — that dispatch's sentinel sample is a PREFILL
@@ -1352,6 +1416,12 @@ class FakeChunkedEngine:
         The EMITTED tokens are the scripted stream either way (the
         exact-match-verification guarantee), so spec on/off transcripts
         are byte-identical by construction here too."""
+        with self._spans.sched.region("dispatch", "dispatch",
+                                      chunk=self._chunks_dispatched + 1,
+                                      slots=0) as entry:
+            self._dispatch_chunk_in_span(entry)
+
+    def _dispatch_chunk_in_span(self, entry: dict) -> None:
         if self.faults is not None:
             # Chunk-path fault seam (mirror of the batcher's): a delay/
             # hang here stalls the dispatch loop exactly like a slow
@@ -1485,13 +1555,19 @@ class FakeChunkedEngine:
         packed = pack_chunk(toks, done, lengths, n_alive, health=health,
                             drafted=drafted if spec else None,
                             accepted=accepted if spec else None)
-        self._inflight.append(("chunk", packed, snapshot, C, spec))
+        chunks_ahead = len(self._inflight)
         self._chunks_dispatched += 1
-        self._chunk_log.append({
-            "t": time.time(), "event": "dispatch",
-            "slots": sum(s is not None for s in snapshot),
-            "pipe": len(self._inflight),
-        })
+        self._inflight.append(("chunk", packed, snapshot, C, spec,
+                               self._chunks_dispatched))
+        for snap in snapshot:
+            # A slot still in its prefill phase rides THIS chunk (ragged
+            # admission); a no-op for every other slot.
+            if snap is not None:
+                self._spans.of(snap).dispatched(
+                    now, self._chunks_dispatched, chunks_ahead,
+                    adm_w=adm_w)
+        entry.update(slots=sum(s is not None for s in snapshot),
+                     admissions=int(bool(adm_w)), pipe=chunks_ahead + 1)
 
     def _spec_slot_rows(self, i: int, slot: _FakeSlot, toks, done,
                         lengths, health, drafted, accepted,
@@ -1561,22 +1637,29 @@ class FakeChunkedEngine:
                     if snap is not None:
                         self._bill_waste(self.chunk_len, snap)
             self._chunks_pruned += 1
+            self._spans.sched.mark("prune", chunk=entry[5])
 
     def _consume_oldest(self) -> None:
-        _, packed, snapshot, ct, is_spec = self._inflight.pop(0)
+        _, packed, snapshot, ct, is_spec, chunk_no = self._inflight.pop(0)
         if self.faults is not None:
             # decode:poison_step — step-wide fault from the fetch, routed
             # into the bisecting containment by the loop's except.
             self.faults.poison_fetch(
                 [r.prompt if r is not None else None for r in snapshot])
-        self._fetches += 1          # the single fetch per chunk
-        res = unpack_chunk(packed, self.batch_size, ct, spec=is_spec)
+        with self._spans.sched.region("fetch_wait", "fetch",
+                                      chunk=chunk_no) as fetched:
+            self._fetches += 1      # the single fetch per chunk
+        with self._spans.sched.region("consume", "consume", chunk=chunk_no,
+                                      fetch_ms=fetched["ms"],
+                                      pipe=len(self._inflight)) as consumed:
+            res = unpack_chunk(packed, self.batch_size, ct, spec=is_spec)
+            consumed["n_alive"] = res.n_alive
+            self._consume_chunk(res, snapshot, ct, is_spec)
+
+    def _consume_chunk(self, res, snapshot, ct: int, is_spec: bool) -> None:
+        """The host work after a chunk's fetch (one ``sched/consume``)."""
         self._chunks_consumed += 1
         self._steptime_consumed = True   # arms the next dispatch's sample
-        self._chunk_log.append({
-            "t": time.time(), "event": "consume", "n_alive": res.n_alive,
-            "pipe": len(self._inflight),
-        })
         self._last_n_alive = res.n_alive
         # Speculative accounting (mirror of the batcher): acceptance
         # counters + the draft_rejected waste class, billed BEFORE the
@@ -1634,6 +1717,7 @@ class FakeChunkedEngine:
                     res.tokens[i], len(slot.emitted), self.eos_ids,
                     slot.req.max_tokens)
                 self._bill_waste(wasted, slot.req)
+            self._spans.of(slot.req).chunk_consumed()
             if new_ids:
                 if slot.t_first is None:
                     # Ragged admission (ISSUE 19): the first sampled
@@ -1641,6 +1725,9 @@ class FakeChunkedEngine:
                     slot.t_first = time.monotonic()
                     if slot.req.t_first0 is None:
                         slot.req.t_first0 = slot.t_first
+                # Closes prefill for the slot whose staged window this
+                # chunk carried; a no-op for every slot already decoding.
+                self._spans.of(slot.req).first_token(time.monotonic())
                 piece = self._piece(new_ids, len(slot.emitted))
                 slot.emitted.extend(new_ids)
                 if slot.req.export is not None:
@@ -1873,10 +1960,13 @@ class FakeChunkedEngine:
                 remaining), slot.req)
         # Ledger + TTFT SLO (mirror of the batcher's _finish).
         self._bill_delivered(slot.req, len(slot.emitted))
+        now = time.monotonic()
+        self._spans.note_slots(self._slots)
+        self._spans.of(slot.req).finished(
+            now, tokens=len(slot.emitted), finish=finish)
         if error is not None:
             slot.req.out_queue.put_nowait(("error", error))
             return
-        now = time.monotonic()
         if (slot.req.t_submit and not slot.req.ttft_exempt
                 and not (slot.req.export is not None
                          and getattr(slot.req.export, "discard", False))):
@@ -2035,6 +2125,8 @@ class FakeChunkedEngine:
                     event, payload = await req.out_queue.get()
                 if event == "error":
                     raise payload
+                if event == "done" and req.spans is not None:
+                    req.spans.resumed(time.monotonic())
                 yield (event, payload)
                 if event == "done":
                     return

@@ -1353,6 +1353,27 @@ class EngineFleet:
             return {}
         return obs_steptime.merge_snapshots(snaps)
 
+    def spans_health(self) -> dict:
+        """Fleet rollup of the replicas' engine-span totals (obs/trace.py
+        SpanStats): counts and totals sum, ``max_ms`` takes the worst
+        replica's; ``sched_thread_s`` sums to scheduler thread-seconds
+        across replicas."""
+        agg: dict = {}
+        for rep in self.replicas:
+            fn = getattr(rep.engine, "spans_health", None)
+            if not callable(fn):
+                continue
+            try:
+                snap = fn() or {}
+            except Exception:   # pragma: no cover - stopped replica
+                continue
+            for name, entry in snap.items():
+                out = agg.setdefault(name, {})
+                for k, v in entry.items():
+                    out[k] = (max(out.get(k, 0), v) if k == "max_ms"
+                              else out.get(k, 0) + v)
+        return agg
+
     def slo_health(self) -> dict:
         """Fleet rollup of the replicas' SLO burn snapshots: per-window
         counts sum, burn rates recompute from the sums (rates don't

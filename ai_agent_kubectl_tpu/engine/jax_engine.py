@@ -44,6 +44,7 @@ import numpy as np
 
 from ..models.config import ModelConfig, get_config
 from ..models.transformer import KVCache, forward, init_params
+from ..obs.trace import RequestSpans, current_trace, trace_event
 from .protocol import EngineResult, EngineUnavailable, GenerationTimeout
 from .sampling import sample_token_traced
 from .tokenizer import StreamDecoder, Tokenizer, load_tokenizer
@@ -1237,9 +1238,11 @@ class JaxEngine:
     def _generate_blocking(self, prompt: str, max_tokens: int,
                            temperature: float, deadline: Optional[float],
                            cancel: Optional["threading.Event"] = None,
-                           seed: Optional[int] = None):
+                           seed: Optional[int] = None,
+                           spans: Optional[RequestSpans] = None):
         """Runs on a worker thread. Yields (event, payload) tuples:
-        ("token", text_piece) ... ("done", EngineResult)."""
+        ("token", text_piece) ... ("done", EngineResult). ``spans`` gets
+        the phase boundaries as this thread crosses them."""
         cfg = self.model_cfg
         t_start = time.monotonic()
 
@@ -1278,6 +1281,12 @@ class JaxEngine:
         next_tok = self._sample_fn(last_logits, key, temp_d)
         first_id = int(next_tok[0])
         t_first = time.monotonic()
+        if spans is not None:
+            # No host admission work apart from the prefill program: the
+            # whole of prefill is its one "chunk".
+            spans.staged(t_prefill0, chunks_ahead=0,
+                         prefill=dict(prompt_tokens=n_prompt))
+            spans.first_token(t_first)
         stopped = False
         if first_id in cfg.eos_ids:
             finish = "stop"
@@ -1385,6 +1394,8 @@ class JaxEngine:
 
         t_end = time.monotonic()
         decode_ms = (t_end - t_decode0) * 1000.0
+        if spans is not None:
+            spans.finished(t_end, tokens=len(detok.ids), finish=finish)
         result = EngineResult(
             text=detok.text,
             prompt_tokens=n_prompt,
@@ -1458,8 +1469,6 @@ class JaxEngine:
                              seed: Optional[int] = None):
         if not self._ready:
             raise EngineUnavailable("JaxEngine not started")
-        from ..obs.trace import trace_event
-
         if seed is not None:
             trace_event(
                 f"engine: submitted to single-sequence engine "
@@ -1486,12 +1495,16 @@ class JaxEngine:
                 # window, during which queued requests finish.
                 if self._shutdown:
                     raise EngineUnavailable("engine stopped")
-                queue_ms = (time.monotonic() - t_queue0) * 1000.0
+                t_adm = time.monotonic()
+                queue_ms = (t_adm - t_queue0) * 1000.0
+                # One sequence at a time: the whole wait was for the slot.
+                spans = RequestSpans(current_trace(), None, t_queue0)
+                spans.admitted(t_adm, t_adm)
                 loop = asyncio.get_running_loop()
                 cancel = threading.Event()
                 gen = self._generate_blocking(prompt, max_tokens,
                                               temperature, deadline, cancel,
-                                              seed=seed)
+                                              seed=seed, spans=spans)
                 try:
                     while True:
                         fut = loop.run_in_executor(None, next, gen, None)
@@ -1514,6 +1527,9 @@ class JaxEngine:
                         event, payload = item
                         if event == "done":
                             payload.queue_ms = queue_ms
+                            spans.resumed(
+                                time.monotonic(),
+                                detok_host_ms=round(payload.detok_ms, 3))
                         yield (event, payload)
                 finally:
                     cancel.set()

@@ -18,6 +18,7 @@ from typing import AsyncIterator, Optional
 
 import httpx
 
+from ..obs.trace import RequestSpans, current_trace
 from .protocol import EngineResult, EngineUnavailable, GenerationTimeout
 
 
@@ -118,6 +119,8 @@ class OpenAICompatEngine:
         text = data["choices"][0]["message"]["content"]
         usage = data.get("usage", {})
         elapsed_ms = (time.monotonic() - t0) * 1000.0
+        RequestSpans(current_trace(), None, t0).whole_call(
+            time.monotonic(), tokens=usage.get("completion_tokens", 0))
         return EngineResult(
             text=text,
             prompt_tokens=usage.get("prompt_tokens", 0),
@@ -174,6 +177,7 @@ class OpenAICompatEngine:
         timeout: Optional[float],
     ) -> AsyncIterator[str]:
         started["flag"] = True
+        t0 = time.monotonic()
         try:
             async with self._client.stream(
                 "POST",
@@ -201,6 +205,8 @@ class OpenAICompatEngine:
                     text = data["choices"][0]["message"]["content"]
                     if text:
                         yield text
+                    RequestSpans(current_trace(), None, t0).whole_call(
+                        time.monotonic())
                     return
                 async for line in resp.aiter_lines():
                     line = line.strip()
@@ -218,6 +224,8 @@ class OpenAICompatEngine:
                     piece = (choices[0].get("delta") or {}).get("content")
                     if piece:
                         yield piece
+                RequestSpans(current_trace(), None, t0).whole_call(
+                    time.monotonic())
         except httpx.TimeoutException as e:
             raise GenerationTimeout(str(e)) from e
         except httpx.HTTPError as e:

@@ -9,7 +9,11 @@ import jax, aiohttp, or prometheus_client. Three pieces:
   active trace travels via a ``contextvars.ContextVar`` through the async
   serving path (middleware → cache → breaker → engine submit → executor)
   and by explicit reference through the batch scheduler's admission queue
-  (``_Request.trace``), whose worker thread annotates it lock-safely.
+  (``_Request.trace``), whose worker thread annotates it lock-safely. The
+  engine's side is stamped where the work happens: ``RequestSpans`` (a
+  request's phases, ``prefill`` with children), ``SchedSpans`` (the
+  scheduler thread's ``sched/*`` intervals and its wall time by state)
+  and ``SpanStats`` (the totals ``/health.spans`` serves).
 - ``obs.recorder`` — ring-buffer flight recorder keeping the full span
   timeline of the last N finished requests (including shed / degraded /
   errored ones), served by ``/debug/requests[/{id}]``.

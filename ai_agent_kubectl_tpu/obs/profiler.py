@@ -49,7 +49,14 @@ def _reap_old(base: str) -> None:
 
 
 async def capture(seconds: float) -> dict:
-    """Run one profiler capture; returns ``{"trace_dir", "seconds"}``.
+    """Run one profiler capture; returns ``{"trace_dir", "seconds",
+    "clock_start", "clock_stop"}``. The two clock entries are
+    ``[time.monotonic(), time.time_ns()]`` pairs taken as the capture
+    starts and stops: the trace's own axis is the wall clock in
+    nanoseconds, every span of this program (flight recorder,
+    /debug/chunks) is stamped with ``time.monotonic()``, and the pair is
+    what places one on the other by hand. The scheduler's sched/* spans
+    need no such arithmetic: they are TraceAnnotations inside the trace.
 
     The caller serializes captures (one at a time) — jax.profiler has one
     global trace session and a second start_trace would raise.
@@ -66,8 +73,11 @@ async def capture(seconds: float) -> dict:
     logger.info("profiler: capturing %.1fs device trace into %s",
                 seconds, trace_dir)
     jax.profiler.start_trace(trace_dir)
+    clock_start = [time.monotonic(), time.time_ns()]
     try:
         await asyncio.sleep(seconds)
     finally:
+        clock_stop = [time.monotonic(), time.time_ns()]
         jax.profiler.stop_trace()
-    return {"trace_dir": trace_dir, "seconds": seconds}
+    return {"trace_dir": trace_dir, "seconds": seconds,
+            "clock_start": clock_start, "clock_stop": clock_stop}

@@ -4,7 +4,10 @@ One ``Trace`` per HTTP request, created by the observability middleware
 and finished when the response (or exception) leaves it. Spans carry
 ``time.monotonic()`` begin/end stamps relative to nothing — offsets are
 computed against the trace's own t0 at serialization time, so clock
-adjustments can never skew a timeline. Events are point-in-time
+adjustments can never skew a timeline. Every span has an ``id`` and a
+``parent`` (the id of the span that caused it, ``None`` at the top level):
+the top level partitions the request's wall time, and a parent's self
+time is its duration less its children's. Events are point-in-time
 annotations ("admitted to slot 3", "breaker opened") recorded from
 wherever the trace travels, including the batch scheduler thread — all
 mutation goes through one lock.
@@ -18,6 +21,14 @@ Propagation is two-legged:
 - **thread leg** (batch scheduler): ContextVars do not cross threads, so
   the engine's submit path captures ``current_trace()`` into the queued
   request object and the scheduler annotates that reference directly.
+
+The engine's side of the timeline is stamped where the work happens:
+``RequestSpans`` (one per queued request) writes the engine phases as the
+scheduler crosses each boundary, ``SchedSpans`` records the scheduler
+thread's own per-chunk intervals into the ``/debug/chunks`` ring, and
+``SpanStats`` keeps the cumulative per-name totals ``/health.spans``
+serves. Both engines with a scheduler (engine/batcher.py, engine/fake.py)
+hold the three in one ``EngineSpans``, so the names cannot drift apart.
 """
 
 from __future__ import annotations
@@ -28,7 +39,7 @@ import threading
 import time
 import uuid
 from contextlib import contextmanager
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Deque, Dict, Iterable, List, Optional, Tuple
 
 #: phase names admitted into the ``request_phase_seconds`` histogram.
 #: A fixed allowlist, NOT whatever span names show up — a bug (or a
@@ -37,7 +48,10 @@ from typing import Any, Dict, List, Optional
 PHASES = (
     "validate",      # body parse + pydantic + sanitation
     "queue_wait",    # submit → admission into a decode slot
-    "prefill",       # prompt prefill (admission latency on the batcher)
+    "prefill",       # admission → first token consumed (children below)
+    "admit_host",    # prefill child: host admission work until staged/armed
+    "stage_wait",    # prefill child: staged → the carrying chunk is issued
+    "first_chunk",   # prefill child: that dispatch → its buffer is fetched
     "decode",        # token generation
     "detokenize",    # token → text + engine/event-loop handoff
     "safety",        # output parsing + safety validation
@@ -67,12 +81,17 @@ def sanitize_request_id(raw: Optional[str]) -> Optional[str]:
 
 class Span:
     """One named interval inside a trace. ``t0``/``t1`` are raw
-    ``time.monotonic()`` stamps; offsets are derived at read time."""
+    ``time.monotonic()`` stamps; offsets are derived at read time.
+    ``id`` is unique within the trace; ``parent`` is the id of the span
+    this one is a part of, or ``None`` at the top level."""
 
-    __slots__ = ("name", "t0", "t1", "meta")
+    __slots__ = ("id", "parent", "name", "t0", "t1", "meta")
 
-    def __init__(self, name: str, t0: float, t1: float,
+    def __init__(self, span_id: int, name: str, t0: float, t1: float,
+                 parent: Optional[int] = None,
                  meta: Optional[Dict[str, Any]] = None):
+        self.id = span_id
+        self.parent = parent
         self.name = name
         self.t0 = t0
         self.t1 = max(t1, t0)
@@ -114,12 +133,17 @@ class Trace:
         finally:
             self.add_span(name, t0, time.monotonic(), **meta)
 
-    def add_span(self, name: str, t0: float, t1: float, **meta) -> None:
-        """Record an interval from explicit monotonic stamps — used when a
-        phase's boundaries are known after the fact (e.g. queue/prefill/
-        decode reconstructed from an EngineResult's timings)."""
+    def add_span(self, name: str, t0: float, t1: float,
+                 parent: Optional[int] = None, **meta) -> int:
+        """Record an interval from explicit monotonic stamps (the
+        scheduler thread stamps boundaries as it crosses them and writes
+        the span when it closes); returns the span's id, which a child
+        names as its ``parent``. Safe from any thread."""
         with self._lock:
-            self._spans.append(Span(name, t0, t1, meta or None))
+            span_id = len(self._spans) + 1
+            self._spans.append(
+                Span(span_id, name, t0, t1, parent, meta or None))
+        return span_id
 
     def event(self, message: str, **meta) -> None:
         """Point-in-time annotation; safe from any thread."""
@@ -154,12 +178,16 @@ class Trace:
         end = self._t_end if self._t_end is not None else time.monotonic()
         return (end - self.t0) * 1000.0
 
-    def phase_durations(self) -> Dict[str, float]:
-        """name → total ms (same-named spans merged), insertion-ordered."""
+    def phase_durations(self, children: bool = False) -> Dict[str, float]:
+        """name → total ms (same-named spans merged), insertion-ordered.
+        Top-level spans only unless ``children``: the top level is what
+        sums to the wall time (Server-Timing, ``timings``); the phase
+        histogram wants every span."""
         out: Dict[str, float] = {}
         with self._lock:
             for s in self._spans:
-                out[s.name] = out.get(s.name, 0.0) + s.duration_ms
+                if children or s.parent is None:
+                    out[s.name] = out.get(s.name, 0.0) + s.duration_ms
         return out
 
     def server_timing(self) -> str:
@@ -190,6 +218,8 @@ class Trace:
         with self._lock:
             spans = [
                 {
+                    "id": s.id,
+                    "parent": s.parent,
                     "phase": s.name,
                     "start_ms": round((s.t0 - self.t0) * 1000.0, 3),
                     "end_ms": round((s.t1 - self.t0) * 1000.0, 3),
@@ -219,6 +249,335 @@ class Trace:
         d["events"] = events
         d["links"] = links
         return d
+
+
+# ------------------------------------------------------ engine-side spans
+
+class SpanStats:
+    """Cumulative ``{count, total_ms, max_ms}`` per span name since start —
+    plain counters behind one lock, updated when a span closes. What
+    ``/health.spans`` serves, so a benchmark can difference two probes
+    into means without a new endpoint. Extra running totals ride an entry
+    by keyword (``queue_wait``'s ``slot_wait_total_ms``)."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._by_name: Dict[str, Dict[str, float]] = {}
+
+    def note_all(self, entries: Iterable[Tuple[str, float, Dict[str, float]]]
+                 ) -> None:
+        """``(name, ms, extra totals)`` for spans that closed together,
+        under ONE lock hold — a reader never sees a parent without the
+        children that were written with it."""
+        with self._lock:
+            for name, ms, totals in entries:
+                e = self._by_name.get(name)
+                if e is None:
+                    e = self._by_name[name] = {
+                        "count": 0, "total_ms": 0.0, "max_ms": 0.0}
+                e["count"] += 1
+                e["total_ms"] += ms
+                e["max_ms"] = max(e["max_ms"], ms)
+                for k, v in totals.items():
+                    e[k] = e.get(k, 0) + v
+
+    def note(self, name: str, ms: float, **totals: float) -> None:
+        self.note_all([(name, ms, totals)])
+
+    def snapshot(self) -> Dict[str, Dict[str, float]]:
+        with self._lock:
+            return {name: {k: round(v, 3) for k, v in e.items()}
+                    for name, e in self._by_name.items()}
+
+
+class RequestSpans:
+    """One request's engine phases, stamped by the scheduler at the moment
+    each boundary is crossed and written (to the request's ``Trace``, if
+    it has one, and to the engine's ``SpanStats``, if it keeps one) when
+    the phase closes:
+
+    ``queue_wait`` → ``prefill`` (children ``admit_host``, ``stage_wait``,
+    ``first_chunk``) → ``decode`` → ``detokenize``
+
+    Every method is a no-op outside the phase it closes, so replays,
+    races and double finishes cannot write a phase twice. A preempted
+    request is ``requeued`` and walks a second ``queue_wait``/``prefill``
+    pair rather than stretching the first."""
+
+    __slots__ = ("trace", "stats", "phase", "t_mark", "t_staged",
+                 "prefill_meta", "admit_meta", "t_disp", "chunk_meta",
+                 "chunks")
+
+    def __init__(self, trace: Optional[Trace], stats: Optional[SpanStats],
+                 t_submit: float):
+        self.trace = trace
+        self.stats = stats
+        self.phase = "queue_wait"
+        self.t_mark = t_submit       # start of the open phase
+        self.t_staged: Optional[float] = None
+        self.t_disp: Optional[float] = None
+        self.prefill_meta: Dict[str, Any] = {}
+        self.admit_meta: Dict[str, Any] = {}
+        self.chunk_meta: Dict[str, Any] = {}
+        self.chunks = 0
+
+    def _write(self, *spans: tuple) -> None:
+        """``(name, t0, t1, totals, meta)``: one span, or a parent and
+        then its children, written (and counted) together."""
+        if self.stats is not None:
+            self.stats.note_all(
+                [(name, max(t1 - t0, 0.0) * 1000.0, totals)
+                 for name, t0, t1, totals, _ in spans])
+        if self.trace is None:
+            return
+        parent = None
+        for name, t0, t1, _, meta in spans:
+            sid = self.trace.add_span(name, t0, t1, parent=parent, **meta)
+            parent = parent or sid
+
+    def admitted(self, t_adm: float, slot_free_since: Optional[float]
+                 ) -> None:
+        """Popped off the queue into a slot. ``slot_free_since`` is when
+        the scheduler last went from no free slot to one (``None`` = it
+        never had none): the wait before it was a wait for a slot, the
+        remainder is time the scheduler was elsewhere."""
+        if self.phase != "queue_wait":
+            return
+        wait = max(t_adm - self.t_mark, 0.0)
+        slot_wait = (0.0 if slot_free_since is None
+                     else min(max(slot_free_since - self.t_mark, 0.0), wait))
+        self._write(("queue_wait", self.t_mark, t_adm,
+                     {"slot_wait_total_ms": slot_wait * 1000.0},
+                     {"slot_wait_ms": round(slot_wait * 1000.0, 3)}))
+        self.phase, self.t_mark = "prefill", t_adm
+        self.t_staged = self.t_disp = None
+        self.prefill_meta, self.admit_meta, self.chunk_meta = {}, {}, {}
+
+    def staged(self, t: float, chunks_ahead: Optional[int] = None,
+               prefill: Optional[Dict[str, Any]] = None, **meta) -> None:
+        """The admission's host work is done: its window is staged for
+        the next chunk (``dispatched`` follows), or its own admission
+        program is issued behind ``chunks_ahead`` decode chunks — then
+        the first token comes from that program and nothing waits for a
+        dispatch. ``prefill`` is the parent span's meta, ``meta``
+        ``admit_host``'s."""
+        if self.phase == "prefill" and self.t_staged is None:
+            self.t_staged, self.admit_meta = t, meta
+            self.prefill_meta = prefill or {}
+            if chunks_ahead is not None:
+                self.chunk_meta = {"chunks_ahead": chunks_ahead}
+
+    def dispatched(self, t: float, chunk: int, chunks_ahead: int,
+                   **meta) -> None:
+        """The chunk that carries the staged window is issued, behind
+        ``chunks_ahead`` decode chunks already queued on the device."""
+        if self.phase == "prefill" and self.t_disp is None:
+            self.t_disp = t
+            self.chunk_meta = dict(meta, chunk=chunk,
+                                   chunks_ahead=chunks_ahead)
+
+    def first_token(self, t: float) -> None:
+        if self.phase != "prefill":
+            return
+        t_adm = self.t_mark
+        t_staged = min(self.t_staged if self.t_staged is not None else t, t)
+        spans = [("prefill", t_adm, t, {}, self.prefill_meta),
+                 ("admit_host", t_adm, t_staged, {}, self.admit_meta)]
+        t_chunk = t_staged
+        if self.t_disp is not None:     # rode a chunk: it waited for one
+            t_chunk = min(max(self.t_disp, t_staged), t)
+            spans.append(("stage_wait", t_staged, t_chunk, {}, {}))
+        spans.append(("first_chunk", t_chunk, t,
+                      {"chunks_ahead_total":
+                       self.chunk_meta.get("chunks_ahead", 0)},
+                      self.chunk_meta))
+        self._write(*spans)
+        self.phase, self.t_mark, self.chunks = "decode", t, 0
+
+    def chunk_consumed(self) -> None:
+        if self.phase == "decode":
+            self.chunks += 1
+
+    def finished(self, t: float, **meta) -> None:
+        """The request's last consume: closes whatever is open (a request
+        that ends before its first token closes ``prefill`` there) and
+        leaves ``detokenize`` open for the handler to close."""
+        if self.phase == "prefill":
+            self.first_token(t)
+        if self.phase != "decode":
+            return
+        self._write(("decode", self.t_mark, t, {},
+                     dict(meta, chunks=self.chunks)))
+        self.phase, self.t_mark = "detokenize", t
+
+    def resumed(self, t: float, **meta) -> None:
+        """The handler's coroutine picked the result up."""
+        if self.phase != "detokenize":
+            return
+        self._write(("detokenize", self.t_mark, t, {}, meta))
+        self.phase = "done"
+
+    def whole_call(self, t_end: float, **meta) -> None:
+        """An engine that is one opaque call (a remote API, the rule
+        table) has no boundaries of its own to stamp: everything between
+        submit and its return is ``decode``, the rest is empty."""
+        t0 = self.t_mark
+        self.admitted(t0, None)
+        self.staged(t0)
+        self.first_token(t0)
+        self.finished(t_end, **meta)
+        self.resumed(time.monotonic())
+
+    def requeued(self, t: float) -> None:
+        """Preempted out of its slot: the open phase ends here and a new
+        ``queue_wait`` starts."""
+        self.finished(t, preempted=True)
+        self.phase, self.t_mark = "queue_wait", t
+
+
+#: the states that partition the scheduler thread's wall time
+SCHED_STATES = ("admit", "dispatch", "fetch_wait", "consume", "idle",
+                "other")
+
+
+class SchedSpans:
+    """The scheduler thread's own spans. One ring (the ``/debug/chunks``
+    log the engine already keeps), one clock (``time.monotonic()``, with
+    ``time.time()`` beside it for readers that join on the wall clock).
+
+    ``region(state, name, chunk)`` marks an interval: the thread's wall
+    time is charged to ``state`` for its length (a cumulative partition
+    over ``SCHED_STATES`` whose parts sum to the thread's elapsed time;
+    whatever no region covers is ``other``), and a named region is also
+    appended to the ring as ``sched/<name>``, counted into ``SpanStats``,
+    and wrapped in ``annotate("sched/<name>", chunk=n)`` — the engine
+    passes ``jax.profiler.TraceAnnotation`` there, so a ``/debug/profile``
+    capture holds the same spans on the scheduler thread's line, on the
+    device ops' clock; a no-op outside a capture. This module stays
+    jax-free."""
+
+    def __init__(self, stats: SpanStats, log: Deque[dict],
+                 annotate: Optional[Callable[..., Any]] = None):
+        self._stats = stats
+        self._log = log
+        self._annotate = annotate
+        self._lock = threading.Lock()   # snapshot() reads from other threads
+        self._state_s = dict.fromkeys(SCHED_STATES, 0.0)
+        self._state: Optional[str] = None
+        self._t_state = 0.0
+        self._elapsed = 0.0             # of scheduler threads that ended
+        self._t_start = 0.0
+
+    def _switch(self, state: Optional[str], now: float) -> Optional[str]:
+        with self._lock:
+            prev = self._state
+            if prev is not None:
+                self._state_s[prev] += now - self._t_state
+            self._state, self._t_state = state, now
+        return prev
+
+    def start(self) -> None:
+        """The scheduler thread (or task) begins."""
+        now = time.monotonic()
+        if self._switch("other", now) is None:
+            self._t_start = now
+
+    def stop(self) -> None:
+        now = time.monotonic()
+        if self._switch(None, now) is not None:
+            with self._lock:
+                self._elapsed += now - self._t_start
+
+    @contextmanager
+    def region(self, state: str, name: Optional[str] = None,
+               chunk: Optional[int] = None, **fields):
+        """Yields the ring entry's field dict: the caller adds what it
+        learns inside the interval (``n_alive`` after a fetch) and, once
+        the region has closed, reads its length back as ``["ms"]``."""
+        t0, wall0 = time.monotonic(), time.time()
+        prev = self._switch(state, t0)
+        try:
+            if name is not None and self._annotate is not None:
+                stat = {} if chunk is None else {"chunk": chunk}
+                with self._annotate(f"sched/{name}", **stat):
+                    yield fields
+            else:
+                yield fields
+        finally:
+            t1 = time.monotonic()
+            # Back to the enclosing state — but only while a scheduler is
+            # running: a region entered before start() charges nothing.
+            self._switch(prev, t1)
+            fields["ms"] = (t1 - t0) * 1000.0
+            if name is not None:
+                self._stats.note(f"sched/{name}", fields["ms"])
+                self._log.append({
+                    "t": wall0, "t0": t0, "t1": t1, "event": name,
+                    "span": f"sched/{name}", "chunk": chunk, **fields})
+
+    def mark(self, event: str, **fields) -> None:
+        """A point in the ring (prune, health trip): no interval."""
+        self._log.append({"t": time.time(), "t0": time.monotonic(),
+                          "event": event, **fields})
+
+    def snapshot(self) -> Dict[str, float]:
+        """Seconds per state since the first start(), and their sum's
+        independent check ``elapsed`` (thread start → now)."""
+        now = time.monotonic()
+        with self._lock:
+            parts = dict(self._state_s)
+            elapsed = self._elapsed
+            if self._state is not None:
+                parts[self._state] += now - self._t_state
+                elapsed += now - self._t_start
+        out = {k: round(v, 6) for k, v in parts.items()}
+        out["elapsed"] = round(elapsed, 6)
+        return out
+
+
+class EngineSpans:
+    """Everything an engine with a scheduler keeps for its spans, so the
+    batcher and its fake twin cannot drift: the totals (``stats``), the
+    scheduler thread's spans over the engine's chunk ring (``sched``), and
+    ``slot_free_since`` — when the free-slot count last left 0 (``None``
+    while no slot is free), the stamp ``queue_wait`` splits on."""
+
+    def __init__(self, log: Deque[dict],
+                 annotate: Optional[Callable[..., Any]] = None):
+        self.stats = SpanStats()
+        self.sched = SchedSpans(self.stats, log, annotate)
+        self.slot_free_since: Optional[float] = time.monotonic()
+
+    def of(self, req) -> RequestSpans:
+        """The request's ``RequestSpans``, made at first use so every
+        constructor of a queued request gets one."""
+        if req.spans is None:
+            req.spans = RequestSpans(req.trace, self.stats,
+                                     req.t_submit or time.monotonic())
+        return req.spans
+
+    def admitted(self, req, t_adm: float) -> RequestSpans:
+        spans = self.of(req)
+        spans.admitted(t_adm, self.slot_free_since)
+        return spans
+
+    def note_slots(self, slots: List[Any]) -> None:
+        """Call wherever a slot is seated or freed, and once per
+        scheduler iteration for paths that free slots wholesale."""
+        if None in slots:
+            if self.slot_free_since is None:
+                self.slot_free_since = time.monotonic()
+        else:
+            self.slot_free_since = None
+
+    def health(self, chunks_consumed: int) -> Dict[str, Any]:
+        """The ``/health.spans`` section: cumulative ``{count, total_ms,
+        max_ms}`` per span name (request spans and ``sched/*`` alike) and
+        the scheduler thread's wall time by state — cheap host counters."""
+        out: Dict[str, Any] = self.stats.snapshot()
+        out["sched_thread_s"] = dict(self.sched.snapshot(),
+                                     chunks_consumed=chunks_consumed)
+        return out
 
 
 # --------------------------------------------------------------- context

@@ -469,9 +469,11 @@ def _finalize_trace(svc: "Service", trace: Trace, status: int,
     still feed the HTTP metrics.
     """
     trace.finish(status=status)
-    for phase, ms in trace.phase_durations().items():
-        # PHASES is a fixed allowlist: label cardinality stays bounded no
-        # matter what span names a future code path (or bug) produces.
+    for phase, ms in trace.phase_durations(children=True).items():
+        # Every span, prefill's children included (the top level alone is
+        # what Server-Timing sums). PHASES is a fixed allowlist: label
+        # cardinality stays bounded no matter what span names a future
+        # code path (or bug) produces.
         if phase in PHASES:
             svc.metrics.request_phase.labels(phase).observe(ms / 1000.0)
     # Unmatched-route 404s stay out too: they bypass the rate limiter
@@ -690,37 +692,6 @@ async def qos_middleware(request: web.Request, handler):
         return await handler(request)
 
 
-def _record_engine_spans(trace: Optional[Trace], t_block0: float,
-                         t_block1: float, er: EngineResult) -> None:
-    """Reconstruct the engine block's phase timeline onto the trace.
-
-    The engine call is one awaited block from the handler's view; the
-    EngineResult carries where that time went (queue_ms / prefill_ms /
-    decode_ms as the engine measured them). They are laid back-to-back
-    from the block's start, and whatever the three phases don't account
-    for — detokenization, event-loop handoff, chunk-pipeline slack — is
-    the ``detokenize`` remainder, so the span durations always sum to the
-    block's wall time (the property the /debug/requests timeline is
-    documented to hold). The separately-measured host detok time rides
-    along as span metadata when the engine reports it.
-    """
-    if trace is None:
-        return
-    k = 1000.0
-    t_q = t_block0 + er.queue_ms / k
-    t_p = t_q + er.prefill_ms / k
-    t_d = t_p + er.decode_ms / k
-    # Clamp into the block: the engine's own spans can overrun the
-    # handler-observed wall time by scheduler jitter; never let a span
-    # escape the block it happened in.
-    t_q, t_p, t_d = (min(t, t_block1) for t in (t_q, t_p, t_d))
-    trace.add_span("queue_wait", t_block0, t_q)
-    trace.add_span("prefill", t_q, t_p)
-    trace.add_span("decode", t_p, t_d)
-    meta = {"detok_host_ms": round(er.detok_ms, 3)} if er.detok_ms else {}
-    trace.add_span("detokenize", t_d, t_block1, **meta)
-
-
 async def handle_kubectl_command(request: web.Request) -> web.Response:
     """POST /kubectl-command (reference app.py:284-346)."""
     svc: Service = request.app["service"]
@@ -800,12 +771,12 @@ async def handle_kubectl_command(request: web.Request) -> web.Response:
             svc.token_rate.add(engine_result.completion_tokens)
             if engine_result.prefix_cache_hit:
                 svc.metrics.prefix_cache_hits.inc()
-            # Non-degraded engine block: lay queue/prefill/decode/detok
-            # spans over it from the engine's own measurements. A degraded
-            # block already carries its "fallback" span (plus the failure
-            # event), and a cache hit its "cache" span below.
-            if not from_cache:
-                _record_engine_spans(trace, t_block0, t_block1, engine_result)
+        # The engine block's queue_wait/prefill/decode/detokenize spans
+        # are already on the trace: the engine stamped them where the
+        # work happened (obs/trace.py RequestSpans), on this route and
+        # the streaming one alike. A degraded block carries its
+        # "fallback" span (plus the failure event), and a cache hit its
+        # "cache" span below.
         engine_md = EngineMetadata(
             queue_ms=engine_result.queue_ms,
             prefill_ms=engine_result.prefill_ms,
@@ -1158,6 +1129,13 @@ async def handle_health(request: web.Request) -> web.Response:
     if callable(sth):
         steptime = sth() or None
     incidents = svc.incidents.snapshot()
+    # Engine spans (obs/trace.py): cumulative {count, total_ms, max_ms}
+    # per span name and the scheduler thread's wall time by state —
+    # cheap host counters, same rule as the rest.
+    spans = None
+    sph = getattr(svc.engine, "spans_health", None)
+    if callable(sph):
+        spans = sph() or None
     body = HealthResponse(
         status="healthy" if ready and breaker == "closed" else "degraded",
         engine=getattr(svc.engine, "name", "unknown"),
@@ -1178,6 +1156,7 @@ async def handle_health(request: web.Request) -> web.Response:
         rollout=rollout,
         steptime=steptime,
         incidents=incidents,
+        spans=spans,
     )
     # The HTTP status tracks engine readiness alone: an open breaker with
     # the engine process alive still serves (fallback and/or cache), and
@@ -1272,12 +1251,14 @@ async def handle_debug_request_detail(request: web.Request) -> web.Response:
 
 
 async def handle_debug_chunks(request: web.Request) -> web.Response:
-    """GET /debug/chunks — the decode pipeline's flight record: the last
-    N chunk dispatch/consume/prune events (timestamps, KV bucket, device
-    n_alive, fetch latency) straight off the scheduler's ring buffer,
-    plus the live pipeline stats. The chunk-granular companion to
-    /debug/requests when 'serving is slower than the device' needs a
-    timeline, not a counter."""
+    """GET /debug/chunks — the decode pipeline's flight record: the
+    scheduler thread's last N spans (``sched/admit|dispatch|fetch|
+    consume`` intervals with their chunk number, on time.monotonic() as
+    ``t0``/``t1`` and on the wall clock as ``t``; KV bucket, device
+    n_alive, fetch latency) and prune/health-trip marks, straight off
+    the scheduler's ring buffer, plus the live pipeline stats. The
+    chunk-granular companion to /debug/requests when 'serving is slower
+    than the device' needs a timeline, not a counter."""
     denied = _debug_forbidden(request)
     if denied is not None:
         return denied

@@ -193,7 +193,11 @@ def build_openapi() -> Dict:
                            "an X-Debug-Token header.",
             "responses": {
                 "200": {"description": "Capture summary JSON "
-                                       "(trace_dir, seconds)"},
+                                       "(trace_dir, seconds, clock_start/"
+                                       "clock_stop: [time.monotonic(), "
+                                       "time.time_ns()] pairs that place "
+                                       "flight-recorder spans on the "
+                                       "trace's axis)"},
                 "400": _err("seconds not a number"),
                 "401": auth_err,
                 "403": _err("Invalid or missing X-Debug-Token (only when "
@@ -245,11 +249,13 @@ def build_openapi() -> Dict:
             },
         }},
         "/debug/chunks": {"get": {
-            "summary": "Decode-pipeline flight record: recent chunk "
-                       "dispatch/consume/prune events + live stats",
-            "description": "The batch scheduler's chunk-event ring "
-                           "(timestamps, KV bucket, device n_alive, "
-                           "fetch latency) plus pipeline stats — pipe "
+            "summary": "Decode-pipeline flight record: the scheduler's "
+                       "recent sched/* spans + live stats",
+            "description": "The batch scheduler's ring of "
+                           "sched/admit|dispatch|fetch|consume intervals "
+                           "(chunk number, monotonic t0/t1 and wall-clock "
+                           "t, KV bucket, device n_alive, fetch latency) "
+                           "and prune marks, plus pipeline stats — pipe "
                            "depth/occupancy, device-side termination "
                            "state, wasted decode steps, chunk totals. "
                            "Same auth/token gating as /debug/profile.",

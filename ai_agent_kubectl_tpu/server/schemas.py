@@ -149,3 +149,13 @@ class HealthResponse(BaseModel):
     # captured/suppressed totals by trigger, and the newest incident id
     # (full bundles live behind token-gated /debug/incidents).
     incidents: Optional[Dict[str, Any]] = None
+    # Engine spans (obs/trace.py): cumulative {count, total_ms, max_ms}
+    # per span name since start — the request phases (queue_wait with
+    # slot_wait_total_ms, prefill and its children admit_host /
+    # stage_wait / first_chunk with chunks_ahead_total, decode,
+    # detokenize) and the scheduler's sched/admit|dispatch|fetch|consume
+    # — plus ``sched_thread_s``: the scheduler thread's wall time by
+    # state (admit, dispatch, fetch_wait, consume, idle, other; they sum
+    # to ``elapsed``) and ``chunks_consumed``. None = engine without a
+    # chunk scheduler.
+    spans: Optional[Dict[str, Any]] = None

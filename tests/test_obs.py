@@ -244,8 +244,10 @@ async def test_request_id_on_inflight_shed():
 
 async def test_server_timing_and_timeline_phases_sum_to_wall():
     """Acceptance: an end-to-end request yields ≥6 named phases in the
-    /debug/requests/{id} timeline whose durations sum to ~wall time, the
-    same phases in the Server-Timing header and the phase histogram."""
+    /debug/requests/{id} timeline whose TOP-LEVEL durations sum to ~wall
+    time (children are parts of their parent, not more time), the same
+    top-level phases in the Server-Timing header, and every span in the
+    phase histogram."""
     engine = FakeEngine(delay=0.05)
     client, _ = await make_client(make_cfg(), engine=engine)
     try:
@@ -270,12 +272,18 @@ async def test_server_timing_and_timeline_phases_sum_to_wall():
         for k in body["timings"]:
             assert k in phases
 
-        # flight-recorder timeline: same phases, sum ≈ wall
+        # flight-recorder timeline: same phases at the top level (the
+        # header names nothing else), their sum ≈ wall; the engine's
+        # prefill children hang under prefill
         detail = await (await client.get(f"/debug/requests/{rid}")).json()
-        span_names = {s["phase"] for s in detail["spans"]}
+        top = [s for s in detail["spans"] if s["parent"] is None]
+        assert {s["phase"] for s in top} == set(phases)
         assert {"validate", "queue_wait", "prefill", "decode",
-                "detokenize", "safety"} <= span_names
-        total = sum(s["duration_ms"] for s in detail["spans"])
+                "detokenize", "safety"} <= set(phases)
+        prefill = next(s for s in top if s["phase"] == "prefill")
+        kids = [s for s in detail["spans"] if s["parent"] is not None]
+        assert kids and all(s["parent"] == prefill["id"] for s in kids)
+        total = sum(s["duration_ms"] for s in top)
         wall = detail["duration_ms"]
         # spans cover the engine block (~50ms of fake delay) plus the
         # handler phases; everything but middleware slack is attributed
