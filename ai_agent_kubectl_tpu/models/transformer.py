@@ -155,24 +155,37 @@ def _pool_flat_pos(tables, positions, page: int, n_blocks: int,
     return flat
 
 
-def _pool_scatter(leaf, flat, updates):
+def _at_layer(layer, *idx):
+    """Index tuple into a cache leaf: ``idx`` within a single layer, or
+    ``(layer,) + idx`` within the stacked [L, ...] leaf the layer scan
+    carries (ISSUE 25)."""
+    return idx if layer is None else (layer,) + idx
+
+
+def _pool_scatter(leaf, flat, updates, layer=None):
     """Scatter [B, S, ...] updates into a [n_blocks, page, ...] pool leaf
-    at flat row indices (OOB drops)."""
-    nb, page = leaf.shape[0], leaf.shape[1]
-    f = leaf.reshape((nb * page,) + leaf.shape[2:])
-    f = f.at[flat].set(updates.astype(leaf.dtype))
+    — or, with ``layer``, into that layer of the stacked [L, n_blocks,
+    page, ...] leaf — at flat row indices (OOB drops). One scatter into
+    the buffer it is given: on a loop-carried, donated pool XLA performs
+    it in place."""
+    i = 0 if layer is None else 1
+    f = leaf.reshape(leaf.shape[:i] + (leaf.shape[i] * leaf.shape[i + 1],)
+                     + leaf.shape[i + 2:])
+    f = f.at[_at_layer(layer, flat)].set(updates.astype(leaf.dtype))
     return f.reshape(leaf.shape)
 
 
-def _pool_gather(leaf, tables, n_pages: int):
-    """Gather each slot's first ``n_pages`` pages into the contiguous
-    [B, n_pages*page, ...] view dense/flash attention reads. Sentinel
-    table entries clamp to a real block — those positions sit beyond the
-    slot's live length, where the causal mask already excludes them."""
-    idx = jnp.clip(tables[:, :n_pages], 0, leaf.shape[0] - 1)
-    g = leaf[idx]
-    return g.reshape((idx.shape[0], n_pages * leaf.shape[1])
-                     + leaf.shape[2:])
+def _pool_gather(leaf, tables, n_pages: int, layer=None):
+    """Gather each slot's first ``n_pages`` pages (of ``layer``, when the
+    leaf is the stacked pool) into the contiguous [B, n_pages*page, ...]
+    view dense/flash attention reads. Sentinel table entries clamp to a
+    real block — those positions sit beyond the slot's live length,
+    where the causal mask already excludes them."""
+    i = 0 if layer is None else 1
+    idx = jnp.clip(tables[:, :n_pages], 0, leaf.shape[i] - 1)
+    g = leaf[_at_layer(layer, idx)]
+    return g.reshape((idx.shape[0], n_pages * leaf.shape[i + 1])
+                     + leaf.shape[i + 2:])
 
 
 # ------------------------------------------------- residual sharding
@@ -299,8 +312,18 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, page_size: int,
            token_mask,
            write_mask=None,
            block_tables=None,
-           q_lens=None) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+           q_lens=None,
+           layer=None) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One transformer block. Returns (h_out, new_layer_k, new_layer_v).
+
+    ``layer`` (a traced scalar; ISSUE 25): ``layer_k``/``layer_v`` are
+    then the WHOLE stacked cache leaves [L, ...] that ``forward``'s layer
+    scan carries, and every access below addresses ``[layer]`` inside
+    them — the rows are scattered into the carried buffer in place, the
+    Pallas kernels pick the layer in their index maps, and the XLA
+    readers slice it where they consume it — so no layer, let alone the
+    pool, is copied out of the stack and back. Without it (the pipe-mesh
+    stage body) they are one layer's leaves, as before.
 
     The ``jax.named_scope`` blocks here (and in ``forward``/sampling) are
     zero-cost HLO metadata: XLA stamps each op's ``op_name`` with the
@@ -335,8 +358,7 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, page_size: int,
         # dense path — only the storage addressing changes, so pool and
         # dense transcripts are bit-identical.
         is_q = isinstance(layer_k, QuantKV)
-        pool_leaf = layer_k.q if is_q else layer_k
-        page, n_blocks = pool_leaf.shape[1], pool_leaf.shape[0]
+        n_blocks, page = (layer_k.q if is_q else layer_k).shape[-4:-2]
         if kv_limit % page:
             raise ValueError(
                 f"pool kv_limit {kv_limit} not a multiple of page {page}")
@@ -345,13 +367,15 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, page_size: int,
         with jax.named_scope("kv_write"):
             if is_q:
                 qk, qv = kv_quantize(k), kv_quantize(v)
-                layer_k = QuantKV(q=_pool_scatter(layer_k.q, flat, qk.q),
-                                  s=_pool_scatter(layer_k.s, flat, qk.s))
-                layer_v = QuantKV(q=_pool_scatter(layer_v.q, flat, qv.q),
-                                  s=_pool_scatter(layer_v.s, flat, qv.s))
+                layer_k = QuantKV(
+                    q=_pool_scatter(layer_k.q, flat, qk.q, layer),
+                    s=_pool_scatter(layer_k.s, flat, qk.s, layer))
+                layer_v = QuantKV(
+                    q=_pool_scatter(layer_v.q, flat, qv.q, layer),
+                    s=_pool_scatter(layer_v.s, flat, qv.s, layer))
             else:
-                layer_k = _pool_scatter(layer_k, flat, k)
-                layer_v = _pool_scatter(layer_v, flat, v)
+                layer_k = _pool_scatter(layer_k, flat, k, layer)
+                layer_v = _pool_scatter(layer_v, flat, v, layer)
         n_pages = kv_limit // page
         kv_pos = jnp.arange(kv_limit)[None, None, :]
         mask = kv_pos <= positions[:, :, None]
@@ -375,14 +399,14 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, page_size: int,
 
                     attn = ragged_attention_pool_sharded(
                         q, layer_k, layer_v, ql, positions[:, 0],
-                        block_tables, mesh, page_size=page)
+                        block_tables, mesh, layer, page_size=page)
                 else:
                     from ..ops.ragged_attention import \
                         ragged_attention_pool
 
                     attn = ragged_attention_pool(
                         q, layer_k, layer_v, ql, positions[:, 0],
-                        block_tables, page_size=page)
+                        block_tables, layer, page_size=page)
             elif attn_impl == "paged" and S == 1 and not is_q:
                 # TPU fast path: the block-table pallas kernel reads only
                 # each slot's live pages straight from the pool — no
@@ -397,26 +421,27 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, page_size: int,
 
                     attn = paged_decode_attention_pool_sharded(
                         q[:, 0], layer_k, layer_v, positions[:, 0],
-                        block_tables, mesh, page_size=page)[:, None]
+                        block_tables, mesh, layer,
+                        page_size=page)[:, None]
                 else:
                     from ..ops.paged_attention import \
                         paged_decode_attention_pool
 
                     attn = paged_decode_attention_pool(
                         q[:, 0], layer_k, layer_v, positions[:, 0],
-                        block_tables, page_size=page)[:, None]
+                        block_tables, layer, page_size=page)[:, None]
             elif is_q:
                 attn = dense_attention_quant(
                     q,
-                    _pool_gather(layer_k.q, block_tables, n_pages),
-                    _pool_gather(layer_k.s, block_tables, n_pages),
-                    _pool_gather(layer_v.q, block_tables, n_pages),
-                    _pool_gather(layer_v.s, block_tables, n_pages),
+                    _pool_gather(layer_k.q, block_tables, n_pages, layer),
+                    _pool_gather(layer_k.s, block_tables, n_pages, layer),
+                    _pool_gather(layer_v.q, block_tables, n_pages, layer),
+                    _pool_gather(layer_v.s, block_tables, n_pages, layer),
                     mask,
                 )
             else:
-                k_ctx = _pool_gather(layer_k, block_tables, n_pages)
-                v_ctx = _pool_gather(layer_v, block_tables, n_pages)
+                k_ctx = _pool_gather(layer_k, block_tables, n_pages, layer)
+                v_ctx = _pool_gather(layer_v, block_tables, n_pages, layer)
                 if attn_impl == "flash" and S > 1:
                     from ..ops.flash_attention import flash_attention_cached
 
@@ -438,10 +463,13 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, page_size: int,
     # (write_mask False) scatter at an out-of-bounds position, which jax
     # drops — the cache row stays untouched.
     if write_mask is not None:
-        _cap = (layer_k.q if isinstance(layer_k, QuantKV) else layer_k).shape[1]
+        _cap = (layer_k.q if isinstance(layer_k, QuantKV)
+                else layer_k).shape[-3]
         w_pos = jnp.where(write_mask[:, None], positions, _cap)
     else:
         w_pos = positions
+    rows = _at_layer(layer, batch_idx, w_pos)
+    ctx = _at_layer(layer, slice(None), slice(None, kv_limit))
     if isinstance(layer_k, QuantKV):
         # int8 KV: quantize the fresh chunk at write; the read span stays
         # int8 all the way into the attention dots —
@@ -452,10 +480,10 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, page_size: int,
         # k/v stay bf16 for the ring path.
         with jax.named_scope("kv_write"):
             qk, qv = kv_quantize(k), kv_quantize(v)
-            layer_k = QuantKV(q=layer_k.q.at[batch_idx, w_pos].set(qk.q),
-                              s=layer_k.s.at[batch_idx, w_pos].set(qk.s))
-            layer_v = QuantKV(q=layer_v.q.at[batch_idx, w_pos].set(qv.q),
-                              s=layer_v.s.at[batch_idx, w_pos].set(qv.s))
+            layer_k = QuantKV(q=layer_k.q.at[rows].set(qk.q),
+                              s=layer_k.s.at[rows].set(qk.s))
+            layer_v = QuantKV(q=layer_v.q.at[rows].set(qv.q),
+                              s=layer_v.s.at[rows].set(qv.s))
         if attn_impl == "paged" and S == 1:
             raise NotImplementedError(
                 "paged decode attention does not read int8 KV; the engine "
@@ -473,8 +501,8 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, page_size: int,
                 mask = kv_pos <= positions[:, :, None]
                 attn = dense_attention_quant(
                     q,
-                    layer_k.q[:, :kv_limit], layer_k.s[:, :kv_limit],
-                    layer_v.q[:, :kv_limit], layer_v.s[:, :kv_limit],
+                    layer_k.q[ctx], layer_k.s[ctx],
+                    layer_v.q[ctx], layer_v.s[ctx],
                     mask,
                 )
         with jax.named_scope("o_proj"):
@@ -488,12 +516,10 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, page_size: int,
         return _shard_residual(mesh, h + mlp), layer_k, layer_v
     else:
         with jax.named_scope("kv_write"):
-            layer_k = layer_k.at[batch_idx, w_pos].set(
-                k.astype(layer_k.dtype))
-            layer_v = layer_v.at[batch_idx, w_pos].set(
-                v.astype(layer_v.dtype))
-        k_ctx = layer_k[:, :kv_limit]
-        v_ctx = layer_v[:, :kv_limit]
+            layer_k = layer_k.at[rows].set(k.astype(layer_k.dtype))
+            layer_v = layer_v.at[rows].set(v.astype(layer_v.dtype))
+        k_ctx = layer_k[ctx]
+        v_ctx = layer_v[ctx]
     # Causal mask over absolute positions (padding queries read garbage but
     # their outputs are never used).
     kv_pos = jnp.arange(kv_limit)[None, None, :]
@@ -503,11 +529,16 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, page_size: int,
         # Ragged decode: each slot reads only its live KV pages
         # (ops/paged_attention.py); kv_limit is irrelevant — cost tracks
         # positions per slot, not the bucket.
-        from ..ops.paged_attention import paged_decode_attention
+        from ..ops.paged_attention import (paged_decode_attention,
+                                           stacked_kv)
 
-        def _paged(q1, k_all, v_all, pos1):
-            return paged_decode_attention(q1, k_all, v_all, pos1,
+        def _paged(q1, k_all, v_all, pos1, lyr):
+            return paged_decode_attention(q1, k_all, v_all, pos1, lyr[0],
                                           page_size=page_size)
+
+        # One form for the kernel and its shard_map: a layer stack (the
+        # carried cache, or the stage body's single layer) and its index.
+        k_st, v_st, lyr = stacked_kv(layer_k, layer_v, layer)
 
         if mesh is not None and (mesh.shape["data"] > 1
                                  or mesh.shape["model"] > 1):
@@ -536,24 +567,24 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, page_size: int,
                 q_ax, kv_ax = "model", None
             else:
                 q_ax, kv_ax = None, None
+            # The layer axis stays whole on every shard.
+            kv_spec = P_(None, d_ax, None, kv_ax, None)
             with jax.named_scope("attention"):
                 attn = jax.shard_map(
                     _paged, mesh=mesh,
-                    in_specs=(P_(d_ax, q_ax, None),
-                              P_(d_ax, None, kv_ax, None),
-                              P_(d_ax, None, kv_ax, None),
-                              P_(d_ax)),
+                    in_specs=(P_(d_ax, q_ax, None), kv_spec, kv_spec,
+                              P_(d_ax), P_(None)),
                     out_specs=P_(d_ax, q_ax, None),
                     axis_names={"data", "model"},
                     # pallas_call can't express per-axis varying metadata
                     # for the VMA checker; the specs above are the
                     # contract.
                     check_vma=False,
-                )(q[:, 0], layer_k, layer_v, positions[:, 0])[:, None]
+                )(q[:, 0], k_st, v_st, positions[:, 0], lyr)[:, None]
         else:
             with jax.named_scope("attention"):
-                attn = _paged(q[:, 0], layer_k, layer_v,
-                              positions[:, 0])[:, None]
+                attn = _paged(q[:, 0], k_st, v_st, positions[:, 0],
+                              lyr)[:, None]
     elif attn_impl == "ring" and S > 1:
         # Sequence-parallel self-attention over the chunk itself (no prior
         # cache context) — the from-scratch long-prefill path. K/V blocks
@@ -685,16 +716,23 @@ def forward(
     else:
         step = partial(_layer, cfg, attn_impl, mesh, page_size, moe_impl)
 
-        def scan_body(h, xs):
-            lp, layer_k, layer_v = xs
-            h, new_k, new_v = step(h, lp, layer_k, layer_v, positions, kv_limit,
-                                   batch_idx, token_mask, write_mask,
-                                   block_tables, q_lens)
-            return h, (new_k, new_v)
+        # The cache rides the CARRY, whole (ISSUE 25): as the scan's xs
+        # and ys it was sliced out a layer at a time and written back
+        # into a second stacked buffer — three moves of the whole KV pool
+        # every forward pass, to write a few rows. Carried, it stays in
+        # one (donated) buffer from argument to result; ``_layer``
+        # addresses its layer by the scanned index. One scan shape for
+        # every path: dense, pool, ragged, int8 KV.
+        def scan_body(carry, xs):
+            h, cache_k, cache_v = carry
+            lp, layer = xs
+            return step(h, lp, cache_k, cache_v, positions, kv_limit,
+                        batch_idx, token_mask, write_mask, block_tables,
+                        q_lens, layer), None
 
-        h, (new_k, new_v) = jax.lax.scan(
-            scan_body, h, (params["layers"], cache.k, cache.v)
-        )
+        (h, new_k, new_v), _ = jax.lax.scan(
+            scan_body, (h, cache.k, cache.v),
+            (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)))
 
     with jax.named_scope("final_norm"):
         h = rms_norm(h, params["final_norm"], cfg.rms_eps, cfg.rms_offset)

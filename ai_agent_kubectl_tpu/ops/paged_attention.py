@@ -55,9 +55,26 @@ def paged_supported(page_size: int, head_dim: int, n_pages: int) -> bool:
     return head_dim % 128 == 0 and page_size >= 8 and n_pages >= 1
 
 
-def _paged_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref,
+def stacked_kv(k, v, layer):
+    """(k, v, layer [1] int32) with K/V as a layer stack: a stacked cache
+    comes with its traced ``layer`` index, a bare layer becomes a
+    one-layer stack (a free reshape), so each kernel has one form and
+    its index map picks the layer — no ``cache[layer]`` copy in front of
+    the call (ISSUE 25)."""
+    if (k.ndim == 5) != (layer is not None):
+        raise ValueError(
+            "a stacked [L, ...] cache takes a layer index and a single "
+            f"layer takes none; got k.ndim={k.ndim}, "
+            f"layer={'set' if layer is not None else None}")
+    if layer is None:
+        k, v, layer = k[None], v[None], 0
+    return k, v, jnp.asarray(layer, jnp.int32).reshape(1)
+
+
+def _paged_kernel(pos_ref, lyr_ref, q_ref, k_ref, v_ref, o_ref,
                   m_scr, l_scr, acc_scr, *, page_size: int, scale: float,
                   n_pages: int, kv_heads: int):
+    del lyr_ref                       # consumed by the index map
     n = pl.program_id(0)
     p = pl.program_id(1)
     pos = pos_ref[n]
@@ -104,16 +121,16 @@ def _paged_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0] = (acc_scr[...] / l).reshape(H, hd).astype(o_ref.dtype)
 
 
-def _paged_pool_kernel(pos_ref, tbl_ref, q_ref, k_ref, v_ref, o_ref,
-                       m_scr, l_scr, acc_scr, *, page_size: int,
+def _paged_pool_kernel(pos_ref, tbl_ref, lyr_ref, q_ref, k_ref, v_ref,
+                       o_ref, m_scr, l_scr, acc_scr, *, page_size: int,
                        scale: float, n_pages: int, kv_heads: int):
     """Block-table variant: identical online-softmax body, but the KV
     blocks arrive via the table-indirected index map (``tbl_ref`` is
     consumed there, not here). Kept separate so the contiguous-cache
     kernel's signature stays frozen."""
     del tbl_ref
-    _paged_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
-                  acc_scr, page_size=page_size, scale=scale,
+    _paged_kernel(pos_ref, lyr_ref, q_ref, k_ref, v_ref, o_ref, m_scr,
+                  l_scr, acc_scr, page_size=page_size, scale=scale,
                   n_pages=n_pages, kv_heads=kv_heads)
 
 
@@ -123,10 +140,11 @@ def _paged_pool_kernel(pos_ref, tbl_ref, q_ref, k_ref, v_ref, o_ref,
 )
 def paged_decode_attention_pool(
     q: jnp.ndarray,            # [N, H, hd]  one decode query per slot
-    k: jnp.ndarray,            # [n_blocks, page, KV, hd]  shared pool
-    v: jnp.ndarray,            # [n_blocks, page, KV, hd]
+    k: jnp.ndarray,            # [n_blocks, page, KV, hd]  one layer, or
+    v: jnp.ndarray,            # [L, n_blocks, page, KV, hd] with ``layer``
     positions: jnp.ndarray,    # [N] int32 absolute query positions
     block_tables: jnp.ndarray,  # [N, max_pages] int32 pool block per page
+    layer=None,                # int32 scalar: layer of a stacked pool
     *,
     page_size: int = 128,
     scale: Optional[float] = None,
@@ -149,7 +167,8 @@ def paged_decode_attention_pool(
             "jax.experimental.pallas.tpu; use the dense gather path"
         )
     N, H, hd = q.shape
-    n_blocks, page, KV, _ = k.shape
+    k, v, lyr = stacked_kv(k, v, layer)
+    _, n_blocks, page, KV, _ = k.shape
     if page != page_size:
         raise ValueError(f"pool page {page} != page_size {page_size}")
     n_pages = block_tables.shape[1]
@@ -167,23 +186,23 @@ def paged_decode_attention_pool(
         n_pages=n_pages, kv_heads=KV,
     )
 
-    def q_map(n, p, pos_ref, tbl_ref):
+    def q_map(n, p, pos_ref, tbl_ref, lyr_ref):
         return (n, 0, 0)
 
-    def kv_map(n, p, pos_ref, tbl_ref):
+    def kv_map(n, p, pos_ref, tbl_ref, lyr_ref):
         # Clamp dead pages to the slot's last live page, then indirect
         # through the table: the repeated block index elides the fetch,
         # pl.when elides the compute.
         pp = jnp.minimum(p, pos_ref[n] // page_size)
-        return (tbl_ref[n, pp], 0, 0, 0)
+        return (lyr_ref[0], tbl_ref[n, pp], 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(N, n_pages),
         in_specs=[
             pl.BlockSpec((1, H, hd), q_map),
-            pl.BlockSpec((1, page_size, KV, hd), kv_map),
-            pl.BlockSpec((1, page_size, KV, hd), kv_map),
+            pl.BlockSpec((None, 1, page_size, KV, hd), kv_map),
+            pl.BlockSpec((None, 1, page_size, KV, hd), kv_map),
         ],
         out_specs=pl.BlockSpec((1, H, hd), q_map),
         scratch_shapes=[
@@ -197,17 +216,18 @@ def paged_decode_attention_pool(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((N, H, hd), q.dtype),
         interpret=interpret,
-    )(pos, tbl, q, k, v)
+    )(pos, tbl, lyr, q, k, v)
     return out
 
 
 def paged_decode_attention_pool_sharded(
     q: jnp.ndarray,            # [N, H, hd]
-    k: jnp.ndarray,            # [n_blocks, page, KV, hd]
-    v: jnp.ndarray,
+    k: jnp.ndarray,            # [n_blocks, page, KV, hd], or the
+    v: jnp.ndarray,            # stacked [L, ...] pool with ``layer``
     positions: jnp.ndarray,    # [N]
     block_tables: jnp.ndarray,  # [N, max_pages]
     mesh,
+    layer=None,
     *,
     page_size: int = 128,
 ) -> jnp.ndarray:
@@ -222,10 +242,10 @@ def paged_decode_attention_pool_sharded(
     head counts don't divide the axis (the gather/dense path serves
     those meshes instead — engine startup picks it)."""
     tp = mesh.shape["model"] if mesh is not None else 1
-    H, KV = q.shape[1], k.shape[2]
+    H, KV = q.shape[1], k.shape[-2]
     if tp <= 1:
         return paged_decode_attention_pool(q, k, v, positions,
-                                           block_tables,
+                                           block_tables, layer,
                                            page_size=page_size)
     if KV % tp or H % tp:
         raise ValueError(
@@ -236,23 +256,25 @@ def paged_decode_attention_pool_sharded(
 
     P_ = jsh.PartitionSpec
 
-    def _local(ql, kl, vl, pos, tbl):
-        return paged_decode_attention_pool(ql, kl, vl, pos, tbl,
+    def _local(ql, kl, vl, pos, tbl, lyr):
+        return paged_decode_attention_pool(ql, kl, vl, pos, tbl, lyr[0],
                                            page_size=page_size)
 
+    # One form through the shard_map: a layer stack (whole on every
+    # shard) and its replicated index.
+    k, v, lyr = stacked_kv(k, v, layer)
+    kv_spec = P_(None, None, None, "model", None)
     return jax.shard_map(
         _local, mesh=mesh,
-        in_specs=(P_(None, "model", None),
-                  P_(None, None, "model", None),
-                  P_(None, None, "model", None),
-                  P_(None), P_(None, None)),
+        in_specs=(P_(None, "model", None), kv_spec, kv_spec,
+                  P_(None), P_(None, None), P_(None)),
         out_specs=P_(None, "model", None),
         axis_names=set(mesh.axis_names),
         # pallas_call can't express per-axis varying metadata for the
         # VMA checker; the specs above are the contract (same rule as
         # the dense-path shard_map in models/transformer.py).
         check_vma=False,
-    )(q, k, v, positions, block_tables)
+    )(q, k, v, positions, block_tables, lyr)
 
 
 @functools.partial(
@@ -261,9 +283,10 @@ def paged_decode_attention_pool_sharded(
 )
 def paged_decode_attention(
     q: jnp.ndarray,          # [N, H, hd]  one decode query per slot
-    k: jnp.ndarray,          # [N, S, KV, hd]  slot caches (abs positions)
-    v: jnp.ndarray,          # [N, S, KV, hd]
+    k: jnp.ndarray,          # [N, S, KV, hd]  slot caches (abs positions),
+    v: jnp.ndarray,          # or the stacked [L, N, S, KV, hd] with ``layer``
     positions: jnp.ndarray,  # [N] int32 absolute query positions
+    layer=None,              # int32 scalar: layer of a stacked cache
     *,
     page_size: int = 128,
     scale: Optional[float] = None,
@@ -282,7 +305,8 @@ def paged_decode_attention(
             "(DECODE_ATTN=dense)"
         )
     N, H, hd = q.shape
-    S, KV = k.shape[1], k.shape[2]
+    k, v, lyr = stacked_kv(k, v, layer)
+    S, KV = k.shape[2], k.shape[3]
     if S % page_size:
         raise ValueError(f"cache span {S} not divisible by page {page_size}")
     n_pages = S // page_size
@@ -299,21 +323,21 @@ def paged_decode_attention(
         kv_heads=KV,
     )
 
-    def q_map(n, p, pos_ref):
+    def q_map(n, p, pos_ref, lyr_ref):
         return (n, 0, 0)
 
-    def kv_map(n, p, pos_ref):
+    def kv_map(n, p, pos_ref, lyr_ref):
         # Clamp dead pages to the last live page: the repeated block index
         # elides the fetch, pl.when elides the compute.
-        return (n, jnp.minimum(p, pos_ref[n] // page_size), 0, 0)
+        return (lyr_ref[0], n, jnp.minimum(p, pos_ref[n] // page_size), 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(N, n_pages),
         in_specs=[
             pl.BlockSpec((1, H, hd), q_map),
-            pl.BlockSpec((1, page_size, KV, hd), kv_map),
-            pl.BlockSpec((1, page_size, KV, hd), kv_map),
+            pl.BlockSpec((None, 1, page_size, KV, hd), kv_map),
+            pl.BlockSpec((None, 1, page_size, KV, hd), kv_map),
         ],
         out_specs=pl.BlockSpec((1, H, hd), q_map),
         scratch_shapes=[
@@ -327,5 +351,5 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((N, H, hd), q.dtype),
         interpret=interpret,
-    )(pos, q, k, v)
+    )(pos, lyr, q, k, v)
     return out

@@ -15,7 +15,12 @@ Shape contract:
   slot n's valid queries are columns ``0 .. q_lens[n]-1``, the first at
   absolute position ``positions[n]`` (so column j sits at
   ``positions[n] + j``).
-- ``k``/``v``      [n_blocks, page, KV, hd] — the shared block pool.
+- ``k``/``v``      [n_blocks, page, KV, hd] — one layer of the shared
+  block pool — or the whole stacked pool [L, n_blocks, page, KV, hd]
+  with ``layer`` (a traced scalar) picking the layer. The layer index is
+  scalar-prefetched into the K/V index map, so the kernel streams pages
+  of that layer straight out of the stacked buffer: a ``pool[layer]``
+  slice in front of the call would copy the layer every pass (ISSUE 25).
 - ``q_lens``       [N] int32 — 0 freezes a slot (output rows are zeros,
   compute masked); 1 = decode; k+1 = verify; span = prefill.
 - ``positions``    [N] int32 — absolute position of query column 0.
@@ -50,6 +55,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .paged_attention import stacked_kv
 
 try:
     from jax.experimental.pallas import tpu as pltpu
@@ -89,8 +96,8 @@ def _last_live_page(pos, q_len, q0, tq: int, page_size: int):
     return (pos + hi - 1) // page_size
 
 
-def _ragged_pool_kernel(pos_ref, qlen_ref, tbl_ref, q_ref, k_ref, v_ref,
-                        o_ref, m_scr, l_scr, acc_scr, *, page_size: int,
+def _ragged_pool_kernel(pos_ref, qlen_ref, tbl_ref, lyr_ref, q_ref, k_ref,
+                        v_ref, o_ref, m_scr, l_scr, acc_scr, *, page_size: int,
                         scale: float, n_pages: int, kv_heads: int,
                         tq: int):
     """Online-softmax body over one (slot, query tile, page) grid cell,
@@ -99,7 +106,7 @@ def _ragged_pool_kernel(pos_ref, qlen_ref, tbl_ref, q_ref, k_ref, v_ref,
     KV-batched ``dot_general`` serves every query column and head of
     the block — the same working-set shape as the W=1 paged kernel,
     widened."""
-    del tbl_ref                       # consumed by the index map
+    del tbl_ref, lyr_ref              # consumed by the index map
     n = pl.program_id(0)
     q0 = pl.program_id(1) * tq        # window column of tile row 0
     p = pl.program_id(2)
@@ -168,11 +175,12 @@ def _ragged_pool_kernel(pos_ref, qlen_ref, tbl_ref, q_ref, k_ref, v_ref,
 )
 def ragged_attention_pool(
     q: jnp.ndarray,             # [N, W, H, hd] per-slot query windows
-    k: jnp.ndarray,             # [n_blocks, page, KV, hd] shared pool
-    v: jnp.ndarray,             # [n_blocks, page, KV, hd]
+    k: jnp.ndarray,             # [n_blocks, page, KV, hd] one layer, or
+    v: jnp.ndarray,             # [L, n_blocks, page, KV, hd] with ``layer``
     q_lens: jnp.ndarray,        # [N] int32 valid queries per slot
     positions: jnp.ndarray,     # [N] int32 abs position of column 0
     block_tables: jnp.ndarray,  # [N, max_pages] int32
+    layer=None,                 # int32 scalar: layer of a stacked pool
     *,
     page_size: int = 128,
     scale: Optional[float] = None,
@@ -191,7 +199,8 @@ def ragged_attention_pool(
             "use the dense gather path"
         )
     N, W, H, hd = q.shape
-    n_blocks, page, KV, _ = k.shape
+    k, v, lyr = stacked_kv(k, v, layer)
+    _, n_blocks, page, KV, _ = k.shape
     if page != page_size:
         raise ValueError(f"pool page {page} != page_size {page_size}")
     n_pages = block_tables.shape[1]
@@ -216,25 +225,25 @@ def ragged_attention_pool(
         n_pages=n_pages, kv_heads=KV, tq=tq,
     )
 
-    def q_map(n, t, p, pos_ref, qlen_ref, tbl_ref):
+    def q_map(n, t, p, pos_ref, qlen_ref, tbl_ref, lyr_ref):
         return (n, t, 0, 0)
 
-    def kv_map(n, t, p, pos_ref, qlen_ref, tbl_ref):
+    def kv_map(n, t, p, pos_ref, qlen_ref, tbl_ref, lyr_ref):
         # Clamp dead pages to the tile's LAST LIVE page (which covers
         # its own freshly-written rows), then indirect through the
         # table — repeat block indices elide the fetch, pl.when elides
         # the compute.
         last = _last_live_page(pos_ref[n], qlen_ref[n], t * tq, tq,
                                page_size)
-        return (tbl_ref[n, jnp.minimum(p, last)], 0, 0, 0)
+        return (lyr_ref[0], tbl_ref[n, jnp.minimum(p, last)], 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(N, n_qt, n_pages),
         in_specs=[
             pl.BlockSpec((1, tq, H, hd), q_map),
-            pl.BlockSpec((1, page_size, KV, hd), kv_map),
-            pl.BlockSpec((1, page_size, KV, hd), kv_map),
+            pl.BlockSpec((None, 1, page_size, KV, hd), kv_map),
+            pl.BlockSpec((None, 1, page_size, KV, hd), kv_map),
         ],
         out_specs=pl.BlockSpec((1, tq, H, hd), q_map),
         scratch_shapes=[
@@ -248,18 +257,19 @@ def ragged_attention_pool(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((N, n_qt * tq, H, hd), q.dtype),
         interpret=interpret,
-    )(pos, qln, tbl, q, k, v)
+    )(pos, qln, tbl, lyr, q, k, v)
     return out[:, :W]
 
 
 def ragged_attention_pool_sharded(
     q: jnp.ndarray,             # [N, W, H, hd]
-    k: jnp.ndarray,             # [n_blocks, page, KV, hd]
-    v: jnp.ndarray,
+    k: jnp.ndarray,             # [n_blocks, page, KV, hd], or the
+    v: jnp.ndarray,             # stacked [L, ...] pool with ``layer``
     q_lens: jnp.ndarray,        # [N]
     positions: jnp.ndarray,     # [N]
     block_tables: jnp.ndarray,  # [N, max_pages]
     mesh,
+    layer=None,
     *,
     page_size: int = 128,
 ) -> jnp.ndarray:
@@ -270,14 +280,16 @@ def ragged_attention_pool_sharded(
     ``model`` — the pool shards on the KV-head axis
     (parallel/sharding.py::pool_cache_specs), so each shard holds whole
     KV groups and the local G = H_local/KV_local stays the true
-    grouping. Positions, query lengths and tables are replicated
-    (per-slot host truth). Head counts that don't divide the axis serve
+    grouping. Positions, query lengths, tables and the layer index are
+    replicated (per-slot host truth); a stacked pool's layer axis stays
+    whole on every shard. Head counts that don't divide the axis serve
     the LOUD gather fallback instead — engine startup resolves that."""
     tp = mesh.shape["model"] if mesh is not None else 1
-    H, KV = q.shape[2], k.shape[2]
+    H, KV = q.shape[2], k.shape[-2]
     if tp <= 1:
         return ragged_attention_pool(q, k, v, q_lens, positions,
-                                     block_tables, page_size=page_size)
+                                     block_tables, layer,
+                                     page_size=page_size)
     if KV % tp or H % tp:
         raise ValueError(
             f"ragged pool kernel needs KV ({KV}) and H ({H}) divisible "
@@ -287,20 +299,22 @@ def ragged_attention_pool_sharded(
 
     P_ = jsh.PartitionSpec
 
-    def _local(ql, kl, vl, qlen, pos, tbl):
-        return ragged_attention_pool(ql, kl, vl, qlen, pos, tbl,
+    def _local(ql, kl, vl, qlen, pos, tbl, lyr):
+        return ragged_attention_pool(ql, kl, vl, qlen, pos, tbl, lyr[0],
                                      page_size=page_size)
 
+    # One form through the shard_map: a layer stack (whole on every
+    # shard) and its replicated index.
+    k, v, lyr = stacked_kv(k, v, layer)
+    kv_spec = P_(None, None, None, "model", None)
     return jax.shard_map(
         _local, mesh=mesh,
-        in_specs=(P_(None, None, "model", None),
-                  P_(None, None, "model", None),
-                  P_(None, None, "model", None),
-                  P_(None), P_(None), P_(None, None)),
+        in_specs=(P_(None, None, "model", None), kv_spec, kv_spec,
+                  P_(None), P_(None), P_(None, None), P_(None)),
         out_specs=P_(None, None, "model", None),
         axis_names=set(mesh.axis_names),
         # pallas_call can't express per-axis varying metadata for the
         # VMA checker; the specs above are the contract (same rule as
         # the paged kernel's shard_map).
         check_vma=False,
-    )(q, k, v, q_lens, positions, block_tables)
+    )(q, k, v, q_lens, positions, block_tables, lyr)

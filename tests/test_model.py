@@ -172,3 +172,85 @@ def test_param_count_sanity():
     assert 6e9 < get_config("llama-3-8b-instruct").param_count() < 9e9
     assert 4e10 < get_config("mixtral-8x7b-instruct").param_count() < 5.2e10
     assert 6e10 < get_config("llama-3-70b-instruct").param_count() < 8e10
+
+
+# ------------------------------------------- the cache rides the layer scan
+#
+# ISSUE 25: as the layer scan's xs/ys the stacked cache was sliced out a
+# layer at a time and written back into a second stacked buffer — on the
+# chip, three moves of the whole KV pool every forward pass. These pin the
+# structure that removed them, in every cache layout.
+
+def _forward_case(cfg, mode):
+    """(cache, forward kwargs) of one cache layout at toy size."""
+    B, page, pages = 2, 8, 4
+    if mode == "dense":
+        return KVCache.zeros(cfg, B, pages * page, dtype=jnp.float32), {}
+    if mode == "dense_int8":
+        return KVCache.zeros(cfg, B, pages * page, kv_quant="int8"), {}
+    n_blocks = 256
+    pool = (cfg.n_layers, n_blocks, page, cfg.n_kv_heads, cfg.head_dim)
+    cache = KVCache(k=jnp.zeros(pool, jnp.float32),
+                    v=jnp.zeros(pool, jnp.float32),
+                    lengths=jnp.zeros((n_blocks,), jnp.int32))
+    tables = jnp.arange(B * pages, dtype=jnp.int32).reshape(B, pages)
+    return cache, dict(
+        block_tables=tables, page_size=page,
+        attn_impl="ragged" if mode == "pool_ragged" else "dense")
+
+
+@pytest.mark.parametrize(
+    "mode", ["pool_ragged", "pool_gather", "dense", "dense_int8"])
+def test_layer_scan_carries_the_cache(toy, mode):
+    """The layer scan has every cache leaf among its CARRIES and nothing
+    cache-shaped among its xs or ys (what it scans over is the layer
+    parameters and the layer index)."""
+    cfg, params = toy
+    cache, kw = _forward_case(cfg, mode)
+    tok = jnp.zeros((2, 1), jnp.int32)
+    pos = jnp.full((2, 1), 5, jnp.int32)
+    jaxpr = jax.make_jaxpr(
+        lambda p, c: forward(p, cfg, tok, pos, c, kv_limit=32, **kw)
+    )(params, cache).jaxpr
+    scans = [e for e in jaxpr.eqns if e.primitive.name == "scan"
+             and e.params["length"] == cfg.n_layers]
+    assert len(scans) == 1, "one layer scan"
+    eqn = scans[0]
+    n_const, n_carry = eqn.params["num_consts"], eqn.params["num_carry"]
+
+    def shapes(vs):
+        return [tuple(v.aval.shape) for v in vs]
+
+    carries = shapes(eqn.invars[n_const:n_const + n_carry])
+    xs = shapes(eqn.invars[n_const + n_carry:])
+    ys = shapes(eqn.outvars[n_carry:])
+    leaves = [tuple(x.shape)
+              for x in jax.tree_util.tree_leaves((cache.k, cache.v))]
+    for leaf in leaves:
+        assert carries.count(leaf) >= leaves.count(leaf), (leaf, carries)
+        assert leaf not in xs and leaf not in ys, (leaf, xs, ys)
+    assert ys == [], f"the layer scan stacks nothing: {ys}"
+    assert (cfg.n_layers,) in xs, "the layer index is scanned over"
+
+
+def test_donated_pool_forward_needs_less_than_one_pool_of_temporaries(toy):
+    """With the pool donated, a pool-mode forward's temporaries stay below
+    one pool's bytes: nothing holds a second copy of it. (As the scan's
+    xs/ys the pool cost at least one more of itself. The gather path is
+    the one measured: this backend runs the Pallas kernel interpreted,
+    which copies its operands — the compiled kernel's side of this is
+    PERF.md's AOT table.)"""
+    cfg, params = toy
+    cache, kw = _forward_case(cfg, "pool_gather")
+    tok = jnp.zeros((2, 1), jnp.int32)
+    pos = jnp.full((2, 1), 5, jnp.int32)
+    compiled = jax.jit(
+        lambda p, c: forward(p, cfg, tok, pos, c, kv_limit=32, **kw),
+        donate_argnums=(1,)).lower(params, cache).compile()
+    mem = compiled.memory_analysis()
+    if mem is None or not hasattr(mem, "temp_size_in_bytes"):
+        pytest.skip("this backend reports no memory analysis")
+    pool_bytes = cache.k.nbytes + cache.v.nbytes
+    assert mem.alias_size_in_bytes >= pool_bytes, "the pool is donated"
+    assert mem.temp_size_in_bytes < pool_bytes, (
+        mem.temp_size_in_bytes, pool_bytes)

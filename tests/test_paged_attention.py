@@ -59,6 +59,43 @@ def test_paged_pool_block_table_matches_dense(kv_heads):
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("kernel", ["contiguous", "block_table"])
+def test_paged_stacked_cache_layer_index_equals_layer_slice(kernel):
+    """ISSUE 25: both single-query kernels read a layer of the STACKED
+    cache through their index maps — the call with the [L, ...] cache and
+    a layer index equals the call on ``cache[layer]`` bit for bit, for a
+    first, middle and last layer (every other layer is NaN)."""
+    from ai_agent_kubectl_tpu.ops.paged_attention import (
+        paged_decode_attention_pool)
+
+    L, N, S, H, KV, hd, page = 3, 3, 64, 4, 2, 64, 16
+    q, k, v = _rand(N, S, H, KV, hd, seed=3)
+    positions = jnp.asarray([40, 17, 63], jnp.int32)
+    tables = jnp.asarray([[7, 2, 9, 12], [7, 5, 12, 12], [0, 1, 3, 4]],
+                         jnp.int32)
+    if kernel == "block_table":
+        k = k.reshape(N * S // page, page, KV, hd)
+        v = v.reshape(N * S // page, page, KV, hd)
+
+    def call(kk, vv, *layer):
+        if kernel == "block_table":
+            return paged_decode_attention_pool(
+                q, kk, vv, positions, tables, *layer, page_size=page,
+                interpret=True)
+        return paged_decode_attention(q, kk, vv, positions, *layer,
+                                      page_size=page, interpret=True)
+
+    sliced = np.asarray(call(k, v))
+    for layer in range(L):
+        nan = jnp.full_like(k, jnp.nan)
+        sk = jnp.stack([k if i == layer else nan for i in range(L)])
+        sv = jnp.stack([v if i == layer else nan for i in range(L)])
+        np.testing.assert_array_equal(
+            np.asarray(call(sk, sv, jnp.int32(layer))), sliced)
+    with pytest.raises(ValueError, match="takes a layer index"):
+        call(jnp.stack([k, k]), jnp.stack([v, v]))
+
+
 @pytest.mark.parametrize("kv_heads", [1, 2])   # MQA and GQA
 def test_paged_matches_dense_ragged(kv_heads):
     N, S, H, hd, page = 4, 128, 4, 64, 16

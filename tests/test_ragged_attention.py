@@ -212,6 +212,41 @@ def test_ragged_kernel_decode_column_equals_own_window():
                                atol=2e-5, rtol=2e-5)
 
 
+@pytest.mark.parametrize("layer", [0, 2, 4], ids=["first", "middle", "last"])
+@pytest.mark.parametrize("W", [1, 4, 24], ids=["decode", "verify", "prefill"])
+def test_ragged_kernel_stacked_pool_layer_index_equals_layer_slice(W, layer):
+    """ISSUE 25: the kernel reads a layer of the STACKED pool through its
+    index map. The 5-D pool + layer-index call equals the 4-D call on
+    ``pool[layer]`` bit for bit — the layer scan hands the kernel the
+    carried pool whole, so no ``pool[layer]`` copy stands in front of
+    it. The prefill width is three query tiles at this head geometry;
+    every other layer is NaN, so a wrong layer cannot pass."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(3)
+    L, page, n_blocks, KV, H, hd, N = 5, 8, 12, 2, 4, 16, 3
+    pool_k = np.full((L, n_blocks, page, KV, hd), np.nan, np.float32)
+    pool_v = np.full_like(pool_k, np.nan)
+    pool_k[layer] = rng.standard_normal(pool_k.shape[1:])
+    pool_v[layer] = rng.standard_normal(pool_v.shape[1:])
+    q = rng.standard_normal((N, W, H, hd)).astype(np.float32)
+    q_lens = np.array([W, max(W - 1, 1), 0], np.int32)
+    positions = np.array([19, 3, 7], np.int32)
+    tables = np.array([[7, 2, 9, 4, 1, 99], [7, 5, 3, 0, 99, 99],
+                       [6, 8, 99, 99, 99, 99]], np.int32)
+    stacked = np.asarray(ragged_attention_pool(
+        q, pool_k, pool_v, q_lens, positions, tables,
+        jnp.int32(layer), page_size=page))
+    sliced = np.asarray(ragged_attention_pool(
+        q, pool_k[layer], pool_v[layer], q_lens, positions, tables,
+        page_size=page))
+    assert not np.isnan(stacked).any(), "another layer's rows leaked"
+    np.testing.assert_array_equal(stacked, sliced)
+    with pytest.raises(ValueError, match="takes a layer index"):
+        ragged_attention_pool(q, pool_k, pool_v, q_lens, positions,
+                              tables, page_size=page)
+
+
 def test_ragged_kernel_sharded_parity_and_head_divisibility():
     """tp=2 divides KV=2/H=4: the shard_mapped kernel is bitwise the
     single-device call. tp=8 does not: a LOUD ValueError (engine
@@ -228,6 +263,11 @@ def test_ragged_kernel_sharded_parity_and_head_divisibility():
     sharded = np.asarray(ragged_attention_pool_sharded(
         q, k, v, q_lens, positions, tables, mesh2, page_size=page))
     np.testing.assert_allclose(sharded, base, atol=2e-5, rtol=2e-5)
+    # The stacked pool passes its layer axis unsharded (ISSUE 25).
+    stacked = np.asarray(ragged_attention_pool_sharded(
+        q, np.stack([np.zeros_like(k), k]), np.stack([np.zeros_like(v), v]),
+        q_lens, positions, tables, mesh2, 1, page_size=page))
+    np.testing.assert_array_equal(stacked, sharded)
 
     devs8 = np.array(jax.devices()[:8]).reshape(1, 8)
     mesh8 = Mesh(devs8, ("data", "model"))

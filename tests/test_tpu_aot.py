@@ -1,0 +1,112 @@
+"""Compiles for a DESCRIBED TPU v5e (no chip attached; the TPU compiler is
+installed here): what the interpreter and the CPU backend cannot show about
+the serving path's programs — that Mosaic accepts a kernel, what XLA keeps in
+HBM, which ops move pool-sized buffers. Nothing runs, so no test here says
+anything about results or times.
+
+Every compile for a described chip lives in THIS file, behind the fixtures
+below: the process that describes the topology loads the TPU library and
+keeps it, so it must happen inside a test, in one worker.
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from ai_agent_kubectl_tpu.models.config import ModelConfig
+from ai_agent_kubectl_tpu.models.transformer import KVCache, forward
+from ai_agent_kubectl_tpu.ops.quant import random_params_int8
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # no TPU compiler here: nothing to compile with
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _results_of_size(hlo: str, sizes) -> list:
+    """(op, result shape) of every HLO instruction whose result holds an
+    array with one of ``sizes`` elements — moves of data only: parameters,
+    tuples and their elements, bitcasts and loops name buffers, they do not
+    fill them."""
+    names = {"parameter", "get-tuple-element", "bitcast", "tuple", "while"}
+    hits = []
+    for line in hlo.splitlines():
+        m = re.match(r"\s+(?:ROOT )?%?[\w.\-]+ = (.*?) ([\w\-]+)\(", line)
+        if not m or m.group(2) in names:
+            continue
+        for dims in re.findall(r"\w+\[([\d,]+)\]", m.group(1)):
+            n = 1
+            for d in dims.split(","):
+                n *= int(d)
+            if n in sizes:
+                hits.append((m.group(2), dims))
+    return hits
+
+
+@pytest.mark.parametrize("W", [1, 64], ids=["decode", "admission"])
+def test_pool_forward_moves_no_pool_sized_buffer_on_v5e(one_chip, W,
+                                                        monkeypatch):
+    """ISSUE 25 at Mistral-7B's widths (2 layers, the benchmark's pool of
+    320 blocks of 64, batch 16): the compiled pool+ragged forward with the
+    cache donated holds the Mosaic kernel, its only ops with a pool-sized
+    or pool-layer-sized result are the two in-place row scatters, and its
+    temporaries stay below one pool (as the scan's xs/ys the pool was
+    sliced, copied and rebuilt every pass, and held twice)."""
+    # ops/ragged_attention.py interprets the kernel off-TPU; this compile
+    # is for the TPU, whatever backend the process runs on.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = ModelConfig(name="aot", vocab_size=32000, dim=4096, n_layers=2,
+                      n_heads=32, n_kv_heads=8, head_dim=128,
+                      mlp_hidden=14336, rope_theta=1e6, eos_ids=(2,),
+                      tie_embeddings=False)
+    B, page, n_blocks, pages = 16, 64, 320, 64
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda x: arg(x.shape, x.dtype),
+        jax.eval_shape(lambda k: random_params_int8(
+            k, cfg, dtype=jnp.bfloat16, quantize_embed=True),
+            jax.random.PRNGKey(0)))
+    pool = (cfg.n_layers, n_blocks, page, cfg.n_kv_heads, cfg.head_dim)
+    cache = KVCache(k=arg(pool, jnp.bfloat16), v=arg(pool, jnp.bfloat16),
+                    lengths=arg((n_blocks,), jnp.int32))
+
+    def step(params, tok, pos, cache, wmask, tables, q_lens):
+        return forward(params, cfg, tok, pos, cache, kv_limit=pages * page,
+                       attn_impl="ragged", token_mask=wmask,
+                       write_mask=wmask, page_size=page,
+                       block_tables=tables, q_lens=q_lens,
+                       logits_at=jnp.maximum(q_lens, 1) - 1)
+
+    compiled = jax.jit(step, donate_argnums=(3,)).lower(
+        params, arg((B, W), jnp.int32), arg((B, W), jnp.int32), cache,
+        arg((B, W), jnp.bool_), arg((B, pages), jnp.int32),
+        arg((B,), jnp.int32)).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo, "the Mosaic kernel is not in the program"
+    leaf = n_blocks * page * cfg.n_kv_heads * cfg.head_dim
+    moved = _results_of_size(hlo, {leaf, cfg.n_layers * leaf})
+    assert moved, "the row writes should be in the program"
+    # each scatter shows twice: inside its fusion, and as the fusion
+    assert {op for op, _ in moved} <= {"scatter", "fusion"}, moved
+    assert len(moved) == 4, moved
+    pool_bytes = 2 * 2 * cfg.n_layers * leaf        # K and V, bf16
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes, "the pool is donated"
+    assert mem.temp_size_in_bytes < pool_bytes, (
+        mem.temp_size_in_bytes, pool_bytes)

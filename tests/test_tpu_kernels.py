@@ -278,6 +278,36 @@ def test_compiled_ragged_pool_matches_gather(H, KV, W):
         assert not out[n, q_len:].any(), f"slot {n}: padding rows not zero"
 
 
+@pytest.mark.parametrize("W", (1, 64, 1024))
+def test_compiled_ragged_stacked_pool_layer_equals_layer_slice(W):
+    """ISSUE 25: the COMPILED kernel handed the stacked pool and a layer
+    index (as the layer scan hands it the carried pool) returns, bit for
+    bit, what it returns for that layer alone — for the first, a middle
+    and the last layer of a stack whose other layers are NaN."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ai_agent_kubectl_tpu.ops.ragged_attention import \
+        ragged_attention_pool
+
+    H, KV = _POOL_GEOMETRIES[0]
+    spans = [(700, 1), (333, min(W, 4)), (0, W), (700, 0)]
+    q, (k, v), _clean, q_lens, positions, tables = _pool_case(
+        H, KV, W, spans, seed=50)
+    alone = np.asarray(ragged_attention_pool(
+        q, k, v, q_lens, positions, tables, page_size=_POOL_PAGE,
+        interpret=False).astype(jnp.float32))
+    L = 3
+    for layer in range(L):
+        nan = jnp.full_like(k, jnp.nan)
+        sk = jnp.stack([k if i == layer else nan for i in range(L)])
+        sv = jnp.stack([v if i == layer else nan for i in range(L)])
+        out = np.asarray(ragged_attention_pool(
+            q, sk, sv, q_lens, positions, tables, jnp.int32(layer),
+            page_size=_POOL_PAGE, interpret=False).astype(jnp.float32))
+        np.testing.assert_array_equal(out, alone)
+
+
 @pytest.mark.parametrize("H,KV", _POOL_GEOMETRIES)
 def test_compiled_paged_pool_matches_gather(H, KV):
     """Compiled block-table decode kernel vs the dense gather reference
