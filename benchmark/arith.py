@@ -31,3 +31,11 @@ def token_rate(requests: Iterable[tuple], t0: float, seconds: float) -> float:
             inside = sum(1 for t in frames if t0 <= t < t0 + seconds)
             total += tokens * inside / len(frames)
     return total / seconds
+
+
+def at_path(tree, path):
+    """The number at ``path`` (a list of keys) in nested dicts such as a
+    /health body; a missing key, or a count that never started, is 0."""
+    for key in path:
+        tree = tree.get(key) if isinstance(tree, dict) else None
+    return tree or 0.0

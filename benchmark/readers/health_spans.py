@@ -12,10 +12,7 @@ admission waited staged: 0 ms each); a program without the section, or a
 denominator of 0, gives ``None`` and the metric is left out."""
 
 
-def _at(spans, path):
-    for key in path:
-        spans = spans.get(key) if isinstance(spans, dict) else None
-    return spans or 0.0
+from arith import at_path as _at
 
 
 def read(ctx, params):

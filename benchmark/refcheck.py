@@ -7,9 +7,13 @@ widths cut to ``reference_check.layers`` layers so that the float32 reference
 fits beside the int8 weights. The program side is the serving path: the
 model's ``forward`` through the block-paged pool and the ragged attention
 kernel — one ragged prefill window over two prompts of unequal length, then
-decode steps through the cache. The reference side is the full forward pass
-of the file named by the configuration, one sequence at a time. Logits are
-compared, never sampled tokens.
+decode steps through the cache. Where the configuration's file names a mesh,
+params and pool are placed over it with the program's own parallel/sharding.py
+functions and ``forward`` is given the mesh, as the engine does, so the
+comparison vouches for the path the server takes. The reference side is the
+full forward pass of the file named by the configuration, one sequence at a
+time, unsharded float32 on one device. Logits are compared, never sampled
+tokens.
 """
 
 from __future__ import annotations
@@ -73,25 +77,49 @@ def reference_weights(params, n_layers: int) -> dict:
     }
 
 
+def place_on_mesh(axes: dict, cfg, params, cache, tables):
+    """Params, pool and block tables over the configuration's mesh, placed as
+    the engine places them (jax_engine.py::_setup_mesh and _load,
+    batcher.py's pool): the program's own mesh builder and sharding policy."""
+    import jax
+
+    from ai_agent_kubectl_tpu.parallel.mesh import MeshConfig, build_mesh
+    from ai_agent_kubectl_tpu.parallel.sharding import (replicate, shard_params,
+                                                        shard_pool_cache)
+
+    mesh_cfg = MeshConfig(**axes)
+    if mesh_cfg.n_devices > len(jax.devices()):
+        raise SystemExit(f"refcheck: the mesh {axes} wants {mesh_cfg.n_devices} devices; "
+                         f"{len(jax.devices())} present")
+    mesh = build_mesh(mesh_cfg, jax.devices()[:mesh_cfg.n_devices])
+    return (mesh, shard_params(params, mesh, cfg), shard_pool_cache(cache, mesh, cfg),
+            replicate(tables, mesh))
+
+
+def weights_function(ref):
+    """``weights_from_program(params, n_layers)`` of the reference's module, for
+    a family whose weight tree ``reference_weights`` does not fit; else that."""
+    return getattr(ref, "weights_from_program", reference_weights)
+
+
 def run(cfg_file: dict, sz: dict, seed: int, rehearse: bool = False) -> dict:
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from ai_agent_kubectl_tpu.models.config import ModelConfig
     from ai_agent_kubectl_tpu.models.transformer import KVCache, forward
     from ai_agent_kubectl_tpu.ops.quant import random_params_int8
-    from modelmap import KEY_MAP
+    from modelmap import key_map, mesh_of, model_config, rehearsal_mesh
 
     t0 = time.monotonic()
     chk = cfg_file["reference_check"]
     sz = dict(sz, num_hidden_layers=chk["layers"])
     if rehearse:
         sz.update(REHEARSAL_SIZES)
-    fields = {KEY_MAP[k]: v for k, v in sz.items() if k in KEY_MAP}
-    cfg = ModelConfig(name="refcheck", eos_ids=(sz["eos_token_id"],), **fields)
-    params = random_params_int8(jax.random.PRNGKey(seed), cfg,
-                                dtype=jnp.bfloat16, quantize_embed=True)
+    cfg = model_config("refcheck", sz, key_map(cfg_file))
+    # the reference reads these, whole on one device, whatever the mesh
+    whole_params = params = random_params_int8(jax.random.PRNGKey(seed), cfg,
+                                               dtype=jnp.bfloat16, quantize_embed=True)
     jax.block_until_ready(params)
     t_init = time.monotonic()
 
@@ -106,12 +134,17 @@ def run(cfg_file: dict, sz: dict, seed: int, rehearse: bool = False) -> dict:
     cache = KVCache(k=jnp.zeros(pool, jnp.bfloat16), v=jnp.zeros(pool, jnp.bfloat16),
                     lengths=jnp.zeros((n_blocks,), jnp.int32))
     tables = jnp.arange(n_blocks, dtype=jnp.int32).reshape(B, pages)
+    axes, mesh = mesh_of(cfg_file), None
+    if rehearse:
+        axes = rehearsal_mesh(axes)
+    if axes:
+        mesh, params, cache, tables = place_on_mesh(axes, cfg, params, cache, tables)
 
     def step(params, tok, pos, cache, wmask, q_lens):
         # engine/batcher.py::ragged_forward_step_fn, with every position's
         # logits kept (the server keeps only the last valid one).
         return forward(params, cfg, tok, pos, cache, kv_limit=pages * PAGE,
-                       attn_impl="ragged", token_mask=wmask, write_mask=wmask,
+                       attn_impl="ragged", mesh=mesh, token_mask=wmask, write_mask=wmask,
                        page_size=PAGE, block_tables=tables, q_lens=q_lens)
 
     step = jax.jit(step)
@@ -133,14 +166,14 @@ def run(cfg_file: dict, sz: dict, seed: int, rehearse: bool = False) -> dict:
 
     t_program = time.monotonic()
     ref = load_reference(cfg_file["reference"])
-    ref_forward = jax.jit(lambda p, t: ref.forward(
-        sz, reference_weights(p, cfg.n_layers), t))
+    weights_of = weights_function(ref)
+    ref_forward = jax.jit(lambda p, t: ref.forward(sz, weights_of(p, cfg.n_layers), t))
     rule = chk.get("clear_if")      # {"aux": <name in the reference's aux>, "min": x}
     clear_errs, unclear_errs, ref_std = [], [], []
     for b, n in enumerate(lens):
         # The whole row, one compiled shape for every sequence: attention is
         # causal, so the tokens past n + steps change nothing before them.
-        want, aux = ref_forward(params, jnp.asarray(toks[b]))
+        want, aux = ref_forward(whole_params, jnp.asarray(toks[b]))
         want = np.asarray(want)[:n + steps]
         have = np.concatenate(got[b], axis=0)
         err = np.abs(have - want).max(axis=1)       # one number a position
@@ -166,8 +199,9 @@ def run(cfg_file: dict, sz: dict, seed: int, rehearse: bool = False) -> dict:
     ok = bool(np.isfinite(rel) and rel <= chk["tolerance_rel"]
               and (rel_unclear is None or rel_unclear <= chk["tolerance_rel"])
               and share_unclear <= share_max)
-    del params, cache
-    return {"ok": ok, "max_abs_err": worst, "ref_logit_std": std, "rel_err": rel,
+    del params, whole_params, cache
+    return {"ok": ok, "mesh": axes, "devices": mesh.size if mesh else 1,
+            "max_abs_err": worst, "ref_logit_std": std, "rel_err": rel,
             "rel_err_unclear_median": rel_unclear,
             "tolerance_rel": chk["tolerance_rel"], "positions_clear": int(clear_errs.size),
             "positions_unclear": int(unclear_errs.size), "layers": cfg.n_layers,

@@ -30,6 +30,7 @@ import dataclasses   # noqa: E402
 import functools     # noqa: E402
 import importlib.util  # noqa: E402
 import json          # noqa: E402
+import math          # noqa: E402
 import os            # noqa: E402
 import re            # noqa: E402
 import shutil        # noqa: E402
@@ -45,6 +46,9 @@ HERE = Path(__file__).resolve().parent
 ROOT = HERE.parent
 sys.path.insert(0, str(HERE))
 sys.path.insert(1, str(ROOT))
+
+from modelmap import (fields, key_map, mesh_of, mesh_problems,  # noqa: E402
+                      rehearsal_mesh, sizes)
 
 READY_TIMEOUT_S = 1100.0
 GO_FILE = "chip_is_free"
@@ -88,7 +92,7 @@ def free_port() -> int:
         return s.getsockname()[1]
 
 
-def child_env(server_env: dict, model: str, port: int, rehearse: bool) -> dict:
+def child_env(cfg_file: dict, port: int, rehearse: bool) -> dict:
     """The caller's environment minus every setting of the service (each
     ServiceConfig field reads the variable of its upper-cased name), plus the
     configuration file's pins."""
@@ -97,12 +101,18 @@ def child_env(server_env: dict, model: str, port: int, rehearse: bool) -> dict:
     knobs = {f.name.upper() for f in dataclasses.fields(ServiceConfig)}
     knobs |= {"TRUST_PROXY", "BENCH_RUN"}
     env = {k: v for k, v in os.environ.items() if k not in knobs}
-    env.update(server_env)
-    env.update({"HOST": "127.0.0.1", "PORT": str(port), "MODEL_NAME": model,
+    env.update(cfg_file["server_env"])
+    env.update({"HOST": "127.0.0.1", "PORT": str(port), "MODEL_NAME": cfg_file["name"],
                 "DRAIN_TIMEOUT_SECS": "2", "PYTHONUNBUFFERED": "1"})
     if rehearse:
+        mesh = rehearsal_mesh(mesh_of(cfg_file))
         env.update(REHEARSAL_ENV)
-        env.update({"JAX_PLATFORMS": "cpu", "MODEL_NAME": "toy-8m"})
+        env.update({"JAX_PLATFORMS": "cpu",
+                    "MODEL_NAME": cfg_file.get("rehearsal_model", "toy-8m")})
+        if mesh:
+            env.update({"MESH_SHAPE": ",".join(f"{a}:{n}" for a, n in mesh.items()),
+                        "XLA_FLAGS": "--xla_force_host_platform_device_count="
+                                     f"{math.prod(mesh.values())}"})
     else:
         env["JAX_PLATFORMS"] = "tpu"
         env["TOKENIZER_PATH"] = str(tokenizer_path())
@@ -129,7 +139,7 @@ def launch(cell: dict, cfg_entry: dict, cfg_file: dict, seed: int, tag: str, reh
     shutil.rmtree(run_dir, ignore_errors=True)
     run_dir.mkdir(parents=True)
     port = free_port()
-    env = child_env(cfg_file["server_env"], cfg_file["name"], port, rehearse)
+    env = child_env(cfg_file, port, rehearse)
     children = start_children(cfg_entry["file"], seed, run_dir, env, rehearse)
     return children, f"http://127.0.0.1:{port}", env, run_dir
 
@@ -271,11 +281,17 @@ def main() -> int:
     peaks = load_json(HERE / "peaks.json")
     rules = load_json(HERE / "trace_categories.json")
 
-    from modelmap import sizes
     import loadgen
     import workgen
 
     sz = sizes(cfg_file)
+    mesh = mesh_of(cfg_file)
+    unfit = mesh_problems(cfg_file, cell["chips"])
+    if unfit:       # before anything is launched
+        print("bench: " + "; ".join(unfit), file=sys.stderr)
+        return 2
+    if args.rehearse:
+        mesh = rehearsal_mesh(mesh)
     children, base, env, run_dir = launch(
         cell, cfg_entry, cfg_file, args.seed, f"seed{args.seed}.trace{args.trace}", args.rehearse)
     words = workgen.Words(None if args.rehearse else str(tokenizer_path()))
@@ -285,9 +301,11 @@ def main() -> int:
     try:
         health = wait_ready(children, base, T_START + READY_TIMEOUT_S)
         ready_s = time.monotonic() - T_START
-        problems = check_health(health, cfg_file, peaks, cell, args.rehearse)
+        problems = check_health(health, cfg_file, peaks, cell, mesh, args.rehearse)
         if problems and not args.rehearse:
             raise BenchFailure("/health: " + "; ".join(problems))
+        if args.rehearse:   # the platform and the kind are always among them
+            say(rehearsal_health_problems=problems)
         refcheck = load_json(run_dir / "refcheck.json")
         model_cfg = load_json(run_dir / "model_config.json")
         say(ready_s=round(ready_s, 2), refcheck=refcheck, offered=plan.offered,
@@ -358,6 +376,22 @@ def main() -> int:
         for child in children:
             stop_child(child)
 
+    # ---- the trace
+    trace = None
+    if args.trace and trace_info.get("trace_dir"):
+        import xtrace
+        path = xtrace.find_xplane(trace_info["trace_dir"])
+        if path:
+            rep = xtrace.load(path)
+            if os.environ.get("BENCH_DESCRIBE_TRACE"):
+                (run_dir / "trace_description.txt").write_text(
+                    xtrace.describe(xtrace.load(path, 600, all_stats=True), 600))
+                (run_dir / "trace_small.json").write_text(
+                    json.dumps(xtrace.excerpt(rep, 0.5, 0.12)))
+            trace = xtrace.reduce(rep, rules, sz["num_hidden_layers"],
+                                  math.prod(mesh.values()))
+            shutil.rmtree(trace_info["trace_dir"], ignore_errors=True)
+
     # ---- correctness
     by_rid = {s["request_id"]: s for s in index.get("requests", [])}
     allow_cache = bool(mix.get("repeats_allowed", False))
@@ -393,29 +427,22 @@ def main() -> int:
         faults.append(f"answers of {off_cap[:5]} tokens, not MAX_NEW_TOKENS = {cap}")
     if not refcheck.get("ok"):
         faults.append(f"the program disagrees with the plain reference: {refcheck}")
+    if refcheck.get("mesh") != mesh:
+        faults.append(f"the comparison ran over the mesh {refcheck.get('mesh')}, not {mesh}")
+    if trace and not args.rehearse:     # the CPU has no device plane
+        faults.extend(trace["problems"])
     correct = not faults
     if faults:      # the last line says only false; this says why
         print("bench: NOT CORRECT: " + "; ".join(faults), file=sys.stderr, flush=True)
 
     # ---- metrics
-    trace = None
-    if args.trace and trace_info.get("trace_dir"):
-        import xtrace
-        path = xtrace.find_xplane(trace_info["trace_dir"])
-        if path:
-            rep = xtrace.load(path)
-            if os.environ.get("BENCH_DESCRIBE_TRACE"):
-                (run_dir / "trace_description.txt").write_text(
-                    xtrace.describe(xtrace.load(path, 600, all_stats=True), 600))
-                (run_dir / "trace_small.json").write_text(
-                    json.dumps(xtrace.excerpt(rep, 0.5, 0.12)))
-            trace = xtrace.reduce(rep, rules, sz["num_hidden_layers"])
-            shutil.rmtree(trace_info["trace_dir"], ignore_errors=True)
     kind = health.get("device_kind", "")
     ctx = {"records": measured, "all_records": records, "window": (t0, args.seconds),
            "setup_s": setup_s, "engine": engine, "health_before": health_before,
            "health_after": health_after, "health_samples": samples, "trace": trace,
-           "trace_rules": rules, "sizes": sz, "peaks": peaks.get(kind, {})}
+           "trace_rules": rules, "sizes": sz, "fields": fields(sz, key_map(cfg_file)),
+           "config": cfg_file, "mesh": mesh, "chips": cell["chips"],
+           "peaks": peaks.get(kind, {})}
     values = {}
     for m in cell_metrics(bench, "per_layer" if args.trace else "end_to_end", cell["name"]):
         spec = load_json(HERE / "metrics" / f"{m['name']}.json")
@@ -458,11 +485,18 @@ def main() -> int:
     return 0
 
 
-def check_health(health: dict, cfg_file: dict, peaks: dict, cell: dict, rehearse: bool) -> list:
+def check_health(health: dict, cfg_file: dict, peaks: dict, cell: dict, mesh: dict,
+                 rehearse: bool) -> list:
+    """Why this server is not the cell: the wrong engine, model, platform or
+    attention regime and, for a configuration with a mesh, anything in
+    /health.sharding (engine/batcher.py::sharding_health) short of that mesh
+    with the weights split and the pool sharded on it. A server that fell back
+    to replicated weights or a gathered pool is another system."""
     problems = []
     if health.get("engine") != "jax-batched":
         problems.append(f"engine {health.get('engine')!r}")
-    if health.get("model") != ("toy-8m" if rehearse else cfg_file["name"]):
+    model = cfg_file.get("rehearsal_model", "toy-8m") if rehearse else cfg_file["name"]
+    if health.get("model") != model:
         problems.append(f"model {health.get('model')!r}")
     if health.get("platform") != "tpu":
         problems.append(f"platform {health.get('platform')!r}, want 'tpu'")
@@ -473,6 +507,32 @@ def check_health(health: dict, cfg_file: dict, peaks: dict, cell: dict, rehearse
     regime = (health.get("kv_pool") or {}).get("attention_regime")
     if regime != "ragged":
         problems.append(f"attention_regime {regime!r}, want 'ragged'")
+    if mesh:
+        problems.extend(sharding_problems(health.get("sharding"), mesh))
+    return problems
+
+
+def sharding_problems(sharding, mesh: dict) -> list:
+    if not sharding:
+        return [f"/health reports no sharding; the configuration's mesh is {mesh}"]
+    problems = []
+    served = {a: n for a, n in (sharding.get("mesh") or {}).items() if n > 1}
+    if served != mesh:
+        problems.append(f"sharding.mesh {served}, want {mesh}")
+    if sharding.get("devices") != math.prod(mesh.values()):
+        problems.append(f"sharding.devices {sharding.get('devices')}, "
+                        f"want {math.prod(mesh.values())}")
+    share = 1.0 / mesh.get("model", 1)
+    if abs((sharding.get("weights_shard_fraction") or 0.0) - share) > 1e-6:
+        problems.append(f"weights_shard_fraction {sharding.get('weights_shard_fraction')}, "
+                        f"want {share} (the weights did not split over the model axis)")
+    if sharding.get("pool_sharded") is not True:
+        problems.append("the KV pool is not sharded (pool_sharded)")
+    if sharding.get("kv_pool_mesh_fallback") is not False:
+        problems.append("the KV pool fell back off the mesh (kv_pool_mesh_fallback)")
+    if sharding.get("attention_regime") != "ragged":
+        problems.append(f"sharding.attention_regime {sharding.get('attention_regime')!r}, "
+                        f"want 'ragged'")
     return problems
 
 

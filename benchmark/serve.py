@@ -9,7 +9,8 @@ What it leaves in ``--run-dir`` for the parent (which never imports jax):
 
 - ``model_config.json``  the ModelConfig the registry now holds under the name;
 - ``compiles.log``       one line ``<unix time> <seconds>`` per backend compile;
-- ``memory.json``        device 0's ``memory_stats()``, rewritten every second.
+- ``memory.json``        every local device's ``memory_stats()`` and, at the top,
+                         those of the fullest one; rewritten every second.
 """
 
 from __future__ import annotations
@@ -27,7 +28,7 @@ HERE = Path(__file__).resolve().parent
 ROOT = HERE.parent
 sys.path.insert(0, str(ROOT))
 
-from modelmap import KEY_MAP, fold_seed, sizes  # noqa: E402
+from modelmap import fold_seed, key_map, model_config, sizes  # noqa: E402
 
 
 def register(cfg_file: dict):
@@ -35,13 +36,10 @@ def register(cfg_file: dict):
     that every key of the file reached the ModelConfig the engine will read."""
     from ai_agent_kubectl_tpu.models import config as mc
 
-    sz = sizes(cfg_file)
-    fields = {KEY_MAP[k]: v for k, v in sz.items() if k in KEY_MAP}
-    eos = sz["eos_token_id"]
-    fields["eos_ids"] = tuple(eos) if isinstance(eos, (list, tuple)) else (eos,)
-    mc._register(mc.ModelConfig(name=cfg_file["name"], **fields))
+    sz, kmap = sizes(cfg_file), key_map(cfg_file)
+    mc._register(model_config(cfg_file["name"], sz, kmap))
     got = dataclasses.asdict(mc.get_config(cfg_file["name"]))
-    for key, field in KEY_MAP.items():
+    for key, field in kmap.items():
         if key in sz and got[field] != sz[key]:
             raise SystemExit(f"serve: {key}={sz[key]!r} did not reach "
                              f"ModelConfig.{field} ({got[field]!r})")
@@ -107,15 +105,15 @@ def watch_compiles(path: Path) -> None:
 def report_memory(path: Path) -> None:
     import jax
 
+    keys = ("peak_bytes_in_use", "bytes_in_use", "bytes_limit")
+
     def loop() -> None:
-        dev = jax.devices()[0]
+        devices = jax.local_devices()
         while True:
-            stats = dev.memory_stats() or {}
+            each = [{k: (d.memory_stats() or {}).get(k) for k in keys} for d in devices]
+            fullest = max(each, key=lambda s: s["peak_bytes_in_use"] or 0)
             tmp = path.with_suffix(".tmp")
-            tmp.write_text(json.dumps({
-                "peak_bytes_in_use": stats.get("peak_bytes_in_use"),
-                "bytes_in_use": stats.get("bytes_in_use"),
-                "bytes_limit": stats.get("bytes_limit")}))
+            tmp.write_text(json.dumps({**fullest, "devices": each}))
             os.replace(tmp, path)
             time.sleep(1.0)
 
@@ -139,10 +137,11 @@ def main() -> None:
     import jax
     jax.config.update("jax_log_compiles", True)    # server.log names each program
     if args.rehearse:
-        # toy-8m is registered by the program; the file's own sizes still go
-        # through the mapping and the reference, at widths a CPU can run.
+        # a toy model the program registers (run.py::rehearsal_model); the
+        # file's own sizes still go through the mapping and the reference, at
+        # widths a CPU can run.
         from ai_agent_kubectl_tpu.models.config import get_config
-        model_cfg = get_config("toy-8m")
+        model_cfg = get_config(os.environ["MODEL_NAME"])
     else:
         model_cfg, _ = register(cfg_file)
     (run_dir / "model_config.json").write_text(

@@ -15,7 +15,15 @@ def test_recorded_trace_reduces():
     xtrace.excerpt: an admission prologue, so the MLP leads."""
     rep = json.load(gzip.open(BENCH / "tests" / "data" / "trace_small.json.gz", "rt"))
     r = xtrace.reduce(rep, RULES, 32)
-    assert r["devices"] == 1
+    assert r["devices"] == 1 and r["problems"] == []
+    # the reduction's arithmetic as it stood before PR 26, to the digit
+    assert (r["busy_s"], r["window_s"], r["forward_passes"]) == (0.119731951, 0.119734013, 0.0)
+    assert r["category_s"] == {
+        "mlp": 0.081709236, "kv_pool_copy": 0.009677147, "other_device": 0.000463673,
+        "norms": 0.000168988, "attn_proj": 0.019802228, "attention": 0.007910679}
+    assert r["breakdown"]["device_ops"][:3] == [
+        ["mlp:fusion", 0.054255608], ["mlp:multiply_convert_fusion", 0.027426832],
+        ["attn_proj:fusion", 0.016645683]]
     assert 0.115 < r["busy_s"] <= r["window_s"] < 0.121
     # leaf ops do not overlap: the categories add up to the busy time
     assert sum(r["category_s"].values()) == pytest.approx(r["busy_s"], rel=0.01)
@@ -26,6 +34,25 @@ def test_recorded_trace_reduces():
     assert ["attention:ragged_attention_pool"] == [n for n, _ in ops if n.startswith("attention:")]
     assert all(" " not in n and "," not in n for n, _ in ops)
     assert ops == sorted(ops, key=lambda kv: -kv[1])
+
+
+def test_recorded_four_chip_trace_bills_collectives_apart():
+    """30 ms of Mistral-7B over a mesh of model:4 on four v5e chips (PR 26's
+    proof run; device planes only): the all-gathers, all-reduces and permutes
+    sit inside the scopes mlp, qkv_proj, o_proj and kv_write."""
+    rep = json.load(gzip.open(BENCH / "tests" / "data" / "trace_tp4_small.json.gz", "rt"))
+    r = xtrace.reduce(rep, RULES, 32, chips=4)
+    assert (r["devices"], r["forward_passes"], r["problems"]) == (4, 2.0, [])
+    cat = r["category_s"]
+    assert (cat["collectives"], cat["mlp"], cat["attn_proj"]) == (
+        0.003319988, 0.0065231915, 0.0011359545)
+    assert sum(cat.values()) == pytest.approx(r["busy_s"], rel=0.01)
+    # by the scope rules alone the weight GEMMs would carry the mesh's time
+    old = xtrace.reduce(rep, dict(RULES, op_rules=[]), 32, chips=4)["category_s"]
+    assert "collectives" not in old
+    assert (old["mlp"], old["attn_proj"]) == (0.00738608025, 0.00253545275)
+    assert xtrace.reduce(rep, RULES, 32, chips=8)["problems"] == [
+        "the trace holds 4 device planes, the cell runs on 8 chips"]
 
 
 def ev(name, start_us, dur_us, scope=""):
@@ -83,4 +110,5 @@ def test_self_time_passes_and_gaps():
 
 
 def test_no_device_plane_gives_nothing():
-    assert xtrace.reduce({"planes": [{"name": "/host:CPU", "lines": []}]}, RULES, 2) == {"devices": 0}
+    r = xtrace.reduce({"planes": [{"name": "/host:CPU", "lines": []}]}, RULES, 2)
+    assert r["devices"] == 0 and r["problems"]

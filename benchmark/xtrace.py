@@ -91,11 +91,16 @@ def load(path: str, max_events_per_line: Optional[int] = None,
 
 
 def categorize(name: str, scope: str, rules: dict) -> str:
+    n = name.lower()
+    # an op that is itself a collective is billed to no scope it sits in: an
+    # all-reduce inside ``mlp`` is the mesh's time, not the weight stream's
+    for cat, keys in rules.get("op_rules", ()):
+        if any(k in n for k in keys):
+            return cat
     s = scope.lower()
     for cat, keys in rules["scope_rules"]:
         if any(k in s for k in keys):
             return cat
-    n = name.lower()
     for cat, keys in rules["hlo_rules"]:
         if any(k in n for k in keys):
             return cat
@@ -172,19 +177,25 @@ def _deepest_at(events: List[list], t: int) -> Optional[str]:
     return best[0] if best else None
 
 
-def reduce(rep: dict, rules: dict, n_layers: int) -> dict:
+def reduce(rep: dict, rules: dict, n_layers: int, chips: int = 1) -> dict:
     """Busy and window seconds (averaged over the device planes that ran
-    anything), seconds per category, forward passes, and the breakdown."""
+    anything), seconds per category, forward passes, and the breakdown.
+    ``problems`` says why the trace cannot stand for a cell of ``chips``
+    chips: fewer device planes than chips, or planes that did not run the
+    same passes. One program across the mesh runs every pass on every chip,
+    in step through its collectives; each of the capture's two edges may cut
+    a pass on some planes and not on others, so sound planes differ by up to
+    2 (a model:4 capture read 220, 219, 218, 218; my chip run, PR 26)."""
     dev = [p for p in rep["planes"] if p["name"].startswith("/device:")
            and any(l["name"] == OPS_LINE and l["events"] for l in p["lines"])]
     host_lines = [l for p in rep["planes"] if p["name"].startswith("/host:")
                   for l in p["lines"]]
     if not dev:
-        return {"devices": 0}
+        return {"devices": 0, "problems": ["the trace holds no device plane that ran an op"]}
     cat_ns: Dict[str, int] = defaultdict(int)
     op_ns: Dict[str, int] = defaultdict(int)
     gap_ns: Dict[str, int] = defaultdict(int)
-    busy, window, passes = [], [], 0
+    busy, window, passes = [], [], []
     launcher = _launch_thread(host_lines)
     for plane in dev:
         ops = next(l["events"] for l in plane["lines"] if l["name"] == OPS_LINE)
@@ -209,7 +220,7 @@ def reduce(rep: dict, rules: dict, n_layers: int) -> dict:
         per_module: Dict[int, int] = defaultdict(int)
         for (m, _), c in head_counts.items():
             per_module[m] = max(per_module[m], c)
-        passes += sum(per_module.values())
+        passes.append(sum(per_module.values()))
         for (_, e0), (s1, _) in zip(merged, merged[1:]):
             gap = s1 - e0
             if gap < MIN_GAP_NS or launcher is None:
@@ -218,15 +229,22 @@ def reduce(rep: dict, rules: dict, n_layers: int) -> dict:
             what = _deepest_at(launcher["events"], (e0 + s1) // 2)
             gap_ns[_label(what) if what else "host:_unknown"] += gap
     n = len(dev)
-    top = lambda d: [[k, v / 1e9] for k, v in sorted(d.items(), key=lambda kv: -kv[1])[:10]]
+    problems = []
+    if n < chips:
+        problems.append(f"the trace holds {n} device planes, the cell runs on {chips} chips")
+    if max(passes) - min(passes) > 2:
+        problems.append(f"the device planes ran different numbers of forward passes: {passes}")
+    # a chip's seconds, like busy_s and category_s: the planes' sum over n
+    top = lambda d: [[k, v / n / 1e9] for k, v in sorted(d.items(), key=lambda kv: -kv[1])[:10]]
     return {
         "devices": n,
         "busy_s": sum(busy) / n / 1e9,
         "window_s": sum(window) / n / 1e9,
         "category_s": {k: v / n / 1e9 for k, v in cat_ns.items()},
-        "forward_passes": passes / n,
+        "forward_passes": sum(passes) / n,
         "n_layers": n_layers,
         "breakdown": {"device_ops": top(op_ns), "idle_gaps": top(gap_ns)},
+        "problems": problems,
     }
 
 
