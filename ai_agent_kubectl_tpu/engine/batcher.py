@@ -2323,30 +2323,38 @@ class BatchedJaxEngine(JaxEngine):
         cfg = self.model_cfg
         shape = (cfg.n_layers, self._pool_n_blocks, self.kv_pool_page,
                  cfg.n_kv_heads, cfg.head_dim)
-        lengths = jnp.zeros((self._pool_n_blocks,), jnp.int32)
-        if self.kv_quant == "int8":
-            from ..ops.quant import QuantKV
+        dtype, kv_quant = self.dtype, self.kv_quant
+        n_blocks = self._pool_n_blocks
 
-            def zq():
-                return QuantKV(q=jnp.zeros(shape, jnp.int8),
-                               s=jnp.ones(shape[:-1], jnp.float32))
+        def make() -> KVCache:
+            lengths = jnp.zeros((n_blocks,), jnp.int32)
+            if kv_quant == "int8":
+                from ..ops.quant import QuantKV
 
-            cache = KVCache(k=zq(), v=zq(), lengths=lengths)
-        else:
-            cache = KVCache(k=jnp.zeros(shape, self.dtype),
-                            v=jnp.zeros(shape, self.dtype),
-                            lengths=lengths)
-        if self.mesh is not None:
-            # Pool-under-mesh (ISSUE 14): KV heads shard over ``model``
-            # exactly like dense KV; the block axis stays whole (it is
-            # shared across slots). Every jitted pool program — prefill
-            # through tables, COW, the decode chunk — inherits this
-            # placement, so XLA keeps TP attention local per shard
-            # until the wo reduce.
-            from ..parallel.sharding import shard_pool_cache
+                def zq():
+                    return QuantKV(q=jnp.zeros(shape, jnp.int8),
+                                   s=jnp.ones(shape[:-1], jnp.float32))
 
-            cache = shard_pool_cache(cache, self.mesh, self.model_cfg)
-        return cache
+                return KVCache(k=zq(), v=zq(), lengths=lengths)
+            return KVCache(k=jnp.zeros(shape, dtype),
+                           v=jnp.zeros(shape, dtype), lengths=lengths)
+
+        if self.mesh is None:
+            return make()
+        # Pool-under-mesh (ISSUE 14): KV heads shard over ``model``
+        # exactly like dense KV; the block axis stays whole (it is
+        # shared across slots). Every jitted pool program — prefill
+        # through tables, COW, the decode chunk — inherits this
+        # placement, so XLA keeps TP attention local per shard until
+        # the wo reduce. The pool is MADE in that placement (one
+        # compiled call, each device zeroing its own heads): beside a
+        # model that fills its chips, a whole pool does not fit one of
+        # them on its way to the mesh (Mixtral-8x7B over model:4: 7.0
+        # GiB a K leaf wanted, 4.86 free; my chip run, PR 27).
+        from ..parallel.sharding import pool_cache_shardings
+
+        return jax.jit(make, out_shardings=pool_cache_shardings(
+            jax.eval_shape(make), self.mesh, self.model_cfg))()
 
     def _tables_d(self, tables: np.ndarray):
         """Device copy of a block-table snapshot — committed REPLICATED
@@ -2472,21 +2480,42 @@ class BatchedJaxEngine(JaxEngine):
         if fn is None:
             page = self.kv_pool_page
 
-            def cow(cache, src_b, dst_b, rows):
+            def cp_rows(leaf, src_b, dst_b, rows):
                 offs = jnp.arange(page)
+                Lx, nb = leaf.shape[0], leaf.shape[1]
+                f = leaf.reshape((Lx, nb * page) + leaf.shape[3:])
+                src_rows = f[:, src_b * page + offs]
+                dst_idx = jnp.where(offs < rows, dst_b * page + offs,
+                                    nb * page)
+                f = f.at[:, dst_idx].set(src_rows)
+                return f.reshape(leaf.shape)
 
-                def cp(leaf):
-                    Lx, nb = leaf.shape[0], leaf.shape[1]
-                    f = leaf.reshape((Lx, nb * page) + leaf.shape[3:])
-                    src_rows = f[:, src_b * page + offs]
-                    dst_idx = jnp.where(offs < rows, dst_b * page + offs,
-                                        nb * page)
-                    f = f.at[:, dst_idx].set(src_rows)
-                    return f.reshape(leaf.shape)
+            def cp_block(leaf, src_b, dst_b, rows):
+                # The same copy as one block read, one select and one
+                # in-place block write. Over a mesh the partitioner
+                # turns cp_rows' scatter through the flattened view
+                # into a copy of the whole leaf (1.75 GiB of
+                # temporaries a chip at Mixtral-8x7B's pool, which did
+                # not fit: my chip run and AOT, PR 27); this form needs
+                # none there or on one device. One device keeps
+                # cp_rows until a one-chip cell has timed the other
+                # (ROADMAP S7).
+                src = jax.lax.dynamic_index_in_dim(leaf, src_b, axis=1)
+                dst = jax.lax.dynamic_index_in_dim(leaf, dst_b, axis=1)
+                keep = (jnp.arange(page) < rows).reshape(
+                    (1, 1, page) + (1,) * (leaf.ndim - 3))
+                return jax.lax.dynamic_update_index_in_dim(
+                    leaf, jnp.where(keep, src, dst), dst_b, axis=1)
+
+            cp = cp_rows if self.mesh is None else cp_block
+
+            def cow(cache, src_b, dst_b, rows):
+                def one(leaf):
+                    return cp(leaf, src_b, dst_b, rows)
 
                 with jax.named_scope("kv_splice"):
-                    return KVCache(k=jax.tree.map(cp, cache.k),
-                                   v=jax.tree.map(cp, cache.v),
+                    return KVCache(k=jax.tree.map(one, cache.k),
+                                   v=jax.tree.map(one, cache.v),
                                    lengths=cache.lengths)
 
             fn = jax.jit(cow, donate_argnums=(0,))
@@ -2976,6 +3005,7 @@ class BatchedJaxEngine(JaxEngine):
             "residual_tp_fraction": residual_fraction(
                 self.mesh, self.batch_size, self.model_cfg.dim),
             "weights_shard_fraction": self._weights_shard_fraction,
+            **self._weights_health(),
             "pool_sharded": bool(self._use_pool),
             "kv_pool_mesh_fallback": bool(self._kv_pool_mesh_fallback),
             # ISSUE 18: whether the draft world rides the mesh, and

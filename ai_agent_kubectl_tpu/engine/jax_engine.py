@@ -127,6 +127,8 @@ class JaxEngine:
         self.dcn_mesh_shape = dcn_mesh_shape
         self.mesh = None               # built in _start_blocking
         self._weights_shard_fraction = 1.0   # measured in _load
+        self._weights_init = {"s": None, "sharded": False}   # seeded init
+        self._weights_bytes_per_device: list = []   # measured in _load
         self.seed = seed
 
         self.tokenizer = tokenizer
@@ -357,6 +359,7 @@ class JaxEngine:
             "residual_tp_fraction": residual_fraction(
                 self.mesh, 1, self.model_cfg.dim),
             "weights_shard_fraction": self._weights_shard_fraction,
+            **self._weights_health(),
             "pool_sharded": False,
             "kv_pool_mesh_fallback": False,
             "draft_sharded": False,
@@ -458,19 +461,12 @@ class JaxEngine:
                 if self.quant in ("int8", "int4"):
                     # A 7B-class bf16 init (~17 GB) would OOM the chip
                     # before quantization ever runs; init directly in
-                    # quantized form on device (ops/quant.py::
-                    # random_params_int8 / quant4.py::random_params_int4 —
+                    # quantized form on device (_seeded_quantized_params:
                     # same tree structure/shapes as a quantized
                     # checkpoint, no full-precision materialization
-                    # anywhere).
-                    from ..ops.quant import random_params_int8
-
-                    self.params = random_params_int8(
-                        jax.random.PRNGKey(self.seed), self.model_cfg,
-                        dtype=self.dtype,
-                        quantize_embed=self._quantize_embed,
-                        int4=(self.quant == "int4"),
-                    )
+                    # anywhere, and over a mesh no leaf whole on one
+                    # device).
+                    self.params = self._seeded_quantized_params(self.seed)
                     self._quantized = True
                 else:
                     self.params = init_params(
@@ -505,6 +501,7 @@ class JaxEngine:
             logger.info("Params sharded over mesh %s (one device holds "
                         "%.4f of wq)", dict(self.mesh.shape),
                         self._weights_shard_fraction)
+            self._note_weights_placement()
         if not self.weights_version:
             # Version the weights we ended up serving: checkpoint paths
             # fingerprint by content manifest; dev random-init versions
@@ -609,6 +606,8 @@ class JaxEngine:
             new_params = shard_params(new_params, self.mesh,
                                       self.model_cfg)
         self.params = new_params
+        if self.mesh is not None:
+            self._note_weights_placement()
         self.weights_version = version
         self.checkpoint_path = path
         logger.info("weights swapped: %s now serves version %s (%s)",
@@ -657,15 +656,74 @@ class JaxEngine:
                 else _zlib.crc32(path.encode("utf-8", "surrogatepass"))
                 & 0x7FFFFFFF)
         if self.quant in ("int8", "int4"):
-            from ..ops.quant import random_params_int8
-
-            return random_params_int8(
-                _jax.random.PRNGKey(seed), self.model_cfg,
-                dtype=self.dtype,
-                quantize_embed=self._quantize_embed,
-                int4=(self.quant == "int4"))
+            return self._seeded_quantized_params(seed)
         return init_params(_jax.random.PRNGKey(seed), self.model_cfg,
                            dtype=self.dtype)
+
+    def _seeded_quantized_params(self, seed: int):
+        """The seeded int8/int4 tree of ``_load`` and of a swap onto a
+        ``dev:...:seed=N`` sentinel (ops/quant.py::random_params_int8 —
+        the same values for the same seed on either path).
+
+        Without a mesh: the generator as it always ran, leaf by leaf on
+        the one device. Over a mesh of more than one device: ONE compiled
+        call whose outputs are born with ``shard_params``'s shardings
+        (``random_params_int8_sharded``), so a device makes and holds
+        only its share — a tree that does not fit one chip never passes
+        through one. Closes a ``weights_init`` span (wall ms; ``sharded``
+        counts the sharded ones) and keeps the seconds for
+        /health.sharding. The sharded call is waited for; the one-device
+        call is not (its device work overlaps the warm-up compiles, as
+        it always did), so there the span is the host's dispatch time."""
+        import time as _time
+
+        from ..ops.quant import random_params_int8
+
+        key = jax.random.PRNGKey(seed)
+        kw = dict(dtype=self.dtype, quantize_embed=self._quantize_embed,
+                  int4=(self.quant == "int4"))
+        sharded = self.mesh is not None and self.mesh.size > 1
+        t0 = _time.monotonic()
+        if sharded:
+            from ..ops.quant import random_params_int8_sharded
+
+            params = jax.block_until_ready(random_params_int8_sharded(
+                key, self.model_cfg, self.mesh, **kw))
+        else:
+            params = random_params_int8(key, self.model_cfg, **kw)
+        seconds = _time.monotonic() - t0
+        self._weights_init = {"s": round(seconds, 3), "sharded": sharded}
+        spans = getattr(self, "_spans", None)
+        if spans is not None:
+            spans.stats.note("weights_init", seconds * 1e3,
+                             sharded=int(sharded))
+        logger.info("Seeded %s weights made in %.1f s (%s)", self.quant,
+                    seconds, "sharded over the mesh" if sharded
+                    else "on one device")
+        return params
+
+    def _note_weights_placement(self) -> None:
+        """Bytes of the live param tree each device of the mesh holds,
+        read off the arrays' addressable shards (not the policy): what
+        /health.sharding reports as ``weights_bytes_per_device``. Even
+        over the ``model`` axis when the Megatron split placed every
+        leaf; a replicated leaf counts whole on each device."""
+        held = {d.id: 0 for d in self.mesh.devices.flat}
+        for leaf in jax.tree_util.tree_leaves(self.params):
+            for shard in getattr(leaf, "addressable_shards", ()):
+                if shard.device.id in held:
+                    held[shard.device.id] += int(shard.data.nbytes)
+        self._weights_bytes_per_device = list(held.values())
+
+    def _weights_health(self) -> dict:
+        """The weights' part of /health.sharding (host attributes only):
+        how long the seeded tree took to make and whether it was made
+        sharded (None / False for a checkpoint), and each device's bytes."""
+        return {
+            "weights_init_s": self._weights_init["s"],
+            "weights_init_sharded": self._weights_init["sharded"],
+            "weights_bytes_per_device": self._weights_bytes_per_device,
+        }
 
     def _prefill_impl_for(self, q_len: int, kv_len: int) -> str:
         """attn impl for a prefill shape, with per-shape dense fallback

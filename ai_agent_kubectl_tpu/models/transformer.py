@@ -283,7 +283,7 @@ def _moe_mlp(cfg: ModelConfig, lp: Params, x: jnp.ndarray,
     from ..parallel.moe import dense_moe, expert_parallel_moe
 
     if moe_impl == "dense":
-        return dense_moe(cfg, lp, x)
+        return dense_moe(cfg, lp, x, mesh)
     if mesh is not None and "expert" in mesh.axis_names:
         ep = mesh.shape["expert"]
         B, S, _ = x.shape
@@ -300,7 +300,7 @@ def _moe_mlp(cfg: ModelConfig, lp: Params, x: jnp.ndarray,
         raise ValueError(
             "MOE_IMPL=ep needs a mesh with an expert axis whose size "
             "divides tokens and experts")
-    return dense_moe(cfg, lp, x)
+    return dense_moe(cfg, lp, x, mesh)
 
 
 def _layer(cfg: ModelConfig, attn_impl: str, mesh, page_size: int,

@@ -296,6 +296,14 @@ _QUANT_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
 def random_params_int8(key, cfg, dtype=None,
                        quantize_embed: bool = False,
                        int4: bool = False) -> Dict[str, Any]:
+    """Random-init a param tree DIRECTLY in quantized form, leaf by leaf
+    and slice by slice (``_random_params_int8`` holds the recipe)."""
+    return _random_params_int8(key, cfg, dtype, quantize_embed, int4,
+                               slices_in_one_op=False)
+
+
+def _random_params_int8(key, cfg, dtype, quantize_embed: bool, int4: bool,
+                        slices_in_one_op: bool) -> Dict[str, Any]:
     """Random-init a param tree DIRECTLY in quantized form — no
     full-precision materialization anywhere (a 7B bf16 init is ~17 GB:
     HBM OOM before quantization could run, and a host-side init pays
@@ -309,6 +317,15 @@ def random_params_int8(key, cfg, dtype=None,
     kernel-tileable projection leaves at PACKED int4 size instead
     (payload [..., in, out/2] + group scales), matching
     ``quantize_params_int4``; non-tileable leaves stay int8.
+
+    ``slices_in_one_op`` draws a stacked leaf's 2D slices with one
+    batched op over their keys (``jax.vmap``: the same values as the
+    loop, key for key) instead of one op a slice. Eager, that would
+    materialize the PRNG's 32-bit words for the whole leaf; under
+    ``jax.jit`` the words never leave the fusion, and the program is
+    one op a leaf instead of one a slice (Mixtral-8x7B: 896 slices),
+    which is what its compile time follows
+    (``random_params_int8_sharded``).
     """
     import jax.numpy as _jnp
 
@@ -345,11 +362,14 @@ def random_params_int8(key, cfg, dtype=None,
                 for d in lead:
                     n_lead *= d
                 lk = jax.random.split(k, n_lead)
-                q = _jnp.stack([
-                    jax.random.randint(lk[i], payload_shape[-2:], -127, 128,
-                                       dtype=_jnp.int8)
-                    for i in range(n_lead)
-                ]).reshape(payload_shape)
+
+                def one(ki):
+                    return jax.random.randint(ki, payload_shape[-2:],
+                                              -127, 128, dtype=_jnp.int8)
+
+                q = (jax.vmap(one)(lk) if slices_in_one_op
+                     else _jnp.stack([one(lk[i]) for i in range(n_lead)])
+                     ).reshape(payload_shape)
             else:
                 q = jax.random.randint(k, payload_shape, -127, 128,
                                        dtype=_jnp.int8)
@@ -384,6 +404,31 @@ def random_params_int8(key, cfg, dtype=None,
                 .astype(dtype)
             )
     return jax.tree_util.tree_unflatten(treedef, out)
+
+
+def random_params_int8_sharded(key, cfg, mesh, dtype=None,
+                               quantize_embed: bool = False,
+                               int4: bool = False) -> Dict[str, Any]:
+    """``random_params_int8``'s tree for the same key, made ON a mesh:
+    one compiled call whose outputs carry the shardings
+    parallel/sharding.py::shard_params would give them
+    (``param_shardings``, the one policy), so each device generates and
+    holds only its share and no leaf is ever whole on one device — a
+    model served over a mesh because one chip cannot hold it (Mixtral-
+    8x7B, 46.7 GB of int8) cannot pass through one chip on its way
+    there. The values are the whole-tree generator's: it is the same
+    recipe under ``jax.jit`` (a stacked leaf's slices drawn by one
+    batched op, key for key), and the PRNG's bits depend on an
+    element's index alone (``jax_threefry_partitionable``, on in the
+    pinned jax), not on which device makes it."""
+    from ..parallel.sharding import param_shardings
+
+    def make(k):
+        return _random_params_int8(k, cfg, dtype, quantize_embed, int4,
+                                   slices_in_one_op=True)
+
+    shardings = param_shardings(jax.eval_shape(make, key), mesh, cfg)
+    return jax.jit(make, out_shardings=shardings)(key)
 
 
 def quantize_params_int8(params: Dict[str, Any],

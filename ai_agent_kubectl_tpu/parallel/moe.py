@@ -62,21 +62,91 @@ def _qeinsum(spec: str, x: jnp.ndarray, w, scale_shape: str) -> jnp.ndarray:
     return (y.astype(jnp.float32) * s).astype(x.dtype)
 
 
-def dense_moe(cfg: ModelConfig, lp: Dict[str, Any], x: jnp.ndarray) -> jnp.ndarray:
+def dense_moe(cfg: ModelConfig, lp: Dict[str, Any], x: jnp.ndarray,
+              mesh: Optional[Mesh] = None) -> jnp.ndarray:
     """All-experts evaluation: x [B, S, D] -> [B, S, D].
 
     w_gate/w_up: [E, D, F], w_down: [E, F, D], router: [D, E] — the
     projections may be QuantInt8 (per-(expert, out-channel) scales; the
-    router never is)."""
+    router never is).
+
+    Over a tensor-parallel ``mesh`` (``model`` > 1 dividing F, every
+    other axis 1) the experts' inner width is split (parallel/
+    sharding.py: w_gate/w_up columns, w_down rows), so each device's
+    down projection is a PARTIAL sum. ``_down_and_mix`` then mixes the
+    experts on each device first and reduces over ``model`` on
+    [B, S, D]; left to the partitioner the reduce lands on the down
+    projection's own result [B, S, E, D], E times the bytes on the
+    wires (read off the compiled program, PERF.md Findings PR 27).
+    Without such a mesh nothing here changes."""
     logits = (x @ lp["router"]).astype(jnp.float32)               # [B, S, E]
     mix, _ = router_weights(cfg, logits)
 
     gate = _qeinsum("bsd,edf->bsef", x, lp["w_gate"], "ef_last2")
     up = _qeinsum("bsd,edf->bsef", x, lp["w_up"], "ef_last2")
     hidden = _act(cfg, gate) * up                                 # [B, S, E, F]
-    y = _qeinsum("bsef,efd->bsed", hidden, lp["w_down"], "ef_last2")
+    if _splits_expert_width(mesh, cfg):
+        return _down_and_mix_sharded(hidden, lp["w_down"], mix, mesh)
+    return _down_and_mix(hidden, lp["w_down"], mix)
+
+
+def _down_and_mix(hidden, w_down, mix):
+    """hidden [B, S, E, F] through w_down [E, F, D], the experts mixed by
+    ``mix`` [B, S, E] (f32, zeros off the top-k) -> [B, S, D]."""
+    y = _qeinsum("bsef,efd->bsed", hidden, w_down, "ef_last2")
     return jnp.einsum("bsed,bse->bsd", y.astype(jnp.float32),
-                      mix).astype(x.dtype)
+                      mix).astype(hidden.dtype)
+
+
+def _splits_expert_width(mesh: Optional[Mesh], cfg: ModelConfig) -> bool:
+    """True on a pure tensor-parallel mesh whose ``model`` axis divides
+    the experts' inner width: the one layout in which ``dense_moe``
+    writes its own reduce (a data/expert/pipe/seq axis > 1 keeps the
+    partitioner's program)."""
+    if mesh is None or "model" not in mesh.axis_names:
+        return False
+    tp = mesh.shape["model"]
+    return tp > 1 and mesh.size == tp and cfg.mlp_hidden % tp == 0
+
+
+def _down_and_mix_sharded(hidden, w_down, mix, mesh: Mesh):
+    """``_down_and_mix`` with F split over ``model``: each device runs
+    exactly the one-device arithmetic on its F/tp columns of ``hidden``
+    and rows of ``w_down`` (dequant scale and bf16 rounding of the
+    partial result included) and mixes its partial expert outputs in
+    f32; ONE reduce over ``model`` then adds the [B, S, D] partials, in
+    the activation dtype as the dense MLP's row-parallel reduce is. It
+    is a reduce-scatter onto the axis the residual is sharded on
+    (parallel/sharding.py::residual_spec: batch at decode widths, else
+    sequence), so the sum arrives where the layer's residual add wants
+    it; a shape the residual policy leaves replicated gets a psum."""
+    from ..ops.quant import QuantInt8
+    from .sharding import residual_spec
+
+    B, S, _, _ = hidden.shape
+    D = w_down.shape[-1]
+    res = residual_spec(mesh, (B, S, D))
+    scatter = None if res is None else 0 if res[0] is not None else 1
+    wspec = P(None, "model", None)
+    if isinstance(w_down, QuantInt8):
+        # [E, 1, D] scales: per (expert, out-channel), whole on every device
+        wspec = QuantInt8(q=wspec, scale=P())
+
+    def part(h, w, m):
+        y = _down_and_mix(h, w, m)
+        if scatter is None:
+            return jax.lax.psum(y, "model")
+        return jax.lax.psum_scatter(y, "model", scatter_dimension=scatter,
+                                    tiled=True)
+
+    out_spec = [None, None, None]
+    if scatter is not None:
+        out_spec[scatter] = "model"
+    return jax.shard_map(
+        part, mesh=mesh,
+        in_specs=(P(None, None, None, "model"), wspec, P()),
+        out_specs=P(*out_spec),
+    )(hidden, w_down, mix)
 
 
 def _act(cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:

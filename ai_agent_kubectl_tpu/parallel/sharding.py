@@ -238,39 +238,46 @@ def token_spec() -> P:
     return P("data", None)
 
 
-def shard_params(params: Params, mesh: Mesh, cfg: ModelConfig) -> Params:
-    """device_put the param tree onto the mesh per the policy (with
-    divisibility sanitization per leaf). Int8-quantized weights
-    (ops/quant.py::QuantInt8) shard their payload with the original
-    weight's spec; the per-output-channel scales follow it (size-1 axes
-    sanitize to replicated, the channel axis inherits the sharding)."""
+def param_shardings(params: Params, mesh: Mesh, cfg: ModelConfig) -> Params:
+    """The ``NamedSharding`` of every leaf of ``params`` under the policy
+    (``param_specs`` through ``sanitize_spec``, leaf by leaf), as a tree
+    of the same structure. ``params`` may hold arrays or only their
+    shapes (``jax.eval_shape``): ``shard_params`` places a live tree by
+    it, and the seeded generator (ops/quant.py::
+    random_params_int8_sharded) compiles its outputs to it, so a tree
+    made on the mesh and a tree moved onto it cannot differ in layout.
+    Int8-quantized weights (ops/quant.py::QuantInt8) shard their payload
+    with the original weight's spec; the per-output-channel scales
+    follow it (size-1 axes sanitize to replicated, the channel axis
+    inherits the sharding; an int4 packed out/2 axis or a group-count
+    axis that no longer divides under TP drops its mesh axis)."""
     import dataclasses as _dc
 
     from ..ops.quant import QuantInt8, QuantInt8W8A8
     from ..ops.quant4 import QuantInt4
 
-    specs = param_specs(cfg)
     qtypes = (QuantInt8, QuantInt8W8A8, QuantInt4)
 
-    def _put(leaf, spec):
+    def _named(leaf, spec):
+        return NamedSharding(mesh, sanitize_spec(mesh, spec, leaf.shape))
+
+    def _of(leaf, spec):
         if isinstance(leaf, qtypes):
-            # Payload and scales follow the original weight's spec
-            # (sanitize_spec drops axes that no longer divide — e.g. an
-            # int4 packed out/2 axis or a group-count axis under TP).
-            return _dc.replace(
-                leaf,
-                q=jax.device_put(leaf.q, NamedSharding(
-                    mesh, sanitize_spec(mesh, spec, leaf.q.shape))),
-                scale=jax.device_put(leaf.scale, NamedSharding(
-                    mesh, sanitize_spec(mesh, spec, leaf.scale.shape))),
-            )
-        s = sanitize_spec(mesh, spec, leaf.shape)
-        return jax.device_put(leaf, NamedSharding(mesh, s))
+            return _dc.replace(leaf, q=_named(leaf.q, spec),
+                               scale=_named(leaf.scale, spec))
+        return _named(leaf, spec)
 
     return jax.tree_util.tree_map(
-        _put, params, specs,
+        _of, params, param_specs(cfg),
         is_leaf=lambda x: isinstance(x, qtypes),
     )
+
+
+def shard_params(params: Params, mesh: Mesh, cfg: ModelConfig) -> Params:
+    """device_put the param tree onto the mesh per the policy
+    (``param_shardings``: divisibility sanitization per leaf, quantized
+    payload and scales by the original weight's spec)."""
+    return jax.device_put(params, param_shardings(params, mesh, cfg))
 
 
 def shard_cache(cache, mesh: Mesh, cfg: ModelConfig):
@@ -304,32 +311,37 @@ def shard_cache(cache, mesh: Mesh, cfg: ModelConfig):
     )
 
 
-def shard_pool_cache(cache, mesh: Mesh, cfg: ModelConfig):
-    """device_put a block-paged pool KVCache onto the mesh: KV heads
-    over ``model``, everything else replicated (``pool_cache_specs``).
-    QuantKV leaves place the int8 payload with the full spec and the
-    per-(block, page-row, head) scales with the same spec minus the
-    trailing head_dim axis — same zip rule as ``shard_cache``."""
+def pool_cache_shardings(cache, mesh: Mesh, cfg: ModelConfig):
+    """The ``NamedSharding`` of every leaf of a block-paged pool KVCache
+    (arrays or only their shapes): KV heads over ``model``, everything
+    else replicated (``pool_cache_specs``). QuantKV leaves place the
+    int8 payload with the full spec and the per-(block, page-row, head)
+    scales with the same spec minus the trailing head_dim axis — same
+    zip rule as ``shard_cache``. ``shard_pool_cache`` moves a pool by
+    it; the engine makes its pool on the mesh by it
+    (engine/batcher.py::_new_pool_cache), so a pool that does not fit
+    one device never has to."""
     from ..models.transformer import KVCache
     from ..ops.quant import QuantKV
 
     specs = pool_cache_specs(cfg)
 
-    def _put_kv(block, spec):
-        def put(a):
-            return jax.device_put(
-                a, NamedSharding(mesh, sanitize_spec(mesh, spec, a.shape)))
+    def _named(a, spec):
+        return NamedSharding(mesh, sanitize_spec(mesh, spec, a.shape))
 
+    def _kv(block, spec):
         if isinstance(block, QuantKV):
-            return QuantKV(q=put(block.q), s=put(block.s))
-        return put(block)
+            return QuantKV(q=_named(block.q, spec), s=_named(block.s, spec))
+        return _named(block, spec)
 
-    return KVCache(
-        k=_put_kv(cache.k, specs["k"]),
-        v=_put_kv(cache.v, specs["v"]),
-        lengths=jax.device_put(
-            cache.lengths, NamedSharding(mesh, P())),
-    )
+    return KVCache(k=_kv(cache.k, specs["k"]), v=_kv(cache.v, specs["v"]),
+                   lengths=NamedSharding(mesh, P()))
+
+
+def shard_pool_cache(cache, mesh: Mesh, cfg: ModelConfig):
+    """device_put a block-paged pool KVCache onto the mesh
+    (``pool_cache_shardings``)."""
+    return jax.device_put(cache, pool_cache_shardings(cache, mesh, cfg))
 
 
 def replicate(arr, mesh: Mesh):

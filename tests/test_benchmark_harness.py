@@ -1,0 +1,272 @@
+"""The benchmark harness's own cases (benchmark/tests/test_harness.py, PR 26:
+a configuration the harness was not written for comes in as files), counted in
+tier-1 since PR 27 (PERF.md Open question 13a), plus the four-chip
+configuration that PR 27 brought in as files only. One parametrised test, a
+case each. ``benchmark/`` is not a package: its modules are found by path, as
+``benchmark/tests/conftest.py`` finds them."""
+
+import copy
+import importlib.util
+import json
+import os
+import subprocess
+import sys
+from pathlib import Path
+
+import pytest
+
+BENCH = Path(__file__).resolve().parent.parent / "benchmark"
+sys.path.insert(0, str(BENCH))
+
+import modelmap  # noqa: E402
+import run as R  # noqa: E402
+import xtrace  # noqa: E402
+
+RULES = json.loads((BENCH / "trace_categories.json").read_text())
+
+
+def ev(name, start_us, dur_us, scope=""):
+    """A trace event as xtrace's reports hold them (benchmark/tests/test_xtrace.py)."""
+    return [name, start_us * 1000, dur_us * 1000, scope]
+
+#: https://huggingface.co/allenai/OLMoE-1B-7B-0125-Instruct/blob/main/config.json
+#: (the catalog's ``config``), with what a configuration file adds around it.
+OLMOE = {
+    "name": "olmoe-1b-7b-0125-instruct", "attention_bias": False, "clip_qkv": None,
+    "hidden_act": "silu", "hidden_size": 2048, "intermediate_size": 1024,
+    "max_position_embeddings": 4096, "model_type": "olmoe", "norm_topk_prob": False,
+    "num_attention_heads": 16, "num_experts": 64, "num_experts_per_tok": 8,
+    "num_hidden_layers": 16, "num_key_value_heads": 16, "rms_norm_eps": 1e-05,
+    "rope_scaling": None, "rope_theta": 10000, "tie_word_embeddings": False,
+    "vocab_size": 50304, "eos_token_id": 50279,
+    "assumed": {"head_dim": 128, "pad_token_id": 1, "bos_token_id": 50279},
+    "keys": {"num_experts": "n_experts"},
+}
+
+
+def shipped(name):
+    return json.loads((BENCH / "configs" / f"{name}.json").read_text())
+
+
+def meshed(mesh, mesh_shape):
+    cfg = shipped("mistral-7b-instruct-v0.2")
+    cfg["mesh"] = mesh
+    cfg["server_env"]["MESH_SHAPE"] = mesh_shape
+    return cfg
+
+
+#: what engine/batcher.py::sharding_health reports for a sound model:4 server
+SOUND = {"mesh": {"data": 1, "expert": 1, "pipe": 1, "seq": 1, "model": 4}, "devices": 4,
+         "residual_tp_fraction": 1.0, "weights_shard_fraction": 0.25, "pool_sharded": True,
+         "kv_pool_mesh_fallback": False, "draft_sharded": False, "draft_kv_fallback": False,
+         "attention_regime": "ragged"}
+
+
+def health(**sharding):
+    return {"engine": "jax-batched", "model": "mistral-7b-instruct-v0.2", "platform": "tpu",
+            "device_kind": "TPU v5 lite", "devices": 4,
+            "kv_pool": {"attention_regime": "ragged"}, "sharding": {**SOUND, **sharding}}
+
+
+def check(h, mesh={"model": 4}):
+    peaks = json.loads((BENCH / "peaks.json").read_text())
+    return R.check_health(h, shipped("mistral-7b-instruct-v0.2"), peaks, {"chips": 4}, mesh, False)
+
+
+def case_own_keys_reach_the_model_config():
+    import serve
+
+    cfg, sz = serve.register(OLMOE)
+    assert (cfg.n_experts, cfg.experts_per_token, cfg.mlp_hidden) == (64, 8, 1024)
+    assert sz["num_experts"] == 64 and "num_local_experts" not in sz    # what the reference gets
+    f = modelmap.fields(sz, modelmap.key_map(OLMOE))
+    assert f["n_experts"] == 64 and f["dim"] == 2048
+    # without its "keys" the file's experts would be dropped in silence: a dense model
+    dense = {k: v for k, v in OLMOE.items() if k != "keys"}
+    assert serve.register(dense)[0].n_experts == 0
+
+
+def case_a_field_the_program_lacks_ends_the_run():
+    import serve
+
+    bad = dict(OLMOE, keys={"num_experts": "n_experts", "norm_topk_prob": "norm_topk_prob"})
+    with pytest.raises(SystemExit) as e:
+        serve.register(bad)
+    assert str(e.value) == ("serve: norm_topk_prob maps to ModelConfig.norm_topk_prob, "
+                            "which the program does not have")
+
+
+def case_two_keys_for_one_field_must_agree():
+    both = dict(OLMOE, num_local_experts=8)
+    with pytest.raises(SystemExit, match="two keys of the file map to ModelConfig.n_experts"):
+        modelmap.model_config("x", modelmap.sizes(both), modelmap.key_map(both))
+
+
+def case_sliding_window_is_refused_only_while_unmapped():
+    cfg = dict(shipped("mistral-7b-instruct-v0.2"), sliding_window=4096)
+    with pytest.raises(SystemExit, match="no sliding-window attention"):
+        modelmap.sizes(cfg)
+    mapped = dict(cfg, keys={"sliding_window": "sliding_window"})
+    assert modelmap.sizes(mapped)["sliding_window"] == 4096      # now the field must exist
+    with pytest.raises(SystemExit, match="ModelConfig.sliding_window, which the program"):
+        modelmap.model_config("x", modelmap.sizes(mapped), modelmap.key_map(mapped))
+
+
+def case_a_collective_inside_a_scope_is_billed_to_collectives():
+    mlp = "jit(chunk)/while/body/while/body/mlp/dot_general"
+    head = "jit(chunk)/while/body/lm_head/dot_general"
+    ops = [ev("%fusion.5 = bf16[] fusion(...)", 0, 60, mlp),
+           ev("%all-reduce.4 = bf16[] all-reduce(...)", 60, 30, mlp),
+           ev("%all-gather-start.2 = bf16[] all-gather-start(...)", 90, 5, "jit(chunk)/o_proj/x"),
+           ev("%fusion.9 = f32[] fusion(...)", 100, 20, head)]
+    plane = lambda i: {"name": f"/device:TPU:{i}", "lines": [
+        {"name": "XLA Modules", "events": [ev("jit_chunk(1)", 0, 120)]},
+        {"name": "XLA Ops", "events": copy.deepcopy(ops)}]}
+    r = xtrace.reduce({"planes": [plane(i) for i in range(4)]}, RULES, 2, chips=4)
+    assert r["problems"] == [] and r["devices"] == 4 and r["forward_passes"] == 1
+    assert r["category_s"]["collectives"] == pytest.approx(35e-6)
+    assert r["category_s"]["mlp"] == pytest.approx(60e-6)       # and to nothing else
+    assert "attn_proj" not in r["category_s"]
+    assert ["collectives:all-reduce", 30e-6] in [[n, pytest.approx(s)] for n, s in
+                                                 r["breakdown"]["device_ops"]]
+    # fewer planes than chips, or planes that ran different passes: not this cell
+    three = xtrace.reduce({"planes": [plane(i) for i in range(3)]}, RULES, 2, chips=4)
+    assert three["problems"] == ["the trace holds 3 device planes, the cell runs on 4 chips"]
+    odd = plane(3)      # up to 2 apart is the capture's two edges
+    odd["lines"][1]["events"] += [ev("%fusion.9 = f32[] fusion(...)", 130 + 25 * i, 20, head)
+                                  for i in range(3)]
+    uneven = xtrace.reduce({"planes": [plane(0), plane(1), plane(2), odd]}, RULES, 2, chips=4)
+    assert uneven["problems"] == [
+        "the device planes ran different numbers of forward passes: [1, 1, 1, 4]"]
+
+
+def case_a_sound_sharded_server_passes():
+    assert check(health()) == []
+
+
+def case_a_fallback_or_a_wrong_mesh_is_refused():
+    assert "kv_pool_mesh_fallback" in check(health(kv_pool_mesh_fallback=True))[0]
+    assert "pool_sharded" in check(health(pool_sharded=False))[0]
+    assert "weights_shard_fraction 1.0" in check(health(weights_shard_fraction=1.0))[0]
+    two = check(health(mesh={"data": 2, "model": 2}, weights_shard_fraction=0.5))
+    assert any("sharding.mesh {'data': 2, 'model': 2}, want {'model': 4}" in p for p in two)
+    assert "sharding.devices 2" in check(health(devices=2))[0]
+    assert "attention_regime 'gather'" in check(health(attention_regime="gather"))[0]
+    unsharded = dict(health(), sharding=None)       # a server that built no mesh
+    assert "no sharding" in check(unsharded)[0]
+    assert check(unsharded, mesh={}) == []          # which is what a one-chip cell wants
+
+
+def case_mesh_chips_and_mesh_shape_must_agree():
+    assert modelmap.mesh_problems(shipped("mistral-7b-instruct-v0.2"), 1) == []
+    assert modelmap.mesh_problems(meshed({"model": 4}, "model:4"), 4) == []
+    assert modelmap.mesh_problems(meshed({"model": 4, "data": 1}, "tp=4"), 4) == []
+    assert "the cell asks for 1 chips" in modelmap.mesh_problems(meshed({"model": 4}, "model:4"), 1)[0]
+    assert "MESH_SHAPE ''" in modelmap.mesh_problems(meshed({"model": 4}, ""), 4)[0]
+    assert "MESH_SHAPE 'model:2'" in modelmap.mesh_problems(meshed({"model": 4}, "model:2"), 4)[0]
+    assert len(modelmap.mesh_problems(shipped("mistral-7b-instruct-v0.2"), 4)) == 1
+    with pytest.raises(SystemExit, match="unknown mesh axis 'tensor'"):
+        modelmap.mesh_of({"mesh": {"tensor": 4}})
+
+
+def case_a_disagreement_is_refused_before_launch(monkeypatch, capsys):
+    cell = {"name": "x", "config": "c", "traffic": "chat-steady", "chips": 4}
+    monkeypatch.setattr(R, "resolve_cell", lambda bench, workload: (
+        cell, {}, meshed({"model": 4}, "model:2"), {}))
+    monkeypatch.setattr(R, "launch", lambda *a, **k: pytest.fail("launched"))
+    monkeypatch.setattr(sys, "argv", ["run.py", "--workload", "x", "--seed", "1", "--seconds", "1"])
+    assert R.main() == 2
+    assert "MESH_SHAPE 'model:2'" in capsys.readouterr().err
+
+
+def case_the_roofline_reader_counts_a_chips_share():
+    spec = importlib.util.spec_from_file_location("r", BENCH / "readers" / "trace_roofline.py")
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    cfg = shipped("mixtral-8x7b-instruct-v0.1-l6")
+    sz = modelmap.sizes(cfg)
+    trace = {"forward_passes": 5.0, "category_s": {"mlp": 0.00018, "lm_head": 0.000115}}
+    ctx = {"trace": trace, "trace_rules": RULES, "sizes": sz, "config": cfg, "mesh": {},
+           "fields": modelmap.fields(sz, modelmap.key_map(cfg)),
+           "peaks": {"hbm_bytes_per_s": 819e9}}
+    whole = reader.read(ctx, {})
+    assert whole == 18291.109670743568        # the parent's reading of this trace, to the digit
+    assert reader.read(dict(ctx, mesh={"model": 4}), {}) == pytest.approx(whole / 4)
+    # experts_streamed: a number, or a counter over a count of layer passes under /health
+    two = reader.read(dict(ctx, config=dict(cfg, experts_streamed=2)), {})
+    counted = dict(cfg, experts_streamed={"counter": ["moe", "experts_read"],
+                                          "per": ["moe", "layer_passes"]})
+    probes = {"health_before": {"moe": {"experts_read": 100, "layer_passes": 10}},
+              "health_after": {"moe": {"experts_read": 300, "layer_passes": 110}}}
+    assert reader.read(dict(ctx, config=counted, **probes), {}) == pytest.approx(two)
+    assert two < whole
+    with pytest.raises(LookupError):          # the file names a counter the program lacks
+        reader.read(dict(ctx, config=counted), {})
+
+
+def case_the_comparison_runs_over_a_two_device_mesh(tmp_path):
+    """--rehearse of refcheck.py: toy widths, the file's mesh cut to two of
+    four pretended CPU devices, placed by the program's own sharding policy."""
+    cfg = meshed({"model": 4}, "model:4")
+    (tmp_path / "cfg.json").write_text(json.dumps(cfg))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    subprocess.run([sys.executable, str(BENCH / "refcheck.py"), "--config",
+                    str(tmp_path / "cfg.json"), "--seed", "11", "--rehearse",
+                    "--out", str(tmp_path / "out.json")], env=env, check=True, timeout=600)
+    out = json.loads((tmp_path / "out.json").read_text())
+    assert out["ok"] and out["mesh"] == {"model": 2} and out["devices"] == 2
+    assert out["rel_err"] < out["tolerance_rel"] and out["positions_clear"] == 167
+
+
+def case_a_reference_may_bring_its_own_weights(tmp_path):
+    import refcheck
+
+    ref = tmp_path / "ref.py"
+    ref.write_text("def forward(cfg, weights, tokens):\n    return weights\n"
+                   "def weights_from_program(params, n_layers):\n    return 'own'\n")
+    mod = refcheck.load_reference(str(ref))
+    assert refcheck.weights_function(mod)(None, 2) == "own"
+    shipped_ref = refcheck.load_reference(shipped("mistral-7b-instruct-v0.2")["reference"])
+    assert refcheck.weights_function(shipped_ref) is refcheck.reference_weights
+
+
+def case_a_configuration_names_its_rehearsal_model():
+    moe = shipped("mixtral-8x7b-instruct-v0.1-l6")
+    assert R.child_env(moe, 1, True)["MODEL_NAME"] == "toy-moe"
+    assert R.child_env(shipped("mistral-7b-instruct-v0.2"), 1, True)["MODEL_NAME"] == "toy-8m"
+    env = R.child_env(meshed({"model": 4}, "model:4"), 1, True)
+    assert env["MESH_SHAPE"] == "model:2" and env["XLA_FLAGS"].endswith("device_count=2")
+    assert R.child_env(moe, 1, False)["MODEL_NAME"] == moe["name"]
+
+
+def case_the_four_chip_configuration_is_a_cell_of_four_chips_only():
+    """mixtral-8x7b-instruct-v0.1 (PR 27): the file's mesh, its MESH_SHAPE and
+    the cell's chips say the same, so a one-chip cell over it is refused
+    before launch; nothing of its source is reduced; it rehearses on the toy
+    expert model over a mesh cut to 2."""
+    cfg = shipped("mixtral-8x7b-instruct-v0.1")
+    assert modelmap.mesh_of(cfg) == {"model": 4}
+    assert modelmap.mesh_problems(cfg, 4) == []
+    assert "the cell asks for 1 chips" in modelmap.mesh_problems(cfg, 1)[0]
+    assert cfg["num_hidden_layers"] == 32 and cfg["reduced"] == {}
+    assert "weights_in_one_call" not in cfg
+    bench = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
+    cell, entry, file, mix = R.resolve_cell(bench, "mixtral8x7b-tp4-chat-steady")
+    assert (cell["chips"], entry["reduced"], file, mix["name"]) == (4, [], cfg, "chat-steady")
+    l6 = shipped("mixtral-8x7b-instruct-v0.1-l6")
+    widths = [k for k in modelmap.sizes(cfg) if k != "num_hidden_layers"]
+    assert all(modelmap.sizes(cfg)[k] == modelmap.sizes(l6)[k] for k in widths)
+    differ = {k for k in cfg["server_env"] if cfg["server_env"][k] != l6["server_env"].get(k)}
+    assert differ == {"MESH_SHAPE", "KV_POOL_BLOCKS"}
+    env = R.child_env(cfg, 1, True)
+    assert (env["MODEL_NAME"], env["MESH_SHAPE"]) == ("toy-moe", "model:2")
+
+
+CASES = [v for k, v in sorted(globals().items()) if k.startswith("case_")]
+
+
+@pytest.mark.parametrize("case", CASES, ids=lambda f: f.__name__[5:])
+def test_harness(case, request):
+    names = case.__code__.co_varnames[:case.__code__.co_argcount]
+    case(**{n: request.getfixturevalue(n) for n in names})

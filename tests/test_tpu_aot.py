@@ -110,3 +110,137 @@ def test_pool_forward_moves_no_pool_sized_buffer_on_v5e(one_chip, W,
     assert mem.alias_size_in_bytes >= pool_bytes, "the pool is donated"
     assert mem.temp_size_in_bytes < pool_bytes, (
         mem.temp_size_in_bytes, pool_bytes)
+
+
+# ----------------------------------------------- four chips (ISSUE 27)
+
+MIXTRAL = dict(vocab_size=32000, dim=4096, n_heads=32, n_kv_heads=8,
+               head_dim=128, mlp_hidden=14336, rope_theta=1e6, eos_ids=(2,),
+               tie_embeddings=False, n_experts=8, experts_per_token=2)
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    from ai_agent_kubectl_tpu.parallel.mesh import MeshConfig, build_mesh
+    return build_mesh(MeshConfig(model=4), topo.devices)
+
+
+def test_seeded_init_over_model4_holds_a_chips_share_on_v5e(mesh4):
+    """ISSUE 27 at Mixtral-8x7B's published sizes (32 layers, 46.7 GB of
+    int8): the ONE program that makes the seeded tree sharded gives each of
+    four chips its quarter, 11.69 GB, which a 16 GB chip holds, with next to
+    no temporaries (the PRNG's 32-bit words stay inside the fusions). Whole
+    on one device, as the engine made it before, it cannot exist."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from ai_agent_kubectl_tpu.ops.quant import _random_params_int8
+    from ai_agent_kubectl_tpu.parallel.sharding import param_shardings
+
+    cfg = ModelConfig(name="aot", n_layers=32, **MIXTRAL)
+
+    def make(k):
+        return _random_params_int8(k, cfg, jnp.bfloat16, True, False,
+                                   slices_in_one_op=True)
+
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32,
+                               sharding=NamedSharding(mesh4, P()))
+    shapes = jax.eval_shape(make, key)
+    whole = sum(leaf.size * leaf.dtype.itemsize
+                for leaf in jax.tree_util.tree_leaves(shapes))
+    assert whole > 46e9
+    compiled = jax.jit(make, out_shardings=param_shardings(
+        shapes, mesh4, cfg)).lower(key).compile()
+    mem = compiled.memory_analysis()
+    # norms, router and the row-parallel scales are whole on every chip
+    assert whole / 4 <= mem.output_size_in_bytes < whole / 4 + 2**24
+    assert mem.output_size_in_bytes < 11.7e9
+    assert mem.temp_size_in_bytes < 2**30
+
+
+@pytest.mark.parametrize("W", [1, 64], ids=["decode", "admission"])
+def test_expert_mlp_over_model4_reduces_after_the_mix_on_v5e(mesh4, W,
+                                                             monkeypatch):
+    """ISSUE 27: with the experts' inner width split over ``model``, each
+    chip's down projection is a partial sum. The program reduces it on
+    [B, S, D] after the experts are mixed (parallel/moe.py::
+    _down_and_mix_sharded); the partitioner alone put the reduce on the
+    projection's own result, E times the bytes: collective-permutes of
+    bf16[8,4096,4,W] round the ring. No collective of the compiled pool+ragged
+    forward may carry the expert axis next to the model width."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from ai_agent_kubectl_tpu.parallel.sharding import (param_shardings,
+                                                        pool_cache_specs,
+                                                        sanitize_spec)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = ModelConfig(name="aot", n_layers=2, **MIXTRAL)
+    B, page, n_blocks, pages = 16, 64, 1040, 65
+    rep = NamedSharding(mesh4, P())
+
+    def arg(shape, dtype, sharding=rep):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    shapes = jax.eval_shape(lambda k: random_params_int8(
+        k, cfg, dtype=jnp.bfloat16, quantize_embed=True),
+        jax.random.PRNGKey(0))
+    params = jax.tree_util.tree_map(
+        lambda x, s: arg(x.shape, x.dtype, s), shapes,
+        param_shardings(shapes, mesh4, cfg))
+    pool = (cfg.n_layers, n_blocks, page, cfg.n_kv_heads, cfg.head_dim)
+    heads = NamedSharding(mesh4, sanitize_spec(
+        mesh4, pool_cache_specs(cfg)["k"], pool))
+    cache = KVCache(k=arg(pool, jnp.bfloat16, heads),
+                    v=arg(pool, jnp.bfloat16, heads),
+                    lengths=arg((n_blocks,), jnp.int32))
+
+    def step(params, tok, pos, cache, wmask, tables, q_lens):
+        return forward(params, cfg, tok, pos, cache, kv_limit=pages * page,
+                       attn_impl="ragged", mesh=mesh4, token_mask=wmask,
+                       write_mask=wmask, page_size=page,
+                       block_tables=tables, q_lens=q_lens,
+                       logits_at=jnp.maximum(q_lens, 1) - 1)
+
+    hlo = jax.jit(step, donate_argnums=(3,)).lower(
+        params, arg((B, W), jnp.int32), arg((B, W), jnp.int32), cache,
+        arg((B, W), jnp.bool_), arg((B, pages), jnp.int32),
+        arg((B,), jnp.int32)).compile().as_text()
+    assert "tpu_custom_call" in hlo, "the Mosaic kernel is not in the program"
+    collectives = re.findall(
+        r"= (.*?) (all-reduce|all-gather|reduce-scatter|all-to-all|"
+        r"collective-permute)(?:-start)?\(", hlo)
+    assert collectives
+    with_experts = [(op, shape) for shape, op in collectives
+                    if re.search(r"\[(?:\d+,)*8,4096[,\]]|\[(?:\d+,)*4096,(?:\d+,)*8[,\]]"
+                                 r"|\[8,4096,", shape)]
+    assert not with_experts, with_experts
+    # the experts' reduce itself: [B, W, D] (or its [B/4, W, D] scatter)
+    assert any(op in ("all-reduce", "reduce-scatter")
+               and re.search(rf"bf16\[(?:16|4),{W},4096\]", shape)
+               for shape, op in collectives), collectives
+
+
+def test_pool_copy_on_write_over_model4_is_in_place_on_v5e(mesh4):
+    """ISSUE 27 at Mixtral-8x7B's pool over ``model:4`` (32 layers, 1,792
+    blocks, 1.75 GiB a leaf a chip): the engine's copy-on-write program
+    holds no leaf-sized temporary. The row form the partitioner turned into
+    a copy of the whole leaf, which did not fit beside the weights (the
+    server died loading ``jit_cow``: my chip run, PR 27)."""
+    import types
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from ai_agent_kubectl_tpu.engine.batcher import BatchedJaxEngine
+
+    shape = (32, 1792, 64, 8, 128)
+    heads = NamedSharding(mesh4, P(None, None, None, "model", None))
+    rep = NamedSharding(mesh4, P())
+    cache = KVCache(k=jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=heads),
+                    v=jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=heads),
+                    lengths=jax.ShapeDtypeStruct((1792,), jnp.int32, sharding=rep))
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=rep)
+    cow = BatchedJaxEngine._pool_cow_fn.fget(
+        types.SimpleNamespace(kv_pool_page=64, mesh=mesh4))
+    mem = cow.lower(cache, scalar, scalar, scalar).compile().memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * 32 * 1792 * 64 * 2 * 128 * 2
+    assert mem.temp_size_in_bytes < 2**24
