@@ -1,5 +1,5 @@
 """Grammar runtime: compiled variants, per-request resolution, and the
-stacked device tables the decode chunk gathers from.
+stacked device tables the decode chunk reads.
 
 One engine owns one :class:`GrammarRuntime`. It compiles the base
 profile (``GRAMMAR_PROFILE``) and the ``readonly`` clamp target at
@@ -7,14 +7,21 @@ startup, and installs per-request *variants* (an allowed-verbs subset,
 ISSUE 11) on demand into a bounded set of profile slots. All variants
 are padded into ONE stacked table set —
 
-    ``tok_class``  [P, vocab]        token → class, per profile slot
-    ``class_ok``   [P·S_max, C_max]  legality, rows keyed by the
-    ``class_next`` [P·S_max, C_max]  *global* state ``pid·S_max + s``
+    ``tok_class``     [P, vocab]        token → class, per profile slot
+    ``class_ok``      [P·S_max, C_max]  legality, rows keyed by the
+    ``class_next``    [P·S_max, C_max]  *global* state ``pid·S_max + s``
+    ``class_ok_bits`` [P·S_max, ceil(C_max/32)] uint32: ``class_ok``
+                      bit-packed, bit ``c & 31`` of word ``c >> 5``
 
 — with fixed shapes, so installing a variant updates device table
 CONTENTS but never re-traces the jitted chunk program. A slot's FSM
 word in the decode carry is the global state; profile identity rides
-inside it (``gs // S_max``).
+inside it (``gs // S_max``). The device holds ``tok_class``,
+``class_next`` and the PACKED legality: the chunk tests each vocabulary
+entry's bit in its slot's row of words (engine/batcher.py::
+grammar_legal_mask; as an element gather through ``class_ok`` the mask
+was 5 ms of every decode step, ISSUE 28). ``class_ok`` itself stays on
+the host, for the tests to read.
 
 Per-request resolution policy (mirrors the X-Priority clamp semantics,
 engine/qos.py): a request may *lower* itself to ``readonly`` (header)
@@ -55,6 +62,14 @@ PROFILES = ("default", "readonly", "permissive")
 #: it falls back to the clamped base profile (logged, never an error).
 _STATE_MARGIN = 8
 _CLASS_MARGIN = 16
+
+
+def pack_class_bits(class_ok: np.ndarray) -> np.ndarray:
+    """[R, C] bool → [R, ceil(C/32)] uint32: bit ``c & 31`` of word
+    ``c >> 5`` is ``class_ok[r, c]``; the bits past ``C`` are zero."""
+    bits = np.packbits(class_ok, axis=1, bitorder="little")
+    bits = np.pad(bits, ((0, 0), (0, -bits.shape[1] % 4)))
+    return bits.view("<u4").astype(np.uint32, copy=False)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -179,6 +194,7 @@ class GrammarRuntime:
         self.tok_class = np.zeros((P, self.vocab_size), np.int32)
         self.class_ok = np.zeros((P * S, C), bool)
         self.class_next = np.zeros((P * S, C), np.int32)
+        self.class_ok_bits = np.zeros((P * S, -(-C // 32)), np.uint32)
         #: bumped on every install; engines compare against their last
         #: uploaded version to refresh device copies.
         self.version = 0
@@ -218,6 +234,8 @@ class GrammarRuntime:
         self.class_next[base:base + S, :] = base + DEAD
         self.class_ok[base:base + ns, :nc] = fsm.class_ok
         self.class_next[base:base + ns, :nc] = base + fsm.class_next
+        self.class_ok_bits[base:base + S] = pack_class_bits(
+            self.class_ok[base:base + S])
         self._fsms[pid] = fsm
         self._keys[key] = pid
         self.version += 1
@@ -278,15 +296,16 @@ class GrammarRuntime:
     # ------------------------------------------------------------ views
 
     def snapshot_tables(self) -> tuple:
-        """(version, tok_class, class_ok, class_next) as a CONSISTENT
-        copy taken under the install lock — an engine refreshing its
-        device tables must never capture a half-written variant row (a
+        """(version, tok_class, class_ok_bits, class_next): what the
+        device holds (the legality packed), as a CONSISTENT copy taken
+        under the install lock — an engine refreshing its device
+        tables must never capture a half-written variant row (a
         torn mask samples off-grammar tokens or wrongly dead-ends a
         slot) nor stamp a post-install version on pre-install contents.
         Copies are a few MB and only happen when the version moved."""
         with self._lock:
             return (self.version, self.tok_class.copy(),
-                    self.class_ok.copy(), self.class_next.copy())
+                    self.class_ok_bits.copy(), self.class_next.copy())
 
     def fsm(self, pid: int) -> TokenFSM:
         return self._fsms[pid]

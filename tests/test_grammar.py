@@ -203,6 +203,161 @@ def test_runtime_variant_overflow_falls_back():
     assert rt.health()["variant_fallbacks"] >= 1
 
 
+# ------------------------------------------------ packed legality (ISSUE 28)
+
+
+def _unpacked(bits: np.ndarray, n_classes: int) -> np.ndarray:
+    """[R, words] uint32 → [R, n_classes] bool, the plain reading of the
+    packing: class ``c`` is bit ``c & 31`` of word ``c >> 5``."""
+    c = np.arange(n_classes)
+    return ((bits[:, c >> 5] >> (c & 31).astype(np.uint32)) & 1).astype(bool)
+
+
+def _device_mask(bits, tok_class, gs, s_max) -> np.ndarray:
+    import jax.numpy as jnp
+
+    from ai_agent_kubectl_tpu.engine.batcher import grammar_legal_mask
+
+    tc = jnp.asarray(tok_class)[jnp.asarray(gs) // s_max]
+    return np.asarray(grammar_legal_mask(jnp.asarray(bits),
+                                         jnp.asarray(gs), tc))
+
+
+def _case_profile_rows(name):
+    def check():
+        rt = mk_runtime()
+        pid = rt.resolve(lane="background" if name == "readonly"
+                         else "interactive")
+        rows = slice(pid * rt.S_max, (pid + 1) * rt.S_max)
+        assert rt.fsm(pid).n_states > 100
+        assert np.array_equal(_unpacked(rt.class_ok_bits[rows], rt.C_max),
+                              rt.class_ok[rows])
+        # ...and through the FSM object: every state's legal set.
+        fsm = rt.fsm(pid)
+        got = _unpacked(rt.class_ok_bits[rows], fsm.n_classes)
+        assert np.array_equal(got[:fsm.n_states], fsm.class_ok)
+    return check
+
+
+def _case_variant_after_start():
+    rt = mk_runtime()
+    v0, _, bits0, _ = rt.snapshot_tables()
+    pid = rt.resolve(lane="interactive", ctx=GrammarContext(
+        allowed_verbs=frozenset({"get", "logs"})))
+    assert pid == 2 and rt.version == v0 + 1     # ONE version, both tables
+    v1, tc, bits1, _ = rt.snapshot_tables()
+    assert v1 == rt.version
+    rows = slice(pid * rt.S_max, (pid + 1) * rt.S_max)
+    assert not bits0[rows].any() and bits1[rows].any()
+    assert np.array_equal(bits1[:pid * rt.S_max], bits0[:pid * rt.S_max])
+    assert np.array_equal(_unpacked(bits1, rt.C_max), rt.class_ok)
+    # the variant's own walk, read off the packed rows
+    gs = rt.start_state(pid)
+    for t in enc("kubectl get pods"):
+        cls = tc[pid, t]
+        assert (bits1[gs, cls >> 5] >> (cls & 31)) & 1
+        gs = rt.advance(gs, t)
+    cls = tc[pid, enc("d")[0]]       # "kubectl d..." is outside the subset
+    gs = rt.run(pid, enc("kubectl "))
+    assert not (bits1[gs, cls >> 5] >> (cls & 31)) & 1
+
+
+def _case_padding_bits_zero():
+    rt = mk_runtime()
+    words = rt.class_ok_bits.shape[1]
+    assert rt.C_max % 32 and words == -(-rt.C_max // 32)   # 50 classes, 2 words
+    beyond = _unpacked(rt.class_ok_bits, words * 32)[:, rt.C_max:]
+    assert beyond.shape[1] == words * 32 - rt.C_max and not beyond.any()
+    # rows past a variant's states and the profile slots nothing fills
+    for pid in (0, 1):
+        n = rt.fsm(pid).n_states
+        assert not rt.class_ok_bits[pid * rt.S_max + n:(pid + 1) * rt.S_max].any()
+    assert not rt.class_ok_bits[2 * rt.S_max:].any()
+
+
+def _case_dead_state_row():
+    rt = mk_runtime()
+    for pid in (0, 1):
+        assert not rt.class_ok_bits[pid * rt.S_max + DEAD].any()
+        assert rt.class_ok_bits[rt.start_state(pid)].any()
+    gs = np.asarray([DEAD, rt.start_state(0), rt.S_max + DEAD], np.int32)
+    mask = _device_mask(rt.class_ok_bits, rt.tok_class, gs, rt.S_max)
+    assert not mask[0].any() and mask[1].any() and not mask[2].any()
+
+
+def _case_pack_widths():
+    from ai_agent_kubectl_tpu.constrain.runtime import pack_class_bits
+
+    rng = np.random.default_rng(28)
+    for n_classes in (1, 31, 32, 33, 64, 455):
+        ok = rng.random((9, n_classes)) < 0.3
+        bits = pack_class_bits(ok)
+        assert bits.dtype == np.uint32
+        assert bits.shape == (9, -(-n_classes // 32))
+        assert np.array_equal(_unpacked(bits, n_classes), ok)
+        assert not _unpacked(bits, bits.shape[1] * 32)[:, n_classes:].any()
+
+
+def _case_device_mask_mixed_profiles():
+    rt = mk_runtime()
+    ro = rt.resolve(lane="background")
+    var = rt.resolve(lane="interactive", ctx=GrammarContext(
+        allowed_verbs=frozenset({"get", "delete"})))
+    states = [rt.start_state(0), rt.run(0, enc("kubectl de")),
+              rt.run(ro, enc("kubectl de")), rt.run(var, enc("kubectl de")),
+              rt.run(ro, enc("kubectl get po")), ro * rt.S_max + DEAD,
+              rt.run(var, enc("kubectl delete ")),
+              rt.run(0, enc("kubectl get pods -n "))]
+    gs = np.asarray(states, np.int32)
+    _, tok_class, bits, _ = rt.snapshot_tables()
+    want = np.take_along_axis(rt.class_ok[gs], tok_class[gs // rt.S_max], 1)
+    got = _device_mask(bits, tok_class, gs, rt.S_max)
+    assert got.dtype == bool and np.array_equal(got, want)
+    for row, g in zip(got, states):
+        assert np.array_equal(row, rt.allowed_np(int(g)))
+    # "de|lete" against "de|scribe": default, readonly, the subset
+    el, s = enc("l")[0], enc("s")[0]
+    assert got[1, el] and not got[2, el] and got[3, el]
+    assert got[1, s] and got[2, s] and not got[3, s]
+
+
+def _case_device_mask_fifteen_words():
+    """The shipped tokenizer's shapes cut down in rows only: 455 classes
+    are 15 words, so the select chain runs at its real length."""
+    from ai_agent_kubectl_tpu.constrain.runtime import pack_class_bits
+
+    rng = np.random.default_rng(5)
+    n_prof, s_max, n_classes, vocab, batch = 3, 40, 455, 2000, 16
+    ok = rng.random((n_prof * s_max, n_classes)) < 0.1
+    tok_class = rng.integers(0, n_classes, (n_prof, vocab)).astype(np.int32)
+    tok_class[:, :n_classes] = np.arange(n_classes)     # every class is used
+    gs = rng.integers(0, n_prof * s_max, batch).astype(np.int32)
+    want = np.take_along_axis(ok[gs], tok_class[gs // s_max], 1)
+    got = _device_mask(pack_class_bits(ok), tok_class, gs, s_max)
+    assert np.array_equal(got, want)
+
+
+@pytest.mark.parametrize("case", [
+    pytest.param(_case_profile_rows("default"), id="default-every-state"),
+    pytest.param(_case_profile_rows("readonly"), id="readonly-every-state"),
+    pytest.param(_case_variant_after_start, id="variant-after-start"),
+    pytest.param(_case_padding_bits_zero, id="padding-bits-zero"),
+    pytest.param(_case_dead_state_row, id="dead-state-row"),
+    pytest.param(_case_pack_widths, id="widths-not-multiples-of-32"),
+    pytest.param(_case_device_mask_mixed_profiles,
+                 id="device-mask-mixed-profiles"),
+    pytest.param(_case_device_mask_fifteen_words,
+                 id="device-mask-fifteen-words"),
+])
+def test_packed_legality_equals_class_ok(case):
+    """ISSUE 28: the device holds ``class_ok`` bit-packed
+    (``class_ok_bits``, written by the same install as ``class_ok``) and
+    ``grammar_legal_mask`` tests each vocabulary entry's bit. Both must
+    say exactly what ``class_ok`` says, entry for entry: the mask is the
+    same work in another form, so no transcript may move."""
+    case()
+
+
 # ------------------------------------------------------- masked sampling
 
 

@@ -9,6 +9,7 @@ below: the process that describes the topology loads the TPU library and
 keeps it, so it must happen inside a test, in one worker.
 """
 
+import math
 import re
 
 import jax
@@ -36,24 +37,30 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _instructions(hlo: str):
+    """(result type text, op, the whole line) of every HLO instruction."""
+    for line in hlo.splitlines():
+        m = re.match(r"\s+(?:ROOT )?%?[\w.\-]+ = (.*?) ([\w\-]+)\(", line)
+        if m:
+            yield m.group(1), m.group(2), line
+
+
+def _sizes(result: str) -> list:
+    """Element counts of the arrays in an instruction's result type."""
+    return [math.prod(int(d) for d in dims.split(","))
+            for dims in re.findall(r"\w+\[([\d,]+)\]", result)]
+
+
 def _results_of_size(hlo: str, sizes) -> list:
     """(op, result shape) of every HLO instruction whose result holds an
     array with one of ``sizes`` elements — moves of data only: parameters,
     tuples and their elements, bitcasts and loops name buffers, they do not
     fill them."""
     names = {"parameter", "get-tuple-element", "bitcast", "tuple", "while"}
-    hits = []
-    for line in hlo.splitlines():
-        m = re.match(r"\s+(?:ROOT )?%?[\w.\-]+ = (.*?) ([\w\-]+)\(", line)
-        if not m or m.group(2) in names:
-            continue
-        for dims in re.findall(r"\w+\[([\d,]+)\]", m.group(1)):
-            n = 1
-            for d in dims.split(","):
-                n *= int(d)
-            if n in sizes:
-                hits.append((m.group(2), dims))
-    return hits
+    return [(op, dims) for result, op, _ in _instructions(hlo)
+            if op not in names
+            for dims in re.findall(r"\w+\[([\d,]+)\]", result)
+            if math.prod(int(d) for d in dims.split(",")) in sizes]
 
 
 @pytest.mark.parametrize("W", [1, 64], ids=["decode", "admission"])
@@ -244,3 +251,133 @@ def test_pool_copy_on_write_over_model4_is_in_place_on_v5e(mesh4):
     mem = cow.lower(cache, scalar, scalar, scalar).compile().memory_analysis()
     assert mem.alias_size_in_bytes >= 2 * 32 * 1792 * 64 * 2 * 128 * 2
     assert mem.temp_size_in_bytes < 2**24
+
+
+# ------------------------------------ the grammar's mask (ISSUE 28)
+
+MISTRAL = dict(vocab_size=32000, dim=4096, n_heads=32, n_kv_heads=8,
+               head_dim=128, mlp_hidden=14336, rope_theta=1e6, eos_ids=(2,),
+               tie_embeddings=False)
+COLLECTIVE = (r"(all-reduce|all-gather|reduce-scatter|all-to-all|"
+              r"collective-permute)(?:-start)?")
+
+
+def _grammar_chunk_hlo(mesh, rep) -> str:
+    """The engine's ragged chunk program with the grammar on, compiled for
+    the described chip(s): batch 16, vocabulary 32,000, 2 layers at
+    Mistral-7B's widths, a 64-wide admission window, the shipped
+    tokenizer's table shapes (6 profile slots x 848 states, 455 classes)."""
+    from ai_agent_kubectl_tpu.engine.batcher import make_termination_chunk_fn
+    from ai_agent_kubectl_tpu.parallel.sharding import (param_shardings,
+                                                        pool_cache_specs,
+                                                        sanitize_spec)
+    from jax.sharding import NamedSharding
+
+    cfg = ModelConfig(name="aot", n_layers=2, **MISTRAL)
+    B, page, n_blocks, pages, W = 16, 64, 320, 64, 64
+    n_prof, s_max, n_classes = 6, 848, 455
+
+    def arg(shape, dtype, sharding=rep):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    shapes = jax.eval_shape(lambda k: random_params_int8(
+        k, cfg, dtype=jnp.bfloat16, quantize_embed=True),
+        jax.random.PRNGKey(0))
+    pool = (cfg.n_layers, n_blocks, page, cfg.n_kv_heads, cfg.head_dim)
+    if mesh is None:
+        params = jax.tree_util.tree_map(lambda x: arg(x.shape, x.dtype),
+                                        shapes)
+        heads = rep
+    else:
+        params = jax.tree_util.tree_map(
+            lambda x, s: arg(x.shape, x.dtype, s), shapes,
+            param_shardings(shapes, mesh, cfg))
+        heads = NamedSharding(mesh, sanitize_spec(
+            mesh, pool_cache_specs(cfg)["k"], pool))
+    cache = KVCache(k=arg(pool, jnp.bfloat16, heads),
+                    v=arg(pool, jnp.bfloat16, heads),
+                    lengths=arg((n_blocks,), jnp.int32))
+    common = dict(kv_limit=pages * page, attn_impl="ragged", mesh=mesh,
+                  page_size=page)
+
+    def step(params, tok, pos, cache, live, tables):
+        return forward(params, cfg, tok, pos, cache,
+                       token_mask=live[:, None], write_mask=live,
+                       block_tables=tables, **common)
+
+    def rstep(params, tok, pos, cache, wmask, tables, q_lens):
+        return forward(params, cfg, tok, pos, cache, token_mask=wmask,
+                       write_mask=wmask, block_tables=tables, q_lens=q_lens,
+                       logits_at=jnp.maximum(q_lens, 1) - 1, **common)
+
+    chunk = make_termination_chunk_fn(
+        step, 16, cfg.eos_ids, 0, 1.0, vocab_size=cfg.vocab_size,
+        pool_tables=True, grammar=True, grammar_s_max=s_max, ragged_w=W,
+        ragged_forward_step=rstep)
+    i32, f32, b = jnp.int32, jnp.float32, jnp.bool_
+
+    def vec(dtype):
+        return arg((B,), dtype)
+
+    return jax.jit(chunk, donate_argnums=(1, 2, 3, 7, 8, 12)).lower(
+        params, arg((B, 1), i32), arg((B, 1), i32), cache, vec(i32),
+        vec(f32), vec(b), vec(b), vec(i32), vec(i32), vec(b),
+        arg((B, pages), i32), vec(i32),
+        arg((n_prof, cfg.vocab_size), i32),
+        arg((n_prof * s_max, -(-n_classes // 32)), jnp.uint32),
+        arg((n_prof * s_max, n_classes), i32),
+        arg((B, W), i32), vec(i32), vec(i32), vec(i32), vec(i32), vec(i32),
+        vec(f32), vec(i32)).compile().as_text()
+
+
+def test_grammar_mask_is_no_element_gather_on_v5e(one_chip, monkeypatch):
+    """ISSUE 28: the legality mask over the vocabulary is bit tests on the
+    slots' packed class rows. As ``take_along_axis(class_ok[gs], tc, 1)`` it
+    compiled to a gather of 16 x 32,000 one-element slices behind a
+    ``GatherScatterIndicesBitpacked`` call that built a two-column index for
+    each of them, every step of the scan (5 ms a pass on the chip). The
+    gathers that stay take rows (the hoisted token->class map: 16 slices of
+    32,000; the packed rows: 16 of 15 words) or sixteen elements."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    hlo = _grammar_chunk_hlo(None, one_chip)
+    assert "tpu_custom_call" in hlo, "the Mosaic kernel is not in the program"
+    gathers = [(shape, line) for shape, op, line in _instructions(hlo)
+               if op == "gather"]
+    assert any(shape.startswith("u32[16,15]") for shape, _ in gathers), (
+        "the packed rows' gather should be in the program")
+    for shape, line in gathers:
+        if "[16,32000]" in shape:
+            assert "slice_sizes={1,32000}" in line, line[:300]
+    index_builds = [shape for shape, op, line in _instructions(hlo)
+                    if "GatherScatterIndicesBitpacked" in line]
+    assert not [s for s in index_builds if "32000" in s], index_builds
+
+
+def test_grammar_mask_over_model4_stays_split_on_v5e(mesh4, monkeypatch):
+    """ISSUE 28 over ``model:4``: the mask is made where the logits are,
+    on each chip's quarter of the vocabulary. No chip holds a [16, 32000]
+    array, and what crosses the mesh under the ``grammar_mask`` and
+    ``sampling`` scopes is what a split vocabulary needs and the gather
+    form crossed too: sixteen-element reductions (any legal token, the
+    argmax's partial winners, the sampled token's class, the slots' packed
+    rows) and the all-to-all that re-splits the argmax's index, which the
+    partitioner builds batch-split."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    hlo = _grammar_chunk_hlo(mesh4, NamedSharding(mesh4, P()))
+    assert "tpu_custom_call" in hlo, "the Mosaic kernel is not in the program"
+    assert "[16,32000]" not in hlo
+    assert "[16,8000]" in hlo
+    crossed = []
+    for shape, op, line in _instructions(hlo):
+        scope = re.search(r'op_name="([^"]*)"', line)
+        if (re.fullmatch(COLLECTIVE, op) and scope
+                and re.search("grammar_mask|sampling", scope.group(1))):
+            crossed.append((op, shape, scope.group(1)))
+    assert crossed, "a split vocabulary reduces across the mesh"
+    for op, shape, scope in crossed:
+        if max(_sizes(shape), default=1) <= 16 * 128:
+            continue
+        assert op == "all-to-all" and shape.startswith("s32[4,4,8000]") \
+            and "grammar_mask" not in scope, (op, shape, scope)
