@@ -17,7 +17,7 @@ Package layout:
                   batching scheduler, jit prefill/decode
 - ``models``    — pure-JAX decoder-only transformer families (Gemma, Llama,
                   Mixtral) and weight conversion
-- ``ops``       — Pallas TPU kernels (flash attention, paged decode
+- ``ops``       — Pallas TPU kernels (flash attention, ragged pool
                   attention, ring attention) and numeric reference ops
 - ``parallel``  — device mesh construction, NamedSharding policies (DP/TP/
                   EP/SP), multi-host initialization

@@ -207,8 +207,8 @@ class ServiceConfig:
     # per-step decode-attention HBM read — on HBM-capped single-chip
     # serving (7B-class) this doubles the decode batch that fits beside
     # the weights. Composes with every mesh axis incl. pipe (the stage
-    # bodies tree-map QuantKV); DECODE_ATTN=paged falls back to the dense
-    # ladder (the paged kernel reads bf16 KV).
+    # bodies tree-map QuantKV); over the pool it serves the ``gather``
+    # regime (the ragged kernel reads bf16 KV).
     kv_quant: str = ""                      # KV_QUANT: "" | int8
     max_seq_len: int = 1024                 # MAX_SEQ_LEN
     max_new_tokens: int = 128               # MAX_NEW_TOKENS
@@ -246,29 +246,11 @@ class ServiceConfig:
     top_k: int = 0                          # TOP_K
     top_p: float = 1.0                      # TOP_P
     attn_impl: str = "auto"                 # ATTN_IMPL: auto | dense | flash (prefill kernel)
-    # Decode attention: "paged" reads only each slot's live KV pages
-    # (ops/paged_attention.py). "auto" picks paged for GQA models on TPU
-    # (measured 2.08x on Llama-3-8B bs=32, raising KV_PAGE_SIZE to >= 64)
-    # and dense-over-KV-bucket for MQA/MHA (faster there, measured).
-    decode_attn: str = "auto"               # DECODE_ATTN: auto | dense | paged
     # MoE dispatch: "auto" uses expert-parallel all-to-all dispatch when
     # the mesh has expert>1, dense all-experts otherwise; "ep" forces the
     # dispatch path (a 1-device expert mesh is built if needed — how one
     # chip serves the real EP program); "dense" forces all-experts.
     moe_impl: str = "auto"                  # MOE_IMPL: auto | ep | dense
-    kv_page_size: int = 16                  # KV_PAGE_SIZE (paged attention)
-    # Ragged paged attention (ISSUE 19; ops/ragged_attention.py): ONE
-    # kernel over the block pool serves decode (q_len=1), spec verify
-    # (q_len=k+1), and admission suffix prefill (q_len=prompt-span), so
-    # a mixed prefill+decode+verify chunk is one program dispatch and
-    # the (bucket, kv_limit) pool-prefill program ladder collapses.
-    # "auto" = on in pool mode on TPU (CPU keeps the legacy ladder —
-    # interpret-mode Pallas has a different cost model); "off" = the
-    # legacy three-regime world for A/B. Falls back loudly (the
-    # attention_regime health field / decode_attention_regime gauge)
-    # when KV is int8-quantized or KV heads don't divide the model
-    # axis.
-    ragged_attention: str = "auto"          # RAGGED_ATTENTION: auto | on | off
     # --- block-paged KV pool + radix prefix sharing (ISSUE 10) ---
     # Replace per-slot dense KV (every request owning an S_alloc-row
     # region — the thing that capped the batch at bs=64 on 7B int8) with
@@ -279,8 +261,9 @@ class ServiceConfig:
     # fallback under a serving mesh — pool TP sharding is ROADMAP 4).
     kv_pool: bool = True                    # KV_POOL
     # Pool page (tokens per block). Must divide the 128-token kv-limit
-    # tile so every gather width is a whole page count; DECODE_ATTN=auto
-    # raises it to 64 on TPU (smaller pages are grid-overhead-bound).
+    # tile so every gather width is a whole page count; on a TPU the
+    # engine raises it to 64 (smaller pages are grid-overhead-bound;
+    # engine/regime.py::resolve_attention_regime).
     kv_pool_page: int = 16                  # KV_POOL_PAGE
     # Total pool blocks. 0 = auto: batch_size x pages-per-slot — the
     # dense HBM envelope, which sharing then oversubscribes. Sizing it
@@ -695,19 +678,6 @@ class ServiceConfig:
             raise ValueError(
                 f"INCIDENT_THRASH_MIN_BLOCKS must be >= 0 (0 disables), "
                 f"got {self.incident_thrash_min_blocks}")
-        # Ragged attention knob (ISSUE 19): a typo'd mode must refuse
-        # to boot, not silently serve the legacy ladder behind a knob
-        # that says otherwise. "on" additionally needs the pool (ragged
-        # is a kernel OVER the block pool — there is no dense variant).
-        if self.ragged_attention not in ("auto", "on", "off"):
-            raise ValueError(
-                f"RAGGED_ATTENTION must be auto|on|off, "
-                f"got {self.ragged_attention!r}")
-        if self.ragged_attention == "on" and not self.kv_pool:
-            raise ValueError(
-                "RAGGED_ATTENTION=on requires KV_POOL=true (the ragged "
-                "kernel reads per-slot block tables over the shared "
-                "pool — the dense ladder has no ragged variant)")
         # Grammar knobs (ISSUE 11): a typo'd profile or an impossible
         # mode combination must refuse to boot, not silently serve
         # unconstrained output behind a knob that says otherwise.
@@ -868,11 +838,7 @@ class ServiceConfig:
             top_k=_env_int("TOP_K", 0),
             top_p=_env_float("TOP_P", 1.0),
             attn_impl=(_env_str("ATTN_IMPL", "auto") or "auto").lower(),
-            decode_attn=(_env_str("DECODE_ATTN", "auto") or "auto").lower(),
             moe_impl=(_env_str("MOE_IMPL", "auto") or "auto").lower(),
-            kv_page_size=_env_int("KV_PAGE_SIZE", 16),
-            ragged_attention=(_env_str("RAGGED_ATTENTION", "auto")
-                              or "auto").lower(),
             kv_pool=_env_bool("KV_POOL", True),
             kv_pool_page=_env_int("KV_POOL_PAGE", 16),
             kv_pool_blocks=_env_int("KV_POOL_BLOCKS", 0),

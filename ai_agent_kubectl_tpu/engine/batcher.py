@@ -62,10 +62,11 @@ from ..ops.quant import (kv_broadcast_rows, kv_set_slots, kv_slot_update,
 from .containment import (CAUSE_SCHEDULER_DEATH, CAUSE_SCHEDULER_ERROR,
                           CAUSE_SLOT_HEALTH, PROBATION_CLEAN_CHUNKS,
                           REASON_HEALTH, REASON_ISOLATED, EngineSupervisor)
-from .jax_engine import JaxEngine
+from .jax_engine import JaxEngine, kv_bucket_ladder
 from .kv_pool import (BlockPool, HostBlockStore, alloc_with_evict,
                       map_prefix, pages_for)
 from .radix_cache import RadixCache
+from .regime import DENSE, RAGGED, resolve_attention_regime
 from .protocol import (HEALTH_GRAMMAR_DEAD, HEALTH_NONFINITE,
                        HEALTH_TOKEN_RANGE, EngineOverloaded,
                        EngineResult, EngineUnavailable, GenerationTimeout,
@@ -79,44 +80,6 @@ from .sampling import eos_mask, greedy_tokens, sample_tokens_seeded
 from .tokenizer import StreamDecoder
 
 logger = logging.getLogger(__name__)
-
-#: smallest KV page the paged decode kernel runs grid-overhead-free at
-#: (page 16 measured 47 ms/layer-call on the round-4 chip — the per-page
-#: program overhead dominates below 64).
-_AUTO_PAGED_MIN_PAGE = 64
-
-
-def resolve_decode_attn(decode_attn: str, cfg, *, kv_quant: str, pipe: int,
-                        page_size: int, backend: str) -> tuple:
-    """Resolve the DECODE_ATTN knob to a concrete impl + page size.
-
-    ``auto`` applies the measured heuristic (VERDICT r4 weak #6): paged
-    decode for GQA models — multiple query heads sharing each of several
-    KV heads, the geometry where the kernel's per-slot ragged reads beat
-    the dense KV ladder 2.08x end-to-end (Llama-3-8B bs=32,
-    tools/bench_paged_gqa.py) — with the page size raised to
-    ``_AUTO_PAGED_MIN_PAGE``; dense for MQA (Gemma-2B measured paged
-    1,599 vs dense 2,584 tok/s) and MHA (q_per_kv == 1, the same
-    no-sharing regime). The heuristic only fires on TPU: its numbers are
-    chip measurements, and interpret-mode paged on CPU has a completely
-    different cost model. Explicit ``dense``/``paged`` pass through
-    (later startup guards still apply); paged never composes with int8
-    KV (the kernel reads bf16) or a pipe mesh (dense stage bodies).
-
-    Returns ``(impl, page_size)``.
-    """
-    if decode_attn != "auto":
-        return decode_attn, page_size
-    from ..ops.paged_attention import paged_supported
-
-    page = max(page_size, _AUTO_PAGED_MIN_PAGE)
-    if (backend == "tpu"
-            and cfg.q_per_kv > 1 and cfg.n_kv_heads > 1
-            and not kv_quant and pipe <= 1
-            and paged_supported(page, cfg.head_dim, 1)):
-        return "paged", page
-    return "dense", page_size
-
 
 def grammar_legal_mask(g_ok: jnp.ndarray, gs: jnp.ndarray,
                        tc: jnp.ndarray) -> jnp.ndarray:
@@ -977,8 +940,7 @@ class BatchedJaxEngine(JaxEngine):
     name = "jax-batched"
 
     def __init__(self, *args, batch_size: int = 8, chunk_len: int = 16,
-                 kv_page_size: int = 16, decode_attn: str = "auto",
-                 ragged_attention: str = "auto",
+                 force_ragged: bool = False,
                  kv_pool: bool = True,
                  kv_pool_page: int = 16,
                  kv_pool_blocks: int = 0,
@@ -1027,14 +989,6 @@ class BatchedJaxEngine(JaxEngine):
             raise ValueError("chunk_len must be >= 1")
         if chunk_pipe_depth < 1:
             raise ValueError("chunk_pipe_depth must be >= 1")
-        if decode_attn not in ("auto", "dense", "paged"):
-            raise ValueError(
-                f"DECODE_ATTN must be auto|dense|paged, got {decode_attn!r}"
-            )
-        if ragged_attention not in ("auto", "on", "off"):
-            raise ValueError(
-                f"RAGGED_ATTENTION must be auto|on|off, "
-                f"got {ragged_attention!r}")
         self.batch_size = batch_size
         self.chunk_len = chunk_len
         # Speculative decode chunks kept in flight ahead of the consumer.
@@ -1058,8 +1012,6 @@ class BatchedJaxEngine(JaxEngine):
         # carries termination to the host in the SAME single fetch as the
         # tokens. False restores the host-side EOS scan (A/B + fallback).
         self.device_termination = device_termination
-        self.kv_page_size = max(1, kv_page_size)
-        self.decode_attn = decode_attn
         # Block-paged KV pool (the ISSUE 10 tentpole): one shared
         # [L, n_blocks, page, KV, hd] cache per layer + per-slot block
         # tables replaces per-slot dense S_alloc regions. ``kv_pool_page``
@@ -1093,21 +1045,18 @@ class BatchedJaxEngine(JaxEngine):
         self._radix: Optional[RadixCache] = None
         self._pool_prefill_fns: dict = {}   # (bucket, kv_limit) -> jitted
         self._pool_starved = 0        # slots truncated by pool exhaustion
-        # Ragged paged attention (ISSUE 19): ONE Pallas kernel serves
-        # decode (q_len=1), spec verify (q_len=k+1), and admission
-        # suffix prefill (q_len=prompt-span) over the block pool, so a
-        # mixed prefill+decode+verify chunk is one program dispatch and
-        # the (bucket, kv_limit) pool-prefill ladder collapses. "auto"
-        # = on in pool mode on TPU (CPU keeps the ladder — interpret-
-        # mode Pallas has a different cost model; tests force "on").
-        # "off" = the legacy three-regime world, kept for A/B.
-        self.ragged_attention = ragged_attention
-        self._use_ragged = False      # resolved at start (pool/TPU gate)
-        # ragged | paged | gather | dense — the regime actually serving
-        # decode attention, surfaced in sharding_health/kv_pool_health
-        # and the decode_attention_regime gauge so fallbacks (int8 KV,
-        # non-dividing tp) are observable instead of inferred.
-        self._attention_regime = "dense"
+        # Which attention serves (engine/regime.py) is worked out at
+        # start from the backend, the mesh, the KV dtype and the model's
+        # geometry; regime and reason ride sharding_health /
+        # kv_pool_health and the decode_attention_regime gauge, so a
+        # fallback (int8 KV, non-dividing tp) is observable instead of
+        # inferred. ``force_ragged`` is for tests: it runs the
+        # interpreted kernel where no TPU is, and no environment
+        # variable reaches it.
+        self.force_ragged = bool(force_ragged)
+        self._use_ragged = False
+        self._attention_regime = DENSE
+        self._attention_regime_reason = "not started"
         self._ragged_chunk_fns: dict = {}   # (adm width, spec) -> jitted
         # slot_idx -> staged admission (ids/start/ngen0/budget/seed/
         # temp/gs): the unmatched prompt suffix rides the NEXT chunk as
@@ -1362,9 +1311,6 @@ class BatchedJaxEngine(JaxEngine):
             batch_size=cfg.decode_batch_size,
             chunk_len=cfg.chunk_len,
             chunk_pipe_depth=cfg.chunk_pipe_depth,
-            kv_page_size=cfg.kv_page_size,
-            decode_attn=cfg.decode_attn,
-            ragged_attention=cfg.ragged_attention,
             kv_pool=cfg.kv_pool,
             kv_pool_page=cfg.kv_pool_page,
             kv_pool_blocks=cfg.kv_pool_blocks,
@@ -1429,25 +1375,36 @@ class BatchedJaxEngine(JaxEngine):
                 ">1 data/pipe/seq axis (MESH_SHAPE); use a tensor/"
                 "expert-parallel mesh or disable one of them")
         self._load()
-        # Block-paged KV pool (ISSUE 10 → ISSUE 14): the default
-        # serving layout, now composing with TP/EP serving meshes — the
-        # pool cache shards on the KV-head axis exactly like dense KV
-        # (parallel/sharding.py::pool_cache_specs) and block tables stay
-        # per-slot host numpy. Only meshes with a >1 data/pipe/seq axis
-        # still force the dense ladder: the pool's block axis is shared
-        # across slots (no slots-over-``data`` partition exists) and the
-        # pipe stage body has no table plumbing. That fallback is LOUD:
-        # kv_pool_mesh_fallback rides /health + /metrics.
-        mesh_pool_ok = self.mesh is None or all(
-            self.mesh.shape[a] == 1 for a in ("data", "pipe", "seq"))
-        self._use_pool = self.kv_pool and mesh_pool_ok
-        self._kv_pool_mesh_fallback = bool(self.kv_pool
-                                           and not mesh_pool_ok)
-        if self._kv_pool_mesh_fallback:
-            logger.warning(
-                "KV_POOL does not compose with data/pipe/seq mesh axes "
-                "(mesh %s); falling back to the dense KV ladder",
-                dict(self.mesh.shape))
+        # Which attention serves, decided once (engine/regime.py). The
+        # block pool is the default layout and composes with TP/EP
+        # meshes (the pool cache shards on the KV-head axis,
+        # parallel/sharding.py::pool_cache_specs; block tables stay
+        # per-slot host numpy); a >1 data/pipe/seq axis forces the dense
+        # ladder, and that fallback is LOUD: kv_pool_mesh_fallback rides
+        # /health + /metrics.
+        backend = jax.default_backend()
+        regime, self.kv_pool_page, reason = resolve_attention_regime(
+            self.model_cfg, backend=backend,
+            mesh_shape=(dict(self.mesh.shape) if self.mesh is not None
+                        else None),
+            kv_quant=self.kv_quant, kv_pool=self.kv_pool,
+            device_termination=self.device_termination,
+            pool_page=self.kv_pool_page, force_ragged=self.force_ragged)
+        self._attention_regime = regime
+        self._attention_regime_reason = reason
+        self._use_pool = regime != DENSE
+        self._use_ragged = regime == RAGGED
+        # The static ``attn_impl`` of every chunk-program forward.
+        self._decode_impl = RAGGED if self._use_ragged else "dense"
+        self._kv_pool_mesh_fallback = self.kv_pool and not self._use_pool
+        # A warning where the operator's pool is refused by the mesh or
+        # a TPU reads it through the gather; information otherwise (the
+        # CPU derives ``gather``).
+        fell_back = self.kv_pool and (
+            regime == DENSE or (backend == "tpu" and regime != RAGGED))
+        logger.log(logging.WARNING if fell_back else logging.INFO,
+                   "attention regime %s (pool page %d): %s",
+                   regime, self.kv_pool_page, reason)
         if self.grammar_decode and self._grammar is None:
             # Grammar runtime (ISSUE 11): compile the kubectl grammar
             # against THIS tokenizer. Host numpy truth; the stacked
@@ -1575,129 +1532,12 @@ class BatchedJaxEngine(JaxEngine):
         # chunk_len < k+1 — so the slack covers the larger of the two.)
         S_alloc = S + max(self.chunk_len, self._chunk_tokens)
 
-        # Decode attention impl: "paged" (ops/paged_attention.py) reads
-        # only each slot's live KV pages — true per-slot raggedness.
-        # auto now applies the measured heuristic (resolve_decode_attn):
-        # paged for GQA models (2.08x on Llama-3-8B bs=32,
-        # tools/bench_paged_gqa.py), dense for MQA/MHA (on Gemma-2B MQA
-        # end-to-end paged measured 1,599 vs dense-ladder 2,584 tok/s —
-        # per-program grid overhead × n_layers outweighs the bandwidth
-        # saved when attention is ~6% of step time). Pages below 64 are
-        # grid-overhead-bound (page 16 measured 47 ms/layer-call), so the
-        # auto-paged path raises the page size to 64. Composes with
-        # data/model mesh axes (the pallas call is shard_mapped in
-        # models/transformer.py); pipe meshes and int8 KV force dense.
-        decode_impl, auto_page = resolve_decode_attn(
-            self.decode_attn, cfg,
-            kv_quant=self.kv_quant,
-            pipe=(self.mesh.shape["pipe"] if self.mesh is not None else 1),
-            page_size=(self.kv_pool_page if self._use_pool
-                       else self.kv_page_size),
-            backend=jax.default_backend(),
-        )
         if self._use_pool:
-            # The pool page IS the paged-attention page: block-table
-            # indirection and the kernel's ragged reads share one
-            # granularity. auto's grid-overhead floor applies the same
-            # way (and 64 still divides the 128-token kv-limit tile).
-            if auto_page != self.kv_pool_page:
-                logger.info("DECODE_ATTN=auto raises KV_POOL_PAGE "
-                            "%d -> %d (smaller pages are "
-                            "grid-overhead-bound)",
-                            self.kv_pool_page, auto_page)
-                self.kv_pool_page = auto_page
-            if decode_impl == "paged" and self.kv_quant:
-                logger.warning(
-                    "DECODE_ATTN=paged does not read int8 KV; pool "
-                    "decode uses the gather path (dense attention)")
-                decode_impl = "dense"
-            if (decode_impl == "paged" and jax.default_backend() == "tpu"):
-                from ..ops.paged_attention import paged_supported
-
-                if not paged_supported(self.kv_pool_page, cfg.head_dim, 1):
-                    logger.warning(
-                        "paged pool decode unsupported for page=%d "
-                        "head_dim=%d; using the gather path",
-                        self.kv_pool_page, cfg.head_dim)
-                    decode_impl = "dense"
-            if (decode_impl == "paged" and self.mesh is not None
-                    and self.mesh.shape["model"] > 1
-                    and (cfg.n_kv_heads % self.mesh.shape["model"]
-                         or cfg.n_heads % self.mesh.shape["model"])):
-                # The shard_mapped pool kernel splits Q and KV heads
-                # together over ``model`` (whole KV groups per shard);
-                # geometries that don't divide serve the gather path.
-                logger.warning(
-                    "paged pool decode needs KV (%d) and H (%d) "
-                    "divisible by the model axis (%d); using the "
-                    "gather path", cfg.n_kv_heads, cfg.n_heads,
-                    self.mesh.shape["model"])
-                decode_impl = "dense"
-            # Ragged paged attention (ISSUE 19): ONE kernel serves
-            # decode, spec verify, AND admission suffix prefill, so the
-            # spec gate below never fires and the (bucket, kv_limit)
-            # prefill ladder collapses. auto = on under the same
-            # TPU-backend rule as resolve_decode_attn (interpret-mode
-            # Pallas on CPU has a different cost model; tests force
-            # "on"); every fallback is LOUD and lands in
-            # _attention_regime.
-            use_ragged = (self.ragged_attention == "on"
-                          or (self.ragged_attention == "auto"
-                              and jax.default_backend() == "tpu"))
-            if use_ragged and not self.device_termination:
-                logger.warning(
-                    "RAGGED_ATTENTION needs DEVICE_TERMINATION (staged "
-                    "admissions arm inside the chunk carry); serving "
-                    "the legacy ladder")
-                use_ragged = False
-            if use_ragged and self.kv_quant:
-                logger.warning(
-                    "RAGGED_ATTENTION: the ragged pool kernel reads "
-                    "bf16 KV; int8 KV serves the gather path")
-                use_ragged = False
-            if use_ragged and jax.default_backend() == "tpu":
-                from ..ops.ragged_attention import ragged_supported
-
-                if not ragged_supported(self.kv_pool_page,
-                                        cfg.head_dim, 1):
-                    logger.warning(
-                        "ragged pool attention unsupported for page=%d "
-                        "head_dim=%d; using the %s path",
-                        self.kv_pool_page, cfg.head_dim, decode_impl)
-                    use_ragged = False
-            if (use_ragged and self.mesh is not None
-                    and self.mesh.shape["model"] > 1
-                    and (cfg.n_kv_heads % self.mesh.shape["model"]
-                         or cfg.n_heads % self.mesh.shape["model"])):
-                logger.warning(
-                    "ragged pool attention needs KV (%d) and H (%d) "
-                    "divisible by the model axis (%d); using the "
-                    "gather path", cfg.n_kv_heads, cfg.n_heads,
-                    self.mesh.shape["model"])
-                use_ragged = False
-            self._use_ragged = use_ragged
-            if use_ragged:
-                decode_impl = "ragged"
-            if decode_impl == "paged" and self._use_spec:
-                # The verify step is a (k+1)-token window — the paged
-                # decode kernel is single-query. Keep the dense gather
-                # path (and its KV-bucket ladder, which the multi-token
-                # verify wants anyway).
-                logger.info("SPEC_DECODE: verify windows are multi-"
-                            "token; decode attention uses the gather "
-                            "path")
-                decode_impl = "dense"
-            self._decode_impl = decode_impl
-            self._attention_regime = (
-                "ragged" if decode_impl == "ragged"
-                else "paged" if decode_impl == "paged" else "gather")
             # Pool geometry: S_alloc page-rounds so every per-slot table
             # has a whole number of pages; kv buckets are 128-tiled, and
             # the page divides 128 by the constructor check, so every
             # gather width is a whole page count.
             S_alloc = -(-S_alloc // self.kv_pool_page) * self.kv_pool_page
-            from .jax_engine import kv_bucket_ladder
-
             self._pool_max_pages = S_alloc // self.kv_pool_page
             self._pool_n_blocks = (self.kv_pool_blocks
                                    or N * self._pool_max_pages)
@@ -1706,80 +1546,19 @@ class BatchedJaxEngine(JaxEngine):
                     f"KV_POOL_BLOCKS={self._pool_n_blocks} cannot hold "
                     f"even one full-length sequence "
                     f"({self._pool_max_pages} pages)")
-            if decode_impl in ("paged", "ragged"):
-                # The pallas pool kernels need no ladder (cost tracks
-                # live pages per slot inside one program) — but under
-                # "paged", PREFILL still gathers [1, kv_limit] views,
-                # so it keeps its own ladder: a 40-token prompt must
-                # not gather (and attend over) the full S_alloc span.
-                # Under "ragged" prefill reads through the SAME kernel
-                # and the prefill ladder collapses to one kv_limit too
-                # (_pool_prefill_span) — the draft model's dense
-                # prefill is the only remaining ladder client.
-                self._kv_buckets = (S_alloc,)
-            else:
-                self._kv_buckets = kv_bucket_ladder(S_alloc)
             self._pool_prefill_kv_buckets = kv_bucket_ladder(S_alloc)
-        elif not self._use_pool:
-            if auto_page != self.kv_page_size:
-                logger.info(
-                    "DECODE_ATTN=auto: GQA model (%d q heads per KV head) "
-                    "serves paged decode; KV_PAGE_SIZE %d -> %d (smaller "
-                    "pages are grid-overhead-bound)",
-                    cfg.q_per_kv, self.kv_page_size, auto_page)
-                self.kv_page_size = auto_page
-        if not self._use_pool:
-            if decode_impl == "paged" and self.kv_quant:
-                # The pallas paged kernel reads bf16 KV; the dense
-                # ladder's dequant fuses into its attention matmuls.
-                logger.warning("DECODE_ATTN=paged does not read int8 KV; "
-                               "falling back to the dense KV ladder")
-                decode_impl = "dense"
-            if (decode_impl == "paged" and self.mesh is not None
-                    and self.mesh.shape["pipe"] > 1):
-                # The pipelined layer path always runs dense attention
-                # (the pallas call doesn't compose with the pipe stage
-                # body); keep the KV ladder rather than the paged
-                # single-bucket setup.
-                logger.warning("paged decode attention does not compose "
-                               "with a pipe mesh axis; falling back to "
-                               "dense")
-                decode_impl = "dense"
-            if decode_impl == "paged" and jax.default_backend() == "tpu":
-                from ..ops.paged_attention import paged_supported
-
-                if not paged_supported(self.kv_page_size, cfg.head_dim, 1):
-                    logger.warning(
-                        "paged decode unsupported for page=%d head_dim=%d "
-                        "on the compiled kernel; falling back to dense",
-                        self.kv_page_size, cfg.head_dim,
-                    )
-                    decode_impl = "dense"
-            self._decode_impl = decode_impl
-            self._attention_regime = (
-                "paged" if decode_impl == "paged" else "dense")
-            if self.ragged_attention == "on":
-                logger.warning(
-                    "RAGGED_ATTENTION=on needs the KV pool; the dense "
-                    "ladder is serving instead")
-
-            # Decode-attention cost grows with the KV span it reads.
-            # Rather than attending over the full S_alloc cache every
-            # token (round-1: cost ∝ max_seq even for 40-token
-            # sequences), the chunk program is compiled per KV *bucket*
-            # — a pow2 ladder topped by S_alloc — and dispatch picks the
-            # smallest bucket covering every live position. All buckets
-            # are warmed at startup, so bucket growth never compiles
-            # mid-serving. Paged decode needs no ladder: its cost tracks
-            # each slot's live pages inside one program.
-            from .jax_engine import kv_bucket_ladder
-
-            if decode_impl == "paged":
-                S_alloc = -(-S_alloc // self.kv_page_size) \
-                    * self.kv_page_size
-                self._kv_buckets = (S_alloc,)
-            else:
-                self._kv_buckets = kv_bucket_ladder(S_alloc)
+        # Attention cost under ``gather`` and ``dense`` grows with the
+        # KV span read, so the chunk program is compiled per KV *bucket*
+        # — a pow2 ladder topped by S_alloc — and dispatch picks the
+        # smallest bucket covering every live position. All buckets are
+        # warmed at startup, so bucket growth never compiles
+        # mid-serving. The ragged kernel needs no ladder (cost tracks
+        # live pages per slot inside one program), and prefill reads
+        # through the same kernel, so its ladder collapses to one
+        # kv_limit too (_pool_prefill_span): the draft model's dense
+        # prefill is the only remaining ladder client.
+        self._kv_buckets = ((S_alloc,) if self._use_ragged
+                            else kv_bucket_ladder(S_alloc))
 
         eos_ids = tuple(sorted(set(cfg.eos_ids)))
 
@@ -1802,7 +1581,6 @@ class BatchedJaxEngine(JaxEngine):
                                    moe_impl=self.moe_impl,
                                    token_mask=live[:, None],
                                    write_mask=live,
-                                   page_size=self.kv_pool_page,
                                    block_tables=tables)
 
                 return step
@@ -1814,8 +1592,7 @@ class BatchedJaxEngine(JaxEngine):
                                mesh=self.mesh,
                                moe_impl=self.moe_impl,
                                token_mask=live[:, None],
-                               write_mask=live,
-                               page_size=self.kv_page_size)
+                               write_mask=live)
 
             return step
 
@@ -1863,9 +1640,6 @@ class BatchedJaxEngine(JaxEngine):
                                         mesh=self.mesh,
                                         moe_impl=self.moe_impl,
                                         token_mask=force[:, None],
-                                        page_size=(self.kv_pool_page
-                                                   if tables is not None
-                                                   else self.kv_page_size),
                                         block_tables=tables)
                 step_logits = logits[:, 0]
                 step_logits = jnp.where(corrupt[:, None],
@@ -1941,7 +1715,6 @@ class BatchedJaxEngine(JaxEngine):
                                moe_impl=self.moe_impl,
                                token_mask=wmask,
                                write_mask=wmask,
-                               page_size=self.kv_pool_page,
                                block_tables=tables,
                                q_lens=q_lens,
                                logits_at=jnp.maximum(q_lens, 1) - 1)
@@ -1985,7 +1758,7 @@ class BatchedJaxEngine(JaxEngine):
             # SETS compile against the mesh at warmup, so the flip is
             # recompile-free there too). The draft runs a dense
             # per-slot cache at the SAME kv_limit (positions are
-            # shared) and never the paged kernel; it DOES ride the
+            # shared) and never a pool kernel; it DOES ride the
             # serving mesh — its forwards and residual path shard
             # through the same f≈1 policy as the target's
             # (parallel/sharding.py), with the KV-head axis replicating
@@ -2399,9 +2172,8 @@ class BatchedJaxEngine(JaxEngine):
     def _pool_kv_limit(self, needed: int) -> int:
         """Smallest PREFILL KV bucket covering ``needed`` positions
         (every bucket is a whole page count: 128-tiled ladder, page
-        divides 128). Prefill keeps its own ladder even when paged
-        decode collapses the chunk buckets to (S_alloc,) — the gather
-        width must track the prompt, not the cache."""
+        divides 128): a gathered prefill's width must track the
+        prompt, not the cache."""
         needed = min(needed, self._S_alloc)
         return next(b for b in self._pool_prefill_kv_buckets
                     if b >= needed)
@@ -2439,7 +2211,6 @@ class BatchedJaxEngine(JaxEngine):
                                    token_mask=mask,
                                    write_mask=mask > 0,
                                    logits_at=jnp.maximum(q_lens - 1, 0),
-                                   page_size=self.kv_pool_page,
                                    block_tables=tables,
                                    q_lens=q_lens)
             else:
@@ -2453,7 +2224,6 @@ class BatchedJaxEngine(JaxEngine):
                                    kv_limit=kv_limit, attn_impl=impl,
                                    mesh=self.mesh, moe_impl=self.moe_impl,
                                    token_mask=mask, logits_at=last,
-                                   page_size=self.kv_pool_page,
                                    block_tables=tables)
 
             fn = jax.jit(pool_prefill, donate_argnums=(3,))
@@ -3043,10 +2813,12 @@ class BatchedJaxEngine(JaxEngine):
             # off the shard-local fast path; fleets OR this flag).
             "draft_sharded": bool(self._draft_sharded),
             "draft_kv_fallback": bool(self._draft_kv_fallback),
-            # ISSUE 19: the regime actually serving decode attention
-            # (ragged | paged | gather | dense) — int8 KV, non-dividing
-            # head counts, and mesh gates all fall back LOUDLY here.
+            # The regime actually serving attention (ragged | gather |
+            # dense) and the condition that selected it — int8 KV,
+            # non-dividing head counts and mesh gates fall back LOUDLY
+            # here.
             "attention_regime": self._attention_regime,
+            "attention_regime_reason": self._attention_regime_reason,
         }
 
     def kv_pool_health(self) -> Optional[dict]:
@@ -3062,6 +2834,7 @@ class BatchedJaxEngine(JaxEngine):
         # Single-chip deployments read the regime here (sharding_health
         # is None without a mesh).
         body["attention_regime"] = self._attention_regime
+        body["attention_regime_reason"] = self._attention_regime_reason
         body["radix"] = (self._radix.stats() if self._radix is not None
                          else None)
         if self._host_store is not None:
@@ -3644,7 +3417,7 @@ class BatchedJaxEngine(JaxEngine):
     def stats(self) -> dict:
         """Live scheduler state for the /metrics gauges (scraped, not
         pushed): slot occupancy, admission queue depth, and page-granular
-        KV-pool accounting (page size = KV_PAGE_SIZE)."""
+        KV accounting (page size = KV_POOL_PAGE in either layout)."""
         slots = list(getattr(self, "_slots", None) or [])
         if self._use_pool and self._pool is not None:
             # Pool truth: pages = pool blocks, used = everything not on
@@ -3652,7 +3425,7 @@ class BatchedJaxEngine(JaxEngine):
             used = self._pool.n_blocks - self._pool.free_count
             pages_total = self._pool.n_blocks
         else:
-            page = self.kv_page_size
+            page = self.kv_pool_page
             pages_per_slot = -(-self.max_seq_len // page)
             # pos can run into the S_alloc slack on a final chunk; clamp
             # so used never exceeds total (utilization ratios stay <= 1).

@@ -42,6 +42,7 @@ from .fallback import extract_query, rule_command  # rules promoted there
 from .kv_pool import (BlockPool, HostBlockStore, PoolExhausted,
                       alloc_with_evict, map_prefix, pages_for)
 from .radix_cache import RadixCache
+from .regime import RAGGED, resolve_attention_regime
 from .protocol import (HEALTH_GRAMMAR_DEAD, HEALTH_NONFINITE,
                        EngineOverloaded, EngineResult, EngineUnavailable,
                        GenerationTimeout, RequestExport,
@@ -267,7 +268,7 @@ class FakeChunkedEngine:
                  host_kv_blocks: int = 0,
                  slo_session_ttft_ms: float = 0.0,
                  session_token_budget: int = 0,
-                 ragged_attention: str = "auto",
+                 force_ragged: bool = False,
                  grammar_decode: bool = False,
                  grammar_profile: str = "default",
                  grammar_forced_run_min: int = 4,
@@ -405,23 +406,21 @@ class FakeChunkedEngine:
         self._pool_starved = 0
         if self.kv_pool:
             self._pool_reset()
-        # Ragged paged attention mirror (ISSUE 19): the fake has no
-        # kernels, so this mirrors the SCHEDULER policy only — "on"
-        # defers the admission's first sampled token to the next chunk
-        # (the batcher's staged-admission prologue), so the deferral
+        # Attention regime mirror: the fake has no kernels, so the
+        # regime (engine/regime.py, the function the batcher calls)
+        # selects SCHEDULER policy only — ``ragged`` defers the
+        # admission's first sampled token to the next chunk (the
+        # batcher's staged-admission prologue), so the deferral
         # bookkeeping (TTFT catch at consume, budget/EOS-at-first edges,
-        # grammar first-pick in-chunk) runs in tier-1. "auto" resolves
-        # off here — the real auto gate is TPU-only.
-        if ragged_attention not in ("auto", "on", "off"):
-            raise ValueError(
-                f"RAGGED_ATTENTION must be auto|on|off, "
-                f"got {ragged_attention!r}")
-        self.ragged_attention = ragged_attention
-        self._use_ragged = (ragged_attention == "on" and self.kv_pool
-                            and self.device_termination)
-        self._attention_regime = ("ragged" if self._use_ragged
-                                  else "paged" if self.kv_pool
-                                  else "dense")
+        # grammar first-pick in-chunk) runs in tier-1. No backend here
+        # is a TPU, so only ``force_ragged`` reaches it.
+        (self._attention_regime, _,
+         self._attention_regime_reason) = resolve_attention_regime(
+            None, backend="fake", mesh_shape=None, kv_quant="",
+            kv_pool=self.kv_pool,
+            device_termination=self.device_termination,
+            pool_page=self.kv_pool_page, force_ragged=force_ragged)
+        self._use_ragged = self._attention_regime == RAGGED
         # Admission width of staged (deferred-first-token) admissions
         # since the last dispatch — keys that dispatch's sentinel
         # sample as a ragged prefill phase (mirror of the batcher).
@@ -657,9 +656,10 @@ class FakeChunkedEngine:
                          else None)
         if self._host_store is not None:
             body["host_tier"] = self._host_store.stats()
-        # ISSUE 19 surface parity: the regime actually serving decode
-        # attention (policy mirror — the fake has no kernels).
+        # Surface parity with the batcher (policy mirror — the fake has
+        # no kernels).
         body["attention_regime"] = self._attention_regime
+        body["attention_regime_reason"] = self._attention_regime_reason
         return body
 
     # ------------------------------- grammar-constrained decode (ISSUE 11)

@@ -134,7 +134,7 @@ def init_params(key: jax.Array, cfg: ModelConfig, dtype=jnp.bfloat16) -> Params:
 # write_mask — drop, exactly like the dense path's OOB trick), reads
 # gather each slot's pages back into the dense [B, kv_limit, KV, hd]
 # view the existing attention backends consume. The TPU fast path skips
-# the gather entirely (ops/paged_attention.py block-table kernel).
+# the gather entirely (ops/ragged_attention.py block-table kernel).
 
 
 def _pool_flat_pos(tables, positions, page: int, n_blocks: int,
@@ -303,8 +303,7 @@ def _moe_mlp(cfg: ModelConfig, lp: Params, x: jnp.ndarray,
     return dense_moe(cfg, lp, x, mesh)
 
 
-def _layer(cfg: ModelConfig, attn_impl: str, mesh, page_size: int,
-           moe_impl: str,
+def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
            h: jnp.ndarray, lp: Params,
            layer_k: jnp.ndarray, layer_v: jnp.ndarray,
            positions: jnp.ndarray, kv_limit: int,
@@ -407,29 +406,6 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, page_size: int,
                     attn = ragged_attention_pool(
                         q, layer_k, layer_v, ql, positions[:, 0],
                         block_tables, layer, page_size=page)
-            elif attn_impl == "paged" and S == 1 and not is_q:
-                # TPU fast path: the block-table pallas kernel reads only
-                # each slot's live pages straight from the pool — no
-                # gathered copy ever materializes. Under a >1 model axis
-                # the kernel runs shard_mapped with Q and KV heads split
-                # together (the pool shards on the KV-head axis, so each
-                # shard holds whole KV groups — ISSUE 14); XLA can't
-                # auto-partition a pallas_call.
-                if mesh is not None and mesh.shape["model"] > 1:
-                    from ..ops.paged_attention import \
-                        paged_decode_attention_pool_sharded
-
-                    attn = paged_decode_attention_pool_sharded(
-                        q[:, 0], layer_k, layer_v, positions[:, 0],
-                        block_tables, mesh, layer,
-                        page_size=page)[:, None]
-                else:
-                    from ..ops.paged_attention import \
-                        paged_decode_attention_pool
-
-                    attn = paged_decode_attention_pool(
-                        q[:, 0], layer_k, layer_v, positions[:, 0],
-                        block_tables, layer, page_size=page)[:, None]
             elif is_q:
                 attn = dense_attention_quant(
                     q,
@@ -484,10 +460,6 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, page_size: int,
                               s=layer_k.s.at[rows].set(qk.s))
             layer_v = QuantKV(q=layer_v.q.at[rows].set(qv.q),
                               s=layer_v.s.at[rows].set(qv.s))
-        if attn_impl == "paged" and S == 1:
-            raise NotImplementedError(
-                "paged decode attention does not read int8 KV; the engine "
-                "resolves KV_QUANT=int8 to the dense KV ladder")
         with jax.named_scope("attention"):
             if attn_impl == "ring" and S > 1:
                 # Ring prefill attends over the chunk's own fresh bf16 k/v
@@ -525,67 +497,7 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, page_size: int,
     kv_pos = jnp.arange(kv_limit)[None, None, :]
     mask = kv_pos <= positions[:, :, None]
 
-    if attn_impl == "paged" and S == 1:
-        # Ragged decode: each slot reads only its live KV pages
-        # (ops/paged_attention.py); kv_limit is irrelevant — cost tracks
-        # positions per slot, not the bucket.
-        from ..ops.paged_attention import (paged_decode_attention,
-                                           stacked_kv)
-
-        def _paged(q1, k_all, v_all, pos1, lyr):
-            return paged_decode_attention(q1, k_all, v_all, pos1, lyr[0],
-                                          page_size=page_size)
-
-        # One form for the kernel and its shard_map: a layer stack (the
-        # carried cache, or the stage body's single layer) and its index.
-        k_st, v_st, lyr = stacked_kv(layer_k, layer_v, layer)
-
-        if mesh is not None and (mesh.shape["data"] > 1
-                                 or mesh.shape["model"] > 1):
-            # XLA can't auto-partition a pallas_call — shard_map it
-            # explicitly: slots over ``data``, heads over ``model``
-            # (VERDICT r3 weak #6). Three TP layouts, mirroring the dense
-            # path's sanitize_spec policy:
-            #   KV % tp == 0   → shard Q and KV heads together (grouping
-            #                    stays aligned: each shard holds whole KV
-            #                    groups, H/tp = G·KV/tp)
-            #   KV == 1 (MQA)  → shard Q heads, the single KV head
-            #                    replicated — every Q head maps to it
-            #   else           → heads replicated (data-only). A replicated
-            #                    KV>1 cache with sharded Q would need a
-            #                    per-shard head offset the kernel doesn't
-            #                    have (it recomputes G from local shapes),
-            #                    silently mis-mapping Q→KV groups.
-            import jax.sharding as jsh
-
-            P_ = jsh.PartitionSpec
-            dp, tp = mesh.shape["data"], mesh.shape["model"]
-            d_ax = "data" if B % dp == 0 else None
-            if KV % tp == 0:
-                q_ax, kv_ax = "model", "model"
-            elif KV == 1 and H % tp == 0:
-                q_ax, kv_ax = "model", None
-            else:
-                q_ax, kv_ax = None, None
-            # The layer axis stays whole on every shard.
-            kv_spec = P_(None, d_ax, None, kv_ax, None)
-            with jax.named_scope("attention"):
-                attn = jax.shard_map(
-                    _paged, mesh=mesh,
-                    in_specs=(P_(d_ax, q_ax, None), kv_spec, kv_spec,
-                              P_(d_ax), P_(None)),
-                    out_specs=P_(d_ax, q_ax, None),
-                    axis_names={"data", "model"},
-                    # pallas_call can't express per-axis varying metadata
-                    # for the VMA checker; the specs above are the
-                    # contract.
-                    check_vma=False,
-                )(q[:, 0], k_st, v_st, positions[:, 0], lyr)[:, None]
-        else:
-            with jax.named_scope("attention"):
-                attn = _paged(q[:, 0], k_st, v_st, positions[:, 0],
-                              lyr)[:, None]
-    elif attn_impl == "ring" and S > 1:
+    if attn_impl == "ring" and S > 1:
         # Sequence-parallel self-attention over the chunk itself (no prior
         # cache context) — the from-scratch long-prefill path. K/V blocks
         # rotate over the ``seq`` mesh axis via ppermute; the cache write
@@ -628,7 +540,9 @@ def forward(
                                       # an "expert" axis >1 is present
     token_mask: Optional[jnp.ndarray] = None,  # [B, S]; 0 marks padding /
                                       # dead-slot tokens (MoE capacity)
-    page_size: int = 128,             # static: KV page for attn_impl="paged"
+    page_size: Optional[int] = None,  # unused (the pool page is the cache
+                                      # leaf's own shape); kept for callers
+                                      # that name it (benchmark/refcheck.py)
     moe_impl: str = "auto",           # static: MoE dispatch policy
                                       # (auto | ep | dense; see _moe_mlp)
     logits_at: Optional[jnp.ndarray] = None,   # [B] int32: emit logits only
@@ -699,7 +613,7 @@ def forward(
         # sharded over ``pipe`` on the layer axis, parallel/sharding.py)
         # runs as a GPipe shard_map instead of the lax.scan — stages relay
         # hidden states over ICI via ppermute, TP stays automatic inside
-        # each stage (parallel/pipeline.py). The Pallas flash/paged kernels
+        # each stage (parallel/pipeline.py). The Pallas flash/ragged kernels
         # and ring attention don't compose with the stage body, so the
         # pipelined path always runs dense attention; MoE layers likewise
         # evaluate densely (no EP all-to-all inside a stage — the engine
@@ -714,7 +628,7 @@ def forward(
             kv_limit=kv_limit, attn_impl="dense",
         )
     else:
-        step = partial(_layer, cfg, attn_impl, mesh, page_size, moe_impl)
+        step = partial(_layer, cfg, attn_impl, mesh, moe_impl)
 
         # The cache rides the CARRY, whole (ISSUE 25): as the scan's xs
         # and ys it was sliced out a layer at a time and written back

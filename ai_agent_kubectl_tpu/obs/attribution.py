@@ -76,7 +76,7 @@ _SCOPE_RULES: Tuple[Tuple[str, Tuple[str, ...]], ...] = (
     ("all_reduce", ("all_reduce",)),
     ("lm_head_sampling", ("lm_head", "sampling")),
     ("kv_write_splice", ("kv_write", "kv_splice", "splice")),
-    ("attention", ("attention", "flash", "paged", "ring")),
+    ("attention", ("attention", "flash", "ragged", "ring")),
     ("norm_rope_residual", ("attn_norm", "mlp_norm", "final_norm",
                             "rms_norm", "rope")),
     ("weight_gemms", ("qkv_proj", "o_proj", "mlp", "embed", "moe",

@@ -6,7 +6,8 @@ Layout:
 - ``attention``       — dense reference attention (GQA, causal, cached) +
                         backend dispatch
 - ``flash_attention`` — Pallas flash attention (prefill)
-- ``paged_attention`` — Pallas paged-KV ragged decode attention
+- ``ragged_attention`` — Pallas ragged attention over the block pool
+                        (decode, spec verify and prefill in one kernel)
 - ``ring_attention``  — sequence-parallel ring attention over a mesh axis
 - ``quant``           — int8 quantized matmul kernels
 """

@@ -5,9 +5,9 @@ ONE kernel for every attention shape the serving loop runs over the
 block pool: per-slot QUERY length ``q_lens[n]`` is 1 for a decode step,
 k+1 for a speculative verify window, and a prompt-span for (suffix)
 prefill — so a mixed chunk (fresh admissions + decoding slots + spec
-verify) is a single program dispatch instead of three compiled worlds
-(the single-query paged kernel, the ``(bucket, kv_limit)`` dense
-prefill ladder, and the dense gather fallback).
+verify) is a single program dispatch instead of separately compiled worlds
+(the ``(bucket, kv_limit)`` dense prefill ladder and the dense gather
+fallback).
 
 Shape contract:
 
@@ -27,8 +27,7 @@ Shape contract:
 - ``block_tables`` [N, max_pages] int32 — pool block per sequence page;
   entries >= n_blocks are the unmapped-page sentinel.
 
-Same TPU-first design as ops/paged_attention.py (this kernel is that
-one generalized from W=1): grid ``(slot, query tile, page)`` with
+TPU-first design: grid ``(slot, query tile, page)`` with
 positions + query lengths + tables scalar-prefetched, dead pages clamped
 to the tile's LAST LIVE page in the BlockSpec index map (repeat block
 indices elide the HBM→VMEM fetch, ``pl.when`` elides the compute),
@@ -56,8 +55,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .paged_attention import stacked_kv
-
 try:
     from jax.experimental.pallas import tpu as pltpu
 except ImportError:  # pragma: no cover
@@ -66,9 +63,25 @@ except ImportError:  # pragma: no cover
 
 def ragged_supported(page_size: int, head_dim: int,
                      n_pages: int) -> bool:
-    """Compiled-kernel constraints — same lane/sublane tiling rules as
-    the single-query paged kernel (ops/paged_attention.py)."""
+    """Compiled-kernel constraints: lanes want a 128-multiple head dim
+    and a sublane-tileable page."""
     return head_dim % 128 == 0 and page_size >= 8 and n_pages >= 1
+
+
+def stacked_kv(k, v, layer):
+    """(k, v, layer [1] int32) with K/V as a layer stack: a stacked cache
+    comes with its traced ``layer`` index, a bare layer becomes a
+    one-layer stack (a free reshape), so the kernel has one form and
+    its index map picks the layer — no ``cache[layer]`` copy in front of
+    the call (ISSUE 25)."""
+    if (k.ndim == 5) != (layer is not None):
+        raise ValueError(
+            "a stacked [L, ...] cache takes a layer index and a single "
+            f"layer takes none; got k.ndim={k.ndim}, "
+            f"layer={'set' if layer is not None else None}")
+    if layer is None:
+        k, v, layer = k[None], v[None], 0
+    return k, v, jnp.asarray(layer, jnp.int32).reshape(1)
 
 
 #: Query elements (columns x heads x head_dim) one grid cell holds: 64
@@ -104,8 +117,7 @@ def _ragged_pool_kernel(pos_ref, qlen_ref, tbl_ref, lyr_ref, q_ref, k_ref,
     ``tq`` query columns at a time. Rows are laid out [KV, tq*G] (row r
     is tile column ``r // G`` of KV group ``r % G``'s block) so one
     KV-batched ``dot_general`` serves every query column and head of
-    the block — the same working-set shape as the W=1 paged kernel,
-    widened."""
+    the block."""
     del tbl_ref, lyr_ref              # consumed by the index map
     n = pl.program_id(0)
     q0 = pl.program_id(1) * tq        # window column of tile row 0
@@ -273,8 +285,7 @@ def ragged_attention_pool_sharded(
     *,
     page_size: int = 128,
 ) -> jnp.ndarray:
-    """Mesh-aware ragged kernel dispatch, mirroring
-    ``paged_decode_attention_pool_sharded`` (ISSUE 14): XLA can't
+    """Mesh-aware ragged kernel dispatch (ISSUE 14): XLA can't
     auto-partition a ``pallas_call``, so under a >1 ``model`` axis the
     kernel runs shard_mapped with Q and KV heads split together over
     ``model`` — the pool shards on the KV-head axis
@@ -314,7 +325,6 @@ def ragged_attention_pool_sharded(
         out_specs=P_(None, None, "model", None),
         axis_names=set(mesh.axis_names),
         # pallas_call can't express per-axis varying metadata for the
-        # VMA checker; the specs above are the contract (same rule as
-        # the paged kernel's shard_map).
+        # VMA checker; the specs above are the contract.
         check_vma=False,
     )(q, k, v, q_lens, positions, block_tables, lyr)

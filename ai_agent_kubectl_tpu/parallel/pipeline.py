@@ -74,7 +74,7 @@ def _pipe_shard(lp, h_mb, pos_mb, k, v, *, cfg: ModelConfig, axis: str,
             # moe_impl="dense": the EP all-to-all can't nest under this
             # shard_map; the engine raises at startup if the operator
             # forced MOE_IMPL=ep onto a pipe mesh.
-            h, k_mb, v_mb = _layer(cfg, attn_impl, None, 128, "dense",
+            h, k_mb, v_mb = _layer(cfg, attn_impl, None, "dense",
                                    h, lp_l, k_mb, v_mb, positions,
                                    kv_limit, batch_idx, None)
             k_l = tmap(
@@ -147,7 +147,7 @@ def pipeline_layers(
 
     Only the ``pipe`` axis is manual here; ``data``/``model``/``expert``
     shardings on the inputs flow through automatically (PP × TP works; the
-    Pallas flash/paged kernels and ring attention do NOT compose with the
+    Pallas flash/ragged kernels and ring attention do NOT compose with the
     stage body — callers pass attn_impl="dense").
     """
     n_stages = mesh.shape[axis]

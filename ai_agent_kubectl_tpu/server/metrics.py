@@ -25,6 +25,8 @@ from prometheus_client import (
 )
 from prometheus_client.exposition import CONTENT_TYPE_LATEST
 
+from ..engine.regime import REGIMES
+
 _TTFT_BUCKETS = (0.005, 0.01, 0.025, 0.05, 0.1, 0.15, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0)
 _LATENCY_BUCKETS = (0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0)
 # Phase spans skew small (sub-ms safety checks next to multi-second
@@ -273,17 +275,16 @@ class Metrics:
             "fallback) — a silent gather must be visible",
             registry=r,
         )
-        # Ragged paged attention (ISSUE 19): which regime actually
-        # serves decode attention — enum-style gauge (1 on the active
-        # label) so a fallback from ragged (int8 KV, non-dividing tp,
-        # CPU auto-off) is a dashboard fact, not an inference.
+        # Which attention regime serves (engine/regime.py) — enum-style
+        # gauge (1 on the active label) so a fallback from ragged (int8
+        # KV, non-dividing tp, no TPU) is a dashboard fact, not an
+        # inference.
         self.decode_attention_regime = Gauge(
             "decode_attention_regime",
             "1 for the attention regime actually serving decode "
             "(ragged = one kernel for prefill/decode/spec-verify over "
-            "the block pool; paged = single-query paged kernel; "
-            "gather = dense gather over pool pages; dense = per-slot "
-            "dense KV ladder)",
+            "the block pool; gather = dense gather over pool pages; "
+            "dense = per-slot dense KV ladder)",
             ["regime"],
             registry=r,
         )
@@ -777,7 +778,7 @@ class Metrics:
     def _set_attention_regime(self, active) -> None:
         if not active:
             return
-        for regime in ("ragged", "paged", "gather", "dense"):
+        for regime in REGIMES:
             self.decode_attention_regime.labels(regime=regime).set(
                 1 if regime == active else 0)
 

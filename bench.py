@@ -841,8 +841,8 @@ async def phase_paged7b(batch_size: int, max_seq: int, kv_quant: str,
 
     cfg7 = get_config("gemma-7b-it")
     tok7, _ = make_tokenizer(cfg7)
-    # Page pinned at 64 (the grid-overhead floor DECODE_ATTN=auto would
-    # pick anyway) so the envelope block count is deterministic.
+    # Page pinned at 64 (the floor a TPU engine raises it to anyway) so
+    # the envelope block count is deterministic.
     page = 64
     pool_blocks = 0
     if kv_pool and pool_envelope_bs:
@@ -1020,16 +1020,13 @@ async def phase_agent7b(batch_size: int, max_seq: int, kv_quant: str,
 
 
 async def phase_ragged7b(batch_size: int, max_seq: int, kv_quant: str,
-                         ragged: bool, spec_k: int = 4,
-                         chunk_len: int = 16) -> dict:
+                         spec_k: int = 4, chunk_len: int = 16) -> dict:
     """One rung of the ISSUE 19 ragged-kernel sweep: a MIXED workload —
     staggered admissions arriving while earlier requests decode, spec
     verify riding the same chunks — served by the single ragged paged
-    kernel vs the legacy (bucket, kv_limit) program ladder. The
-    artifact carries tok/s AND the compiled-program count (chunk +
-    prefill + ragged sets): the perf claim is one kernel serving
-    prefill, decode, and verify from one program set, so the count
-    must drop alongside the throughput story. The workload staggers
+    kernel. The artifact carries tok/s AND the compiled-program count
+    (chunk + prefill + ragged sets): one kernel serves prefill, decode
+    and verify from one program set. The workload staggers
     three admission waves (full bs, then bs/2 twice, offset by a
     quarter of the decode span) so ragged rungs actually exercise
     mixed prefill+decode+verify chunks rather than one clean burst."""
@@ -1044,8 +1041,7 @@ async def phase_ragged7b(batch_size: int, max_seq: int, kv_quant: str,
 
     cfg7 = get_config("gemma-7b-it")
     tok7, _ = make_tokenizer(cfg7)
-    log(f"bench: ragged7b rung bs={batch_size} "
-        f"ragged={'on' if ragged else 'off'} k={spec_k}")
+    log(f"bench: ragged7b rung bs={batch_size} k={spec_k}")
     eng = BatchedJaxEngine(
         cfg7,
         tokenizer=tok7,
@@ -1057,7 +1053,6 @@ async def phase_ragged7b(batch_size: int, max_seq: int, kv_quant: str,
         batch_size=batch_size,
         chunk_len=chunk_len,
         kv_pool=True,
-        ragged_attention="on" if ragged else "off",
         model_path=os.environ.get("MODEL_PATH") or None,
         spec_decode=True,
         spec_draft_k=spec_k,
@@ -1103,7 +1098,6 @@ async def phase_ragged7b(batch_size: int, max_seq: int, kv_quant: str,
         "batch_size": batch_size,
         "max_seq_len": max_seq,
         "kv_quant": kv_quant,
-        "ragged": ragged,
         "spec_k": spec_k,
         "attention_regime": pool_stats.get("attention_regime"),
         "compiled_programs": programs,
@@ -1275,8 +1269,8 @@ def _run_phase(args: list, timeout: float, script: str | None = None,
                env: dict | None = None) -> dict | None:
     """Run one phase subprocess; parse its final stdout line as JSON.
 
-    Also used by tools/bench_paged_gqa.py (pass ``script``) so there is
-    one hardened spawn-and-parse path. Failures return an EXPLICIT
+    ``script`` runs another file through the same hardened
+    spawn-and-parse path. Failures return an EXPLICIT
     ``{"status": "timeout" | "error"}`` entry instead of None, and the
     orchestrator records those entries into the artifact — the perf
     gate (tools/perf_gate.py) must be able to tell "this phase got
@@ -1536,9 +1530,9 @@ def orchestrate() -> dict:
 
         # Ragged-kernel sweep (ISSUE 19): the mixed workload (staggered
         # admissions + spec verify in the same chunks) under the single
-        # ragged paged kernel vs the legacy program ladder, at bs 48 and
-        # 192 (the pool geometry the kernel is supposed to carry).
-        # Keyed per (bs, mode) like tp_spec_sweep so the perf gate's
+        # ragged paged kernel, at bs 48 and 192 (the pool geometry the
+        # kernel is supposed to carry).
+        # Keyed per bs like tp_spec_sweep so the perf gate's
         # dict walk reaches each rung's tok/s and program count; a
         # failed rung rides its key as an explicit {"status": ...}.
         ragged_sweep: dict = {}
@@ -1546,25 +1540,20 @@ def orchestrate() -> dict:
                        "attention_regime", "acceptance_ratio",
                        "completion_tokens", "step_time")
         for bs in (48, 192):
-            for mode in ("ragged", "ladder"):
-                rr = _run_phase(
-                    ["--phase", "ragged7b", "--bs", str(bs),
-                     "--max-seq", str(extra7["max_seq_len"]),
-                     "--kv-quant", extra7["kv_quant"],
-                     "--ragged", "on" if mode == "ragged" else "off"],
-                    timeout=1800)
-                if isinstance(rr, dict) and "skipped" in rr:
-                    log(f"bench: ragged7b bs={bs} {mode} skipped "
-                        f"({rr['skipped']})")
-                    continue
-                key = f"bs{bs}_{mode}"
-                if _ok(rr):
-                    ragged_sweep[key] = {k: rr.get(k)
-                                         for k in ragged_keys}
-                elif isinstance(rr, dict) and "status" in rr:
-                    ragged_sweep[key] = rr
-                    log(f"bench: ragged7b bs={bs} {mode} failed; "
-                        "continuing")
+            rr = _run_phase(
+                ["--phase", "ragged7b", "--bs", str(bs),
+                 "--max-seq", str(extra7["max_seq_len"]),
+                 "--kv-quant", extra7["kv_quant"]],
+                timeout=1800)
+            if isinstance(rr, dict) and "skipped" in rr:
+                log(f"bench: ragged7b bs={bs} skipped ({rr['skipped']})")
+                continue
+            key = f"bs{bs}_ragged"
+            if _ok(rr):
+                ragged_sweep[key] = {k: rr.get(k) for k in ragged_keys}
+            elif isinstance(rr, dict) and "status" in rr:
+                ragged_sweep[key] = rr
+                log(f"bench: ragged7b bs={bs} failed; continuing")
         if ragged_sweep:
             extra7["ragged_sweep"] = ragged_sweep
 
@@ -1680,7 +1669,6 @@ def main() -> None:
     ap.add_argument("--spec-k", type=int, default=4)
     ap.add_argument("--mesh", default="tp=8")
     ap.add_argument("--model", default="gemma-7b-it")
-    ap.add_argument("--ragged", choices=["on", "off"], default="on")
     ns = ap.parse_args()
 
     if ns.phase == "7b":
@@ -1719,7 +1707,7 @@ def main() -> None:
     elif ns.phase == "ragged7b":
         result = asyncio.run(
             phase_ragged7b(ns.bs, ns.max_seq, ns.kv_quant,
-                           ns.ragged == "on", ns.spec_k, ns.chunk_len))
+                           ns.spec_k, ns.chunk_len))
     elif ns.phase == "attr7b":
         result = phase_attr7b(ns.bs, ns.max_seq, ns.kv_quant)
     elif ns.phase == "2b":

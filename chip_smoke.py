@@ -5,7 +5,7 @@ The quickest proof that the system still starts on the chip. It starts the
 real server (``python -m ai_agent_kubectl_tpu.server``) as its ONE child, at
 the full registered width and depth of Llama-3-8B-Instruct (int8 weights
 random-initialised from a seed, bf16 KV, every shipped default: KV pool,
-radix cache, RAGGED_ATTENTION=auto, DECODE_ATTN=auto, CHUNK_LEN=16,
+radix cache, CHUNK_LEN=16,
 PREFILL_BUCKETS 64..1024, MAX_SEQ_LEN=1024), waits for /health to report
 ready, then drives it over HTTP:
 

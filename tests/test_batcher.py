@@ -224,41 +224,6 @@ def test_from_config_round_trips_scheduler_shape(monkeypatch):
     assert eng_off.device_termination is False
 
 
-def test_resolve_decode_attn_heuristic():
-    """DECODE_ATTN=auto picks paged exactly for GQA geometries on TPU
-    (VERDICT r4 weak #6: the 2.08x Llama-8B paged win must be the
-    default), dense for MQA/MHA, and never composes with int8 KV, pipe
-    meshes, or off-TPU backends."""
-    from ai_agent_kubectl_tpu.engine.batcher import resolve_decode_attn
-    from ai_agent_kubectl_tpu.models.config import get_config
-
-    llama = get_config("llama-3-8b-instruct")   # GQA: 32 q / 8 kv
-    gemma2b = get_config("gemma-2b-it")         # MQA: 8 q / 1 kv
-    gemma7b = get_config("gemma-7b-it")         # MHA: 16 q / 16 kv
-
-    kw = dict(kv_quant="", pipe=1, page_size=16, backend="tpu")
-    assert resolve_decode_attn("auto", llama, **kw) == ("paged", 64)
-    assert resolve_decode_attn("auto", gemma2b, **kw) == ("dense", 16)
-    assert resolve_decode_attn("auto", gemma7b, **kw) == ("dense", 16)
-    # A page size the operator already raised is kept.
-    assert resolve_decode_attn(
-        "auto", llama, kv_quant="", pipe=1, page_size=128,
-        backend="tpu") == ("paged", 128)
-    # Non-compositions fall back to dense.
-    assert resolve_decode_attn(
-        "auto", llama, kv_quant="int8", pipe=1, page_size=16,
-        backend="tpu")[0] == "dense"
-    assert resolve_decode_attn(
-        "auto", llama, kv_quant="", pipe=2, page_size=16,
-        backend="tpu")[0] == "dense"
-    assert resolve_decode_attn(
-        "auto", llama, kv_quant="", pipe=1, page_size=16,
-        backend="cpu")[0] == "dense"
-    # Explicit settings pass through untouched.
-    assert resolve_decode_attn("dense", llama, **kw) == ("dense", 16)
-    assert resolve_decode_attn("paged", gemma2b, **kw) == ("paged", 16)
-
-
 async def test_group_admission_burst_parity():
     """Concurrent prefix-hit requests admit through the batched group path
     (one prefill program for the whole burst) and produce exactly the

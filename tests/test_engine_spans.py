@@ -31,7 +31,7 @@ ROUTES = ("/kubectl-command", "/kubectl-command/stream")
 
 def _fake(**kw):
     defaults = dict(batch_size=2, chunk_len=2, chunk_pipe_depth=3,
-                    kv_pool=True, ragged_attention="on")
+                    kv_pool=True, force_ragged=True)
     defaults.update(kw)
     return FakeChunkedEngine(**defaults)
 
@@ -44,7 +44,7 @@ def _toy_jax():
     return BatchedJaxEngine(
         get_config("toy-8m"), tokenizer=ByteTokenizer(), dtype="float32",
         max_seq_len=192, prefill_buckets=(32, 64), prefix_cache=False,
-        batch_size=2, chunk_len=4, ragged_attention="on")
+        batch_size=2, chunk_len=4, force_ragged=True)
 
 
 async def _client(engine, max_new_tokens: int = 12):

@@ -97,12 +97,15 @@ async def test_greedy_parity_full_precision_vs_int8_kv(engines):
     assert [r.text for r in full] == [r.text for r in quant]
 
 
-async def test_int8_kv_paged_falls_back_to_dense():
+async def test_int8_kv_pool_serves_gather_and_says_why():
+    """The ragged kernel reads bf16 KV: an int8 pool serves the
+    ``gather`` regime even where the kernel was asked for, and /health
+    names the condition."""
     eng = BatchedJaxEngine(
         get_config("toy-8m"),
         dtype="float32",
         kv_quant="int8",
-        decode_attn="paged",
+        force_ragged=True,
         max_seq_len=128,
         prefill_buckets=(64,),
         batch_size=2,
@@ -111,7 +114,10 @@ async def test_int8_kv_paged_falls_back_to_dense():
     )
     await eng.start()
     try:
-        assert eng._decode_impl == "dense"
+        assert eng._decode_impl == "dense" and not eng._use_ragged
+        health = eng.kv_pool_health()
+        assert health["attention_regime"] == "gather"
+        assert "KV_QUANT=int8" in health["attention_regime_reason"]
         r = await eng.generate("get pods -o wide", max_tokens=8,
                                temperature=0.0)
         assert r.completion_tokens > 0

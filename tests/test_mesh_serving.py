@@ -225,25 +225,6 @@ async def test_batched_serving_pp_tp_int8_kv_parity():
         await eng.stop()
 
 
-async def test_batched_serving_paged_decode_on_mesh_parity():
-    """Mesh-sharded paged decode attention (VERDICT r3 item 5): the paged
-    pallas kernel runs shard_mapped (slots over data, heads over model)
-    inside the serving decode program, with greedy parity vs the
-    single-device dense engine."""
-    ref = await _serve(_batched_dense(""))
-
-    eng = _batched_dense("dp=2,tp=2", decode_attn="paged", kv_page_size=16)
-    await eng.start()
-    try:
-        assert eng._decode_impl == "paged"
-        out = await asyncio.gather(*[
-            eng.generate(p, max_tokens=8, temperature=0.0) for p in PROMPTS
-        ])
-        assert [r.text for r in out] == ref
-    finally:
-        await eng.stop()
-
-
 def test_mesh_shape_too_many_devices_fails_fast():
     eng = _batched("dp=16")
     with pytest.raises(ValueError, match="devices"):

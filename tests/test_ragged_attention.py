@@ -14,8 +14,9 @@ admission+decode chunk landing as ONE dispatch with the pool books
 balanced, the compiled-program ledger collapsing strictly below the
 ``(bucket, kv_limit)`` ladder and surviving containment reset + warm
 weight swap without a re-trace (the PR 13 id()/_cache_size()
-technique), the ``attention_regime`` health/gauge field, and
-RAGGED_ATTENTION config validation.
+technique), the ``attention_regime`` health/gauge field, and the table
+of ``engine/regime.py::resolve_attention_regime``, the one place that
+decides which attention serves.
 
 The engine-building tests are slow-marked (each compiles a program set
 on the CPU backend); the CI "Ragged-kernel parity smoke" step runs
@@ -23,6 +24,7 @@ this file with NO marker filter, so every one still gates every run.
 """
 
 import asyncio
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
@@ -54,7 +56,7 @@ def _mk(**kw):
 
 
 def _mk_ragged(**kw):
-    return _mk(ragged_attention="on", **kw)
+    return _mk(force_ragged=True, **kw)
 
 
 def _books(eng) -> None:
@@ -276,6 +278,138 @@ def test_ragged_kernel_sharded_parity_and_head_divisibility():
                                       tables, mesh8, page_size=page)
 
 
+# The single-query decode read (q_len = 1 on every slot) against
+# ops/attention.py::dense_attention — what the deleted single-query
+# paged kernel's file pinned, as cases of the one kernel that stays.
+
+def _decode_case(N, S, H, KV, hd, page, seed, dtype=np.float32):
+    """Per-slot contiguous caches [N, S, KV, hd] laid into a pool as
+    consecutive blocks, with the identity table; q is one column."""
+    import jax
+    import jax.numpy as jnp
+
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(ks[0], (N, 1, H, hd), dtype)
+    k = jax.random.normal(ks[1], (N, S, KV, hd), dtype)
+    v = jax.random.normal(ks[2], (N, S, KV, hd), dtype)
+    tables = jnp.arange(N * S // page, dtype=jnp.int32).reshape(N, -1)
+    blocks = (N * S // page, page, KV, hd)
+    return q, k, v, k.reshape(blocks), v.reshape(blocks), tables
+
+
+def _dense_decode_ref(q, k, v, positions):
+    """dense_attention over each slot's whole cache, decode causal
+    mask, in float32."""
+    import jax.numpy as jnp
+
+    from ai_agent_kubectl_tpu.ops.attention import dense_attention
+
+    mask = jnp.arange(k.shape[1])[None, None, :] <= positions[:, None, None]
+    return np.asarray(dense_attention(
+        q.astype(jnp.float32), k.astype(jnp.float32),
+        v.astype(jnp.float32), mask))
+
+
+def _decode(q, kp, vp, positions, tables, page):
+    import jax.numpy as jnp
+
+    return np.asarray(ragged_attention_pool(
+        q, kp, vp, jnp.ones((q.shape[0],), jnp.int32), positions, tables,
+        page_size=page).astype(jnp.float32))
+
+
+@pytest.mark.parametrize("kv_heads", [1, 2], ids=["mqa", "gqa"])
+def test_ragged_decode_matches_dense_at_differing_positions(kv_heads):
+    """Per-slot positions that differ, on the page-boundary edges: a
+    single live token, exactly page-1, exactly page, mid-cache."""
+    import jax.numpy as jnp
+
+    q, k, v, kp, vp, tables = _decode_case(4, 128, 4, kv_heads, 64, 16, 0)
+    positions = jnp.asarray([0, 15, 16, 77], jnp.int32)
+    np.testing.assert_allclose(
+        _decode(q, kp, vp, positions, tables, 16),
+        _dense_decode_ref(q, k, v, positions), rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("kv_heads", [1, 2], ids=["mqa", "gqa"])
+def test_ragged_decode_shared_and_sentinel_blocks_match_dense(kv_heads):
+    """Slots read scattered pool blocks by table indirection; a block
+    shared by two tables (radix sharing) and sentinel entries past the
+    live span must not change the math vs dense attention over the
+    gathered per-slot view."""
+    import jax
+    import jax.numpy as jnp
+
+    N, n_blocks, page, H, hd = 3, 10, 16, 4, 64
+    ks = jax.random.split(jax.random.PRNGKey(7), 3)
+    q = jax.random.normal(ks[0], (N, 1, H, hd), jnp.float32)
+    kp = jax.random.normal(ks[1], (n_blocks, page, kv_heads, hd))
+    vp = jax.random.normal(ks[2], (n_blocks, page, kv_heads, hd))
+    tables = jnp.asarray([[7, 2, 9, 10], [7, 5, 10, 10], [0, 1, 3, 4]],
+                         jnp.int32)
+    positions = jnp.asarray([40, 17, 63], jnp.int32)
+    idx = jnp.clip(tables, 0, n_blocks - 1)
+    kg = kp[idx].reshape(N, 4 * page, kv_heads, hd)
+    vg = vp[idx].reshape(N, 4 * page, kv_heads, hd)
+    np.testing.assert_allclose(
+        _decode(q, kp, vp, positions, tables, page),
+        _dense_decode_ref(q, kg, vg, positions), rtol=2e-5, atol=2e-5)
+
+
+def test_ragged_decode_full_table_last_row_of_last_page():
+    """A full table: one slot's row is the last row of the last page,
+    another's the first row of the last page."""
+    import jax.numpy as jnp
+
+    S, page = 64, 16
+    q, k, v, kp, vp, tables = _decode_case(2, S, 4, 2, 64, page, 1)
+    positions = jnp.asarray([S - 1, S - page], jnp.int32)
+    np.testing.assert_allclose(
+        _decode(q, kp, vp, positions, tables, page),
+        _dense_decode_ref(q, k, v, positions), rtol=2e-5, atol=2e-5)
+
+
+def test_ragged_decode_bf16_inputs():
+    import jax.numpy as jnp
+
+    q, k, v, kp, vp, tables = _decode_case(2, 64, 4, 1, 128, 16, 2,
+                                           dtype=jnp.bfloat16)
+    positions = jnp.asarray([33, 5], jnp.int32)
+    np.testing.assert_allclose(
+        _decode(q, kp, vp, positions, tables, 16),
+        _dense_decode_ref(q, k, v, positions), rtol=2e-2, atol=2e-2)
+
+
+def test_pool_forward_refuses_kv_limit_that_is_not_whole_pages():
+    """The pool read is page-granular: ``forward`` refuses a kv_limit
+    that is not a whole page count, and the kernel a pool whose page is
+    not the one it was told."""
+    import jax
+    import jax.numpy as jnp
+
+    from ai_agent_kubectl_tpu.models.config import get_config
+    from ai_agent_kubectl_tpu.models.transformer import (KVCache, forward,
+                                                         init_params)
+
+    cfg = get_config("toy-8m")
+    params = init_params(jax.random.PRNGKey(0), cfg, dtype=jnp.float32)
+    page, n_blocks = 16, 4
+    leaf = jnp.zeros((cfg.n_layers, n_blocks, page, cfg.n_kv_heads,
+                      cfg.head_dim), jnp.float32)
+    cache = KVCache(k=leaf, v=leaf, lengths=jnp.zeros((n_blocks,),
+                                                      jnp.int32))
+    tok = jnp.zeros((1, 1), jnp.int32)
+    tables = jnp.arange(n_blocks, dtype=jnp.int32)[None]
+    with pytest.raises(ValueError, match="not a multiple of page"):
+        forward(params, cfg, tok, tok, cache, kv_limit=60,
+                attn_impl="ragged", block_tables=tables)
+    q = jnp.zeros((1, 1, cfg.n_heads, cfg.head_dim), jnp.float32)
+    with pytest.raises(ValueError, match="pool page 16 != page_size 8"):
+        ragged_attention_pool(q, leaf[0], leaf[0], jnp.ones((1,), jnp.int32),
+                              jnp.zeros((1,), jnp.int32), tables,
+                              page_size=8)
+
+
 def test_ragged_supported_gate():
     """Compiled-kernel tiling constraints (interpret mode skips them —
     the CPU tests above run hd=16 on purpose)."""
@@ -286,38 +420,96 @@ def test_ragged_supported_gate():
     assert not ragged_supported(page_size=128, head_dim=128, n_pages=0)
 
 
-# ------------------------------------------------- config + fake (tier-1)
+# ------------------------------------------- regime table + fake (tier-1)
 
-def test_config_validates_ragged_knob():
+# The three numbers the decision reads from a model config.
+_MISTRAL = SimpleNamespace(n_heads=32, n_kv_heads=8, head_dim=128)
+# a head_dim the compiled kernel refuses
+_TOY = SimpleNamespace(n_heads=4, n_kv_heads=2, head_dim=32)
+# heads that model:2 does not divide
+_ODD = SimpleNamespace(n_heads=6, n_kv_heads=3, head_dim=128)
+_ON_TPU = dict(backend="tpu", mesh_shape=None, kv_quant="", kv_pool=True,
+               device_termination=True, pool_page=64)
+
+
+@pytest.mark.parametrize("cfg,over,regime,page,reason", [
+    (_MISTRAL, {}, "ragged", 64, "TPU backend"),
+    (_MISTRAL, {"mesh_shape": {"data": 1, "model": 4}}, "ragged", 64,
+     "TPU backend"),
+    (_MISTRAL, {"pool_page": 16}, "ragged", 64, "TPU backend"),
+    (_MISTRAL, {"pool_page": 128}, "ragged", 128, "TPU backend"),
+    (_MISTRAL, {"kv_quant": "int8"}, "gather", 64, "KV_QUANT=int8"),
+    (_MISTRAL, {"device_termination": False}, "gather", 64,
+     "DEVICE_TERMINATION=false"),
+    (_ODD, {"mesh_shape": {"model": 2}}, "gather", 64,
+     "do not divide the model axis (2)"),
+    (_TOY, {}, "gather", 64, "does not support page=64 head_dim=32"),
+    (_MISTRAL, {"backend": "cpu", "pool_page": 16}, "gather", 16,
+     "backend cpu is not a TPU"),
+    (_TOY, {"backend": "cpu", "pool_page": 16, "force_ragged": True},
+     "ragged", 16, "force_ragged"),
+    (_TOY, {"backend": "cpu", "force_ragged": True, "kv_quant": "int8"},
+     "gather", 64, "KV_QUANT=int8"),
+    (None, {"backend": "fake", "pool_page": 16}, "gather", 16,
+     "backend fake is not a TPU"),
+    (None, {"backend": "fake", "pool_page": 16, "force_ragged": True},
+     "ragged", 16, "force_ragged"),
+    (_MISTRAL, {"kv_pool": False, "pool_page": 16}, "dense", 16,
+     "KV_POOL=false"),
+    (_MISTRAL, {"mesh_shape": {"data": 2, "model": 2}, "pool_page": 16},
+     "dense", 16, "data mesh axis"),
+    (_MISTRAL, {"mesh_shape": {"pipe": 2, "seq": 2}, "force_ragged": True},
+     "dense", 64, "pipe/seq mesh axis"),
+], ids=["tpu", "tpu-tp4", "tpu-page-floor", "tpu-page-128", "int8-kv",
+        "host-termination", "heads-not-dividing", "page-or-head-dim",
+        "cpu", "cpu-forced", "cpu-forced-int8", "fake", "fake-forced",
+        "pool-off", "data-mesh", "pipe-seq-mesh"])
+def test_attention_regime_table(cfg, over, regime, page, reason):
+    """One row per branch of the decision: what the engine can observe
+    in, (regime, pool page, reason) out. ``force_ragged`` stands in for
+    the TPU alone — int8 KV and the mesh still decide first — and the
+    page floor applies on a TPU only."""
+    from ai_agent_kubectl_tpu.engine.regime import resolve_attention_regime
+
+    got = resolve_attention_regime(cfg, **{**_ON_TPU, **over})
+    assert got[:2] == (regime, page)
+    assert reason in got[2], got[2]
+
+
+def test_no_setting_reaches_force_ragged(monkeypatch):
+    """``force_ragged`` is a constructor argument for tests: no field
+    of ServiceConfig names it, and an engine built from the environment
+    never has it, whatever the environment says."""
+    import dataclasses
+
     from ai_agent_kubectl_tpu.config import ServiceConfig
+    from ai_agent_kubectl_tpu.engine.batcher import BatchedJaxEngine
 
-    with pytest.raises(ValueError, match="RAGGED_ATTENTION"):
-        ServiceConfig(ragged_attention="sometimes")
-    with pytest.raises(ValueError, match="requires KV_POOL"):
-        ServiceConfig(ragged_attention="on", kv_pool=False)
-    assert ServiceConfig(ragged_attention="on").ragged_attention == "on"
-    assert ServiceConfig().ragged_attention == "auto"   # env default
-
-    with pytest.raises(ValueError, match="RAGGED_ATTENTION"):
-        FakeChunkedEngine(ragged_attention="bogus")
+    monkeypatch.setenv("MODEL_NAME", "toy-8m")
+    monkeypatch.setenv("FORCE_RAGGED", "true")
+    cfg = ServiceConfig.from_env(env_file=None)
+    assert not [f.name for f in dataclasses.fields(cfg)
+                if "ragged" in f.name]
+    assert BatchedJaxEngine.from_config(cfg).force_ragged is False
 
 
 async def test_fake_ragged_parity_and_regime():
-    """The fake mirror: ragged-on transcripts equal ragged-off byte for
-    byte (the admission restructure, not the kernel, is what the fake
-    models) and the attention_regime field tracks the mode."""
-    on = FakeChunkedEngine(batch_size=4, chunk_len=4,
-                           ragged_attention="on")
-    off = FakeChunkedEngine(batch_size=4, chunk_len=4,
-                            ragged_attention="off")
+    """The fake mirror: ragged transcripts equal gather's byte for byte
+    (the admission restructure, not the kernel, is what the fake
+    models), and regime and reason come from the function the batcher
+    calls."""
+    on = FakeChunkedEngine(batch_size=4, chunk_len=4, force_ragged=True)
+    off = FakeChunkedEngine(batch_size=4, chunk_len=4)
     await on.start()
     await off.start()
     try:
         assert on._use_ragged and not off._use_ragged
         assert on.kv_pool_health()["attention_regime"] == "ragged"
-        assert off.kv_pool_health()["attention_regime"] == "paged"
+        health = off.kv_pool_health()
+        assert health["attention_regime"] == "gather"
+        assert "not a TPU" in health["attention_regime_reason"]
         dense = FakeChunkedEngine(batch_size=4, chunk_len=4,
-                                  kv_pool=False)
+                                  kv_pool=False, force_ragged=True)
         assert dense._attention_regime == "dense"
         for prompt, temp, seed in zip(PROMPTS, TEMPS, SEEDS):
             a = await on.generate(prompt, max_tokens=12,
@@ -340,7 +532,7 @@ async def test_jax_ragged_vs_ladder_byte_identity_one_dispatch():
     chunk-log entry carries admissions>0 AND already-decoding slots),
     health/regime fields report, and the pool books balance after."""
     ragged = _mk_ragged()
-    ladder = _mk(ragged_attention="off")
+    ladder = _mk()
     await ragged.start()
     ladder.tokenizer = ragged.tokenizer
     await ladder.start()
@@ -349,8 +541,7 @@ async def test_jax_ragged_vs_ladder_byte_identity_one_dispatch():
         # Single-chip deployments read the regime from kv_pool_health
         # (sharding_health is None without a mesh).
         assert ragged.kv_pool_health()["attention_regime"] == "ragged"
-        assert ladder.kv_pool_health()["attention_regime"] in (
-            "paged", "gather")
+        assert ladder.kv_pool_health()["attention_regime"] == "gather"
         # Stagger a second wave so admissions stage into chunks that
         # already carry decoding slots.
         async def wave(eng):
@@ -434,7 +625,7 @@ async def test_jax_ragged_program_collapse_and_warm_swap():
     traffic. A warm weight swap keeps every ragged program object and
     its trace cache (PR 13's id()/_cache_size() technique)."""
     ragged = _mk_ragged()
-    ladder = _mk(ragged_attention="off")
+    ladder = _mk()
     await ragged.start()
     ladder.tokenizer = ragged.tokenizer
     await ladder.start()

@@ -59,34 +59,6 @@ def test_compiled_flash_matches_dense_prefill_shapes():
             np.asarray(ref).astype(np.float32), rtol=3e-2, atol=3e-2)
 
 
-def test_compiled_paged_matches_dense_decode():
-    """Compiled paged decode kernel vs dense over the serving geometry
-    (64 slots, ragged lengths, MQA + GQA)."""
-    import jax.numpy as jnp
-    import numpy as np
-
-    from ai_agent_kubectl_tpu.ops.attention import dense_attention
-    from ai_agent_kubectl_tpu.ops.paged_attention import paged_decode_attention
-
-    for KV in (1, 2):
-        N, S, H, hd, page = 64, 1024, 8, 256, 128
-        q = _rand((N, H, hd), 3, jnp.bfloat16)
-        k = _rand((N, S, KV, hd), 4, jnp.bfloat16)
-        v = _rand((N, S, KV, hd), 5, jnp.bfloat16)
-        positions = jnp.asarray(
-            np.random.RandomState(0).randint(0, S, (N,)), jnp.int32)
-
-        out = paged_decode_attention(q, k, v, positions, page_size=page,
-                                     interpret=False)
-
-        kv_pos = jnp.arange(S)[None, None, :]
-        mask = kv_pos <= positions[:, None, None]
-        ref = dense_attention(q[:, None], k, v, mask)[:, 0]
-        np.testing.assert_allclose(
-            np.asarray(out).astype(np.float32),
-            np.asarray(ref).astype(np.float32), rtol=3e-2, atol=3e-2)
-
-
 def test_quant_attention_reads_int8_kv_without_materializing():
     """The r5 serving contract for KV_QUANT=int8
     (ops/attention.py::dense_attention_quant): the int8 payload feeds the
@@ -181,13 +153,11 @@ def test_int8_convert_fuses_into_weight_read():
 
 # ------------------------------------------- block-pool kernels (PR 21)
 #
-# The two kernels that read the shared block pool through per-slot
-# tables — the ragged kernel carries the whole pool serving path on TPU
-# (RAGGED_ATTENTION=auto), the single-query paged kernel serves
-# RAGGED_ATTENTION=off — at the geometry chip_smoke.py serves:
-# Llama-3-8B heads (H 32, KV 8, hd 128), the page DECODE_ATTN=auto picks
-# on TPU (64), the per-slot table of MAX_SEQ_LEN=1024, and the same
-# model's tp=4 shard (H 8, KV 2).
+# The kernel that reads the shared block pool through per-slot tables —
+# it carries the whole pool serving path on TPU — at the geometry
+# chip_smoke.py serves: Llama-3-8B heads (H 32, KV 8, hd 128), the page
+# floor a TPU engine serves (64), the per-slot table of
+# MAX_SEQ_LEN=1024, and the same model's tp=4 shard (H 8, KV 2).
 
 _POOL_GEOMETRIES = ((32, 8), (8, 2))      # (H, KV)
 _POOL_HD, _POOL_PAGE, _POOL_PAGES = 128, 64, 17
@@ -309,14 +279,14 @@ def test_compiled_ragged_stacked_pool_layer_equals_layer_slice(W):
 
 
 @pytest.mark.parametrize("H,KV", _POOL_GEOMETRIES)
-def test_compiled_paged_pool_matches_gather(H, KV):
-    """Compiled block-table decode kernel vs the dense gather reference
+def test_compiled_ragged_pool_decode_batch_matches_gather(H, KV):
+    """Compiled ragged kernel at q_len = 1 vs the dense gather reference
     over a full batch of ragged positions (first row of a sequence, page
     edges, the last row the table can hold)."""
     import numpy as np
 
-    from ai_agent_kubectl_tpu.ops.paged_attention import \
-        paged_decode_attention_pool
+    from ai_agent_kubectl_tpu.ops.ragged_attention import \
+        ragged_attention_pool
 
     edge = [0, _POOL_PAGE - 1, _POOL_PAGE, _POOL_PAGES * _POOL_PAGE - 1]
     rng = np.random.RandomState(1)
@@ -324,10 +294,10 @@ def test_compiled_paged_pool_matches_gather(H, KV):
         rng.randint(0, _POOL_PAGES * _POOL_PAGE, 32 - len(edge)))]
     q, (k, v), clean, q_lens, positions, tables = _pool_case(
         H, KV, 1, spans, seed=40)
-    out = np.asarray(paged_decode_attention_pool(
-        q[:, 0], k, v, positions, tables, page_size=_POOL_PAGE,
+    out = np.asarray(ragged_attention_pool(
+        q, k, v, q_lens, positions, tables, page_size=_POOL_PAGE,
         interpret=False)).astype(np.float32)
     assert np.isfinite(out).all(), "a dead page leaked into the output"
     ref = np.asarray(_gather_reference(q, *clean, q_lens, positions,
-                                       tables))[:, 0]
+                                       tables))
     np.testing.assert_allclose(out, ref, rtol=3e-2, atol=3e-2)

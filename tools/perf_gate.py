@@ -80,8 +80,7 @@ METRICS: Tuple[Tuple[str, str, Tuple[str, ...]], ...] = (
     ("gemma_7b.tp_spec.bs192.spec_step_ms", "steptime",
      ("extra", "gemma_7b", "tp_spec_sweep", "bs192", "spec_step_ms")),
     # Ragged-kernel sweep (ISSUE 19): the mixed workload under the
-    # single ragged paged kernel vs the legacy program ladder, keyed
-    # per (bs, mode). Required once a trajectory artifact records them
+    # single ragged paged kernel, keyed per bs. Required once a trajectory artifact records them
     # — a ragged rung that stops being served (kernel gate regressed to
     # the gather fallback and the phase crashed, or the phase vanished)
     # fails as absent/timed_out, never as a silent pass. The ragged
@@ -93,12 +92,6 @@ METRICS: Tuple[Tuple[str, str, Tuple[str, ...]], ...] = (
       "tokens_per_sec_per_chip")),
     ("gemma_7b.ragged.bs192.tok_s", "throughput",
      ("extra", "gemma_7b", "ragged_sweep", "bs192_ragged",
-      "tokens_per_sec_per_chip")),
-    ("gemma_7b.ragged.bs48_ladder.tok_s", "throughput",
-     ("extra", "gemma_7b", "ragged_sweep", "bs48_ladder",
-      "tokens_per_sec_per_chip")),
-    ("gemma_7b.ragged.bs192_ladder.tok_s", "throughput",
-     ("extra", "gemma_7b", "ragged_sweep", "bs192_ladder",
       "tokens_per_sec_per_chip")),
     ("gemma_7b.ragged.bs48.programs", "steptime",
      ("extra", "gemma_7b", "ragged_sweep", "bs48_ragged",

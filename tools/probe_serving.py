@@ -179,19 +179,19 @@ def print_containment_summary(gauges: Dict[str, float]) -> None:
 
 
 def print_attention_regime(gauges: Dict[str, float]) -> None:
-    """Which attention path is actually serving decode (ISSUE 19):
-    the enum gauge ``decode_attention_regime{regime=...}`` carries 1 on
-    exactly one label — ragged (single paged kernel), paged (legacy
-    in-chunk ladder), gather (ragged requested but KV heads don't
-    divide tp / KV is int8), or dense (no block pool at all)."""
+    """Which attention path is actually serving decode: the enum gauge
+    ``decode_attention_regime{regime=...}`` carries 1 on exactly one
+    label — ragged (one kernel over the block pool), gather (the pool
+    read through a dense gather: int8 KV, KV heads that don't divide
+    tp, or no TPU), or dense (no block pool at all). /health carries
+    the reason beside the regime."""
     regimes = _sum_labelled(gauges, "decode_attention_regime")
     active = [k.split("=")[-1].strip('"') for k, v in regimes.items()
               if v >= 1.0]
     if not active:
         return      # engine predating the regime gauge
     note = {"ragged": "one kernel for prefill/decode/verify",
-            "paged": "legacy per-bucket pool ladder",
-            "gather": "ragged fell back — KV gathered densely",
+            "gather": "block pool, KV gathered densely",
             "dense": "no block pool (dense KV ladder)"}
     log("probe[attention]: decode attention regime")
     for r in active:
