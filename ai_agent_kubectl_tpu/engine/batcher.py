@@ -1057,6 +1057,7 @@ class BatchedJaxEngine(JaxEngine):
         self._use_ragged = False
         self._attention_regime = DENSE
         self._attention_regime_reason = "not started"
+        self._attention_steps = (None, None)
         self._ragged_chunk_fns: dict = {}   # (adm width, spec) -> jitted
         # slot_idx -> staged admission (ids/start/ngen0/budget/seed/
         # temp/gs): the unmatched prompt suffix rides the NEXT chunk as
@@ -1547,6 +1548,11 @@ class BatchedJaxEngine(JaxEngine):
                     f"even one full-length sequence "
                     f"({self._pool_max_pages} pages)")
             self._pool_prefill_kv_buckets = kv_bucket_ladder(S_alloc)
+            if self._use_ragged:
+                self._attention_steps = self._resolve_attention_steps()
+                logger.info("ragged kernel: %d KV pages a grid step, %d "
+                            "grid steps a decode call",
+                            *self._attention_steps)
         # Attention cost under ``gather`` and ``dense`` grows with the
         # KV span read, so the chunk program is compiled per KV *bucket*
         # — a pow2 ladder topped by S_alloc — and dispatch picks the
@@ -2813,12 +2819,36 @@ class BatchedJaxEngine(JaxEngine):
             # off the shard-local fast path; fleets OR this flag).
             "draft_sharded": bool(self._draft_sharded),
             "draft_kv_fallback": bool(self._draft_kv_fallback),
-            # The regime actually serving attention (ragged | gather |
-            # dense) and the condition that selected it — int8 KV,
-            # non-dividing head counts and mesh gates fall back LOUDLY
-            # here.
+            **self._attention_health(),
+        }
+
+    def _resolve_attention_steps(self) -> tuple:
+        """(KV pages a grid step, grid steps a call) of the ragged kernel
+        in the decode program, from the shapes a chip sees: its share of
+        the heads, the whole table, the decode window (k+1 columns under
+        speculation)."""
+        from ..ops.ragged_attention import grid_steps, pages_per_step
+
+        cfg = self.model_cfg
+        tp = self.mesh.shape["model"] if self.mesh is not None else 1
+        shape = (self._pool_max_pages, self.kv_pool_page,
+                 cfg.n_heads // tp, cfg.n_kv_heads // tp, cfg.head_dim,
+                 self.spec_draft_k + 1 if self._spec_live else 1,
+                 jnp.dtype(self.dtype).itemsize)
+        return (pages_per_step(*shape),
+                grid_steps(self.batch_size, *shape))
+
+    def _attention_health(self) -> dict:
+        """The regime actually serving attention (ragged | gather | dense)
+        and the condition that selected it — int8 KV, non-dividing head
+        counts and mesh gates fall back LOUDLY here — and, under ragged,
+        what the kernel resolved at start (null otherwise)."""
+        pages, steps = self._attention_steps
+        return {
             "attention_regime": self._attention_regime,
             "attention_regime_reason": self._attention_regime_reason,
+            "attention_pages_per_step": pages,
+            "attention_decode_grid_steps": steps,
         }
 
     def kv_pool_health(self) -> Optional[dict]:
@@ -2833,8 +2863,7 @@ class BatchedJaxEngine(JaxEngine):
         body["starved_slots_total"] = self._pool_starved
         # Single-chip deployments read the regime here (sharding_health
         # is None without a mesh).
-        body["attention_regime"] = self._attention_regime
-        body["attention_regime_reason"] = self._attention_regime_reason
+        body.update(self._attention_health())
         body["radix"] = (self._radix.stats() if self._radix is not None
                          else None)
         if self._host_store is not None:

@@ -18,25 +18,38 @@ Shape contract:
 - ``k``/``v``      [n_blocks, page, KV, hd] — one layer of the shared
   block pool — or the whole stacked pool [L, n_blocks, page, KV, hd]
   with ``layer`` (a traced scalar) picking the layer. The layer index is
-  scalar-prefetched into the K/V index map, so the kernel streams pages
-  of that layer straight out of the stacked buffer: a ``pool[layer]``
-  slice in front of the call would copy the layer every pass (ISSUE 25).
+  scalar-prefetched, so the kernel copies pages of that layer straight
+  out of the stacked buffer: a ``pool[layer]`` slice in front of the call
+  would copy the layer every pass (ISSUE 25).
 - ``q_lens``       [N] int32 — 0 freezes a slot (output rows are zeros,
   compute masked); 1 = decode; k+1 = verify; span = prefill.
 - ``positions``    [N] int32 — absolute position of query column 0.
 - ``block_tables`` [N, max_pages] int32 — pool block per sequence page;
   entries >= n_blocks are the unmapped-page sentinel.
 
-TPU-first design: grid ``(slot, query tile, page)`` with
-positions + query lengths + tables scalar-prefetched, dead pages clamped
-to the tile's LAST LIVE page in the BlockSpec index map (repeat block
-indices elide the HBM→VMEM fetch, ``pl.when`` elides the compute),
-online softmax state persisted in VMEM scratch across the sequential
-page dimension. The window is cut into query tiles of ``_q_tile``
-columns so the VMEM working set depends on the head geometry alone,
-never on W: Mosaic refused every admission width above 64 when a grid
-cell held the whole window (scoped-VMEM limit, v5e). Tiles wholly past
-``q_lens[n]`` cost neither fetches nor compute. Causal-in-window
+TPU-first design: grid ``(slot, query tile, page block)``, the page axis
+``cdiv(max_pages, P)`` blocks of ``P`` pages (``pages_per_step``: as many
+as cover 512 KV positions, fewer where the query tile's scores leave no
+VMEM for them). Positions, query lengths, tables and the layer index are
+scalar-prefetched; K and V stay in HBM and the kernel fetches them itself
+(ISSUE 30): one async copy per LIVE page of a block (through
+``tbl[n, j]`` and the layer index) into a ``[2, P, page, KV, hd]`` VMEM
+scratch, all in flight together. The live blocks of a call are one stream
+through the two buffers: each starts the copies of the NEXT live block —
+of its own tile, or the first block of the next tile that reads anything —
+before it waits for its own, so a slot's copies fly while the slot before
+it computes. A block is ONE ``[KV, tq*G, P*page]`` score tile; online
+softmax state persists in VMEM scratch across the sequential block axis.
+Pages past a tile's last live page are never copied and blocks wholly past
+it do nothing but step (0.05 us; under one page a step through BlockSpecs
+a dead step cost 0.16 us and a decode call over the engine's 65-page table
+took 1,040 of them: 194 us against 27 now, v5e, PR 30).
+
+The window is cut into query tiles of ``_q_tile`` columns so the VMEM
+working set depends on the head geometry alone, never on W: Mosaic
+refused every admission width above 64 when a grid cell held the whole
+window (scoped-VMEM limit, v5e). Tiles wholly past ``q_lens[n]`` cost
+neither fetches nor compute. Causal-in-window
 masking: query column j attends kv positions ``<= positions[n] + j`` —
 bitwise the same semantics as the dense gather path
 (models/transformer.py::_pool_gather + dense_attention with the decode
@@ -72,7 +85,7 @@ def stacked_kv(k, v, layer):
     """(k, v, layer [1] int32) with K/V as a layer stack: a stacked cache
     comes with its traced ``layer`` index, a bare layer becomes a
     one-layer stack (a free reshape), so the kernel has one form and
-    its index map picks the layer — no ``cache[layer]`` copy in front of
+    its page copies pick the layer — no ``cache[layer]`` copy in front of
     the call (ISSUE 25)."""
     if (k.ndim == 5) != (layer is not None):
         raise ValueError(
@@ -100,40 +113,157 @@ def _q_tile(w: int, n_heads: int, head_dim: int) -> int:
     return min(w, 1 << (tq.bit_length() - 1))
 
 
-def _last_live_page(pos, q_len, q0, tq: int, page_size: int):
-    """Last KV page any valid column of the tile starting at window
-    column ``q0`` attends: the page of its last valid column's own
-    freshly-written row. Shared by the kernel's compute gate and the
-    index map's fetch clamp so the two never disagree."""
-    hi = jnp.maximum(jnp.minimum(q0 + tq, q_len), 1)
-    return (pos + hi - 1) // page_size
+#: KV positions one grid step attends: eight pages of 64.
+_KV_STEP_POSITIONS = 512
+
+#: What a step's KV may take of the 16 MiB scoped VMEM (v5e): the K and V
+#: double buffers plus the f32 score tile and its temporaries. The rest is
+#: the query tile's (``_Q_TILE_ELEMS``: q/out blocks, accumulator,
+#: lane-padded softmax state). Against the compiler's own count at
+#: Mistral-7B's heads and a 64-column tile: 4 pages a step allocate 12.25
+#: MiB, 8 pages 15.06, 16 pages 24.66 (refused); a decode row with 8 pages
+#: 4.08 (AOT for a v5e, PR 30).
+_KV_VMEM_BYTES = 9 * 2**20
 
 
-def _ragged_pool_kernel(pos_ref, qlen_ref, tbl_ref, lyr_ref, q_ref, k_ref,
-                        v_ref, o_ref, m_scr, l_scr, acc_scr, *, page_size: int,
-                        scale: float, n_pages: int, kv_heads: int,
-                        tq: int):
-    """Online-softmax body over one (slot, query tile, page) grid cell,
-    ``tq`` query columns at a time. Rows are laid out [KV, tq*G] (row r
-    is tile column ``r // G`` of KV group ``r % G``'s block) so one
-    KV-batched ``dot_general`` serves every query column and head of
-    the block."""
-    del tbl_ref, lyr_ref              # consumed by the index map
-    n = pl.program_id(0)
-    q0 = pl.program_id(1) * tq        # window column of tile row 0
-    p = pl.program_id(2)
-    pos = pos_ref[n]
-    q_len = qlen_ref[n]
-    last_page = _last_live_page(pos, q_len, q0, tq, page_size)
+def pages_per_step(n_pages: int, page_size: int, n_heads: int,
+                   kv_heads: int, head_dim: int, w: int,
+                   itemsize: int = 2) -> int:
+    """KV pages one grid step fetches and attends, from shapes alone: the
+    largest power of two whose pages cover at most ``_KV_STEP_POSITIONS``
+    and whose VMEM — K and V double-buffered (Mosaic tiles a page's
+    [KV, hd] rows compactly: 2 KV heads of a mesh shard take a quarter of
+    8) plus three f32 score tiles of the query tile's rows — stays within
+    ``_KV_VMEM_BYTES``. Never wider than the table."""
+    tq = _q_tile(w, n_heads, head_dim)
+    kv_bytes = 2 * 2 * page_size * kv_heads * head_dim * itemsize
+    rows = kv_heads * -(-tq * (n_heads // kv_heads) // 8) * 8
+    score_bytes = 3 * rows * page_size * 4
+    p = max(1, min(_KV_STEP_POSITIONS // page_size,
+                   _KV_VMEM_BYTES // (kv_bytes + score_bytes), n_pages))
+    return 1 << (p.bit_length() - 1)
 
-    @pl.when(p == 0)
+
+def grid_steps(n_slots: int, n_pages: int, page_size: int, n_heads: int,
+               kv_heads: int, head_dim: int, w: int,
+               itemsize: int = 2) -> int:
+    """Grid steps of one kernel call (what /health reports for decode)."""
+    pps = pages_per_step(n_pages, page_size, n_heads, kv_heads, head_dim, w,
+                         itemsize)
+    tq = _q_tile(w, n_heads, head_dim)
+    return n_slots * pl.cdiv(w, tq) * pl.cdiv(n_pages, pps)
+
+
+def _ragged_pool_kernel(pos_ref, qlen_ref, tbl_ref, lyr_ref, q_ref, k_hbm,
+                        v_hbm, o_ref, k_buf, v_buf, sems, done_ref, m_scr,
+                        l_scr, acc_scr, *, page_size: int, scale: float,
+                        n_pages: int, kv_heads: int, tq: int, pps: int,
+                        grid: tuple):
+    """Online-softmax body over one (slot, query tile, page block) grid
+    cell: ``tq`` query columns against ``pps`` pages. Rows are laid out
+    [KV, tq*G] (row r is tile column ``r // G`` of KV group ``r % G``'s
+    block) so one KV-batched ``dot_general`` serves every query column
+    and head of the block.
+
+    The LIVE blocks of a call, in grid order, are one stream through two
+    buffers: each starts the copies of the one after it — the next block
+    of its tile or, past the tile's last live page, block 0 of the next
+    tile that reads anything (frozen slots and tiles past ``q_len`` read
+    nothing) — before it waits for its own. ``done_ref`` counts the
+    blocks computed; its parity is the buffer the next one lands in.
+
+    Scalar code divides with ``lax.div``/``lax.rem`` (operands are never
+    negative): ``//`` and ``%`` each lower through a traced sign
+    correction, which cost 0.4 s of lowering a chunk program at every
+    server start, compile cache or not."""
+    n, t, b = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    n_slots, n_qt, n_blk = grid
+    n_cells = n_slots * n_qt
+    div, rem = jax.lax.div, jax.lax.rem
+
+    def last_page_of(n_, t_):
+        """Pages tile ``t_`` of slot ``n_`` reads: 0 .. this; -1 = none
+        (a tile wholly past ``q_len``). The last is the page of the
+        tile's last valid column's own freshly-written row. Copies and
+        compute are gated by the same number."""
+        q_len = qlen_ref[n_]
+        hi = jnp.minimum(t_ * tq + tq, q_len)
+        last = jnp.minimum(div(pos_ref[n_] + hi - 1, page_size), n_pages - 1)
+        return jnp.where(t_ * tq < q_len, last, -1)
+
+    def for_live_pages(n_, blk, last, slot, do, block_of):
+        """``do`` the K and V copy of every page of block ``blk`` up to
+        page ``last`` (into buffer ``slot``, from pool block
+        ``block_of(slot n_'s table, page)``)."""
+        def page(j, _):
+            for c, (hbm, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
+                do(pltpu.make_async_copy(
+                    hbm.at[lyr_ref[0], block_of(n_, j)],
+                    buf.at[slot, j - blk * pps], sems.at[c, slot]))
+
+        jax.lax.fori_loop(blk * pps, jnp.minimum(blk * pps + pps, last + 1),
+                          page, None)
+
+    def start(cp):
+        cp.start()
+
+    def from_table(n_, j):
+        return tbl_ref[n_, j]
+
+    def tile_of(cell):
+        return (cell, 0) if n_qt == 1 else (div(cell, n_qt), rem(cell, n_qt))
+
+    @pl.when(b == 0)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    @pl.when(jnp.logical_and(p <= last_page, q0 < q_len))
+        @pl.when(jnp.logical_and(n == 0, t == 0))
+        def _first():
+            # A page past the live span of a half-live block is never
+            # copied; its V rows meet probability 0, and 0 x NaN is NaN.
+            # So the buffers only ever hold zeros or pool rows.
+            v_buf[...] = jnp.zeros_like(v_buf)
+            done_ref[0] = 0
+
+    pos = pos_ref[n]
+    q_len = qlen_ref[n]
+    q0 = t * tq                       # window column of tile row 0
+    last_page = last_page_of(n, t)
+
+    @pl.when(b * pps <= last_page)
     def _accumulate():
+        done = done_ref[0]
+        done_ref[0] = done + 1
+        slot = jnp.bitwise_and(done, 1)
+
+        @pl.when(done == 0)
+        def _own():     # the call's first live block: none before it
+            for_live_pages(n, b, last_page, slot, start, from_table)
+
+        # The next live block: of this tile, or block 0 of the first
+        # tile after it (in grid order) that reads any page.
+        more = (b + 1) * pps <= last_page
+        cell = n * n_qt + t
+
+        def reads_nothing(c):
+            return last_page_of(*tile_of(jnp.minimum(c, n_cells - 1))) < 0
+
+        nxt = jax.lax.while_loop(
+            lambda c: jnp.logical_and(c < n_cells, reads_nothing(c)),
+            lambda c: c + 1, jnp.where(more, cell, cell + 1))
+
+        @pl.when(nxt < n_cells)
+        def _next():
+            n_, t_ = tile_of(nxt)
+            for_live_pages(n_, jnp.where(more, b + 1, 0),
+                           last_page_of(n_, t_), 1 - slot, start, from_table)
+
+        # A wait needs only the destination and the semaphore.
+        for_live_pages(n, b, last_page, slot, lambda cp: cp.wait(),
+                       lambda n_, j: 0)
+
         H, hd = q_ref.shape[2], q_ref.shape[3]
         G = H // kv_heads
         # [tq, H, hd] -> [KV, tq*G, hd]: head h of column j lands at row
@@ -142,19 +272,24 @@ def _ragged_pool_kernel(pos_ref, qlen_ref, tbl_ref, lyr_ref, q_ref, k_ref,
         qg = jnp.swapaxes(
             q_ref[0].reshape(tq, kv_heads, G, hd), 0, 1
         ).reshape(kv_heads, tq * G, hd)
-        k = jnp.swapaxes(k_ref[0], 0, 1)                # [KV, page, hd]
-        v = jnp.swapaxes(v_ref[0], 0, 1)
+        span = pps * page_size
+        k = jnp.swapaxes(                               # [KV, span, hd]
+            k_buf[slot].reshape(span, kv_heads, hd), 0, 1)
+        v = jnp.swapaxes(
+            v_buf[slot].reshape(span, kv_heads, hd), 0, 1)
         s = jax.lax.dot_general(
             qg, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
-        ) * scale                                       # [KV, tq*G, page]
-        kv_ids = p * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 2
-        )
-        q_ids = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) // G
-        # Causal-in-window: column j attends kv <= pos + j; padded
-        # columns (j >= q_len) mask everything — their normalizer stays
-        # 0 and the finalize writes zeros (outputs are never read).
+        ) * scale                                       # [KV, tq*G, span]
+        # The mask is the same for every KV group: [1, tq*G, span].
+        kv_ids = b * span + jax.lax.broadcasted_iota(
+            jnp.int32, (1, 1, span), 2)
+        q_ids = q0 + div(jax.lax.broadcasted_iota(
+            jnp.int32, (1, tq * G, 1), 1), G)
+        # Causal-in-window: column j attends kv <= pos + j (which also
+        # masks the block's pages past the live span); padded columns
+        # (j >= q_len) mask everything — their normalizer stays 0 and the
+        # finalize writes zeros (outputs are never read).
         mask = jnp.logical_and(kv_ids <= pos + q_ids, q_ids < q_len)
         s = jnp.where(mask, s, -jnp.inf)
         m_prev, l_prev = m_scr[...], l_scr[...]
@@ -170,7 +305,7 @@ def _ragged_pool_kernel(pos_ref, qlen_ref, tbl_ref, lyr_ref, q_ref, k_ref,
             preferred_element_type=jnp.float32,
         )                                               # [KV, tq*G, hd]
 
-    @pl.when(p == n_pages - 1)
+    @pl.when(b == n_blk - 1)
     def _finalize():
         H, hd = o_ref.shape[2], o_ref.shape[3]
         G = H // kv_heads
@@ -231,34 +366,33 @@ def ragged_attention_pool(
     pos = positions.astype(jnp.int32)
     qln = q_lens.astype(jnp.int32)
     tbl = jnp.clip(block_tables.astype(jnp.int32), 0, n_blocks - 1)
+    # A table the block does not divide needs no padding: a page past the
+    # table is past every live span, and only live pages are looked up.
+    pps = pages_per_step(n_pages, page_size, H, KV, hd, W, k.dtype.itemsize)
 
+    grid = (N, n_qt, pl.cdiv(n_pages, pps))
     kernel = functools.partial(
         _ragged_pool_kernel, page_size=page_size, scale=scale,
-        n_pages=n_pages, kv_heads=KV, tq=tq,
+        n_pages=n_pages, kv_heads=KV, tq=tq, pps=pps, grid=grid,
     )
 
-    def q_map(n, t, p, pos_ref, qlen_ref, tbl_ref, lyr_ref):
+    def q_map(n, t, b, pos_ref, qlen_ref, tbl_ref, lyr_ref):
         return (n, t, 0, 0)
-
-    def kv_map(n, t, p, pos_ref, qlen_ref, tbl_ref, lyr_ref):
-        # Clamp dead pages to the tile's LAST LIVE page (which covers
-        # its own freshly-written rows), then indirect through the
-        # table — repeat block indices elide the fetch, pl.when elides
-        # the compute.
-        last = _last_live_page(pos_ref[n], qlen_ref[n], t * tq, tq,
-                               page_size)
-        return (lyr_ref[0], tbl_ref[n, jnp.minimum(p, last)], 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(N, n_qt, n_pages),
+        grid=grid,
         in_specs=[
             pl.BlockSpec((1, tq, H, hd), q_map),
-            pl.BlockSpec((None, 1, page_size, KV, hd), kv_map),
-            pl.BlockSpec((None, 1, page_size, KV, hd), kv_map),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((1, tq, H, hd), q_map),
         scratch_shapes=[
+            pltpu.VMEM((2, pps, page_size, KV, hd), k.dtype),
+            pltpu.VMEM((2, pps, page_size, KV, hd), v.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
             pltpu.VMEM((KV, tq * G, 1), jnp.float32),
             pltpu.VMEM((KV, tq * G, 1), jnp.float32),
             pltpu.VMEM((KV, tq * G, hd), jnp.float32),

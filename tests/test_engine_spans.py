@@ -368,10 +368,21 @@ async def test_jax_span_tree_both_routes_and_the_four_event_messages():
     queue_ms/prefill_ms/decode_ms read them until a benchmark issue
     retires the regex; the spans agree with what it recovers."""
     run = _bench_run()
-    client = await _client(_toy_jax())
+    eng = _toy_jax()
+    client = await _client(eng)
     try:
         await _ask(client, ROUTES[1], "warm every shape first")
-        before = (await (await client.get("/health")).json())["spans"]
+        health = await (await client.get("/health")).json()
+        before = health["spans"]
+        # ISSUE 30: what the ragged kernel resolved at start rides /health
+        # beside the regime: pages a grid step, and the decode program's
+        # grid steps a call (slots x one query tile x page blocks).
+        pool = health["kv_pool"]
+        assert pool["attention_regime"] == "ragged"
+        pages = pool["attention_pages_per_step"]
+        assert 1 < pages <= eng._pool_max_pages
+        assert pool["attention_decode_grid_steps"] == \
+            eng.batch_size * -(-eng._pool_max_pages // pages)
         for route in ROUTES:
             detail = await _ask(client, route, f"list pods via {route}")
             by = _check_tree(detail)

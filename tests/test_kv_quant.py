@@ -118,6 +118,9 @@ async def test_int8_kv_pool_serves_gather_and_says_why():
         health = eng.kv_pool_health()
         assert health["attention_regime"] == "gather"
         assert "KV_QUANT=int8" in health["attention_regime_reason"]
+        # no kernel, so nothing of it was resolved (ISSUE 30)
+        assert health["attention_pages_per_step"] is None
+        assert health["attention_decode_grid_steps"] is None
         r = await eng.generate("get pods -o wide", max_tokens=8,
                                temperature=0.0)
         assert r.completion_tokens > 0

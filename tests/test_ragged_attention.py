@@ -197,6 +197,63 @@ def test_ragged_kernel_query_tiles_match_reference():
         assert np.all(out[n, qn:] == 0.0)
 
 
+def _block_case(name):
+    """Cases for the page-block axis (ISSUE 30): (H, KV, W, tables' width,
+    [(position, q_len), ...]) at page 64, where the kernel resolves 8 pages
+    a step — so 11 pages are two blocks, the second of three pages."""
+    return {
+        # the block does not divide the table; spans end in both blocks,
+        # one on the table's last page
+        "table-not-divided": (4, 2, 4, 11, [(560, 4), (240, 1), (690, 4)]),
+        # the last live page is the LAST of block 0 (7), the FIRST of
+        # block 1 (8), the first page of all (0) and the table's last (15)
+        "block-edges": (4, 2, 1, 16, [(511, 1), (512, 1), (0, 1), (1023, 1)]),
+        # every slot frozen but one, which is neither first nor last
+        "all-frozen-but-one": (4, 2, 4, 16,
+                               [(70, 0), (70, 0), (616, 3), (70, 0)]),
+        # Mixtral-8x7B over model:4: a chip holds 8Q/2KV
+        "mesh-local-8q-2kv": (8, 2, 6, 11,
+                              [(640, 1), (136, 6), (0, 5), (320, 0)]),
+    }[name]
+
+
+@pytest.mark.parametrize("name", ["table-not-divided", "block-edges",
+                                  "all-frozen-but-one", "mesh-local-8q-2kv"])
+def test_ragged_kernel_page_blocks_match_reference(name):
+    """The kernel fetches and attends a block of pages a grid step: spans
+    that end anywhere in a block, a table the block does not divide and
+    frozen neighbours all match the gather reference. Every block the
+    tables do not map is NaN, so a page copied past the live span, or a
+    never-copied buffer page meeting probability 0, poisons the output."""
+    from ai_agent_kubectl_tpu.ops.ragged_attention import pages_per_step
+
+    H, KV, W, pages, spans = _block_case(name)
+    page, hd, N = 64, 16, len(spans)
+    assert pages_per_step(pages, page, H, KV, hd, W, itemsize=4) == 8
+    rng = np.random.default_rng(5)
+    n_blocks = N * pages + 1
+    k = np.full((n_blocks, page, KV, hd), np.nan, np.float32)
+    v = np.full_like(k, np.nan)
+    tables = np.full((N, pages), n_blocks + 3, np.int32)
+    for n, (pos, q_len) in enumerate(spans):
+        live = -(-(pos + max(q_len, 1)) // page)
+        tables[n, :live] = n * pages + np.arange(live)
+        k[tables[n, :live]] = rng.standard_normal((live, page, KV, hd))
+        v[tables[n, :live]] = rng.standard_normal((live, page, KV, hd))
+    q = rng.standard_normal((N, W, H, hd)).astype(np.float32)
+    positions = np.array([s[0] for s in spans], np.int32)
+    q_lens = np.array([s[1] for s in spans], np.int32)
+    out = np.asarray(ragged_attention_pool(
+        q, k, v, q_lens, positions, tables, page_size=page))
+    assert not np.isnan(out).any(), "an unmapped or uncopied page leaked"
+    ref = _reference(q, k, v, q_lens, positions, tables, page)
+    for n, qn in enumerate(q_lens):
+        np.testing.assert_allclose(out[n, :qn], ref[n, :qn],
+                                   atol=2e-5, rtol=2e-5,
+                                   err_msg=f"{name}: slot {n} (q_len={qn})")
+        assert np.all(out[n, qn:] == 0.0)
+
+
 def test_ragged_kernel_decode_column_equals_own_window():
     """Window invariance: the LAST column of a 5-wide verify window over
     positions p..p+4 equals a 1-wide decode call at position p+4 — the

@@ -166,9 +166,9 @@ _POOL_HD, _POOL_PAGE, _POOL_PAGES = 128, 64, 17
 _WARMED_WIDTHS = (1, 4, 64, 128, 256, 512, 1024)
 
 
-def _pool_case(H, KV, W, spans, seed):
-    """A block pool plus per-slot tables for ``spans`` = [(position,
-    q_len), ...]: every slot maps exactly the pages its live rows need
+def _pool_case(H, KV, W, spans, seed, pages=_POOL_PAGES):
+    """A block pool plus per-slot tables (``pages`` wide) for ``spans`` =
+    [(position, q_len), ...]: every slot maps exactly the pages its live rows need
     and the sentinel elsewhere, slot 1 shares slot 0's first block (a
     radix-shared prefix page), and the block the sentinel clamps to is
     NaN in the pool the kernel reads — a fetch that escaped the
@@ -178,14 +178,14 @@ def _pool_case(H, KV, W, spans, seed):
     import numpy as np
 
     N = len(spans)
-    n_blocks = N * _POOL_PAGES + 1
+    n_blocks = N * pages + 1
     k = _rand((n_blocks, _POOL_PAGE, KV, _POOL_HD), seed, jnp.bfloat16)
     v = _rand((n_blocks, _POOL_PAGE, KV, _POOL_HD), seed + 1, jnp.bfloat16)
     q = _rand((N, W, H, _POOL_HD), seed + 2, jnp.bfloat16)
-    tables = np.full((N, _POOL_PAGES), n_blocks + 5, np.int32)
+    tables = np.full((N, pages), n_blocks + 5, np.int32)
     for n, (pos, q_len) in enumerate(spans):
         live = -(-(pos + max(q_len, 1)) // _POOL_PAGE)
-        tables[n, :live] = n * _POOL_PAGES + np.arange(live)
+        tables[n, :live] = n * pages + np.arange(live)
     tables[1, 0] = tables[0, 0]
     dead = n_blocks - 1
     clean = (k.at[dead].set(0), v.at[dead].set(0))
@@ -204,8 +204,8 @@ def _gather_reference(q, k, v, q_lens, positions, tables):
     from ai_agent_kubectl_tpu.models.transformer import _pool_gather
     from ai_agent_kubectl_tpu.ops.attention import dense_attention
 
-    W = q.shape[1]
-    kv_len = _POOL_PAGES * _POOL_PAGE
+    W, pages = q.shape[1], tables.shape[1]
+    kv_len = pages * _POOL_PAGE
     cols = jnp.arange(W)[None, :, None]
     kv_pos = jnp.arange(kv_len)[None, None, :]
     mask = jnp.logical_and(kv_pos <= positions[:, None, None] + cols,
@@ -213,8 +213,8 @@ def _gather_reference(q, k, v, q_lens, positions, tables):
     with jax.default_matmul_precision("highest"):
         return dense_attention(
             q.astype(jnp.float32),
-            _pool_gather(k, tables, _POOL_PAGES).astype(jnp.float32),
-            _pool_gather(v, tables, _POOL_PAGES).astype(jnp.float32),
+            _pool_gather(k, tables, pages).astype(jnp.float32),
+            _pool_gather(v, tables, pages).astype(jnp.float32),
             mask)
 
 
@@ -279,21 +279,23 @@ def test_compiled_ragged_stacked_pool_layer_equals_layer_slice(W):
 
 
 @pytest.mark.parametrize("H,KV", _POOL_GEOMETRIES)
-def test_compiled_ragged_pool_decode_batch_matches_gather(H, KV):
+@pytest.mark.parametrize("pages", (_POOL_PAGES, 64))
+def test_compiled_ragged_pool_decode_batch_matches_gather(H, KV, pages):
     """Compiled ragged kernel at q_len = 1 vs the dense gather reference
     over a full batch of ragged positions (first row of a sequence, page
-    edges, the last row the table can hold)."""
+    edges, the last row the table can hold), at a table the page block
+    does not divide (17) and at MAX_SEQ_LEN 4096's width (64)."""
     import numpy as np
 
     from ai_agent_kubectl_tpu.ops.ragged_attention import \
         ragged_attention_pool
 
-    edge = [0, _POOL_PAGE - 1, _POOL_PAGE, _POOL_PAGES * _POOL_PAGE - 1]
+    edge = [0, _POOL_PAGE - 1, _POOL_PAGE, pages * _POOL_PAGE - 1]
     rng = np.random.RandomState(1)
     spans = [(int(p), 1) for p in edge + list(
-        rng.randint(0, _POOL_PAGES * _POOL_PAGE, 32 - len(edge)))]
+        rng.randint(0, pages * _POOL_PAGE, 32 - len(edge)))]
     q, (k, v), clean, q_lens, positions, tables = _pool_case(
-        H, KV, 1, spans, seed=40)
+        H, KV, 1, spans, seed=40, pages=pages)
     out = np.asarray(ragged_attention_pool(
         q, k, v, q_lens, positions, tables, page_size=_POOL_PAGE,
         interpret=False)).astype(np.float32)
