@@ -46,7 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.transformer import KVCache, forward
+from ..models.transformer import KVCache, forward, serves_grouped
 from ..obs.ledger import (CLASS_DELIVERED, CLASS_DRAFT_REJECTED,
                           CLASS_HEDGE_LOSER, CLASS_PREEMPTED,
                           CLASS_QUARANTINE_BURN, CLASS_REPLAYED,
@@ -298,6 +298,7 @@ def make_termination_chunk_fn(forward_step, chunk_len: int, eos_ids,
                 gs = jnp.where(is_adm, adm[7], gs)
         live0 = jnp.logical_and(active, force)
         health0 = jnp.zeros_like(ngen)
+        cache = _zero_counts(cache)
         tc = None
         if grammar:
             # Per-slot token→class rows, hoisted OUT of the scan: the
@@ -410,7 +411,9 @@ def make_termination_chunk_fn(forward_step, chunk_len: int, eos_ids,
             toks = jnp.concatenate([nxt0[:, None], toks], axis=1)
         done = jnp.logical_and(force, jnp.logical_not(live))
         packed = finalize(pack_chunk(toks, done, ngen, jnp.sum(live),
-                                     health=health, xp=jnp))
+                                     health=health,
+                                     experts_read=cache.experts_read,
+                                     sel_rows=cache.sel_rows, xp=jnp))
         out = (packed, tok, pos, cache, live, ngen)
         if grammar:
             out = out + (gs,)
@@ -442,6 +445,7 @@ def make_termination_chunk_fn(forward_step, chunk_len: int, eos_ids,
                 gs = jnp.where(is_adm, adm[7], gs)
         live0 = jnp.logical_and(active, force)
         health0 = jnp.zeros_like(ngen)
+        cache = _zero_counts(cache)
         zeros = jnp.zeros_like(ngen)
         tc = None
         if grammar:
@@ -633,7 +637,9 @@ def make_termination_chunk_fn(forward_step, chunk_len: int, eos_ids,
         done = jnp.logical_and(force, jnp.logical_not(live))
         packed = finalize(pack_chunk(buf, done, ngen, jnp.sum(live),
                                      health=health, drafted=drafted,
-                                     accepted=accepted, xp=jnp))
+                                     accepted=accepted,
+                                     experts_read=cache.experts_read,
+                                     sel_rows=cache.sel_rows, xp=jnp))
         out = (packed, tok, pos, cache, live, ngen, dcache)
         if grammar:
             out = out + (gs,)
@@ -773,6 +779,49 @@ def make_termination_chunk_fn(forward_step, chunk_len: int, eos_ids,
                                   force, active, ngen, budget, corrupt)
 
     return batched_chunk
+
+
+def _zero_counts(cache):
+    """The chunk program counts what its own passes read and kept."""
+    zeroed = {name: jnp.zeros_like(getattr(cache, name))
+              for name in ("experts_read", "sel_rows")
+              if getattr(cache, name) is not None}
+    return dataclasses.replace(cache, **zeroed) if zeroed else cache
+
+
+def staged_suffix_len(suffix: int, buckets) -> int:
+    """How many of an admission's ``suffix`` unmatched tokens ride the next
+    chunk's window; the rest, its head, prefills eagerly. A suffix the
+    widest window holds rides whole. A longer one's head is eager
+    whatever rides, and a window costs EVERY slot of the batch its width
+    (N x W rows through the projections and the MLP, where an eager piece
+    pays for one slot's), so it rides the narrowest: 64 rows against 512
+    read first_chunk 1,207 against 1,568 ms on 11k-token prompts (my chip
+    runs, PR 31)."""
+    return suffix if suffix <= buckets[-1] else buckets[0]
+
+
+def selection_refusal(model_cfg, regime: str, mesh_shape, kv_quant: str
+                      ) -> Optional[str]:
+    """Why this engine cannot serve a key-selecting configuration
+    (``ModelConfig.index_topk``), or None. Its index keys are a leaf of
+    the block pool, one device's whole: the dense per-slot ladder, an
+    int8 pool and a mesh carry no such leaf."""
+    if not model_cfg.selects_keys:
+        return None
+    why = None
+    if regime == DENSE:
+        why = ("the dense per-slot KV ladder has no index-key leaf "
+               "(KV_POOL=false, or a mesh axis the pool refuses)")
+    elif kv_quant:
+        why = f"KV_QUANT={kv_quant}: key selection reads a bf16 pool"
+    elif any(n > 1 for n in (mesh_shape or {}).values()):
+        why = (f"MESH_SHAPE {dict(mesh_shape)}: the index-key leaf and the "
+               f"selected-row fetch are not sharded")
+    if why is None:
+        return None
+    return (f"{model_cfg.name} selects its keys (index_topk="
+            f"{model_cfg.index_topk}) and is not served here: {why}")
 
 
 @dataclasses.dataclass
@@ -1057,6 +1106,15 @@ class BatchedJaxEngine(JaxEngine):
         self._use_ragged = False
         self._attention_regime = DENSE
         self._attention_regime_reason = "not started"
+        # ISSUE 31 counters (/health.moe, /health.sparse_attention)
+        self._counts_experts = False
+        self._moe_experts_read = 0
+        self._moe_layer_passes = 0
+        # window rows are the scheduler's arithmetic on prompt lengths; what
+        # decode queries saw and kept is counted on the device (sel_rows)
+        self._selection_counts = dict.fromkeys(
+            ("index_rows_scanned", "window_rows", "forward_passes"), 0)
+        self._sel_rows_dev = [0, 0]
         self._attention_steps = (None, None)
         self._ragged_chunk_fns: dict = {}   # (adm width, spec) -> jitted
         # slot_idx -> staged admission (ids/start/ngen0/budget/seed/
@@ -1395,6 +1453,20 @@ class BatchedJaxEngine(JaxEngine):
         self._attention_regime_reason = reason
         self._use_pool = regime != DENSE
         self._use_ragged = regime == RAGGED
+        refusal = selection_refusal(
+            self.model_cfg, regime,
+            dict(self.mesh.shape) if self.mesh is not None else None,
+            self.kv_quant)
+        if refusal:
+            # A selecting configuration's index keys live in the block
+            # pool's own leaf: what cannot carry that leaf refuses the
+            # model at start (server: engine "degraded", this reason).
+            logger.error("%s", refusal)
+            raise ValueError(refusal)
+        # The grouped expert path counts the experts it reads
+        # (models/transformer.py::_moe_mlp's own rule).
+        self._counts_experts = self._use_pool and serves_grouped(
+            self.model_cfg, self.mesh, self.moe_impl)
         # The static ``attn_impl`` of every chunk-program forward.
         self._decode_impl = RAGGED if self._use_ragged else "dense"
         self._kv_pool_mesh_fallback = self.kv_pool and not self._use_pool
@@ -2133,6 +2205,7 @@ class BatchedJaxEngine(JaxEngine):
                  cfg.n_kv_heads, cfg.head_dim)
         dtype, kv_quant = self.dtype, self.kv_quant
         n_blocks = self._pool_n_blocks
+        counts_experts = self._counts_experts
 
         def make() -> KVCache:
             lengths = jnp.zeros((n_blocks,), jnp.int32)
@@ -2144,8 +2217,19 @@ class BatchedJaxEngine(JaxEngine):
                                    s=jnp.ones(shape[:-1], jnp.float32))
 
                 return KVCache(k=zq(), v=zq(), lengths=lengths)
-            return KVCache(k=jnp.zeros(shape, dtype),
-                           v=jnp.zeros(shape, dtype), lengths=lengths)
+            # A selecting configuration's index keys: one row a token a
+            # layer in the SAME blocks. Where the grouped expert path
+            # serves, the count of experts it read since the chunk
+            # program zeroed it rides the cache too.
+            return KVCache(
+                k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
+                lengths=lengths,
+                ik=(jnp.zeros(shape[:3] + (cfg.index_key_width,), dtype)
+                    if cfg.selects_keys else None),
+                experts_read=(jnp.zeros((), jnp.int32)
+                              if counts_experts else None),
+                sel_rows=(jnp.zeros((2,), jnp.int32)
+                          if cfg.selects_keys else None))
 
         if self.mesh is None:
             return make()
@@ -2319,9 +2403,12 @@ class BatchedJaxEngine(JaxEngine):
                     return cp(leaf, src_b, dst_b, rows)
 
                 with jax.named_scope("kv_splice"):
-                    return KVCache(k=jax.tree.map(one, cache.k),
-                                   v=jax.tree.map(one, cache.v),
-                                   lengths=cache.lengths)
+                    # every paged leaf: K, V and (a selecting
+                    # configuration's) index keys
+                    return dataclasses.replace(
+                        cache, k=jax.tree.map(one, cache.k),
+                        v=jax.tree.map(one, cache.v),
+                        ik=jax.tree.map(one, cache.ik))
 
             fn = jax.jit(cow, donate_argnums=(0,))
             self._pool_cow_jit = fn
@@ -2340,7 +2427,8 @@ class BatchedJaxEngine(JaxEngine):
         (QuantKV under int8 contributes q and s leaves), so onload can
         split the bytes back by the same walk — the checksum stamped
         over this buffer covers every quantized leaf too."""
-        leaves = jax.tree_util.tree_leaves((self._cache.k, self._cache.v))
+        leaves = jax.tree_util.tree_leaves(
+            (self._cache.k, self._cache.v, self._cache.ik))
         parts = [np.ascontiguousarray(jax.device_get(leaf[:, block]))
                  for leaf in leaves]
         return np.concatenate(
@@ -2353,7 +2441,7 @@ class BatchedJaxEngine(JaxEngine):
         scatter on the existing leaves."""
         flat = np.ascontiguousarray(np.asarray(data, dtype=np.uint8))
         kv, treedef = jax.tree_util.tree_flatten(
-            (self._cache.k, self._cache.v))
+            (self._cache.k, self._cache.v, self._cache.ik))
         off, out = 0, []
         for leaf in kv:
             sub = (leaf.shape[0],) + tuple(leaf.shape[2:])
@@ -2364,8 +2452,8 @@ class BatchedJaxEngine(JaxEngine):
             off += n
             out.append(leaf.at[:, block].set(
                 jnp.asarray(part, dtype=leaf.dtype)))
-        k, v = jax.tree_util.tree_unflatten(treedef, out)
-        self._cache = KVCache(k=k, v=v, lengths=self._cache.lengths)
+        k, v, ik = jax.tree_util.tree_unflatten(treedef, out)
+        self._cache = dataclasses.replace(self._cache, k=k, v=v, ik=ik)
 
     def _pool_alloc(self, n: int) -> Optional[List[int]]:
         """Allocate with radix-eviction backpressure (kv_pool.py helper,
@@ -2411,6 +2499,7 @@ class BatchedJaxEngine(JaxEngine):
                 self.params, jnp.asarray(tokens), jnp.asarray(positions),
                 self._cache, jnp.asarray(mask), tables_d)
             offset += L
+            self._selection_counts["forward_passes"] += 1
         return logits[:, 0]
 
     def _pool_ensure_coverage(self, idx: int, slot: "_Slot",
@@ -2545,9 +2634,9 @@ class BatchedJaxEngine(JaxEngine):
                 # prologue prefills, samples, and arms in ONE program
                 # with everyone else's decode step (same fold_in
                 # indices and grammar advance as the legacy path —
-                # byte-identical transcripts). Only the head beyond the
-                # widest admission window prefills eagerly.
-                stage_start = max(m, len(span) - self.prefill_buckets[-1])
+                # byte-identical transcripts).
+                stage_start = len(span) - staged_suffix_len(
+                    len(span) - m, self.prefill_buckets)
                 if stage_start > m:
                     self._pool_prefill_span(
                         self._tables[slot_idx], span[:stage_start], m)
@@ -2626,6 +2715,12 @@ class BatchedJaxEngine(JaxEngine):
         self._spans.note_slots(self._slots)
         prefill_meta = dict(prompt_tokens=n_prompt, prefix_hit_tokens=m,
                             staged_w=len(staged["ids"]) if staged else 0)
+        if self.model_cfg.selects_keys:
+            # window rows m .. n_prompt-1, each scanning the index keys
+            # up to its own
+            self._selection_counts["window_rows"] += n_prompt - m
+            self._selection_counts["index_rows_scanned"] += (
+                n_prompt * (n_prompt + 1) - m * (m + 1)) // 2
         if run:
             t_dk = time.monotonic()
             piece = slot.detok.push(*run)
@@ -2684,6 +2779,20 @@ class BatchedJaxEngine(JaxEngine):
             min(pages_for(b, self.kv_pool_page), self._pool_max_pages))
         row[:len(blocks)] = blocks
         self._pool_prefill_span(row, [0] * b, 0)
+        if cfg.selects_keys:
+            # A selecting configuration is served for prompts far past
+            # the widest bucket: their heads are prefilled eagerly, piece
+            # by piece, and the last piece of a head may be any bucket
+            # (one compiled inside a measured window otherwise). It is
+            # the one kind served with such prompts; warming these for
+            # every model would add compiles to every other start.
+            for wb in self.prefill_buckets[1:]:
+                more = self._pool.alloc(min(
+                    pages_for(wb, self.kv_pool_page), self._pool_max_pages))
+                wide = row.copy()
+                wide[:len(more)] = more
+                self._pool_prefill_span(wide, [0] * wb, 0)
+                self._pool.decref(more)
         self._key_d = jax.random.PRNGKey(self.seed)
         self._sample_fn(
             jnp.zeros((1, cfg.vocab_size), jnp.float32), self._key_d,
@@ -2844,12 +2953,52 @@ class BatchedJaxEngine(JaxEngine):
         counts and mesh gates fall back LOUDLY here — and, under ragged,
         what the kernel resolved at start (null otherwise)."""
         pages, steps = self._attention_steps
+        cfg = self.model_cfg
         return {
             "attention_regime": self._attention_regime,
             "attention_regime_reason": self._attention_regime_reason,
             "attention_pages_per_step": pages,
             "attention_decode_grid_steps": steps,
+            # What a selecting configuration resolved at start: how many
+            # keys a query keeps, and how each form reads them.
+            "attention_selects_keys": (
+                {"index_topk": cfg.index_topk,
+                 "index_heads": cfg.index_heads,
+                 "index_head_dim": cfg.index_head_dim,
+                 "rows": f"exact top-k of the index scores as a per-row "
+                         f"mask on the {self._attention_regime} path's "
+                         f"causal scores, decode and window rows alike",
+                 "dense_while_ctx_at_most": cfg.index_topk}
+                if cfg.selects_keys else None),
         }
+
+    def moe_health(self) -> Optional[dict]:
+        """/health.moe: experts whose weights the grouped expert path
+        read, and the layer passes they were read in, both over the chunk
+        programs' passes (cumulative host counters; None where another
+        MoE path, or none, serves)."""
+        if not self._counts_experts:
+            return None
+        return {"experts_read": self._moe_experts_read,
+                "layer_passes": self._moe_layer_passes}
+
+    def sparse_attention_health(self) -> Optional[dict]:
+        """/health.sparse_attention (cumulative; None for a configuration
+        that attends to every key). ``decode_rows_live`` /
+        ``decode_rows_selected``: the keys the chunk programs' decode
+        queries had before them and the keys the selector's mask kept of
+        those, counted on the device in every layer and given per layer.
+        ``window_rows`` and the window part of ``index_rows_scanned`` are
+        the scheduler's arithmetic on prompt lengths (window row t scans
+        t + 1 index keys); a decode query scans its live keys."""
+        if not self.model_cfg.selects_keys:
+            return None
+        live, kept = (n // self.model_cfg.n_layers
+                      for n in self._sel_rows_dev)
+        c = dict(self._selection_counts, decode_rows_live=live,
+                 decode_rows_selected=kept)
+        c["index_rows_scanned"] += live
+        return c
 
     def kv_pool_health(self) -> Optional[dict]:
         """Cheap pool view for /health (never stats() — same rule as
@@ -3490,6 +3639,10 @@ class BatchedJaxEngine(JaxEngine):
             # — delta-mirrored into Prometheus at scrape time
             # (Metrics.observe_kv_pool) and summarized in /health.
             "kv_pool": self.kv_pool_health(),
+            # Grouped expert GEMM / key selection (ISSUE 31): cumulative
+            # counters, null where the configuration has neither.
+            "moe": self.moe_health(),
+            "sparse_attention": self.sparse_attention_health(),
             "sharding": self.sharding_health(),
             "queue_rejections": self._rejections,
             "max_queue_depth": self.max_queue_depth,
@@ -5229,13 +5382,17 @@ class BatchedJaxEngine(JaxEngine):
         self._to_host_async(packed_d)  # overlap the transfer (see _admit_one)
         chunks_ahead = self._chunks_in_pipe()
         self._chunks_dispatched += 1
+        # forward passes of this chunk program (a spec chunk: its verifies)
+        self._selection_counts["forward_passes"] += (
+            self._spec_steps if spec else self.chunk_len)
         self._inflight.append(("chunk", packed_d, snapshot, ct, spec,
                                self._chunks_dispatched))
         for i in staged:
             # This chunk carries slot i's prologue: its stage_wait ends
             # where this dispatch began, behind the chunks already queued.
             self._spans.of(self._slots[i].req).dispatched(
-                t_disp, self._chunks_dispatched, chunks_ahead, adm_w=adm_w)
+                t_disp, self._chunks_dispatched, chunks_ahead, adm_w=adm_w,
+                ctx_tokens=self._slots[i].n_prompt)
         entry.update(kv_bucket=bucket, slots=len(active_slots),
                      admissions=len(staged), pipe=chunks_ahead + 1)
 
@@ -5383,8 +5540,22 @@ class BatchedJaxEngine(JaxEngine):
         with self._spans.sched.region("consume", "consume", chunk=chunk_no,
                                       fetch_ms=fetched["ms"],
                                       pipe=self._chunks_in_pipe()) as consumed:
-            res = unpack_chunk(buf, self.batch_size, ct, spec=is_spec)
+            res = unpack_chunk(buf, self.batch_size, ct, spec=is_spec,
+                               moe=self._counts_experts,
+                               sel=self.model_cfg.selects_keys)
             consumed["n_alive"] = res.n_alive
+            if res.experts_read is not None:
+                # One pass a scan step (the prologue is one of them), each
+                # through every layer.
+                self._moe_experts_read += res.experts_read
+                self._moe_layer_passes += (
+                    (self._spec_steps if is_spec else self.chunk_len)
+                    * self.model_cfg.n_layers)
+            if res.sel_rows is not None:
+                # The device's own count of what its decode queries saw
+                # and kept, summed over the layers of every step.
+                self._sel_rows_dev[0] += res.sel_rows[0]
+                self._sel_rows_dev[1] += res.sel_rows[1]
             self._consume_chunk(res, snapshot, ct, is_spec)
 
     def _consume_chunk(self, res, snapshot, ct: int, is_spec: bool) -> None:

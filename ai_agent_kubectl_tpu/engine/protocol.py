@@ -139,10 +139,14 @@ def describe_health(word: int) -> str:
 
 
 def packed_chunk_size(n_slots: int, chunk_len: int,
-                      spec: bool = False) -> int:
+                      spec: bool = False, moe: bool = False,
+                      sel: bool = False) -> int:
     """Flat length of one packed chunk buffer (``spec`` adds the two
-    per-slot drafted/accepted lanes of the v3 speculative contract)."""
-    return n_slots * chunk_len + (5 if spec else 3) * n_slots + 1
+    per-slot drafted/accepted lanes of the v3 speculative contract,
+    ``moe`` the one-word ``experts_read`` lane, ``sel`` the two-word
+    ``sel_rows`` lane)."""
+    return (n_slots * chunk_len + (5 if spec else 3) * n_slots + 1
+            + (1 if moe else 0) + (2 if sel else 0))
 
 
 @dataclass
@@ -158,10 +162,20 @@ class ChunkResult:
     #: each slot THIS chunk. All-zero when the chunk ran plain decode.
     drafted: Optional[np.ndarray] = None   # [n_slots] int32
     accepted: Optional[np.ndarray] = None  # [n_slots] int32
+    #: grouped expert GEMM (optional lane): experts whose weights the
+    #: chunk's passes read, summed over layers and steps. None where the
+    #: chunk program has no such path (every engine but the jax batcher
+    #: serving ``ModelConfig.grouped_experts``; the fake never sets it).
+    experts_read: Optional[int] = None
+    #: key selection (optional lane): (keys the chunk's decode queries had
+    #: before them, keys the selector kept of those), summed over layers
+    #: and steps. None where the chunk program selects no keys.
+    sel_rows: Optional[tuple] = None
 
 
 def pack_chunk(tokens, done, lengths, n_alive, *, health=None,
-               drafted=None, accepted=None, xp=np):
+               drafted=None, accepted=None, experts_read=None,
+               sel_rows=None, xp=np):
     """Flatten one chunk's results into the single-fetch buffer.
 
     ``xp`` is the array namespace — ``numpy`` for the fake engine,
@@ -169,7 +183,9 @@ def pack_chunk(tokens, done, lengths, n_alive, *, health=None,
     happens on device and the scheduler fetches one array). ``health``
     defaults to all-healthy for callers predating the v2 lane;
     ``drafted``/``accepted`` (v3) ride only when the chunk ran the
-    speculative draft/verify body — pass both or neither."""
+    speculative draft/verify body — pass both or neither.
+    ``sel_rows`` (two words) and ``experts_read`` (a scalar) ride, when
+    given, in that order just before ``n_alive``."""
     done = done.astype(xp.int32)
     if health is None:
         health = xp.zeros_like(done)
@@ -185,19 +201,26 @@ def pack_chunk(tokens, done, lengths, n_alive, *, health=None,
     if drafted is not None:
         parts.append(drafted.astype(xp.int32))
         parts.append(accepted.astype(xp.int32))
+    if sel_rows is not None:
+        parts.append(xp.reshape(xp.asarray(sel_rows, dtype=xp.int32), (2,)))
+    if experts_read is not None:
+        parts.append(xp.reshape(xp.asarray(experts_read, dtype=xp.int32),
+                                (1,)))
     parts.append(xp.reshape(xp.asarray(n_alive, dtype=xp.int32), (1,)))
     return xp.concatenate(parts)
 
 
 def unpack_chunk(buf, n_slots: int, chunk_len: int,
-                 spec: bool = False) -> ChunkResult:
+                 spec: bool = False, moe: bool = False,
+                 sel: bool = False) -> ChunkResult:
     """Inverse of ``pack_chunk`` (always numpy — this is the host side)."""
     buf = np.asarray(buf)
-    want = packed_chunk_size(n_slots, chunk_len, spec=spec)
+    want = packed_chunk_size(n_slots, chunk_len, spec=spec, moe=moe, sel=sel)
     if buf.shape != (want,):
         raise ValueError(
             f"packed chunk buffer has shape {buf.shape}, expected ({want},) "
-            f"for n_slots={n_slots} chunk_len={chunk_len} spec={spec}")
+            f"for n_slots={n_slots} chunk_len={chunk_len} spec={spec}"
+            + (" moe=True" if moe else "") + (" sel=True" if sel else ""))
     nt = n_slots * chunk_len
     drafted = accepted = None
     if spec:
@@ -211,6 +234,9 @@ def unpack_chunk(buf, n_slots: int, chunk_len: int,
         n_alive=int(buf[-1]),
         drafted=drafted,
         accepted=accepted,
+        experts_read=int(buf[-2]) if moe else None,
+        sel_rows=((int(buf[-4 if moe else -3]), int(buf[-3 if moe else -2]))
+                  if sel else None),
     )
 
 

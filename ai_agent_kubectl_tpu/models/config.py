@@ -8,6 +8,11 @@ test models. Family differences are expressed as data, not subclasses:
            head_dim 256, MHA (7B) / MQA (2B)
 - Llama-3: plain RMSNorm, SiLU-GLU, GQA 8 KV heads, theta 500k, untied
 - Mixtral: Llama geometry + 8-expert top-2 MoE MLP
+- Keye-VL-2.0-30B-A3B's language model (registered by the benchmark from
+  benchmark/configs/, not a preset here): QK-norm, 128 experts of their
+  own width through the grouped expert path (parallel/moe.py), and a
+  learned top-k key selector (``index_topk``) inside paged attention
+  whose keys live in a pool leaf of their own
 """
 
 from __future__ import annotations
@@ -35,6 +40,23 @@ class ModelConfig:
     # MoE (0 experts = dense MLP)
     n_experts: int = 0
     experts_per_token: int = 0
+    # Width of the source's dense MLP where every layer is sparse and the
+    # experts' own width is ``mlp_hidden`` (the Qwen3-MoE family's
+    # ``intermediate_size`` beside ``moe_intermediate_size``): recorded so
+    # the source's key has a field, computed by nothing.
+    dense_mlp_hidden: int = 0
+    # Per-head RMSNorm on q and k before the rotary embedding (weights
+    # ``q_norm``/``k_norm`` [head_dim]; the Qwen3 convention).
+    qk_norm: bool = False
+    # Learned sparse attention (DeepSeek-V3.2-Exp's lightning indexer on a
+    # GQA model): query t scores every key s <= t with ``index_heads``
+    # heads of ``index_head_dim`` against ONE index key a token,
+    # I[t,s] = sum_j w[t,j] relu(q'[t,j] . k'[s]), and attends only to the
+    # ``index_topk`` best (ties to the lower s), one set for all heads.
+    # 0 = every key (no indexer leaves, no index-key cache).
+    index_topk: int = 0
+    index_heads: int = 0
+    index_head_dim: int = 0
     # Special tokens (tokenizer-dependent; defaults overridden per family)
     bos_id: int = 1
     eos_ids: Tuple[int, ...] = (2,)
@@ -44,6 +66,38 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def selects_keys(self) -> bool:
+        return self.index_topk > 0
+
+    @property
+    def index_key_width(self) -> int:
+        """Row width of the index-key pool leaf: ``index_head_dim`` rounded
+        up to the TPU's 128 lanes (zeros above the key). A 64-wide bf16
+        minor dim has no row-major home in HBM: the compiler either pads
+        it to 128 lanes or, as it chose for [L, blocks, 64, 64], lays the
+        BLOCK axis minor-most, which turns every page read into a strided
+        one and put a copy of the whole leaf in front of each (AOT, PR 31).
+        Rows of 128 lanes are written and read like K and V."""
+        return -(-self.index_head_dim // 128) * 128
+
+    @property
+    def grouped_experts(self) -> bool:
+        """The one static rule that picks the MoE path on one device
+        (models/transformer.py::_moe_mlp). Each shipped family is on the
+        only path that can serve it: ``dense_moe`` evaluates every expert
+        for every token, n_experts / experts_per_token times the picked
+        experts' arithmetic — 4x at Mixtral's 8 experts, top-2, but 16x
+        and ~5 GB of [8192, 128, 768] temporaries a 512-wide window at 128
+        experts, top-8; the token-grouped GEMM holds a whole expert in VMEM,
+        which three 4096 x 14336 matrices (337 MB against 128 MiB) do not
+        fit (AOT, PR 31: tests/test_tpu_aot.py pins the refusal). The 8x
+        sits between the two; nothing between them has been timed on the
+        chip, and the threshold is to be measured once an F-tiled grouped
+        kernel can take wide experts (ROADMAP S2)."""
+        return (self.n_experts > 0
+                and self.n_experts >= 8 * self.experts_per_token)
 
     @property
     def q_per_kv(self) -> int:
@@ -60,6 +114,10 @@ class ModelConfig:
         mlp_units = max(self.n_experts, 1)
         mlp = self.n_layers * mlp_units * 3 * self.dim * self.mlp_hidden
         router = self.n_layers * self.dim * self.n_experts
+        if self.selects_keys:
+            attn += self.n_layers * self.dim * (
+                self.index_heads * self.index_head_dim    # idx_wq
+                + self.index_head_dim + self.index_heads)  # idx_wk, idx_ww
         norms = self.n_layers * 2 * self.dim + self.dim
         head = 0 if self.tie_embeddings else self.vocab_size * self.dim
         return embed + attn + mlp + router + norms + head
@@ -82,6 +140,15 @@ TOY_MOE = _register(ModelConfig(
     name="toy-moe", vocab_size=512, dim=256, n_layers=2, n_heads=4,
     n_kv_heads=2, head_dim=64, mlp_hidden=448, n_experts=4,
     experts_per_token=2, max_seq_len=2048,
+))
+# Many small experts through the grouped expert path, QK-norm, and a key
+# selector whose top-k (48) the tests' prompts cross: the toy of the
+# benchmark's keye-vl-2.0-30b-a3b-l8 configuration.
+TOY_SPARSE_MOE = _register(ModelConfig(
+    name="toy-sparse-moe", vocab_size=512, dim=128, n_layers=2, n_heads=4,
+    n_kv_heads=2, head_dim=64, mlp_hidden=64, n_experts=16,
+    experts_per_token=2, qk_norm=True, index_topk=48, index_heads=4,
+    index_head_dim=32, max_seq_len=2048,
 ))
 
 # --- Gemma (HF: google/gemma-{2b,7b}-it) ---

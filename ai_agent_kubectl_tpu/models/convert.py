@@ -73,6 +73,15 @@ def convert_hf_checkpoint(
     (ops/quant4.py::pick_format). ``quantize_embed`` stores the
     embedding per-row int8 (the tied-head read halves).
     """
+    if cfg.qk_norm or cfg.selects_keys:
+        # The Keye-VL-2.0 family (QK-norm, a key selector with its own
+        # projections, experts under the Qwen3-MoE names): its checkpoint
+        # names are not published with what this repo has, and a guessed
+        # mapping would load the wrong tensors in silence.
+        raise NotImplementedError(
+            f"{cfg.name}: no checkpoint mapping for a configuration with "
+            f"QK-norm or a key selector (qk_norm={cfg.qk_norm}, index_topk="
+            f"{cfg.index_topk}); it is served with seeded weights only")
     get, keys = _open_checkpoint(path)
     pfx = "model." if any(k.startswith("model.") for k in keys) else ""
     L = cfg.n_layers

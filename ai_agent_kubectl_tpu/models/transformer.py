@@ -51,11 +51,32 @@ class KVCache:
              model dtype, or ``QuantKV`` (int8 payload + per-(position,
              head) f32 scales) when built with ``kv_quant="int8"``
     lengths: [batch] — number of valid positions per slot
+    ik:      pool mode of a selecting configuration only
+             (``ModelConfig.index_topk``): the indexer's keys, [n_layers,
+             n_blocks, page, index_key_width] (the key in the first
+             ``index_head_dim`` lanes), addressed by the K pool's
+             block table — written, shared and copied wherever K and V
+             are. Absent (None) everywhere else; ``forward``, given a
+             selecting configuration and no leaf, makes a zero one of the
+             K pool's block geometry and returns it.
+    experts_read: int32 scalar, where the grouped expert path serves: the
+             experts whose weights it has read (summed over layers and
+             passes) since the chunk program last zeroed it
+             (/health.moe.experts_read). Absent elsewhere.
+    sel_rows: int32 [2], for a selecting configuration's engine: the keys
+             its DECODE queries had before them and the keys the selector
+             kept of those (the mask the kernel applies, counted where it
+             is made), summed over layers and passes since the chunk
+             program last zeroed it (/health.sparse_attention). Absent
+             elsewhere.
     """
 
     k: Any
     v: Any
     lengths: jnp.ndarray
+    ik: Any = None
+    experts_read: Any = None
+    sel_rows: Any = None
 
     @classmethod
     def zeros(cls, cfg: ModelConfig, batch: int, max_seq: int,
@@ -102,6 +123,14 @@ def init_params(key: jax.Array, cfg: ModelConfig, dtype=jnp.bfloat16) -> Params:
         "wo": _dense_init(next(keys), (L, H * hd, d), (H * hd) ** -0.5),
         "mlp_norm": jnp.zeros((L, d), dtype) if cfg.rms_offset else jnp.ones((L, d), dtype),
     }
+    if cfg.qk_norm:
+        layers["q_norm"] = jnp.ones((L, hd), dtype)
+        layers["k_norm"] = jnp.ones((L, hd), dtype)
+    if cfg.selects_keys:
+        J, di = cfg.index_heads, cfg.index_head_dim
+        layers["idx_wq"] = _dense_init(next(keys), (L, d, J * di), s_in)
+        layers["idx_wk"] = _dense_init(next(keys), (L, d, di), s_in)
+        layers["idx_ww"] = _dense_init(next(keys), (L, d, J), s_in)
     if cfg.is_moe:
         E = cfg.n_experts
         layers["router"] = _dense_init(next(keys), (L, d, E), s_in)
@@ -257,18 +286,35 @@ def _dense_mlp(cfg: ModelConfig, lp: Params, x: jnp.ndarray) -> jnp.ndarray:
     return qmatmul(gate * qmatmul(x, lp["w_up"]), lp["w_down"])
 
 
+#: The expert leaves of a layer (stacked [L, E, in, out] in the param tree).
+EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
+
+
+def serves_grouped(cfg: ModelConfig, mesh, moe_impl: str) -> bool:
+    """Whether ``_moe_mlp`` takes the token-grouped expert GEMM: one
+    device, no forced implementation, and the configuration's own static
+    rule (``ModelConfig.grouped_experts``)."""
+    return cfg.grouped_experts and mesh is None and moe_impl == "auto"
+
+
 def _moe_mlp(cfg: ModelConfig, lp: Params, x: jnp.ndarray,
              mesh=None, token_mask=None,
-             moe_impl: str = "auto") -> jnp.ndarray:
+             moe_impl: str = "auto", layer=None):
     """MoE MLP with impl selection (the seam VERDICT r2 item 2 asked for).
+    Returns (y, experts_read): the count is the grouped path's, None on
+    the others.
 
     ``moe_impl``:
 
     - ``auto``: the expert-parallel all-to-all dispatch
       (parallel/moe.py::expert_parallel_moe) whenever a mesh with a >1
       ``expert`` axis is in scope and the static shapes divide it;
-      otherwise the dense all-experts evaluation — the single-device
-      reference the EP path is parity-tested against.
+      otherwise, on one device, the token-grouped expert GEMM
+      (parallel/moe.py::grouped_moe) for a configuration with many
+      experts a token does not pick (``ModelConfig.grouped_experts``,
+      the one static rule; no setting), and else the dense all-experts
+      evaluation — the single-device reference the other two are
+      parity-tested against.
     - ``ep``: ALWAYS the dispatch (requires a mesh with an ``expert``
       axis; ep=1 degenerates the all_to_alls to local copies) — how a
       single chip serves/benches the real dispatch path rather than the
@@ -280,10 +326,10 @@ def _moe_mlp(cfg: ModelConfig, lp: Params, x: jnp.ndarray,
     overhead. ``token_mask`` ([B, S], 0 = dead slot or bucket padding)
     keeps garbage tokens from consuming expert capacity.
     """
-    from ..parallel.moe import dense_moe, expert_parallel_moe
+    from ..parallel.moe import dense_moe, expert_parallel_moe, grouped_moe
 
     if moe_impl == "dense":
-        return dense_moe(cfg, lp, x, mesh)
+        return dense_moe(cfg, lp, x, mesh), None
     if mesh is not None and "expert" in mesh.axis_names:
         ep = mesh.shape["expert"]
         B, S, _ = x.shape
@@ -295,12 +341,115 @@ def _moe_mlp(cfg: ModelConfig, lp: Params, x: jnp.ndarray,
             # at negligible buffer cost, preserving single-device parity.
             capacity = (B * S) // ep if S == 1 else None
             return expert_parallel_moe(cfg, lp, x, mesh, capacity=capacity,
-                                       token_mask=token_mask)
+                                       token_mask=token_mask), None
     if moe_impl == "ep":
         raise ValueError(
             "MOE_IMPL=ep needs a mesh with an expert axis whose size "
             "divides tokens and experts")
-    return dense_moe(cfg, lp, x, mesh)
+    if serves_grouped(cfg, mesh, moe_impl):
+        # ``lp``'s expert leaves are then the WHOLE stacks and ``layer``
+        # picks the layer inside the kernel's index maps (``forward``'s
+        # scan closes over them): sliced out as the scan's xs they were
+        # copied, 604 MB a layer a pass, before the kernel read them.
+        return grouped_moe(cfg, lp, x, token_mask, layer=layer)
+    return dense_moe(cfg, lp, x, mesh), None
+
+
+def _select_and_attend(cfg: ModelConfig, attn_impl: str, q, qi, wi,
+                       layer_k, layer_v, layer_ik, positions, q_lens,
+                       block_tables, n_pages: int, layer, token_mask):
+    """Attention of a selecting configuration over the block pool, inside
+    the caller's ``attention`` scope (ops/sparse_select.py has the
+    mathematics). Returns (attn, int32 [2]: the keys the call's live
+    decode rows had before them, and the keys kept of those — read off
+    the mask itself, so a selector that keeps too much or too little
+    shows in /health.sparse_attention). While no live row of the call
+    has more than ``index_topk`` keys before it, every key is selected
+    and the dense path serves, bit for bit. Past that, every row that
+    rides (a decode step's one, a slot's first in a mixed window, and
+    all the rows of a slot that brings a window) scores the slot's index keys through the
+    block table, takes the exact top-k as a per-row mask, and the paged
+    kernel applies it to its causal scores.
+
+    Decode rows take the same path as window rows, a window of one. The
+    first version fetched a decode query's selected K/V rows one by one
+    (``jax.lax.top_k`` and an XLA gather through the table, 32,768 rows
+    of 1 KB a layer at batch 16); on the chip it was never the cost (a
+    command read 45.1 s with it and 46.8 s without: copies of whole pool
+    leaves were, PERF.md PR 31), and it went for being a second path: at
+    2,048 of ~11,000 keys every 64-row page holds a dozen selected rows,
+    so the kernel's page stream reads little that a row fetch would skip.
+    A kernel that keeps only the selected rows is ROADMAP's."""
+    from ..ops.ragged_attention import ragged_attention_pool
+    from ..ops.sparse_select import index_scores, window_selection
+
+    B, S = positions.shape
+    page = layer_k.shape[-3]
+    kv_limit = n_pages * page
+    ql = (jnp.full((B,), S, jnp.int32) if q_lens is None
+          else q_lens.astype(jnp.int32))
+
+    def attend(sel=None):
+        if attn_impl == "ragged":
+            return ragged_attention_pool(
+                q, layer_k, layer_v, ql, positions[:, 0], block_tables,
+                layer, sel=sel, page_size=page)
+        mask = (jnp.arange(kv_limit)[None, None, :]
+                <= positions[:, :, None])
+        if sel is not None:
+            mask = jnp.logical_and(mask, sel)
+        return dense_attention(
+            q, _pool_gather(layer_k, block_tables, n_pages, layer),
+            _pool_gather(layer_v, block_tables, n_pages, layer), mask)
+
+    # A decode row: a live slot's one query (a window's first column).
+    decode = ql == 1
+    if token_mask is not None:
+        decode = jnp.logical_and(decode, token_mask[:, 0])
+    live = jnp.sum(jnp.where(decode, positions[:, 0] + 1, 0))
+
+    def every_key(_):
+        return attend(), jnp.stack([live, live])
+
+    def select(qi, wi, ik, positions):
+        with jax.named_scope("index_scores"):
+            scores = index_scores(qi, wi, ik, positions)      # [b, s, K]
+        with jax.named_scope("select"):
+            return window_selection(scores, cfg.index_topk)
+
+    def selected(_):
+        with jax.named_scope("index_scores"):
+            ik = _pool_gather(layer_ik, block_tables, n_pages, layer)
+            # the leaf's rows are 128 lanes wide, zeros above the key
+            wide = jnp.pad(
+                qi, ((0, 0),) * 3 + ((0, ik.shape[-1] - qi.shape[-1]),))
+        # Every slot's first row, batched: a decode step's one row, and in
+        # a mixed window the row of each slot that rides at q_len 1.
+        sel = select(wide[:, :1], wi[:, :1], ik, positions[:, :1])
+        if S > 1:
+            # Only a slot that brings a window (q_len > 1) pays for a
+            # window's rows, one slot at a time; the rows nobody rides
+            # keep every key (the kernel masks them out whole).
+            def window(xs):
+                q_b, w_b, ik_b, pos_b, n = xs
+                return jax.lax.cond(
+                    n > 1,
+                    lambda: select(q_b[None], w_b[None], ik_b[None],
+                                   pos_b[None])[0],
+                    lambda: jnp.ones((S, ik_b.shape[0]), bool))
+
+            rows = jax.lax.map(window, (wide, wi, ik, positions, ql))
+            first = jnp.where((ql > 1)[:, None, None], rows[:, :1], sel)
+            sel = jnp.concatenate([first, rows[:, 1:]], axis=1)
+        with jax.named_scope("select"):
+            kept = jnp.sum(jnp.where(decode[:, None], sel[:, 0], False),
+                           dtype=jnp.int32)
+        return attend(sel), jnp.stack([live, kept])
+
+    last = positions[:, 0] + ql          # keys the row's last query sees
+    return jax.lax.cond(
+        jnp.max(jnp.where(ql > 0, last, 0)) <= cfg.index_topk,
+        every_key, selected, None)
 
 
 def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
@@ -312,8 +461,14 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
            write_mask=None,
            block_tables=None,
            q_lens=None,
-           layer=None) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """One transformer block. Returns (h_out, new_layer_k, new_layer_v).
+           layer=None,
+           layer_ik=None) -> Tuple[jnp.ndarray, ...]:
+    """One transformer block. Returns (h_out, new_layer_k, new_layer_v,
+    new_layer_ik, counts): ``layer_ik`` is the index-key leaf of a
+    selecting configuration (None otherwise, returned as given) and
+    ``counts`` holds what this pass counted on the device, under the
+    ``KVCache`` field each adds to: ``experts_read`` where the grouped
+    expert path served, ``sel_rows`` where keys were selected.
 
     ``layer`` (a traced scalar; ISSUE 25): ``layer_k``/``layer_v`` are
     then the WHOLE stacked cache leaves [L, ...] that ``forward``'s layer
@@ -346,9 +501,37 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
         q = qmatmul(x, lp["wq"]).reshape(B, S, H, hd)
         k = qmatmul(x, lp["wk"]).reshape(B, S, KV, hd)
         v = qmatmul(x, lp["wv"]).reshape(B, S, KV, hd)
+        if cfg.qk_norm:
+            q = rms_norm(q, lp["q_norm"], cfg.rms_eps)
+            k = rms_norm(k, lp["k_norm"], cfg.rms_eps)
+        if cfg.selects_keys:
+            # The indexer reads the same normed hidden state: its
+            # queries, its ONE key a token, its per-head weights.
+            J, di = cfg.index_heads, cfg.index_head_dim
+            qi = qmatmul(x, lp["idx_wq"]).reshape(B, S, J, di)
+            ki = qmatmul(x, lp["idx_wk"]).reshape(B, S, 1, di)
+            wi = x @ lp["idx_ww"]
     with jax.named_scope("rope"):
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
+        if cfg.selects_keys:
+            qi = apply_rope(qi, positions, cfg.rope_theta)
+            ki = apply_rope(ki, positions, cfg.rope_theta)[:, :, 0]
+    if cfg.selects_keys and (block_tables is None or layer_ik is None):
+        raise NotImplementedError(
+            f"{cfg.name} selects its keys (index_topk={cfg.index_topk}): "
+            "its index keys live in the block pool, so it is served "
+            "through the pool alone (KV_POOL, no pipe axis)")
+    counts = {}
+
+    def mlp_block(h):
+        x = rms_norm(h, lp["mlp_norm"], cfg.rms_eps, cfg.rms_offset)
+        if not cfg.is_moe:
+            return _dense_mlp(cfg, lp, x)
+        y, n_read = _moe_mlp(cfg, lp, x, mesh, token_mask, moe_impl, layer)
+        if n_read is not None:
+            counts["experts_read"] = n_read
+        return y
 
     if block_tables is not None:
         # Block-paged pool (ISSUE 10): layer_k/v are [n_blocks, page, KV,
@@ -375,11 +558,24 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
             else:
                 layer_k = _pool_scatter(layer_k, flat, k, layer)
                 layer_v = _pool_scatter(layer_v, flat, v, layer)
+            if cfg.selects_keys:
+                layer_ik = _pool_scatter(layer_ik, flat, jnp.pad(
+                    ki, ((0, 0), (0, 0),
+                         (0, cfg.index_key_width - ki.shape[-1]))), layer)
         n_pages = kv_limit // page
         kv_pos = jnp.arange(kv_limit)[None, None, :]
         mask = kv_pos <= positions[:, :, None]
         with jax.named_scope("attention"):
-            if attn_impl == "ragged" and not is_q:
+            if cfg.selects_keys and kv_limit > cfg.index_topk:
+                if is_q:
+                    raise NotImplementedError(
+                        "key selection reads bf16 K/V (KV_QUANT is not "
+                        "served with index_topk)")
+                attn, counts["sel_rows"] = _select_and_attend(
+                    cfg, attn_impl, q, qi, wi, layer_k, layer_v, layer_ik,
+                    positions, q_lens, block_tables, n_pages, layer,
+                    token_mask)
+            elif attn_impl == "ragged" and not is_q:
                 # ONE kernel for every window shape (ISSUE 19): per-slot
                 # q_len is 1 for decode, k+1 for spec verify, a prompt
                 # span for (suffix) prefill — a mixed chunk is a single
@@ -429,10 +625,9 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
             h = _shard_residual(
                 mesh, h + qmatmul(attn.reshape(B, S, H * hd), lp["wo"]))
         with jax.named_scope("mlp"):
-            x = rms_norm(h, lp["mlp_norm"], cfg.rms_eps, cfg.rms_offset)
-            mlp = (_moe_mlp(cfg, lp, x, mesh, token_mask, moe_impl)
-                   if cfg.is_moe else _dense_mlp(cfg, lp, x))
-        return _shard_residual(mesh, h + mlp), layer_k, layer_v
+            mlp = mlp_block(h)
+        return (_shard_residual(mesh, h + mlp), layer_k, layer_v, layer_ik,
+                counts)
 
     # Write this chunk's K/V into the cache at its absolute positions.
     # (scatter; positions are per-slot absolute indices). Dead rows
@@ -482,10 +677,9 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
                 mesh, h + qmatmul(attn.reshape(B, S, H * hd), lp["wo"]))
 
         with jax.named_scope("mlp"):
-            x = rms_norm(h, lp["mlp_norm"], cfg.rms_eps, cfg.rms_offset)
-            mlp = (_moe_mlp(cfg, lp, x, mesh, token_mask, moe_impl)
-                   if cfg.is_moe else _dense_mlp(cfg, lp, x))
-        return _shard_residual(mesh, h + mlp), layer_k, layer_v
+            mlp = mlp_block(h)
+        return (_shard_residual(mesh, h + mlp), layer_k, layer_v, layer_ik,
+                counts)
     else:
         with jax.named_scope("kv_write"):
             layer_k = layer_k.at[rows].set(k.astype(layer_k.dtype))
@@ -519,10 +713,9 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
             mesh, h + qmatmul(attn.reshape(B, S, H * hd), lp["wo"]))
 
     with jax.named_scope("mlp"):
-        x = rms_norm(h, lp["mlp_norm"], cfg.rms_eps, cfg.rms_offset)
-        mlp = (_moe_mlp(cfg, lp, x, mesh, token_mask, moe_impl) if cfg.is_moe
-               else _dense_mlp(cfg, lp, x))
-    return _shard_residual(mesh, h + mlp), layer_k, layer_v
+        mlp = mlp_block(h)
+    return (_shard_residual(mesh, h + mlp), layer_k, layer_v, layer_ik,
+            counts)
 
 
 # -------------------------------------------------------------- forward
@@ -584,6 +777,9 @@ def forward(
         kv_limit = cache.max_seq
     B, S = tokens.shape
     batch_idx = jnp.arange(B)[:, None]
+    new_ik = cache.ik
+    counted = {"experts_read": cache.experts_read,
+               "sel_rows": cache.sel_rows}
 
     # final_norm is always a plain array in the model dtype — it anchors
     # the activation dtype when the embedding is stored int8.
@@ -637,16 +833,38 @@ def forward(
         # one (donated) buffer from argument to result; ``_layer``
         # addresses its layer by the scanned index. One scan shape for
         # every path: dense, pool, ragged, int8 KV.
-        def scan_body(carry, xs):
-            h, cache_k, cache_v = carry
-            lp, layer = xs
-            return step(h, lp, cache_k, cache_v, positions, kv_limit,
-                        batch_idx, token_mask, write_mask, block_tables,
-                        q_lens, layer), None
+        # A selecting configuration's index keys ride it too; a caller
+        # that built the pool without the leaf (benchmark/refcheck.py)
+        # gets a zero one of the K pool's block geometry, returned below.
+        ik = cache.ik
+        if cfg.selects_keys and ik is None and block_tables is not None:
+            ik = jnp.zeros(cache.k.shape[:3] + (cfg.index_key_width,),
+                           cache.k.dtype)
 
-        (h, new_k, new_v), _ = jax.lax.scan(
-            scan_body, (h, cache.k, cache.v),
-            (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)))
+        # The grouped expert kernel reads its experts out of the stacked
+        # leaves by the layer index, so those stay out of the scan's xs.
+        layers = params["layers"]
+        stacks = {}
+        if serves_grouped(cfg, mesh, moe_impl):
+            stacks = {k: layers[k] for k in EXPERT_LEAVES}
+            layers = {k: v for k, v in layers.items() if k not in stacks}
+
+        def scan_body(carry, xs):
+            h, cache_k, cache_v, cache_ik = carry
+            lp, layer = xs
+            lp = {**lp, **stacks}
+            h, cache_k, cache_v, cache_ik, counts = step(
+                h, lp, cache_k, cache_v, positions, kv_limit, batch_idx,
+                token_mask, write_mask, block_tables, q_lens, layer,
+                cache_ik)
+            return (h, cache_k, cache_v, cache_ik), counts
+
+        (h, new_k, new_v, new_ik), counts = jax.lax.scan(
+            scan_body, (h, cache.k, cache.v, ik),
+            (layers, jnp.arange(cfg.n_layers, dtype=jnp.int32)))
+        for name, per_layer in counts.items():
+            if counted[name] is not None:
+                counted[name] = counted[name] + jnp.sum(per_layer, axis=0)
 
     with jax.named_scope("final_norm"):
         h = rms_norm(h, params["final_norm"], cfg.rms_eps, cfg.rms_offset)
@@ -669,4 +887,5 @@ def forward(
         new_lengths = cache.lengths
     else:
         new_lengths = jnp.maximum(cache.lengths, positions.max(axis=1) + 1)
-    return logits.astype(jnp.float32), KVCache(k=new_k, v=new_v, lengths=new_lengths)
+    return logits.astype(jnp.float32), KVCache(
+        k=new_k, v=new_v, lengths=new_lengths, ik=new_ik, **counted)

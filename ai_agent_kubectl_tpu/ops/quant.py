@@ -290,7 +290,17 @@ def to_w8a8(params):
 
 #: projection weights eligible for quantization (matmul RHS with the
 #: output channel last). Embeddings/norms/router excluded.
-_QUANT_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+_QUANT_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
+               "idx_wq", "idx_wk")
+
+
+#: The per-head q/k RMSNorm gains this SEEDED generator writes (bench/dev
+#: only; ``init_params`` and a checkpoint have their own). Not 1: with unit
+#: gains and random weights q.k/sqrt(hd) has unit variance, attention is a
+#: near-uniform average of ~N values, a 2% part of the residual, and no
+#: comparison of logits could tell which keys were attended. At 2 the
+#: scores' standard deviation is 4, as in a trained model.
+SEEDED_QK_NORM_GAIN = 2.0
 
 
 def random_params_int8(key, cfg, dtype=None,
@@ -387,6 +397,8 @@ def _random_params_int8(key, cfg, dtype, quantize_embed: bool, int4: bool,
             scale = _jnp.full(sshape, (sds.shape[-2] ** -0.5) / 127.0,
                               _jnp.float32)
             out.append(QuantInt8(q=q, scale=scale))
+        elif name in ("q_norm", "k_norm"):
+            out.append(_jnp.full(sds.shape, SEEDED_QK_NORM_GAIN, dtype))
         elif name.endswith("norm"):
             fill = _jnp.zeros if cfg.rms_offset else _jnp.ones
             out.append(fill(sds.shape, dtype))

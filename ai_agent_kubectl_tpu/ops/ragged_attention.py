@@ -155,10 +155,9 @@ def grid_steps(n_slots: int, n_pages: int, page_size: int, n_heads: int,
 
 
 def _ragged_pool_kernel(pos_ref, qlen_ref, tbl_ref, lyr_ref, q_ref, k_hbm,
-                        v_hbm, o_ref, k_buf, v_buf, sems, done_ref, m_scr,
-                        l_scr, acc_scr, *, page_size: int, scale: float,
+                        v_hbm, *rest, page_size: int, scale: float,
                         n_pages: int, kv_heads: int, tq: int, pps: int,
-                        grid: tuple):
+                        grid: tuple, selects: bool = False):
     """Online-softmax body over one (slot, query tile, page block) grid
     cell: ``tq`` query columns against ``pps`` pages. Rows are laid out
     [KV, tq*G] (row r is tile column ``r // G`` of KV group ``r % G``'s
@@ -175,7 +174,16 @@ def _ragged_pool_kernel(pos_ref, qlen_ref, tbl_ref, lyr_ref, q_ref, k_hbm,
     Scalar code divides with ``lax.div``/``lax.rem`` (operands are never
     negative): ``//`` and ``%`` each lower through a traced sign
     correction, which cost 0.4 s of lowering a chunk program at every
-    server start, compile cache or not."""
+    server start, compile cache or not.
+
+    ``selects`` (a key-selecting configuration, ops/sparse_select.py):
+    one more operand, ``sel_ref`` [1, tq, pps*page] int8, nonzero where
+    the tile's query column may attend the block's key; it is ANDed into
+    the causal mask and nothing else changes."""
+    sel_ref = None
+    if selects:
+        sel_ref, *rest = rest
+    (o_ref, k_buf, v_buf, sems, done_ref, m_scr, l_scr, acc_scr) = rest
     n, t, b = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     n_slots, n_qt, n_blk = grid
     n_cells = n_slots * n_qt
@@ -291,6 +299,12 @@ def _ragged_pool_kernel(pos_ref, qlen_ref, tbl_ref, lyr_ref, q_ref, k_hbm,
         # (j >= q_len) mask everything — their normalizer stays 0 and the
         # finalize writes zeros (outputs are never read).
         mask = jnp.logical_and(kv_ids <= pos + q_ids, q_ids < q_len)
+        if sel_ref is not None:
+            # [tq, span] -> row j*G + g reads column j's row
+            picked = jnp.broadcast_to(
+                (sel_ref[0].astype(jnp.int32) != 0)[:, None, :],
+                (tq, G, span)).reshape(1, tq * G, span)
+            mask = jnp.logical_and(mask, picked)
         s = jnp.where(mask, s, -jnp.inf)
         m_prev, l_prev = m_scr[...], l_scr[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
@@ -328,6 +342,8 @@ def ragged_attention_pool(
     positions: jnp.ndarray,     # [N] int32 abs position of column 0
     block_tables: jnp.ndarray,  # [N, max_pages] int32
     layer=None,                 # int32 scalar: layer of a stacked pool
+    sel=None,                   # bool [N, W, max_pages*page]: keys each
+                                # column may attend (None: every key)
     *,
     page_size: int = 128,
     scale: Optional[float] = None,
@@ -335,7 +351,10 @@ def ragged_attention_pool(
 ) -> jnp.ndarray:
     """Ragged block-paged attention over the pool. Returns
     [N, W, H, hd]; rows past ``q_lens[n]`` are zeros (never read —
-    ``logits_at`` gathers the last valid column).
+    ``logits_at`` gathers the last valid column). ``sel`` restricts each
+    query column to the keys it names, inside the causal mask (a column
+    whose ``sel`` row names every causal key gives the bits it gives
+    without ``sel``).
 
     Cost per slot tracks ``ceil((positions[n]+q_lens[n])/page)`` live
     pages, whatever mixture of decode / verify / prefill widths the
@@ -374,10 +393,20 @@ def ragged_attention_pool(
     kernel = functools.partial(
         _ragged_pool_kernel, page_size=page_size, scale=scale,
         n_pages=n_pages, kv_heads=KV, tq=tq, pps=pps, grid=grid,
+        selects=sel is not None,
     )
 
     def q_map(n, t, b, pos_ref, qlen_ref, tbl_ref, lyr_ref):
         return (n, t, 0, 0)
+
+    operands, sel_specs = [pos, qln, tbl, lyr, q, k, v], []
+    if sel is not None:
+        span = pps * page_size
+        sel = jnp.pad(sel.astype(jnp.int8), (
+            (0, 0), (0, n_qt * tq - W), (0, grid[2] * span - sel.shape[2])))
+        operands.append(sel)
+        sel_specs = [pl.BlockSpec(
+            (1, tq, span), lambda n, t, b, *_: (n, t, b))]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
@@ -386,7 +415,7 @@ def ragged_attention_pool(
             pl.BlockSpec((1, tq, H, hd), q_map),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
-        ],
+        ] + sel_specs,
         out_specs=pl.BlockSpec((1, tq, H, hd), q_map),
         scratch_shapes=[
             pltpu.VMEM((2, pps, page_size, KV, hd), k.dtype),
@@ -403,7 +432,7 @@ def ragged_attention_pool(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((N, n_qt * tq, H, hd), q.dtype),
         interpret=interpret,
-    )(pos, qln, tbl, lyr, q, k, v)
+    )(*operands)
     return out[:, :W]
 
 

@@ -1,4 +1,5 @@
-"""Mixture-of-Experts MLP: dense reference + expert-parallel dispatch.
+"""Mixture-of-Experts MLP: dense reference, token-grouped expert GEMM,
+expert-parallel dispatch.
 
 ``dense_moe`` evaluates every expert and mixes by router weights — O(E)
 FLOPs but correct for any batch and trivially shardable; it is the
@@ -12,6 +13,14 @@ GShard-style capacity-bounded dispatch/combine with two
 ``jax.lax.all_to_all`` collectives riding ICI. FLOPs per token are O(k),
 not O(E).
 
+``grouped_moe`` (ISSUE 31) is the one-device path of a configuration with
+many experts a token does not pick (128 experts, 8 a token: evaluating
+all of them is 16x the arithmetic): the (token, expert) pairs are sorted
+by expert into tile-aligned groups and ONE Pallas kernel runs gate, up
+and down for each tile against its expert's int8 leaves — only picked
+experts' weights cross HBM, and no dequantized copy of a layer's experts
+is ever made. It is told which experts it holds and computes those alone.
+
 Routing follows Mixtral (top-k over router logits, softmax *after*
 selection, renormalized over the selected experts).
 """
@@ -23,7 +32,13 @@ from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 from jax.sharding import Mesh, PartitionSpec as P
+
+try:
+    from jax.experimental.pallas import tpu as pltpu
+except ImportError:  # pragma: no cover
+    pltpu = None
 
 from ..models.config import ModelConfig
 
@@ -281,3 +296,182 @@ def expert_parallel_moe(
     flat = fn(x.reshape(T, D), token_mask.reshape(T), lp["router"],
               lp["w_gate"], lp["w_up"], lp["w_down"])
     return flat.reshape(B, S, D)
+
+
+# ------------------------------------------------ token-grouped expert GEMM
+
+#: Rows of one tile of the grouped kernel: a power of two near the mean
+#: group (pairs / experts), at least one bf16 sublane tile, at most what
+#: keeps a tile's activations small beside its expert's weights in VMEM.
+_GROUP_TILE_MIN, _GROUP_TILE_MAX = 16, 256
+
+
+def _group_tile(pairs: int, experts: int) -> int:
+    mean = max(1, pairs // max(1, experts))
+    return min(_GROUP_TILE_MAX,
+               max(_GROUP_TILE_MIN, 1 << (mean.bit_length() - 1)))
+
+
+def _payload_and_scale(w, stacked: bool):
+    """(int8 or plain payload [L, E, in, out], f32 scales [L, E, 1, out]);
+    one layer's leaves become a one-layer stack (a free reshape)."""
+    from ..ops.quant import QuantInt8
+
+    q, scale = (w.q, w.scale) if isinstance(w, QuantInt8) else (w, None)
+    if not stacked:
+        q, scale = q[None], None if scale is None else scale[None]
+    if scale is None:
+        scale = jnp.ones(q.shape[:2] + (1, q.shape[3]), jnp.float32)
+    return q, scale
+
+
+def _grouped_ffn_kernel(te_ref, live_ref, lyr_ref, x_ref, wg_ref, sg_ref,
+                        wu_ref, su_ref, wd_ref, sd_ref, o_ref, *, gelu: bool):
+    """One tile of rows that share an expert: silu/gelu(x Wg) * (x Wu),
+    then Wd. The weights arrive as stored (int8 where quantized) and are
+    converted in VMEM; the per-channel scales multiply the f32 results."""
+    i = pl.program_id(0)
+
+    @pl.when(live_ref[i] == 0)
+    def _dead():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(live_ref[i] != 0)
+    def _tile():
+        x = x_ref[...]
+        g = jnp.dot(x, wg_ref[0, 0].astype(x.dtype),
+                    preferred_element_type=jnp.float32) * sg_ref[0, 0]
+        u = jnp.dot(x, wu_ref[0, 0].astype(x.dtype),
+                    preferred_element_type=jnp.float32) * su_ref[0, 0]
+        act = jax.nn.gelu(g, approximate=True) if gelu else jax.nn.silu(g)
+        h = (act * u).astype(x.dtype)
+        y = jnp.dot(h, wd_ref[0, 0].astype(x.dtype),
+                    preferred_element_type=jnp.float32) * sd_ref[0, 0]
+        o_ref[...] = y.astype(o_ref.dtype)
+
+
+def _grouped_ffn(cfg: ModelConfig, lp, xs, tile_expert, tile_live, tm: int,
+                 layer=None):
+    """xs [n_tiles*tm, D] (tile i holds rows of expert ``tile_expert[i]``)
+    -> [n_tiles*tm, D]. The tile's expert (and, for stacked leaves, the
+    scalar-prefetched ``layer``) picks the weight block in the index maps,
+    so no layer is sliced out of the stack in front of the call, and
+    consecutive tiles of one expert (and the dead tiles after the last
+    live one, which repeat its expert) fetch nothing new."""
+    stacked = layer is not None
+    wg, sg = _payload_and_scale(lp["w_gate"], stacked)
+    wu, su = _payload_and_scale(lp["w_up"], stacked)
+    wd, sd = _payload_and_scale(lp["w_down"], stacked)
+    _, _, D, F = wg.shape
+    n_tiles = tile_expert.shape[0]
+    lyr = jnp.asarray(0 if layer is None else layer, jnp.int32).reshape(1)
+
+    def rows(i, te, live, lyr):
+        return (i, 0)
+
+    def expert(i, te, live, lyr):
+        return (lyr[0], te[i], 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(n_tiles,),
+        in_specs=[
+            pl.BlockSpec((tm, D), rows),
+            pl.BlockSpec((1, 1, D, F), expert),
+            pl.BlockSpec((1, 1, 1, F), expert),
+            pl.BlockSpec((1, 1, D, F), expert),
+            pl.BlockSpec((1, 1, 1, F), expert),
+            pl.BlockSpec((1, 1, F, D), expert),
+            pl.BlockSpec((1, 1, 1, D), expert),
+        ],
+        out_specs=pl.BlockSpec((tm, D), rows),
+    )
+    interpret = jax.default_backend() != "tpu"
+    return pl.pallas_call(
+        partial(_grouped_ffn_kernel, gelu=cfg.activation == "gelu"),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(xs.shape, xs.dtype),
+        interpret=interpret,
+        name="grouped_expert_ffn",
+        **({} if interpret else {"compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_GROUPED_VMEM_BYTES)}),
+    )(tile_expert, tile_live, lyr, xs, wg, sg, wu, su, wd, sd)
+
+
+#: Scoped VMEM of the grouped kernel: three expert matrices double-
+#: buffered as stored plus their converted copies and a tile's
+#: activations (Keye's 2048 x 768 int8: 9.4 MiB + 9.4 MiB bf16 + < 4 MiB)
+#: pass the 16 MiB default; a v5e core has 128 MiB.
+_GROUPED_VMEM_BYTES = 64 * 2**20
+
+
+def grouped_moe(cfg: ModelConfig, lp: Dict[str, Any], x: jnp.ndarray,
+                token_mask: Optional[jnp.ndarray] = None,
+                first_expert: int = 0, layer=None):
+    """Top-k MoE that computes only picked experts: x [B, S, D] ->
+    (y [B, S, D], experts_read int32).
+
+    The router scores all ``cfg.n_experts``; this device holds the
+    experts of its leaves ([E, in, out], or with ``layer`` the whole
+    stacks [L, E, in, out] of which the kernel reads that layer) from
+    ``first_expert`` on (all of
+    them on the one chip the benchmark serves) and computes the part of
+    the result those give — a pick of an expert held elsewhere adds
+    nothing here. ``token_mask`` ([B, S], 0 = padding or a dead slot)
+    keeps garbage rows out of every group, so they read no expert.
+    ``experts_read`` counts the held experts with at least one row:
+    whose weights this pass streamed."""
+    B, S, D = x.shape
+    T, k = B * S, cfg.experts_per_token
+    held = (lp["w_gate"].q if hasattr(lp["w_gate"], "q")
+            else lp["w_gate"]).shape[0 if layer is None else 1]
+    xf = x.reshape(T, D)
+    logits = (xf @ lp["router"]).astype(jnp.float32)              # [T, E]
+    top_vals, top_idx = jax.lax.top_k(logits, k)
+    top_w = jax.nn.softmax(top_vals, axis=-1)                      # [T, k]
+
+    # (token, pick) pairs -> local expert id; ``held`` = no expert here.
+    e = top_idx.astype(jnp.int32) - first_expert
+    e = jnp.where(jnp.logical_and(e >= 0, e < held), e, held)
+    if token_mask is not None:
+        e = jnp.where(token_mask.reshape(T, 1) > 0, e, held)
+    e = e.reshape(T * k)
+    M = T * k
+    tm = _group_tile(M, held)
+    n_tiles = -(-M // tm) + held
+
+    sizes = jnp.sum(e[:, None] == jnp.arange(held)[None, :], axis=0,
+                    dtype=jnp.int32)                               # [held]
+    tiles_of = (sizes + tm - 1) // tm
+    tile_end = jnp.cumsum(tiles_of)                                # [held]
+    start = (tile_end - tiles_of) * tm          # a group's first padded row
+    order = jnp.argsort(e, stable=True)                            # [M]
+    e_sorted = e[order]
+    first_of = jnp.cumsum(sizes) - sizes        # a group's first sorted pair
+    safe = jnp.minimum(e_sorted, held - 1)
+    row_sorted = jnp.where(
+        e_sorted < held,
+        start[safe] + jnp.arange(M, dtype=jnp.int32) - first_of[safe],
+        n_tiles * tm)                           # unheld / masked: dropped
+    # padded row -> source token (T = a zero row)
+    src = jnp.full((n_tiles * tm,), T, jnp.int32).at[row_sorted].set(
+        (order // k).astype(jnp.int32), mode="drop")
+    xs = jnp.concatenate([xf, jnp.zeros((1, D), xf.dtype)])[src]
+
+    tile = jnp.arange(n_tiles, dtype=jnp.int32)
+    n_live = tile_end[-1]
+    tile_expert = jnp.searchsorted(tile_end, jnp.minimum(tile, n_live - 1),
+                                   side="right").astype(jnp.int32)
+    tile_expert = jnp.clip(tile_expert, 0, held - 1)
+    ys = _grouped_ffn(cfg, lp, xs, tile_expert,
+                      (tile < n_live).astype(jnp.int32), tm, layer)
+
+    # each pair's padded row, back in (token, pick) order
+    row_of = jnp.zeros((M,), jnp.int32).at[order].set(row_sorted)
+    picked = row_of < n_tiles * tm
+    rows = jnp.minimum(row_of, n_tiles * tm - 1).reshape(T, k)
+    w = jnp.where(picked.reshape(T, k), top_w, 0.0)
+    y = jnp.einsum("tkd,tk->td", ys[rows].astype(jnp.float32), w)
+    return (y.astype(x.dtype).reshape(B, S, D),
+            jnp.sum(sizes > 0, dtype=jnp.int32))

@@ -74,7 +74,7 @@ def _pipe_shard(lp, h_mb, pos_mb, k, v, *, cfg: ModelConfig, axis: str,
             # moe_impl="dense": the EP all-to-all can't nest under this
             # shard_map; the engine raises at startup if the operator
             # forced MOE_IMPL=ep onto a pipe mesh.
-            h, k_mb, v_mb = _layer(cfg, attn_impl, None, "dense",
+            h, k_mb, v_mb, _, _ = _layer(cfg, attn_impl, None, "dense",
                                    h, lp_l, k_mb, v_mb, positions,
                                    kv_limit, batch_idx, None)
             k_l = tmap(

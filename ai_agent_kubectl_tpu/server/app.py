@@ -1098,6 +1098,15 @@ async def handle_health(request: web.Request) -> web.Response:
     kph = getattr(svc.engine, "kv_pool_health", None)
     if callable(kph):
         kv_pool = kph() or None
+    # Grouped experts / key selection (ISSUE 31): experts read a layer
+    # pass, rows live and selected — cheap host counters, same rule.
+    moe = sparse_attention = None
+    mh = getattr(svc.engine, "moe_health", None)
+    if callable(mh):
+        moe = mh() or None
+    sah = getattr(svc.engine, "sparse_attention_health", None)
+    if callable(sah):
+        sparse_attention = sah() or None
     # Sharding (ISSUE 14): mesh shape, residual TP fraction, pool-
     # sharded + mesh-fallback flags — cheap host attributes, same rule.
     sharding = None
@@ -1150,6 +1159,8 @@ async def handle_health(request: web.Request) -> web.Response:
         qos=qos,
         slo=slo,
         kv_pool=kv_pool,
+        moe=moe,
+        sparse_attention=sparse_attention,
         sharding=sharding,
         grammar=grammar,
         spec=spec,

@@ -116,6 +116,13 @@ class HealthResponse(BaseModel):
     # with a >1 data/pipe/seq axis, or the single-sequence/fake/openai
     # paths). TP/EP meshes serve the pool (ISSUE 14).
     kv_pool: Optional[Dict[str, Any]] = None
+    # Grouped expert GEMM and learned key selection (ISSUE 31;
+    # engine/batcher.py::moe_health, sparse_attention_health): cumulative
+    # experts_read / layer_passes, and decode rows live / selected, index
+    # rows scanned, window rows, forward passes. None where the
+    # configuration has neither.
+    moe: Optional[Dict[str, Any]] = None
+    sparse_attention: Optional[Dict[str, Any]] = None
     # Tensor-parallel serving (ISSUE 14, parallel/sharding.py): the
     # active mesh shape + device count, the residual TP fraction the
     # f≈1 policy achieves at the decode shape, whether the KV pool is

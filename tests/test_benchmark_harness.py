@@ -263,6 +263,112 @@ def case_the_four_chip_configuration_is_a_cell_of_four_chips_only():
     assert (env["MODEL_NAME"], env["MESH_SHAPE"]) == ("toy-moe", "model:2")
 
 
+#: https://huggingface.co/Kwai-Keye/Keye-VL-2.0-30B-A3B/blob/main/config.json as
+#: the catalog beside the model-configs guide has it: every number of it.
+KEYE_SOURCE = {
+    "decoder_sparse_step": 1, "head_dim": 128, "hidden_size": 2048, "intermediate_size": 6144,
+    "max_position_embeddings": 262144, "max_window_layers": 48, "moe_intermediate_size": 768,
+    "num_attention_heads": 32, "num_experts": 128, "num_experts_per_tok": 8,
+    "num_hidden_layers": 48, "num_key_value_heads": 4, "num_local_experts": 128,
+    "rms_norm_eps": 1e-06, "rope_theta": 10000000, "vocab_size": 151936}
+KEYE_GROUPS = {
+    "rope_scaling": {"mrope_section": [16, 24, 24], "rope_type": "default", "type": "default"},
+    "sa_config": {"indexer_head_dim": 64, "indexer_num_heads": 16, "indexer_num_kv_heads": 1,
+                  "kv_chunk_size": 512, "q_chunk_size": 512, "topk": 2048}}
+
+
+def case_the_keye_configuration_is_its_source_cut_in_depth_alone():
+    """keye-vl-2.0-30b-a3b-l8 (PR 31): every number of the source under its own
+    key, depth the one cut, sa_config's entries also flat (modelmap.sizes reads
+    the top level), every mapped key reaching the ModelConfig, and the bytes the
+    file states are the bytes the ops/bytes functions count."""
+    import opsbytes
+    import serve
+
+    cfg = shipped("keye-vl-2.0-30b-a3b-l8")
+    differ = {k for k, v in KEYE_SOURCE.items() if cfg.get(k) != v}
+    assert differ == {"num_hidden_layers"} == set(cfg["reduced"])
+    assert cfg["reduced"]["num_hidden_layers"] == {**cfg["reduced"]["num_hidden_layers"],
+                                                  "source": 48, "here": 8}
+    assert all(cfg[g] == v for g, v in KEYE_GROUPS.items())
+    assert all(cfg[k] == v for k, v in KEYE_GROUPS["sa_config"].items())      # flat too
+    model, sz = serve.register(cfg)
+    assert (model.n_layers, model.n_experts, model.experts_per_token) == (8, 128, 8)
+    assert (model.mlp_hidden, model.dense_mlp_hidden, model.dim) == (768, 6144, 2048)
+    assert (model.index_topk, model.index_heads, model.index_head_dim) == (2048, 16, 64)
+    assert model.qk_norm and model.selects_keys and model.grouped_experts
+    assert (model.n_heads, model.n_kv_heads, model.head_dim, model.vocab_size) == (32, 4, 128, 151936)
+    assert sz["topk"] == 2048 and sz["moe_intermediate_size"] == 768     # what the reference gets
+    f = modelmap.fields(sz, modelmap.key_map(cfg))
+    layer = 128 * 3 * 2048 * 768 + 2 * 2048 * 32 * 128 + 2 * 2048 * 4 * 128
+    assert opsbytes.weight_stream_bytes(f) == 8 * layer + 2048 * 151936
+    indexer_and_router = 8 * 2048 * (16 * 64 + 64 + 16 + 128)
+    total = 8 * layer + indexer_and_router + 2 * 2048 * 151936
+    assert round(total / 1e9, 2) == cfg["sizing"]["weights_GB"] == 5.63
+    assert opsbytes.weight_stream_bytes(f, experts_streamed=82) < 0.7 * opsbytes.weight_stream_bytes(f)
+    sizing = cfg["sizing"]
+    assert opsbytes.kv_bytes_per_token(f) == sizing["kv_bytes_per_token"] == 16384
+    # an index-key row is 128 lanes wide (ModelConfig.index_key_width), the key its first 64
+    assert model.index_key_width == 128
+    assert sizing["cache_bytes_per_token"] == 16384 + 8 * 128 * 2 == 18432
+    env = cfg["server_env"]
+    assert int(env["KV_POOL_BLOCKS"]) * int(env["KV_POOL_PAGE"]) == sizing["pool_tokens"] == 262144
+    assert round(sizing["pool_tokens"] * 18432 / 1e9, 2) == sizing["pool_GB"]
+    assert modelmap.mesh_problems(cfg, 1) == [] and modelmap.mesh_of(cfg) == {}
+    bench = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
+    cell, entry, file, mix = R.resolve_cell(bench, "keye30b-l8-longlogs-replay")
+    assert (cell["chips"], entry["reduced"], file) == (1, ["num_hidden_layers"], cfg)
+    assert (mix["pattern"], mix["asks_per_log"], mix["ask_distance"]) == ("replay", 3, 6)
+    longest = 80 + max(mix["log_tokens"]) + mix["question_tokens"] + int(env["MAX_NEW_TOKENS"])
+    assert (min(mix["log_tokens"]), max(mix["log_tokens"])) == (6144, 15360)
+    assert longest <= int(env["MAX_SEQ_LEN"]) and min(mix["log_tokens"]) > 3 * cfg["topk"] - 1
+    assert R.child_env(cfg, 1, True)["MODEL_NAME"] == "toy-sparse-moe"
+    ref = refcheck_module().load_reference(cfg["reference"])
+    assert callable(ref.forward) and cfg["reference_check"]["clear_if"]["aux"] == "clear_score"
+    chk = cfg["reference_check"]
+    assert min(chk["prompt_tokens"]) > cfg["topk"] and chk["window"] >= max(chk["prompt_tokens"])
+
+
+def refcheck_module():
+    import refcheck
+
+    return refcheck
+
+
+def case_the_selection_and_expert_counters_are_read_as_growth_between_the_probes():
+    """The three metrics PR 31 adds: growth of /health counts between the
+    probes; a program without the counters (the parent) gives None."""
+    ratio, roof = R.load_reader("health_growth_ratio"), R.load_reader("sparse_attention_roofline")
+    spec = lambda n: json.loads((BENCH / "metrics" / f"{n}.json").read_text())
+    before = {"sparse_attention": {"decode_rows_live": 1000, "decode_rows_selected": 1000,
+                                   "index_rows_scanned": 5000, "forward_passes": 10},
+              "moe": {"experts_read": 800, "layer_passes": 8}}
+    after = {"sparse_attention": {"decode_rows_live": 101000, "decode_rows_selected": 21000,
+                                  "index_rows_scanned": 405000, "forward_passes": 210},
+             "moe": {"experts_read": 8800 + 800, "layer_passes": 108}}
+    ctx = {"health_before": before, "health_after": after}
+    assert ratio.read(ctx, spec("sparse_attn_rows_read_share")["params"]) == 20.0
+    assert ratio.read(ctx, spec("experts_read_per_layer_pass")["params"]) == 88.0
+    cfg = shipped("keye-vl-2.0-30b-a3b-l8")
+    sz = modelmap.sizes(cfg)
+    fields = modelmap.fields(sz, modelmap.key_map(cfg))
+    trace = {"forward_passes": 50, "category_s": {"attention": 0.001}, "busy_s": 1.0}
+    ctx.update(trace=trace, fields=fields, peaks={"hbm_bytes_per_s": 819e9})
+    # 8 layers x (400,000 scanned x 128 B + 20,000 selected x 2,048 B) over the
+    # run's 200 passes, the capture holding 50 of them
+    least = 8 * (400000 * 128 + 20000 * 2048) * 50 / 200 / 819e9
+    assert roof.read(ctx, {}) == pytest.approx(100.0 * least / 0.001)
+    for parent in ({}, {"sparse_attention": None, "moe": None}):
+        old = dict(ctx, health_before=parent, health_after=parent)
+        assert ratio.read(old, spec("sparse_attn_rows_read_share")["params"]) is None
+        assert ratio.read(old, spec("experts_read_per_layer_pass")["params"]) is None
+        assert roof.read(old, {}) is None
+    assert roof.read(dict(ctx, trace=None), {}) is None
+    mistral = shipped("mistral-7b-instruct-v0.2")
+    dense = modelmap.fields(modelmap.sizes(mistral), modelmap.key_map(mistral))
+    assert roof.read(dict(ctx, fields=dense), {}) is None
+
+
 CASES = [v for k, v in sorted(globals().items()) if k.startswith("case_")]
 
 

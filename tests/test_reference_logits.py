@@ -51,16 +51,16 @@ LENS, W, STEPS, PAGE = (100, 61), 128, 3, 64
 TOLERANCE = 1e-4
 
 
-def load_reference():
+def load_reference(name="mixtral-8x7b-instruct-v0.1"):
     spec = importlib.util.spec_from_file_location(
         "plain_reference",
-        ROOT / "benchmark/configs/mixtral-8x7b-instruct-v0.1.reference.py")
+        ROOT / f"benchmark/configs/{name}.reference.py")
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
 
 
-def program_logits(params, axes):
+def program_logits(params, axes, CFG=CFG, LENS=LENS, PAGE=PAGE):
     """Every position's logits of the two sequences, as refcheck.run takes
     them: the window, then STEPS single-token steps through the pool."""
     B = len(LENS)
@@ -103,7 +103,7 @@ def program_logits(params, axes):
     return toks, [np.concatenate(g, axis=0) for g in got]
 
 
-def worst_rel_err(ref, sizes, weights, toks, got):
+def worst_rel_err(ref, sizes, weights, toks, got, LENS=LENS):
     worst, stds = 0.0, []
     for b, n in enumerate(LENS):
         want, _ = ref.forward(sizes, weights, jnp.asarray(toks[b]))
@@ -139,3 +139,26 @@ def test_the_tolerance_tells_a_fault_from_rounding(seeded):
     assert worst_rel_err(ref, SIZES, rounded, toks, got) > 100 * TOLERANCE
     top1 = dict(SIZES, num_experts_per_tok=1)
     assert worst_rel_err(ref, top1, weights, toks, got) > 1000 * TOLERANCE
+
+
+def test_the_selecting_configuration_matches_its_plain_reference():
+    """keye-vl-2.0-30b-a3b-l8's reference (QK-norm, the lightning indexer and
+    top-k key selection, 16 experts top-2 here through the grouped expert GEMM)
+    against ``toy-sparse-moe`` through the pool, the index-key leaf made by
+    ``forward`` as on the chip: 100 and 61 tokens, both past index_topk = 48,
+    so window rows and decode rows select. What the tolerance must refuse:
+    every key attended, and the selector keeping one key too few."""
+    cfg = get_config("toy-sparse-moe")
+    sizes = {"num_attention_heads": cfg.n_heads, "num_key_value_heads": cfg.n_kv_heads,
+             "head_dim": cfg.head_dim, "rope_theta": cfg.rope_theta,
+             "rms_norm_eps": cfg.rms_eps, "num_experts_per_tok": cfg.experts_per_token,
+             "indexer_num_heads": cfg.index_heads, "indexer_head_dim": cfg.index_head_dim,
+             "topk": cfg.index_topk, "q_chunk_size": 64}
+    params = random_params_int8(jax.random.PRNGKey(31), cfg, dtype=jnp.float32,
+                                quantize_embed=True)
+    ref = load_reference("keye-vl-2.0-30b-a3b-l8")
+    weights = refcheck.weights_function(ref)(params, cfg.n_layers)
+    toks, got = program_logits(params, {}, CFG=cfg, PAGE=16)
+    assert worst_rel_err(ref, sizes, weights, toks, got) < TOLERANCE
+    assert worst_rel_err(ref, dict(sizes, topk=10 ** 6), weights, toks, got) > 1000 * TOLERANCE
+    assert worst_rel_err(ref, dict(sizes, topk=47), weights, toks, got) > 10 * TOLERANCE

@@ -411,3 +411,96 @@ def test_grammar_mask_over_model4_stays_split_on_v5e(mesh4, monkeypatch):
             continue
         assert op == "all-to-all" and shape.startswith("s32[4,4,8000]") \
             and "grammar_mask" not in scope, (op, shape, scope)
+
+
+# ------------------------- key selection and grouped experts (ISSUE 31)
+
+KEYE = dict(vocab_size=151936, dim=2048, n_heads=32, n_kv_heads=4,
+            head_dim=128, mlp_hidden=768, rope_theta=1e7, eos_ids=(2,),
+            tie_embeddings=False, n_experts=128, experts_per_token=8,
+            qk_norm=True, index_topk=2048, index_heads=16,
+            index_head_dim=64)
+
+
+@pytest.mark.parametrize("W", [1, 512], ids=["decode", "window-512"])
+def test_selecting_forward_compiles_at_published_widths_on_v5e(one_chip, W,
+                                                                monkeypatch):
+    """keye-vl-2.0-30b-a3b-l8's block (2 layers, every width as published,
+    batch 16, the engine's 257-page table over a 512-block pool): Mosaic
+    accepts the ragged kernel with its ``sel`` operand at 32Q/4KV heads
+    (the [tq, span] mask broadcast over a KV group's 8 query heads) and the
+    grouped expert kernel over int8 experts of 2048 x 768 (scoped VMEM
+    raised for the three double-buffered matrices); the index-key leaf
+    rides the donated cache; both branches of the selection are in the
+    program. The pool write is in place for all three leaves."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = ModelConfig(name="aot-keye", n_layers=2, **KEYE)
+    B, page, n_blocks, pages = 16, 64, 512, 257
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda x: arg(x.shape, x.dtype),
+        jax.eval_shape(lambda k: random_params_int8(
+            k, cfg, dtype=jnp.bfloat16, quantize_embed=True),
+            jax.random.PRNGKey(0)))
+    pool = (cfg.n_layers, n_blocks, page, cfg.n_kv_heads, cfg.head_dim)
+    cache = KVCache(k=arg(pool, jnp.bfloat16), v=arg(pool, jnp.bfloat16),
+                    lengths=arg((n_blocks,), jnp.int32),
+                    ik=arg(pool[:3] + (cfg.index_key_width,), jnp.bfloat16),
+                    experts_read=arg((), jnp.int32),
+                    sel_rows=arg((2,), jnp.int32))
+
+    def step(params, tok, pos, cache, wmask, tables, q_lens):
+        return forward(params, cfg, tok, pos, cache, kv_limit=pages * page,
+                       attn_impl="ragged", token_mask=wmask,
+                       write_mask=wmask, block_tables=tables, q_lens=q_lens,
+                       logits_at=jnp.maximum(q_lens, 1) - 1)
+
+    compiled = jax.jit(step, donate_argnums=(3,)).lower(
+        params, arg((B, W), jnp.int32), arg((B, W), jnp.int32), cache,
+        arg((B, W), jnp.bool_), arg((B, pages), jnp.int32),
+        arg((B,), jnp.int32)).compile()
+    hlo = compiled.as_text()
+    # the grouped expert kernel, and the ragged kernel in the dense branch
+    # (and, for a window, again with its mask in the selecting branch)
+    assert hlo.count('custom_call_target="tpu_custom_call"') >= (3 if W > 1 else 2)
+    assert "conditional" in hlo, "selection is decided at run time"
+    mem = compiled.memory_analysis()
+    leaves = 2 * cfg.n_layers * n_blocks * page * (2 * 4 * 128 + 128)
+    assert mem.alias_size_in_bytes >= leaves, "K, V and index keys are donated"
+    # a window's index scores are tiled: the temporaries stay far under
+    # the 16 x 512 x 16 heads x 16,448 float32 scores (8.6 GB) of one piece
+    assert mem.temp_size_in_bytes < 4 * 2 ** 30, mem.temp_size_in_bytes
+
+
+def test_the_grouped_expert_kernel_cannot_hold_a_mixtral_expert_on_v5e(one_chip,
+                                                                       monkeypatch):
+    """Why ``ModelConfig.grouped_experts`` leaves Mixtral on ``dense_moe``: the
+    grouped kernel holds a tile's whole expert in VMEM (three matrices as
+    stored, double-buffered), and three of 4096 x 14336 int8 are 337 MB against
+    a v5e core's 128 MiB. When an F-tiled kernel makes this compile, the rule's
+    threshold is to be measured on the chip (ROADMAP S2), not kept."""
+    from ai_agent_kubectl_tpu.ops.quant import QuantInt8
+    from ai_agent_kubectl_tpu.parallel.moe import grouped_moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    E, D, F = 8, 4096, 14336
+    cfg = ModelConfig(name="aot-mixtral", vocab_size=32000, dim=D, n_layers=1,
+                      n_heads=32, n_kv_heads=8, head_dim=128, mlp_hidden=F,
+                      n_experts=E, experts_per_token=2)
+    assert not cfg.grouped_experts
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def leaf(i, o):
+        return QuantInt8(q=arg((E, i, o), jnp.int8),
+                         scale=arg((E, 1, o), jnp.float32))
+
+    lp = {"router": arg((D, E), jnp.bfloat16), "w_gate": leaf(D, F),
+          "w_up": leaf(D, F), "w_down": leaf(F, D)}
+    with pytest.raises(Exception, match="(?i)vmem"):
+        jax.jit(lambda lp, x: grouped_moe(cfg, lp, x)).lower(
+            lp, arg((16, 1, D), jnp.bfloat16)).compile()
