@@ -1115,7 +1115,7 @@ class BatchedJaxEngine(JaxEngine):
         self._selection_counts = dict.fromkeys(
             ("index_rows_scanned", "window_rows", "forward_passes"), 0)
         self._sel_rows_dev = [0, 0]
-        self._attention_steps = (None, None)
+        self._attention_steps = (None, None, None)
         self._ragged_chunk_fns: dict = {}   # (adm width, spec) -> jitted
         # slot_idx -> staged admission (ids/start/ngen0/budget/seed/
         # temp/gs): the unmatched prompt suffix rides the NEXT chunk as
@@ -1623,7 +1623,8 @@ class BatchedJaxEngine(JaxEngine):
             if self._use_ragged:
                 self._attention_steps = self._resolve_attention_steps()
                 logger.info("ragged kernel: %d KV pages a grid step, %d "
-                            "grid steps a decode call",
+                            "grid steps a decode call, its live blocks "
+                            "streamed through %d buffers",
                             *self._attention_steps)
         # Attention cost under ``gather`` and ``dense`` grows with the
         # KV span read, so the chunk program is compiled per KV *bucket*
@@ -2932,11 +2933,12 @@ class BatchedJaxEngine(JaxEngine):
         }
 
     def _resolve_attention_steps(self) -> tuple:
-        """(KV pages a grid step, grid steps a call) of the ragged kernel
-        in the decode program, from the shapes a chip sees: its share of
-        the heads, the whole table, the decode window (k+1 columns under
-        speculation)."""
-        from ..ops.ragged_attention import grid_steps, pages_per_step
+        """(KV pages a grid step, grid steps a call, buffers its live
+        blocks stream through) of the ragged kernel in the decode program,
+        from the shapes a chip sees: its share of the heads, the whole
+        table, the decode window (k+1 columns under speculation)."""
+        from ..ops.ragged_attention import (grid_steps, pages_per_step,
+                                            stream_depth)
 
         cfg = self.model_cfg
         tp = self.mesh.shape["model"] if self.mesh is not None else 1
@@ -2945,20 +2947,21 @@ class BatchedJaxEngine(JaxEngine):
                  self.spec_draft_k + 1 if self._spec_live else 1,
                  jnp.dtype(self.dtype).itemsize)
         return (pages_per_step(*shape),
-                grid_steps(self.batch_size, *shape))
+                grid_steps(self.batch_size, *shape), stream_depth(*shape))
 
     def _attention_health(self) -> dict:
         """The regime actually serving attention (ragged | gather | dense)
         and the condition that selected it — int8 KV, non-dividing head
         counts and mesh gates fall back LOUDLY here — and, under ragged,
         what the kernel resolved at start (null otherwise)."""
-        pages, steps = self._attention_steps
+        pages, steps, depth = self._attention_steps
         cfg = self.model_cfg
         return {
             "attention_regime": self._attention_regime,
             "attention_regime_reason": self._attention_regime_reason,
             "attention_pages_per_step": pages,
             "attention_decode_grid_steps": steps,
+            "attention_stream_depth": depth,
             # What a selecting configuration resolved at start: how many
             # keys a query keeps, and how each form reads them.
             "attention_selects_keys": (

@@ -33,17 +33,32 @@ as cover 512 KV positions, fewer where the query tile's scores leave no
 VMEM for them). Positions, query lengths, tables and the layer index are
 scalar-prefetched; K and V stay in HBM and the kernel fetches them itself
 (ISSUE 30): one async copy per LIVE page of a block (through
-``tbl[n, j]`` and the layer index) into a ``[2, P, page, KV, hd]`` VMEM
-scratch, all in flight together. The live blocks of a call are one stream
-through the two buffers: each starts the copies of the NEXT live block —
-of its own tile, or the first block of the next tile that reads anything —
-before it waits for its own, so a slot's copies fly while the slot before
-it computes. A block is ONE ``[KV, tq*G, P*page]`` score tile; online
-softmax state persists in VMEM scratch across the sequential block axis.
-Pages past a tile's last live page are never copied and blocks wholly past
-it do nothing but step (0.05 us; under one page a step through BlockSpecs
-a dead step cost 0.16 us and a decode call over the engine's 65-page table
-took 1,040 of them: 194 us against 27 now, v5e, PR 30).
+``tbl[n, j]`` and the layer index) into a ring of ``stream_depth`` VMEM
+buffers (2 to 4 blocks of ``P`` pages each of K and V, as VMEM allows),
+all in flight together. The live blocks of a call are one stream through
+the ring: a fetch cursor runs up to ``stream_depth - 1`` live blocks ahead
+of the block being computed — through its own tile, then the first block
+of the next tile that reads anything — so a slot's copies fly while the
+slots before it compute (ISSUE 32). Online softmax state persists in VMEM
+scratch across the sequential block axis. Pages past a tile's last live
+page are never copied and blocks wholly past it do nothing but step (0.05
+us; under one page a step through BlockSpecs a dead step cost 0.16 us and
+a decode call over the engine's 65-page table took 1,040 of them: 194 us
+against 27 now, v5e, PR 30).
+
+A block's arithmetic takes one of two forms, by the tile's rows. A wide
+tile (a prefill window) transposes the block to [KV, span, hd] and scores
+it as ONE ``[KV, tq*G, P*page]`` tile. A narrow one (a decode row, a
+verify window: while rows x KV heads <= ``_FLAT_SCORES_MAX``) cannot pay
+for that: in buffers tiled [page, KV, hd] every key is a (KV, hd) tile of
+its own, and loading and transposing 512 of them a leaf was 2.06 us of a
+block's 2.50 at 2, 4 and 8 KV heads alike, whatever the bytes (v5e, PR 32:
+the long-log cell's decode rows read 13-31 full blocks a slot a layer).
+Its buffers are tiled by ROWS, [page*KV, hd] — the same bytes, so a
+page's copy is unchanged — and it multiplies its [tq*H, hd] rows against
+the block as stored, masking the columns of the other KV groups: 0.84 us
+of arithmetic a block, and the stream then bounds the call at 86% of HBM
+bandwidth (1.45 us a 1 MB block).
 
 The window is cut into query tiles of ``_q_tile`` columns so the VMEM
 working set depends on the head geometry alone, never on W: Mosaic
@@ -62,6 +77,7 @@ Interpret mode runs the same kernel on CPU for tests and CI.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -116,14 +132,42 @@ def _q_tile(w: int, n_heads: int, head_dim: int) -> int:
 #: KV positions one grid step attends: eight pages of 64.
 _KV_STEP_POSITIONS = 512
 
-#: What a step's KV may take of the 16 MiB scoped VMEM (v5e): the K and V
-#: double buffers plus the f32 score tile and its temporaries. The rest is
+#: What a step's KV may take of the 16 MiB scoped VMEM (v5e): the ring of
+#: K and V buffers plus the f32 score tile and its temporaries. The rest is
 #: the query tile's (``_Q_TILE_ELEMS``: q/out blocks, accumulator,
 #: lane-padded softmax state). Against the compiler's own count at
-#: Mistral-7B's heads and a 64-column tile: 4 pages a step allocate 12.25
-#: MiB, 8 pages 15.06, 16 pages 24.66 (refused); a decode row with 8 pages
-#: 4.08 (AOT for a v5e, PR 30).
+#: Mistral-7B's heads and a 64-column tile, two buffers: 4 pages a step
+#: allocate 12.25 MiB, 8 pages 15.06, 16 pages 24.66 (refused); a decode
+#: row with 8 pages 4.08 (AOT for a v5e, PR 30).
 _KV_VMEM_BYTES = 9 * 2**20
+
+#: Buffers the live-block stream may use where VMEM allows more: a block
+#: being computed and three in flight.
+_STREAM_DEPTH_MAX = 4
+
+#: Score elements a KEY may cost a tile (its rows x the KV heads) for the
+#: tile to multiply against the block as stored and mask the other KV
+#: groups' columns, instead of transposing the block to [KV, span, hd]
+#: (``_ragged_pool_kernel``). Timed at a verify window of 4 (v5e, PR 32, us
+#: a call as stored / transposed): 32Q/4KV, 512 elements, 869 / 958; 8Q/2KV,
+#: 64, 49.8 / 87.2; 32Q/8KV, 1,024, 128.8 / 103.9; and at 2 columns of
+#: 32Q/8KV, 512, 103.6 / 104.2.
+_FLAT_SCORES_MAX = 512
+
+
+def _flat(tq: int, n_heads: int, kv_heads: int) -> bool:
+    return tq * n_heads * kv_heads <= _FLAT_SCORES_MAX
+
+
+def _score_bytes_per_page(page_size: int, n_heads: int, kv_heads: int,
+                          tq: int) -> int:
+    """Three f32 score tiles of a query tile's rows against one page."""
+    if _flat(tq, n_heads, kv_heads):
+        rows, cols = -(-tq * n_heads // 8) * 8, page_size * kv_heads
+    else:
+        rows = kv_heads * -(-tq * (n_heads // kv_heads) // 8) * 8
+        cols = page_size
+    return 3 * rows * cols * 4
 
 
 def pages_per_step(n_pages: int, page_size: int, n_heads: int,
@@ -131,17 +175,35 @@ def pages_per_step(n_pages: int, page_size: int, n_heads: int,
                    itemsize: int = 2) -> int:
     """KV pages one grid step fetches and attends, from shapes alone: the
     largest power of two whose pages cover at most ``_KV_STEP_POSITIONS``
-    and whose VMEM — K and V double-buffered (Mosaic tiles a page's
-    [KV, hd] rows compactly: 2 KV heads of a mesh shard take a quarter of
-    8) plus three f32 score tiles of the query tile's rows — stays within
-    ``_KV_VMEM_BYTES``. Never wider than the table."""
+    and whose VMEM — two buffers each of K and V, the least the stream
+    runs on (Mosaic tiles a page's [KV, hd] rows compactly: 2 KV heads of
+    a mesh shard take a quarter of 8), plus three f32 score tiles of the
+    query tile's rows — stays within ``_KV_VMEM_BYTES``. Never wider than
+    the table."""
     tq = _q_tile(w, n_heads, head_dim)
     kv_bytes = 2 * 2 * page_size * kv_heads * head_dim * itemsize
-    rows = kv_heads * -(-tq * (n_heads // kv_heads) // 8) * 8
-    score_bytes = 3 * rows * page_size * 4
+    score_bytes = _score_bytes_per_page(page_size, n_heads, kv_heads, tq)
     p = max(1, min(_KV_STEP_POSITIONS // page_size,
                    _KV_VMEM_BYTES // (kv_bytes + score_bytes), n_pages))
     return 1 << (p.bit_length() - 1)
+
+
+def stream_depth(n_pages: int, page_size: int, n_heads: int,
+                 kv_heads: int, head_dim: int, w: int,
+                 itemsize: int = 2) -> int:
+    """Buffers in the ring the live blocks stream through (the fetch
+    cursor runs this many less one blocks ahead), from shapes alone: as
+    many blocks of ``pages_per_step`` pages, K and V, as ``_KV_VMEM_BYTES``
+    holds beside the score tiles, between 2 and ``_STREAM_DEPTH_MAX``: 4
+    where a block is 1 MiB or less, 3 at 8 KV heads, at a decode row (2 MiB
+    a block) and beside a full query tile (whose score tiles take 6
+    MiB) alike."""
+    shape = (page_size, n_heads, kv_heads, head_dim, w, itemsize)
+    pps = pages_per_step(n_pages, *shape)
+    block = 2 * pps * page_size * kv_heads * head_dim * itemsize
+    scores = pps * _score_bytes_per_page(
+        page_size, n_heads, kv_heads, _q_tile(w, n_heads, head_dim))
+    return max(2, min(_STREAM_DEPTH_MAX, (_KV_VMEM_BYTES - scores) // block))
 
 
 def grid_steps(n_slots: int, n_pages: int, page_size: int, n_heads: int,
@@ -157,19 +219,38 @@ def grid_steps(n_slots: int, n_pages: int, page_size: int, n_heads: int,
 def _ragged_pool_kernel(pos_ref, qlen_ref, tbl_ref, lyr_ref, q_ref, k_hbm,
                         v_hbm, *rest, page_size: int, scale: float,
                         n_pages: int, kv_heads: int, tq: int, pps: int,
-                        grid: tuple, selects: bool = False):
+                        depth: int, flat: bool, grid: tuple,
+                        selects: bool = False):
     """Online-softmax body over one (slot, query tile, page block) grid
-    cell: ``tq`` query columns against ``pps`` pages. Rows are laid out
-    [KV, tq*G] (row r is tile column ``r // G`` of KV group ``r % G``'s
-    block) so one KV-batched ``dot_general`` serves every query column
-    and head of the block.
+    cell: ``tq`` query columns against ``pps`` pages.
 
-    The LIVE blocks of a call, in grid order, are one stream through two
-    buffers: each starts the copies of the one after it — the next block
-    of its tile or, past the tile's last live page, block 0 of the next
-    tile that reads anything (frozen slots and tiles past ``q_len`` read
-    nothing) — before it waits for its own. ``done_ref`` counts the
-    blocks computed; its parity is the buffer the next one lands in.
+    The LIVE blocks of a call, in grid order, are one stream through a
+    ring of ``depth`` buffers. A fetch cursor (``ring_ref``: blocks
+    computed, blocks issued, and the cell, block and last page of the
+    block it fetches next) runs up to ``depth - 1`` live blocks ahead of
+    the block being computed: every live block first issues until
+    ``depth`` blocks are out (the call's first primes the ring, the others
+    issue one), into buffer ``issued % depth``, moving the cursor to the
+    next block of its tile or, past the tile's last live page, to block 0
+    of the next tile that reads anything (frozen slots and tiles past
+    ``q_len`` read nothing); then it waits for its own copies in buffer
+    ``done % depth`` and computes. A full block is waited for once a leaf,
+    on the buffer's whole size; a tile's last, half-live block page by
+    page.
+
+    Two forms of the same arithmetic, by the tile's rows (``flat``). A
+    wide tile's buffers are tiled as the pool is, [page, KV, hd]; it lays
+    rows out [KV, tq*G] (row r is tile column ``r // G`` of KV group
+    ``r % G``'s block), transposes the block to [KV, span, hd] once and
+    serves every column and head by one KV-batched ``dot_general``. A
+    narrow tile (a decode or verify row) has too few rows to pay for
+    loading 512 one-key tiles a leaf and transposing them (module
+    docstring): its buffers are tiled by rows, [page*KV, hd] — a page's
+    bytes are the same, so its copy reads the pool through a reshaped
+    view — and it multiplies its [tq*H, hd] rows against the block AS
+    STORED, [span*KV, hd], masking the columns of the other KV groups.
+    Sums run over the same keys with zeros between them, so the two forms
+    agree to float32 rounding, not to the bit.
 
     Scalar code divides with ``lax.div``/``lax.rem`` (operands are never
     negative): ``//`` and ``%`` each lower through a traced sign
@@ -177,13 +258,14 @@ def _ragged_pool_kernel(pos_ref, qlen_ref, tbl_ref, lyr_ref, q_ref, k_hbm,
     server start, compile cache or not.
 
     ``selects`` (a key-selecting configuration, ops/sparse_select.py):
-    one more operand, ``sel_ref`` [1, tq, pps*page] int8, nonzero where
+    one more operand, ``sel_ref`` int8 [1, tq, pps*page] (``flat``: every
+    key repeated for each KV head, [1, tq, pps*page*KV]), nonzero where
     the tile's query column may attend the block's key; it is ANDed into
     the causal mask and nothing else changes."""
     sel_ref = None
     if selects:
         sel_ref, *rest = rest
-    (o_ref, k_buf, v_buf, sems, done_ref, m_scr, l_scr, acc_scr) = rest
+    (o_ref, k_buf, v_buf, sems, ring_ref, m_scr, l_scr, acc_scr) = rest
     n, t, b = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     n_slots, n_qt, n_blk = grid
     n_cells = n_slots * n_qt
@@ -199,27 +281,33 @@ def _ragged_pool_kernel(pos_ref, qlen_ref, tbl_ref, lyr_ref, q_ref, k_hbm,
         last = jnp.minimum(div(pos_ref[n_] + hi - 1, page_size), n_pages - 1)
         return jnp.where(t_ * tq < q_len, last, -1)
 
-    def for_live_pages(n_, blk, last, slot, do, block_of):
-        """``do`` the K and V copy of every page of block ``blk`` up to
-        page ``last`` (into buffer ``slot``, from pool block
-        ``block_of(slot n_'s table, page)``)."""
-        def page(j, _):
-            for c, (hbm, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
-                do(pltpu.make_async_copy(
-                    hbm.at[lyr_ref[0], block_of(n_, j)],
-                    buf.at[slot, j - blk * pps], sems.at[c, slot]))
-
-        jax.lax.fori_loop(blk * pps, jnp.minimum(blk * pps + pps, last + 1),
-                          page, None)
-
-    def start(cp):
-        cp.start()
-
-    def from_table(n_, j):
-        return tbl_ref[n_, j]
-
     def tile_of(cell):
         return (cell, 0) if n_qt == 1 else (div(cell, n_qt), rem(cell, n_qt))
+
+    def first_reader(cell):
+        """(cell, its last page) of the first cell at or after ``cell``,
+        in grid order, that reads any page; ``n_cells`` when none does."""
+        def last_of(c):
+            return last_page_of(*tile_of(jnp.minimum(c, n_cells - 1)))
+
+        return jax.lax.while_loop(
+            lambda s: jnp.logical_and(s[0] < n_cells, s[1] < 0),
+            lambda s: (s[0] + 1, last_of(s[0] + 1)), (cell, last_of(cell)))
+
+    def for_live_pages(blk, last, do):
+        """``do(page, its row of the buffer)`` for every page of block
+        ``blk`` up to page ``last``."""
+        jax.lax.fori_loop(
+            blk * pps, jnp.minimum(blk * pps + pps, last + 1),
+            lambda j, _: do(j, j - blk * pps), None)
+
+    leaves = ((k_hbm, k_buf), (v_hbm, v_buf))
+    if flat:
+        # A page's [page, KV, hd] rows are [page*KV, hd] rows, byte for
+        # byte: the copies land them as that, in buffers tiled by rows.
+        # (Reshaping the VMEM side instead aborts the compiler.)
+        leaves = tuple((hbm.reshape(*hbm.shape[:2], -1, hbm.shape[-1]), buf)
+                       for hbm, buf in leaves)
 
     @pl.when(b == 0)
     def _init():
@@ -233,7 +321,9 @@ def _ragged_pool_kernel(pos_ref, qlen_ref, tbl_ref, lyr_ref, q_ref, k_hbm,
             # copied; its V rows meet probability 0, and 0 x NaN is NaN.
             # So the buffers only ever hold zeros or pool rows.
             v_buf[...] = jnp.zeros_like(v_buf)
-            done_ref[0] = 0
+            cell, last = first_reader(0)
+            for i, x in enumerate((0, 0, cell, 0, last)):
+                ring_ref[i] = x
 
     pos = pos_ref[n]
     q_len = qlen_ref[n]
@@ -242,92 +332,133 @@ def _ragged_pool_kernel(pos_ref, qlen_ref, tbl_ref, lyr_ref, q_ref, k_hbm,
 
     @pl.when(b * pps <= last_page)
     def _accumulate():
-        done = done_ref[0]
-        done_ref[0] = done + 1
-        slot = jnp.bitwise_and(done, 1)
+        done = ring_ref[0]
+        ring_ref[0] = done + 1
 
-        @pl.when(done == 0)
-        def _own():     # the call's first live block: none before it
-            for_live_pages(n, b, last_page, slot, start, from_table)
+        def issue(s):
+            issued, cell, blk, last = s
+            n_ = tile_of(cell)[0]
+            buf_i = rem(issued, depth)
 
-        # The next live block: of this tile, or block 0 of the first
-        # tile after it (in grid order) that reads any page.
-        more = (b + 1) * pps <= last_page
-        cell = n * n_qt + t
+            def start(j, row):
+                for c, (hbm, buf) in enumerate(leaves):
+                    pltpu.make_async_copy(
+                        hbm.at[lyr_ref[0], tbl_ref[n_, j]],
+                        buf.at[buf_i, row], sems.at[c, buf_i]).start()
 
-        def reads_nothing(c):
-            return last_page_of(*tile_of(jnp.minimum(c, n_cells - 1))) < 0
+            for_live_pages(blk, last, start)
+            more = (blk + 1) * pps <= last
+            cell, last = first_reader(jnp.where(more, cell, cell + 1))
+            return issued + 1, cell, jnp.where(more, blk + 1, 0), last
 
-        nxt = jax.lax.while_loop(
-            lambda c: jnp.logical_and(c < n_cells, reads_nothing(c)),
-            lambda c: c + 1, jnp.where(more, cell, cell + 1))
+        cursor = jax.lax.while_loop(
+            lambda s: jnp.logical_and(s[0] < done + depth, s[1] < n_cells),
+            issue, tuple(ring_ref[i] for i in range(1, 5)))
+        for i, x in enumerate(cursor):
+            ring_ref[1 + i] = x
 
-        @pl.when(nxt < n_cells)
-        def _next():
-            n_, t_ = tile_of(nxt)
-            for_live_pages(n_, jnp.where(more, b + 1, 0),
-                           last_page_of(n_, t_), 1 - slot, start, from_table)
+        slot = rem(done, depth)
+        full = b * pps + pps - 1 <= last_page
 
         # A wait needs only the destination and the semaphore.
-        for_live_pages(n, b, last_page, slot, lambda cp: cp.wait(),
-                       lambda n_, j: 0)
+        @pl.when(full)
+        def _whole():
+            for c, (_, buf) in enumerate(leaves):
+                pltpu.make_async_copy(buf.at[slot], buf.at[slot],
+                                      sems.at[c, slot]).wait()
+
+        @pl.when(jnp.logical_not(full))
+        def _pages():
+            def wait(j, row):
+                for c, (hbm, buf) in enumerate(leaves):
+                    pltpu.make_async_copy(hbm.at[lyr_ref[0], 0],
+                                          buf.at[slot, row],
+                                          sems.at[c, slot]).wait()
+
+            for_live_pages(b, last_page, wait)
 
         H, hd = q_ref.shape[2], q_ref.shape[3]
         G = H // kv_heads
-        # [tq, H, hd] -> [KV, tq*G, hd]: head h of column j lands at row
-        # j*G + h%G of KV group h//G — query column recoverable as
-        # q0 + row // G for the causal mask below.
-        qg = jnp.swapaxes(
-            q_ref[0].reshape(tq, kv_heads, G, hd), 0, 1
-        ).reshape(kv_heads, tq * G, hd)
         span = pps * page_size
-        k = jnp.swapaxes(                               # [KV, span, hd]
-            k_buf[slot].reshape(span, kv_heads, hd), 0, 1)
-        v = jnp.swapaxes(
-            v_buf[slot].reshape(span, kv_heads, hd), 0, 1)
-        s = jax.lax.dot_general(
-            qg, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        ) * scale                                       # [KV, tq*G, span]
-        # The mask is the same for every KV group: [1, tq*G, span].
-        kv_ids = b * span + jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, span), 2)
-        q_ids = q0 + div(jax.lax.broadcasted_iota(
-            jnp.int32, (1, tq * G, 1), 1), G)
+        red = 1 if flat else 2          # the scores' key axis
+        if flat:
+            # Rows [tq*H]: column j's head h at j*H + h. Keys as stored:
+            # position p's KV head g at p*KV + g.
+            rows, cols = tq * H, span * kv_heads
+            qg = q_ref[0].reshape(rows, hd)
+            k = k_buf[slot].reshape(cols, hd)
+            v = v_buf[slot].reshape(cols, hd)
+            s = jax.lax.dot_general(
+                qg, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale   # [rows, cols]
+            col = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
+            row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+            kv_ids = b * span + div(col, kv_heads)
+            q_ids = q0 + div(row, H)
+            own = rem(col, kv_heads) == div(rem(row, H), G)
+        else:
+            # [tq, H, hd] -> [KV, tq*G, hd]: head h of column j lands at
+            # row j*G + h%G of KV group h//G — query column recoverable
+            # as q0 + row // G for the causal mask below.
+            qg = jnp.swapaxes(
+                q_ref[0].reshape(tq, kv_heads, G, hd), 0, 1
+            ).reshape(kv_heads, tq * G, hd)
+            k = jnp.swapaxes(                           # [KV, span, hd]
+                k_buf[slot].reshape(span, kv_heads, hd), 0, 1)
+            v = jnp.swapaxes(
+                v_buf[slot].reshape(span, kv_heads, hd), 0, 1)
+            s = jax.lax.dot_general(
+                qg, k, (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32,
+            ) * scale                                   # [KV, tq*G, span]
+            # The mask is the same for every KV group: [1, tq*G, span].
+            kv_ids = b * span + jax.lax.broadcasted_iota(
+                jnp.int32, (1, 1, span), 2)
+            q_ids = q0 + div(jax.lax.broadcasted_iota(
+                jnp.int32, (1, tq * G, 1), 1), G)
         # Causal-in-window: column j attends kv <= pos + j (which also
         # masks the block's pages past the live span); padded columns
         # (j >= q_len) mask everything — their normalizer stays 0 and the
         # finalize writes zeros (outputs are never read).
         mask = jnp.logical_and(kv_ids <= pos + q_ids, q_ids < q_len)
+        if flat:    # ... and only its own KV group's columns
+            mask = jnp.logical_and(mask, own)
         if sel_ref is not None:
-            # [tq, span] -> row j*G + g reads column j's row
-            picked = jnp.broadcast_to(
-                (sel_ref[0].astype(jnp.int32) != 0)[:, None, :],
-                (tq, G, span)).reshape(1, tq * G, span)
+            picked = sel_ref[0].astype(jnp.int32) != 0
+            if flat:    # [tq, cols] -> a column's row for each of its heads
+                picked = jnp.broadcast_to(
+                    picked[:, None, :], (tq, H, cols)).reshape(rows, cols)
+            else:       # [tq, span] -> row j*G + g reads column j's row
+                picked = jnp.broadcast_to(
+                    picked[:, None, :], (tq, G, span)
+                ).reshape(1, tq * G, span)
             mask = jnp.logical_and(mask, picked)
         s = jnp.where(mask, s, -jnp.inf)
         m_prev, l_prev = m_scr[...], l_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=red, keepdims=True))
         pexp = jnp.where(mask, jnp.exp(s - m_new), 0.0)
         alpha = jnp.where(m_prev == -jnp.inf, 0.0,
                           jnp.exp(m_prev - m_new))
         m_scr[...] = m_new
-        l_scr[...] = l_prev * alpha + jnp.sum(pexp, axis=2,
+        l_scr[...] = l_prev * alpha + jnp.sum(pexp, axis=red,
                                               keepdims=True)
         acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            pexp.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+            pexp.astype(v.dtype), v,
+            (((1,), (0,)), ((), ())) if flat
+            else (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
-        )                                               # [KV, tq*G, hd]
+        )                               # [tq*H, hd] | [KV, tq*G, hd]
 
     @pl.when(b == n_blk - 1)
     def _finalize():
         H, hd = o_ref.shape[2], o_ref.shape[3]
-        G = H // kv_heads
         l = l_scr[...]
         l = jnp.where(l == 0.0, 1.0, l)
-        out = (acc_scr[...] / l).reshape(kv_heads, tq, G, hd)
-        o_ref[0] = jnp.swapaxes(out, 0, 1).reshape(
-            tq, H, hd).astype(o_ref.dtype)
+        out = acc_scr[...] / l
+        if not flat:
+            out = jnp.swapaxes(
+                out.reshape(kv_heads, tq, H // kv_heads, hd), 0, 1)
+        o_ref[0] = out.reshape(tq, H, hd).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -375,7 +506,6 @@ def ragged_attention_pool(
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
 
-    G = H // KV
     tq = _q_tile(W, H, hd)
     n_qt = pl.cdiv(W, tq)
     if n_qt * tq != W:
@@ -387,13 +517,15 @@ def ragged_attention_pool(
     tbl = jnp.clip(block_tables.astype(jnp.int32), 0, n_blocks - 1)
     # A table the block does not divide needs no padding: a page past the
     # table is past every live span, and only live pages are looked up.
-    pps = pages_per_step(n_pages, page_size, H, KV, hd, W, k.dtype.itemsize)
+    shape = (n_pages, page_size, H, KV, hd, W, k.dtype.itemsize)
+    pps, depth, flat = pages_per_step(*shape), stream_depth(*shape), \
+        _flat(tq, H, KV)
 
     grid = (N, n_qt, pl.cdiv(n_pages, pps))
     kernel = functools.partial(
         _ragged_pool_kernel, page_size=page_size, scale=scale,
-        n_pages=n_pages, kv_heads=KV, tq=tq, pps=pps, grid=grid,
-        selects=sel is not None,
+        n_pages=n_pages, kv_heads=KV, tq=tq, pps=pps, depth=depth,
+        flat=flat, grid=grid, selects=sel is not None,
     )
 
     def q_map(n, t, b, pos_ref, qlen_ref, tbl_ref, lyr_ref):
@@ -402,12 +534,26 @@ def ragged_attention_pool(
     operands, sel_specs = [pos, qln, tbl, lyr, q, k, v], []
     if sel is not None:
         span = pps * page_size
-        sel = jnp.pad(sel.astype(jnp.int8), (
+        sel = jnp.pad(sel, (
             (0, 0), (0, n_qt * tq - W), (0, grid[2] * span - sel.shape[2])))
+        if flat:
+            # A key's bit for each of its KV heads' columns, by the MXU:
+            # chunks of keys times a 0/1 matrix. ``jnp.repeat`` along the
+            # minor axis compiles to two transposing copies, 72 us of a
+            # 578 us decode call at the long-log geometry (v5e, PR 32).
+            c = math.gcd(span, 128)
+            spread = (jnp.arange(c * KV)[None, :] // KV
+                      == jnp.arange(c)[:, None]).astype(jnp.bfloat16)
+            sel = jnp.dot(sel.reshape(-1, c).astype(jnp.bfloat16),
+                          spread).reshape(N, n_qt * tq, -1)
+            span *= KV
+        sel = sel.astype(jnp.int8)
         operands.append(sel)
         sel_specs = [pl.BlockSpec(
             (1, tq, span), lambda n, t, b, *_: (n, t, b))]
 
+    rows = (tq * H,) if flat else (KV, tq * (H // KV))
+    page_rows = (page_size * KV,) if flat else (page_size, KV)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=grid,
@@ -418,13 +564,13 @@ def ragged_attention_pool(
         ] + sel_specs,
         out_specs=pl.BlockSpec((1, tq, H, hd), q_map),
         scratch_shapes=[
-            pltpu.VMEM((2, pps, page_size, KV, hd), k.dtype),
-            pltpu.VMEM((2, pps, page_size, KV, hd), v.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.SMEM((1,), jnp.int32),
-            pltpu.VMEM((KV, tq * G, 1), jnp.float32),
-            pltpu.VMEM((KV, tq * G, 1), jnp.float32),
-            pltpu.VMEM((KV, tq * G, hd), jnp.float32),
+            pltpu.VMEM((depth, pps) + page_rows + (hd,), k.dtype),
+            pltpu.VMEM((depth, pps) + page_rows + (hd,), v.dtype),
+            pltpu.SemaphoreType.DMA((2, depth)),
+            pltpu.SMEM((5,), jnp.int32),
+            pltpu.VMEM(rows + (1,), jnp.float32),
+            pltpu.VMEM(rows + (1,), jnp.float32),
+            pltpu.VMEM(rows + (hd,), jnp.float32),
         ],
     )
     out = pl.pallas_call(

@@ -383,6 +383,13 @@ async def test_jax_span_tree_both_routes_and_the_four_event_messages():
         assert 1 < pages <= eng._pool_max_pages
         assert pool["attention_decode_grid_steps"] == \
             eng.batch_size * -(-eng._pool_max_pages // pages)
+        # ISSUE 32: and the buffers its live blocks stream through, from
+        # the same shapes (the toy's whole ring is a few KB: the cap)
+        from ai_agent_kubectl_tpu.ops.ragged_attention import stream_depth
+        cfg = eng.model_cfg
+        assert pool["attention_stream_depth"] == stream_depth(
+            eng._pool_max_pages, eng.kv_pool_page, cfg.n_heads,
+            cfg.n_kv_heads, cfg.head_dim, 1, 4) == 4
         for route in ROUTES:
             detail = await _ask(client, route, f"list pods via {route}")
             by = _check_tree(detail)

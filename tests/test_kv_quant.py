@@ -121,6 +121,7 @@ async def test_int8_kv_pool_serves_gather_and_says_why():
         # no kernel, so nothing of it was resolved (ISSUE 30)
         assert health["attention_pages_per_step"] is None
         assert health["attention_decode_grid_steps"] is None
+        assert health["attention_stream_depth"] is None     # ISSUE 32
         r = await eng.generate("get pods -o wide", max_tokens=8,
                                temperature=0.0)
         assert r.completion_tokens > 0

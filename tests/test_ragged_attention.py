@@ -24,6 +24,7 @@ this file with NO marker filter, so every one still gates every run.
 """
 
 import asyncio
+import os
 from types import SimpleNamespace
 
 import numpy as np
@@ -92,10 +93,11 @@ def _program_total(eng) -> int:
 # reference comparison here is the semantic ground truth for every
 # engine-level byte-identity test below.
 
-def _reference(q, k, v, q_lens, positions, tables, page):
+def _reference(q, k, v, q_lens, positions, tables, page, sel=None):
     """Dense gather reference: per slot, gather kv rows 0..pos+q_len-1
     through the block table, softmax per (query column, head) with the
-    causal-in-window rule (column j attends kv <= pos+j)."""
+    causal-in-window rule (column j attends kv <= pos+j), over the keys
+    ``sel[n, j]`` names when it is given."""
     N, W, H, hd = q.shape
     KV = k.shape[2]
     G = H // KV
@@ -116,6 +118,8 @@ def _reference(q, k, v, q_lens, positions, tables, page):
             for h in range(H):
                 g = h // G
                 s = (ks[:kj, g] @ q[n, j, h]) * scale
+                if sel is not None:
+                    s = np.where(sel[n, j, :kj], s, -np.inf)
                 s = s - s.max()
                 w = np.exp(s)
                 w /= w.sum()
@@ -197,39 +201,63 @@ def test_ragged_kernel_query_tiles_match_reference():
         assert np.all(out[n, qn:] == 0.0)
 
 
-def _block_case(name):
-    """Cases for the page-block axis (ISSUE 30): (H, KV, W, tables' width,
-    [(position, q_len), ...]) at page 64, where the kernel resolves 8 pages
-    a step — so 11 pages are two blocks, the second of three pages."""
-    return {
-        # the block does not divide the table; spans end in both blocks,
-        # one on the table's last page
-        "table-not-divided": (4, 2, 4, 11, [(560, 4), (240, 1), (690, 4)]),
-        # the last live page is the LAST of block 0 (7), the FIRST of
-        # block 1 (8), the first page of all (0) and the table's last (15)
-        "block-edges": (4, 2, 1, 16, [(511, 1), (512, 1), (0, 1), (1023, 1)]),
-        # every slot frozen but one, which is neither first nor last
-        "all-frozen-but-one": (4, 2, 4, 16,
-                               [(70, 0), (70, 0), (616, 3), (70, 0)]),
-        # Mixtral-8x7B over model:4: a chip holds 8Q/2KV
-        "mesh-local-8q-2kv": (8, 2, 6, 11,
-                              [(640, 1), (136, 6), (0, 5), (320, 0)]),
-    }[name]
+#: Cases for the page-block axis (ISSUE 30) and the live-block stream (ISSUE
+#: 32): name -> (H, KV, W, tables' width, [(position, q_len), ...]) at page
+#: 64, where the kernel resolves 8 pages a step (so 11 pages are two blocks,
+#: the second of three pages) and a ring of 4 buffers. A name ending in
+#: "+sel" also hands the kernel a ``sel`` operand; one starting with "tiles:"
+#: runs with the query tile cut to 8 columns, so a 24-wide window is three
+#: tiles of 256 rows (the form that transposes a block), where every other
+#: case here is one narrow tile (the form that reads a block as stored).
+_BLOCK_CASES = {
+    # the block does not divide the table; spans end in both blocks,
+    # one on the table's last page
+    "table-not-divided": (4, 2, 4, 11, [(560, 4), (240, 1), (690, 4)]),
+    # the last live page is the LAST of block 0 (7), the FIRST of
+    # block 1 (8), the first page of all (0) and the table's last (15)
+    "block-edges": (4, 2, 1, 16, [(511, 1), (512, 1), (0, 1), (1023, 1)]),
+    # every slot frozen but one, which is neither first nor last
+    "all-frozen-but-one": (4, 2, 4, 16,
+                           [(70, 0), (70, 0), (616, 3), (70, 0)]),
+    # Mixtral-8x7B over model:4: a chip holds 8Q/2KV
+    "mesh-local-8q-2kv": (8, 2, 6, 11,
+                          [(640, 1), (136, 6), (0, 5), (320, 0)]),
+    # a slot with more live blocks (6, then 7) than the ring has buffers:
+    # every buffer is reused while the cursor is ahead
+    "more-blocks-than-ring": (4, 2, 1, 56,
+                              [(2900, 1), (70, 1), (3500, 1)]),
+    # two live blocks in the whole call: the ring is never full, and the
+    # cursor runs off the end of the grid while priming
+    "fewer-blocks-than-ring": (4, 2, 1, 16, [(100, 0), (300, 1), (30, 1)]),
+    # Keye's heads at decode, three blocks a slot, a frozen slot last
+    "32q-4kv-decode": (32, 4, 1, 24, [(1400, 1), (0, 1), (777, 1), (64, 0)]),
+    # ... with the selector's mask
+    "32q-4kv-decode+sel": (32, 4, 1, 24,
+                           [(1400, 1), (0, 1), (777, 1), (64, 0)]),
+    # ... and a verify window of 4 (128 rows: the widest narrow tile)
+    "32q-4kv-verify+sel": (32, 4, 4, 24, [(1400, 4), (510, 2), (64, 0),
+                                          (777, 4)]),
+    # frozen slots and tiles past q_len BETWEEN live ones, each live slot
+    # with more blocks than the cursor's lead: it has to step over them
+    "tiles:dead-between-live": (32, 4, 24, 40, [
+        (1500, 24), (70, 0), (900, 3), (70, 0), (2000, 20)]),
+    # a window's tiles, then decode rows in the same call
+    "tiles:window-then-decode-rows+sel": (32, 4, 24, 40, [
+        (1100, 24), (2400, 1), (0, 1), (1999, 1)]),
+}
+
+#: ``ragged_attention_pool``'s outputs for ``_BLOCK_CASES`` at the parent
+#: of ISSUE 32 (commit 883044c: two buffers, one block ahead, every tile
+#: through the transposing form), written by running this file (bottom).
+_PARENT_OUTPUTS = os.path.join(os.path.dirname(__file__), "data",
+                               "ragged_kernel_outputs_883044c.npz")
 
 
-@pytest.mark.parametrize("name", ["table-not-divided", "block-edges",
-                                  "all-frozen-but-one", "mesh-local-8q-2kv"])
-def test_ragged_kernel_page_blocks_match_reference(name):
-    """The kernel fetches and attends a block of pages a grid step: spans
-    that end anywhere in a block, a table the block does not divide and
-    frozen neighbours all match the gather reference. Every block the
-    tables do not map is NaN, so a page copied past the live span, or a
-    never-copied buffer page meeting probability 0, poisons the output."""
-    from ai_agent_kubectl_tpu.ops.ragged_attention import pages_per_step
-
-    H, KV, W, pages, spans = _block_case(name)
+def _block_inputs(name):
+    """(q, k, v, q_lens, positions, tables, sel or None) of a case, from a
+    fixed seed. Every block the tables do not map is NaN."""
+    H, KV, W, pages, spans = _BLOCK_CASES[name]
     page, hd, N = 64, 16, len(spans)
-    assert pages_per_step(pages, page, H, KV, hd, W, itemsize=4) == 8
     rng = np.random.default_rng(5)
     n_blocks = N * pages + 1
     k = np.full((n_blocks, page, KV, hd), np.nan, np.float32)
@@ -243,15 +271,102 @@ def test_ragged_kernel_page_blocks_match_reference(name):
     q = rng.standard_normal((N, W, H, hd)).astype(np.float32)
     positions = np.array([s[0] for s in spans], np.int32)
     q_lens = np.array([s[1] for s in spans], np.int32)
-    out = np.asarray(ragged_attention_pool(
-        q, k, v, q_lens, positions, tables, page_size=page))
+    sel = None
+    if name.endswith("+sel"):
+        # two keys in five, and always a column's own row
+        sel = rng.random((N, W, pages * page)) < 0.4
+        for n, (pos, _q_len) in enumerate(spans):
+            sel[n, np.arange(W), pos + np.arange(W)] = True
+    return q, k, v, q_lens, positions, tables, sel
+
+
+def _run_block_case(ra, name):
+    """The case through ``ra.ragged_attention_pool`` (this checkout's
+    module, or the parent's when the saved outputs are written)."""
+    q, k, v, q_lens, positions, tables, sel = _block_inputs(name)
+    elems = ra._Q_TILE_ELEMS
+    if name.startswith("tiles:"):
+        ra._Q_TILE_ELEMS = 8 * q.shape[2] * q.shape[3]
+    try:
+        # the undecorated function: the jit's cache does not know the tile
+        kw = {} if sel is None else {"sel": sel}
+        return np.asarray(ra.ragged_attention_pool.__wrapped__(
+            q, k, v, q_lens, positions, tables, page_size=64,
+            interpret=True, **kw))
+    finally:
+        ra._Q_TILE_ELEMS = elems
+
+
+@pytest.mark.parametrize("name", list(_BLOCK_CASES))
+def test_ragged_kernel_page_blocks_match_reference(name):
+    """The kernel fetches and attends a block of pages a grid step, its
+    live blocks streamed through a ring of buffers by a cursor that runs
+    ahead: spans that end anywhere in a block, a table the block does not
+    divide, frozen neighbours, more blocks than buffers and fewer, dead
+    tiles under the cursor, the selector's mask — all match the gather
+    reference. Every block the tables do not map is NaN, so a page copied
+    past the live span, a buffer read before its copy lands, or a
+    never-copied buffer page meeting probability 0, poisons the output.
+    Against the parent's saved outputs: a tile that transposes the block
+    does the parent's arithmetic and gives its bits; a narrow tile reads
+    the block as stored (sums over the same keys in another order) and
+    agrees to float32 rounding."""
+    from ai_agent_kubectl_tpu.ops import ragged_attention as ra
+
+    H, KV, W, pages, spans = _BLOCK_CASES[name]
+    tq = 8 if name.startswith("tiles:") else W
+    shape = (pages, 64, H, KV, 16, tq, 4)
+    assert ra.pages_per_step(*shape) == 8
+    assert ra.stream_depth(*shape) == 4
+    q, k, v, q_lens, positions, tables, sel = _block_inputs(name)
+    out = _run_block_case(ra, name)
     assert not np.isnan(out).any(), "an unmapped or uncopied page leaked"
-    ref = _reference(q, k, v, q_lens, positions, tables, page)
+    ref = _reference(q, k, v, q_lens, positions, tables, 64, sel)
     for n, qn in enumerate(q_lens):
         np.testing.assert_allclose(out[n, :qn], ref[n, :qn],
                                    atol=2e-5, rtol=2e-5,
                                    err_msg=f"{name}: slot {n} (q_len={qn})")
         assert np.all(out[n, qn:] == 0.0)
+    with np.load(_PARENT_OUTPUTS) as saved:
+        parent = saved[name]
+    if ra._flat(tq, H, KV):
+        np.testing.assert_allclose(out, parent, atol=2e-6, rtol=2e-6)
+    else:
+        np.testing.assert_array_equal(out, parent)
+
+
+@pytest.mark.parametrize("shape,pages,depth,flat", [
+    # (table pages, page, H, KV, hd, W, itemsize): the four cells' chunk
+    # programs, bf16 KV at page 64
+    ((65, 64, 32, 8, 128, 1, 2), 8, 3, True),       # Mistral / Mixtral-l6 decode
+    ((65, 64, 32, 8, 128, 2, 2), 8, 3, True),       # ... a verify window of 2
+    ((65, 64, 32, 8, 128, 4, 2), 8, 4, False),      # ... of 4: 1,024 a key
+    ((65, 64, 32, 8, 128, 64, 2), 4, 3, False),     # ... one full query tile
+    ((65, 64, 32, 8, 128, 1024, 2), 4, 3, False),   # ... its widest admission
+    ((65, 64, 8, 2, 128, 1, 2), 8, 4, True),        # a chip of model:4, decode
+    ((65, 64, 8, 2, 128, 512, 2), 4, 4, False),     # ... a 256-column tile
+    ((257, 64, 32, 4, 128, 1, 2), 8, 4, True),      # Keye-l8 decode
+    ((257, 64, 32, 4, 128, 64, 2), 4, 4, False),    # ... its riding window
+    ((257, 64, 32, 4, 128, 512, 2), 4, 4, False),   # ... an eager piece
+    ((4, 16, 4, 2, 32, 1, 4), 4, 4, True),          # the toy engine's table
+    ((1, 64, 32, 8, 128, 1, 2), 1, 4, True),        # never wider than the table
+], ids=lambda x: "-".join(map(str, x)) if isinstance(x, tuple) else None)
+def test_pages_per_step_and_stream_depth_from_shapes(shape, pages, depth,
+                                                     flat):
+    """What a call resolves from its shapes, and nothing else decides: the
+    pages a grid step attends, the buffers its live blocks stream through
+    (2 .. 4: what ``_KV_VMEM_BYTES`` holds beside the score tiles), and
+    which form a tile's rows take. Within the budget every time."""
+    from ai_agent_kubectl_tpu.ops import ragged_attention as ra
+
+    n_pages, page, H, KV, hd, W, itemsize = shape
+    assert ra.pages_per_step(*shape) == pages
+    assert ra.stream_depth(*shape) == depth
+    tq = ra._q_tile(W, H, hd)
+    assert ra._flat(tq, H, KV) is flat
+    ring = depth * 2 * pages * page * KV * hd * itemsize
+    scores = pages * ra._score_bytes_per_page(page, H, KV, tq)
+    assert ring + scores <= ra._KV_VMEM_BYTES
 
 
 def test_ragged_kernel_decode_column_equals_own_window():
@@ -792,3 +907,18 @@ async def test_jax_ragged_containment_reset_keeps_programs_warm():
         _books(eng)
     finally:
         await eng.stop()
+
+
+if __name__ == "__main__":
+    # PYTHONPATH=<parent checkout> python tests/test_ragged_attention.py \
+    #     <parent checkout>
+    # writes _PARENT_OUTPUTS from THAT checkout's kernel.
+    import sys
+
+    sys.path.insert(0, os.path.abspath(sys.argv[1]))
+    from ai_agent_kubectl_tpu.ops import ragged_attention as _parent_ra
+
+    assert os.path.abspath(sys.argv[1]) in _parent_ra.__file__
+    os.makedirs(os.path.dirname(_PARENT_OUTPUTS), exist_ok=True)
+    np.savez_compressed(_PARENT_OUTPUTS, **{
+        name: _run_block_case(_parent_ra, name) for name in _BLOCK_CASES})

@@ -72,6 +72,21 @@ def _pallas_grids(jaxpr):
             yield from _pallas_grids(sub)
 
 
+def _ragged_kv_buffers(jaxpr):
+    """The shape of the ragged kernel's K buffer ring (its first VMEM
+    scratch) in every ``pallas_call`` of a jaxpr that has a DMA semaphore
+    among its scratch, sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            n = eqn.params["grid_mapping"].num_scratch_operands
+            scratch = [v.aval for v in eqn.params["jaxpr"].invars[-n:]] \
+                if n else []
+            if any("dma" in str(a).lower() for a in scratch):
+                yield tuple(scratch[0].shape)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _ragged_kv_buffers(sub)
+
+
 @pytest.mark.parametrize("H,KV", [(32, 8), (8, 2)],
                          ids=["one-chip-32q8kv", "mesh-local-8q2kv"])
 @pytest.mark.parametrize("W", [1, 64, 1024],
@@ -87,11 +102,17 @@ def test_pool_forward_moves_no_pool_sized_buffer_on_v5e(one_chip, W, H, KV,
     the pool was sliced, copied and rebuilt every pass, and held twice).
     ISSUE 30: the kernel's page axis is ``cdiv(pages, P)`` for the P its
     shapes resolve, 8 pages a decode step and 4 beside a full query tile's
-    scores: one page a step again (1,040 steps a decode call) fails here."""
+    scores: one page a step again (1,040 steps a decode call) fails here.
+    ISSUE 32: its live blocks stream through a ring of ``stream_depth``
+    buffers (4 or 3 at decode, 3 or 4 beside a full tile), row-tiled
+    [pages, page*KV, hd] where a narrow tile reads a block as stored; a
+    ring Mosaic cannot fit in scoped VMEM is refused here, not on the
+    chip."""
     from jax.experimental import pallas as pl
 
     from ai_agent_kubectl_tpu.ops.ragged_attention import (_q_tile,
-                                                           pages_per_step)
+                                                           pages_per_step,
+                                                           stream_depth)
 
     # ops/ragged_attention.py interprets the kernel off-TPU; this compile
     # is for the TPU, whatever backend the process runs on.
@@ -132,6 +153,11 @@ def test_pool_forward_moves_no_pool_sized_buffer_on_v5e(one_chip, W, H, KV,
     assert pps == 8 if W == 1 else pps in (4, 8), pps
     grid = (B, pl.cdiv(W, _q_tile(W, H, cfg.head_dim)), pl.cdiv(pages, pps))
     assert set(_pallas_grids(traced.jaxpr.jaxpr)) == {grid}
+    depth = stream_depth(pages, page, H, KV, cfg.head_dim, W)
+    assert depth == {(1, 8): 3, (64, 8): 3, (1024, 8): 3}.get((W, KV), 4)
+    assert set(_ragged_kv_buffers(traced.jaxpr.jaxpr)) == {
+        (depth, pps) + ((page * KV,) if W == 1 else (page, KV))
+        + (cfg.head_dim,)}
     compiled = traced.lower().compile()
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo, "the Mosaic kernel is not in the program"
@@ -458,10 +484,17 @@ def test_selecting_forward_compiles_at_published_widths_on_v5e(one_chip, W,
                        write_mask=wmask, block_tables=tables, q_lens=q_lens,
                        logits_at=jnp.maximum(q_lens, 1) - 1)
 
-    compiled = jax.jit(step, donate_argnums=(3,)).lower(
+    traced = jax.jit(step, donate_argnums=(3,)).trace(
         params, arg((B, W), jnp.int32), arg((B, W), jnp.int32), cache,
         arg((B, W), jnp.bool_), arg((B, pages), jnp.int32),
-        arg((B,), jnp.int32)).compile()
+        arg((B,), jnp.int32))
+    # ISSUE 32: both ragged calls of the program (with the mask and
+    # without) stream their live blocks through 4 buffers: a decode row's
+    # row-tiled, 8 pages of [64 * 4 KV, 128]; a window's 4 pages of
+    # [64, 4, 128]
+    assert set(_ragged_kv_buffers(traced.jaxpr.jaxpr)) == {
+        (4, 8, 256, 128) if W == 1 else (4, 4, 64, 4, 128)}
+    compiled = traced.lower().compile()
     hlo = compiled.as_text()
     # the grouped expert kernel, and the ragged kernel in the dense branch
     # (and, for a window, again with its mask in the selecting branch)

@@ -159,7 +159,7 @@ def test_int8_convert_fuses_into_weight_read():
 # floor a TPU engine serves (64), the per-slot table of
 # MAX_SEQ_LEN=1024, and the same model's tp=4 shard (H 8, KV 2).
 
-_POOL_GEOMETRIES = ((32, 8), (8, 2))      # (H, KV)
+_POOL_GEOMETRIES = ((32, 8), (8, 2), (32, 4))      # (H, KV); Keye's last
 _POOL_HD, _POOL_PAGE, _POOL_PAGES = 128, 64, 17
 #: every window width the engine warms a ragged program for: decode (1),
 #: a spec verify window (k+1 = 4), and the default PREFILL_BUCKETS.
@@ -279,21 +279,27 @@ def test_compiled_ragged_stacked_pool_layer_equals_layer_slice(W):
 
 
 @pytest.mark.parametrize("H,KV", _POOL_GEOMETRIES)
-@pytest.mark.parametrize("pages", (_POOL_PAGES, 64))
+@pytest.mark.parametrize("pages", (_POOL_PAGES, 64, 257))
 def test_compiled_ragged_pool_decode_batch_matches_gather(H, KV, pages):
     """Compiled ragged kernel at q_len = 1 vs the dense gather reference
     over a full batch of ragged positions (first row of a sequence, page
     edges, the last row the table can hold), at a table the page block
-    does not divide (17) and at MAX_SEQ_LEN 4096's width (64)."""
+    does not divide (17) and at MAX_SEQ_LEN 4096's width (64); and at the
+    long-log cell's 257-page table with 100-245 live pages a slot (13-31
+    full blocks: the ring's buffers reused all the way)."""
     import numpy as np
 
     from ai_agent_kubectl_tpu.ops.ragged_attention import \
         ragged_attention_pool
 
-    edge = [0, _POOL_PAGE - 1, _POOL_PAGE, pages * _POOL_PAGE - 1]
     rng = np.random.RandomState(1)
-    spans = [(int(p), 1) for p in edge + list(
-        rng.randint(0, pages * _POOL_PAGE, 32 - len(edge)))]
+    if pages == 257:
+        spans = [(int(p), 1) for p in rng.randint(
+            100 * _POOL_PAGE, 245 * _POOL_PAGE, 16)]
+    else:
+        edge = [0, _POOL_PAGE - 1, _POOL_PAGE, pages * _POOL_PAGE - 1]
+        spans = [(int(p), 1) for p in edge + list(
+            rng.randint(0, pages * _POOL_PAGE, 32 - len(edge)))]
     q, (k, v), clean, q_lens, positions, tables = _pool_case(
         H, KV, 1, spans, seed=40, pages=pages)
     out = np.asarray(ragged_attention_pool(
