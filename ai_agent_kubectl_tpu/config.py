@@ -279,6 +279,10 @@ class ServiceConfig:
     # LRU budget (blocks) the radix tree may keep cached. 0 = auto
     # (a quarter of the pool).
     radix_lru_blocks: int = 0               # RADIX_LRU_BLOCKS
+    # Snapshots of a recurrent state the device holds beside the block
+    # pool, for a model with state-space layers (0 = auto, 4 a decode
+    # slot; engine/kv_pool.py::StateStore). Ignored by every other model.
+    state_snapshots: int = 0                # STATE_SNAPSHOTS
     # --- two-tier KV: host-RAM block offload (ISSUE 20) ---
     # Capacity (blocks) of the pinned host-RAM second tier behind the
     # radix tree: eviction under HBM pressure DEMOTES cold chains there
@@ -648,6 +652,10 @@ class ServiceConfig:
             raise ValueError(
                 f"KV_POOL_BLOCKS must be >= 0 (0 = auto), "
                 f"got {self.kv_pool_blocks}")
+        if self.state_snapshots < 0:
+            raise ValueError(
+                f"STATE_SNAPSHOTS must be >= 0 (0 = auto), "
+                f"got {self.state_snapshots}")
         if self.radix_lru_blocks < 0:
             raise ValueError(
                 f"RADIX_LRU_BLOCKS must be >= 0 (0 = auto), "
@@ -844,6 +852,7 @@ class ServiceConfig:
             kv_pool_blocks=_env_int("KV_POOL_BLOCKS", 0),
             radix_cache=_env_bool("RADIX_CACHE", True),
             radix_lru_blocks=_env_int("RADIX_LRU_BLOCKS", 0),
+            state_snapshots=_env_int("STATE_SNAPSHOTS", 0),
             host_kv_blocks=_env_int("HOST_KV_BLOCKS", 0),
             grammar_decode=_env_bool("GRAMMAR_DECODE", False),
             grammar_profile=(_env_str("GRAMMAR_PROFILE", "default")

@@ -34,6 +34,7 @@ from __future__ import annotations
 import asyncio
 import collections
 import dataclasses
+import functools
 import logging
 import queue as _queue
 import threading
@@ -46,7 +47,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.transformer import KVCache, forward, serves_grouped
+from ..models.transformer import (KVCache, forward, serves_grouped,
+                                  state_put_row, state_take_row, state_zeros)
 from ..obs.ledger import (CLASS_DELIVERED, CLASS_DRAFT_REJECTED,
                           CLASS_HEDGE_LOSER, CLASS_PREEMPTED,
                           CLASS_QUARANTINE_BURN, CLASS_REPLAYED,
@@ -63,8 +65,9 @@ from .containment import (CAUSE_SCHEDULER_DEATH, CAUSE_SCHEDULER_ERROR,
                           CAUSE_SLOT_HEALTH, PROBATION_CLEAN_CHUNKS,
                           REASON_HEALTH, REASON_ISOLATED, EngineSupervisor)
 from .jax_engine import JaxEngine, kv_bucket_ladder
-from .kv_pool import (BlockPool, HostBlockStore, alloc_with_evict,
-                      map_prefix, pages_for)
+from .kv_pool import (BlockPool, HostBlockStore, StateStore,
+                      alloc_with_evict, map_prefix, pages_for, release_state,
+                      state_cuts, take_snapshot)
 from .radix_cache import RadixCache
 from .regime import DENSE, RAGGED, resolve_attention_regime
 from .protocol import (HEALTH_GRAMMAR_DEAD, HEALTH_NONFINITE,
@@ -801,6 +804,35 @@ def staged_suffix_len(suffix: int, buckets) -> int:
     return suffix if suffix <= buckets[-1] else buckets[0]
 
 
+def state_refusal(model_cfg, regime: str, mesh_shape, spec_decode: bool
+                  ) -> Optional[str]:
+    """Why this engine cannot serve a configuration with state-space
+    layers (``ModelConfig.keeps_state``), or None. Its recurrent state is
+    a leaf of the pool engine's cache, a row a decode slot, with
+    snapshots on the radix tree: the dense per-slot ladder has neither,
+    parallel/sharding.py::param_specs has no rule for the family's
+    leaves, and a rejected draft token would have moved a state that
+    cannot be moved back."""
+    if not model_cfg.keeps_state:
+        return None
+    why = None
+    if regime == DENSE:
+        why = ("the dense per-slot KV ladder keeps no recurrent state "
+               "(KV_POOL=false, or a mesh axis the pool refuses)")
+    elif any(n > 1 for n in (mesh_shape or {}).values()):
+        why = (f"MESH_SHAPE {dict(mesh_shape)}: parallel/sharding.py has no "
+               f"rule for the state-space and per-kind leaves; the family "
+               f"is served on one device")
+    elif spec_decode:
+        why = ("SPEC_DECODE: a rejected draft position would have advanced "
+               "the recurrent state")
+    if why is None:
+        return None
+    return (f"{model_cfg.name} keeps a recurrent state (layer_pattern "
+            f"{model_cfg.layer_pattern[:model_cfg.n_layers]!r}) and is not "
+            f"served here: {why}")
+
+
 def selection_refusal(model_cfg, regime: str, mesh_shape, kv_quant: str
                       ) -> Optional[str]:
     """Why this engine cannot serve a key-selecting configuration
@@ -996,6 +1028,7 @@ class BatchedJaxEngine(JaxEngine):
                  radix_cache: bool = True,
                  radix_lru_blocks: int = 0,
                  host_kv_blocks: int = 0,
+                 state_snapshots: int = 0,
                  grammar_decode: bool = False,
                  grammar_profile: str = "default",
                  grammar_forced_run_min: int = 4,
@@ -1083,6 +1116,13 @@ class BatchedJaxEngine(JaxEngine):
         # behind the radix tree; 0 keeps the single-tier world.
         self.host_kv_blocks = max(0, host_kv_blocks)
         self._host_store: Optional[HostBlockStore] = None
+        # Recurrent-state cache (ISSUE 33), for a model with state-space
+        # layers only: ``state_snapshots`` rows of device memory hold
+        # snapshots of a sequence's state (0 = auto, 4 a decode slot);
+        # the StateStore is their host truth, rebuilt with the pool.
+        self.state_snapshots = max(0, state_snapshots)
+        self._state: Optional[StateStore] = None
+        self._snap_ssm = self._snap_conv = None
         self._use_pool = False        # resolved at start (mesh fallback)
         # True when KV_POOL was requested but the mesh forced the dense
         # ladder (data/pipe/seq axes >1 — the pool's block axis is a
@@ -1110,6 +1150,7 @@ class BatchedJaxEngine(JaxEngine):
         self._counts_experts = False
         self._moe_experts_read = 0
         self._moe_layer_passes = 0
+        self._eager_passes = 0        # one-sequence prefill pieces run
         # window rows are the scheduler's arithmetic on prompt lengths; what
         # decode queries saw and kept is counted on the device (sel_rows)
         self._selection_counts = dict.fromkeys(
@@ -1376,6 +1417,7 @@ class BatchedJaxEngine(JaxEngine):
             radix_cache=cfg.radix_cache,
             radix_lru_blocks=cfg.radix_lru_blocks,
             host_kv_blocks=cfg.host_kv_blocks,
+            state_snapshots=cfg.state_snapshots,
             grammar_decode=cfg.grammar_decode,
             grammar_profile=cfg.grammar_profile,
             grammar_forced_run_min=cfg.grammar_forced_run_min,
@@ -1457,10 +1499,15 @@ class BatchedJaxEngine(JaxEngine):
             self.model_cfg, regime,
             dict(self.mesh.shape) if self.mesh is not None else None,
             self.kv_quant)
+        refusal = refusal or state_refusal(
+            self.model_cfg, regime,
+            dict(self.mesh.shape) if self.mesh is not None else None,
+            self.spec_decode)
         if refusal:
             # A selecting configuration's index keys live in the block
-            # pool's own leaf: what cannot carry that leaf refuses the
-            # model at start (server: engine "degraded", this reason).
+            # pool's own leaf, a state-keeping one's state beside it: what
+            # cannot carry that leaf refuses the model at start (server:
+            # engine "degraded", this reason).
             logger.error("%s", refusal)
             raise ValueError(refusal)
         # The grouped expert path counts the experts it reads
@@ -2108,7 +2155,25 @@ class BatchedJaxEngine(JaxEngine):
             # re-allocate; the radix tree repopulates organically).
             self._cache = self._new_pool_cache()
             prev_pool, prev_radix = self._pool, self._radix
-            prev_store = self._host_store
+            prev_store, prev_state = self._host_store, self._state
+            self._state = None
+            if self.model_cfg.keeps_state:
+                # One live state a slot rides the cache; the snapshot
+                # store is device memory of its own, ``capacity`` rows,
+                # whose host truth is rebuilt with the pool's (a reset
+                # condemns every snapshot with the K/V it belongs to).
+                cap = self.state_snapshots or 4 * N
+                self._snap_ssm, self._snap_conv = state_zeros(
+                    self.model_cfg, cap, self.dtype)
+                self._state = StateStore(
+                    cap, N, self.model_cfg.state_bytes(),
+                    snapshot_fn=self._state_snapshot_dev,
+                    restore_fn=self._state_restore_dev,
+                    zero_fn=self._state_zero_dev,
+                    region=lambda name, **meta: self._spans.sched.region(
+                        "admit", name, **meta))
+                if prev_state is not None:
+                    self._state.carry_counters(prev_state)
             self._pool = BlockPool(self._pool_n_blocks, self.kv_pool_page)
             # Two-tier rebuild (ISSUE 20): a reset condemns the host
             # tier too — its payloads were gathered from the poisoned
@@ -2121,7 +2186,8 @@ class BatchedJaxEngine(JaxEngine):
                                       host_store=self._host_store,
                                       offload_fn=self._pool_offload_block,
                                       onload_fn=self._pool_onload_block,
-                                      faults=self.faults)
+                                      faults=self.faults,
+                                      state_store=self._state)
                            if self.radix_cache else None)
             # Cumulative counters survive the rebuild — the /metrics
             # delta-mirror must never see totals go backwards.
@@ -2202,11 +2268,20 @@ class BatchedJaxEngine(JaxEngine):
         under KV_QUANT=int8). ``lengths`` is [n_blocks]-shaped and purely
         structural — per-slot lengths are host truth (slot.pos)."""
         cfg = self.model_cfg
-        shape = (cfg.n_layers, self._pool_n_blocks, self.kv_pool_page,
+        # A patterned configuration's pool holds its ATTENTION layers' rows
+        # alone, and its cache a live recurrent state a decode slot.
+        shape = (cfg.n_of("*"), self._pool_n_blocks, self.kv_pool_page,
                  cfg.n_kv_heads, cfg.head_dim)
         dtype, kv_quant = self.dtype, self.kv_quant
+        N = self.batch_size
         n_blocks = self._pool_n_blocks
         counts_experts = self._counts_experts
+
+        def state() -> dict:
+            if not cfg.keeps_state:
+                return {}
+            ssm, conv = state_zeros(cfg, N, dtype)
+            return {"ssm": ssm, "conv": conv}
 
         def make() -> KVCache:
             lengths = jnp.zeros((n_blocks,), jnp.int32)
@@ -2217,7 +2292,7 @@ class BatchedJaxEngine(JaxEngine):
                     return QuantKV(q=jnp.zeros(shape, jnp.int8),
                                    s=jnp.ones(shape[:-1], jnp.float32))
 
-                return KVCache(k=zq(), v=zq(), lengths=lengths)
+                return KVCache(k=zq(), v=zq(), lengths=lengths, **state())
             # A selecting configuration's index keys: one row a token a
             # layer in the SAME blocks. Where the grouped expert path
             # serves, the count of experts it read since the chunk
@@ -2230,7 +2305,8 @@ class BatchedJaxEngine(JaxEngine):
                 experts_read=(jnp.zeros((), jnp.int32)
                               if counts_experts else None),
                 sel_rows=(jnp.zeros((2,), jnp.int32)
-                          if cfg.selects_keys else None))
+                          if cfg.selects_keys else None),
+                **state())
 
         if self.mesh is None:
             return make()
@@ -2248,6 +2324,52 @@ class BatchedJaxEngine(JaxEngine):
 
         return jax.jit(make, out_shardings=pool_cache_shardings(
             jax.eval_shape(make), self.mesh, self.model_cfg))()
+
+    # ------------------------------ recurrent-state rows (ISSUE 33)
+    #
+    # Three small device programs move one sequence's state (12.8 MB at
+    # the benchmark's cut) between a decode slot's row of the cache and
+    # a row of the snapshot store; the StateStore decides when. They
+    # dispatch in order with every other program, so a restore lands
+    # before the prefill that reads it and a snapshot after the prefill
+    # whose end it saves.
+
+    @functools.cached_property
+    def _state_copy_fn(self):
+        def copy(dst_ssm, dst_conv, src_ssm, src_conv, dst, src):
+            one = lambda a: jax.lax.dynamic_slice_in_dim(a, src, 1, axis=1)
+            put = lambda a, u: jax.lax.dynamic_update_slice_in_dim(
+                a, u, dst, axis=1)
+            with jax.named_scope("ssm/state_copy"):
+                return put(dst_ssm, one(src_ssm)), put(dst_conv, one(src_conv))
+
+        return jax.jit(copy, donate_argnums=(0, 1))
+
+    def _state_snapshot_dev(self, slot: int, handle: int) -> None:
+        self._snap_ssm, self._snap_conv = self._state_copy_fn(
+            self._snap_ssm, self._snap_conv, self._cache.ssm,
+            self._cache.conv, np.int32(handle), np.int32(slot))
+
+    def _state_restore_dev(self, slot: int, handle: int) -> None:
+        ssm, conv = self._state_copy_fn(
+            self._cache.ssm, self._cache.conv, self._snap_ssm,
+            self._snap_conv, np.int32(slot), np.int32(handle))
+        self._cache = dataclasses.replace(self._cache, ssm=ssm, conv=conv)
+
+    @functools.cached_property
+    def _state_zero_fn(self):
+        def zero(ssm, conv, slot):
+            z = lambda a: jax.lax.dynamic_update_slice_in_dim(
+                a, jnp.zeros_like(a[:, :1]), slot, axis=1)
+            with jax.named_scope("ssm/state_copy"):
+                return z(ssm), z(conv)
+
+        return jax.jit(zero, donate_argnums=(0, 1))
+
+    def _state_zero_dev(self, slot: int) -> None:
+        ssm, conv = self._state_zero_fn(self._cache.ssm, self._cache.conv,
+                                        np.int32(slot))
+        self._cache = dataclasses.replace(self._cache, ssm=ssm, conv=conv)
 
     def _tables_d(self, tables: np.ndarray):
         """Device copy of a block-table snapshot — committed REPLICATED
@@ -2280,6 +2402,22 @@ class BatchedJaxEngine(JaxEngine):
         fn = self._pool_prefill_fns.get(key)
         if fn is None:
             cfg = self.model_cfg
+
+            def one_row(run):
+                """``run(cache) -> (logits, cache)`` on a cache whose state
+                leaves are cut to decode slot ``slot``'s row: a one-
+                sequence prefill continues THAT slot's recurrent state
+                and leaves it there (stateless models: ``run`` as is)."""
+                def with_slot(cache, slot):
+                    if not cfg.keeps_state:
+                        return run(cache)
+                    logits, out = run(state_take_row(cache, slot))
+                    return logits, state_put_row(
+                        dataclasses.replace(out, ssm=cache.ssm,
+                                            conv=cache.conv),
+                        out.ssm, out.conv, slot)
+                return with_slot
+
             if self._use_ragged:
                 # Ragged mode (ISSUE 19): the standalone prefill reads
                 # through the SAME kernel as decode — per-row q_lens
@@ -2292,30 +2430,32 @@ class BatchedJaxEngine(JaxEngine):
                 # garbage at future positions; both are never attended
                 # before being rewritten).
                 def pool_prefill(params, tokens, positions, cache, mask,
-                                 tables):
+                                 tables, slot):
                     q_lens = mask.sum(axis=1).astype(jnp.int32)
-                    return forward(params, cfg, tokens, positions,
-                                   cache, kv_limit=kv_limit,
-                                   attn_impl="ragged",
-                                   mesh=self.mesh,
-                                   moe_impl=self.moe_impl,
-                                   token_mask=mask,
-                                   write_mask=mask > 0,
-                                   logits_at=jnp.maximum(q_lens - 1, 0),
-                                   block_tables=tables,
-                                   q_lens=q_lens)
+                    return one_row(lambda cache: forward(
+                        params, cfg, tokens, positions,
+                        cache, kv_limit=kv_limit,
+                        attn_impl="ragged",
+                        mesh=self.mesh,
+                        moe_impl=self.moe_impl,
+                        token_mask=mask,
+                        write_mask=mask > 0,
+                        logits_at=jnp.maximum(q_lens - 1, 0),
+                        block_tables=tables,
+                        q_lens=q_lens))(cache, slot)
             else:
                 impl = self._prefill_impl_for(bucket, kv_limit)
 
                 def pool_prefill(params, tokens, positions, cache, mask,
-                                 tables):
+                                 tables, slot):
                     last = jnp.maximum(
                         mask.sum(axis=1).astype(jnp.int32) - 1, 0)
-                    return forward(params, cfg, tokens, positions, cache,
-                                   kv_limit=kv_limit, attn_impl=impl,
-                                   mesh=self.mesh, moe_impl=self.moe_impl,
-                                   token_mask=mask, logits_at=last,
-                                   block_tables=tables)
+                    return one_row(lambda cache: forward(
+                        params, cfg, tokens, positions, cache,
+                        kv_limit=kv_limit, attn_impl=impl,
+                        mesh=self.mesh, moe_impl=self.moe_impl,
+                        token_mask=mask, logits_at=last,
+                        block_tables=tables))(cache, slot)
 
             fn = jax.jit(pool_prefill, donate_argnums=(3,))
             self._pool_prefill_fns[key] = fn
@@ -2461,17 +2601,39 @@ class BatchedJaxEngine(JaxEngine):
         shared verbatim with the fake engine)."""
         return alloc_with_evict(self._pool, self._radix, n)
 
-    def _pool_map_prefix(self, ids: List[int],
-                         match_all: bool = False) -> tuple:
+    def _pool_map_prefix(self, ids: List[int], match_all: bool = False,
+                         slot_idx: int = 0) -> tuple:
         """Build a slot's block chain (kv_pool.map_prefix — THE shared
         admission path, run verbatim by the fake engine too): shared
         full blocks + tail COW (the device copy is this engine's jitted
         ``_run_cow``) + fresh blocks. Returns (blocks, m)."""
         return map_prefix(self._pool, self._radix, ids,
-                          match_all=match_all, cow=self._run_cow)
+                          match_all=match_all, cow=self._run_cow,
+                          state=self._state, slot=slot_idx)
+
+    def _pool_prefill_to_cuts(self, slot_idx: int, ids: List[int],
+                              start: int, stop: int, n_prompt: int):
+        """Prefill ``ids[start:stop]`` for a model that keeps a recurrent
+        state, stopping at every block edge ``kv_pool.state_cuts`` names
+        to save the state there (the snapshot hangs on the tree at once
+        where the tree has the edge's node — a shared preamble — else on
+        the slot until its chain is inserted at release). Returns the
+        last valid position's logits (None when nothing was run)."""
+        logits, pos = None, start
+        cuts = (state_cuts(self._state, slot_idx, n_prompt,
+                           self.kv_pool_page, start)
+                if self._state is not None else [])
+        for edge in sorted({e for e in cuts if e <= stop} | {stop}):
+            if edge > pos:
+                logits = self._pool_prefill_span(
+                    self._tables[slot_idx], ids[:edge], pos, slot_idx)
+                pos = edge
+            if edge in cuts:
+                take_snapshot(self._state, self._radix, slot_idx, ids, edge)
+        return logits
 
     def _pool_prefill_span(self, table_row: np.ndarray, ids: List[int],
-                           start: int):
+                           start: int, slot_idx: int = 0):
         """Prefill ``ids[start:]`` at absolute offsets through the
         slot's table, largest-bucket chunks (the unified short / suffix /
         long-prompt path — a chunk IS a suffix of everything before it).
@@ -2498,9 +2660,11 @@ class BatchedJaxEngine(JaxEngine):
             logits, self._cache = self._get_pool_prefill_fn(
                 bucket, kv_limit)(
                 self.params, jnp.asarray(tokens), jnp.asarray(positions),
-                self._cache, jnp.asarray(mask), tables_d)
+                self._cache, jnp.asarray(mask), tables_d,
+                np.int32(slot_idx))
             offset += L
             self._selection_counts["forward_passes"] += 1
+            self._eager_passes += 1
         return logits[:, 0]
 
     def _pool_ensure_coverage(self, idx: int, slot: "_Slot",
@@ -2558,6 +2722,12 @@ class BatchedJaxEngine(JaxEngine):
                 self._radix.insert(chain, slot.blocks)
             except Exception:  # pragma: no cover - defensive
                 logger.exception("radix insert failed; chain not cached")
+                cache_chain = False
+            if self._state is not None and idx is not None:
+                release_state(self._state, self._radix, idx, chain,
+                              cache_chain)
+        elif self._state is not None and idx is not None:
+            release_state(self._state, None, idx, (), False)
         self._pool.decref(slot.blocks)
         slot.blocks = []
 
@@ -2600,7 +2770,7 @@ class BatchedJaxEngine(JaxEngine):
             else:
                 run, ends_eos = [], False
         full = ids + run
-        blocks, m = self._pool_map_prefix(ids)
+        blocks, m = self._pool_map_prefix(ids, slot_idx=slot_idx)
         # Session SLO gate (ISSUE 20): a seating that radix-matched at
         # least one full page is a warm re-admission — the only kind the
         # turn-N TTFT SLO judges (onload-served pages count: the match
@@ -2638,9 +2808,17 @@ class BatchedJaxEngine(JaxEngine):
                 # byte-identical transcripts).
                 stage_start = len(span) - staged_suffix_len(
                     len(span) - m, self.prefill_buckets)
+                if self._state is not None:
+                    # the window is cut at the prompt's last block edge,
+                    # so that the state there exists to be saved: what
+                    # rides is the prompt's last partial block (and the
+                    # forced run), the rest prefills eagerly
+                    stage_start = max(m, (n_prompt - 1)
+                                      // self.kv_pool_page
+                                      * self.kv_pool_page)
                 if stage_start > m:
-                    self._pool_prefill_span(
-                        self._tables[slot_idx], span[:stage_start], m)
+                    self._pool_prefill_to_cuts(slot_idx, span, m,
+                                               stage_start, n_prompt)
                 staged = dict(
                     ids=list(span[stage_start:]),
                     start=stage_start,
@@ -2666,8 +2844,8 @@ class BatchedJaxEngine(JaxEngine):
                 # 0..pos-1 and the draft world has no ragged window.
                 self._draft_prefill_slot(slot_idx, list(span))
             elif not done_at_admit:
-                last_logits = self._pool_prefill_span(
-                    self._tables[slot_idx], span, m)
+                last_logits = self._pool_prefill_to_cuts(
+                    slot_idx, span, m, len(span), n_prompt)
                 first_tok_d = self._grammar_first_sample(
                     last_logits, req, gs1, len(run))
                 self._run_arm(slot_idx, n_prompt + len(run), first_tok_d,
@@ -2683,10 +2861,13 @@ class BatchedJaxEngine(JaxEngine):
                 # span (the known spec-decode admission overhead).
                 self._draft_prefill_slot(slot_idx, list(span))
             else:
-                self._pool_prefill_span(self._tables[slot_idx], span, m)
+                self._pool_prefill_to_cuts(slot_idx, span, m, len(span),
+                                           n_prompt)
         except Exception:
             self._tables[slot_idx, :] = self._pool_n_blocks
             self._pool.decref(blocks)
+            if self._state is not None:
+                release_state(self._state, None, slot_idx, (), False)
             raise
         slot = _Slot(
             req=req,
@@ -2780,7 +2961,7 @@ class BatchedJaxEngine(JaxEngine):
             min(pages_for(b, self.kv_pool_page), self._pool_max_pages))
         row[:len(blocks)] = blocks
         self._pool_prefill_span(row, [0] * b, 0)
-        if cfg.selects_keys:
+        if cfg.selects_keys or cfg.keeps_state:
             # A selecting configuration is served for prompts far past
             # the widest bucket: their heads are prefilled eagerly, piece
             # by piece, and the last piece of a head may be any bucket
@@ -2863,6 +3044,10 @@ class BatchedJaxEngine(JaxEngine):
         so it stays hot) and does not survive an engine reset (the next
         admission re-prefills and re-caches it)."""
         if self._radix is None or not self.use_prefix_cache:
+            return
+        if self._state is not None:
+            # under a page it is a partial tail, which a model that keeps
+            # a recurrent state can never start from
             return
         from .prompts import SYSTEM_PROMPT
 
@@ -2984,6 +3169,25 @@ class BatchedJaxEngine(JaxEngine):
             return None
         return {"experts_read": self._moe_experts_read,
                 "layer_passes": self._moe_layer_passes}
+
+    def ssm_health(self) -> Optional[dict]:
+        """/health.ssm (cumulative; None for a model without state-space
+        layers): the snapshot store's counters (kv_pool.StateStore.stats)
+        and ``layer_passes`` by kind — forward passes the scheduler
+        dispatched (chunk programs' steps and one-sequence prefill
+        pieces) times the layers of the kind."""
+        if self._state is None:
+            return None
+        body = self._state.stats()
+        passes = self._selection_counts["forward_passes"]
+        body["forward_passes"] = passes
+        body["eager_prefill_passes"] = self._eager_passes
+        body["live_rows"] = self.batch_size
+        body["layer_passes"] = {
+            name: passes * self.model_cfg.n_of(kind)
+            for name, kind in (("ssm", "M"), ("experts", "E"),
+                               ("attention", "*"))}
+        return body
 
     def sparse_attention_health(self) -> Optional[dict]:
         """/health.sparse_attention (cumulative; None for a configuration
@@ -3327,9 +3531,18 @@ class BatchedJaxEngine(JaxEngine):
         the post-run index — byte-identical to what masked step-by-step
         decode (singleton support forces the same tokens) would have
         produced, which is the fast-forward on/off parity the tests
-        pin."""
+        pin.
+
+        A state-keeping model splices only while none of the slot's
+        decode chunks is in flight: the chunks already dispatched run
+        before the splice's prefill and have fed the run's first tokens
+        to the slot's recurrent state, and K/V rows are rewritten by
+        position where a state would take those tokens a second time.
+        The masked chunks force the same tokens, so the run is decoded."""
         if (self._grammar is None or not self._use_pool
                 or slot.req.gpid < 0 or slot.exhausted):
+            return
+        if self.model_cfg.keeps_state and slot.decode_chunks_inflight > 0:
             return
         req = slot.req
         g = len(slot.detok.ids)
@@ -3364,7 +3577,7 @@ class BatchedJaxEngine(JaxEngine):
         ids_full = list(slot.pool_ids or []) + list(slot.detok.ids) + run
         self._pool_prefill_span(self._tables[idx],
                                 ids_full[:base + len(run) - 1],
-                                max(0, base - 1))
+                                max(0, base - 1), idx)
         # Speculative decoding (ISSUE 12): mirror the forced span into
         # the draft cache (from base-1, attending over the slot's
         # already-decoded draft rows) — forced runs bypass drafting
@@ -3646,6 +3859,7 @@ class BatchedJaxEngine(JaxEngine):
             # counters, null where the configuration has neither.
             "moe": self.moe_health(),
             "sparse_attention": self.sparse_attention_health(),
+            "ssm": self.ssm_health(),
             "sharding": self.sharding_health(),
             "queue_rejections": self._rejections,
             "max_queue_depth": self.max_queue_depth,
@@ -4155,13 +4369,16 @@ class BatchedJaxEngine(JaxEngine):
             if len(replay_ids) > max_prompt:
                 replay_ids = replay_ids[-max_prompt:]
             n_total = len(replay_ids)
-            blocks, m = self._pool_map_prefix(replay_ids, match_all=True)
+            blocks, m = self._pool_map_prefix(replay_ids, match_all=True,
+                                              slot_idx=slot_idx)
             try:
                 self._tables[slot_idx, :] = self._pool_n_blocks
                 self._tables[slot_idx, :len(blocks)] = blocks
                 if m < n_total:
+                    # a model with a recurrent state resumes from the
+                    # nearest snapshot on the chain, or from token 0
                     self._pool_prefill_span(self._tables[slot_idx],
-                                            replay_ids, m)
+                                            replay_ids, m, slot_idx)
                 self._run_arm(slot_idx, n_total,
                               jnp.asarray([ids[-1]], jnp.int32),
                               req.temperature, req.max_tokens, req.seed, g)
@@ -4173,6 +4390,8 @@ class BatchedJaxEngine(JaxEngine):
             except Exception:
                 self._tables[slot_idx, :] = self._pool_n_blocks
                 self._pool.decref(blocks)
+                if self._state is not None:
+                    release_state(self._state, None, slot_idx, (), False)
                 raise
             slot.blocks = blocks
             # The chain basis (admitted prompt part) for the eventual
@@ -5553,7 +5772,7 @@ class BatchedJaxEngine(JaxEngine):
                 self._moe_experts_read += res.experts_read
                 self._moe_layer_passes += (
                     (self._spec_steps if is_spec else self.chunk_len)
-                    * self.model_cfg.n_layers)
+                    * self.model_cfg.n_of("E"))
             if res.sel_rows is not None:
                 # The device's own count of what its decode queries saw
                 # and kept, summed over the layers of every step.

@@ -39,8 +39,9 @@ from .containment import (CAUSE_SCHEDULER_DEATH, CAUSE_SCHEDULER_ERROR,
                           CAUSE_SLOT_HEALTH, PROBATION_CLEAN_CHUNKS,
                           REASON_HEALTH, REASON_ISOLATED, EngineSupervisor)
 from .fallback import extract_query, rule_command  # rules promoted there
-from .kv_pool import (BlockPool, HostBlockStore, PoolExhausted,
-                      alloc_with_evict, map_prefix, pages_for)
+from .kv_pool import (BlockPool, HostBlockStore, PoolExhausted, StateStore,
+                      alloc_with_evict, map_prefix, pages_for, release_state,
+                      state_cuts, take_snapshot)
 from .radix_cache import RadixCache
 from .regime import RAGGED, resolve_attention_regime
 from .protocol import (HEALTH_GRAMMAR_DEAD, HEALTH_NONFINITE,
@@ -219,6 +220,8 @@ class _FakeSlot:
     # (pool exhausted even after eviction -> finish at current length).
     blocks: List[int] = dataclasses.field(default_factory=list)
     pool_ids: List[int] = dataclasses.field(default_factory=list)
+    # the decode slot whose recurrent state this seating holds (-1: none)
+    state_slot: int = -1
     pool_starved: bool = False
     # Grammar mirror (ISSUE 11): ``gs`` = host-truth FSM state over the
     # CONSUMED stream, ``dev_gs`` = the device twin's speculative state
@@ -266,6 +269,7 @@ class FakeChunkedEngine:
                  radix_cache: bool = True,
                  radix_lru_blocks: int = 0,
                  host_kv_blocks: int = 0,
+                 state_snapshots: int = 0,
                  slo_session_ttft_ms: float = 0.0,
                  session_token_budget: int = 0,
                  force_ragged: bool = False,
@@ -392,6 +396,11 @@ class FakeChunkedEngine:
         self.kv_pool_page = max(1, kv_pool_page)
         self.radix_cache = bool(radix_cache)
         self.radix_lru_blocks = max(0, radix_lru_blocks)
+        # Recurrent-state mirror (ISSUE 33): > 0 plays a model that keeps
+        # a recurrent state — the batcher's StateStore, snapshot policy
+        # and radix rule verbatim, over a state of no bytes.
+        self.state_snapshots = max(0, state_snapshots)
+        self._state: Optional[StateStore] = None
         self.max_seq_len = max(chunk_len + 1, max_seq_len)
         self._pool_max_pages = pages_for(self.max_seq_len + chunk_len,
                                          self.kv_pool_page)
@@ -535,7 +544,14 @@ class FakeChunkedEngine:
         and replays re-allocate. Cumulative counters carry over (the
         /metrics delta-mirror must never see totals go backwards)."""
         prev_pool, prev_radix = self._pool, self._radix
-        prev_store = self._host_store
+        prev_store, prev_state = self._host_store, self._state
+        self._state = (StateStore(
+            self.state_snapshots, self.batch_size,
+            region=lambda name, **meta: self._spans.sched.region(
+                "admit", name, **meta))
+            if self.state_snapshots > 0 else None)
+        if prev_state is not None and self._state is not None:
+            self._state.carry_counters(prev_state)
         self._pool = BlockPool(self._pool_n_blocks, self.kv_pool_page)
         # Two-tier rebuild (ISSUE 20): a containment reset condemns the
         # host tier too — its payloads were captured from the poisoned
@@ -547,7 +563,8 @@ class FakeChunkedEngine:
         self._radix = (RadixCache(self._pool,
                                   max_blocks=self.radix_lru_blocks,
                                   host_store=self._host_store,
-                                  faults=self.faults)
+                                  faults=self.faults,
+                                  state_store=self._state)
                        if self.radix_cache else None)
         if prev_pool is not None:
             self._pool.carry_counters(prev_pool)
@@ -575,14 +592,16 @@ class FakeChunkedEngine:
                     % 1_000_000)
         return out
 
-    def _pool_map_prefix(self, ids: List[int], match_all: bool = False):
+    def _pool_map_prefix(self, ids: List[int], match_all: bool = False,
+                         slot_idx: int = 0):
         """kv_pool.map_prefix — the batcher's exact admission path; the
         COW callback is None because only the accounting is real here
         (the copy itself is device work)."""
         return map_prefix(self._pool, self._radix, ids,
-                          match_all=match_all, cow=None)
+                          match_all=match_all, cow=None,
+                          state=self._state, slot=slot_idx)
 
-    def _pool_seat(self, req: _FakeReq, g: int) -> tuple:
+    def _pool_seat(self, req: _FakeReq, g: int, slot_idx: int = 0) -> tuple:
         """Allocate one seating's chain: the replay basis is
         prompt + emitted[:-1] (the rows a real device has verifiably
         written). Returns (blocks, pool_ids); raises PoolExhausted with
@@ -592,7 +611,14 @@ class FakeChunkedEngine:
         basis = list(req.prompt_ids)
         gen = list(req.resume_ids or [])[:g]
         chain = basis + (gen[:-1] if gen else [])
-        blocks, m = self._pool_map_prefix(chain, match_all=bool(gen))
+        blocks, m = self._pool_map_prefix(chain, match_all=bool(gen),
+                                          slot_idx=slot_idx)
+        if self._state is not None and not gen:
+            # the batcher's prefill stops at these edges to save the state
+            for edge in state_cuts(self._state, slot_idx, len(chain),
+                                   self.kv_pool_page, m):
+                take_snapshot(self._state, self._radix, slot_idx, chain,
+                              edge)
         # Session SLO gate (ISSUE 20): a seating that radix-matched at
         # least one full page is a warm re-admission — the only kind the
         # turn-N TTFT SLO judges (onload-served pages count here too:
@@ -640,9 +666,19 @@ class FakeChunkedEngine:
             try:
                 self._radix.insert(chain, slot.blocks)
             except Exception:  # pragma: no cover - defensive
-                pass
+                cache_chain = False
+            if self._state is not None and slot.state_slot >= 0:
+                release_state(self._state, self._radix, slot.state_slot,
+                              chain, cache_chain)
+        elif self._state is not None and slot.state_slot >= 0:
+            release_state(self._state, None, slot.state_slot, (), False)
         self._pool.decref(slot.blocks)
         slot.blocks = []
+
+    def ssm_health(self) -> Optional[dict]:
+        """/health.ssm: the snapshot store's counters (mirror of the
+        batcher's; None unless the fake plays a state-keeping model)."""
+        return self._state.stats() if self._state is not None else None
 
     def kv_pool_health(self) -> Optional[dict]:
         """Cheap pool view for /health (mirror of the batcher's)."""
@@ -893,6 +929,7 @@ class FakeChunkedEngine:
                                 parked=len(self._parked),
                                 slot_health_check=self.slot_health_check),
             "kv_pool": self.kv_pool_health(),
+            "ssm": self.ssm_health(),
             "ledger": self.ledger.snapshot(),
             "slo": self._slo.snapshot(),
             "grammar": self.grammar_health(),
@@ -1224,7 +1261,7 @@ class FakeChunkedEngine:
                 # instead of re-prefilling (kv_pool.map_prefix).
                 g = len(req.resume_ids)
                 try:
-                    blocks, basis = self._pool_seat(req, g)
+                    blocks, basis = self._pool_seat(req, g, i)
                 except PoolExhausted:
                     req.out_queue.put_nowait(("error", EngineUnavailable(
                         "admission failed: kv pool exhausted")))
@@ -1241,7 +1278,7 @@ class FakeChunkedEngine:
                                 if self.device_termination else True),
                     last_tok=req.resume_ids[-1],
                     t_first=time.monotonic(),
-                    blocks=blocks, pool_ids=basis,
+                    blocks=blocks, pool_ids=basis, state_slot=i,
                     gs=gs_r, dev_gs=gs_r)
                 if req.export is not None and blocks:
                     req.export.blocks = list(blocks)
@@ -1330,7 +1367,7 @@ class FakeChunkedEngine:
                     gs0 = self._grammar.advance(gs0, first)
                     self._grammar_masked += 1
             try:
-                blocks, basis = self._pool_seat(req, 0)
+                blocks, basis = self._pool_seat(req, 0, i)
             except PoolExhausted:
                 req.out_queue.put_nowait(("error", EngineUnavailable(
                     "admission failed: kv pool exhausted")))
@@ -1343,7 +1380,7 @@ class FakeChunkedEngine:
                              t_first=(time.monotonic() if emitted0
                                       else None),
                              blocks=blocks, pool_ids=basis,
-                             gs=gs0, dev_gs=gs0)
+                             state_slot=i, gs=gs0, dev_gs=gs0)
             if req.export is not None and blocks:
                 req.export.blocks = list(blocks)
             if req.t_first0 is None:
@@ -1909,8 +1946,9 @@ class FakeChunkedEngine:
             chain = slot.pool_ids + (slot.emitted[:-1] if slot.emitted
                                      else [])
             try:
-                slot.blocks, _ = self._pool_map_prefix(chain,
-                                                       match_all=True)
+                slot.blocks, _ = self._pool_map_prefix(
+                    chain, match_all=True, slot_idx=i)
+                slot.state_slot = i
             except PoolExhausted:
                 req.out_queue.put_nowait(("error", EngineUnavailable(
                     "replay failed: kv pool exhausted")))

@@ -36,10 +36,21 @@ re-prefill, never a wrong transcript. The store is id-addressed (host
 block ids are an independent namespace from device block ids) and, like
 the pool, is host truth under the single-writer discipline; the ``check``
 methods together assert exact balance across both tiers.
+
+Recurrent state (ISSUE 33): ``StateStore`` is the host truth of a second
+KIND of cache, for a model with state-space layers
+(``ModelConfig.keeps_state``). Such a layer's state is one fixed block a
+sequence whatever its length (12.8 MB at the benchmark's cut, the K/V of
+6,250 tokens), so it cannot be kept at every block edge as K and V are:
+every decode slot owns one LIVE state, and a store of fixed capacity
+holds SNAPSHOTS, each hung on the radix node of the block edge it was
+taken at. A prefix match is usable only as deep as the last snapshot on
+its path (``RadixCache.match``); ``map_prefix`` seats the slot from it.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import itertools
 import zlib
@@ -77,7 +88,8 @@ def alloc_with_evict(pool: "BlockPool", radix, n: int):
 
 
 def map_prefix(pool: "BlockPool", radix, ids: Sequence[int], *,
-               match_all: bool = False, cow=None):
+               match_all: bool = False, cow=None, state=None,
+               slot: Optional[int] = None):
     """Build one slot's block chain for token sequence ``ids`` — THE
     shared admission path (run verbatim by the jax batcher and the fake
     engine, so refcount behaviour can never diverge between them):
@@ -90,6 +102,12 @@ def map_prefix(pool: "BlockPool", radix, ids: Sequence[int], *,
        None — its KV is fictional, only the accounting is real),
     3. fresh blocks cover the remaining pages.
 
+    With ``state`` (a ``StateStore``: the model keeps a recurrent state)
+    the match is usable only as deep as the last snapshot on its path —
+    the tree returns the blocks up to there and the snapshot, K/V blocks
+    past it are left to the tree and recomputed into fresh ones — and
+    decode slot ``slot`` is seated from that snapshot, or from zero.
+
     Returns ``(blocks, m)``: the table blocks in page order and the
     count of tokens whose KV is already valid (prefill starts at m).
     Admissions pass match_all=False — the LAST token must run forward
@@ -98,6 +116,7 @@ def map_prefix(pool: "BlockPool", radix, ids: Sequence[int], *,
     page = pool.page
     blocks: List[int] = []
     m = 0
+    mr = None
     if radix is not None:
         upto = len(ids) if match_all else max(0, len(ids) - 1)
         mr = radix.match(ids[:upto])
@@ -116,15 +135,63 @@ def map_prefix(pool: "BlockPool", radix, ids: Sequence[int], *,
             pool.note_cow()
             blocks += c
             m += mr.tail_rows
+    if state is not None:
+        state.seat(slot, mr)
     grow = pages_for(len(ids), page) - len(blocks)
     if grow > 0:
         fresh = alloc_with_evict(pool, radix, grow)
         if fresh is None:
             if blocks:
                 pool.decref(blocks)
+            if state is not None:
+                state.release(slot)
             raise PoolExhausted(f"kv pool exhausted ({grow} blocks short)")
         blocks += fresh
     return blocks, m
+
+
+def state_cuts(state: "StateStore", slot: int, n_prompt: int, page: int,
+               start: int) -> List[int]:
+    """The block edges in (``start``, ``n_prompt`` - 1] at which an
+    admission's prefill stops so that the state there exists to be saved
+    (THE shared snapshot policy, run by the jax batcher and the fake):
+
+    - the deepest edge the prompt's K/V matched in the tree, where that is
+      past the snapshot it was seated from AND the node there has more
+      than one child sequence already: other sequences branch there (two
+      agents over a shared preamble leave the node, the third recomputes
+      the preamble once more and leaves the snapshot every later one
+      restores). A turn that outruns its own session's snapshot matches
+      to a node with one child, the turn before, and leaves nothing there:
+      nobody else will come that way;
+    - the end of the prompt's last whole block, which the same prompt
+      grown by a turn will match."""
+    last = (n_prompt - 1) // page * page
+    edges = {state.branch_edge(slot), last}
+    return sorted(e for e in edges if start < e <= last)
+
+
+def take_snapshot(state: "StateStore", radix, slot: int,
+                  ids: Sequence[int], edge: int) -> None:
+    """Save slot ``slot``'s state, which stands at ``ids[:edge]``: on the
+    tree at once where it has that node (a prefix others share), else
+    pending on the slot until ``release_state`` finds the node."""
+    handle = state.take(slot, edge)
+    if handle is not None and radix is not None:
+        radix.attach_snapshot(ids, edge, handle)
+
+
+def release_state(state: "StateStore", radix, slot: int,
+                  chain: Sequence[int], cached: bool) -> None:
+    """A leaving slot's snapshots: those not yet on a node (the edge was
+    beyond the tree when they were taken) are hung on the chain the
+    release just inserted — or freed where the chain was not cached or
+    the node holds one already — and every pin of the slot drops."""
+    for edge, handle in state.pending(slot):
+        if not (cached and radix is not None
+                and radix.attach_snapshot(chain, edge, handle)):
+            state.free(handle)
+    state.release(slot)
 
 
 @dataclasses.dataclass
@@ -426,3 +493,241 @@ class HostBlockStore:
             raise AssertionError(
                 f"host store over capacity: {len(self._data)} > "
                 f"{self.capacity}")
+
+
+class StateStore:
+    """Recurrent-state snapshots: the host truth (ISSUE 33).
+
+    ``capacity`` handles name the rows of a device store the engine owns
+    (``snapshot_fn(slot, handle)`` copies a slot's live state into a row,
+    ``restore_fn(slot, handle)`` a row into a slot, ``zero_fn(slot)``
+    starts a slot from nothing; the fake engine passes none — its state
+    has no bytes, the bookkeeping is all of it). A handle is FREE, or
+    PENDING (taken by a live slot whose chain is not in the tree yet), or
+    ATTACHED to exactly one radix node. Every live slot PINS the
+    snapshots on its matched path and the ones it took: eviction is LRU
+    among attached snapshots nobody pins, independent of the blocks' LRU
+    — but a node that loses its block loses its snapshot (``drop``). The
+    host tier takes no states: a demoted page keeps its K/V and not this.
+
+    Single-writer like the pool (the scheduler thread, or the fake's
+    loop); ``stats()`` is the /health.ssm section, cheap counters only.
+    """
+
+    def __init__(self, capacity: int, n_slots: int, state_bytes: int = 0, *,
+                 snapshot_fn=None, restore_fn=None, zero_fn=None,
+                 region=None):
+        if capacity < 1:
+            raise ValueError("the state store needs at least 1 snapshot")
+        self.capacity = int(capacity)
+        self.state_bytes = int(state_bytes)
+        self._snapshot_fn, self._restore_fn = snapshot_fn, restore_fn
+        self._zero_fn = zero_fn
+        self._region = region
+        self._free: deque = deque(range(self.capacity))
+        self._node: Dict[int, object] = {}      # attached handle -> node
+        self._last: Dict[int, int] = {}         # held handle -> LRU stamp
+        self._pins: Dict[int, int] = {}         # held handle -> live pinners
+        self._slot_pins: List[List[int]] = [[] for _ in range(n_slots)]
+        self._slot_pending: List[List[tuple]] = [[] for _ in range(n_slots)]
+        self._branch_edge = [0] * n_slots
+        self._clock = itertools.count(1)
+        self.held_peak = 0
+        self.restore_depth_peak = 0     # deepest LRU rank a restore found
+        self.snapshots_taken = 0
+        self.snapshots_evicted = 0
+        self.snapshots_skipped = 0      # store full of pinned snapshots
+        self.restores = 0
+        self.prefix_tokens_matched = 0
+        self.prefix_tokens_usable = 0
+        self.prefix_tokens_recomputed = 0
+        self.state_bytes_moved = 0
+
+    COUNTERS = ("held_peak", "restore_depth_peak", "snapshots_taken",
+                "snapshots_evicted",
+                "snapshots_skipped", "restores", "prefix_tokens_matched",
+                "prefix_tokens_usable", "prefix_tokens_recomputed",
+                "state_bytes_moved")
+
+    def carry_counters(self, prev: "StateStore") -> None:
+        for name in self.COUNTERS:
+            setattr(self, name, getattr(prev, name))
+
+    # ------------------------------------------------------------ slots
+
+    def _timed(self, name: str, **meta):
+        return (self._region(name, **meta) if self._region is not None
+                else contextlib.nullcontext())
+
+    def _pin(self, slot: int, handle: int) -> None:
+        self._pins[handle] = self._pins.get(handle, 0) + 1
+        self._slot_pins[slot].append(handle)
+
+    def seat(self, slot: int, mr) -> None:
+        """Seat decode slot ``slot`` for a sequence whose match is ``mr``
+        (``RadixCache.match``'s result, None with no tree): restore the
+        snapshot the match ended at, or start from zero; pin every
+        snapshot on the path until ``release``."""
+        self.release(slot)
+        handle = mr.snapshot if mr is not None else None
+        self._branch_edge[slot] = (mr.kv_matched_edge
+                                   if mr is not None and mr.kv_branching else 0)
+        if handle is None:
+            if mr is not None:
+                self.prefix_tokens_matched += mr.kv_matched
+                self.prefix_tokens_recomputed += mr.kv_matched
+            if self._zero_fn is not None:
+                self._zero_fn(slot)
+            return
+        usable = mr.n_tokens
+        self.prefix_tokens_matched += mr.kv_matched
+        self.prefix_tokens_usable += usable
+        self.prefix_tokens_recomputed += mr.kv_matched - usable
+        for h in mr.path_snapshots:
+            self._pin(slot, h)
+        # An LRU store is full in any long run, so ``held_peak`` reads its
+        # capacity; what sizes it is how far down the LRU order a restore
+        # reaches (1 = the newest): a store of that many and the live
+        # slots' pins would have served every restore so far.
+        stamp = self._last[handle]
+        self.restore_depth_peak = max(
+            self.restore_depth_peak,
+            sum(1 for t in self._last.values() if t >= stamp))
+        self._last[handle] = next(self._clock)
+        with self._timed("state_restore", slot=slot, tokens=usable):
+            if self._restore_fn is not None:
+                self._restore_fn(slot, handle)
+        self.restores += 1
+        self.state_bytes_moved += self.state_bytes
+
+    def branch_edge(self, slot: int) -> int:
+        """How deep (tokens, a block edge) the slot's sequence matched
+        K/V in the tree when it was seated — past its snapshot or not —
+        where the node there is one that sequences branch from; else 0."""
+        return self._branch_edge[slot]
+
+    def take(self, slot: int, edge: int) -> Optional[int]:
+        """Snapshot slot ``slot``'s live state, which stands at token
+        ``edge`` of its sequence. The handle is pending (and pinned) on
+        the slot until a node takes it. None when every snapshot is
+        pinned: the state is simply not saved."""
+        handle = self._alloc()
+        if handle is None:
+            self.snapshots_skipped += 1
+            return None
+        self._last[handle] = next(self._clock)
+        self._pin(slot, handle)
+        self._slot_pending[slot].append((edge, handle))
+        with self._timed("state_snapshot", slot=slot, tokens=edge):
+            if self._snapshot_fn is not None:
+                self._snapshot_fn(slot, handle)
+        self.snapshots_taken += 1
+        self.state_bytes_moved += self.state_bytes
+        self.held_peak = max(self.held_peak, self.held)
+        return handle
+
+    def pending(self, slot: int) -> List[tuple]:
+        """(edge, handle) of the slot's snapshots no node holds yet."""
+        return list(self._slot_pending[slot])
+
+    def release(self, slot: int) -> None:
+        """Drop every pin of ``slot`` (it leaves, or is re-seated)."""
+        for h in self._slot_pins[slot]:
+            n = self._pins.get(h, 0) - 1
+            if n > 0:
+                self._pins[h] = n
+            else:
+                self._pins.pop(h, None)
+        self._slot_pins[slot] = []
+        self._slot_pending[slot] = []
+
+    # ---------------------------------------------------------- handles
+
+    @property
+    def held(self) -> int:
+        return self.capacity - len(self._free)
+
+    @property
+    def on_nodes(self) -> int:
+        """Snapshots a radix node holds (the rest of ``held`` is pending)."""
+        return len(self._node)
+
+    def _alloc(self) -> Optional[int]:
+        if not self._free:
+            victims = [h for h in self._node if not self._pins.get(h)]
+            if not victims:
+                return None
+            victim = min(victims, key=self._last.__getitem__)
+            self._node[victim].snap = None
+            self.snapshots_evicted += 1
+            self._forget(victim)
+        return self._free.popleft()
+
+    def _forget(self, handle: int) -> None:
+        """Back to the free list, with every trace of its holders gone: a
+        handle is reissued, and a stale pin would then be another's."""
+        self._node.pop(handle, None)
+        self._last.pop(handle, None)
+        if self._pins.pop(handle, None):
+            for pins in self._slot_pins:
+                pins[:] = [h for h in pins if h != handle]
+        for pend in self._slot_pending:
+            pend[:] = [(e, h) for e, h in pend if h != handle]
+        self._free.append(handle)
+
+    def attached(self, handle: int, node) -> None:
+        """A radix node took a pending handle (``attach_snapshot``)."""
+        self._node[handle] = node
+        for pend in self._slot_pending:
+            pend[:] = [(e, h) for e, h in pend if h != handle]
+
+    def free(self, handle: int) -> None:
+        """A pending handle nobody will hang anywhere."""
+        if handle in self._last and handle not in self._node:
+            self._forget(handle)
+
+    def drop(self, handle: int) -> None:
+        """The node holding ``handle`` lost its block (evicted, demoted,
+        cleared): the snapshot goes with it, pinned or not — a slot that
+        descends from it resumes from a shallower one, or from zero."""
+        if handle in self._node:
+            self.snapshots_evicted += 1
+            self._forget(handle)
+
+    # ------------------------------------------------------- accounting
+
+    def stats(self) -> dict:
+        body = {"snapshots_held": self.held, "capacity": self.capacity,
+                "bytes": self.held * self.state_bytes,
+                "state_bytes": self.state_bytes,
+                "snapshots_pinned": len(self._pins)}
+        body.update({name: getattr(self, name) for name in self.COUNTERS})
+        return body
+
+    def check(self) -> None:
+        """Exact balance: every handle is free once, or held by exactly
+        one node, or pending on exactly one slot; pins name held handles
+        only and match the slots' lists."""
+        free = list(self._free)
+        if len(free) != len(set(free)):
+            raise AssertionError("a snapshot handle is free twice")
+        pending = [h for pend in self._slot_pending for _, h in pend]
+        owned = list(self._node) + pending
+        if len(owned) != len(set(owned)):
+            raise AssertionError("a snapshot handle has two holders")
+        if set(owned) & set(free) or len(owned) + len(free) != self.capacity:
+            raise AssertionError(
+                f"snapshot handles out of balance: {len(free)} free, "
+                f"{len(self._node)} attached, {len(pending)} pending of "
+                f"{self.capacity}")
+        for h, node in self._node.items():
+            if getattr(node, "snap", None) != h:
+                raise AssertionError(f"snapshot {h}: its node does not hold it")
+        want: Dict[int, int] = {}
+        for pins in self._slot_pins:
+            for h in pins:
+                want[h] = want.get(h, 0) + 1
+        want = {h: n for h, n in want.items() if h in self._last}
+        have = {h: n for h, n in self._pins.items() if h in self._last}
+        if want != have:
+            raise AssertionError(f"snapshot pins {have} != slots' {want}")

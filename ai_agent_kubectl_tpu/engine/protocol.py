@@ -302,7 +302,11 @@ class RequestExport:
     generated-prefix ids, seed) is everything needed to re-splice the
     request onto a DIFFERENT engine replica and continue the transcript
     bit-identically — nothing recoverable is welded to one engine's
-    slots."""
+    slots. That holds for a model with a recurrent state too (ISSUE 33):
+    the state is NOT exported; a resume (preemption, replay, quarantine
+    re-splice, migration) is seated from the nearest snapshot its chain
+    finds on the target's radix tree — in practice its prompt's last
+    block edge — or from token 0, and re-runs what follows."""
 
     ids: List[int] = field(default_factory=list)
     #: block-paged KV pool (ISSUE 10): the pool block ids this request's

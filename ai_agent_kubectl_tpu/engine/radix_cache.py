@@ -49,6 +49,16 @@ single-tier behaviour. ``offload:fail`` / ``onload:corrupt`` drill
 points (testing/faults.py) are consumed through the duck-typed
 ``faults`` hook so both engines inherit them.
 
+Recurrent state (ISSUE 33): with a ``StateStore`` attached (a model with
+state-space layers) a node may also hold a SNAPSHOT handle — the model's
+recurrent state after exactly the node's prefix. K and V exist at every
+block edge, a state only where one was saved, so ``match`` then returns
+the blocks up to the deepest node on the path that holds a snapshot (and
+that snapshot): the K/V blocks matched beyond it stay in the tree and are
+recomputed by the caller, counted as misses. ``attach_snapshot`` hangs a
+handle on a node; a node that gives up its block (evicted, demoted,
+cleared) gives up its snapshot. The host tier takes no states.
+
 Host-side, numpy/stdlib only; single-writer (scheduler thread / event
 loop) like the pool itself.
 """
@@ -80,11 +90,23 @@ class MatchResult:
     blocks: List[int] = dataclasses.field(default_factory=list)
     tail_block: Optional[int] = None
     tail_rows: int = 0
+    # With a state store: ``n_tokens``/``blocks`` end at the deepest
+    # snapshot on the path (``snapshot``; None = start from zero, nothing
+    # usable), ``path_snapshots`` are all the handles down to it,
+    # ``kv_matched`` the tokens whose K/V the tree held (tail rows too),
+    # ``kv_matched_edge`` the full pages of those, in tokens, and
+    # ``kv_branching`` whether the node there has more than one child
+    # sequence already (others branch from it; this one will too).
+    snapshot: Optional[int] = None
+    path_snapshots: List[int] = dataclasses.field(default_factory=list)
+    kv_matched: int = 0
+    kv_matched_edge: int = 0
+    kv_branching: bool = False
 
 
 class _Node:
     __slots__ = ("children", "block", "host", "tail", "parent", "key",
-                 "last")
+                 "last", "snap")
 
     def __init__(self, parent: Optional["_Node"], key: Optional[tuple],
                  block: Optional[int]):
@@ -101,13 +123,19 @@ class _Node:
         # least shareable KV — it drops first instead).
         self.tail: Optional[Tuple[tuple, int, int]] = None
         self.last = 0                # LRU stamp (monotonic, BOTH tiers)
+        self.snap: Optional[int] = None   # StateStore handle (ISSUE 33)
 
 
 class RadixCache:
     def __init__(self, pool: BlockPool, *, max_blocks: int = 0,
                  host_store: Optional[HostBlockStore] = None,
-                 offload_fn=None, onload_fn=None, faults=None):
+                 offload_fn=None, onload_fn=None, faults=None,
+                 state_store=None):
         self.pool = pool
+        # Recurrent-state snapshots (ISSUE 33; kv_pool.StateStore): set
+        # for a model that keeps a state, and then a match is usable only
+        # as deep as the last snapshot on its path.
+        self.state_store = state_store
         self.page = pool.page
         # Host tier (ISSUE 20): demote target for cold pages. offload_fn
         # (block -> np.ndarray) reads the page's device KV at demote;
@@ -209,6 +237,7 @@ class RadixCache:
         page = self.page
         node, n = self._root, 0
         blocks: List[int] = []
+        path: List[_Node] = []
         stamp = next(self._clock)
         node.last = stamp
         self._protect_stamp = stamp
@@ -221,11 +250,14 @@ class RadixCache:
                 if child.block is None and not self._promote(child):
                     break
                 blocks.append(child.block)
+                path.append(child)
                 node = child
                 n += page
         finally:
             self._protect_stamp = 0
         tail_block, tail_rows = None, 0
+        if self.state_store is not None:
+            return self._match_to_snapshot(ids, node, n, blocks, path)
         if node.tail is not None:
             t_tokens, t_block, t_rows = node.tail
             limit = min(t_rows, len(ids) - n)
@@ -244,6 +276,60 @@ class RadixCache:
             self.pool.incref([tail_block])
         return MatchResult(n_tokens=matched, blocks=blocks,
                            tail_block=tail_block, tail_rows=tail_rows)
+
+    def _match_to_snapshot(self, ids, node: _Node, n: int,
+                           blocks: List[int], path: List[_Node]
+                           ) -> MatchResult:
+        """The match of a model that keeps a recurrent state: K/V matched
+        ``n`` tokens down ``path`` (and maybe some tail rows), but a
+        prefill can only start where the state is known — the deepest
+        node of the path with a snapshot. Only the tokens up to there are
+        hits; no partial tail is ever usable (snapshots sit on edges)."""
+        tail_rows = 0
+        if node.tail is not None:
+            t_tokens, _, t_rows = node.tail
+            limit = min(t_rows, len(ids) - n)
+            while tail_rows < limit and t_tokens[tail_rows] == ids[n + tail_rows]:
+                tail_rows += 1
+        deepest = max((i for i, nd in enumerate(path) if nd.snap is not None),
+                      default=-1)
+        usable = (deepest + 1) * self.page
+        blocks = blocks[:deepest + 1]
+        self.hit_tokens_total += usable
+        self.miss_tokens_total += len(ids) - usable
+        if blocks:
+            self.pool.incref(blocks)
+            self.pool.note_shared(len(blocks))
+        return MatchResult(
+            n_tokens=usable, blocks=blocks,
+            snapshot=path[deepest].snap if deepest >= 0 else None,
+            path_snapshots=[nd.snap for nd in path[:deepest + 1]
+                            if nd.snap is not None],
+            kv_matched=n + tail_rows, kv_matched_edge=n,
+            kv_branching=len(node.children) > 1)
+
+    def attach_snapshot(self, ids: Sequence[int], edge: int,
+                        handle: int) -> bool:
+        """Hang state snapshot ``handle``, taken after ``ids[:edge]``
+        (``edge`` a page multiple), on the node of that prefix. False
+        (the caller frees the handle) where the tree holds no such node
+        on the device tier, or the node has a snapshot already."""
+        node = self._root
+        for i in range(edge // self.page):
+            node = node.children.get(
+                tuple(ids[i * self.page:(i + 1) * self.page]))
+            if node is None or node.block is None:
+                return False
+        if node is self._root or node.snap is not None:
+            return False
+        node.snap = handle
+        self.state_store.attached(handle, node)
+        return True
+
+    def _lose_snapshot(self, node: _Node) -> None:
+        if node.snap is not None:
+            self.state_store.drop(node.snap)
+            node.snap = None
 
     # ------------------------------------------------------------ insert
 
@@ -359,6 +445,7 @@ class RadixCache:
     def _drop_node(self, node: _Node) -> None:
         del node.parent.children[node.key]
         self._nodes -= 1
+        self._lose_snapshot(node)
         self._release(node.block)
         if node.children:
             # All host-resident (the _demotable precondition): dropping
@@ -502,6 +589,7 @@ class RadixCache:
             self._drop_host_leaf(victim)
         data = self._page_payload(node)
         hbid = store.put(data)
+        self._lose_snapshot(node)       # the host tier takes no states
         node.host = hbid
         self._host_nodes[hbid] = node
         b = node.block
@@ -620,6 +708,8 @@ class RadixCache:
             "insertions": self.insertions_total,
             "evicted_blocks": self.evicted_blocks_total,
         }
+        if self.state_store is not None:
+            body["snapshots"] = self.state_store.on_nodes
         if self.host_store is not None:
             body["host_resident_nodes"] = len(self._host_nodes)
         return body

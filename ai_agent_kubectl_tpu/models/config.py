@@ -13,6 +13,12 @@ test models. Family differences are expressed as data, not subclasses:
   own width through the grouped expert path (parallel/moe.py), and a
   learned top-k key selector (``index_topk``) inside paged attention
   whose keys live in a pool leaf of their own
+- NVIDIA-Nemotron-3-Nano-30B-A3B's language model (registered by the
+  benchmark too; ``toy-hybrid-moe`` is its toy): ``layer_pattern`` gives
+  every layer ONE mixer — a Mamba-2 layer (ops/ssd_scan.py, its state a
+  cache leaf of its own), two-matrix relu^2 experts under a sigmoid router
+  with a selection bias beside a shared expert, or GQA without a rotary
+  embedding — and the weights are stacked per kind
 """
 
 from __future__ import annotations
@@ -57,6 +63,34 @@ class ModelConfig:
     index_topk: int = 0
     index_heads: int = 0
     index_head_dim: int = 0
+    # One mixer a layer (the nemotron_h family): character l names layer
+    # l's kind, ``M`` a Mamba-2 layer, ``E`` an expert layer, ``*``
+    # attention, each ``x + mixer(norm(x))`` with no MLP behind it. A
+    # string so the dataclass stays hashable; longer than ``n_layers`` it
+    # means its first ``n_layers`` characters (a configuration cut in depth
+    # keeps the published string). "" = every layer is attention then MLP.
+    layer_pattern: str = ""
+    # Mamba-2 sizes: d_inner = ssm_heads x ssm_head_dim, B and C of
+    # ``ssm_state`` shared by the heads of each of ``ssm_groups`` groups, a
+    # causal depthwise convolution of ``ssm_conv`` taps over [x | B | C],
+    # the scan's chunk ``ssm_chunk`` (tiling only: changes no result).
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 0
+    ssm_conv: int = 0
+    ssm_chunk: int = 0
+    # An expert every token takes beside the routed ones (0 = none), the
+    # router's kind (``softmax``: the k largest logits, softmax over them;
+    # ``sigmoid_bias``: sigmoid scores, the k largest of score + a learned
+    # selection bias, the picked scores renormalised) and the factor the
+    # routed sum is scaled by.
+    shared_mlp_hidden: int = 0
+    router: str = "softmax"
+    router_scale: float = 1.0
+    # False: attention takes no rotary embedding (its positions come from
+    # the state-space layers); ``rope_theta`` is then carried, unused.
+    use_rope: bool = True
     # Special tokens (tokenizer-dependent; defaults overridden per family)
     bos_id: int = 1
     eos_ids: Tuple[int, ...] = (2,)
@@ -70,6 +104,52 @@ class ModelConfig:
     @property
     def selects_keys(self) -> bool:
         return self.index_topk > 0
+
+    @property
+    def gated_mlp(self) -> bool:
+        """Three matrices (act(x Wg) * (x Wu), then Wd) or, for ``relu2``,
+        two (relu(x Wu)^2, then Wd: no gate leaf anywhere in the tree)."""
+        return self.activation != "relu2"
+
+    @property
+    def layer_kinds(self) -> Tuple[str, ...]:
+        """Layer l's kind for a patterned configuration, () otherwise."""
+        if not self.layer_pattern:
+            return ()
+        kinds = tuple(self.layer_pattern[:self.n_layers])
+        if len(kinds) < self.n_layers or set(kinds) - set("ME*"):
+            raise ValueError(
+                f"{self.name}: layer_pattern {self.layer_pattern!r} does "
+                f"not name {self.n_layers} layers of kinds M, E, *")
+        return kinds
+
+    def n_of(self, kind: str) -> int:
+        """Layers of ``kind``; of a uniform block, every layer is each."""
+        kinds = self.layer_kinds
+        return kinds.count(kind) if kinds else self.n_layers
+
+    @property
+    def keeps_state(self) -> bool:
+        """A recurrent state beside the KV cache (engine/kv_pool.py::
+        StateStore): some layer is a state-space layer."""
+        return "M" in self.layer_kinds
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels the convolution runs over: [x | B | C]."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+
+    def state_bytes(self) -> int:
+        """One sequence's recurrent state: float32 [heads, head_dim,
+        state] and a bf16 convolution tail a state-space layer."""
+        return self.n_of("M") * (
+            4 * self.ssm_heads * self.ssm_head_dim * self.ssm_state
+            + 2 * (self.ssm_conv - 1) * self.ssm_conv_dim
+        ) if self.keeps_state else 0
 
     @property
     def index_key_width(self) -> int:
@@ -106,6 +186,8 @@ class ModelConfig:
     def param_count(self) -> int:
         """Approximate parameter count (embeddings + layers)."""
         embed = self.vocab_size * self.dim
+        if self.layer_kinds:
+            return self._patterned_param_count()
         attn = self.n_layers * (
             self.dim * self.n_heads * self.head_dim          # wq
             + 2 * self.dim * self.n_kv_heads * self.head_dim  # wk, wv
@@ -121,6 +203,21 @@ class ModelConfig:
         norms = self.n_layers * 2 * self.dim + self.dim
         head = 0 if self.tie_embeddings else self.vocab_size * self.dim
         return embed + attn + mlp + router + norms + head
+
+    def _patterned_param_count(self) -> int:
+        d, mats = self.dim, 3 if self.gated_mlp else 2
+        attn = 2 * d * self.head_dim * (self.n_heads + self.n_kv_heads)
+        moe = (self.n_experts * mats * d * self.mlp_hidden
+               + mats * d * self.shared_mlp_hidden
+               + d * self.n_experts + self.n_experts)
+        ssm = (d * (2 * self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+                    + self.ssm_heads)
+               + self.ssm_inner * d + (self.ssm_conv + 1) * self.ssm_conv_dim
+               + 3 * self.ssm_heads + self.ssm_inner)
+        per = {"*": attn, "E": moe, "M": ssm}
+        layers = sum(per[k] + d for k in self.layer_kinds)
+        head = 0 if self.tie_embeddings else self.vocab_size * d
+        return self.vocab_size * d + layers + d + head
 
 
 _CONFIGS: Dict[str, ModelConfig] = {}
@@ -149,6 +246,21 @@ TOY_SPARSE_MOE = _register(ModelConfig(
     n_kv_heads=2, head_dim=64, mlp_hidden=64, n_experts=16,
     experts_per_token=2, qk_norm=True, index_topk=48, index_heads=4,
     index_head_dim=32, max_seq_len=2048,
+))
+
+# One mixer a layer, all three kinds, two periods: a Mamba-2 layer, two-
+# matrix relu^2 experts (width 72: not a multiple of 128) under a sigmoid
+# router with a selection bias beside a shared expert, attention without
+# a rotary embedding: the toy of the benchmark's
+# nemotron-3-nano-30b-a3b-l13 configuration.
+TOY_HYBRID_MOE = _register(ModelConfig(
+    name="toy-hybrid-moe", vocab_size=512, dim=128, n_layers=6, n_heads=4,
+    n_kv_heads=2, head_dim=32, mlp_hidden=72, rms_eps=1e-5,
+    activation="relu2", n_experts=16, experts_per_token=2,
+    layer_pattern="ME*ME*", ssm_heads=8, ssm_head_dim=16, ssm_state=32,
+    ssm_groups=2, ssm_conv=4, ssm_chunk=16, shared_mlp_hidden=144,
+    router="sigmoid_bias", router_scale=2.5, use_rope=False,
+    max_seq_len=2048,
 ))
 
 # --- Gemma (HF: google/gemma-{2b,7b}-it) ---

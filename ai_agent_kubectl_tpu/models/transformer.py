@@ -69,6 +69,20 @@ class KVCache:
              is made), summed over layers and passes since the chunk
              program last zeroed it (/health.sparse_attention). Absent
              elsewhere.
+    ssm, conv: a configuration with state-space layers
+             (``ModelConfig.keeps_state``) only: the recurrent state,
+             float32 [n_ssm_layers, batch, heads, head_dim, state], and
+             the convolution's tail, [n_ssm_layers, batch, conv - 1,
+             conv channels] — one plane a state-space layer, one row a
+             batch row of the call, whatever the sequences' lengths. A
+             row's state is the state at the last token the calls so far
+             ran for it; a caller that continues another sequence in the
+             row puts that sequence's state there first (engine/
+             kv_pool.py::StateStore). ``k``/``v`` then hold a row only for
+             each ATTENTION layer, addressed by its ordinal among them
+             (spare rows are tolerated). ``forward``, given such a
+             configuration and no leaf, starts every row from zero and
+             returns the leaves.
     """
 
     k: Any
@@ -77,11 +91,13 @@ class KVCache:
     ik: Any = None
     experts_read: Any = None
     sel_rows: Any = None
+    ssm: Any = None
+    conv: Any = None
 
     @classmethod
     def zeros(cls, cfg: ModelConfig, batch: int, max_seq: int,
               dtype=jnp.bfloat16, kv_quant: str = "") -> "KVCache":
-        shape = (cfg.n_layers, batch, max_seq, cfg.n_kv_heads, cfg.head_dim)
+        shape = (cfg.n_of("*"), batch, max_seq, cfg.n_kv_heads, cfg.head_dim)
         if kv_quant == "int8":
             def zq():
                 return QuantKV(q=jnp.zeros(shape, jnp.int8),
@@ -101,10 +117,129 @@ class KVCache:
         return leaf.shape[2]
 
 
+def state_zeros(cfg: ModelConfig, rows: int, dtype=jnp.bfloat16):
+    """(ssm, conv) leaves of ``rows`` sequences, every one at its start."""
+    from ..ops.ssd_scan import STATE_DTYPE
+
+    n = cfg.n_of("M")
+    return (jnp.zeros((n, rows, cfg.ssm_heads, cfg.ssm_head_dim,
+                       cfg.ssm_state), STATE_DTYPE),
+            jnp.zeros((n, rows, cfg.ssm_conv - 1, cfg.ssm_conv_dim), dtype))
+
+
+def state_take_row(cache: KVCache, row) -> KVCache:
+    """The cache with its state leaves cut to the one row ``row`` (a
+    traced scalar): what a one-sequence call for that slot is handed."""
+    one = lambda a: jax.lax.dynamic_slice_in_dim(a, row, 1, axis=1)
+    return dataclasses.replace(cache, ssm=one(cache.ssm),
+                               conv=one(cache.conv))
+
+
+def state_put_row(cache: KVCache, ssm, conv, row) -> KVCache:
+    """``cache`` with one-row state leaves written back at ``row``."""
+    put = lambda a, u: jax.lax.dynamic_update_slice_in_dim(a, u, row, axis=1)
+    return dataclasses.replace(cache, ssm=put(cache.ssm, ssm),
+                               conv=put(cache.conv, conv))
+
+
 # ----------------------------------------------------------------- init
+
+def small_leaf_init(name: str, shape, dtype, key):
+    """The leaves of a patterned configuration that are neither a
+    projection nor a norm gain, by name (None for any other name); shared
+    by ``init_params`` and the seeded int8 generator (ops/quant.py), so a
+    layer's memory is a trained model's in both: ``A = -exp(A_log)`` from
+    1 to 16 over the heads and a step bias whose softplus runs from 1e-3
+    to 1e-1 (Mamba-2's initialisation) make a head forget over a few
+    tokens to a few thousand — with a zero bias every head forgets in
+    two, and neither a carried state nor its precision could be told
+    from a logit."""
+    H = shape[-1]
+    if name == "ssm_A_log":
+        # spread over the heads in another order than the steps are (h ->
+        # 37 h mod H, a permutation for the even H of every preset), as
+        # the two are drawn independently in the published initialisation:
+        # every pairing of a short or long step with a slow or fast decay
+        v = jnp.log(jnp.linspace(1.0, 16.0, H))[(37 * jnp.arange(H)) % H]
+    elif name == "ssm_dt_bias":
+        dt = jnp.exp(jnp.linspace(jnp.log(1e-3), jnp.log(1e-1), H))
+        v = dt + jnp.log(-jnp.expm1(-dt))           # softplus^-1(dt)
+    elif name == "ssm_D":
+        v = jnp.ones((H,))
+    elif name == "ssm_conv_w":
+        return (jax.random.normal(key, shape, jnp.float32)
+                * shape[-2] ** -0.5).astype(dtype)
+    elif name in ("ssm_conv_b", "router_bias"):
+        return (jax.random.normal(key, shape, jnp.float32)
+                * 0.02).astype(jnp.float32 if name == "router_bias"
+                               else dtype)
+    else:
+        return None
+    return jnp.broadcast_to(v, shape).astype(jnp.float32)
+
+
+def _init_patterned(key: jax.Array, cfg: ModelConfig, dtype) -> Params:
+    """A patterned configuration's tree: one stacked leaf a weight A KIND,
+    its leading axis the layers of that kind in order."""
+
+    def dense(k, shape):
+        return (jax.random.normal(k, shape, jnp.float32)
+                * shape[-2] ** -0.5).astype(dtype)
+
+    keys = iter(jax.random.split(key, 32))
+    d, hd, H, KV = cfg.dim, cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
+    nA, nE, nM = cfg.n_of("*"), cfg.n_of("E"), cfg.n_of("M")
+    layers: Params = {}
+    if nA:
+        layers.update(
+            attn_norm=jnp.ones((nA, d), dtype),
+            wq=dense(next(keys), (nA, d, H * hd)),
+            wk=dense(next(keys), (nA, d, KV * hd)),
+            wv=dense(next(keys), (nA, d, KV * hd)),
+            wo=dense(next(keys), (nA, H * hd, d)))
+    if nE:
+        E, F, Fs = cfg.n_experts, cfg.mlp_hidden, cfg.shared_mlp_hidden
+        layers.update(
+            mlp_norm=jnp.ones((nE, d), dtype),
+            router=dense(next(keys), (nE, d, E)),
+            w_up=dense(next(keys), (nE, E, d, F)),
+            w_down=dense(next(keys), (nE, E, F, d)))
+        if cfg.router == "sigmoid_bias":
+            layers["router_bias"] = small_leaf_init(
+                "router_bias", (nE, E), dtype, next(keys))
+        if cfg.gated_mlp:
+            layers["w_gate"] = dense(next(keys), (nE, E, d, F))
+        if Fs:
+            layers["shared_up"] = dense(next(keys), (nE, d, Fs))
+            layers["shared_down"] = dense(next(keys), (nE, Fs, d))
+            if cfg.gated_mlp:
+                layers["shared_gate"] = dense(next(keys), (nE, d, Fs))
+    if nM:
+        Hs, di, C = cfg.ssm_heads, cfg.ssm_inner, cfg.ssm_conv_dim
+        layers.update(
+            ssm_in_norm=jnp.ones((nM, d), dtype),
+            ssm_in=dense(next(keys), (nM, d, di + C + Hs)),
+            ssm_gate_norm=jnp.ones((nM, di), dtype),
+            ssm_out=dense(next(keys), (nM, di, d)))
+        for name, shape in (("ssm_conv_w", (nM, cfg.ssm_conv, C)),
+                            ("ssm_conv_b", (nM, C)), ("ssm_dt_bias", (nM, Hs)),
+                            ("ssm_A_log", (nM, Hs)), ("ssm_D", (nM, Hs))):
+            layers[name] = small_leaf_init(name, shape, dtype, next(keys))
+    params: Params = {
+        "embed": (jax.random.normal(next(keys), (cfg.vocab_size, d),
+                                    jnp.float32)).astype(dtype),
+        "layers": layers,
+        "final_norm": jnp.ones((d,), dtype),
+    }
+    if not cfg.tie_embeddings:
+        params["lm_head"] = dense(next(keys), (d, cfg.vocab_size))
+    return params
+
 
 def init_params(key: jax.Array, cfg: ModelConfig, dtype=jnp.bfloat16) -> Params:
     """Random init (scaled normal) with the layer axis stacked for scan."""
+    if cfg.layer_kinds:
+        return _init_patterned(key, cfg, dtype)
 
     def _dense_init(k, shape, scale):
         return (jax.random.normal(k, shape, dtype=jnp.float32) * scale).astype(dtype)
@@ -278,12 +413,21 @@ def _shard_logits(mesh, logits: jnp.ndarray) -> jnp.ndarray:
 def _activation(cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
     if cfg.activation == "gelu":
         return jax.nn.gelu(x, approximate=True)
+    if cfg.activation == "relu2":
+        return jnp.square(jax.nn.relu(x))
     return jax.nn.silu(x)
 
 
-def _dense_mlp(cfg: ModelConfig, lp: Params, x: jnp.ndarray) -> jnp.ndarray:
-    gate = _activation(cfg, qmatmul(x, lp["w_gate"]))
-    return qmatmul(gate * qmatmul(x, lp["w_up"]), lp["w_down"])
+def _dense_mlp(cfg: ModelConfig, lp: Params, x: jnp.ndarray,
+               prefix: str = "w_") -> jnp.ndarray:
+    """The dense MLP of leaves ``<prefix>gate/up/down``: gated, or for
+    ``relu2`` two matrices with the activation between them."""
+    up = qmatmul(x, lp[prefix + "up"])
+    if cfg.gated_mlp:
+        up = _activation(cfg, qmatmul(x, lp[prefix + "gate"])) * up
+    else:
+        up = _activation(cfg, up)
+    return qmatmul(up, lp[prefix + "down"])
 
 
 #: The expert leaves of a layer (stacked [L, E, in, out] in the param tree).
@@ -512,8 +656,9 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
             ki = qmatmul(x, lp["idx_wk"]).reshape(B, S, 1, di)
             wi = x @ lp["idx_ww"]
     with jax.named_scope("rope"):
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        if cfg.use_rope:
+            q = apply_rope(q, positions, cfg.rope_theta)
+            k = apply_rope(k, positions, cfg.rope_theta)
         if cfg.selects_keys:
             qi = apply_rope(qi, positions, cfg.rope_theta)
             ki = apply_rope(ki, positions, cfg.rope_theta)[:, :, 0]
@@ -532,6 +677,16 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
         if n_read is not None:
             counts["experts_read"] = n_read
         return y
+
+    def after_attention(h):
+        """The block's second half: the MLP and its residual — nothing for
+        a patterned configuration, whose attention layer is that mixer
+        alone."""
+        if cfg.layer_pattern:
+            return h
+        with jax.named_scope("mlp"):
+            mlp = mlp_block(h)
+        return _shard_residual(mesh, h + mlp)
 
     if block_tables is not None:
         # Block-paged pool (ISSUE 10): layer_k/v are [n_blocks, page, KV,
@@ -624,10 +779,7 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
         with jax.named_scope("o_proj"):
             h = _shard_residual(
                 mesh, h + qmatmul(attn.reshape(B, S, H * hd), lp["wo"]))
-        with jax.named_scope("mlp"):
-            mlp = mlp_block(h)
-        return (_shard_residual(mesh, h + mlp), layer_k, layer_v, layer_ik,
-                counts)
+        return after_attention(h), layer_k, layer_v, layer_ik, counts
 
     # Write this chunk's K/V into the cache at its absolute positions.
     # (scatter; positions are per-slot absolute indices). Dead rows
@@ -675,11 +827,7 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
         with jax.named_scope("o_proj"):
             h = _shard_residual(
                 mesh, h + qmatmul(attn.reshape(B, S, H * hd), lp["wo"]))
-
-        with jax.named_scope("mlp"):
-            mlp = mlp_block(h)
-        return (_shard_residual(mesh, h + mlp), layer_k, layer_v, layer_ik,
-                counts)
+        return after_attention(h), layer_k, layer_v, layer_ik, counts
     else:
         with jax.named_scope("kv_write"):
             layer_k = layer_k.at[rows].set(k.astype(layer_k.dtype))
@@ -711,11 +859,127 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
     with jax.named_scope("o_proj"):
         h = _shard_residual(
             mesh, h + qmatmul(attn.reshape(B, S, H * hd), lp["wo"]))
+    return after_attention(h), layer_k, layer_v, layer_ik, counts
 
+
+# ------------------------------------------- one mixer a layer (patterned)
+
+#: A patterned configuration's leaves by kind (those the tree holds).
+ATTENTION_LEAVES = ("attn_norm", "wq", "wk", "wv", "wo")
+EXPERT_LAYER_LEAVES = ("router", "router_bias", "w_gate", "w_up", "w_down",
+                       "shared_gate", "shared_up", "shared_down")
+
+
+def _at(leaf, j: int):
+    """Layer ``j`` of a kind's stacked leaf (plain or quantized)."""
+    return jax.tree_util.tree_map(lambda a: a[j], leaf)
+
+
+def _expert_mixer(cfg: ModelConfig, layers: Params, j: int, h, mesh,
+                  token_mask, moe_impl: str):
+    """``h + experts(norm(h))`` for expert layer ``j``: the routed experts
+    (``_moe_mlp``'s paths; the grouped one reads layer ``j`` out of the
+    whole stacks) plus the shared expert every token takes. Returns
+    (h, experts_read or None)."""
+    with jax.named_scope("mlp_norm"):
+        x = rms_norm(h, layers["mlp_norm"][j], cfg.rms_eps, cfg.rms_offset)
+    grouped = serves_grouped(cfg, mesh, moe_impl)
+    lp = {k: (layers[k] if grouped and k in EXPERT_LEAVES
+              else _at(layers[k], j))
+          for k in EXPERT_LAYER_LEAVES if k in layers}
     with jax.named_scope("mlp"):
-        mlp = mlp_block(h)
-    return (_shard_residual(mesh, h + mlp), layer_k, layer_v, layer_ik,
-            counts)
+        y, n_read = _moe_mlp(cfg, lp, x, mesh, token_mask, moe_impl,
+                             jnp.asarray(j, jnp.int32) if grouped else None)
+        if cfg.shared_mlp_hidden:
+            y = y + _dense_mlp(cfg, lp, x, "shared_")
+    return h + y, n_read
+
+
+def _ssm_mixer(cfg: ModelConfig, layers: Params, j: int, h, ssm, conv,
+               valid):
+    """``h + mamba2(norm(h))`` for state-space layer ``j``, from and to
+    plane ``j`` of the state leaves. ``valid`` [B, S] bool marks a row's
+    real tokens (a prefix of its columns): the rest neither move the
+    state nor enter the convolution's tail. Scopes ``ssm/*`` on purpose
+    hold no keyword of the benchmark's trace categories: the mixer is
+    its own device time, not the attention's or the MLP's."""
+    from ..ops.ssd_scan import causal_conv, gated_group_norm, ssd_scan
+
+    B, S, _ = h.shape
+    H, P, N, G = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state,
+                  cfg.ssm_groups)
+    di = H * P
+    n_valid = jnp.sum(valid, axis=1, dtype=jnp.int32)
+    with jax.named_scope("ssm"):
+        with jax.named_scope("norm"):
+            x = rms_norm(h, layers["ssm_in_norm"][j], cfg.rms_eps,
+                         cfg.rms_offset)
+        with jax.named_scope("in_proj"):
+            zxd = qmatmul(x, _at(layers["ssm_in"], j))
+            z, xbc, dt = (zxd[..., :di], zxd[..., di:di + cfg.ssm_conv_dim],
+                          zxd[..., di + cfg.ssm_conv_dim:])
+        with jax.named_scope("conv"):
+            xbc, tail = causal_conv(xbc, conv[j], layers["ssm_conv_w"][j],
+                                    layers["ssm_conv_b"][j], n_valid)
+        with jax.named_scope("scan"):
+            dt = jax.nn.softplus(dt.astype(jnp.float32)
+                                 + layers["ssm_dt_bias"][j])
+            dt = jnp.where(valid[..., None], dt, 0.0)
+            y, state = ssd_scan(
+                xbc[..., :di].reshape(B, S, H, P), dt,
+                -jnp.exp(layers["ssm_A_log"][j].astype(jnp.float32)),
+                xbc[..., di:di + G * N].reshape(B, S, G, N),
+                xbc[..., di + G * N:].reshape(B, S, G, N),
+                layers["ssm_D"][j], ssm[j], cfg.ssm_chunk)
+            ssm = ssm.at[j].set(state)
+            conv = conv.at[j].set(tail)
+        with jax.named_scope("gate_norm"):
+            y = gated_group_norm(y.reshape(B, S, di), z,
+                                 layers["ssm_gate_norm"][j], G, cfg.rms_eps)
+        with jax.named_scope("out_proj"):
+            out = qmatmul(y, _at(layers["ssm_out"], j))
+    return h + out, ssm, conv
+
+
+def _patterned_layers(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
+                      layers: Params, h, cache: KVCache, positions,
+                      kv_limit: int, batch_idx, token_mask, write_mask,
+                      block_tables, q_lens):
+    """The layer loop of a patterned configuration, unrolled: layer l runs
+    as the kind ``cfg.layer_kinds[l]`` names, on layer j of that kind's
+    stacks (j its ordinal among its kind) — three kinds cannot share a
+    scan body. Returns (h, k, v, ssm, conv, experts_read or None)."""
+    B, S, _ = h.shape
+    valid = jnp.ones((B, S), bool)
+    if token_mask is not None:
+        valid = jnp.logical_and(valid, token_mask > 0)
+    if write_mask is not None:
+        valid = jnp.logical_and(
+            valid, write_mask if write_mask.ndim == 2 else write_mask[:, None])
+    if q_lens is not None:
+        valid = jnp.logical_and(valid, jnp.arange(S)[None, :] < q_lens[:, None])
+    k, v, ssm, conv = cache.k, cache.v, cache.ssm, cache.conv
+    if cfg.keeps_state and ssm is None:
+        ssm, conv = state_zeros(cfg, B, h.dtype)
+    step = partial(_layer, cfg, attn_impl, mesh, moe_impl)
+    n_read = None
+    seen = dict.fromkeys("ME*", 0)
+    for kind in cfg.layer_kinds:
+        j = seen[kind]
+        seen[kind] += 1
+        if kind == "M":
+            h, ssm, conv = _ssm_mixer(cfg, layers, j, h, ssm, conv, valid)
+        elif kind == "E":
+            h, n = _expert_mixer(cfg, layers, j, h, mesh, token_mask,
+                                 moe_impl)
+            if n is not None:
+                n_read = n if n_read is None else n_read + n
+        else:
+            lp = {name: _at(layers[name], j) for name in ATTENTION_LEAVES}
+            h, k, v, _, _ = step(h, lp, k, v, positions, kv_limit, batch_idx,
+                                 token_mask, write_mask, block_tables, q_lens,
+                                 jnp.asarray(j, jnp.int32), None)
+    return h, k, v, ssm, conv, n_read
 
 
 # -------------------------------------------------------------- forward
@@ -777,9 +1041,14 @@ def forward(
         kv_limit = cache.max_seq
     B, S = tokens.shape
     batch_idx = jnp.arange(B)[:, None]
-    new_ik = cache.ik
+    new_ik, new_ssm, new_conv = cache.ik, cache.ssm, cache.conv
     counted = {"experts_read": cache.experts_read,
                "sel_rows": cache.sel_rows}
+    if cfg.layer_kinds and mesh is not None and mesh.size > 1:
+        raise NotImplementedError(
+            f"{cfg.name} runs one mixer a layer (layer_pattern) and is "
+            "served on one device: parallel/sharding.py has no rule for "
+            "its leaves")
 
     # final_norm is always a plain array in the model dtype — it anchors
     # the activation dtype when the embedding is stored int8.
@@ -823,6 +1092,13 @@ def forward(
             params["layers"], cfg, h, positions, cache.k, cache.v, mesh,
             kv_limit=kv_limit, attn_impl="dense",
         )
+    elif cfg.layer_kinds:
+        h, new_k, new_v, new_ssm, new_conv, n_read = _patterned_layers(
+            cfg, attn_impl, mesh, moe_impl, params["layers"], h, cache,
+            positions, kv_limit, batch_idx, token_mask, write_mask,
+            block_tables, q_lens)
+        if n_read is not None and counted["experts_read"] is not None:
+            counted["experts_read"] = counted["experts_read"] + n_read
     else:
         step = partial(_layer, cfg, attn_impl, mesh, moe_impl)
 
@@ -846,7 +1122,7 @@ def forward(
         layers = params["layers"]
         stacks = {}
         if serves_grouped(cfg, mesh, moe_impl):
-            stacks = {k: layers[k] for k in EXPERT_LEAVES}
+            stacks = {k: layers[k] for k in EXPERT_LEAVES if k in layers}
             layers = {k: v for k, v in layers.items() if k not in stacks}
 
         def scan_body(carry, xs):
@@ -888,4 +1164,5 @@ def forward(
     else:
         new_lengths = jnp.maximum(cache.lengths, positions.max(axis=1) + 1)
     return logits.astype(jnp.float32), KVCache(
-        k=new_k, v=new_v, lengths=new_lengths, ik=new_ik, **counted)
+        k=new_k, v=new_v, lengths=new_lengths, ik=new_ik, ssm=new_ssm,
+        conv=new_conv, **counted)

@@ -291,7 +291,8 @@ def to_w8a8(params):
 #: projection weights eligible for quantization (matmul RHS with the
 #: output channel last). Embeddings/norms/router excluded.
 _QUANT_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
-               "idx_wq", "idx_wk")
+               "idx_wq", "idx_wk", "ssm_in", "ssm_out", "shared_gate",
+               "shared_up", "shared_down")
 
 
 #: The per-head q/k RMSNorm gains this SEEDED generator writes (bench/dev
@@ -301,6 +302,37 @@ _QUANT_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
 #: comparison of logits could tell which keys were attended. At 2 the
 #: scores' standard deviation is 4, as in a trained model.
 SEEDED_QK_NORM_GAIN = 2.0
+
+
+#: An eagerly made leaf of this many bytes or more is filled in place,
+#: slice by slice (``_slices_in_place``): its slices, their stack and the
+#: stack's copy into the layout the TPU keeps a 4-D leaf in are three
+#: times the leaf at once, and at 128 experts of 2688 x 1856 over 5 layers
+#: (3.2 GB a leaf) the seeded init peaked at 16.68 GB of a chip's 16.9
+#: (my chip run, PR 33). The same values as the stacked form, slice for
+#: slice. Mixtral-8x7B's six-layer cut has leaves this large too (6 x 8
+#: experts of 4096 x 14336: 2.8 GB) and takes this path; smaller leaves
+#: keep the stacked form they were measured with.
+_IN_PLACE_BYTES = 2 * 2 ** 30
+
+
+def _slices_in_place(one, keys, shape):
+    """int8 [*lead, in, out]: slice i is ``one(keys[i])``, as the stacked
+    form draws it, written into one preallocated (donated) leaf."""
+    import numpy as np
+
+    lead = shape[:-2]
+
+    def put(buf, k, idx):
+        return jax.lax.dynamic_update_slice(
+            buf, one(k).reshape((1,) * len(lead) + shape[-2:]),
+            tuple(idx) + (0, 0))
+
+    put = jax.jit(put, donate_argnums=0)
+    buf = jnp.zeros(shape, jnp.int8)
+    for i, idx in enumerate(np.ndindex(*lead)):
+        buf = put(buf, keys[i], jnp.asarray(idx, jnp.int32))
+    return buf
 
 
 def random_params_int8(key, cfg, dtype=None,
@@ -339,7 +371,7 @@ def _random_params_int8(key, cfg, dtype, quantize_embed: bool, int4: bool,
     """
     import jax.numpy as _jnp
 
-    from ..models.transformer import init_params
+    from ..models.transformer import init_params, small_leaf_init
     from .quant4 import QuantInt4, pick_format
 
     if dtype is None:
@@ -377,9 +409,15 @@ def _random_params_int8(key, cfg, dtype, quantize_embed: bool, int4: bool,
                     return jax.random.randint(ki, payload_shape[-2:],
                                               -127, 128, dtype=_jnp.int8)
 
-                q = (jax.vmap(one)(lk) if slices_in_one_op
-                     else _jnp.stack([one(lk[i]) for i in range(n_lead)])
-                     ).reshape(payload_shape)
+                if slices_in_one_op:
+                    q = jax.vmap(one)(lk).reshape(payload_shape)
+                elif (n_lead * sds.shape[-2] * payload_shape[-1]
+                        >= _IN_PLACE_BYTES
+                        and not isinstance(key, jax.core.Tracer)):
+                    q = _slices_in_place(one, lk, payload_shape)
+                else:
+                    q = _jnp.stack([one(lk[i]) for i in range(n_lead)]
+                                   ).reshape(payload_shape)
             else:
                 q = jax.random.randint(k, payload_shape, -127, 128,
                                        dtype=_jnp.int8)
@@ -397,6 +435,10 @@ def _random_params_int8(key, cfg, dtype, quantize_embed: bool, int4: bool,
             scale = _jnp.full(sshape, (sds.shape[-2] ** -0.5) / 127.0,
                               _jnp.float32)
             out.append(QuantInt8(q=q, scale=scale))
+        elif (small := small_leaf_init(name, sds.shape, dtype, k)) is not None:
+            # a patterned configuration's step biases, decays, convolution
+            # and selection bias: the values init_params gives them
+            out.append(small)
         elif name in ("q_norm", "k_norm"):
             out.append(_jnp.full(sds.shape, SEEDED_QK_NORM_GAIN, dtype))
         elif name.endswith("norm"):
@@ -411,6 +453,10 @@ def _random_params_int8(key, cfg, dtype, quantize_embed: bool, int4: bool,
             ))
         else:
             scale = 1.0 if name == "embed" else sds.shape[0] ** -0.5
+            if name == "router" and cfg.router == "sigmoid_bias":
+                # unit-variance logits: scores spread over (0, 1) and the
+                # top-k is the input's, not a few saturated experts'
+                scale = sds.shape[-2] ** -0.5
             out.append(
                 (jax.random.normal(k, sds.shape, _jnp.float32) * scale)
                 .astype(dtype)

@@ -43,15 +43,46 @@ except ImportError:  # pragma: no cover
 from ..models.config import ModelConfig
 
 
-def router_weights(cfg: ModelConfig, logits: jnp.ndarray):
+def router_logits(cfg: ModelConfig, lp: Dict[str, Any], x: jnp.ndarray):
+    """x [..., D] -> float32 [..., E]. The sigmoid router's scores decide
+    a top-k among near-equal values, so its product runs in float32 at
+    the highest precision (T x D x E: nothing beside an expert); the
+    softmax router's is the activation dtype's, as it always was."""
+    if cfg.router == "sigmoid_bias":
+        return jnp.dot(x.astype(jnp.float32),
+                       lp["router"].astype(jnp.float32),
+                       precision=jax.lax.Precision.HIGHEST)
+    return (x @ lp["router"]).astype(jnp.float32)
+
+
+def top_k_routing(cfg: ModelConfig, logits: jnp.ndarray, bias=None):
+    """logits float32 [..., E] -> (weights float32 [..., k], idx [..., k]).
+
+    ``softmax`` (Mixtral, Qwen3-MoE): the k largest logits, softmax over
+    them. ``sigmoid_bias`` (the DeepSeek-V3 router nemotron_h takes): the
+    k largest of sigmoid(logits) + ``bias`` [E] (a learned selection
+    bias that enters nothing else), the picked scores divided by their
+    sum, times ``router_scale``."""
+    k = cfg.experts_per_token
+    if cfg.router == "sigmoid_bias":
+        s = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), k)
+        w = jnp.take_along_axis(s, idx, axis=-1)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        return w * cfg.router_scale, idx
+    if cfg.router != "softmax":
+        raise ValueError(f"unknown router kind {cfg.router!r}")
+    top_vals, idx = jax.lax.top_k(logits, k)
+    return jax.nn.softmax(top_vals.astype(jnp.float32), axis=-1), idx
+
+
+def router_weights(cfg: ModelConfig, logits: jnp.ndarray, bias=None):
     """Top-k routing. logits [..., E] -> (mix [..., E], idx [..., k]).
 
     ``mix`` is dense over E with zeros off the top-k — dense mixing keeps
     the op jit-friendly (no ragged gathers) and maps to pure VPU work.
     """
-    k = cfg.experts_per_token
-    top_vals, top_idx = jax.lax.top_k(logits, k)                  # [..., k]
-    top_w = jax.nn.softmax(top_vals.astype(jnp.float32), axis=-1)  # renorm over k
+    top_w, top_idx = top_k_routing(cfg, logits, bias)
     mix = jnp.zeros(logits.shape, dtype=jnp.float32)
     mix = jnp.put_along_axis(mix, top_idx, top_w, axis=-1, inplace=False)
     return mix, top_idx
@@ -94,12 +125,15 @@ def dense_moe(cfg: ModelConfig, lp: Dict[str, Any], x: jnp.ndarray,
     projection's own result [B, S, E, D], E times the bytes on the
     wires (read off the compiled program, PERF.md Findings PR 27).
     Without such a mesh nothing here changes."""
-    logits = (x @ lp["router"]).astype(jnp.float32)               # [B, S, E]
-    mix, _ = router_weights(cfg, logits)
+    logits = router_logits(cfg, lp, x)                            # [B, S, E]
+    mix, _ = router_weights(cfg, logits, lp.get("router_bias"))
 
-    gate = _qeinsum("bsd,edf->bsef", x, lp["w_gate"], "ef_last2")
     up = _qeinsum("bsd,edf->bsef", x, lp["w_up"], "ef_last2")
-    hidden = _act(cfg, gate) * up                                 # [B, S, E, F]
+    if cfg.gated_mlp:
+        gate = _qeinsum("bsd,edf->bsef", x, lp["w_gate"], "ef_last2")
+        hidden = _act(cfg, gate) * up                             # [B, S, E, F]
+    else:
+        hidden = _act(cfg, up)
     if _splits_expert_width(mesh, cfg):
         return _down_and_mix_sharded(hidden, lp["w_down"], mix, mesh)
     return _down_and_mix(hidden, lp["w_down"], mix)
@@ -169,6 +203,8 @@ def _act(cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
     change can never make them silently diverge."""
     if cfg.activation == "gelu":
         return jax.nn.gelu(x, approximate=True)
+    if cfg.activation == "relu2":
+        return jnp.square(jax.nn.relu(x))
     return jax.nn.silu(x)
 
 
@@ -325,27 +361,44 @@ def _payload_and_scale(w, stacked: bool):
     return q, scale
 
 
-def _grouped_ffn_kernel(te_ref, live_ref, lyr_ref, x_ref, wg_ref, sg_ref,
-                        wu_ref, su_ref, wd_ref, sd_ref, o_ref, *, gelu: bool):
-    """One tile of rows that share an expert: silu/gelu(x Wg) * (x Wu),
+def _grouped_ffn_kernel(te_ref, live_ref, lyr_ref, x_ref, *refs,
+                        activation: str, up_t: bool):
+    """One tile of rows that share an expert. Gated (six weight refs):
+    silu/gelu(x Wg) * (x Wu), then Wd; ``relu2`` (four): relu(x Wu)^2,
     then Wd. The weights arrive as stored (int8 where quantized) and are
-    converted in VMEM; the per-channel scales multiply the f32 results."""
+    converted in VMEM; the per-channel scales multiply the f32 results.
+    ``up_t``: the up (and gate) blocks are handed over as [F, D] (see
+    ``_grouped_ffn``) and contract on their minor axis."""
+    *w_refs, o_ref = refs
     i = pl.program_id(0)
 
     @pl.when(live_ref[i] == 0)
     def _dead():
         o_ref[...] = jnp.zeros_like(o_ref)
 
+    def up(x, w_ref, s_ref):
+        w = w_ref[0, 0].astype(x.dtype)
+        if up_t:
+            y = jax.lax.dot_general(x, w, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+        else:
+            y = jnp.dot(x, w, preferred_element_type=jnp.float32)
+        return y * s_ref[0, 0]
+
     @pl.when(live_ref[i] != 0)
     def _tile():
         x = x_ref[...]
-        g = jnp.dot(x, wg_ref[0, 0].astype(x.dtype),
-                    preferred_element_type=jnp.float32) * sg_ref[0, 0]
-        u = jnp.dot(x, wu_ref[0, 0].astype(x.dtype),
-                    preferred_element_type=jnp.float32) * su_ref[0, 0]
-        act = jax.nn.gelu(g, approximate=True) if gelu else jax.nn.silu(g)
-        h = (act * u).astype(x.dtype)
-        y = jnp.dot(h, wd_ref[0, 0].astype(x.dtype),
+        if activation == "relu2":
+            wu_ref, su_ref, wd_ref, sd_ref = w_refs
+            h = jnp.square(jnp.maximum(up(x, wu_ref, su_ref), 0.0))
+        else:
+            wg_ref, sg_ref, wu_ref, su_ref, wd_ref, sd_ref = w_refs
+            g = up(x, wg_ref, sg_ref)
+            u = up(x, wu_ref, su_ref)
+            act = (jax.nn.gelu(g, approximate=True) if activation == "gelu"
+                   else jax.nn.silu(g))
+            h = act * u
+        y = jnp.dot(h.astype(x.dtype), wd_ref[0, 0].astype(x.dtype),
                     preferred_element_type=jnp.float32) * sd_ref[0, 0]
         o_ref[...] = y.astype(o_ref.dtype)
 
@@ -359,10 +412,27 @@ def _grouped_ffn(cfg: ModelConfig, lp, xs, tile_expert, tile_live, tm: int,
     consecutive tiles of one expert (and the dead tiles after the last
     live one, which repeat its expert) fetch nothing new."""
     stacked = layer is not None
-    wg, sg = _payload_and_scale(lp["w_gate"], stacked)
     wu, su = _payload_and_scale(lp["w_up"], stacked)
     wd, sd = _payload_and_scale(lp["w_down"], stacked)
-    _, _, D, F = wg.shape
+    _, _, D, F = wu.shape
+    # An expert's block is its whole matrix, so F is a full dimension of
+    # the block and need not be a multiple of 128 (1,856). But where F is
+    # not and D is, the TPU keeps a [.., D, F] leaf in HBM with D minor-
+    # most (no lane padding), and a kernel that asks for it row-major gets
+    # a copy of the WHOLE stack in front of every call (638 MB a layer a
+    # pass at 2688 x 1856; AOT, PR 33). The up (and gate) stacks are then
+    # handed over as [.., F, D] — the same bytes, a free relabelling —
+    # and contracted on their minor axis (tests/test_tpu_aot.py pins
+    # that no stack-sized copy is left).
+    up_t = F % 128 != 0 and D % 128 == 0
+    ups = [(wu, su)]
+    if cfg.gated_mlp:
+        ups.insert(0, _payload_and_scale(lp["w_gate"], stacked))
+    if up_t:
+        ups = [(jnp.swapaxes(w, -1, -2), sc) for w, sc in ups]
+    weights = tuple(a for pair in ups for a in pair) + (wd, sd)
+    kernel = partial(_grouped_ffn_kernel, activation=cfg.activation,
+                     up_t=up_t)
     n_tiles = tile_expert.shape[0]
     lyr = jnp.asarray(0 if layer is None else layer, jnp.int32).reshape(1)
 
@@ -375,20 +445,13 @@ def _grouped_ffn(cfg: ModelConfig, lp, xs, tile_expert, tile_live, tm: int,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((tm, D), rows),
-            pl.BlockSpec((1, 1, D, F), expert),
-            pl.BlockSpec((1, 1, 1, F), expert),
-            pl.BlockSpec((1, 1, D, F), expert),
-            pl.BlockSpec((1, 1, 1, F), expert),
-            pl.BlockSpec((1, 1, F, D), expert),
-            pl.BlockSpec((1, 1, 1, D), expert),
-        ],
+        in_specs=[pl.BlockSpec((tm, D), rows)] + [
+            pl.BlockSpec((1, 1) + w.shape[2:], expert) for w in weights],
         out_specs=pl.BlockSpec((tm, D), rows),
     )
     interpret = jax.default_backend() != "tpu"
     return pl.pallas_call(
-        partial(_grouped_ffn_kernel, gelu=cfg.activation == "gelu"),
+        kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(xs.shape, xs.dtype),
         interpret=interpret,
@@ -396,7 +459,7 @@ def _grouped_ffn(cfg: ModelConfig, lp, xs, tile_expert, tile_live, tm: int,
         **({} if interpret else {"compiler_params": pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_GROUPED_VMEM_BYTES)}),
-    )(tile_expert, tile_live, lyr, xs, wg, sg, wu, su, wd, sd)
+    )(tile_expert, tile_live, lyr, xs, *weights)
 
 
 #: Scoped VMEM of the grouped kernel: three expert matrices double-
@@ -424,12 +487,11 @@ def grouped_moe(cfg: ModelConfig, lp: Dict[str, Any], x: jnp.ndarray,
     whose weights this pass streamed."""
     B, S, D = x.shape
     T, k = B * S, cfg.experts_per_token
-    held = (lp["w_gate"].q if hasattr(lp["w_gate"], "q")
-            else lp["w_gate"]).shape[0 if layer is None else 1]
+    held = (lp["w_up"].q if hasattr(lp["w_up"], "q")
+            else lp["w_up"]).shape[0 if layer is None else 1]
     xf = x.reshape(T, D)
-    logits = (xf @ lp["router"]).astype(jnp.float32)              # [T, E]
-    top_vals, top_idx = jax.lax.top_k(logits, k)
-    top_w = jax.nn.softmax(top_vals, axis=-1)                      # [T, k]
+    top_w, top_idx = top_k_routing(cfg, router_logits(cfg, lp, xf),
+                                   lp.get("router_bias"))          # [T, k]
 
     # (token, pick) pairs -> local expert id; ``held`` = no expert here.
     e = top_idx.astype(jnp.int32) - first_expert

@@ -1107,6 +1107,11 @@ async def handle_health(request: web.Request) -> web.Response:
     sah = getattr(svc.engine, "sparse_attention_health", None)
     if callable(sah):
         sparse_attention = sah() or None
+    # Recurrent-state snapshots (ISSUE 33): same cheap host counters.
+    ssm = None
+    ssh = getattr(svc.engine, "ssm_health", None)
+    if callable(ssh):
+        ssm = ssh() or None
     # Sharding (ISSUE 14): mesh shape, residual TP fraction, pool-
     # sharded + mesh-fallback flags — cheap host attributes, same rule.
     sharding = None
@@ -1161,6 +1166,7 @@ async def handle_health(request: web.Request) -> web.Response:
         kv_pool=kv_pool,
         moe=moe,
         sparse_attention=sparse_attention,
+        ssm=ssm,
         sharding=sharding,
         grammar=grammar,
         spec=spec,
@@ -1501,6 +1507,9 @@ async def handle_metrics(request: web.Request) -> web.Response:
         # sharing/COW/radix-hit counters — same delta-mirror pattern.
         if stats.get("kv_pool"):
             svc.metrics.observe_kv_pool(stats["kv_pool"])
+        # Recurrent-state snapshots (ISSUE 33): same delta-mirror.
+        if stats.get("ssm"):
+            svc.metrics.observe_state_cache(stats["ssm"])
         # Tensor-parallel serving (ISSUE 14): mesh device count,
         # residual TP fraction, and the kv_pool_mesh_fallback flag —
         # gauges sampled at scrape time.

@@ -228,6 +228,39 @@ class Metrics:
             "chain purge, or reset drain)",
             registry=r,
         )
+        # Recurrent-state cache (ISSUE 33, engine/kv_pool.py::StateStore):
+        # snapshots of a state-space model's state beside the block pool.
+        self.state_snapshots = Gauge(
+            "state_snapshots",
+            "Recurrent-state snapshots the device store holds, by state "
+            "(held | capacity | pinned | restore_depth_peak: the deepest "
+            "LRU rank a restore found, what the store's size answers to)",
+            ["state"],
+            registry=r,
+        )
+        self.state_cache_events = Counter(
+            "state_cache_events_total",
+            "Recurrent-state cache events (snapshots_taken | "
+            "snapshots_evicted | snapshots_skipped | restores)",
+            ["event"],
+            registry=r,
+        )
+        self.state_prefix_tokens = Counter(
+            "state_prefix_tokens_total",
+            "Prompt tokens the radix tree matched for a model with a "
+            "recurrent state, by what became of them (usable: at or "
+            "before the restored snapshot | recomputed: K/V matched but "
+            "past it)",
+            ["outcome"],
+            registry=r,
+        )
+        self.state_bytes_moved = Counter(
+            "state_bytes_moved_total",
+            "Bytes of recurrent state copied between decode slots and "
+            "the snapshot store",
+            registry=r,
+        )
+        self._state_seen: dict = {}
         self._kv_pool_seen = {"shared": 0, "cow": 0, "hit": 0, "miss": 0,
                               "demoted": 0, "onloaded": 0, "dropped": 0,
                               "fail_corrupt": 0, "fail_exhausted": 0}
@@ -759,6 +792,33 @@ class Metrics:
                 if total > seen[key]:
                     counter.inc(total - seen[key])
                     seen[key] = total
+
+    def observe_state_cache(self, ssm: dict) -> None:
+        """Mirror /health.ssm (stats()["ssm"]) at scrape time: gauges set
+        directly, cumulative totals delta-inc'd like the pool's."""
+        self.state_snapshots.labels(state="held").set(
+            ssm.get("snapshots_held", 0))
+        self.state_snapshots.labels(state="capacity").set(
+            ssm.get("capacity", 0))
+        self.state_snapshots.labels(state="pinned").set(
+            ssm.get("snapshots_pinned", 0))
+        self.state_snapshots.labels(state="restore_depth_peak").set(
+            ssm.get("restore_depth_peak", 0))
+        counters = [(e, self.state_cache_events.labels(event=e), e)
+                    for e in ("snapshots_taken", "snapshots_evicted",
+                              "snapshots_skipped", "restores")]
+        counters += [
+            ("usable", self.state_prefix_tokens.labels(outcome="usable"),
+             "prefix_tokens_usable"),
+            ("recomputed",
+             self.state_prefix_tokens.labels(outcome="recomputed"),
+             "prefix_tokens_recomputed"),
+            ("bytes", self.state_bytes_moved, "state_bytes_moved")]
+        for key, counter, field in counters:
+            total = ssm.get(field, 0)
+            if total > self._state_seen.get(key, 0):
+                counter.inc(total - self._state_seen.get(key, 0))
+                self._state_seen[key] = total
 
     def observe_sharding(self, sharding: dict) -> None:
         """Mirror the engine's sharding view (stats()["sharding"],

@@ -123,6 +123,13 @@ class HealthResponse(BaseModel):
     # configuration has neither.
     moe: Optional[Dict[str, Any]] = None
     sparse_attention: Optional[Dict[str, Any]] = None
+    # Recurrent-state cache of a model with state-space layers (ISSUE 33;
+    # engine/kv_pool.py::StateStore.stats, batcher.py::ssm_health):
+    # snapshots held / capacity / bytes and their peak, snapshots taken /
+    # evicted / skipped, restores, prefix tokens matched / usable /
+    # recomputed, state bytes moved, layer passes by kind. None for every
+    # other model.
+    ssm: Optional[Dict[str, Any]] = None
     # Tensor-parallel serving (ISSUE 14, parallel/sharding.py): the
     # active mesh shape + device count, the residual TP fraction the
     # f≈1 policy achieves at the decode shape, whether the KV pool is

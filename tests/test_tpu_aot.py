@@ -537,3 +537,73 @@ def test_the_grouped_expert_kernel_cannot_hold_a_mixtral_expert_on_v5e(one_chip,
     with pytest.raises(Exception, match="(?i)vmem"):
         jax.jit(lambda lp, x: grouped_moe(cfg, lp, x)).lower(
             lp, arg((16, 1, D), jnp.bfloat16)).compile()
+
+
+# ------------------------------------ one mixer a layer (ISSUE 33)
+
+NEMOTRON = dict(vocab_size=131072, dim=2688, n_heads=32, n_kv_heads=2,
+                head_dim=128, mlp_hidden=1856, rms_eps=1e-5,
+                activation="relu2", n_experts=128, experts_per_token=6,
+                eos_ids=(2,), ssm_heads=64, ssm_head_dim=64, ssm_state=128,
+                ssm_groups=8, ssm_conv=4, ssm_chunk=128,
+                shared_mlp_hidden=3712, router="sigmoid_bias",
+                router_scale=2.5, use_rope=False)
+
+
+@pytest.mark.parametrize("B,W", [(16, 1), (16, 64), (1, 512)],
+                         ids=["decode", "window-64", "eager-512"])
+def test_patterned_forward_compiles_at_published_widths_on_v5e(one_chip, B, W,
+                                                                monkeypatch):
+    """nemotron-3-nano-30b-a3b-l13's three kinds (one layer each, every
+    width as published, the engine's 257-page table over a 4,096-block
+    pool): Mosaic accepts the grouped expert kernel over int8 experts of
+    TWO matrices, 2688 x 1856 (1,856 is no multiple of 128: the TPU keeps
+    that leaf with 2,688 minor-most, and the kernel takes the up stack as
+    [F, D] so that no copy of the stack stands in front of it — as
+    [D, F] a 638 MB copy a layer a pass did), and the ragged kernel at
+    32Q/2KV without a rotary embedding; the state leaves ride the
+    donated cache; nothing expert-stack-sized or pool-sized moves."""
+    from ai_agent_kubectl_tpu.models.transformer import state_zeros
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = ModelConfig(name="aot-nemotron", n_layers=3, layer_pattern="ME*",
+                      **NEMOTRON)
+    page, n_blocks, pages = 64, 4096, 257
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda x: arg(x.shape, x.dtype),
+        jax.eval_shape(lambda k: random_params_int8(
+            k, cfg, dtype=jnp.bfloat16, quantize_embed=True),
+            jax.random.PRNGKey(0)))
+    pool = (1, n_blocks, page, cfg.n_kv_heads, cfg.head_dim)
+    ssm, conv = jax.eval_shape(lambda: state_zeros(cfg, B, jnp.bfloat16))
+    cache = KVCache(k=arg(pool, jnp.bfloat16), v=arg(pool, jnp.bfloat16),
+                    lengths=arg((n_blocks,), jnp.int32),
+                    experts_read=arg((), jnp.int32),
+                    ssm=arg(ssm.shape, ssm.dtype),
+                    conv=arg(conv.shape, conv.dtype))
+
+    def step(params, tok, pos, cache, wmask, tables, q_lens):
+        return forward(params, cfg, tok, pos, cache, kv_limit=pages * page,
+                       attn_impl="ragged", token_mask=wmask,
+                       write_mask=wmask, block_tables=tables, q_lens=q_lens,
+                       logits_at=jnp.maximum(q_lens, 1) - 1)
+
+    compiled = jax.jit(step, donate_argnums=(3,)).trace(
+        params, arg((B, W), jnp.int32), arg((B, W), jnp.int32), cache,
+        arg((B, W), jnp.bool_), arg((B, pages), jnp.int32),
+        arg((B,), jnp.int32)).lower().compile()
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+    assert not _results_of_size(hlo, {128 * 2688 * 1856}), "an expert stack moved"
+    # the pool: the row writes alone, in place (each scatter shows twice:
+    # inside its fusion, and as the fusion)
+    moved = _results_of_size(hlo, {math.prod(pool)})
+    assert {op for op, _ in moved} <= {"scatter", "fusion"}, moved
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= (2 * math.prod(pool) * 2
+                                       + math.prod(ssm.shape) * 4)
+    assert mem.temp_size_in_bytes < 2 ** 30, mem.temp_size_in_bytes
