@@ -3164,11 +3164,22 @@ class BatchedJaxEngine(JaxEngine):
         """/health.moe: experts whose weights the grouped expert path
         read, and the layer passes they were read in, both over the chunk
         programs' passes (cumulative host counters; None where another
-        MoE path, or none, serves)."""
+        MoE path, or none, serves); beside them what the kernel resolves
+        from shapes (tile rows, grid steps) for a decode pass, the widest
+        window and an eager piece of the widest bucket."""
         if not self._counts_experts:
             return None
+        from ..parallel.moe import grouped_kernel_shape
+
+        cfg, wide = self.model_cfg, self.prefill_buckets[-1]
         return {"experts_read": self._moe_experts_read,
-                "layer_passes": self._moe_layer_passes}
+                "layer_passes": self._moe_layer_passes,
+                # what the kernel resolves from a call's shapes (ISSUE 34)
+                "kernel": {
+                    "decode": grouped_kernel_shape(cfg, self.batch_size),
+                    "widest_window": grouped_kernel_shape(
+                        cfg, self.batch_size * wide),
+                    "eager_piece": grouped_kernel_shape(cfg, wide)}}
 
     def ssm_health(self) -> Optional[dict]:
         """/health.ssm (cumulative; None for a model without state-space

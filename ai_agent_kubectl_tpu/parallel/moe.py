@@ -27,6 +27,7 @@ selection, renormalized over the selected experts).
 
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import Any, Dict, Optional
 
@@ -336,16 +337,52 @@ def expert_parallel_moe(
 
 # ------------------------------------------------ token-grouped expert GEMM
 
-#: Rows of one tile of the grouped kernel: a power of two near the mean
-#: group (pairs / experts), at least one bf16 sublane tile, at most what
-#: keeps a tile's activations small beside its expert's weights in VMEM.
+#: Rows of one tile of the grouped kernel: at least one bf16 sublane tile,
+#: at most what keeps a tile's activations small beside its expert's
+#: weights in VMEM.
 _GROUP_TILE_MIN, _GROUP_TILE_MAX = 16, 256
 
 
 def _group_tile(pairs: int, experts: int) -> int:
-    mean = max(1, pairs // max(1, experts))
-    return min(_GROUP_TILE_MAX,
-               max(_GROUP_TILE_MIN, 1 << (mean.bit_length() - 1)))
+    """Rows of a tile, from the call's shapes: what holds most groups
+    WHOLE — the mean group (pairs / experts) plus its spread (a uniform
+    router's groups scatter by about the root of their mean), in whole
+    bf16 sublane tiles. The kernel is bound by its experts' stream (a
+    live tile's conversion and products take under half the time its
+    expert takes to arrive), and the pipeline fetches one step ahead: the
+    first tile of a group split over two computes with nothing in flight
+    and the second waits out the next expert's whole stream, 5.8 + 13.9
+    us against 13.9 at 2688 x 1856 (PERF.md Findings, PR 34). At 24 rows
+    an expert (an eager 512-wide piece of top-6 of 128) 16-row tiles
+    split nearly every group and 32-row tiles one in twenty. No power of
+    two: at 64 rows an expert 128-row tiles cost more in products than
+    the stream hides, 80-row tiles do not. Decode (under one row an
+    expert) stays at the smallest tile."""
+    mean = -(-pairs // max(1, experts))
+    need = mean + math.isqrt(mean)
+    return min(_GROUP_TILE_MAX, -(-need // _GROUP_TILE_MIN) * _GROUP_TILE_MIN)
+
+
+def _grid_tiles(pairs: int, tm: int, experts: int) -> int:
+    """Steps of the kernel's grid: the most tiles ``pairs`` rows in groups
+    of ``experts`` experts can need (every group that can have a row has
+    one, the other rows fill whole tiles), and one more, so that the last
+    tile is always dead: its zero rows are what an unpicked pair reads.
+    A decode pass of 96 pairs over 128 experts cannot light 134 tiles;
+    the rows the tighter bound saves are the gather's and the mix's, a
+    dead step itself is 0.07 us (PR 34)."""
+    groups = min(pairs, experts)
+    return groups + (pairs - groups) // tm + 1
+
+
+def grouped_kernel_shape(cfg: ModelConfig, tokens: int) -> Dict[str, int]:
+    """What the grouped kernel resolves from shapes for a call of
+    ``tokens`` tokens over all of ``cfg``'s experts (``/health.moe``,
+    tools/time_grouped_kernel.py): a tile's rows and the grid's steps."""
+    pairs = tokens * cfg.experts_per_token
+    tm = _group_tile(pairs, cfg.n_experts)
+    return {"tile_rows": tm,
+            "grid_steps": _grid_tiles(pairs, tm, cfg.n_experts)}
 
 
 def _payload_and_scale(w, stacked: bool):
@@ -410,7 +447,9 @@ def _grouped_ffn(cfg: ModelConfig, lp, xs, tile_expert, tile_live, tm: int,
     scalar-prefetched ``layer``) picks the weight block in the index maps,
     so no layer is sliced out of the stack in front of the call, and
     consecutive tiles of one expert (and the dead tiles after the last
-    live one, which repeat its expert) fetch nothing new."""
+    live one, which repeat its expert) fetch nothing new. The live tiles
+    are a prefix of the grid and its last tile is dead (``_grid_tiles``):
+    every dead step names that tile's rows, so they move one tile."""
     stacked = layer is not None
     wu, su = _payload_and_scale(lp["w_up"], stacked)
     wd, sd = _payload_and_scale(lp["w_down"], stacked)
@@ -437,7 +476,9 @@ def _grouped_ffn(cfg: ModelConfig, lp, xs, tile_expert, tile_live, tm: int,
     lyr = jnp.asarray(0 if layer is None else layer, jnp.int32).reshape(1)
 
     def rows(i, te, live, lyr):
-        return (i, 0)
+        # a dead step names the last tile (always dead): the dead steps of
+        # a call fetch one tile of rows and write one tile of zeros
+        return (jnp.where(live[i] != 0, i, n_tiles - 1), 0)
 
     def expert(i, te, live, lyr):
         return (lyr[0], te[i], 0, 0)
@@ -462,10 +503,14 @@ def _grouped_ffn(cfg: ModelConfig, lp, xs, tile_expert, tile_live, tm: int,
     )(tile_expert, tile_live, lyr, xs, *weights)
 
 
-#: Scoped VMEM of the grouped kernel: three expert matrices double-
-#: buffered as stored plus their converted copies and a tile's
-#: activations (Keye's 2048 x 768 int8: 9.4 MiB + 9.4 MiB bf16 + < 4 MiB)
-#: pass the 16 MiB default; a v5e core has 128 MiB.
+#: Scoped VMEM of the grouped kernel: an expert's matrices as stored,
+#: double-buffered by the pipeline, their converted copies and a tile's
+#: activations (Nemotron's 2 x 2688 x 1856 int8: 19 MiB + 19 MiB bf16 +
+#: < 10 MiB at 256 rows; Keye's 3 x 2048 x 768: 9 + 9 + < 8) pass the
+#: 16 MiB default; a v5e core has 128 MiB. Taking the inner width in
+#: pieces would take the converted copy out, and buys no time: a tile's
+#: conversion and products hide behind its expert's stream whole, and
+#: in pieces they took 9.1 us against 6.4 (PERF.md Findings, PR 34).
 _GROUPED_VMEM_BYTES = 64 * 2**20
 
 
@@ -501,7 +546,7 @@ def grouped_moe(cfg: ModelConfig, lp: Dict[str, Any], x: jnp.ndarray,
     e = e.reshape(T * k)
     M = T * k
     tm = _group_tile(M, held)
-    n_tiles = -(-M // tm) + held
+    n_tiles = _grid_tiles(M, tm, held)
 
     sizes = jnp.sum(e[:, None] == jnp.arange(held)[None, :], axis=0,
                     dtype=jnp.int32)                               # [held]

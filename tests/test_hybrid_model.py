@@ -220,6 +220,47 @@ def test_two_matrix_experts_sigmoid_router_and_shared_expert(ref, params, path):
     assert margin.shape == (18,)
 
 
+@pytest.mark.parametrize("quant", [False, True], ids=["plain", "int8"])
+@pytest.mark.parametrize("F,masked", [(232, 0), (464, 5), (384, 5), (464, 18)],
+                         ids=["29x8", "29x16-rows-masked", "three-lane-tiles",
+                              "every-row-masked"])
+def test_two_matrix_experts_of_other_widths_against_the_reference(ref, F, masked, quant):
+    """ISSUE 34: relu^2 experts of inner widths 29 x 8 and 29 x 16 (the up block
+    handed over as [F, D], as Nemotron's 29 x 64 is) and 3 x 128 (as [D, F])
+    through the grouped kernel under the sigmoid router with its selection bias:
+    against the reference's loop over whole experts, live rows one by one; masked
+    rows read no expert and a pass with none live returns zeros."""
+    from ai_agent_kubectl_tpu.ops.quant import QuantInt8, quantize_int8
+    cfg = dataclasses.replace(CFG, name="made-up-relu2", mlp_hidden=F)
+    D, E = cfg.dim, cfg.n_experts
+    r = np.random.default_rng(F)
+    up = jnp.asarray(r.normal(size=(E, D, F)) * D ** -0.5, jnp.float32)
+    down = jnp.asarray(r.normal(size=(E, F, D)) * F ** -0.5, jnp.float32)
+    lp = {"router": jnp.asarray(r.normal(size=(D, E)), jnp.float32),
+          "router_bias": jnp.asarray(r.normal(size=E) * 0.1, jnp.float32),
+          "w_up": quantize_int8(up) if quant else up,
+          "w_down": quantize_int8(down) if quant else down}
+    x = jnp.asarray(r.normal(size=(2, 9, D)), jnp.float32)
+    mask = jnp.ones((18,), jnp.float32).at[18 - masked:].set(0).reshape(2, 9)
+    y, n_read = jax.jit(lambda lp, x, m: grouped_moe(cfg, lp, x, m))(lp, x, mask)
+    y = np.asarray(y).reshape(18, D)
+    live = np.asarray(mask).reshape(18) > 0
+    assert np.abs(y[~live]).max(initial=0) == 0
+    if masked == 18:
+        assert int(n_read) == 0
+        return
+    lw = {"router": lp["router"], "router_bias": lp["router_bias"],
+          "shared_up": jnp.zeros((D, 8)), "shared_down": jnp.zeros((8, D))}
+    for name in ("w_up", "w_down"):
+        w = lp[name]
+        lw[name] = ({"q": w.q, "scale": w.scale} if isinstance(w, QuantInt8) else
+                    {"q": w, "scale": jnp.ones((E, 1, w.shape[2]))})
+    with jax.default_matmul_precision("highest"):
+        want, _ = ref.experts(SIZES, lw, x.reshape(18, D))
+    np.testing.assert_allclose(y[live], np.asarray(want)[live], rtol=2e-4, atol=2e-4)
+    assert 1 <= int(n_read) <= E
+
+
 def test_selection_bias_picks_but_does_not_weigh(params):
     """A large bias on one expert makes every token pick it; its weight is still
     its own sigmoid score over the picked scores' sum."""

@@ -17,7 +17,7 @@ import pytest
 from ai_agent_kubectl_tpu.models.config import get_config
 from ai_agent_kubectl_tpu.models.transformer import (KVCache, forward,
                                                      init_params)
-from ai_agent_kubectl_tpu.ops.quant import (quantize_params_int8,
+from ai_agent_kubectl_tpu.ops.quant import (quantize_int8, quantize_params_int8,
                                             random_params_int8)
 from ai_agent_kubectl_tpu.ops.ragged_attention import ragged_attention_pool
 from ai_agent_kubectl_tpu.ops.rope import apply_rope
@@ -238,6 +238,158 @@ def test_grouped_expert_path_equals_dense_moe(name, quant):
         assert len(picked) < cfg.n_experts          # some expert is empty
 
 
+# ------------- the grouped kernel at made-up expert shapes and group sizes
+# (ISSUE 34: a tile's rows follow the group; the kernel's body is the parent's)
+
+def _expert_case(D, F, activation, quant, dtype=jnp.float32, E=16, k=2, seed=0):
+    """(cfg, one layer's seeded leaves) of E experts D x F."""
+    cfg = dataclasses.replace(get_config("toy-sparse-moe"), name="made-up-experts",
+                              dim=D, mlp_hidden=F, activation=activation,
+                              n_experts=E, experts_per_token=k)
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+
+    def leaf(key, i, o):
+        w = jax.random.normal(key, (E, i, o), jnp.float32) * i ** -0.5
+        return quantize_int8(w) if quant else w.astype(dtype)
+
+    lp = {"router": jax.random.normal(keys[0], (D, E), jnp.float32).astype(dtype),
+          "w_up": leaf(keys[1], D, F), "w_down": leaf(keys[2], F, D)}
+    if cfg.gated_mlp:
+        lp["w_gate"] = leaf(keys[3], D, F)
+    return cfg, lp
+
+
+WIDTH_CASES = {
+    # D, F, activation: D 128 hands the up blocks over as [F, D] where F is no
+    # multiple of 128 (up_t), D 64 and an F of whole lanes as [D, F]
+    "gated-29x8": (64, 232, "silu"),
+    "gated-29x16": (64, 464, "silu"),
+    "gated-29x16-up-as-F-by-D": (128, 464, "silu"),
+    "gated-gelu-three-lane-tiles": (64, 384, "gelu"),
+    "relu2-29x8-up-as-F-by-D": (128, 232, "relu2"),
+    "relu2-29x16": (64, 464, "relu2"),
+    "relu2-two-lane-tiles-up-as-D-by-F": (128, 256, "relu2"),
+}
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["plain", "int8"])
+@pytest.mark.parametrize("case", list(WIDTH_CASES))
+def test_grouped_kernel_at_odd_inner_widths_equals_dense_moe(case, quant):
+    """Inner widths that are no multiple of a lane tile (29 x 8, 29 x 16) and
+    ones that are, gated and two-matrix, the up blocks either way round:
+    against every expert evaluated for every token, a masked row zeros."""
+    D, F, activation = WIDTH_CASES[case]
+    cfg, lp = _expert_case(D, F, activation, quant)
+    assert (F % 128 != 0 and D % 128 == 0) == ("F-by-D" in case)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 5, D), jnp.float32)
+    mask = jnp.ones((2, 5)).at[0, 3].set(0)
+    want = np.array(dense_moe(cfg, lp, x))
+    want[0, 3] = 0
+    got, n_read = jax.jit(lambda lp, x, m: grouped_moe(cfg, lp, x, m))(lp, x, mask)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-5)
+    assert 1 <= int(n_read) <= cfg.n_experts
+
+
+@pytest.mark.parametrize("tokens,tiles", [(24, 2), (40, 3)], ids=["two-tiles", "three-tiles"])
+@pytest.mark.parametrize("activation", ["silu", "relu2"])
+def test_an_expert_over_consecutive_tiles_of_bf16_rows(tokens, tiles, activation):
+    """Every token picks expert 3 (a rigged router column), so its group spans
+    ``tiles`` consecutive 16-row tiles that name the same blocks; int8 experts
+    under bf16 rows, as served."""
+    from ai_agent_kubectl_tpu.parallel.moe import _group_tile
+    D, F = 128, 232
+    cfg, lp = _expert_case(D, F, activation, True, jnp.bfloat16)
+    lp["router"] = lp["router"].at[0, 3].set(40.0)
+    x = jax.random.normal(jax.random.PRNGKey(2), (1, tokens, D), jnp.float32)
+    x = x.at[:, :, 0].set(2.0).astype(jnp.bfloat16)
+    assert _group_tile(tokens * 2, cfg.n_experts) == 16 and -(-tokens // 16) == tiles
+    got, n_read = jax.jit(lambda lp, x: grouped_moe(cfg, lp, x))(lp, x)
+    want = np.asarray(dense_moe(cfg, lp, x), np.float32)
+    np.testing.assert_allclose(np.asarray(got, np.float32), want, rtol=0.03,
+                               atol=0.03 * np.abs(want).max())
+    assert 2 <= int(n_read) <= cfg.n_experts
+
+
+@pytest.mark.parametrize("activation", ["silu", "relu2"])
+@pytest.mark.parametrize("rows", [16, 32, 48, 80])
+def test_a_rows_result_does_not_depend_on_its_tiles_rows(rows, activation, monkeypatch):
+    """What ISSUE 34 changes is how many rows share a tile, never a row's
+    arithmetic: the same 48 tokens through tiles of 16 (the parent's choice
+    for this call), 32, 48 and 80 rows differ by under 2e-6 of the largest result
+    (on the chip the MXU sums a row's products in one order whatever the
+    tile; the CPU's blocked dot may not)."""
+    from ai_agent_kubectl_tpu.parallel import moe
+    cfg, lp = _expert_case(128, 232, activation, True)
+    x = jax.random.normal(jax.random.PRNGKey(4), (3, 16, 128), jnp.float32)
+    mask = jnp.ones((3, 16)).at[2, 9:].set(0)
+    run = lambda: np.asarray(jax.jit(                                   # noqa: E731
+        lambda lp, x, m: grouped_moe(cfg, lp, x, m))(lp, x, mask)[0])
+    assert moe._group_tile(48 * 2, cfg.n_experts) == 16
+    parent = run()
+    monkeypatch.setattr(moe, "_GROUP_TILE_MIN", rows)
+    assert moe._group_tile(48 * 2, cfg.n_experts) == rows
+    assert np.abs(run() - parent).max() <= 2e-6 * np.abs(parent).max()
+
+
+@pytest.mark.parametrize("activation", ["silu", "relu2"])
+def test_a_pass_with_no_live_tile_returns_zeros(activation):
+    """Every row masked: no group has a row, no tile is live, no expert is read
+    and the result is zeros."""
+    cfg, lp = _expert_case(128, 232, activation, True)
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, 4, 128), jnp.float32)
+    got, n_read = jax.jit(lambda lp, x, m: grouped_moe(cfg, lp, x, m))(
+        lp, x, jnp.zeros((2, 4)))
+    assert int(n_read) == 0 and np.abs(np.asarray(got)).max() == 0
+
+
+@pytest.mark.parametrize("pairs,experts,rows,steps", [
+    (16 * 6, 128, 16, 97), (16 * 8, 128, 16, 129), (11 * 6, 128, 16, 67),   # decode passes
+    (512 * 6, 128, 32, 221), (512 * 8, 128, 48, 211),             # an eager 512-wide piece
+    (16 * 64 * 6, 128, 64, 223), (16 * 64 * 8, 128, 80, 229),    # the 64-wide window
+    (12, 16, 16, 13), (10 ** 6, 8, 256, 3915)])
+def test_a_tiles_rows_follow_the_group_and_the_grid_the_pairs(pairs, experts, rows, steps):
+    """What holds most groups whole (mean + its root, in whole sublane tiles),
+    from 16 at decode to 256: 24 rows an expert get 32-row tiles, not two of 16. The
+    grid is the most tiles the pairs can light and one dead one; no split of
+    the pairs over the experts lights more."""
+    from ai_agent_kubectl_tpu.parallel.moe import _grid_tiles, _group_tile
+    assert _group_tile(pairs, experts) == rows
+    assert _grid_tiles(pairs, rows, experts) == steps
+    r = np.random.default_rng(pairs)
+    for _ in range(50):
+        sizes = r.multinomial(pairs, r.dirichlet(np.full(experts, r.choice([0.05, 1, 20]))))
+        assert (-(-sizes // rows)).sum() < steps
+    spread = np.full(experts, pairs // experts)          # every group one row over whole tiles
+    spread[: pairs - spread.sum()] += 1
+    assert (-(-spread // rows)).sum() < steps
+
+
+@pytest.mark.parametrize("geometry", ["nemotron30b", "keye30b"])
+def test_the_kernel_timing_tool_rehearses_every_form(geometry, tmp_path):
+    """tools/time_grouped_kernel.py (ISSUE 34) takes the kernel apart by patching
+    what its body calls while it is traced; its rehearsal runs every form on tiny
+    shapes through the interpreter, so a change to the kernel's signature or to the
+    tile rule shows here and not on the chip."""
+    import importlib.util
+    import json
+    import pathlib
+    path = pathlib.Path(__file__).resolve().parents[1] / "tools" / "time_grouped_kernel.py"
+    spec = importlib.util.spec_from_file_location("time_grouped_kernel", path)
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    out = tmp_path / "lines.jsonl"
+    dot = jnp.dot
+    assert tool.main(["--rehearse", "--geometry", geometry, "--shape", "decode",
+                      "eager-512-tail", "--form", *tool._FORMS, "--out", str(out)]) == 0
+    assert jnp.dot is dot                        # the stream form's patch is undone
+    lines = [json.loads(x) for x in out.read_text().splitlines()]
+    assert [x.get("error") for x in lines] == [None] * (2 * len(tool._FORMS))
+    assert all(x["rehearsal"] and x["us_per_call"] > 0 for x in lines)
+    decode = lines[0]
+    assert decode["tile_rows"] == 16 and decode["resolved"]["grid_steps"] == decode["grid_steps"]
+    assert 1 <= decode["live_experts"] <= decode["live_tiles"] < decode["grid_steps"]
+
+
 def test_the_path_is_chosen_by_one_static_rule():
     assert get_config("toy-sparse-moe").grouped_experts
     assert not get_config("toy-moe").grouped_experts
@@ -302,6 +454,10 @@ def test_a_second_ask_maps_the_cached_log_and_its_index_keys(force_ragged):
     assert sel["forward_passes"] > 0
     assert st["moe"]["layer_passes"] > 0
     assert 1 <= st["moe"]["experts_read"] / st["moe"]["layer_passes"] <= CFG.n_experts
+    # ISSUE 34: what the grouped kernel resolves from shapes, beside the counters
+    kern = st["moe"]["kernel"]
+    assert kern["decode"]["tile_rows"] == 16 and kern["decode"]["grid_steps"] >= 2
+    assert kern["widest_window"]["tile_rows"] >= kern["eager_piece"]["tile_rows"] >= 16
     regime = st["kv_pool"]
     assert regime["attention_regime"] == ("ragged" if force_ragged else "gather")
     assert regime["attention_selects_keys"]["index_topk"] == CFG.index_topk

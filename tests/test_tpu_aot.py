@@ -72,19 +72,34 @@ def _pallas_grids(jaxpr):
             yield from _pallas_grids(sub)
 
 
+def _pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation of a jaxpr, sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls(sub)
+
+
 def _ragged_kv_buffers(jaxpr):
     """The shape of the ragged kernel's K buffer ring (its first VMEM
     scratch) in every ``pallas_call`` of a jaxpr that has a DMA semaphore
-    among its scratch, sub-jaxprs included."""
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            n = eqn.params["grid_mapping"].num_scratch_operands
-            scratch = [v.aval for v in eqn.params["jaxpr"].invars[-n:]] \
-                if n else []
-            if any("dma" in str(a).lower() for a in scratch):
-                yield tuple(scratch[0].shape)
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _ragged_kv_buffers(sub)
+    among its scratch."""
+    for eqn in _pallas_calls(jaxpr):
+        n = eqn.params["grid_mapping"].num_scratch_operands
+        scratch = [v.aval for v in eqn.params["jaxpr"].invars[-n:]] if n else []
+        if any("dma" in str(a).lower() for a in scratch):
+            yield tuple(scratch[0].shape)
+
+
+def _grouped_kernel_calls(jaxpr):
+    """(rows of a tile, grid steps, scoped VMEM asked for) of every call of
+    the grouped expert kernel in a jaxpr."""
+    for eqn in _pallas_calls(jaxpr):
+        if eqn.params["name"] == "grouped_expert_ffn":
+            gm = eqn.params["grid_mapping"]
+            yield (gm.block_mappings[0].block_shape[0].block_size, gm.grid[0],
+                   eqn.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes)
 
 
 @pytest.mark.parametrize("H,KV", [(32, 8), (8, 2)],
@@ -494,6 +509,13 @@ def test_selecting_forward_compiles_at_published_widths_on_v5e(one_chip, W,
     # [64, 4, 128]
     assert set(_ragged_kv_buffers(traced.jaxpr.jaxpr)) == {
         (4, 8, 256, 128) if W == 1 else (4, 4, 64, 4, 128)}
+    # ISSUE 34: the grouped expert kernel's tile rows and grid follow the
+    # call's shapes (a decode pass: 16 rows, 129 steps for 128 pairs; the
+    # 512-wide window of 16 slots: 256 rows); its scoped VMEM is stated: an
+    # expert's three matrices as stored, double-buffered (9 MiB), converted
+    # (9 MiB) and a tile's rows, in 64 MiB of a v5e core's 128
+    assert set(_grouped_kernel_calls(traced.jaxpr.jaxpr)) == {
+        (16, 129, 64 * 2 ** 20) if W == 1 else (256, 384, 64 * 2 ** 20)}
     compiled = traced.lower().compile()
     hlo = compiled.as_text()
     # the grouped expert kernel, and the ragged kernel in the dense branch
@@ -592,10 +614,17 @@ def test_patterned_forward_compiles_at_published_widths_on_v5e(one_chip, B, W,
                        write_mask=wmask, block_tables=tables, q_lens=q_lens,
                        logits_at=jnp.maximum(q_lens, 1) - 1)
 
-    compiled = jax.jit(step, donate_argnums=(3,)).trace(
+    traced = jax.jit(step, donate_argnums=(3,)).trace(
         params, arg((B, W), jnp.int32), arg((B, W), jnp.int32), cache,
         arg((B, W), jnp.bool_), arg((B, pages), jnp.int32),
-        arg((B,), jnp.int32)).lower().compile()
+        arg((B,), jnp.int32))
+    # ISSUE 34: a tile's rows and the grid from the call's shapes (24 rows
+    # an expert in an eager piece get 32-row tiles, not two of 16); the
+    # kernel's scoped VMEM is stated: two matrices as stored, double-
+    # buffered (19 MiB), converted (19 MiB) and a tile's rows, in 64 MiB
+    assert set(_grouped_kernel_calls(traced.jaxpr.jaxpr)) == {
+        {1: (16, 97), 64: (64, 223), 512: (32, 221)}[W] + (64 * 2 ** 20,)}
+    compiled = traced.lower().compile()
     hlo = compiled.as_text()
     assert hlo.count('custom_call_target="tpu_custom_call"') == 2
     assert not _results_of_size(hlo, {128 * 2688 * 1856}), "an expert stack moved"
