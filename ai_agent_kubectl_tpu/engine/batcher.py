@@ -2170,8 +2170,7 @@ class BatchedJaxEngine(JaxEngine):
                     snapshot_fn=self._state_snapshot_dev,
                     restore_fn=self._state_restore_dev,
                     zero_fn=self._state_zero_dev,
-                    region=lambda name, **meta: self._spans.sched.region(
-                        "admit", name, **meta))
+                    region=self._spans.sched.child)
                 if prev_state is not None:
                     self._state.carry_counters(prev_state)
             self._pool = BlockPool(self._pool_n_blocks, self.kv_pool_page)
@@ -2187,7 +2186,8 @@ class BatchedJaxEngine(JaxEngine):
                                       offload_fn=self._pool_offload_block,
                                       onload_fn=self._pool_onload_block,
                                       faults=self.faults,
-                                      state_store=self._state)
+                                      state_store=self._state,
+                                      region=self._spans.sched.child)
                            if self.radix_cache else None)
             # Cumulative counters survive the rebuild — the /metrics
             # delta-mirror must never see totals go backwards.
@@ -2488,17 +2488,19 @@ class BatchedJaxEngine(JaxEngine):
     def _run_arm(self, slot_idx: int, n_prompt: int, first_tok_d,
                  temperature: float, max_toks: int, seed: int,
                  ngen0: int) -> None:
-        (self._tok_d, self._pos_d, self._temps_d, self._active_d,
-         self._ngen_d, self._budget_d, self._seeds_d) = self._pool_arm_fn(
-            self._tok_d, self._pos_d, self._temps_d, self._active_d,
-            self._ngen_d, self._budget_d, self._seeds_d,
-            jnp.asarray(slot_idx, jnp.int32),
-            jnp.asarray(n_prompt, jnp.int32), first_tok_d,
-            jnp.asarray(temperature, jnp.float32),
-            jnp.asarray(max_toks, jnp.int32),
-            jnp.asarray(seed, jnp.int32),
-            jnp.asarray(ngen0, jnp.int32),
-        )
+        with self._spans.sched.child("arm", slot=slot_idx):
+            (self._tok_d, self._pos_d, self._temps_d, self._active_d,
+             self._ngen_d, self._budget_d,
+             self._seeds_d) = self._pool_arm_fn(
+                self._tok_d, self._pos_d, self._temps_d, self._active_d,
+                self._ngen_d, self._budget_d, self._seeds_d,
+                jnp.asarray(slot_idx, jnp.int32),
+                jnp.asarray(n_prompt, jnp.int32), first_tok_d,
+                jnp.asarray(temperature, jnp.float32),
+                jnp.asarray(max_toks, jnp.int32),
+                jnp.asarray(seed, jnp.int32),
+                jnp.asarray(ngen0, jnp.int32),
+            )
 
     @property
     def _pool_cow_fn(self):
@@ -2556,9 +2558,10 @@ class BatchedJaxEngine(JaxEngine):
         return fn
 
     def _run_cow(self, src: int, dst: int, rows: int) -> None:
-        self._cache = self._pool_cow_fn(
-            self._cache, jnp.asarray(src, jnp.int32),
-            jnp.asarray(dst, jnp.int32), jnp.asarray(rows, jnp.int32))
+        with self._spans.sched.child("cow", rows=rows):
+            self._cache = self._pool_cow_fn(
+                self._cache, jnp.asarray(src, jnp.int32),
+                jnp.asarray(dst, jnp.int32), jnp.asarray(rows, jnp.int32))
 
     # ------------------------------- host-tier block transfer (ISSUE 20)
 
@@ -2609,7 +2612,8 @@ class BatchedJaxEngine(JaxEngine):
         ``_run_cow``) + fresh blocks. Returns (blocks, m)."""
         return map_prefix(self._pool, self._radix, ids,
                           match_all=match_all, cow=self._run_cow,
-                          state=self._state, slot=slot_idx)
+                          state=self._state, slot=slot_idx,
+                          region=self._spans.sched.child)
 
     def _pool_prefill_to_cuts(self, slot_idx: int, ids: List[int],
                               start: int, stop: int, n_prompt: int):
@@ -2652,16 +2656,25 @@ class BatchedJaxEngine(JaxEngine):
             # its dense per-slot scratch really does gather kv_limit.
             kv_limit = (self._S_alloc if self._use_ragged
                         else self._pool_kv_limit(offset + bucket))
-            tokens = np.zeros((1, bucket), np.int32)
-            tokens[0, :L] = ids[offset:offset + L]
-            positions = np.broadcast_to(
-                offset + np.arange(bucket), (1, bucket)).astype(np.int32)
-            mask = (np.arange(bucket) < L)[None, :].astype(np.float32)
-            logits, self._cache = self._get_pool_prefill_fn(
-                bucket, kv_limit)(
-                self.params, jnp.asarray(tokens), jnp.asarray(positions),
-                self._cache, jnp.asarray(mask), tables_d,
-                np.int32(slot_idx))
+            # One eager piece (sched/eager_prefill): staging through the
+            # program call's return; ``call_ms`` is the jitted call alone,
+            # the launch and whatever it blocks on.
+            with self._spans.sched.child(
+                    "eager_prefill", totals=("tokens", "call_ms"),
+                    slot=slot_idx, tokens=L, bucket=bucket) as piece:
+                tokens = np.zeros((1, bucket), np.int32)
+                tokens[0, :L] = ids[offset:offset + L]
+                positions = np.broadcast_to(
+                    offset + np.arange(bucket), (1, bucket)).astype(np.int32)
+                mask = (np.arange(bucket) < L)[None, :].astype(np.float32)
+                fn = self._get_pool_prefill_fn(bucket, kv_limit)
+                tokens_d, positions_d, mask_d = (
+                    jnp.asarray(x) for x in (tokens, positions, mask))
+                t_call = time.monotonic()
+                logits, self._cache = fn(
+                    self.params, tokens_d, positions_d, self._cache, mask_d,
+                    tables_d, np.int32(slot_idx))
+                piece["call_ms"] = (time.monotonic() - t_call) * 1000.0
             offset += L
             self._selection_counts["forward_passes"] += 1
             self._eager_passes += 1
@@ -4012,6 +4025,7 @@ class BatchedJaxEngine(JaxEngine):
                     self.faults.check_scheduler_die()
                 self._last_progress = time.monotonic()
                 self._spans.note_slots(self._slots)
+                self._spans.note_pipe(self._inflight)
                 # Bisection probation: the parked half is exonerated when
                 # the probe group fully drains (no slots, no pipeline) —
                 # or earlier, after PROBATION_CLEAN_CHUNKS clean chunks in
@@ -5461,7 +5475,7 @@ class BatchedJaxEngine(JaxEngine):
         marks a dispatch that found nothing left to run."""
         with self._spans.sched.region("dispatch", "dispatch",
                                       chunk=self._chunks_dispatched + 1,
-                                      slots=0) as entry:
+                                      slots=0, pipe_empty_ms=0.0) as entry:
             self._dispatch_chunk_in_span(entry)
 
     def _dispatch_chunk_in_span(self, entry: dict) -> None:
@@ -5620,6 +5634,7 @@ class BatchedJaxEngine(JaxEngine):
             self._spec_steps if spec else self.chunk_len)
         self._inflight.append(("chunk", packed_d, snapshot, ct, spec,
                                self._chunks_dispatched))
+        entry["pipe_empty_ms"] = self._spans.note_pipe(self._inflight)
         for i in staged:
             # This chunk carries slot i's prologue: its stage_wait ends
             # where this dispatch began, behind the chunks already queued.
@@ -5735,6 +5750,7 @@ class BatchedJaxEngine(JaxEngine):
                         self._bill_waste(self.chunk_len, snap)
             self._chunks_pruned += 1
             self._spans.sched.mark("prune", chunk=entry[5])
+            self._spans.note_pipe(self._inflight)
 
     def _consume_oldest(self) -> None:
         self._last_progress = time.monotonic()
@@ -5767,6 +5783,8 @@ class BatchedJaxEngine(JaxEngine):
         with self._spans.sched.region("fetch_wait", "fetch",
                                       chunk=chunk_no) as fetched:
             buf = self._fetch(packed_d)
+        # the pipe holds this chunk until its buffer is here
+        self._spans.note_pipe(self._inflight)
         # sched/fetch's two stamps, measured once: the same interval is
         # the chunk_fetch_seconds sample.
         self._fetch_samples.append(fetched["ms"] / 1000.0)

@@ -547,8 +547,7 @@ class FakeChunkedEngine:
         prev_store, prev_state = self._host_store, self._state
         self._state = (StateStore(
             self.state_snapshots, self.batch_size,
-            region=lambda name, **meta: self._spans.sched.region(
-                "admit", name, **meta))
+            region=self._spans.sched.child)
             if self.state_snapshots > 0 else None)
         if prev_state is not None and self._state is not None:
             self._state.carry_counters(prev_state)
@@ -564,7 +563,8 @@ class FakeChunkedEngine:
                                   max_blocks=self.radix_lru_blocks,
                                   host_store=self._host_store,
                                   faults=self.faults,
-                                  state_store=self._state)
+                                  state_store=self._state,
+                                  region=self._spans.sched.child)
                        if self.radix_cache else None)
         if prev_pool is not None:
             self._pool.carry_counters(prev_pool)
@@ -599,7 +599,8 @@ class FakeChunkedEngine:
         (the copy itself is device work)."""
         return map_prefix(self._pool, self._radix, ids,
                           match_all=match_all, cow=None,
-                          state=self._state, slot=slot_idx)
+                          state=self._state, slot=slot_idx,
+                          region=self._spans.sched.child)
 
     def _pool_seat(self, req: _FakeReq, g: int, slot_idx: int = 0) -> tuple:
         """Allocate one seating's chain: the replay basis is
@@ -1030,6 +1031,7 @@ class FakeChunkedEngine:
         if self.faults is not None:
             self.faults.check_scheduler_die()
         self._spans.note_slots(self._slots)
+        self._spans.note_pipe(self._inflight)
         self._sweep()
         if (self._parked and not self._inflight
                 and all(s is None for s in self._slots)):
@@ -1455,7 +1457,7 @@ class FakeChunkedEngine:
         are byte-identical by construction here too."""
         with self._spans.sched.region("dispatch", "dispatch",
                                       chunk=self._chunks_dispatched + 1,
-                                      slots=0) as entry:
+                                      slots=0, pipe_empty_ms=0.0) as entry:
             self._dispatch_chunk_in_span(entry)
 
     def _dispatch_chunk_in_span(self, entry: dict) -> None:
@@ -1596,6 +1598,7 @@ class FakeChunkedEngine:
         self._chunks_dispatched += 1
         self._inflight.append(("chunk", packed, snapshot, C, spec,
                                self._chunks_dispatched))
+        entry["pipe_empty_ms"] = self._spans.note_pipe(self._inflight)
         for snap in snapshot:
             # A slot still in its prefill phase rides THIS chunk (ragged
             # admission); a no-op for every other slot.
@@ -1675,6 +1678,7 @@ class FakeChunkedEngine:
                         self._bill_waste(self.chunk_len, snap)
             self._chunks_pruned += 1
             self._spans.sched.mark("prune", chunk=entry[5])
+            self._spans.note_pipe(self._inflight)
 
     def _consume_oldest(self) -> None:
         _, packed, snapshot, ct, is_spec, chunk_no = self._inflight.pop(0)
@@ -1686,6 +1690,8 @@ class FakeChunkedEngine:
         with self._spans.sched.region("fetch_wait", "fetch",
                                       chunk=chunk_no) as fetched:
             self._fetches += 1      # the single fetch per chunk
+        # the pipe holds this chunk until its buffer is here
+        self._spans.note_pipe(self._inflight)
         with self._spans.sched.region("consume", "consume", chunk=chunk_no,
                                       fetch_ms=fetched["ms"],
                                       pipe=len(self._inflight)) as consumed:

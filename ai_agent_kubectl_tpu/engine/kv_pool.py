@@ -71,6 +71,15 @@ def pages_for(n_tokens: int, page: int) -> int:
     return -(-max(0, n_tokens) // page)
 
 
+def no_region(name: str, totals: Sequence[str] = (), **fields):
+    """The ``region=`` callback of an owner that keeps no spans. An engine
+    passes ``obs.trace.SchedSpans.child``: ``region(name, totals, **meta)``
+    is a context manager around one named part of the scheduler thread's
+    work (``sched/<name>``) that yields the entry's field dict; the fields
+    ``totals`` names run as totals in ``/health.spans``."""
+    return contextlib.nullcontext(fields)
+
+
 def alloc_with_evict(pool: "BlockPool", radix, n: int):
     """Allocate ``n`` blocks with radix-eviction backpressure: cached
     blocks are reclaimable capacity, so allocation only truly fails once
@@ -89,7 +98,7 @@ def alloc_with_evict(pool: "BlockPool", radix, n: int):
 
 def map_prefix(pool: "BlockPool", radix, ids: Sequence[int], *,
                match_all: bool = False, cow=None, state=None,
-               slot: Optional[int] = None):
+               slot: Optional[int] = None, region=no_region):
     """Build one slot's block chain for token sequence ``ids`` — THE
     shared admission path (run verbatim by the jax batcher and the fake
     engine, so refcount behaviour can never diverge between them):
@@ -112,14 +121,18 @@ def map_prefix(pool: "BlockPool", radix, ids: Sequence[int], *,
     count of tokens whose KV is already valid (prefill starts at m).
     Admissions pass match_all=False — the LAST token must run forward
     for its logits; replays pass True (the carry token is forced).
-    Raises PoolExhausted with every ref released on failure."""
+    Raises PoolExhausted with every ref released on failure. ``region``
+    times the match (``sched/radix_match``)."""
     page = pool.page
     blocks: List[int] = []
     m = 0
     mr = None
     if radix is not None:
         upto = len(ids) if match_all else max(0, len(ids) - 1)
-        mr = radix.match(ids[:upto])
+        with region("radix_match", totals=("tokens", "matched"),
+                    tokens=upto) as matched:
+            mr = radix.match(ids[:upto])
+            matched["matched"] = mr.n_tokens
         blocks = list(mr.blocks)
         m = len(blocks) * page
         if mr.tail_block is not None:
@@ -516,7 +529,7 @@ class StateStore:
 
     def __init__(self, capacity: int, n_slots: int, state_bytes: int = 0, *,
                  snapshot_fn=None, restore_fn=None, zero_fn=None,
-                 region=None):
+                 region=no_region):
         if capacity < 1:
             raise ValueError("the state store needs at least 1 snapshot")
         self.capacity = int(capacity)
@@ -555,10 +568,6 @@ class StateStore:
 
     # ------------------------------------------------------------ slots
 
-    def _timed(self, name: str, **meta):
-        return (self._region(name, **meta) if self._region is not None
-                else contextlib.nullcontext())
-
     def _pin(self, slot: int, handle: int) -> None:
         self._pins[handle] = self._pins.get(handle, 0) + 1
         self._slot_pins[slot].append(handle)
@@ -594,7 +603,7 @@ class StateStore:
             self.restore_depth_peak,
             sum(1 for t in self._last.values() if t >= stamp))
         self._last[handle] = next(self._clock)
-        with self._timed("state_restore", slot=slot, tokens=usable):
+        with self._region("state_restore", slot=slot, tokens=usable):
             if self._restore_fn is not None:
                 self._restore_fn(slot, handle)
         self.restores += 1
@@ -618,7 +627,7 @@ class StateStore:
         self._last[handle] = next(self._clock)
         self._pin(slot, handle)
         self._slot_pending[slot].append((edge, handle))
-        with self._timed("state_snapshot", slot=slot, tokens=edge):
+        with self._region("state_snapshot", slot=slot, tokens=edge):
             if self._snapshot_fn is not None:
                 self._snapshot_fn(slot, handle)
         self.snapshots_taken += 1

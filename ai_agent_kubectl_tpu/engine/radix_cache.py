@@ -72,7 +72,8 @@ from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
-from .kv_pool import BlockPool, HostBlockStore, alloc_with_evict
+from .kv_pool import (BlockPool, HostBlockStore, alloc_with_evict,
+                      no_region)
 
 
 @dataclasses.dataclass
@@ -130,8 +131,11 @@ class RadixCache:
     def __init__(self, pool: BlockPool, *, max_blocks: int = 0,
                  host_store: Optional[HostBlockStore] = None,
                  offload_fn=None, onload_fn=None, faults=None,
-                 state_store=None):
+                 state_store=None, region=no_region):
         self.pool = pool
+        # Times the eviction walk and the insert (kv_pool.no_region says
+        # what an engine passes): sched/radix_evict, sched/radix_insert.
+        self._region = region
         # Recurrent-state snapshots (ISSUE 33; kv_pool.StateStore): set
         # for a model that keeps a state, and then a match is usable only
         # as deep as the last snapshot on its path.
@@ -347,6 +351,15 @@ class RadixCache:
             raise ValueError(
                 f"chain of {len(ids)} tokens needs "
                 f"{pages_needed(len(ids), page)} blocks, got {len(blocks)}")
+        with self._region("radix_insert", totals=("blocks",),
+                          tokens=len(ids)) as inserted:
+            taken = inserted["blocks"] = self._insert(ids, blocks)
+            self.enforce_budget()
+        return taken
+
+    def _insert(self, ids: Sequence[int], blocks: Sequence[int]) -> int:
+        """``insert`` before its budget walk: the blocks newly cached."""
+        page = self.page
         node, taken = self._root, 0
         stamp = next(self._clock)
         node.last = stamp
@@ -392,7 +405,6 @@ class RadixCache:
                 node.tail = (t_tokens, b, rows)
                 taken += 1
         self.insertions_total += 1
-        self.enforce_budget()
         return taken
 
     def _drop_tail(self, node: _Node) -> None:
@@ -465,13 +477,23 @@ class RadixCache:
         With a host store attached, "evict" means demote-to-host where
         the page qualifies (cold, unmapped, store has or can make room)
         and plain drop otherwise — either way the device block frees.
-        Returns False once nothing evictable remains."""
+        Returns False once nothing evictable remains. A walk that really
+        runs is one ``sched/radix_evict``: the units it collected and the
+        device blocks it freed run as totals beside its time."""
         if done():
             return True
+        with self._region("radix_evict",
+                          totals=("nodes_walked", "blocks_freed")) as walk:
+            return self._walk_and_evict(done, walk)
+
+    def _walk_and_evict(self, done, walk: dict) -> bool:
+        """``_evict_until`` past its first ``done()``; ``walk`` is the
+        region's entry."""
         heap = [(last, kind, i, node)
                 for i, (last, kind, node) in enumerate(self._evictables())]
         heapq.heapify(heap)
         seq = len(heap)                  # tie-break for lazy pushes
+        walk["nodes_walked"], walk["blocks_freed"] = seq, 0
         while not done():
             while heap:
                 _, kind, _, node = heapq.heappop(heap)
@@ -481,6 +503,7 @@ class RadixCache:
                     if node.tail is None or self._protected(node):
                         continue
                     self._drop_tail(node)
+                    walk["blocks_freed"] += 1
                     if self._demotable(node):
                         # The tail was the node's last blocker — its
                         # block itself is evictable now.
@@ -494,6 +517,7 @@ class RadixCache:
                     parent = node.parent
                     if not self._demote_node(node):
                         self._drop_node(node)
+                    walk["blocks_freed"] += 1
                     if self._demotable(parent):
                         heapq.heappush(heap,
                                        (parent.last, 1, seq, parent))

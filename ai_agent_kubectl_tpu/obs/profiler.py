@@ -20,6 +20,8 @@ import shutil
 import tempfile
 import time
 
+from .trace import spans_growth
+
 logger = logging.getLogger(__name__)
 
 #: traces are tens of MB each; keep the newest few and reap the rest.
@@ -48,15 +50,19 @@ def _reap_old(base: str) -> None:
             shutil.rmtree(os.path.join(base, d), ignore_errors=True)
 
 
-async def capture(seconds: float) -> dict:
+async def capture(seconds: float, probe=None) -> dict:
     """Run one profiler capture; returns ``{"trace_dir", "seconds",
-    "clock_start", "clock_stop"}``. The two clock entries are
+    "clock_start", "clock_stop", "spans"}``. The two clock entries are
     ``[time.monotonic(), time.time_ns()]`` pairs taken as the capture
     starts and stops: the trace's own axis is the wall clock in
     nanoseconds, every span of this program (flight recorder,
     /debug/chunks) is stamped with ``time.monotonic()``, and the pair is
     what places one on the other by hand. The scheduler's sched/* spans
     need no such arithmetic: they are TraceAnnotations inside the trace.
+    ``spans`` is what ``probe()`` (the engine's ``/health.spans``) grew by
+    between the two stamps: the same regions' counts and ms inside the
+    capture, where the profiler's Python tracer hooks every call, to hold
+    against a whole run's (None without a probe).
 
     The caller serializes captures (one at a time) — jax.profiler has one
     global trace session and a second start_trace would raise.
@@ -74,10 +80,20 @@ async def capture(seconds: float) -> dict:
                 seconds, trace_dir)
     jax.profiler.start_trace(trace_dir)
     clock_start = [time.monotonic(), time.time_ns()]
+    before = probe() if probe is not None else None
     try:
         await asyncio.sleep(seconds)
     finally:
+        after = probe() if probe is not None else None
         clock_stop = [time.monotonic(), time.time_ns()]
         jax.profiler.stop_trace()
+    spans = spans_growth(before, after)
+    if spans:
+        # the server's log keeps what the caller may not: the scheduler
+        # thread's regions inside the capture (count, ms)
+        logger.info("profiler: sched regions inside the capture: %s", {
+            name: (e.get("count"), e.get("total_ms"))
+            for name, e in spans.items() if name.startswith("sched/")})
     return {"trace_dir": trace_dir, "seconds": seconds,
-            "clock_start": clock_start, "clock_stop": clock_stop}
+            "clock_start": clock_start, "clock_stop": clock_stop,
+            "spans": spans}

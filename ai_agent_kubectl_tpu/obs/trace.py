@@ -25,7 +25,9 @@ Propagation is two-legged:
 The engine's side of the timeline is stamped where the work happens:
 ``RequestSpans`` (one per queued request) writes the engine phases as the
 scheduler crosses each boundary, ``SchedSpans`` records the scheduler
-thread's own per-chunk intervals into the ``/debug/chunks`` ring, and
+thread's own per-chunk intervals, their named parts (the radix walk, an
+eager prefill piece) and the time the chunk pipe stood empty into the
+``/debug/chunks`` ring and ``/health.spans``, and
 ``SpanStats`` keeps the cumulative per-name totals ``/health.spans``
 serves. Both engines with a scheduler (engine/batcher.py, engine/fake.py)
 hold the three in one ``EngineSpans``, so the names cannot drift apart.
@@ -38,7 +40,7 @@ import re
 import threading
 import time
 import uuid
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Any, Callable, Deque, Dict, Iterable, List, Optional, Tuple
 
 #: phase names admitted into the ``request_phase_seconds`` histogram.
@@ -290,6 +292,25 @@ class SpanStats:
                     for name, e in self._by_name.items()}
 
 
+def spans_growth(before: Optional[Dict[str, Dict[str, float]]],
+                 after: Optional[Dict[str, Dict[str, float]]]
+                 ) -> Optional[Dict[str, Dict[str, float]]]:
+    """What two ``/health.spans`` sections (``EngineSpans.health``) differ
+    by, entry by entry: counts, totals and seconds grow; ``max_ms`` is kept
+    only where it grew, since a larger one was set inside the interval."""
+    if not after:
+        return None
+    out: Dict[str, Dict[str, float]] = {}
+    for name, entry in after.items():
+        was = (before or {}).get(name, {})
+        grown = {k: round(v - was.get(k, 0), 6) for k, v in entry.items()
+                 if k != "max_ms"}
+        if entry.get("max_ms", 0) > was.get("max_ms", 0):
+            grown["max_ms"] = entry["max_ms"]
+        out[name] = grown
+    return out
+
+
 class RequestSpans:
     """One request's engine phases, stamped by the scheduler at the moment
     each boundary is crossed and written (to the request's ``Trace``, if
@@ -454,7 +475,23 @@ class SchedSpans:
     passes ``jax.profiler.TraceAnnotation`` there, so a ``/debug/profile``
     capture holds the same spans on the scheduler thread's line, on the
     device ops' clock; a no-op outside a capture. This module stays
-    jax-free."""
+    jax-free.
+
+    ``child(name)`` names a part of whatever region runs it (the radix
+    walk inside ``sched/admit``): the same ring entry, totals and
+    annotation, under the enclosing region's chunk number, but the time
+    stays charged to the enclosing state, so the partition is untouched
+    and a parent's ``total_ms`` less its children's is the host work that
+    still has no name. Only while a scheduler runs: the start-up warm-up
+    calls the same code from another thread and is nobody's child.
+
+    Beside the partition the thread keeps a second one, ``starved``: the
+    same seconds by state, counted only while the chunk pipe holds no
+    chunk program (``note_pipe``) and a slot is live (``note_live``) or an
+    admission is in hand (state ``admit``). It is the host's view of a
+    drained pipe, in every run and with no profiler attached: it learns
+    that the device is done with a chunk only where it looks (the
+    scheduler's loop, a dispatch, a fetch, the end of a child region)."""
 
     def __init__(self, stats: SpanStats, log: Deque[dict],
                  annotate: Optional[Callable[..., Any]] = None):
@@ -463,16 +500,37 @@ class SchedSpans:
         self._annotate = annotate
         self._lock = threading.Lock()   # snapshot() reads from other threads
         self._state_s = dict.fromkeys(SCHED_STATES, 0.0)
+        self._starved_s = dict.fromkeys(SCHED_STATES, 0.0)
         self._state: Optional[str] = None
         self._t_state = 0.0
         self._elapsed = 0.0             # of scheduler threads that ended
         self._t_start = 0.0
+        self._chunk: Optional[int] = None   # of the innermost open region
+        self._pipe_chunks = 0           # chunk programs in flight
+        self._live = False              # any slot seated
+        self._starved_at_empty = 0.0    # starved total when the pipe drained
+        # ``EngineSpans`` sets it: counts the pipe anew. A chunk leaves the
+        # pipe when the DEVICE is done with it, which no call site marks,
+        # so every child region's end looks (an admission is many).
+        self.pipe_probe: Optional[Callable[[], int]] = None
+
+    def _starving(self) -> bool:
+        return self._pipe_chunks == 0 and (self._live
+                                           or self._state == "admit")
+
+    def _settle(self, now: float) -> None:
+        """Charge the open state up to ``now`` (caller holds the lock)."""
+        if self._state is not None:
+            dt = now - self._t_state
+            self._state_s[self._state] += dt
+            if self._starving():
+                self._starved_s[self._state] += dt
+            self._t_state = now
 
     def _switch(self, state: Optional[str], now: float) -> Optional[str]:
         with self._lock:
             prev = self._state
-            if prev is not None:
-                self._state_s[prev] += now - self._t_state
+            self._settle(now)
             self._state, self._t_state = state, now
         return prev
 
@@ -488,14 +546,44 @@ class SchedSpans:
             with self._lock:
                 self._elapsed += now - self._t_start
 
+    def note_live(self, live: bool) -> None:
+        """Whether any slot is seated (``EngineSpans.note_slots``)."""
+        if live != self._live:
+            with self._lock:
+                self._settle(time.monotonic())
+                self._live = live
+
+    def note_pipe(self, chunks: int) -> float:
+        """The number of chunk programs in flight, told wherever it
+        changes. Returns, when the pipe goes from empty to holding one,
+        the ms it stood empty with work at hand (the growth of the
+        starved total since it drained), else 0."""
+        if chunks == self._pipe_chunks:
+            return 0.0
+        with self._lock:
+            self._settle(time.monotonic())
+            was, self._pipe_chunks = self._pipe_chunks, chunks
+            total = sum(self._starved_s.values())
+            if chunks == 0:
+                self._starved_at_empty = total
+            elif was == 0:
+                return (total - self._starved_at_empty) * 1000.0
+        return 0.0
+
     @contextmanager
-    def region(self, state: str, name: Optional[str] = None,
-               chunk: Optional[int] = None, **fields):
+    def region(self, state: Optional[str], name: Optional[str] = None,
+               chunk: Optional[int] = None, totals: Iterable[str] = (),
+               **fields):
         """Yields the ring entry's field dict: the caller adds what it
         learns inside the interval (``n_alive`` after a fetch) and, once
-        the region has closed, reads its length back as ``["ms"]``."""
+        the region has closed, reads its length back as ``["ms"]``.
+        ``totals`` names fields that also run as totals beside
+        ``total_ms`` in ``SpanStats``: ``tokens`` as ``tokens_total``,
+        ``call_ms`` as ``call_total_ms``. ``state`` None (``child``)
+        keeps the enclosing state."""
         t0, wall0 = time.monotonic(), time.time()
-        prev = self._switch(state, t0)
+        prev = self._switch(state, t0) if state is not None else None
+        outer, self._chunk = self._chunk, chunk
         try:
             if name is not None and self._annotate is not None:
                 stat = {} if chunk is None else {"chunk": chunk}
@@ -505,15 +593,29 @@ class SchedSpans:
                 yield fields
         finally:
             t1 = time.monotonic()
-            # Back to the enclosing state — but only while a scheduler is
-            # running: a region entered before start() charges nothing.
-            self._switch(prev, t1)
+            self._chunk = outer
+            if state is not None:
+                # Back to the enclosing state — but only while a scheduler
+                # is running: a region entered before start() charges
+                # nothing.
+                self._switch(prev, t1)
+            elif self.pipe_probe is not None:
+                self.note_pipe(self.pipe_probe())
             fields["ms"] = (t1 - t0) * 1000.0
             if name is not None:
-                self._stats.note(f"sched/{name}", fields["ms"])
+                self._stats.note(f"sched/{name}", fields["ms"], **{
+                    (k[:-3] + "_total_ms" if k.endswith("_ms")
+                     else k + "_total"): fields.get(k, 0) for k in totals})
                 self._log.append({
                     "t": wall0, "t0": t0, "t1": t1, "event": name,
                     "span": f"sched/{name}", "chunk": chunk, **fields})
+
+    def child(self, name: str, totals: Iterable[str] = (), **fields):
+        """A named part of the region that runs it (see the class)."""
+        if self._state is None:
+            return nullcontext(fields)
+        return self.region(None, name, chunk=self._chunk, totals=totals,
+                           **fields)
 
     def mark(self, event: str, **fields) -> None:
         """A point in the ring (prune, health trip): no interval."""
@@ -534,6 +636,26 @@ class SchedSpans:
         out["elapsed"] = round(elapsed, 6)
         return out
 
+    def starved(self) -> Dict[str, float]:
+        """The part of each state's seconds spent with the pipe empty
+        and work at hand, and ``total``, their sum."""
+        now = time.monotonic()
+        with self._lock:
+            parts = dict(self._starved_s)
+            if self._state is not None and self._starving():
+                parts[self._state] += now - self._t_state
+        out = {k: round(v, 6) for k, v in parts.items()}
+        out["total"] = round(sum(parts.values()), 6)
+        return out
+
+
+def _on_device(buf: Any) -> bool:
+    """Is the device still at work on this dispatched buffer? A
+    ``jax.Array`` says so itself, without blocking; the fake engine's
+    buffer is numpy, done when the scheduler fetches it."""
+    ready = getattr(buf, "is_ready", None)
+    return ready is None or not ready()
+
 
 class EngineSpans:
     """Everything an engine with a scheduler keeps for its spans, so the
@@ -546,6 +668,8 @@ class EngineSpans:
                  annotate: Optional[Callable[..., Any]] = None):
         self.stats = SpanStats()
         self.sched = SchedSpans(self.stats, log, annotate)
+        self.sched.pipe_probe = self._pipe_chunks
+        self._inflight: List[tuple] = []
         self.slot_free_since: Optional[float] = time.monotonic()
 
     def of(self, req) -> RequestSpans:
@@ -569,6 +693,21 @@ class EngineSpans:
                 self.slot_free_since = time.monotonic()
         else:
             self.slot_free_since = None
+        self.sched.note_live(any(s is not None for s in slots))
+
+    def note_pipe(self, inflight: List[tuple]) -> float:
+        """Call wherever the engine's in-flight queue changes, and once
+        per scheduler iteration for paths that clear it wholesale: the
+        pipe is the chunk programs among its entries that the device is
+        not done with (``SchedSpans.note_pipe``). The list is the
+        engine's own, changed in place: the scheduler's child regions
+        count it again as they end."""
+        self._inflight = inflight
+        return self.sched.note_pipe(self._pipe_chunks())
+
+    def _pipe_chunks(self) -> int:
+        return sum(1 for e in self._inflight
+                   if e[0] == "chunk" and _on_device(e[1]))
 
     def health(self, chunks_consumed: int) -> Dict[str, Any]:
         """The ``/health.spans`` section: cumulative ``{count, total_ms,
@@ -577,6 +716,7 @@ class EngineSpans:
         out: Dict[str, Any] = self.stats.snapshot()
         out["sched_thread_s"] = dict(self.sched.snapshot(),
                                      chunks_consumed=chunks_consumed)
+        out["sched_starved_s"] = self.sched.starved()
         return out
 
 

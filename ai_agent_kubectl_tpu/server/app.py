@@ -1222,8 +1222,10 @@ async def handle_debug_profile(request: web.Request) -> web.Response:
     if request.app.get("_tracing"):
         return _json_error(409, "a trace is already in progress")
     request.app["_tracing"] = True
+    sph = getattr(request.app["service"].engine, "spans_health", None)
     try:
-        result = await obs_profiler.capture(seconds)
+        result = await obs_profiler.capture(
+            seconds, probe=sph if callable(sph) else None)
     except Exception as e:  # pragma: no cover - backend-dependent
         logger.exception("trace capture failed")
         return _json_error(500, f"trace capture failed: {e}")

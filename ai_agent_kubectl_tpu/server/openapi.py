@@ -197,7 +197,9 @@ def build_openapi() -> Dict:
                                        "clock_stop: [time.monotonic(), "
                                        "time.time_ns()] pairs that place "
                                        "flight-recorder spans on the "
-                                       "trace's axis)"},
+                                       "trace's axis; spans: what "
+                                       "/health.spans grew by between "
+                                       "them)"},
                 "400": _err("seconds not a number"),
                 "401": auth_err,
                 "403": _err("Invalid or missing X-Debug-Token (only when "

@@ -204,6 +204,171 @@ def test_sched_spans_partition_and_ring():
     assert stats.snapshot()["sched/dispatch"]["count"] == 2
 
 
+def test_child_region_keeps_the_enclosing_state_and_its_chunk():
+    """A child names a part of the region that runs it: ring entry,
+    totals and annotation under the parent's chunk number, the thread's
+    time still charged to the parent's state, the six states still
+    summing to elapsed; outside a running scheduler it records nothing."""
+    from collections import deque
+
+    ring, stats = deque(maxlen=16), SpanStats()
+    calls = []
+
+    class Ann:
+        def __init__(self, name, **kw):
+            calls.append((name, kw))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    sched = SchedSpans(stats, ring, annotate=Ann)
+    with sched.child("radix_match", totals=("tokens",), tokens=5) as e:
+        e["tokens"] = 7                      # the warm-up's: nobody's child
+    assert not ring and not calls and stats.snapshot() == {}
+    sched.start()
+    with sched.region("admit", "admit", chunk=4):
+        time.sleep(0.005)
+        with sched.child("radix_match", totals=("tokens", "matched"),
+                         tokens=40) as e:
+            time.sleep(0.01)
+            e["matched"] = 32
+            with sched.child("radix_evict", totals=("blocks_freed",)) as w:
+                time.sleep(0.005)
+                w["blocks_freed"] = 3
+        with sched.child("eager_prefill", totals=("tokens", "call_ms"),
+                         tokens=9) as e:
+            e["call_ms"] = 2.5
+    with sched.region("consume", "consume", chunk=4):
+        with sched.child("radix_insert", totals=("blocks",)) as e:
+            time.sleep(0.005)
+            e["blocks"] = 2
+    sched.stop()
+    snap = sched.snapshot()
+    assert sum(snap[s] for s in SCHED_STATES) == pytest.approx(
+        snap["elapsed"], rel=1e-3)
+    # the children's time is the parents': admit holds the match and its
+    # walk, consume the insert, and no state but those two and "other"
+    assert snap["admit"] >= 0.019 and snap["consume"] >= 0.004
+    assert snap["dispatch"] == snap["fetch_wait"] == snap["idle"] == 0.0
+    got = stats.snapshot()
+    assert got["sched/radix_match"] == {
+        "count": 1, "total_ms": got["sched/radix_match"]["total_ms"],
+        "max_ms": got["sched/radix_match"]["max_ms"],
+        "tokens_total": 40, "matched_total": 32}
+    assert got["sched/radix_evict"]["blocks_freed_total"] == 3
+    assert got["sched/eager_prefill"]["tokens_total"] == 9
+    assert got["sched/eager_prefill"]["call_total_ms"] == 2.5
+    assert got["sched/radix_insert"]["blocks_total"] == 2
+    assert (got["sched/radix_evict"]["total_ms"]
+            <= got["sched/radix_match"]["total_ms"]
+            <= got["sched/admit"]["total_ms"])
+    assert got["sched/radix_insert"]["total_ms"] <= \
+        got["sched/consume"]["total_ms"]
+    by = {e["event"]: e for e in ring}
+    # a child closes before its parent: it is in the ring first
+    assert [e["event"] for e in ring] == [
+        "radix_evict", "radix_match", "eager_prefill", "admit",
+        "radix_insert", "consume"]
+    for kid, parent in (("radix_evict", "radix_match"),
+                        ("radix_match", "admit"), ("eager_prefill", "admit"),
+                        ("radix_insert", "consume")):
+        assert by[parent]["t0"] <= by[kid]["t0"] <= by[kid]["t1"] \
+            <= by[parent]["t1"], (kid, parent)
+        assert by[kid]["chunk"] == 4 and by[kid]["span"] == f"sched/{kid}"
+    assert by["radix_match"]["tokens"] == 40 and by["radix_match"]["matched"] == 32
+    assert ("sched/radix_evict", {"chunk": 4}) in calls
+    assert calls[0] == ("sched/admit", {"chunk": 4})
+
+
+def test_starved_partition_counts_only_an_empty_pipe_with_work_at_hand():
+    from collections import deque
+
+    sched = SchedSpans(SpanStats(), deque(maxlen=8))
+    sched.start()
+    sched.note_live(True)
+    assert sched.note_pipe(1) < 1.0        # ms: empty for no time at all
+    with sched.region("consume", "consume", chunk=1):
+        time.sleep(0.01)                   # a chunk is in flight: not starved
+    assert sched.starved()["total"] < 0.002
+    sched.note_pipe(0)
+    with sched.region("consume", "consume", chunk=1):
+        time.sleep(0.01)
+    with sched.region("admit", "admit", chunk=2):
+        time.sleep(0.01)
+    with sched.region("dispatch", "dispatch", chunk=2) as entry:
+        time.sleep(0.005)
+        entry["pipe_empty_ms"] = sched.note_pipe(1)
+        time.sleep(0.005)                  # the launch is out: not starved
+    starved = sched.starved()
+    assert starved["consume"] >= 0.009 and starved["admit"] >= 0.009
+    assert 0.004 <= starved["dispatch"] < 0.009
+    assert sum(starved[s] for s in SCHED_STATES) == pytest.approx(
+        starved["total"], abs=1e-5)
+    # how long the pipe stood empty when that chunk was issued
+    assert entry["pipe_empty_ms"] == pytest.approx(
+        starved["total"] * 1e3, rel=0.1)
+    # nothing live and nothing in hand: an idle engine is not starved
+    sched.note_pipe(0)
+    sched.note_live(False)
+    with sched.region("idle"):
+        time.sleep(0.01)
+    assert sched.starved()["idle"] == 0.0
+    with sched.region("admit", "admit", chunk=3):   # an admission in hand
+        time.sleep(0.005)
+    sched.stop()
+    after = sched.starved()
+    assert after["admit"] >= starved["admit"] + 0.004
+    snap = sched.snapshot()
+    for s in SCHED_STATES:
+        assert after[s] <= snap[s] + 1e-6, s
+
+
+def test_a_chunk_the_device_is_done_with_has_left_the_pipe():
+    """The pipe is what the DEVICE still works on: a dispatched chunk
+    whose buffer is ready has left it though nobody fetched it, and the
+    thread learns so where it looks, the end of a child region among
+    them (a long admission is many)."""
+    from collections import deque
+
+    from ai_agent_kubectl_tpu.obs.trace import EngineSpans
+
+    class Buf:
+        done = False
+
+        def is_ready(self):
+            return self.done
+
+    spans = EngineSpans(deque(maxlen=16))
+    inflight = [("first", Buf()), ("chunk", Buf())]
+    inflight[0][1].done = True             # not a chunk program: no matter
+    spans.sched.start()
+    spans.note_slots([object(), None])
+    spans.note_pipe(inflight)
+    with spans.sched.region("admit", "admit", chunk=2):
+        with spans.sched.child("eager_prefill"):
+            time.sleep(0.005)
+        assert spans.sched.starved()["total"] < 0.001   # the device is at it
+        inflight[1][1].done = True         # ... and done, unseen so far
+        with spans.sched.child("eager_prefill"):
+            time.sleep(0.005)              # its end looks at the pipe
+        time.sleep(0.01)
+    starved = spans.sched.starved()
+    assert 0.009 <= starved["admit"] < 0.015
+    with spans.sched.region("dispatch", "dispatch", chunk=2) as entry:
+        inflight.append(("chunk", Buf()))
+        entry["pipe_empty_ms"] = spans.note_pipe(inflight)
+    assert entry["pipe_empty_ms"] == pytest.approx(
+        spans.sched.starved()["total"] * 1e3, rel=0.05)
+    assert entry["pipe_empty_ms"] >= 9.0
+    time.sleep(0.005)                      # one chunk at work: not starved
+    spans.sched.stop()
+    assert spans.sched.starved()["total"] == pytest.approx(
+        entry["pipe_empty_ms"] / 1e3, rel=0.05)
+
+
 # ----------------------------------------------- fake engine, both routes
 
 @pytest.mark.parametrize("route", ROUTES)
@@ -350,6 +515,147 @@ async def test_sched_thread_parts_sum_to_elapsed_and_health_counts():
         await client.close()
 
 
+def _nested_in_a_parent(ring, kid) -> bool:
+    return any(p["event"] in ("admit", "dispatch", "consume")
+               and p["t0"] <= kid["t0"] and kid["t1"] <= p["t1"]
+               and p["chunk"] == kid["chunk"] for p in ring)
+
+
+async def test_radix_regions_on_the_fake_under_a_tiny_lru_budget():
+    """The radix regions come from the shared code (radix_cache.py,
+    kv_pool.map_prefix), so the fake has them: a match an admission, an
+    insert a release, and ``sched/radix_evict`` only once a walk really
+    ran, with the blocks it freed as a total beside its time."""
+    eng = _fake(kv_pool_page=4, radix_lru_blocks=6)
+    await eng.start()
+    try:
+        def prompt(i):
+            return " ".join(f"w{i}x{j}" for j in range(9))
+
+        # 9 prompt tokens + 5 emitted: 4 blocks of 4 under a budget of 6
+        await eng.generate(prompt(0), max_tokens=6)
+        spans, radix = eng.spans_health(), eng._radix.stats()
+        assert "sched/radix_evict" not in spans       # no walk ran yet
+        assert radix["evicted_blocks"] == 0
+        assert spans["sched/radix_insert"]["count"] == 1
+        assert spans["sched/radix_insert"]["blocks_total"] == \
+            radix["cached_blocks"] == 4
+        n = 7
+        for i in range(1, n):
+            await eng.generate(prompt(i), max_tokens=6)
+        await eng.generate(prompt(n - 1), max_tokens=6)   # a re-ask: a hit
+        spans, radix = eng.spans_health(), eng._radix.stats()
+        walk = spans["sched/radix_evict"]
+        assert 0 < walk["count"] <= n
+        assert walk["blocks_freed_total"] == radix["evicted_blocks"] > 0
+        assert walk["nodes_walked_total"] >= walk["count"]
+        match, ins = spans["sched/radix_match"], spans["sched/radix_insert"]
+        assert match["count"] == spans["sched/admit"]["count"] == n + 1
+        assert match["tokens_total"] == \
+            radix["hit_tokens"] + radix["miss_tokens"]
+        assert match["matched_total"] == radix["hit_tokens"] > 0
+        assert ins["count"] == radix["insertions"] == n + 1
+        # a child's time is inside its parent's
+        assert walk["total_ms"] <= ins["total_ms"]
+        assert match["total_ms"] <= spans["sched/admit"]["total_ms"]
+        assert ins["total_ms"] <= (spans["sched/consume"]["total_ms"]
+                                   + spans["sched/admit"]["total_ms"])
+        assert walk["max_ms"] <= ins["max_ms"]
+        ring = list(eng._chunk_log)
+        kids = [e for e in ring if e["event"].startswith("radix_")]
+        assert {e["event"] for e in kids} == {
+            "radix_match", "radix_insert", "radix_evict"}
+        for kid in kids:
+            assert kid["span"] == f"sched/{kid['event']}"
+            assert _nested_in_a_parent(ring, kid), kid
+        freed = [e for e in kids if e["event"] == "radix_evict"]
+        assert all(e["blocks_freed"] >= 1 and e["nodes_walked"] >= 1
+                   for e in freed)
+        # the six states still sum to the thread's elapsed time
+        sched = spans["sched_thread_s"]
+        assert sum(sched[s] for s in SCHED_STATES) == pytest.approx(
+            sched["elapsed"], rel=0.02)
+    finally:
+        await eng.stop()
+
+
+async def test_starved_seconds_on_the_fake_follow_the_pipe():
+    """``sched_starved_s``: nothing is charged while a chunk program is
+    in flight; with a pipe one deep every consume and dispatch finds it
+    empty with a slot live, and an admission that finds it so is charged
+    its whole length; each dispatch's ring entry says how long the pipe
+    had stood empty when it was issued."""
+    inj = FaultInjector()
+    inj.set("chunk", "delay", 0.003)
+    eng = _fake(batch_size=2, faults=inj, chunk_pipe_depth=3,
+                stream_fn=lambda _p: [9] * 80 + [2])
+    await eng.start()
+    try:
+        seen = []
+        async for _ in eng.generate_stream("long runner", max_tokens=60):
+            seen.append(eng.spans_health()["sched_starved_s"])
+        # the first admission found an idle engine: in hand, pipe empty
+        assert seen[0]["admit"] > 0 and seen[0]["dispatch"] > 0
+        # from the second chunk to the one before the last the pipe held
+        # a chunk all the time: not one more microsecond
+        assert len(seen) > 10
+        assert seen[2] == seen[-3]
+        for snap in seen:
+            assert sum(snap[s] for s in SCHED_STATES) == pytest.approx(
+                snap["total"], abs=1e-5)
+    finally:
+        await eng.stop()
+
+    eng = _fake(batch_size=2, faults=inj, chunk_pipe_depth=1,
+                stream_fn=lambda _p: [9] * 400 + [2])
+    nap = 0.004
+    mapped = eng._pool_map_prefix
+
+    def slow_map(*a, **kw):
+        time.sleep(nap)                   # an admission that takes a while
+        return mapped(*a, **kw)
+
+    eng._pool_map_prefix = slow_map
+    await eng.start()
+    try:
+        runner = asyncio.ensure_future(
+            eng.generate("long runner", max_tokens=200))
+        await asyncio.sleep(0.05)
+        grew = []
+        for phase in range(6):
+            # ticks alternate dispatch and consume at depth 1: an admission
+            # behind a dispatch finds a chunk in flight, one behind a
+            # consume finds none; walk both phases
+            before = eng.spans_health()["sched_starved_s"]["admit"]
+            for _ in range(phase):
+                await asyncio.sleep(0)
+            await eng.generate(f"late joiner {phase}", max_tokens=2)
+            grew.append(
+                eng.spans_health()["sched_starved_s"]["admit"] - before)
+        spans = eng.spans_health()
+        starved, thread = spans["sched_starved_s"], spans["sched_thread_s"]
+        # an admission is charged whole or not at all
+        assert any(g >= nap * 0.9 for g in grew), grew
+        assert all(g >= nap * 0.9 or g < 0.001 for g in grew), grew
+        assert starved["consume"] > 0 and starved["dispatch"] > 0
+        assert starved["fetch_wait"] == 0.0     # the chunk is in the pipe
+        for s in SCHED_STATES:
+            assert starved[s] <= thread[s] + 1e-4, s
+        assert sum(starved[s] for s in SCHED_STATES) == pytest.approx(
+            starved["total"], abs=1e-5)
+        disp = [e for e in eng._chunk_log if e["event"] == "dispatch"]
+        assert disp and all(e["pipe_empty_ms"] > 0 for e in disp
+                            if e["slots"])
+        # whatever was charged was charged before some dispatch refilled
+        # the pipe: the ring's entries cannot exceed the total
+        assert sum(e["pipe_empty_ms"] for e in disp) <= \
+            starved["total"] * 1e3 + 0.01
+        runner.cancel()
+    finally:
+        inj.clear()
+        await eng.stop()
+
+
 # ------------------------------------------------- toy JAX engine (CPU)
 
 def _bench_run():
@@ -439,10 +745,66 @@ async def test_jax_span_tree_both_routes_and_the_four_event_messages():
         await client.close()
 
 
+async def test_jax_eager_pieces_are_counted_and_timed_one_for_one():
+    """Every eager prefill piece the scheduler thread runs is one
+    ``sched/eager_prefill``: its count follows the engine's own pass count,
+    its ``tokens_total`` the rows that were prefilled eagerly (prompt less
+    what the tree matched less what rode the chunk's window), and the
+    jitted call alone is a part of the piece."""
+    eng = _toy_jax()
+    client = await _client(eng)
+    try:
+        await _ask(client, ROUTES[1], "warm every shape first")
+        before = (await (await client.get("/health")).json())["spans"]
+        passes0, rows = eng._eager_passes, 0
+        for i in range(3):
+            detail = await _ask(client, ROUTES[i % 2], f"list pods of app {i}")
+            meta = next(s["meta"] for s in detail["spans"]
+                        if s["phase"] == "prefill")
+            rows += (meta["prompt_tokens"] - meta["prefix_hit_tokens"]
+                     - meta["staged_w"])
+        spans = (await (await client.get("/health")).json())["spans"]
+        piece, was = spans["sched/eager_prefill"], before["sched/eager_prefill"]
+        assert piece["count"] - was["count"] == eng._eager_passes - passes0 > 0
+        assert piece["tokens_total"] - was["tokens_total"] == rows > 0
+        assert 0 < piece["call_total_ms"] <= piece["total_ms"]
+        # the warm-up's pieces ran before the scheduler did: not its children
+        assert piece["count"] < eng._eager_passes
+        # children are inside sched/admit, and what they leave is its rest
+        kids = ("radix_match", "eager_prefill", "arm", "cow")
+        inside = sum(spans.get(f"sched/{k}", {}).get("total_ms", 0)
+                     for k in kids)
+        assert 0 < inside <= spans["sched/admit"]["total_ms"]
+        assert spans["sched/arm"]["count"] >= 3
+        assert spans["sched/radix_match"]["count"] == \
+            spans["sched/admit"]["count"]
+        sched = spans["sched_thread_s"]
+        assert sum(sched[s] for s in SCHED_STATES) == pytest.approx(
+            sched["elapsed"], rel=0.02)
+        starved = spans["sched_starved_s"]
+        assert sum(starved[s] for s in SCHED_STATES) == pytest.approx(
+            starved["total"], abs=1e-5)
+        assert 0 < starved["total"] <= sched["elapsed"]
+        ring = (await (await client.get("/debug/chunks?limit=500")).json()
+                )["events"]
+        pieces = [e for e in ring if e["event"] == "eager_prefill"]
+        assert pieces and all(
+            0 < e["call_ms"] <= e["ms"] and 0 < e["tokens"] <= e["bucket"]
+            and _nested_in_a_parent(ring, e) for e in pieces)
+        assert all("pipe_empty_ms" in e for e in ring
+                   if e["event"] == "dispatch")
+    finally:
+        await client.close()
+
+
 async def test_profile_capture_holds_sched_annotations_with_chunk_numbers():
     """A /debug/profile capture on the CPU backend holds the scheduler's
     spans as TraceAnnotations with a ``chunk`` stat, on the trace's own
-    clock; the summary carries the two clock pairs."""
+    clock; the summary carries the two clock pairs. The child regions are
+    there too, on the scheduler thread's line, nested inside the
+    ``sched/admit`` (or ``sched/consume``) that ran them, under its chunk
+    number; and the response says what ``/health.spans`` grew by between
+    the two stamps."""
     import glob
 
     import jax
@@ -460,13 +822,17 @@ async def test_profile_capture_holds_sched_annotations_with_chunk_numbers():
         assert m1 - m0 >= 1.0
         path = glob.glob(body["trace_dir"] + "/plugins/profile/*/*.xplane.pb")
         data = jax.profiler.ProfileData.from_file(path[0])
-        seen = {}
+        seen, lines = {}, {}
         for plane in data.planes:
             for line in plane.lines:
                 for ev in line.events:
                     if ev.name.startswith("sched/"):
+                        chunk = dict(ev.stats).get("chunk")
                         seen.setdefault(ev.name, []).append(
-                            (dict(ev.stats).get("chunk"), ev.duration_ns))
+                            (chunk, ev.duration_ns))
+                        lines.setdefault((plane.name, line.name), []).append(
+                            (ev.name, ev.start_ns,
+                             ev.start_ns + ev.duration_ns, chunk))
         assert {"sched/dispatch", "sched/fetch", "sched/consume",
                 "sched/admit"} <= set(seen), sorted(seen)
         for name in ("sched/dispatch", "sched/fetch"):
@@ -475,5 +841,29 @@ async def test_profile_capture_holds_sched_annotations_with_chunk_numbers():
         # the same chunk is dispatched, then fetched
         assert {c for c, _ in seen["sched/fetch"] if c is not None} & \
             {c for c, _ in seen["sched/dispatch"]}
+        # every region is on ONE line, the scheduler thread's, and a child
+        # lies inside the parent that ran it, under the parent's chunk
+        assert len(lines) == 1, sorted(lines)
+        (events,) = lines.values()
+        parents = {"sched/radix_match": "sched/admit",
+                   "sched/eager_prefill": "sched/admit",
+                   "sched/arm": "sched/admit",
+                   "sched/radix_insert": "sched/consume"}
+        assert set(parents) <= set(seen), sorted(seen)
+        for name, t0, t1, chunk in events:
+            if name in parents:
+                assert any(p == parents[name] and p0 <= t0 and t1 <= p1
+                           and pc == chunk
+                           for p, p0, p1, pc in events), (name, chunk)
+        # what /health.spans grew by between clock_start and clock_stop:
+        # the same regions, counted on the host's clock inside the capture
+        grew = body["spans"]
+        for name in parents:
+            assert grew[name]["count"] == len(seen[name]), name
+            assert grew[name]["total_ms"] > 0
+        assert grew["sched/eager_prefill"]["tokens_total"] > 0
+        assert grew["sched_thread_s"]["elapsed"] == pytest.approx(
+            m1 - m0, abs=0.1)
+        assert grew["decode"]["count"] == 1
     finally:
         await client.close()
