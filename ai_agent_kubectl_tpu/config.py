@@ -218,15 +218,19 @@ class ServiceConfig:
     # coarser granularity (TTFT under load). 16 came from an earlier chip
     # run (chunk 32 measured -15% throughput and 2x TTFT), not re-measured.
     chunk_len: int = 16                     # CHUNK_LEN
-    # Speculative decode chunks kept in flight ahead of the consumer.
-    # With device-side termination (the done mask in the decode chunk's
-    # carry — see DEVICE_TERMINATION) a deeper pipe no longer wastes a
-    # speculative chunk per finished request, so the default is 3: the
-    # consumer stays two fetches ahead of the device. Chosen on an
-    # earlier chip setup with a slow host↔device link; not re-measured
-    # on a local chip (ROADMAP S2). Depth 2 was the old default (and
-    # remains the right choice with DEVICE_TERMINATION=false).
-    chunk_pipe_depth: int = 3               # CHUNK_PIPE_DEPTH
+    # Decode chunks kept in flight: 2 = one running on the device, one
+    # queued behind it. The queued chunk covers the host's work between
+    # chunks (6-12 ms a chunk on a local v5e against a chunk period of
+    # 234-453 ms; the scheduler thread is 96-98% blocked in the fetch).
+    # A prompt staged after chunk N is consumed rides chunk N+depth, so
+    # every chunk beyond the second costs a request one chunk period
+    # before its first token and covers nothing there (both depths
+    # measured on every benchmark cell: PERF.md section 5, PR 36). 3 is
+    # for a chip behind a link whose round trip outlasts a chunk.
+    # Outputs do not depend on the depth; with DEVICE_TERMINATION=false
+    # each chunk beyond the first also wastes a chunk of steps per
+    # finished request.
+    chunk_pipe_depth: int = 2               # CHUNK_PIPE_DEPTH
     # Device-resident request termination: the decode chunk compares each
     # sampled token against the EOS set and the per-slot max_tokens
     # budget INSIDE the jitted scan, freezes finished slots mid-chunk
@@ -839,7 +843,7 @@ class ServiceConfig:
             max_new_tokens=_env_int("MAX_NEW_TOKENS", 128),
             decode_batch_size=_env_int("DECODE_BATCH_SIZE", 8),
             chunk_len=_env_int("CHUNK_LEN", 16),
-            chunk_pipe_depth=_env_int("CHUNK_PIPE_DEPTH", 3),
+            chunk_pipe_depth=_env_int("CHUNK_PIPE_DEPTH", 2),
             device_termination=_env_bool("DEVICE_TERMINATION", True),
             prefill_buckets=_env_str("PREFILL_BUCKETS", "64,128,256,512,1024"),
             temperature=_env_float("TEMPERATURE", 0.0),

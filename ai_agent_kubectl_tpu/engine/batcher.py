@@ -1040,7 +1040,7 @@ class BatchedJaxEngine(JaxEngine):
                  watchdog_secs: float = 120.0,
                  startup_grace_secs: float = 900.0,
                  admit_scratch_mb: int = 512,
-                 chunk_pipe_depth: int = 3,
+                 chunk_pipe_depth: int = 2,
                  max_queue_depth: int = 64,
                  device_termination: bool = True,
                  slot_health_check: bool = True,
@@ -1073,16 +1073,11 @@ class BatchedJaxEngine(JaxEngine):
             raise ValueError("chunk_pipe_depth must be >= 1")
         self.batch_size = batch_size
         self.chunk_len = chunk_len
-        # Speculative decode chunks kept in flight ahead of the consumer.
-        # Depth 2 hides one fetch round trip behind one chunk of compute;
-        # with DEVICE-side termination (the done mask in the chunk carry)
-        # deeper pipes stopped costing a wasted speculative chunk per tail
-        # — finished slots freeze inside the very chunk that finished them
-        # — so the default is now 3: the consumer stays two fetches ahead
-        # of the device. The depth was chosen on an earlier chip setup
-        # whose host↔device link was slow; not re-measured on a local
-        # chip (ROADMAP S2). A knob (CHUNK_PIPE_DEPTH) until a cell
-        # measures it.
+        # Decode chunks kept in flight: one running, one queued behind it
+        # (why 2, and when 3: config.py, chunk_pipe_depth). A prompt
+        # staged after chunk N is consumed rides chunk N+depth
+        # (_worker_loop), so the depth is chunk periods a request waits
+        # before its first token.
         # chunk_len=16 matches the bench-proven serving default
         # (config.py CHUNK_LEN).
         self.chunk_pipe_depth = chunk_pipe_depth
@@ -3993,10 +3988,10 @@ class BatchedJaxEngine(JaxEngine):
 
     def _worker_loop(self) -> None:
         # Chunk pipeline, CHUNK_PIPE_DEPTH deep (default 2): dispatch chunk
-        # N+1 (chained on device
-        # arrays) before pulling chunk N's tokens, so the host↔device round
-        # trip overlaps decode compute. The inflight queue carries two entry
-        # kinds, consumed strictly FIFO:
+        # N+1 (chained on device arrays) before pulling chunk N's tokens,
+        # so the fetch and the host's work between chunks overlap decode
+        # compute. The inflight queue carries two entry kinds, consumed
+        # strictly FIFO:
         #
         # - ("chunk", toks_d, snapshot): a decode chunk for all slots, with
         #   a snapshot of slot→request at dispatch time; a row whose slot
@@ -4009,10 +4004,12 @@ class BatchedJaxEngine(JaxEngine):
         #   overlaps the transfer.
         #
         # Admissions splice onto the *latest* device state, so a request
-        # admitted while two chunks are in flight starts decoding two
-        # chunks later — ordering stays linear because everything chains
-        # through donated buffers. Only "chunk" entries count against the
-        # pipeline depth; first-token entries are transfers, not compute.
+        # admitted with k chunks in flight starts decoding k chunks later
+        # (at the default depth: right after a consume, behind the one
+        # chunk that is running) — ordering stays linear because
+        # everything chains through donated buffers. Only "chunk" entries
+        # count against the pipeline depth; first-token entries are
+        # transfers, not compute.
         # (self._inflight is created at startup and deliberately NOT
         # reset here: a supervisor restart may already have queued
         # replayed admissions' first-token entries.)
@@ -4063,10 +4060,10 @@ class BatchedJaxEngine(JaxEngine):
                 if n_active > 0 and chunks_in_pipe < self.chunk_pipe_depth:
                     # Burst ramp: slots a chunk is dispatched without can't
                     # join it — a request that misses the first
-                    # CHUNK_PIPE_DEPTH speculative chunks (~0.5 s each on
-                    # 7B geometry) starts >1 s late even though the whole
-                    # burst arrived
-                    # within ~65 ms (round-4 probe). While admissions still
+                    # CHUNK_PIPE_DEPTH chunks (~0.23 s each on 7B geometry,
+                    # PERF.md section 5) starts that many chunk periods
+                    # late even though the whole burst arrived within
+                    # ~65 ms (round-4 probe). While admissions still
                     # show momentum (one landed within the last 30 ms) and
                     # free slots remain, nap briefly instead of dispatching
                     # chunk 1, so the rest of the burst boards it. Costs a

@@ -248,7 +248,7 @@ class FakeChunkedEngine:
     name = "fake-chunked"
 
     def __init__(self, *, batch_size: int = 4, chunk_len: int = 4,
-                 chunk_pipe_depth: int = 3, eos_ids=(2,),
+                 chunk_pipe_depth: int = 2, eos_ids=(2,),
                  device_termination: bool = True,
                  slot_health_check: bool = True,
                  quarantine_retry_budget: int = 1,

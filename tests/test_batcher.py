@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import pytest
 
 from ai_agent_kubectl_tpu.engine.batcher import BatchedJaxEngine
+from ai_agent_kubectl_tpu.engine.fake import FakeChunkedEngine
 from ai_agent_kubectl_tpu.engine.jax_engine import JaxEngine
 from ai_agent_kubectl_tpu.engine.protocol import GenerationTimeout
 from ai_agent_kubectl_tpu.models.config import get_config
@@ -209,13 +210,18 @@ def test_from_config_round_trips_scheduler_shape(monkeypatch):
     eng = BatchedJaxEngine.from_config(cfg)
     assert eng.chunk_len == 16
     assert eng.chunk_pipe_depth == 3
-    # Defaults: chunk 16 (earlier chip run, not re-measured) / depth 3 (device-side
-    # termination made the deeper pipe free on tails — ISSUE 4), with
-    # DEVICE_TERMINATION defaulting on.
+    # Defaults: chunk 16 (earlier chip run, not re-measured) / depth 2 (one
+    # chunk running, one queued: a third cost every request a chunk period
+    # before its first token and covered nothing — ISSUE 36), with
+    # DEVICE_TERMINATION defaulting on. The fake engine, which runs the
+    # same protocol, keeps the same default (the real engine's own is
+    # pinned by test_termination_pipeline's default_depth fixture).
     monkeypatch.delenv("CHUNK_LEN")
     monkeypatch.delenv("CHUNK_PIPE_DEPTH")
     dflt = ServiceConfig.from_env(env_file=None)
-    assert (dflt.chunk_len, dflt.chunk_pipe_depth) == (16, 3)
+    assert (dflt.chunk_len, dflt.chunk_pipe_depth) == (16, 2)
+    assert ServiceConfig().chunk_pipe_depth == 2
+    assert FakeChunkedEngine().chunk_pipe_depth == 2
     assert dflt.device_termination is True
     monkeypatch.setenv("DEVICE_TERMINATION", "false")
     off = ServiceConfig.from_env(env_file=None)

@@ -473,6 +473,61 @@ async def test_chunks_ahead_is_the_pipes_content_at_dispatch():
         await eng.stop()
 
 
+async def test_default_depth_puts_one_chunk_in_front_of_a_late_prompt():
+    """At the DEFAULT pipe depth (2: one chunk running, one queued) a
+    prompt that arrives while the pipe is full is staged right after the
+    consume the scheduler was in, finds ONE chunk in front of the chunk
+    that carries it, and has its first token with the SECOND chunk
+    consumed after it was staged (a depth of 3 made that the third)."""
+    inj = FaultInjector()
+    inj.set("chunk", "delay", 0.005)
+    eng = FakeChunkedEngine(batch_size=2, chunk_len=2, kv_pool=True,
+                            force_ragged=True, faults=inj,
+                            stream_fn=lambda _p: [9] * 60 + [2])
+    assert eng.chunk_pipe_depth == ServiceConfig().chunk_pipe_depth == 2
+    # The late prompt arrives during a consume that found the pipe full:
+    # the event wakes its submitter before the scheduler's next tick.
+    arrived = asyncio.Event()
+    consume = eng._consume_oldest
+
+    def consume_with_the_pipe_full():
+        full = len(eng._inflight) == eng.chunk_pipe_depth
+        consume()
+        if full and eng._chunks_consumed >= 3:
+            arrived.set()
+
+    eng._consume_oldest = consume_with_the_pipe_full
+    await eng.start()
+    try:
+        traces = [Trace(new_request_id()) for _ in range(2)]
+
+        async def run(t, prompt):
+            with use_trace(t):
+                return await eng.generate(prompt, max_tokens=40)
+
+        first = asyncio.ensure_future(run(traces[0], "long runner"))
+        await arrived.wait()
+        # awaited in this task, not as a new one: the prompt is in the
+        # queue before the scheduler's next tick
+        await run(traces[1], "late joiner")
+        await first
+        late = next(s for s in traces[1].to_dict()["spans"]
+                    if s["phase"] == "first_chunk")["meta"]
+        assert late["chunks_ahead"] == 1
+        ring = list(eng._chunk_log)
+        staged = max(i for i, e in enumerate(ring)
+                     if e["event"] == "admit" and e.get("requests"))
+        assert ring[staged]["chunk"] == late["chunk"]
+        consumed = [e["chunk"] for e in ring[staged:]
+                    if e["event"] == "consume"
+                    and e["chunk"] <= late["chunk"]]
+        assert consumed == [late["chunk"] - 1, late["chunk"]]
+        assert eng.spans_health()["first_chunk"]["chunks_ahead_total"] == 1
+    finally:
+        inj.clear()
+        await eng.stop()
+
+
 async def test_sched_thread_parts_sum_to_elapsed_and_health_counts():
     inj = FaultInjector()
     inj.set("chunk", "delay", 0.005)

@@ -3,7 +3,7 @@
 Covers the packed chunk-result contract (one fetch per chunk carrying
 tokens + done mask + live lengths + n_alive), the device-resident
 termination semantics (EOS mid-chunk, per-request max_tokens expiring
-mid-chunk, all-done-early chunks), the CHUNK_PIPE_DEPTH 1-vs-3 transcript
+mid-chunk, all-done-early chunks), the CHUNK_PIPE_DEPTH 1/2/3 transcript
 invariance, wasted-decode-step accounting, and deep-pipe client
 disconnects — on both the numpy FakeChunkedEngine (milliseconds, runs the
 same protocol.py consume code) and the real BatchedJaxEngine on CPU.
@@ -119,12 +119,14 @@ async def _run_fake(depth, device_termination=True):
     return out, stats
 
 
-async def test_fake_depth_sweep_same_transcripts():
-    """Depth 1 and depth 3 must serve byte-identical transcripts and
-    finish reasons over a ragged mix of EOS- and budget-terminated
-    requests (the CI depth-sweep smoke)."""
+@pytest.mark.parametrize("depth", [1, 2, 3])
+async def test_fake_depth_sweep_same_transcripts(depth):
+    """Every pipe depth (2 is the default) must serve the depth-1
+    transcripts and finish reasons byte for byte over a ragged mix of
+    EOS- and budget-terminated requests (the CI depth-sweep smoke): the
+    depth decides when a chunk is fetched, never what is served."""
     a, sa = await _run_fake(1)
-    b, sb = await _run_fake(3)
+    b, sb = await _run_fake(depth)
     assert a == b
     # The ragged mix must actually exercise both finish flavours.
     reasons = {r for _, _, r in a}
@@ -132,6 +134,7 @@ async def test_fake_depth_sweep_same_transcripts():
     # Done-mask accounting: no decode steps for already-finished slots.
     assert sa["wasted_decode_steps"] == 0
     assert sb["wasted_decode_steps"] == 0
+    assert sb["pipe_depth"] == depth
 
 
 async def test_fake_legacy_host_scan_same_transcripts_but_wastes():
@@ -230,31 +233,46 @@ ENGINE_KW = dict(dtype="float32", max_seq_len=128, prefill_buckets=(32,),
                  batch_size=3, chunk_len=4)
 
 
-@pytest.fixture(scope="module")
-def deep():
+def _started(**kw):
     eng = BatchedJaxEngine(get_config("toy-8m"), tokenizer=ByteTokenizer(),
-                           chunk_pipe_depth=3, **ENGINE_KW)
+                           **ENGINE_KW, **kw)
     asyncio.run(eng.start())
     yield eng
     asyncio.run(eng.stop())
+
+
+@pytest.fixture(scope="module")
+def deep():
+    yield from _started(chunk_pipe_depth=3)
 
 
 @pytest.fixture(scope="module")
 def shallow():
-    eng = BatchedJaxEngine(get_config("toy-8m"), tokenizer=ByteTokenizer(),
-                           chunk_pipe_depth=1, **ENGINE_KW)
-    asyncio.run(eng.start())
-    yield eng
-    asyncio.run(eng.stop())
+    yield from _started(chunk_pipe_depth=1)
 
 
-async def test_jax_depth_parity_ragged(deep, shallow):
-    """CHUNK_PIPE_DEPTH 1 vs 3 serve identical transcripts on the real
-    engine (greedy; budgets chosen to expire at every chunk phase)."""
+@pytest.fixture(scope="module")
+def default_depth():
+    yield from _started()
+
+
+@pytest.fixture(params=[("deep", 3), ("default_depth", 2)],
+                ids=lambda p: p[0])
+def piped(request):
+    name, depth = request.param
+    eng = request.getfixturevalue(name)
+    assert eng.chunk_pipe_depth == depth
+    return eng
+
+
+async def test_jax_depth_parity_ragged(piped, shallow):
+    """CHUNK_PIPE_DEPTH 3 and the default (2) serve the depth-1
+    transcripts on the real engine (greedy; budgets chosen to expire at
+    every chunk phase)."""
     prompts = [("list pods", 9), ("get events", 6), ("describe node x", 13),
                ("scale web to 3", 4)]
     for p, mt in prompts:
-        a = await deep.generate(p, max_tokens=mt, temperature=0.0)
+        a = await piped.generate(p, max_tokens=mt, temperature=0.0)
         b = await shallow.generate(p, max_tokens=mt, temperature=0.0)
         assert a.text == b.text
         assert a.completion_tokens == b.completion_tokens
