@@ -36,6 +36,7 @@ import collections
 import dataclasses
 import functools
 import logging
+import math
 import queue as _queue
 import threading
 import time
@@ -416,7 +417,8 @@ def make_termination_chunk_fn(forward_step, chunk_len: int, eos_ids,
         packed = finalize(pack_chunk(toks, done, ngen, jnp.sum(live),
                                      health=health,
                                      experts_read=cache.experts_read,
-                                     sel_rows=cache.sel_rows, xp=jnp))
+                                     sel_rows=_attention_rows(cache),
+                                     xp=jnp))
         out = (packed, tok, pos, cache, live, ngen)
         if grammar:
             out = out + (gs,)
@@ -642,7 +644,8 @@ def make_termination_chunk_fn(forward_step, chunk_len: int, eos_ids,
                                      health=health, drafted=drafted,
                                      accepted=accepted,
                                      experts_read=cache.experts_read,
-                                     sel_rows=cache.sel_rows, xp=jnp))
+                                     sel_rows=_attention_rows(cache),
+                                     xp=jnp))
         out = (packed, tok, pos, cache, live, ngen, dcache)
         if grammar:
             out = out + (gs,)
@@ -787,9 +790,18 @@ def make_termination_chunk_fn(forward_step, chunk_len: int, eos_ids,
 def _zero_counts(cache):
     """The chunk program counts what its own passes read and kept."""
     zeroed = {name: jnp.zeros_like(getattr(cache, name))
-              for name in ("experts_read", "sel_rows")
+              for name in ("experts_read", "sel_rows", "lat_rows")
               if getattr(cache, name) is not None}
     return dataclasses.replace(cache, **zeroed) if zeroed else cache
+
+
+def _attention_rows(cache):
+    """The two words the attention counted on the device, for the packed
+    chunk's two-word lane (engine/protocol.py's ``sel_rows``): a selecting
+    configuration's (keys before its decode queries, keys kept), or a
+    latent one's (decode queries, cached rows before them). No
+    configuration is both."""
+    return cache.sel_rows if cache.sel_rows is not None else cache.lat_rows
 
 
 def staged_suffix_len(suffix: int, buckets) -> int:
@@ -831,6 +843,34 @@ def state_refusal(model_cfg, regime: str, mesh_shape, spec_decode: bool
     return (f"{model_cfg.name} keeps a recurrent state (layer_pattern "
             f"{model_cfg.layer_pattern[:model_cfg.n_layers]!r}) and is not "
             f"served here: {why}")
+
+
+def latent_refusal(model_cfg, regime: str, mesh_shape, kv_quant: str,
+                   spec_decode: bool) -> Optional[str]:
+    """Why this engine cannot serve a latent-attention configuration
+    (``ModelConfig.latent``), or None. Its cache is ONE leaf of the block
+    pool with no head axis: the dense per-slot ladder has no such leaf,
+    an int8 pool quantizes K and V it does not have, a mesh would shard a
+    KV-head axis it lacks (parallel/sharding.py::pool_cache_specs), and
+    the speculative path has never run over it."""
+    if not model_cfg.latent:
+        return None
+    why = None
+    if regime == DENSE:
+        why = ("the dense per-slot KV ladder has no latent leaf "
+               "(KV_POOL=false, or a mesh axis the pool refuses)")
+    elif kv_quant:
+        why = f"KV_QUANT={kv_quant}: the latent rows are kept in bf16"
+    elif any(n > 1 for n in (mesh_shape or {}).values()):
+        why = (f"MESH_SHAPE {dict(mesh_shape)}: the latent leaf has no "
+               f"KV-head axis to shard and its projections no rule in "
+               f"parallel/sharding.py")
+    elif spec_decode:
+        why = "SPEC_DECODE: draft/verify windows are untried over latent rows"
+    if why is None:
+        return None
+    return (f"{model_cfg.name} keeps a latent cache (kv_lora_rank="
+            f"{model_cfg.kv_lora_rank}) and is not served here: {why}")
 
 
 def selection_refusal(model_cfg, regime: str, mesh_shape, kv_quant: str
@@ -1498,6 +1538,10 @@ class BatchedJaxEngine(JaxEngine):
             self.model_cfg, regime,
             dict(self.mesh.shape) if self.mesh is not None else None,
             self.spec_decode)
+        refusal = refusal or latent_refusal(
+            self.model_cfg, regime,
+            dict(self.mesh.shape) if self.mesh is not None else None,
+            self.kv_quant, self.spec_decode)
         if refusal:
             # A selecting configuration's index keys live in the block
             # pool's own leaf, a state-keeping one's state beside it: what
@@ -2280,6 +2324,17 @@ class BatchedJaxEngine(JaxEngine):
 
         def make() -> KVCache:
             lengths = jnp.zeros((n_blocks,), jnp.int32)
+            if cfg.latent:
+                # Latent attention: the pool IS the one compressed row a
+                # token a layer (a pair of tokens a leaf row: models/
+                # transformer.py::KVCache.lat); no K, no V.
+                return KVCache(
+                    k=None, v=None, lengths=lengths,
+                    lat=jnp.zeros((cfg.n_layers, n_blocks, shape[2] // 2,
+                                   2 * cfg.latent_row), dtype),
+                    lat_rows=jnp.zeros((2,), jnp.int32),
+                    experts_read=(jnp.zeros((), jnp.int32)
+                                  if counts_experts else None))
             if kv_quant == "int8":
                 from ..ops.quant import QuantKV
 
@@ -2529,8 +2584,8 @@ class BatchedJaxEngine(JaxEngine):
                 # (ROADMAP S7).
                 src = jax.lax.dynamic_index_in_dim(leaf, src_b, axis=1)
                 dst = jax.lax.dynamic_index_in_dim(leaf, dst_b, axis=1)
-                keep = (jnp.arange(page) < rows).reshape(
-                    (1, 1, page) + (1,) * (leaf.ndim - 3))
+                keep = (jnp.arange(leaf.shape[2]) < rows).reshape(
+                    (1, 1, leaf.shape[2]) + (1,) * (leaf.ndim - 3))
                 return jax.lax.dynamic_update_index_in_dim(
                     leaf, jnp.where(keep, src, dst), dst_b, axis=1)
 
@@ -2540,13 +2595,25 @@ class BatchedJaxEngine(JaxEngine):
                 def one(leaf):
                     return cp(leaf, src_b, dst_b, rows)
 
+                def pairs(leaf):
+                    # the latent leaf holds two tokens a row: the rows that
+                    # cover the first ``rows`` tokens (an odd count copies
+                    # its last token's partner too, which the new owner
+                    # writes before any query may read it). The block
+                    # form on one device too: cp_rows' scatter across the
+                    # layers made the compiler lay the leaf out layer-
+                    # innermost, a copy of the pool in and one out (5.0
+                    # GiB of temporaries; my chip run and AOT, PR 38).
+                    return cp_block(leaf, src_b, dst_b, (rows + 1) // 2)
+
                 with jax.named_scope("kv_splice"):
                     # every paged leaf: K, V and (a selecting
-                    # configuration's) index keys
+                    # configuration's) index keys; or the latent leaf
                     return dataclasses.replace(
                         cache, k=jax.tree.map(one, cache.k),
                         v=jax.tree.map(one, cache.v),
-                        ik=jax.tree.map(one, cache.ik))
+                        ik=jax.tree.map(one, cache.ik),
+                        lat=jax.tree.map(pairs, cache.lat))
 
             fn = jax.jit(cow, donate_argnums=(0,))
             self._pool_cow_jit = fn
@@ -2566,8 +2633,7 @@ class BatchedJaxEngine(JaxEngine):
         (QuantKV under int8 contributes q and s leaves), so onload can
         split the bytes back by the same walk — the checksum stamped
         over this buffer covers every quantized leaf too."""
-        leaves = jax.tree_util.tree_leaves(
-            (self._cache.k, self._cache.v, self._cache.ik))
+        leaves = jax.tree_util.tree_leaves(self._cache.paged())
         parts = [np.ascontiguousarray(jax.device_get(leaf[:, block]))
                  for leaf in leaves]
         return np.concatenate(
@@ -2579,8 +2645,7 @@ class BatchedJaxEngine(JaxEngine):
         leaf walk; placement (mesh sharding) is preserved by the .at
         scatter on the existing leaves."""
         flat = np.ascontiguousarray(np.asarray(data, dtype=np.uint8))
-        kv, treedef = jax.tree_util.tree_flatten(
-            (self._cache.k, self._cache.v, self._cache.ik))
+        kv, treedef = jax.tree_util.tree_flatten(self._cache.paged())
         off, out = 0, []
         for leaf in kv:
             sub = (leaf.shape[0],) + tuple(leaf.shape[2:])
@@ -2591,8 +2656,8 @@ class BatchedJaxEngine(JaxEngine):
             off += n
             out.append(leaf.at[:, block].set(
                 jnp.asarray(part, dtype=leaf.dtype)))
-        k, v, ik = jax.tree_util.tree_unflatten(treedef, out)
-        self._cache = dataclasses.replace(self._cache, k=k, v=v, ik=ik)
+        self._cache = self._cache.with_paged(
+            jax.tree_util.tree_unflatten(treedef, out))
 
     def _pool_alloc(self, n: int) -> Optional[List[int]]:
         """Allocate with radix-eviction backpressure (kv_pool.py helper,
@@ -2905,6 +2970,11 @@ class BatchedJaxEngine(JaxEngine):
         self._spans.note_slots(self._slots)
         prefill_meta = dict(prompt_tokens=n_prompt, prefix_hit_tokens=m,
                             staged_w=len(staged["ids"]) if staged else 0)
+        if self.model_cfg.latent:
+            # window rows m .. n_prompt-1, row t over its t + 1 rows
+            self._selection_counts["window_rows"] += n_prompt - m
+            self._selection_counts["index_rows_scanned"] += (
+                n_prompt * (n_prompt + 1) - m * (m + 1)) // 2
         if self.model_cfg.selects_keys:
             # window rows m .. n_prompt-1, each scanning the index keys
             # up to its own
@@ -2969,7 +3039,7 @@ class BatchedJaxEngine(JaxEngine):
             min(pages_for(b, self.kv_pool_page), self._pool_max_pages))
         row[:len(blocks)] = blocks
         self._pool_prefill_span(row, [0] * b, 0)
-        if cfg.selects_keys or cfg.keeps_state:
+        if cfg.selects_keys or cfg.keeps_state or cfg.latent:
             # A selecting configuration is served for prompts far past
             # the widest bucket: their heads are prefilled eagerly, piece
             # by piece, and the last piece of a head may be any bucket
@@ -3135,8 +3205,13 @@ class BatchedJaxEngine(JaxEngine):
 
         cfg = self.model_cfg
         tp = self.mesh.shape["model"] if self.mesh is not None else 1
-        shape = (self._pool_max_pages, self.kv_pool_page,
-                 cfg.n_heads // tp, cfg.n_kv_heads // tp, cfg.head_dim,
+        heads = (cfg.n_heads // tp, cfg.n_kv_heads // tp, cfg.head_dim)
+        if cfg.latent:
+            # one key row for all heads; the kernel's query is
+            # ops/ragged_attention.py::latent_query's
+            heads = (cfg.n_heads, 1,
+                     cfg.kv_lora_rank + 4 * cfg.qk_rope_head_dim)
+        shape = (self._pool_max_pages, self.kv_pool_page, *heads,
                  self.spec_draft_k + 1 if self._spec_live else 1,
                  jnp.dtype(self.dtype).itemsize)
         return (pages_per_step(*shape),
@@ -3182,6 +3257,11 @@ class BatchedJaxEngine(JaxEngine):
         cfg, wide = self.model_cfg, self.prefill_buckets[-1]
         return {"experts_read": self._moe_experts_read,
                 "layer_passes": self._moe_layer_passes,
+                # a chip's share (ISSUE 38): the experts this tree holds,
+                # the first of them, and how many the router scores
+                "experts_held": cfg.n_experts,
+                "first_expert": cfg.first_expert,
+                "router_width": cfg.experts_scored,
                 # what the kernel resolves from a call's shapes (ISSUE 34)
                 "kernel": {
                     "decode": grouped_kernel_shape(cfg, self.batch_size),
@@ -3207,6 +3287,42 @@ class BatchedJaxEngine(JaxEngine):
             for name, kind in (("ssm", "M"), ("experts", "E"),
                                ("attention", "*"))}
         return body
+
+    def _pool_bytes_per_token(self) -> float:
+        """What one token keeps in the pool: every paged leaf's own size
+        (all layers; an int8 pool's scales too) over the tokens the pool
+        holds. Read off the leaves, not the configuration: a cache that
+        grew a leaf says so here."""
+        leaves = jax.tree_util.tree_leaves(self._cache.paged())
+        held = sum(math.prod(a.shape) * a.dtype.itemsize for a in leaves)
+        return held / (self._pool_n_blocks * self.kv_pool_page)
+
+    def latent_attention_health(self) -> Optional[dict]:
+        """/health.latent_attention (cumulative; None for a model that
+        caches K and V). ``row_bytes``: what a token keeps in the pool,
+        all layers (640 B a layer at the published sizes: the pool's
+        bytes a token, and all of them). ``decode_rows``: decode queries
+        the chunk programs ran (a pass's rows, counted once whatever the
+        depth); ``latent_rows_read``: the cached rows those queries had
+        before them, summed over the layers — both counted on the device.
+        ``window_rows_absorbed`` / ``window_rows_expanded``: prompt rows
+        prefilled (the scheduler's arithmetic) by the form that attended
+        them; the expanded form serves none (ops/latent_attention.py);
+        ``window_pairs``: the (query, cached row) pairs of those rows in
+        one layer."""
+        cfg = self.model_cfg
+        if not cfg.latent:
+            return None
+        queries, rows = self._sel_rows_dev
+        return {"row_bytes": self._pool_bytes_per_token(),
+                "layers": cfg.n_layers,
+                "decode_rows": queries // cfg.n_layers,
+                "latent_rows_read": rows,
+                "window_rows_absorbed": self._selection_counts["window_rows"],
+                "window_rows_expanded": 0,
+                # (query, cached row) pairs of those prompt rows, a layer
+                "window_pairs": self._selection_counts["index_rows_scanned"],
+                "forward_passes": self._selection_counts["forward_passes"]}
 
     def sparse_attention_health(self) -> Optional[dict]:
         """/health.sparse_attention (cumulative; None for a configuration
@@ -3236,6 +3352,7 @@ class BatchedJaxEngine(JaxEngine):
                   else ())
         body = self._pool.stats(cached).as_dict()
         body["starved_slots_total"] = self._pool_starved
+        body["bytes_per_token"] = self._pool_bytes_per_token()
         # Single-chip deployments read the regime here (sharding_health
         # is None without a mesh).
         body.update(self._attention_health())
@@ -3878,6 +3995,7 @@ class BatchedJaxEngine(JaxEngine):
             # counters, null where the configuration has neither.
             "moe": self.moe_health(),
             "sparse_attention": self.sparse_attention_health(),
+            "latent_attention": self.latent_attention_health(),
             "ssm": self.ssm_health(),
             "sharding": self.sharding_health(),
             "queue_rejections": self._rejections,
@@ -5790,7 +5908,8 @@ class BatchedJaxEngine(JaxEngine):
                                       pipe=self._chunks_in_pipe()) as consumed:
             res = unpack_chunk(buf, self.batch_size, ct, spec=is_spec,
                                moe=self._counts_experts,
-                               sel=self.model_cfg.selects_keys)
+                               sel=(self.model_cfg.selects_keys
+                                    or self.model_cfg.latent))
             consumed["n_alive"] = res.n_alive
             if res.experts_read is not None:
                 # One pass a scan step (the prologue is one of them), each

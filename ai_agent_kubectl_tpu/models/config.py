@@ -19,6 +19,14 @@ test models. Family differences are expressed as data, not subclasses:
   cache leaf of its own), two-matrix relu^2 experts under a sigmoid router
   with a selection bias beside a shared expert, or GQA without a rotary
   embedding — and the weights are stacked per kind
+- Mistral-Small-4-119B-2603's language model (``mistral4``; registered by
+  the benchmark, ``toy-mla-moe`` is its toy): LATENT attention — queries
+  and keys/values through low-rank projections, the cache ONE compressed
+  row a token (``kv_lora_rank`` + ``qk_rope_head_dim`` values, no head
+  axis) that every head reads through absorbed projections — YaRN
+  frequencies on interleaved pairs, a position-dependent query scale,
+  and a CHIP'S SHARE of the routed experts (``n_experts`` held of the
+  ``router_width`` the router scores) beside a shared expert
 """
 
 from __future__ import annotations
@@ -88,6 +96,39 @@ class ModelConfig:
     shared_mlp_hidden: int = 0
     router: str = "softmax"
     router_scale: float = 1.0
+    # An expert layer that is GIVEN a share of its experts (one chip of an
+    # expert-parallel deployment): the router scores ``router_width``
+    # experts (0 = ``n_experts``) and picks among all of them; the leaves
+    # hold ``n_experts`` of them, from ``first_expert`` on; a pick of an
+    # expert held elsewhere adds nothing here, and the partial sum goes on.
+    router_width: int = 0
+    first_expert: int = 0
+    # Latent attention (MLA; DeepSeek-V2's, as ``mistral4`` takes it), on
+    # where ``kv_lora_rank`` > 0: q = RMSNorm(x W_dq) W_uq, per head
+    # ``qk_nope_head_dim`` values without and ``qk_rope_head_dim`` with a
+    # rotary embedding; [c | kr] = x W_dkv, c = RMSNorm(c) of
+    # ``kv_lora_rank`` values and ONE rope key a token for all heads; a
+    # head's key is [c W_uk_h | kr] and its value c W_uv_h (``v_head_dim``).
+    # The cache is the row [c | kr] and nothing else; ``n_kv_heads`` and
+    # ``head_dim`` are carried as published and read by nothing here.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # Rotary scaling: pairs (2i, 2i+1) instead of (i, i + d/2); YaRN
+    # frequencies (``rope_factor`` > 1 over ``rope_original_max``
+    # positions, the ramp between ``rope_beta_fast`` and ``rope_beta_slow``
+    # rotations; ops/rope.py); and the Llama-4 query scale 1 +
+    # ``q_scale_beta`` ln(1 + floor(pos / rope_original_max)).
+    rope_interleave: bool = False
+    rope_factor: float = 1.0
+    rope_original_max: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+    q_scale_beta: float = 0.0
     # False: attention takes no rotary embedding (its positions come from
     # the state-space layers); ``rope_theta`` is then carried, unused.
     use_rope: bool = True
@@ -104,6 +145,22 @@ class ModelConfig:
     @property
     def selects_keys(self) -> bool:
         return self.index_topk > 0
+
+    @property
+    def latent(self) -> bool:
+        """Latent attention: the cache is one compressed row a token."""
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_row(self) -> int:
+        """Values of a token's cached row: the normed latent and the one
+        rotated rope key (256 + 64 = 320: 640 B in bf16)."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def experts_scored(self) -> int:
+        """Experts the router scores (those held here are ``n_experts``)."""
+        return self.router_width or self.n_experts
 
     @property
     def gated_mlp(self) -> bool:
@@ -177,7 +234,7 @@ class ModelConfig:
         chip, and the threshold is to be measured once an F-tiled grouped
         kernel can take wide experts (ROADMAP S2)."""
         return (self.n_experts > 0
-                and self.n_experts >= 8 * self.experts_per_token)
+                and self.experts_scored >= 8 * self.experts_per_token)
 
     @property
     def q_per_kv(self) -> int:
@@ -193,9 +250,18 @@ class ModelConfig:
             + 2 * self.dim * self.n_kv_heads * self.head_dim  # wk, wv
             + self.n_heads * self.head_dim * self.dim         # wo
         )
+        if self.latent:
+            H = self.n_heads
+            attn = self.n_layers * (
+                self.dim * self.q_lora_rank + self.q_lora_rank * H
+                * (self.qk_nope_head_dim + self.qk_rope_head_dim)
+                + self.dim * self.latent_row + self.kv_lora_rank * H
+                * (self.qk_nope_head_dim + self.v_head_dim)
+                + H * self.v_head_dim * self.dim)
         mlp_units = max(self.n_experts, 1)
-        mlp = self.n_layers * mlp_units * 3 * self.dim * self.mlp_hidden
-        router = self.n_layers * self.dim * self.n_experts
+        mlp = self.n_layers * 3 * self.dim * (
+            mlp_units * self.mlp_hidden + self.shared_mlp_hidden)
+        router = self.n_layers * self.dim * self.experts_scored
         if self.selects_keys:
             attn += self.n_layers * self.dim * (
                 self.index_heads * self.index_head_dim    # idx_wq
@@ -261,6 +327,21 @@ TOY_HYBRID_MOE = _register(ModelConfig(
     ssm_groups=2, ssm_conv=4, ssm_chunk=16, shared_mlp_hidden=144,
     router="sigmoid_bias", router_scale=2.5, use_rope=False,
     max_seq_len=2048,
+))
+
+# Latent attention over a compressed row a token (YaRN over 64 positions,
+# interleaved pairs, the position-dependent query scale: the tests cross
+# the boundary), and a chip's share of the experts (the router scores 16,
+# this tree holds 4 of them from the first) beside a shared expert: the toy
+# of the benchmark's mistral-small-4-119b-2603-l9 configuration.
+TOY_MLA_MOE = _register(ModelConfig(
+    name="toy-mla-moe", vocab_size=512, dim=128, n_layers=2, n_heads=4,
+    n_kv_heads=4, head_dim=32, mlp_hidden=64, n_experts=4,
+    experts_per_token=2, router_width=16, shared_mlp_hidden=64,
+    q_lora_rank=48, kv_lora_rank=32, qk_nope_head_dim=16,
+    qk_rope_head_dim=16, v_head_dim=32, rope_interleave=True,
+    rope_factor=8.0, rope_original_max=64, rope_mscale=1.0,
+    rope_mscale_all_dim=1.0, q_scale_beta=0.1, max_seq_len=2048,
 ))
 
 # --- Gemma (HF: google/gemma-{2b,7b}-it) ---

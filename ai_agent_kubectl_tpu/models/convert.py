@@ -73,6 +73,12 @@ def convert_hf_checkpoint(
     (ops/quant4.py::pick_format). ``quantize_embed`` stores the
     embedding per-row int8 (the tied-head read halves).
     """
+    if cfg.latent or cfg.router_width:
+        raise NotImplementedError(
+            f"{cfg.name}: no checkpoint mapping for latent attention or a "
+            f"share of the experts (kv_lora_rank={cfg.kv_lora_rank}, "
+            f"router_width={cfg.router_width}); it is served with seeded "
+            f"weights only")
     if cfg.qk_norm or cfg.selects_keys:
         # The Keye-VL-2.0 family (QK-norm, a key selector with its own
         # projections, experts under the Qwen3-MoE names): its checkpoint

@@ -83,6 +83,22 @@ class KVCache:
              (spare rows are tolerated). ``forward``, given such a
              configuration and no leaf, starts every row from zero and
              returns the leaves.
+    lat:     pool mode of a latent-attention configuration
+             (``ModelConfig.latent``) only, and then THE cache: one
+             compressed row a token a layer, [normed latent | rotated rope
+             key], shared by every head — [n_layers, n_blocks, page / 2,
+             2 x latent_row], a PAIR of tokens a leaf row (ops/
+             ragged_attention.py::latent_pack: 640 B a token at the
+             published sizes, every part on a lane tile's edge), addressed
+             by the same block tables. ``k``/``v`` are then read by nothing:
+             the engine's pool leaves them None; a caller that hands K and
+             V pools anyway (benchmark/refcheck.py) gets them back
+             untouched and, given no ``lat``, a zero one made on the K
+             pool's block geometry and returned.
+    lat_rows: int32 [2], for a latent configuration's engine: the DECODE
+             queries the passes ran and the cached rows those had before
+             them, summed over layers and passes since the chunk program
+             last zeroed it (/health.latent_attention). Absent elsewhere.
     """
 
     k: Any
@@ -93,6 +109,8 @@ class KVCache:
     sel_rows: Any = None
     ssm: Any = None
     conv: Any = None
+    lat: Any = None
+    lat_rows: Any = None
 
     @classmethod
     def zeros(cls, cfg: ModelConfig, batch: int, max_seq: int,
@@ -115,6 +133,16 @@ class KVCache:
     def max_seq(self) -> int:
         leaf = self.k.q if isinstance(self.k, QuantKV) else self.k
         return leaf.shape[2]
+
+    #: the leaves addressed through the block tables (pool mode): written,
+    #: shared, copied, demoted and promoted together
+    PAGED = ("k", "v", "ik", "lat")
+
+    def paged(self) -> Tuple[Any, ...]:
+        return tuple(getattr(self, name) for name in self.PAGED)
+
+    def with_paged(self, leaves) -> "KVCache":
+        return dataclasses.replace(self, **dict(zip(self.PAGED, leaves)))
 
 
 def state_zeros(cfg: ModelConfig, rows: int, dtype=jnp.bfloat16):
@@ -244,7 +272,8 @@ def init_params(key: jax.Array, cfg: ModelConfig, dtype=jnp.bfloat16) -> Params:
     def _dense_init(k, shape, scale):
         return (jax.random.normal(k, shape, dtype=jnp.float32) * scale).astype(dtype)
 
-    keys = iter(jax.random.split(key, 16))
+    # (a split's keys depend on its count: the other families keep theirs)
+    keys = iter(jax.random.split(key, 32 if cfg.latent else 16))
     d, hd, H, KV, F, L = (cfg.dim, cfg.head_dim, cfg.n_heads, cfg.n_kv_heads,
                           cfg.mlp_hidden, cfg.n_layers)
     s_in = d ** -0.5
@@ -258,6 +287,25 @@ def init_params(key: jax.Array, cfg: ModelConfig, dtype=jnp.bfloat16) -> Params:
         "wo": _dense_init(next(keys), (L, H * hd, d), (H * hd) ** -0.5),
         "mlp_norm": jnp.zeros((L, d), dtype) if cfg.rms_offset else jnp.ones((L, d), dtype),
     }
+    if cfg.latent:
+        # Latent attention: the query's down and up projections, the joint
+        # down projection of latent and rope key, a norm behind each down
+        # projection, and ONE up-projection of keys and values [latent,
+        # heads x (nope | v)] — bf16 in an int8 tree too: absorbed, it is
+        # contracted over its OUTPUT channels, where int8's per-channel
+        # scales sit; no wq / wk / wv.
+        Qr, C = cfg.q_lora_rank, cfg.kv_lora_rank
+        N, R, V = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        for name in ("wq", "wk", "wv"):
+            del layers[name]
+        layers.update(
+            w_dq=_dense_init(next(keys), (L, d, Qr), s_in),
+            dq_norm=jnp.ones((L, Qr), dtype),
+            w_uq=_dense_init(next(keys), (L, Qr, H * (N + R)), Qr ** -0.5),
+            w_dkv=_dense_init(next(keys), (L, d, C + R), s_in),
+            dkv_norm=jnp.ones((L, C), dtype),
+            w_ukv=_dense_init(next(keys), (L, C, H * (N + V)), C ** -0.5),
+            wo=_dense_init(next(keys), (L, H * V, d), (H * V) ** -0.5))
     if cfg.qk_norm:
         layers["q_norm"] = jnp.ones((L, hd), dtype)
         layers["k_norm"] = jnp.ones((L, hd), dtype)
@@ -267,11 +315,19 @@ def init_params(key: jax.Array, cfg: ModelConfig, dtype=jnp.bfloat16) -> Params:
         layers["idx_wk"] = _dense_init(next(keys), (L, d, di), s_in)
         layers["idx_ww"] = _dense_init(next(keys), (L, d, J), s_in)
     if cfg.is_moe:
-        E = cfg.n_experts
-        layers["router"] = _dense_init(next(keys), (L, d, E), s_in)
+        # the router scores every expert; the leaves hold those of this
+        # share (all of them unless ``router_width`` says otherwise)
+        E, Fs = cfg.n_experts, cfg.shared_mlp_hidden
+        layers["router"] = _dense_init(next(keys),
+                                       (L, d, cfg.experts_scored), s_in)
         layers["w_gate"] = _dense_init(next(keys), (L, E, d, F), s_in)
         layers["w_up"] = _dense_init(next(keys), (L, E, d, F), s_in)
         layers["w_down"] = _dense_init(next(keys), (L, E, F, d), s_mlp)
+        if Fs:
+            layers["shared_gate"] = _dense_init(next(keys), (L, d, Fs), s_in)
+            layers["shared_up"] = _dense_init(next(keys), (L, d, Fs), s_in)
+            layers["shared_down"] = _dense_init(next(keys), (L, Fs, d),
+                                                Fs ** -0.5)
     else:
         layers["w_gate"] = _dense_init(next(keys), (L, d, F), s_in)
         layers["w_up"] = _dense_init(next(keys), (L, d, F), s_in)
@@ -495,7 +551,8 @@ def _moe_mlp(cfg: ModelConfig, lp: Params, x: jnp.ndarray,
         # picks the layer inside the kernel's index maps (``forward``'s
         # scan closes over them): sliced out as the scan's xs they were
         # copied, 604 MB a layer a pass, before the kernel read them.
-        return grouped_moe(cfg, lp, x, token_mask, layer=layer)
+        return grouped_moe(cfg, lp, x, token_mask, cfg.first_expert,
+                           layer=layer)
     return dense_moe(cfg, lp, x, mesh), None
 
 
@@ -596,6 +653,102 @@ def _select_and_attend(cfg: ModelConfig, attn_impl: str, q, qi, wi,
         every_key, selected, None)
 
 
+def _latent_attention(cfg: ModelConfig, attn_impl: str, x, lp: Params,
+                      layer_lat, positions, kv_limit: int, token_mask,
+                      write_mask, block_tables, q_lens, layer):
+    """Latent attention (MLA) of the normed hidden state ``x`` [B, S, d]
+    over the block pool's latent leaf (ops/latent_attention.py has the two
+    forms; this is the ABSORBED one for every row, decode and window
+    alike, so a row that reads pooled tokens and one that reads its own
+    window's are the same row). Returns (the layer's attention output
+    through ``wo`` [B, S, d], the leaf with the window's rows written,
+    int32 [2]: the call's live decode rows and the cached rows those had
+    before them).
+
+    The sizes are the configuration's latent ones, never ``head_dim``. The
+    softmax scale (``qk_head_dim ** -0.5`` times YaRN's temperature
+    squared) and the position-dependent query scale are folded into the
+    queries, in float32, before they are rounded: the kernel multiplies by
+    1. Scopes: the projections and the absorption ``qkv_proj``, the
+    un-absorption and ``wo`` ``o_proj``; the write ``kv_write``."""
+    from ..ops.latent_attention import absorbed_attention, write_rows
+    from ..ops.ragged_attention import (latent_attention_pool, latent_query,
+                                        latent_unpack)
+    from ..ops.rope import (apply_rope_scaled, query_scale, yarn_frequencies,
+                            yarn_mscale)
+
+    if block_tables is None or layer_lat is None:
+        raise NotImplementedError(
+            f"{cfg.name} keeps a latent cache (kv_lora_rank="
+            f"{cfg.kv_lora_rank}): its rows live in the block pool, so it "
+            "is served through the pool alone (KV_POOL, one device)")
+    B, S, _ = x.shape
+    H, C = cfg.n_heads, cfg.kv_lora_rank
+    N, R, V = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    with jax.named_scope("qkv_proj"):
+        cq = rms_norm(qmatmul(x, lp["w_dq"]), lp["dq_norm"], cfg.rms_eps)
+        q = qmatmul(cq, lp["w_uq"]).reshape(B, S, H, N + R)
+        ckr = qmatmul(x, lp["w_dkv"])
+        c = rms_norm(ckr[..., :C], lp["dkv_norm"], cfg.rms_eps)
+    with jax.named_scope("rope"):
+        inv_freq = yarn_frequencies(
+            R, cfg.rope_theta, cfg.rope_factor, cfg.rope_original_max,
+            cfg.rope_beta_fast, cfg.rope_beta_slow)
+        factor = (yarn_mscale(cfg.rope_factor, cfg.rope_mscale)
+                  / yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim))
+        q_r = apply_rope_scaled(q[..., N:], positions, inv_freq,
+                                cfg.rope_interleave, factor)
+        kr = apply_rope_scaled(ckr[..., None, C:], positions, inv_freq,
+                               cfg.rope_interleave, factor)[:, :, 0]
+    w_ukv = lp["w_ukv"].reshape(C, H, N + V)
+    with jax.named_scope("qkv_proj"):
+        scale = ((N + R) ** -0.5
+                 * yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim) ** 2)
+        g = scale * (query_scale(positions, cfg.q_scale_beta,
+                                 cfg.rope_original_max)
+                     if cfg.q_scale_beta else jnp.ones(positions.shape,
+                                                       jnp.float32))
+        g = g[..., None, None]
+        # float32 operands (a TPU multiplies them as bf16 and accumulates
+        # in float32 all the same; the CPU has no bf16 x bf16 -> f32 dot)
+        q_c = jnp.einsum("bshn,chn->bshc", q[..., :N].astype(jnp.float32),
+                         w_ukv[..., :N].astype(jnp.float32))
+        q_c = (q_c * g).astype(x.dtype)
+        q_r = (q_r.astype(jnp.float32) * g).astype(x.dtype)
+
+    n_blocks, half_page = layer_lat.shape[-3:-1]
+    page = 2 * half_page
+    if kv_limit % page:
+        raise ValueError(
+            f"pool kv_limit {kv_limit} not a multiple of page {page}")
+    flat = _pool_flat_pos(block_tables, positions, page, n_blocks, write_mask)
+    with jax.named_scope("kv_write"):
+        layer_lat = write_rows(layer_lat, flat, positions, c, kr, layer)
+    ql = (jnp.full((B,), S, jnp.int32) if q_lens is None
+          else q_lens.astype(jnp.int32))
+    with jax.named_scope("attention"):
+        if attn_impl == "ragged":
+            o_c = latent_attention_pool(
+                latent_query(q_c, q_r), layer_lat, ql, positions[:, 0],
+                block_tables, layer, v_lanes=C, page_size=page)
+        else:
+            n_pages = kv_limit // page
+            c_ctx, kr_ctx = latent_unpack(
+                _pool_gather(layer_lat, block_tables, n_pages, layer), C)
+            mask = jnp.arange(kv_limit)[None, None, :] <= positions[:, :, None]
+            o_c = absorbed_attention(q_c, q_r, c_ctx, kr_ctx, mask)
+    with jax.named_scope("o_proj"):
+        o = jnp.einsum("bshc,chv->bshv", o_c, w_ukv[..., N:])
+        out = qmatmul(o.reshape(B, S, H * V), lp["wo"])
+    decode = ql == 1
+    if token_mask is not None:
+        decode = jnp.logical_and(decode, token_mask[:, 0] > 0)
+    rows = jnp.stack([
+        jnp.sum(decode, dtype=jnp.int32),
+        jnp.sum(jnp.where(decode, positions[:, 0] + 1, 0), dtype=jnp.int32)])
+    return out, layer_lat, rows
+
+
 def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
            h: jnp.ndarray, lp: Params,
            layer_k: jnp.ndarray, layer_v: jnp.ndarray,
@@ -609,10 +762,13 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
            layer_ik=None) -> Tuple[jnp.ndarray, ...]:
     """One transformer block. Returns (h_out, new_layer_k, new_layer_v,
     new_layer_ik, counts): ``layer_ik`` is the index-key leaf of a
-    selecting configuration (None otherwise, returned as given) and
+    selecting configuration — or the latent leaf of a latent-attention
+    one, whose K and V are returned as given (``_latent_attention``) —
+    (None otherwise, returned as given) and
     ``counts`` holds what this pass counted on the device, under the
     ``KVCache`` field each adds to: ``experts_read`` where the grouped
-    expert path served, ``sel_rows`` where keys were selected.
+    expert path served, ``sel_rows`` where keys were selected,
+    ``lat_rows`` where latent rows were read.
 
     ``layer`` (a traced scalar; ISSUE 25): ``layer_k``/``layer_v`` are
     then the WHOLE stacked cache leaves [L, ...] that ``forward``'s layer
@@ -638,9 +794,38 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
     """
     B, S, d = h.shape
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    counts = {}
+
+    def mlp_block(h):
+        x = rms_norm(h, lp["mlp_norm"], cfg.rms_eps, cfg.rms_offset)
+        if not cfg.is_moe:
+            return _dense_mlp(cfg, lp, x)
+        y, n_read = _moe_mlp(cfg, lp, x, mesh, token_mask, moe_impl, layer)
+        if n_read is not None:
+            counts["experts_read"] = n_read
+        if cfg.shared_mlp_hidden:
+            # the expert every token takes, whole on every chip
+            y = y + _dense_mlp(cfg, lp, x, "shared_")
+        return y
+
+    def after_attention(h):
+        """The block's second half: the MLP and its residual — nothing for
+        a patterned configuration, whose attention layer is that mixer
+        alone."""
+        if cfg.layer_pattern:
+            return h
+        with jax.named_scope("mlp"):
+            mlp = mlp_block(h)
+        return _shard_residual(mesh, h + mlp)
 
     with jax.named_scope("attn_norm"):
         x = rms_norm(h, lp["attn_norm"], cfg.rms_eps, cfg.rms_offset)
+    if cfg.latent:
+        out, layer_ik, counts["lat_rows"] = _latent_attention(
+            cfg, attn_impl, x, lp, layer_ik, positions, kv_limit,
+            token_mask, write_mask, block_tables, q_lens, layer)
+        return (after_attention(_shard_residual(mesh, h + out)),
+                layer_k, layer_v, layer_ik, counts)
     with jax.named_scope("qkv_proj"):
         q = qmatmul(x, lp["wq"]).reshape(B, S, H, hd)
         k = qmatmul(x, lp["wk"]).reshape(B, S, KV, hd)
@@ -667,26 +852,6 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
             f"{cfg.name} selects its keys (index_topk={cfg.index_topk}): "
             "its index keys live in the block pool, so it is served "
             "through the pool alone (KV_POOL, no pipe axis)")
-    counts = {}
-
-    def mlp_block(h):
-        x = rms_norm(h, lp["mlp_norm"], cfg.rms_eps, cfg.rms_offset)
-        if not cfg.is_moe:
-            return _dense_mlp(cfg, lp, x)
-        y, n_read = _moe_mlp(cfg, lp, x, mesh, token_mask, moe_impl, layer)
-        if n_read is not None:
-            counts["experts_read"] = n_read
-        return y
-
-    def after_attention(h):
-        """The block's second half: the MLP and its residual — nothing for
-        a patterned configuration, whose attention layer is that mixer
-        alone."""
-        if cfg.layer_pattern:
-            return h
-        with jax.named_scope("mlp"):
-            mlp = mlp_block(h)
-        return _shard_residual(mesh, h + mlp)
 
     if block_tables is not None:
         # Block-paged pool (ISSUE 10): layer_k/v are [n_blocks, page, KV,
@@ -1043,7 +1208,12 @@ def forward(
     batch_idx = jnp.arange(B)[:, None]
     new_ik, new_ssm, new_conv = cache.ik, cache.ssm, cache.conv
     counted = {"experts_read": cache.experts_read,
-               "sel_rows": cache.sel_rows}
+               "sel_rows": cache.sel_rows, "lat_rows": cache.lat_rows}
+    if cfg.latent and (cfg.layer_kinds or (mesh is not None
+                                           and mesh.size > 1)):
+        raise NotImplementedError(
+            f"{cfg.name} keeps a latent cache: served as a uniform block on "
+            "one device (parallel/sharding.py has no rule for its leaves)")
     if cfg.layer_kinds and mesh is not None and mesh.size > 1:
         raise NotImplementedError(
             f"{cfg.name} runs one mixer a layer (layer_pattern) and is "
@@ -1116,6 +1286,14 @@ def forward(
         if cfg.selects_keys and ik is None and block_tables is not None:
             ik = jnp.zeros(cache.k.shape[:3] + (cfg.index_key_width,),
                            cache.k.dtype)
+        # A latent configuration's ONE leaf rides in that place (it has no
+        # index keys); K and V, where a caller hands them, ride untouched.
+        if cfg.latent:
+            ik = cache.lat
+            if ik is None and block_tables is not None:
+                L_, nb, page = cache.k.shape[:3]
+                ik = jnp.zeros((L_, nb, page // 2, 2 * cfg.latent_row),
+                               cache.k.dtype)
 
         # The grouped expert kernel reads its experts out of the stacked
         # leaves by the layer index, so those stay out of the scan's xs.
@@ -1163,6 +1341,9 @@ def forward(
         new_lengths = cache.lengths
     else:
         new_lengths = jnp.maximum(cache.lengths, positions.max(axis=1) + 1)
+    new_lat = None
+    if cfg.latent:
+        new_lat, new_ik = new_ik, None
     return logits.astype(jnp.float32), KVCache(
         k=new_k, v=new_v, lengths=new_lengths, ik=new_ik, ssm=new_ssm,
-        conv=new_conv, **counted)
+        conv=new_conv, lat=new_lat, **counted)

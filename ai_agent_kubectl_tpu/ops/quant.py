@@ -288,11 +288,29 @@ def to_w8a8(params):
     return out
 
 
+#: The latent attention's key/value up-projection as this SEEDED generator
+#: draws it (bench/dev only), in units of a unit-variance projection's
+#: deviation (latent width ** -0.5). At 1 the scores' deviation is 1 (the int8
+#: projections around it are uniform, deviation 0.58 of theirs) and attention
+#: is a near-uniform average no comparison of logits could tell from another;
+#: at 2 it is 1.9 and the values weigh twice as much in the residual. (At 4
+#: the scores' deviation is 3.6 and bf16's rounding of queries and rows alone
+#: moves a logit by 0.07 of the logits' deviation at toy widths, past what
+#: the comparison can hold the other precisions to; at the stack's leading
+#: size, what a bf16 leaf gets by default, it was 10: every softmax one key.)
+SEEDED_UKV_GAIN = 2.0
+
+
 #: projection weights eligible for quantization (matmul RHS with the
 #: output channel last). Embeddings/norms/router excluded.
 _QUANT_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
                "idx_wq", "idx_wk", "ssm_in", "ssm_out", "shared_gate",
-               "shared_up", "shared_down")
+               "shared_up", "shared_down",
+               # latent attention's projections; ``w_ukv`` stays out: the
+               # absorbed form contracts it over its OUTPUT channels, where
+               # the per-channel scales sit (models/transformer.py::
+               # init_params)
+               "w_dq", "w_uq", "w_dkv")
 
 
 #: The per-head q/k RMSNorm gains this SEEDED generator writes (bench/dev
@@ -453,10 +471,15 @@ def _random_params_int8(key, cfg, dtype, quantize_embed: bool, int4: bool,
             ))
         else:
             scale = 1.0 if name == "embed" else sds.shape[0] ** -0.5
-            if name == "router" and cfg.router == "sigmoid_bias":
+            if name == "router" and (cfg.router == "sigmoid_bias"
+                                     or cfg.router_width):
                 # unit-variance logits: scores spread over (0, 1) and the
-                # top-k is the input's, not a few saturated experts'
+                # top-k is the input's, not a few saturated experts' (a
+                # softmax router's logits at the default scale have a
+                # deviation of 20-45: every token one expert at weight 1)
                 scale = sds.shape[-2] ** -0.5
+            if name == "w_ukv":
+                scale = SEEDED_UKV_GAIN * sds.shape[-2] ** -0.5
             out.append(
                 (jax.random.normal(k, sds.shape, _jnp.float32) * scale)
                 .astype(dtype)

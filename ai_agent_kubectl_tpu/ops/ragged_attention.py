@@ -217,10 +217,10 @@ def grid_steps(n_slots: int, n_pages: int, page_size: int, n_heads: int,
 
 
 def _ragged_pool_kernel(pos_ref, qlen_ref, tbl_ref, lyr_ref, q_ref, k_hbm,
-                        v_hbm, *rest, page_size: int, scale: float,
+                        *rest, page_size: int, scale: float,
                         n_pages: int, kv_heads: int, tq: int, pps: int,
                         depth: int, flat: bool, grid: tuple,
-                        selects: bool = False):
+                        selects: bool = False, v_lanes: int = 0):
     """Online-softmax body over one (slot, query tile, page block) grid
     cell: ``tq`` query columns against ``pps`` pages.
 
@@ -261,11 +261,21 @@ def _ragged_pool_kernel(pos_ref, qlen_ref, tbl_ref, lyr_ref, q_ref, k_hbm,
     one more operand, ``sel_ref`` int8 [1, tq, pps*page] (``flat``: every
     key repeated for each KV head, [1, tq, pps*page*KV]), nonzero where
     the tile's query column may attend the block's key; it is ANDed into
-    the causal mask and nothing else changes."""
-    sel_ref = None
+    the causal mask and nothing else changes.
+
+    ``v_lanes`` (latent attention, ``latent_attention_pool``): there is
+    no V operand and no V ring; ONE leaf streams, of one row a key (the
+    ``flat`` form with one KV head), and a key's value is the first
+    ``v_lanes`` lanes of its own row."""
+    sel_ref = v_hbm = v_buf = None
+    if not v_lanes:
+        v_hbm, *rest = rest
     if selects:
         sel_ref, *rest = rest
-    (o_ref, k_buf, v_buf, sems, ring_ref, m_scr, l_scr, acc_scr) = rest
+    if v_lanes:
+        (o_ref, k_buf, sems, ring_ref, m_scr, l_scr, acc_scr) = rest
+    else:
+        (o_ref, k_buf, v_buf, sems, ring_ref, m_scr, l_scr, acc_scr) = rest
     n, t, b = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     n_slots, n_qt, n_blk = grid
     n_cells = n_slots * n_qt
@@ -301,8 +311,9 @@ def _ragged_pool_kernel(pos_ref, qlen_ref, tbl_ref, lyr_ref, q_ref, k_hbm,
             blk * pps, jnp.minimum(blk * pps + pps, last + 1),
             lambda j, _: do(j, j - blk * pps), None)
 
-    leaves = ((k_hbm, k_buf), (v_hbm, v_buf))
-    if flat:
+    leaves = (((k_hbm, k_buf),) if v_lanes
+              else ((k_hbm, k_buf), (v_hbm, v_buf)))
+    if flat and not v_lanes:
         # A page's [page, KV, hd] rows are [page*KV, hd] rows, byte for
         # byte: the copies land them as that, in buffers tiled by rows.
         # (Reshaping the VMEM side instead aborts the compiler.)
@@ -320,7 +331,8 @@ def _ragged_pool_kernel(pos_ref, qlen_ref, tbl_ref, lyr_ref, q_ref, k_hbm,
             # A page past the live span of a half-live block is never
             # copied; its V rows meet probability 0, and 0 x NaN is NaN.
             # So the buffers only ever hold zeros or pool rows.
-            v_buf[...] = jnp.zeros_like(v_buf)
+            values = k_buf if v_lanes else v_buf
+            values[...] = jnp.zeros_like(values)
             cell, last = first_reader(0)
             for i, x in enumerate((0, 0, cell, 0, last)):
                 ring_ref[i] = x
@@ -381,7 +393,32 @@ def _ragged_pool_kernel(pos_ref, qlen_ref, tbl_ref, lyr_ref, q_ref, k_hbm,
         G = H // kv_heads
         span = pps * page_size
         red = 1 if flat else 2          # the scores' key axis
-        if flat:
+        if v_lanes:
+            # Latent rows, stored a PAIR of tokens a row (see
+            # ``latent_attention_pool``): [c_even | c_odd | kr_even kr_odd].
+            # Rows [tq*H] as in the flat form; the block's keys come out
+            # even tokens first, then odd ones, and the mask follows.
+            C, rows, half = v_lanes, tq * H, span // 2
+            R2 = k_buf.shape[-1] - 2 * C
+            qg = q_ref[0].reshape(rows, hd)
+            kb = k_buf[slot].reshape(half, 2 * C + R2)
+            c_even, c_odd, kr = kb[:, :C], kb[:, C:2 * C], kb[:, 2 * C:]
+
+            def qk(a, b_):
+                return jax.lax.dot_general(
+                    a, b_, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+
+            s = jnp.concatenate(
+                [qk(qg[:, :C], c_even) + qk(qg[:, C:C + R2], kr),
+                 qk(qg[:, :C], c_odd) + qk(qg[:, C + R2:], kr)],
+                axis=1) * scale                         # [rows, span]
+            col = jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
+            odd = (col >= half).astype(jnp.int32)
+            kv_ids = b * span + 2 * (col - odd * half) + odd
+            q_ids = q0 + div(jax.lax.broadcasted_iota(
+                jnp.int32, (rows, 1), 0), H)
+        elif flat:
             # Rows [tq*H]: column j's head h at j*H + h. Keys as stored:
             # position p's KV head g at p*KV + g.
             rows, cols = tq * H, span * kv_heads
@@ -421,7 +458,7 @@ def _ragged_pool_kernel(pos_ref, qlen_ref, tbl_ref, lyr_ref, q_ref, k_hbm,
         # (j >= q_len) mask everything — their normalizer stays 0 and the
         # finalize writes zeros (outputs are never read).
         mask = jnp.logical_and(kv_ids <= pos + q_ids, q_ids < q_len)
-        if flat:    # ... and only its own KV group's columns
+        if flat and not v_lanes:    # ... and only its own KV group's columns
             mask = jnp.logical_and(mask, own)
         if sel_ref is not None:
             picked = sel_ref[0].astype(jnp.int32) != 0
@@ -442,12 +479,21 @@ def _ragged_pool_kernel(pos_ref, qlen_ref, tbl_ref, lyr_ref, q_ref, k_hbm,
         m_scr[...] = m_new
         l_scr[...] = l_prev * alpha + jnp.sum(pexp, axis=red,
                                               keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            pexp.astype(v.dtype), v,
-            (((1,), (0,)), ((), ())) if flat
-            else (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )                               # [tq*H, hd] | [KV, tq*G, hd]
+        if v_lanes:
+            def pv(p_, c_):
+                return jnp.dot(p_.astype(c_.dtype), c_,
+                               preferred_element_type=jnp.float32)
+
+            weighted = (pv(pexp[:, :half], c_even)
+                        + pv(pexp[:, half:], c_odd))    # [tq*H, C]
+        else:
+            weighted = jax.lax.dot_general(
+                pexp.astype(v.dtype), v,
+                (((1,), (0,)), ((), ())) if flat
+                else (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32,
+            )                           # [tq*H, hd] | [KV, tq*G, hd]
+        acc_scr[...] = acc_scr[...] * alpha + weighted
 
     @pl.when(b == n_blk - 1)
     def _finalize():
@@ -463,7 +509,7 @@ def _ragged_pool_kernel(pos_ref, qlen_ref, tbl_ref, lyr_ref, q_ref, k_hbm,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("page_size", "scale", "interpret"),
+    static_argnames=("page_size", "scale", "interpret", "v_lanes"),
 )
 def ragged_attention_pool(
     q: jnp.ndarray,             # [N, W, H, hd] per-slot query windows
@@ -479,6 +525,7 @@ def ragged_attention_pool(
     page_size: int = 128,
     scale: Optional[float] = None,
     interpret: Optional[bool] = None,
+    v_lanes: int = 0,
 ) -> jnp.ndarray:
     """Ragged block-paged attention over the pool. Returns
     [N, W, H, hd]; rows past ``q_lens[n]`` are zeros (never read —
@@ -486,6 +533,10 @@ def ragged_attention_pool(
     query column to the keys it names, inside the causal mask (a column
     whose ``sel`` row names every causal key gives the bits it gives
     without ``sel``).
+
+    ``v_lanes`` > 0 (``latent_attention_pool``): ``v`` is None, ``k`` has
+    one KV head, and a key's value is the first ``v_lanes`` lanes of its
+    row; returns [N, W, H, v_lanes].
 
     Cost per slot tracks ``ceil((positions[n]+q_lens[n])/page)`` live
     pages, whatever mixture of decode / verify / prefill widths the
@@ -496,8 +547,22 @@ def ragged_attention_pool(
             "use the dense gather path"
         )
     N, W, H, hd = q.shape
-    k, v, lyr = stacked_kv(k, v, layer)
-    _, n_blocks, page, KV, _ = k.shape
+    if v_lanes:
+        # the latent leaf as it is stored, [L, n_blocks, page/2, lanes]: a
+        # head axis of 1 put into it would be a copy of the pool
+        if v is not None or sel is not None:
+            raise ValueError("latent rows: one leaf, no V, no selection")
+        if (k.ndim == 4) != (layer is not None):
+            raise ValueError("a stacked latent leaf takes a layer index "
+                             "and a single layer takes none")
+        if layer is None:
+            k, layer = k[None], 0
+        lyr = jnp.asarray(layer, jnp.int32).reshape(1)
+        (_, n_blocks, page, _), KV = k.shape, 1
+        page *= LATENT_PAIR     # a row of the leaf holds two tokens
+    else:
+        k, v, lyr = stacked_kv(k, v, layer)
+        _, n_blocks, page, KV, _ = k.shape
     if page != page_size:
         raise ValueError(f"pool page {page} != page_size {page_size}")
     n_pages = block_tables.shape[1]
@@ -520,18 +585,23 @@ def ragged_attention_pool(
     shape = (n_pages, page_size, H, KV, hd, W, k.dtype.itemsize)
     pps, depth, flat = pages_per_step(*shape), stream_depth(*shape), \
         _flat(tq, H, KV)
+    if v_lanes:
+        # one row a key is the flat form at any tile width; the budget
+        # (reckoned for a K and a V ring) only has more room
+        flat = True
 
     grid = (N, n_qt, pl.cdiv(n_pages, pps))
     kernel = functools.partial(
         _ragged_pool_kernel, page_size=page_size, scale=scale,
         n_pages=n_pages, kv_heads=KV, tq=tq, pps=pps, depth=depth,
-        flat=flat, grid=grid, selects=sel is not None,
+        flat=flat, grid=grid, selects=sel is not None, v_lanes=v_lanes,
     )
 
     def q_map(n, t, b, pos_ref, qlen_ref, tbl_ref, lyr_ref):
         return (n, t, 0, 0)
 
-    operands, sel_specs = [pos, qln, tbl, lyr, q, k, v], []
+    operands, sel_specs = [pos, qln, tbl, lyr, q, k] + (
+        [] if v_lanes else [v]), []
     if sel is not None:
         span = pps * page_size
         sel = jnp.pad(sel, (
@@ -554,32 +624,90 @@ def ragged_attention_pool(
 
     rows = (tq * H,) if flat else (KV, tq * (H // KV))
     page_rows = (page_size * KV,) if flat else (page_size, KV)
+    if v_lanes:
+        page_rows = (page_size // 2,)
+    vd = v_lanes or hd                  # a value's width
+    ring = pltpu.VMEM((depth, pps) + page_rows + (k.shape[-1],), k.dtype)
+    any_space = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, tq, H, hd), q_map),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ] + sel_specs,
-        out_specs=pl.BlockSpec((1, tq, H, hd), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((depth, pps) + page_rows + (hd,), k.dtype),
-            pltpu.VMEM((depth, pps) + page_rows + (hd,), v.dtype),
+        in_specs=[pl.BlockSpec((1, tq, H, hd), q_map), any_space]
+        + ([] if v_lanes else [any_space]) + sel_specs,
+        out_specs=pl.BlockSpec((1, tq, H, vd), q_map),
+        scratch_shapes=[ring] + ([] if v_lanes else [ring]) + [
             pltpu.SemaphoreType.DMA((2, depth)),
             pltpu.SMEM((5,), jnp.int32),
             pltpu.VMEM(rows + (1,), jnp.float32),
             pltpu.VMEM(rows + (1,), jnp.float32),
-            pltpu.VMEM(rows + (hd,), jnp.float32),
+            pltpu.VMEM(rows + (vd,), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((N, n_qt * tq, H, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((N, n_qt * tq, H, vd), q.dtype),
         interpret=interpret,
     )(*operands)
     return out[:, :W]
+
+
+#: Tokens a row of the latent leaf holds (``latent_pack``).
+LATENT_PAIR = 2
+
+
+def latent_pack(c, kr):
+    """Token rows -> the latent leaf's rows: ``c`` [..., T, C] (the normed
+    latent) and ``kr`` [..., T, R] (the rotated rope key), T even ->
+    [..., T/2, 2C + 2R], a PAIR of tokens a row, laid out [c_even | c_odd
+    | kr_even | kr_odd]. A row a token would be C + R = 320 lanes at the
+    published sizes, no multiple of the TPU's 128: the compiler pads such
+    a leaf to 384 lanes in HBM (768 B a token, not 640) and Mosaic refuses
+    to copy a 320-lane page (AOT, PR 38). Two tokens a row are 640 lanes,
+    five whole lane tiles, every part on a tile's edge: the leaf takes its
+    640 B a token and not a byte more, and the kernel slices it for
+    nothing."""
+    pair = lambda a: a.reshape(a.shape[:-2] + (a.shape[-2] // 2, 2,
+                                               a.shape[-1]))
+    c, kr = pair(c), pair(kr)
+    return jnp.concatenate([c[..., 0, :], c[..., 1, :],
+                            kr[..., 0, :], kr[..., 1, :]], axis=-1)
+
+
+def latent_unpack(rows, c_lanes: int):
+    """``latent_pack``'s inverse: [..., T/2, 2C + 2R] -> (c [..., T, C],
+    kr [..., T, R])."""
+    C = c_lanes
+    R = (rows.shape[-1] - 2 * C) // 2
+    tok = lambda a, b: jnp.stack([a, b], axis=-2).reshape(
+        rows.shape[:-2] + (2 * rows.shape[-2], a.shape[-1]))
+    return (tok(rows[..., :C], rows[..., C:2 * C]),
+            tok(rows[..., 2 * C:2 * C + R], rows[..., 2 * C + R:]))
+
+
+def latent_query(q_c, q_r):
+    """The kernel's query rows for absorbed queries ``q_c`` [..., C]
+    (against the latent) and ``q_r`` [..., R] (against the rope key):
+    [q_c | q_r 0 | 0 q_r], so that the rope part of an even and of an odd
+    token's score each contract over the pair's whole 2R-lane tile."""
+    z = jnp.zeros_like(q_r)
+    return jnp.concatenate([q_c, q_r, z, z, q_r], axis=-1)
+
+
+def latent_attention_pool(q, rows, q_lens, positions, block_tables,
+                          layer=None, *, v_lanes: int, page_size: int,
+                          interpret: Optional[bool] = None) -> jnp.ndarray:
+    """Absorbed latent attention (MLA) over the pool: the same kernel, the
+    same stream, ONE leaf. ``q`` [N, W, H, C + 4R] is ``latent_query``'s
+    rows — each head's absorbed query, already times the softmax scale —
+    and ``rows`` is the latent leaf (``latent_pack``), [n_blocks, page/2,
+    2C + 2R] or stacked [L, ...] with ``layer``: 640 B a token at the
+    published sizes, shared by every head (no head axis), a token's first
+    ``v_lanes`` = C lanes also its value. ``page_size`` counts tokens.
+    Returns [N, W, H, C]."""
+    return ragged_attention_pool(
+        q, rows, None, q_lens, positions, block_tables, layer,
+        page_size=page_size, scale=1.0, interpret=interpret, v_lanes=v_lanes)
 
 
 def ragged_attention_pool_sharded(

@@ -49,7 +49,10 @@ def router_logits(cfg: ModelConfig, lp: Dict[str, Any], x: jnp.ndarray):
     a top-k among near-equal values, so its product runs in float32 at
     the highest precision (T x D x E: nothing beside an expert); the
     softmax router's is the activation dtype's, as it always was."""
-    if cfg.router == "sigmoid_bias":
+    if cfg.router == "sigmoid_bias" or cfg.router_width:
+        # (a router over a SHARE of the experts decides which chip computes
+        # a token's expert: its top-k among 128 near-equal scores is taken
+        # in float32 too, as the family's published code scores)
         return jnp.dot(x.astype(jnp.float32),
                        lp["router"].astype(jnp.float32),
                        precision=jax.lax.Precision.HIGHEST)
@@ -74,7 +77,8 @@ def top_k_routing(cfg: ModelConfig, logits: jnp.ndarray, bias=None):
     if cfg.router != "softmax":
         raise ValueError(f"unknown router kind {cfg.router!r}")
     top_vals, idx = jax.lax.top_k(logits, k)
-    return jax.nn.softmax(top_vals.astype(jnp.float32), axis=-1), idx
+    w = jax.nn.softmax(top_vals.astype(jnp.float32), axis=-1)
+    return (w * cfg.router_scale if cfg.router_scale != 1.0 else w), idx
 
 
 def router_weights(cfg: ModelConfig, logits: jnp.ndarray, bias=None):
@@ -128,6 +132,10 @@ def dense_moe(cfg: ModelConfig, lp: Dict[str, Any], x: jnp.ndarray,
     Without such a mesh nothing here changes."""
     logits = router_logits(cfg, lp, x)                            # [B, S, E]
     mix, _ = router_weights(cfg, logits, lp.get("router_bias"))
+    if cfg.router_width:
+        # a share of the experts: the router scored all of them, the
+        # leaves hold ``n_experts`` from ``first_expert`` on
+        mix = mix[..., cfg.first_expert:cfg.first_expert + cfg.n_experts]
 
     up = _qeinsum("bsd,edf->bsef", x, lp["w_up"], "ef_last2")
     if cfg.gated_mlp:
@@ -380,7 +388,8 @@ def grouped_kernel_shape(cfg: ModelConfig, tokens: int) -> Dict[str, int]:
     ``tokens`` tokens over all of ``cfg``'s experts (``/health.moe``,
     tools/time_grouped_kernel.py): a tile's rows and the grid's steps."""
     pairs = tokens * cfg.experts_per_token
-    tm = _group_tile(pairs, cfg.n_experts)
+    tm = _group_tile(pairs * cfg.n_experts // cfg.experts_scored,
+                     cfg.n_experts)
     return {"tile_rows": tm,
             "grid_steps": _grid_tiles(pairs, tm, cfg.n_experts)}
 
@@ -545,7 +554,9 @@ def grouped_moe(cfg: ModelConfig, lp: Dict[str, Any], x: jnp.ndarray,
         e = jnp.where(token_mask.reshape(T, 1) > 0, e, held)
     e = e.reshape(T * k)
     M = T * k
-    tm = _group_tile(M, held)
+    # a share of the experts expects its share of the pairs (a tile's
+    # rows); the grid still has room for every pair landing here
+    tm = _group_tile(M * held // cfg.experts_scored, held)
     n_tiles = _grid_tiles(M, tm, held)
 
     sizes = jnp.sum(e[:, None] == jnp.arange(held)[None, :], axis=0,

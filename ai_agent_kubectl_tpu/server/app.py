@@ -1107,6 +1107,12 @@ async def handle_health(request: web.Request) -> web.Response:
     sah = getattr(svc.engine, "sparse_attention_health", None)
     if callable(sah):
         sparse_attention = sah() or None
+    # Latent attention (ISSUE 38): bytes a token, rows its decode queries
+    # read — cheap host counters, same rule.
+    latent_attention = None
+    lah = getattr(svc.engine, "latent_attention_health", None)
+    if callable(lah):
+        latent_attention = lah() or None
     # Recurrent-state snapshots (ISSUE 33): same cheap host counters.
     ssm = None
     ssh = getattr(svc.engine, "ssm_health", None)
@@ -1166,6 +1172,7 @@ async def handle_health(request: web.Request) -> web.Response:
         kv_pool=kv_pool,
         moe=moe,
         sparse_attention=sparse_attention,
+        latent_attention=latent_attention,
         ssm=ssm,
         sharding=sharding,
         grammar=grammar,
@@ -1512,6 +1519,9 @@ async def handle_metrics(request: web.Request) -> web.Response:
         # Recurrent-state snapshots (ISSUE 33): same delta-mirror.
         if stats.get("ssm"):
             svc.metrics.observe_state_cache(stats["ssm"])
+        # Latent attention (ISSUE 38): same delta-mirror.
+        if stats.get("latent_attention"):
+            svc.metrics.observe_latent_attention(stats["latent_attention"])
         # Tensor-parallel serving (ISSUE 14): mesh device count,
         # residual TP fraction, and the kv_pool_mesh_fallback flag —
         # gauges sampled at scrape time.

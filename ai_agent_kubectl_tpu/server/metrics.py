@@ -260,6 +260,23 @@ class Metrics:
             "the snapshot store",
             registry=r,
         )
+        # Latent attention (ISSUE 38): mirrors of /health.latent_attention.
+        self.latent_row_bytes = Gauge(
+            "latent_cache_row_bytes",
+            "Bytes one token keeps in the block pool of a latent-attention "
+            "model, all layers (0: the model caches K and V)",
+            registry=r,
+        )
+        self.latent_rows = Counter(
+            "latent_attention_rows_total",
+            "Rows of latent attention (decode_rows: decode queries run | "
+            "latent_rows_read: cached rows they had before them, summed "
+            "over layers | window_rows_absorbed | window_rows_expanded: "
+            "prompt rows prefilled, by the form that attended them)",
+            ["kind"],
+            registry=r,
+        )
+        self._latent_seen: dict = {}
         self._state_seen: dict = {}
         self._kv_pool_seen = {"shared": 0, "cow": 0, "hit": 0, "miss": 0,
                               "demoted": 0, "onloaded": 0, "dropped": 0,
@@ -792,6 +809,18 @@ class Metrics:
                 if total > seen[key]:
                     counter.inc(total - seen[key])
                     seen[key] = total
+
+    def observe_latent_attention(self, lat: dict) -> None:
+        """Mirror /health.latent_attention at scrape time: the row's bytes
+        a gauge, the cumulative rows delta-inc'd like the pool's."""
+        self.latent_row_bytes.set(lat.get("row_bytes", 0))
+        for kind in ("decode_rows", "latent_rows_read",
+                     "window_rows_absorbed", "window_rows_expanded"):
+            total = lat.get(kind, 0)
+            if total > self._latent_seen.get(kind, 0):
+                self.latent_rows.labels(kind=kind).inc(
+                    total - self._latent_seen.get(kind, 0))
+                self._latent_seen[kind] = total
 
     def observe_state_cache(self, ssm: dict) -> None:
         """Mirror /health.ssm (stats()["ssm"]) at scrape time: gauges set

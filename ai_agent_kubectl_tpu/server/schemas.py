@@ -123,6 +123,12 @@ class HealthResponse(BaseModel):
     # configuration has neither.
     moe: Optional[Dict[str, Any]] = None
     sparse_attention: Optional[Dict[str, Any]] = None
+    # Latent attention (ISSUE 38; engine/batcher.py::
+    # latent_attention_health): the compressed cache's bytes a token
+    # (row_bytes, all layers), decode queries run and the cached rows they
+    # read (summed over layers), prompt rows prefilled by the absorbed and
+    # by the expanded form. None for a model that caches K and V.
+    latent_attention: Optional[Dict[str, Any]] = None
     # Recurrent-state cache of a model with state-space layers (ISSUE 33;
     # engine/kv_pool.py::StateStore.stats, batcher.py::ssm_health):
     # snapshots held / capacity / bytes and their peak, snapshots taken /

@@ -69,11 +69,13 @@ def test_declared_in_the_benchmark_like_its_file(name):
     for key in ("unit", "better", "source", "layer", "moves"):
         assert by[name][key] == spec[key], (name, key)
     assert "/health.spans" in spec["what"]
-    # appended: what the benchmark had keeps its place
+    # appended in this order, and kept so by what later PRs append after them
     names = [m["name"] for m in bench["per_layer"]]
-    assert names[-4:] == ["radix_evict_ms_per_chunk",
-                          "eager_prefill_ms_per_chunk",
-                          "admit_launch_ms_per_chunk", "pipe_starved_share"]
+    first = names.index("radix_evict_ms_per_chunk")
+    assert names[first:first + 4] == ["radix_evict_ms_per_chunk",
+                                      "eager_prefill_ms_per_chunk",
+                                      "admit_launch_ms_per_chunk",
+                                      "pipe_starved_share"]
     # every cell reports the end-to-end metric it moves
     for cell in bench["workloads"]:
         reported = {m["name"] for m in

@@ -636,3 +636,103 @@ def test_patterned_forward_compiles_at_published_widths_on_v5e(one_chip, B, W,
     assert mem.alias_size_in_bytes >= (2 * math.prod(pool) * 2
                                        + math.prod(ssm.shape) * 4)
     assert mem.temp_size_in_bytes < 2 ** 30, mem.temp_size_in_bytes
+
+
+# ------------------- latent attention and a share of the experts (ISSUE 38)
+
+MISTRAL4 = dict(vocab_size=131072, dim=4096, n_heads=32, n_kv_heads=32,
+                head_dim=128, mlp_hidden=2048, eos_ids=(2,), n_experts=32,
+                experts_per_token=4, router_width=128, shared_mlp_hidden=2048,
+                dense_mlp_hidden=12288, q_lora_rank=1024, kv_lora_rank=256,
+                qk_nope_head_dim=64, qk_rope_head_dim=64, v_head_dim=128,
+                rope_interleave=True, rope_factor=128.0, rope_original_max=8192,
+                rope_mscale=1.0, rope_mscale_all_dim=1.0, q_scale_beta=0.1)
+
+
+@pytest.mark.parametrize("B,W", [(16, 1), (16, 64), (1, 512)],
+                         ids=["decode", "window-64", "eager-512"])
+def test_latent_forward_compiles_at_published_widths_on_v5e(one_chip, B, W,
+                                                             monkeypatch):
+    """mistral-small-4-119b-2603-l9's block (2 layers, every width as
+    published, the engine's 513-page table over the cell's 8,192-block pool):
+    Mosaic accepts the ragged kernel in its latent form — ONE leaf of 640
+    lanes a pair of tokens streamed through the ring, 32 query heads over one
+    key row, a decode row and a 16-column prefill tile — and the grouped
+    expert kernel over the 32 int8 experts of 4096 x 2048 this chip holds of
+    the 128 the router scores; the latent leaf rides the donated cache and
+    is the whole of it (640 B a token a layer, not a lane more); the write
+    is one gather of the window's rows and one in-place scatter; nothing
+    pool-sized or expert-stack-sized moves."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = ModelConfig(name="aot-mistral4", n_layers=2, **MISTRAL4)
+    page, n_blocks, pages = 64, 8192, 513
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda x: arg(x.shape, x.dtype),
+        jax.eval_shape(lambda k: random_params_int8(
+            k, cfg, dtype=jnp.bfloat16, quantize_embed=True),
+            jax.random.PRNGKey(0)))
+    leaf = (cfg.n_layers, n_blocks, page // 2, 2 * cfg.latent_row)
+    assert leaf[-1] == 640 and cfg.latent_row * 2 == 640     # bytes a token a layer
+    cache = KVCache(k=None, v=None, lengths=arg((n_blocks,), jnp.int32),
+                    lat=arg(leaf, jnp.bfloat16),
+                    lat_rows=arg((2,), jnp.int32),
+                    experts_read=arg((), jnp.int32))
+
+    def step(params, tok, pos, cache, wmask, tables, q_lens):
+        return forward(params, cfg, tok, pos, cache, kv_limit=pages * page,
+                       attn_impl="ragged", token_mask=wmask,
+                       write_mask=wmask, block_tables=tables, q_lens=q_lens,
+                       logits_at=jnp.maximum(q_lens, 1) - 1)
+
+    traced = jax.jit(step, donate_argnums=(3,)).trace(
+        params, arg((B, W), jnp.int32), arg((B, W), jnp.int32), cache,
+        arg((B, W), jnp.bool_), arg((B, pages), jnp.int32),
+        arg((B,), jnp.int32))
+    # the latent ring: 4 buffers of 8 pages of [32 pair rows, 640 lanes]
+    assert set(_ragged_kv_buffers(traced.jaxpr.jaxpr)) == {(4, 8, 32, 640)}
+    # a quarter of the pairs are expected here: 16 rows a tile at decode, 32
+    # where 16 x 64 x 4 / 4 pairs over 32 experts are 32 rows an expert
+    calls = set(_grouped_kernel_calls(traced.jaxpr.jaxpr))
+    assert {c[0] for c in calls} == {{1: 16, 64: 48, 512: 32}[W]}, calls
+    compiled = traced.lower().compile()
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+    assert not _results_of_size(hlo, {32 * 4096 * 2048}), "an expert stack moved"
+    moved = _results_of_size(hlo, {math.prod(leaf), math.prod(leaf[1:])})
+    assert {op for op, _ in moved} <= {"scatter", "fusion"}, moved
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= math.prod(leaf) * 2
+    assert mem.temp_size_in_bytes < 2 ** 30, mem.temp_size_in_bytes
+    print(f"\nAOT mistral4 B={B} W={W}: temp {mem.temp_size_in_bytes / 2**20:.0f} MiB, "
+          f"arguments {mem.argument_size_in_bytes / 2**30:.2f} GiB, "
+          f"aliased {mem.alias_size_in_bytes / 2**30:.2f} GiB")
+
+
+def test_latent_copy_on_write_is_in_place_on_v5e(one_chip):
+    """The cell's pool (9 layers, 8,192 blocks: 2.81 GiB): the engine's
+    copy-on-write program holds no leaf-sized temporary. The row form's
+    scatter across the layers made the compiler lay the leaf out layer-
+    innermost: a copy of the pool in and one out, 5.0 GiB that did not fit
+    beside the weights (the server died loading ``jit_cow``: my chip run,
+    PR 38)."""
+    import types
+
+    from ai_agent_kubectl_tpu.engine.batcher import BatchedJaxEngine
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    leaf = (9, 8192, 32, 640)
+    cache = KVCache(k=None, v=None, lengths=arg((8192,), jnp.int32),
+                    lat=arg(leaf, jnp.bfloat16), lat_rows=arg((2,), jnp.int32),
+                    experts_read=arg((), jnp.int32))
+    cow = BatchedJaxEngine._pool_cow_fn.fget(
+        types.SimpleNamespace(kv_pool_page=64, mesh=None))
+    scalar = arg((), jnp.int32)
+    mem = cow.lower(cache, scalar, scalar, scalar).compile().memory_analysis()
+    assert mem.alias_size_in_bytes >= math.prod(leaf) * 2
+    assert mem.temp_size_in_bytes < 2**24
