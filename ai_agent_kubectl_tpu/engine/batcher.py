@@ -70,7 +70,8 @@ from .kv_pool import (BlockPool, HostBlockStore, StateStore,
                       alloc_with_evict, map_prefix, pages_for, release_state,
                       state_cuts, take_snapshot)
 from .radix_cache import RadixCache
-from .regime import DENSE, RAGGED, resolve_attention_regime
+from .regime import (DENSE, RAGGED, resolve_attention_regime,
+                     stage_window)
 from .protocol import (HEALTH_GRAMMAR_DEAD, HEALTH_NONFINITE,
                        HEALTH_TOKEN_RANGE, EngineOverloaded,
                        EngineResult, EngineUnavailable, GenerationTimeout,
@@ -808,11 +809,12 @@ def staged_suffix_len(suffix: int, buckets) -> int:
     """How many of an admission's ``suffix`` unmatched tokens ride the next
     chunk's window; the rest, its head, prefills eagerly. A suffix the
     widest window holds rides whole. A longer one's head is eager
-    whatever rides, and a window costs EVERY slot of the batch its width
-    (N x W rows through the projections and the MLP, where an eager piece
-    pays for one slot's), so it rides the narrowest: 64 rows against 512
-    read first_chunk 1,207 against 1,568 ms on 11k-token prompts (my chip
-    runs, PR 31)."""
+    whatever rides, so it rides the narrowest. That rule was settled
+    while a window cost every slot of the batch its width (64 rows
+    against 512 read first_chunk 1,207 against 1,568 ms on 11k-token
+    prompts: my chip runs, PR 31); since ISSUE 39 a window costs its
+    valid rows, and how much of a long prompt's head should ride it is
+    open (ROADMAP S1 a)."""
     return suffix if suffix <= buckets[-1] else buckets[0]
 
 
@@ -1195,8 +1197,16 @@ class BatchedJaxEngine(JaxEngine):
         self._ragged_chunk_fns: dict = {}   # (adm width, spec) -> jitted
         # slot_idx -> staged admission (ids/start/ngen0/budget/seed/
         # temp/gs): the unmatched prompt suffix rides the NEXT chunk as
-        # a long-q_len slot instead of a separately compiled prefill.
+        # a long-q_len slot instead of a separately compiled prefill
+        # (in arrival order: the staging rule reads it, ``stage_window``).
         self._pending_adm: dict = {}
+        # /health.ragged.window (ISSUE 39), the scheduler's arithmetic at
+        # dispatch: chunks that carried a window, the rows their slots
+        # brought (staged suffixes + one a rider), the rows the prologue
+        # computed (width + batch; slot x width before the rows were
+        # packed), and staged suffixes that waited a chunk for room.
+        self._window_counts = dict.fromkeys(
+            ("windows", "rows_valid", "rows_computed", "deferred"), 0)
         # Grammar-constrained decoding (ISSUE 11): the kubectl token
         # FSM masks sampling device-side and forced runs fast-forward
         # as suffix prefills. Requires device termination (the FSM
@@ -1870,7 +1880,10 @@ class BatchedJaxEngine(JaxEngine):
             pick each row's valid prefix, the 2-D write mask gates the
             KV scatter to exactly those columns, and logits_at keeps
             only the last valid position's row (the one the fold
-            samples from)."""
+            samples from). Its residual stream is the window's valid
+            rows, packed (ISSUE 39): the staged suffixes' lengths sum to
+            at most W (``regime.stage_window``) and every other slot rides one
+            column, so W + N rows hold them, where N x W were computed."""
 
             def rstep(params, tok, pos, cache, wmask, tables, q_lens):
                 return forward(params, cfg, tok, pos, cache,
@@ -1882,7 +1895,8 @@ class BatchedJaxEngine(JaxEngine):
                                write_mask=wmask,
                                block_tables=tables,
                                q_lens=q_lens,
-                               logits_at=jnp.maximum(q_lens, 1) - 1)
+                               logits_at=jnp.maximum(q_lens, 1) - 1,
+                               packed_rows=sum(tok.shape))
 
             return rstep
 
@@ -3243,6 +3257,14 @@ class BatchedJaxEngine(JaxEngine):
                 if cfg.selects_keys else None),
         }
 
+    def ragged_health(self) -> Optional[dict]:
+        """/health.ragged: what the mixed chunks' windows carried and
+        cost (``_window_counts``; cumulative). None off the ragged
+        regime."""
+        if not self._use_ragged:
+            return None
+        return {"window": dict(self._window_counts)}
+
     def moe_health(self) -> Optional[dict]:
         """/health.moe: experts whose weights the grouped expert path
         read, and the layer passes they were read in, both over the chunk
@@ -3266,7 +3288,7 @@ class BatchedJaxEngine(JaxEngine):
                 "kernel": {
                     "decode": grouped_kernel_shape(cfg, self.batch_size),
                     "widest_window": grouped_kernel_shape(
-                        cfg, self.batch_size * wide),
+                        cfg, self.batch_size + wide),
                     "eager_piece": grouped_kernel_shape(cfg, wide)}}
 
     def ssm_health(self) -> Optional[dict]:
@@ -3997,6 +4019,7 @@ class BatchedJaxEngine(JaxEngine):
             "sparse_attention": self.sparse_attention_health(),
             "latent_attention": self.latent_attention_health(),
             "ssm": self.ssm_health(),
+            "ragged": self.ragged_health(),
             "sharding": self.sharding_health(),
             "queue_rejections": self._rejections,
             "max_queue_depth": self.max_queue_depth,
@@ -5610,24 +5633,33 @@ class BatchedJaxEngine(JaxEngine):
                     "non-speculative decode")
         spec = self._spec_active()
         ct = self._chunk_tokens if spec else self.chunk_len
-        # Ragged staged admissions (ISSUE 19): every pending suffix
-        # window rides THIS chunk — the prologue prefills, samples, and
-        # arms them in the same program dispatch as everyone else's
-        # decode/verify step. The admission width is the smallest
-        # prefill bucket covering the longest staged suffix; a spec
-        # chunk's row widens by the prologue's one token.
+        # Ragged staged admissions (ISSUE 19): pending suffix windows
+        # ride THIS chunk — the prologue prefills, samples, and arms
+        # them in the same program dispatch as everyone else's
+        # decode/verify step. Which of them, and how wide the window
+        # is, is ``stage_window``'s rule (ISSUE 39: the prologue
+        # computes the window's valid rows, so their lengths add up);
+        # the ones that do not fit stay staged, their slots sit this
+        # chunk out (``deferred``) and they head the next one's line. A
+        # spec chunk's row widens by the prologue's one token.
         adm_w: Optional[int] = None
         adm_args: tuple = ()
         staged: dict = {}
+        deferred: set = set()
         if self._use_ragged and self._pending_adm:
-            staged = {i: e for i, e in self._pending_adm.items()
-                      if self._slots[i] is not None
-                      and not self._slots[i].exhausted}
-            self._pending_adm.clear()
+            waiting = {i: e for i, e in self._pending_adm.items()
+                       if self._slots[i] is not None
+                       and not self._slots[i].exhausted}
+            taken, width = stage_window(
+                [len(e["ids"]) for e in waiting.values()],
+                self.prefill_buckets)
+            adm_w = width or None
+            line = list(waiting.items())
+            staged = dict(line[:taken])
+            self._pending_adm = dict(line[taken:])
+            deferred = set(self._pending_adm)
         t_disp = time.monotonic()
         if staged:
-            longest = max(len(e["ids"]) for e in staged.values())
-            adm_w = next(b for b in self.prefill_buckets if b >= longest)
             if spec:
                 ct = self._chunk_tokens + 1
             N = self.batch_size
@@ -5654,8 +5686,12 @@ class BatchedJaxEngine(JaxEngine):
                 a_temp))
             if self._grammar is not None:
                 adm_args = adm_args + (jnp.asarray(a_gs),)
-        active_slots = [s for s in self._slots
-                        if s is not None and not s.exhausted]
+        def rides(i: int) -> bool:
+            s = self._slots[i]
+            return s is not None and not s.exhausted and i not in deferred
+
+        active_slots = [self._slots[i] for i in range(self.batch_size)
+                        if rides(i)]
         if not active_slots:
             return
         if self._use_pool:
@@ -5665,16 +5701,14 @@ class BatchedJaxEngine(JaxEngine):
             # live span needs). A slot the pool can't serve is marked
             # exhausted and excluded from this chunk.
             for i, s in enumerate(self._slots):
-                if s is not None and not s.exhausted:
+                if rides(i):
                     self._pool_ensure_coverage(i, s, ct)
-            active_slots = [s for s in self._slots
-                            if s is not None and not s.exhausted]
+            active_slots = [self._slots[i] for i in range(self.batch_size)
+                            if rides(i)]
             if not active_slots:
                 return
-        force = jnp.asarray(
-            [s is not None and not s.exhausted for s in self._slots],
-            jnp.bool_,
-        )
+        force = jnp.asarray([rides(i) for i in range(self.batch_size)],
+                            jnp.bool_)
         # Smallest KV bucket covering every live position this chunk can
         # reach: decode attention cost tracks actual sequence lengths, not
         # max_seq. Buckets only grow, so recently-admitted short sequences
@@ -5733,10 +5767,8 @@ class BatchedJaxEngine(JaxEngine):
             bucket, force, corrupt_d,
             self._tables_d(self._tables) if self._use_pool else None,
             spec=spec, adm_w=adm_w, adm_args=adm_args)
-        snapshot = [
-            s.req if s is not None and not s.exhausted else None
-            for s in self._slots
-        ]
+        snapshot = [self._slots[i].req if rides(i) else None
+                    for i in range(self.batch_size)]
         for s in active_slots:
             s.pos += ct
             s.chunks_inflight += 1
@@ -5750,6 +5782,15 @@ class BatchedJaxEngine(JaxEngine):
         self._inflight.append(("chunk", packed_d, snapshot, ct, spec,
                                self._chunks_dispatched))
         entry["pipe_empty_ms"] = self._spans.note_pipe(self._inflight)
+        if staged:
+            wc = self._window_counts
+            wc["windows"] += 1
+            wc["rows_valid"] += (
+                sum(len(e["ids"]) for e in staged.values())
+                + sum(rides(i) and i not in staged
+                      for i in range(self.batch_size)))
+            wc["rows_computed"] += adm_w + self.batch_size
+            wc["deferred"] += len(deferred)
         for i in staged:
             # This chunk carries slot i's prologue: its stage_wait ends
             # where this dispatch began, behind the chunks already queued.
@@ -5757,7 +5798,8 @@ class BatchedJaxEngine(JaxEngine):
                 t_disp, self._chunks_dispatched, chunks_ahead, adm_w=adm_w,
                 ctx_tokens=self._slots[i].n_prompt)
         entry.update(kv_bucket=bucket, slots=len(active_slots),
-                     admissions=len(staged), pipe=chunks_ahead + 1)
+                     admissions=len(staged), adm_w=adm_w or 0,
+                     pipe=chunks_ahead + 1)
 
     # ----------------------------------------------------------- watchdog
 

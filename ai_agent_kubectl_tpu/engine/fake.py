@@ -31,7 +31,8 @@ from ..obs.ledger import (CLASS_DELIVERED, CLASS_DRAFT_REJECTED,
                           CLASS_WASTED_MASKED, GoodputLedger)
 from ..obs.slo import (SLO_QUEUE_WAIT, SLO_SESSION_TTFT, SLO_TTFT,
                        SloEngine)
-from ..obs.steptime import (PHASE_DECODE, PHASE_PREFILL,
+from ..obs.steptime import (DEFAULT_PREFILL_BUCKETS, PHASE_DECODE,
+                            PHASE_PREFILL,
                             PHASE_SPEC_VERIFY, StepTimeSentinel,
                             prefill_bucket)
 from ..obs.trace import EngineSpans, RequestSpans, current_trace
@@ -43,7 +44,7 @@ from .kv_pool import (BlockPool, HostBlockStore, PoolExhausted, StateStore,
                       alloc_with_evict, map_prefix, pages_for, release_state,
                       state_cuts, take_snapshot)
 from .radix_cache import RadixCache
-from .regime import RAGGED, resolve_attention_regime
+from .regime import RAGGED, resolve_attention_regime, stage_window
 from .protocol import (HEALTH_GRAMMAR_DEAD, HEALTH_NONFINITE,
                        EngineOverloaded, EngineResult, EngineUnavailable,
                        GenerationTimeout, RequestExport,
@@ -430,10 +431,15 @@ class FakeChunkedEngine:
             device_termination=self.device_termination,
             pool_page=self.kv_pool_page, force_ragged=force_ragged)
         self._use_ragged = self._attention_regime == RAGGED
-        # Admission width of staged (deferred-first-token) admissions
-        # since the last dispatch — keys that dispatch's sentinel
-        # sample as a ragged prefill phase (mirror of the batcher).
-        self._pending_adm_w = 0
+        # Staged (deferred-first-token) admissions waiting for a chunk,
+        # slot -> (request, tokens staged), in arrival order: the next
+        # dispatch carries as many as the staging rule lets ride
+        # (``stage_window``, the batcher's) and its sentinel sample is
+        # a ragged prefill phase keyed by their window's width.
+        self._pending_adm: Dict[int, tuple] = {}
+        # /health.ragged.window (mirror of the batcher's counters)
+        self._window_counts = dict.fromkeys(
+            ("windows", "rows_valid", "rows_computed", "deferred"), 0)
         # Grammar-constrained decoding mirror (ISSUE 11): the SAME
         # GrammarRuntime/TokenFSM compile the batcher runs, built
         # against the ByteTokenizer the fake's grammar streams use
@@ -680,6 +686,13 @@ class FakeChunkedEngine:
         """/health.ssm: the snapshot store's counters (mirror of the
         batcher's; None unless the fake plays a state-keeping model)."""
         return self._state.stats() if self._state is not None else None
+
+    def ragged_health(self) -> Optional[dict]:
+        """/health.ragged (mirror of the batcher's; None off the ragged
+        regime)."""
+        if not self._use_ragged:
+            return None
+        return {"window": dict(self._window_counts)}
 
     def kv_pool_health(self) -> Optional[dict]:
         """Cheap pool view for /health (mirror of the batcher's)."""
@@ -931,6 +944,7 @@ class FakeChunkedEngine:
                                 slot_health_check=self.slot_health_check),
             "kv_pool": self.kv_pool_health(),
             "ssm": self.ssm_health(),
+            "ragged": self.ragged_health(),
             "ledger": self.ledger.snapshot(),
             "slo": self._slo.snapshot(),
             "grammar": self.grammar_health(),
@@ -1411,9 +1425,10 @@ class FakeChunkedEngine:
                 # chunk — that dispatch's sentinel sample is a PREFILL
                 # phase keyed by the admission width, not a decode
                 # sample (mirror of the batcher's mixed-chunk keying).
-                self._pending_adm_w = max(
-                    self._pending_adm_w,
-                    prefill_bucket(len(req.prompt_ids)))
+                self._pending_adm.pop(i, None)
+                self._pending_adm[i] = (req, min(
+                    max(len(req.prompt_ids), 1),
+                    DEFAULT_PREFILL_BUCKETS[-1]))
             else:
                 # Sentinel prefill sample (mirror of the batcher's
                 # admission→first-token measurement; the fake's
@@ -1489,8 +1504,26 @@ class FakeChunkedEngine:
         # Ragged admission (ISSUE 19): a chunk carrying a staged
         # admission is a PREFILL-phase sample keyed by the admission
         # width, so mixed chunks never pollute the decode digests
-        # (mirror of the batcher's keying).
-        adm_w, self._pending_adm_w = self._pending_adm_w, 0
+        # (mirror of the batcher's keying). Which staged admissions ride
+        # is the batcher's rule too (ISSUE 39): in arrival order while
+        # their tokens sum to at most the widest bucket; the rest sit
+        # this chunk out and head the next one's line.
+        waiting = {i: n for i, (req, n) in self._pending_adm.items()
+                   if self._slots[i] is not None
+                   and self._slots[i].req is req}
+        taken, adm_w = stage_window(list(waiting.values()),
+                                    DEFAULT_PREFILL_BUCKETS)
+        line = list(waiting)
+        staged = {i: waiting[i] for i in line[:taken]}
+        self._pending_adm = {i: self._pending_adm[i] for i in line[taken:]}
+        deferred = set(self._pending_adm)
+        n_live -= len(deferred)
+        if staged:
+            wc = self._window_counts
+            wc["windows"] += 1
+            wc["rows_valid"] += sum(staged.values()) + n_live - len(staged)
+            wc["rows_computed"] += adm_w + self.batch_size
+            wc["deferred"] += len(deferred)
         self._steptime_pending = (
             now,
             PHASE_PREFILL if adm_w else (
@@ -1512,7 +1545,7 @@ class FakeChunkedEngine:
                  for s in self._slots]))
         snapshot: List[Optional[_FakeReq]] = [None] * N
         for i, slot in enumerate(self._slots):
-            if slot is None:
+            if slot is None or i in deferred:
                 continue
             if (self._pool is not None
                     and not self._pool_ensure_coverage(slot, C)):
@@ -1607,7 +1640,8 @@ class FakeChunkedEngine:
                     now, self._chunks_dispatched, chunks_ahead,
                     adm_w=adm_w)
         entry.update(slots=sum(s is not None for s in snapshot),
-                     admissions=int(bool(adm_w)), pipe=chunks_ahead + 1)
+                     admissions=len(staged), adm_w=adm_w,
+                     pipe=chunks_ahead + 1)
 
     def _spec_slot_rows(self, i: int, slot: _FakeSlot, toks, done,
                         lengths, health, drafted, accepted,

@@ -20,7 +20,7 @@ Jax-free: the fake scheduler calls it too.
 
 from __future__ import annotations
 
-from typing import Mapping, Optional, Tuple
+from typing import Mapping, Optional, Sequence, Tuple
 
 RAGGED, GATHER, DENSE = "ragged", "gather", "dense"
 REGIMES = (RAGGED, GATHER, DENSE)
@@ -97,3 +97,25 @@ def resolve_attention_regime(
             f"head_dim={model_cfg.head_dim}")
     return RAGGED, pool_page, (
         "block pool, bf16 KV, device termination, TPU backend")
+
+
+def stage_window(lengths: Sequence[int], buckets: Sequence[int]
+                 ) -> Tuple[int, int]:
+    """The staging rule of a mixed chunk's window (ISSUE 39), both
+    schedulers': ``(taken, width)``. Of the staged suffixes waiting, whose
+    ``lengths`` come in arrival order, the next chunk carries the first
+    ``taken``: as many as SUM to at most the widest bucket, so that the
+    window's valid rows fit the width + batch rows its program computes.
+    ``width`` is the smallest bucket that covers the sum (0 with nothing
+    waiting). The rest wait for the chunk after: order is kept, and the
+    head of the line always rides (a staged suffix is at most the widest
+    bucket long: ``batcher.staged_suffix_len``), so nothing starves."""
+    total = taken = 0
+    for n in lengths:
+        if taken and total + n > buckets[-1]:
+            break
+        total += n
+        taken += 1
+    if not taken:
+        return 0, 0
+    return taken, next(b for b in buckets if b >= total)

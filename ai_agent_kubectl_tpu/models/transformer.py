@@ -27,7 +27,7 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -408,6 +408,60 @@ def _pool_gather(leaf, tables, n_pages: int, layer=None):
                      + leaf.shape[i + 2:])
 
 
+# ------------------------------------------------ a window's valid rows
+#
+# A mixed admission window (ISSUE 19) is [N, W]: N slots, each with its
+# first q_lens[n] columns valid (a staged suffix, or one decode token). Its
+# residual stream carries only those rows, packed (ISSUE 39): R = W + N
+# rows at most, where slot x width was 16 x 128 = 2,048 rows of GEMM for
+# the 115 that were valid. The mixers (paged attention, the key selector,
+# the state-space scan) and the cache writes keep their [N, W, ...]
+# operands; ``unpack`` and ``pack`` are the two gathers around them.
+
+
+class WindowRows(NamedTuple):
+    """The valid rows of an [N, W] window in slot order, then padding rows
+    (``valid`` False; they repeat row 0's indices and nothing reads what
+    they compute)."""
+
+    slot: jnp.ndarray     # [R] int32: the row's slot
+    col: jnp.ndarray      # [R] int32: its column of the window
+    valid: jnp.ndarray    # [1, R] bool: the packed token mask
+    pos: jnp.ndarray      # [1, R] int32: its absolute position
+    row_of: jnp.ndarray   # [N, W] int32: the packed row of (slot, column);
+                          # an invalid column names some other row, whose
+                          # values the mixers mask and the writes drop
+
+    def pack(self, x: jnp.ndarray) -> jnp.ndarray:
+        """[N, W, ...] -> [1, R, ...]"""
+        return x[self.slot, self.col][None]
+
+    def unpack(self, x: jnp.ndarray) -> jnp.ndarray:
+        """[1, R, ...] -> [N, W, ...]"""
+        return x[0][self.row_of]
+
+
+def window_rows(q_lens: jnp.ndarray, positions: jnp.ndarray,
+                n_rows: int) -> WindowRows:
+    """Pack an [N, W] window (``positions``) whose slot n brings its first
+    ``q_lens[n]`` columns into ``n_rows`` rows. The caller sees to it that
+    they fit: the scheduler stages suffixes whose lengths sum to at most
+    W, the other slots ride one column each, so W + N rows hold them."""
+    N, W = positions.shape
+    q_lens = q_lens.astype(jnp.int32)
+    end = jnp.cumsum(q_lens)
+    first = end - q_lens
+    r = jnp.arange(n_rows, dtype=jnp.int32)
+    valid = r < end[-1]
+    slot = jnp.where(valid, jnp.minimum(
+        jnp.searchsorted(end, r, side="right").astype(jnp.int32), N - 1), 0)
+    col = jnp.where(valid, r - first[slot], 0)
+    row_of = jnp.minimum(
+        first[:, None] + jnp.arange(W, dtype=jnp.int32)[None, :], n_rows - 1)
+    return WindowRows(slot, col, valid[None], positions[slot, col][None],
+                      row_of)
+
+
 # ------------------------------------------------- residual sharding
 #
 # f≈1 residual-path TP sharding (ISSUE 14): with weights Megatron-split
@@ -655,7 +709,7 @@ def _select_and_attend(cfg: ModelConfig, attn_impl: str, q, qi, wi,
 
 def _latent_attention(cfg: ModelConfig, attn_impl: str, x, lp: Params,
                       layer_lat, positions, kv_limit: int, token_mask,
-                      write_mask, block_tables, q_lens, layer):
+                      write_mask, block_tables, q_lens, layer, win=None):
     """Latent attention (MLA) of the normed hidden state ``x`` [B, S, d]
     over the block pool's latent leaf (ops/latent_attention.py has the two
     forms; this is the ABSORBED one for every row, decode and window
@@ -663,7 +717,9 @@ def _latent_attention(cfg: ModelConfig, attn_impl: str, x, lp: Params,
     window's are the same row). Returns (the layer's attention output
     through ``wo`` [B, S, d], the leaf with the window's rows written,
     int32 [2]: the call's live decode rows and the cached rows those had
-    before them).
+    before them). With ``win`` ``x`` is the [1, R, d] packed rows of the
+    [B, S] window (``_layer``): the projections, the rotary and both
+    absorptions run packed, the write and the attention on the window.
 
     The sizes are the configuration's latent ones, never ``head_dim``. The
     softmax scale (``qk_head_dim ** -0.5`` times YaRN's temperature
@@ -682,12 +738,14 @@ def _latent_attention(cfg: ModelConfig, attn_impl: str, x, lp: Params,
             f"{cfg.name} keeps a latent cache (kv_lora_rank="
             f"{cfg.kv_lora_rank}): its rows live in the block pool, so it "
             "is served through the pool alone (KV_POOL, one device)")
-    B, S, _ = x.shape
+    B, S = positions.shape
+    Bh, Sh, _ = x.shape
+    rope_pos = positions if win is None else win.pos
     H, C = cfg.n_heads, cfg.kv_lora_rank
     N, R, V = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     with jax.named_scope("qkv_proj"):
         cq = rms_norm(qmatmul(x, lp["w_dq"]), lp["dq_norm"], cfg.rms_eps)
-        q = qmatmul(cq, lp["w_uq"]).reshape(B, S, H, N + R)
+        q = qmatmul(cq, lp["w_uq"]).reshape(Bh, Sh, H, N + R)
         ckr = qmatmul(x, lp["w_dkv"])
         c = rms_norm(ckr[..., :C], lp["dkv_norm"], cfg.rms_eps)
     with jax.named_scope("rope"):
@@ -696,17 +754,17 @@ def _latent_attention(cfg: ModelConfig, attn_impl: str, x, lp: Params,
             cfg.rope_beta_fast, cfg.rope_beta_slow)
         factor = (yarn_mscale(cfg.rope_factor, cfg.rope_mscale)
                   / yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim))
-        q_r = apply_rope_scaled(q[..., N:], positions, inv_freq,
+        q_r = apply_rope_scaled(q[..., N:], rope_pos, inv_freq,
                                 cfg.rope_interleave, factor)
-        kr = apply_rope_scaled(ckr[..., None, C:], positions, inv_freq,
+        kr = apply_rope_scaled(ckr[..., None, C:], rope_pos, inv_freq,
                                cfg.rope_interleave, factor)[:, :, 0]
     w_ukv = lp["w_ukv"].reshape(C, H, N + V)
     with jax.named_scope("qkv_proj"):
         scale = ((N + R) ** -0.5
                  * yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim) ** 2)
-        g = scale * (query_scale(positions, cfg.q_scale_beta,
+        g = scale * (query_scale(rope_pos, cfg.q_scale_beta,
                                  cfg.rope_original_max)
-                     if cfg.q_scale_beta else jnp.ones(positions.shape,
+                     if cfg.q_scale_beta else jnp.ones(rope_pos.shape,
                                                        jnp.float32))
         g = g[..., None, None]
         # float32 operands (a TPU multiplies them as bf16 and accumulates
@@ -715,6 +773,10 @@ def _latent_attention(cfg: ModelConfig, attn_impl: str, x, lp: Params,
                          w_ukv[..., :N].astype(jnp.float32))
         q_c = (q_c * g).astype(x.dtype)
         q_r = (q_r.astype(jnp.float32) * g).astype(x.dtype)
+    if win is not None:
+        with jax.named_scope("window_unpack"):
+            q_c, q_r, c, kr = (win.unpack(q_c), win.unpack(q_r),
+                               win.unpack(c), win.unpack(kr))
 
     n_blocks, half_page = layer_lat.shape[-3:-1]
     page = 2 * half_page
@@ -738,8 +800,10 @@ def _latent_attention(cfg: ModelConfig, attn_impl: str, x, lp: Params,
             mask = jnp.arange(kv_limit)[None, None, :] <= positions[:, :, None]
             o_c = absorbed_attention(q_c, q_r, c_ctx, kr_ctx, mask)
     with jax.named_scope("o_proj"):
+        if win is not None:
+            o_c = win.pack(o_c)
         o = jnp.einsum("bshc,chv->bshv", o_c, w_ukv[..., N:])
-        out = qmatmul(o.reshape(B, S, H * V), lp["wo"])
+        out = qmatmul(o.reshape(Bh, Sh, H * V), lp["wo"])
     decode = ql == 1
     if token_mask is not None:
         decode = jnp.logical_and(decode, token_mask[:, 0] > 0)
@@ -759,7 +823,8 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
            block_tables=None,
            q_lens=None,
            layer=None,
-           layer_ik=None) -> Tuple[jnp.ndarray, ...]:
+           layer_ik=None,
+           win: Optional[WindowRows] = None) -> Tuple[jnp.ndarray, ...]:
     """One transformer block. Returns (h_out, new_layer_k, new_layer_v,
     new_layer_ik, counts): ``layer_ik`` is the index-key leaf of a
     selecting configuration — or the latent leaf of a latent-attention
@@ -791,16 +856,25 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
     slots terminated mid-chunk by the device-resident done mask
     (engine/batcher.py) stop mutating their cache region instead of
     rewriting garbage at a frozen position every remaining step.
+
+    ``win`` (ISSUE 39): ``h`` is then the [1, R, d] packed valid rows of
+    the [N, W] window that ``positions``, the masks and ``q_lens``
+    describe. Norms, projections, rotary and the MLP run on the packed
+    rows; q, k, v (and a selector's operands) are unpacked to [N, W, ...]
+    for the cache write and the attention, whose output is packed again.
     """
-    B, S, d = h.shape
+    B, S = positions.shape              # the window the mixer sees
+    Bh, Sh, d = h.shape                 # the rows the residual carries
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     counts = {}
+    rope_pos = positions if win is None else win.pos
+    mlp_mask = token_mask if win is None else win.valid
 
     def mlp_block(h):
         x = rms_norm(h, lp["mlp_norm"], cfg.rms_eps, cfg.rms_offset)
         if not cfg.is_moe:
             return _dense_mlp(cfg, lp, x)
-        y, n_read = _moe_mlp(cfg, lp, x, mesh, token_mask, moe_impl, layer)
+        y, n_read = _moe_mlp(cfg, lp, x, mesh, mlp_mask, moe_impl, layer)
         if n_read is not None:
             counts["experts_read"] = n_read
         if cfg.shared_mlp_hidden:
@@ -818,18 +892,27 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
             mlp = mlp_block(h)
         return _shard_residual(mesh, h + mlp)
 
+    def out_proj(attn):
+        """The attention's [B, S, H, hd] output through ``wo`` onto the
+        residual (packed first where the residual is)."""
+        with jax.named_scope("o_proj"):
+            if win is not None:
+                attn = win.pack(attn)
+            return _shard_residual(
+                mesh, h + qmatmul(attn.reshape(Bh, Sh, H * hd), lp["wo"]))
+
     with jax.named_scope("attn_norm"):
         x = rms_norm(h, lp["attn_norm"], cfg.rms_eps, cfg.rms_offset)
     if cfg.latent:
         out, layer_ik, counts["lat_rows"] = _latent_attention(
             cfg, attn_impl, x, lp, layer_ik, positions, kv_limit,
-            token_mask, write_mask, block_tables, q_lens, layer)
+            token_mask, write_mask, block_tables, q_lens, layer, win)
         return (after_attention(_shard_residual(mesh, h + out)),
                 layer_k, layer_v, layer_ik, counts)
     with jax.named_scope("qkv_proj"):
-        q = qmatmul(x, lp["wq"]).reshape(B, S, H, hd)
-        k = qmatmul(x, lp["wk"]).reshape(B, S, KV, hd)
-        v = qmatmul(x, lp["wv"]).reshape(B, S, KV, hd)
+        q = qmatmul(x, lp["wq"]).reshape(Bh, Sh, H, hd)
+        k = qmatmul(x, lp["wk"]).reshape(Bh, Sh, KV, hd)
+        v = qmatmul(x, lp["wv"]).reshape(Bh, Sh, KV, hd)
         if cfg.qk_norm:
             q = rms_norm(q, lp["q_norm"], cfg.rms_eps)
             k = rms_norm(k, lp["k_norm"], cfg.rms_eps)
@@ -837,16 +920,23 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
             # The indexer reads the same normed hidden state: its
             # queries, its ONE key a token, its per-head weights.
             J, di = cfg.index_heads, cfg.index_head_dim
-            qi = qmatmul(x, lp["idx_wq"]).reshape(B, S, J, di)
-            ki = qmatmul(x, lp["idx_wk"]).reshape(B, S, 1, di)
+            qi = qmatmul(x, lp["idx_wq"]).reshape(Bh, Sh, J, di)
+            ki = qmatmul(x, lp["idx_wk"]).reshape(Bh, Sh, 1, di)
             wi = x @ lp["idx_ww"]
     with jax.named_scope("rope"):
         if cfg.use_rope:
-            q = apply_rope(q, positions, cfg.rope_theta)
-            k = apply_rope(k, positions, cfg.rope_theta)
+            q = apply_rope(q, rope_pos, cfg.rope_theta)
+            k = apply_rope(k, rope_pos, cfg.rope_theta)
         if cfg.selects_keys:
-            qi = apply_rope(qi, positions, cfg.rope_theta)
-            ki = apply_rope(ki, positions, cfg.rope_theta)[:, :, 0]
+            qi = apply_rope(qi, rope_pos, cfg.rope_theta)
+            ki = apply_rope(ki, rope_pos, cfg.rope_theta)[:, :, 0]
+    if win is not None:
+        # the mixer's operands, a slot a row of the window again
+        with jax.named_scope("window_unpack"):
+            q, k, v = win.unpack(q), win.unpack(k), win.unpack(v)
+            if cfg.selects_keys:
+                qi, ki, wi = (win.unpack(qi), win.unpack(ki),
+                              win.unpack(wi))
     if cfg.selects_keys and (block_tables is None or layer_ik is None):
         raise NotImplementedError(
             f"{cfg.name} selects its keys (index_topk={cfg.index_topk}): "
@@ -941,10 +1031,8 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
                                                   positions)
                 else:
                     attn = dense_attention(q, k_ctx, v_ctx, mask)
-        with jax.named_scope("o_proj"):
-            h = _shard_residual(
-                mesh, h + qmatmul(attn.reshape(B, S, H * hd), lp["wo"]))
-        return after_attention(h), layer_k, layer_v, layer_ik, counts
+        return (after_attention(out_proj(attn)), layer_k, layer_v, layer_ik,
+                counts)
 
     # Write this chunk's K/V into the cache at its absolute positions.
     # (scatter; positions are per-slot absolute indices). Dead rows
@@ -989,10 +1077,8 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
                     layer_v.q[ctx], layer_v.s[ctx],
                     mask,
                 )
-        with jax.named_scope("o_proj"):
-            h = _shard_residual(
-                mesh, h + qmatmul(attn.reshape(B, S, H * hd), lp["wo"]))
-        return after_attention(h), layer_k, layer_v, layer_ik, counts
+        return (after_attention(out_proj(attn)), layer_k, layer_v, layer_ik,
+                counts)
     else:
         with jax.named_scope("kv_write"):
             layer_k = layer_k.at[rows].set(k.astype(layer_k.dtype))
@@ -1021,10 +1107,7 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
     else:
         with jax.named_scope("attention"):
             attn = dense_attention(q, k_ctx, v_ctx, mask)
-    with jax.named_scope("o_proj"):
-        h = _shard_residual(
-            mesh, h + qmatmul(attn.reshape(B, S, H * hd), lp["wo"]))
-    return after_attention(h), layer_k, layer_v, layer_ik, counts
+    return after_attention(out_proj(attn)), layer_k, layer_v, layer_ik, counts
 
 
 # ------------------------------------------- one mixer a layer (patterned)
@@ -1061,16 +1144,19 @@ def _expert_mixer(cfg: ModelConfig, layers: Params, j: int, h, mesh,
 
 
 def _ssm_mixer(cfg: ModelConfig, layers: Params, j: int, h, ssm, conv,
-               valid):
+               valid, win: Optional[WindowRows] = None):
     """``h + mamba2(norm(h))`` for state-space layer ``j``, from and to
     plane ``j`` of the state leaves. ``valid`` [B, S] bool marks a row's
     real tokens (a prefix of its columns): the rest neither move the
-    state nor enter the convolution's tail. Scopes ``ssm/*`` on purpose
+    state nor enter the convolution's tail. With ``win`` ``h`` is the
+    window's packed rows: the two projections and the gate run on them,
+    the convolution and the scan, which need a slot's tokens in a row,
+    on the unpacked [B, S]. Scopes ``ssm/*`` on purpose
     hold no keyword of the benchmark's trace categories: the mixer is
     its own device time, not the attention's or the MLP's."""
     from ..ops.ssd_scan import causal_conv, gated_group_norm, ssd_scan
 
-    B, S, _ = h.shape
+    B, S = valid.shape
     H, P, N, G = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state,
                   cfg.ssm_groups)
     di = H * P
@@ -1083,6 +1169,8 @@ def _ssm_mixer(cfg: ModelConfig, layers: Params, j: int, h, ssm, conv,
             zxd = qmatmul(x, _at(layers["ssm_in"], j))
             z, xbc, dt = (zxd[..., :di], zxd[..., di:di + cfg.ssm_conv_dim],
                           zxd[..., di + cfg.ssm_conv_dim:])
+            if win is not None:
+                xbc, dt = win.unpack(xbc), win.unpack(dt)
         with jax.named_scope("conv"):
             xbc, tail = causal_conv(xbc, conv[j], layers["ssm_conv_w"][j],
                                     layers["ssm_conv_b"][j], n_valid)
@@ -1099,7 +1187,10 @@ def _ssm_mixer(cfg: ModelConfig, layers: Params, j: int, h, ssm, conv,
             ssm = ssm.at[j].set(state)
             conv = conv.at[j].set(tail)
         with jax.named_scope("gate_norm"):
-            y = gated_group_norm(y.reshape(B, S, di), z,
+            y = y.reshape(B, S, di)
+            if win is not None:
+                y = win.pack(y)
+            y = gated_group_norm(y, z,
                                  layers["ssm_gate_norm"][j], G, cfg.rms_eps)
         with jax.named_scope("out_proj"):
             out = qmatmul(y, _at(layers["ssm_out"], j))
@@ -1109,12 +1200,15 @@ def _ssm_mixer(cfg: ModelConfig, layers: Params, j: int, h, ssm, conv,
 def _patterned_layers(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
                       layers: Params, h, cache: KVCache, positions,
                       kv_limit: int, batch_idx, token_mask, write_mask,
-                      block_tables, q_lens):
+                      block_tables, q_lens, win=None):
     """The layer loop of a patterned configuration, unrolled: layer l runs
     as the kind ``cfg.layer_kinds[l]`` names, on layer j of that kind's
     stacks (j its ordinal among its kind) — three kinds cannot share a
-    scan body. Returns (h, k, v, ssm, conv, experts_read or None)."""
-    B, S, _ = h.shape
+    scan body. ``win``: ``h`` is the window's packed rows, which an
+    expert layer takes as they are and the two sequence mixers unpack
+    around their scan or attention. Returns (h, k, v, ssm, conv,
+    experts_read or None)."""
+    B, S = positions.shape
     valid = jnp.ones((B, S), bool)
     if token_mask is not None:
         valid = jnp.logical_and(valid, token_mask > 0)
@@ -1133,17 +1227,19 @@ def _patterned_layers(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
         j = seen[kind]
         seen[kind] += 1
         if kind == "M":
-            h, ssm, conv = _ssm_mixer(cfg, layers, j, h, ssm, conv, valid)
+            h, ssm, conv = _ssm_mixer(cfg, layers, j, h, ssm, conv, valid,
+                                      win)
         elif kind == "E":
-            h, n = _expert_mixer(cfg, layers, j, h, mesh, token_mask,
-                                 moe_impl)
+            h, n = _expert_mixer(
+                cfg, layers, j, h, mesh,
+                token_mask if win is None else win.valid, moe_impl)
             if n is not None:
                 n_read = n if n_read is None else n_read + n
         else:
             lp = {name: _at(layers[name], j) for name in ATTENTION_LEAVES}
             h, k, v, _, _ = step(h, lp, k, v, positions, kv_limit, batch_idx,
                                  token_mask, write_mask, block_tables, q_lens,
-                                 jnp.asarray(j, jnp.int32), None)
+                                 jnp.asarray(j, jnp.int32), None, win)
     return h, k, v, ssm, conv, n_read
 
 
@@ -1187,6 +1283,11 @@ def forward(
                                       # (1=decode, k+1=spec verify,
                                       # span=prefill; 0 freezes). None =
                                       # all S columns valid. ISSUE 19.
+    packed_rows: Optional[int] = None,  # static, with q_lens and
+                                      # logits_at: the residual carries
+                                      # the window's valid rows packed
+                                      # into [1, packed_rows, D] (they
+                                      # must fit), not [B, S, D]. ISSUE 39.
 ) -> Tuple[jnp.ndarray, KVCache]:
     """Run the model over a token chunk (prefill: S>1; decode: S=1).
 
@@ -1201,6 +1302,13 @@ def forward(
     2B prefill's FLOPs (bucket × dim × 256k-vocab) and its largest
     activation (bucket × vocab f32) — this turns both into 1/bucket of
     themselves.
+
+    ``packed_rows`` is the mixed admission window's entry (ISSUE 39): the
+    arguments describe the [B, S] window as ever, and everything that
+    works a row at a time (embedding, norms, projections, rotary, the MLP
+    and the experts, the final norm) runs on its valid rows alone
+    (``window_rows``); the cache writes and the sequence mixers see the
+    window (``_layer``). ``logits_at`` then picks each slot's row.
     """
     if kv_limit is None:
         kv_limit = cache.max_seq
@@ -1222,6 +1330,13 @@ def forward(
 
     # final_norm is always a plain array in the model dtype — it anchors
     # the activation dtype when the embedding is stored int8.
+    win = None
+    if packed_rows is not None:
+        if q_lens is None or logits_at is None or block_tables is None:
+            raise ValueError("packed_rows is the pool's mixed window: it "
+                             "needs block_tables, q_lens and logits_at")
+        win = window_rows(q_lens, positions, packed_rows)
+        tokens = win.pack(tokens)
     with jax.named_scope("embed"):
         h = embed_lookup(params["embed"], tokens,
                          dtype=params["final_norm"].dtype)
@@ -1266,7 +1381,7 @@ def forward(
         h, new_k, new_v, new_ssm, new_conv, n_read = _patterned_layers(
             cfg, attn_impl, mesh, moe_impl, params["layers"], h, cache,
             positions, kv_limit, batch_idx, token_mask, write_mask,
-            block_tables, q_lens)
+            block_tables, q_lens, win)
         if n_read is not None and counted["experts_read"] is not None:
             counted["experts_read"] = counted["experts_read"] + n_read
     else:
@@ -1310,7 +1425,7 @@ def forward(
             h, cache_k, cache_v, cache_ik, counts = step(
                 h, lp, cache_k, cache_v, positions, kv_limit, batch_idx,
                 token_mask, write_mask, block_tables, q_lens, layer,
-                cache_ik)
+                cache_ik, win)
             return (h, cache_k, cache_v, cache_ik), counts
 
         (h, new_k, new_v, new_ik), counts = jax.lax.scan(
@@ -1322,7 +1437,9 @@ def forward(
 
     with jax.named_scope("final_norm"):
         h = rms_norm(h, params["final_norm"], cfg.rms_eps, cfg.rms_offset)
-    if logits_at is not None:
+    if win is not None:
+        h = h[0][win.row_of[jnp.arange(B), logits_at]][:, None]
+    elif logits_at is not None:
         h = h[jnp.arange(B), logits_at][:, None]       # [B, 1, D]
     with jax.named_scope("lm_head"):
         if cfg.tie_embeddings:

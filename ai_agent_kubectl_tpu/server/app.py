@@ -1118,6 +1118,12 @@ async def handle_health(request: web.Request) -> web.Response:
     ssh = getattr(svc.engine, "ssm_health", None)
     if callable(ssh):
         ssm = ssh() or None
+    # Mixed chunks' windows (ISSUE 39): rows brought, rows computed,
+    # suffixes that waited a chunk — cheap host counters, same rule.
+    ragged = None
+    rgh = getattr(svc.engine, "ragged_health", None)
+    if callable(rgh):
+        ragged = rgh() or None
     # Sharding (ISSUE 14): mesh shape, residual TP fraction, pool-
     # sharded + mesh-fallback flags — cheap host attributes, same rule.
     sharding = None
@@ -1174,6 +1180,7 @@ async def handle_health(request: web.Request) -> web.Response:
         sparse_attention=sparse_attention,
         latent_attention=latent_attention,
         ssm=ssm,
+        ragged=ragged,
         sharding=sharding,
         grammar=grammar,
         spec=spec,

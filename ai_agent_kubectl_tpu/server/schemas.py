@@ -136,6 +136,12 @@ class HealthResponse(BaseModel):
     # recomputed, state bytes moved, layer passes by kind. None for every
     # other model.
     ssm: Optional[Dict[str, Any]] = None
+    # The mixed chunks' admission windows (ISSUE 39; engine/batcher.py::
+    # ragged_health, mirrored by the fake scheduler): ``window`` holds
+    # chunks that carried one, the valid rows they brought, the rows
+    # their prologues computed (width + batch each) and staged suffixes
+    # deferred a chunk. None off the ragged regime.
+    ragged: Optional[Dict[str, Any]] = None
     # Tensor-parallel serving (ISSUE 14, parallel/sharding.py): the
     # active mesh shape + device count, the residual TP fraction the
     # f≈1 policy achieves at the decode shape, whether the KV pool is

@@ -338,14 +338,22 @@ def _grammar_chunk_hlo(mesh, rep) -> str:
     the described chip(s): batch 16, vocabulary 32,000, 2 layers at
     Mistral-7B's widths, a 64-wide admission window, the shipped
     tokenizer's table shapes (6 profile slots x 848 states, 455 classes)."""
+    return _chunk_program(mesh, rep, ModelConfig(name="aot", n_layers=2,
+                                                 **MISTRAL)).as_text()
+
+
+def _chunk_program(mesh, rep, cfg, W=64, n_blocks=320, pages=64):
+    """The engine's ragged chunk program for a ``W``-wide window, grammar
+    on, compiled: its prologue's model call is the engine's
+    (``batcher.py::ragged_forward_step_fn``: the window's valid rows packed
+    into W + batch)."""
     from ai_agent_kubectl_tpu.engine.batcher import make_termination_chunk_fn
     from ai_agent_kubectl_tpu.parallel.sharding import (param_shardings,
                                                         pool_cache_specs,
                                                         sanitize_spec)
     from jax.sharding import NamedSharding
 
-    cfg = ModelConfig(name="aot", n_layers=2, **MISTRAL)
-    B, page, n_blocks, pages, W = 16, 64, 320, 64, 64
+    B, page = 16, 64
     n_prof, s_max, n_classes = 6, 848, 455
 
     def arg(shape, dtype, sharding=rep):
@@ -379,7 +387,8 @@ def _grammar_chunk_hlo(mesh, rep) -> str:
     def rstep(params, tok, pos, cache, wmask, tables, q_lens):
         return forward(params, cfg, tok, pos, cache, token_mask=wmask,
                        write_mask=wmask, block_tables=tables, q_lens=q_lens,
-                       logits_at=jnp.maximum(q_lens, 1) - 1, **common)
+                       logits_at=jnp.maximum(q_lens, 1) - 1,
+                       packed_rows=sum(tok.shape), **common)
 
     chunk = make_termination_chunk_fn(
         step, 16, cfg.eos_ids, 0, 1.0, vocab_size=cfg.vocab_size,
@@ -398,7 +407,7 @@ def _grammar_chunk_hlo(mesh, rep) -> str:
         arg((n_prof * s_max, -(-n_classes // 32)), jnp.uint32),
         arg((n_prof * s_max, n_classes), i32),
         arg((B, W), i32), vec(i32), vec(i32), vec(i32), vec(i32), vec(i32),
-        vec(f32), vec(i32)).compile().as_text()
+        vec(f32), vec(i32)).compile()
 
 
 def test_grammar_mask_is_no_element_gather_on_v5e(one_chip, monkeypatch):
@@ -452,6 +461,57 @@ def test_grammar_mask_over_model4_stays_split_on_v5e(mesh4, monkeypatch):
             continue
         assert op == "all-to-all" and shape.startswith("s32[4,4,8000]") \
             and "grammar_mask" not in scope, (op, shape, scope)
+
+
+# ------------------------------- the window's valid rows, packed (ISSUE 39)
+
+
+@pytest.mark.parametrize("widths,layers,W,n_blocks,pages,was_gib,limit_gib", [
+    (MISTRAL, 32, 1024, 320, 64, 1.25, 1.05),
+    (MIXTRAL, 6, 512, 1040, 65, 1.89, 0.45),
+], ids=["mistral-1024-wide", "mixtral-l6-512-wide"])
+def test_widest_chunk_programs_temporaries_follow_the_rows_on_v5e(
+        one_chip, monkeypatch, widths, layers, W, n_blocks, pages, was_gib,
+        limit_gib):
+    """ISSUE 39: the prologue's residual is the window's valid rows, W + 16
+    of them, so the widest chunk program's temporaries are no longer 16 x W
+    rows of MLP (``was_gib``: AOT, PR 25) but the mixers' [16, W] q/k/v and
+    W + 16 rows of everything else: 1.004 and 0.381 GiB (AOT, PR 39). Of
+    Mistral's, 0.76 GiB is there at any width (its 64-wide program's: the
+    32-layer scan's own, no window's), so the 1,024-wide window costs 0.25."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = ModelConfig(name="aot", n_layers=layers, **widths)
+    compiled = _chunk_program(None, one_chip, cfg, W, n_blocks, pages)
+    assert "tpu_custom_call" in compiled.as_text()
+    temp = compiled.memory_analysis().temp_size_in_bytes / 2 ** 30
+    assert temp < limit_gib < was_gib, temp
+
+
+def test_packed_prologue_over_model4_moves_rows_not_the_window_on_v5e(
+        mesh4, monkeypatch):
+    """ISSUE 39 over ``model:4`` (Mixtral-8x7B's widths, the 512-wide chunk
+    program): the packed residual [1, W + 16, D] takes the sequence-axis rule
+    (``parallel/sharding.py::residual_spec``), q/k/v are unpacked head-split
+    where the projections left them, and nothing the size of the [16, W, D]
+    window, or of a chip's quarter of it, crosses the mesh: the slot x width
+    form gathered bf16[16,512,4096] and scattered bf16[4,512,4096] a layer."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    N, W, D = 16, 512, 4096
+    cfg = ModelConfig(name="aot", n_layers=2, **MIXTRAL)
+    hlo = _chunk_program(mesh4, NamedSharding(mesh4, P()), cfg, W, 1792,
+                         65).as_text()
+    assert "tpu_custom_call" in hlo, "the Mosaic kernel is not in the program"
+    crossed = [(op, shape) for shape, op, _ in _instructions(hlo)
+               if re.fullmatch(COLLECTIVE, op)]
+    assert any(op.startswith("all-gather") and f"[1,{W + N},{D}]" in shape
+               for op, shape in crossed), crossed
+    wide = [(op, shape) for op, shape in crossed
+            if max(_sizes(shape), default=0) >= N * W * D // 8]
+    assert not wide, wide
+    # the kernel's operands stay a chip's heads: 8 of 32 Q, 2 of 8 KV
+    assert f"bf16[{N},{W},8,128]" in hlo and f"bf16[{N},{W},32,128]" not in hlo
 
 
 # ------------------------- key selection and grouped experts (ISSUE 31)
