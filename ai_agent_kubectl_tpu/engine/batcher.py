@@ -49,7 +49,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.transformer import (KVCache, forward, serves_grouped,
-                                  state_put_row, state_take_row, state_zeros)
+                                  sliding_zeros, state_put_row,
+                                  state_take_row, state_zeros)
 from ..obs.ledger import (CLASS_DELIVERED, CLASS_DRAFT_REJECTED,
                           CLASS_HEDGE_LOSER, CLASS_PREEMPTED,
                           CLASS_QUARANTINE_BURN, CLASS_REPLAYED,
@@ -68,7 +69,7 @@ from .containment import (CAUSE_SCHEDULER_DEATH, CAUSE_SCHEDULER_ERROR,
 from .jax_engine import JaxEngine, kv_bucket_ladder
 from .kv_pool import (BlockPool, HostBlockStore, StateStore,
                       alloc_with_evict, map_prefix, pages_for, release_state,
-                      state_cuts, take_snapshot)
+                      span_window_counts, state_cuts, take_snapshot)
 from .radix_cache import RadixCache
 from .regime import (DENSE, RAGGED, resolve_attention_regime,
                      stage_window)
@@ -791,18 +792,29 @@ def make_termination_chunk_fn(forward_step, chunk_len: int, eos_ids,
 def _zero_counts(cache):
     """The chunk program counts what its own passes read and kept."""
     zeroed = {name: jnp.zeros_like(getattr(cache, name))
-              for name in ("experts_read", "sel_rows", "lat_rows")
+              for name in KVCache.COUNTS
               if getattr(cache, name) is not None}
     return dataclasses.replace(cache, **zeroed) if zeroed else cache
 
 
 def _attention_rows(cache):
-    """The two words the attention counted on the device, for the packed
-    chunk's two-word lane (engine/protocol.py's ``sel_rows``): a selecting
-    configuration's (keys before its decode queries, keys kept), or a
-    latent one's (decode queries, cached rows before them). No
-    configuration is both."""
-    return cache.sel_rows if cache.sel_rows is not None else cache.lat_rows
+    """The words the attention counted on the device, for the packed
+    chunk's ``sel_rows`` lane (engine/protocol.py): a selecting
+    configuration's two (keys before its decode queries, keys kept), a
+    latent one's two (decode queries, cached rows before them), or the
+    four of one with sliding layers (``KVCache.span_rows``). No
+    configuration is two of these."""
+    for name in ("sel_rows", "lat_rows", "span_rows"):
+        if getattr(cache, name) is not None:
+            return getattr(cache, name)
+    return None
+
+
+def attention_words(model_cfg) -> int:
+    """Words of the packed chunk's ``sel_rows`` lane for this model."""
+    if model_cfg.slides:
+        return 4
+    return 2 if model_cfg.selects_keys or model_cfg.latent else 0
 
 
 def staged_suffix_len(suffix: int, buckets) -> int:
@@ -818,21 +830,24 @@ def staged_suffix_len(suffix: int, buckets) -> int:
     return suffix if suffix <= buckets[-1] else buckets[0]
 
 
-def state_refusal(model_cfg, regime: str, mesh_shape, spec_decode: bool
-                  ) -> Optional[str]:
-    """Why this engine cannot serve a configuration with state-space
-    layers (``ModelConfig.keeps_state``), or None. Its recurrent state is
-    a leaf of the pool engine's cache, a row a decode slot, with
-    snapshots on the radix tree: the dense per-slot ladder has neither,
+def state_refusal(model_cfg, regime: str, mesh_shape, spec_decode: bool,
+                  kv_quant: str = "") -> Optional[str]:
+    """Why this engine cannot serve a configuration with state-space or
+    sliding-attention layers (``ModelConfig.keeps_state``), or None. Its
+    bounded state is a leaf of the pool engine's cache, a row a decode
+    slot, with snapshots on the radix tree: the dense per-slot ladder has
+    neither (and would attend a sliding layer to every key),
     parallel/sharding.py::param_specs has no rule for the family's
-    leaves, and a rejected draft token would have moved a state that
-    cannot be moved back."""
+    leaves, a rejected draft token would have moved a state that
+    cannot be moved back, and a sliding layer's ring holds bf16 rows."""
     if not model_cfg.keeps_state:
         return None
     why = None
     if regime == DENSE:
-        why = ("the dense per-slot KV ladder keeps no recurrent state "
-               "(KV_POOL=false, or a mesh axis the pool refuses)")
+        why = ("the dense per-slot KV ladder keeps no bounded state a "
+               "sequence, recurrent or sliding, and would attend a sliding "
+               "layer to every key (KV_POOL=false, or a mesh axis the pool "
+               "refuses)")
     elif any(n > 1 for n in (mesh_shape or {}).values()):
         why = (f"MESH_SHAPE {dict(mesh_shape)}: parallel/sharding.py has no "
                f"rule for the state-space and per-kind leaves; the family "
@@ -840,10 +855,15 @@ def state_refusal(model_cfg, regime: str, mesh_shape, spec_decode: bool
     elif spec_decode:
         why = ("SPEC_DECODE: a rejected draft position would have advanced "
                "the recurrent state")
+    elif kv_quant and model_cfg.slides:
+        why = (f"KV_QUANT={kv_quant}: the sliding layers' rings are bf16 "
+               f"rows beside the pool")
     if why is None:
         return None
-    return (f"{model_cfg.name} keeps a recurrent state (layer_pattern "
-            f"{model_cfg.layer_pattern[:model_cfg.n_layers]!r}) and is not "
+    state = ("a recurrent state" if model_cfg.has_ssm
+             else "a sliding-attention state")
+    return (f"{model_cfg.name} keeps {state} (layer_pattern "
+            f"{''.join(model_cfg.layer_kinds)!r}) and is not "
             f"served here: {why}")
 
 
@@ -1159,7 +1179,7 @@ class BatchedJaxEngine(JaxEngine):
         # the StateStore is their host truth, rebuilt with the pool.
         self.state_snapshots = max(0, state_snapshots)
         self._state: Optional[StateStore] = None
-        self._snap_ssm = self._snap_conv = None
+        self._snap: dict = {}
         self._use_pool = False        # resolved at start (mesh fallback)
         # True when KV_POOL was requested but the mesh forced the dense
         # ladder (data/pipe/seq axes >1 — the pool's block axis is a
@@ -1192,7 +1212,11 @@ class BatchedJaxEngine(JaxEngine):
         # decode queries saw and kept is counted on the device (sel_rows)
         self._selection_counts = dict.fromkeys(
             ("index_rows_scanned", "window_rows", "forward_passes"), 0)
-        self._sel_rows_dev = [0, 0]
+        self._sel_rows_dev = [0, 0, 0, 0]
+        # a model with sliding layers: prompt rows prefilled and the
+        # (query, key) pairs of those in one layer of each kind
+        self._span_counts = dict.fromkeys(
+            ("window_rows", "window_pairs_sliding", "window_pairs_full"), 0)
         self._attention_steps = (None, None, None)
         self._ragged_chunk_fns: dict = {}   # (adm width, spec) -> jitted
         # slot_idx -> staged admission (ids/start/ngen0/budget/seed/
@@ -1547,7 +1571,7 @@ class BatchedJaxEngine(JaxEngine):
         refusal = refusal or state_refusal(
             self.model_cfg, regime,
             dict(self.mesh.shape) if self.mesh is not None else None,
-            self.spec_decode)
+            self.spec_decode, self.kv_quant)
         refusal = refusal or latent_refusal(
             self.model_cfg, regime,
             dict(self.mesh.shape) if self.mesh is not None else None,
@@ -2216,8 +2240,7 @@ class BatchedJaxEngine(JaxEngine):
                 # whose host truth is rebuilt with the pool's (a reset
                 # condemns every snapshot with the K/V it belongs to).
                 cap = self.state_snapshots or 4 * N
-                self._snap_ssm, self._snap_conv = state_zeros(
-                    self.model_cfg, cap, self.dtype)
+                self._snap = self._state_leaves_zeros(cap, snapshots=True)
                 self._state = StateStore(
                     cap, N, self.model_cfg.state_bytes(),
                     snapshot_fn=self._state_snapshot_dev,
@@ -2333,8 +2356,10 @@ class BatchedJaxEngine(JaxEngine):
         def state() -> dict:
             if not cfg.keeps_state:
                 return {}
-            ssm, conv = state_zeros(cfg, N, dtype)
-            return {"ssm": ssm, "conv": conv}
+            leaves = self._state_leaves_zeros(N)
+            if cfg.slides:
+                leaves["span_rows"] = jnp.zeros((4,), jnp.int32)
+            return leaves
 
         def make() -> KVCache:
             lengths = jnp.zeros((n_blocks,), jnp.int32)
@@ -2398,42 +2423,88 @@ class BatchedJaxEngine(JaxEngine):
     # before the prefill that reads it and a snapshot after the prefill
     # whose end it saves.
 
+    def _state_leaves_zeros(self, rows: int, snapshots: bool = False) -> dict:
+        """The state leaves (``KVCache.STATE``) of ``rows`` sequences: a
+        decode slot's live ones, or the snapshot store's. A sliding
+        layer's live K/V are a ring (the span and the widest prefill
+        window beside it); a snapshot keeps the span's rows alone."""
+        cfg, leaves = self.model_cfg, {}
+        if cfg.has_ssm:
+            leaves["ssm"], leaves["conv"] = state_zeros(cfg, rows, self.dtype)
+        if cfg.slides:
+            length = (cfg.sliding_window if snapshots else cfg.sliding_ring(
+                self.prefill_buckets[-1], self.kv_pool_page))
+            leaves["sk"], leaves["sv"] = sliding_zeros(cfg, rows, length,
+                                                       self.dtype)
+        return leaves
+
+    def _live_state(self) -> dict:
+        return {name: getattr(self._cache, name) for name in self._snap}
+
     @functools.cached_property
     def _state_copy_fn(self):
-        def copy(dst_ssm, dst_conv, src_ssm, src_conv, dst, src):
-            one = lambda a: jax.lax.dynamic_slice_in_dim(a, src, 1, axis=1)
-            put = lambda a, u: jax.lax.dynamic_update_slice_in_dim(
-                a, u, dst, axis=1)
-            with jax.named_scope("ssm/state_copy"):
-                return put(dst_ssm, one(src_ssm)), put(dst_conv, one(src_conv))
+        span = self.model_cfg.sliding_window
 
-        return jax.jit(copy, donate_argnums=(0, 1))
+        def row_of(a, i):
+            return jax.lax.dynamic_slice_in_dim(a, i, 1, axis=1)
+
+        def put(a, u, i):
+            return jax.lax.dynamic_update_slice_in_dim(a, u, i, axis=1)
+
+        def span_rows(live, edge):
+            """The ring's rows for positions edge - span .. edge - 1 (rows
+            of positions under 0 are never read)."""
+            return (edge - span + jnp.arange(span)) % live.shape[2]
+
+        def snapshot(snap, live, handle, slot, edge):
+            out = {}
+            with jax.named_scope("ssm/state_copy"):
+                for name, a in snap.items():
+                    one = row_of(live[name], slot)
+                    if name in ("sk", "sv"):
+                        one = one[:, :, span_rows(one, edge)]
+                    out[name] = put(a, one, handle)
+            return out
+
+        def restore(live, snap, slot, handle, edge):
+            out = {}
+            with jax.named_scope("ssm/state_copy"):
+                for name, a in live.items():
+                    one = row_of(snap[name], handle)
+                    if name in ("sk", "sv"):
+                        one = row_of(a, slot).at[
+                            :, :, span_rows(a, edge)].set(one)
+                    out[name] = put(a, one, slot)
+            return out
+
+        return (jax.jit(snapshot, donate_argnums=(0,)),
+                jax.jit(restore, donate_argnums=(0,)))
 
     def _state_snapshot_dev(self, slot: int, handle: int) -> None:
-        self._snap_ssm, self._snap_conv = self._state_copy_fn(
-            self._snap_ssm, self._snap_conv, self._cache.ssm,
-            self._cache.conv, np.int32(handle), np.int32(slot))
+        self._snap = self._state_copy_fn[0](
+            self._snap, self._live_state(), np.int32(handle), np.int32(slot),
+            np.int32(self._state.edge(handle)))
 
     def _state_restore_dev(self, slot: int, handle: int) -> None:
-        ssm, conv = self._state_copy_fn(
-            self._cache.ssm, self._cache.conv, self._snap_ssm,
-            self._snap_conv, np.int32(slot), np.int32(handle))
-        self._cache = dataclasses.replace(self._cache, ssm=ssm, conv=conv)
+        self._cache = dataclasses.replace(
+            self._cache, **self._state_copy_fn[1](
+                self._live_state(), self._snap, np.int32(slot),
+                np.int32(handle), np.int32(self._state.edge(handle))))
 
     @functools.cached_property
     def _state_zero_fn(self):
-        def zero(ssm, conv, slot):
+        def zero(live, slot):
             z = lambda a: jax.lax.dynamic_update_slice_in_dim(
                 a, jnp.zeros_like(a[:, :1]), slot, axis=1)
             with jax.named_scope("ssm/state_copy"):
-                return z(ssm), z(conv)
+                return {name: z(a) for name, a in live.items()}
 
-        return jax.jit(zero, donate_argnums=(0, 1))
+        return jax.jit(zero, donate_argnums=(0,))
 
     def _state_zero_dev(self, slot: int) -> None:
-        ssm, conv = self._state_zero_fn(self._cache.ssm, self._cache.conv,
-                                        np.int32(slot))
-        self._cache = dataclasses.replace(self._cache, ssm=ssm, conv=conv)
+        self._cache = dataclasses.replace(
+            self._cache, **self._state_zero_fn(self._live_state(),
+                                               np.int32(slot)))
 
     def _tables_d(self, tables: np.ndarray):
         """Device copy of a block-table snapshot — committed REPLICATED
@@ -2476,10 +2547,7 @@ class BatchedJaxEngine(JaxEngine):
                     if not cfg.keeps_state:
                         return run(cache)
                     logits, out = run(state_take_row(cache, slot))
-                    return logits, state_put_row(
-                        dataclasses.replace(out, ssm=cache.ssm,
-                                            conv=cache.conv),
-                        out.ssm, out.conv, slot)
+                    return logits, state_put_row(cache, out, slot)
                 return with_slot
 
             if self._use_ragged:
@@ -2989,6 +3057,10 @@ class BatchedJaxEngine(JaxEngine):
             self._selection_counts["window_rows"] += n_prompt - m
             self._selection_counts["index_rows_scanned"] += (
                 n_prompt * (n_prompt + 1) - m * (m + 1)) // 2
+        if self.model_cfg.slides:
+            for name, n in span_window_counts(
+                    m, n_prompt, self.model_cfg.sliding_window).items():
+                self._span_counts[name] += n
         if self.model_cfg.selects_keys:
             # window rows m .. n_prompt-1, each scanning the index keys
             # up to its own
@@ -3307,8 +3379,35 @@ class BatchedJaxEngine(JaxEngine):
         body["layer_passes"] = {
             name: passes * self.model_cfg.n_of(kind)
             for name, kind in (("ssm", "M"), ("experts", "E"),
-                               ("attention", "*"))}
+                               ("attention", "*"), ("sliding", "S"),
+                               ("dense_mlp", "D"))}
         return body
+
+    def sliding_attention_health(self) -> Optional[dict]:
+        """/health.sliding_attention (cumulative; None for a model whose
+        attention layers are of one kind). ``decode_rows_sliding`` /
+        ``sliding_keys_read``: the decode queries the chunk programs' sliding
+        layers ran (a pass's rows times those layers) and the keys those
+        had inside their span; ``decode_rows_full`` / ``full_keys_read``:
+        the same for the full layers, the live context — all four counted
+        on the device, beside the mask. ``window_*``: prompt rows prefilled
+        and their (query, key) pairs in ONE layer of each kind (the
+        scheduler's arithmetic). ``ring_rows``: what a decode slot keeps a
+        sliding layer, ``snapshot_rows`` what a snapshot does."""
+        cfg = self.model_cfg
+        if not cfg.slides:
+            return None
+        rows_s, keys_s, rows_f, keys_f = self._sel_rows_dev
+        return {"span": cfg.sliding_window,
+                "ring_rows": self._cache.sk.shape[2],
+                "snapshot_rows": cfg.sliding_window,
+                "layers_sliding": cfg.n_of("S"), "layers_full": cfg.n_of("*"),
+                "heads_sliding": cfg.heads_of("S"),
+                "heads_full": cfg.heads_of("*"),
+                "decode_rows_sliding": rows_s, "sliding_keys_read": keys_s,
+                "decode_rows_full": rows_f, "full_keys_read": keys_f,
+                **self._span_counts,
+                "forward_passes": self._selection_counts["forward_passes"]}
 
     def _pool_bytes_per_token(self) -> float:
         """What one token keeps in the pool: every paged leaf's own size
@@ -3335,7 +3434,7 @@ class BatchedJaxEngine(JaxEngine):
         cfg = self.model_cfg
         if not cfg.latent:
             return None
-        queries, rows = self._sel_rows_dev
+        queries, rows = self._sel_rows_dev[:2]
         return {"row_bytes": self._pool_bytes_per_token(),
                 "layers": cfg.n_layers,
                 "decode_rows": queries // cfg.n_layers,
@@ -3358,7 +3457,7 @@ class BatchedJaxEngine(JaxEngine):
         if not self.model_cfg.selects_keys:
             return None
         live, kept = (n // self.model_cfg.n_layers
-                      for n in self._sel_rows_dev)
+                      for n in self._sel_rows_dev[:2])
         c = dict(self._selection_counts, decode_rows_live=live,
                  decode_rows_selected=kept)
         c["index_rows_scanned"] += live
@@ -4018,6 +4117,7 @@ class BatchedJaxEngine(JaxEngine):
             "moe": self.moe_health(),
             "sparse_attention": self.sparse_attention_health(),
             "latent_attention": self.latent_attention_health(),
+            "sliding_attention": self.sliding_attention_health(),
             "ssm": self.ssm_health(),
             "ragged": self.ragged_health(),
             "sharding": self.sharding_health(),
@@ -5950,8 +6050,7 @@ class BatchedJaxEngine(JaxEngine):
                                       pipe=self._chunks_in_pipe()) as consumed:
             res = unpack_chunk(buf, self.batch_size, ct, spec=is_spec,
                                moe=self._counts_experts,
-                               sel=(self.model_cfg.selects_keys
-                                    or self.model_cfg.latent))
+                               sel=attention_words(self.model_cfg))
             consumed["n_alive"] = res.n_alive
             if res.experts_read is not None:
                 # One pass a scan step (the prologue is one of them), each
@@ -5963,8 +6062,8 @@ class BatchedJaxEngine(JaxEngine):
             if res.sel_rows is not None:
                 # The device's own count of what its decode queries saw
                 # and kept, summed over the layers of every step.
-                self._sel_rows_dev[0] += res.sel_rows[0]
-                self._sel_rows_dev[1] += res.sel_rows[1]
+                for i, n in enumerate(res.sel_rows):
+                    self._sel_rows_dev[i] += n
             self._consume_chunk(res, snapshot, ct, is_spec)
 
     def _consume_chunk(self, res, snapshot, ct: int, is_spec: bool) -> None:

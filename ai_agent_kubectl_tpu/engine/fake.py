@@ -42,7 +42,7 @@ from .containment import (CAUSE_SCHEDULER_DEATH, CAUSE_SCHEDULER_ERROR,
 from .fallback import extract_query, rule_command  # rules promoted there
 from .kv_pool import (BlockPool, HostBlockStore, PoolExhausted, StateStore,
                       alloc_with_evict, map_prefix, pages_for, release_state,
-                      state_cuts, take_snapshot)
+                      span_window_counts, state_cuts, take_snapshot)
 from .radix_cache import RadixCache
 from .regime import RAGGED, resolve_attention_regime, stage_window
 from .protocol import (HEALTH_GRAMMAR_DEAD, HEALTH_NONFINITE,
@@ -271,6 +271,7 @@ class FakeChunkedEngine:
                  radix_lru_blocks: int = 0,
                  host_kv_blocks: int = 0,
                  state_snapshots: int = 0,
+                 sliding_window: int = 0,
                  slo_session_ttft_ms: float = 0.0,
                  session_token_budget: int = 0,
                  force_ragged: bool = False,
@@ -402,6 +403,14 @@ class FakeChunkedEngine:
         # and radix rule verbatim, over a state of no bytes.
         self.state_snapshots = max(0, state_snapshots)
         self._state: Optional[StateStore] = None
+        # > 0 (with ``state_snapshots``) plays a model whose state is its
+        # sliding layers' last ``sliding_window`` K/V rows (ISSUE 40): the
+        # batcher's /health.sliding_attention arithmetic, one layer a kind.
+        self.sliding_window = max(0, sliding_window)
+        self._span_counts = dict.fromkeys(
+            ("window_rows", "window_pairs_sliding", "window_pairs_full",
+             "decode_rows_sliding", "sliding_keys_read", "decode_rows_full",
+             "full_keys_read"), 0)
         self.max_seq_len = max(chunk_len + 1, max_seq_len)
         self._pool_max_pages = pages_for(self.max_seq_len + chunk_len,
                                          self.kv_pool_page)
@@ -620,6 +629,10 @@ class FakeChunkedEngine:
         chain = basis + (gen[:-1] if gen else [])
         blocks, m = self._pool_map_prefix(chain, match_all=bool(gen),
                                           slot_idx=slot_idx)
+        if self.sliding_window and not gen:
+            for name, n in span_window_counts(m, len(chain),
+                                              self.sliding_window).items():
+                self._span_counts[name] += n
         if self._state is not None and not gen:
             # the batcher's prefill stops at these edges to save the state
             for edge in state_cuts(self._state, slot_idx, len(chain),
@@ -686,6 +699,27 @@ class FakeChunkedEngine:
         """/health.ssm: the snapshot store's counters (mirror of the
         batcher's; None unless the fake plays a state-keeping model)."""
         return self._state.stats() if self._state is not None else None
+
+    def _count_decode_row(self, slot: _FakeSlot) -> None:
+        """One decode query of a model with sliding layers: the keys it
+        reads in a sliding layer (its span's) and in a full one (the
+        batcher counts these on the device, beside the mask)."""
+        if not self.sliding_window:
+            return
+        keys = len(slot.pool_ids) + slot.dev_ngen + 1
+        c = self._span_counts
+        c["decode_rows_sliding"] += 1
+        c["decode_rows_full"] += 1
+        c["sliding_keys_read"] += min(keys, self.sliding_window)
+        c["full_keys_read"] += keys
+
+    def sliding_attention_health(self) -> Optional[dict]:
+        """/health.sliding_attention (mirror of the batcher's counters, one
+        layer of each kind; None unless the fake plays such a model)."""
+        if not self.sliding_window:
+            return None
+        return {"span": self.sliding_window, "layers_sliding": 1,
+                "layers_full": 1, **self._span_counts}
 
     def ragged_health(self) -> Optional[dict]:
         """/health.ragged (mirror of the batcher's; None off the ragged
@@ -944,6 +978,7 @@ class FakeChunkedEngine:
                                 slot_health_check=self.slot_health_check),
             "kv_pool": self.kv_pool_health(),
             "ssm": self.ssm_health(),
+            "sliding_attention": self.sliding_attention_health(),
             "ragged": self.ragged_health(),
             "ledger": self.ledger.snapshot(),
             "slo": self._slo.snapshot(),
@@ -1605,6 +1640,7 @@ class FakeChunkedEngine:
                     if grammar_on:
                         slot.dev_gs = self._grammar.advance(
                             slot.dev_gs, nxt)
+                    self._count_decode_row(slot)
                     slot.dev_idx += 1
                     slot.dev_ngen += 1
                     if slot.dev_ngen >= slot.req.max_tokens:

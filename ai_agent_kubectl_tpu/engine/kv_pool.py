@@ -178,10 +178,28 @@ def state_cuts(state: "StateStore", slot: int, n_prompt: int, page: int,
       to a node with one child, the turn before, and leaves nothing there:
       nobody else will come that way;
     - the end of the prompt's last whole block, which the same prompt
-      grown by a turn will match."""
+      grown by a turn will match;
+    - and the block edge before that one, which a prompt that DIVERGES
+      from this one within its last page will match (a second question
+      about the same pasted log: the questions differ, the log's blocks
+      are shared, and the last whole block's edge may lie past where they
+      part). It costs one more stop, of at most a page, and keeps a
+      re-ask from recomputing the log for want of a state at its end."""
     last = (n_prompt - 1) // page * page
-    edges = {state.branch_edge(slot), last}
+    edges = {state.branch_edge(slot), last, last - page}
     return sorted(e for e in edges if start < e <= last)
+
+
+def span_window_counts(m: int, n: int, span: int) -> dict:
+    """Prompt rows m .. n-1 prefilled, and the (query, key) pairs of those
+    rows in one layer: row t has t + 1 keys before it in a full layer and
+    min(t + 1, span) in a sliding one (the scheduler's arithmetic, run by
+    the jax batcher and the fake alike)."""
+    tri = lambda x: x * (x + 1) // 2
+    return {"window_rows": n - m,
+            "window_pairs_full": tri(n) - tri(m),
+            "window_pairs_sliding": (tri(min(n, span)) - tri(min(m, span))
+                                     + span * (max(n, span) - max(m, span)))}
 
 
 def take_snapshot(state: "StateStore", radix, slot: int,
@@ -543,6 +561,7 @@ class StateStore:
         self._pins: Dict[int, int] = {}         # held handle -> live pinners
         self._slot_pins: List[List[int]] = [[] for _ in range(n_slots)]
         self._slot_pending: List[List[tuple]] = [[] for _ in range(n_slots)]
+        self._edge: Dict[int, int] = {}         # held handle -> its edge
         self._branch_edge = [0] * n_slots
         self._clock = itertools.count(1)
         self.held_peak = 0
@@ -609,6 +628,11 @@ class StateStore:
         self.restores += 1
         self.state_bytes_moved += self.state_bytes
 
+    def edge(self, handle: int) -> int:
+        """The token a held snapshot stands at (a state whose bytes depend
+        on the position, a sliding layer's last rows, is copied by it)."""
+        return self._edge[handle]
+
     def branch_edge(self, slot: int) -> int:
         """How deep (tokens, a block edge) the slot's sequence matched
         K/V in the tree when it was seated — past its snapshot or not —
@@ -625,6 +649,7 @@ class StateStore:
             self.snapshots_skipped += 1
             return None
         self._last[handle] = next(self._clock)
+        self._edge[handle] = edge
         self._pin(slot, handle)
         self._slot_pending[slot].append((edge, handle))
         with self._region("state_snapshot", slot=slot, tokens=edge):
@@ -677,6 +702,7 @@ class StateStore:
         handle is reissued, and a stale pin would then be another's."""
         self._node.pop(handle, None)
         self._last.pop(handle, None)
+        self._edge.pop(handle, None)
         if self._pins.pop(handle, None):
             for pins in self._slot_pins:
                 pins[:] = [h for h in pins if h != handle]

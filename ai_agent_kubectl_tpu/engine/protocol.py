@@ -138,15 +138,21 @@ def describe_health(word: int) -> str:
     return "|".join(parts) or "ok"
 
 
+def _sel_words(sel) -> int:
+    """Words of the ``sel_rows`` lane: ``sel`` is how many (True: two)."""
+    return 2 if sel is True else int(sel)
+
+
 def packed_chunk_size(n_slots: int, chunk_len: int,
                       spec: bool = False, moe: bool = False,
-                      sel: bool = False) -> int:
+                      sel=False) -> int:
     """Flat length of one packed chunk buffer (``spec`` adds the two
     per-slot drafted/accepted lanes of the v3 speculative contract,
-    ``moe`` the one-word ``experts_read`` lane, ``sel`` the two-word
-    ``sel_rows`` lane)."""
+    ``moe`` the one-word ``experts_read`` lane, ``sel`` the ``sel_rows``
+    lane: True for its two words, or their number where the attention
+    counts more — four for a model with sliding layers)."""
     return (n_slots * chunk_len + (5 if spec else 3) * n_slots + 1
-            + (1 if moe else 0) + (2 if sel else 0))
+            + (1 if moe else 0) + _sel_words(sel))
 
 
 @dataclass
@@ -169,7 +175,9 @@ class ChunkResult:
     experts_read: Optional[int] = None
     #: key selection (optional lane): (keys the chunk's decode queries had
     #: before them, keys the selector kept of those), summed over layers
-    #: and steps. None where the chunk program selects no keys.
+    #: and steps; a latent or a sliding configuration's own counts ride
+    #: the same lane (engine/batcher.py::_attention_rows). None where the
+    #: chunk program counts none.
     sel_rows: Optional[tuple] = None
 
 
@@ -184,8 +192,8 @@ def pack_chunk(tokens, done, lengths, n_alive, *, health=None,
     defaults to all-healthy for callers predating the v2 lane;
     ``drafted``/``accepted`` (v3) ride only when the chunk ran the
     speculative draft/verify body — pass both or neither.
-    ``sel_rows`` (two words) and ``experts_read`` (a scalar) ride, when
-    given, in that order just before ``n_alive``."""
+    ``sel_rows`` (two words, or four) and ``experts_read`` (a scalar)
+    ride, when given, in that order just before ``n_alive``."""
     done = done.astype(xp.int32)
     if health is None:
         health = xp.zeros_like(done)
@@ -202,7 +210,7 @@ def pack_chunk(tokens, done, lengths, n_alive, *, health=None,
         parts.append(drafted.astype(xp.int32))
         parts.append(accepted.astype(xp.int32))
     if sel_rows is not None:
-        parts.append(xp.reshape(xp.asarray(sel_rows, dtype=xp.int32), (2,)))
+        parts.append(xp.reshape(xp.asarray(sel_rows, dtype=xp.int32), (-1,)))
     if experts_read is not None:
         parts.append(xp.reshape(xp.asarray(experts_read, dtype=xp.int32),
                                 (1,)))
@@ -212,7 +220,7 @@ def pack_chunk(tokens, done, lengths, n_alive, *, health=None,
 
 def unpack_chunk(buf, n_slots: int, chunk_len: int,
                  spec: bool = False, moe: bool = False,
-                 sel: bool = False) -> ChunkResult:
+                 sel=False) -> ChunkResult:
     """Inverse of ``pack_chunk`` (always numpy — this is the host side)."""
     buf = np.asarray(buf)
     want = packed_chunk_size(n_slots, chunk_len, spec=spec, moe=moe, sel=sel)
@@ -220,7 +228,7 @@ def unpack_chunk(buf, n_slots: int, chunk_len: int,
         raise ValueError(
             f"packed chunk buffer has shape {buf.shape}, expected ({want},) "
             f"for n_slots={n_slots} chunk_len={chunk_len} spec={spec}"
-            + (" moe=True" if moe else "") + (" sel=True" if sel else ""))
+            + (" moe=True" if moe else "") + (f" sel={sel}" if sel else ""))
     nt = n_slots * chunk_len
     drafted = accepted = None
     if spec:
@@ -235,8 +243,9 @@ def unpack_chunk(buf, n_slots: int, chunk_len: int,
         drafted=drafted,
         accepted=accepted,
         experts_read=int(buf[-2]) if moe else None,
-        sel_rows=((int(buf[-4 if moe else -3]), int(buf[-3 if moe else -2]))
-                  if sel else None),
+        sel_rows=(tuple(int(n) for n in buf[
+            len(buf) - (2 if moe else 1) - _sel_words(sel):
+            len(buf) - (2 if moe else 1)]) if sel else None),
     )
 
 

@@ -27,6 +27,16 @@ test models. Family differences are expressed as data, not subclasses:
   frequencies on interleaved pairs, a position-dependent query scale,
   and a CHIP'S SHARE of the routed experts (``n_experts`` held of the
   ``router_width`` the router scores) beside a shared expert
+- Laguna-S-2.1's language model (``laguna``; registered by the benchmark,
+  ``toy-sliding-moe`` is its toy): attention layers of TWO kinds in one
+  model, ``*`` full and ``S`` sliding (``sliding_window`` keys, the
+  query's own included), each kind with its own head count over the same
+  KV heads, its own rotary rule (theta, rotated share, YaRN) and a
+  per-head sigmoid gate on its output; a pre-norm block is TWO one-mixer
+  layers of ``layer_pattern`` (``mixers_per_layer`` 2: attention, then a
+  ``D`` dense MLP or ``E`` experts), weights stacked per kind. A sliding
+  layer's K/V are a bounded state a sequence (a ring of the span plus a
+  window, ``KVCache.sk``/``sv``), not rows of the paged pool
 """
 
 from __future__ import annotations
@@ -73,11 +83,33 @@ class ModelConfig:
     index_head_dim: int = 0
     # One mixer a layer (the nemotron_h family): character l names layer
     # l's kind, ``M`` a Mamba-2 layer, ``E`` an expert layer, ``*``
-    # attention, each ``x + mixer(norm(x))`` with no MLP behind it. A
-    # string so the dataclass stays hashable; longer than ``n_layers`` it
-    # means its first ``n_layers`` characters (a configuration cut in depth
-    # keeps the published string). "" = every layer is attention then MLP.
+    # attention, ``S`` sliding-window attention, ``D`` a dense MLP, each
+    # ``x + mixer(norm(x))`` with nothing behind it. A string so the
+    # dataclass stays hashable; longer than the layers it names it means
+    # its first characters (a configuration cut in depth keeps the
+    # published string). "" = every layer is attention then MLP.
+    # ``mixers_per_layer`` characters make one of the source's layers (2
+    # where the source's layer is a pre-norm block, attention then an MLP,
+    # written as its two mixers): ``n_layers`` counts the source's.
     layer_pattern: str = ""
+    mixers_per_layer: int = 1
+    # Two kinds of attention in one model. An ``S`` layer's query at
+    # position t sees keys s with t - ``sliding_window`` < s <= t, has
+    # ``sliding_n_heads`` query heads over the same KV heads (0 =
+    # ``n_heads``) and the plain rotary embedding of ``sliding_rope_theta``
+    # on the first ``sliding_rope_partial`` of its head's lanes. A ``*``
+    # layer keeps ``n_heads``, ``rope_theta`` and the YaRN fields below,
+    # on the first ``rope_partial`` of the lanes, cos and sin times
+    # ``rope_attention_factor`` (0 = none given: 1). ``attn_gate``
+    # "per-head": sigmoid(x W_g) [heads], from the layer's normed input,
+    # multiplies each head's attention output before W_o (both kinds).
+    sliding_window: int = 0
+    sliding_n_heads: int = 0
+    sliding_rope_theta: float = 10000.0
+    sliding_rope_partial: float = 1.0
+    rope_partial: float = 1.0
+    rope_attention_factor: float = 0.0
+    attn_gate: str = ""
     # Mamba-2 sizes: d_inner = ssm_heads x ssm_head_dim, B and C of
     # ``ssm_state`` shared by the heads of each of ``ssm_groups`` groups, a
     # causal depthwise convolution of ``ssm_conv`` taps over [x | B | C],
@@ -173,11 +205,14 @@ class ModelConfig:
         """Layer l's kind for a patterned configuration, () otherwise."""
         if not self.layer_pattern:
             return ()
-        kinds = tuple(self.layer_pattern[:self.n_layers])
-        if len(kinds) < self.n_layers or set(kinds) - set("ME*"):
+        n = self.n_layers * self.mixers_per_layer
+        kinds = tuple(self.layer_pattern[:n])
+        if len(kinds) < n or set(kinds) - set(MIXER_KINDS):
             raise ValueError(
                 f"{self.name}: layer_pattern {self.layer_pattern!r} does "
-                f"not name {self.n_layers} layers of kinds M, E, *")
+                f"not name {n} mixers of kinds {', '.join(MIXER_KINDS)}")
+        if "S" in kinds and self.sliding_window < 1:
+            raise ValueError(f"{self.name}: an S layer needs sliding_window")
         return kinds
 
     def n_of(self, kind: str) -> int:
@@ -187,9 +222,30 @@ class ModelConfig:
 
     @property
     def keeps_state(self) -> bool:
-        """A recurrent state beside the KV cache (engine/kv_pool.py::
-        StateStore): some layer is a state-space layer."""
+        """A bounded state a sequence beside the paged KV cache (engine/
+        kv_pool.py::StateStore): some layer is a state-space layer, or a
+        sliding-attention one."""
+        return self.has_ssm or self.slides
+
+    @property
+    def has_ssm(self) -> bool:
         return "M" in self.layer_kinds
+
+    @property
+    def slides(self) -> bool:
+        """Some attention layer reads a bounded span of keys."""
+        return "S" in self.layer_kinds
+
+    def heads_of(self, kind: str) -> int:
+        """Query heads of attention kind ``*`` or ``S``."""
+        return (self.sliding_n_heads or self.n_heads) if kind == "S" \
+            else self.n_heads
+
+    def sliding_ring(self, widest_window: int, page: int) -> int:
+        """Rows of a sequence's sliding state as it is kept live: the span
+        and the widest window one call writes beside it, in whole pages. A
+        row is addressed by its position modulo this."""
+        return -(-(self.sliding_window + widest_window) // page) * page
 
     @property
     def ssm_inner(self) -> int:
@@ -201,12 +257,19 @@ class ModelConfig:
         return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
 
     def state_bytes(self) -> int:
-        """One sequence's recurrent state: float32 [heads, head_dim,
-        state] and a bf16 convolution tail a state-space layer."""
-        return self.n_of("M") * (
+        """One sequence's bounded state as a snapshot holds it: float32
+        [heads, head_dim, state] and a bf16 convolution tail a state-space
+        layer."""
+        ssm = self.n_of("M") * (
             4 * self.ssm_heads * self.ssm_head_dim * self.ssm_state
             + 2 * (self.ssm_conv - 1) * self.ssm_conv_dim
-        ) if self.keeps_state else 0
+        ) if self.has_ssm else 0
+        # ... and the last ``sliding_window`` K and V rows (bf16) a
+        # sliding-attention layer
+        sliding = self.n_of("S") * (
+            2 * 2 * self.sliding_window * self.n_kv_heads * self.head_dim
+        ) if self.slides else 0
+        return ssm + sliding
 
     @property
     def index_key_width(self) -> int:
@@ -272,19 +335,30 @@ class ModelConfig:
 
     def _patterned_param_count(self) -> int:
         d, mats = self.dim, 3 if self.gated_mlp else 2
-        attn = 2 * d * self.head_dim * (self.n_heads + self.n_kv_heads)
+
+        def attn_of(kind):
+            H = self.heads_of(kind)
+            return (2 * d * self.head_dim * (H + self.n_kv_heads)
+                    + (d * H if self.attn_gate else 0))
+
+        attn = attn_of("*")
         moe = (self.n_experts * mats * d * self.mlp_hidden
                + mats * d * self.shared_mlp_hidden
-               + d * self.n_experts + self.n_experts)
+               + d * self.experts_scored
+               + (self.n_experts if self.router == "sigmoid_bias" else 0))
         ssm = (d * (2 * self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
                     + self.ssm_heads)
                + self.ssm_inner * d + (self.ssm_conv + 1) * self.ssm_conv_dim
                + 3 * self.ssm_heads + self.ssm_inner)
-        per = {"*": attn, "E": moe, "M": ssm}
+        per = {"*": attn, "E": moe, "M": ssm, "S": attn_of("S"),
+               "D": mats * d * self.dense_mlp_hidden}
         layers = sum(per[k] + d for k in self.layer_kinds)
         head = 0 if self.tie_embeddings else self.vocab_size * d
         return self.vocab_size * d + layers + d + head
 
+
+#: The kinds ``layer_pattern`` may name.
+MIXER_KINDS = ("M", "E", "*", "S", "D")
 
 _CONFIGS: Dict[str, ModelConfig] = {}
 
@@ -342,6 +416,27 @@ TOY_MLA_MOE = _register(ModelConfig(
     qk_rope_head_dim=16, v_head_dim=32, rope_interleave=True,
     rope_factor=8.0, rope_original_max=64, rope_mscale=1.0,
     rope_mscale_all_dim=1.0, q_scale_beta=0.1, max_seq_len=2048,
+))
+
+# Attention of two kinds (full, sliding, full, sliding, sliding, full: a
+# kind after itself and after the other; a count of each that no prefix of
+# the published order has, which is how the plain reference tells the two
+# orders apart) in blocks written as their two mixers: a span (24) the
+# tests' prompts cross
+# many times, head counts that differ by kind with groups of 3 and 2 over
+# the same 2 KV heads, YaRN on half the full kind's lanes and a plain
+# rotary embedding on all of the sliding kind's, a per-head gate, a leading
+# dense MLP and a chip's share of the experts (the router scores 16, this
+# tree holds 4): the toy of the benchmark's laguna-s-2.1-l12 configuration.
+TOY_SLIDING_MOE = _register(ModelConfig(
+    name="toy-sliding-moe", vocab_size=512, dim=128, n_layers=6, n_heads=4,
+    n_kv_heads=2, head_dim=32, mlp_hidden=64, dense_mlp_hidden=192,
+    n_experts=4, experts_per_token=2, router_width=16, router_scale=2.5,
+    shared_mlp_hidden=64, layer_pattern="*DSE*ESESE*E", mixers_per_layer=2,
+    sliding_window=24, sliding_n_heads=6, sliding_rope_theta=10000.0,
+    rope_theta=500000.0, rope_partial=0.5, rope_factor=8.0,
+    rope_original_max=64, rope_attention_factor=1.2, attn_gate="per-head",
+    max_seq_len=2048,
 ))
 
 # --- Gemma (HF: google/gemma-{2b,7b}-it) ---

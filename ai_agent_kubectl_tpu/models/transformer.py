@@ -25,7 +25,9 @@ reference) or "flash" (Pallas, ops/flash_attention.py).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import math
 from functools import partial
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
@@ -99,6 +101,24 @@ class KVCache:
              queries the passes ran and the cached rows those had before
              them, summed over layers and passes since the chunk program
              last zeroed it (/health.latent_attention). Absent elsewhere.
+    sk, sv:  a configuration with sliding-attention layers
+             (``ModelConfig.slides``) only: those layers' K and V, a
+             BOUNDED state a sequence and not rows of the pool — [n_sliding
+             layers, batch, ring, n_kv_heads, head_dim], position p's row
+             at ``p % ring``, the ring the span and the widest window a
+             call writes beside it (``ModelConfig.sliding_ring``; the
+             leaf's own shape is what the code reads). One row a batch row
+             of the call, carried, cut and put back as ``ssm``/``conv``
+             are; ``k``/``v`` hold a row for each FULL attention layer
+             only. ``forward``, given such a configuration and no leaf,
+             makes a zero one wide enough for the call's own window and
+             returns it.
+    span_rows: int32 [4], for such a configuration's engine: the DECODE
+             queries its sliding layers ran and the keys those had inside
+             their span, then the same two for its full layers (the live
+             context), summed over layers and passes since the chunk
+             program last zeroed it (/health.sliding_attention). Absent
+             elsewhere.
     """
 
     k: Any
@@ -111,6 +131,14 @@ class KVCache:
     conv: Any = None
     lat: Any = None
     lat_rows: Any = None
+    sk: Any = None
+    sv: Any = None
+    span_rows: Any = None
+
+    #: the leaves that hold one bounded state a batch row (axis 1)
+    STATE = ("ssm", "conv", "sk", "sv")
+    #: what the passes count on the device, zeroed by the chunk program
+    COUNTS = ("experts_read", "sel_rows", "lat_rows", "span_rows")
 
     @classmethod
     def zeros(cls, cfg: ModelConfig, batch: int, max_seq: int,
@@ -155,19 +183,34 @@ def state_zeros(cfg: ModelConfig, rows: int, dtype=jnp.bfloat16):
             jnp.zeros((n, rows, cfg.ssm_conv - 1, cfg.ssm_conv_dim), dtype))
 
 
+def sliding_zeros(cfg: ModelConfig, rows: int, ring: int,
+                  dtype=jnp.bfloat16):
+    """(sk, sv) leaves of ``rows`` sequences: ``ring`` K and V rows a
+    sliding-attention layer."""
+    shape = (cfg.n_of("S"), rows, ring, cfg.n_kv_heads, cfg.head_dim)
+    return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+
+
+def _state_leaves(cache: KVCache):
+    return {name: getattr(cache, name) for name in KVCache.STATE
+            if getattr(cache, name) is not None}
+
+
 def state_take_row(cache: KVCache, row) -> KVCache:
     """The cache with its state leaves cut to the one row ``row`` (a
     traced scalar): what a one-sequence call for that slot is handed."""
     one = lambda a: jax.lax.dynamic_slice_in_dim(a, row, 1, axis=1)
-    return dataclasses.replace(cache, ssm=one(cache.ssm),
-                               conv=one(cache.conv))
+    return dataclasses.replace(
+        cache, **{n: one(a) for n, a in _state_leaves(cache).items()})
 
 
-def state_put_row(cache: KVCache, ssm, conv, row) -> KVCache:
-    """``cache`` with one-row state leaves written back at ``row``."""
+def state_put_row(cache: KVCache, out: KVCache, row) -> KVCache:
+    """``out`` (a one-row call's result) with ``cache``'s state leaves in
+    the place of its one-row ones, those written back at ``row``."""
     put = lambda a, u: jax.lax.dynamic_update_slice_in_dim(a, u, row, axis=1)
-    return dataclasses.replace(cache, ssm=put(cache.ssm, ssm),
-                               conv=put(cache.conv, conv))
+    return dataclasses.replace(
+        out, **{n: put(a, getattr(out, n))
+                for n, a in _state_leaves(cache).items()})
 
 
 # ----------------------------------------------------------------- init
@@ -215,21 +258,29 @@ def _init_patterned(key: jax.Array, cfg: ModelConfig, dtype) -> Params:
                 * shape[-2] ** -0.5).astype(dtype)
 
     keys = iter(jax.random.split(key, 32))
-    d, hd, H, KV = cfg.dim, cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
+    d, hd, KV = cfg.dim, cfg.head_dim, cfg.n_kv_heads
     nA, nE, nM = cfg.n_of("*"), cfg.n_of("E"), cfg.n_of("M")
     layers: Params = {}
+
+    def attention(kind: str, n: int) -> Params:
+        H = cfg.heads_of(kind)
+        leaves = dict(zip(ATTENTION_LEAVES[kind], (
+            jnp.ones((n, d), dtype),
+            dense(next(keys), (n, d, H * hd)),
+            dense(next(keys), (n, d, KV * hd)),
+            dense(next(keys), (n, d, KV * hd)),
+            dense(next(keys), (n, H * hd, d)))))
+        if cfg.attn_gate:
+            leaves[ATTENTION_LEAVES[kind][-1]] = dense(next(keys), (n, d, H))
+        return leaves
+
     if nA:
-        layers.update(
-            attn_norm=jnp.ones((nA, d), dtype),
-            wq=dense(next(keys), (nA, d, H * hd)),
-            wk=dense(next(keys), (nA, d, KV * hd)),
-            wv=dense(next(keys), (nA, d, KV * hd)),
-            wo=dense(next(keys), (nA, H * hd, d)))
+        layers.update(attention("*", nA))
     if nE:
         E, F, Fs = cfg.n_experts, cfg.mlp_hidden, cfg.shared_mlp_hidden
         layers.update(
             mlp_norm=jnp.ones((nE, d), dtype),
-            router=dense(next(keys), (nE, d, E)),
+            router=dense(next(keys), (nE, d, cfg.experts_scored)),
             w_up=dense(next(keys), (nE, E, d, F)),
             w_down=dense(next(keys), (nE, E, F, d)))
         if cfg.router == "sigmoid_bias":
@@ -253,6 +304,16 @@ def _init_patterned(key: jax.Array, cfg: ModelConfig, dtype) -> Params:
                             ("ssm_conv_b", (nM, C)), ("ssm_dt_bias", (nM, Hs)),
                             ("ssm_A_log", (nM, Hs)), ("ssm_D", (nM, Hs))):
             layers[name] = small_leaf_init(name, shape, dtype, next(keys))
+    if cfg.n_of("S"):
+        layers.update(attention("S", cfg.n_of("S")))
+    if nD := cfg.n_of("D"):
+        Fd = cfg.dense_mlp_hidden
+        layers.update(
+            dense_norm=jnp.ones((nD, d), dtype),
+            dense_up=dense(next(keys), (nD, d, Fd)),
+            dense_down=dense(next(keys), (nD, Fd, d)))
+        if cfg.gated_mlp:
+            layers["dense_gate"] = dense(next(keys), (nD, d, Fd))
     params: Params = {
         "embed": (jax.random.normal(next(keys), (cfg.vocab_size, d),
                                     jnp.float32)).astype(dtype),
@@ -813,6 +874,101 @@ def _latent_attention(cfg: ModelConfig, attn_impl: str, x, lp: Params,
     return out, layer_lat, rows
 
 
+def _rotary_rule(cfg: ModelConfig, kind: str):
+    """``rotate(x, positions)`` of attention kind ``kind``: the plain rotary
+    embedding on every lane for every family but one; for a configuration
+    with a rule a kind, the kind's theta on its rotated share of the lanes,
+    the full kind's with YaRN frequencies and its factor on cos and sin."""
+    from ..ops.rope import apply_rope_partial, yarn_frequencies
+
+    theta, part = ((cfg.sliding_rope_theta, cfg.sliding_rope_partial)
+                   if kind == "S" else (cfg.rope_theta, cfg.rope_partial))
+    scaled = kind != "S" and (cfg.rope_factor > 1.0
+                              or cfg.rope_attention_factor)
+    if part == 1.0 and not scaled:
+        return lambda x, pos: apply_rope(x, pos, theta)
+    rot = int(cfg.head_dim * part) // 2 * 2
+    # (a factor of 1 gives the plain frequencies)
+    inv_freq = yarn_frequencies(
+        rot, theta, cfg.rope_factor if scaled else 1.0,
+        cfg.rope_original_max, cfg.rope_beta_fast, cfg.rope_beta_slow)
+    factor = (cfg.rope_attention_factor or 1.0) if scaled else 1.0
+    return lambda x, pos: apply_rope_partial(x, pos, inv_freq, factor)
+
+
+def _query_lens(positions, q_lens):
+    """[B] int32: each row's valid query columns (every column without
+    ``q_lens``)."""
+    B, S = positions.shape
+    return (jnp.full((B,), S, jnp.int32) if q_lens is None
+            else q_lens.astype(jnp.int32))
+
+
+def _span_rows(cfg: ModelConfig, kind: str, positions, q_lens, token_mask):
+    """int32 [4]: this layer's live decode rows and the keys their queries
+    see, in the sliding kind's two words or the full kind's (``KVCache.
+    span_rows``) — the bound the mask below applies, counted beside it."""
+    decode = _query_lens(positions, q_lens) == 1
+    if token_mask is not None:
+        decode = jnp.logical_and(decode, token_mask[:, 0] > 0)
+    keys = positions[:, 0] + 1
+    if kind == "S":
+        keys = jnp.minimum(keys, cfg.sliding_window)
+    pair = jnp.stack([jnp.sum(decode, dtype=jnp.int32),
+                      jnp.sum(jnp.where(decode, keys, 0), dtype=jnp.int32)])
+    zero = jnp.zeros((2,), jnp.int32)
+    return jnp.concatenate([pair, zero] if kind == "S" else [zero, pair])
+
+
+def _ring_write(sk, sv, k, v, positions, write_mask, layer):
+    """The window's K/V rows into layer ``layer`` of the sequences' rings,
+    position p at row ``p % ring`` of its batch row; masked rows drop."""
+    B, ring = positions.shape[0], sk.shape[2]
+    row = positions % ring
+    if write_mask is not None:
+        wm = write_mask if write_mask.ndim == 2 else write_mask[:, None]
+        row = jnp.where(wm, row, ring)            # out of bounds: dropped
+    idx = (layer, jnp.arange(B)[:, None], row)
+    return (sk.at[idx].set(k.astype(sk.dtype), mode="drop"),
+            sv.at[idx].set(v.astype(sv.dtype), mode="drop"))
+
+
+def _sliding_attention(cfg: ModelConfig, attn_impl: str, q, sk, sv,
+                       positions, q_lens, kv_limit: int, layer):
+    """Attention of a sliding layer over the sequences' rings (``sk``/``sv``
+    [layers, B, ring, KV, hd], the window's own rows already written):
+    query t sees keys s with t - span < s <= t. The ragged kernel reads a
+    ring as a small pool of its own — batch row b's sequence page p is the
+    ring's block ``b * ring_pages + p % ring_pages`` — through the SAME
+    page stream, started at the page that holds the tile's first key; the
+    gather path reads the span + S - 1 rows the window can see."""
+    B, S = positions.shape
+    span, ring = cfg.sliding_window, sk.shape[2]
+    if S + span > ring + 1:
+        raise ValueError(
+            f"a {S}-wide window beside a span of {span} needs a ring of "
+            f"{S + span - 1} rows; this one has {ring}")
+    if attn_impl == "ragged":
+        from ..ops.ragged_attention import ragged_attention_pool, ring_tables
+
+        page = math.gcd(ring, 64)     # the view's own page, not the pool's
+        as_pool = lambda a: a.reshape(a.shape[0], B * (ring // page), page,
+                                      *a.shape[3:])
+        return ragged_attention_pool(
+            q, as_pool(sk), as_pool(sv), _query_lens(positions, q_lens),
+            positions[:, 0],
+            ring_tables(B, ring // page, -(-kv_limit // page)), layer,
+            page_size=page, window=span)
+    first = positions[:, :1] - (span - 1)                       # [B, 1]
+    key_pos = first + jnp.arange(span + S - 1)[None, :]          # [B, K]
+    rows = (jnp.arange(B)[:, None], key_pos % ring)
+    mask = jnp.logical_and(
+        key_pos[:, None, :] <= positions[:, :, None],
+        jnp.logical_and(key_pos[:, None, :] > positions[:, :, None] - span,
+                        key_pos[:, None, :] >= 0))
+    return dense_attention(q, sk[layer][rows], sv[layer][rows], mask)
+
+
 def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
            h: jnp.ndarray, lp: Params,
            layer_k: jnp.ndarray, layer_v: jnp.ndarray,
@@ -824,7 +980,8 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
            q_lens=None,
            layer=None,
            layer_ik=None,
-           win: Optional[WindowRows] = None) -> Tuple[jnp.ndarray, ...]:
+           win: Optional[WindowRows] = None,
+           kind: str = "*") -> Tuple[jnp.ndarray, ...]:
     """One transformer block. Returns (h_out, new_layer_k, new_layer_v,
     new_layer_ik, counts): ``layer_ik`` is the index-key leaf of a
     selecting configuration — or the latent leaf of a latent-attention
@@ -862,10 +1019,16 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
     describe. Norms, projections, rotary and the MLP run on the packed
     rows; q, k, v (and a selector's operands) are unpacked to [N, W, ...]
     for the cache write and the attention, whose output is packed again.
+
+    ``kind`` (static; a configuration with attention of two kinds): ``S``
+    is a sliding-attention layer — its own head count and rotary rule,
+    ``layer_k``/``layer_v`` the sequences' RINGS (``KVCache.sk``/``sv``)
+    and not the pool, every query bounded to its span
+    (``_sliding_attention``) — and ``*`` the full kind.
     """
     B, S = positions.shape              # the window the mixer sees
     Bh, Sh, d = h.shape                 # the rows the residual carries
-    H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    H, KV, hd = cfg.heads_of(kind), cfg.n_kv_heads, cfg.head_dim
     counts = {}
     rope_pos = positions if win is None else win.pos
     mlp_mask = token_mask if win is None else win.valid
@@ -923,10 +1086,14 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
             qi = qmatmul(x, lp["idx_wq"]).reshape(Bh, Sh, J, di)
             ki = qmatmul(x, lp["idx_wk"]).reshape(Bh, Sh, 1, di)
             wi = x @ lp["idx_ww"]
+        gate = None
+        if cfg.attn_gate:
+            # one scalar a head, from the layer's normed input
+            gate = jax.nn.sigmoid((x @ lp["wg"]).astype(jnp.float32))
     with jax.named_scope("rope"):
         if cfg.use_rope:
-            q = apply_rope(q, rope_pos, cfg.rope_theta)
-            k = apply_rope(k, rope_pos, cfg.rope_theta)
+            rotate = _rotary_rule(cfg, kind)
+            q, k = rotate(q, rope_pos), rotate(k, rope_pos)
         if cfg.selects_keys:
             qi = apply_rope(qi, rope_pos, cfg.rope_theta)
             ki = apply_rope(ki, rope_pos, cfg.rope_theta)[:, :, 0]
@@ -937,6 +1104,35 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
             if cfg.selects_keys:
                 qi, ki, wi = (win.unpack(qi), win.unpack(ki),
                               win.unpack(wi))
+            if gate is not None:
+                gate = win.unpack(gate)
+
+    def gated(attn):
+        """Each head's output times its gate (float32, rounded once)."""
+        if gate is None:
+            return attn
+        return (attn.astype(jnp.float32) * gate[..., None]).astype(attn.dtype)
+
+    if cfg.slides:
+        if block_tables is None:
+            raise NotImplementedError(
+                f"{cfg.name} has sliding-attention layers (sliding_window="
+                f"{cfg.sliding_window}): their bounded K/V ride the pool "
+                "engine's cache, so it is served through the pool alone "
+                "(KV_POOL, one device); the dense per-slot ladder would "
+                "attend to every key")
+        counts["span_rows"] = _span_rows(cfg, kind, positions, q_lens,
+                                         token_mask)
+    if kind == "S":
+        with jax.named_scope("kv_write"):
+            layer_k, layer_v = _ring_write(layer_k, layer_v, k, v, positions,
+                                           write_mask, layer)
+        with jax.named_scope("attention"), jax.named_scope("sliding"):
+            attn = gated(_sliding_attention(
+                cfg, attn_impl, q, layer_k, layer_v, positions, q_lens,
+                kv_limit, layer))
+        return (after_attention(out_proj(attn)), layer_k, layer_v, layer_ik,
+                counts)
     if cfg.selects_keys and (block_tables is None or layer_ik is None):
         raise NotImplementedError(
             f"{cfg.name} selects its keys (index_topk={cfg.index_topk}): "
@@ -975,7 +1171,9 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
         n_pages = kv_limit // page
         kv_pos = jnp.arange(kv_limit)[None, None, :]
         mask = kv_pos <= positions[:, :, None]
-        with jax.named_scope("attention"):
+        with jax.named_scope("attention"), (
+                jax.named_scope("full") if cfg.slides
+                else contextlib.nullcontext()):
             if cfg.selects_keys and kv_limit > cfg.index_topk:
                 if is_q:
                     raise NotImplementedError(
@@ -1031,9 +1229,13 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
                                                   positions)
                 else:
                     attn = dense_attention(q, k_ctx, v_ctx, mask)
+            attn = gated(attn)
         return (after_attention(out_proj(attn)), layer_k, layer_v, layer_ik,
                 counts)
 
+    if gate is not None:
+        raise NotImplementedError(
+            f"{cfg.name}: the per-head gate is served through the pool alone")
     # Write this chunk's K/V into the cache at its absolute positions.
     # (scatter; positions are per-slot absolute indices). Dead rows
     # (write_mask False) scatter at an out-of-bounds position, which jax
@@ -1112,8 +1314,13 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
 
 # ------------------------------------------- one mixer a layer (patterned)
 
-#: A patterned configuration's leaves by kind (those the tree holds).
-ATTENTION_LEAVES = ("attn_norm", "wq", "wk", "wv", "wo")
+#: A patterned configuration's leaves by kind (those the tree holds). An
+#: attention kind's are its norm, wq, wk, wv, wo and (``attn_gate``) the
+#: per-head gate's projection, in that order: ``_layer`` reads them under
+#: the full kind's names.
+ATTENTION_LEAVES = {
+    "*": ("attn_norm", "wq", "wk", "wv", "wo", "wg"),
+    "S": ("sw_norm", "sw_wq", "sw_wk", "sw_wv", "sw_wo", "sw_wg")}
 EXPERT_LAYER_LEAVES = ("router", "router_bias", "w_gate", "w_up", "w_down",
                        "shared_gate", "shared_up", "shared_down")
 
@@ -1205,9 +1412,10 @@ def _patterned_layers(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
     as the kind ``cfg.layer_kinds[l]`` names, on layer j of that kind's
     stacks (j its ordinal among its kind) — three kinds cannot share a
     scan body. ``win``: ``h`` is the window's packed rows, which an
-    expert layer takes as they are and the two sequence mixers unpack
-    around their scan or attention. Returns (h, k, v, ssm, conv,
-    experts_read or None)."""
+    expert layer and a dense MLP take as they are and the sequence mixers
+    unpack around their scan or attention. Returns (h, the cache with its
+    K/V and state leaves as the layers left them, what the passes counted
+    by ``KVCache`` field)."""
     B, S = positions.shape
     valid = jnp.ones((B, S), bool)
     if token_mask is not None:
@@ -1218,11 +1426,22 @@ def _patterned_layers(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
     if q_lens is not None:
         valid = jnp.logical_and(valid, jnp.arange(S)[None, :] < q_lens[:, None])
     k, v, ssm, conv = cache.k, cache.v, cache.ssm, cache.conv
-    if cfg.keeps_state and ssm is None:
+    sk, sv = cache.sk, cache.sv
+    if cfg.has_ssm and ssm is None:
         ssm, conv = state_zeros(cfg, B, h.dtype)
+    if cfg.slides and sk is None and block_tables is not None:
+        # no leaf given (benchmark/refcheck.py): one that holds this call's
+        # own window beside the span, in the K pool's pages
+        sk, sv = sliding_zeros(cfg, B, cfg.sliding_ring(S, k.shape[-3]),
+                               h.dtype)
     step = partial(_layer, cfg, attn_impl, mesh, moe_impl)
-    n_read = None
-    seen = dict.fromkeys("ME*", 0)
+    counts: Dict[str, Any] = {}
+
+    def count(new):
+        for name, n in new.items():
+            counts[name] = n if name not in counts else counts[name] + n
+
+    seen = dict.fromkeys(cfg.layer_kinds, 0)
     for kind in cfg.layer_kinds:
         j = seen[kind]
         seen[kind] += 1
@@ -1234,13 +1453,29 @@ def _patterned_layers(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
                 cfg, layers, j, h, mesh,
                 token_mask if win is None else win.valid, moe_impl)
             if n is not None:
-                n_read = n if n_read is None else n_read + n
+                count({"experts_read": n})
+        elif kind == "D":
+            with jax.named_scope("mlp_norm"):
+                x = rms_norm(h, layers["dense_norm"][j], cfg.rms_eps,
+                             cfg.rms_offset)
+            with jax.named_scope("mlp"):
+                h = h + _dense_mlp(
+                    cfg, {name: _at(layers[name], j) for name in layers
+                          if name.startswith("dense_")}, x, "dense_")
         else:
-            lp = {name: _at(layers[name], j) for name in ATTENTION_LEAVES}
-            h, k, v, _, _ = step(h, lp, k, v, positions, kv_limit, batch_idx,
-                                 token_mask, write_mask, block_tables, q_lens,
-                                 jnp.asarray(j, jnp.int32), None, win)
-    return h, k, v, ssm, conv, n_read
+            lp = {name: _at(layers[own], j) for name, own
+                  in zip(ATTENTION_LEAVES["*"], ATTENTION_LEAVES[kind])
+                  if own in layers}
+            args = (positions, kv_limit, batch_idx, token_mask, write_mask,
+                    block_tables, q_lens, jnp.asarray(j, jnp.int32), None,
+                    win, kind)
+            if kind == "S":
+                h, sk, sv, _, n = step(h, lp, sk, sv, *args)
+            else:
+                h, k, v, _, n = step(h, lp, k, v, *args)
+            count(n)
+    return h, dataclasses.replace(cache, k=k, v=v, ssm=ssm, conv=conv,
+                                  sk=sk, sv=sv), counts
 
 
 # -------------------------------------------------------------- forward
@@ -1314,9 +1549,8 @@ def forward(
         kv_limit = cache.max_seq
     B, S = tokens.shape
     batch_idx = jnp.arange(B)[:, None]
-    new_ik, new_ssm, new_conv = cache.ik, cache.ssm, cache.conv
-    counted = {"experts_read": cache.experts_read,
-               "sel_rows": cache.sel_rows, "lat_rows": cache.lat_rows}
+    new_ik, state = cache.ik, cache
+    counted = {name: getattr(cache, name) for name in KVCache.COUNTS}
     if cfg.latent and (cfg.layer_kinds or (mesh is not None
                                            and mesh.size > 1)):
         raise NotImplementedError(
@@ -1378,12 +1612,14 @@ def forward(
             kv_limit=kv_limit, attn_impl="dense",
         )
     elif cfg.layer_kinds:
-        h, new_k, new_v, new_ssm, new_conv, n_read = _patterned_layers(
+        h, state, counts = _patterned_layers(
             cfg, attn_impl, mesh, moe_impl, params["layers"], h, cache,
             positions, kv_limit, batch_idx, token_mask, write_mask,
             block_tables, q_lens, win)
-        if n_read is not None and counted["experts_read"] is not None:
-            counted["experts_read"] = counted["experts_read"] + n_read
+        new_k, new_v = state.k, state.v
+        for name, n in counts.items():
+            if counted[name] is not None:
+                counted[name] = counted[name] + n
     else:
         step = partial(_layer, cfg, attn_impl, mesh, moe_impl)
 
@@ -1462,5 +1698,5 @@ def forward(
     if cfg.latent:
         new_lat, new_ik = new_ik, None
     return logits.astype(jnp.float32), KVCache(
-        k=new_k, v=new_v, lengths=new_lengths, ik=new_ik, ssm=new_ssm,
-        conv=new_conv, lat=new_lat, **counted)
+        k=new_k, v=new_v, lengths=new_lengths, ik=new_ik, lat=new_lat,
+        **_state_leaves(state), **counted)

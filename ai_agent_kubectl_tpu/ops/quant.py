@@ -310,7 +310,11 @@ _QUANT_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
                # absorbed form contracts it over its OUTPUT channels, where
                # the per-channel scales sit (models/transformer.py::
                # init_params)
-               "w_dq", "w_uq", "w_dkv")
+               "w_dq", "w_uq", "w_dkv",
+               # the sliding-attention kind's projections and the dense
+               # MLP mixer's (a configuration with attention of two kinds)
+               "sw_wq", "sw_wk", "sw_wv", "sw_wo",
+               "dense_gate", "dense_up", "dense_down")
 
 
 #: The per-head q/k RMSNorm gains this SEEDED generator writes (bench/dev
@@ -480,6 +484,10 @@ def _random_params_int8(key, cfg, dtype, quantize_embed: bool, int4: bool,
                 scale = sds.shape[-2] ** -0.5
             if name == "w_ukv":
                 scale = SEEDED_UKV_GAIN * sds.shape[-2] ** -0.5
+            if name in ("wg", "sw_wg"):
+                # a head's gate logit of unit variance: gates spread over
+                # (0.1, 0.9), not all at 1/2
+                scale = sds.shape[-2] ** -0.5
             out.append(
                 (jax.random.normal(k, sds.shape, _jnp.float32) * scale)
                 .astype(dtype)

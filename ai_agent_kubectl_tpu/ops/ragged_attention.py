@@ -220,7 +220,8 @@ def _ragged_pool_kernel(pos_ref, qlen_ref, tbl_ref, lyr_ref, q_ref, k_hbm,
                         *rest, page_size: int, scale: float,
                         n_pages: int, kv_heads: int, tq: int, pps: int,
                         depth: int, flat: bool, grid: tuple,
-                        selects: bool = False, v_lanes: int = 0):
+                        selects: bool = False, v_lanes: int = 0,
+                        window: int = 0):
     """Online-softmax body over one (slot, query tile, page block) grid
     cell: ``tq`` query columns against ``pps`` pages.
 
@@ -266,7 +267,14 @@ def _ragged_pool_kernel(pos_ref, qlen_ref, tbl_ref, lyr_ref, q_ref, k_hbm,
     ``v_lanes`` (latent attention, ``latent_attention_pool``): there is
     no V operand and no V ring; ONE leaf streams, of one row a key (the
     ``flat`` form with one KV head), and a key's value is the first
-    ``v_lanes`` lanes of its own row."""
+    ``v_lanes`` lanes of its own row.
+
+    ``window`` > 0 (a sliding-attention layer): query column j attends
+    only keys ``> pos + j - window``, and the tile's page stream STARTS at
+    the page that holds its first column's first key instead of at page 0
+    — the same stream, cursor and ring, with a lower end. The grid's block
+    axis then counts from the tile's first live block (``first_block_of``)
+    and is only as long as a span and a tile need."""
     sel_ref = v_hbm = v_buf = None
     if not v_lanes:
         v_hbm, *rest = rest
@@ -276,7 +284,7 @@ def _ragged_pool_kernel(pos_ref, qlen_ref, tbl_ref, lyr_ref, q_ref, k_hbm,
         (o_ref, k_buf, sems, ring_ref, m_scr, l_scr, acc_scr) = rest
     else:
         (o_ref, k_buf, v_buf, sems, ring_ref, m_scr, l_scr, acc_scr) = rest
-    n, t, b = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    n, t, b_grid = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     n_slots, n_qt, n_blk = grid
     n_cells = n_slots * n_qt
     div, rem = jax.lax.div, jax.lax.rem
@@ -291,6 +299,17 @@ def _ragged_pool_kernel(pos_ref, qlen_ref, tbl_ref, lyr_ref, q_ref, k_hbm,
         last = jnp.minimum(div(pos_ref[n_] + hi - 1, page_size), n_pages - 1)
         return jnp.where(t_ * tq < q_len, last, -1)
 
+    def first_page_of(n_, t_):
+        """The first page tile ``t_`` of slot ``n_`` reads: page 0, or the
+        page of its first column's first key under a window."""
+        if not window:
+            return 0
+        return div(jnp.maximum(pos_ref[n_] + t_ * tq - (window - 1), 0),
+                   page_size)
+
+    def first_block_of(n_, t_):
+        return div(first_page_of(n_, t_), pps) if window else 0
+
     def tile_of(cell):
         return (cell, 0) if n_qt == 1 else (div(cell, n_qt), rem(cell, n_qt))
 
@@ -304,11 +323,12 @@ def _ragged_pool_kernel(pos_ref, qlen_ref, tbl_ref, lyr_ref, q_ref, k_hbm,
             lambda s: jnp.logical_and(s[0] < n_cells, s[1] < 0),
             lambda s: (s[0] + 1, last_of(s[0] + 1)), (cell, last_of(cell)))
 
-    def for_live_pages(blk, last, do):
+    def for_live_pages(blk, first, last, do):
         """``do(page, its row of the buffer)`` for every page of block
-        ``blk`` up to page ``last``."""
+        ``blk`` from page ``first`` up to page ``last``."""
         jax.lax.fori_loop(
-            blk * pps, jnp.minimum(blk * pps + pps, last + 1),
+            jnp.maximum(blk * pps, first),
+            jnp.minimum(blk * pps + pps, last + 1),
             lambda j, _: do(j, j - blk * pps), None)
 
     leaves = (((k_hbm, k_buf),) if v_lanes
@@ -320,7 +340,7 @@ def _ragged_pool_kernel(pos_ref, qlen_ref, tbl_ref, lyr_ref, q_ref, k_hbm,
         leaves = tuple((hbm.reshape(*hbm.shape[:2], -1, hbm.shape[-1]), buf)
                        for hbm, buf in leaves)
 
-    @pl.when(b == 0)
+    @pl.when(b_grid == 0)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
         l_scr[...] = jnp.zeros_like(l_scr)
@@ -334,13 +354,18 @@ def _ragged_pool_kernel(pos_ref, qlen_ref, tbl_ref, lyr_ref, q_ref, k_hbm,
             values = k_buf if v_lanes else v_buf
             values[...] = jnp.zeros_like(values)
             cell, last = first_reader(0)
-            for i, x in enumerate((0, 0, cell, 0, last)):
+            blk0 = first_block_of(*tile_of(jnp.minimum(cell, n_cells - 1)))
+            for i, x in enumerate((0, 0, cell, blk0, last)):
                 ring_ref[i] = x
 
     pos = pos_ref[n]
     q_len = qlen_ref[n]
     q0 = t * tq                       # window column of tile row 0
     last_page = last_page_of(n, t)
+    first_page = first_page_of(n, t)
+    # the block this grid step stands for: the tile's blocks from its first
+    # live one (block 0 without a window)
+    b = b_grid + first_block_of(n, t)
 
     @pl.when(b * pps <= last_page)
     def _accumulate():
@@ -349,7 +374,7 @@ def _ragged_pool_kernel(pos_ref, qlen_ref, tbl_ref, lyr_ref, q_ref, k_hbm,
 
         def issue(s):
             issued, cell, blk, last = s
-            n_ = tile_of(cell)[0]
+            n_, t_ = tile_of(cell)
             buf_i = rem(issued, depth)
 
             def start(j, row):
@@ -358,10 +383,11 @@ def _ragged_pool_kernel(pos_ref, qlen_ref, tbl_ref, lyr_ref, q_ref, k_hbm,
                         hbm.at[lyr_ref[0], tbl_ref[n_, j]],
                         buf.at[buf_i, row], sems.at[c, buf_i]).start()
 
-            for_live_pages(blk, last, start)
+            for_live_pages(blk, first_page_of(n_, t_), last, start)
             more = (blk + 1) * pps <= last
             cell, last = first_reader(jnp.where(more, cell, cell + 1))
-            return issued + 1, cell, jnp.where(more, blk + 1, 0), last
+            blk0 = first_block_of(*tile_of(jnp.minimum(cell, n_cells - 1)))
+            return issued + 1, cell, jnp.where(more, blk + 1, blk0), last
 
         cursor = jax.lax.while_loop(
             lambda s: jnp.logical_and(s[0] < done + depth, s[1] < n_cells),
@@ -370,7 +396,8 @@ def _ragged_pool_kernel(pos_ref, qlen_ref, tbl_ref, lyr_ref, q_ref, k_hbm,
             ring_ref[1 + i] = x
 
         slot = rem(done, depth)
-        full = b * pps + pps - 1 <= last_page
+        full = jnp.logical_and(b * pps + pps - 1 <= last_page,
+                               b * pps >= first_page)
 
         # A wait needs only the destination and the semaphore.
         @pl.when(full)
@@ -387,7 +414,7 @@ def _ragged_pool_kernel(pos_ref, qlen_ref, tbl_ref, lyr_ref, q_ref, k_hbm,
                                           buf.at[slot, row],
                                           sems.at[c, slot]).wait()
 
-            for_live_pages(b, last_page, wait)
+            for_live_pages(b, first_page, last_page, wait)
 
         H, hd = q_ref.shape[2], q_ref.shape[3]
         G = H // kv_heads
@@ -458,6 +485,8 @@ def _ragged_pool_kernel(pos_ref, qlen_ref, tbl_ref, lyr_ref, q_ref, k_hbm,
         # (j >= q_len) mask everything — their normalizer stays 0 and the
         # finalize writes zeros (outputs are never read).
         mask = jnp.logical_and(kv_ids <= pos + q_ids, q_ids < q_len)
+        if window:                  # ... and only the keys inside its span
+            mask = jnp.logical_and(mask, kv_ids > pos + q_ids - window)
         if flat and not v_lanes:    # ... and only its own KV group's columns
             mask = jnp.logical_and(mask, own)
         if sel_ref is not None:
@@ -495,7 +524,7 @@ def _ragged_pool_kernel(pos_ref, qlen_ref, tbl_ref, lyr_ref, q_ref, k_hbm,
             )                           # [tq*H, hd] | [KV, tq*G, hd]
         acc_scr[...] = acc_scr[...] * alpha + weighted
 
-    @pl.when(b == n_blk - 1)
+    @pl.when(b_grid == n_blk - 1)
     def _finalize():
         H, hd = o_ref.shape[2], o_ref.shape[3]
         l = l_scr[...]
@@ -509,7 +538,7 @@ def _ragged_pool_kernel(pos_ref, qlen_ref, tbl_ref, lyr_ref, q_ref, k_hbm,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("page_size", "scale", "interpret", "v_lanes"),
+    static_argnames=("page_size", "scale", "interpret", "v_lanes", "window"),
 )
 def ragged_attention_pool(
     q: jnp.ndarray,             # [N, W, H, hd] per-slot query windows
@@ -526,6 +555,7 @@ def ragged_attention_pool(
     scale: Optional[float] = None,
     interpret: Optional[bool] = None,
     v_lanes: int = 0,
+    window: int = 0,
 ) -> jnp.ndarray:
     """Ragged block-paged attention over the pool. Returns
     [N, W, H, hd]; rows past ``q_lens[n]`` are zeros (never read —
@@ -537,6 +567,12 @@ def ragged_attention_pool(
     ``v_lanes`` > 0 (``latent_attention_pool``): ``v`` is None, ``k`` has
     one KV head, and a key's value is the first ``v_lanes`` lanes of its
     row; returns [N, W, H, v_lanes].
+
+    ``window`` > 0 (sliding attention): column j attends only the
+    ``window`` keys up to its own, ``positions[n] + j - window < kv``, and
+    no page before the one holding a tile's first such key is visited —
+    whatever the pool, a shared one through real tables or a sequence's
+    ring through ``ring_tables``.
 
     Cost per slot tracks ``ceil((positions[n]+q_lens[n])/page)`` live
     pages, whatever mixture of decode / verify / prefill widths the
@@ -590,11 +626,20 @@ def ragged_attention_pool(
         # (reckoned for a K and a V ring) only has more room
         flat = True
 
-    grid = (N, n_qt, pl.cdiv(n_pages, pps))
+    n_blk = pl.cdiv(n_pages, pps)
+    if window:
+        if sel is not None or v_lanes:
+            raise ValueError("a window takes neither a selection nor "
+                             "latent rows")
+        # a tile's keys: ``window + tq - 1`` positions from anywhere in a
+        # block
+        n_blk = min(n_blk, (window + tq - 2) // (pps * page_size) + 2)
+    grid = (N, n_qt, n_blk)
     kernel = functools.partial(
         _ragged_pool_kernel, page_size=page_size, scale=scale,
         n_pages=n_pages, kv_heads=KV, tq=tq, pps=pps, depth=depth,
         flat=flat, grid=grid, selects=sel is not None, v_lanes=v_lanes,
+        window=window,
     )
 
     def q_map(n, t, b, pos_ref, qlen_ref, tbl_ref, lyr_ref):
@@ -650,6 +695,16 @@ def ragged_attention_pool(
         interpret=interpret,
     )(*operands)
     return out[:, :W]
+
+
+def ring_tables(n_rows: int, ring_pages: int, n_pages: int) -> jnp.ndarray:
+    """Block tables [n_rows, n_pages] that read a leaf of ``n_rows`` rings
+    of ``ring_pages`` pages each ([n_rows * ring_pages, page, ...]) as a
+    pool: row r's sequence page p is block ``r * ring_pages + p %
+    ring_pages`` — where position ``p * page + i`` was written, at row
+    ``(p * page + i) % ring`` of its ring."""
+    return (jnp.arange(n_rows, dtype=jnp.int32)[:, None] * ring_pages
+            + jnp.arange(n_pages, dtype=jnp.int32)[None, :] % ring_pages)
 
 
 #: Tokens a row of the latent leaf holds (``latent_pack``).

@@ -6,7 +6,9 @@ paged decode correct: a token's rotation depends on its absolute position
 in the logical sequence, not on where its KV happens to live in cache
 memory (SURVEY.md §7, "Prefix-KV sharing" hard part).
 
-``apply_rope`` is the plain rotary embedding of every family but one.
+``apply_rope`` is the plain rotary embedding of most families.
+``apply_rope_partial`` rotates the first lanes of a head and passes the rest
+(``laguna``: YaRN on half the full-attention kind's lanes).
 ``apply_rope_scaled`` is the latent-attention family's (``mistral4``, after
 DeepSeek-V2): YaRN frequencies, pairs taken interleaved, a factor on cos
 and sin; ``query_scale`` is its position-dependent scale on the queries.
@@ -102,3 +104,17 @@ def query_scale(positions: jnp.ndarray, beta: float, original_max: int) -> jnp.n
     floor(pos / original_max)) — 1 inside the trained positions."""
     return 1.0 + beta * jnp.log1p(
         jnp.floor(positions.astype(jnp.float32) / original_max))
+
+
+
+def apply_rope_partial(x: jnp.ndarray, positions: jnp.ndarray, inv_freq,
+                       factor: float = 1.0) -> jnp.ndarray:
+    """Rotate the first ``2 x len(inv_freq)`` lanes of [batch, seq, heads,
+    dim], pairs (i, i + rot/2) within them, cos and sin times ``factor``;
+    the lanes past them pass as they are (``partial_rotary_factor``)."""
+    rot = 2 * len(inv_freq)
+    if rot == x.shape[-1]:
+        return apply_rope_scaled(x, positions, inv_freq, factor=factor)
+    return jnp.concatenate(
+        [apply_rope_scaled(x[..., :rot], positions, inv_freq, factor=factor),
+         x[..., rot:]], axis=-1)

@@ -366,7 +366,8 @@ def _group_tile(pairs: int, experts: int) -> int:
     two: at 64 rows an expert 128-row tiles cost more in products than
     the stream hides, 80-row tiles do not. Decode (under one row an
     expert) stays at the smallest tile."""
-    mean = -(-pairs // max(1, experts))
+    # (a share's expected pairs can round to none: one row of a small batch)
+    mean = max(1, -(-pairs // max(1, experts)))
     need = mean + math.isqrt(mean)
     return min(_GROUP_TILE_MAX, -(-need // _GROUP_TILE_MIN) * _GROUP_TILE_MIN)
 

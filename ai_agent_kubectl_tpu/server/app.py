@@ -1113,6 +1113,12 @@ async def handle_health(request: web.Request) -> web.Response:
     lah = getattr(svc.engine, "latent_attention_health", None)
     if callable(lah):
         latent_attention = lah() or None
+    # Attention of two kinds (ISSUE 40): keys a decode query read in its
+    # sliding and in its full layers — same rule.
+    sliding_attention = None
+    slh = getattr(svc.engine, "sliding_attention_health", None)
+    if callable(slh):
+        sliding_attention = slh() or None
     # Recurrent-state snapshots (ISSUE 33): same cheap host counters.
     ssm = None
     ssh = getattr(svc.engine, "ssm_health", None)
@@ -1179,6 +1185,7 @@ async def handle_health(request: web.Request) -> web.Response:
         moe=moe,
         sparse_attention=sparse_attention,
         latent_attention=latent_attention,
+        sliding_attention=sliding_attention,
         ssm=ssm,
         ragged=ragged,
         sharding=sharding,

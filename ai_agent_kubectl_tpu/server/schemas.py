@@ -129,6 +129,13 @@ class HealthResponse(BaseModel):
     # read (summed over layers), prompt rows prefilled by the absorbed and
     # by the expanded form. None for a model that caches K and V.
     latent_attention: Optional[Dict[str, Any]] = None
+    # Attention of two kinds (ISSUE 40; engine/batcher.py::
+    # sliding_attention_health): the span and the ring a decode slot keeps
+    # a sliding layer, decode queries run and the keys they read in the
+    # sliding and in the full layers (counted on the device), prompt rows
+    # prefilled and their pairs a layer of each kind. None for a model
+    # whose attention layers are of one kind.
+    sliding_attention: Optional[Dict[str, Any]] = None
     # Recurrent-state cache of a model with state-space layers (ISSUE 33;
     # engine/kv_pool.py::StateStore.stats, batcher.py::ssm_health):
     # snapshots held / capacity / bytes and their peak, snapshots taken /

@@ -108,8 +108,13 @@ def case_sliding_window_is_refused_only_while_unmapped():
         modelmap.sizes(cfg)
     mapped = dict(cfg, keys={"sliding_window": "sliding_window"})
     assert modelmap.sizes(mapped)["sliding_window"] == 4096      # now the field must exist
-    with pytest.raises(SystemExit, match="ModelConfig.sliding_window, which the program"):
-        modelmap.model_config("x", modelmap.sizes(mapped), modelmap.key_map(mapped))
+    # ... and since PR 40 it does: the key reaches it
+    built = modelmap.model_config("x", modelmap.sizes(mapped), modelmap.key_map(mapped))
+    assert built.sliding_window == 4096
+    # a key mapped to a field the program lacks still ends the run there
+    lacking = dict(cfg, keys={"sliding_window": "no_such_field"})
+    with pytest.raises(SystemExit, match="ModelConfig.no_such_field, which the program"):
+        modelmap.model_config("x", modelmap.sizes(lacking), modelmap.key_map(lacking))
 
 
 def case_a_collective_inside_a_scope_is_billed_to_collectives():

@@ -52,9 +52,10 @@ def test_match_is_usable_only_as_deep_as_the_last_snapshot():
     pool, store, radix = world()
     a = list(range(100, 122))                 # 22 tokens: last whole block ends at 20
     blocks, m = admit(pool, store, radix, 0, a)
-    assert m == 0 and store.pending(0) == [(20, store.pending(0)[0][1])]
+    # its last whole block's edge and the edge before it (ISSUE 40)
+    assert m == 0 and [e for e, _ in store.pending(0)] == [16, 20]
     finish(pool, store, radix, 0, a, blocks)
-    assert store.stats()["snapshots_held"] == 1 and not store.pending(0)
+    assert store.stats()["snapshots_held"] == 2 and not store.pending(0)
     hit0, miss0 = radix.hit_tokens_total, radix.miss_tokens_total
     # the same 22 tokens grown by a turn: K/V matches 5 blocks + 1 tail row,
     # the state only the 5 blocks
@@ -74,14 +75,14 @@ def test_match_is_usable_only_as_deep_as_the_last_snapshot():
     blocks, m = admit(pool, store, radix, 2, c)
     assert m == 0 and store.branch_edge(2) == 0
     assert store.prefix_tokens_recomputed == 2 + 12
-    assert [e for e, _ in store.pending(2)] == [20]
+    assert [e for e, _ in store.pending(2)] == [16, 20]
     finish(pool, store, radix, 2, c, blocks)
     # the next one finds a node two sequences branch from: it recomputes the 12
     # too and leaves the snapshot there, which the one after restores
     d = a[:12] + list(range(700, 709))
     blocks, m = admit(pool, store, radix, 0, d)
     assert m == 0 and store.branch_edge(0) == 12
-    assert [e for e, _ in store.pending(0)] == [20]            # 12 went on the tree
+    assert [e for e, _ in store.pending(0)] == [16, 20]        # 12 went on the tree
     finish(pool, store, radix, 0, d, blocks)
     e = a[:12] + list(range(800, 809))
     blocks, m = admit(pool, store, radix, 1, e)
@@ -92,22 +93,23 @@ def test_match_is_usable_only_as_deep_as_the_last_snapshot():
 def test_eviction_is_lru_among_snapshots_no_live_slot_descends_from():
     calls = []
     pool, store, radix = world(
-        capacity=2, snapshot_fn=lambda s, h: calls.append(("snap", s, h)),
+        capacity=4, snapshot_fn=lambda s, h: calls.append(("snap", s, h)),
         restore_fn=lambda s, h: calls.append(("restore", s, h)),
         zero_fn=lambda s: calls.append(("zero", s)))
     a, b, c = (list(range(k, k + 10)) for k in (100, 200, 300))
     for slot, ids in ((0, a), (1, b)):
         blocks, _ = admit(pool, store, radix, slot, ids)
         finish(pool, store, radix, slot, ids, blocks)
-    assert store.held == 2 and [k for k, *_ in calls] == ["zero", "snap"] * 2
-    # a live slot descends from a's snapshot (the OLDER one): c's snapshot
+    # two snapshots a prompt: its last block edge and the one before it
+    assert store.held == 4 and [k for k, *_ in calls] == ["zero", "snap", "snap"] * 2
+    # a live slot descends from a's snapshots (the OLDER ones): c's snapshots
     # must evict b's
     live, m = admit(pool, store, radix, 0, a + [1, 2, 3, 4, 5])
     assert m == 8 and calls[-2][0] == "restore"
-    assert store.restore_depth_peak == 2              # the older of the two held
+    assert store.restore_depth_peak == 3              # a's deepest: older than b's two
     held_by_a = calls[-2][2]
     blocks, _ = admit(pool, store, radix, 1, c)
-    assert store.snapshots_evicted == 1
+    assert store.snapshots_evicted == 2
     finish(pool, store, radix, 1, c, blocks)
     _, m_b = map_prefix(pool, radix, b + [9], state=store, slot=2)
     assert m_b == 0                                   # b's snapshot is gone
@@ -115,7 +117,8 @@ def test_eviction_is_lru_among_snapshots_no_live_slot_descends_from():
     # every snapshot pinned: a new one is skipped, not forced
     pool2, store2, radix2 = world(capacity=1)
     blocks, _ = admit(pool2, store2, radix2, 0, a)
-    assert store2.take(1, 8) is None and store2.snapshots_skipped == 1
+    assert store2.held == 1 and store2.snapshots_skipped == 1      # a's second cut
+    assert store2.take(1, 8) is None and store2.snapshots_skipped == 2
     store2.check()
 
 
@@ -124,9 +127,9 @@ def test_a_node_that_loses_its_block_loses_its_snapshot():
     a = list(range(100, 110))
     blocks, _ = admit(pool, store, radix, 0, a)
     finish(pool, store, radix, 0, a, blocks)
-    assert store.held == 1
+    assert store.held == 2
     assert radix.evict_for(8)                 # the pool wants everything back
-    assert store.held == 0 and store.snapshots_evicted == 1
+    assert store.held == 0 and store.snapshots_evicted == 2
     store.check()
     radix.clear()
     assert radix.stats()["snapshots"] == 0
@@ -217,8 +220,16 @@ def _mk(**kw):
 
 
 PREAMBLE = "cluster context: " + "node pool alpha beta gamma delta " * 3      # 116 chars
-TURNS = ["agent one asks about pods; ", "tool says twelve pods are ready; ",
+TURNS = ["agent one asks about pods in kube-system;  ",     # 160 tokens with the preamble
+         "tool says twelve pods are ready; ",
          "tool says one pod is crash looping now; "]
+
+
+# (longer than two pages of 16: a prompt's two cuts then stand past the
+# preamble's end, and the snapshot there is the branch rule's to leave)
+AGENT_TWO = "agent two starts here and lists every deployment; "
+AGENT_THREE = "agent three is here and wants the node status; "
+AGENT_FOUR = "agent four came too and asks about services; "
 
 
 @pytest.fixture(scope="module")
@@ -230,7 +241,7 @@ def from_token_zero():
 
     async def run():
         out, hist = {}, PREAMBLE
-        for t in TURNS + ["agent two starts here; "]:
+        for t in TURNS + [AGENT_TWO]:
             prompt = (PREAMBLE + t) if t.startswith("agent two") else (hist + t)
             out[prompt] = (await eng.generate(prompt, max_tokens=10, temperature=0.0)).text
             hist = prompt if not t.startswith("agent two") else hist
@@ -275,10 +286,11 @@ async def test_restored_snapshot_answers_as_a_prefill_from_token_zero(from_token
         assert usable[1] == (n1 - 1) // 16 * 16
         assert usable[2] == (n1 + len(TURNS[1]) - 1) // 16 * 16
         st = eng.ssm_health()
-        # one snapshot a turn, at its prompt's end: where turn 2 left turn 1's
-        # chain the node had one child, and nobody else comes that way
+        # two snapshots a turn, at its prompt's last block edge and the edge
+        # before it: where turn 2 left turn 1's chain the node had one child,
+        # and nobody else comes that way
         assert st["restores"] - st0["restores"] == 2
-        assert st["snapshots_taken"] - st0["snapshots_taken"] == 3
+        assert st["snapshots_taken"] - st0["snapshots_taken"] == 6
         # matched K/V past the snapshot (the rest of the last prompt and its
         # answer's rows) was recomputed, and counted so
         assert st["prefix_tokens_recomputed"] > 0
@@ -287,21 +299,22 @@ async def test_restored_snapshot_answers_as_a_prefill_from_token_zero(from_token
         radix = eng.kv_pool_health()["radix"]
         assert radix["hit_tokens"] == st["prefix_tokens_usable"]
         # a second agent: K/V matches the preamble, no snapshot there yet
-        r = await eng.generate(PREAMBLE + "agent two starts here; ", max_tokens=10,
+        r = await eng.generate(PREAMBLE + AGENT_TWO, max_tokens=10,
                                temperature=0.0)
-        assert r.text == from_token_zero[PREAMBLE + "agent two starts here; "]
+        assert r.text == from_token_zero[PREAMBLE + AGENT_TWO]
         st2 = eng.ssm_health()
         edge = len(eng.tokenizer.encode(PREAMBLE)) // 16 * 16
         assert st2["prefix_tokens_recomputed"] - st["prefix_tokens_recomputed"] >= edge
-        assert st2["snapshots_taken"] == st["snapshots_taken"] + 1    # its prompt's end
+        assert st2["snapshots_taken"] == st["snapshots_taken"] + 2    # its prompt's end
         # a third finds the preamble's end a node two sequences branch from:
         # it recomputes the preamble once more and leaves the snapshot there
-        await eng.generate(PREAMBLE + "agent three is here; ", max_tokens=4, temperature=0.0)
+        await eng.generate(PREAMBLE + AGENT_THREE, max_tokens=4, temperature=0.0)
         st3 = eng.ssm_health()
         assert st3["prefix_tokens_recomputed"] - st2["prefix_tokens_recomputed"] >= edge
-        assert st3["snapshots_taken"] == st2["snapshots_taken"] + 2   # branch + prompt end
+        # the branch edge + its prompt's two
+        assert st3["snapshots_taken"] == st2["snapshots_taken"] + 3
         # which a fourth restores
-        await eng.generate(PREAMBLE + "agent four came too; ", max_tokens=4, temperature=0.0)
+        await eng.generate(PREAMBLE + AGENT_FOUR, max_tokens=4, temperature=0.0)
         st3, st2 = eng.ssm_health(), st3
         assert st3["prefix_tokens_usable"] - st2["prefix_tokens_usable"] == edge
         assert st3["layer_passes"]["ssm"] == st3["forward_passes"] * 2

@@ -796,3 +796,93 @@ def test_latent_copy_on_write_is_in_place_on_v5e(one_chip):
     mem = cow.lower(cache, scalar, scalar, scalar).compile().memory_analysis()
     assert mem.alias_size_in_bytes >= math.prod(leaf) * 2
     assert mem.temp_size_in_bytes < 2**24
+
+
+# ------------- attention of two kinds and a ring beside the pool (ISSUE 40)
+
+LAGUNA = dict(vocab_size=100352, dim=3072, n_heads=48, n_kv_heads=8,
+              head_dim=128, mlp_hidden=1024, dense_mlp_hidden=12288,
+              eos_ids=(2,), n_experts=64, experts_per_token=10,
+              router_width=256, router_scale=2.5, shared_mlp_hidden=1024,
+              mixers_per_layer=2, sliding_window=512, sliding_n_heads=72,
+              sliding_rope_theta=10000.0, rope_theta=500000.0,
+              rope_partial=0.5, rope_factor=128.0, rope_original_max=8192,
+              rope_attention_factor=1.4852030263919618,
+              attn_gate="per-head")
+
+
+@pytest.mark.parametrize("B,W,packed", [(16, 1, None), (16, 64, 80),
+                                        (16, 512, 528), (1, 512, None)],
+                         ids=["decode", "window-64", "window-512", "eager-512"])
+def test_sliding_forward_compiles_at_published_widths_on_v5e(one_chip, B, W,
+                                                             packed,
+                                                             monkeypatch):
+    """laguna-s-2.1-l12's four mixers (layer 0, full attention then the
+    dense MLP, and layer 1, sliding attention then experts; every width as
+    published, the engine's 257-page table over a 4,096-block pool and a
+    ring of 1,024 rows a slot): Mosaic accepts the ragged kernel at 6 and
+    at 9 query heads a KV head — a decode row of the sliding kind is 72 x 8
+    = 576 score elements a key, past the flat form's 512, so it takes the
+    transposed form with 9 rows a KV head — with the page stream's lower
+    bound, reading the ring through a computed table; the pool holds the
+    full layer's rows alone; the rings ride the donated cache and are
+    written in place; nothing pool-sized or expert-stack-sized moves."""
+    from ai_agent_kubectl_tpu.models.transformer import sliding_zeros
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = ModelConfig(name="aot-laguna", n_layers=2, layer_pattern="*DSE",
+                      **LAGUNA)
+    page, n_blocks, pages = 64, 4096, 257
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda x: arg(x.shape, x.dtype),
+        jax.eval_shape(lambda k: random_params_int8(
+            k, cfg, dtype=jnp.bfloat16, quantize_embed=True),
+            jax.random.PRNGKey(0)))
+    pool = (cfg.n_of("*"), n_blocks, page, cfg.n_kv_heads, cfg.head_dim)
+    ring = cfg.sliding_ring(512, page)
+    sk, _ = jax.eval_shape(lambda: sliding_zeros(cfg, B, ring, jnp.bfloat16))
+    assert pool[0] == 1 and sk.shape == (1, B, 1024, 8, 128)
+    cache = KVCache(k=arg(pool, jnp.bfloat16), v=arg(pool, jnp.bfloat16),
+                    lengths=arg((n_blocks,), jnp.int32),
+                    experts_read=arg((), jnp.int32),
+                    sk=arg(sk.shape, sk.dtype), sv=arg(sk.shape, sk.dtype),
+                    span_rows=arg((4,), jnp.int32))
+
+    def step(params, tok, pos, cache, wmask, tables, q_lens):
+        return forward(params, cfg, tok, pos, cache, kv_limit=pages * page,
+                       attn_impl="ragged", token_mask=wmask,
+                       write_mask=wmask, block_tables=tables, q_lens=q_lens,
+                       logits_at=jnp.maximum(q_lens, 1) - 1,
+                       packed_rows=packed)
+
+    traced = jax.jit(step, donate_argnums=(3,)).trace(
+        params, arg((B, W), jnp.int32), arg((B, W), jnp.int32), cache,
+        arg((B, W), jnp.bool_), arg((B, pages), jnp.int32),
+        arg((B,), jnp.int32))
+    # the sliding layer's grid visits a span's blocks, not the table's 33
+    # (a decode row: 2 blocks of 8 pages against the table's 33; a 16-column
+    # tile of 72 heads: 4 blocks of 4 pages against 65)
+    full, sliding = sorted((g for g in _pallas_grids(traced.jaxpr.jaxpr)
+                            if len(g) == 3), key=lambda g: -g[2])
+    assert full[0] == sliding[0] == B
+    assert (sliding[2], full[2]) == ((2, 33) if W == 1 else (4, 65))
+    compiled = traced.lower().compile()
+    hlo = compiled.as_text()
+    # two attention kernels and the grouped expert GEMM
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 3
+    assert not _results_of_size(hlo, {64 * 3072 * 1024}), "an expert stack moved"
+    moved = _results_of_size(hlo, {math.prod(pool)})
+    assert {op for op, _ in moved} <= {"scatter", "fusion"}, moved
+    # the rings: written in place; no synchronous copy of one (this one-layer
+    # leaf is small enough that the compiler may prefetch it whole into
+    # VMEM for the kernel, an async copy-start; nine layers' is not)
+    ring_ops = {op for op, _ in _results_of_size(hlo, {math.prod(sk.shape)})}
+    assert "scatter" in ring_ops and not ring_ops & {"copy", "transpose"}, ring_ops
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * 2 * (math.prod(pool)
+                                               + math.prod(sk.shape))
+    assert mem.temp_size_in_bytes < 2 ** 29, mem.temp_size_in_bytes
