@@ -10,7 +10,10 @@ SURVEY.md §3.5):
   ``n_layers`` axis and the layer loop is a ``lax.scan``. One layer gets
   traced/compiled once regardless of depth — an 80-layer Llama-70B compiles
   in roughly the time of one layer, and XLA still overlaps per-layer
-  collectives with compute.
+  collectives with compute. A projection's head split stays OUTSIDE its
+  dot (``ops/quant.py::qmatmul_heads``), or the scan stages the layer's
+  matrix in VMEM and turns it over every pass (tests/test_tpu_aot.py::
+  test_decode_projections_stream_their_weights_as_stored_on_v5e).
 - **Static shapes everywhere**: tokens are padded to bucket sizes; the KV
   cache is a fixed [L, B, S, KV, d] buffer with explicit write positions, so
   jit never recompiles across requests (SURVEY.md §7 hard part "continuous
@@ -37,7 +40,7 @@ import jax.numpy as jnp
 from ..ops.attention import dense_attention, dense_attention_quant
 from ..ops.norms import rms_norm
 from ..ops.quant import (QuantKV, embed_lookup, kv_quantize, qmatmul,
-                         tied_head)
+                         qmatmul_heads, tied_head)
 from ..ops.rope import apply_rope
 from .config import ModelConfig
 
@@ -806,7 +809,7 @@ def _latent_attention(cfg: ModelConfig, attn_impl: str, x, lp: Params,
     N, R, V = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     with jax.named_scope("qkv_proj"):
         cq = rms_norm(qmatmul(x, lp["w_dq"]), lp["dq_norm"], cfg.rms_eps)
-        q = qmatmul(cq, lp["w_uq"]).reshape(Bh, Sh, H, N + R)
+        q = qmatmul_heads(cq, lp["w_uq"], H, N + R)
         ckr = qmatmul(x, lp["w_dkv"])
         c = rms_norm(ckr[..., :C], lp["dkv_norm"], cfg.rms_eps)
     with jax.named_scope("rope"):
@@ -1073,9 +1076,9 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
         return (after_attention(_shard_residual(mesh, h + out)),
                 layer_k, layer_v, layer_ik, counts)
     with jax.named_scope("qkv_proj"):
-        q = qmatmul(x, lp["wq"]).reshape(Bh, Sh, H, hd)
-        k = qmatmul(x, lp["wk"]).reshape(Bh, Sh, KV, hd)
-        v = qmatmul(x, lp["wv"]).reshape(Bh, Sh, KV, hd)
+        q = qmatmul_heads(x, lp["wq"], H, hd)
+        k = qmatmul_heads(x, lp["wk"], KV, hd)
+        v = qmatmul_heads(x, lp["wv"], KV, hd)
         if cfg.qk_norm:
             q = rms_norm(q, lp["q_norm"], cfg.rms_eps)
             k = rms_norm(k, lp["k_norm"], cfg.rms_eps)
@@ -1083,7 +1086,7 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
             # The indexer reads the same normed hidden state: its
             # queries, its ONE key a token, its per-head weights.
             J, di = cfg.index_heads, cfg.index_head_dim
-            qi = qmatmul(x, lp["idx_wq"]).reshape(Bh, Sh, J, di)
+            qi = qmatmul_heads(x, lp["idx_wq"], J, di)
             ki = qmatmul(x, lp["idx_wk"]).reshape(Bh, Sh, 1, di)
             wi = x @ lp["idx_ww"]
         gate = None

@@ -168,6 +168,26 @@ def qmatmul(x: jnp.ndarray, w) -> jnp.ndarray:
     return x @ w
 
 
+def qmatmul_heads(x: jnp.ndarray, w, heads: int, head_dim: int) -> jnp.ndarray:
+    """``qmatmul(x, w)`` [..., heads * head_dim] split into [..., heads,
+    head_dim], the split kept OUTSIDE the projection's dot: the values are
+    ``qmatmul(x, w).reshape(...)``'s, bit for bit.
+
+    Why not the plain reshape: the TPU compiler folds a head split that
+    follows a dot into the dot's result ([16, 32, 128] for a decode pass),
+    gives the weight operand a contraction-minor layout to match, and — the
+    weight being one layer of a stack the layer scan slices — then slices
+    the layer's matrix out to VMEM with a blocking fusion and turns it over
+    with a copy, every layer of every pass, before the dot reads it (25 MB a
+    layer for Mistral-7B's wq, wk and wv: tests/test_tpu_aot.py::
+    test_decode_projections_stream_their_weights_as_stored_on_v5e). Behind
+    the barrier the dot is a plain [rows, in] x [in, out] over the weight as
+    stored, the layer's slice fuses into it as it does for ``wo`` and the
+    MLP's, and the bytes stream once from HBM."""
+    y = jax.lax.optimization_barrier(qmatmul(x, w))
+    return y.reshape(*x.shape[:-1], heads, head_dim)
+
+
 # --------------------------------------------------------------- KV cache
 #
 # int8 KV cache (KV_QUANT=int8): decode attention reads the whole live KV

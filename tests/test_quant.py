@@ -8,7 +8,8 @@ import numpy as np
 import pytest
 
 from ai_agent_kubectl_tpu.ops.quant import (
-    QuantInt8, dequantize, qmatmul, quantize_int8, quantize_params_int8,
+    QuantInt8, QuantInt8W8A8, dequantize, qmatmul, qmatmul_heads,
+    quantize_int8, quantize_params_int8,
 )
 
 
@@ -34,6 +35,30 @@ def test_qmatmul_matches_dequant_matmul():
     # Plain weights pass through untouched.
     np.testing.assert_allclose(np.asarray(qmatmul(x, w)), np.asarray(x @ w),
                                rtol=1e-6)
+
+
+@pytest.mark.parametrize("kind", ["plain", "int8", "w8a8"])
+@pytest.mark.parametrize("jitted", [False, True], ids=["eager", "jit"])
+def test_qmatmul_heads_is_the_reshape_bit_for_bit(kind, jitted):
+    """The head split kept outside the dot (ISSUE 43) changes no value: the
+    same dot, the same float32 epilogue, the same cast, then a reshape."""
+    heads, hd = 4, 8
+    w = jax.random.normal(jax.random.PRNGKey(3), (64, heads * hd), jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(4), (2, 5, 64),
+                          jnp.float32).astype(jnp.bfloat16)
+    if kind == "plain":
+        w = w.astype(jnp.bfloat16)
+    else:
+        qw = quantize_int8(w)
+        w = qw if kind == "int8" else QuantInt8W8A8(q=qw.q, scale=qw.scale)
+    split = lambda x, w: qmatmul_heads(x, w, heads, hd)
+    plain = lambda x, w: qmatmul(x, w).reshape(2, 5, heads, hd)
+    if jitted:
+        split, plain = jax.jit(split), jax.jit(plain)
+    out, ref = split(x, w), plain(x, w)
+    assert out.shape == (2, 5, heads, hd) and out.dtype == ref.dtype
+    np.testing.assert_array_equal(np.asarray(out.astype(jnp.float32)),
+                                  np.asarray(ref.astype(jnp.float32)))
 
 
 def test_quantize_params_covers_moe_and_skips_small_leaves():

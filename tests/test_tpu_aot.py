@@ -346,7 +346,8 @@ def _chunk_program(mesh, rep, cfg, W=64, n_blocks=320, pages=64):
     """The engine's ragged chunk program for a ``W``-wide window, grammar
     on, compiled: its prologue's model call is the engine's
     (``batcher.py::ragged_forward_step_fn``: the window's valid rows packed
-    into W + batch)."""
+    into W + batch). ``W=0``: the plain program, 16 decode steps and no
+    prologue, which is what three chunks of four run."""
     from ai_agent_kubectl_tpu.engine.batcher import make_termination_chunk_fn
     from ai_agent_kubectl_tpu.parallel.sharding import (param_shardings,
                                                         pool_cache_specs,
@@ -393,21 +394,22 @@ def _chunk_program(mesh, rep, cfg, W=64, n_blocks=320, pages=64):
     chunk = make_termination_chunk_fn(
         step, 16, cfg.eos_ids, 0, 1.0, vocab_size=cfg.vocab_size,
         pool_tables=True, grammar=True, grammar_s_max=s_max, ragged_w=W,
-        ragged_forward_step=rstep)
+        ragged_forward_step=rstep if W else None)
     i32, f32, b = jnp.int32, jnp.float32, jnp.bool_
 
     def vec(dtype):
         return arg((B,), dtype)
 
+    # the staged windows' vectors: the plain program (W = 0) has none
+    adm = (arg((B, W), i32), vec(i32), vec(i32), vec(i32), vec(i32), vec(i32),
+           vec(f32), vec(i32)) if W else ()
     return jax.jit(chunk, donate_argnums=(1, 2, 3, 7, 8, 12)).lower(
         params, arg((B, 1), i32), arg((B, 1), i32), cache, vec(i32),
         vec(f32), vec(b), vec(b), vec(i32), vec(i32), vec(b),
         arg((B, pages), i32), vec(i32),
         arg((n_prof, cfg.vocab_size), i32),
         arg((n_prof * s_max, -(-n_classes // 32)), jnp.uint32),
-        arg((n_prof * s_max, n_classes), i32),
-        arg((B, W), i32), vec(i32), vec(i32), vec(i32), vec(i32), vec(i32),
-        vec(f32), vec(i32)).compile()
+        arg((n_prof * s_max, n_classes), i32), *adm).compile()
 
 
 def test_grammar_mask_is_no_element_gather_on_v5e(one_chip, monkeypatch):
@@ -467,18 +469,21 @@ def test_grammar_mask_over_model4_stays_split_on_v5e(mesh4, monkeypatch):
 
 
 @pytest.mark.parametrize("widths,layers,W,n_blocks,pages,was_gib,limit_gib", [
-    (MISTRAL, 32, 1024, 320, 64, 1.25, 1.05),
-    (MIXTRAL, 6, 512, 1040, 65, 1.89, 0.45),
+    (MISTRAL, 32, 1024, 320, 64, 1.004, 0.30),
+    (MIXTRAL, 6, 512, 1040, 65, 0.381, 0.30),
 ], ids=["mistral-1024-wide", "mixtral-l6-512-wide"])
 def test_widest_chunk_programs_temporaries_follow_the_rows_on_v5e(
         one_chip, monkeypatch, widths, layers, W, n_blocks, pages, was_gib,
         limit_gib):
     """ISSUE 39: the prologue's residual is the window's valid rows, W + 16
     of them, so the widest chunk program's temporaries are no longer 16 x W
-    rows of MLP (``was_gib``: AOT, PR 25) but the mixers' [16, W] q/k/v and
-    W + 16 rows of everything else: 1.004 and 0.381 GiB (AOT, PR 39). Of
-    Mistral's, 0.76 GiB is there at any width (its 64-wide program's: the
-    32-layer scan's own, no window's), so the 1,024-wide window costs 0.25."""
+    rows of MLP (1.25 and 1.89 GiB: AOT, PR 25) but the mixers' [16, W] q/k/v
+    and W + 16 rows of everything else: 1.004 and 0.381 GiB (``was_gib``: AOT,
+    PR 39). ISSUE 43: of Mistral's, 0.75 GiB was there at any width and was no
+    window's: the ``wq``, ``wk`` and ``wv`` stacks turned over whole (805 MB,
+    151 MB for the six layers) for dots whose result carried the head split,
+    a copy a chunk. With the split outside the dot: 0.254 and 0.240 GiB (AOT,
+    PR 43), the 1,024-wide window's own."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = ModelConfig(name="aot", n_layers=layers, **widths)
     compiled = _chunk_program(None, one_chip, cfg, W, n_blocks, pages)
@@ -512,6 +517,71 @@ def test_packed_prologue_over_model4_moves_rows_not_the_window_on_v5e(
     assert not wide, wide
     # the kernel's operands stay a chip's heads: 8 of 32 Q, 2 of 8 KV
     assert f"bf16[{N},{W},8,128]" in hlo and f"bf16[{N},{W},32,128]" not in hlo
+
+
+# ------------------ a projection's head split outside its dot (ISSUE 43)
+
+
+def _staging_tool():
+    """tools/aot_weight_staging.py: the decode ``forward`` compiled for a
+    described chip, and the counts of a compiled module's int8 slices staged
+    in VMEM by a blocking fusion, int8 copies and int8 ``copy-start``s."""
+    import importlib.util
+    from pathlib import Path
+
+    spec = importlib.util.spec_from_file_location(
+        "aot_weight_staging",
+        Path(__file__).resolve().parents[1] / "tools" / "aot_weight_staging.py")
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    return tool
+
+
+@pytest.mark.parametrize("widths,layers,n_blocks,program", [
+    (MISTRAL, 2, 320, "forward"),
+    (dict(MISTRAL, n_heads=8, n_kv_heads=2), 2, 1280, "forward"),
+    (MIXTRAL, 6, 1040, "plain chunk"),
+], ids=["one-chip-32q8kv", "mesh-local-8q2kv", "mixtral-l6-plain-chunk"])
+def test_decode_projections_stream_their_weights_as_stored_on_v5e(
+        one_chip, monkeypatch, widths, layers, n_blocks, program):
+    """ISSUE 43: a decode pass (batch 16, one token a slot) reads ``wq``,
+    ``wk`` and ``wv`` as ``wo`` and the MLP's are read, the layer's
+    ``dynamic-slice`` inside the dot's fusion and the bytes streamed once from
+    HBM. With the head split folded into the dot (``qmatmul(x, w).reshape(B,
+    S, H, hd)``) the compiler gave the weight operand a contraction-minor
+    layout and the layer loop's body held, for those three matrices alone, a
+    blocking fusion that sliced the layer's matrix out to VMEM (an ``s8``
+    result in ``S(1)`` from a fusion whose root is a ``dynamic-slice``) and a
+    ``copy`` that turned it over: 3 and 3 at Mistral-7B's widths, 25.2 MB a
+    layer a pass. ``ops/quant.py::qmatmul_heads`` keeps the split outside.
+
+    The third case is Mixtral-8x7B's six-layer cut in the engine's PLAIN chunk
+    program (16 decode steps, grammar on): there the turned-over copy was of
+    the three whole stacks, hoisted out of the loops (151 MB of temporaries),
+    and the 100.7 MB ``wq`` stack, small enough for the alternate memory, was
+    parked there and copied out and back (two ``copy-start``s over ``s8``: PERF.md,
+    Open question 15, 0.9 ms a pass on the chip). All of it goes with the
+    fold."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    tool = _staging_tool()
+    cfg = ModelConfig(name="aot", n_layers=layers, **widths)
+    if program == "plain chunk":
+        compiled = _chunk_program(None, one_chip, cfg, 0, n_blocks, 65)
+    else:
+        compiled = tool.decode_program(cfg, one_chip, n_blocks=n_blocks)
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo, "the Mosaic kernel is not in the program"
+    assert re.search(r"while\(", hlo), "the layer loop is not in the program"
+    found = tool.weight_staging(hlo)
+    assert (found["staged"], found["copies"]) == (0, 0), found
+    if program == "plain chunk":
+        # (a TWO-layer stack of w_down, 117 MB, fits the alternate memory
+        # too and is parked there in the cases above once the staged slices
+        # no longer fill it, as the parent's 8q2kv program parked it: the
+        # depth of the test, not of a cell)
+        assert found["bounces"] == 0, found
+    # what the hoisted copies of the stacks held (151 MB) is no temporary
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 26
 
 
 # ------------------------- key selection and grouped experts (ISSUE 31)
