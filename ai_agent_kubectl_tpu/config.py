@@ -504,9 +504,9 @@ class ServiceConfig:
     slo_objective: float = 0.99             # SLO_OBJECTIVE
     # --- perf-regression sentinel (ISSUE 15; obs/steptime.py) ---
     # Baseline envelope file for the step-time sentinel: JSON with a
-    # step_time_ms table ({phase: {bucket|"default": ms}}), seeded from
-    # the BENCH_r*.json numbers of record (PERF_BASELINES.json in the
-    # repo root). Empty = no file; every digest then self-calibrates
+    # step_time_ms table ({phase: {bucket|"default": ms}}) an operator
+    # measured on their own chips (obs/steptime.py::load_baselines says
+    # the format). Empty = no file; every digest then self-calibrates
     # from its first SENTINEL_MIN_SAMPLES samples. A set-but-unloadable
     # path refuses to boot.
     perf_baselines: str = ""                # PERF_BASELINES

@@ -42,15 +42,17 @@ import threading
 import time
 import zlib
 from functools import partial
-from typing import List, Optional
+from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..models.families import (attention_counted, attention_words,
+                               kernel_heads, long_prompts,
+                               resolved_at_start)
 from ..models.transformer import (KVCache, forward, serves_grouped,
-                                  sliding_zeros, state_put_row,
-                                  state_take_row, state_zeros)
+                                  state_put_row, state_take_row)
 from ..obs.ledger import (CLASS_DELIVERED, CLASS_DRAFT_REJECTED,
                           CLASS_HEDGE_LOSER, CLASS_PREEMPTED,
                           CLASS_QUARANTINE_BURN, CLASS_REPLAYED,
@@ -67,11 +69,11 @@ from .containment import (CAUSE_SCHEDULER_DEATH, CAUSE_SCHEDULER_ERROR,
                           CAUSE_SLOT_HEALTH, PROBATION_CLEAN_CHUNKS,
                           REASON_HEALTH, REASON_ISOLATED, EngineSupervisor)
 from .jax_engine import JaxEngine, kv_bucket_ladder
-from .kv_pool import (BlockPool, HostBlockStore, StateStore,
+from .kv_pool import (BlockPool, CacheCounters, HostBlockStore, StateStore,
                       alloc_with_evict, map_prefix, pages_for, release_state,
-                      span_window_counts, state_cuts, take_snapshot)
+                      state_cuts, take_snapshot)
 from .radix_cache import RadixCache
-from .regime import (DENSE, RAGGED, resolve_attention_regime,
+from .regime import (DENSE, RAGGED, cache_refusal, resolve_attention_regime,
                      stage_window)
 from .protocol import (HEALTH_GRAMMAR_DEAD, HEALTH_NONFINITE,
                        HEALTH_TOKEN_RANGE, EngineOverloaded,
@@ -144,13 +146,10 @@ def make_termination_chunk_fn(forward_step, chunk_len: int, eos_ids,
     all-False in normal serving, it NaNs a slot's step logits so drills
     exercise the real detection path, not a shortcut.
 
-    Shared by the serving engine and obs/attribution.py so "the traced
-    program IS the serving program" holds by construction, not by
-    synchronized copies. ``forward_step(params, tok, pos, cache, live)``
-    supplies the model call (the engine closes over kv_limit/mesh/attn
-    impl per KV bucket; attribution closes over its own); ``finalize``
-    post-processes the packed buffer (the engine pins it replicated
-    under a mesh).
+    ``forward_step(params, tok, pos, cache, live)`` supplies the model
+    call (the engine closes over kv_limit/mesh/attn impl per KV bucket);
+    ``finalize`` post-processes the packed buffer (the engine pins it
+    replicated under a mesh).
 
     Grammar-constrained decoding (ISSUE 11, ``grammar=True``): the
     carry grows a per-slot FSM state word ``gs`` (global state =
@@ -419,7 +418,7 @@ def make_termination_chunk_fn(forward_step, chunk_len: int, eos_ids,
         packed = finalize(pack_chunk(toks, done, ngen, jnp.sum(live),
                                      health=health,
                                      experts_read=cache.experts_read,
-                                     sel_rows=_attention_rows(cache),
+                                     sel_rows=attention_counted(cache),
                                      xp=jnp))
         out = (packed, tok, pos, cache, live, ngen)
         if grammar:
@@ -646,7 +645,7 @@ def make_termination_chunk_fn(forward_step, chunk_len: int, eos_ids,
                                      health=health, drafted=drafted,
                                      accepted=accepted,
                                      experts_read=cache.experts_read,
-                                     sel_rows=_attention_rows(cache),
+                                     sel_rows=attention_counted(cache),
                                      xp=jnp))
         out = (packed, tok, pos, cache, live, ngen, dcache)
         if grammar:
@@ -797,26 +796,6 @@ def _zero_counts(cache):
     return dataclasses.replace(cache, **zeroed) if zeroed else cache
 
 
-def _attention_rows(cache):
-    """The words the attention counted on the device, for the packed
-    chunk's ``sel_rows`` lane (engine/protocol.py): a selecting
-    configuration's two (keys before its decode queries, keys kept), a
-    latent one's two (decode queries, cached rows before them), or the
-    four of one with sliding layers (``KVCache.span_rows``). No
-    configuration is two of these."""
-    for name in ("sel_rows", "lat_rows", "span_rows"):
-        if getattr(cache, name) is not None:
-            return getattr(cache, name)
-    return None
-
-
-def attention_words(model_cfg) -> int:
-    """Words of the packed chunk's ``sel_rows`` lane for this model."""
-    if model_cfg.slides:
-        return 4
-    return 2 if model_cfg.selects_keys or model_cfg.latent else 0
-
-
 def staged_suffix_len(suffix: int, buckets) -> int:
     """How many of an admission's ``suffix`` unmatched tokens ride the next
     chunk's window; the rest, its head, prefills eagerly. A suffix the
@@ -828,94 +807,6 @@ def staged_suffix_len(suffix: int, buckets) -> int:
     valid rows, and how much of a long prompt's head should ride it is
     open (ROADMAP S1 a)."""
     return suffix if suffix <= buckets[-1] else buckets[0]
-
-
-def state_refusal(model_cfg, regime: str, mesh_shape, spec_decode: bool,
-                  kv_quant: str = "") -> Optional[str]:
-    """Why this engine cannot serve a configuration with state-space or
-    sliding-attention layers (``ModelConfig.keeps_state``), or None. Its
-    bounded state is a leaf of the pool engine's cache, a row a decode
-    slot, with snapshots on the radix tree: the dense per-slot ladder has
-    neither (and would attend a sliding layer to every key),
-    parallel/sharding.py::param_specs has no rule for the family's
-    leaves, a rejected draft token would have moved a state that
-    cannot be moved back, and a sliding layer's ring holds bf16 rows."""
-    if not model_cfg.keeps_state:
-        return None
-    why = None
-    if regime == DENSE:
-        why = ("the dense per-slot KV ladder keeps no bounded state a "
-               "sequence, recurrent or sliding, and would attend a sliding "
-               "layer to every key (KV_POOL=false, or a mesh axis the pool "
-               "refuses)")
-    elif any(n > 1 for n in (mesh_shape or {}).values()):
-        why = (f"MESH_SHAPE {dict(mesh_shape)}: parallel/sharding.py has no "
-               f"rule for the state-space and per-kind leaves; the family "
-               f"is served on one device")
-    elif spec_decode:
-        why = ("SPEC_DECODE: a rejected draft position would have advanced "
-               "the recurrent state")
-    elif kv_quant and model_cfg.slides:
-        why = (f"KV_QUANT={kv_quant}: the sliding layers' rings are bf16 "
-               f"rows beside the pool")
-    if why is None:
-        return None
-    state = ("a recurrent state" if model_cfg.has_ssm
-             else "a sliding-attention state")
-    return (f"{model_cfg.name} keeps {state} (layer_pattern "
-            f"{''.join(model_cfg.layer_kinds)!r}) and is not "
-            f"served here: {why}")
-
-
-def latent_refusal(model_cfg, regime: str, mesh_shape, kv_quant: str,
-                   spec_decode: bool) -> Optional[str]:
-    """Why this engine cannot serve a latent-attention configuration
-    (``ModelConfig.latent``), or None. Its cache is ONE leaf of the block
-    pool with no head axis: the dense per-slot ladder has no such leaf,
-    an int8 pool quantizes K and V it does not have, a mesh would shard a
-    KV-head axis it lacks (parallel/sharding.py::pool_cache_specs), and
-    the speculative path has never run over it."""
-    if not model_cfg.latent:
-        return None
-    why = None
-    if regime == DENSE:
-        why = ("the dense per-slot KV ladder has no latent leaf "
-               "(KV_POOL=false, or a mesh axis the pool refuses)")
-    elif kv_quant:
-        why = f"KV_QUANT={kv_quant}: the latent rows are kept in bf16"
-    elif any(n > 1 for n in (mesh_shape or {}).values()):
-        why = (f"MESH_SHAPE {dict(mesh_shape)}: the latent leaf has no "
-               f"KV-head axis to shard and its projections no rule in "
-               f"parallel/sharding.py")
-    elif spec_decode:
-        why = "SPEC_DECODE: draft/verify windows are untried over latent rows"
-    if why is None:
-        return None
-    return (f"{model_cfg.name} keeps a latent cache (kv_lora_rank="
-            f"{model_cfg.kv_lora_rank}) and is not served here: {why}")
-
-
-def selection_refusal(model_cfg, regime: str, mesh_shape, kv_quant: str
-                      ) -> Optional[str]:
-    """Why this engine cannot serve a key-selecting configuration
-    (``ModelConfig.index_topk``), or None. Its index keys are a leaf of
-    the block pool, one device's whole: the dense per-slot ladder, an
-    int8 pool and a mesh carry no such leaf."""
-    if not model_cfg.selects_keys:
-        return None
-    why = None
-    if regime == DENSE:
-        why = ("the dense per-slot KV ladder has no index-key leaf "
-               "(KV_POOL=false, or a mesh axis the pool refuses)")
-    elif kv_quant:
-        why = f"KV_QUANT={kv_quant}: key selection reads a bf16 pool"
-    elif any(n > 1 for n in (mesh_shape or {}).values()):
-        why = (f"MESH_SHAPE {dict(mesh_shape)}: the index-key leaf and the "
-               f"selected-row fetch are not sharded")
-    if why is None:
-        return None
-    return (f"{model_cfg.name} selects its keys (index_topk="
-            f"{model_cfg.index_topk}) and is not served here: {why}")
 
 
 @dataclasses.dataclass
@@ -1203,20 +1094,10 @@ class BatchedJaxEngine(JaxEngine):
         self._use_ragged = False
         self._attention_regime = DENSE
         self._attention_regime_reason = "not started"
-        # ISSUE 31 counters (/health.moe, /health.sparse_attention)
+        # What the kinds of this configuration's cache count
+        # (models/families.py; /health's family sections).
         self._counts_experts = False
-        self._moe_experts_read = 0
-        self._moe_layer_passes = 0
-        self._eager_passes = 0        # one-sequence prefill pieces run
-        # window rows are the scheduler's arithmetic on prompt lengths; what
-        # decode queries saw and kept is counted on the device (sel_rows)
-        self._selection_counts = dict.fromkeys(
-            ("index_rows_scanned", "window_rows", "forward_passes"), 0)
-        self._sel_rows_dev = [0, 0, 0, 0]
-        # a model with sliding layers: prompt rows prefilled and the
-        # (query, key) pairs of those in one layer of each kind
-        self._span_counts = dict.fromkeys(
-            ("window_rows", "window_pairs_sliding", "window_pairs_full"), 0)
+        self._counts = CacheCounters(self.model_cfg)
         self._attention_steps = (None, None, None)
         self._ragged_chunk_fns: dict = {}   # (adm width, spec) -> jitted
         # slot_idx -> staged admission (ids/start/ngen0/budget/seed/
@@ -1564,23 +1445,11 @@ class BatchedJaxEngine(JaxEngine):
         self._attention_regime_reason = reason
         self._use_pool = regime != DENSE
         self._use_ragged = regime == RAGGED
-        refusal = selection_refusal(
-            self.model_cfg, regime,
-            dict(self.mesh.shape) if self.mesh is not None else None,
-            self.kv_quant)
-        refusal = refusal or state_refusal(
-            self.model_cfg, regime,
-            dict(self.mesh.shape) if self.mesh is not None else None,
-            self.spec_decode, self.kv_quant)
-        refusal = refusal or latent_refusal(
+        refusal = cache_refusal(
             self.model_cfg, regime,
             dict(self.mesh.shape) if self.mesh is not None else None,
             self.kv_quant, self.spec_decode)
         if refusal:
-            # A selecting configuration's index keys live in the block
-            # pool's own leaf, a state-keeping one's state beside it: what
-            # cannot carry that leaf refuses the model at start (server:
-            # engine "degraded", this reason).
             logger.error("%s", refusal)
             raise ValueError(refusal)
         # The grouped expert path counts the experts it reads
@@ -1797,11 +1666,10 @@ class BatchedJaxEngine(JaxEngine):
 
         def batched_chunk(kv_limit):
             # The device-termination chunk body lives in
-            # make_termination_chunk_fn (module level), shared verbatim
-            # with obs/attribution.py: ``force`` is the host's view of
-            # live slots (excludes freed/exhausted), ``active``/``ngen``
-            # the device-resident carry, ``budget`` the per-slot
-            # max_tokens vector set at splice time, ``seeds`` the
+            # make_termination_chunk_fn (module level): ``force`` is the
+            # host's view of live slots (excludes freed/exhausted),
+            # ``active``/``ngen`` the device-resident carry, ``budget``
+            # the per-slot max_tokens vector set at splice time, ``seeds`` the
             # per-request sampling seeds, ``corrupt`` the decode:nan
             # fault seam; ONE packed buffer (pinned replicated under a
             # mesh) returns tokens + termination + occupancy + per-slot
@@ -2240,7 +2108,9 @@ class BatchedJaxEngine(JaxEngine):
                 # whose host truth is rebuilt with the pool's (a reset
                 # condemns every snapshot with the K/V it belongs to).
                 cap = self.state_snapshots or 4 * N
-                self._snap = self._state_leaves_zeros(cap, snapshots=True)
+                self._snap = KVCache.state_leaves_zeros(
+                    self.model_cfg, cap, dtype=self.dtype,
+                    ring=self.model_cfg.sliding_window)
                 self._state = StateStore(
                     cap, N, self.model_cfg.state_bytes(),
                     snapshot_fn=self._state_snapshot_dev,
@@ -2340,63 +2210,17 @@ class BatchedJaxEngine(JaxEngine):
     # every row it will ever read — stale garbage can never surface.
 
     def _new_pool_cache(self) -> KVCache:
-        """The shared [L, n_blocks, page, KV, hd] cache (QuantKV leaves
-        under KV_QUANT=int8). ``lengths`` is [n_blocks]-shaped and purely
-        structural — per-slot lengths are host truth (slot.pos)."""
+        """The shared [L, n_blocks, page, KV, hd] cache with the leaves of
+        the configuration's kinds (models/transformer.py::
+        KVCache.pool_zeros), a live state row a decode slot."""
         cfg = self.model_cfg
-        # A patterned configuration's pool holds its ATTENTION layers' rows
-        # alone, and its cache a live recurrent state a decode slot.
-        shape = (cfg.n_of("*"), self._pool_n_blocks, self.kv_pool_page,
-                 cfg.n_kv_heads, cfg.head_dim)
-        dtype, kv_quant = self.dtype, self.kv_quant
-        N = self.batch_size
-        n_blocks = self._pool_n_blocks
-        counts_experts = self._counts_experts
-
-        def state() -> dict:
-            if not cfg.keeps_state:
-                return {}
-            leaves = self._state_leaves_zeros(N)
-            if cfg.slides:
-                leaves["span_rows"] = jnp.zeros((4,), jnp.int32)
-            return leaves
-
-        def make() -> KVCache:
-            lengths = jnp.zeros((n_blocks,), jnp.int32)
-            if cfg.latent:
-                # Latent attention: the pool IS the one compressed row a
-                # token a layer (a pair of tokens a leaf row: models/
-                # transformer.py::KVCache.lat); no K, no V.
-                return KVCache(
-                    k=None, v=None, lengths=lengths,
-                    lat=jnp.zeros((cfg.n_layers, n_blocks, shape[2] // 2,
-                                   2 * cfg.latent_row), dtype),
-                    lat_rows=jnp.zeros((2,), jnp.int32),
-                    experts_read=(jnp.zeros((), jnp.int32)
-                                  if counts_experts else None))
-            if kv_quant == "int8":
-                from ..ops.quant import QuantKV
-
-                def zq():
-                    return QuantKV(q=jnp.zeros(shape, jnp.int8),
-                                   s=jnp.ones(shape[:-1], jnp.float32))
-
-                return KVCache(k=zq(), v=zq(), lengths=lengths, **state())
-            # A selecting configuration's index keys: one row a token a
-            # layer in the SAME blocks. Where the grouped expert path
-            # serves, the count of experts it read since the chunk
-            # program zeroed it rides the cache too.
-            return KVCache(
-                k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
-                lengths=lengths,
-                ik=(jnp.zeros(shape[:3] + (cfg.index_key_width,), dtype)
-                    if cfg.selects_keys else None),
-                experts_read=(jnp.zeros((), jnp.int32)
-                              if counts_experts else None),
-                sel_rows=(jnp.zeros((2,), jnp.int32)
-                          if cfg.selects_keys else None),
-                **state())
-
+        make = functools.partial(
+            KVCache.pool_zeros, cfg, n_blocks=self._pool_n_blocks,
+            page=self.kv_pool_page, slots=self.batch_size,
+            ring=cfg.sliding_ring(self.prefill_buckets[-1],
+                                  self.kv_pool_page),
+            dtype=self.dtype, kv_quant=self.kv_quant,
+            counts_experts=self._counts_experts)
         if self.mesh is None:
             return make()
         # Pool-under-mesh (ISSUE 14): KV heads shard over ``model``
@@ -2422,21 +2246,6 @@ class BatchedJaxEngine(JaxEngine):
     # dispatch in order with every other program, so a restore lands
     # before the prefill that reads it and a snapshot after the prefill
     # whose end it saves.
-
-    def _state_leaves_zeros(self, rows: int, snapshots: bool = False) -> dict:
-        """The state leaves (``KVCache.STATE``) of ``rows`` sequences: a
-        decode slot's live ones, or the snapshot store's. A sliding
-        layer's live K/V are a ring (the span and the widest prefill
-        window beside it); a snapshot keeps the span's rows alone."""
-        cfg, leaves = self.model_cfg, {}
-        if cfg.has_ssm:
-            leaves["ssm"], leaves["conv"] = state_zeros(cfg, rows, self.dtype)
-        if cfg.slides:
-            length = (cfg.sliding_window if snapshots else cfg.sliding_ring(
-                self.prefill_buckets[-1], self.kv_pool_page))
-            leaves["sk"], leaves["sv"] = sliding_zeros(cfg, rows, length,
-                                                       self.dtype)
-        return leaves
 
     def _live_state(self) -> dict:
         return {name: getattr(self._cache, name) for name in self._snap}
@@ -2818,8 +2627,7 @@ class BatchedJaxEngine(JaxEngine):
                     tables_d, np.int32(slot_idx))
                 piece["call_ms"] = (time.monotonic() - t_call) * 1000.0
             offset += L
-            self._selection_counts["forward_passes"] += 1
-            self._eager_passes += 1
+            self._counts.note_passes(1, eager=True)
         return logits[:, 0]
 
     def _pool_ensure_coverage(self, idx: int, slot: "_Slot",
@@ -3052,21 +2860,7 @@ class BatchedJaxEngine(JaxEngine):
         self._spans.note_slots(self._slots)
         prefill_meta = dict(prompt_tokens=n_prompt, prefix_hit_tokens=m,
                             staged_w=len(staged["ids"]) if staged else 0)
-        if self.model_cfg.latent:
-            # window rows m .. n_prompt-1, row t over its t + 1 rows
-            self._selection_counts["window_rows"] += n_prompt - m
-            self._selection_counts["index_rows_scanned"] += (
-                n_prompt * (n_prompt + 1) - m * (m + 1)) // 2
-        if self.model_cfg.slides:
-            for name, n in span_window_counts(
-                    m, n_prompt, self.model_cfg.sliding_window).items():
-                self._span_counts[name] += n
-        if self.model_cfg.selects_keys:
-            # window rows m .. n_prompt-1, each scanning the index keys
-            # up to its own
-            self._selection_counts["window_rows"] += n_prompt - m
-            self._selection_counts["index_rows_scanned"] += (
-                n_prompt * (n_prompt + 1) - m * (m + 1)) // 2
+        self._counts.note_admission(m, n_prompt)
         if run:
             t_dk = time.monotonic()
             piece = slot.detok.push(*run)
@@ -3125,13 +2919,12 @@ class BatchedJaxEngine(JaxEngine):
             min(pages_for(b, self.kv_pool_page), self._pool_max_pages))
         row[:len(blocks)] = blocks
         self._pool_prefill_span(row, [0] * b, 0)
-        if cfg.selects_keys or cfg.keeps_state or cfg.latent:
-            # A selecting configuration is served for prompts far past
-            # the widest bucket: their heads are prefilled eagerly, piece
-            # by piece, and the last piece of a head may be any bucket
-            # (one compiled inside a measured window otherwise). It is
-            # the one kind served with such prompts; warming these for
-            # every model would add compiles to every other start.
+        if long_prompts(cfg):
+            # Served with prompts far past the widest bucket: their heads
+            # are prefilled eagerly, piece by piece, and the last piece of
+            # a head may be any bucket (one compiled inside a measured
+            # window otherwise). Warming these for every model would add
+            # compiles to every other start.
             for wb in self.prefill_buckets[1:]:
                 more = self._pool.alloc(min(
                     pages_for(wb, self.kv_pool_page), self._pool_max_pages))
@@ -3256,7 +3049,7 @@ class BatchedJaxEngine(JaxEngine):
         """Cheap sharding view for /health (ISSUE 14; host attributes
         only — same rule as qos_health): the active mesh shape, the
         residual TP fraction the policy achieves at the decode shape
-        (1.0 = the f≈1 layout tools/tp_projection.py prices), whether
+        (1.0 = every residual-path tensor batch-sharded), whether
         the KV pool is mesh-sharded, and the kv_pool_mesh_fallback flag
         — a pool that silently fell back dense must be visible."""
         if self.mesh is None:
@@ -3291,13 +3084,8 @@ class BatchedJaxEngine(JaxEngine):
 
         cfg = self.model_cfg
         tp = self.mesh.shape["model"] if self.mesh is not None else 1
-        heads = (cfg.n_heads // tp, cfg.n_kv_heads // tp, cfg.head_dim)
-        if cfg.latent:
-            # one key row for all heads; the kernel's query is
-            # ops/ragged_attention.py::latent_query's
-            heads = (cfg.n_heads, 1,
-                     cfg.kv_lora_rank + 4 * cfg.qk_rope_head_dim)
-        shape = (self._pool_max_pages, self.kv_pool_page, *heads,
+        shape = (self._pool_max_pages, self.kv_pool_page,
+                 *kernel_heads(cfg, tp),
                  self.spec_draft_k + 1 if self._spec_live else 1,
                  jnp.dtype(self.dtype).itemsize)
         return (pages_per_step(*shape),
@@ -3309,24 +3097,14 @@ class BatchedJaxEngine(JaxEngine):
         counts and mesh gates fall back LOUDLY here — and, under ragged,
         what the kernel resolved at start (null otherwise)."""
         pages, steps, depth = self._attention_steps
-        cfg = self.model_cfg
         return {
             "attention_regime": self._attention_regime,
             "attention_regime_reason": self._attention_regime_reason,
             "attention_pages_per_step": pages,
             "attention_decode_grid_steps": steps,
             "attention_stream_depth": depth,
-            # What a selecting configuration resolved at start: how many
-            # keys a query keeps, and how each form reads them.
-            "attention_selects_keys": (
-                {"index_topk": cfg.index_topk,
-                 "index_heads": cfg.index_heads,
-                 "index_head_dim": cfg.index_head_dim,
-                 "rows": f"exact top-k of the index scores as a per-row "
-                         f"mask on the {self._attention_regime} path's "
-                         f"causal scores, decode and window rows alike",
-                 "dense_while_ctx_at_most": cfg.index_topk}
-                if cfg.selects_keys else None),
+            # what a kind of the cache resolved at start
+            **resolved_at_start(self.model_cfg, self._attention_regime),
         }
 
     def ragged_health(self) -> Optional[dict]:
@@ -3337,77 +3115,20 @@ class BatchedJaxEngine(JaxEngine):
             return None
         return {"window": dict(self._window_counts)}
 
-    def moe_health(self) -> Optional[dict]:
-        """/health.moe: experts whose weights the grouped expert path
-        read, and the layer passes they were read in, both over the chunk
-        programs' passes (cumulative host counters; None where another
-        MoE path, or none, serves); beside them what the kernel resolves
-        from shapes (tile rows, grid steps) for a decode pass, the widest
-        window and an eager piece of the widest bucket."""
-        if not self._counts_experts:
-            return None
-        from ..parallel.moe import grouped_kernel_shape
-
-        cfg, wide = self.model_cfg, self.prefill_buckets[-1]
-        return {"experts_read": self._moe_experts_read,
-                "layer_passes": self._moe_layer_passes,
-                # a chip's share (ISSUE 38): the experts this tree holds,
-                # the first of them, and how many the router scores
-                "experts_held": cfg.n_experts,
-                "first_expert": cfg.first_expert,
-                "router_width": cfg.experts_scored,
-                # what the kernel resolves from a call's shapes (ISSUE 34)
-                "kernel": {
-                    "decode": grouped_kernel_shape(cfg, self.batch_size),
-                    "widest_window": grouped_kernel_shape(
-                        cfg, self.batch_size + wide),
-                    "eager_piece": grouped_kernel_shape(cfg, wide)}}
-
-    def ssm_health(self) -> Optional[dict]:
-        """/health.ssm (cumulative; None for a model without state-space
-        layers): the snapshot store's counters (kv_pool.StateStore.stats)
-        and ``layer_passes`` by kind — forward passes the scheduler
-        dispatched (chunk programs' steps and one-sequence prefill
-        pieces) times the layers of the kind."""
-        if self._state is None:
-            return None
-        body = self._state.stats()
-        passes = self._selection_counts["forward_passes"]
-        body["forward_passes"] = passes
-        body["eager_prefill_passes"] = self._eager_passes
-        body["live_rows"] = self.batch_size
-        body["layer_passes"] = {
-            name: passes * self.model_cfg.n_of(kind)
-            for name, kind in (("ssm", "M"), ("experts", "E"),
-                               ("attention", "*"), ("sliding", "S"),
-                               ("dense_mlp", "D"))}
-        return body
-
-    def sliding_attention_health(self) -> Optional[dict]:
-        """/health.sliding_attention (cumulative; None for a model whose
-        attention layers are of one kind). ``decode_rows_sliding`` /
-        ``sliding_keys_read``: the decode queries the chunk programs' sliding
-        layers ran (a pass's rows times those layers) and the keys those
-        had inside their span; ``decode_rows_full`` / ``full_keys_read``:
-        the same for the full layers, the live context — all four counted
-        on the device, beside the mask. ``window_*``: prompt rows prefilled
-        and their (query, key) pairs in ONE layer of each kind (the
-        scheduler's arithmetic). ``ring_rows``: what a decode slot keeps a
-        sliding layer, ``snapshot_rows`` what a snapshot does."""
-        cfg = self.model_cfg
-        if not cfg.slides:
-            return None
-        rows_s, keys_s, rows_f, keys_f = self._sel_rows_dev
-        return {"span": cfg.sliding_window,
-                "ring_rows": self._cache.sk.shape[2],
-                "snapshot_rows": cfg.sliding_window,
-                "layers_sliding": cfg.n_of("S"), "layers_full": cfg.n_of("*"),
-                "heads_sliding": cfg.heads_of("S"),
-                "heads_full": cfg.heads_of("*"),
-                "decode_rows_sliding": rows_s, "sliding_keys_read": keys_s,
-                "decode_rows_full": rows_f, "full_keys_read": keys_f,
-                **self._span_counts,
-                "forward_passes": self._selection_counts["forward_passes"]}
+    def family_health(self) -> Dict[str, Optional[dict]]:
+        """/health.{moe, sparse_attention, latent_attention,
+        sliding_attention, ssm}: what the kinds of the cache counted
+        (engine/kv_pool.py::CacheCounters; None where the configuration
+        is of no kind that gives a section) beside the engine's facts."""
+        sk = getattr(getattr(self, "_cache", None), "sk", None)
+        return self._counts.sections(
+            batch_size=self.batch_size,
+            widest_window=self.prefill_buckets[-1],
+            counts_experts=self._counts_experts,
+            pool_bytes_per_token=(self._pool_bytes_per_token()
+                                  if self._pool is not None else None),
+            ring_rows=sk.shape[2] if sk is not None else None,
+            store=self._state.stats() if self._state is not None else None)
 
     def _pool_bytes_per_token(self) -> float:
         """What one token keeps in the pool: every paged leaf's own size
@@ -3417,51 +3138,6 @@ class BatchedJaxEngine(JaxEngine):
         leaves = jax.tree_util.tree_leaves(self._cache.paged())
         held = sum(math.prod(a.shape) * a.dtype.itemsize for a in leaves)
         return held / (self._pool_n_blocks * self.kv_pool_page)
-
-    def latent_attention_health(self) -> Optional[dict]:
-        """/health.latent_attention (cumulative; None for a model that
-        caches K and V). ``row_bytes``: what a token keeps in the pool,
-        all layers (640 B a layer at the published sizes: the pool's
-        bytes a token, and all of them). ``decode_rows``: decode queries
-        the chunk programs ran (a pass's rows, counted once whatever the
-        depth); ``latent_rows_read``: the cached rows those queries had
-        before them, summed over the layers — both counted on the device.
-        ``window_rows_absorbed`` / ``window_rows_expanded``: prompt rows
-        prefilled (the scheduler's arithmetic) by the form that attended
-        them; the expanded form serves none (ops/latent_attention.py);
-        ``window_pairs``: the (query, cached row) pairs of those rows in
-        one layer."""
-        cfg = self.model_cfg
-        if not cfg.latent:
-            return None
-        queries, rows = self._sel_rows_dev[:2]
-        return {"row_bytes": self._pool_bytes_per_token(),
-                "layers": cfg.n_layers,
-                "decode_rows": queries // cfg.n_layers,
-                "latent_rows_read": rows,
-                "window_rows_absorbed": self._selection_counts["window_rows"],
-                "window_rows_expanded": 0,
-                # (query, cached row) pairs of those prompt rows, a layer
-                "window_pairs": self._selection_counts["index_rows_scanned"],
-                "forward_passes": self._selection_counts["forward_passes"]}
-
-    def sparse_attention_health(self) -> Optional[dict]:
-        """/health.sparse_attention (cumulative; None for a configuration
-        that attends to every key). ``decode_rows_live`` /
-        ``decode_rows_selected``: the keys the chunk programs' decode
-        queries had before them and the keys the selector's mask kept of
-        those, counted on the device in every layer and given per layer.
-        ``window_rows`` and the window part of ``index_rows_scanned`` are
-        the scheduler's arithmetic on prompt lengths (window row t scans
-        t + 1 index keys); a decode query scans its live keys."""
-        if not self.model_cfg.selects_keys:
-            return None
-        live, kept = (n // self.model_cfg.n_layers
-                      for n in self._sel_rows_dev[:2])
-        c = dict(self._selection_counts, decode_rows_live=live,
-                 decode_rows_selected=kept)
-        c["index_rows_scanned"] += live
-        return c
 
     def kv_pool_health(self) -> Optional[dict]:
         """Cheap pool view for /health (never stats() — same rule as
@@ -4112,13 +3788,10 @@ class BatchedJaxEngine(JaxEngine):
             # — delta-mirrored into Prometheus at scrape time
             # (Metrics.observe_kv_pool) and summarized in /health.
             "kv_pool": self.kv_pool_health(),
-            # Grouped expert GEMM / key selection (ISSUE 31): cumulative
-            # counters, null where the configuration has neither.
-            "moe": self.moe_health(),
-            "sparse_attention": self.sparse_attention_health(),
-            "latent_attention": self.latent_attention_health(),
-            "sliding_attention": self.sliding_attention_health(),
-            "ssm": self.ssm_health(),
+            # The cache kinds' sections (moe, sparse_attention,
+            # latent_attention, sliding_attention, ssm): cumulative
+            # counters, null where the configuration is not of the kind.
+            **self.family_health(),
             "ragged": self.ragged_health(),
             "sharding": self.sharding_health(),
             "queue_rejections": self._rejections,
@@ -5877,8 +5550,7 @@ class BatchedJaxEngine(JaxEngine):
         chunks_ahead = self._chunks_in_pipe()
         self._chunks_dispatched += 1
         # forward passes of this chunk program (a spec chunk: its verifies)
-        self._selection_counts["forward_passes"] += (
-            self._spec_steps if spec else self.chunk_len)
+        self._counts.note_passes(self._spec_steps if spec else self.chunk_len)
         self._inflight.append(("chunk", packed_d, snapshot, ct, spec,
                                self._chunks_dispatched))
         entry["pipe_empty_ms"] = self._spans.note_pipe(self._inflight)
@@ -6052,18 +5724,9 @@ class BatchedJaxEngine(JaxEngine):
                                moe=self._counts_experts,
                                sel=attention_words(self.model_cfg))
             consumed["n_alive"] = res.n_alive
-            if res.experts_read is not None:
-                # One pass a scan step (the prologue is one of them), each
-                # through every layer.
-                self._moe_experts_read += res.experts_read
-                self._moe_layer_passes += (
-                    (self._spec_steps if is_spec else self.chunk_len)
-                    * self.model_cfg.n_of("E"))
-            if res.sel_rows is not None:
-                # The device's own count of what its decode queries saw
-                # and kept, summed over the layers of every step.
-                for i, n in enumerate(res.sel_rows):
-                    self._sel_rows_dev[i] += n
+            # One pass a scan step (the prologue is one of them).
+            self._counts.note_chunk(
+                res, self._spec_steps if is_spec else self.chunk_len)
             self._consume_chunk(res, snapshot, ct, is_spec)
 
     def _consume_chunk(self, res, snapshot, ct: int, is_spec: bool) -> None:

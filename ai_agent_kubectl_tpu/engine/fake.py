@@ -25,6 +25,7 @@ from typing import AsyncIterator, Callable, Dict, List, Optional
 
 import numpy as np
 
+from ..models.families import SECTIONS
 from ..obs.ledger import (CLASS_DELIVERED, CLASS_DRAFT_REJECTED,
                           CLASS_HEDGE_LOSER, CLASS_PREEMPTED,
                           CLASS_QUARANTINE_BURN, CLASS_REPLAYED,
@@ -695,11 +696,6 @@ class FakeChunkedEngine:
         self._pool.decref(slot.blocks)
         slot.blocks = []
 
-    def ssm_health(self) -> Optional[dict]:
-        """/health.ssm: the snapshot store's counters (mirror of the
-        batcher's; None unless the fake plays a state-keeping model)."""
-        return self._state.stats() if self._state is not None else None
-
     def _count_decode_row(self, slot: _FakeSlot) -> None:
         """One decode query of a model with sliding layers: the keys it
         reads in a sliding layer (its span's) and in a full one (the
@@ -713,13 +709,19 @@ class FakeChunkedEngine:
         c["sliding_keys_read"] += min(keys, self.sliding_window)
         c["full_keys_read"] += keys
 
-    def sliding_attention_health(self) -> Optional[dict]:
-        """/health.sliding_attention (mirror of the batcher's counters, one
-        layer of each kind; None unless the fake plays such a model)."""
-        if not self.sliding_window:
-            return None
-        return {"span": self.sliding_window, "layers_sliding": 1,
-                "layers_full": 1, **self._span_counts}
+    def family_health(self) -> Dict[str, Optional[dict]]:
+        """The cache kinds' /health sections (mirror of the batcher's;
+        models/families.py::SECTIONS). The fake plays a model without a
+        configuration: ``ssm`` is the snapshot store's counters where it
+        plays a state-keeping one, ``sliding_attention`` its own counts of
+        one layer of each kind where it plays sliding layers."""
+        sliding = ({"span": self.sliding_window, "layers_sliding": 1,
+                    "layers_full": 1, **self._span_counts}
+                   if self.sliding_window else None)
+        return {**dict.fromkeys(SECTIONS),
+                "ssm": (self._state.stats() if self._state is not None
+                        else None),
+                "sliding_attention": sliding}
 
     def ragged_health(self) -> Optional[dict]:
         """/health.ragged (mirror of the batcher's; None off the ragged
@@ -977,8 +979,7 @@ class FakeChunkedEngine:
                                 parked=len(self._parked),
                                 slot_health_check=self.slot_health_check),
             "kv_pool": self.kv_pool_health(),
-            "ssm": self.ssm_health(),
-            "sliding_attention": self.sliding_attention_health(),
+            **self.family_health(),
             "ragged": self.ragged_health(),
             "ledger": self.ledger.snapshot(),
             "slo": self._slo.snapshot(),

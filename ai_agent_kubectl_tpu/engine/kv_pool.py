@@ -6,8 +6,7 @@ an ``S_alloc``-row cache region) caps the decode batch at the HBM budget's
 ``S_alloc``. The pool replaces per-slot regions with one shared
 ``[n_layers, n_blocks, page, KV, hd]`` cache plus per-slot *block tables*:
 a slot owns exactly the pages its live positions span, so the same HBM
-admits ~``S_alloc / avg_len`` times the slots — the bs≈192 rung
-``tools/tp_projection.py`` says the 2k tok/s/chip TP=8 north star needs.
+admits ~``S_alloc / avg_len`` times the slots.
 
 This module is the HOST truth: a free-list allocator with per-block
 refcounts. Device arrays never carry ownership — the scheduler thread (or
@@ -58,6 +57,8 @@ from collections import deque
 from typing import Dict, Iterable, List, Optional, Sequence
 
 import numpy as np
+
+from ..models.families import SECTIONS, kinds_of
 
 
 class PoolExhausted(RuntimeError):
@@ -200,6 +201,66 @@ def span_window_counts(m: int, n: int, span: int) -> dict:
             "window_pairs_full": tri(n) - tri(m),
             "window_pairs_sliding": (tri(min(n, span)) - tri(min(m, span))
                                      + span * (max(n, span) - max(m, span)))}
+
+
+class CacheCounters:
+    """What the kinds of a configuration's cache (models/families.py)
+    count, cumulative, on the scheduler's thread: the words their passes
+    counted on the device, the arithmetic on the prompt lengths admitted,
+    the forward passes dispatched. ``sections`` gives /health's."""
+
+    def __init__(self, model_cfg):
+        self.cfg = model_cfg
+        self.kinds = kinds_of(model_cfg)
+        self.counts = {
+            kind.name: {"dev": [0] * kind.count_words,
+                        "host": dict.fromkeys(kind.window_counts, 0),
+                        "passes": 0}
+            for kind in self.kinds}
+        self.forward_passes = 0
+        self.eager_passes = 0         # one-sequence prefill pieces run
+
+    def note_admission(self, matched: int, n_prompt: int) -> None:
+        """Prompt rows ``matched`` .. ``n_prompt`` - 1 are prefilled: each
+        kind adds, under its own names, the rows and their pairs a layer."""
+        rule = span_window_counts(matched, n_prompt,
+                                  self.cfg.sliding_window)
+        for kind in self.kinds:
+            host = self.counts[kind.name]["host"]
+            for own, name in kind.window_counts.items():
+                host[own] += rule[name]
+
+    def note_passes(self, passes: int, eager: bool = False) -> None:
+        """Forward passes dispatched: a chunk program's steps (a spec
+        chunk: its verifies), or one eager prefill piece."""
+        self.forward_passes += passes
+        if eager:
+            self.eager_passes += passes
+
+    def note_chunk(self, result, passes: int) -> None:
+        """A fetched chunk (engine/protocol.py::ChunkResult) of ``passes``
+        passes: the device's own count of what those read and kept."""
+        for kind in self.kinds:
+            words = getattr(result, kind.lane) if kind.lane else None
+            if words is None:
+                continue
+            c = self.counts[kind.name]
+            c["passes"] += passes
+            for i, n in enumerate(words if kind.count_shape else (words,)):
+                c["dev"][i] += n
+
+    def sections(self, **facts) -> Dict[str, Optional[dict]]:
+        """The kinds' /health sections, None where the configuration is of
+        no kind that gives one. ``facts``: what only the engine knows
+        (batch_size, widest_window, counts_experts, pool_bytes_per_token,
+        ring_rows, store: StateStore.stats())."""
+        facts.update(forward_passes=self.forward_passes,
+                     eager_passes=self.eager_passes)
+        out: Dict[str, Optional[dict]] = dict.fromkeys(SECTIONS)
+        for kind in self.kinds:
+            for section, body in kind.health.items():
+                out[section] = body(self.cfg, self.counts[kind.name], facts)
+        return out
 
 
 def take_snapshot(state: "StateStore", radix, slot: int,

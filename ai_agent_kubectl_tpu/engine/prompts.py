@@ -21,9 +21,3 @@ USER_TEMPLATE = "User Request: {query}\nKubectl Command:"
 def render_prompt(query: str) -> str:
     """Full prompt = shared system prefix + per-request suffix."""
     return SYSTEM_PROMPT + USER_TEMPLATE.format(query=query)
-
-
-def split_prompt(query: str) -> tuple[str, str]:
-    """(shared_prefix, per_request_suffix) — the prefix half is what the
-    prefix-KV cache keys on."""
-    return SYSTEM_PROMPT, USER_TEMPLATE.format(query=query)

@@ -176,7 +176,7 @@ class ChunkResult:
     #: key selection (optional lane): (keys the chunk's decode queries had
     #: before them, keys the selector kept of those), summed over layers
     #: and steps; a latent or a sliding configuration's own counts ride
-    #: the same lane (engine/batcher.py::_attention_rows). None where the
+    #: the same lane (models/families.py::attention_counted). None where the
     #: chunk program counts none.
     sel_rows: Optional[tuple] = None
 

@@ -22,6 +22,8 @@ from __future__ import annotations
 
 from typing import Mapping, Optional, Sequence, Tuple
 
+from ..models.families import OBSTACLES, kinds_of
+
 RAGGED, GATHER, DENSE = "ragged", "gather", "dense"
 REGIMES = (RAGGED, GATHER, DENSE)
 
@@ -97,6 +99,27 @@ def resolve_attention_regime(
             f"head_dim={model_cfg.head_dim}")
     return RAGGED, pool_page, (
         "block pool, bf16 KV, device termination, TPU backend")
+
+
+def cache_refusal(model_cfg, regime: str,
+                  mesh_shape: Optional[Mapping[str, int]], kv_quant: str,
+                  spec_decode: bool) -> Optional[str]:
+    """Why an engine about to start cannot serve this configuration, or
+    None: the first obstacle (models/families.py::OBSTACLES), in the
+    kind's own order, that a kind of the configuration's cache cannot
+    ride. What cannot carry a kind's leaf refuses the model at start
+    (server: engine "degraded", this reason)."""
+    mesh = dict(mesh_shape or {})
+    present = dict(zip(OBSTACLES, (
+        regime == DENSE, bool(kv_quant),
+        any(n > 1 for n in mesh.values()), bool(spec_decode))))
+    for kind in kinds_of(model_cfg):
+        for obstacle, why in kind.refuses.items():
+            if present[obstacle]:
+                return (f"{model_cfg.name} {kind.says(model_cfg)} and is "
+                        f"not served here: "
+                        + why.format(kv_quant=kv_quant, mesh=mesh))
+    return None
 
 
 def stage_window(lengths: Sequence[int], buckets: Sequence[int]

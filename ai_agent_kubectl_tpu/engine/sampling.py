@@ -16,8 +16,8 @@ vocab (a [batch, 256k] sort + 256k random draws per step inside the decode
 chunk). ``top_k == 0`` with ``top_p < 1`` still needs the full-vocab sort
 (the nucleus cutoff is defined over all logits); plain temperature
 sampling (no filters) pays only the categorical. Everything here runs
-under a ``jax.named_scope`` so the decode-step attribution tool
-(obs/attribution.py, tools/attribute_step.py) can bill it as a category.
+under a ``jax.named_scope`` so the benchmark's trace reduction
+(benchmark/xtrace.py) can bill it as a category.
 """
 
 from __future__ import annotations
@@ -162,9 +162,8 @@ def sample_tokens_batched(
     the rows by the categorical. Since the seeded-sampling switch (ISSUE
     5) the serving decode step runs ``sample_tokens_seeded`` instead —
     this variant is kept as the reference implementation for the
-    distribution-parity tests (tests/test_sampling.py) and the decode
-    profiling tool (tools/profile_decode.py), which has no per-request
-    seeds to thread. Same ``_sample_rows`` scaffold and
+    distribution-parity tests (tests/test_sampling.py), which have no
+    per-request seeds to thread. Same ``_sample_rows`` scaffold and
     ``_sample_filtered`` body, so the two variants cannot diverge in
     anything but key derivation.
 

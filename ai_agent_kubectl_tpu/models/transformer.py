@@ -147,18 +147,56 @@ class KVCache:
     def zeros(cls, cfg: ModelConfig, batch: int, max_seq: int,
               dtype=jnp.bfloat16, kv_quant: str = "") -> "KVCache":
         shape = (cfg.n_of("*"), batch, max_seq, cfg.n_kv_heads, cfg.head_dim)
-        if kv_quant == "int8":
-            def zq():
-                return QuantKV(q=jnp.zeros(shape, jnp.int8),
-                               s=jnp.ones(shape[:-1], jnp.float32))
+        return cls(k=_kv_zeros(shape, dtype, kv_quant),
+                   v=_kv_zeros(shape, dtype, kv_quant),
+                   lengths=jnp.zeros((batch,), dtype=jnp.int32))
 
-            return cls(k=zq(), v=zq(),
-                       lengths=jnp.zeros((batch,), dtype=jnp.int32))
-        return cls(
-            k=jnp.zeros(shape, dtype=dtype),
-            v=jnp.zeros(shape, dtype=dtype),
-            lengths=jnp.zeros((batch,), dtype=jnp.int32),
-        )
+    @classmethod
+    def state_leaves_zeros(cls, cfg: ModelConfig, rows: int, *, ring: int,
+                           dtype=jnp.bfloat16) -> Dict[str, jnp.ndarray]:
+        """The state leaves (``STATE``) of ``rows`` sequences. A sliding
+        layer's live K/V are a ring (``ModelConfig.sliding_ring``); a
+        snapshot keeps the span's rows alone (``ring=cfg.sliding_window``)."""
+        leaves = {}
+        if cfg.has_ssm:
+            leaves["ssm"], leaves["conv"] = state_zeros(cfg, rows, dtype)
+        if cfg.slides:
+            leaves["sk"], leaves["sv"] = sliding_zeros(cfg, rows, ring, dtype)
+        return leaves
+
+    @classmethod
+    def pool_zeros(cls, cfg: ModelConfig, *, n_blocks: int, page: int,
+                   slots: int, ring: int = 0, dtype=jnp.bfloat16,
+                   kv_quant: str = "", counts_experts: bool = False
+                   ) -> "KVCache":
+        """The pool engine's cache: the paged leaves ([layers, n_blocks,
+        page, ...]: K and V of the attention layers alone and a selecting
+        configuration's index keys, or a latent one's compressed rows and
+        nothing else), a live state row for each of ``slots`` decode slots,
+        and the count leaves of the configuration's kinds (models/
+        families.py; ``experts_read`` where the grouped path serves).
+        ``lengths`` is [n_blocks]-shaped and purely structural: per-slot
+        lengths are host truth."""
+        from .families import kinds_of
+
+        shape = (cfg.n_of("*"), n_blocks, page, cfg.n_kv_heads, cfg.head_dim)
+        if cfg.latent:
+            # a pair of tokens a leaf row (``lat`` above); no K, no V
+            paged = dict(k=None, v=None, lat=jnp.zeros(
+                (cfg.n_layers, n_blocks, page // 2, 2 * cfg.latent_row),
+                dtype))
+        else:
+            paged = dict(k=_kv_zeros(shape, dtype, kv_quant),
+                         v=_kv_zeros(shape, dtype, kv_quant))
+            if cfg.selects_keys:
+                paged["ik"] = jnp.zeros(
+                    shape[:3] + (cfg.index_key_width,), dtype)
+        counts = {kind.count_leaf: jnp.zeros(kind.count_shape, jnp.int32)
+                  for kind in kinds_of(cfg) if kind.count_leaf
+                  and (counts_experts or kind.lane != "experts_read")}
+        return cls(lengths=jnp.zeros((n_blocks,), jnp.int32), **paged,
+                   **counts, **cls.state_leaves_zeros(
+                       cfg, slots, ring=ring, dtype=dtype))
 
     @property
     def max_seq(self) -> int:
@@ -174,6 +212,14 @@ class KVCache:
 
     def with_paged(self, leaves) -> "KVCache":
         return dataclasses.replace(self, **dict(zip(self.PAGED, leaves)))
+
+
+def _kv_zeros(shape, dtype, kv_quant: str):
+    """A K or V leaf of ``shape``: ``QuantKV`` (unit scales) under int8."""
+    if kv_quant == "int8":
+        return QuantKV(q=jnp.zeros(shape, jnp.int8),
+                       s=jnp.ones(shape[:-1], jnp.float32))
+    return jnp.zeros(shape, dtype)
 
 
 def state_zeros(cfg: ModelConfig, rows: int, dtype=jnp.bfloat16):
@@ -531,22 +577,21 @@ def window_rows(q_lens: jnp.ndarray, positions: jnp.ndarray,
 # f≈1 residual-path TP sharding (ISSUE 14): with weights Megatron-split
 # over ``model``, the classic layout replicates the [B, S, d] residual
 # on every TP shard — norms, RoPE epilogues, residual adds and the
-# sampling scratch then run tp× redundantly, which is exactly the
-# (1−f)·residual term tools/tp_projection.py prices. Pinning the
+# sampling scratch then run tp× redundantly. Pinning the
 # residual batch-sharded over data×model at the sites below makes XLA
 # fuse each row-parallel GEMM's all-reduce into a reduce-scatter at its
 # output (plus one all-gather at the next column-parallel input): the
 # elementwise segments between GEMMs run 1/tp-sized per shard and the
-# collective count stays 2 fused pairs per layer — the projection's
-# priced model. ``parallel/sharding.py::residual_spec`` owns the
+# collective count stays 2 fused pairs per layer.
+# ``parallel/sharding.py::residual_spec`` owns the
 # policy (and the pipe/expert/divisibility gates).
 
 
 def _shard_residual(mesh, x: jnp.ndarray) -> jnp.ndarray:
     """Pin the [B, S, d] residual to the f≈1 layout (no-op when the
     policy doesn't apply to this mesh/shape). The named_scope is what
-    lets obs/attribution.py bill the fused collectives XLA materializes
-    at this boundary as the ``all_reduce`` category."""
+    lets benchmark/xtrace.py find the fused collectives XLA materializes
+    at this boundary."""
     if mesh is None:
         return x
     from ..parallel.sharding import residual_spec
@@ -1006,9 +1051,9 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
 
     The ``jax.named_scope`` blocks here (and in ``forward``/sampling) are
     zero-cost HLO metadata: XLA stamps each op's ``op_name`` with the
-    scope path, which the profiler trace exports — the decode-step
-    attribution tool (obs/attribution.py) bills device spans to op
-    categories by these names instead of guessing from HLO op types.
+    scope path, which the profiler trace exports — the benchmark's trace
+    reduction (benchmark/xtrace.py) bills device spans to op categories
+    by these names instead of guessing from HLO op types.
 
     ``write_mask`` ([B] bool, decode only): rows whose mask is False skip
     the KV-cache scatter entirely — their write positions are pushed out

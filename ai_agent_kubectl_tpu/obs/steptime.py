@@ -2,11 +2,11 @@
 time, with online regression detection against a baseline envelope.
 
 Every prior observability layer answers a question about ONE request or
-ONE scrape: attribution explains a step, the ledger bills it, the trace
-times it. Nothing watched the step itself *over time* — a 20% step-time
-regression from a bad checkpoint, a straggling replica, or a
-speculative-decode acceptance collapse was invisible until a human ran
-``bench.py``. This module is the missing signal: both engine schedulers
+ONE scrape: the ledger bills a step, the trace times it. Nothing watched
+the step itself *over time* — a 20% step-time regression from a bad
+checkpoint, a straggling replica, or a speculative-decode acceptance
+collapse was invisible until a human ran the benchmark. This module is
+the missing signal: both engine schedulers
 feed it one sample per decode-chunk cycle (and one per admission
 prefill), keyed by ``(phase, bucket)``:
 

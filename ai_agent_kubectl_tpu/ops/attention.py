@@ -142,9 +142,3 @@ def causal_mask(q_len: int, kv_len: int, q_offset: jnp.ndarray | int = 0) -> jnp
     q_pos = jnp.arange(q_len)[:, None] + q_offset
     kv_pos = jnp.arange(kv_len)[None, :]
     return (kv_pos <= q_pos)[None, :, :]
-
-
-def length_mask(kv_lens: jnp.ndarray, kv_len: int) -> jnp.ndarray:
-    """[batch, 1, kv_len] validity mask for padded caches: position j is
-    valid iff j < kv_lens[b]."""
-    return (jnp.arange(kv_len)[None, None, :] < kv_lens[:, None, None])

@@ -73,13 +73,6 @@ def init_distributed(
     return True
 
 
-def is_primary() -> bool:
-    """True on the process that should run HTTP ingress (process 0)."""
-    import jax
-
-    return jax.process_index() == 0
-
-
 def _int_env(name: str) -> Optional[int]:
     v = os.getenv(name)
     return int(v) if v else None

@@ -132,7 +132,3 @@ def single_device_mesh() -> Mesh:
     """A 1×1×1×1 mesh on the first device — lets all sharded code paths run
     unchanged on one chip."""
     return build_mesh(MeshConfig(), devices=jax.devices()[:1])
-
-
-def mesh_axis_size(mesh: Mesh, axis: str) -> int:
-    return mesh.shape[axis]

@@ -219,10 +219,9 @@ def logits_spec(mesh: Mesh, vocab: int) -> Optional[P]:
 def residual_fraction(mesh: Optional[Mesh], batch: int, dim: int) -> float:
     """The TP-shardable residual fraction f the active policy achieves
     at the decode shape [batch, 1, dim] — 1.0 when the residual
-    batch-shards over data×model (the tp_projection.py f≈1 row), else
-    0.0 (classic replicated residual). Surfaced in /health's sharding
-    section so the operator can see whether the serving config actually
-    hits the priced f."""
+    batch-shards over data×model, else 0.0 (classic replicated
+    residual). Surfaced in /health's sharding section so the operator
+    can see which layout the serving config actually runs."""
     if mesh is None:
         return 0.0
     spec = residual_spec(mesh, (batch, 1, dim))

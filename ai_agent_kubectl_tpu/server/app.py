@@ -222,11 +222,8 @@ class Service:
     def _engine_view(self, name: str) -> Optional[dict]:
         """One cheap engine health view, or None (absent/failing) — the
         incident plane must never take the serving path down."""
-        fn = getattr(self.engine, name, None)
-        if not callable(fn):
-            return None
         try:
-            return fn() or None
+            return _engine_view(self.engine, name)
         except Exception:  # pragma: no cover - defensive
             return None
 
@@ -1048,6 +1045,18 @@ def _device_info(app: web.Application) -> dict:
     return info
 
 
+def _engine_view(engine, method: str):
+    """What ``engine.<method>()`` gives, or None (no such method, or
+    nothing to say)."""
+    fn = getattr(engine, method, None)
+    return (fn() or None) if callable(fn) else None
+
+
+#: /health sections an engine gives through ``<section>_health()``.
+_ENGINE_VIEWS = ("fleet", "qos", "slo", "kv_pool", "ragged", "sharding",
+                 "grammar", "spec", "steptime", "spans")
+
+
 async def handle_health(request: web.Request) -> web.Response:
     """GET /health — readiness-gated (SURVEY.md §3.3), with the breaker's
     state surfaced so operators can tell "engine down" from "engine up but
@@ -1067,107 +1076,23 @@ async def handle_health(request: web.Request) -> web.Response:
         last_reset = (time.strftime("%Y-%m-%dT%H:%M:%S",
                                     time.gmtime(sup.last_reset_wall)) + "Z")
         last_cause = sup.last_reset_cause
-    # Fleet deployments (engine/fleet.py): a per-replica section — state,
-    # breaker, occupancy, last reset/cause — plus the fleet rollup
-    # (migration/hedge/drain counters). The cheap health view never calls
-    # stats() (that drains samples owed to the /metrics scrape). The
-    # fleet's most-recent reset also backfills the top-level fields.
-    fleet = None
-    fh = getattr(svc.engine, "fleet_health", None)
-    if callable(fh):
-        fleet = fh() or None
+    # The engine's sections, each a cheap host view (counters, bounded
+    # rings; NEVER stats(), which drains samples owed to the /metrics
+    # scrape) read through a method the engine may not have: None then,
+    # and where the engine has nothing to say. server/schemas.py::
+    # HealthResponse says what each holds.
+    views = {section: _engine_view(svc.engine, f"{section}_health")
+             for section in _ENGINE_VIEWS}
+    # The fleet's most-recent reset backfills the top-level fields.
+    fleet = views["fleet"]
     if fleet is not None and last_reset is None:
         last_reset = fleet.get("last_reset")
         last_cause = fleet.get("last_reset_cause")
-    # QoS ring (ISSUE 7): per-lane queue depth, brownout level/shares,
-    # and preemptions in the last minute — the cheap view (qos_health
-    # never calls stats(), same rule as the fleet section).
-    qos = None
-    qh = getattr(svc.engine, "qos_health", None)
-    if callable(qh):
-        qos = qh() or None
-    # SLO burn rates (ISSUE 8): multi-window error-budget view — cheap
-    # (a bounded-deque scan, never stats()), same rule as qos/fleet.
-    slo = None
-    sh = getattr(svc.engine, "slo_health", None)
-    if callable(sh):
-        slo = sh() or None
-    # KV pool (ISSUE 10): block-state counts + radix hit rates — cheap
-    # (host counters, never stats()), same rule as qos/fleet/slo.
-    kv_pool = None
-    kph = getattr(svc.engine, "kv_pool_health", None)
-    if callable(kph):
-        kv_pool = kph() or None
-    # Grouped experts / key selection (ISSUE 31): experts read a layer
-    # pass, rows live and selected — cheap host counters, same rule.
-    moe = sparse_attention = None
-    mh = getattr(svc.engine, "moe_health", None)
-    if callable(mh):
-        moe = mh() or None
-    sah = getattr(svc.engine, "sparse_attention_health", None)
-    if callable(sah):
-        sparse_attention = sah() or None
-    # Latent attention (ISSUE 38): bytes a token, rows its decode queries
-    # read — cheap host counters, same rule.
-    latent_attention = None
-    lah = getattr(svc.engine, "latent_attention_health", None)
-    if callable(lah):
-        latent_attention = lah() or None
-    # Attention of two kinds (ISSUE 40): keys a decode query read in its
-    # sliding and in its full layers — same rule.
-    sliding_attention = None
-    slh = getattr(svc.engine, "sliding_attention_health", None)
-    if callable(slh):
-        sliding_attention = slh() or None
-    # Recurrent-state snapshots (ISSUE 33): same cheap host counters.
-    ssm = None
-    ssh = getattr(svc.engine, "ssm_health", None)
-    if callable(ssh):
-        ssm = ssh() or None
-    # Mixed chunks' windows (ISSUE 39): rows brought, rows computed,
-    # suffixes that waited a chunk — cheap host counters, same rule.
-    ragged = None
-    rgh = getattr(svc.engine, "ragged_health", None)
-    if callable(rgh):
-        ragged = rgh() or None
-    # Sharding (ISSUE 14): mesh shape, residual TP fraction, pool-
-    # sharded + mesh-fallback flags — cheap host attributes, same rule.
-    sharding = None
-    shh = getattr(svc.engine, "sharding_health", None)
-    if callable(shh):
-        sharding = shh() or None
-    # Grammar (ISSUE 11): compiled-grammar hash, state count, forced/
-    # masked totals — cheap host counters, same rule as the rest.
-    grammar = None
-    gh = getattr(svc.engine, "grammar_health", None)
-    if callable(gh):
-        grammar = gh() or None
-    # Speculative decoding (ISSUE 12): draft model id, k, acceptance
-    # rate, degradation state — cheap host counters, same rule.
-    spec = None
-    sph = getattr(svc.engine, "spec_health", None)
-    if callable(sph):
-        spec = sph() or None
-    # Weight rollout (ISSUE 13): state machine position, target/stable
-    # versions, the per-replica version table, rollbacks by cause —
-    # cheap controller counters, same rule as the rest. The fleet
-    # section above carries each replica's weights_version too.
-    rollout = svc.rollout.health() if svc.rollout is not None else None
-    # Perf-regression sentinel (ISSUE 15): step-time digest summary +
-    # breach state (cheap bounded-ring reads), and the incident ring's
-    # captured/suppressed totals.
-    steptime = None
-    sth = getattr(svc.engine, "steptime_health", None)
-    if callable(sth):
-        steptime = sth() or None
-    incidents = svc.incidents.snapshot()
-    # Engine spans (obs/trace.py): cumulative {count, total_ms, max_ms}
-    # per span name and the scheduler thread's wall time by state —
-    # cheap host counters, same rule as the rest.
-    spans = None
-    sph = getattr(svc.engine, "spans_health", None)
-    if callable(sph):
-        spans = sph() or None
+    # The cache kinds' sections (models/families.py::SECTIONS), all from
+    # one method.
+    families = {name: section or None
+                for name, section in (_engine_view(
+                    svc.engine, "family_health") or {}).items()}
     body = HealthResponse(
         status="healthy" if ready and breaker == "closed" else "degraded",
         engine=getattr(svc.engine, "name", "unknown"),
@@ -1178,23 +1103,10 @@ async def handle_health(request: web.Request) -> web.Response:
         degraded_fallback=svc.fallback is not None,
         last_reset=last_reset,
         last_reset_cause=last_cause,
-        fleet=fleet,
-        qos=qos,
-        slo=slo,
-        kv_pool=kv_pool,
-        moe=moe,
-        sparse_attention=sparse_attention,
-        latent_attention=latent_attention,
-        sliding_attention=sliding_attention,
-        ssm=ssm,
-        ragged=ragged,
-        sharding=sharding,
-        grammar=grammar,
-        spec=spec,
-        rollout=rollout,
-        steptime=steptime,
-        incidents=incidents,
-        spans=spans,
+        **views,
+        **families,
+        rollout=svc.rollout.health() if svc.rollout is not None else None,
+        incidents=svc.incidents.snapshot(),
     )
     # The HTTP status tracks engine readiness alone: an open breaker with
     # the engine process alive still serves (fallback and/or cache), and
@@ -1494,6 +1406,16 @@ async def handle_admin_rollout_abort(request: web.Request) -> web.Response:
     return web.json_response(status)
 
 
+#: stats() section -> the Metrics method that mirrors it at a scrape.
+_SECTION_MIRRORS = {
+    "fleet": "observe_fleet", "qos": "observe_qos",
+    "ledger": "observe_ledger", "slo": "observe_slo",
+    "kv_pool": "observe_kv_pool", "ssm": "observe_state_cache",
+    "latent_attention": "observe_latent_attention",
+    "sharding": "observe_sharding", "grammar": "observe_grammar",
+    "spec": "observe_spec", "steptime": "observe_steptime"}
+
+
 async def handle_metrics(request: web.Request) -> web.Response:
     svc: Service = request.app["service"]
     # Engine gauges are sampled at scrape time (live scheduler state, not a
@@ -1512,47 +1434,11 @@ async def handle_metrics(request: web.Request) -> web.Response:
         # Containment counters (resets, quarantines, health trips,
         # replayed tokens) — same delta-mirror pattern.
         svc.metrics.observe_containment(stats)
-        # Fleet section (engine/fleet.py): per-replica gauges +
-        # migration/hedge/drain/eject counters.
-        if stats.get("fleet"):
-            svc.metrics.observe_fleet(stats["fleet"])
-        # QoS section (engine/qos.py): per-lane depth/occupancy gauges +
-        # preemption/expiry/displacement counters + brownout level.
-        if stats.get("qos"):
-            svc.metrics.observe_qos(stats["qos"])
-        # Telemetry plane (ISSUE 8): goodput ledger lane table +
-        # SLO burn-rate gauges — same delta-mirror pattern.
-        if stats.get("ledger"):
-            svc.metrics.observe_ledger(stats["ledger"])
-        if stats.get("slo"):
-            svc.metrics.observe_slo(stats["slo"])
-        # KV pool + radix sharing (ISSUE 10): block-state gauges +
-        # sharing/COW/radix-hit counters — same delta-mirror pattern.
-        if stats.get("kv_pool"):
-            svc.metrics.observe_kv_pool(stats["kv_pool"])
-        # Recurrent-state snapshots (ISSUE 33): same delta-mirror.
-        if stats.get("ssm"):
-            svc.metrics.observe_state_cache(stats["ssm"])
-        # Latent attention (ISSUE 38): same delta-mirror.
-        if stats.get("latent_attention"):
-            svc.metrics.observe_latent_attention(stats["latent_attention"])
-        # Tensor-parallel serving (ISSUE 14): mesh device count,
-        # residual TP fraction, and the kv_pool_mesh_fallback flag —
-        # gauges sampled at scrape time.
-        if stats.get("sharding"):
-            svc.metrics.observe_sharding(stats["sharding"])
-        # Grammar-constrained decoding (ISSUE 11): forced/masked token
-        # + dead-end counters — same delta-mirror pattern.
-        if stats.get("grammar"):
-            svc.metrics.observe_grammar(stats["grammar"])
-        # Speculative decoding (ISSUE 12): drafted/accepted counters +
-        # the acceptance-ratio gauge — same delta-mirror pattern.
-        if stats.get("spec"):
-            svc.metrics.observe_spec(stats["spec"])
-        # Perf-regression sentinel (ISSUE 15): step_time_seconds
-        # quantile gauges + per-rung tok/s + the breach-trip counter.
-        if stats.get("steptime"):
-            svc.metrics.observe_steptime(stats["steptime"])
+        # The engine's sections, each delta-mirrored (or sampled) into
+        # its own series by server/metrics.py where the engine has one.
+        for section, observe in _SECTION_MIRRORS.items():
+            if stats.get(section):
+                getattr(svc.metrics, observe)(stats[section])
     # Incident plane (ISSUE 15): a scrape is also a trigger-evaluation
     # round (cooldowns make redundant evaluation free), so deployments
     # with SENTINEL_EVAL_SECS=0 still capture incidents at scrape

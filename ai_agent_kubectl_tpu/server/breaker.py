@@ -159,7 +159,3 @@ class CircuitBreaker:
         while self._failures and self._failures[0] <= horizon:
             self._failures.popleft()
         return len(self._failures)
-
-    @property
-    def state_code(self) -> int:
-        return STATE_CODES[self.state]

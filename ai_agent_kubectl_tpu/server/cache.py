@@ -107,10 +107,6 @@ class SingleFlight(Generic[K, V]):
     def __init__(self) -> None:
         self._inflight: Dict[K, "asyncio.Task[V]"] = {}
 
-    @property
-    def inflight_count(self) -> int:
-        return len(self._inflight)
-
     async def do(self, key: K, supplier: Callable[[], Awaitable[V]]) -> Tuple[V, bool]:
         """Return (value, shared) — shared=True when this call piggybacked on
         another caller's in-flight computation."""

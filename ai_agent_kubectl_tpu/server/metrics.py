@@ -284,8 +284,8 @@ class Metrics:
 
         # Tensor-parallel serving (ISSUE 14, parallel/sharding.py):
         # the active mesh size, the residual TP fraction the f≈1 policy
-        # achieves at the decode shape (1.0 = the layout
-        # tools/tp_projection.py prices), and the loud-fallback flag
+        # achieves at the decode shape (1.0 = every residual-path
+        # tensor batch-sharded), and the loud-fallback flag
         # for a KV pool forced back to the dense ladder by a
         # data/pipe/seq mesh axis. Gauges sampled at scrape time from
         # stats()["sharding"].

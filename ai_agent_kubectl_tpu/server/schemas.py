@@ -116,28 +116,30 @@ class HealthResponse(BaseModel):
     # with a >1 data/pipe/seq axis, or the single-sequence/fake/openai
     # paths). TP/EP meshes serve the pool (ISSUE 14).
     kv_pool: Optional[Dict[str, Any]] = None
-    # Grouped expert GEMM and learned key selection (ISSUE 31;
-    # engine/batcher.py::moe_health, sparse_attention_health): cumulative
+    # The cache kinds' sections, this one and the four below (one
+    # description a kind, models/families.py; an engine gives them all
+    # through ``family_health()``). Grouped expert GEMM and learned key
+    # selection (ISSUE 31; ``_moe_section``, ``_sparse_section``): cumulative
     # experts_read / layer_passes, and decode rows live / selected, index
     # rows scanned, window rows, forward passes. None where the
     # configuration has neither.
     moe: Optional[Dict[str, Any]] = None
     sparse_attention: Optional[Dict[str, Any]] = None
-    # Latent attention (ISSUE 38; engine/batcher.py::
-    # latent_attention_health): the compressed cache's bytes a token
-    # (row_bytes, all layers), decode queries run and the cached rows they
-    # read (summed over layers), prompt rows prefilled by the absorbed and
-    # by the expanded form. None for a model that caches K and V.
+    # Latent attention (ISSUE 38; ``_latent_section``): the compressed
+    # cache's bytes a token (row_bytes, all layers), decode queries run
+    # and the cached rows they read (summed over layers), prompt rows
+    # prefilled by the absorbed and by the expanded form. None for a
+    # model that caches K and V.
     latent_attention: Optional[Dict[str, Any]] = None
-    # Attention of two kinds (ISSUE 40; engine/batcher.py::
-    # sliding_attention_health): the span and the ring a decode slot keeps
-    # a sliding layer, decode queries run and the keys they read in the
-    # sliding and in the full layers (counted on the device), prompt rows
-    # prefilled and their pairs a layer of each kind. None for a model
-    # whose attention layers are of one kind.
+    # Attention of two kinds (ISSUE 40; ``_sliding_section``): the span
+    # and the ring a decode slot keeps a sliding layer, decode queries
+    # run and the keys they read in the sliding and in the full layers
+    # (counted on the device), prompt rows prefilled and their pairs a
+    # layer of each kind. None for a model whose attention layers are of
+    # one kind.
     sliding_attention: Optional[Dict[str, Any]] = None
     # Recurrent-state cache of a model with state-space layers (ISSUE 33;
-    # engine/kv_pool.py::StateStore.stats, batcher.py::ssm_health):
+    # engine/kv_pool.py::StateStore.stats, ``_state_section``):
     # snapshots held / capacity / bytes and their peak, snapshots taken /
     # evicted / skipped, restores, prefix tokens matched / usable /
     # recomputed, state bytes moved, layer passes by kind. None for every

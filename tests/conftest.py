@@ -53,6 +53,32 @@ def pytest_pyfunc_call(pyfuncitem):
     return None
 
 
+#: The long files, longest first (seconds on the CPU under six workers at
+#: PR 44: 541, 429, 388, 370, 315, 244, 202, 190, 157; the next is 97).
+#: Under ``--dist loadfile`` a file is one worker's. xdist hands files out by
+#: their NUMBER of tests, most first, so a long file of few tests (the
+#: sliding model against its reference: 4 tests, 388 s) started last, ~680 s
+#: into the run, and the run waited for it (ROADMAP D10). Here they are
+#: handed out in the order collected, and these are collected first.
+_LONG_FILES = ("test_sparse_attention.py", "test_tpu_aot.py",
+               "test_reference_logits_sliding.py", "test_sliding_attention.py",
+               "test_latent_attention.py", "test_window_staging.py",
+               "test_packed_window.py", "test_state_cache.py",
+               "test_hybrid_model.py")
+
+
+def pytest_configure(config):
+    if hasattr(config.option, "loadscopereorder"):       # xdist is loaded
+        config.option.loadscopereorder = False
+
+
+def pytest_collection_modifyitems(items):
+    """The same order in every worker (xdist requires it): a stable sort,
+    so the order inside a file and among the other files is kept."""
+    rank = {name: i for i, name in enumerate(_LONG_FILES)}
+    items.sort(key=lambda item: rank.get(item.path.name, len(rank)))
+
+
 FAKE_KUBECTL = r"""#!/usr/bin/env python3
 # Scriptable kubectl stand-in for executor tests.
 import os, sys, time

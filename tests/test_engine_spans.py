@@ -811,7 +811,7 @@ async def test_jax_eager_pieces_are_counted_and_timed_one_for_one():
     try:
         await _ask(client, ROUTES[1], "warm every shape first")
         before = (await (await client.get("/health")).json())["spans"]
-        passes0, rows = eng._eager_passes, 0
+        passes0, rows = eng._counts.eager_passes, 0
         for i in range(3):
             detail = await _ask(client, ROUTES[i % 2], f"list pods of app {i}")
             meta = next(s["meta"] for s in detail["spans"]
@@ -820,11 +820,11 @@ async def test_jax_eager_pieces_are_counted_and_timed_one_for_one():
                      - meta["staged_w"])
         spans = (await (await client.get("/health")).json())["spans"]
         piece, was = spans["sched/eager_prefill"], before["sched/eager_prefill"]
-        assert piece["count"] - was["count"] == eng._eager_passes - passes0 > 0
+        assert piece["count"] - was["count"] == eng._counts.eager_passes - passes0 > 0
         assert piece["tokens_total"] - was["tokens_total"] == rows > 0
         assert 0 < piece["call_total_ms"] <= piece["total_ms"]
         # the warm-up's pieces ran before the scheduler did: not its children
-        assert piece["count"] < eng._eager_passes
+        assert piece["count"] < eng._counts.eager_passes
         # children are inside sched/admit, and what they leave is its rest
         kids = ("radix_match", "eager_prefill", "arm", "cow")
         inside = sum(spans.get(f"sched/{k}", {}).get("total_ms", 0)

@@ -3,7 +3,7 @@
 The reference has no KV cache at all (the forward pass is a remote call,
 /root/reference/app.py:184); int8 KV is a build-side capacity lever — it
 halves the decode KV pool, which is what caps batch size on HBM-bound
-single-chip 7B serving (bench.py round 4). Tests: quantization error
+single-chip 7B serving. Tests: quantization error
 bounds, cache structure, and greedy serving parity against the
 full-precision KV path on the toy model.
 """

@@ -470,24 +470,6 @@ def test_a_demoted_block_is_promoted_and_answers_the_same():
     asyncio.run(go())
 
 
-@pytest.mark.parametrize("regime,mesh,kv_quant,spec,says", [
-    ("dense", None, "", False, "the dense per-slot KV ladder has no latent leaf "
-                               "(KV_POOL=false, or a mesh axis the pool refuses)"),
-    ("gather", None, "int8", False, "KV_QUANT=int8: the latent rows are kept in bf16"),
-    ("ragged", {"model": 4}, "", False,
-     "MESH_SHAPE {'model': 4}: the latent leaf has no KV-head axis to shard and its "
-     "projections no rule in parallel/sharding.py"),
-    ("ragged", None, "", True, "SPEC_DECODE: draft/verify windows are untried over latent rows"),
-])
-def test_what_cannot_carry_the_leaf_refuses_the_model(regime, mesh, kv_quant, spec, says):
-    from ai_agent_kubectl_tpu.engine.batcher import latent_refusal
-
-    assert latent_refusal(CFG, regime, mesh, kv_quant, spec) == (
-        f"toy-mla-moe keeps a latent cache (kv_lora_rank=32) and is not served here: {says}")
-    assert latent_refusal(get_config("toy-moe"), regime, mesh, kv_quant, spec) is None
-    assert latent_refusal(CFG, "ragged", {"model": 1}, "", False) is None
-
-
 def test_an_engine_without_the_pool_refuses_at_start():
     async def go():
         with pytest.raises(ValueError, match="keeps a latent cache .* the dense per-slot KV"):

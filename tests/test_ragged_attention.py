@@ -81,8 +81,7 @@ async def _serve(eng) -> list:
 
 
 def _program_total(eng) -> int:
-    """Every compiled attention-bearing program the engine owns — the
-    ledger bench.py --phase ragged7b records as ``compiled_programs``."""
+    """Every compiled attention-bearing program the engine owns."""
     return (len(eng._batch_chunk_fns) + len(eng._spec_chunk_fns)
             + len(eng._ragged_chunk_fns) + len(eng._pool_prefill_fns))
 

@@ -491,23 +491,6 @@ def test_copy_on_write_and_the_host_tier_carry_the_leaf():
     asyncio.run(go())
 
 
-@pytest.mark.parametrize("regime,mesh,kv_quant,says", [
-    ("dense", None, "", "the dense per-slot KV ladder has no index-key leaf "
-                        "(KV_POOL=false, or a mesh axis the pool refuses)"),
-    ("gather", None, "int8", "KV_QUANT=int8: key selection reads a bf16 pool"),
-    ("ragged", {"model": 4}, "", "MESH_SHAPE {'model': 4}: the index-key leaf and the "
-                                 "selected-row fetch are not sharded"),
-])
-def test_what_cannot_carry_the_leaf_refuses_the_model(regime, mesh, kv_quant, says):
-    from ai_agent_kubectl_tpu.engine.batcher import selection_refusal
-
-    msg = selection_refusal(CFG, regime, mesh, kv_quant)
-    assert msg == (f"toy-sparse-moe selects its keys (index_topk=48) and is not "
-                   f"served here: {says}")
-    assert selection_refusal(get_config("toy-moe"), regime, mesh, kv_quant) is None
-    assert selection_refusal(CFG, "ragged", {"model": 1}, "") is None
-
-
 def test_an_engine_without_the_pool_refuses_at_start():
     async def go():
         eng = _engine(kv_pool=False)

@@ -13,15 +13,10 @@ quarantines only the poisoned request while innocents replay
 byte-identical and the books balance, the ``draft_sharded`` /
 ``draft_kv_fallback`` health fields and their fleet OR-rollup, the
 step-time sentinel's spec_verify digests keyed under the mesh with
-worst-replica merge attribution, and a bench ``--phase tp_spec7b``
-subprocess smoke (slow-marked; CI's Spec×TP step runs it unfiltered).
+worst-replica merge attribution.
 """
 
 import asyncio
-import json
-import subprocess
-import sys
-from pathlib import Path
 
 import pytest
 
@@ -294,37 +289,3 @@ def test_fleet_ors_draft_kv_fallback():
     fleet.replicas = [_Rep(_Eng(False)), _Rep(_Eng(False))]
     assert fleet.sharding_health()["draft_kv_fallback"] is False
     assert fleet.spec_health()["draft_kv_fallback"] is False
-
-
-# ------------------------------------------------------ bench rung smoke
-
-
-@pytest.mark.slow
-def test_bench_tp_spec7b_phase_runs_on_virtual_mesh():
-    """The Spec×TP bench rung end-to-end in a subprocess (toy model,
-    tp=8 virtual mesh): the artifact carries the spec window price, the
-    composed tok/s/chip, the measured acceptance, and the draft
-    sharding flags the driver records into gemma_7b.tp_spec_sweep."""
-    root = Path(__file__).resolve().parent.parent
-    import os
-
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + " --xla_force_host_platform_device_count=8"
-                        ).strip()
-    proc = subprocess.run(
-        [sys.executable, str(root / "bench.py"), "--phase", "tp_spec7b",
-         "--bs", "8", "--mesh", "tp=8", "--max-seq", "128",
-         "--model", "toy-8m", "--spec-k", "2", "--chunk-len", "4"],
-        capture_output=True, text=True, timeout=600, env=env)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    rung = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert rung["mesh"] == "tp=8"
-    assert rung["spec_k"] == 2
-    assert rung["spec_step_ms"] > 0
-    assert rung["plain_step_ms"] > 0
-    assert rung["tok_s_chip"] > 0
-    assert 0.0 <= rung["acceptance_ratio"] <= 1.0
-    assert rung["draft_sharded"] is True
-    assert rung["draft_kv_fallback"] is True    # toy 2 KV heads vs tp=8
-    assert rung["verify_windows_per_chunk"] >= 1

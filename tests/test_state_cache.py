@@ -273,10 +273,10 @@ async def test_restored_snapshot_answers_as_a_prefill_from_token_zero(from_token
         assert st0["capacity"] == 8
         hist, usable = PREAMBLE, []
         for t in TURNS:
-            before = eng.ssm_health()
+            before = eng.family_health()["ssm"]
             r = await eng.generate(hist + t, max_tokens=10, temperature=0.0)
             assert r.text == from_token_zero[hist + t], t
-            after = eng.ssm_health()
+            after = eng.family_health()["ssm"]
             usable.append(after["prefix_tokens_usable"] - before["prefix_tokens_usable"])
             hist += t
         n1 = len(eng.tokenizer.encode(PREAMBLE + TURNS[0]))     # bytes and a BOS
@@ -285,7 +285,7 @@ async def test_restored_snapshot_answers_as_a_prefill_from_token_zero(from_token
         assert usable[0] == 0
         assert usable[1] == (n1 - 1) // 16 * 16
         assert usable[2] == (n1 + len(TURNS[1]) - 1) // 16 * 16
-        st = eng.ssm_health()
+        st = eng.family_health()["ssm"]
         # two snapshots a turn, at its prompt's last block edge and the edge
         # before it: where turn 2 left turn 1's chain the node had one child,
         # and nobody else comes that way
@@ -302,20 +302,20 @@ async def test_restored_snapshot_answers_as_a_prefill_from_token_zero(from_token
         r = await eng.generate(PREAMBLE + AGENT_TWO, max_tokens=10,
                                temperature=0.0)
         assert r.text == from_token_zero[PREAMBLE + AGENT_TWO]
-        st2 = eng.ssm_health()
+        st2 = eng.family_health()["ssm"]
         edge = len(eng.tokenizer.encode(PREAMBLE)) // 16 * 16
         assert st2["prefix_tokens_recomputed"] - st["prefix_tokens_recomputed"] >= edge
         assert st2["snapshots_taken"] == st["snapshots_taken"] + 2    # its prompt's end
         # a third finds the preamble's end a node two sequences branch from:
         # it recomputes the preamble once more and leaves the snapshot there
         await eng.generate(PREAMBLE + AGENT_THREE, max_tokens=4, temperature=0.0)
-        st3 = eng.ssm_health()
+        st3 = eng.family_health()["ssm"]
         assert st3["prefix_tokens_recomputed"] - st2["prefix_tokens_recomputed"] >= edge
         # the branch edge + its prompt's two
         assert st3["snapshots_taken"] == st2["snapshots_taken"] + 3
         # which a fourth restores
         await eng.generate(PREAMBLE + AGENT_FOUR, max_tokens=4, temperature=0.0)
-        st3, st2 = eng.ssm_health(), st3
+        st3, st2 = eng.family_health()["ssm"], st3
         assert st3["prefix_tokens_usable"] - st2["prefix_tokens_usable"] == edge
         assert st3["layer_passes"]["ssm"] == st3["forward_passes"] * 2
         eng._state.check()
@@ -361,7 +361,7 @@ async def test_preempt_and_replay_of_a_sequence_with_state(from_token_zero):
         got = await task
         assert eng.stats()["qos"]["preemptions"] >= 1
         assert got.text == want
-        assert eng.ssm_health()["restores"] >= 1
+        assert eng.family_health()["ssm"]["restores"] >= 1
         eng._state.check()
     finally:
         await eng.stop()
@@ -418,12 +418,3 @@ async def test_family_is_refused_where_it_cannot_be_served():
         assert plain.stats()["ssm"] is None and plain._state is None
     finally:
         await plain.stop()
-
-
-def test_a_mesh_is_refused_with_its_message():
-    from ai_agent_kubectl_tpu.engine.batcher import state_refusal
-    from ai_agent_kubectl_tpu.models.config import get_config
-
-    why = state_refusal(get_config("toy-hybrid-moe"), "ragged", {"model": 4}, False)
-    assert "MESH_SHAPE" in why and "parallel/sharding.py has no rule" in why
-    assert state_refusal(get_config("toy-8m"), "dense", {"model": 4}, True) is None

@@ -11,15 +11,10 @@ across the TP group, collectives fused at the GEMM boundaries and kept
 scan-resident), the loud dense fallback for data/pipe/seq meshes, the
 SPEC_DECODE+mesh capability check (tp/ep compose since ISSUE 18;
 data/pipe/seq refuse), replicated grammar tables, the sharding
-/health + /metrics surfaces, the v2 ``all_reduce`` attribution category,
-and tp_projection's measured re-pricing mode.
+/health + /metrics surfaces.
 """
 
 import asyncio
-import json
-import subprocess
-import sys
-from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -129,11 +124,10 @@ async def test_pool_vs_dense_under_mesh_byte_identical_and_fused():
         assert n_coll >= 1, "expected fused TP collectives in the HLO"
         # The layer loop stays a lax.scan ("while" in HLO): the
         # residual collectives live ONCE in the scan body and execute
-        # per layer — the 2-fused-pairs-per-layer cost model
-        # tools/tp_projection.py prices (the measured comm share rides
-        # bench --phase tp7b via the all_reduce attribution category;
-        # an instruction count here would pin XLA:CPU partitioner
-        # noise, not the model).
+        # per layer (the measured comm share is the mesh cell's
+        # ``collectives_dev_share``, benchmark/xtrace.py; an instruction
+        # count here would pin XLA:CPU partitioner noise, not the
+        # model).
         assert "while" in hlo, "layer scan must not be unrolled"
 
         outs = await asyncio.gather(*[
@@ -271,24 +265,6 @@ def test_config_mesh_device_count_parser():
     assert _mesh_device_count("data:2, model:2") == 4
 
 
-def test_attribution_all_reduce_category():
-    """v2 schema: collectives bill to the comm category — scope-tagged
-    spans AND bare partitioner-emitted HLO names — never to
-    data_movement, so the sharded step's comm time is accounted."""
-    from ai_agent_kubectl_tpu.obs.attribution import (CATEGORIES,
-                                                      SCHEMA_ID,
-                                                      categorize)
-
-    assert "all_reduce" in CATEGORIES
-    assert SCHEMA_ID.endswith("/v2")
-    assert categorize("transformer/all_reduce/custom-call.7") \
-        == "all_reduce"
-    assert categorize("%all-reduce.12") == "all_reduce"
-    assert categorize("reduce-scatter.3") == "all_reduce"
-    assert categorize("all-gather-start.1") == "all_reduce"
-    assert categorize("copy.3") == "data_movement"
-
-
 def test_metrics_observe_sharding_renders_gauges():
     from ai_agent_kubectl_tpu.server.metrics import Metrics
 
@@ -341,65 +317,3 @@ async def test_health_and_metrics_expose_sharding_section():
     finally:
         await client.close()
         await engine.stop()
-
-
-def test_tp_projection_measured_repricing():
-    """--measured-step / --measured-json add the measured section whose
-    tok/s/chip is arithmetic on the measurement (bs / step / tp) and
-    whose implied f back-solves the model — projection and
-    implementation converge on one number."""
-    root = Path(__file__).resolve().parent.parent
-    out = subprocess.run(
-        [sys.executable, str(root / "tools" / "tp_projection.py"),
-         "--measured-step", "12.05", "--measured-bs", "192"],
-        capture_output=True, text=True, check=True).stdout
-    assert "Measured TP=8 step" in out
-    line = next(ln for ln in out.splitlines()
-                if ln.startswith("| 192 | 12.05"))
-    # 192 / 12.05ms / 8 chips = 1991 tok/s/chip — the same number the
-    # f=1.0/bs=192 projection row prices.
-    assert "**1992**" in line or "**1991**" in line, line
-    # Measured step == the f=1 model's step => implied f ~ 1.
-    f_col = line.split("|")[4].strip()
-    assert abs(float(f_col) - 1.0) < 0.05, line
-
-    art = {"gemma_7b": {"tp_sweep": {"rungs": [
-        {"bs": 48, "step_ms": 5.59, "allreduce_ms": 1.43}]}}}
-    import tempfile
-
-    with tempfile.NamedTemporaryFile("w", suffix=".json",
-                                     delete=False) as f:
-        json.dump(art, f)
-        path = f.name
-    out = subprocess.run(
-        [sys.executable, str(root / "tools" / "tp_projection.py"),
-         "--measured-json", path],
-        capture_output=True, text=True, check=True).stdout
-    assert "| 48 | 5.59" in out
-
-
-def test_bench_tp7b_phase_runs_on_virtual_mesh():
-    """The bench rung end-to-end in a subprocess (toy model, tp=8
-    virtual mesh): artifact carries step_ms, tok_s_chip, the all-reduce
-    share, and the sharding flags the driver records into
-    gemma_7b.tp_sweep."""
-    root = Path(__file__).resolve().parent.parent
-    import os
-
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + " --xla_force_host_platform_device_count=8"
-                        ).strip()
-    proc = subprocess.run(
-        [sys.executable, str(root / "bench.py"), "--phase", "tp7b",
-         "--bs", "8", "--mesh", "tp=8", "--max-seq", "128",
-         "--model", "toy-8m", "--chunk-len", "4"],
-        capture_output=True, text=True, timeout=600, env=env)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    rung = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert rung["mesh"] == "tp=8"
-    assert rung["step_ms"] > 0
-    assert rung["tok_s_chip"] > 0
-    assert rung["pool_sharded"] is True
-    assert rung["kv_pool_mesh_fallback"] is False
-    assert rung["residual_tp_fraction"] == 1.0   # bs=8 divides tp=8

@@ -37,6 +37,17 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _engine_cache(arg, cfg, n_blocks: int, page: int, slots: int):
+    """The pool engine's cache for ``cfg`` as the engine builds it
+    (``KVCache.pool_zeros``), abstract: what the compiler is shown is what
+    the engine holds. Prefill buckets up to 512; the grouped path counts."""
+    made = jax.eval_shape(lambda: KVCache.pool_zeros(
+        cfg, n_blocks=n_blocks, page=page, slots=slots,
+        ring=cfg.sliding_ring(512, page), dtype=jnp.bfloat16,
+        counts_experts=cfg.grouped_experts))
+    return jax.tree_util.tree_map(lambda a: arg(a.shape, a.dtype), made)
+
+
 def _instructions(hlo: str):
     """(result type text, op, the whole line) of every HLO instruction."""
     for line in hlo.splitlines():
@@ -616,12 +627,7 @@ def test_selecting_forward_compiles_at_published_widths_on_v5e(one_chip, W,
         jax.eval_shape(lambda k: random_params_int8(
             k, cfg, dtype=jnp.bfloat16, quantize_embed=True),
             jax.random.PRNGKey(0)))
-    pool = (cfg.n_layers, n_blocks, page, cfg.n_kv_heads, cfg.head_dim)
-    cache = KVCache(k=arg(pool, jnp.bfloat16), v=arg(pool, jnp.bfloat16),
-                    lengths=arg((n_blocks,), jnp.int32),
-                    ik=arg(pool[:3] + (cfg.index_key_width,), jnp.bfloat16),
-                    experts_read=arg((), jnp.int32),
-                    sel_rows=arg((2,), jnp.int32))
+    cache = _engine_cache(arg, cfg, n_blocks, page, B)
 
     def step(params, tok, pos, cache, wmask, tables, q_lens):
         return forward(params, cfg, tok, pos, cache, kv_limit=pages * page,
@@ -715,8 +721,6 @@ def test_patterned_forward_compiles_at_published_widths_on_v5e(one_chip, B, W,
     [D, F] a 638 MB copy a layer a pass did), and the ragged kernel at
     32Q/2KV without a rotary embedding; the state leaves ride the
     donated cache; nothing expert-stack-sized or pool-sized moves."""
-    from ai_agent_kubectl_tpu.models.transformer import state_zeros
-
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = ModelConfig(name="aot-nemotron", n_layers=3, layer_pattern="ME*",
                       **NEMOTRON)
@@ -730,13 +734,9 @@ def test_patterned_forward_compiles_at_published_widths_on_v5e(one_chip, B, W,
         jax.eval_shape(lambda k: random_params_int8(
             k, cfg, dtype=jnp.bfloat16, quantize_embed=True),
             jax.random.PRNGKey(0)))
-    pool = (1, n_blocks, page, cfg.n_kv_heads, cfg.head_dim)
-    ssm, conv = jax.eval_shape(lambda: state_zeros(cfg, B, jnp.bfloat16))
-    cache = KVCache(k=arg(pool, jnp.bfloat16), v=arg(pool, jnp.bfloat16),
-                    lengths=arg((n_blocks,), jnp.int32),
-                    experts_read=arg((), jnp.int32),
-                    ssm=arg(ssm.shape, ssm.dtype),
-                    conv=arg(conv.shape, conv.dtype))
+    cache = _engine_cache(arg, cfg, n_blocks, page, B)
+    pool, ssm = cache.k.shape, cache.ssm
+    assert pool[0] == 1 and ssm.shape[1] == B
 
     def step(params, tok, pos, cache, wmask, tables, q_lens):
         return forward(params, cfg, tok, pos, cache, kv_limit=pages * page,
@@ -805,12 +805,10 @@ def test_latent_forward_compiles_at_published_widths_on_v5e(one_chip, B, W,
         jax.eval_shape(lambda k: random_params_int8(
             k, cfg, dtype=jnp.bfloat16, quantize_embed=True),
             jax.random.PRNGKey(0)))
-    leaf = (cfg.n_layers, n_blocks, page // 2, 2 * cfg.latent_row)
-    assert leaf[-1] == 640 and cfg.latent_row * 2 == 640     # bytes a token a layer
-    cache = KVCache(k=None, v=None, lengths=arg((n_blocks,), jnp.int32),
-                    lat=arg(leaf, jnp.bfloat16),
-                    lat_rows=arg((2,), jnp.int32),
-                    experts_read=arg((), jnp.int32))
+    cache = _engine_cache(arg, cfg, n_blocks, page, B)
+    leaf = cache.lat.shape
+    assert leaf == (cfg.n_layers, n_blocks, page // 2, 640)  # bytes a token a layer
+    assert cache.k is None and cache.v is None
 
     def step(params, tok, pos, cache, wmask, tables, q_lens):
         return forward(params, cfg, tok, pos, cache, kv_limit=pages * page,
@@ -897,8 +895,6 @@ def test_sliding_forward_compiles_at_published_widths_on_v5e(one_chip, B, W,
     bound, reading the ring through a computed table; the pool holds the
     full layer's rows alone; the rings ride the donated cache and are
     written in place; nothing pool-sized or expert-stack-sized moves."""
-    from ai_agent_kubectl_tpu.models.transformer import sliding_zeros
-
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = ModelConfig(name="aot-laguna", n_layers=2, layer_pattern="*DSE",
                       **LAGUNA)
@@ -912,15 +908,9 @@ def test_sliding_forward_compiles_at_published_widths_on_v5e(one_chip, B, W,
         jax.eval_shape(lambda k: random_params_int8(
             k, cfg, dtype=jnp.bfloat16, quantize_embed=True),
             jax.random.PRNGKey(0)))
-    pool = (cfg.n_of("*"), n_blocks, page, cfg.n_kv_heads, cfg.head_dim)
-    ring = cfg.sliding_ring(512, page)
-    sk, _ = jax.eval_shape(lambda: sliding_zeros(cfg, B, ring, jnp.bfloat16))
+    cache = _engine_cache(arg, cfg, n_blocks, page, B)
+    pool, sk = cache.k.shape, cache.sk
     assert pool[0] == 1 and sk.shape == (1, B, 1024, 8, 128)
-    cache = KVCache(k=arg(pool, jnp.bfloat16), v=arg(pool, jnp.bfloat16),
-                    lengths=arg((n_blocks,), jnp.int32),
-                    experts_read=arg((), jnp.int32),
-                    sk=arg(sk.shape, sk.dtype), sv=arg(sk.shape, sk.dtype),
-                    span_rows=arg((4,), jnp.int32))
 
     def step(params, tok, pos, cache, wmask, tables, q_lens):
         return forward(params, cfg, tok, pos, cache, kv_limit=pages * page,

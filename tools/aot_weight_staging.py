@@ -25,6 +25,7 @@ is counted:
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import math
 import re
@@ -114,31 +115,16 @@ def decode_program(cfg, sharding, *, batch=16, window=1, n_blocks=320,
             param_shardings(shapes, mesh, cfg))
         heads = NamedSharding(mesh, sanitize_spec(
             mesh, pool_cache_specs(cfg)["k"], pool))
-    lengths = arg((n_blocks,), jnp.int32)
-    counts = arg((), jnp.int32) if cfg.grouped_experts else None
-    if cfg.latent:
-        cache = KVCache(k=None, v=None, lengths=lengths,
-                        lat=arg((cfg.n_layers, n_blocks, page // 2,
-                                 2 * cfg.latent_row), jnp.bfloat16),
-                        lat_rows=arg((2,), jnp.int32), experts_read=counts)
-    else:
-        extra = {}
-        if cfg.selects_keys:
-            extra.update(ik=arg(pool[:3] + (cfg.index_key_width,), jnp.bfloat16),
-                         sel_rows=arg((2,), jnp.int32))
-        if cfg.slides:
-            sk, _ = jax.eval_shape(lambda: tf.sliding_zeros(
-                cfg, batch, cfg.sliding_ring(512, page), jnp.bfloat16))
-            extra.update(sk=arg(sk.shape, sk.dtype), sv=arg(sk.shape, sk.dtype),
-                         span_rows=arg((4,), jnp.int32))
-        elif cfg.keeps_state:
-            ssm, conv = jax.eval_shape(
-                lambda: tf.state_zeros(cfg, batch, jnp.bfloat16))
-            extra.update(ssm=arg(ssm.shape, ssm.dtype),
-                         conv=arg(conv.shape, conv.dtype))
-        cache = KVCache(k=arg(pool, jnp.bfloat16, heads),
-                        v=arg(pool, jnp.bfloat16, heads), lengths=lengths,
-                        experts_read=counts, **extra)
+    # the pool engine's cache as the engine builds it, abstract
+    cache = jax.tree_util.tree_map(
+        lambda a: arg(a.shape, a.dtype), jax.eval_shape(
+            lambda: KVCache.pool_zeros(
+                cfg, n_blocks=n_blocks, page=page, slots=batch,
+                ring=cfg.sliding_ring(512, page), dtype=jnp.bfloat16,
+                counts_experts=cfg.grouped_experts)))
+    if cache.k is not None:
+        cache = dataclasses.replace(cache, k=arg(pool, jnp.bfloat16, heads),
+                                    v=arg(pool, jnp.bfloat16, heads))
 
     def step(params, tok, pos, cache, wmask, tables, q_lens):
         return forward(params, cfg, tok, pos, cache, kv_limit=pages * page,

@@ -1,8 +1,7 @@
 """Serving-path attribution probe.
 
-Two measurements `tools/profile_decode.py` can't make (it builds bf16
-params from scratch; this builds the REAL engine, including QUANT /
-KV_QUANT / prefix cache / scheduler):
+Two measurements of the REAL engine (QUANT / KV_QUANT / prefix cache /
+scheduler included) outside the benchmark's cells:
 
 1. **Decode-chunk device ceiling**: chained dispatches of the engine's own
    compiled batch-chunk programs, per KV-ladder bucket — the marginal
@@ -201,7 +200,7 @@ def print_attention_regime(gauges: Dict[str, float]) -> None:
 def print_mesh_summary(gauges: Dict[str, float]) -> None:
     """Tensor-parallel serving (ISSUE 14) from the same /metrics
     scrape: mesh size, the residual TP fraction the active policy
-    achieves (1.0 = the f≈1 layout tp_projection prices), and whether
+    achieves (1.0 = every residual-path tensor batch-sharded), and whether
     a requested KV pool silently fell back to the dense ladder."""
     devices = gauges.get("mesh_devices", 0.0)
     if not devices:
