@@ -2605,7 +2605,15 @@ class BatchedJaxEngine(JaxEngine):
             # collapsing the (bucket, kv_limit) program-set keys. The
             # draft prefill (_draft_prefill_slot) keeps its ladder —
             # its dense per-slot scratch really does gather kv_limit.
-            kv_limit = (self._S_alloc if self._use_ragged
+            # A model served with prompts past the widest bucket takes
+            # the one rung under ``gather`` too (off the TPU, or with an
+            # int8 pool where its cache rides one; no shipped cell): a
+            # piece at any depth then runs a program the warm-up ran, at
+            # the price of a gather as wide as the cache, where a rung a
+            # depth compiled at a request's first piece that deep,
+            # seconds on the scheduler's thread.
+            kv_limit = (self._S_alloc
+                        if self._use_ragged or long_prompts(self.model_cfg)
                         else self._pool_kv_limit(offset + bucket))
             # One eager piece (sched/eager_prefill): staging through the
             # program call's return; ``call_ms`` is the jitted call alone,
@@ -2932,6 +2940,15 @@ class BatchedJaxEngine(JaxEngine):
                 wide[:len(more)] = more
                 self._pool_prefill_span(wide, [0] * wb, 0)
                 self._pool.decref(more)
+        if self._state is not None:
+            # the restore program (snapshot row 0, all zeros, into slot 0):
+            # the warm-up answer runs the zero and snapshot programs but
+            # restores nothing, and the first request seated from a
+            # snapshot compiled this one inside a measured window
+            self._cache = dataclasses.replace(
+                self._cache, **self._state_copy_fn[1](
+                    self._live_state(), self._snap, np.int32(0), np.int32(0),
+                    np.int32(0)))
         self._key_d = jax.random.PRNGKey(self.seed)
         self._sample_fn(
             jnp.zeros((1, cfg.vocab_size), jnp.float32), self._key_d,

@@ -596,9 +596,13 @@ class StateStore:
     starts a slot from nothing; the fake engine passes none — its state
     has no bytes, the bookkeeping is all of it). A handle is FREE, or
     PENDING (taken by a live slot whose chain is not in the tree yet), or
-    ATTACHED to exactly one radix node. Every live slot PINS the
-    snapshots on its matched path and the ones it took: eviction is LRU
-    among attached snapshots nobody pins, independent of the blocks' LRU
+    ATTACHED to exactly one radix node. Every live slot PINS the deepest
+    two snapshots on its matched path (the one it was seated from, which
+    a replay of it restores again, and the one before it) and the ones it
+    took: eviction is LRU among attached snapshots nobody pins, the
+    earlier turns' of a live session among them (pinning every one on the
+    path held 8 x 6 x 2 rows of 54.7 MB for eight agents of six turns;
+    ISSUE 45), independent of the blocks' LRU
     — but a node that loses its block loses its snapshot (``drop``). The
     host tier takes no states: a demoted page keeps its K/V and not this.
 
@@ -655,8 +659,8 @@ class StateStore:
     def seat(self, slot: int, mr) -> None:
         """Seat decode slot ``slot`` for a sequence whose match is ``mr``
         (``RadixCache.match``'s result, None with no tree): restore the
-        snapshot the match ended at, or start from zero; pin every
-        snapshot on the path until ``release``."""
+        snapshot the match ended at, or start from zero; pin the deepest
+        two snapshots on the path until ``release``."""
         self.release(slot)
         handle = mr.snapshot if mr is not None else None
         self._branch_edge[slot] = (mr.kv_matched_edge
@@ -672,7 +676,7 @@ class StateStore:
         self.prefix_tokens_matched += mr.kv_matched
         self.prefix_tokens_usable += usable
         self.prefix_tokens_recomputed += mr.kv_matched - usable
-        for h in mr.path_snapshots:
+        for h in mr.path_snapshots[-2:]:
             self._pin(slot, h)
         # An LRU store is full in any long run, so ``held_peak`` reads its
         # capacity; what sizes it is how far down the LRU order a restore

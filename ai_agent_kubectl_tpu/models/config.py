@@ -37,6 +37,16 @@ test models. Family differences are expressed as data, not subclasses:
   ``D`` dense MLP or ``E`` experts), weights stacked per kind. A sliding
   layer's K/V are a bounded state a sequence (a ring of the span plus a
   window, ``KVCache.sk``/``sv``), not rows of the paged pool
+- Olmo-Hybrid-7B (``olmo_hybrid``; registered by the benchmark,
+  ``toy-linear-hybrid`` is its toy): LINEAR-attention layers (``L``: the
+  gated delta rule, ops/gated_delta.py — a [key_dim, value_dim] matrix a
+  head that every token decays, erases along its key and writes to; the
+  state a cache leaf of its own beside a 4-tap convolution's tail) three
+  to one full-attention layer without a rotary embedding, each followed
+  by a ``D`` dense MLP (``mixers_per_layer`` 2), and the Olmo block form:
+  a sublayer's OUTPUT is normed, ``x + norm(f(x))``, its input is not
+  (``post_norm``), and the QK-norm spans the whole projection before the
+  head split (``qk_norm_whole``)
 """
 
 from __future__ import annotations
@@ -54,7 +64,9 @@ class ModelConfig:
     n_heads: int
     n_kv_heads: int
     head_dim: int
-    mlp_hidden: int
+    # the MLP's width, or the experts'; 0 where every MLP is a ``D`` mixer
+    # of ``dense_mlp_hidden``
+    mlp_hidden: int = 0
     rope_theta: float = 10000.0
     rms_eps: float = 1e-6
     rms_offset: float = 0.0          # 1.0 for Gemma's (1+w) norm
@@ -72,6 +84,14 @@ class ModelConfig:
     # Per-head RMSNorm on q and k before the rotary embedding (weights
     # ``q_norm``/``k_norm`` [head_dim]; the Qwen3 convention).
     qk_norm: bool = False
+    # ... or ONE RMSNorm over a token's whole q projection and one over its
+    # k projection, before the head split (weights ``q_norm`` [heads x
+    # head_dim], ``k_norm`` [kv heads x head_dim]; the Olmo 2 convention).
+    qk_norm_whole: bool = False
+    # Where a sublayer's norm sits: False ``x + f(norm(x))``, True ``x +
+    # norm(f(x))`` with no norm on the input (the Olmo 2 block; patterned
+    # configurations only: every mixer's norm leaf is then its output's).
+    post_norm: bool = False
     # Learned sparse attention (DeepSeek-V3.2-Exp's lightning indexer on a
     # GQA model): query t scores every key s <= t with ``index_heads``
     # heads of ``index_head_dim`` against ONE index key a token,
@@ -84,6 +104,7 @@ class ModelConfig:
     # One mixer a layer (the nemotron_h family): character l names layer
     # l's kind, ``M`` a Mamba-2 layer, ``E`` an expert layer, ``*``
     # attention, ``S`` sliding-window attention, ``D`` a dense MLP, each
+    # ``L`` a linear-attention layer (the gated delta rule), each
     # ``x + mixer(norm(x))`` with nothing behind it. A string so the
     # dataclass stays hashable; longer than the layers it names it means
     # its first characters (a configuration cut in depth keeps the
@@ -120,6 +141,20 @@ class ModelConfig:
     ssm_groups: int = 0
     ssm_conv: int = 0
     ssm_chunk: int = 0
+    # Linear attention by the gated delta rule (``L`` layers; ops/
+    # gated_delta.py has the recurrence): ``lin_value_heads`` heads, each a
+    # float32 state [``lin_key_dim``, ``lin_value_dim``]; q and k of
+    # ``lin_key_heads`` heads (equal to the value heads: a configuration
+    # whose counts differ is refused until the repeat is built); a causal
+    # depthwise convolution of ``lin_conv`` taps over [q | k | v];
+    # ``lin_neg_eigval``: the write strength beta runs over (0, 2), not
+    # (0, 1).
+    lin_key_heads: int = 0
+    lin_value_heads: int = 0
+    lin_key_dim: int = 0
+    lin_value_dim: int = 0
+    lin_conv: int = 0
+    lin_neg_eigval: bool = False
     # An expert every token takes beside the routed ones (0 = none), the
     # router's kind (``softmax``: the k largest logits, softmax over them;
     # ``sigmoid_bias``: sigmoid scores, the k largest of score + a learned
@@ -213,6 +248,11 @@ class ModelConfig:
                 f"not name {n} mixers of kinds {', '.join(MIXER_KINDS)}")
         if "S" in kinds and self.sliding_window < 1:
             raise ValueError(f"{self.name}: an S layer needs sliding_window")
+        if "L" in kinds and self.lin_key_heads != self.lin_value_heads:
+            raise ValueError(
+                f"{self.name}: lin_value_heads {self.lin_value_heads} over "
+                f"lin_key_heads {self.lin_key_heads}: a key head repeated "
+                "over several value heads is not built (ROADMAP)")
         return kinds
 
     def n_of(self, kind: str) -> int:
@@ -223,13 +263,24 @@ class ModelConfig:
     @property
     def keeps_state(self) -> bool:
         """A bounded state a sequence beside the paged KV cache (engine/
-        kv_pool.py::StateStore): some layer is a state-space layer, or a
-        sliding-attention one."""
-        return self.has_ssm or self.slides
+        kv_pool.py::StateStore): some layer is a state-space layer, a
+        linear-attention one, or a sliding-attention one."""
+        return self.has_ssm or self.has_linear or self.slides
 
     @property
     def has_ssm(self) -> bool:
         return "M" in self.layer_kinds
+
+    @property
+    def has_linear(self) -> bool:
+        """Some layer is linear attention (the gated delta rule)."""
+        return "L" in self.layer_kinds
+
+    @property
+    def lin_conv_dim(self) -> int:
+        """Channels the linear layers' convolution runs over: [q | k | v]."""
+        return (2 * self.lin_key_heads * self.lin_key_dim
+                + self.lin_value_heads * self.lin_value_dim)
 
     @property
     def slides(self) -> bool:
@@ -269,7 +320,13 @@ class ModelConfig:
         sliding = self.n_of("S") * (
             2 * 2 * self.sliding_window * self.n_kv_heads * self.head_dim
         ) if self.slides else 0
-        return ssm + sliding
+        # ... and float32 [key_dim, heads x value_dim] with a bf16
+        # convolution tail a linear-attention layer
+        linear = self.n_of("L") * (
+            4 * self.lin_key_dim * self.lin_value_heads * self.lin_value_dim
+            + 2 * (self.lin_conv - 1) * self.lin_conv_dim
+        ) if self.has_linear else 0
+        return ssm + sliding + linear
 
     @property
     def index_key_width(self) -> int:
@@ -298,6 +355,19 @@ class ModelConfig:
         kernel can take wide experts (ROADMAP S2)."""
         return (self.n_experts > 0
                 and self.experts_scored >= 8 * self.experts_per_token)
+
+    @property
+    def kv_heads_paged(self) -> int:
+        """KV heads a row of the cache holds a token: ``n_kv_heads``, in
+        whole sublane tiles of 8 where there are more than 8 (30 -> 32).
+        The TPU keeps a [30, 128] bf16 row as 32 in HBM whatever the leaf's
+        shape says, and the paged kernel cannot slice 30 of a tile's 32
+        (Mosaic: "must be aligned to tiling (8)"; AOT, PR 45): the leaf
+        says what the memory holds, the spare heads stay zero, and
+        attention runs its queries padded alike (models/transformer.py::
+        _layer)."""
+        KV = self.n_kv_heads
+        return KV if KV <= 8 else -(-KV // 8) * 8
 
     @property
     def q_per_kv(self) -> int:
@@ -338,8 +408,10 @@ class ModelConfig:
 
         def attn_of(kind):
             H = self.heads_of(kind)
+            whole = (H + self.n_kv_heads) * self.head_dim
             return (2 * d * self.head_dim * (H + self.n_kv_heads)
-                    + (d * H if self.attn_gate else 0))
+                    + (d * H if self.attn_gate else 0)
+                    + (whole if self.qk_norm_whole else 0))
 
         attn = attn_of("*")
         moe = (self.n_experts * mats * d * self.mlp_hidden
@@ -350,15 +422,22 @@ class ModelConfig:
                     + self.ssm_heads)
                + self.ssm_inner * d + (self.ssm_conv + 1) * self.ssm_conv_dim
                + 3 * self.ssm_heads + self.ssm_inner)
+        # W_q | W_k | W_v | W_g in, W_o out, W_a and W_b, the convolution,
+        # A_log and dt_bias, the gated norm's gain
+        vals = self.lin_value_heads * self.lin_value_dim
+        linear = (d * (self.lin_conv_dim + vals) + vals * d
+                  + 2 * d * self.lin_value_heads
+                  + self.lin_conv * self.lin_conv_dim
+                  + 2 * self.lin_value_heads + self.lin_value_dim)
         per = {"*": attn, "E": moe, "M": ssm, "S": attn_of("S"),
-               "D": mats * d * self.dense_mlp_hidden}
+               "D": mats * d * self.dense_mlp_hidden, "L": linear}
         layers = sum(per[k] + d for k in self.layer_kinds)
         head = 0 if self.tie_embeddings else self.vocab_size * d
         return self.vocab_size * d + layers + d + head
 
 
 #: The kinds ``layer_pattern`` may name.
-MIXER_KINDS = ("M", "E", "*", "S", "D")
+MIXER_KINDS = ("M", "E", "*", "S", "D", "L")
 
 _CONFIGS: Dict[str, ModelConfig] = {}
 
@@ -437,6 +516,21 @@ TOY_SLIDING_MOE = _register(ModelConfig(
     rope_theta=500000.0, rope_partial=0.5, rope_factor=8.0,
     rope_original_max=64, rope_attention_factor=1.2, attn_gate="per-head",
     max_seq_len=2048,
+))
+
+# Linear attention by the gated delta rule three layers to one of full
+# attention (MHA, no rotary embedding, QK-norm over the whole projection),
+# each followed by a dense MLP, a sublayer's output normed and not its
+# input; key and value dims that differ and are no multiple of 128 lanes,
+# two periods: the toy of
+# the benchmark's olmo-hybrid-7b configuration.
+TOY_LINEAR_HYBRID = _register(ModelConfig(
+    name="toy-linear-hybrid", vocab_size=512, dim=128, n_layers=8,
+    n_heads=4, n_kv_heads=4, head_dim=32, dense_mlp_hidden=192,
+    layer_pattern="LDLDLD*D" * 2, mixers_per_layer=2, lin_key_heads=4,
+    lin_value_heads=4, lin_key_dim=24, lin_value_dim=40, lin_conv=4,
+    lin_neg_eigval=True, post_norm=True, qk_norm_whole=True,
+    use_rope=False, max_seq_len=2048,
 ))
 
 # --- Gemma (HF: google/gemma-{2b,7b}-it) ---

@@ -26,7 +26,7 @@ from .config import ModelConfig
 OBSTACLES = ("dense", "kv_quant", "mesh", "spec")
 
 _LAYER_PASS_KINDS = (("ssm", "M"), ("experts", "E"), ("attention", "*"),
-                     ("sliding", "S"), ("dense_mlp", "D"))
+                     ("sliding", "S"), ("dense_mlp", "D"), ("linear", "L"))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -143,6 +143,24 @@ def _sliding_section(cfg, counts, facts) -> dict:
             "forward_passes": facts["forward_passes"]}
 
 
+def _linear_section(cfg, counts, facts) -> dict:
+    """/health.linear_attention. ``state_bytes_per_sequence``: what a
+    snapshot keeps, every linear layer. ``decode_rows_linear`` /
+    ``window_rows_linear`` / ``chunks_scanned``: the decode rows and the
+    prompt rows the linear layers ran and the chunks their scans ran over
+    (padding included); ``decode_rows_full`` / ``full_keys_read``: the
+    decode queries the full-attention layers ran and the keys those had
+    before them (all five counted on the device, summed over the layers
+    of the kind)."""
+    rows_l, window_l, chunks, rows_f, keys_f = counts["dev"]
+    return {"layers_linear": cfg.n_of("L"), "layers_full": cfg.n_of("*"),
+            "state_bytes_per_sequence": cfg.state_bytes(),
+            "decode_rows_linear": rows_l, "window_rows_linear": window_l,
+            "chunks_scanned": chunks,
+            "decode_rows_full": rows_f, "full_keys_read": keys_f,
+            "forward_passes": facts["forward_passes"]}
+
+
 def _state_section(cfg, counts, facts) -> Optional[dict]:
     """/health.ssm (None until the engine has its snapshot store): the
     store's counters (engine/kv_pool.py::StateStore.stats) and forward
@@ -235,6 +253,19 @@ CACHE_KINDS: Tuple[CacheKind, ...] = (
                        "window_pairs_full": "window_pairs_full"},
         health={"sliding_attention": _sliding_section,
                 "ssm": _state_section}),
+    # The same store; the state is a float32 matrix a head (the gated
+    # delta rule's) and its passes count their rows beside the full
+    # layers' keys.
+    CacheKind(
+        name="linear",
+        of=lambda cfg: cfg.has_linear,
+        says=lambda cfg: _STATE_SAYS.format(
+            state="a linear-attention state",
+            pattern="".join(cfg.layer_kinds)),
+        count_leaf="lin_rows", count_shape=(5,), lane="sel_rows",
+        refuses=_STATE_REFUSES,
+        health={"linear_attention": _linear_section,
+                "ssm": _state_section}),
     # Its cache is ONE leaf of the block pool with no head axis.
     CacheKind(
         name="latent",
@@ -296,7 +327,10 @@ def kernel_heads(cfg: ModelConfig, tp: int = 1) -> Tuple[int, int, int]:
     for kind in kinds_of(cfg):
         if kind.kernel_heads is not None:
             return kind.kernel_heads(cfg)
-    return cfg.n_heads // tp, cfg.n_kv_heads // tp, cfg.head_dim
+    # (a cache row's spare KV heads, 32 held for 30, run with their queries)
+    spare = cfg.kv_heads_paged - cfg.n_kv_heads
+    return ((cfg.n_heads + spare * cfg.q_per_kv) // tp,
+            cfg.kv_heads_paged // tp, cfg.head_dim)
 
 
 def resolved_at_start(cfg: ModelConfig, regime: str) -> Dict[str, Any]:

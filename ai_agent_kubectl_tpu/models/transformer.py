@@ -122,6 +122,20 @@ class KVCache:
              context), summed over layers and passes since the chunk
              program last zeroed it (/health.sliding_attention). Absent
              elsewhere.
+    lin, lconv: a configuration with linear-attention layers
+             (``ModelConfig.has_linear``) only: the gated delta rule's
+             matrix state, float32 [n_linear_layers, batch, key_dim, heads
+             x value_dim] (the heads side by side along the lanes:
+             ops/gated_delta.py says why), and its convolution's tail,
+             [n_linear_layers, batch, conv - 1, conv channels]; carried,
+             cut and put back as ``ssm``/``conv`` are, and made by
+             ``forward`` where absent.
+    lin_rows: int32 [5], for such a configuration's engine: the DECODE
+             rows its linear layers ran, the window rows they ran and the
+             chunks their scans ran over, then the decode queries its full
+             attention layers ran and the keys those had before them,
+             summed over layers and passes since the chunk program last
+             zeroed it (/health.linear_attention). Absent elsewhere.
     """
 
     k: Any
@@ -137,16 +151,21 @@ class KVCache:
     sk: Any = None
     sv: Any = None
     span_rows: Any = None
+    lin: Any = None
+    lconv: Any = None
+    lin_rows: Any = None
 
     #: the leaves that hold one bounded state a batch row (axis 1)
-    STATE = ("ssm", "conv", "sk", "sv")
+    STATE = ("ssm", "conv", "sk", "sv", "lin", "lconv")
     #: what the passes count on the device, zeroed by the chunk program
-    COUNTS = ("experts_read", "sel_rows", "lat_rows", "span_rows")
+    COUNTS = ("experts_read", "sel_rows", "lat_rows", "span_rows",
+              "lin_rows")
 
     @classmethod
     def zeros(cls, cfg: ModelConfig, batch: int, max_seq: int,
               dtype=jnp.bfloat16, kv_quant: str = "") -> "KVCache":
-        shape = (cfg.n_of("*"), batch, max_seq, cfg.n_kv_heads, cfg.head_dim)
+        shape = (cfg.n_of("*"), batch, max_seq, cfg.kv_heads_paged,
+                 cfg.head_dim)
         return cls(k=_kv_zeros(shape, dtype, kv_quant),
                    v=_kv_zeros(shape, dtype, kv_quant),
                    lengths=jnp.zeros((batch,), dtype=jnp.int32))
@@ -162,6 +181,8 @@ class KVCache:
             leaves["ssm"], leaves["conv"] = state_zeros(cfg, rows, dtype)
         if cfg.slides:
             leaves["sk"], leaves["sv"] = sliding_zeros(cfg, rows, ring, dtype)
+        if cfg.has_linear:
+            leaves["lin"], leaves["lconv"] = linear_zeros(cfg, rows, dtype)
         return leaves
 
     @classmethod
@@ -179,7 +200,8 @@ class KVCache:
         lengths are host truth."""
         from .families import kinds_of
 
-        shape = (cfg.n_of("*"), n_blocks, page, cfg.n_kv_heads, cfg.head_dim)
+        shape = (cfg.n_of("*"), n_blocks, page, cfg.kv_heads_paged,
+                 cfg.head_dim)
         if cfg.latent:
             # a pair of tokens a leaf row (``lat`` above); no K, no V
             paged = dict(k=None, v=None, lat=jnp.zeros(
@@ -232,6 +254,16 @@ def state_zeros(cfg: ModelConfig, rows: int, dtype=jnp.bfloat16):
             jnp.zeros((n, rows, cfg.ssm_conv - 1, cfg.ssm_conv_dim), dtype))
 
 
+def linear_zeros(cfg: ModelConfig, rows: int, dtype=jnp.bfloat16):
+    """(lin, lconv) leaves of ``rows`` sequences, every one at its start."""
+    from ..ops.gated_delta import STATE_DTYPE
+
+    n = cfg.n_of("L")
+    return (jnp.zeros((n, rows, cfg.lin_key_dim,
+                       cfg.lin_value_heads * cfg.lin_value_dim), STATE_DTYPE),
+            jnp.zeros((n, rows, cfg.lin_conv - 1, cfg.lin_conv_dim), dtype))
+
+
 def sliding_zeros(cfg: ModelConfig, rows: int, ring: int,
                   dtype=jnp.bfloat16):
     """(sk, sv) leaves of ``rows`` sequences: ``ring`` K and V rows a
@@ -273,8 +305,25 @@ def small_leaf_init(name: str, shape, dtype, key):
     to 1e-1 (Mamba-2's initialisation) make a head forget over a few
     tokens to a few thousand — with a zero bias every head forgets in
     two, and neither a carried state nor its precision could be told
-    from a logit."""
+    from a logit. A linear-attention layer's decay is the same product
+    (``g = -exp(A_log) softplus(x W_a + dt_bias)``) with a data-dependent
+    part: ``W_a`` and ``W_b`` are small enough that the residual stream
+    (whose RMS grows to ~8 over 64 output-normed sublayers) moves a head's
+    step by a factor of a few and its write strength ``beta = 2
+    sigmoid(x W_b)`` over both sides of 1, without saturating either. Of
+    30 heads that is three that remember over 100 tokens, the slowest
+    ~970 (~150 at the deepest layers), and a median of 12: the published
+    initialisation's own spread. Not made slower for the comparison's
+    sake: with EVERY head at 1,000 tokens a bf16 state still reads as
+    float32 does over 3,500 positions on the chip (PERF.md, PR 45), what
+    a long memory integrates being the bf16 activations' rounding."""
     H = shape[-1]
+    if name in ("lin_wa", "lin_wb"):
+        return (jax.random.normal(key, shape, jnp.float32)
+                * (0.25 if name == "lin_wa" else 0.5) * shape[-2] ** -0.5
+                ).astype(dtype)
+    name = {"lin_A_log": "ssm_A_log", "lin_dt_bias": "ssm_dt_bias",
+            "lin_conv_w": "ssm_conv_w"}.get(name, name)
     if name == "ssm_A_log":
         # spread over the heads in another order than the steps are (h ->
         # 37 h mod H, a permutation for the even H of every preset), as
@@ -313,14 +362,18 @@ def _init_patterned(key: jax.Array, cfg: ModelConfig, dtype) -> Params:
 
     def attention(kind: str, n: int) -> Params:
         H = cfg.heads_of(kind)
-        leaves = dict(zip(ATTENTION_LEAVES[kind], (
+        names = ATTENTION_LEAVES[kind]
+        leaves = dict(zip(names, (
             jnp.ones((n, d), dtype),
             dense(next(keys), (n, d, H * hd)),
             dense(next(keys), (n, d, KV * hd)),
             dense(next(keys), (n, d, KV * hd)),
             dense(next(keys), (n, H * hd, d)))))
         if cfg.attn_gate:
-            leaves[ATTENTION_LEAVES[kind][-1]] = dense(next(keys), (n, d, H))
+            leaves[names[5]] = dense(next(keys), (n, d, H))
+        if cfg.qk_norm_whole:
+            leaves[names[6]] = jnp.ones((n, H * hd), dtype)
+            leaves[names[7]] = jnp.ones((n, KV * hd), dtype)
         return leaves
 
     if nA:
@@ -355,6 +408,21 @@ def _init_patterned(key: jax.Array, cfg: ModelConfig, dtype) -> Params:
             layers[name] = small_leaf_init(name, shape, dtype, next(keys))
     if cfg.n_of("S"):
         layers.update(attention("S", cfg.n_of("S")))
+    if nL := cfg.n_of("L"):
+        # W_q | W_k | W_v | W_g fused (the first three under the
+        # convolution), W_o; W_a, W_b, the convolution, the decays and the
+        # step biases are small leaves, bf16 or float32 in an int8 tree too
+        Hl, dv, Cl = cfg.lin_value_heads, cfg.lin_value_dim, cfg.lin_conv_dim
+        layers.update(
+            lin_norm=jnp.ones((nL, d), dtype),
+            lin_in=dense(next(keys), (nL, d, Cl + Hl * dv)),
+            lin_gate_norm=jnp.ones((nL, dv), dtype),
+            lin_out=dense(next(keys), (nL, Hl * dv, d)))
+        for name, shape in (("lin_conv_w", (nL, cfg.lin_conv, Cl)),
+                            ("lin_wa", (nL, d, Hl)), ("lin_wb", (nL, d, Hl)),
+                            ("lin_dt_bias", (nL, Hl)),
+                            ("lin_A_log", (nL, Hl))):
+            layers[name] = small_leaf_init(name, shape, dtype, next(keys))
     if nD := cfg.n_of("D"):
         Fd = cfg.dense_mlp_hidden
         layers.update(
@@ -968,6 +1036,11 @@ def _span_rows(cfg: ModelConfig, kind: str, positions, q_lens, token_mask):
     return jnp.concatenate([pair, zero] if kind == "S" else [zero, pair])
 
 
+def _pad_heads(a, n: int):
+    """``a`` [..., heads, lanes] with ``n`` zero heads behind its own."""
+    return jnp.pad(a, ((0, 0),) * (a.ndim - 2) + ((0, n), (0, 0)))
+
+
 def _ring_write(sk, sv, k, v, positions, write_mask, layer):
     """The window's K/V rows into layer ``layer`` of the sequences' rings,
     position p at row ``p % ring`` of its batch row; masked rows drop."""
@@ -1105,15 +1178,23 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
 
     def out_proj(attn):
         """The attention's [B, S, H, hd] output through ``wo`` onto the
-        residual (packed first where the residual is)."""
+        residual (packed first where the residual is), normed on its way
+        where the block norms a sublayer's output."""
         with jax.named_scope("o_proj"):
+            attn = attn[..., :H, :]         # less the spare heads' zeros
             if win is not None:
                 attn = win.pack(attn)
-            return _shard_residual(
-                mesh, h + qmatmul(attn.reshape(Bh, Sh, H * hd), lp["wo"]))
+            out = qmatmul(attn.reshape(Bh, Sh, H * hd), lp["wo"])
+        if cfg.post_norm:
+            with jax.named_scope("attn_norm"):
+                out = rms_norm(out, lp["attn_norm"], cfg.rms_eps,
+                               cfg.rms_offset)
+        return _shard_residual(mesh, h + out)
 
-    with jax.named_scope("attn_norm"):
-        x = rms_norm(h, lp["attn_norm"], cfg.rms_eps, cfg.rms_offset)
+    x = h
+    if not cfg.post_norm:
+        with jax.named_scope("attn_norm"):
+            x = rms_norm(h, lp["attn_norm"], cfg.rms_eps, cfg.rms_offset)
     if cfg.latent:
         out, layer_ik, counts["lat_rows"] = _latent_attention(
             cfg, attn_impl, x, lp, layer_ik, positions, kv_limit,
@@ -1127,6 +1208,11 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
         if cfg.qk_norm:
             q = rms_norm(q, lp["q_norm"], cfg.rms_eps)
             k = rms_norm(k, lp["k_norm"], cfg.rms_eps)
+        if cfg.qk_norm_whole:
+            # one norm over a token's whole projection, every head's lanes
+            whole = lambda a, w: rms_norm(
+                a.reshape(Bh, Sh, -1), w, cfg.rms_eps).reshape(a.shape)
+            q, k = whole(q, lp["q_norm"]), whole(k, lp["k_norm"])
         if cfg.selects_keys:
             # The indexer reads the same normed hidden state: its
             # queries, its ONE key a token, its per-head weights.
@@ -1159,7 +1245,18 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
         """Each head's output times its gate (float32, rounded once)."""
         if gate is None:
             return attn
+        attn = attn[..., :H, :]
         return (attn.astype(jnp.float32) * gate[..., None]).astype(attn.dtype)
+
+    # A cache row may hold more KV heads than the model has (``ModelConfig.
+    # kv_heads_paged``: 30 are kept as 32): the spare heads' K and V are
+    # written as zeros and their queries run as zeros, whose outputs
+    # ``out_proj`` drops.
+    spare = (layer_k.q if isinstance(layer_k, QuantKV)
+             else layer_k).shape[-2] - KV
+    if spare:
+        q, k, v = (_pad_heads(q, spare * (H // KV)), _pad_heads(k, spare),
+                   _pad_heads(v, spare))
 
     if cfg.slides:
         if block_tables is None:
@@ -1171,6 +1268,11 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
                 "attend to every key")
         counts["span_rows"] = _span_rows(cfg, kind, positions, q_lens,
                                          token_mask)
+    if cfg.has_linear:
+        # the full layers' two words of ``KVCache.lin_rows``
+        counts["lin_rows"] = jnp.concatenate([
+            jnp.zeros((3,), jnp.int32),
+            _span_rows(cfg, kind, positions, q_lens, token_mask)[2:]])
     if kind == "S":
         with jax.named_scope("kv_write"):
             layer_k, layer_v = _ring_write(layer_k, layer_v, k, v, positions,
@@ -1367,8 +1469,9 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
 #: per-head gate's projection, in that order: ``_layer`` reads them under
 #: the full kind's names.
 ATTENTION_LEAVES = {
-    "*": ("attn_norm", "wq", "wk", "wv", "wo", "wg"),
-    "S": ("sw_norm", "sw_wq", "sw_wk", "sw_wv", "sw_wo", "sw_wg")}
+    "*": ("attn_norm", "wq", "wk", "wv", "wo", "wg", "q_norm", "k_norm"),
+    "S": ("sw_norm", "sw_wq", "sw_wk", "sw_wv", "sw_wo", "sw_wg",
+          "sw_q_norm", "sw_k_norm")}
 EXPERT_LAYER_LEAVES = ("router", "router_bias", "w_gate", "w_up", "w_down",
                        "shared_gate", "shared_up", "shared_down")
 
@@ -1452,16 +1555,90 @@ def _ssm_mixer(cfg: ModelConfig, layers: Params, j: int, h, ssm, conv,
     return h + out, ssm, conv
 
 
+def _linear_mixer(cfg: ModelConfig, lp: Params, j, h, lin, lconv,
+                  valid, q_lens, win: Optional[WindowRows] = None):
+    """A linear-attention layer (the gated delta rule, ops/gated_delta.py)
+    of leaves ``lp`` onto the residual, from and to plane ``j`` of the
+    state leaves (a traced scalar inside the scan over periods); returns
+    (h, lin, lconv, int32 [5]: the first three words of
+    ``KVCache.lin_rows``). ``valid`` as for ``_ssm_mixer``: a padded token
+    has ``g = 0`` and ``beta = 0`` and stays out of the convolution's tail.
+    With ``win`` the projections, the gated norm and the output projection
+    run on the window's packed rows, the convolution and the scan on the
+    unpacked [B, S]. Scopes ``lin/*`` hold, like ``ssm/*``, no keyword of
+    the benchmark's trace categories: the mixer is its own device time."""
+    from ..ops import gated_delta
+    from ..ops.ssd_scan import causal_conv
+
+    B, S = valid.shape
+    H, dk, dv = cfg.lin_value_heads, cfg.lin_key_dim, cfg.lin_value_dim
+    C = cfg.lin_conv_dim
+    n_valid = jnp.sum(valid, axis=1, dtype=jnp.int32)
+    with jax.named_scope("lin"):
+        x = h
+        if not cfg.post_norm:
+            with jax.named_scope("norm"):
+                x = rms_norm(h, lp["lin_norm"], cfg.rms_eps, cfg.rms_offset)
+        with jax.named_scope("in_proj"):
+            qkvz = qmatmul(x, lp["lin_in"])
+            qkv, z = qkvz[..., :C], qkvz[..., C:]
+            a = (x @ lp["lin_wa"]).astype(jnp.float32)
+            b = (x @ lp["lin_wb"]).astype(jnp.float32)
+            if win is not None:
+                qkv, a, b = win.unpack(qkv), win.unpack(a), win.unpack(b)
+        with jax.named_scope("conv"):
+            plane = lambda a: jax.lax.dynamic_index_in_dim(a, j, 0, False)
+            qkv, tail = causal_conv(qkv, plane(lconv), lp["lin_conv_w"],
+                                    None, n_valid)
+        with jax.named_scope("scan"):
+            q = gated_delta.l2_normalize(qkv[..., :H * dk].reshape(B, S, H, dk),
+                             dk ** -0.5)
+            k = gated_delta.l2_normalize(
+                qkv[..., H * dk:2 * H * dk].reshape(B, S, H, dk))
+            g = -jnp.exp(lp["lin_A_log"].astype(jnp.float32)) \
+                * jax.nn.softplus(a + lp["lin_dt_bias"])
+            beta = (2.0 if cfg.lin_neg_eigval else 1.0) * jax.nn.sigmoid(b)
+            g = jnp.where(valid[..., None], g, 0.0)
+            beta = jnp.where(valid[..., None], beta, 0.0)
+            o, state = gated_delta.gated_delta_scan(
+                q, k, qkv[..., 2 * H * dk:].reshape(B, S, H, dv), g, beta,
+                plane(lin))
+            lin = jax.lax.dynamic_update_index_in_dim(lin, state, j, 0)
+            lconv = jax.lax.dynamic_update_index_in_dim(lconv, tail, j, 0)
+        with jax.named_scope("gate_norm"):
+            if win is not None:
+                o = win.pack(o)
+            y = gated_delta.gated_head_norm(
+                o, z.reshape(z.shape[:-1] + (H, dv)),
+                lp["lin_gate_norm"], cfg.rms_eps).astype(h.dtype)
+        with jax.named_scope("out_proj"):
+            out = qmatmul(y.reshape(y.shape[:-2] + (H * dv,)), lp["lin_out"])
+        if cfg.post_norm:
+            with jax.named_scope("norm"):
+                out = rms_norm(out, lp["lin_norm"], cfg.rms_eps,
+                               cfg.rms_offset)
+    decode = jnp.logical_and(_query_lens(valid, q_lens) == 1, valid[:, 0])
+    chunks = 0 if S == 1 else B * -(-S // min(gated_delta.CHUNK, S))
+    rows = jnp.stack([
+        jnp.sum(decode, dtype=jnp.int32),
+        jnp.sum(jnp.where(decode, 0, n_valid), dtype=jnp.int32),
+        jnp.asarray(chunks, jnp.int32)])
+    return h + out, lin, lconv, jnp.concatenate(
+        [rows, jnp.zeros((2,), jnp.int32)])
+
+
 def _patterned_layers(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
                       layers: Params, h, cache: KVCache, positions,
                       kv_limit: int, batch_idx, token_mask, write_mask,
                       block_tables, q_lens, win=None):
-    """The layer loop of a patterned configuration, unrolled: layer l runs
-    as the kind ``cfg.layer_kinds[l]`` names, on layer j of that kind's
-    stacks (j its ordinal among its kind) — three kinds cannot share a
-    scan body. ``win``: ``h`` is the window's packed rows, which an
-    expert layer and a dense MLP take as they are and the sequence mixers
-    unpack around their scan or attention. Returns (h, the cache with its
+    """The layer loop of a patterned configuration: layer l runs as the
+    kind ``cfg.layer_kinds[l]`` names, on layer j of that kind's stacks (j
+    its ordinal among its kind) — unrolled, for three kinds cannot share
+    a scan body, but where every kind can take a traced ordinal
+    (``_scan_period``): then one period's mixers are the body of a scan
+    over its repeats, be there one. ``win``: ``h`` is the window's packed
+    rows, which an expert layer and a dense MLP take as they are and the
+    sequence mixers unpack around their scan or attention. Returns (h, the cache with its
     K/V and state leaves as the layers left them, what the passes counted
     by ``KVCache`` field)."""
     B, S = positions.shape
@@ -1474,56 +1651,126 @@ def _patterned_layers(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
     if q_lens is not None:
         valid = jnp.logical_and(valid, jnp.arange(S)[None, :] < q_lens[:, None])
     k, v, ssm, conv = cache.k, cache.v, cache.ssm, cache.conv
-    sk, sv = cache.sk, cache.sv
+    sk, sv, lin, lconv = cache.sk, cache.sv, cache.lin, cache.lconv
     if cfg.has_ssm and ssm is None:
         ssm, conv = state_zeros(cfg, B, h.dtype)
+    if cfg.has_linear and lin is None:
+        lin, lconv = linear_zeros(cfg, B, h.dtype)
     if cfg.slides and sk is None and block_tables is not None:
         # no leaf given (benchmark/refcheck.py): one that holds this call's
         # own window beside the span, in the K pool's pages
         sk, sv = sliding_zeros(cfg, B, cfg.sliding_ring(S, k.shape[-3]),
                                h.dtype)
     step = partial(_layer, cfg, attn_impl, mesh, moe_impl)
-    counts: Dict[str, Any] = {}
 
-    def count(new):
-        for name, n in new.items():
-            counts[name] = n if name not in counts else counts[name] + n
+    def run(kinds, h, st, counts, ordinal):
+        """The mixers ``kinds`` in order. ``ordinal(kind, i)``: which layer
+        of its kind the i-th ``kind`` of ``kinds`` is, in the kind's stacked
+        leaves and in its planes of the cache alike (a traced scalar inside
+        the scan over periods). ``st``: (k, v, ssm, conv, sk, sv, lin,
+        lconv); ``counts`` is added to."""
+        k, v, ssm, conv, sk, sv, lin, lconv = st
+        leaf = lambda name, j: _at(layers[name], j)
 
-    seen = dict.fromkeys(cfg.layer_kinds, 0)
-    for kind in cfg.layer_kinds:
-        j = seen[kind]
-        seen[kind] += 1
-        if kind == "M":
-            h, ssm, conv = _ssm_mixer(cfg, layers, j, h, ssm, conv, valid,
-                                      win)
-        elif kind == "E":
-            h, n = _expert_mixer(
-                cfg, layers, j, h, mesh,
-                token_mask if win is None else win.valid, moe_impl)
-            if n is not None:
-                count({"experts_read": n})
-        elif kind == "D":
-            with jax.named_scope("mlp_norm"):
-                x = rms_norm(h, layers["dense_norm"][j], cfg.rms_eps,
-                             cfg.rms_offset)
-            with jax.named_scope("mlp"):
-                h = h + _dense_mlp(
-                    cfg, {name: _at(layers[name], j) for name in layers
-                          if name.startswith("dense_")}, x, "dense_")
-        else:
-            lp = {name: _at(layers[own], j) for name, own
-                  in zip(ATTENTION_LEAVES["*"], ATTENTION_LEAVES[kind])
-                  if own in layers}
-            args = (positions, kv_limit, batch_idx, token_mask, write_mask,
-                    block_tables, q_lens, jnp.asarray(j, jnp.int32), None,
-                    win, kind)
-            if kind == "S":
-                h, sk, sv, _, n = step(h, lp, sk, sv, *args)
+        def count(new):
+            for name, n in new.items():
+                counts[name] = n if name not in counts else counts[name] + n
+
+        seen = dict.fromkeys(kinds, 0)
+        for kind in kinds:
+            i = seen[kind]
+            seen[kind] += 1
+            j = ordinal(kind, i)
+            if kind == "M":
+                h, ssm, conv = _ssm_mixer(cfg, layers, j, h, ssm, conv, valid,
+                                          win)
+            elif kind == "E":
+                h, n = _expert_mixer(
+                    cfg, layers, j, h, mesh,
+                    token_mask if win is None else win.valid, moe_impl)
+                if n is not None:
+                    count({"experts_read": n})
+            elif kind == "L":
+                h, lin, lconv, n = _linear_mixer(
+                    cfg, {name: leaf(name, j) for name in layers
+                          if name.startswith("lin_")},
+                    j, h, lin, lconv, valid, q_lens, win)
+                count({"lin_rows": n})
+            elif kind == "D":
+                lp = {name: leaf(name, j) for name in layers
+                      if name.startswith("dense_")}
+                norm = lambda a, w=lp["dense_norm"]: rms_norm(
+                    a, w, cfg.rms_eps, cfg.rms_offset)
+                # the norm on the MLP's input, or (``post_norm``) its output
+                with jax.named_scope("mlp_norm"):
+                    x = h if cfg.post_norm else norm(h)
+                with jax.named_scope("mlp"):
+                    y = _dense_mlp(cfg, lp, x, "dense_")
+                    if not cfg.post_norm:
+                        h = h + y
+                if cfg.post_norm:
+                    with jax.named_scope("mlp_norm"):
+                        h = h + norm(y)
             else:
-                h, k, v, _, n = step(h, lp, k, v, *args)
-            count(n)
+                lp = {name: leaf(own, j) for name, own
+                      in zip(ATTENTION_LEAVES["*"], ATTENTION_LEAVES[kind])
+                      if own in layers}
+                args = (positions, kv_limit, batch_idx, token_mask,
+                        write_mask, block_tables, q_lens,
+                        jnp.asarray(j, jnp.int32), None, win, kind)
+                if kind == "S":
+                    h, sk, sv, _, n = step(h, lp, sk, sv, *args)
+                else:
+                    h, k, v, _, n = step(h, lp, k, v, *args)
+                count(n)
+        return h, (k, v, ssm, conv, sk, sv, lin, lconv)
+
+    kinds = cfg.layer_kinds
+    st = (k, v, ssm, conv, sk, sv, lin, lconv)
+    counts: Dict[str, Any] = {}
+    period = _scan_period(kinds)
+    if not period:
+        h, st = run(kinds, h, st, counts, lambda kind, i: i)
+    else:
+        # A pattern of these kinds (LDLDLD*D eight times; once, where the
+        # comparison with the reference cuts the depth) is ONE period's
+        # mixers scanned over its repeats, the stacks and the caches whole
+        # (closed over, and in the carry), a layer addressed in both by its
+        # traced ordinal: each dot reads its own slice of its stack (cut
+        # [repeats, layers a period, ...] as the scan's xs, a period's three
+        # or four layers of a kind were copied out of the stack together,
+        # every weight every pass: AOT, PR 45). A program of 8 mixers where
+        # the unrolled loop's had 64, whose chunk programs took longer to
+        # compile than a cell may take to start.
+        reps = len(kinds) // period
+        per = {kind: kinds[:period].count(kind) for kind in set(kinds)}
+
+        def body(carry, r):
+            h, st = carry
+            got: Dict[str, Any] = {}
+            h, st = run(kinds[:period], h, st, got,
+                        lambda kind, i: r * per[kind] + i)
+            return (h, st), got
+
+        (h, st), per_rep = jax.lax.scan(
+            body, (h, st), jnp.arange(reps, dtype=jnp.int32))
+        counts = {name: jnp.sum(n, axis=0) for name, n in per_rep.items()}
+    k, v, ssm, conv, sk, sv, lin, lconv = st
     return h, dataclasses.replace(cache, k=k, v=v, ssm=ssm, conv=conv,
-                                  sk=sk, sv=sv), counts
+                                  sk=sk, sv=sv, lin=lin, lconv=lconv), counts
+
+
+def _scan_period(kinds: Tuple[str, ...]) -> int:
+    """The length of the shortest pattern that ``kinds`` is repeats of (its
+    own, where nothing shorter repeats: a scan of one step, so that a
+    configuration cut to one period runs the program its full depth does),
+    where every mixer of it can address its layer by a traced index (linear
+    attention, full attention, a dense MLP); 0 = run unrolled."""
+    if set(kinds) - set("L*D"):
+        return 0
+    n = len(kinds)
+    return next(p for p in range(1, n + 1)
+                if n % p == 0 and kinds == kinds[:p] * (n // p))
 
 
 # -------------------------------------------------------------- forward
@@ -1597,6 +1844,15 @@ def forward(
         kv_limit = cache.max_seq
     B, S = tokens.shape
     batch_idx = jnp.arange(B)[:, None]
+    if (block_tables is not None and cache.k is not None
+            and not isinstance(cache.k, QuantKV)
+            and (short := cfg.kv_heads_paged - cache.k.shape[-2]) > 0):
+        # a caller's own pool with ``n_kv_heads`` heads a row (benchmark/
+        # refcheck.py builds one): made the engine's leaf once, here, and
+        # returned so, so that every layer below writes and reads the rows
+        # the server's do, their spare heads zeros (``KVCache.pool_zeros``)
+        cache = dataclasses.replace(cache, k=_pad_heads(cache.k, short),
+                                    v=_pad_heads(cache.v, short))
     new_ik, state = cache.ik, cache
     counted = {name: getattr(cache, name) for name in KVCache.COUNTS}
     if cfg.latent and (cfg.layer_kinds or (mesh is not None
@@ -1604,6 +1860,10 @@ def forward(
         raise NotImplementedError(
             f"{cfg.name} keeps a latent cache: served as a uniform block on "
             "one device (parallel/sharding.py has no rule for its leaves)")
+    if cfg.post_norm and not cfg.layer_kinds:
+        raise NotImplementedError(
+            f"{cfg.name}: a block that norms its sublayers' outputs "
+            "(post_norm) is written as its mixers (layer_pattern)")
     if cfg.layer_kinds and mesh is not None and mesh.size > 1:
         raise NotImplementedError(
             f"{cfg.name} runs one mixer a layer (layer_pattern) and is "

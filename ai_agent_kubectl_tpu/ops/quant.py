@@ -334,7 +334,10 @@ _QUANT_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
                # the sliding-attention kind's projections and the dense
                # MLP mixer's (a configuration with attention of two kinds)
                "sw_wq", "sw_wk", "sw_wv", "sw_wo",
-               "dense_gate", "dense_up", "dense_down")
+               "dense_gate", "dense_up", "dense_down",
+               # a linear-attention layer's fused in-projection and its
+               # output projection (W_a, W_b stay bf16: small leaves)
+               "lin_in", "lin_out")
 
 
 #: The per-head q/k RMSNorm gains this SEEDED generator writes (bench/dev

@@ -48,13 +48,14 @@ def causal_conv(xbc, tail, w, b, q_lens):
 
     xbc [B, S, C]; tail [B, K-1, C]: the K-1 inputs before the window
     (zeros at a sequence's start); w [K, C] (w[K-1] multiplies the
-    current input); b [C]; q_lens [B]: a row's valid columns.
+    current input); b [C], or None for no bias; q_lens [B]: a row's valid
+    columns.
     Returns (y [B, S, C], the new tail: the last K-1 inputs up to each
     row's q_len — the old tail where q_len is 0)."""
     K = w.shape[0]
     S = xbc.shape[1]
     full = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)
-    acc = b.astype(jnp.float32)[None, None, :]
+    acc = 0.0 if b is None else b.astype(jnp.float32)[None, None, :]
     for k in range(K):
         acc = acc + (full[:, k:k + S].astype(jnp.float32)
                      * w[k].astype(jnp.float32)[None, None, :])

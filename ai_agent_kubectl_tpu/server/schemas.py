@@ -138,6 +138,12 @@ class HealthResponse(BaseModel):
     # layer of each kind. None for a model whose attention layers are of
     # one kind.
     sliding_attention: Optional[Dict[str, Any]] = None
+    # Linear attention by the gated delta rule (ISSUE 45;
+    # ``_linear_section``): linear and full layers, the state's bytes a
+    # sequence, decode rows and window rows the linear layers ran and the
+    # chunks their scans ran over, decode queries and keys read in the
+    # full layers (counted on the device). None for every other model.
+    linear_attention: Optional[Dict[str, Any]] = None
     # Recurrent-state cache of a model with state-space layers (ISSUE 33;
     # engine/kv_pool.py::StateStore.stats, ``_state_section``):
     # snapshots held / capacity / bytes and their peak, snapshots taken /

@@ -64,7 +64,7 @@ _LONG_FILES = ("test_sparse_attention.py", "test_tpu_aot.py",
                "test_reference_logits_sliding.py", "test_sliding_attention.py",
                "test_latent_attention.py", "test_window_staging.py",
                "test_packed_window.py", "test_state_cache.py",
-               "test_hybrid_model.py")
+               "test_hybrid_model.py", "test_linear_attention.py")
 
 
 def pytest_configure(config):

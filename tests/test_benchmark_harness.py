@@ -334,6 +334,80 @@ def case_the_keye_configuration_is_its_source_cut_in_depth_alone():
     assert min(chk["prompt_tokens"]) > cfg["topk"] and chk["window"] >= max(chk["prompt_tokens"])
 
 
+#: https://huggingface.co/allenai/Olmo-Hybrid-7B/blob/main/config.json as the
+#: catalog beside the model-configs guide has it: every number of it.
+OLMO_HYBRID_SOURCE = {
+    "vocab_size": 100352, "hidden_size": 3840, "intermediate_size": 11008,
+    "num_hidden_layers": 32, "num_attention_heads": 30, "num_key_value_heads": 30,
+    "max_position_embeddings": 65536, "rms_norm_eps": 1e-06, "linear_num_key_heads": 30,
+    "linear_num_value_heads": 30, "linear_key_head_dim": 96, "linear_value_head_dim": 192,
+    "linear_conv_kernel_dim": 4}
+
+
+def case_the_olmo_hybrid_configuration_is_its_source_whole():
+    """olmo-hybrid-7b (PR 45): every number of the source under its own key,
+    NOTHING reduced, the per-layer list copied whole and said again flat as the
+    two-mixers-a-layer pattern, every mapped key reaching the ModelConfig, the
+    bytes the file states the bytes the ops/bytes functions count, the cell
+    found by its name."""
+    import opsbytes_linear
+    import serve
+
+    cfg = shipped("olmo-hybrid-7b")
+    assert {k for k, v in OLMO_HYBRID_SOURCE.items() if cfg.get(k) != v} == set()
+    assert cfg["reduced"] == {} and cfg["model_type"] == "olmo_hybrid"
+    assert cfg["hidden_act"] == "silu" and cfg["linear_allow_neg_eigval"] is True
+    assert cfg["attention_bias"] is False and cfg["tie_word_embeddings"] is False
+    assert cfg["rope_parameters"] == {"rope_theta": None}
+    assert cfg["layer_types"] == (["linear_attention"] * 3 + ["full_attention"]) * 8
+    mixers = "".join({"linear_attention": "LD", "full_attention": "*D"}[t]
+                     for t in cfg["layer_types"])
+    assert cfg["layer_mixers"] == mixers and cfg["mixers_per_layer"] == 2
+    model, sz = serve.register(cfg)
+    assert (model.n_layers, model.dim, model.n_heads, model.n_kv_heads, model.head_dim) == (
+        32, 3840, 30, 30, 128)
+    assert (model.dense_mlp_hidden, model.mlp_hidden, model.vocab_size) == (11008, 0, 100352)
+    assert (model.lin_key_heads, model.lin_value_heads, model.lin_key_dim,
+            model.lin_value_dim, model.lin_conv, model.lin_neg_eigval) == (30, 30, 96, 192, 4, True)
+    assert model.post_norm and model.qk_norm_whole and not model.use_rope
+    assert model.has_linear and model.keeps_state and not model.has_ssm and not model.is_moe
+    assert (model.n_of("L"), model.n_of("*"), model.n_of("D")) == (24, 8, 32)
+    assert model.kv_heads_paged == 32           # a pool row: whole tiles of 8 heads
+    assert sz["linear_key_head_dim"] == 96 and sz["layer_mixers"] == mixers   # the reference's
+    f = modelmap.fields(sz, modelmap.key_map(cfg))
+    sizing, env = cfg["sizing"], cfg["server_env"]
+    assert round(opsbytes_linear.whole_model_bytes(f) / 1e9, 2) == sizing["weights_GB"] == 7.43
+    assert round(model.param_count() / 1e9, 2) == 7.43
+    assert model.state_bytes() == sizing["state_bytes_per_sequence"] == 54743040
+    assert opsbytes_linear.kv_bytes_per_token(f) == sizing["kv_bytes_per_token"] == 122880
+    assert int(env["KV_POOL_BLOCKS"]) * int(env["KV_POOL_PAGE"]) == sizing["pool_tokens"]
+    # the pool's rows hold 32 heads for the model's 30
+    assert round(sizing["pool_tokens"] * 122880 * 32 / 30 / 1e9, 2) == sizing["pool_GB"]
+    assert round(int(env["STATE_SNAPSHOTS"]) * 54743040 / 1e9, 2) == sizing["snapshot_store_GB"]
+    assert round(int(env["DECODE_BATCH_SIZE"]) * 54743040 / 1e9, 2) == sizing["live_states_GB"]
+    assert modelmap.mesh_problems(cfg, 1) == [] and modelmap.mesh_of(cfg) == {}
+    bench = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
+    cell, entry, file, mix = R.resolve_cell(bench, "olmohybrid7b-agent-sessions")
+    assert (cell["chips"], entry["reduced"], file) == (1, [], cfg)
+    assert entry["source"] == cfg["source"] == (
+        "https://huggingface.co/allenai/Olmo-Hybrid-7B/blob/main/config.json")
+    assert (mix["name"], mix["clients"], mix["preamble_tokens"]) == ("agent-sessions", 8, 2048)
+    longest = mix["preamble_tokens"] + sum(mix["turn_added_tokens"]) + int(env["MAX_NEW_TOKENS"])
+    assert longest < int(env["MAX_SEQ_LEN"]) == 4096
+    assert R.child_env(cfg, 1, True)["MODEL_NAME"] == "toy-linear-hybrid"
+    ref = refcheck_module().load_reference(cfg["reference"])
+    assert callable(ref.forward) and callable(ref.weights_from_program)
+    # a sequence's first two tokens are held as a group, every other one alone
+    chk = cfg["reference_check"]
+    assert chk["clear_if"] == {"aux": "position", "min": 2} and chk["layers"] == 8
+    share = 2 * chk["batch"] / (sum(chk["prompt_tokens"]) + 3 * chk["batch"])
+    assert share < chk["unclear_share_max"] <= 0.01
+    # a program without the fields ends at once, by name (what the parent does)
+    lacking = dict(cfg, keys=dict(cfg["keys"], linear_key_head_dim="no_such_field"))
+    with pytest.raises(SystemExit, match="linear_key_head_dim maps to ModelConfig.no_such_field"):
+        modelmap.model_config("x", modelmap.sizes(lacking), modelmap.key_map(lacking))
+
+
 def refcheck_module():
     import refcheck
 

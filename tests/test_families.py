@@ -30,7 +30,7 @@ REPO = Path(__file__).resolve().parent.parent
 #: a toy of each kind; plain GQA is of none
 TOYS = {"gqa": "toy-8m", "selecting": "toy-sparse-moe",
         "recurrent": "toy-hybrid-moe", "sliding": "toy-sliding-moe",
-        "latent": "toy-mla-moe"}
+        "latent": "toy-mla-moe", "linear": "toy-linear-hybrid"}
 
 # ------------------------------------------------ what a kind cannot ride
 
@@ -45,6 +45,8 @@ _RECURS = ("toy-hybrid-moe keeps a recurrent state (layer_pattern 'ME*ME*') and 
            "served here: ")
 _SLIDES = ("toy-sliding-moe keeps a sliding-attention state (layer_pattern "
            "'*DSE*ESESE*E') and is not served here: ")
+_LINEAR = ("toy-linear-hybrid keeps a linear-attention state (layer_pattern "
+           "'LDLDLD*DLDLDLD*D') and is not served here: ")
 _LATENT = "toy-mla-moe keeps a latent cache (kv_lora_rank=32) and is not served here: "
 _NO_STATE = ("the dense per-slot KV ladder keeps no bounded state a sequence, recurrent "
              "or sliding, and would attend a sliding layer to every key (KV_POOL=false, "
@@ -73,6 +75,10 @@ REFUSALS = {
         "KV_QUANT=int8: the sliding layers' rings are bf16 rows beside the pool"),
     ("sliding", "mesh"): _SLIDES + _NO_RULE,
     ("sliding", "spec"): _SLIDES + _NO_REWIND,
+    ("linear", "dense"): _LINEAR + _NO_STATE,
+    ("linear", "kv_quant"): None,
+    ("linear", "mesh"): _LINEAR + _NO_RULE,
+    ("linear", "spec"): _LINEAR + _NO_REWIND,
     ("latent", "dense"): _LATENT + (
         "the dense per-slot KV ladder has no latent leaf (KV_POOL=false, or a mesh axis "
         "the pool refuses)"),
@@ -101,6 +107,7 @@ def test_a_kind_tests_its_obstacles_in_its_own_order():
     assert order == {"experts": (), "selecting": ("dense", "kv_quant", "mesh"),
                      "recurrent": ("dense", "mesh", "spec"),
                      "sliding": ("dense", "mesh", "spec", "kv_quant"),
+                     "linear": ("dense", "mesh", "spec"),
                      "latent": ("dense", "kv_quant", "mesh", "spec")}
     assert all(set(k.refuses) <= set(OBSTACLES) for k in CACHE_KINDS)
     both = ("ragged", {"model": 2}, "int8", False)
@@ -138,12 +145,18 @@ POOLS = {
                       ".lengths": ((12,), _I32), ".experts_read": ((), _I32),
                       ".sk": ((3, 3, 96, 2, 32), _BF16), ".sv": ((3, 3, 96, 2, 32), _BF16),
                       ".span_rows": ((4,), _I32)},
+    ("linear", ""): {".k": ((2, 12, 16, 4, 32), _BF16), ".v": ((2, 12, 16, 4, 32), _BF16),
+                     ".lengths": ((12,), _I32),
+                     ".lin": ((6, 3, 24, 160), "float32"),
+                     ".lconv": ((6, 3, 3, 352), _BF16), ".lin_rows": ((5,), _I32)},
 }
 #: a snapshot store's leaves, 5 rows
 SNAPSHOTS = {"recurrent": {"ssm": ((2, 5, 8, 16, 32), "float32"),
                            "conv": ((2, 5, 3, 256), _BF16)},
              "sliding": {"sk": ((3, 5, 24, 2, 32), _BF16),
-                         "sv": ((3, 5, 24, 2, 32), _BF16)}}
+                         "sv": ((3, 5, 24, 2, 32), _BF16)},
+             "linear": {"lin": ((6, 5, 24, 160), "float32"),
+                        "lconv": ((6, 5, 3, 352), _BF16)}}
 
 
 def _leaves(tree):
@@ -165,7 +178,8 @@ def test_the_pool_cache_is_built_beside_the_model(kind, kv_quant):
                                           dtype=jnp.bfloat16)
         assert {n: (a.shape, str(a.dtype)) for n, a in snap.items()} == SNAPSHOTS[kind]
     # the count lane of the packed chunk is as wide as the kind's leaf
-    words = {"gqa": 0, "selecting": 2, "recurrent": 0, "sliding": 4, "latent": 2}
+    words = {"gqa": 0, "selecting": 2, "recurrent": 0, "sliding": 4, "latent": 2,
+             "linear": 5}
     assert attention_words(cfg) == words[kind]
     assert long_prompts(cfg) == (kind != "gqa")
 
@@ -192,6 +206,10 @@ HEALTH = {
         "heads_sliding", "heads_full", "decode_rows_sliding", "sliding_keys_read",
         "decode_rows_full", "full_keys_read", "window_rows", "window_pairs_sliding",
         "window_pairs_full", "forward_passes"]),
+    "linear_attention": ("linear", [
+        "layers_linear", "layers_full", "state_bytes_per_sequence", "decode_rows_linear",
+        "window_rows_linear", "chunks_scanned", "decode_rows_full", "full_keys_read",
+        "forward_passes"]),
     "ssm": ("recurrent", _SSM),
 }
 
@@ -244,7 +262,15 @@ def test_the_sections_count_what_the_scheduler_counted():
     # the sliding state rides the same store
     assert sl["ssm"]["eager_prefill_passes"] == 1 and sl["ssm"]["live_rows"] == 3
     assert _sections("recurrent")["ssm"]["layer_passes"] == {
-        "ssm": 10, "experts": 10, "attention": 10, "sliding": 0, "dense_mlp": 0}
+        "ssm": 10, "experts": 10, "attention": 10, "sliding": 0, "dense_mlp": 0,
+        "linear": 0}
+    # the linear layers' state rides the same store; their five words
+    lin = _sections("linear")
+    assert [lin["linear_attention"][k] for k in (
+        "decode_rows_linear", "window_rows_linear", "chunks_scanned", "decode_rows_full",
+        "full_keys_read")] == [10, 11, 12, 13, 14]
+    assert lin["ssm"]["layer_passes"]["linear"] == 5 * 6
+    assert lin["ssm"]["state_bytes"] == get_config("toy-linear-hybrid").state_bytes()
 
 
 def _health_paths():
@@ -306,7 +332,8 @@ def test_the_selector_says_what_it_resolved_at_start():
 # ------------------------------------------------------------------ the seam
 
 _NAMES_A_FAMILY = re.compile(
-    r"\.latent\b|\.slides\b|\.selects_keys|has_ssm|index_topk|kv_lora_rank")
+    r"\.latent\b|\.slides\b|\.selects_keys|has_ssm|has_linear|index_topk|kv_lora_rank"
+    r"|lin_rows|linear_attention")
 _GONE = ("state_refusal", "latent_refusal", "selection_refusal", "attention_words",
          "_attention_rows", "_state_leaves_zeros", "moe_health", "ssm_health",
          "sliding_attention_health", "latent_attention_health",
@@ -314,7 +341,8 @@ _GONE = ("state_refusal", "latent_refusal", "selection_refusal", "attention_word
 
 
 @pytest.mark.parametrize("module", ["engine/batcher.py", "engine/fake.py",
-                                    "engine/protocol.py", "server/app.py"])
+                                    "engine/regime.py", "engine/protocol.py",
+                                    "server/app.py"])
 def test_the_scheduler_the_wire_and_the_http_layer_name_no_family(module):
     """The next family adds a row to models/families.py and nothing here
     (30 such lines in batcher.py before ISSUE 44)."""

@@ -367,15 +367,18 @@ async def test_preempt_and_replay_of_a_sequence_with_state(from_token_zero):
         await eng.stop()
 
 
-async def test_forced_run_splice_never_feeds_the_state_a_token_twice():
+@pytest.mark.parametrize("model", ["toy-hybrid-moe", "toy-linear-hybrid"])
+async def test_forced_run_splice_never_feeds_the_state_a_token_twice(model):
     """Grammar fast-forward on against off, with chunks in flight: K/V rows are
     written by position, so a forced run spliced over chunks the device has
     already run only rewrites them; a recurrent state would take the run's
-    tokens a second time. A state-keeping model splices only while none of the
+    tokens a second time (a Mamba-2 state and the gated delta rule's matrix
+    alike). A state-keeping model splices only while none of the
     slot's decode chunks is in flight (the masked chunks force the same tokens),
     and the transcripts are the same byte for byte."""
-    on = _mk(grammar_decode=True, grammar_forced_run_min=1, chunk_len=1)
-    off = _mk(grammar_decode=True, grammar_forced_run_min=10 ** 6, chunk_len=1)
+    on = _mk(model=model, grammar_decode=True, grammar_forced_run_min=1, chunk_len=1)
+    off = _mk(model=model, grammar_decode=True, grammar_forced_run_min=10 ** 6,
+              chunk_len=1)
     seen = []
     splice = on._grammar_fast_forward
 
@@ -418,3 +421,28 @@ async def test_family_is_refused_where_it_cannot_be_served():
         assert plain.stats()["ssm"] is None and plain._state is None
     finally:
         await plain.stop()
+
+
+def test_a_live_slot_pins_the_deepest_two_snapshots_of_its_path():
+    """ISSUE 45 (ROADMAP R8 c): a session's fourth turn descends from six
+    snapshots; it pins the one it was seated from and the one before it, and
+    the earlier turns' are the LRU's to evict while it is still live."""
+    pool, store, radix = world(capacity=8, slots=2)
+    ids = list(range(100, 110))
+    for turn in range(3):
+        blocks, _ = admit(pool, store, radix, 0, ids)
+        finish(pool, store, radix, 0, ids, blocks)
+        ids = ids + list(range(1000 * (turn + 1), 1000 * (turn + 1) + 9))
+    assert store.held == 6
+    live, m = admit(pool, store, radix, 0, ids)
+    mr_path = sorted(store._edge[h] for h in store._node)
+    assert m == 24 and mr_path == [4, 8, 12, 16, 20, 24]
+    pinned = sorted(store._edge[h] for h in store._pins)
+    assert pinned == [20, 24, 32, 36]           # two of the path, the two it took
+    # another sequence's snapshots evict the session's EARLIER turns', not those
+    other = list(range(500, 514))
+    blocks, _ = admit(pool, store, radix, 1, other)
+    assert store.snapshots_evicted == 2 and store.snapshots_skipped == 0
+    assert sorted(store._edge[h] for h in store._node if store._edge[h] < 20) == [12, 16]
+    finish(pool, store, radix, 1, other, blocks)
+    finish(pool, store, radix, 0, ids, live)

@@ -353,19 +353,22 @@ def _grammar_chunk_hlo(mesh, rep) -> str:
                                                  **MISTRAL)).as_text()
 
 
-def _chunk_program(mesh, rep, cfg, W=64, n_blocks=320, pages=64):
+def _chunk_program(mesh, rep, cfg, W=64, n_blocks=320, pages=64, B=16,
+                   engine_cache=False):
     """The engine's ragged chunk program for a ``W``-wide window, grammar
     on, compiled: its prologue's model call is the engine's
     (``batcher.py::ragged_forward_step_fn``: the window's valid rows packed
     into W + batch). ``W=0``: the plain program, 16 decode steps and no
-    prologue, which is what three chunks of four run."""
+    prologue, which is what three chunks of four run. ``engine_cache``:
+    the cache is the pool engine's for ``cfg`` (its state and count leaves
+    with it), not K and V alone."""
     from ai_agent_kubectl_tpu.engine.batcher import make_termination_chunk_fn
     from ai_agent_kubectl_tpu.parallel.sharding import (param_shardings,
                                                         pool_cache_specs,
                                                         sanitize_spec)
     from jax.sharding import NamedSharding
 
-    B, page = 16, 64
+    page = 64
     n_prof, s_max, n_classes = 6, 848, 455
 
     def arg(shape, dtype, sharding=rep):
@@ -388,6 +391,8 @@ def _chunk_program(mesh, rep, cfg, W=64, n_blocks=320, pages=64):
     cache = KVCache(k=arg(pool, jnp.bfloat16, heads),
                     v=arg(pool, jnp.bfloat16, heads),
                     lengths=arg((n_blocks,), jnp.int32))
+    if engine_cache:
+        cache = _engine_cache(arg, cfg, n_blocks, page, B)
     common = dict(kv_limit=pages * page, attn_impl="ragged", mesh=mesh,
                   page_size=page)
 
@@ -946,3 +951,119 @@ def test_sliding_forward_compiles_at_published_widths_on_v5e(one_chip, B, W,
     assert mem.alias_size_in_bytes >= 2 * 2 * (math.prod(pool)
                                                + math.prod(sk.shape))
     assert mem.temp_size_in_bytes < 2 ** 29, mem.temp_size_in_bytes
+
+
+# ------ linear attention by the gated delta rule beside full (ISSUE 45)
+
+OLMO = dict(vocab_size=100352, dim=3840, n_heads=30, n_kv_heads=30,
+            head_dim=128, dense_mlp_hidden=11008, eos_ids=(2,),
+            mixers_per_layer=2, layer_pattern="LDLDLD*D" * 8,
+            lin_key_heads=30, lin_value_heads=30, lin_key_dim=96,
+            lin_value_dim=192, lin_conv=4, lin_neg_eigval=True,
+            post_norm=True, qk_norm_whole=True, use_rope=False)
+
+
+@pytest.mark.parametrize("B,W,packed", [(8, 1, None), (8, 512, 520),
+                                        (1, 512, None)],
+                         ids=["decode", "window-512", "eager-512"])
+def test_linear_forward_compiles_at_published_widths_on_v5e(one_chip, B, W,
+                                                            packed,
+                                                            monkeypatch):
+    """olmo-hybrid-7b's first two periods (each three linear layers, one
+    full, four dense MLPs: the pattern repeats, so one period's mixers are
+    the body of a scan over the two; every width as published, the cell's
+    64-page table over its 448-block pool): Mosaic accepts the ragged kernel at MHA's 30 KV heads
+    with ONE query head each because a pool row holds 32 (it refused to
+    slice 30 of the tile's 32: ``ModelConfig.kv_heads_paged``); the matrix
+    state [6, B, 96, 30 x 192] rides the donated cache (the scan's carry)
+    in whole lane tiles and is written in place a layer, at a traced plane,
+    never copied or turned over whole; the
+    chunked scan's triangular solve and the single step compile."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = ModelConfig(name="aot-olmo", n_layers=8, **OLMO)
+    page, n_blocks, pages = 64, 448, 64
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda x: arg(x.shape, x.dtype),
+        jax.eval_shape(lambda k: random_params_int8(
+            k, cfg, dtype=jnp.bfloat16, quantize_embed=True),
+            jax.random.PRNGKey(0)))
+    cache = _engine_cache(arg, cfg, n_blocks, page, B)
+    pool, lin = cache.k.shape, cache.lin.shape
+    assert pool == (2, n_blocks, page, 32, 128) and lin == (6, B, 96, 5760)
+    assert cache.lconv.shape == (6, B, 3, 11520) and cache.lin_rows.shape == (5,)
+
+    def step(params, tok, pos, cache, wmask, tables, q_lens):
+        return forward(params, cfg, tok, pos, cache, kv_limit=pages * page,
+                       attn_impl="ragged", token_mask=wmask,
+                       write_mask=wmask, block_tables=tables, q_lens=q_lens,
+                       logits_at=jnp.maximum(q_lens, 1) - 1,
+                       packed_rows=packed)
+
+    traced = jax.jit(step, donate_argnums=(3,)).trace(
+        params, arg((B, W), jnp.int32), arg((B, W), jnp.int32), cache,
+        arg((B, W), jnp.bool_), arg((B, pages), jnp.int32),
+        arg((B,), jnp.int32))
+    # one attention kernel, its K ring's rows 32 heads of 128 lanes
+    ring, = set(_ragged_kv_buffers(traced.jaxpr.jaxpr))
+    assert ring[-2:] == (32, 128), ring
+    compiled = traced.lower().compile()
+    hlo = compiled.as_text()
+    # one kernel in the program: the period's one full layer, in the scan
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    assert " while(" in hlo
+    carried = {"scatter", "fusion", "while", "parameter", "tuple",
+               "get-tuple-element", "bitcast"}
+    moved = {op for op, _ in _results_of_size(hlo, {math.prod(pool)})}
+    assert moved <= carried, moved
+    # the whole state leaf: carried, and written in place (a dynamic-
+    # update-slice a layer, each in its fusion); this six-layer leaf is
+    # small enough that the compiler may prefetch it whole (an async
+    # copy-start), which 24 layers' it does not: the whole model's decode
+    # program has 1.9 MB of temporaries (AOT, PR 45)
+    state_ops = {op for op, _ in _results_of_size(hlo, {math.prod(lin)})}
+    assert "dynamic-update-slice" in state_ops, state_ops
+    assert state_ops <= carried | {"dynamic-update-slice", "copy-start",
+                                   "copy-done"}, state_ops
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= (2 * math.prod(pool) * 2
+                                       + math.prod(lin) * 4)
+    # a 512-wide window's convolution and scan over 8 slots' columns, every
+    # chunk's solve at once (1.07 GiB here, 1.87 for the whole model)
+    assert mem.temp_size_in_bytes < (1.5 * 2 ** 30 if B * W > 512
+                                     else 2 ** 28), mem.temp_size_in_bytes
+
+
+def test_linear_chunk_program_and_copy_on_write_compile_on_v5e(one_chip,
+                                                               monkeypatch):
+    """The engine's own programs at olmo-hybrid-7b's widths (two periods,
+    batch 8, the cell's 448 blocks): the 512-wide ragged chunk program with
+    the grammar on, its cache the engine's (state and count leaves carried
+    through the decode loop), and ``jit_cow`` over the 32-head pool."""
+    import types
+
+    from ai_agent_kubectl_tpu.engine.batcher import BatchedJaxEngine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = ModelConfig(name="aot-olmo", n_layers=8, **OLMO)
+    compiled = _chunk_program(None, one_chip, cfg, 512, 448, 64, B=8,
+                              engine_cache=True)
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1.5 * 2 ** 30, mem.temp_size_in_bytes
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    whole = ModelConfig(name="aot-olmo-whole", n_layers=32, **OLMO)
+    cache = _engine_cache(arg, whole, 448, 64, 8)
+    assert cache.k.shape == (8, 448, 64, 32, 128)
+    cow = BatchedJaxEngine._pool_cow_fn.fget(
+        types.SimpleNamespace(kv_pool_page=64, mesh=None))
+    scalar = arg((), jnp.int32)
+    mem = cow.lower(cache, scalar, scalar, scalar).compile().memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * math.prod(cache.k.shape) * 2
+    assert mem.temp_size_in_bytes < 2 ** 24

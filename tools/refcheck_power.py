@@ -12,8 +12,9 @@ shipped pair and for two pairs that must read ``ok: false``:
   context — the selector left out of the mathematics;
 - ``w8a8``: the program with 8-bit activations (ops/quant.py::to_w8a8)
   against the shipped reference — the nearest precision below bf16;
-- ``bf16_state`` (a configuration with state-space layers): the program with
-  its recurrent state carried in bf16 (ops/ssd_scan.py::STATE_DTYPE) instead
+- ``bf16_state`` (a configuration with state-space or linear-attention
+  layers): the program with its recurrent state carried in bf16 (ops/
+  ssd_scan.py::STATE_DTYPE, ops/gated_delta.py::STATE_DTYPE) instead
   of float32, and rounded at every token as decoding rounds it (the scan's
   chunk set to 1: inside a chunk the state is never formed, so at the
   served chunk of 128 a 900-token prompt would round it 7 times where 900
@@ -68,7 +69,7 @@ def variants_of(cfg_file: dict) -> list:
     out = ["shipped", "w8a8"]
     if "topk" in cfg_file:
         out.insert(1, "every_key")
-    if "ssm_state_size" in cfg_file:
+    if "ssm_state_size" in cfg_file or "linear_key_head_dim" in cfg_file:
         out.append("bf16_state")
     return out
 
@@ -119,7 +120,7 @@ def run_continued(refcheck, cfg_file: dict, sz: dict, seed: int, more: list,
     toks = np.random.default_rng(seed).integers(
         3, min(cfg.vocab_size, 1337), size=(B, max(totals) + steps), dtype=np.int32)
     pages = -(-(max(totals) + steps) // PAGE)
-    pool = (cfg.n_layers, B * pages, PAGE, cfg.n_kv_heads, cfg.head_dim)
+    pool = (cfg.n_layers, B * pages, PAGE, cfg.kv_heads_paged, cfg.head_dim)
     cache = transformer.KVCache(k=jnp.zeros(pool, jnp.bfloat16), v=jnp.zeros(pool, jnp.bfloat16),
                                 lengths=jnp.zeros((B * pages,), jnp.int32))
     tables = jnp.arange(B * pages, dtype=jnp.int32).reshape(B, pages)
@@ -147,7 +148,7 @@ def run_continued(refcheck, cfg_file: dict, sz: dict, seed: int, more: list,
     weights_of = refcheck.weights_function(ref)
     ref_forward = jax.jit(lambda p, t: ref.forward(sz, weights_of(p, cfg.n_layers), t))
     rule = chk.get("clear_if")
-    clear_errs, unclear_errs, stds, second, where = [], [], [], [], []
+    clear_errs, unclear_errs, stds, second, where, worst = [], [], [], [], [], []
     for b in range(B):
         n = totals[b] + steps
         want, aux = ref_forward(params, jnp.asarray(toks[b]))
@@ -157,25 +158,32 @@ def run_continued(refcheck, cfg_file: dict, sz: dict, seed: int, more: list,
         clear_errs.append(err[clear])
         unclear_errs.append(err[~clear])
         second.append(err[lens[b]:])                # the later windows and the decode steps
-        where.append(np.arange(n)[~clear])
+        # (without a rule every position is clear: the medians by position
+        # are then over all of them)
+        where.append(np.arange(n)[~clear] if rule else np.arange(n))
         stds.append(float(want.std()))
+        worst += [(float(err[i]), b, int(i)) for i in np.argsort(err)[-3:]]
     std = float(np.mean(stds))
     clear_errs, unclear_errs = np.concatenate(clear_errs), np.concatenate(unclear_errs)
     rel = float(clear_errs.max()) / std if clear_errs.size else float("nan")
     rel_unclear = float(np.median(unclear_errs)) / std if unclear_errs.size else None
     second, where = np.concatenate(second), np.concatenate(where)
+    group = unclear_errs if rule else clear_errs
     edges = [0, 256, 1024, 2048, 4096, 8192, 1 << 30]
-    by_position = {f"{lo}-{hi if hi < 1 << 30 else ''}": float(np.median(unclear_errs[sel])) / std
+    by_position = {f"{lo}-{hi if hi < 1 << 30 else ''}": float(np.median(group[sel])) / std
                    for lo, hi in zip(edges, edges[1:])
                    if (sel := (where >= lo) & (where < hi)).any()}
     ok = bool((not clear_errs.size or rel <= chk["tolerance_rel"])
               and (rel_unclear is None or rel_unclear <= chk["tolerance_rel"]))
     return {"ok": ok, "rel_err": rel, "rel_err_unclear_median": rel_unclear,
+            "rel_err_median": float(np.median(clear_errs)) / std if clear_errs.size else None,
             "rel_err_second_window_median": float(np.median(second)) / std,
             "rel_err_second_window_p99": float(np.percentile(second, 99)) / std,
             "rel_err_unclear_median_by_position": by_position,
             "tolerance_rel": chk["tolerance_rel"], "decode_steps": steps,
             "windows": schedule if len(schedule) <= 2 else [len(schedule), W, totals],
+            # where the largest errors are: [share of the deviation, row, position]
+            "worst_positions": [[round(e / std, 3), b, i] for e, b, i in sorted(worst)[-5:]],
             "positions_clear": int(clear_errs.size), "positions_unclear": int(unclear_errs.size),
             "layers": cfg.n_layers, "platform": jax.devices()[0].platform}
 
@@ -194,13 +202,15 @@ def main() -> None:
     ap.add_argument("--long-window", type=int, default=512)
     ap.add_argument("--long-only", action="store_true",
                     help="skip refcheck.run's own comparison")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="compare at this depth, not reference_check.layers")
     ap.add_argument("--out", default=str(ROOT / "chiprun_out" / "refcheck_power.jsonl"))
     args = ap.parse_args()
 
     import refcheck
     import jax.numpy as jnp
     from ai_agent_kubectl_tpu.models import transformer
-    from ai_agent_kubectl_tpu.ops import ssd_scan
+    from ai_agent_kubectl_tpu.ops import gated_delta, ssd_scan
     from ai_agent_kubectl_tpu.ops.quant import to_w8a8
     from modelmap import fold_seed, sizes
 
@@ -215,9 +225,11 @@ def main() -> None:
             jax.config.update("jax_compilation_cache_dir", DEFAULT_COMPILE_CACHE_DIR)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.2)
     cfg_file = json.loads(Path(args.config).read_text())
+    if args.layers:
+        cfg_file["reference_check"]["layers"] = args.layers
     only = args.only or variants_of(cfg_file)
     load_reference, forward = refcheck.load_reference, transformer.forward
-    state_dtype = ssd_scan.STATE_DTYPE
+    state_dtype, lin_chunk = ssd_scan.STATE_DTYPE, gated_delta.CHUNK
 
     def every_key_reference(path):
         ref = load_reference(path)
@@ -234,9 +246,14 @@ def main() -> None:
             for what in only:
                 refcheck.load_reference = every_key_reference if what == "every_key" else load_reference
                 transformer.forward = w8a8_forward if what == "w8a8" else forward
-                ssd_scan.STATE_DTYPE = jnp.bfloat16 if what.startswith("bf16_state") else state_dtype
+                # the linear layers' scan in chunks of ONE token: the state is
+                # formed, and rounded, at every token (the state-space layers'
+                # chunk is a key of the file, set below)
+                gated_delta.CHUNK = 1 if what == "bf16_state" else lin_chunk
+                ssd_scan.STATE_DTYPE = gated_delta.STATE_DTYPE = (
+                    jnp.bfloat16 if what.startswith("bf16_state") else state_dtype)
                 sz = sizes(cfg_file)
-                if what == "bf16_state":
+                if what == "bf16_state" and "chunk_size" in sz:
                     sz["chunk_size"] = 1
                 try:
                     results = {}
@@ -254,7 +271,8 @@ def main() -> None:
                             args.long_window)
                 finally:
                     refcheck.load_reference, transformer.forward = load_reference, forward
-                    ssd_scan.STATE_DTYPE = state_dtype
+                    ssd_scan.STATE_DTYPE = gated_delta.STATE_DTYPE = state_dtype
+                    gated_delta.CHUNK = lin_chunk
                 for name, res in results.items():
                     line = json.dumps({"seed": seed, "what": name, **res})
                     print("power: " + line, flush=True)

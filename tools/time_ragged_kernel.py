@@ -55,6 +55,14 @@ GEOMETRIES = {
     "keye30b-longlogs": (32, 4, 8, 257, 4096,
                          [6144 + 128 + (15360 - 6144) * i // 15
                           for i in range(16)], True),
+    # MHA, 30 KV heads with ONE query head each: a pool row holds 32
+    # (ModelConfig.kv_heads_paged) and the kernel runs 32Q/32KV, the two
+    # spare heads zeros. 8 agents at 2.3k-3.4k + 128 keys, 8 slots dead
+    "olmohybrid7b-sessions": (30, 30, 8, 64, 448,
+                              [2300 + 128 + 157 * i for i in range(8)]
+                              + [0] * 8, False),
+    # ... and an eager piece (--width 512): one sequence 3,072 keys deep
+    "olmohybrid7b-piece": (30, 30, 8, 64, 448, [3072] + [0] * 15, False),
 }
 _HD, _PAGE, _N = 128, 64, 16
 
@@ -65,6 +73,9 @@ def _case(name, live_slots, live_pages, width, rehearse):
     import numpy as np
 
     H, KV, L, pages, n_blocks, contexts, selects = GEOMETRIES[name]
+    if KV > 8 and KV % 8:       # whole tiles of 8 heads a row, as the pool's
+        spare = -KV % 8
+        H, KV = H + spare * (H // KV), KV + spare
     hd = _HD
     if rehearse:    # control flow only: two layers, a narrow head, few pages
         L, hd, pages = 2, 16, min(pages, 40)
