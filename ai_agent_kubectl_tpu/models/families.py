@@ -150,13 +150,15 @@ def _linear_section(cfg, counts, facts) -> dict:
     prompt rows the linear layers ran and the chunks their scans ran over
     (padding included); ``decode_rows_full`` / ``full_keys_read``: the
     decode queries the full-attention layers ran and the keys those had
-    before them (all five counted on the device, summed over the layers
-    of the kind)."""
-    rows_l, window_l, chunks, rows_f, keys_f = counts["dev"]
+    before them; ``decode_rows_still``: the rows of decode passes that did
+    not move (dead slots), whose state the step kernel neither read nor
+    wrote (all six counted on the device, summed over the layers of the
+    kind)."""
+    rows_l, window_l, chunks, rows_f, keys_f, still = counts["dev"]
     return {"layers_linear": cfg.n_of("L"), "layers_full": cfg.n_of("*"),
             "state_bytes_per_sequence": cfg.state_bytes(),
             "decode_rows_linear": rows_l, "window_rows_linear": window_l,
-            "chunks_scanned": chunks,
+            "chunks_scanned": chunks, "decode_rows_still": still,
             "decode_rows_full": rows_f, "full_keys_read": keys_f,
             "forward_passes": facts["forward_passes"]}
 
@@ -262,7 +264,7 @@ CACHE_KINDS: Tuple[CacheKind, ...] = (
         says=lambda cfg: _STATE_SAYS.format(
             state="a linear-attention state",
             pattern="".join(cfg.layer_kinds)),
-        count_leaf="lin_rows", count_shape=(5,), lane="sel_rows",
+        count_leaf="lin_rows", count_shape=(6,), lane="sel_rows",
         refuses=_STATE_REFUSES,
         health={"linear_attention": _linear_section,
                 "ssm": _state_section}),

@@ -130,12 +130,14 @@ class KVCache:
              [n_linear_layers, batch, conv - 1, conv channels]; carried,
              cut and put back as ``ssm``/``conv`` are, and made by
              ``forward`` where absent.
-    lin_rows: int32 [5], for such a configuration's engine: the DECODE
+    lin_rows: int32 [6], for such a configuration's engine: the DECODE
              rows its linear layers ran, the window rows they ran and the
              chunks their scans ran over, then the decode queries its full
-             attention layers ran and the keys those had before them,
-             summed over layers and passes since the chunk program last
-             zeroed it (/health.linear_attention). Absent elsewhere.
+             attention layers ran and the keys those had before them, then
+             the rows of decode passes whose state the linear layers' step
+             kernel neither read nor wrote (dead slots), summed over
+             layers and passes since the chunk program last zeroed it
+             (/health.linear_attention). Absent elsewhere.
     """
 
     k: Any
@@ -1272,7 +1274,8 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
         # the full layers' two words of ``KVCache.lin_rows``
         counts["lin_rows"] = jnp.concatenate([
             jnp.zeros((3,), jnp.int32),
-            _span_rows(cfg, kind, positions, q_lens, token_mask)[2:]])
+            _span_rows(cfg, kind, positions, q_lens, token_mask)[2:],
+            jnp.zeros((1,), jnp.int32)])
     if kind == "S":
         with jax.named_scope("kv_write"):
             layer_k, layer_v = _ring_write(layer_k, layer_v, k, v, positions,
@@ -1560,9 +1563,11 @@ def _linear_mixer(cfg: ModelConfig, lp: Params, j, h, lin, lconv,
     """A linear-attention layer (the gated delta rule, ops/gated_delta.py)
     of leaves ``lp`` onto the residual, from and to plane ``j`` of the
     state leaves (a traced scalar inside the scan over periods); returns
-    (h, lin, lconv, int32 [5]: the first three words of
-    ``KVCache.lin_rows``). ``valid`` as for ``_ssm_mixer``: a padded token
-    has ``g = 0`` and ``beta = 0`` and stays out of the convolution's tail.
+    (h, lin, lconv, int32 [6]: the first three words of
+    ``KVCache.lin_rows`` and its last). ``valid`` as for ``_ssm_mixer``: a
+    padded token has ``g = 0`` and ``beta = 0`` and stays out of the
+    convolution's tail; a decode pass (S == 1) takes the step kernel on the
+    whole leaf, which passes over such a row's state altogether.
     With ``win`` the projections, the gated norm and the output projection
     run on the window's packed rows, the convolution and the scan on the
     unpacked [B, S]. Scopes ``lin/*`` hold, like ``ssm/*``, no keyword of
@@ -1600,10 +1605,15 @@ def _linear_mixer(cfg: ModelConfig, lp: Params, j, h, lin, lconv,
             beta = (2.0 if cfg.lin_neg_eigval else 1.0) * jax.nn.sigmoid(b)
             g = jnp.where(valid[..., None], g, 0.0)
             beta = jnp.where(valid[..., None], beta, 0.0)
-            o, state = gated_delta.gated_delta_scan(
-                q, k, qkv[..., 2 * H * dk:].reshape(B, S, H, dv), g, beta,
-                plane(lin))
-            lin = jax.lax.dynamic_update_index_in_dim(lin, state, j, 0)
+            v = qkv[..., 2 * H * dk:].reshape(B, S, H, dv)
+            if S == 1:
+                # a decode step: the kernel takes the whole leaf, in place
+                o, lin = gated_delta.gated_delta_step_kernel(
+                    q, k, v, g, beta, lin, j, valid[:, 0])
+            else:
+                o, state = gated_delta.gated_delta_scan(q, k, v, g, beta,
+                                                        plane(lin))
+                lin = jax.lax.dynamic_update_index_in_dim(lin, state, j, 0)
             lconv = jax.lax.dynamic_update_index_in_dim(lconv, tail, j, 0)
         with jax.named_scope("gate_norm"):
             if win is not None:
@@ -1623,8 +1633,11 @@ def _linear_mixer(cfg: ModelConfig, lp: Params, j, h, lin, lconv,
         jnp.sum(decode, dtype=jnp.int32),
         jnp.sum(jnp.where(decode, 0, n_valid), dtype=jnp.int32),
         jnp.asarray(chunks, jnp.int32)])
+    # the rows of a decode pass that the step kernel passed over
+    still = jnp.sum(jnp.logical_not(valid[:, 0]) if S == 1 else 0,
+                    dtype=jnp.int32)
     return h + out, lin, lconv, jnp.concatenate(
-        [rows, jnp.zeros((2,), jnp.int32)])
+        [rows, jnp.zeros((2,), jnp.int32), still[None]])
 
 
 def _patterned_layers(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,

@@ -37,20 +37,39 @@ tests/test_linear_attention.py holds the dtype and shows a bf16 state's
 drift over a long decode), and it is kept ``[B, d_k, H x d_v]``, the heads
 side by side along the lanes: at the published sizes (96 x 30 x 192) that
 row has whole 128-lane tiles, where ``[H, d_k, d_v]`` pads every 192-wide
-row to 256 and the leaf by a third. The step therefore never splits the
-lane axis into heads: a head's ``S^T k`` is one product of ALL heads' keys
-with the row, of which the head's own block of lanes is kept (``_own``), 30
-times the arithmetic of the head-by-head product and still nothing beside
-the state's bytes.
+row to 256 and the leaf by a third.
 
-Plain ``jax.numpy``, float32 at the highest matmul precision, as
-ops/ssd_scan.py: a kernel is ROADMAP's.
+A decode step is one Pallas kernel a layer (``gated_delta_step_kernel``,
+ISSUE 46). It takes the WHOLE leaf ``[layers, B, d_k, H x d_v]``, aliased
+input to output, and the layer as a prefetched scalar of its index maps, so
+no plane is sliced out in front of it and none written back behind it; its
+grid is (row, lane block of a whole number of heads in whole lane tiles: 10
+of the 30, [96, 1,920]). A block's state is read into VMEM once; a pair of
+heads (three 128-lane tiles) at a time, and of it a tile at a time, the key
+and the query are broadcast down their own head's lanes, multiplied with
+the tile and reduced over its sublanes (``S^T k`` and ``S^T q`` on the VPU,
+exact float32), ``u``, ``alpha S + k u^T`` and ``o`` follow in float32, and
+the tile is written once: the state crosses HBM once in and once out, and a
+row that does not move (padding, a dead slot) not at all: the moving rows
+take the grid's first steps and the others' steps name the block before
+them again. ``gated_delta_step`` is the same step in ``jnp`` on one plane,
+kept as what the tests hold the kernel to: it never splits the lane axis
+into heads either, so a head's ``S^T k`` is one product of ALL heads' keys
+with the row, of which the head's own block of lanes is kept (``_own``), 30
+times the arithmetic and a second and third trip over the state.
+
+The window's scan is plain ``jax.numpy``, float32 at the highest matmul
+precision, as ops/ssd_scan.py: a kernel for it is ROADMAP's.
 """
 
 from __future__ import annotations
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.scipy.linalg import solve_triangular
 
 #: dtype of the carried state (tools/refcheck_power.py patches it to read
@@ -104,6 +123,191 @@ def gated_delta_step(q, k, v, g, beta, S0):
                     axis=-1)
     o = alpha * Sq + lanes(kdotq) * u
     return o.reshape(B, 1, H, dv), S
+
+
+#: Bytes of the state the kernel takes a grid step at the most: a lane block
+#: is the most heads in whole 128-lane tiles under it (10 of the published
+#: 30: [96, 1,920] float32, 737 KB, 3 steps a row; tools/
+#: time_gated_delta_step.py times the other widths).
+_STEP_BLOCK_BYTES = 2 ** 20
+#: Scoped VMEM the kernel asks for: a block in and out, each double-buffered
+#: by the pipeline (4 x 737 KB), and a tile's temporaries; a whole 30-head
+#: row (4 x 2.2 MB) passes the 16 MiB default with them.
+_STEP_VMEM_BYTES = 32 * 2 ** 20
+
+
+def _block_heads(H: int, dk: int, dv: int, itemsize: int) -> int:
+    """Heads a lane block of the step kernel: the most that divide ``H``,
+    fill whole 128-lane tiles and stay under ``_STEP_BLOCK_BYTES``; the
+    fewest that fill whole tiles, where none does; all ``H`` (the whole lane
+    axis, which any width may be) where no number of heads fills tiles."""
+    whole = [n for n in range(1, H + 1) if H % n == 0 and n * dv % 128 == 0]
+    small = [n for n in whole if n * dv * dk * itemsize <= _STEP_BLOCK_BYTES]
+    return max(small) if small else min(whole, default=H)
+
+
+def _step_kernel(lyr_ref, order_ref, n_live_ref, kq_ref, vec_ref, s_ref,
+                 o_ref, s_out_ref, *, dv: int):
+    """Grid step (i, c): lane block c (``hb`` heads side by side) of the
+    state of row ``order_ref[i]`` in layer ``lyr_ref[0]``, for the
+    ``n_live_ref[0]`` rows that move (``order_ref`` names them first); a
+    later step's row does not move, its state block is the last moving
+    row's last one again (nothing is fetched, nothing written back) and its
+    output is zeros. kq_ref [1,1,dk,2*hb]: the block's heads' keys, then
+    their queries, a head a column; vec_ref [1,3,W]: v, alpha and beta
+    along the lanes; s_ref, s_out_ref [1,1,dk,W] (one buffer: the leaf is
+    aliased); o_ref [1,1,W]. The block is taken a GROUP of heads at a time,
+    the fewest whose lanes are whole 128-lane tiles (2 of 192 lanes: three
+    tiles), in a loop whose body is traced once (a kernel is lowered anew
+    for every program of every start: its jaxpr's length is ``setup_s``),
+    and a group a tile at a time."""
+    del lyr_ref, order_ref
+    dk, W = s_ref.shape[-2:]
+    hb = kq_ref.shape[-1] // 2
+    G = next((n for n in range(1, hb) if hb % n == 0 and n * dv % 128 == 0),
+             hb)
+    T = 128 if G * dv % 128 == 0 else G * dv
+    n_live = n_live_ref[0]
+
+    @pl.when(pl.program_id(0) >= n_live)
+    def _stays():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when((n_live == 0) & (pl.program_id(0) + pl.program_id(1) == 0))
+    def _none_moves():      # the one block every step names: as it came
+        s_out_ref[...] = s_ref[...]
+
+    def group(p, carry):
+        """Heads p*G .. p*G + G - 1 of the block: lanes p*G*dv onward (whole
+        tiles in, or p is 0)."""
+        kq = kq_ref[0, 0]
+        at = jax.lax.broadcasted_iota(jnp.int32, kq.shape, 1)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (dk, T), 1)
+
+        def col(c):
+            """[dk, T]: column c of ``kq`` down every lane (the one lane
+            kept and summed: exact, and c may be traced)."""
+            return jnp.broadcast_to(jnp.sum(
+                jnp.where(at == c, kq, 0.0), axis=1, keepdims=True), (dk, T))
+
+        for lo in range(0, G * dv, T):
+            heads = range(lo // dv, (lo + T - 1) // dv + 1)
+
+            def down_the_lanes(first):
+                """[dk, T]: lane l holds its own head's column of kq."""
+                x = col(first + p * G + heads[0])
+                for h in heads[1:]:
+                    x = jnp.where(lane >= h * dv - lo, col(first + p * G + h),
+                                  x)
+                return x
+
+            kx, qx = down_the_lanes(0), down_the_lanes(hb)
+            lanes = pl.ds(pl.multiple_of(p * (G * dv) + lo, 128), T)
+            S = s_ref[0, 0, :, lanes].astype(jnp.float32)
+            vec = vec_ref[0, :, lanes]
+            v, alpha, beta = vec[0:1], vec[1:2], vec[2:3]
+            Sk = jnp.sum(S * kx, axis=0, keepdims=True)             # [1, T]
+            Sq = jnp.sum(S * qx, axis=0, keepdims=True)
+            kdotq = jnp.sum(kx * qx, axis=0, keepdims=True)
+            u = beta * (v - alpha * Sk)
+            s_out_ref[0, 0, :, lanes] = (alpha * S + kx * u).astype(
+                s_out_ref.dtype)
+            o_ref[0, :, lanes] = alpha * Sq + kdotq * u
+        return carry
+
+    @pl.when(pl.program_id(0) < n_live)
+    def _moves():
+        jax.lax.fori_loop(0, hb // G, group, None)
+
+
+def gated_delta_step_kernel(q, k, v, g, beta, state, layer, moves=None,
+                            block_heads: int = 0):
+    """``gated_delta_step`` on plane ``layer`` (a traced scalar) of the WHOLE
+    state leaf ``state`` [layers, B, dk, H*dv], as one Pallas kernel that
+    reads each moving row's plane once, updates it in VMEM and writes it
+    once, in place: the leaf is aliased input to output and the layer is a
+    prefetched scalar of the index maps, so no plane is sliced out in front
+    of the call and none written back behind it. The grid is (row, lane
+    block of ``block_heads`` heads; 0: ``_block_heads``); a head's ``S^T k``
+    and ``S^T q`` are taken on the VPU in float32, the key broadcast down the
+    head's own lanes, multiplied and reduced over the sublanes: exact
+    float32 products, no ``_own`` mask and no 30-fold product. ``moves`` [B]
+    bool: the rows whose token is real; absent, the rows whose ``g`` and
+    ``beta`` are not all 0. A row that does not move (padding, a dead slot)
+    has its state neither read nor written (the rows that move take the
+    grid's first steps, the others' steps name the block before them
+    again) and its output is zeros. Other arguments as
+    ``gated_delta_step``'s. Returns (o [B,1,H,dv] float32, the leaf). Off
+    the TPU the kernel runs interpreted."""
+    return _step_call(q, k, v, g, beta, state, layer, moves,
+                      block_heads=block_heads,
+                      interpret=jax.default_backend() != "tpu")
+
+
+@partial(jax.jit, static_argnames=("block_heads", "interpret"))
+def _step_call(q, k, v, g, beta, state, layer, moves, *, block_heads: int,
+               interpret: bool):
+    """``gated_delta_step_kernel``, under a jit of its own: a period's three
+    linear layers, and every program of a start, then share ONE trace of the
+    kernel, and a program lowers it once (a chunk program's lowering is
+    ``setup_s``, compile-cache hit or not)."""
+    B, _, H, dk = q.shape
+    dv = v.shape[-1]
+    L = H * dv
+    hb = block_heads or _block_heads(H, dk, dv, state.dtype.itemsize)
+    nb, W = H // hb, hb * dv
+    f32 = lambda a: a[:, 0].astype(jnp.float32)
+    q, k, v, g, beta = f32(q), f32(k), f32(v), f32(g), f32(beta)
+    if moves is None:
+        moves = jnp.any(jnp.logical_or(g != 0, beta != 0), axis=-1)    # [B]
+    # the rows that move first, in their order, then the others (a stable
+    # argsort, as comparisons: a sort of 8 is a program of its own a layer)
+    m = moves.astype(jnp.int32)
+    n_live = jnp.sum(m).reshape(1)
+    before = jnp.tril(jnp.ones((B, B), jnp.int32), -1)
+    place = jnp.where(moves, before @ m, n_live + before @ (1 - m))
+    rows = jnp.arange(B, dtype=jnp.int32)
+    order = jnp.sum(jnp.where(place[None, :] == rows[:, None], rows[None, :],
+                              0), axis=1)
+    lanes = lambda a: jnp.repeat(a, dv, axis=-1)                    # [B, L]
+    # a block's heads' keys then queries, each a column: [B, nb, dk, 2*hb]
+    cols = lambda a: jnp.swapaxes(a.reshape(B, nb, hb, dk), 2, 3)
+    kq = jnp.concatenate([cols(k), cols(q)], axis=-1)
+    vec = jnp.stack([v.reshape(B, L), lanes(jnp.exp(g)), lanes(beta)],
+                    axis=1)                                         # [B, 3, L]
+    lyr = jnp.asarray(layer, jnp.int32).reshape(1)
+
+    def plane(i, c, lyr, order, n_live):
+        # past the rows that move: the last of them, its last block
+        last = jnp.maximum(n_live[0] - 1, 0)
+        return (lyr[0], order[jnp.minimum(i, last)], 0,
+                jnp.where(i < n_live[0], c, nb - 1))
+
+    row = lambda i, c, lyr, order, n_live: (order[i], 0, c)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B, nb),
+        in_specs=[pl.BlockSpec((1, 1, dk, 2 * hb),
+                               lambda i, c, lyr, order, n_live:
+                               (order[i], c, 0, 0)),
+                  pl.BlockSpec((1, 3, W), row),
+                  pl.BlockSpec((1, 1, dk, W), plane)],
+        out_specs=[pl.BlockSpec((1, 1, W), row),
+                   pl.BlockSpec((1, 1, dk, W), plane)],
+    )
+    o, state = pl.pallas_call(
+        partial(_step_kernel, dv=dv),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((B, 1, L), jnp.float32),
+                   jax.ShapeDtypeStruct(state.shape, state.dtype)],
+        input_output_aliases={5: 1},
+        interpret=interpret,
+        name="gated_delta_step",
+        **({} if interpret else {"compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_STEP_VMEM_BYTES)}),
+    )(lyr, order, n_live, kq, vec, state)
+    return o.reshape(B, 1, H, dv), state
 
 
 def gated_delta_scan(q, k, v, g, beta, S0, chunk: int = 0):

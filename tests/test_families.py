@@ -148,7 +148,7 @@ POOLS = {
     ("linear", ""): {".k": ((2, 12, 16, 4, 32), _BF16), ".v": ((2, 12, 16, 4, 32), _BF16),
                      ".lengths": ((12,), _I32),
                      ".lin": ((6, 3, 24, 160), "float32"),
-                     ".lconv": ((6, 3, 3, 352), _BF16), ".lin_rows": ((5,), _I32)},
+                     ".lconv": ((6, 3, 3, 352), _BF16), ".lin_rows": ((6,), _I32)},
 }
 #: a snapshot store's leaves, 5 rows
 SNAPSHOTS = {"recurrent": {"ssm": ((2, 5, 8, 16, 32), "float32"),
@@ -179,7 +179,7 @@ def test_the_pool_cache_is_built_beside_the_model(kind, kv_quant):
         assert {n: (a.shape, str(a.dtype)) for n, a in snap.items()} == SNAPSHOTS[kind]
     # the count lane of the packed chunk is as wide as the kind's leaf
     words = {"gqa": 0, "selecting": 2, "recurrent": 0, "sliding": 4, "latent": 2,
-             "linear": 5}
+             "linear": 6}
     assert attention_words(cfg) == words[kind]
     assert long_prompts(cfg) == (kind != "gqa")
 
@@ -208,8 +208,8 @@ HEALTH = {
         "window_pairs_full", "forward_passes"]),
     "linear_attention": ("linear", [
         "layers_linear", "layers_full", "state_bytes_per_sequence", "decode_rows_linear",
-        "window_rows_linear", "chunks_scanned", "decode_rows_full", "full_keys_read",
-        "forward_passes"]),
+        "window_rows_linear", "chunks_scanned", "decode_rows_still", "decode_rows_full",
+        "full_keys_read", "forward_passes"]),
     "ssm": ("recurrent", _SSM),
 }
 
@@ -264,11 +264,11 @@ def test_the_sections_count_what_the_scheduler_counted():
     assert _sections("recurrent")["ssm"]["layer_passes"] == {
         "ssm": 10, "experts": 10, "attention": 10, "sliding": 0, "dense_mlp": 0,
         "linear": 0}
-    # the linear layers' state rides the same store; their five words
+    # the linear layers' state rides the same store; their six words
     lin = _sections("linear")
     assert [lin["linear_attention"][k] for k in (
         "decode_rows_linear", "window_rows_linear", "chunks_scanned", "decode_rows_full",
-        "full_keys_read")] == [10, 11, 12, 13, 14]
+        "full_keys_read", "decode_rows_still")] == [10, 11, 12, 13, 14, 15]
     assert lin["ssm"]["layer_passes"]["linear"] == 5 * 6
     assert lin["ssm"]["state_bytes"] == get_config("toy-linear-hybrid").state_bytes()
 

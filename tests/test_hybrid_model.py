@@ -354,3 +354,89 @@ def test_a_large_seeded_leaf_filled_in_place_holds_the_same_values(monkeypatch):
     in_place = random_params_int8(jax.random.PRNGKey(4), CFG, dtype=jnp.bfloat16)
     for a, b in zip(jax.tree_util.tree_leaves(stacked), jax.tree_util.tree_leaves(in_place)):
         assert a.dtype == b.dtype and np.array_equal(np.asarray(a), np.asarray(b))
+
+
+# ------ linear attention's decode step as a kernel (ISSUE 46; the model is
+# ------ tests/test_linear_attention.py's, ``toy-linear-hybrid``)
+
+def _plane_step(q, k, v, g, beta, state, layer, moves=None):
+    """``gated_delta_step_kernel``'s contract by the plain ``jnp`` step."""
+    from ai_agent_kubectl_tpu.ops import gated_delta as GD
+
+    o, S = GD.gated_delta_step(q, k, v, g, beta,
+                               jax.lax.dynamic_index_in_dim(state, layer, 0, False))
+    return o, jax.lax.dynamic_update_index_in_dim(state, S, layer, 0)
+
+
+def test_decode_logits_through_the_step_kernel_equal_the_jnp_steps(monkeypatch):
+    """``forward`` at S == 1 takes ops/gated_delta.py's kernel (interpreted here)
+    on the whole state leaf inside the scan over periods: after a 37-token window
+    (the chunked scan, untouched), 12 decode steps' logits and the state they
+    leave equal those of the ``jnp`` step from and to a sliced plane, one row of
+    the two dead from the fifth step on (its state stays as it was)."""
+    from ai_agent_kubectl_tpu.ops import gated_delta as GD
+
+    cfg = get_config("toy-linear-hybrid")
+    params = init_params(jax.random.PRNGKey(3), cfg, dtype=jnp.float32)
+    toks = np.random.default_rng(9).integers(3, 500, size=(2, 64), dtype=np.int32)
+
+    def decoded(step_fn):
+        monkeypatch.setattr(GD, "gated_delta_step_kernel", step_fn)
+        run = jax.jit(lambda tok, pos, cache, mask: forward(
+            params, cfg, tok, pos, cache, token_mask=mask, write_mask=mask))
+        cache = KVCache.zeros(cfg, 2, 64, dtype=jnp.float32)
+        pos = jnp.broadcast_to(jnp.arange(37, dtype=jnp.int32), (2, 37))
+        _, cache = run(jnp.asarray(toks[:, :37]), pos, cache, jnp.ones((2, 37), bool))
+        out = []
+        for t in range(37, 49):
+            live = jnp.asarray([[True], [t < 41]])
+            logits, cache = run(jnp.asarray(toks[:, t:t + 1]),
+                                jnp.full((2, 1), t, jnp.int32), cache, live)
+            out.append(np.asarray(logits[:, 0]))
+            if t == 40:
+                at_its_death = np.asarray(cache.lin[:, 1])
+        np.testing.assert_array_equal(np.asarray(cache.lin[:, 1]), at_its_death)
+        return np.stack(out), np.asarray(cache.lin)
+
+    kernel = GD.gated_delta_step_kernel
+    want, want_state = decoded(_plane_step)
+    got, state = decoded(kernel)
+    assert state.dtype == np.float32 and state.shape == (6, 2, 24, 4 * 40)
+    np.testing.assert_allclose(got[:, 0], want[:, 0], rtol=2e-4, atol=2e-4 * want.std())
+    np.testing.assert_allclose(got[:4, 1], want[:4, 1], rtol=2e-4, atol=2e-4 * want.std())
+    np.testing.assert_allclose(state, want_state, rtol=2e-4, atol=2e-5)
+
+
+def test_the_step_kernels_float32_state_drifts_no_more_than_the_jnp_steps():
+    """The slow head of tests/test_linear_attention.py's drift test (decay 0.999
+    a step, 1,500 decode steps) through the kernel: as near the float64
+    recurrence as the ``jnp`` step's float32 state ends."""
+    from ai_agent_kubectl_tpu.ops import gated_delta as GD
+
+    T, dk, dv = 1500, 8, 8
+    r = np.random.default_rng(0)
+    a = dict(q=GD.l2_normalize(r.normal(size=(1, T, 1, dk)), dk ** -0.5),
+             k=GD.l2_normalize(r.normal(size=(1, T, 1, dk))),
+             v=jnp.asarray(r.normal(size=(1, T, 1, dv)), jnp.float32),
+             g=jnp.full((1, T, 1), -1e-3, jnp.float32),
+             beta=jnp.full((1, T, 1), 0.5, jnp.float32))
+    S0 = r.normal(size=(1, dk, dv))
+    f = {n: np.asarray(x, np.float64) for n, x in a.items()}
+    S, want = S0[0].copy(), np.zeros((T, dv))
+    for t in range(T):
+        k, al = f["k"][0, t, 0], np.exp(f["g"][0, t, 0])
+        u = f["beta"][0, t, 0] * (f["v"][0, t, 0] - al * S.T @ k)
+        S = al * S + np.outer(k, u)
+        want[t] = S.T @ f["q"][0, t, 0]
+
+    def decoded(step_fn):
+        def step(leaf, t):
+            o, leaf = step_fn(*(jax.lax.dynamic_slice_in_dim(a[n], t, 1, 1) for n in a),
+                              leaf, jnp.asarray(0, jnp.int32))
+            return leaf, o[0, 0, 0]
+
+        _, os = jax.lax.scan(step, jnp.asarray(S0, jnp.float32)[None], jnp.arange(T))
+        return np.abs(np.asarray(os)[-200:] - want[-200:]).mean()
+
+    err_kernel, err_jnp = decoded(GD.gated_delta_step_kernel), decoded(_plane_step)
+    assert err_kernel < 1.5 * err_jnp + 1e-7, (err_kernel, err_jnp)

@@ -115,6 +115,93 @@ def test_single_steps_equal_the_recurrence_and_a_window_of_one():
     np.testing.assert_array_equal(np.asarray(S1), np.asarray(S2))
 
 
+def step_inputs(seed, H, dk, dv, layers=3):
+    """Three rows of one token each: row 0 live, row 1 padded (a real token's
+    q, k and v with g = 0 and beta = 0), row 2 a dead slot (zeros, which
+    ``l2_normalize`` leaves zero); a leaf of ``layers`` planes."""
+    a = scan_inputs(seed, 3, 1, [1, 0, 0], H=H, dk=dk, dv=dv)
+    for n in ("q", "k", "v"):
+        a[n] = a[n].at[2].set(0.0)
+    leaf = jnp.asarray(np.random.default_rng(seed + 1).normal(
+        size=(layers,) + a.pop("S0").shape), jnp.float32)
+    return a, leaf
+
+
+@pytest.mark.parametrize("H,dk,dv,block_heads", [
+    (30, 96, 192, 0), (30, 96, 192, 2), (30, 96, 192, 30), (4, 24, 40, 0), (4, 24, 64, 2)],
+    ids=["published", "published-2-heads", "published-30-heads", "small", "small-2-heads"])
+def test_the_step_kernel_equals_the_jnp_step_on_live_padded_and_dead_rows(H, dk, dv,
+                                                                          block_heads):
+    """ops/gated_delta.py::gated_delta_step_kernel (interpreted here) on plane 1 of
+    a three-plane leaf against ``gated_delta_step`` on that plane: outputs and
+    state equal to float32 rounding (the kernel sums a head's 96 products in
+    another order), a padded and a dead row's state bit for bit its input (the
+    kernel neither reads nor writes it) and their outputs zeros, the other planes
+    untouched, the leaf float32."""
+    a, leaf = step_inputs(H, H, dk, dv)
+    assert GD._block_heads(30, 96, 192, 4) == 10 and GD._block_heads(4, 24, 40, 4) == 4
+    want_o, want_S = GD.gated_delta_step(*a.values(), leaf[1])
+    o, out = jax.jit(GD.gated_delta_step_kernel, static_argnums=8)(
+        *a.values(), leaf, jnp.asarray(1, jnp.int32), None, block_heads)
+    np.testing.assert_allclose(np.asarray(o)[0], np.asarray(want_o)[0], rtol=1e-5, atol=1e-6)
+    assert not np.asarray(o)[1:].any()          # a row that does not move reads nothing
+    np.testing.assert_allclose(np.asarray(out[1]), np.asarray(want_S), rtol=1e-5, atol=1e-6)
+    assert np.abs(np.asarray(out[1, 0]) - np.asarray(leaf[1, 0])).max() > 0.1
+    np.testing.assert_array_equal(np.asarray(out[1, 1:]), np.asarray(leaf[1, 1:]))
+    np.testing.assert_array_equal(np.asarray(out[::2]), np.asarray(leaf[::2]))
+    assert out.dtype == jnp.float32 and out.shape == leaf.shape
+    assert o.dtype == jnp.float32 and o.shape == (3, 1, H, dv)
+
+
+@pytest.mark.parametrize("moves", ["1111", "0000", "0101", "0010", "1000", "0111"])
+def test_the_step_kernel_visits_only_the_rows_that_move(moves):
+    """Whichever rows move (g, beta != 0), first, last, every or none: their
+    outputs and state equal the ``jnp`` step's, and every other row's state is
+    bit for bit its input and its output zeros: the kernel takes the moving
+    rows in its grid's first steps and gives the others no block of their own."""
+    live = np.asarray([c == "1" for c in moves])
+    a = scan_inputs(3, 4, 1, live.astype(int), H=4, dk=24, dv=64)
+    leaf = jnp.asarray(np.random.default_rng(4).normal(size=(2,) + a.pop("S0").shape),
+                       jnp.float32)
+    want_o, want_S = GD.gated_delta_step(*a.values(), leaf[1])
+    o, out = jax.jit(GD.gated_delta_step_kernel, static_argnums=8)(
+        *a.values(), leaf, jnp.asarray(1, jnp.int32), None, 2)
+    np.testing.assert_allclose(np.asarray(o)[live], np.asarray(want_o)[live],
+                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(out[1])[live], np.asarray(want_S)[live],
+                               rtol=1e-5, atol=1e-6)
+    assert not np.asarray(o)[~live].any()
+    np.testing.assert_array_equal(np.asarray(out[1])[~live], np.asarray(leaf[1])[~live])
+    np.testing.assert_array_equal(np.asarray(out[0]), np.asarray(leaf[0]))
+
+
+def test_the_step_kernel_scanned_over_a_leafs_planes_equals_the_recurrence():
+    """As ``_patterned_layers`` runs it: the whole leaf on a scan's carry, the
+    plane a traced index, 40 tokens a plane; every plane ends where the float64
+    recurrence from its own initial state does."""
+    T, planes = 40, 3
+    a = scan_inputs(11, 2, T, [T, T])
+    leaf = jnp.asarray(np.random.default_rng(12).normal(
+        size=(planes,) + a.pop("S0").shape), jnp.float32)
+
+    @jax.jit
+    def decode(leaf):
+        def token(leaf, t):
+            def layer(leaf, j):
+                o, leaf = GD.gated_delta_step_kernel(
+                    *(jax.lax.dynamic_slice_in_dim(a[n], t, 1, 1) for n in a), leaf, j)
+                return leaf, o[:, 0]
+            return jax.lax.scan(layer, leaf, jnp.arange(planes, dtype=jnp.int32))
+        return jax.lax.scan(token, leaf, jnp.arange(T))
+
+    out, o = decode(leaf)                                   # o [T, planes, B, H, dv]
+    for j in range(planes):
+        want_o, want_S = recurrence(**a, S0=leaf[j])
+        np.testing.assert_allclose(np.asarray(o)[:, j].swapaxes(0, 1), want_o,
+                                   rtol=2e-4, atol=2e-5)
+        np.testing.assert_allclose(np.asarray(out[j]), want_S, rtol=2e-4, atol=2e-5)
+
+
 def test_a_token_erases_along_its_key_before_it_writes():
     """What the delta rule has that a decayed sum has not: the same key written
     twice at beta 1 holds the SECOND value, not the sum of both."""
@@ -452,6 +539,8 @@ async def test_a_session_seated_from_snapshots_answers_as_from_token_zero(from_t
         assert (lin["layers_linear"], lin["layers_full"]) == (6, 2)
         # three linear layers to one full one, each decode row through all
         assert lin["decode_rows_linear"] == 3 * lin["decode_rows_full"] > 0
+        # the other slots' rows of those passes: the step kernel passed them over
+        assert lin["decode_rows_still"] > 0 and lin["decode_rows_still"] % 6 == 0
         assert lin["full_keys_read"] > 100 * lin["decode_rows_full"] / 2
         if force_ragged:
             assert lin["window_rows_linear"] > 0 and lin["chunks_scanned"] > 0

@@ -978,7 +978,12 @@ def test_linear_forward_compiles_at_published_widths_on_v5e(one_chip, B, W,
     state [6, B, 96, 30 x 192] rides the donated cache (the scan's carry)
     in whole lane tiles and is written in place a layer, at a traced plane,
     never copied or turned over whole; the
-    chunked scan's triangular solve and the single step compile."""
+    chunked scan's triangular solve compiles. A decode pass (ISSUE 46) runs
+    ops/gated_delta.py's step kernel a linear layer on the WHOLE aliased
+    leaf: Mosaic accepts it ([96, 1,920] blocks, a key broadcast down a
+    head's lanes), its blocks fit the VMEM it asks for, and no ``dynamic-
+    slice``, ``copy`` or ``dynamic-update-slice`` the size of the leaf or of
+    one of its planes is left in the program."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = ModelConfig(name="aot-olmo", n_layers=8, **OLMO)
     page, n_blocks, pages = 64, 448, 64
@@ -994,7 +999,7 @@ def test_linear_forward_compiles_at_published_widths_on_v5e(one_chip, B, W,
     cache = _engine_cache(arg, cfg, n_blocks, page, B)
     pool, lin = cache.k.shape, cache.lin.shape
     assert pool == (2, n_blocks, page, 32, 128) and lin == (6, B, 96, 5760)
-    assert cache.lconv.shape == (6, B, 3, 11520) and cache.lin_rows.shape == (5,)
+    assert cache.lconv.shape == (6, B, 3, 11520) and cache.lin_rows.shape == (6,)
 
     def step(params, tok, pos, cache, wmask, tables, q_lens):
         return forward(params, cfg, tok, pos, cache, kv_limit=pages * page,
@@ -1012,22 +1017,39 @@ def test_linear_forward_compiles_at_published_widths_on_v5e(one_chip, B, W,
     assert ring[-2:] == (32, 128), ring
     compiled = traced.lower().compile()
     hlo = compiled.as_text()
-    # one kernel in the program: the period's one full layer, in the scan
-    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    # the kernels of the program, in the scan: the period's one full layer's
+    # and, at decode, its three linear layers' steps
+    steps = [eqn for eqn in _pallas_calls(traced.jaxpr.jaxpr)
+             if eqn.params["name"] == "gated_delta_step"]
+    assert len(steps) == (3 if W == 1 else 0)
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1 + len(steps)
     assert " while(" in hlo
     carried = {"scatter", "fusion", "while", "parameter", "tuple",
                "get-tuple-element", "bitcast"}
     moved = {op for op, _ in _results_of_size(hlo, {math.prod(pool)})}
     assert moved <= carried, moved
-    # the whole state leaf: carried, and written in place (a dynamic-
-    # update-slice a layer, each in its fusion); this six-layer leaf is
+    # the whole state leaf: carried, and by a window written in place (a
+    # dynamic-update-slice a layer, each in its fusion); this six-layer leaf is
     # small enough that the compiler may prefetch it whole (an async
     # copy-start), which 24 layers' it does not: the whole model's decode
     # program has 1.9 MB of temporaries (AOT, PR 45)
     state_ops = {op for op, _ in _results_of_size(hlo, {math.prod(lin)})}
-    assert "dynamic-update-slice" in state_ops, state_ops
-    assert state_ops <= carried | {"dynamic-update-slice", "copy-start",
-                                   "copy-done"}, state_ops
+    if W == 1:
+        # the step kernel alone names the leaf, and nothing moves a plane
+        assert state_ops <= carried | {"custom-call"}, state_ops
+        assert not _results_of_size(hlo, {math.prod(lin[1:])})
+        for eqn in steps:
+            gm = eqn.params["grid_mapping"]
+            assert gm.grid == (B, 3)
+            blocks = sum(math.prod(b if isinstance(b, int) else b.block_size
+                                   for b in bm.block_shape) * 4
+                         for bm in gm.block_mappings)
+            limit = eqn.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
+            assert 2 * 96 * 1920 * 4 < blocks and 2 * blocks < limit
+    else:
+        assert "dynamic-update-slice" in state_ops, state_ops
+        assert state_ops <= carried | {"dynamic-update-slice", "copy-start",
+                                       "copy-done"}, state_ops
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= (2 * math.prod(pool) * 2
                                        + math.prod(lin) * 4)

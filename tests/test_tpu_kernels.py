@@ -309,3 +309,40 @@ def test_compiled_ragged_pool_decode_batch_matches_gather(H, KV, pages):
     ref = np.asarray(_gather_reference(q, *clean, q_lens, positions,
                                        tables))
     np.testing.assert_allclose(out, ref, rtol=3e-2, atol=3e-2)
+
+
+def test_compiled_gated_delta_step_matches_the_jnp_step_and_skips_still_rows():
+    """ops/gated_delta.py's step kernel COMPILED, at olmo-hybrid-7b's head
+    geometry, on plane 1 of a three-plane leaf under jit with the leaf donated:
+    the rows that move equal the ``jnp`` step to float32 rounding; the rows
+    that do not (first, between and last; and every row) keep their state bit
+    for bit, through grid steps that name another row's block again, which the
+    interpreter does not model: and the other planes are untouched."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ai_agent_kubectl_tpu.ops import gated_delta as GD
+
+    B, H, dk, dv = 8, 30, 96, 192
+    r = np.random.default_rng(0)
+    q = GD.l2_normalize(r.normal(size=(B, 1, H, dk)), dk ** -0.5)
+    k = GD.l2_normalize(r.normal(size=(B, 1, H, dk)))
+    v = jnp.asarray(r.normal(size=(B, 1, H, dv)), jnp.float32)
+    leaf0 = r.normal(size=(3, B, dk, H * dv)).astype(np.float32)
+    step = jax.jit(GD.gated_delta_step_kernel, static_argnums=8, donate_argnums=5)
+    for moves, hb in (("11111111", 0), ("01101100", 0), ("00000000", 0),
+                      ("10000001", 30), ("00010000", 2)):
+        live = np.asarray([c == "1" for c in moves])
+        g = jnp.asarray(np.where(live[:, None, None], -r.uniform(1e-3, 0.7, (B, 1, H)), 0.0),
+                        jnp.float32)
+        beta = jnp.asarray(np.where(live[:, None, None], r.uniform(0.1, 2.0, (B, 1, H)), 0.0),
+                           jnp.float32)
+        want_o, want_S = GD.gated_delta_step(q, k, v, g, beta, jnp.asarray(leaf0[1]))
+        o, out = step(q, k, v, g, beta, jnp.asarray(leaf0), jnp.asarray(1, jnp.int32), None, hb)
+        o, out = np.asarray(o), np.asarray(out)
+        np.testing.assert_allclose(o[live], np.asarray(want_o)[live], rtol=2e-5, atol=2e-6)
+        np.testing.assert_allclose(out[1][live], np.asarray(want_S)[live], rtol=2e-5, atol=2e-6)
+        assert not o[~live].any(), moves
+        np.testing.assert_array_equal(out[1][~live], leaf0[1][~live])
+        np.testing.assert_array_equal(out[::2], leaf0[::2])
