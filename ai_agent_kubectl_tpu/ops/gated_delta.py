@@ -16,16 +16,26 @@ initial state in chunks of C tokens. Inside a chunk, with ``gamma`` the
 running sum of ``g`` and ``S_0`` the state the chunk starts with:
 
     A_ij = beta_i (k_i . k_j) exp(gamma_i - gamma_j)   for j < i, else 0
-    T    = (I + A)^-1              (unit lower triangular: a forward
-                                    substitution, here ``solve_triangular``)
+    T    = (I + A)^-1              (unit lower triangular: by blocks,
+                                    ``unit_lower_inverse``)
     U    = T (beta * V) - T (beta * exp(gamma) * K) S_0
     O    = (exp(gamma) * Q) S_0 + tril(Q K^T * exp(gamma_i - gamma_j)) U
     S_C  = exp(gamma_C) S_0 + (exp(gamma_C - gamma) * K)^T U
 
 ``T`` and the two products it is applied to hold nothing of the state, so
 every chunk's are made at once; only the three products with ``S_0`` run
-chunk after chunk. Every decay is formed as ``exp(gamma_i - gamma_j)`` under
-the causal mask: the factored ``exp(gamma_i) * exp(-gamma_j)`` overflows.
+chunk after chunk. ``T`` is made by blocks (ISSUE 47): forward substitution
+inside the diagonal blocks of 16 rows, every block of every chunk, head and
+row at once, then the blocks merged two and two on the MXU, ``[[T1, 0],
+[-T2 A21 T1, T2]]``, 16 -> 32 -> 64, and applied as one product. A row-by-row
+solve of the whole chunk is 64 dependent steps for 0.6 M multiply-adds, and
+XLA's (``triangular_solve``: on the TPU a custom call that inverts the one
+diagonal block a 64-row system is) took 5 us a matrix, 77-80% of a
+window's scan. The product of powers ``(I - A)(I + A^2)(I + A^4)...`` has
+fewer steps still and is NOT used: it is off by 2e+19 where a chunk's keys
+are nearly equal and written at ``beta`` 2. Every decay is formed as
+``exp(gamma_i - gamma_j)`` under the causal mask: the factored
+``exp(gamma_i) * exp(-gamma_j)`` overflows.
 ``gated_delta_step`` is the single-token update of a decode step. A token
 with ``g = 0`` and ``beta = 0`` neither decays the state nor writes to it,
 which is how padding is kept out: the caller zeroes both past a row's
@@ -70,7 +80,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from jax.scipy.linalg import solve_triangular
 
 #: dtype of the carried state (tools/refcheck_power.py patches it to read
 #: what the comparison makes of a bf16 state).
@@ -310,6 +319,63 @@ def _step_call(q, k, v, g, beta, state, layer, moves, *, block_heads: int,
     return o.reshape(B, 1, H, dv), state
 
 
+#: Rows a diagonal block of ``unit_lower_inverse``: the dependent steps of its
+#: substitution, before log2(C / rows) merges on the MXU.
+_SOLVE_BLOCK = 16
+
+
+def unit_lower_inverse(A):
+    """``(I + A)^-1`` for a strictly lower triangular ``A`` [..., C, C], float32,
+    by blocks. Inside the diagonal blocks of ``_SOLVE_BLOCK`` rows, forward
+    substitution row by row: row i of every block of every matrix at once is
+    ``e_i - A_i T``, the order of operations of a row-by-row solve (which is why
+    it keeps that solve's accuracy), ``_SOLVE_BLOCK`` - 1 dependent steps in a
+    loop whose body is traced once. Then the blocks merge two and two, every
+    pair of every matrix in one batched product: the inverse of ``[[I + A11,
+    0], [A21, I + A22]]`` is ``[[T1, 0], [-T2 A21 T1, T2]]``. NOT by powers,
+    ``(I - A)(I + A^2)(I + A^4)...``: that is the same matrix in exact
+    arithmetic and reads a relative error of 2e+19 on a chunk of nearly equal
+    keys written at ``beta`` 2, where ``A``'s powers grow like binomials times
+    2^k and cancel (tests/test_linear_attention.py). A ``C`` that is not a
+    block times a power of two is padded to one with identity rows; one below
+    a block is one block."""
+    C = A.shape[-1]
+    b = size = min(_SOLVE_BLOCK, C)
+    while size < C:
+        size *= 2
+    A = jnp.pad(A, ((0, 0),) * (A.ndim - 2) + ((0, size - C),) * 2)
+
+    def diagonal(rows):     # A's diagonal blocks [..., size // rows, rows, rows]
+        return jnp.stack([A[..., i:i + rows, i:i + rows]
+                          for i in range(0, size, rows)], axis=-3)
+
+    inside, eye = diagonal(b), jnp.eye(b, dtype=A.dtype)
+
+    def row(i, T):          # rows < i of every block of T are done
+        at = lambda x: jax.lax.dynamic_index_in_dim(x, i, -2, keepdims=False)
+        new = at(eye) - jnp.einsum("...j,...jc->...c", at(inside), T,
+                                   precision=_HI)
+        return jax.lax.dynamic_update_index_in_dim(T, new, i, -2)
+
+    T = jax.lax.fori_loop(1, b, row, jnp.broadcast_to(eye, inside.shape))
+    while b < size:
+        T1, T2 = T[..., 0::2, :, :], T[..., 1::2, :, :]
+        low = -jnp.einsum("...ij,...jk,...kl->...il", T2,
+                          diagonal(2 * b)[..., b:, :b], T1, precision=_HI)
+        T = jnp.concatenate([
+            jnp.concatenate([T1, jnp.zeros_like(T1)], axis=-1),
+            jnp.concatenate([low, T2], axis=-1)], axis=-2)
+        b *= 2
+    return T[..., 0, :C, :C]
+
+
+def _unit_lower_solve(A, rhs):
+    """``(I + A)^-1 rhs`` (tools/time_gated_delta_window.py times the scan
+    with this call's result replaced by ``rhs``)."""
+    return jnp.einsum("...ij,...jv->...iv", unit_lower_inverse(A), rhs,
+                      precision=_HI)
+
+
 def gated_delta_scan(q, k, v, g, beta, S0, chunk: int = 0):
     """A window a row, in chunks of ``chunk`` tokens (0: ``CHUNK``). q, k [B,S,H,dk]
     (normalised); v [B,S,H,dv]; g, beta [B,S,H] (0 on padding); S0
@@ -338,8 +404,7 @@ def gated_delta_scan(q, k, v, g, beta, S0, chunk: int = 0):
                   bc[..., None] * kk * decay, 0.0)
     eg = jnp.exp(gamma)[..., None]
     rhs = jnp.concatenate([bc[..., None] * vc, bc[..., None] * eg * kc], -1)
-    X = solve_triangular(A + jnp.eye(C, dtype=jnp.float32), rhs, lower=True,
-                         unit_diagonal=True)
+    X = _unit_lower_solve(A, rhs)
     U0, Wm = X[..., :dv], X[..., dv:]           # T (beta V), T (beta e^g K)
     qk = jnp.einsum("bnhik,bnhjk->bnhij", qc, kc, precision=_HI) * decay
     to_end = jnp.exp(gamma[..., -1:] - gamma)[..., None] * kc   # [B,n,H,C,dk]

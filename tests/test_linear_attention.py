@@ -82,11 +82,15 @@ def scan_inputs(seed, B, S, q_lens, H=4, dk=24, dv=40):
         S0=jnp.asarray(r.normal(size=(B, dk, H * dv)), jnp.float32))
 
 
-@pytest.mark.parametrize("S,chunk", [(150, 16), (150, 64), (64, 64), (37, 64), (5, 16)])
+@pytest.mark.parametrize("S,chunk", [(150, 16), (150, 64), (64, 64), (37, 64), (5, 16),
+                                     (150, 24), (40, 64)])
 def test_chunked_scan_equals_the_recurrence_from_a_state_with_padding(S, chunk):
     """gated_delta_scan from an INITIAL state, rows padded past unequal q_lens
     (g and beta 0 there): outputs equal the recurrence's at every real token and
-    the state returned is the state at each row's q_len."""
+    the state returned is the state at each row's q_len. The chunk lengths (16,
+    64, 64, 37, 5, 24, 40) are one, four, two and a half and a third of
+    ``unit_lower_inverse``'s 16-row blocks: 24, 37 and 40 cross a block's edge
+    unevenly and are padded with identity rows."""
     q_lens = [S, max(1, S // 4), 0]
     a = scan_inputs(S, 3, S, q_lens)
     want_o, want_S = recurrence(**a)
@@ -96,6 +100,85 @@ def test_chunked_scan_equals_the_recurrence_from_a_state_with_padding(S, chunk):
     np.testing.assert_allclose(np.asarray(S1), want_S, rtol=2e-4, atol=2e-5)
     np.testing.assert_array_equal(np.asarray(S1)[2], np.asarray(a["S0"])[2])
     assert S1.dtype == jnp.float32 and S1.shape == a["S0"].shape
+
+
+@pytest.mark.parametrize("S,chunk", [(64, 64), (40, 64), (150, 24)])
+def test_rows_that_brought_no_token_keep_their_state_bit_for_bit(S, chunk):
+    """A padded row (real q, k and v, g = 0 and beta = 0 at every token) and a
+    dead one (zeros) beside a live row: their ``A`` is zero, every block of
+    ``unit_lower_inverse`` inverts to the identity and every merge adds zeros,
+    so the state comes back as it went in, whole blocks or not."""
+    a = scan_inputs(S, 3, S, [S, 0, 0])
+    for n in ("q", "k", "v"):
+        a[n] = a[n].at[2].set(0.0)
+    o, S1 = jax.jit(GD.gated_delta_scan, static_argnums=6)(*a.values(), chunk)
+    np.testing.assert_array_equal(np.asarray(S1)[1:], np.asarray(a["S0"])[1:])
+    assert np.abs(np.asarray(S1)[0] - np.asarray(a["S0"])[0]).max() > 0.1
+    assert np.isfinite(np.asarray(o)).all()
+
+
+def worst_case_inputs(seed, B=2, S=64, H=4, dk=24, dv=40):
+    """The hardest chunk the rule allows: every key (and query) within 0.05 of
+    ONE unit vector a head, written at ``beta`` 1.8-2.0 with almost no decay:
+    each token all but reflects the state along the one direction, and ``A`` is
+    ~1.9 everywhere under its diagonal."""
+    r = np.random.default_rng(seed)
+    unit = GD.l2_normalize(r.normal(size=(B, 1, H, dk)))
+    near = lambda: GD.l2_normalize(
+        unit + 0.05 * GD.l2_normalize(r.normal(size=(B, S, H, dk))))
+    a = dict(q=near() * dk ** -0.5, k=near(),
+             v=jnp.asarray(r.normal(size=(B, S, H, dv)), jnp.float32),
+             g=jnp.asarray(-r.uniform(1e-4, 1e-3, (B, S, H)), jnp.float32),
+             beta=jnp.asarray(r.uniform(1.8, 2.0, (B, S, H)), jnp.float32),
+             S0=jnp.asarray(r.normal(size=(B, dk, H * dv)), jnp.float32))
+    assert np.abs(np.asarray(a["k"]) - np.asarray(unit)).max() < 0.05
+    return a
+
+
+@pytest.mark.parametrize("seed", [0, 2, 3])
+def test_a_chunk_of_nearly_equal_keys_written_at_full_strength(seed):
+    """``worst_case_inputs`` from a random state against the float64
+    recurrence. The tolerance is what ``jax.scipy.linalg.solve_triangular``
+    met here before ISSUE 47: at rtol 2e-4 it needed an atol of 2.3e-5 at the
+    most over seeds 0-5 (outputs up to 3.7, a state up to 7.6), the inverse by
+    blocks needs 3.0e-5, and 5e-5 is twice the former. The product of powers,
+    ``(I - A)(I + A^2)(I + A^4)...``, is the same matrix on paper and reads
+    2e+19 here (the test below): not a faster form of this, another result."""
+    a = worst_case_inputs(seed)
+    want_o, want_S = recurrence(**a)
+    o, S1 = jax.jit(GD.gated_delta_scan)(*a.values())
+    np.testing.assert_allclose(np.asarray(o), want_o, rtol=2e-4, atol=5e-5)
+    np.testing.assert_allclose(np.asarray(S1), want_S, rtol=2e-4, atol=5e-5)
+
+
+def test_the_inverse_by_blocks_holds_where_the_product_of_powers_does_not():
+    """``unit_lower_inverse`` on the worst chunk's ``A`` against a float64
+    inverse: a relative error of float32's rounding (3e-6 at the most), as a
+    row-by-row substitution's, and an ``A`` of zeros gives the identity bit
+    for bit. The log-depth form, six products of ``A``'s powers in float32,
+    is off by more than the answer is large: ``A``'s powers grow like
+    binomials times 2^k before they cancel."""
+    a = worst_case_inputs(1)
+    k, beta = np.asarray(a["k"], np.float64), np.asarray(a["beta"], np.float64)
+    gamma = np.cumsum(np.asarray(a["g"], np.float64), axis=1)
+    A = np.tril(np.einsum("bihk,bjhk->bhij", k, k)
+                * np.moveaxis(beta, 1, 2)[..., None]
+                * np.exp(np.moveaxis(gamma, 1, 2)[..., :, None]
+                         - np.moveaxis(gamma, 1, 2)[..., None, :]), -1)
+    want = np.linalg.inv(np.eye(64) + A)
+    rel = lambda T: (np.linalg.norm(np.asarray(T, np.float64) - want)
+                     / np.linalg.norm(want))
+    A32 = jnp.asarray(A, jnp.float32)
+    assert rel(jax.jit(GD.unit_lower_inverse)(A32)) < 1e-5
+    eye = np.eye(64, dtype=np.float32)
+    np.testing.assert_array_equal(
+        np.asarray(GD.unit_lower_inverse(jnp.zeros((3, 64, 64)))),
+        np.broadcast_to(eye, (3, 64, 64)))
+    power, T = np.asarray(A32), eye - np.asarray(A32)
+    for _ in range(5):                          # A^2, A^4, ... A^32
+        power = power @ power
+        T = T @ (eye + power)
+    assert rel(T) > 1e12
 
 
 def test_single_steps_equal_the_recurrence_and_a_window_of_one():

@@ -346,3 +346,34 @@ def test_compiled_gated_delta_step_matches_the_jnp_step_and_skips_still_rows():
         assert not o[~live].any(), moves
         np.testing.assert_array_equal(out[1][~live], leaf0[1][~live])
         np.testing.assert_array_equal(out[::2], leaf0[::2])
+
+
+def test_compiled_window_scan_matches_the_float64_recurrence():
+    """ops/gated_delta.py::gated_delta_scan COMPILED (ISSUE 47: its
+    unit-triangular inverse by blocks, every product through the MXU at the
+    highest precision), at olmo-hybrid-7b's head sizes and four heads, against
+    tests/test_linear_attention.py's float64 recurrence: random keys over rows
+    of 150, 64 and no tokens (the last keeps its state bit for bit) at that
+    file's tolerance (the chip reads 8e-7), and a chunk of nearly equal keys
+    written at ``beta`` 1.8-2.0 at twice what the chip reads there: an atol of
+    5.8e-5 at rtol 2e-4 by blocks as by ``solve_triangular`` before (the
+    MXU's six-pass float32 products are the error on the chip, not how ``T``
+    is made; the CPU's exact float32 reads 3.1e-5)."""
+    import jax
+    import numpy as np
+    from test_linear_attention import recurrence, scan_inputs, worst_case_inputs
+
+    from ai_agent_kubectl_tpu.ops import gated_delta as GD
+
+    sizes = dict(H=4, dk=96, dv=192)
+    for a, q_lens, atol in (
+            (scan_inputs(0, 3, 150, [150, 64, 0], **sizes), [150, 64, 0], 2e-5),
+            (worst_case_inputs(0, 2, 64, **sizes), [64, 64], 1.2e-4)):
+        want_o, want_S = recurrence(**a)
+        o, S1 = jax.jit(GD.gated_delta_scan)(*a.values())
+        for b, n in enumerate(q_lens):
+            np.testing.assert_allclose(np.asarray(o)[b, :n], want_o[b, :n],
+                                       rtol=2e-4, atol=atol)
+            if n == 0:
+                np.testing.assert_array_equal(np.asarray(S1)[b], np.asarray(a["S0"])[b])
+        np.testing.assert_allclose(np.asarray(S1), want_S, rtol=2e-4, atol=atol)
