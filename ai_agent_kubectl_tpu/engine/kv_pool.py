@@ -240,12 +240,19 @@ class CacheCounters:
     def note_chunk(self, result, passes: int) -> None:
         """A fetched chunk (engine/protocol.py::ChunkResult) of ``passes``
         passes: the device's own count of what those read and kept."""
+        at = {}                 # lane -> words already given to a kind
         for kind in self.kinds:
             words = getattr(result, kind.lane) if kind.lane else None
             if words is None:
                 continue
             c = self.counts[kind.name]
             c["passes"] += passes
+            if kind.count_shape:
+                # the kinds of one lane lie end to end (models/families.py::
+                # attention_counted)
+                first = at.get(kind.lane, 0)
+                at[kind.lane] = first + kind.count_words
+                words = words[first:at[kind.lane]]
             for i, n in enumerate(words if kind.count_shape else (words,)):
                 c["dev"][i] += n
 
@@ -259,7 +266,12 @@ class CacheCounters:
         out: Dict[str, Optional[dict]] = dict.fromkeys(SECTIONS)
         for kind in self.kinds:
             for section, body in kind.health.items():
-                out[section] = body(self.cfg, self.counts[kind.name], facts)
+                said = body(self.cfg, self.counts[kind.name], facts)
+                # two kinds may fill one section (an expert share's picks
+                # beside the experts read): the later adds its keys
+                out[section] = ({**out[section], **said}
+                                if out[section] and said else
+                                said or out[section])
         return out
 
 

@@ -47,6 +47,16 @@ test models. Family differences are expressed as data, not subclasses:
   a sublayer's OUTPUT is normed, ``x + norm(f(x))``, its input is not
   (``post_norm``), and the QK-norm spans the whole projection before the
   head split (``qk_norm_whole``)
+- Ling-3.0-flash-VL's language model (registered by the benchmark,
+  ``toy-kda-mla-moe`` is its toy): a matrix-state layer and a latent layer
+  in ONE pattern — ``L`` is Kimi delta attention (the delta rule with a
+  decay for every KEY CHANNEL, bounded below: ``lin_channel_decay``,
+  ``lin_decay_floor``; a sigmoid output gate, ``lin_out_gate``), ``*``
+  every sixth layer is latent attention WITHOUT a query LoRA
+  (``q_lora_rank`` 0), plain half-split rope and a per-head output gate,
+  its ``lat`` leaf a plane a latent layer — behind two dense layers, a
+  chip's share of 512 experts under a GROUP-LIMITED sigmoid router
+  (``n_group`` / ``topk_group``)
 """
 
 from __future__ import annotations
@@ -155,6 +165,15 @@ class ModelConfig:
     lin_value_dim: int = 0
     lin_conv: int = 0
     lin_neg_eigval: bool = False
+    # Kimi delta attention (``lin_channel_decay``): the decay is a VECTOR, one
+    # for every key channel of a head, and bounded below: g = ``lin_decay_
+    # floor`` x sigmoid(exp(A_log_h) (x W_f + f_bias)) lies in (floor, 0), so
+    # 16 tokens' decays sum to no less than 16 x floor, which float32's exp
+    # must hold (ops/gated_delta.py::channel_decay_scan). The gate on the
+    # normed output: ``silu`` (the gated delta rule's) or ``sigmoid`` (KDA's).
+    lin_channel_decay: bool = False
+    lin_decay_floor: float = 0.0
+    lin_out_gate: str = "silu"
     # An expert every token takes beside the routed ones (0 = none), the
     # router's kind (``softmax``: the k largest logits, softmax over them;
     # ``sigmoid_bias``: sigmoid scores, the k largest of score + a learned
@@ -163,6 +182,12 @@ class ModelConfig:
     shared_mlp_hidden: int = 0
     router: str = "softmax"
     router_scale: float = 1.0
+    # A group-limited choice (DeepSeek-V3's; ``sigmoid_bias`` only): the
+    # scored experts are ``n_group`` groups of equal size, a group's score
+    # the sum of its two largest score + bias, only the ``topk_group`` best
+    # groups' experts may be picked. 1 and 1 = no limit.
+    n_group: int = 1
+    topk_group: int = 1
     # An expert layer that is GIVEN a share of its experts (one chip of an
     # expert-parallel deployment): the router scores ``router_width``
     # experts (0 = ``n_experts``) and picks among all of them; the leaves
@@ -171,7 +196,8 @@ class ModelConfig:
     router_width: int = 0
     first_expert: int = 0
     # Latent attention (MLA; DeepSeek-V2's, as ``mistral4`` takes it), on
-    # where ``kv_lora_rank`` > 0: q = RMSNorm(x W_dq) W_uq, per head
+    # where ``kv_lora_rank`` > 0 (in a pattern: its ``*`` layers): q =
+    # RMSNorm(x W_dq) W_uq (``q_lora_rank`` 0: q = x W_q, no norm), per head
     # ``qk_nope_head_dim`` values without and ``qk_rope_head_dim`` with a
     # rotary embedding; [c | kr] = x W_dkv, c = RMSNorm(c) of
     # ``kv_lora_rank`` values and ONE rope key a token for all heads; a
@@ -253,6 +279,12 @@ class ModelConfig:
                 f"{self.name}: lin_value_heads {self.lin_value_heads} over "
                 f"lin_key_heads {self.lin_key_heads}: a key head repeated "
                 "over several value heads is not built (ROADMAP)")
+        if ("L" in kinds and self.lin_channel_decay
+                and not -5.5 <= self.lin_decay_floor < 0):
+            raise ValueError(
+                f"{self.name}: a decay a key channel needs lin_decay_floor "
+                f"in [-5.5, 0), not {self.lin_decay_floor}: 16 rows' decays "
+                "are factored in float32 (ops/gated_delta.py)")
         return kinds
 
     def n_of(self, kind: str) -> int:
@@ -414,10 +446,21 @@ class ModelConfig:
                     + (whole if self.qk_norm_whole else 0))
 
         attn = attn_of("*")
+        if self.latent:
+            # W_q (or W_dq, its norm, W_uq), W_dkv and its norm, W_ukv, W_o,
+            # the per-head gate
+            H, Qr = self.n_heads, self.q_lora_rank
+            qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+            attn = ((d * Qr + Qr + Qr * H * qk) if Qr else d * H * qk) + (
+                d * self.latent_row + self.kv_lora_rank
+                + self.kv_lora_rank * H * (self.qk_nope_head_dim
+                                           + self.v_head_dim)
+                + H * self.v_head_dim * d + (d * H if self.attn_gate else 0))
         moe = (self.n_experts * mats * d * self.mlp_hidden
                + mats * d * self.shared_mlp_hidden
                + d * self.experts_scored
-               + (self.n_experts if self.router == "sigmoid_bias" else 0))
+               + (self.experts_scored if self.router == "sigmoid_bias"
+                  else 0))
         ssm = (d * (2 * self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
                     + self.ssm_heads)
                + self.ssm_inner * d + (self.ssm_conv + 1) * self.ssm_conv_dim
@@ -429,6 +472,10 @@ class ModelConfig:
                   + 2 * d * self.lin_value_heads
                   + self.lin_conv * self.lin_conv_dim
                   + 2 * self.lin_value_heads + self.lin_value_dim)
+        if self.lin_channel_decay:
+            # W_a is W_f [d, heads x key_dim] and the step bias a channel's
+            keys = self.lin_key_heads * self.lin_key_dim
+            linear += (d + 1) * (keys - self.lin_value_heads)
         per = {"*": attn, "E": moe, "M": ssm, "S": attn_of("S"),
                "D": mats * d * self.dense_mlp_hidden, "L": linear}
         layers = sum(per[k] + d for k in self.layer_kinds)
@@ -531,6 +578,26 @@ TOY_LINEAR_HYBRID = _register(ModelConfig(
     lin_value_heads=4, lin_key_dim=24, lin_value_dim=40, lin_conv=4,
     lin_neg_eigval=True, post_norm=True, qk_norm_whole=True,
     use_rope=False, max_seq_len=2048,
+))
+
+# Kimi delta attention (a decay a key channel, floor -5, key and value dims
+# that differ) two layers to one of latent attention without a query LoRA
+# (plain half-split rope, a per-head gate), a leading dense layer, then a
+# chip's share of the experts (the router scores 16 in 4 groups of which 2
+# stay, this tree holds 4) under the sigmoid router with a bias, scaled 2.5,
+# beside a shared expert; four KDA layers, two latent, one dense, five of
+# experts: counts no prefix of the published order (five to one, two dense)
+# has. The toy of the benchmark's ling-3.0-flash-vl-l12 configuration.
+TOY_KDA_MLA_MOE = _register(ModelConfig(
+    name="toy-kda-mla-moe", vocab_size=512, dim=128, n_layers=6, n_heads=4,
+    n_kv_heads=4, head_dim=32, mlp_hidden=64, dense_mlp_hidden=192,
+    n_experts=4, experts_per_token=2, router_width=16, router_scale=2.5,
+    router="sigmoid_bias", n_group=4, topk_group=2, shared_mlp_hidden=64,
+    layer_pattern="LDLE*ELELE*E", mixers_per_layer=2, lin_key_heads=4,
+    lin_value_heads=4, lin_key_dim=24, lin_value_dim=40, lin_conv=4,
+    lin_channel_decay=True, lin_decay_floor=-5.0, lin_out_gate="sigmoid",
+    kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=16, v_head_dim=32,
+    rope_theta=6000000.0, attn_gate="head_wise", max_seq_len=2048,
 ))
 
 # --- Gemma (HF: google/gemma-{2b,7b}-it) ---

@@ -81,11 +81,24 @@ def _moe_section(cfg, counts, facts) -> Optional[dict]:
             "experts_held": cfg.n_experts,
             "first_expert": cfg.first_expert,
             "router_width": cfg.experts_scored,
+            # a group-limited choice: groups, and how many of them stay
+            "n_group": cfg.n_group, "topk_group": cfg.topk_group,
             # what the kernel resolves from a call's shapes (ISSUE 34)
             "kernel": {
                 "decode": grouped_kernel_shape(cfg, rows),
                 "widest_window": grouped_kernel_shape(cfg, rows + wide),
                 "eager_piece": grouped_kernel_shape(cfg, wide)}}
+
+
+def _picks_section(cfg, counts, facts) -> Optional[dict]:
+    """/health.moe of a chip's share of the experts, beside ``_moe_section``'s
+    keys: the picks the chunk programs' live rows made among all the experts
+    scored and those that fell on an expert held here (counted on the
+    device, summed over the expert layers)."""
+    if not facts["counts_experts"]:
+        return None
+    picks, held = counts["dev"]
+    return {"picks": picks, "picks_held": held}
 
 
 def _sparse_section(cfg, counts, facts) -> dict:
@@ -104,17 +117,18 @@ def _sparse_section(cfg, counts, facts) -> dict:
 
 def _latent_section(cfg, counts, facts) -> dict:
     """/health.latent_attention. ``row_bytes``: what a token keeps in the
-    pool, all layers. ``decode_rows``: decode queries the chunk programs
-    ran (counted once whatever the depth); ``latent_rows_read``: the
-    cached rows they had before them, summed over the layers (both
+    pool, all latent layers (``layers``: every layer of a uniform block, a
+    pattern's ``*`` layers). ``decode_rows``: decode queries the chunk
+    programs ran (counted once whatever the depth); ``latent_rows_read``:
+    the cached rows they had before them, summed over the layers (both
     counted on the device). ``window_rows_absorbed`` / ``_expanded``:
     prompt rows prefilled, by the form that attended them (the expanded
     form serves none); ``window_pairs``: their (query, cached row) pairs
     in one layer."""
     queries, rows = counts["dev"]
     return {"row_bytes": facts["pool_bytes_per_token"],
-            "layers": cfg.n_layers,
-            "decode_rows": queries // cfg.n_layers,
+            "layers": cfg.n_of("*"),
+            "decode_rows": queries // cfg.n_of("*"),
             "latent_rows_read": rows,
             "window_rows_absorbed": counts["host"]["window_rows_absorbed"],
             "window_rows_expanded": 0,
@@ -203,7 +217,8 @@ _STATE_REFUSES = {
             "the recurrent state",
 }
 
-#: In the order a configuration of two kinds would be refused by.
+#: In the order a configuration of two kinds is refused by, and in which the
+#: words of two kinds on one count lane lie.
 CACHE_KINDS: Tuple[CacheKind, ...] = (
     # The grouped expert path counts the experts it read; whether it
     # serves is the engine's fact (models/transformer.py::serves_grouped).
@@ -292,6 +307,16 @@ CACHE_KINDS: Tuple[CacheKind, ...] = (
         # ops/ragged_attention.py::latent_query's
         kernel_heads=lambda cfg: (
             cfg.n_heads, 1, cfg.kv_lora_rank + 4 * cfg.qk_rope_head_dim)),
+    # A chip's share of the experts under a group-limited choice: how many
+    # of the picks land here (its two words the last of the lane). A share
+    # under a plain top-k router keeps the programs it had.
+    CacheKind(
+        name="expert_share",
+        of=lambda cfg: (cfg.grouped_experts and cfg.router_width > 0
+                        and cfg.n_group > 1),
+        count_leaf="expert_picks", count_shape=(2,), lane="sel_rows",
+        health={"moe": _picks_section},
+        long_prompts=False),
 )
 
 #: The /health sections the kinds give (server/schemas.py::HealthResponse).
@@ -303,18 +328,25 @@ def kinds_of(cfg: ModelConfig) -> Tuple[CacheKind, ...]:
 
 
 def attention_words(cfg: ModelConfig) -> int:
-    """Words of the packed chunk's ``sel_rows`` lane for this model (no
-    configuration is of two kinds that ride it)."""
-    return next((k.count_words for k in kinds_of(cfg)
-                 if k.lane == "sel_rows"), 0)
+    """Words of the packed chunk's ``sel_rows`` lane for this model: those of
+    every kind of its cache that rides the lane, one after another."""
+    return sum(k.count_words for k in kinds_of(cfg) if k.lane == "sel_rows")
 
 
 def attention_counted(cache):
-    """Those words on the device: the count leaf of ``cache`` (a KVCache)
-    that rides the ``sel_rows`` lane, or None."""
-    leaves = (getattr(cache, k.count_leaf) for k in CACHE_KINDS
-              if k.lane == "sel_rows")
-    return next((leaf for leaf in leaves if leaf is not None), None)
+    """Those words on the device: the count leaves of ``cache`` (a KVCache)
+    that ride the ``sel_rows`` lane, end to end in ``CACHE_KINDS``' order
+    (a leaf is there for each kind the configuration is of: ``KVCache.
+    pool_zeros``), or None."""
+    leaves = [leaf for k in CACHE_KINDS if k.lane == "sel_rows"
+              and (leaf := getattr(cache, k.count_leaf)) is not None]
+    if len(leaves) < 2:
+        return leaves[0] if leaves else None
+    # (this module stays jax-free for the fake scheduler: the one call that
+    # needs an array library is reached from a chunk program alone)
+    import jax.numpy as jnp
+
+    return jnp.concatenate(leaves)
 
 
 def long_prompts(cfg: ModelConfig) -> bool:

@@ -89,9 +89,10 @@ class KVCache:
              configuration and no leaf, starts every row from zero and
              returns the leaves.
     lat:     pool mode of a latent-attention configuration
-             (``ModelConfig.latent``) only, and then THE cache: one
-             compressed row a token a layer, [normed latent | rotated rope
-             key], shared by every head — [n_layers, n_blocks, page / 2,
+             (``ModelConfig.latent``) only, and then THE paged cache: one
+             compressed row a token a latent layer (in a pattern: a ``*``
+             layer, addressed by its ordinal among them), [normed latent |
+             rotated rope key], shared by every head — [n_layers, n_blocks, page / 2,
              2 x latent_row], a PAIR of tokens a leaf row (ops/
              ragged_attention.py::latent_pack: 640 B a token at the
              published sizes, every part on a lane tile's edge), addressed
@@ -138,6 +139,12 @@ class KVCache:
              kernel neither read nor wrote (dead slots), summed over
              layers and passes since the chunk program last zeroed it
              (/health.linear_attention). Absent elsewhere.
+    expert_picks: int32 [2], where the grouped expert path serves a chip's
+             SHARE of the experts (``ModelConfig.router_width``): the picks
+             the passes' live rows made among all the experts scored, and
+             those that fell on an expert held here, summed over layers
+             and passes since the chunk program last zeroed it
+             (/health.moe.picks). Absent elsewhere.
     """
 
     k: Any
@@ -156,12 +163,13 @@ class KVCache:
     lin: Any = None
     lconv: Any = None
     lin_rows: Any = None
+    expert_picks: Any = None
 
     #: the leaves that hold one bounded state a batch row (axis 1)
     STATE = ("ssm", "conv", "sk", "sv", "lin", "lconv")
     #: what the passes count on the device, zeroed by the chunk program
     COUNTS = ("experts_read", "sel_rows", "lat_rows", "span_rows",
-              "lin_rows")
+              "lin_rows", "expert_picks")
 
     @classmethod
     def zeros(cls, cfg: ModelConfig, batch: int, max_seq: int,
@@ -205,9 +213,10 @@ class KVCache:
         shape = (cfg.n_of("*"), n_blocks, page, cfg.kv_heads_paged,
                  cfg.head_dim)
         if cfg.latent:
-            # a pair of tokens a leaf row (``lat`` above); no K, no V
+            # a pair of tokens a leaf row (``lat`` above), a plane a latent
+            # layer (every layer of a uniform block); no K, no V
             paged = dict(k=None, v=None, lat=jnp.zeros(
-                (cfg.n_layers, n_blocks, page // 2, 2 * cfg.latent_row),
+                (cfg.n_of("*"), n_blocks, page // 2, 2 * cfg.latent_row),
                 dtype))
         else:
             paged = dict(k=_kv_zeros(shape, dtype, kv_quant),
@@ -320,10 +329,22 @@ def small_leaf_init(name: str, shape, dtype, key):
     float32 does over 3,500 positions on the chip (PERF.md, PR 45), what
     a long memory integrates being the bf16 activations' rounding."""
     H = shape[-1]
-    if name in ("lin_wa", "lin_wb"):
+    if name in ("lin_wa", "lin_wb", "lin_wf"):
         return (jax.random.normal(key, shape, jnp.float32)
-                * (0.25 if name == "lin_wa" else 0.5) * shape[-2] ** -0.5
+                * (0.5 if name == "lin_wb" else 0.25) * shape[-2] ** -0.5
                 ).astype(dtype)
+    if name == "lin_f_bias":
+        # Kimi delta attention's bias a key channel, [.., heads, key_dim]: a
+        # channel's decay is floor x sigmoid(A_h (x W_f + bias)); A_h bias
+        # runs evenly from -2 to -9 over a head's channels whatever A_h is
+        # (``lin_A_log`` below), so that at the floor of -5 a head's
+        # channels remember from 2 tokens to ~1,600, evenly in the
+        # logarithm, and the data (W_f: a deviation of 0.14 to 2.3 in that
+        # argument, by the head's A) moves each by a factor of a few
+        A = jnp.linspace(1.0, 16.0, shape[-2])[
+            (37 * jnp.arange(shape[-2])) % shape[-2]]
+        v = jnp.linspace(-2.0, -9.0, H)[None, :] / A[:, None]
+        return jnp.broadcast_to(v, shape).astype(jnp.float32)
     name = {"lin_A_log": "ssm_A_log", "lin_dt_bias": "ssm_dt_bias",
             "lin_conv_w": "ssm_conv_w"}.get(name, name)
     if name == "ssm_A_log":
@@ -378,7 +399,11 @@ def _init_patterned(key: jax.Array, cfg: ModelConfig, dtype) -> Params:
             leaves[names[7]] = jnp.ones((n, KV * hd), dtype)
         return leaves
 
-    if nA:
+    if nA and cfg.latent:
+        # latent attention inside a pattern: one stack a leaf over the
+        # latent layers (``init_params`` below has the uniform block's)
+        layers.update(_latent_leaves(cfg, nA, dense, keys, dtype))
+    elif nA:
         layers.update(attention("*", nA))
     if nE:
         E, F, Fs = cfg.n_experts, cfg.mlp_hidden, cfg.shared_mlp_hidden
@@ -388,8 +413,9 @@ def _init_patterned(key: jax.Array, cfg: ModelConfig, dtype) -> Params:
             w_up=dense(next(keys), (nE, E, d, F)),
             w_down=dense(next(keys), (nE, E, F, d)))
         if cfg.router == "sigmoid_bias":
+            # one a SCORED expert: it enters the choice among all of them
             layers["router_bias"] = small_leaf_init(
-                "router_bias", (nE, E), dtype, next(keys))
+                "router_bias", (nE, cfg.experts_scored), dtype, next(keys))
         if cfg.gated_mlp:
             layers["w_gate"] = dense(next(keys), (nE, E, d, F))
         if Fs:
@@ -420,9 +446,15 @@ def _init_patterned(key: jax.Array, cfg: ModelConfig, dtype) -> Params:
             lin_in=dense(next(keys), (nL, d, Cl + Hl * dv)),
             lin_gate_norm=jnp.ones((nL, dv), dtype),
             lin_out=dense(next(keys), (nL, Hl * dv, d)))
+        # (a decay a key channel: W_f [d, heads x key_dim], a projection
+        # like the others, and a bias a channel, in the places of W_a and
+        # the step bias a head)
+        dkl = cfg.lin_key_dim
+        decay = ((("lin_wf", (nL, d, Hl * dkl)),
+                  ("lin_f_bias", (nL, Hl, dkl))) if cfg.lin_channel_decay
+                 else (("lin_wa", (nL, d, Hl)), ("lin_dt_bias", (nL, Hl))))
         for name, shape in (("lin_conv_w", (nL, cfg.lin_conv, Cl)),
-                            ("lin_wa", (nL, d, Hl)), ("lin_wb", (nL, d, Hl)),
-                            ("lin_dt_bias", (nL, Hl)),
+                            decay[0], ("lin_wb", (nL, d, Hl)), decay[1],
                             ("lin_A_log", (nL, Hl))):
             layers[name] = small_leaf_init(name, shape, dtype, next(keys))
     if nD := cfg.n_of("D"):
@@ -442,6 +474,33 @@ def _init_patterned(key: jax.Array, cfg: ModelConfig, dtype) -> Params:
     if not cfg.tie_embeddings:
         params["lm_head"] = dense(next(keys), (d, cfg.vocab_size))
     return params
+
+
+def _latent_leaves(cfg: ModelConfig, L: int, dense, keys, dtype) -> Params:
+    """The leaves of ``L`` latent-attention layers, stacked: the query's
+    down and up projections with a norm between (``q_lora_rank`` 0: ONE
+    projection ``wq`` and no norm), the joint down projection of latent and
+    rope key with its norm, ONE up-projection of keys and values [latent,
+    heads x (nope | v)] (bf16 in an int8 tree too: absorbed, it is
+    contracted over its OUTPUT channels, where int8's per-channel scales
+    sit), ``wo``, and the per-head gate's projection where there is one.
+    ``dense(key, shape)`` draws a projection."""
+    d, H, Qr, C = cfg.dim, cfg.n_heads, cfg.q_lora_rank, cfg.kv_lora_rank
+    N, R, V = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    leaves: Params = {"attn_norm": jnp.ones((L, d), dtype)}
+    if Qr:
+        leaves.update(w_dq=dense(next(keys), (L, d, Qr)),
+                      dq_norm=jnp.ones((L, Qr), dtype),
+                      w_uq=dense(next(keys), (L, Qr, H * (N + R))))
+    else:
+        leaves["wq"] = dense(next(keys), (L, d, H * (N + R)))
+    leaves.update(w_dkv=dense(next(keys), (L, d, C + R)),
+                  dkv_norm=jnp.ones((L, C), dtype),
+                  w_ukv=dense(next(keys), (L, C, H * (N + V))),
+                  wo=dense(next(keys), (L, H * V, d)))
+    if cfg.attn_gate:
+        leaves["wg"] = dense(next(keys), (L, d, H))
+    return leaves
 
 
 def init_params(key: jax.Array, cfg: ModelConfig, dtype=jnp.bfloat16) -> Params:
@@ -734,8 +793,8 @@ def _moe_mlp(cfg: ModelConfig, lp: Params, x: jnp.ndarray,
              mesh=None, token_mask=None,
              moe_impl: str = "auto", layer=None):
     """MoE MLP with impl selection (the seam VERDICT r2 item 2 asked for).
-    Returns (y, experts_read): the count is the grouped path's, None on
-    the others.
+    Returns (y, what the pass counted by ``KVCache`` field): the grouped
+    path's ``experts_read`` and ``expert_picks``, nothing on the others.
 
     ``moe_impl``:
 
@@ -759,10 +818,11 @@ def _moe_mlp(cfg: ModelConfig, lp: Params, x: jnp.ndarray,
     overhead. ``token_mask`` ([B, S], 0 = dead slot or bucket padding)
     keeps garbage tokens from consuming expert capacity.
     """
-    from ..parallel.moe import dense_moe, expert_parallel_moe, grouped_moe
+    from ..parallel.moe import (dense_moe, expert_parallel_moe,
+                                grouped_moe_counted)
 
     if moe_impl == "dense":
-        return dense_moe(cfg, lp, x, mesh), None
+        return dense_moe(cfg, lp, x, mesh), {}
     if mesh is not None and "expert" in mesh.axis_names:
         ep = mesh.shape["expert"]
         B, S, _ = x.shape
@@ -774,7 +834,7 @@ def _moe_mlp(cfg: ModelConfig, lp: Params, x: jnp.ndarray,
             # at negligible buffer cost, preserving single-device parity.
             capacity = (B * S) // ep if S == 1 else None
             return expert_parallel_moe(cfg, lp, x, mesh, capacity=capacity,
-                                       token_mask=token_mask), None
+                                       token_mask=token_mask), {}
     if moe_impl == "ep":
         raise ValueError(
             "MOE_IMPL=ep needs a mesh with an expert axis whose size "
@@ -784,9 +844,13 @@ def _moe_mlp(cfg: ModelConfig, lp: Params, x: jnp.ndarray,
         # picks the layer inside the kernel's index maps (``forward``'s
         # scan closes over them): sliced out as the scan's xs they were
         # copied, 604 MB a layer a pass, before the kernel read them.
-        return grouped_moe(cfg, lp, x, token_mask, cfg.first_expert,
-                           layer=layer)
-    return dense_moe(cfg, lp, x, mesh), None
+        y, n_read, picks = grouped_moe_counted(
+            cfg, lp, x, token_mask, cfg.first_expert, layer=layer)
+        counted = {"experts_read": n_read}
+        if cfg.n_group > 1:     # models/families.py's ``expert_share`` kind
+            counted["expert_picks"] = picks
+        return y, counted
+    return dense_moe(cfg, lp, x, mesh), {}
 
 
 def _select_and_attend(cfg: ModelConfig, attn_impl: str, q, qi, wi,
@@ -923,10 +987,18 @@ def _latent_attention(cfg: ModelConfig, attn_impl: str, x, lp: Params,
     H, C = cfg.n_heads, cfg.kv_lora_rank
     N, R, V = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     with jax.named_scope("qkv_proj"):
-        cq = rms_norm(qmatmul(x, lp["w_dq"]), lp["dq_norm"], cfg.rms_eps)
-        q = qmatmul_heads(cq, lp["w_uq"], H, N + R)
+        if cfg.q_lora_rank:
+            cq = rms_norm(qmatmul(x, lp["w_dq"]), lp["dq_norm"], cfg.rms_eps)
+            q = qmatmul_heads(cq, lp["w_uq"], H, N + R)
+        else:
+            # no query LoRA: one projection, no norm on the query's side
+            q = qmatmul_heads(x, lp["wq"], H, N + R)
         ckr = qmatmul(x, lp["w_dkv"])
         c = rms_norm(ckr[..., :C], lp["dkv_norm"], cfg.rms_eps)
+        gate = None
+        if cfg.attn_gate:
+            # one scalar a head, from the layer's normed input
+            gate = jax.nn.sigmoid((x @ lp["wg"]).astype(jnp.float32))
     with jax.named_scope("rope"):
         inv_freq = yarn_frequencies(
             R, cfg.rope_theta, cfg.rope_factor, cfg.rope_original_max,
@@ -982,6 +1054,8 @@ def _latent_attention(cfg: ModelConfig, attn_impl: str, x, lp: Params,
         if win is not None:
             o_c = win.pack(o_c)
         o = jnp.einsum("bshc,chv->bshv", o_c, w_ukv[..., N:])
+        if gate is not None:
+            o = (o.astype(jnp.float32) * gate[..., None]).astype(o.dtype)
         out = qmatmul(o.reshape(Bh, Sh, H * V), lp["wo"])
     decode = ql == 1
     if token_mask is not None:
@@ -1160,9 +1234,8 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
         x = rms_norm(h, lp["mlp_norm"], cfg.rms_eps, cfg.rms_offset)
         if not cfg.is_moe:
             return _dense_mlp(cfg, lp, x)
-        y, n_read = _moe_mlp(cfg, lp, x, mesh, mlp_mask, moe_impl, layer)
-        if n_read is not None:
-            counts["experts_read"] = n_read
+        y, got = _moe_mlp(cfg, lp, x, mesh, mlp_mask, moe_impl, layer)
+        counts.update(got)
         if cfg.shared_mlp_hidden:
             # the expert every token takes, whole on every chip
             y = y + _dense_mlp(cfg, lp, x, "shared_")
@@ -1475,6 +1548,9 @@ ATTENTION_LEAVES = {
     "*": ("attn_norm", "wq", "wk", "wv", "wo", "wg", "q_norm", "k_norm"),
     "S": ("sw_norm", "sw_wq", "sw_wk", "sw_wv", "sw_wo", "sw_wg",
           "sw_q_norm", "sw_k_norm")}
+#: ... and a latent-attention layer's in a pattern (``_latent_leaves``).
+LATENT_LEAVES = ("attn_norm", "wq", "w_dq", "dq_norm", "w_uq", "w_dkv",
+                 "dkv_norm", "w_ukv", "wo", "wg")
 EXPERT_LAYER_LEAVES = ("router", "router_bias", "w_gate", "w_up", "w_down",
                        "shared_gate", "shared_up", "shared_down")
 
@@ -1489,7 +1565,7 @@ def _expert_mixer(cfg: ModelConfig, layers: Params, j: int, h, mesh,
     """``h + experts(norm(h))`` for expert layer ``j``: the routed experts
     (``_moe_mlp``'s paths; the grouped one reads layer ``j`` out of the
     whole stacks) plus the shared expert every token takes. Returns
-    (h, experts_read or None)."""
+    (h, what ``_moe_mlp`` counted)."""
     with jax.named_scope("mlp_norm"):
         x = rms_norm(h, layers["mlp_norm"][j], cfg.rms_eps, cfg.rms_offset)
     grouped = serves_grouped(cfg, mesh, moe_impl)
@@ -1497,11 +1573,11 @@ def _expert_mixer(cfg: ModelConfig, layers: Params, j: int, h, mesh,
               else _at(layers[k], j))
           for k in EXPERT_LAYER_LEAVES if k in layers}
     with jax.named_scope("mlp"):
-        y, n_read = _moe_mlp(cfg, lp, x, mesh, token_mask, moe_impl,
-                             jnp.asarray(j, jnp.int32) if grouped else None)
+        y, got = _moe_mlp(cfg, lp, x, mesh, token_mask, moe_impl,
+                          jnp.asarray(j, jnp.int32) if grouped else None)
         if cfg.shared_mlp_hidden:
             y = y + _dense_mlp(cfg, lp, x, "shared_")
-    return h + y, n_read
+    return h + y, got
 
 
 def _ssm_mixer(cfg: ModelConfig, layers: Params, j: int, h, ssm, conv,
@@ -1587,7 +1663,13 @@ def _linear_mixer(cfg: ModelConfig, lp: Params, j, h, lin, lconv,
         with jax.named_scope("in_proj"):
             qkvz = qmatmul(x, lp["lin_in"])
             qkv, z = qkvz[..., :C], qkvz[..., C:]
-            a = (x @ lp["lin_wa"]).astype(jnp.float32)
+            if cfg.lin_channel_decay:       # W_f: a decay a key channel
+                # (the head split outside the dot: folded into it, the
+                # compiler turned W_f over with a copy every layer of every
+                # pass, 10.5 MB each: tools/aot_weight_staging.py, PR 48)
+                a = qmatmul_heads(x, lp["lin_wf"], H, dk).astype(jnp.float32)
+            else:
+                a = (x @ lp["lin_wa"]).astype(jnp.float32)
             b = (x @ lp["lin_wb"]).astype(jnp.float32)
             if win is not None:
                 qkv, a, b = win.unpack(qkv), win.unpack(a), win.unpack(b)
@@ -1600,19 +1682,28 @@ def _linear_mixer(cfg: ModelConfig, lp: Params, j, h, lin, lconv,
                              dk ** -0.5)
             k = gated_delta.l2_normalize(
                 qkv[..., H * dk:2 * H * dk].reshape(B, S, H, dk))
-            g = -jnp.exp(lp["lin_A_log"].astype(jnp.float32)) \
-                * jax.nn.softplus(a + lp["lin_dt_bias"])
+            if cfg.lin_channel_decay:
+                # a vector a head, in (floor, 0) for every key channel
+                g = cfg.lin_decay_floor * jax.nn.sigmoid(
+                    jnp.exp(lp["lin_A_log"].astype(jnp.float32))[:, None]
+                    * (a + lp["lin_f_bias"]))
+                scan = gated_delta.channel_decay_scan
+            else:
+                g = -jnp.exp(lp["lin_A_log"].astype(jnp.float32)) \
+                    * jax.nn.softplus(a + lp["lin_dt_bias"])
+                scan = gated_delta.gated_delta_scan
             beta = (2.0 if cfg.lin_neg_eigval else 1.0) * jax.nn.sigmoid(b)
-            g = jnp.where(valid[..., None], g, 0.0)
+            g = jnp.where(valid.reshape(valid.shape + (1,) * (g.ndim - 2)),
+                          g, 0.0)
             beta = jnp.where(valid[..., None], beta, 0.0)
             v = qkv[..., 2 * H * dk:].reshape(B, S, H, dv)
             if S == 1:
                 # a decode step: the kernel takes the whole leaf, in place
-                o, lin = gated_delta.gated_delta_step_kernel(
-                    q, k, v, g, beta, lin, j, valid[:, 0])
+                with jax.named_scope("step"):
+                    o, lin = gated_delta.gated_delta_step_kernel(
+                        q, k, v, g, beta, lin, j, valid[:, 0])
             else:
-                o, state = gated_delta.gated_delta_scan(q, k, v, g, beta,
-                                                        plane(lin))
+                o, state = scan(q, k, v, g, beta, plane(lin))
                 lin = jax.lax.dynamic_update_index_in_dim(lin, state, j, 0)
             lconv = jax.lax.dynamic_update_index_in_dim(lconv, tail, j, 0)
         with jax.named_scope("gate_norm"):
@@ -1620,7 +1711,9 @@ def _linear_mixer(cfg: ModelConfig, lp: Params, j, h, lin, lconv,
                 o = win.pack(o)
             y = gated_delta.gated_head_norm(
                 o, z.reshape(z.shape[:-1] + (H, dv)),
-                lp["lin_gate_norm"], cfg.rms_eps).astype(h.dtype)
+                lp["lin_gate_norm"], cfg.rms_eps,
+                **({"gate": jax.nn.sigmoid} if cfg.lin_out_gate == "sigmoid"
+                   else {})).astype(h.dtype)
         with jax.named_scope("out_proj"):
             out = qmatmul(y.reshape(y.shape[:-2] + (H * dv,)), lp["lin_out"])
         if cfg.post_norm:
@@ -1665,6 +1758,14 @@ def _patterned_layers(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
         valid = jnp.logical_and(valid, jnp.arange(S)[None, :] < q_lens[:, None])
     k, v, ssm, conv = cache.k, cache.v, cache.ssm, cache.conv
     sk, sv, lin, lconv = cache.sk, cache.sv, cache.lin, cache.lconv
+    lat = cache.lat
+    if cfg.latent and lat is None and block_tables is not None:
+        # no leaf given (benchmark/refcheck.py hands K and V pools, which
+        # ride untouched): a zero one on the K pool's block geometry, a
+        # plane a LATENT layer
+        nb, page = k.shape[1:3]
+        lat = jnp.zeros((cfg.n_of("*"), nb, page // 2, 2 * cfg.latent_row),
+                        k.dtype)
     if cfg.has_ssm and ssm is None:
         ssm, conv = state_zeros(cfg, B, h.dtype)
     if cfg.has_linear and lin is None:
@@ -1681,8 +1782,8 @@ def _patterned_layers(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
         of its kind the i-th ``kind`` of ``kinds`` is, in the kind's stacked
         leaves and in its planes of the cache alike (a traced scalar inside
         the scan over periods). ``st``: (k, v, ssm, conv, sk, sv, lin,
-        lconv); ``counts`` is added to."""
-        k, v, ssm, conv, sk, sv, lin, lconv = st
+        lconv, lat); ``counts`` is added to."""
+        k, v, ssm, conv, sk, sv, lin, lconv, lat = st
         leaf = lambda name, j: _at(layers[name], j)
 
         def count(new):
@@ -1701,8 +1802,7 @@ def _patterned_layers(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
                 h, n = _expert_mixer(
                     cfg, layers, j, h, mesh,
                     token_mask if win is None else win.valid, moe_impl)
-                if n is not None:
-                    count({"experts_read": n})
+                count(n)
             elif kind == "L":
                 h, lin, lconv, n = _linear_mixer(
                     cfg, {name: leaf(name, j) for name in layers
@@ -1725,21 +1825,29 @@ def _patterned_layers(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
                     with jax.named_scope("mlp_norm"):
                         h = h + norm(y)
             else:
-                lp = {name: leaf(own, j) for name, own
-                      in zip(ATTENTION_LEAVES["*"], ATTENTION_LEAVES[kind])
-                      if own in layers}
+                latent = cfg.latent and kind == "*"
+                lp = ({name: leaf(name, j) for name in LATENT_LEAVES
+                       if name in layers} if latent else
+                      {name: leaf(own, j) for name, own
+                       in zip(ATTENTION_LEAVES["*"], ATTENTION_LEAVES[kind])
+                       if own in layers})
                 args = (positions, kv_limit, batch_idx, token_mask,
                         write_mask, block_tables, q_lens,
-                        jnp.asarray(j, jnp.int32), None, win, kind)
+                        jnp.asarray(j, jnp.int32))
                 if kind == "S":
-                    h, sk, sv, _, n = step(h, lp, sk, sv, *args)
+                    h, sk, sv, _, n = step(h, lp, sk, sv, *args, None, win,
+                                           kind)
+                elif latent:
+                    # plane j of the latent leaf; K and V ride untouched
+                    h, _, _, lat, n = step(h, lp, None, None, *args, lat,
+                                           win, kind)
                 else:
-                    h, k, v, _, n = step(h, lp, k, v, *args)
+                    h, k, v, _, n = step(h, lp, k, v, *args, None, win, kind)
                 count(n)
-        return h, (k, v, ssm, conv, sk, sv, lin, lconv)
+        return h, (k, v, ssm, conv, sk, sv, lin, lconv, lat)
 
     kinds = cfg.layer_kinds
-    st = (k, v, ssm, conv, sk, sv, lin, lconv)
+    st = (k, v, ssm, conv, sk, sv, lin, lconv, lat)
     counts: Dict[str, Any] = {}
     period = _scan_period(kinds)
     if not period:
@@ -1768,9 +1876,10 @@ def _patterned_layers(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
         (h, st), per_rep = jax.lax.scan(
             body, (h, st), jnp.arange(reps, dtype=jnp.int32))
         counts = {name: jnp.sum(n, axis=0) for name, n in per_rep.items()}
-    k, v, ssm, conv, sk, sv, lin, lconv = st
+    k, v, ssm, conv, sk, sv, lin, lconv, lat = st
     return h, dataclasses.replace(cache, k=k, v=v, ssm=ssm, conv=conv,
-                                  sk=sk, sv=sv, lin=lin, lconv=lconv), counts
+                                  sk=sk, sv=sv, lin=lin, lconv=lconv,
+                                  lat=lat), counts
 
 
 def _scan_period(kinds: Tuple[str, ...]) -> int:
@@ -1868,11 +1977,14 @@ def forward(
                                     v=_pad_heads(cache.v, short))
     new_ik, state = cache.ik, cache
     counted = {name: getattr(cache, name) for name in KVCache.COUNTS}
-    if cfg.latent and (cfg.layer_kinds or (mesh is not None
-                                           and mesh.size > 1)):
+    if cfg.latent and mesh is not None and mesh.size > 1:
         raise NotImplementedError(
-            f"{cfg.name} keeps a latent cache: served as a uniform block on "
-            "one device (parallel/sharding.py has no rule for its leaves)")
+            f"{cfg.name} keeps a latent cache: served on one device "
+            "(parallel/sharding.py has no rule for its leaves)")
+    if cfg.latent and not cfg.q_lora_rank and not cfg.layer_kinds:
+        raise NotImplementedError(
+            f"{cfg.name}: latent attention without a query LoRA "
+            "(q_lora_rank 0) is written as a pattern's * layers")
     if cfg.post_norm and not cfg.layer_kinds:
         raise NotImplementedError(
             f"{cfg.name}: a block that norms its sublayers' outputs "
@@ -1938,6 +2050,8 @@ def forward(
             positions, kv_limit, batch_idx, token_mask, write_mask,
             block_tables, q_lens, win)
         new_k, new_v = state.k, state.v
+        if cfg.latent:
+            new_ik = state.lat          # (the leaf's place: see below)
         for name, n in counts.items():
             if counted[name] is not None:
                 counted[name] = counted[name] + n

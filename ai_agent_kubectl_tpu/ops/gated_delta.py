@@ -156,7 +156,7 @@ def _block_heads(H: int, dk: int, dv: int, itemsize: int) -> int:
 
 
 def _step_kernel(lyr_ref, order_ref, n_live_ref, kq_ref, vec_ref, s_ref,
-                 o_ref, s_out_ref, *, dv: int):
+                 o_ref, s_out_ref, *, dv: int, channel: bool = False):
     """Grid step (i, c): lane block c (``hb`` heads side by side) of the
     state of row ``order_ref[i]`` in layer ``lyr_ref[0]``, for the
     ``n_live_ref[0]`` rows that move (``order_ref`` names them first); a
@@ -169,10 +169,14 @@ def _step_kernel(lyr_ref, order_ref, n_live_ref, kq_ref, vec_ref, s_ref,
     the fewest whose lanes are whole 128-lane tiles (2 of 192 lanes: three
     tiles), in a loop whose body is traced once (a kernel is lowered anew
     for every program of every start: its jaxpr's length is ``setup_s``),
-    and a group a tile at a time."""
+    and a group a tile at a time. ``channel`` (Kimi delta attention): the
+    decay is a vector a head, one ``alpha`` a key channel; kq_ref then
+    holds it as a third run of columns [1,1,dk,3*hb], it multiplies the
+    ROWS of the head's tile before anything reads them, and vec_ref is
+    [1,2,W]: v and beta."""
     del lyr_ref, order_ref
     dk, W = s_ref.shape[-2:]
-    hb = kq_ref.shape[-1] // 2
+    hb = kq_ref.shape[-1] // (3 if channel else 2)
     G = next((n for n in range(1, hb) if hb % n == 0 and n * dv % 128 == 0),
              hb)
     T = 128 if G * dv % 128 == 0 else G * dv
@@ -214,6 +218,17 @@ def _step_kernel(lyr_ref, order_ref, n_live_ref, kq_ref, vec_ref, s_ref,
             lanes = pl.ds(pl.multiple_of(p * (G * dv) + lo, 128), T)
             S = s_ref[0, 0, :, lanes].astype(jnp.float32)
             vec = vec_ref[0, :, lanes]
+            if channel:
+                # S' = Diag(alpha) S; S = S' + k u^T; o = S^T q
+                S = down_the_lanes(2 * hb) * S
+                v, beta = vec[0:1], vec[1:2]
+                u = beta * (v - jnp.sum(S * kx, axis=0, keepdims=True))
+                s_out_ref[0, 0, :, lanes] = (S + kx * u).astype(
+                    s_out_ref.dtype)
+                o_ref[0, :, lanes] = (
+                    jnp.sum(S * qx, axis=0, keepdims=True)
+                    + jnp.sum(kx * qx, axis=0, keepdims=True) * u)
+                continue
             v, alpha, beta = vec[0:1], vec[1:2], vec[2:3]
             Sk = jnp.sum(S * kx, axis=0, keepdims=True)             # [1, T]
             Sq = jnp.sum(S * qx, axis=0, keepdims=True)
@@ -246,8 +261,10 @@ def gated_delta_step_kernel(q, k, v, g, beta, state, layer, moves=None,
     has its state neither read nor written (the rows that move take the
     grid's first steps, the others' steps name the block before them
     again) and its output is zeros. Other arguments as
-    ``gated_delta_step``'s. Returns (o [B,1,H,dv] float32, the leaf). Off
-    the TPU the kernel runs interpreted."""
+    ``gated_delta_step``'s; ``g`` [B,1,H,dk] is a decay a key channel
+    (``channel_decay_step``'s rule: it multiplies the rows of a head's tile).
+    Returns (o [B,1,H,dv] float32, the leaf). Off the TPU the kernel runs
+    interpreted."""
     return _step_call(q, k, v, g, beta, state, layer, moves,
                       block_heads=block_heads,
                       interpret=jax.default_backend() != "tpu")
@@ -267,8 +284,11 @@ def _step_call(q, k, v, g, beta, state, layer, moves, *, block_heads: int,
     nb, W = H // hb, hb * dv
     f32 = lambda a: a[:, 0].astype(jnp.float32)
     q, k, v, g, beta = f32(q), f32(k), f32(v), f32(g), f32(beta)
+    channel = g.ndim == 3               # [B, H, dk]: a decay a key channel
     if moves is None:
-        moves = jnp.any(jnp.logical_or(g != 0, beta != 0), axis=-1)    # [B]
+        moves = jnp.any(jnp.logical_or(
+            jnp.any(g != 0, axis=-1) if channel else g != 0, beta != 0),
+            axis=-1)                                                   # [B]
     # the rows that move first, in their order, then the others (a stable
     # argsort, as comparisons: a sort of 8 is a program of its own a layer)
     m = moves.astype(jnp.int32)
@@ -281,9 +301,13 @@ def _step_call(q, k, v, g, beta, state, layer, moves, *, block_heads: int,
     lanes = lambda a: jnp.repeat(a, dv, axis=-1)                    # [B, L]
     # a block's heads' keys then queries, each a column: [B, nb, dk, 2*hb]
     cols = lambda a: jnp.swapaxes(a.reshape(B, nb, hb, dk), 2, 3)
-    kq = jnp.concatenate([cols(k), cols(q)], axis=-1)
-    vec = jnp.stack([v.reshape(B, L), lanes(jnp.exp(g)), lanes(beta)],
-                    axis=1)                                         # [B, 3, L]
+    if channel:
+        kq = jnp.concatenate([cols(k), cols(q), cols(jnp.exp(g))], axis=-1)
+        vec = jnp.stack([v.reshape(B, L), lanes(beta)], axis=1)     # [B, 2, L]
+    else:
+        kq = jnp.concatenate([cols(k), cols(q)], axis=-1)
+        vec = jnp.stack([v.reshape(B, L), lanes(jnp.exp(g)), lanes(beta)],
+                        axis=1)                                     # [B, 3, L]
     lyr = jnp.asarray(layer, jnp.int32).reshape(1)
 
     def plane(i, c, lyr, order, n_live):
@@ -296,16 +320,17 @@ def _step_call(q, k, v, g, beta, state, layer, moves, *, block_heads: int,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(B, nb),
-        in_specs=[pl.BlockSpec((1, 1, dk, 2 * hb),
+        in_specs=[pl.BlockSpec((1, 1, dk, kq.shape[-1]),
                                lambda i, c, lyr, order, n_live:
                                (order[i], c, 0, 0)),
-                  pl.BlockSpec((1, 3, W), row),
+                  pl.BlockSpec((1, vec.shape[1], W), row),
                   pl.BlockSpec((1, 1, dk, W), plane)],
         out_specs=[pl.BlockSpec((1, 1, W), row),
                    pl.BlockSpec((1, 1, dk, W), plane)],
     )
     o, state = pl.pallas_call(
-        partial(_step_kernel, dv=dv),
+        partial(_step_kernel, dv=dv, **({"channel": True} if channel
+                                         else {})),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, 1, L), jnp.float32),
                    jax.ShapeDtypeStruct(state.shape, state.dtype)],
@@ -409,6 +434,19 @@ def gated_delta_scan(q, k, v, g, beta, S0, chunk: int = 0):
     qk = jnp.einsum("bnhik,bnhjk->bnhij", qc, kc, precision=_HI) * decay
     to_end = jnp.exp(gamma[..., -1:] - gamma)[..., None] * kc   # [B,n,H,C,dk]
     end = jnp.exp(gamma[..., -1])[..., None, None]              # [B,n,H,1,1]
+    return _run_chunks(U0, Wm, qk, eg, qc, to_end, end, S0, S, H, dv)
+
+
+def _run_chunks(U0, Wm, qk, eg, qc, to_end, end, S0, S: int, H: int,
+                dv: int):
+    """The chunks one after another from the state ``S0`` [B,dk,H*dv]: what a
+    chunk's rows write given the state it starts with (``U0`` less ``Wm``
+    times it), what they read (``eg * qc`` from it, ``qk`` from the chunk's own
+    writes), and the state it leaves (``end`` times it, a scalar a head
+    [B,n,H,1,1] or a decay a key channel [B,n,H,dk,1], and ``to_end`` times
+    the writes). Returns ``gated_delta_scan``'s pair for the first ``S``
+    rows."""
+    B, n, _, C, dk = Wm.shape
 
     def body(Sh, xs):
         U0_, Wm_, qk_, qe_, to_end_, end_ = xs
@@ -425,15 +463,116 @@ def gated_delta_scan(q, k, v, g, beta, S0, chunk: int = 0):
         body, Sh, tuple(jnp.moveaxis(a, 1, 0)
                         for a in (U0, Wm, qk, eg * qc, to_end, end)))
     o = jnp.moveaxis(jnp.moveaxis(O, 0, 1), 2, 3)               # [B,n,C,H,dv]
-    o = o.reshape(B, S + pad, H, dv)[:, :S]
+    o = o.reshape(B, n * C, H, dv)[:, :S]
     S1 = jnp.moveaxis(Sh, 1, 2).reshape(B, dk, H * dv)
     return o, S1.astype(STATE_DTYPE)
 
 
-def gated_head_norm(o, z, w, eps: float):
+def channel_decay_step(q, k, v, g, beta, S0):
+    """``gated_delta_step`` with a decay for every key channel (Kimi delta
+    attention): g [B,1,H,dk], ``alpha = exp(g)`` multiplies the ROWS of a
+    head's state before the token reads and writes it,
+
+        S' = Diag(alpha_t) S_{t-1}      u_t = beta_t (v_t - S'^T k_t)
+        S_t = S' + k_t u_t^T            o_t = S_t^T q_t
+
+    (with a head's channels all equal it is ``gated_delta_step``). Plain
+    ``jnp`` on one plane; ``gated_delta_step_kernel`` takes the same ``g`` on
+    the whole leaf."""
+    B, _, H, dk = q.shape
+    dv = v.shape[-1]
+    f32 = lambda a: a[:, 0].astype(jnp.float32)
+    q, k, v, g, beta = f32(q), f32(k), f32(v), f32(g), f32(beta)
+    S = S0.astype(jnp.float32).reshape(B, dk, H, dv)
+    S = jnp.swapaxes(jnp.exp(g), 1, 2)[..., None] * S
+    u = beta[..., None] * (v - jnp.einsum("bkhv,bhk->bhv", S, k,
+                                          precision=_HI))
+    S = (S + jnp.einsum("bhk,bhv->bkhv", k, u, precision=_HI)
+         ).astype(STATE_DTYPE)
+    o = jnp.einsum("bkhv,bhk->bhv", S.astype(jnp.float32), q, precision=_HI)
+    return o[:, None], S.reshape(B, dk, H * dv)
+
+
+#: Rows of a window ``channel_decay_scan`` takes at once; more run in groups
+#: of this many, one group after another.
+_ROWS_AT_ONCE = 4
+
+
+def channel_decay_scan(q, k, v, g, beta, S0, chunk: int = 0):
+    """``gated_delta_scan`` with a decay for every key channel
+    (``channel_decay_step``'s rule): g [B,S,H,dk] <= 0, bounded below so that
+    ``_SOLVE_BLOCK`` rows' decays sum to no less than -88 (``ModelConfig.
+    lin_decay_floor``: 16 x -5 = -80). The chunked form is the scalar one
+    with the decay INSIDE each product of a row i with a row j <= i,
+
+        A_ij = beta_i sum_c k_ic k_jc exp(gamma_ic - gamma_jc)
+
+    which does not factor over a chunk (``exp(-gamma_j)`` overflows: 64 rows
+    at the floor are -320) and would be a [C, C, d_k] tensor unfactored. It
+    factors over a BLOCK of 16 columns against the block's last row: for
+    column block J, row i >= J's first brings ``k_i exp(gamma_i - gamma_J)``
+    (at most e^75 for J's own rows, at most 1 for later ones; earlier rows
+    are above the diagonal and left out) and column j ``k_j exp(gamma_J -
+    gamma_j)`` (at most 1): one product [rows, d_k] x [d_k, 16] a block, on
+    the MXU, its operands a chunk's rows at the most (all four blocks' at
+    once were 1.6 GiB of a 16 x 512 window's temporaries: AOT, PR 48). Everything else is ``gated_delta_scan``'s: the unit-triangular
+    inverse by blocks, the chunks one after another (``_run_chunks``)."""
+    B, S, H, dk = q.shape
+    dv = v.shape[-1]
+    if S == 1:
+        return channel_decay_step(q, k, v, g, beta, S0)
+    if B > _ROWS_AT_ONCE:
+        # (a mixed window's 16 rows of 512 columns: a dozen float32
+        # [B,n,H,C,dk] temporaries at once were 1.6 GiB beside the weights)
+        row = lambda xs: tuple(a[0] for a in channel_decay_scan(
+            *(x[None] for x in xs), chunk))
+        return jax.lax.map(row, (q, k, v, g, beta, S0),
+                           batch_size=_ROWS_AT_ONCE)
+    b = min(_SOLVE_BLOCK, S, chunk or CHUNK)
+    C = min(chunk or CHUNK, -(-S // b) * b)
+    if C % b:
+        raise ValueError(f"chunk {C} is not whole blocks of {b} rows")
+    pad, nb = -S % C, C // b
+    n = (S + pad) // C
+
+    def chunks(a):      # [B, S, H, ...] -> [B, n, H, C, ...]
+        a = jnp.pad(a.astype(jnp.float32),
+                    ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+        return jnp.moveaxis(a.reshape((B, n, C) + a.shape[2:]), 3, 2)
+
+    qc, kc, vc, gc, bc = (chunks(a) for a in (q, k, v, g, beta))
+    gamma = jnp.cumsum(gc, axis=-2)                         # [B,n,H,C,dk] <= 0
+    kk, qk = [], []
+    for J in range(nb):
+        # column block J against the rows at or below it (the rows above
+        # are above the diagonal: zeros), both sides relative to J's last row
+        edge = gamma[..., J * b + b - 1, None, :]           # [B,n,H,1,dk]
+        cols = slice(J * b, J * b + b)
+        right = kc[..., cols, :] * jnp.exp(edge - gamma[..., cols, :])
+        left = jnp.exp(gamma[..., J * b:, :] - edge)        # [B,n,H,C-Jb,dk]
+        for a, out in ((kc, kk), (qc, qk)):
+            out.append(jnp.pad(
+                jnp.einsum("bnhic,bnhjc->bnhij", a[..., J * b:, :] * left,
+                           right, precision=_HI),
+                ((0, 0),) * 3 + ((J * b, 0), (0, 0))))
+    tril = jnp.tril(jnp.ones((C, C), bool))
+    A = jnp.where(jnp.tril(tril, -1),
+                  bc[..., None] * jnp.concatenate(kk, axis=-1), 0.0)
+    eg = jnp.exp(gamma)
+    rhs = jnp.concatenate([bc[..., None] * vc, bc[..., None] * eg * kc], -1)
+    X = _unit_lower_solve(A, rhs)
+    qk = jnp.where(tril, jnp.concatenate(qk, axis=-1), 0.0)
+    to_end = jnp.exp(gamma[..., -1:, :] - gamma) * kc       # [B,n,H,C,dk]
+    end = jnp.exp(gamma[..., -1, :])[..., None]             # [B,n,H,dk,1]
+    return _run_chunks(X[..., :dv], X[..., dv:], qk, eg, qc, to_end, end,
+                       S0, S, H, dv)
+
+
+def gated_head_norm(o, z, w, eps: float, gate=jax.nn.silu):
     """RMSNorm over each head's ``d_v`` values of ``o`` [..., H, dv], times
-    the gain ``w`` [dv], THEN times ``silu(z)`` (norm first, the gate
-    after: the reverse of Mamba-2's ``gated_group_norm``). float32."""
+    the gain ``w`` [dv], THEN times ``gate(z)`` (norm first, the gate
+    after: the reverse of Mamba-2's ``gated_group_norm``; ``silu`` is the
+    gated delta rule's, ``sigmoid`` Kimi delta attention's). float32."""
     o = o.astype(jnp.float32)
     o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps)
-    return o * w.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    return o * w.astype(jnp.float32) * gate(z.astype(jnp.float32))

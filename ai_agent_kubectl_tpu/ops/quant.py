@@ -336,8 +336,17 @@ _QUANT_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
                "sw_wq", "sw_wk", "sw_wv", "sw_wo",
                "dense_gate", "dense_up", "dense_down",
                # a linear-attention layer's fused in-projection and its
-               # output projection (W_a, W_b stay bf16: small leaves)
-               "lin_in", "lin_out")
+               # output projection (W_a, W_b stay bf16: small leaves), and
+               # the projection of a decay a key channel (W_f: a full matrix)
+               "lin_in", "lin_out", "lin_wf")
+
+
+#: The decay projection of Kimi delta attention as this SEEDED generator
+#: draws it, in units of the other projections' scale: the argument of the
+#: decay's sigmoid is A_h (x W_f + bias) with A_h up to 16, and at 1 every
+#: channel of the heads with a large A would sit at the floor or at none
+#: (models/transformer.py::small_leaf_init has the bias and the spread).
+SEEDED_DECAY_PROJ_GAIN = 0.25
 
 
 #: The per-head q/k RMSNorm gains this SEEDED generator writes (bench/dev
@@ -477,7 +486,8 @@ def _random_params_int8(key, cfg, dtype, quantize_embed: bool, int4: bool,
             sshape = tuple(1 if i == len(sds.shape) - 2 else s
                            for i, s in enumerate(sds.shape))
             # Plausible magnitude: absmax ≈ the init scale init_params uses.
-            scale = _jnp.full(sshape, (sds.shape[-2] ** -0.5) / 127.0,
+            gain = SEEDED_DECAY_PROJ_GAIN if name == "lin_wf" else 1.0
+            scale = _jnp.full(sshape, gain * (sds.shape[-2] ** -0.5) / 127.0,
                               _jnp.float32)
             out.append(QuantInt8(q=q, scale=scale))
         elif (small := small_leaf_init(name, sds.shape, dtype, k)) is not None:

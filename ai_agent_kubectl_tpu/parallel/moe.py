@@ -66,11 +66,24 @@ def top_k_routing(cfg: ModelConfig, logits: jnp.ndarray, bias=None):
     them. ``sigmoid_bias`` (the DeepSeek-V3 router nemotron_h takes): the
     k largest of sigmoid(logits) + ``bias`` [E] (a learned selection
     bias that enters nothing else), the picked scores divided by their
-    sum, times ``router_scale``."""
+    sum, times ``router_scale``. With ``n_group`` > 1 the choice is
+    group-limited: the E scored experts are ``n_group`` groups side by
+    side, a group's score the sum of its two largest score + bias, and
+    only the experts of the ``topk_group`` best groups can be picked."""
     k = cfg.experts_per_token
     if cfg.router == "sigmoid_bias":
         s = jax.nn.sigmoid(logits)
-        _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), k)
+        choice = s + bias.astype(jnp.float32)
+        if cfg.n_group > 1:
+            with jax.named_scope("group_choice"):
+                groups = choice.reshape(choice.shape[:-1] + (cfg.n_group, -1))
+                score = jnp.sum(jax.lax.top_k(groups, 2)[0], axis=-1)
+                _, best = jax.lax.top_k(score, cfg.topk_group)
+                stays = jnp.any(best[..., None] == jnp.arange(cfg.n_group),
+                                axis=-2)                      # [..., n_group]
+                choice = jnp.where(stays[..., None], groups,
+                                   -jnp.inf).reshape(choice.shape)
+        _, idx = jax.lax.top_k(choice, k)
         w = jnp.take_along_axis(s, idx, axis=-1)
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
         return w * cfg.router_scale, idx
@@ -527,8 +540,18 @@ _GROUPED_VMEM_BYTES = 64 * 2**20
 def grouped_moe(cfg: ModelConfig, lp: Dict[str, Any], x: jnp.ndarray,
                 token_mask: Optional[jnp.ndarray] = None,
                 first_expert: int = 0, layer=None):
+    """``grouped_moe_counted`` less the picks: (y, experts_read)."""
+    return grouped_moe_counted(cfg, lp, x, token_mask, first_expert,
+                               layer)[:2]
+
+
+def grouped_moe_counted(cfg: ModelConfig, lp: Dict[str, Any], x: jnp.ndarray,
+                        token_mask: Optional[jnp.ndarray] = None,
+                        first_expert: int = 0, layer=None):
     """Top-k MoE that computes only picked experts: x [B, S, D] ->
-    (y [B, S, D], experts_read int32).
+    (y [B, S, D], experts_read int32, int32 [2]: the picks the live rows
+    made among all the experts scored, and those of them that fell on an
+    expert held here).
 
     The router scores all ``cfg.n_experts``; this device holds the
     experts of its leaves ([E, in, out], or with ``layer`` the whole
@@ -551,9 +574,12 @@ def grouped_moe(cfg: ModelConfig, lp: Dict[str, Any], x: jnp.ndarray,
     # (token, pick) pairs -> local expert id; ``held`` = no expert here.
     e = top_idx.astype(jnp.int32) - first_expert
     e = jnp.where(jnp.logical_and(e >= 0, e < held), e, held)
+    live = jnp.asarray(T, jnp.int32)
     if token_mask is not None:
         e = jnp.where(token_mask.reshape(T, 1) > 0, e, held)
+        live = jnp.sum(token_mask > 0, dtype=jnp.int32)
     e = e.reshape(T * k)
+    picks = jnp.stack([live * k, jnp.sum(e < held, dtype=jnp.int32)])
     M = T * k
     # a share of the experts expects its share of the pairs (a tile's
     # rows); the grid still has room for every pair landing here
@@ -593,4 +619,4 @@ def grouped_moe(cfg: ModelConfig, lp: Dict[str, Any], x: jnp.ndarray,
     w = jnp.where(picked.reshape(T, k), top_w, 0.0)
     y = jnp.einsum("tkd,tk->td", ys[rows].astype(jnp.float32), w)
     return (y.astype(x.dtype).reshape(B, S, D),
-            jnp.sum(sizes > 0, dtype=jnp.int32))
+            jnp.sum(sizes > 0, dtype=jnp.int32), picks)

@@ -53,18 +53,27 @@ def pytest_pyfunc_call(pyfuncitem):
     return None
 
 
-#: The long files, longest first (seconds on the CPU under six workers at
-#: PR 44: 541, 429, 388, 370, 315, 244, 202, 190, 157; the next is 97).
-#: Under ``--dist loadfile`` a file is one worker's. xdist hands files out by
-#: their NUMBER of tests, most first, so a long file of few tests (the
-#: sliding model against its reference: 4 tests, 388 s) started last, ~680 s
-#: into the run, and the run waited for it (ROADMAP D10). Here they are
-#: handed out in the order collected, and these are collected first.
+#: The long files (seconds on the CPU under six workers at PR 48: 489, 677,
+#: 580, 523, 289, 317, 244, 178, 191, 249, 334; the next is 100). Under
+#: ``--dist loadfile`` a file is one worker's. xdist hands files out by their
+#: NUMBER of tests, most first, so a long file of few tests (the sliding
+#: model against its reference: 4 tests, 388 s) started last, ~680 s into the
+#: run, and the run waited for it (ROADMAP D10). Here they are handed out in
+#: the order collected, and these are collected first; the first six start
+#: together. (All longest first was tried at PR 48 and is slower: the
+#: compiles of the heaviest files then contend from the first second, 1,227
+#: s against 1,065.)
 _LONG_FILES = ("test_sparse_attention.py", "test_tpu_aot.py",
-               "test_reference_logits_sliding.py", "test_sliding_attention.py",
-               "test_latent_attention.py", "test_window_staging.py",
-               "test_packed_window.py", "test_state_cache.py",
-               "test_hybrid_model.py", "test_linear_attention.py")
+               "test_kda_latent.py", "test_reference_logits_sliding.py",
+               "test_sliding_attention.py", "test_latent_attention.py",
+               "test_window_staging.py", "test_packed_window.py",
+               "test_state_cache.py", "test_hybrid_model.py",
+               "test_linear_attention.py")
+#: Files that hold the program to a clock (a 6 ms step against a 50 ms fault)
+#: are collected last: they then run while the other workers are finishing
+#: short files, not under a long file's compiles (three whole runs of three
+#: at PR 48 tripped the sentinel's healthy phase under load).
+_LAST_FILES = ("test_sentinel.py",)
 
 
 def pytest_configure(config):
@@ -76,7 +85,8 @@ def pytest_collection_modifyitems(items):
     """The same order in every worker (xdist requires it): a stable sort,
     so the order inside a file and among the other files is kept."""
     rank = {name: i for i, name in enumerate(_LONG_FILES)}
-    items.sort(key=lambda item: rank.get(item.path.name, len(rank)))
+    rank.update({name: len(rank) + 1 for name in _LAST_FILES})
+    items.sort(key=lambda item: rank.get(item.path.name, len(_LONG_FILES)))
 
 
 FAKE_KUBECTL = r"""#!/usr/bin/env python3

@@ -408,6 +408,86 @@ def case_the_olmo_hybrid_configuration_is_its_source_whole():
         modelmap.model_config("x", modelmap.sizes(lacking), modelmap.key_map(lacking))
 
 
+#: https://huggingface.co/inclusionAI/Ling-3.0-flash-VL/blob/main/config.json as
+#: the catalog beside the model-configs guide has it: every number of it.
+LING3_FLASH_SOURCE = {
+    "image_patch_token": 157157, "video_patch_token": 156909, "image_start_token": 157158,
+    "video_start_token": 157160, "num_hidden_layers": 42, "hidden_size": 2560,
+    "intermediate_size": 6144, "first_k_dense_replace": 2, "max_position_embeddings": 131072,
+    "moe_intermediate_size": 768, "num_experts_per_tok": 8, "num_attention_heads": 32,
+    "kv_lora_rank": 512, "qk_nope_head_dim": 128, "qk_rope_head_dim": 64, "v_head_dim": 128,
+    "num_experts": 512, "num_key_value_heads": 32, "rope_theta": 6000000, "rms_norm_eps": 1e-06,
+    "head_dim": 128, "vocab_size": 157184, "partial_rotary_factor": 0.5,
+    "routed_scaling_factor": 2.5, "n_group": 8, "topk_group": 4,
+    "moe_shared_expert_intermediate_size": 768, "layer_group_size": 6,
+    "num_kv_heads_for_linear_attn": 0, "group_norm_size": 1, "rotary_dim": 64,
+    "short_conv_kernel_size": 4, "kda_lower_bound": -5}
+
+
+def case_the_ling3_flash_configuration_keeps_every_width_and_says_its_two_cuts():
+    """ling-3.0-flash-vl-l12 (PR 48): every number of the source under its own
+    key but the two the file lists under ``reduced`` (depth, and the experts
+    this chip holds: the published 512 stays the router's width), the order's
+    rule said again flat as the two-mixers-a-layer pattern, every mapped key
+    reaching the ModelConfig, the cell found by its name."""
+    import serve
+
+    cfg = shipped("ling-3.0-flash-vl-l12")
+    differ = {k for k, v in LING3_FLASH_SOURCE.items() if cfg.get(k) != v}
+    assert differ == {"num_hidden_layers", "num_experts"} == set(cfg["reduced"])
+    assert {k: (v["source"], v["here"]) for k, v in cfg["reduced"].items()} == {
+        "num_hidden_layers": (42, 12), "num_experts": (512, 128)}
+    assert cfg["q_lora_rank"] is None and "q_lora_rank" not in cfg["keys"]
+    assert cfg["score_function"] == "sigmoid" and cfg["moe_router_enable_expert_bias"] is True
+    assert cfg["expert_swiglu_limit_list"][:12] == [0] * 12 == cfg[
+        "share_expert_swiglu_limit_list"][:12] and len(cfg["expert_swiglu_limit_list"]) == 42
+    # the order, by the source's own rule
+    mixers = "".join(("*" if (i + 1) % cfg["layer_group_size"] == 0 else "L")
+                     + ("D" if i < cfg["first_k_dense_replace"] else "E") for i in range(12))
+    assert cfg["layer_mixers"] == mixers and cfg["mixers_per_layer"] == 2
+    model, sz = serve.register(cfg)
+    assert (model.n_layers, model.dim, model.n_heads, model.vocab_size) == (12, 2560, 32, 157184)
+    assert (model.dense_mlp_hidden, model.mlp_hidden, model.shared_mlp_hidden) == (6144, 768, 768)
+    assert (model.n_experts, model.experts_scored, model.first_expert,
+            model.experts_per_token) == (128, 512, 0, 8)
+    assert (model.n_group, model.topk_group, model.router, model.router_scale) == (
+        8, 4, "sigmoid_bias", 2.5)
+    assert (model.kv_lora_rank, model.q_lora_rank, model.qk_nope_head_dim,
+            model.qk_rope_head_dim, model.v_head_dim, model.latent_row) == (512, 0, 128, 64, 128, 576)
+    assert (model.lin_key_heads, model.lin_value_heads, model.lin_key_dim, model.lin_value_dim,
+            model.lin_conv, model.lin_channel_decay, model.lin_decay_floor,
+            model.lin_out_gate) == (32, 32, 128, 128, 4, True, -5, "sigmoid")
+    assert model.rope_theta == 6000000 and model.rope_factor == 1.0 and not model.rope_interleave
+    assert model.latent and model.has_linear and model.keeps_state and model.grouped_experts
+    assert (model.n_of("L"), model.n_of("*"), model.n_of("D"), model.n_of("E")) == (10, 2, 2, 10)
+    sizing, env = cfg["sizing"], cfg["server_env"]
+    assert model.param_count() == sizing["param_count"]
+    assert round(model.param_count() / 1e9, 2) == sizing["weights_GB"] == 9.22
+    assert model.state_bytes() == sizing["state_bytes_per_sequence"] == 21708800
+    assert sizing["cache_bytes_per_token"] == 2 * 576 * 2
+    assert int(env["KV_POOL_BLOCKS"]) * int(env["KV_POOL_PAGE"]) == sizing["pool_tokens"]
+    assert round(sizing["pool_tokens"] * 2304 / 1e9, 2) == sizing["pool_GB"] == 1.21
+    assert round(int(env["STATE_SNAPSHOTS"]) * 21708800 / 1e9, 2) == sizing["snapshot_store_GB"]
+    assert round(int(env["DECODE_BATCH_SIZE"]) * 21708800 / 1e9, 2) == sizing["live_states_GB"]
+    assert modelmap.mesh_problems(cfg, 1) == [] and modelmap.mesh_of(cfg) == {}
+    bench = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
+    cell, entry, file, mix = R.resolve_cell(bench, "ling3flash-l12-xlonglogs-replay")
+    assert (cell["chips"], entry["reduced"], file) == (1, list(cfg["reduced"]), cfg)
+    assert entry["source"] == cfg["source"] == (
+        "https://huggingface.co/inclusionAI/Ling-3.0-flash-VL/blob/main/config.json")
+    assert mix["name"] == "xlong-logs-replay" and mix["clients"]["from_server_env"] == \
+        "DECODE_BATCH_SIZE" and env["DECODE_BATCH_SIZE"] == "16"
+    assert R.child_env(cfg, 1, True)["MODEL_NAME"] == "toy-kda-mla-moe"
+    ref = refcheck_module().load_reference(cfg["reference"])
+    assert callable(ref.forward) and callable(ref.weights_from_program)
+    assert [m for m, _ in ref.order({}, 12)] == ["kda"] * 5 + ["latent"] + ["kda"] * 5 + ["latent"]
+    assert [m for _, m in ref.order({}, 12)] == ["dense"] * 2 + ["experts"] * 10
+    # a program without the fields ends at once, by name (what the parent does)
+    lacking = dict(cfg, keys=dict(cfg["keys"], n_group="no_such_field"))
+    with pytest.raises(SystemExit, match="n_group maps to ModelConfig.no_such_field"):
+        modelmap.model_config("x", modelmap.sizes(lacking), modelmap.key_map(lacking))
+
+
 def refcheck_module():
     import refcheck
 

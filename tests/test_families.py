@@ -30,7 +30,9 @@ REPO = Path(__file__).resolve().parent.parent
 #: a toy of each kind; plain GQA is of none
 TOYS = {"gqa": "toy-8m", "selecting": "toy-sparse-moe",
         "recurrent": "toy-hybrid-moe", "sliding": "toy-sliding-moe",
-        "latent": "toy-mla-moe", "linear": "toy-linear-hybrid"}
+        "latent": "toy-mla-moe", "linear": "toy-linear-hybrid",
+        # of two kinds on one count lane: a matrix state beside latent rows
+        "kda": "toy-kda-mla-moe"}
 
 # ------------------------------------------------ what a kind cannot ride
 
@@ -48,6 +50,8 @@ _SLIDES = ("toy-sliding-moe keeps a sliding-attention state (layer_pattern "
 _LINEAR = ("toy-linear-hybrid keeps a linear-attention state (layer_pattern "
            "'LDLDLD*DLDLDLD*D') and is not served here: ")
 _LATENT = "toy-mla-moe keeps a latent cache (kv_lora_rank=32) and is not served here: "
+_KDA = ("toy-kda-mla-moe keeps a linear-attention state (layer_pattern "
+        "'LDLE*ELELE*E') and is not served here: ")
 _NO_STATE = ("the dense per-slot KV ladder keeps no bounded state a sequence, recurrent "
              "or sliding, and would attend a sliding layer to every key (KV_POOL=false, "
              "or a mesh axis the pool refuses)")
@@ -88,6 +92,13 @@ REFUSALS = {
         "projections no rule in parallel/sharding.py"),
     ("latent", "spec"): _LATENT + (
         "SPEC_DECODE: draft/verify windows are untried over latent rows"),
+    # the union of its two kinds' lists, the linear kind's heard first
+    ("kda", "dense"): _KDA + _NO_STATE,
+    ("kda", "kv_quant"): (
+        "toy-kda-mla-moe keeps a latent cache (kv_lora_rank=32) and is not served here: "
+        "KV_QUANT=int8: the latent rows are kept in bf16"),
+    ("kda", "mesh"): _KDA + _NO_RULE,
+    ("kda", "spec"): _KDA + _NO_REWIND,
 }
 
 
@@ -108,7 +119,8 @@ def test_a_kind_tests_its_obstacles_in_its_own_order():
                      "recurrent": ("dense", "mesh", "spec"),
                      "sliding": ("dense", "mesh", "spec", "kv_quant"),
                      "linear": ("dense", "mesh", "spec"),
-                     "latent": ("dense", "kv_quant", "mesh", "spec")}
+                     "latent": ("dense", "kv_quant", "mesh", "spec"),
+                     "expert_share": ()}
     assert all(set(k.refuses) <= set(OBSTACLES) for k in CACHE_KINDS)
     both = ("ragged", {"model": 2}, "int8", False)
     assert "KV_QUANT=int8" in cache_refusal(get_config("toy-mla-moe"), *both)
@@ -149,6 +161,12 @@ POOLS = {
                      ".lengths": ((12,), _I32),
                      ".lin": ((6, 3, 24, 160), "float32"),
                      ".lconv": ((6, 3, 3, 352), _BF16), ".lin_rows": ((6,), _I32)},
+    # no K, no V: a plane a LATENT layer (2 of the 6) beside a plane a linear one
+    ("kda", ""): {".lengths": ((12,), _I32), ".experts_read": ((), _I32),
+                  ".lat": ((2, 12, 8, 96), _BF16), ".lat_rows": ((2,), _I32),
+                  ".lin": ((4, 3, 24, 160), "float32"),
+                  ".lconv": ((4, 3, 3, 352), _BF16), ".lin_rows": ((6,), _I32),
+                  ".expert_picks": ((2,), _I32)},
 }
 #: a snapshot store's leaves, 5 rows
 SNAPSHOTS = {"recurrent": {"ssm": ((2, 5, 8, 16, 32), "float32"),
@@ -156,7 +174,9 @@ SNAPSHOTS = {"recurrent": {"ssm": ((2, 5, 8, 16, 32), "float32"),
              "sliding": {"sk": ((3, 5, 24, 2, 32), _BF16),
                          "sv": ((3, 5, 24, 2, 32), _BF16)},
              "linear": {"lin": ((6, 5, 24, 160), "float32"),
-                        "lconv": ((6, 5, 3, 352), _BF16)}}
+                        "lconv": ((6, 5, 3, 352), _BF16)},
+             "kda": {"lin": ((4, 5, 24, 160), "float32"),
+                     "lconv": ((4, 5, 3, 352), _BF16)}}
 
 
 def _leaves(tree):
@@ -178,8 +198,11 @@ def test_the_pool_cache_is_built_beside_the_model(kind, kv_quant):
                                           dtype=jnp.bfloat16)
         assert {n: (a.shape, str(a.dtype)) for n, a in snap.items()} == SNAPSHOTS[kind]
     # the count lane of the packed chunk is as wide as the kind's leaf
-    words = {"gqa": 0, "selecting": 2, "recurrent": 0, "sliding": 4, "latent": 2,
-             "linear": 6}
+    # (a chip's share of the experts under a group limit adds its two; the
+    # latent and the sliding toy hold 4 of 16 under a plain top-k router and
+    # keep their lanes; two kinds on the lane lie end to end)
+    words = {"gqa": 0, "selecting": 2, "recurrent": 0, "sliding": 4,
+             "latent": 2, "linear": 6, "kda": 6 + 2 + 2}
     assert attention_words(cfg) == words[kind]
     assert long_prompts(cfg) == (kind != "gqa")
 
@@ -193,7 +216,7 @@ _SSM = [*_STORE, "forward_passes", "eager_prefill_passes", "live_rows", "layer_p
 #: section -> (the toy that gives it, its keys)
 HEALTH = {
     "moe": ("selecting", ["experts_read", "layer_passes", "experts_held", "first_expert",
-                          "router_width", "kernel"]),
+                          "router_width", "n_group", "topk_group", "kernel"]),
     "sparse_attention": ("selecting", ["index_rows_scanned", "window_rows",
                                        "forward_passes", "decode_rows_live",
                                        "decode_rows_selected"]),
@@ -271,6 +294,20 @@ def test_the_sections_count_what_the_scheduler_counted():
         "full_keys_read", "decode_rows_still")] == [10, 11, 12, 13, 14, 15]
     assert lin["ssm"]["layer_passes"]["linear"] == 5 * 6
     assert lin["ssm"]["state_bytes"] == get_config("toy-linear-hybrid").state_bytes()
+    # a chip's share of the experts under a plain top-k router counts no
+    # picks (its programs are the parent's); under a group limit, see below
+    assert "picks" not in _sections("latent")["moe"]
+    # two kinds on the lane: the linear kind's six words, the latent kind's
+    # two (over its 2 latent layers), the share's two
+    kda = _sections("kda")
+    assert [kda["linear_attention"][k] for k in (
+        "decode_rows_linear", "full_keys_read", "decode_rows_still")] == [10, 14, 15]
+    assert [kda["latent_attention"][k] for k in ("layers", "decode_rows", "latent_rows_read")] \
+        == [2, 16 // 2, 17]
+    assert [kda["moe"][k] for k in ("picks", "picks_held", "n_group", "topk_group")] \
+        == [18, 19, 4, 2]
+    assert kda["ssm"]["layer_passes"] == {"ssm": 0, "experts": 25, "attention": 10,
+                                          "sliding": 0, "dense_mlp": 5, "linear": 20}
 
 
 def _health_paths():
@@ -304,6 +341,8 @@ def test_every_health_path_the_benchmark_reads_resolves():
     assert ("state_snapshots_held_peak", ("ssm", "held_peak")) in paths
     for metric, (section, *keys) in paths:
         body = _sections(HEALTH[section][0])[section]
+        if keys[0] not in body:     # a chip's share adds its keys to /health.moe
+            body = _sections("kda")[section]
         for key in keys:
             assert key in body, f"{metric}: /health.{section}.{key} is gone"
             body = body[key]
@@ -367,3 +406,5 @@ def test_the_description_imports_without_jax():
             "sys.exit('jax' in sys.modules)")
     assert subprocess.run([sys.executable, "-c", code], cwd=REPO).returncode == 0
     assert [k.name for k in kinds_of(get_config("toy-sliding-moe"))] == ["experts", "sliding"]
+    assert [k.name for k in kinds_of(get_config("toy-kda-mla-moe"))] == [
+        "experts", "linear", "latent", "expert_share"]

@@ -319,7 +319,7 @@ def test_four_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer(ref):
                        for k, v in lp.items()}
                 y, n = _moe_mlp(cfg, cut, x, moe_impl=moe_impl)
                 parts.append(y)
-                read += 0 if n is None else int(n)
+                read += int(n.get("experts_read", 0))
             np.testing.assert_allclose(np.asarray(sum(parts)), np.asarray(uncut), atol=2e-5)
             assert moe_impl == "dense" or 4 <= read <= 16
         # the reference, told the same shares: routed parts + the shared

@@ -1105,3 +1105,143 @@ def test_linear_chunk_program_and_copy_on_write_compile_on_v5e(one_chip,
     mem = cow.lower(cache, scalar, scalar, scalar).compile().memory_analysis()
     assert mem.alias_size_in_bytes >= 2 * math.prod(cache.k.shape) * 2
     assert mem.temp_size_in_bytes < 2 ** 24
+
+
+# ------ Kimi delta attention beside latent attention in one pattern (ISSUE 48)
+
+LING = dict(vocab_size=157184, dim=2560, n_heads=32, n_kv_heads=32,
+            head_dim=128, mlp_hidden=768, dense_mlp_hidden=6144,
+            shared_mlp_hidden=768, n_experts=128, router_width=512,
+            experts_per_token=8, router="sigmoid_bias", router_scale=2.5,
+            n_group=8, topk_group=4, eos_ids=(2,), mixers_per_layer=2,
+            layer_pattern="LDLDLELELE*E" + "LELELELELE*E",
+            lin_key_heads=32, lin_value_heads=32, lin_key_dim=128,
+            lin_value_dim=128, lin_conv=4, lin_channel_decay=True,
+            lin_decay_floor=-5.0, lin_out_gate="sigmoid", kv_lora_rank=512,
+            qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+            rope_theta=6e6, attn_gate="head_wise")
+
+
+@pytest.mark.parametrize("layers,B,W,packed", [(12, 16, 1, None),
+                                               (6, 16, 512, 528),
+                                               (6, 1, 512, None)],
+                         ids=["decode-full-depth", "window-512", "eager-512"])
+def test_kda_latent_forward_compiles_at_published_widths_on_v5e(
+        one_chip, layers, B, W, packed, monkeypatch):
+    """ling-3.0-flash-vl-l12 (ten KDA layers, two latent ones, two dense MLPs,
+    ten expert layers of 128 held of 512; every width as published, the cell's
+    512-page table over its 8,192-block pool; the decode program at its full
+    depth, a window's at one whole period): Mosaic accepts the latent kernel
+    at rows of 576 values (a pair of tokens a 1,152-lane leaf row, queries of
+    768 lanes), the step kernel with a decay a key channel ([128, 2,048]
+    blocks of 16 heads, the decay a third run of columns beside keys and
+    queries), and the grouped expert kernel at 128 held experts of 768. The
+    latent leaf [2, 8192, 32, 1152] has a plane a LATENT layer and rides the
+    donated cache with the matrix state [10, B, 128, 4096]; a decode pass
+    names the state leaf in its ten step kernels alone and moves no plane of
+    it; a window writes a plane in place. The 16 x 512 window's temporaries
+    stay under 1.5 GiB because the scan with a decay a key channel takes four
+    rows at a time (ops/gated_delta.py::_ROWS_AT_ONCE: a dozen float32
+    [B,n,H,C,dk] arrays at once were 1.6 GiB at full depth beside 10.1 GiB of
+    arguments; 1.23 now, the file's ``sizing`` has the full-depth readings)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = ModelConfig(name="aot-ling", n_layers=layers, **LING)
+    page, n_blocks, pages = 64, 8192, 512
+    nL, nA, nE = cfg.n_of("L"), cfg.n_of("*"), cfg.n_of("E")
+    assert (nL, nA, nE) == ((10, 2, 10) if layers == 12 else (5, 1, 4))
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda x: arg(x.shape, x.dtype),
+        jax.eval_shape(lambda k: random_params_int8(
+            k, cfg, dtype=jnp.bfloat16, quantize_embed=True),
+            jax.random.PRNGKey(0)))
+    assert params["layers"]["lin_wf"].q.shape == (nL, 2560, 4096)
+    assert params["layers"]["wq"].q.shape == (nA, 2560, 32 * 192)
+    assert params["layers"]["router_bias"].shape == (nE, 512)
+    cache = _engine_cache(arg, cfg, n_blocks, page, B)
+    lat, lin = cache.lat.shape, cache.lin.shape
+    assert cache.k is None and lat == (nA, n_blocks, 32, 1152)
+    assert lin == (nL, B, 128, 4096) and cache.lconv.shape == (nL, B, 3, 12288)
+
+    def step(params, tok, pos, cache, wmask, tables, q_lens):
+        return forward(params, cfg, tok, pos, cache, kv_limit=pages * page,
+                       attn_impl="ragged", token_mask=wmask,
+                       write_mask=wmask, block_tables=tables, q_lens=q_lens,
+                       logits_at=jnp.maximum(q_lens, 1) - 1,
+                       packed_rows=packed)
+
+    traced = jax.jit(step, donate_argnums=(3,)).trace(
+        params, arg((B, W), jnp.int32), arg((B, W), jnp.int32), cache,
+        arg((B, W), jnp.bool_), arg((B, pages), jnp.int32),
+        arg((B,), jnp.int32))
+    compiled = traced.lower().compile()
+    hlo = compiled.as_text()
+    steps = [eqn for eqn in _pallas_calls(traced.jaxpr.jaxpr)
+             if eqn.params["name"] == "gated_delta_step"]
+    assert len(steps) == (nL if W == 1 else 0)
+    # ... beside a latent kernel a latent layer and a grouped one an expert layer
+    assert hlo.count('custom_call_target="tpu_custom_call"') == (
+        len(steps) + nA + nE)
+    assert "triangular" not in hlo.lower()
+    carried = {"scatter", "fusion", "while", "parameter", "tuple",
+               "get-tuple-element", "bitcast"}
+    moved = {op for op, _ in _results_of_size(hlo, {math.prod(lat)})}
+    assert moved <= carried | {"custom-call"}, moved
+    state_ops = {op for op, _ in _results_of_size(hlo, {math.prod(lin)})}
+    if W == 1:
+        assert state_ops <= carried | {"custom-call"}, state_ops
+        assert not _results_of_size(hlo, {math.prod(lin[1:])})
+        for eqn in steps:
+            gm = eqn.params["grid_mapping"]
+            assert gm.grid == (B, 2)            # 16 of the 32 heads a block
+            shapes = [tuple(b if isinstance(b, int) else b.block_size
+                            for b in bm.block_shape)
+                      for bm in gm.block_mappings]
+            # keys, queries and decays a column each; v and beta; the state
+            assert shapes[:3] == [(1, 1, 128, 48), (1, 2, 2048),
+                                  (1, 1, 128, 2048)], shapes
+    else:
+        assert "dynamic-update-slice" in state_ops, state_ops
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= math.prod(lat) * 2 + math.prod(lin) * 4
+    assert mem.temp_size_in_bytes < (1.5 * 2 ** 30 if B * W > 512
+                                     else 2 ** 28), mem.temp_size_in_bytes
+
+
+def test_kda_latent_chunk_program_and_copy_on_write_compile_on_v5e(
+        one_chip, monkeypatch):
+    """The engine's own programs at ling-3.0-flash-vl-l12's widths: the
+    512-wide ragged chunk program with the grammar on over one whole period,
+    batch 16, the cell's 8,192 blocks, its cache the engine's (the latent
+    leaf, the state and BOTH kinds' count leaves carried through the decode
+    loop, the count lane ten words wide), and ``jit_cow`` over the full
+    depth's latent leaf: a block's 32 pair rows copied in place."""
+    import types
+
+    from ai_agent_kubectl_tpu.engine.batcher import BatchedJaxEngine
+    from ai_agent_kubectl_tpu.models.families import attention_words
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = ModelConfig(name="aot-ling", n_layers=6, **LING)
+    assert attention_words(cfg) == 6 + 2 + 2
+    compiled = _chunk_program(None, one_chip, cfg, 512, 8192, 512, B=16,
+                              engine_cache=True)
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1.5 * 2 ** 30, mem.temp_size_in_bytes
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    whole = ModelConfig(name="aot-ling-whole", n_layers=12, **LING)
+    cache = _engine_cache(arg, whole, 8192, 64, 16)
+    assert cache.lat.shape == (2, 8192, 32, 1152) and cache.k is None
+    cow = BatchedJaxEngine._pool_cow_fn.fget(
+        types.SimpleNamespace(kv_pool_page=64, mesh=None))
+    scalar = arg((), jnp.int32)
+    mem = cow.lower(cache, scalar, scalar, scalar).compile().memory_analysis()
+    assert mem.alias_size_in_bytes >= math.prod(cache.lat.shape) * 2
+    assert mem.temp_size_in_bytes < 2 ** 24

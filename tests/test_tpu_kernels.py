@@ -377,3 +377,92 @@ def test_compiled_window_scan_matches_the_float64_recurrence():
             if n == 0:
                 np.testing.assert_array_equal(np.asarray(S1)[b], np.asarray(a["S0"])[b])
         np.testing.assert_allclose(np.asarray(S1), want_S, rtol=2e-4, atol=atol)
+
+
+def _record(name, **readings):
+    """What the chip read, beside the verdict: chiprun_out/kernel_parity.jsonl."""
+    import json
+    from pathlib import Path
+
+    out = Path(__file__).resolve().parent.parent / "chiprun_out"
+    out.mkdir(exist_ok=True)
+    with open(out / "kernel_parity.jsonl", "a") as f:
+        f.write(json.dumps({"test": name, **readings}) + "\n")
+
+
+def test_compiled_step_kernel_takes_a_decay_a_key_channel():
+    """ops/gated_delta.py's step kernel COMPILED through its ``channel`` branch
+    (ISSUE 48: ``g`` [B, 1, H, 128], a decay a KEY CHANNEL, multiplying the rows
+    of a head's tile), at ling-3.0-flash-vl-l12's geometry (16 rows, 32 heads
+    of 128 x 128) on plane 1 of a three-plane leaf under jit with the leaf
+    donated, three tokens running: the rows that move equal the ``jnp`` step
+    to float32 rounding and tests/test_kda_latent.py's float64 recurrence at
+    that file's tolerance, a row with EVERY channel at the floor of -5 among
+    them; the rows that do not keep their state bit for bit and read zeros;
+    the other planes are untouched."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from test_kda_latent import recurrence, scan_inputs
+
+    from ai_agent_kubectl_tpu.ops import gated_delta as GD
+
+    B, H, dk, dv, T = 16, 32, 128, 128, 3
+    moves = "1011111111101101"
+    live = np.asarray([c == "1" for c in moves])
+    a = scan_inputs(48, B, T, [T if m else 0 for m in live], H=H, dk=dk, dv=dv)
+    a["g"] = a["g"].at[2].set(-5.0)
+    want_o, want_S = recurrence(**a)
+    r = np.random.default_rng(1)
+    leaf0 = r.normal(size=(3, B, dk, H * dv)).astype(np.float32)
+    leaf0[1] = np.asarray(a["S0"])
+    step = jax.jit(GD.gated_delta_step_kernel, static_argnums=8, donate_argnums=5)
+    leaf, S, worst = jnp.asarray(leaf0), a["S0"], [0.0, 0.0]
+    for t in range(T):
+        one = [a[n][:, t:t + 1] for n in ("q", "k", "v", "g", "beta")]
+        o_j, S = GD.channel_decay_step(*one, S)
+        o_k, leaf = step(*one, leaf, jnp.asarray(1, jnp.int32), None, 0)
+        o_k = np.asarray(o_k)
+        worst[0] = max(worst[0], float(np.abs(o_k[live] - np.asarray(o_j)[live]).max()))
+        worst[1] = max(worst[1], float(np.abs(o_k[live, 0] - want_o[live, t]).max()))
+        np.testing.assert_allclose(o_k[live], np.asarray(o_j)[live], rtol=2e-5, atol=2e-6)
+        np.testing.assert_allclose(o_k[live, 0], want_o[live, t], rtol=2e-4, atol=2e-5)
+        assert not o_k[~live].any()
+    out = np.asarray(leaf)
+    _record("step_kernel_channel", o_against_jnp=worst[0], o_against_float64=worst[1],
+            state_against_float64=float(np.abs(out[1] - want_S).max()))
+    np.testing.assert_allclose(out[1][live], want_S[live], rtol=2e-4, atol=2e-5)
+    np.testing.assert_array_equal(out[1][~live], leaf0[1][~live])
+    np.testing.assert_array_equal(out[::2], leaf0[::2])
+
+
+@pytest.mark.parametrize("at_floor", (0.5, 1.0))
+def test_compiled_channel_decay_scan_matches_the_float64_recurrence(at_floor):
+    """ops/gated_delta.py::channel_decay_scan COMPILED at the window the cell
+    runs (16 rows of 512, taken four at a time; heads of 128 x 128, four of
+    them) from an initial state, rows of unequal lengths across the 64-row
+    chunks and 16-row blocks, one empty (its state kept bit for bit), with
+    half or ALL of the channels at the floor of -5: against
+    tests/test_kda_latent.py's float64 recurrence. The MXU's six-pass float32
+    products are the error here (``gated_delta_scan`` reads 5.8e-5 on its
+    worst chunk); the CPU's exact float32 meets 2e-5."""
+    import jax
+    import numpy as np
+    from test_kda_latent import recurrence, scan_inputs
+
+    from ai_agent_kubectl_tpu.ops import gated_delta as GD
+
+    q_lens = [512, 500, 452, 131, 70, 3, 0, 64, 65, 16, 17, 480, 256, 300, 1, 511]
+    a = scan_inputs(7, 16, 512, q_lens, at_floor=at_floor, H=4, dk=128, dv=128)
+    assert float(a["g"].min()) == -5.0
+    want_o, want_S = recurrence(**a)
+    o, S1 = jax.jit(GD.channel_decay_scan)(*a.values())
+    o, S1 = np.asarray(o), np.asarray(S1)
+    assert np.isfinite(o).all() and np.isfinite(S1).all()
+    _record("channel_decay_scan", at_floor=at_floor,
+            o=max(float(np.abs(o[b, :n] - want_o[b, :n]).max()) for b, n in enumerate(q_lens) if n),
+            state=float(np.abs(S1 - want_S).max()))
+    for b, n in enumerate(q_lens):
+        np.testing.assert_allclose(o[b, :n], want_o[b, :n], rtol=2e-4, atol=1e-4)
+    np.testing.assert_allclose(S1, want_S, rtol=2e-4, atol=1e-4)
+    np.testing.assert_array_equal(S1[6], np.asarray(a["S0"])[6])

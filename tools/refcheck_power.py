@@ -35,6 +35,10 @@ in ``w``-wide windows as the engine prefills them, each window from the state
 the last one left, then the decode steps; the group median as a whole and by
 position (``by_position``), so that a reading says from which context length
 a control is told apart (lines ``"what": "<variant>+long"``).
+``--positions file`` dumps the file's own shape position by position (error,
+and the reference's ``aux``: the margins a rule for ``clear_if`` is fitted
+to); ``--prompts`` and ``--decode-steps`` put another shape in its place, such
+as short prompts and many decode steps.
 ``bf16_state_chunked`` is the bf16 state as a long prefill would round it: at
 the served chunk's edges and at decode steps, not at every token.
 
@@ -88,14 +92,19 @@ def long_schedule(n: int, rows: int, window: int) -> list:
 
 
 def run_continued(refcheck, cfg_file: dict, sz: dict, seed: int, more: list,
-                  rehearse: bool, schedule: list = None, window: int = None) -> dict:
+                  rehearse: bool, schedule: list = None, window: int = None,
+                  positions: list = None) -> dict:
     """``refcheck.run``'s comparison with a second window: prompts of
     ``reference_check.prompt_tokens`` in one ragged window, then row b
     continued by ``more[b]`` tokens in another (a window that STARTS from a
     carried recurrent state and a filled pool), then the decode steps. Same
     seeded weights, same reference, same two-group rule and tolerance.
     With ``schedule`` (``long_schedule``) the windows are those, ``window``
-    wide, in place of the file's prompts and ``more``."""
+    wide, in place of the file's prompts and ``more``. ``positions``, a list,
+    is given a row's every position: its error as a share of the deviation
+    and what the reference's ``aux`` says of it. What has been done so far
+    goes to stderr, so that a call cut at its limit says where it was."""
+    said = lambda *what: print("power:", *what, file=sys.stderr, flush=True)
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -144,6 +153,9 @@ def run_continued(refcheck, cfg_file: dict, sz: dict, seed: int, more: list,
         for b in range(B):
             got[b].append(np.asarray(logits[b, :q[b]]))
         done += q
+        if w > 1:
+            said("the program's window to", done.tolist())
+    said("the program's", steps, "decode steps")
     ref = refcheck.load_reference(cfg_file["reference"])
     weights_of = refcheck.weights_function(ref)
     ref_forward = jax.jit(lambda p, t: ref.forward(sz, weights_of(p, cfg.n_layers), t))
@@ -163,6 +175,10 @@ def run_continued(refcheck, cfg_file: dict, sz: dict, seed: int, more: list,
         where.append(np.arange(n)[~clear] if rule else np.arange(n))
         stds.append(float(want.std()))
         worst += [(float(err[i]), b, int(i)) for i in np.argsort(err)[-3:]]
+        said("the reference's row", b, "of", n, "tokens")
+        if positions is not None:
+            positions.append({"prompt": lens[b], "err": (err / stds[-1]).round(5).tolist(), **{
+                name: np.asarray(a)[:n].round(6).tolist() for name, a in aux.items()}})
     std = float(np.mean(stds))
     clear_errs, unclear_errs = np.concatenate(clear_errs), np.concatenate(unclear_errs)
     rel = float(clear_errs.max()) / std if clear_errs.size else float("nan")
@@ -204,6 +220,13 @@ def main() -> None:
                     help="skip refcheck.run's own comparison")
     ap.add_argument("--layers", type=int, default=0,
                     help="compare at this depth, not reference_check.layers")
+    ap.add_argument("--prompts", type=int, nargs="*", default=None,
+                    help="these prompts in the one window, not reference_check.prompt_tokens")
+    ap.add_argument("--decode-steps", type=int, default=0,
+                    help="this many decode steps, not reference_check.decode_steps")
+    ap.add_argument("--positions", default="",
+                    help="write every position's error and aux of the file's own shape "
+                         "(one window, then the decode steps) to this file, a line a run")
     ap.add_argument("--out", default=str(ROOT / "chiprun_out" / "refcheck_power.jsonl"))
     args = ap.parse_args()
 
@@ -227,6 +250,10 @@ def main() -> None:
     cfg_file = json.loads(Path(args.config).read_text())
     if args.layers:
         cfg_file["reference_check"]["layers"] = args.layers
+    if args.prompts:
+        cfg_file["reference_check"].update(prompt_tokens=args.prompts, batch=len(args.prompts))
+    if args.decode_steps:
+        cfg_file["reference_check"]["decode_steps"] = args.decode_steps
     only = args.only or variants_of(cfg_file)
     load_reference, forward = refcheck.load_reference, transformer.forward
     state_dtype, lin_chunk = ssd_scan.STATE_DTYPE, gated_delta.CHUNK
@@ -264,6 +291,13 @@ def main() -> None:
                         results[what + "+continued"] = run_continued(
                             refcheck, cfg_file, sz, fold_seed(seed),
                             args.continued, args.rehearse)
+                    if args.positions:
+                        chk, rows = cfg_file["reference_check"], []
+                        results[what + "+positions"] = run_continued(
+                            refcheck, cfg_file, sz, fold_seed(seed), None, args.rehearse,
+                            [list(chk["prompt_tokens"])], chk["window"], rows)
+                        with open(args.positions, "a") as f:
+                            f.write(json.dumps({"seed": seed, "what": what, "rows": rows}) + "\n")
                     if args.long:
                         results[what + "+long"] = run_continued(
                             refcheck, cfg_file, sz, fold_seed(seed), None, args.rehearse,
