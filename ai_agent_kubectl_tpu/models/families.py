@@ -193,6 +193,16 @@ def _state_section(cfg, counts, facts) -> Optional[dict]:
                              for name, kind in _LAYER_PASS_KINDS}}
 
 
+def _recurrent_section(cfg, counts, facts) -> Optional[dict]:
+    """/health.ssm of a configuration with state-space layers:
+    ``_state_section``'s keys and ``decode_rows_still``, the rows of decode
+    passes that did not move (dead slots), whose state the step kernel
+    neither read nor wrote (counted on the device, summed over the
+    state-space layers)."""
+    said = _state_section(cfg, counts, facts)
+    return said and {**said, "decode_rows_still": counts["dev"][0]}
+
+
 def _selection_resolved(cfg, regime: str) -> dict:
     """How many keys a query keeps, and how each form reads them."""
     return {"index_topk": cfg.index_topk,
@@ -246,14 +256,16 @@ CACHE_KINDS: Tuple[CacheKind, ...] = (
         health={"sparse_attention": _sparse_section},
         resolves={"attention_selects_keys": _selection_resolved}),
     # Its bounded state is a leaf of the pool engine's cache, a row a
-    # decode slot, with snapshots on the radix tree.
+    # decode slot, with snapshots on the radix tree; its decode passes
+    # count the rows they passed over.
     CacheKind(
         name="recurrent",
         of=lambda cfg: cfg.has_ssm,
         says=lambda cfg: _STATE_SAYS.format(
             state="a recurrent state", pattern="".join(cfg.layer_kinds)),
+        count_leaf="ssm_rows", count_shape=(1,), lane="sel_rows",
         refuses=_STATE_REFUSES,
-        health={"ssm": _state_section}),
+        health={"ssm": _recurrent_section}),
     # The same, and a sliding layer's ring holds bf16 rows.
     CacheKind(
         name="sliding",

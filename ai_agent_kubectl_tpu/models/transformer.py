@@ -88,6 +88,11 @@ class KVCache:
              (spare rows are tolerated). ``forward``, given such a
              configuration and no leaf, starts every row from zero and
              returns the leaves.
+    ssm_rows: int32 [1], for such a configuration's engine: the rows of
+             decode passes whose state the state-space layers' step kernel
+             neither read nor wrote (dead slots), summed over layers and
+             passes since the chunk program last zeroed it
+             (/health.ssm.decode_rows_still). Absent elsewhere.
     lat:     pool mode of a latent-attention configuration
              (``ModelConfig.latent``) only, and then THE paged cache: one
              compressed row a token a latent layer (in a pattern: a ``*``
@@ -155,6 +160,7 @@ class KVCache:
     sel_rows: Any = None
     ssm: Any = None
     conv: Any = None
+    ssm_rows: Any = None
     lat: Any = None
     lat_rows: Any = None
     sk: Any = None
@@ -168,8 +174,8 @@ class KVCache:
     #: the leaves that hold one bounded state a batch row (axis 1)
     STATE = ("ssm", "conv", "sk", "sv", "lin", "lconv")
     #: what the passes count on the device, zeroed by the chunk program
-    COUNTS = ("experts_read", "sel_rows", "lat_rows", "span_rows",
-              "lin_rows", "expert_picks")
+    COUNTS = ("experts_read", "sel_rows", "ssm_rows", "lat_rows",
+              "span_rows", "lin_rows", "expert_picks")
 
     @classmethod
     def zeros(cls, cfg: ModelConfig, batch: int, max_seq: int,
@@ -1583,15 +1589,20 @@ def _expert_mixer(cfg: ModelConfig, layers: Params, j: int, h, mesh,
 def _ssm_mixer(cfg: ModelConfig, layers: Params, j: int, h, ssm, conv,
                valid, win: Optional[WindowRows] = None):
     """``h + mamba2(norm(h))`` for state-space layer ``j``, from and to
-    plane ``j`` of the state leaves. ``valid`` [B, S] bool marks a row's
+    plane ``j`` of the state leaves; returns (h, ssm, conv, int32 [1]:
+    ``KVCache.ssm_rows``). ``valid`` [B, S] bool marks a row's
     real tokens (a prefix of its columns): the rest neither move the
-    state nor enter the convolution's tail. With ``win`` ``h`` is the
+    state nor enter the convolution's tail; a decode pass (S == 1) takes
+    the step kernel on the whole ``ssm`` leaf, which passes over such a
+    row's state altogether (a window slices its plane out and sets it
+    back). With ``win`` ``h`` is the
     window's packed rows: the two projections and the gate run on them,
     the convolution and the scan, which need a slot's tokens in a row,
     on the unpacked [B, S]. Scopes ``ssm/*`` on purpose
     hold no keyword of the benchmark's trace categories: the mixer is
     its own device time, not the attention's or the MLP's."""
-    from ..ops.ssd_scan import causal_conv, gated_group_norm, ssd_scan
+    from ..ops.ssd_scan import (causal_conv, gated_group_norm, ssd_scan,
+                                ssd_step_kernel)
 
     B, S = valid.shape
     H, P, N, G = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state,
@@ -1615,13 +1626,18 @@ def _ssm_mixer(cfg: ModelConfig, layers: Params, j: int, h, ssm, conv,
             dt = jax.nn.softplus(dt.astype(jnp.float32)
                                  + layers["ssm_dt_bias"][j])
             dt = jnp.where(valid[..., None], dt, 0.0)
-            y, state = ssd_scan(
-                xbc[..., :di].reshape(B, S, H, P), dt,
-                -jnp.exp(layers["ssm_A_log"][j].astype(jnp.float32)),
-                xbc[..., di:di + G * N].reshape(B, S, G, N),
-                xbc[..., di + G * N:].reshape(B, S, G, N),
-                layers["ssm_D"][j], ssm[j], cfg.ssm_chunk)
-            ssm = ssm.at[j].set(state)
+            args = (xbc[..., :di].reshape(B, S, H, P), dt,
+                    -jnp.exp(layers["ssm_A_log"][j].astype(jnp.float32)),
+                    xbc[..., di:di + G * N].reshape(B, S, G, N),
+                    xbc[..., di + G * N:].reshape(B, S, G, N),
+                    layers["ssm_D"][j])
+            if S == 1:
+                # a decode step: the kernel takes the whole leaf, in place
+                with jax.named_scope("step"):
+                    y, ssm = ssd_step_kernel(*args, ssm, j, valid[:, 0])
+            else:
+                y, state = ssd_scan(*args, ssm[j], cfg.ssm_chunk)
+                ssm = ssm.at[j].set(state)
             conv = conv.at[j].set(tail)
         with jax.named_scope("gate_norm"):
             y = y.reshape(B, S, di)
@@ -1631,7 +1647,10 @@ def _ssm_mixer(cfg: ModelConfig, layers: Params, j: int, h, ssm, conv,
                                  layers["ssm_gate_norm"][j], G, cfg.rms_eps)
         with jax.named_scope("out_proj"):
             out = qmatmul(y, _at(layers["ssm_out"], j))
-    return h + out, ssm, conv
+    # the rows of a decode pass that the step kernel passed over
+    still = jnp.sum(jnp.logical_not(valid[:, 0]) if S == 1 else 0,
+                    dtype=jnp.int32)
+    return h + out, ssm, conv, still[None]
 
 
 def _linear_mixer(cfg: ModelConfig, lp: Params, j, h, lin, lconv,
@@ -1796,8 +1815,9 @@ def _patterned_layers(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
             seen[kind] += 1
             j = ordinal(kind, i)
             if kind == "M":
-                h, ssm, conv = _ssm_mixer(cfg, layers, j, h, ssm, conv, valid,
-                                          win)
+                h, ssm, conv, n = _ssm_mixer(cfg, layers, j, h, ssm, conv,
+                                             valid, win)
+                count({"ssm_rows": n})
             elif kind == "E":
                 h, n = _expert_mixer(
                     cfg, layers, j, h, mesh,

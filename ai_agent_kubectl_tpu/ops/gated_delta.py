@@ -270,6 +270,23 @@ def gated_delta_step_kernel(q, k, v, g, beta, state, layer, moves=None,
                       interpret=jax.default_backend() != "tpu")
 
 
+def moving_rows_first(moves):
+    """(order int32 [B], n_live int32 [1]) of ``moves`` [B] bool: the rows
+    that move first, in their order, then the others; how many move. A step
+    kernel's grid takes the rows in this order, so that the rows it passes
+    over are its last steps (ops/ssd_scan.py's takes it too). A stable
+    argsort, as comparisons: a sort of 8 is a program of its own a layer."""
+    B = moves.shape[0]
+    m = moves.astype(jnp.int32)
+    n_live = jnp.sum(m).reshape(1)
+    before = jnp.tril(jnp.ones((B, B), jnp.int32), -1)
+    place = jnp.where(moves, before @ m, n_live + before @ (1 - m))
+    rows = jnp.arange(B, dtype=jnp.int32)
+    order = jnp.sum(jnp.where(place[None, :] == rows[:, None], rows[None, :],
+                              0), axis=1)
+    return order, n_live
+
+
 @partial(jax.jit, static_argnames=("block_heads", "interpret"))
 def _step_call(q, k, v, g, beta, state, layer, moves, *, block_heads: int,
                interpret: bool):
@@ -289,15 +306,7 @@ def _step_call(q, k, v, g, beta, state, layer, moves, *, block_heads: int,
         moves = jnp.any(jnp.logical_or(
             jnp.any(g != 0, axis=-1) if channel else g != 0, beta != 0),
             axis=-1)                                                   # [B]
-    # the rows that move first, in their order, then the others (a stable
-    # argsort, as comparisons: a sort of 8 is a program of its own a layer)
-    m = moves.astype(jnp.int32)
-    n_live = jnp.sum(m).reshape(1)
-    before = jnp.tril(jnp.ones((B, B), jnp.int32), -1)
-    place = jnp.where(moves, before @ m, n_live + before @ (1 - m))
-    rows = jnp.arange(B, dtype=jnp.int32)
-    order = jnp.sum(jnp.where(place[None, :] == rows[:, None], rows[None, :],
-                              0), axis=1)
+    order, n_live = moving_rows_first(moves)
     lanes = lambda a: jnp.repeat(a, dv, axis=-1)                    # [B, L]
     # a block's heads' keys then queries, each a column: [B, nb, dk, 2*hb]
     cols = lambda a: jnp.swapaxes(a.reshape(B, nb, hb, dk), 2, 3)

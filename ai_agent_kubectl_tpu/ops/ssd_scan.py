@@ -25,16 +25,45 @@ readings by context length).
 with the ``K - 1`` inputs before the window carried in the cache beside
 the state.
 
-Plain ``jax.numpy``: at the serving shapes the scan is 2-3% of a layer's
-arithmetic beside its two projections (a 64-token chunk of 64 heads is 0.5
-GFLOP against the in-projection's 57), so it runs in float32 at the
-highest matmul precision and a kernel is ROADMAP's.
+A decode step is one Pallas kernel a layer (``ssd_step_kernel``, ISSUE 49).
+It takes the WHOLE leaf ``[layers, B, H, head_dim, state]``, aliased input
+to output, and the layer as a prefetched scalar of its index maps, so no
+plane is sliced out in front of it and none written back behind it (in
+``jnp`` from ``ssm[j]`` to ``ssm.at[j].set`` the chunk loop copied the whole
+live leaf twice a step, 2 x 201 MB, and went over a plane two to three
+times in float32 fusions); its grid is (row, block of whole groups of
+heads: 32 of the published 64, [32, 64, 128]). A head's tile [64, 128] is
+read into VMEM once, decayed, added the outer product of the head's
+``dt x`` (a column: eight heads' inputs are turned over at once, on the
+XLU; picked out of a row's inputs by a lane compare and a sum, or brought
+to the first lane by a rotation, a head's column cost as much again as its
+tile's update) with its group's ``B`` (a row), written once, and ``C . h``
+is a sum along its lanes, all float32 on the VPU and XLU: the state
+crosses HBM once in and once out, and a row that does not move (padding,
+a dead slot) not at all: the moving rows take the grid's first steps and
+the others' steps name the block before them again (ops/gated_delta.py's
+step kernel is the same shape around another recurrence). ``ssd_step`` is
+the same step in ``jnp`` on one plane, kept as what the tests hold the
+kernel to.
+
+The window's scan is plain ``jax.numpy``: at the serving shapes it is 2-3%
+of a layer's arithmetic beside its two projections (a 64-token chunk of 64
+heads is 0.5 GFLOP against the in-projection's 57), so it runs in float32
+at the highest matmul precision, from and to a plane sliced out of the
+leaf, and a kernel for it is ROADMAP's.
 """
 
 from __future__ import annotations
 
+import math
+from functools import partial
+
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .gated_delta import moving_rows_first
 
 #: dtype of the carried recurrent state (tools/refcheck_power.py patches
 #: it to read what the comparison makes of a bf16 state, by context length).
@@ -91,6 +120,163 @@ def ssd_step(x, dt, A, Bm, Cm, D, h0):
     y = y.reshape(B_, 1, H, P) + (D.astype(jnp.float32)[None, None, :, None]
                                   * x.astype(jnp.float32))
     return y.astype(x.dtype), h.reshape(h0.shape)
+
+
+#: Bytes of the state the step kernel takes a grid step at the most: a block
+#: is the most whole groups of heads under it (32 of the published 64:
+#: [32, 64, 128] float32, 1 MiB, 2 steps a row; tools/time_ssd_step.py times
+#: the other widths).
+_STEP_BLOCK_BYTES = 2 ** 20
+#: Scoped VMEM the kernel asks for: a block in and out, each double-buffered
+#: by the pipeline (4 x 1 MiB), a row's inputs and a head's temporaries.
+_STEP_VMEM_BYTES = 16 * 2 ** 20
+
+
+def _block_heads(H: int, G: int, head_bytes: int) -> int:
+    """Heads a block of the step kernel: the most whole groups that divide
+    ``H`` and stay under ``_STEP_BLOCK_BYTES``; one group where none does."""
+    Hg = H // G
+    fits = [n for n in range(Hg, H + 1, Hg)
+            if H % n == 0 and n * head_bytes <= _STEP_BLOCK_BYTES]
+    return max(fits, default=Hg)
+
+
+def _step_kernel(lyr_ref, order_ref, n_live_ref, x_ref, decay_ref, bc_ref,
+                 s_ref, y_ref, s_out_ref, *, heads_a_group: int):
+    """Grid step (i, c): heads c*hb .. c*hb + hb - 1 of the state of row
+    ``order_ref[i]`` in layer ``lyr_ref[0]``, for the ``n_live_ref[0]`` rows
+    that move (``order_ref`` names them first); a later step's row does not
+    move, its state block is the last moving row's last one again (nothing
+    is fetched, nothing written back) and its output is zeros. x_ref
+    [1,H/u,u,Pp]: the row's ``dt x``, ``u`` heads a tile, a head a sublane;
+    decay_ref [1,H/u,u,N]: its ``exp(dt A)``, along the lanes; bc_ref
+    [1,G,2,N]: a group's ``B`` then ``C``; s_ref, s_out_ref [1,1,hb,P,N]
+    (one buffer: the leaf is aliased); y_ref [1,1,P,hb]: ``C . h``, a head a
+    column. ``u`` heads at a time, in a loop whose body is traced once (a
+    kernel is lowered anew for every program of every start: its jaxpr's
+    length is ``setup_s``): their tile of ``dt x`` is turned over once, so
+    that a head's is a column (P down the sublanes, as its state's tile
+    has it) to broadcast along the lanes; a head's tile [P, N] is read
+    once, decayed, added the outer product with ``B`` and written once, and
+    ``C . h`` is a sum along its lanes, all float32 on the VPU and XLU."""
+    del lyr_ref, order_ref
+    hb, P = s_ref.shape[2:4]
+    u = x_ref.shape[2]
+    n_live = n_live_ref[0]
+    first = pl.program_id(1) * hb
+
+    @pl.when(pl.program_id(0) >= n_live)
+    def _stays():
+        y_ref[...] = jnp.zeros_like(y_ref)
+
+    @pl.when((n_live == 0) & (pl.program_id(0) + pl.program_id(1) == 0))
+    def _none_moves():      # the one block every step names: as it came
+        s_out_ref[...] = s_ref[...]
+
+    column = jax.lax.broadcasted_iota(jnp.int32, y_ref.shape[2:], 1)
+
+    def heads(t, y):
+        at = first // u + t
+        cols, decay = x_ref[0, at].T, decay_ref[0, at]      # [Pp, u], [u, N]
+        for k in range(u):
+            i = t * u + k
+            bc = bc_ref[0, (first + i) // heads_a_group]                # [2, N]
+            state = (s_ref[0, 0, i].astype(jnp.float32) * decay[k:k + 1]
+                     + cols[:P, k:k + 1] * bc[0:1]).astype(s_out_ref.dtype)
+            s_out_ref[0, 0, i] = state
+            y = jnp.where(column == i,
+                          jnp.sum(state.astype(jnp.float32) * bc[1:2], axis=1,
+                                  keepdims=True), y)
+        return y
+
+    @pl.when(pl.program_id(0) < n_live)
+    def _moves():
+        y_ref[0, 0] = jax.lax.fori_loop(0, hb // u, heads,
+                                        jnp.zeros(y_ref.shape[2:], jnp.float32))
+
+
+def ssd_step_kernel(x, dt, A, Bm, Cm, D, state, layer, moves=None,
+                    block_heads: int = 0):
+    """``ssd_step`` on plane ``layer`` (a Python int or a traced scalar) of
+    the WHOLE state leaf ``state`` [layers, B, H, P, N], as one Pallas kernel
+    that reads each moving row's plane once, updates it in VMEM and writes
+    it once, in place: the leaf is aliased input to output and the layer is
+    a prefetched scalar of the index maps, so no plane is sliced out in
+    front of the call and none written back behind it. The grid is (row,
+    block of ``block_heads`` heads; 0: ``_block_heads``). ``moves`` [B]
+    bool: the rows whose token is real; absent, the rows whose ``dt`` is not
+    all 0. A row that does not move (padding, a dead slot) has its state
+    neither read nor written (the rows that move take the grid's first
+    steps, the others' steps name the block before them again) and its
+    output is zeros (``ssd_step`` gives it ``C . h + D x`` of its unmoved
+    state, which nothing reads). Other arguments as ``ssd_step``'s.
+    Returns (y [B,1,H,P] in x's dtype, the leaf). Off the TPU the kernel
+    runs interpreted."""
+    return _step_call(x, dt, A, Bm, Cm, D, state, layer, moves,
+                      block_heads=block_heads,
+                      interpret=jax.default_backend() != "tpu")
+
+
+@partial(jax.jit, static_argnames=("block_heads", "interpret"))
+def _step_call(x, dt, A, Bm, Cm, D, state, layer, moves, *, block_heads: int,
+               interpret: bool):
+    """``ssd_step_kernel``, under a jit of its own: a model's state-space
+    layers, and every program of a start, then share ONE trace of the
+    kernel, and a program lowers it once (a chunk program's lowering is
+    ``setup_s``, compile-cache hit or not)."""
+    B, _, H, P = x.shape
+    G, N = Bm.shape[2:]
+    hb = block_heads or _block_heads(H, G, P * N * state.dtype.itemsize)
+    nb = H // hb
+    u = math.gcd(hb, 8)                 # heads the kernel's loop takes at once
+    f32 = lambda a: a.astype(jnp.float32)
+    x32, dt = f32(x[:, 0]), f32(dt[:, 0])                   # [B,H,P], [B,H]
+    if moves is None:
+        moves = jnp.any(dt != 0, axis=-1)
+    order, n_live = moving_rows_first(moves)
+    # ``u`` heads a tile: ``dt x`` a head a sublane, P along the lanes (whole
+    # lane tiles: the kernel turns a tile over), and the decay along N lanes
+    dtx = jnp.pad(dt[..., None] * x32, ((0, 0), (0, 0), (0, -P % 128)))
+    dtx = dtx.reshape(B, H // u, u, -1)
+    decay = jnp.broadcast_to(jnp.exp(dt * f32(A))[..., None],
+                             (B, H, N)).reshape(B, H // u, u, N)
+    bc = jnp.stack([f32(Bm[:, 0]), f32(Cm[:, 0])], axis=2)          # [B,G,2,N]
+    lyr = jnp.asarray(layer, jnp.int32).reshape(1)
+
+    def plane(i, c, lyr, order, n_live):
+        # past the rows that move: the last of them, its last block
+        last = jnp.maximum(n_live[0] - 1, 0)
+        return (lyr[0], order[jnp.minimum(i, last)],
+                jnp.where(i < n_live[0], c, nb - 1), 0, 0)
+
+    row = lambda i, c, lyr, order, n_live: (order[i], 0, 0, 0)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B, nb),
+        in_specs=[pl.BlockSpec((1,) + dtx.shape[1:], row),
+                  pl.BlockSpec((1,) + decay.shape[1:], row),
+                  pl.BlockSpec((1, G, 2, N), row),
+                  pl.BlockSpec((1, 1, hb, P, N), plane)],
+        out_specs=[pl.BlockSpec((1, 1, P, hb),
+                                lambda i, c, lyr, order, n_live:
+                                (order[i], c, 0, 0)),
+                   pl.BlockSpec((1, 1, hb, P, N), plane)],
+    )
+    y, state = pl.pallas_call(
+        partial(_step_kernel, heads_a_group=H // G),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((B, nb, P, hb), jnp.float32),
+                   jax.ShapeDtypeStruct(state.shape, state.dtype)],
+        input_output_aliases={6: 1},
+        interpret=interpret,
+        name="ssd_step",
+        **({} if interpret else {"compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_STEP_VMEM_BYTES)}),
+    )(lyr, order, n_live, dtx, decay, bc, state)
+    y = jnp.swapaxes(y, 2, 3).reshape(B, H, P) + f32(D)[:, None] * x32
+    y = jnp.where(moves[:, None, None], y, 0.0)[:, None]
+    return y.astype(x.dtype), state
 
 
 def ssd_scan(x, dt, A, Bm, Cm, D, h0, chunk: int):

@@ -440,3 +440,202 @@ def test_the_step_kernels_float32_state_drifts_no_more_than_the_jnp_steps():
 
     err_kernel, err_jnp = decoded(GD.gated_delta_step_kernel), decoded(_plane_step)
     assert err_kernel < 1.5 * err_jnp + 1e-7, (err_kernel, err_jnp)
+
+
+# ------ Mamba-2's decode step as a kernel (ISSUE 49; ops/ssd_scan.py::
+# ------ ssd_step_kernel, interpreted here, against ``ssd_step``)
+
+def _plane_ssd_step(x, dt, A, Bm, Cm, D, state, layer, moves=None):
+    """``ssd_step_kernel``'s contract by the plain ``jnp`` step, from and to a
+    sliced plane (what ``_ssm_mixer`` ran at S == 1 before the kernel)."""
+    y, h = ssd_step(x, dt, A, Bm, Cm, D,
+                    jax.lax.dynamic_index_in_dim(state, layer, 0, False))
+    return y, jax.lax.dynamic_update_index_in_dim(state, h, layer, 0)
+
+
+def step_inputs(seed, rows, H, P, G, N, planes=3):
+    """One token a row (``scan_inputs``, float32) and a leaf of ``planes``
+    planes."""
+    a = {k: jnp.asarray(v, jnp.float32)
+         for k, v in scan_inputs(seed, rows, 1, H=H, P=P, G=G, N=N).items()}
+    h0 = a.pop("h0")
+    return a, jnp.stack([h0 * (j + 1) for j in range(planes)])
+
+
+@pytest.mark.parametrize("H,P,G,N,block_heads", [
+    (64, 64, 8, 128, 0), (64, 64, 8, 128, 8), (64, 64, 8, 128, 64), (4, 8, 2, 16, 0),
+    (4, 8, 2, 16, 2)],
+    ids=["published", "published-8-heads", "published-64-heads", "small", "small-2-heads"])
+def test_the_ssd_step_kernel_equals_the_jnp_step_on_live_padded_and_dead_rows(H, P, G, N,
+                                                                              block_heads):
+    """On plane 1 of a three-plane leaf against ``ssd_step`` on that plane, three
+    rows: row 0 live, row 1 padded (a real token's x, B and C with dt = 0), row 2 a
+    dead slot (zeros). The live row's output and state equal to float32 rounding
+    (the kernel sums a head's 128 products along the lanes, the einsum in another
+    order), a padded and a dead row's state bit for bit its input (the kernel
+    neither reads nor writes it) and their outputs zeros, the other planes
+    untouched, the leaf float32, the output in x's dtype."""
+    from ai_agent_kubectl_tpu.ops import ssd_scan as S
+
+    assert S._block_heads(64, 8, 64 * 128 * 4) == 32 and S._block_heads(4, 2, 8 * 16 * 4) == 4
+    a, leaf = step_inputs(H, 3, H, P, G, N)
+    a["dt"] = a["dt"].at[1:].set(0.0)
+    for n in ("x", "Bm", "Cm"):
+        a[n] = a[n].at[2].set(0.0)
+    want_y, want_h = ssd_step(*a.values(), leaf[1])
+    y, out = jax.jit(S.ssd_step_kernel, static_argnums=9)(
+        *a.values(), leaf, jnp.asarray(1, jnp.int32), None, block_heads)
+    np.testing.assert_allclose(np.asarray(y)[0], np.asarray(want_y)[0], rtol=1e-5, atol=1e-5)
+    assert not np.asarray(y)[1:].any()          # a row that does not move reads nothing
+    np.testing.assert_allclose(np.asarray(out[1]), np.asarray(want_h), rtol=1e-6, atol=1e-6)
+    assert np.abs(np.asarray(out[1, 0]) - np.asarray(leaf[1, 0])).max() > 0.1
+    np.testing.assert_array_equal(np.asarray(out[1, 1:]), np.asarray(leaf[1, 1:]))
+    np.testing.assert_array_equal(np.asarray(out[::2]), np.asarray(leaf[::2]))
+    assert out.dtype == jnp.float32 and out.shape == leaf.shape
+    y16, _ = S.ssd_step_kernel(a["x"].astype(jnp.bfloat16), *list(a.values())[1:], leaf, 1)
+    assert y.dtype == jnp.float32 and y.shape == (3, 1, H, P) and y16.dtype == jnp.bfloat16
+
+
+@pytest.mark.parametrize("moves", ["0000", "0010", "1111", "1000", "0001", "0101"],
+                         ids=["none", "one", "all", "first", "last", "alternating"])
+@pytest.mark.parametrize("given", [False, True], ids=["from-dt", "moves-given"])
+def test_the_ssd_step_kernel_visits_only_the_rows_that_move(moves, given):
+    """Whichever rows move (read off ``dt != 0``, or told by ``moves``): their
+    outputs and state equal the ``jnp`` step's, every other row's state is bit for
+    bit its input and its output zeros: the kernel takes the moving rows in its
+    grid's first steps and gives the others no block of their own."""
+    from ai_agent_kubectl_tpu.ops import ssd_scan as S
+
+    live = np.asarray([c == "1" for c in moves])
+    a, leaf = step_inputs(3, 4, 4, 8, 2, 16, planes=2)
+    a["dt"] = a["dt"] * live[:, None, None]
+    want_y, want_h = ssd_step(*a.values(), leaf[1])
+    y, out = jax.jit(S.ssd_step_kernel, static_argnums=9)(
+        *a.values(), leaf, jnp.asarray(1, jnp.int32), jnp.asarray(live) if given else None, 2)
+    np.testing.assert_allclose(np.asarray(y)[live], np.asarray(want_y)[live],
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(out[1])[live], np.asarray(want_h)[live],
+                               rtol=1e-6, atol=1e-6)
+    assert not np.asarray(y)[~live].any()
+    np.testing.assert_array_equal(np.asarray(out[1])[~live], np.asarray(leaf[1])[~live])
+    np.testing.assert_array_equal(np.asarray(out[0]), np.asarray(leaf[0]))
+
+
+def test_the_ssd_step_kernel_over_every_plane_in_turn_equals_the_recurrence():
+    """As ``_patterned_layers`` runs it, and as a scan over periods would: the
+    whole leaf carried, 24 tokens a plane, the plane a Python int (Nemotron's
+    unrolled layers) or a traced index; every plane ends where the float64
+    recurrence from its own initial state does, and a call on one plane leaves the
+    others bit for bit."""
+    from ai_agent_kubectl_tpu.ops import ssd_scan as S
+
+    T, planes = 24, 3
+    raw = scan_inputs(11, 2, T)
+    a = {k: jnp.asarray(v, jnp.float32) for k, v in raw.items()}
+    h0 = a.pop("h0")
+    leaf = jnp.stack([h0 * (j + 1) for j in range(planes)])
+    token = lambda t: [jax.lax.dynamic_slice_in_dim(a[n], t, 1, 1) if a[n].ndim > 1
+                       else a[n] for n in ("x", "dt", "A", "Bm", "Cm", "D")]
+
+    @jax.jit
+    def traced(leaf):
+        def step(leaf, t):
+            def layer(leaf, j):
+                y, leaf = S.ssd_step_kernel(*token(t), leaf, j)
+                return leaf, y[:, 0]
+            return jax.lax.scan(layer, leaf, jnp.arange(planes, dtype=jnp.int32))
+        return jax.lax.scan(step, leaf, jnp.arange(T))
+
+    @jax.jit
+    def unrolled(leaf):
+        def step(leaf, t):
+            ys = []
+            for j in range(planes):
+                y, leaf = S.ssd_step_kernel(*token(t), leaf, j)
+                ys.append(y[:, 0])
+            return leaf, jnp.stack(ys)
+        return jax.lax.scan(step, leaf, jnp.arange(T))
+
+    out, y = traced(leaf)                                   # y [T, planes, B, H, P]
+    out_u, y_u = unrolled(leaf)
+    # (to rounding: the CPU's compiler contracts the small programs around the
+    # call to fused multiply-adds in the one and not in the other)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(out_u), rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_u), rtol=1e-4, atol=1e-5)
+    for j in range(planes):
+        want_y, want_h = recurrence(**{**raw, "h0": raw["h0"] * (j + 1)})
+        np.testing.assert_allclose(np.asarray(y)[:, j].swapaxes(0, 1), want_y,
+                                   rtol=2e-4, atol=2e-4)
+        np.testing.assert_allclose(np.asarray(out[j]), want_h, rtol=2e-4, atol=2e-4)
+        _, one = S.ssd_step_kernel(*token(0), leaf, j)
+        others = [i for i in range(planes) if i != j]
+        np.testing.assert_array_equal(np.asarray(one)[others], np.asarray(leaf)[others])
+        assert np.abs(np.asarray(one[j]) - np.asarray(leaf[j])).max() > 1e-3
+
+
+def test_decode_logits_through_the_ssd_step_kernel_equal_the_jnp_steps(params, monkeypatch):
+    """``forward`` at S == 1 takes ops/ssd_scan.py's kernel (interpreted here) on
+    the whole ``ssm`` leaf: after a 37-token window (``ssd_scan`` from and to a
+    sliced plane, untouched), 12 decode steps' logits and the state they leave
+    equal those of ``ssd_step`` patched in from and to a sliced plane, one row of
+    the two dead from the fifth step on: its state stays bit for bit as it was,
+    which ``ssd_step`` shows too, and its logits, which nothing samples, are
+    the only ones the two differ in (the kernel reads a dead row's state not at
+    all, so its mixer output is zeros and not ``C . h + D x``)."""
+    from ai_agent_kubectl_tpu.ops import ssd_scan as S
+
+    toks = np.random.default_rng(9).integers(3, 500, size=(2, 64), dtype=np.int32)
+
+    def decoded(step_fn):
+        monkeypatch.setattr(S, "ssd_step_kernel", step_fn)
+        run = jax.jit(lambda tok, pos, cache, mask: forward(
+            params, CFG, tok, pos, cache, token_mask=mask, write_mask=mask))
+        cache = KVCache.zeros(CFG, 2, 64, dtype=jnp.float32)
+        pos = jnp.broadcast_to(jnp.arange(37, dtype=jnp.int32), (2, 37))
+        _, cache = run(jnp.asarray(toks[:, :37]), pos, cache, jnp.ones((2, 37), bool))
+        out = []
+        for t in range(37, 49):
+            live = jnp.asarray([[True], [t < 41]])
+            logits, cache = run(jnp.asarray(toks[:, t:t + 1]),
+                                jnp.full((2, 1), t, jnp.int32), cache, live)
+            out.append(np.asarray(logits[:, 0]))
+            if t == 40:
+                at_its_death = np.asarray(cache.ssm[:, 1])
+        np.testing.assert_array_equal(np.asarray(cache.ssm[:, 1]), at_its_death)
+        return np.stack(out), np.asarray(cache.ssm)
+
+    kernel = S.ssd_step_kernel
+    want, want_state = decoded(_plane_ssd_step)
+    got, state = decoded(kernel)
+    assert state.dtype == np.float32 and state.shape == (CFG.n_of("M"), 2, 8, 16, 32)
+    np.testing.assert_allclose(got[:, 0], want[:, 0], rtol=2e-4, atol=2e-4 * want.std())
+    np.testing.assert_allclose(got[:4, 1], want[:4, 1], rtol=2e-4, atol=2e-4 * want.std())
+    np.testing.assert_allclose(state, want_state, rtol=2e-4, atol=2e-5)
+
+
+def test_the_ssd_step_kernels_float32_state_drifts_no_more_than_the_jnp_steps():
+    """The slow head of the drift test above (decay 0.999 a step, 1,500 decode
+    steps) through the kernel: as near the float64 recurrence as ``ssd_step``'s
+    float32 state ends."""
+    from ai_agent_kubectl_tpu.ops import ssd_scan as S
+
+    r = np.random.default_rng(0)
+    T, H, P, N = 1500, 1, 4, 8
+    a = dict(x=r.normal(size=(1, T, H, P)), dt=np.full((1, T, H), 1e-3), A=-np.ones(H),
+             Bm=r.normal(size=(1, T, 1, N)), Cm=r.normal(size=(1, T, 1, N)), D=np.zeros(H),
+             h0=r.normal(size=(1, H, P, N)))
+    want_y, _ = recurrence(**a)
+    f = {k: jnp.asarray(v, jnp.float32) for k, v in a.items()}
+
+    def decoded(step_fn):
+        def step(leaf, t):
+            y, leaf = step_fn(f["x"][:, t][:, None], f["dt"][:, t][:, None], f["A"],
+                              f["Bm"][:, t][:, None], f["Cm"][:, t][:, None], f["D"],
+                              leaf, jnp.asarray(0, jnp.int32))
+            return leaf, y[:, 0]
+
+        _, ys = jax.lax.scan(step, f["h0"][None], jnp.arange(T))
+        return np.abs(np.asarray(ys)[-200:, 0] - want_y[0, -200:]).mean()
+
+    err_kernel, err_jnp = decoded(S.ssd_step_kernel), decoded(_plane_ssd_step)
+    assert err_kernel < 1.5 * err_jnp + 1e-7, (err_kernel, err_jnp)

@@ -318,6 +318,12 @@ async def test_restored_snapshot_answers_as_a_prefill_from_token_zero(from_token
         st3, st2 = eng.family_health()["ssm"], st3
         assert st3["prefix_tokens_usable"] - st2["prefix_tokens_usable"] == edge
         assert st3["layer_passes"]["ssm"] == st3["forward_passes"] * 2
+        # the other slots' rows of the decode passes: the state-space layers'
+        # step kernel passed them over (ISSUE 49), in both layers, and no more
+        # rows than the chunk programs' passes had
+        steps = st3["forward_passes"] - st3["eager_prefill_passes"]
+        assert 0 < st3["decode_rows_still"] <= steps * st3["live_rows"] * 2
+        assert st3["decode_rows_still"] % 2 == 0
         eng._state.check()
         spans = eng.spans_health()
         assert spans["sched/state_restore"]["count"] == st3["restores"]

@@ -74,6 +74,38 @@ def _results_of_size(hlo: str, sizes) -> list:
             if math.prod(int(d) for d in dims.split(",")) in sizes]
 
 
+def _loop_bodies(hlo: str) -> str:
+    """The text of every computation a ``while`` of the program runs: its
+    body and whatever that calls (fusions, nested loops), as HLO lines."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        m = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if m:
+            name = m.group(1)
+            comps[name] = []
+        elif name is not None:
+            comps[name].append(line)
+    seen = set()
+
+    def walk(name):
+        if name in seen or name not in comps:
+            return
+        seen.add(name)
+        for line in comps[name]:
+            for called in re.findall(
+                    r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)", line):
+                walk(called)
+            for group in re.findall(r"branch_computations=\{([^}]*)\}", line):
+                for called in group.split(","):
+                    walk(called.strip().lstrip("%"))
+
+    for lines in list(comps.values()):
+        for line in lines:
+            if " while(" in line:
+                walk(re.search(r"body=%?([\w.\-]+)", line).group(1))
+    return "\n".join(line for name in seen for line in comps[name])
+
+
 def _pallas_grids(jaxpr):
     """The grid of every ``pallas_call`` in a jaxpr, sub-jaxprs included."""
     for eqn in jaxpr.eqns:
@@ -761,7 +793,25 @@ def test_patterned_forward_compiles_at_published_widths_on_v5e(one_chip, B, W,
         {1: (16, 97), 64: (64, 223), 512: (32, 221)}[W] + (64 * 2 ** 20,)}
     compiled = traced.lower().compile()
     hlo = compiled.as_text()
-    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+    # the grouped expert kernel, the ragged attention kernel and, in a decode
+    # pass (ISSUE 49: it was 2 there too while the step ran in ``jnp``),
+    # ops/ssd_scan.py's step kernel on the WHOLE aliased ``ssm`` leaf: a
+    # row's 64 heads in two blocks [32, 64, 128], in and out double-buffered
+    # inside the VMEM it asks for (this one-plane leaf, 33.5 MB, the compiler
+    # prefetches whole with a ``copy-start``; the loop of two planes below,
+    # and the cell's of six, it does not)
+    steps = [eqn for eqn in _pallas_calls(traced.jaxpr.jaxpr)
+             if eqn.params["name"] == "ssd_step"]
+    assert len(steps) == (1 if W == 1 else 0)
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2 + len(steps)
+    for eqn in steps:
+        gm = eqn.params["grid_mapping"]
+        assert gm.grid == (B, 2)
+        blocks = sum(math.prod(b if isinstance(b, int) else b.block_size
+                               for b in bm.block_shape) * 4
+                     for bm in gm.block_mappings)
+        limit = eqn.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
+        assert 2 * 32 * 64 * 128 * 4 < blocks and 2 * blocks < limit
     assert not _results_of_size(hlo, {128 * 2688 * 1856}), "an expert stack moved"
     # the pool: the row writes alone, in place (each scatter shows twice:
     # inside its fusion, and as the fusion)
@@ -771,6 +821,36 @@ def test_patterned_forward_compiles_at_published_widths_on_v5e(one_chip, B, W,
     assert mem.alias_size_in_bytes >= (2 * math.prod(pool) * 2
                                        + math.prod(ssm.shape) * 4)
     assert mem.temp_size_in_bytes < 2 ** 30, mem.temp_size_in_bytes
+
+
+def test_state_space_decode_loop_holds_no_copy_of_the_state_on_v5e(one_chip,
+                                                                    monkeypatch):
+    """The engine's ragged chunk program (a 64-wide prologue window, 16
+    decode steps, the grammar on, the engine's cache) for a pattern with TWO
+    state-space layers at nemotron-3-nano-30b-a3b-l13's widths, batch 16: the
+    decode loop carries the state leaf [2, 16, 64, 64, 128] float32 and its
+    body holds nothing of the leaf's size or of a plane's but the two step
+    kernels' calls on the whole aliased leaf (ISSUE 49). With ``ssd_step`` in
+    ``jnp`` from ``ssm[j]`` to ``ssm.at[j].set`` the body held, a layer, a
+    plane-sized ``slice`` and a ``dynamic-update-slice`` of the leaf with the
+    plane's multiplies, adds and broadcasts in their fusions and, at the
+    cell's own 13 layers (six planes), two ``copy`` of the whole leaf a step
+    (201 MB each: AOT, PR 49, of the parent; what the chip's trace showed at
+    PR 33). The prologue's window still slices its plane and sets it, once a
+    chunk, in place (no ``copy`` of the leaf's size anywhere)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = ModelConfig(name="aot-nemotron", n_layers=4, layer_pattern="MEM*",
+                      **NEMOTRON)
+    hlo = _chunk_program(None, one_chip, cfg, 64, 4096, 257, B=16,
+                         engine_cache=True).as_text()
+    plane = 16 * 64 * 64 * 128
+    in_loop = _results_of_size(_loop_bodies(hlo), {plane, 2 * plane})
+    assert in_loop and {op for op, _ in in_loop} == {"custom-call"}, in_loop
+    assert len(in_loop) == 2 and {dims for _, dims in in_loop} == {
+        "2,16,64,64,128"}
+    whole = {op for op, _ in _results_of_size(hlo, {2 * plane})}
+    assert "dynamic-update-slice" in whole and not whole & {
+        "copy", "copy-start", "copy-done"}, whole
 
 
 # ------------------- latent attention and a share of the experts (ISSUE 38)

@@ -466,3 +466,53 @@ def test_compiled_channel_decay_scan_matches_the_float64_recurrence(at_floor):
         np.testing.assert_allclose(o[b, :n], want_o[b, :n], rtol=2e-4, atol=1e-4)
     np.testing.assert_allclose(S1, want_S, rtol=2e-4, atol=1e-4)
     np.testing.assert_array_equal(S1[6], np.asarray(a["S0"])[6])
+
+
+def test_compiled_ssd_step_matches_the_jnp_step_and_skips_still_rows():
+    """ops/ssd_scan.py's step kernel COMPILED (ISSUE 49), at nemotron-3-nano-30b-
+    a3b's geometry (16 rows, 64 heads of 64 x 128 in 8 groups), on plane 1 of a
+    three-plane leaf under jit with the leaf donated: the rows that move equal
+    ``ssd_step`` to float32 rounding (the output a lane sum of 128 products
+    against the MXU's six-pass einsum); the rows that do not (first, between and
+    last; and every row) keep their state bit for bit, through grid steps that
+    name another row's block again, which the interpreter does not model, and
+    read zeros; the other planes are untouched; bf16 inputs, as the mixer hands
+    them, give a bf16 output within an ulp."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ai_agent_kubectl_tpu.ops import ssd_scan as S
+
+    B, H, P, G, N = 16, 64, 64, 8, 128
+    r = np.random.default_rng(0)
+    f32 = lambda a: jnp.asarray(a, jnp.float32)
+    x = f32(r.normal(size=(B, 1, H, P)))
+    Bm, Cm = f32(r.normal(size=(B, 1, G, N))), f32(r.normal(size=(B, 1, G, N)))
+    A, D = f32(-r.uniform(0.5, 4.0, H)), f32(r.normal(size=H))
+    leaf0 = r.normal(size=(3, B, H, P, N)).astype(np.float32)
+    step = jax.jit(S.ssd_step_kernel, static_argnums=9, donate_argnums=6)
+    worst = [0.0, 0.0]
+    for moves, hb in (("1" * 16, 0), ("0110110011110101", 0), ("0" * 16, 0),
+                      ("1000000000000001", 64), ("0000000100000000", 8)):
+        live = np.asarray([c == "1" for c in moves])
+        dt = f32(np.where(live[:, None, None], r.uniform(0.01, 0.5, (B, 1, H)), 0.0))
+        want_y, want_h = S.ssd_step(x, dt, A, Bm, Cm, D, jnp.asarray(leaf0[1]))
+        y, out = step(x, dt, A, Bm, Cm, D, jnp.asarray(leaf0), jnp.asarray(1, jnp.int32),
+                      None, hb)
+        y, out, want_y, want_h = (np.asarray(a) for a in (y, out, want_y, want_h))
+        if live.any():
+            worst[0] = max(worst[0], float(np.abs(y[live] - want_y[live]).max()))
+            worst[1] = max(worst[1], float(np.abs(out[1][live] - want_h[live]).max()))
+        np.testing.assert_allclose(y[live], want_y[live], rtol=2e-5, atol=2e-4)
+        np.testing.assert_allclose(out[1][live], want_h[live], rtol=2e-5, atol=2e-6)
+        assert not y[~live].any(), moves
+        np.testing.assert_array_equal(out[1][~live], leaf0[1][~live])
+        np.testing.assert_array_equal(out[::2], leaf0[::2])
+    _record("ssd_step_kernel", y_against_jnp=worst[0], state_against_jnp=worst[1])
+    bf16 = lambda a: a.astype(jnp.bfloat16)
+    y16, _ = step(bf16(x), dt, A, bf16(Bm), bf16(Cm), D, jnp.asarray(leaf0), 1, None, 0)
+    want16, _ = S.ssd_step(bf16(x), dt, A, bf16(Bm), bf16(Cm), D, jnp.asarray(leaf0[1]))
+    assert y16.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(y16.astype(jnp.float32))[live],
+                               np.asarray(want16.astype(jnp.float32))[live], rtol=1e-2, atol=1e-2)
