@@ -2198,6 +2198,9 @@ class BatchedJaxEngine(JaxEngine):
             self._no_corrupt_d = shard_tokens(self._no_corrupt_d, self.mesh)
             if self._grammar is not None:
                 self._fsm_d = shard_tokens(self._fsm_d, self.mesh)
+        # the newest launch is one of the fills above: whatever handle of
+        # the old world the spans held goes with it
+        self._spans.launched(self._seeds_d)
 
     # ------------------------------------- block-paged KV pool (ISSUE 10)
     #
@@ -2293,12 +2296,14 @@ class BatchedJaxEngine(JaxEngine):
         self._snap = self._state_copy_fn[0](
             self._snap, self._live_state(), np.int32(handle), np.int32(slot),
             np.int32(self._state.edge(handle)))
+        self._launched(self._snap)
 
     def _state_restore_dev(self, slot: int, handle: int) -> None:
         self._cache = dataclasses.replace(
             self._cache, **self._state_copy_fn[1](
                 self._live_state(), self._snap, np.int32(slot),
                 np.int32(handle), np.int32(self._state.edge(handle))))
+        self._launched(self._live_state())
 
     @functools.cached_property
     def _state_zero_fn(self):
@@ -2314,6 +2319,15 @@ class BatchedJaxEngine(JaxEngine):
         self._cache = dataclasses.replace(
             self._cache, **self._state_zero_fn(self._live_state(),
                                                np.int32(slot)))
+        self._launched(self._live_state())
+
+    def _launched(self, out) -> None:
+        """``EngineSpans.launched`` for a program whose outputs are all
+        handed on, donated, to a later one (a state copy, a block copy,
+        the draft's splice): a leaf of ``out``. The reference keeps
+        nothing alive that the engine does not hold itself, and is dead
+        once donated."""
+        self._spans.launched(jax.tree_util.tree_leaves(out)[0])
 
     def _tables_d(self, tables: np.ndarray):
         """Device copy of a block-table snapshot — committed REPLICATED
@@ -2442,6 +2456,9 @@ class BatchedJaxEngine(JaxEngine):
                 jnp.asarray(seed, jnp.int32),
                 jnp.asarray(ngen0, jnp.int32),
             )
+            # the chunk programs take the carry donated; the seeds only
+            # the next arm does
+            self._spans.launched(self._seeds_d)
 
     @property
     def _pool_cow_fn(self):
@@ -2515,6 +2532,7 @@ class BatchedJaxEngine(JaxEngine):
             self._cache = self._pool_cow_fn(
                 self._cache, jnp.asarray(src, jnp.int32),
                 jnp.asarray(dst, jnp.int32), jnp.asarray(rows, jnp.int32))
+            self._launched(self._cache.paged())
 
     # ------------------------------- host-tier block transfer (ISSUE 20)
 
@@ -2549,6 +2567,7 @@ class BatchedJaxEngine(JaxEngine):
                 jnp.asarray(part, dtype=leaf.dtype)))
         self._cache = self._cache.with_paged(
             jax.tree_util.tree_unflatten(treedef, out))
+        self._launched(out)
 
     def _pool_alloc(self, n: int) -> Optional[List[int]]:
         """Allocate with radix-eviction backpressure (kv_pool.py helper,
@@ -2634,9 +2653,15 @@ class BatchedJaxEngine(JaxEngine):
                     self.params, tokens_d, positions_d, self._cache, mask_d,
                     tables_d, np.int32(slot_idx))
                 piece["call_ms"] = (time.monotonic() - t_call) * 1000.0
+                self._spans.launched(logits)
             offset += L
             self._counts.note_passes(1, eager=True)
-        return logits[:, 0]
+        # One more program, the slice of the last row: a launch that waits
+        # behind the pieces like any other (sched/eager_tail).
+        with self._spans.sched.child("eager_tail", slot=slot_idx):
+            last = logits[:, 0]
+            self._spans.launched(last)
+        return last
 
     def _pool_ensure_coverage(self, idx: int, slot: "_Slot",
                               chunk_tokens: Optional[int] = None) -> bool:
@@ -2806,8 +2831,10 @@ class BatchedJaxEngine(JaxEngine):
                 # The token is a placeholder — the prologue overrides
                 # tok/pos/ngen/active for staged slots and the chunk
                 # returns the real carry.
-                self._run_arm(slot_idx, stage_start,
-                              jnp.zeros((1,), jnp.int32),
+                with self._spans.sched.child("placeholder", slot=slot_idx):
+                    no_tok_d = jnp.zeros((1,), jnp.int32)
+                    self._spans.launched(no_tok_d)
+                self._run_arm(slot_idx, stage_start, no_tok_d,
                               req.temperature, req.max_tokens, req.seed,
                               len(run))
                 # The draft still mirrors the FULL span now — the spec
@@ -2909,7 +2936,9 @@ class BatchedJaxEngine(JaxEngine):
         self._inflight.append(("first", first_tok_d, req, slot_idx))
         self._last_admit_t = time.monotonic()
         spans.staged(self._last_admit_t, prefill=prefill_meta,
-                     chunks_ahead=self._chunks_in_pipe(), blocks=len(blocks))
+                     chunks_ahead=self._chunks_in_pipe(),
+                     chunks_unready=self._spans.pipe_chunks(),
+                     blocks=len(blocks))
 
     def _pool_warmup(self) -> None:
         """Eager startup warm of the pool serving programs: the smallest
@@ -3304,6 +3333,7 @@ class BatchedJaxEngine(JaxEngine):
         self._draft_cache = self._draft_splice_fn(
             self._draft_cache, scratch.k, scratch.v,
             jnp.asarray(slot_idx, jnp.int32))
+        self._launched(self._draft_cache)
 
     def _chunk_waste_bound(self) -> int:
         """Per-in-flight-chunk bound on counted device steps, for the
@@ -3395,6 +3425,7 @@ class BatchedJaxEngine(JaxEngine):
         self._fsm_d = self._grammar_set_fn(
             self._fsm_d, jnp.asarray(slot_idx, jnp.int32),
             jnp.asarray(gs, jnp.int32))
+        self._spans.launched(self._fsm_d)
 
     @property
     def _grammar_arm_sampled_fn(self):
@@ -3420,6 +3451,7 @@ class BatchedJaxEngine(JaxEngine):
         self._fsm_d = self._grammar_arm_sampled_fn(
             self._fsm_d, tc, nx, jnp.asarray(slot_idx, jnp.int32),
             jnp.asarray(gs_base, jnp.int32), first_tok_d)
+        self._spans.launched(self._fsm_d)
 
     def _grammar_first_sample(self, last_logits, req: "_Request",
                               gs: int, gen_index: int):
@@ -3430,10 +3462,13 @@ class BatchedJaxEngine(JaxEngine):
         key = jax.random.fold_in(jax.random.PRNGKey(req.seed), gen_index)
         temp = jnp.asarray(req.temperature, jnp.float32)
         if self._grammar is None or req.gpid < 0:
-            return self._sample_fn(last_logits, key, temp)
-        mask_d = jnp.asarray(self._grammar.allowed_np(gs))
-        return self._grammar_mask_sample_fn(last_logits, key, temp,
-                                            mask_d)
+            first_tok_d = self._sample_fn(last_logits, key, temp)
+        else:
+            mask_d = jnp.asarray(self._grammar.allowed_np(gs))
+            first_tok_d = self._grammar_mask_sample_fn(last_logits, key,
+                                                       temp, mask_d)
+        self._spans.launched(first_tok_d)
+        return first_tok_d
 
     @property
     def _grammar_mask_sample_fn(self):
@@ -4375,6 +4410,7 @@ class BatchedJaxEngine(JaxEngine):
                 jnp.asarray(req.seed, jnp.int32),
                 jnp.asarray(g, jnp.int32),
             )
+            self._spans.launched(self._seeds_d)
         slot.pos = n_total
         slot.anchor_pos = n_total
         slot.anchor_g = g
@@ -5120,6 +5156,7 @@ class BatchedJaxEngine(JaxEngine):
                 jnp.asarray(budgets), jnp.asarray(seeds),
             )
         )
+        self._spans.launched(self._seeds_d)
         self._to_host_async(first_toks_d)
         self._inflight.append(("firsts", first_toks_d, pairs))
         self._group_admitted += 1
@@ -5128,6 +5165,7 @@ class BatchedJaxEngine(JaxEngine):
         for i, req in enumerate(live):
             req.spans.staged(
                 self._last_admit_t, chunks_ahead=self._chunks_in_pipe(),
+                chunks_unready=self._spans.pipe_chunks(),
                 prefill=dict(prompt_tokens=int(n_prompts[i]),
                              prefix_hit_tokens=prefix.n, staged_w=0))
 
@@ -5183,6 +5221,7 @@ class BatchedJaxEngine(JaxEngine):
             jnp.asarray(req.max_tokens, jnp.int32),
             jnp.asarray(req.seed, jnp.int32), jnp.asarray(1, jnp.int32),
         )
+        self._spans.launched(self._seeds_d)
 
         if gs0 >= 0:
             self._grammar_arm_after_sample(slot_idx, gs0, first_tok_d)
@@ -5213,6 +5252,7 @@ class BatchedJaxEngine(JaxEngine):
         self._spans.note_slots(self._slots)
         spans.staged(
             self._last_admit_t, chunks_ahead=self._chunks_in_pipe(),
+            chunks_unready=self._spans.pipe_chunks(),
             prefill=dict(
                 prompt_tokens=n_prompt, staged_w=0,
                 prefix_hit_tokens=self._prefix.n if prefix_hit else 0))
@@ -5275,6 +5315,7 @@ class BatchedJaxEngine(JaxEngine):
         # chunk's first row is this segment's first token.
         self._spans.note_slots(self._slots)
         spans.staged(time.monotonic(), chunks_ahead=self._chunks_in_pipe(),
+                     chunks_unready=self._spans.pipe_chunks(),
                      prefill=dict(prompt_tokens=len(req.prompt_ids),
                                   resumed_tokens=len(req.resume_ids)))
 
@@ -5403,7 +5444,8 @@ class BatchedJaxEngine(JaxEngine):
         marks a dispatch that found nothing left to run."""
         with self._spans.sched.region("dispatch", "dispatch",
                                       chunk=self._chunks_dispatched + 1,
-                                      slots=0, pipe_empty_ms=0.0) as entry:
+                                      slots=0, pipe_empty_ms=0.0,
+                                      drained_ms=0.0) as entry:
             self._dispatch_chunk_in_span(entry)
 
     def _dispatch_chunk_in_span(self, entry: dict) -> None:
@@ -5565,12 +5607,13 @@ class BatchedJaxEngine(JaxEngine):
             s.decode_chunks_inflight += 1
         self._to_host_async(packed_d)  # overlap the transfer (see _admit_one)
         chunks_ahead = self._chunks_in_pipe()
+        chunks_unready = self._spans.pipe_chunks()
         self._chunks_dispatched += 1
         # forward passes of this chunk program (a spec chunk: its verifies)
         self._counts.note_passes(self._spec_steps if spec else self.chunk_len)
         self._inflight.append(("chunk", packed_d, snapshot, ct, spec,
                                self._chunks_dispatched))
-        entry["pipe_empty_ms"] = self._spans.note_pipe(self._inflight)
+        entry.update(self._spans.dispatched(self._inflight, packed_d))
         if staged:
             wc = self._window_counts
             wc["windows"] += 1
@@ -5584,7 +5627,8 @@ class BatchedJaxEngine(JaxEngine):
             # This chunk carries slot i's prologue: its stage_wait ends
             # where this dispatch began, behind the chunks already queued.
             self._spans.of(self._slots[i].req).dispatched(
-                t_disp, self._chunks_dispatched, chunks_ahead, adm_w=adm_w,
+                t_disp, self._chunks_dispatched, chunks_ahead,
+                chunks_unready, adm_w=adm_w,
                 ctx_tokens=self._slots[i].n_prompt)
         entry.update(kv_bucket=bucket, slots=len(active_slots),
                      admissions=len(staged), adm_w=adm_w or 0,

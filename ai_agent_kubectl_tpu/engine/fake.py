@@ -1508,7 +1508,8 @@ class FakeChunkedEngine:
         are byte-identical by construction here too."""
         with self._spans.sched.region("dispatch", "dispatch",
                                       chunk=self._chunks_dispatched + 1,
-                                      slots=0, pipe_empty_ms=0.0) as entry:
+                                      slots=0, pipe_empty_ms=0.0,
+                                      drained_ms=0.0) as entry:
             self._dispatch_chunk_in_span(entry)
 
     def _dispatch_chunk_in_span(self, entry: dict) -> None:
@@ -1665,17 +1666,18 @@ class FakeChunkedEngine:
                             drafted=drafted if spec else None,
                             accepted=accepted if spec else None)
         chunks_ahead = len(self._inflight)
+        chunks_unready = self._spans.pipe_chunks()
         self._chunks_dispatched += 1
         self._inflight.append(("chunk", packed, snapshot, C, spec,
                                self._chunks_dispatched))
-        entry["pipe_empty_ms"] = self._spans.note_pipe(self._inflight)
+        entry.update(self._spans.dispatched(self._inflight, packed))
         for snap in snapshot:
             # A slot still in its prefill phase rides THIS chunk (ragged
             # admission); a no-op for every other slot.
             if snap is not None:
                 self._spans.of(snap).dispatched(
                     now, self._chunks_dispatched, chunks_ahead,
-                    adm_w=adm_w)
+                    chunks_unready, adm_w=adm_w)
         entry.update(slots=sum(s is not None for s in snapshot),
                      admissions=len(staged), adm_w=adm_w,
                      pipe=chunks_ahead + 1)

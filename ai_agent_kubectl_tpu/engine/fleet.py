@@ -1357,7 +1357,16 @@ class EngineFleet:
         """Fleet rollup of the replicas' engine-span totals (obs/trace.py
         SpanStats): counts and totals sum, ``max_ms`` takes the worst
         replica's; ``sched_thread_s`` sums to scheduler thread-seconds
-        across replicas."""
+        across replicas, and so do ``sched_starved_s`` and
+        ``sched_drained_s``, the latter's parts one level further down."""
+        def add(out: dict, entry: dict) -> None:
+            for k, v in entry.items():
+                if isinstance(v, dict):
+                    add(out.setdefault(k, {}), v)
+                else:
+                    out[k] = (max(out.get(k, 0), v) if k == "max_ms"
+                              else out.get(k, 0) + v)
+
         agg: dict = {}
         for rep in self.replicas:
             fn = getattr(rep.engine, "spans_health", None)
@@ -1367,11 +1376,7 @@ class EngineFleet:
                 snap = fn() or {}
             except Exception:   # pragma: no cover - stopped replica
                 continue
-            for name, entry in snap.items():
-                out = agg.setdefault(name, {})
-                for k, v in entry.items():
-                    out[k] = (max(out.get(k, 0), v) if k == "max_ms"
-                              else out.get(k, 0) + v)
+            add(agg, snap)
         return agg
 
     def slo_health(self) -> dict:

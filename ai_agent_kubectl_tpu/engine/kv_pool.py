@@ -682,7 +682,8 @@ class StateStore:
                 self.prefix_tokens_matched += mr.kv_matched
                 self.prefix_tokens_recomputed += mr.kv_matched
             if self._zero_fn is not None:
-                self._zero_fn(slot)
+                with self._region("state_zero", slot=slot):
+                    self._zero_fn(slot)
             return
         usable = mr.n_tokens
         self.prefix_tokens_matched += mr.kv_matched

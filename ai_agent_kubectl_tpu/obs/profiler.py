@@ -1,8 +1,9 @@
 """On-demand ``jax.profiler`` capture for a live server.
 
-``POST /debug/profile?seconds=N`` lands here: start a device trace into a
-fresh directory, sleep N seconds while live traffic keeps decoding, stop,
-and report the directory (TensorBoard-loadable, ``xprof`` readable). The
+``POST /debug/profile?seconds=N[&python_tracer=1]`` lands here: start a
+device trace into a fresh directory, sleep N seconds while live traffic
+keeps decoding, stop, and report the directory (TensorBoard-loadable,
+``xprof`` readable). The
 whole point is catching "why is decode slow *right now*" without
 restarting the server with profiling baked in.
 
@@ -50,19 +51,35 @@ def _reap_old(base: str) -> None:
             shutil.rmtree(os.path.join(base, d), ignore_errors=True)
 
 
-async def capture(seconds: float, probe=None) -> dict:
+async def capture(seconds: float, probe=None,
+                  python_tracer: bool = False) -> dict:
     """Run one profiler capture; returns ``{"trace_dir", "seconds",
-    "clock_start", "clock_stop", "spans"}``. The two clock entries are
-    ``[time.monotonic(), time.time_ns()]`` pairs taken as the capture
-    starts and stops: the trace's own axis is the wall clock in
-    nanoseconds, every span of this program (flight recorder,
+    "python_tracer", "clock_start", "clock_stop", "spans"}``. The two
+    clock entries are ``[time.monotonic(), time.time_ns()]`` pairs taken
+    as the capture starts and stops: the trace's own axis is the wall
+    clock in nanoseconds, every span of this program (flight recorder,
     /debug/chunks) is stamped with ``time.monotonic()``, and the pair is
     what places one on the other by hand. The scheduler's sched/* spans
     need no such arithmetic: they are TraceAnnotations inside the trace.
     ``spans`` is what ``probe()`` (the engine's ``/health.spans``) grew by
-    between the two stamps: the same regions' counts and ms inside the
-    capture, where the profiler's Python tracer hooks every call, to hold
-    against a whole run's (None without a probe).
+    between the two stamps (None without a probe): the same regions'
+    counts and ms, and the scheduler thread's three partitions
+    (``sched_thread_s``, ``sched_starved_s``, ``sched_drained_s`` with
+    its ``by_region``), so one capture yields the device's idle seconds
+    from the trace and the program's own account of them over the same
+    interval.
+
+    The profiler's Python tracer is OFF unless ``python_tracer``: it
+    hooks every call of every thread, the scheduler's among them, and
+    made a radix walk twenty times longer inside a capture than outside
+    (3.83 against 79.8 ms; chip run, PR 35), which the trace then showed
+    as the device standing idle. Without it the capture holds the device
+    planes, the runtime's own TraceMe events and the sched/* annotations
+    on the scheduler thread's line (the host tracer's level is left as it
+    is), no ``$file:line function`` frame, and the program runs as it does
+    outside one. An operator who wants frames asks for them
+    (``python_tracer=1``) and reads the host's times with that in mind;
+    the answer says which it was.
 
     The caller serializes captures (one at a time) — jax.profiler has one
     global trace session and a second start_trace would raise.
@@ -76,9 +93,12 @@ async def capture(seconds: float, probe=None) -> dict:
     trace_dir = tempfile.mkdtemp(
         prefix=f"{time.strftime('%Y%m%d-%H%M%S')}-", dir=base
     )
-    logger.info("profiler: capturing %.1fs device trace into %s",
-                seconds, trace_dir)
-    jax.profiler.start_trace(trace_dir)
+    logger.info("profiler: capturing %.1fs device trace into %s "
+                "(python tracer %s)", seconds, trace_dir,
+                "on" if python_tracer else "off")
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = int(python_tracer)
+    jax.profiler.start_trace(trace_dir, profiler_options=options)
     clock_start = [time.monotonic(), time.time_ns()]
     before = probe() if probe is not None else None
     try:
@@ -86,14 +106,24 @@ async def capture(seconds: float, probe=None) -> dict:
     finally:
         after = probe() if probe is not None else None
         clock_stop = [time.monotonic(), time.time_ns()]
+        # Writing the trace out takes several times the capture's length
+        # and holds this event loop, so every handler of the server, for
+        # it: 18 s for 3 s of one busy chip. From another thread it let
+        # the server go on but took 54-72 s there and 209 s over four
+        # chips, against the 300 s a benchmark run waits for this answer
+        # (chip runs, PR 50): it stays here until that is understood.
         jax.profiler.stop_trace()
     spans = spans_growth(before, after)
     if spans:
         # the server's log keeps what the caller may not: the scheduler
-        # thread's regions inside the capture (count, ms)
+        # thread's regions inside the capture (count, ms) and the seconds
+        # the device had nothing to run
         logger.info("profiler: sched regions inside the capture: %s", {
             name: (e.get("count"), e.get("total_ms"))
             for name, e in spans.items() if name.startswith("sched/")})
+        logger.info("profiler: sched_drained_s inside the capture: %s",
+                    spans.get("sched_drained_s"))
     return {"trace_dir": trace_dir, "seconds": seconds,
+            "python_tracer": python_tracer,
             "clock_start": clock_start, "clock_stop": clock_stop,
             "spans": spans}

@@ -26,8 +26,9 @@ The engine's side of the timeline is stamped where the work happens:
 ``RequestSpans`` (one per queued request) writes the engine phases as the
 scheduler crosses each boundary, ``SchedSpans`` records the scheduler
 thread's own per-chunk intervals, their named parts (the radix walk, an
-eager prefill piece) and the time the chunk pipe stood empty into the
-``/debug/chunks`` ring and ``/health.spans``, and
+eager prefill piece), the time the chunk pipe stood empty and the time the
+device had nothing at all to run into the ``/debug/chunks`` ring and
+``/health.spans``, and
 ``SpanStats`` keeps the cumulative per-name totals ``/health.spans``
 serves. Both engines with a scheduler (engine/batcher.py, engine/fake.py)
 hold the three in one ``EngineSpans``, so the names cannot drift apart.
@@ -292,23 +293,29 @@ class SpanStats:
                     for name, e in self._by_name.items()}
 
 
-def spans_growth(before: Optional[Dict[str, Dict[str, float]]],
-                 after: Optional[Dict[str, Dict[str, float]]]
-                 ) -> Optional[Dict[str, Dict[str, float]]]:
+def _grown(entry: Dict[str, Any], was: Dict[str, Any]) -> Dict[str, Any]:
+    out: Dict[str, Any] = {}
+    for k, v in entry.items():
+        if isinstance(v, dict):
+            out[k] = _grown(v, was.get(k) or {})
+        elif k != "max_ms":
+            out[k] = round(v - was.get(k, 0), 6)
+    if entry.get("max_ms", 0) > was.get("max_ms", 0):
+        out["max_ms"] = entry["max_ms"]
+    return out
+
+
+def spans_growth(before: Optional[Dict[str, Dict[str, Any]]],
+                 after: Optional[Dict[str, Dict[str, Any]]]
+                 ) -> Optional[Dict[str, Dict[str, Any]]]:
     """What two ``/health.spans`` sections (``EngineSpans.health``) differ
-    by, entry by entry: counts, totals and seconds grow; ``max_ms`` is kept
-    only where it grew, since a larger one was set inside the interval."""
+    by, entry by entry: counts, totals and seconds grow (the parts of
+    ``sched_drained_s`` one level further down); ``max_ms`` is kept only
+    where it grew, since a larger one was set inside the interval."""
     if not after:
         return None
-    out: Dict[str, Dict[str, float]] = {}
-    for name, entry in after.items():
-        was = (before or {}).get(name, {})
-        grown = {k: round(v - was.get(k, 0), 6) for k, v in entry.items()
-                 if k != "max_ms"}
-        if entry.get("max_ms", 0) > was.get("max_ms", 0):
-            grown["max_ms"] = entry["max_ms"]
-        out[name] = grown
-    return out
+    return {name: _grown(entry, (before or {}).get(name) or {})
+            for name, entry in after.items()}
 
 
 class RequestSpans:
@@ -375,6 +382,7 @@ class RequestSpans:
         self.prefill_meta, self.admit_meta, self.chunk_meta = {}, {}, {}
 
     def staged(self, t: float, chunks_ahead: Optional[int] = None,
+               chunks_unready: Optional[int] = None,
                prefill: Optional[Dict[str, Any]] = None, **meta) -> None:
         """The admission's host work is done: its window is staged for
         the next chunk (``dispatched`` follows), or its own admission
@@ -386,16 +394,18 @@ class RequestSpans:
             self.t_staged, self.admit_meta = t, meta
             self.prefill_meta = prefill or {}
             if chunks_ahead is not None:
-                self.chunk_meta = {"chunks_ahead": chunks_ahead}
+                self.chunk_meta = _ahead(chunks_ahead, chunks_unready)
 
     def dispatched(self, t: float, chunk: int, chunks_ahead: int,
-                   **meta) -> None:
+                   chunks_unready: Optional[int] = None, **meta) -> None:
         """The chunk that carries the staged window is issued, behind
-        ``chunks_ahead`` decode chunks already queued on the device."""
+        ``chunks_ahead`` decode chunks nobody has fetched yet, of which
+        the device is still at work on ``chunks_unready``
+        (``EngineSpans.pipe_chunks``): what the window really waits for."""
         if self.phase == "prefill" and self.t_disp is None:
             self.t_disp = t
             self.chunk_meta = dict(meta, chunk=chunk,
-                                   chunks_ahead=chunks_ahead)
+                                   **_ahead(chunks_ahead, chunks_unready))
 
     def first_token(self, t: float) -> None:
         if self.phase != "prefill":
@@ -409,8 +419,8 @@ class RequestSpans:
             t_chunk = min(max(self.t_disp, t_staged), t)
             spans.append(("stage_wait", t_staged, t_chunk, {}, {}))
         spans.append(("first_chunk", t_chunk, t,
-                      {"chunks_ahead_total":
-                       self.chunk_meta.get("chunks_ahead", 0)},
+                      {f"{k}_total": self.chunk_meta.get(k, 0)
+                       for k in ("chunks_ahead", "chunks_unready")},
                       self.chunk_meta))
         self._write(*spans)
         self.phase, self.t_mark, self.chunks = "decode", t, 0
@@ -456,6 +466,14 @@ class RequestSpans:
         self.phase, self.t_mark = "queue_wait", t
 
 
+def _ahead(chunks_ahead: int, chunks_unready: Optional[int]) -> Dict[str, int]:
+    """``first_chunk``'s two counts of the chunks in front of it; a caller
+    that asks no buffer (one opaque program) has them equal."""
+    return {"chunks_ahead": chunks_ahead,
+            "chunks_unready": (chunks_ahead if chunks_unready is None
+                               else chunks_unready)}
+
+
 #: the states that partition the scheduler thread's wall time
 SCHED_STATES = ("admit", "dispatch", "fetch_wait", "consume", "idle",
                 "other")
@@ -485,47 +503,115 @@ class SchedSpans:
     still has no name. Only while a scheduler runs: the start-up warm-up
     calls the same code from another thread and is nobody's child.
 
-    Beside the partition the thread keeps a second one, ``starved``: the
-    same seconds by state, counted only while the chunk pipe holds no
-    chunk program (``note_pipe``) and a slot is live (``note_live``) or an
-    admission is in hand (state ``admit``). It is the host's view of a
-    drained pipe, in every run and with no profiler attached: it learns
-    that the device is done with a chunk only where it looks (the
-    scheduler's loop, a dispatch, a fetch, the end of a child region)."""
+    Beside the partition the thread keeps two more, with no profiler
+    attached and in every run. Both learn what the device has done only
+    where the thread looks: ``look`` (the scheduler's loop, a dispatch,
+    after a fetch, the end of every child region) asks ``probe``.
+
+    ``starved``: the same seconds by state, counted only while the chunk
+    pipe holds no chunk program and a slot is live (``note_live``) or an
+    admission is in hand (state ``admit``). The host's view of a drained
+    pipe, and an upper bound on idle: an admission's eager pieces, state
+    copies and arm run on the device meanwhile.
+
+    ``drained``: the seconds while the NEWEST program the thread launched,
+    of any kind, is done (``launched`` tells of each; the device runs one
+    stream in launch order, so it then has nothing to run), split by
+    whether there was work at hand (``with_work``: what a host-side change
+    can win) or not (``no_work``: the traffic is too light), the former
+    also by the innermost open named region (``by_region``, ``none`` where
+    no region is open). A stretch is counted from the first look that
+    finds the device done; what lies between the last look that saw it
+    busy and that one, less the thread's own waits for the device
+    (``fetch_wait``: it was busy, or the wait was over at once), is
+    ``unseen`` (from the thread's start on: a launch of the warm-up is
+    nobody's until a scheduler runs). So ``total`` is a lower bound of the device's idle time
+    with work at hand and ``total + unseen`` an upper one, but for what
+    the thread cannot see: a program queued behind its own host-to-device
+    copy, and a buffer's way back to the host."""
 
     def __init__(self, stats: SpanStats, log: Deque[dict],
                  annotate: Optional[Callable[..., Any]] = None):
         self._stats = stats
         self._log = log
         self._annotate = annotate
-        self._lock = threading.Lock()   # snapshot() reads from other threads
-        self._state_s = dict.fromkeys(SCHED_STATES, 0.0)
-        self._starved_s = dict.fromkeys(SCHED_STATES, 0.0)
+        self._lock = threading.Lock()   # the readers are other threads
+        # every second the thread has charged, ``_charge``'s to add to
+        self._acc: Dict[str, Dict[str, float]] = {
+            table: dict.fromkeys(SCHED_STATES, 0.0)
+            for table in ("state", "starved", "with_work", "no_work")}
+        self._acc["by_region"] = {}
         self._state: Optional[str] = None
         self._t_state = 0.0
         self._elapsed = 0.0             # of scheduler threads that ended
         self._t_start = 0.0
-        self._chunk: Optional[int] = None   # of the innermost open region
+        # of the innermost open region: its chunk number, and the name of
+        # the innermost NAMED one
+        self._chunk: Optional[int] = None
+        self._region: Optional[str] = None
         self._pipe_chunks = 0           # chunk programs in flight
         self._live = False              # any slot seated
         self._starved_at_empty = 0.0    # starved total when the pipe drained
-        # ``EngineSpans`` sets it: counts the pipe anew. A chunk leaves the
-        # pipe when the DEVICE is done with it, which no call site marks,
-        # so every child region's end looks (an admission is many).
-        self.pipe_probe: Optional[Callable[[], int]] = None
+        self._drained = True            # nothing launched yet: nothing to run
+        self._t_busy = 0.0              # the last look that saw it busy
+        self._fetch_s_at_busy = 0.0     # ... and fetch_wait's seconds then
+        self._unseen_s = 0.0
+        self._drained_at_mark = 0.0     # with_work total at ``drained_ms``
+        # ``EngineSpans`` sets it: (chunk programs the device is not done
+        # with, is it done with the newest launch). No call site marks the
+        # moment the DEVICE ends a program, so the thread asks.
+        self.probe: Optional[Callable[[], Tuple[int, bool]]] = None
 
-    def _starving(self) -> bool:
-        return self._pipe_chunks == 0 and (self._live
-                                           or self._state == "admit")
+    def _has_work(self) -> bool:
+        return self._live or self._state == "admit"
+
+    def _charge(self, acc: Dict[str, Dict[str, float]], dt: float) -> None:
+        """``dt`` more seconds of the open state, into every table they
+        belong to."""
+        state = self._state
+        acc["state"][state] += dt
+        work = self._has_work()
+        if work and self._pipe_chunks == 0:
+            acc["starved"][state] += dt
+        if self._drained:
+            acc["with_work" if work else "no_work"][state] += dt
+            if work:
+                name = self._region or "none"
+                acc["by_region"][name] = acc["by_region"].get(name, 0.0) + dt
 
     def _settle(self, now: float) -> None:
         """Charge the open state up to ``now`` (caller holds the lock)."""
         if self._state is not None:
-            dt = now - self._t_state
-            self._state_s[self._state] += dt
-            if self._starving():
-                self._starved_s[self._state] += dt
+            self._charge(self._acc, now - self._t_state)
             self._t_state = now
+
+    def sections(self) -> Dict[str, Dict[str, Any]]:
+        """The three partitions as ``/health.spans`` serves them, from ONE
+        read of the tables with the open state charged up to now.
+        ``sched_thread_s``: seconds per state since the first start(), and
+        their sum's independent check ``elapsed`` (thread start → now).
+        ``sched_starved_s``: the part of each spent with the pipe empty and
+        work at hand, and ``total``, their sum. ``sched_drained_s``: the
+        part of each spent with the newest launch done, with work at hand
+        and without, each with its ``total``; the section's ``total`` is
+        ``with_work``'s, ``by_region`` its split by the innermost open
+        named region, ``unseen`` what no look saw (see the class)."""
+        now = time.monotonic()
+        with self._lock:
+            acc = {k: dict(v) for k, v in self._acc.items()}
+            elapsed, unseen = self._elapsed, self._unseen_s
+            if self._state is not None:
+                self._charge(acc, now - self._t_state)
+                elapsed += now - self._t_start
+        drained: Dict[str, Any] = {k: _with_total(acc[k])
+                                   for k in ("with_work", "no_work")}
+        drained.update(total=drained["with_work"]["total"],
+                       unseen=round(unseen, 6),
+                       by_region=_rounded(acc["by_region"]))
+        return {"sched_thread_s": dict(_rounded(acc["state"]),
+                                       elapsed=round(elapsed, 6)),
+                "sched_starved_s": _with_total(acc["starved"]),
+                "sched_drained_s": drained}
 
     def _switch(self, state: Optional[str], now: float) -> Optional[str]:
         with self._lock:
@@ -553,22 +639,62 @@ class SchedSpans:
                 self._settle(time.monotonic())
                 self._live = live
 
-    def note_pipe(self, chunks: int) -> float:
-        """The number of chunk programs in flight, told wherever it
-        changes. Returns, when the pipe goes from empty to holding one,
-        the ms it stood empty with work at hand (the growth of the
-        starved total since it drained), else 0."""
-        if chunks == self._pipe_chunks:
-            return 0.0
+    def _note_device(self, done: bool, now: float) -> None:
+        """What a look found of the newest launch (lock held, settled)."""
+        if not done:
+            self._drained, self._t_busy = False, now
+            self._fetch_s_at_busy = self._acc["state"]["fetch_wait"]
+        elif not self._drained:
+            self._drained = True
+            if self._state is None:     # the warm-up's: nobody's seconds
+                return
+            waited = self._acc["state"]["fetch_wait"] - self._fetch_s_at_busy
+            since = max(self._t_busy, self._t_start)
+            self._unseen_s += max(now - since - waited, 0.0)
+
+    def note_pipe(self, chunks: int, done: Optional[bool] = None) -> float:
+        """One look: the number of chunk programs in flight and whether
+        the device is done with the newest launch (None: not asked).
+        Returns, when the pipe goes from empty to holding one, the ms it
+        stood empty with work at hand (the growth of the starved total
+        since it drained), else 0."""
+        now = time.monotonic()
         with self._lock:
-            self._settle(time.monotonic())
+            self._settle(now)
+            if done is not None:
+                self._note_device(done, now)
             was, self._pipe_chunks = self._pipe_chunks, chunks
-            total = sum(self._starved_s.values())
+            if (chunks == 0) == (was == 0):
+                return 0.0
+            total = sum(self._acc["starved"].values())
             if chunks == 0:
                 self._starved_at_empty = total
-            elif was == 0:
-                return (total - self._starved_at_empty) * 1000.0
-        return 0.0
+                return 0.0
+            return (total - self._starved_at_empty) * 1000.0
+
+    def look(self) -> float:
+        """Ask ``probe`` and note its answer (``note_pipe``)."""
+        return self.note_pipe(*self.probe()) if self.probe is not None else 0.0
+
+    def note_launch(self, prev_done: bool) -> None:
+        """A device program is out. ``prev_done``: the device was done
+        with the launch before it, so it had stood with nothing to run
+        for a stretch of which no look saw the end: ``unseen``, whole."""
+        now = time.monotonic()
+        with self._lock:
+            self._settle(now)
+            if prev_done:
+                self._note_device(True, now)
+            self._note_device(False, now)
+
+    def drained_ms(self) -> float:
+        """The ms the device had nothing to run with work at hand since
+        the last call: a dispatch's ``drained_ms``, one chunk period's."""
+        with self._lock:
+            self._settle(time.monotonic())
+            total = sum(self._acc["with_work"].values())
+            was, self._drained_at_mark = self._drained_at_mark, total
+        return (total - was) * 1000.0
 
     @contextmanager
     def region(self, state: Optional[str], name: Optional[str] = None,
@@ -582,8 +708,15 @@ class SchedSpans:
         ``call_ms`` as ``call_total_ms``. ``state`` None (``child``)
         keeps the enclosing state."""
         t0, wall0 = time.monotonic(), time.time()
-        prev = self._switch(state, t0) if state is not None else None
-        outer, self._chunk = self._chunk, chunk
+        if state is not None:
+            prev = self._switch(state, t0)
+        else:       # what ran so far is the enclosing region's
+            with self._lock:
+                self._settle(t0)
+        outer = self._chunk, self._region
+        self._chunk = chunk
+        if name is not None:
+            self._region = f"sched/{name}"
         try:
             if name is not None and self._annotate is not None:
                 stat = {} if chunk is None else {"chunk": chunk}
@@ -593,14 +726,14 @@ class SchedSpans:
                 yield fields
         finally:
             t1 = time.monotonic()
-            self._chunk = outer
             if state is not None:
                 # Back to the enclosing state — but only while a scheduler
                 # is running: a region entered before start() charges
                 # nothing.
                 self._switch(prev, t1)
-            elif self.pipe_probe is not None:
-                self.note_pipe(self.pipe_probe())
+            else:
+                self.look()     # an admission is many children
+            self._chunk, self._region = outer
             fields["ms"] = (t1 - t0) * 1000.0
             if name is not None:
                 self._stats.note(f"sched/{name}", fields["ms"], **{
@@ -623,30 +756,21 @@ class SchedSpans:
                           "event": event, **fields})
 
     def snapshot(self) -> Dict[str, float]:
-        """Seconds per state since the first start(), and their sum's
-        independent check ``elapsed`` (thread start → now)."""
-        now = time.monotonic()
-        with self._lock:
-            parts = dict(self._state_s)
-            elapsed = self._elapsed
-            if self._state is not None:
-                parts[self._state] += now - self._t_state
-                elapsed += now - self._t_start
-        out = {k: round(v, 6) for k, v in parts.items()}
-        out["elapsed"] = round(elapsed, 6)
-        return out
+        return self.sections()["sched_thread_s"]
 
     def starved(self) -> Dict[str, float]:
-        """The part of each state's seconds spent with the pipe empty
-        and work at hand, and ``total``, their sum."""
-        now = time.monotonic()
-        with self._lock:
-            parts = dict(self._starved_s)
-            if self._state is not None and self._starving():
-                parts[self._state] += now - self._t_state
-        out = {k: round(v, 6) for k, v in parts.items()}
-        out["total"] = round(sum(parts.values()), 6)
-        return out
+        return self.sections()["sched_starved_s"]
+
+    def drained(self) -> Dict[str, Any]:
+        return self.sections()["sched_drained_s"]
+
+
+def _rounded(parts: Dict[str, float]) -> Dict[str, float]:
+    return {k: round(v, 6) for k, v in parts.items()}
+
+
+def _with_total(parts: Dict[str, float]) -> Dict[str, float]:
+    return dict(_rounded(parts), total=round(sum(parts.values()), 6))
 
 
 def _on_device(buf: Any) -> bool:
@@ -660,16 +784,19 @@ def _on_device(buf: Any) -> bool:
 class EngineSpans:
     """Everything an engine with a scheduler keeps for its spans, so the
     batcher and its fake twin cannot drift: the totals (``stats``), the
-    scheduler thread's spans over the engine's chunk ring (``sched``), and
+    scheduler thread's spans over the engine's chunk ring (``sched``),
     ``slot_free_since`` — when the free-slot count last left 0 (``None``
-    while no slot is free), the stamp ``queue_wait`` splits on."""
+    while no slot is free), the stamp ``queue_wait`` splits on — and what
+    ``sched`` asks at every look: the engine's in-flight queue and the
+    newest launch's handle."""
 
     def __init__(self, log: Deque[dict],
                  annotate: Optional[Callable[..., Any]] = None):
         self.stats = SpanStats()
         self.sched = SchedSpans(self.stats, log, annotate)
-        self.sched.pipe_probe = self._pipe_chunks
+        self.sched.probe = self._probe
         self._inflight: List[tuple] = []
+        self._newest: Any = None
         self.slot_free_since: Optional[float] = time.monotonic()
 
     def of(self, req) -> RequestSpans:
@@ -697,26 +824,74 @@ class EngineSpans:
 
     def note_pipe(self, inflight: List[tuple]) -> float:
         """Call wherever the engine's in-flight queue changes, and once
-        per scheduler iteration for paths that clear it wholesale: the
-        pipe is the chunk programs among its entries that the device is
-        not done with (``SchedSpans.note_pipe``). The list is the
-        engine's own, changed in place: the scheduler's child regions
-        count it again as they end."""
+        per scheduler iteration for paths that clear it wholesale: one
+        look (``SchedSpans.look``) at the pipe, the chunk programs among
+        its entries that the device is not done with, and at the newest
+        launch. The list is the engine's own, changed in place: the
+        scheduler's child regions look again as they end."""
         self._inflight = inflight
-        return self.sched.note_pipe(self._pipe_chunks())
+        return self.sched.look()
 
-    def _pipe_chunks(self) -> int:
+    def _probe(self) -> Tuple[int, bool]:
+        return self.pipe_chunks(), self._device_done()
+
+    def pipe_chunks(self) -> int:
+        """The chunk programs in flight that the device is not done with:
+        ``first_chunk``'s ``chunks_unready`` when asked at a dispatch."""
         return sum(1 for e in self._inflight
                    if e[0] == "chunk" and _on_device(e[1]))
+
+    def launched(self, buf: Any) -> None:
+        """The scheduler thread has issued a device program of any kind
+        (a chunk, an eager piece, an arm, a copy, a one-op slice): ``buf``
+        is a handle of its output, the newest launch from now on. Hand in
+        a SMALL output that no later program is given as a donated
+        argument where there is one (the packed buffer, a token, a logits
+        row); a donated one is read as busy once it is deleted, until the
+        program that took it has told of itself here. Off the scheduler
+        thread (the warm-up) it charges nothing."""
+        prev_done = self._device_done()
+        self._newest = buf
+        self.sched.note_launch(prev_done)
+
+    def _device_done(self) -> bool:
+        """Is the device done with everything this thread has launched:
+        with the newest launch, since it runs one stream in launch order.
+        One ``is_ready()``, and none once it has said yes."""
+        buf = self._newest
+        if buf is None:
+            return True
+        if getattr(buf, "is_ready", None) is None:
+            # the fake engine's numpy buffer: at work until it is fetched
+            done = not any(e[1] is buf for e in self._inflight)
+        else:
+            deleted = getattr(buf, "is_deleted", None)
+            done = not (deleted is not None and deleted()) and buf.is_ready()
+        if done:
+            self._newest = None
+        return done
+
+    def dispatched(self, inflight: List[tuple], buf: Any) -> Dict[str, float]:
+        """A chunk program is out, ``buf`` its packed buffer, its entry
+        appended to ``inflight``: the two fields its ``sched/dispatch``
+        ring entry takes. ``pipe_empty_ms``: how long the pipe had stood
+        empty with work at hand when it was issued; ``drained_ms``: how
+        long, since the dispatch before, the device had nothing at all to
+        run with work at hand."""
+        self.launched(buf)
+        return {"pipe_empty_ms": self.note_pipe(inflight),
+                "drained_ms": self.sched.drained_ms()}
 
     def health(self, chunks_consumed: int) -> Dict[str, Any]:
         """The ``/health.spans`` section: cumulative ``{count, total_ms,
         max_ms}`` per span name (request spans and ``sched/*`` alike) and
-        the scheduler thread's wall time by state — cheap host counters."""
+        the scheduler thread's wall time by state, whole
+        (``sched_thread_s``), with the pipe empty (``sched_starved_s``)
+        and with the device done with all it was given
+        (``sched_drained_s``) — cheap host counters."""
         out: Dict[str, Any] = self.stats.snapshot()
-        out["sched_thread_s"] = dict(self.sched.snapshot(),
-                                     chunks_consumed=chunks_consumed)
-        out["sched_starved_s"] = self.sched.starved()
+        out.update(self.sched.sections())
+        out["sched_thread_s"]["chunks_consumed"] = chunks_consumed
         return out
 
 

@@ -1141,9 +1141,11 @@ def _debug_forbidden(request: web.Request) -> Optional[web.Response]:
 async def handle_debug_profile(request: web.Request) -> web.Response:
     """POST /debug/profile?seconds=N — capture a jax.profiler device trace
     while live traffic runs (SURVEY.md §5 tracing row; TensorBoard-
-    loadable). Auth- and token-gated; one capture at a time; only the
-    newest few captures are retained (obs/profiler.py). ``/debug/trace``
-    is the pre-rename alias."""
+    loadable). ``python_tracer=1`` turns the profiler's Python tracer on
+    (frames of every call, at the price of a slower host; off by default).
+    Auth- and token-gated; one capture at a time; only the newest few
+    captures are retained (obs/profiler.py). ``/debug/trace`` is the
+    pre-rename alias."""
     denied = _debug_forbidden(request)
     if denied is not None:
         return denied
@@ -1158,7 +1160,9 @@ async def handle_debug_profile(request: web.Request) -> web.Response:
     sph = getattr(request.app["service"].engine, "spans_health", None)
     try:
         result = await obs_profiler.capture(
-            seconds, probe=sph if callable(sph) else None)
+            seconds, probe=sph if callable(sph) else None,
+            python_tracer=request.query.get("python_tracer", "0").lower()
+            in ("1", "true", "yes", "on"))
     except Exception as e:  # pragma: no cover - backend-dependent
         logger.exception("trace capture failed")
         return _json_error(500, f"trace capture failed: {e}")

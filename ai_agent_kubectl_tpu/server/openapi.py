@@ -187,19 +187,35 @@ def build_openapi() -> Dict:
                            "[0.1, 30]) starts a jax.profiler capture "
                            "while live traffic keeps serving and returns "
                            "the TensorBoard-loadable trace directory. "
+                           "python_tracer=1 turns the profiler's Python "
+                           "tracer on (a frame for every call, and a "
+                           "slower host); it is off by default. "
                            "One capture at a time (409 otherwise); the "
                            "newest few captures are retained. Gated by "
                            "API-key auth AND — when DEBUG_TOKEN is set — "
                            "an X-Debug-Token header.",
+            "parameters": [{
+                "name": "seconds", "in": "query", "required": False,
+                "schema": {"type": "number", "default": 2.0},
+                "description": "Capture length, clamped to [0.1, 30]",
+            }, {
+                "name": "python_tracer", "in": "query", "required": False,
+                "schema": {"type": "integer", "enum": [0, 1], "default": 0},
+                "description": "1 turns the profiler's Python tracer on",
+            }],
             "responses": {
                 "200": {"description": "Capture summary JSON "
-                                       "(trace_dir, seconds, clock_start/"
+                                       "(trace_dir, seconds, python_tracer: "
+                                       "whether the Python tracer was on, "
+                                       "clock_start/"
                                        "clock_stop: [time.monotonic(), "
                                        "time.time_ns()] pairs that place "
                                        "flight-recorder spans on the "
                                        "trace's axis; spans: what "
                                        "/health.spans grew by between "
-                                       "them)"},
+                                       "them, sched_thread_s, "
+                                       "sched_starved_s and sched_drained_s "
+                                       "(with by_region) among it)"},
                 "400": _err("seconds not a number"),
                 "401": auth_err,
                 "403": _err("Invalid or missing X-Debug-Token (only when "

@@ -193,10 +193,15 @@ class HealthResponse(BaseModel):
     # Engine spans (obs/trace.py): cumulative {count, total_ms, max_ms}
     # per span name since start — the request phases (queue_wait with
     # slot_wait_total_ms, prefill and its children admit_host /
-    # stage_wait / first_chunk with chunks_ahead_total, decode,
-    # detokenize) and the scheduler's sched/admit|dispatch|fetch|consume
+    # stage_wait / first_chunk with chunks_ahead_total and
+    # chunks_unready_total, decode, detokenize) and the scheduler's
+    # sched/admit|dispatch|fetch|consume and their named children
     # — plus ``sched_thread_s``: the scheduler thread's wall time by
     # state (admit, dispatch, fetch_wait, consume, idle, other; they sum
-    # to ``elapsed``) and ``chunks_consumed``. None = engine without a
-    # chunk scheduler.
+    # to ``elapsed``) and ``chunks_consumed``; ``sched_starved_s``: the
+    # part of it with no chunk program in flight and work at hand; and
+    # ``sched_drained_s``: the part with the NEWEST launch of any kind
+    # done, so that the device had nothing to run (``with_work`` and
+    # ``no_work`` by state, ``total`` = with_work's, ``unseen``,
+    # ``by_region``). None = engine without a chunk scheduler.
     spans: Optional[Dict[str, Any]] = None

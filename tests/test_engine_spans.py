@@ -1,9 +1,12 @@
 """Engine spans stamped where the work happens (obs/trace.py RequestSpans,
 SchedSpans, SpanStats): the span tree of a request on both generate
 routes, the slot-wait split of queue_wait, the carrying chunk's
-``chunks_ahead``, the scheduler thread's wall-time partition,
-``/health.spans``, the four event messages the benchmark's regex reads, and
-the ``sched/*`` annotations in a ``jax.profiler`` capture."""
+``chunks_ahead`` and ``chunks_unready``, the scheduler thread's wall-time
+partition and its two companions (``sched_starved_s``, the pipe empty;
+``sched_drained_s``, the newest launch of any kind done), ``/health.spans``,
+the four event messages the benchmark's regex reads, and the ``sched/*``
+annotations in a ``jax.profiler`` capture, which holds no Python-tracer
+frame unless asked."""
 
 import asyncio
 import importlib.util
@@ -369,6 +372,286 @@ def test_a_chunk_the_device_is_done_with_has_left_the_pipe():
         entry["pipe_empty_ms"] / 1e3, rel=0.05)
 
 
+class _Buf:
+    """A launch's output handle: what ``jax.Array`` answers, by hand."""
+
+    def __init__(self, done=False):
+        self.done, self.deleted, self.asked = done, False, 0
+
+    def is_ready(self):
+        self.asked += 1
+        if self.deleted:
+            raise RuntimeError("Array has been deleted.")
+        return self.done
+
+    def is_deleted(self):
+        return self.deleted
+
+
+def _engine_spans(n=32):
+    from collections import deque
+
+    from ai_agent_kubectl_tpu.obs.trace import EngineSpans
+
+    return EngineSpans(deque(maxlen=n))
+
+
+def _check_drained(drained: dict, thread: dict) -> None:
+    """``sched_drained_s``: two partitions by state, each with its total,
+    the section's total the one with work, the regions' seconds its split."""
+    assert set(drained) == {"with_work", "no_work", "total", "unseen",
+                            "by_region"}
+    for part in ("with_work", "no_work"):
+        assert set(drained[part]) == {*SCHED_STATES, "total"}
+        assert sum(drained[part][s] for s in SCHED_STATES) == pytest.approx(
+            drained[part]["total"], abs=1e-5)
+    assert drained["total"] == drained["with_work"]["total"]
+    assert sum(drained["by_region"].values()) == pytest.approx(
+        drained["total"], abs=1e-4)
+    assert drained["unseen"] >= 0
+    for s in SCHED_STATES:      # parts of the thread's seconds, state by state
+        assert drained["with_work"][s] + drained["no_work"][s] <= \
+            thread[s] + 1e-4, s
+    assert drained["with_work"]["total"] + drained["no_work"]["total"] <= \
+        thread["elapsed"] + 1e-4
+
+
+def test_drained_is_a_partition_split_by_the_work_at_hand():
+    """The seconds while the newest launch is done, by state: with a slot
+    live or an admission in hand they are what the host could win, with
+    neither they say the traffic left the device alone. Nothing is charged
+    while the device is at a launch of any kind."""
+    spans = _engine_spans()
+    spans.sched.start()
+    spans.note_slots([None, None])
+    with spans.sched.region("idle"):
+        time.sleep(0.01)                    # nothing launched, nothing to do
+    first = spans.sched.drained()
+    assert first["no_work"]["idle"] >= 0.009 and first["total"] == 0.0
+    spans.note_slots([object(), None])
+    piece = _Buf()
+    with spans.sched.region("admit", "admit", chunk=1):
+        time.sleep(0.005)                   # in hand, nothing on the device
+        spans.launched(piece)
+        time.sleep(0.01)                    # the device is at the piece
+    mid = spans.sched.drained()
+    assert 0.004 <= mid["with_work"]["admit"] < 0.009
+    piece.done = True
+    with spans.sched.region("consume", "consume", chunk=1):
+        time.sleep(0.005)                   # done, but nobody has looked
+    assert spans.sched.drained()["total"] == mid["total"]
+    spans.note_pipe([])                     # the loop's look
+    with spans.sched.region("dispatch", "dispatch", chunk=2) as entry:
+        time.sleep(0.005)
+        chunk = _Buf()
+        inflight = [("chunk", chunk)]
+        entry.update(spans.dispatched(inflight, chunk))
+        time.sleep(0.005)                   # the chunk is out
+    spans.sched.stop()
+    drained, thread = spans.sched.drained(), spans.sched.snapshot()
+    _check_drained(drained, thread)
+    assert 0.004 <= drained["with_work"]["dispatch"] < 0.009
+    assert drained["with_work"]["consume"] == 0.0
+    assert drained["no_work"]["idle"] == first["no_work"]["idle"]
+    assert drained["no_work"]["total"] == pytest.approx(
+        first["no_work"]["total"], abs=0.001)
+    # the dispatch's ring entry: what the device stood drained since the
+    # dispatch before it (none: since the start), beside pipe_empty_ms
+    assert entry["drained_ms"] == pytest.approx(drained["total"] * 1e3,
+                                                rel=0.05)
+    assert entry["pipe_empty_ms"] > entry["drained_ms"]
+    assert piece.asked >= 1 and chunk.asked >= 1
+
+
+def test_an_eager_piece_on_the_device_is_starved_and_not_drained():
+    """The two accounts apart: no chunk program is in flight, so the pipe
+    is empty and the seconds are ``starved``; the admission's eager piece
+    is still on the device, so not one of them is ``drained``."""
+    spans = _engine_spans()
+    inflight = []
+    spans.sched.start()
+    spans.note_slots([object(), None])
+    spans.note_pipe(inflight)
+    piece = _Buf()
+    with spans.sched.region("admit", "admit", chunk=1):
+        with spans.sched.child("eager_prefill"):
+            spans.launched(piece)
+        time.sleep(0.01)
+        with spans.sched.child("radix_evict"):      # its end looks
+            time.sleep(0.002)
+    spans.sched.stop()
+    assert spans.sched.starved()["admit"] >= 0.011
+    drained = spans.sched.drained()
+    assert drained["total"] < 0.0005 and drained["unseen"] == 0.0
+
+
+def test_unseen_grows_only_between_a_busy_look_and_the_done_look_after():
+    """What lies between the last look that saw the device busy and the
+    first that saw it done is not known: ``unseen``. Looks that agree add
+    nothing, and neither does a wait for the device (``fetch_wait``), at
+    whose end the thread KNOWS when it was done."""
+    spans = _engine_spans()
+    inflight = []
+    spans.sched.start()
+    spans.note_slots([object()])
+    buf = _Buf()
+    spans.launched(buf)
+    for _ in range(3):
+        time.sleep(0.003)
+        spans.note_pipe(inflight)           # busy, busy, busy
+    assert spans.sched.drained()["unseen"] == 0.0
+    time.sleep(0.01)
+    buf.done = True                         # ... somewhere in here
+    time.sleep(0.002)
+    spans.note_pipe(inflight)               # the first look that sees it
+    unseen = spans.sched.drained()["unseen"]
+    assert 0.011 <= unseen < 0.02
+    assert buf.asked == 4
+    for _ in range(3):
+        time.sleep(0.003)
+        spans.note_pipe(inflight)           # done, done, done
+    assert spans.sched.drained()["unseen"] == unseen
+    assert buf.asked == 4                   # done is done: not asked again
+    total = spans.sched.drained()["total"]
+    assert 0.008 <= total < 0.015           # counted from the look on
+    # the thread blocks on the newest launch's buffer: busy until then
+    buf = _Buf()
+    spans.launched(buf)
+    spans.note_pipe(inflight)
+    with spans.sched.region("fetch_wait", "fetch", chunk=1):
+        time.sleep(0.01)
+        buf.done = True
+    spans.note_pipe(inflight)
+    assert spans.sched.drained()["unseen"] == pytest.approx(unseen, abs=0.001)
+    # a launch that finds the one before it done, unseen by any look
+    unseen = spans.sched.drained()["unseen"]
+    buf, t0 = _Buf(), time.monotonic()
+    spans.launched(buf)
+    buf.done = True
+    time.sleep(0.005)
+    spans.launched(_Buf())
+    grew = spans.sched.drained()["unseen"] - unseen
+    assert 0.004 <= grew <= time.monotonic() - t0
+    spans.sched.stop()
+    _check_drained(spans.sched.drained(), spans.sched.snapshot())
+
+
+def test_a_launch_before_the_thread_starts_leaves_nothing_unseen():
+    """The warm-up launches from another thread, long before a scheduler
+    runs: the stretch from its launch to the scheduler's first look is
+    nobody's, and ``unseen`` stays within the thread's own seconds."""
+    spans = _engine_spans()
+    warm = _Buf()
+    spans.launched(warm)                    # no scheduler yet
+    time.sleep(0.02)
+    warm.done = True
+    spans.sched.start()
+    spans.note_slots([object()])
+    time.sleep(0.003)
+    spans.note_pipe([])                     # the first look: done
+    spans.sched.stop()
+    drained, thread = spans.sched.drained(), spans.sched.snapshot()
+    assert drained["unseen"] <= thread["elapsed"] < 0.015
+    _check_drained(drained, thread)
+    # ... and one that ends before any scheduler has started adds nothing
+    spans = _engine_spans()
+    spans.launched(_Buf(done=True))
+    time.sleep(0.005)
+    spans.launched(_Buf(done=True))
+    assert spans.sched.drained()["unseen"] == 0.0
+
+
+def test_by_region_bills_a_drained_stretch_to_the_innermost_open_region():
+    spans = _engine_spans()
+    inflight = []
+    spans.sched.start()
+    spans.note_slots([object()])
+    spans.note_pipe(inflight)               # nothing launched: drained
+    with spans.sched.region("admit", "admit", chunk=3):
+        time.sleep(0.004)                   # the admission's own
+        with spans.sched.child("radix_evict"):
+            time.sleep(0.006)
+        with spans.sched.child("arm") as arm:
+            time.sleep(0.008)               # the launch waits on the host
+            spans.launched(_Buf())
+            time.sleep(0.005)               # and then the device has it
+    spans.sched.stop()
+    by = spans.sched.drained()["by_region"]
+    assert 0.0075 <= by["sched/arm"] < 0.012
+    assert 0.0055 <= by["sched/radix_evict"] < 0.008
+    assert 0.0035 <= by["sched/admit"] < 0.006
+    assert set(by) == {"sched/arm", "sched/radix_evict", "sched/admit",
+                       "none"}
+    assert by["none"] < 0.001               # between start() and the admit
+    assert arm["ms"] >= 13.0
+    _check_drained(spans.sched.drained(), spans.sched.snapshot())
+
+
+def test_a_donated_or_deleted_handle_does_not_raise():
+    """``is_ready()`` of a donated array raises: a deleted handle is not
+    asked, and reads as busy (a later program took it) until that program
+    has told of itself. With real arrays and a real donation too."""
+    import jax
+    import jax.numpy as jnp
+
+    spans = _engine_spans()
+    spans.sched.start()
+    spans.note_slots([object()])
+    gone = _Buf()
+    spans.launched(gone)
+    was = spans.sched.drained()["total"]    # start() to the launch
+    gone.deleted = True
+    assert spans.note_pipe([]) == 0.0
+    time.sleep(0.003)
+    spans.note_pipe([])
+    assert gone.asked == 0 and spans.sched.drained()["total"] == was
+    spans.launched(_Buf(done=True))         # the program that took it
+    spans.note_pipe([])
+    time.sleep(0.003)
+    assert spans.sched.drained()["total"] >= was + 0.002
+
+    bump = jax.jit(lambda a: a + 1, donate_argnums=(0,))
+    a = bump(jnp.zeros((8,), jnp.int32))
+    spans.launched(a)
+    b = bump(a)                             # takes ``a`` donated
+    assert a.is_deleted()
+    spans.note_pipe([])                     # looks at a deleted handle
+    spans.launched(b)
+    b.block_until_ready()
+    spans.note_pipe([])
+    spans.launched(None)                    # nothing in flight
+    spans.note_pipe([])
+    spans.sched.stop()
+    _check_drained(spans.sched.drained(), spans.sched.snapshot())
+
+
+def test_spans_growth_reaches_into_the_drained_sections_parts():
+    from ai_agent_kubectl_tpu.obs.trace import spans_growth
+
+    before = {"sched/arm": {"count": 2, "total_ms": 3.0, "max_ms": 2.0},
+              "sched_drained_s": {
+                  "with_work": {"admit": 1.0, "total": 1.0},
+                  "no_work": {"idle": 4.0, "total": 4.0},
+                  "total": 1.0, "unseen": 0.25,
+                  "by_region": {"sched/arm": 1.0}}}
+    after = {"sched/arm": {"count": 5, "total_ms": 9.5, "max_ms": 2.0},
+             "sched_drained_s": {
+                 "with_work": {"admit": 1.5, "total": 1.5},
+                 "no_work": {"idle": 4.0, "total": 4.0},
+                 "total": 1.5, "unseen": 0.5,
+                 "by_region": {"sched/arm": 1.25, "none": 0.25}}}
+    assert spans_growth(before, after) == {
+        "sched/arm": {"count": 3, "total_ms": 6.5},
+        "sched_drained_s": {
+            "with_work": {"admit": 0.5, "total": 0.5},
+            "no_work": {"idle": 0.0, "total": 0.0},
+            "total": 0.5, "unseen": 0.25,
+            "by_region": {"sched/arm": 0.25, "none": 0.25}}}
+    assert spans_growth(None, after)["sched_drained_s"]["by_region"] == \
+        after["sched_drained_s"]["by_region"]
+    assert spans_growth(before, None) is None
+
+
 # ----------------------------------------------- fake engine, both routes
 
 @pytest.mark.parametrize("route", ROUTES)
@@ -711,6 +994,62 @@ async def test_starved_seconds_on_the_fake_follow_the_pipe():
         await eng.stop()
 
 
+async def test_drained_seconds_and_unready_chunks_on_the_fake():
+    """The fake scheduler goes through the same ``EngineSpans``: its packed
+    buffer is the newest launch, on the device until it is fetched. With
+    the pipe kept full nothing is drained; once the last chunk is fetched
+    everything is, and with no request left the seconds are ``no_work``'s.
+    Every ``first_chunk`` carries both counts of the chunks in front."""
+    inj = FaultInjector()
+    inj.set("chunk", "delay", 0.003)
+    eng = _fake(batch_size=2, faults=inj, chunk_pipe_depth=3,
+                stream_fn=lambda _p: [9] * 80 + [2])
+    await eng.start()
+    try:
+        seen = []
+        async for _ in eng.generate_stream("long runner", max_tokens=60):
+            seen.append(eng.spans_health()["sched_drained_s"])
+        assert len(seen) > 10
+        # from the second chunk to the one before the last a newer chunk
+        # was always out: not one more microsecond, seen or unseen
+        for key in ("with_work", "unseen", "by_region"):
+            assert seen[2][key] == seen[-3][key], key
+        traces = [Trace(new_request_id()) for _ in range(3)]
+
+        async def run(t, prompt):
+            with use_trace(t):
+                return await eng.generate(prompt, max_tokens=24)
+
+        first = asyncio.ensure_future(run(traces[0], "another long runner"))
+        await asyncio.sleep(0.05)
+        await asyncio.gather(first, run(traces[1], "late joiner"),
+                             run(traces[2], "later joiner"))
+        metas = [next(s for s in t.to_dict()["spans"]
+                      if s["phase"] == "first_chunk")["meta"] for t in traces]
+        assert all(0 <= m["chunks_unready"] <= m["chunks_ahead"]
+                   for m in metas), metas
+        await asyncio.sleep(0.06)          # nothing to run, nothing to do
+        spans = eng.spans_health()
+        drained, thread = spans["sched_drained_s"], spans["sched_thread_s"]
+        _check_drained(drained, thread)
+        assert drained["no_work"]["idle"] >= 0.04
+        assert drained["no_work"]["total"] > drained["with_work"]["total"]
+        assert set(drained["by_region"]) <= {
+            "none", "sched/admit", "sched/dispatch", "sched/consume",
+            "sched/fetch", "sched/radix_match", "sched/radix_insert",
+            "sched/radix_evict"}
+        fc = spans["first_chunk"]
+        assert fc["chunks_unready_total"] == sum(
+            m["chunks_unready"] for m in metas) <= fc["chunks_ahead_total"]
+        disp = [e for e in eng._chunk_log if e["event"] == "dispatch"]
+        assert disp and all(e["drained_ms"] >= 0 for e in disp)
+        assert sum(e["drained_ms"] for e in disp) <= \
+            drained["total"] * 1e3 + 0.01
+    finally:
+        inj.clear()
+        await eng.stop()
+
+
 # ------------------------------------------------- toy JAX engine (CPU)
 
 def _bench_run():
@@ -852,14 +1191,83 @@ async def test_jax_eager_pieces_are_counted_and_timed_one_for_one():
         await client.close()
 
 
-async def test_profile_capture_holds_sched_annotations_with_chunk_numbers():
+async def test_jax_unnamed_launches_have_names_and_every_launch_is_told():
+    """The two launches of an admission that had no name: the slice of an
+    eager span's last row (``sched/eager_tail``) and the placeholder token
+    in front of the arm (``sched/placeholder``), children of the
+    ``sched/admit`` of their chunk number. Every launch tells
+    ``EngineSpans.launched``, so ``sched_drained_s`` is there, a partition,
+    on the real engine; its donated handles never raise; and a
+    ``first_chunk`` counts the chunks the device is still at beside the
+    ones nobody has fetched."""
+    eng = _toy_jax()
+    client = await _client(eng)
+    try:
+        await _ask(client, ROUTES[1], "warm every shape first")
+        before = (await (await client.get("/health")).json())["spans"]
+        metas = []
+        for i in range(3):
+            detail = await _ask(client, ROUTES[i % 2], f"list pods of app {i}")
+            metas.append(next(s["meta"] for s in detail["spans"]
+                              if s["phase"] == "first_chunk"))
+        await asyncio.sleep(0.1)           # an idle engine in the books
+        spans = (await (await client.get("/health")).json())["spans"]
+        assert all(0 <= m["chunks_unready"] <= m["chunks_ahead"]
+                   for m in metas), metas
+        fc = spans["first_chunk"]
+        assert fc["chunks_unready_total"] <= fc["chunks_ahead_total"]
+        grew = {name: spans[name]["count"] - before.get(name, {}).get(
+            "count", 0) for name in ("sched/eager_tail", "sched/placeholder",
+                                     "sched/arm", "sched/admit")}
+        # one placeholder an arm (every admission stages a window), one
+        # tail an eager span
+        assert grew["sched/placeholder"] == grew["sched/arm"] == 3
+        assert 1 <= grew["sched/eager_tail"] <= \
+            spans["sched/eager_prefill"]["count"]
+        ring = (await (await client.get("/debug/chunks?limit=500")).json()
+                )["events"]
+        for name in ("eager_tail", "placeholder"):
+            kids = [e for e in ring if e["event"] == name]
+            assert kids and all(
+                e["span"] == f"sched/{name}" and _nested_in_a_parent(ring, e)
+                and e["chunk"] >= 1 for e in kids), name
+            admits = [p for p in ring if p["event"] == "admit"]
+            assert all(any(p["t0"] <= e["t0"] and e["t1"] <= p["t1"]
+                           and p["chunk"] == e["chunk"] for p in admits)
+                       for e in kids), name
+        # what sched/admit's children leave unnamed
+        kids = ("radix_match", "eager_prefill", "eager_tail", "placeholder",
+                "arm", "cow")
+        inside = sum(spans.get(f"sched/{k}", {}).get("total_ms", 0)
+                     for k in kids)
+        assert 0 < inside <= spans["sched/admit"]["total_ms"]
+        drained, thread = spans["sched_drained_s"], spans["sched_thread_s"]
+        _check_drained(drained, thread)
+        assert drained["no_work"]["idle"] > 0.05
+        assert drained["total"] > 0        # the CPU "device" is quick
+        assert set(drained["by_region"]) <= {"none"} | {
+            n for n in spans if n.startswith("sched/")}
+        disp = [e for e in ring if e["event"] == "dispatch"]
+        assert disp and all("drained_ms" in e and "pipe_empty_ms" in e
+                            for e in disp)
+    finally:
+        await client.close()
+
+
+@pytest.mark.parametrize("python_tracer", [False, True])
+async def test_profile_capture_holds_sched_annotations_with_chunk_numbers(
+        python_tracer):
     """A /debug/profile capture on the CPU backend holds the scheduler's
     spans as TraceAnnotations with a ``chunk`` stat, on the trace's own
     clock; the summary carries the two clock pairs. The child regions are
     there too, on the scheduler thread's line, nested inside the
     ``sched/admit`` (or ``sched/consume``) that ran them, under its chunk
     number; and the response says what ``/health.spans`` grew by between
-    the two stamps."""
+    the two stamps, the scheduler thread's three partitions among it. By
+    default the capture holds NO frame of the profiler's Python tracer
+    (``$file:line function``), which hooked every call of the scheduler
+    thread; ``python_tracer=1`` brings them back, and the answer says
+    which it was."""
     import glob
 
     import jax
@@ -867,20 +1275,23 @@ async def test_profile_capture_holds_sched_annotations_with_chunk_numbers():
     client = await _client(_toy_jax())
     try:
         await _ask(client, ROUTES[1], "warm every shape first")
-        prof = asyncio.ensure_future(
-            client.post("/debug/profile?seconds=1.0"))
+        prof = asyncio.ensure_future(client.post(
+            "/debug/profile?seconds=1.0"
+            + ("&python_tracer=1" if python_tracer else "")))
         await asyncio.sleep(0.2)
         await _ask(client, ROUTES[1], "list pods while the capture runs")
         body = await (await prof).json()
+        assert body["python_tracer"] is python_tracer
         (m0, w0), (m1, w1) = body["clock_start"], body["clock_stop"]
         assert m1 - m0 == pytest.approx((w1 - w0) / 1e9, abs=0.05)
         assert m1 - m0 >= 1.0
         path = glob.glob(body["trace_dir"] + "/plugins/profile/*/*.xplane.pb")
         data = jax.profiler.ProfileData.from_file(path[0])
-        seen, lines = {}, {}
+        seen, lines, frames = {}, {}, 0
         for plane in data.planes:
             for line in plane.lines:
                 for ev in line.events:
+                    frames += ev.name.startswith("$")
                     if ev.name.startswith("sched/"):
                         chunk = dict(ev.stats).get("chunk")
                         seen.setdefault(ev.name, []).append(
@@ -890,6 +1301,7 @@ async def test_profile_capture_holds_sched_annotations_with_chunk_numbers():
                              ev.start_ns + ev.duration_ns, chunk))
         assert {"sched/dispatch", "sched/fetch", "sched/consume",
                 "sched/admit"} <= set(seen), sorted(seen)
+        assert (frames > 100) if python_tracer else (frames == 0), frames
         for name in ("sched/dispatch", "sched/fetch"):
             chunks = [c for c, _ in seen[name] if c is not None]
             assert chunks and all(int(c) >= 1 for c in chunks), seen[name]
@@ -902,6 +1314,8 @@ async def test_profile_capture_holds_sched_annotations_with_chunk_numbers():
         (events,) = lines.values()
         parents = {"sched/radix_match": "sched/admit",
                    "sched/eager_prefill": "sched/admit",
+                   "sched/eager_tail": "sched/admit",
+                   "sched/placeholder": "sched/admit",
                    "sched/arm": "sched/admit",
                    "sched/radix_insert": "sched/consume"}
         assert set(parents) <= set(seen), sorted(seen)
@@ -920,5 +1334,12 @@ async def test_profile_capture_holds_sched_annotations_with_chunk_numbers():
         assert grew["sched_thread_s"]["elapsed"] == pytest.approx(
             m1 - m0, abs=0.1)
         assert grew["decode"]["count"] == 1
+        # the three partitions over the capture's own interval: the
+        # program's account of the device's idle seconds beside the trace's
+        assert sum(grew["sched_thread_s"][s] for s in SCHED_STATES) == \
+            pytest.approx(m1 - m0, abs=0.1)
+        assert 0 < grew["sched_starved_s"]["total"] <= m1 - m0 + 0.1
+        _check_drained(grew["sched_drained_s"], grew["sched_thread_s"])
+        assert grew["sched_drained_s"]["no_work"]["total"] > 0.2
     finally:
         await client.close()
