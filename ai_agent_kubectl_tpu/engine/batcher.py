@@ -65,6 +65,7 @@ from ..obs.steptime import (PHASE_DECODE, PHASE_PREFILL,
 from ..obs.trace import EngineSpans, RequestSpans, Trace, current_trace
 from ..ops.quant import (kv_broadcast_rows, kv_set_slots, kv_slot_update,
                          kv_tokens, kv_update_slice)
+from ..ops.ragged_attention import lane_heads
 from .containment import (CAUSE_SCHEDULER_DEATH, CAUSE_SCHEDULER_ERROR,
                           CAUSE_SLOT_HEALTH, PROBATION_CLEAN_CHUNKS,
                           REASON_HEALTH, REASON_ISOLATED, EngineSupervisor)
@@ -1094,6 +1095,7 @@ class BatchedJaxEngine(JaxEngine):
         self._use_ragged = False
         self._attention_regime = DENSE
         self._attention_regime_reason = "not started"
+        self._lane_heads = 1
         # What the kinds of this configuration's cache count
         # (models/families.py; /health's family sections).
         self._counts_experts = False
@@ -1445,6 +1447,13 @@ class BatchedJaxEngine(JaxEngine):
         self._attention_regime_reason = reason
         self._use_pool = regime != DENSE
         self._use_ragged = regime == RAGGED
+        # KV heads of a pool row that share a lane tile: the compiled
+        # kernel reads heads of 64 two a row of 128 lanes, and the pool is
+        # made so (ops/ragged_attention.py::lane_heads); 1 everywhere else.
+        self._lane_heads = (
+            lane_heads(self.model_cfg.head_dim,
+                       self.model_cfg.kv_heads_paged)
+            if self._use_ragged and backend == "tpu" else 1)
         refusal = cache_refusal(
             self.model_cfg, regime,
             dict(self.mesh.shape) if self.mesh is not None else None,
@@ -2223,7 +2232,8 @@ class BatchedJaxEngine(JaxEngine):
             ring=cfg.sliding_ring(self.prefill_buckets[-1],
                                   self.kv_pool_page),
             dtype=self.dtype, kv_quant=self.kv_quant,
-            counts_experts=self._counts_experts)
+            counts_experts=self._counts_experts,
+            lane_heads=self._lane_heads)
         if self.mesh is None:
             return make()
         # Pool-under-mesh (ISSUE 14): KV heads shard over ``model``
@@ -3131,7 +3141,7 @@ class BatchedJaxEngine(JaxEngine):
         cfg = self.model_cfg
         tp = self.mesh.shape["model"] if self.mesh is not None else 1
         shape = (self._pool_max_pages, self.kv_pool_page,
-                 *kernel_heads(cfg, tp),
+                 *kernel_heads(cfg, tp, self._lane_heads),
                  self.spec_draft_k + 1 if self._spec_live else 1,
                  jnp.dtype(self.dtype).itemsize)
         return (pages_per_step(*shape),
@@ -3147,6 +3157,8 @@ class BatchedJaxEngine(JaxEngine):
             "attention_regime": self._attention_regime,
             "attention_regime_reason": self._attention_regime_reason,
             "attention_pages_per_step": pages,
+            # KV heads of a pool row a 128-lane tile (1: a head fills its own)
+            "attention_lane_heads": self._lane_heads,
             "attention_decode_grid_steps": steps,
             "attention_stream_depth": depth,
             # what a kind of the cache resolved at start

@@ -91,12 +91,19 @@ def resolve_attention_regime(
         return GATHER, pool_page, (
             f"backend {backend} is not a TPU (the kernel would run "
             f"interpreted)")
-    from ..ops.ragged_attention import ragged_supported
+    from ..ops.ragged_attention import lane_heads, ragged_supported
 
-    if not ragged_supported(pool_page, model_cfg.head_dim, 1):
+    # KV heads a row of the pool holds (a ModelConfig says; the tests'
+    # stand-ins give the model's own count)
+    kv_row = getattr(model_cfg, "kv_heads_paged", model_cfg.n_kv_heads)
+    if tp > 1 and lane_heads(model_cfg.head_dim, kv_row) != 1:
+        return GATHER, pool_page, (
+            f"head_dim={model_cfg.head_dim} is no whole lane tile: the "
+            f"kernel pairs such heads on one device only (model axis {tp})")
+    if not ragged_supported(pool_page, model_cfg.head_dim, 1, kv_row):
         return GATHER, pool_page, (
             f"the compiled kernel does not support page={pool_page} "
-            f"head_dim={model_cfg.head_dim}")
+            f"head_dim={model_cfg.head_dim} over {kv_row} KV heads a row")
     return RAGGED, pool_page, (
         "block pool, bf16 KV, device termination, TPU backend")
 

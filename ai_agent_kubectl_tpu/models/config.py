@@ -57,6 +57,14 @@ test models. Family differences are expressed as data, not subclasses:
   its ``lat`` leaf a plane a latent layer — behind two dense layers, a
   chip's share of 512 experts under a GROUP-LIMITED sigmoid router
   (``n_group`` / ``topk_group``)
+- Granite-4.0-H-Micro (``granitemoehybrid``; registered by the benchmark,
+  ``toy-ssm-dense`` is its toy): Mamba-2 layers nine to one of attention
+  without a rotary embedding, each followed by a ``D`` dense MLP
+  (``mixers_per_layer`` 2: a period of 20 mixers, scanned), one
+  state-space group, attention heads of 64 (two KV heads a lane tile of
+  the pool: ops/ragged_attention.py::lane_heads), a tied head, and four
+  scalars: ``embed_multiplier``, ``residual_multiplier``,
+  ``attention_multiplier``, ``logits_scaling``
 """
 
 from __future__ import annotations
@@ -225,6 +233,18 @@ class ModelConfig:
     # False: attention takes no rotary embedding (its positions come from
     # the state-space layers); ``rope_theta`` is then carried, unused.
     use_rope: bool = True
+    # Four scalars of a maximal-update parametrisation (the granitemoehybrid
+    # family's), each 1 (the attention's 0) where a model has none, and then
+    # in no program: the embedding times ``embed_multiplier``; every
+    # sublayer's output times ``residual_multiplier`` before it joins the
+    # residual (patterned configurations: each mixer's); the softmax scale
+    # ``attention_multiplier`` in the place of head_dim ** -0.5 (0: that);
+    # the logits divided by ``logits_scaling``, before a grammar's mask and
+    # the sampler see them.
+    embed_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    logits_scaling: float = 1.0
     # Special tokens (tokenizer-dependent; defaults overridden per family)
     bos_id: int = 1
     eos_ids: Tuple[int, ...] = (2,)
@@ -404,6 +424,11 @@ class ModelConfig:
     @property
     def q_per_kv(self) -> int:
         return self.n_heads // self.n_kv_heads
+
+    @property
+    def softmax_scale(self) -> float:
+        """What the attention scores are multiplied by."""
+        return self.attention_multiplier or self.head_dim ** -0.5
 
     def param_count(self) -> int:
         """Approximate parameter count (embeddings + layers)."""
@@ -598,6 +623,24 @@ TOY_KDA_MLA_MOE = _register(ModelConfig(
     lin_channel_decay=True, lin_decay_floor=-5.0, lin_out_gate="sigmoid",
     kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=16, v_head_dim=32,
     rope_theta=6000000.0, attn_gate="head_wise", max_seq_len=2048,
+))
+
+# Mamba-2 layers four to one of attention WITHOUT a rotary embedding, each
+# followed by a gated dense MLP (a pre-norm block written as its two mixers),
+# ONE state-space group, 64-wide attention heads four to a KV head (two KV
+# heads a 128-lane tile), a tied head, and the four multipliers all away from
+# 1 (a left-out one moves the logits): two periods of ten mixers, the
+# attention layer in the middle of its period, so that the scanned period has
+# state-space layers on both sides of it. The toy of the benchmark's
+# granite-4.0-h-micro configuration.
+TOY_SSM_DENSE = _register(ModelConfig(
+    name="toy-ssm-dense", vocab_size=512, dim=128, n_layers=10, n_heads=8,
+    n_kv_heads=2, head_dim=64, dense_mlp_hidden=192, rms_eps=1e-5,
+    tie_embeddings=True, layer_pattern="MDMD*DMDMD" * 2, mixers_per_layer=2,
+    ssm_heads=8, ssm_head_dim=16, ssm_state=32, ssm_groups=1, ssm_conv=4,
+    ssm_chunk=16, use_rope=False, embed_multiplier=6.0,
+    residual_multiplier=0.35, attention_multiplier=0.03125,
+    logits_scaling=4.0, max_seq_len=2048,
 ))
 
 # --- Gemma (HF: google/gemma-{2b,7b}-it) ---

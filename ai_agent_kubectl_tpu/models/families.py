@@ -367,16 +367,19 @@ def long_prompts(cfg: ModelConfig) -> bool:
     return any(k.long_prompts for k in kinds_of(cfg))
 
 
-def kernel_heads(cfg: ModelConfig, tp: int = 1) -> Tuple[int, int, int]:
+def kernel_heads(cfg: ModelConfig, tp: int = 1,
+                 lane_heads: int = 1) -> Tuple[int, int, int]:
     """(query heads, KV heads, lanes a head) the ragged kernel is sized by
-    on one of ``tp`` model-axis shards."""
+    on one of ``tp`` model-axis shards, ``lane_heads`` KV heads of the
+    pool's rows sharing a lane tile (ops/ragged_attention.py::lane_heads)."""
     for kind in kinds_of(cfg):
         if kind.kernel_heads is not None:
             return kind.kernel_heads(cfg)
     # (a cache row's spare KV heads, 32 held for 30, run with their queries)
     spare = cfg.kv_heads_paged - cfg.n_kv_heads
     return ((cfg.n_heads + spare * cfg.q_per_kv) // tp,
-            cfg.kv_heads_paged // tp, cfg.head_dim)
+            cfg.kv_heads_paged // tp // lane_heads,
+            cfg.head_dim * lane_heads)
 
 
 def resolved_at_start(cfg: ModelConfig, regime: str) -> Dict[str, Any]:

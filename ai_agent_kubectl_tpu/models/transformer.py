@@ -204,8 +204,8 @@ class KVCache:
     @classmethod
     def pool_zeros(cls, cfg: ModelConfig, *, n_blocks: int, page: int,
                    slots: int, ring: int = 0, dtype=jnp.bfloat16,
-                   kv_quant: str = "", counts_experts: bool = False
-                   ) -> "KVCache":
+                   kv_quant: str = "", counts_experts: bool = False,
+                   lane_heads: int = 1) -> "KVCache":
         """The pool engine's cache: the paged leaves ([layers, n_blocks,
         page, ...]: K and V of the attention layers alone and a selecting
         configuration's index keys, or a latent one's compressed rows and
@@ -213,11 +213,15 @@ class KVCache:
         and the count leaves of the configuration's kinds (models/
         families.py; ``experts_read`` where the grouped path serves).
         ``lengths`` is [n_blocks]-shaped and purely structural: per-slot
-        lengths are host truth."""
+        lengths are host truth. ``lane_heads`` KV heads of a row share a
+        lane tile (ops/ragged_attention.py::lane_heads: a head under 128
+        lanes before the compiled kernel): K and V are then [.., KV /
+        lane_heads, lane_heads x head_dim], the same bytes in the same
+        order."""
         from .families import kinds_of
 
-        shape = (cfg.n_of("*"), n_blocks, page, cfg.kv_heads_paged,
-                 cfg.head_dim)
+        shape = (cfg.n_of("*"), n_blocks, page,
+                 cfg.kv_heads_paged // lane_heads, cfg.head_dim * lane_heads)
         if cfg.latent:
             # a pair of tokens a leaf row (``lat`` above), a plane a latent
             # layer (every layer of a uniform block); no K, no V
@@ -472,8 +476,10 @@ def _init_patterned(key: jax.Array, cfg: ModelConfig, dtype) -> Params:
         if cfg.gated_mlp:
             layers["dense_gate"] = dense(next(keys), (nD, d, Fd))
     params: Params = {
+        # (drawn without what the model multiplies its embedding by)
         "embed": (jax.random.normal(next(keys), (cfg.vocab_size, d),
-                                    jnp.float32)).astype(dtype),
+                                    jnp.float32)
+                  / cfg.embed_multiplier).astype(dtype),
         "layers": layers,
         "final_norm": jnp.ones((d,), dtype),
     }
@@ -579,7 +585,8 @@ def init_params(key: jax.Array, cfg: ModelConfig, dtype=jnp.bfloat16) -> Params:
         layers["w_down"] = _dense_init(next(keys), (L, F, d), s_mlp)
 
     params: Params = {
-        "embed": _dense_init(next(keys), (cfg.vocab_size, d), 1.0),
+        "embed": _dense_init(next(keys), (cfg.vocab_size, d),
+                             1.0 / cfg.embed_multiplier),
         "layers": layers,
         "final_norm": jnp.zeros((d,), dtype) if cfg.rms_offset else jnp.ones((d,), dtype),
     }
@@ -770,6 +777,15 @@ def _activation(cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
     if cfg.activation == "relu2":
         return jnp.square(jax.nn.relu(x))
     return jax.nn.silu(x)
+
+
+def _scaled(cfg: ModelConfig, out: jnp.ndarray) -> jnp.ndarray:
+    """A sublayer's output as it joins the residual: times ``residual_
+    multiplier`` where the model has one (no multiply in a program of a
+    model that has none)."""
+    if cfg.residual_multiplier == 1.0:
+        return out
+    return out * jnp.asarray(cfg.residual_multiplier, out.dtype)
 
 
 def _dense_mlp(cfg: ModelConfig, lp: Params, x: jnp.ndarray,
@@ -1255,7 +1271,7 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
             return h
         with jax.named_scope("mlp"):
             mlp = mlp_block(h)
-        return _shard_residual(mesh, h + mlp)
+        return _shard_residual(mesh, h + _scaled(cfg, mlp))
 
     def out_proj(attn):
         """The attention's [B, S, H, hd] output through ``wo`` onto the
@@ -1270,7 +1286,7 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
             with jax.named_scope("attn_norm"):
                 out = rms_norm(out, lp["attn_norm"], cfg.rms_eps,
                                cfg.rms_offset)
-        return _shard_residual(mesh, h + out)
+        return _shard_residual(mesh, h + _scaled(cfg, out))
 
     x = h
     if not cfg.post_norm:
@@ -1280,7 +1296,7 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
         out, layer_ik, counts["lat_rows"] = _latent_attention(
             cfg, attn_impl, x, lp, layer_ik, positions, kv_limit,
             token_mask, write_mask, block_tables, q_lens, layer, win)
-        return (after_attention(_shard_residual(mesh, h + out)),
+        return (after_attention(_shard_residual(mesh, h + _scaled(cfg, out))),
                 layer_k, layer_v, layer_ik, counts)
     with jax.named_scope("qkv_proj"):
         q = qmatmul_heads(x, lp["wq"], H, hd)
@@ -1333,8 +1349,20 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
     # kv_heads_paged``: 30 are kept as 32): the spare heads' K and V are
     # written as zeros and their queries run as zeros, whose outputs
     # ``out_proj`` drops.
-    spare = (layer_k.q if isinstance(layer_k, QuantKV)
-             else layer_k).shape[-2] - KV
+    # ... or hold several KV heads a lane tile (``KVCache.pool_zeros``'
+    # ``lane_heads``: heads of 64 lie two a row of 128 lanes): the leaf's
+    # own shape says which.
+    leaf_heads, leaf_lanes = (layer_k.q if isinstance(layer_k, QuantKV)
+                              else layer_k).shape[-2:]
+    pair = leaf_lanes // hd
+    spare = leaf_heads * pair - KV
+    if pair > 1 and (spare or cfg.selects_keys
+                     or isinstance(layer_k, QuantKV)
+                     or (mesh is not None and mesh.size > 1)):
+        raise NotImplementedError(
+            f"{cfg.name}: {pair} KV heads a lane tile of the pool serve "
+            "plain attention over a bf16 pool on one device, in whole "
+            "tiles of the KV heads")
     if spare:
         q, k, v = (_pad_heads(q, spare * (H // KV)), _pad_heads(k, spare),
                    _pad_heads(v, spare))
@@ -1384,6 +1412,9 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
                 f"pool kv_limit {kv_limit} not a multiple of page {page}")
         flat = _pool_flat_pos(block_tables, positions, page, n_blocks,
                               write_mask)
+        if pair > 1:
+            # a token's row as the leaf holds it: the same lanes
+            k, v = (a.reshape(B, S, KV // pair, pair * hd) for a in (k, v))
         with jax.named_scope("kv_write"):
             if is_q:
                 qk, qv = kv_quantize(k), kv_quantize(v)
@@ -1436,12 +1467,16 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
                         q, layer_k, layer_v, ql, positions[:, 0],
                         block_tables, mesh, layer, page_size=page)
                 else:
-                    from ..ops.ragged_attention import \
-                        ragged_attention_pool
+                    from ..ops.ragged_attention import (
+                        pair_queries, ragged_attention_pool, unpair_outputs)
 
+                    # (a query in its own KV head's lanes of the pool's row)
                     attn = ragged_attention_pool(
-                        q, layer_k, layer_v, ql, positions[:, 0],
-                        block_tables, layer, page_size=page)
+                        pair_queries(q, KV, pair) if pair > 1 else q,
+                        layer_k, layer_v, ql, positions[:, 0], block_tables,
+                        layer, page_size=page, scale=cfg.softmax_scale)
+                    if pair > 1:
+                        attn = unpair_outputs(attn, KV, pair)
             elif is_q:
                 attn = dense_attention_quant(
                     q,
@@ -1449,18 +1484,22 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
                     _pool_gather(layer_k.s, block_tables, n_pages, layer),
                     _pool_gather(layer_v.q, block_tables, n_pages, layer),
                     _pool_gather(layer_v.s, block_tables, n_pages, layer),
-                    mask,
+                    mask, scale=cfg.softmax_scale,
                 )
             else:
                 k_ctx = _pool_gather(layer_k, block_tables, n_pages, layer)
                 v_ctx = _pool_gather(layer_v, block_tables, n_pages, layer)
+                if pair > 1:
+                    k_ctx, v_ctx = (a.reshape(B, kv_limit, KV, hd)
+                                    for a in (k_ctx, v_ctx))
                 if attn_impl == "flash" and S > 1:
                     from ..ops.flash_attention import flash_attention_cached
 
                     attn = flash_attention_cached(q, k_ctx, v_ctx,
                                                   positions)
                 else:
-                    attn = dense_attention(q, k_ctx, v_ctx, mask)
+                    attn = dense_attention(q, k_ctx, v_ctx, mask,
+                                           scale=cfg.softmax_scale)
             attn = gated(attn)
         return (after_attention(out_proj(attn)), layer_k, layer_v, layer_ik,
                 counts)
@@ -1509,7 +1548,7 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
                     q,
                     layer_k.q[ctx], layer_k.s[ctx],
                     layer_v.q[ctx], layer_v.s[ctx],
-                    mask,
+                    mask, scale=cfg.softmax_scale,
                 )
         return (after_attention(out_proj(attn)), layer_k, layer_v, layer_ik,
                 counts)
@@ -1540,7 +1579,8 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
             attn = flash_attention_cached(q, k_ctx, v_ctx, positions)
     else:
         with jax.named_scope("attention"):
-            attn = dense_attention(q, k_ctx, v_ctx, mask)
+            attn = dense_attention(q, k_ctx, v_ctx, mask,
+                                   scale=cfg.softmax_scale)
     return after_attention(out_proj(attn)), layer_k, layer_v, layer_ik, counts
 
 
@@ -1583,7 +1623,7 @@ def _expert_mixer(cfg: ModelConfig, layers: Params, j: int, h, mesh,
                           jnp.asarray(j, jnp.int32) if grouped else None)
         if cfg.shared_mlp_hidden:
             y = y + _dense_mlp(cfg, lp, x, "shared_")
-    return h + y, got
+    return h + _scaled(cfg, y), got
 
 
 def _ssm_mixer(cfg: ModelConfig, layers: Params, j: int, h, ssm, conv,
@@ -1650,7 +1690,7 @@ def _ssm_mixer(cfg: ModelConfig, layers: Params, j: int, h, ssm, conv,
     # the rows of a decode pass that the step kernel passed over
     still = jnp.sum(jnp.logical_not(valid[:, 0]) if S == 1 else 0,
                     dtype=jnp.int32)
-    return h + out, ssm, conv, still[None]
+    return h + _scaled(cfg, out), ssm, conv, still[None]
 
 
 def _linear_mixer(cfg: ModelConfig, lp: Params, j, h, lin, lconv,
@@ -1748,7 +1788,7 @@ def _linear_mixer(cfg: ModelConfig, lp: Params, j, h, lin, lconv,
     # the rows of a decode pass that the step kernel passed over
     still = jnp.sum(jnp.logical_not(valid[:, 0]) if S == 1 else 0,
                     dtype=jnp.int32)
-    return h + out, lin, lconv, jnp.concatenate(
+    return h + _scaled(cfg, out), lin, lconv, jnp.concatenate(
         [rows, jnp.zeros((2,), jnp.int32), still[None]])
 
 
@@ -1840,10 +1880,10 @@ def _patterned_layers(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
                 with jax.named_scope("mlp"):
                     y = _dense_mlp(cfg, lp, x, "dense_")
                     if not cfg.post_norm:
-                        h = h + y
+                        h = h + _scaled(cfg, y)
                 if cfg.post_norm:
                     with jax.named_scope("mlp_norm"):
-                        h = h + norm(y)
+                        h = h + _scaled(cfg, norm(y))
             else:
                 latent = cfg.latent and kind == "*"
                 lp = ({name: leaf(name, j) for name in LATENT_LEAVES
@@ -1907,8 +1947,10 @@ def _scan_period(kinds: Tuple[str, ...]) -> int:
     own, where nothing shorter repeats: a scan of one step, so that a
     configuration cut to one period runs the program its full depth does),
     where every mixer of it can address its layer by a traced index (linear
-    attention, full attention, a dense MLP); 0 = run unrolled."""
-    if set(kinds) - set("L*D"):
+    attention, a Mamba-2 layer, full attention, a dense MLP; an expert layer
+    and a sliding-attention layer cannot: a pattern that holds one keeps
+    the unrolled programs it was measured with); 0 = run unrolled."""
+    if set(kinds) - set("LM*D"):
         return 0
     n = len(kinds)
     return next(p for p in range(1, n + 1)
@@ -1988,6 +2030,7 @@ def forward(
     batch_idx = jnp.arange(B)[:, None]
     if (block_tables is not None and cache.k is not None
             and not isinstance(cache.k, QuantKV)
+            and cache.k.shape[-1] == cfg.head_dim
             and (short := cfg.kv_heads_paged - cache.k.shape[-2]) > 0):
         # a caller's own pool with ``n_kv_heads`` heads a row (benchmark/
         # refcheck.py builds one): made the engine's leaf once, here, and
@@ -1995,6 +2038,28 @@ def forward(
         # the server's do, their spare heads zeros (``KVCache.pool_zeros``)
         cache = dataclasses.replace(cache, k=_pad_heads(cache.k, short),
                                     v=_pad_heads(cache.v, short))
+    if (block_tables is not None and attn_impl == "ragged"
+            and cache.k is not None and not isinstance(cache.k, QuantKV)
+            and cache.k.shape[-1] == cfg.head_dim
+            and jax.default_backend() == "tpu"):
+        # ... and one with a head a row of lanes where the compiled kernel
+        # wants whole lane tiles (heads of 64): the engine's leaf likewise,
+        # several KV heads a tile (``KVCache.pool_zeros``' ``lane_heads``)
+        from ..ops.ragged_attention import lane_heads
+
+        if (n := lane_heads(cfg.head_dim, cfg.kv_heads_paged)) > 1:
+            tiled = lambda a: a.reshape(a.shape[:-2] + (a.shape[-2] // n,
+                                                        n * a.shape[-1]))
+            cache = dataclasses.replace(cache, k=tiled(cache.k),
+                                        v=tiled(cache.v))
+    if cfg.attention_multiplier and (
+            cfg.selects_keys or cfg.slides or cfg.latent
+            or attn_impl in ("flash", "ring")
+            or (mesh is not None and mesh.size > 1)):
+        raise NotImplementedError(
+            f"{cfg.name} names its own softmax scale (attention_multiplier="
+            f"{cfg.attention_multiplier}): plain attention on one device "
+            "takes it, dense, gathered or through the ragged kernel")
     new_ik, state = cache.ik, cache
     counted = {name: getattr(cache, name) for name in KVCache.COUNTS}
     if cfg.latent and mesh is not None and mesh.size > 1:
@@ -2029,6 +2094,8 @@ def forward(
                          dtype=params["final_norm"].dtype)
         if cfg.embed_scale:
             h = h * jnp.asarray(cfg.dim ** 0.5, h.dtype)
+        if cfg.embed_multiplier != 1.0:
+            h = h * jnp.asarray(cfg.embed_multiplier, h.dtype)
 
     if (block_tables is not None and mesh is not None
             and "pipe" in mesh.axis_names and mesh.shape["pipe"] > 1):
@@ -2152,6 +2219,10 @@ def forward(
     new_lat = None
     if cfg.latent:
         new_lat, new_ik = new_ik, None
-    return logits.astype(jnp.float32), KVCache(
+    logits = logits.astype(jnp.float32)
+    if cfg.logits_scaling != 1.0:
+        # in float32, before a grammar's mask and the sampler see them
+        logits = logits / cfg.logits_scaling
+    return logits, KVCache(
         k=new_k, v=new_v, lengths=new_lengths, ik=new_ik, lat=new_lat,
         **_state_leaves(state), **counted)

@@ -501,13 +501,19 @@ def _random_params_int8(key, cfg, dtype, quantize_embed: bool, int4: bool,
             out.append(fill(sds.shape, dtype))
         elif name == "embed" and quantize_embed:
             q = jax.random.randint(k, sds.shape, -127, 128, dtype=_jnp.int8)
+            # (what the model multiplies its embedding by, the seeded rows
+            # are drawn without: ``embed_multiplier`` x E has the unit scale
+            # every other configuration's embedding has, and the layers'
+            # outputs, not the token's own row, decide a logit)
             out.append(QuantInt8(
                 q=q,
-                scale=_jnp.full((sds.shape[0], 1), 1.0 / 127.0,
+                scale=_jnp.full((sds.shape[0], 1),
+                                1.0 / 127.0 / cfg.embed_multiplier,
                                 _jnp.float32),
             ))
         else:
-            scale = 1.0 if name == "embed" else sds.shape[0] ** -0.5
+            scale = (1.0 / cfg.embed_multiplier if name == "embed"
+                     else sds.shape[0] ** -0.5)
             if name == "router" and (cfg.router == "sigmoid_bias"
                                      or cfg.router_width):
                 # unit-variance logits: scores spread over (0, 1) and the

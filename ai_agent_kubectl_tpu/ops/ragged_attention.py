@@ -90,11 +90,64 @@ except ImportError:  # pragma: no cover
     pltpu = None
 
 
-def ragged_supported(page_size: int, head_dim: int,
-                     n_pages: int) -> bool:
-    """Compiled-kernel constraints: lanes want a 128-multiple head dim
-    and a sublane-tileable page."""
-    return head_dim % 128 == 0 and page_size >= 8 and n_pages >= 1
+#: Lanes of a TPU vector register: the minor dim of every tile the compiled
+#: kernel copies, slices and multiplies.
+LANES = 128
+
+
+def lane_heads(head_dim: int, kv_heads: int) -> int:
+    """KV heads a row of the pool holds a lane tile, as the COMPILED kernel
+    reads the pool: 1 where a head is whole lane tiles (every 128-wide
+    head); ``128 // head_dim`` where a head is a whole fraction of one and
+    the KV heads come in such groups (64-wide heads, two a tile); 0 where
+    no form serves the head. A token's K row of 8 heads of 64 is the same
+    512 lanes as 4 heads of 128: the leaf is MADE [.., KV / n, n x head_dim]
+    (models/transformer.py::KVCache.pool_zeros; a 64-lane minor dim has no
+    home in HBM, the compiler pads it to 128 or turns the leaf over), the
+    kernel runs ``heads x n / KV`` query heads a row of 128 lanes
+    (``pair_queries``: a query's values in its own KV head's lanes, zeros
+    in the others', so its scores are its own head's and its output's other
+    lanes are the neighbour's values, dropped by ``unpair_outputs``). The
+    K/V bytes a call streams, which bound a decode call, are unchanged; the
+    MXU multiplies ``n`` times the lanes."""
+    if head_dim % LANES == 0:
+        return 1
+    n = LANES // head_dim if LANES % head_dim == 0 else 0
+    return n if n and kv_heads % n == 0 else 0
+
+
+def ragged_supported(page_size: int, head_dim: int, n_pages: int,
+                     kv_heads: int = 1) -> bool:
+    """Compiled-kernel constraints: a head of whole lane tiles, or whole
+    groups of KV heads that fill one (``lane_heads``), and a
+    sublane-tileable page."""
+    return (lane_heads(head_dim, kv_heads) > 0 and page_size >= 8
+            and n_pages >= 1)
+
+
+def _own_lanes(n_heads: int, kv_heads: int, n: int) -> jnp.ndarray:
+    """bool [heads, n]: which of a pool row's ``n`` heads a lane tile is
+    query head h's own KV head (h // (heads / KV)) % n."""
+    own = (jnp.arange(n_heads) // (n_heads // kv_heads)) % n
+    return own[:, None] == jnp.arange(n)[None, :]
+
+
+def pair_queries(q: jnp.ndarray, kv_heads: int, n: int) -> jnp.ndarray:
+    """[N, W, H, hd] -> [N, W, H, n x hd]: each query in the lanes of its
+    own KV head of the ``n`` a pool row holds a tile, zeros in the others
+    (``lane_heads``)."""
+    own = _own_lanes(q.shape[2], kv_heads, n)
+    wide = jnp.where(own[:, :, None], q[..., None, :], jnp.zeros((), q.dtype))
+    return wide.reshape(q.shape[:-1] + (n * q.shape[-1],))
+
+
+def unpair_outputs(o: jnp.ndarray, kv_heads: int, n: int) -> jnp.ndarray:
+    """``pair_queries``' way back: [N, W, H, n x hd] -> [N, W, H, hd], each
+    head's own KV head's lanes of its output."""
+    own = _own_lanes(o.shape[2], kv_heads, n)
+    o = o.reshape(o.shape[:-1] + (n, o.shape[-1] // n))
+    return jnp.sum(jnp.where(own[:, :, None], o, jnp.zeros((), o.dtype)),
+                   axis=-2)
 
 
 def stacked_kv(k, v, layer):

@@ -73,7 +73,8 @@ def variants_of(cfg_file: dict) -> list:
     out = ["shipped", "w8a8"]
     if "topk" in cfg_file:
         out.insert(1, "every_key")
-    if "ssm_state_size" in cfg_file or "linear_key_head_dim" in cfg_file:
+    if any(key in cfg_file for key in ("ssm_state_size", "mamba_d_state",
+                                       "linear_key_head_dim")):
         out.append("bf16_state")
     return out
 
@@ -235,7 +236,7 @@ def main() -> None:
     from ai_agent_kubectl_tpu.models import transformer
     from ai_agent_kubectl_tpu.ops import gated_delta, ssd_scan
     from ai_agent_kubectl_tpu.ops.quant import to_w8a8
-    from modelmap import fold_seed, sizes
+    from modelmap import fold_seed, key_map, sizes
 
     if not args.rehearse:
         # refcheck.main's own rule for the compile cache: every run of a
@@ -280,8 +281,11 @@ def main() -> None:
                 ssd_scan.STATE_DTYPE = gated_delta.STATE_DTYPE = (
                     jnp.bfloat16 if what.startswith("bf16_state") else state_dtype)
                 sz = sizes(cfg_file)
-                if what == "bf16_state" and "chunk_size" in sz:
-                    sz["chunk_size"] = 1
+                if what == "bf16_state":
+                    # the file's own name for the state-space scan's chunk
+                    for key, field in key_map(cfg_file).items():
+                        if field == "ssm_chunk" and key in sz:
+                            sz[key] = 1
                 try:
                     results = {}
                     if not args.long_only:
