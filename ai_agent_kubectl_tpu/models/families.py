@@ -203,6 +203,20 @@ def _recurrent_section(cfg, counts, facts) -> Optional[dict]:
     return said and {**said, "decode_rows_still": counts["dev"][0]}
 
 
+def _window_section(cfg, counts, facts) -> Optional[dict]:
+    """/health.ssm of such a configuration, beside ``_recurrent_section``'s
+    keys: the rows of WINDOW passes (a chunk program's prologue; an eager
+    piece counts no words) whose state the window kernel updated, those it
+    neither read nor wrote because they brought no token, and the chunks it
+    passed over past a moving row's ``q_len`` (counted on the device,
+    summed over the state-space layers)."""
+    if facts["store"] is None:
+        return None
+    moved, still, skipped = counts["dev"]
+    return {"window_rows_moved": moved, "window_rows_still": still,
+            "window_chunks_skipped": skipped}
+
+
 def _selection_resolved(cfg, regime: str) -> dict:
     """How many keys a query keeps, and how each form reads them."""
     return {"index_topk": cfg.index_topk,
@@ -266,6 +280,12 @@ CACHE_KINDS: Tuple[CacheKind, ...] = (
         count_leaf="ssm_rows", count_shape=(1,), lane="sel_rows",
         refuses=_STATE_REFUSES,
         health={"ssm": _recurrent_section}),
+    # Its window passes count the rows and chunks they passed over too.
+    CacheKind(
+        name="recurrent_window",
+        of=lambda cfg: cfg.has_ssm,
+        count_leaf="ssm_window", count_shape=(3,), lane="sel_rows",
+        health={"ssm": _window_section}),
     # The same, and a sliding layer's ring holds bf16 rows.
     CacheKind(
         name="sliding",

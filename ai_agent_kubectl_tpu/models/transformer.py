@@ -36,6 +36,7 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..ops.attention import dense_attention, dense_attention_quant
 from ..ops.norms import rms_norm
@@ -93,6 +94,12 @@ class KVCache:
              neither read nor wrote (dead slots), summed over layers and
              passes since the chunk program last zeroed it
              (/health.ssm.decode_rows_still). Absent elsewhere.
+    ssm_window: int32 [3], beside it: the rows of WINDOW passes whose state
+             the state-space layers' window kernel updated, the rows it
+             neither read nor wrote (they brought no token) and the chunks
+             it passed over past a moving row's ``q_len``, summed likewise
+             (/health.ssm.window_rows_moved, .window_rows_still,
+             .window_chunks_skipped). Absent elsewhere.
     lat:     pool mode of a latent-attention configuration
              (``ModelConfig.latent``) only, and then THE paged cache: one
              compressed row a token a latent layer (in a pattern: a ``*``
@@ -161,6 +168,7 @@ class KVCache:
     ssm: Any = None
     conv: Any = None
     ssm_rows: Any = None
+    ssm_window: Any = None
     lat: Any = None
     lat_rows: Any = None
     sk: Any = None
@@ -174,8 +182,8 @@ class KVCache:
     #: the leaves that hold one bounded state a batch row (axis 1)
     STATE = ("ssm", "conv", "sk", "sv", "lin", "lconv")
     #: what the passes count on the device, zeroed by the chunk program
-    COUNTS = ("experts_read", "sel_rows", "ssm_rows", "lat_rows",
-              "span_rows", "lin_rows", "expert_picks")
+    COUNTS = ("experts_read", "sel_rows", "ssm_rows", "ssm_window",
+              "lat_rows", "span_rows", "lin_rows", "expert_picks")
 
     @classmethod
     def zeros(cls, cfg: ModelConfig, batch: int, max_seq: int,
@@ -234,7 +242,10 @@ class KVCache:
             if cfg.selects_keys:
                 paged["ik"] = jnp.zeros(
                     shape[:3] + (cfg.index_key_width,), dtype)
-        counts = {kind.count_leaf: jnp.zeros(kind.count_shape, jnp.int32)
+        # (a few zero words are PUT on the device, not computed there: every
+        # shape of ``jnp.zeros`` called eagerly is a program a start compiles)
+        counts = {kind.count_leaf: jnp.asarray(np.zeros(kind.count_shape,
+                                                        np.int32))
                   for kind in kinds_of(cfg) if kind.count_leaf
                   and (counts_experts or kind.lane != "experts_read")}
         return cls(lengths=jnp.zeros((n_blocks,), jnp.int32), **paged,
@@ -1629,20 +1640,22 @@ def _expert_mixer(cfg: ModelConfig, layers: Params, j: int, h, mesh,
 def _ssm_mixer(cfg: ModelConfig, layers: Params, j: int, h, ssm, conv,
                valid, win: Optional[WindowRows] = None):
     """``h + mamba2(norm(h))`` for state-space layer ``j``, from and to
-    plane ``j`` of the state leaves; returns (h, ssm, conv, int32 [1]:
-    ``KVCache.ssm_rows``). ``valid`` [B, S] bool marks a row's
-    real tokens (a prefix of its columns): the rest neither move the
-    state nor enter the convolution's tail; a decode pass (S == 1) takes
-    the step kernel on the whole ``ssm`` leaf, which passes over such a
-    row's state altogether (a window slices its plane out and sets it
-    back). With ``win`` ``h`` is the
+    plane ``j`` of the state leaves (a traced scalar inside the scan over
+    periods); returns (h, ssm, conv, int32 [4]: ``KVCache.ssm_rows`` and
+    the three words of ``KVCache.ssm_window``). ``valid`` [B, S] bool marks
+    a row's real tokens (a prefix of its columns): the rest neither move
+    the state nor enter the convolution's tail; a decode pass (S == 1)
+    takes the step kernel and a window the window kernel, both on the whole
+    ``ssm`` leaf in place, and both pass over the state of a row that
+    brought no token altogether (no plane is sliced out, none set back).
+    With ``win`` ``h`` is the
     window's packed rows: the two projections and the gate run on them,
     the convolution and the scan, which need a slot's tokens in a row,
     on the unpacked [B, S]. Scopes ``ssm/*`` on purpose
     hold no keyword of the benchmark's trace categories: the mixer is
     its own device time, not the attention's or the MLP's."""
-    from ..ops.ssd_scan import (causal_conv, gated_group_norm, ssd_scan,
-                                ssd_step_kernel)
+    from ..ops.ssd_scan import (causal_conv, gated_group_norm,
+                                ssd_step_kernel, ssd_window, window_counts)
 
     B, S = valid.shape
     H, P, N, G = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state,
@@ -1676,8 +1689,8 @@ def _ssm_mixer(cfg: ModelConfig, layers: Params, j: int, h, ssm, conv,
                 with jax.named_scope("step"):
                     y, ssm = ssd_step_kernel(*args, ssm, j, valid[:, 0])
             else:
-                y, state = ssd_scan(*args, ssm[j], cfg.ssm_chunk)
-                ssm = ssm.at[j].set(state)
+                with jax.named_scope("window"):
+                    y, ssm = ssd_window(*args, ssm, j, cfg.ssm_chunk, n_valid)
             conv = conv.at[j].set(tail)
         with jax.named_scope("gate_norm"):
             y = y.reshape(B, S, di)
@@ -1687,10 +1700,14 @@ def _ssm_mixer(cfg: ModelConfig, layers: Params, j: int, h, ssm, conv,
                                  layers["ssm_gate_norm"][j], G, cfg.rms_eps)
         with jax.named_scope("out_proj"):
             out = qmatmul(y, _at(layers["ssm_out"], j))
-    # the rows of a decode pass that the step kernel passed over
-    still = jnp.sum(jnp.logical_not(valid[:, 0]) if S == 1 else 0,
-                    dtype=jnp.int32)
-    return h + _scaled(cfg, out), ssm, conv, still[None]
+    # the rows of a decode pass that the step kernel passed over, then what
+    # the window kernel updated and passed over
+    none = jnp.zeros((3,), jnp.int32)
+    counted = (jnp.concatenate([jnp.sum(n_valid == 0, dtype=jnp.int32)[None],
+                                none]) if S == 1 else
+               jnp.concatenate([none[:1],
+                                window_counts(n_valid, S, cfg.ssm_chunk)]))
+    return h + _scaled(cfg, out), ssm, conv, counted
 
 
 def _linear_mixer(cfg: ModelConfig, lp: Params, j, h, lin, lconv,
@@ -1857,7 +1874,7 @@ def _patterned_layers(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
             if kind == "M":
                 h, ssm, conv, n = _ssm_mixer(cfg, layers, j, h, ssm, conv,
                                              valid, win)
-                count({"ssm_rows": n})
+                count({"ssm_rows": n[:1], "ssm_window": n[1:]})
             elif kind == "E":
                 h, n = _expert_mixer(
                     cfg, layers, j, h, mesh,

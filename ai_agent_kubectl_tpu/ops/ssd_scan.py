@@ -46,11 +46,29 @@ step kernel is the same shape around another recurrence). ``ssd_step`` is
 the same step in ``jnp`` on one plane, kept as what the tests hold the
 kernel to.
 
-The window's scan is plain ``jax.numpy``: at the serving shapes it is 2-3%
-of a layer's arithmetic beside its two projections (a 64-token chunk of 64
-heads is 0.5 GFLOP against the in-projection's 57), so it runs in float32
-at the highest matmul precision, from and to a plane sliced out of the
-leaf, and a kernel for it is ROADMAP's.
+A window (S > 1: an eager piece of one sequence, a chunk program's
+prologue over every slot) is one Pallas kernel a layer too (``ssd_window``,
+ISSUE 54), on the same whole aliased leaf with the layer a prefetched
+scalar. Its grid is (row, block of heads as the step kernel's, chunk of
+``window_chunk`` tokens: 128 of a 512-wide window), the chunks innermost:
+a block of a row's state is fetched once, stays in VMEM across the row's
+chunks and is written once; the rows that brought tokens take the grid's
+first steps, a row that brought none has its state neither read nor
+written, and a chunk wholly past a row's ``q_len`` is passed over. Inside
+a chunk a head's decay-and-score tile ``M`` [Q, Q] (``exp(cum_i - cum_j)
+dt_j C_i.B_j`` under the diagonal; ``cum`` the chunk's running sum of ``dt
+A``, made in XLA in front of the call, a head a sublane) exists in VMEM
+only, and the three products (``M x``, ``C h``, ``x^T B``) run on the MXU
+in float32 at the highest precision. The outputs go straight to the
+window's rows [B, S, H*P], whole lane tiles, so nothing is stacked, moved
+or reshaped behind the loop. In ``jnp`` (``ssd_scan`` from ``ssm[j]`` to
+``ssm.at[j].set``) a ``lax.scan`` over chunks stacked each chunk's outputs
+with a ``dynamic_update_slice`` of 134 us for 4 MB (the chunk axis sat
+inside the buffer's tile), went over a [B, Q, Q, H] float32 decay matrix in
+HBM (16 MB a row a chunk at Q = 256) in elementwise fusions, and scanned
+every slot's row and every column of a prologue, padding too: 8.6% of
+granite-4.0-h-micro's device in the stacking alone (ledger, PR 53).
+``ssd_scan`` is that plain form, kept as what the tests hold the kernel to.
 """
 
 from __future__ import annotations
@@ -277,6 +295,261 @@ def _step_call(x, dt, A, Bm, Cm, D, state, layer, moves, *, block_heads: int,
     y = jnp.swapaxes(y, 2, 3).reshape(B, H, P) + f32(D)[:, None] * x32
     y = jnp.where(moves[:, None, None], y, 0.0)[:, None]
     return y.astype(x.dtype), state
+
+
+#: Scoped VMEM the window kernel asks for: a block of the state in and out
+#: (2 MiB where one group is 64 heads), a chunk's inputs and outputs
+#: [128, 4096], each double-buffered, and ``u`` heads' temporaries.
+_WINDOW_VMEM_BYTES = 48 * 2 ** 20
+#: Tokens a chunk of the window kernel at the most: a chunk's decay-and-score
+#: tile is [Q, Q] a head, so its exponents and its ``M x`` product grow with
+#: Q a token while the two products with the state do not; 128 fills the
+#: MXU's rows and a lane tile (tools/time_ssd_window.py times the others).
+_WINDOW_CHUNK = 128
+
+
+def window_chunk(S: int, chunk: int) -> int:
+    """Tokens a chunk the window kernel walks a window of ``S`` in, under a
+    configuration's ``ssm_chunk`` ("tiling only: changes no result"): the
+    narrower of it and ``_WINDOW_CHUNK``; a window under that is one chunk
+    (whole sublane tiles)."""
+    return min(chunk, _WINDOW_CHUNK, S + -S % 8)
+
+
+def window_counts(q_lens, S: int, chunk: int):
+    """int32 [3], what ``ssd_window`` does with a window of ``S`` whose rows
+    brought ``q_lens`` [B] tokens: the rows whose state it updates, the rows
+    it passes over (they brought none) and the chunks past a moving row's
+    ``q_len`` that it passes over (``KVCache.ssm_window``)."""
+    Q = window_chunk(S, chunk)
+    moved = jnp.sum(q_lens > 0, dtype=jnp.int32)
+    skipped = jnp.where(q_lens > 0, -(-S // Q) - _chunks_with_tokens(q_lens, Q),
+                        0)
+    return jnp.stack([moved, q_lens.shape[0] - moved,
+                      jnp.sum(skipped, dtype=jnp.int32)])
+
+
+def _chunks_with_tokens(q_lens, Q: int):
+    return (q_lens + Q - 1) // Q
+
+
+def _window_kernel(lyr_ref, order_ref, n_live_ref, chunks_ref, x_ref, cum_ref,
+                   dt_ref, b_ref, c_ref, d_ref, s_ref, y_ref, s_out_ref,
+                   cb_ref, *, heads_a_group: int):
+    """Grid step (i, c, k): chunk k of heads c*hb .. c*hb + hb - 1 of row
+    ``order_ref[i]``'s window, from and to its state in layer ``lyr_ref[0]``,
+    for the ``n_live_ref[0]`` rows that brought tokens (``order_ref`` names
+    them first) and the ``chunks_ref[row]`` chunks that hold a row's tokens;
+    every other step names the blocks of the step before it again (nothing is
+    fetched, nothing written back) and does nothing. The state block
+    s_out_ref [1,1,hb,P,N] (one buffer with s_ref: the leaf is aliased) is
+    filled from s_ref at a row's first chunk, stays in VMEM across its
+    chunks and is written back once. x_ref, y_ref [1,Q,hb*P]: the chunk's
+    inputs and outputs, a head P lanes, as the mixer has them; cum_ref,
+    dt_ref [1,hb,Q]: the chunk's running sum of ``dt A`` (<= 0) and ``dt``, a
+    head a sublane, a token a lane; b_ref, c_ref [1,Q,gb*N]: ``B`` and ``C``
+    of the block's groups; d_ref [1,hb*P]: ``D``, a head's P lanes over;
+    cb_ref [Q,Q]: scratch, a group's ``C . B`` scores.
+
+    ``u`` heads at a time, in a loop whose body is traced once: their
+    [u,Q] tiles of ``cum`` and ``dt`` are turned over once, so that a head's
+    is a column too. A head's decay-and-score tile ``M`` [Q,Q] (``exp(cum_i
+    - cum_j) dt_j C_i.B_j`` under the diagonal) is made in VMEM and
+    multiplied with the head's inputs on the MXU, the heads of one lane tile
+    into one lane tile (each against the tile's inputs with the other heads'
+    lanes zeroed: the MXU is a lane tile wide either way); ``C h`` and ``x^T
+    B`` are one product each for the ``u`` heads, whose per-head factors
+    (``exp(cum_i)``; ``exp(cum_Q - cum_j) dt_j``) are columns spread over a
+    head's lanes. All float32, every product at the highest precision."""
+    del lyr_ref
+    Q = x_ref.shape[1]
+    hb, P, N = s_ref.shape[2:]
+    u = math.gcd(hb, 8, heads_a_group)              # heads of one group
+    lanes = min(128, u * P) if P < 128 else P       # of one store of outputs
+    r = lanes // P                                  # heads that share it
+    i, k = pl.program_id(0), pl.program_id(2)
+    n_live = n_live_ref[0]
+    first = pl.program_id(1) * hb
+    dot = partial(jax.lax.dot_general, precision=_HI,
+                  preferred_element_type=jnp.float32)
+    f32 = lambda a: a.astype(jnp.float32)
+
+    @pl.when((i < n_live) & (k == 0)
+             | (n_live == 0) & (i + pl.program_id(1) + k == 0))
+    def _fetched():     # (where no row moves: the one block every step names)
+        s_out_ref[...] = s_ref[...]
+
+    causal = (jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+              >= jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1))
+    lane = jax.lax.broadcasted_iota(jnp.int32, (Q, lanes), 1)
+
+    def spread(cols, at):
+        """[Q, lanes]: column ``at + n`` of ``cols`` over lanes n*P .. n*P +
+        P - 1."""
+        out = jnp.broadcast_to(cols[:, at + r - 1:at + r], (Q, lanes))
+        for n in range(r - 2, -1, -1):
+            out = jnp.where(lane < (n + 1) * P,
+                            jnp.broadcast_to(cols[:, at + n:at + n + 1],
+                                             (Q, lanes)), out)
+        return out
+
+    def heads(t, carry):
+        h0 = t * u                                  # in the block
+        g = (first + h0) // heads_a_group - first // heads_a_group
+        Bg = f32(b_ref[0, :, pl.ds(pl.multiple_of(g * N, N), N)])     # [Q,N]
+        Cg = f32(c_ref[0, :, pl.ds(pl.multiple_of(g * N, N), N)])
+
+        @pl.when((t == 0) | ((first + h0) % heads_a_group == 0))
+        def _scores():
+            cb_ref[...] = dot(Cg, Bg, (((1,), (1,)), ((), ())))
+        cb = cb_ref[...]
+        cum, dt = cum_ref[0, pl.ds(h0, u)], dt_ref[0, pl.ds(h0, u)]   # [u,Q]
+        last = cum[:, Q - 1:Q]                                        # [u,1]
+        cum_c = cum.T                                                 # [Q,u]
+        grow_c, to_end_c = jnp.exp(cum_c), (jnp.exp(last - cum) * dt).T
+        state = s_out_ref[0, 0, pl.ds(h0, u)]                         # [u,P,N]
+        state2 = f32(state).reshape(u * P, N)
+        from_state = dot(Cg, state2, (((1,), (1,)), ((), ())))      # [Q,u*P]
+        scaled = []
+        for n in range(u // r):             # a lane tile of outputs at a time
+            at = pl.multiple_of((h0 + n * r) * P, lanes)
+            xs = f32(x_ref[0, :, pl.ds(at, lanes)])                  # [Q,lanes]
+            y = (from_state[:, n * lanes:(n + 1) * lanes]
+                 * spread(grow_c, n * r) + xs * d_ref[:, pl.ds(at, lanes)])
+            for m in range(r):
+                j = n * r + m
+                decay = jnp.exp(jnp.where(causal, cum_c[:, j:j + 1]
+                                          - cum[j:j + 1], -jnp.inf))
+                own = xs if r == 1 else jnp.where(
+                    (lane >= m * P) & (lane < (m + 1) * P), xs, 0.0)
+                y = y + dot(decay * dt[j:j + 1] * cb, own,
+                            (((1,), (0,)), ((), ())))
+            y_ref[0, :, pl.ds(at, lanes)] = y.astype(y_ref.dtype)
+            scaled.append(xs * spread(to_end_c, n * r))
+        added = dot(jnp.concatenate(scaled, axis=1), Bg,
+                    (((0,), (0,)), ((), ())))                         # [u*P,N]
+        kept = jnp.exp(jnp.broadcast_to(last, (u, N)))      # the chunk's decay
+        for j in range(u):
+            s_out_ref[0, 0, h0 + j] = (
+                f32(state[j]) * kept[j:j + 1]
+                + added[j * P:(j + 1) * P]).astype(s_out_ref.dtype)
+        return carry
+
+    @pl.when((i < n_live) & (k < chunks_ref[order_ref[i]]))
+    def _moves():
+        jax.lax.fori_loop(0, hb // u, heads, 0)
+
+
+def ssd_window(x, dt, A, Bm, Cm, D, state, layer, chunk: int, q_lens=None):
+    """``ssd_scan`` from and to plane ``layer`` (a Python int or a traced
+    scalar) of the WHOLE state leaf ``state`` [layers, B, H, P, N], as one
+    Pallas kernel: the leaf is aliased input to output and the layer a
+    prefetched scalar of the index maps, so no plane is sliced out in front
+    of the call and none set back behind it. The grid is (row, block of
+    ``_block_heads`` heads, chunk of ``window_chunk`` tokens), the chunks
+    innermost: a block of a row's state is read once, stays in VMEM across
+    the row's chunks and is written once; a chunk's [Q, Q] decay-and-score
+    tiles are made in VMEM and its outputs written straight to the
+    window's rows [B, S, H*P], so nothing is stacked behind the loop.
+    ``q_lens`` [B]: a row's real tokens, a prefix of its columns (absent:
+    up to its last ``dt`` that is not 0); ``dt`` is 0 past them. A row that
+    brought none has its state neither read nor written (the rows that
+    brought some take the grid's first steps) and a chunk wholly past a
+    row's ``q_len`` is passed over; outputs past ``q_len`` are zeros
+    (``ssd_scan`` gives them ``C . h + D x``, which nothing reads). Other
+    arguments as ``ssd_scan``'s. Returns (y [B,S,H,P] in x's dtype, the
+    leaf). Off the TPU the kernel runs interpreted."""
+    return _window_call(x, dt, A, Bm, Cm, D, state, layer, q_lens,
+                        chunk=window_chunk(x.shape[1], chunk),
+                        interpret=jax.default_backend() != "tpu")
+
+
+@partial(jax.jit, static_argnames=("chunk", "interpret"))
+def _window_call(x, dt, A, Bm, Cm, D, state, layer, q_lens, *, chunk: int,
+                 interpret: bool):
+    """``ssd_window``, under a jit of its own (as ``_step_call``: one trace
+    of the kernel a start, one lowering a program)."""
+    B, S, H, P = x.shape
+    G, N = Bm.shape[2:]
+    Q = chunk
+    pad = -S % Q
+    n_chunks = (S + pad) // Q
+    hb = _block_heads(H, G, P * N * state.dtype.itemsize)
+    nb, Hg = H // hb, H // G
+    gb = max(hb // Hg, 1)                           # groups a block
+    f32 = lambda a: a.astype(jnp.float32)
+    dt = f32(dt)
+    if q_lens is None:
+        q_lens = jnp.max(jnp.where(jnp.any(dt != 0, axis=-1),
+                                   jnp.arange(1, S + 1), 0), axis=1)
+    q_lens = q_lens.astype(jnp.int32)
+    order, n_live = moving_rows_first(q_lens > 0)
+    live_chunks = _chunks_with_tokens(q_lens, Q)
+    rows = lambda a: jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+    # ``dt`` and a chunk's running sum of ``dt A``, a head a sublane, a token
+    # a lane
+    dth = jnp.moveaxis(rows(dt), 1, 2)                          # [B,H,S+pad]
+    cum = jnp.cumsum((dth * f32(A)[:, None]).reshape(B, H, n_chunks, Q),
+                     axis=-1).reshape(dth.shape)
+    lyr = jnp.asarray(layer, jnp.int32).reshape(1)
+
+    def at(i, c, k, lyr, order, n_live, live_chunks):
+        """(row, block, chunk) of step (i, c, k): its own while the row moves
+        and the chunk holds tokens of it, else the last that did (as it is,
+        the block of ``cum`` and ``dt`` [B, H, S])."""
+        moving = i < n_live[0]
+        row = order[jnp.minimum(i, jnp.maximum(n_live[0] - 1, 0))]
+        last = jnp.maximum(live_chunks[row] - 1, 0)
+        return (row, jnp.where(moving, c, nb - 1),
+                jnp.where(moving, jnp.minimum(k, last), last))
+
+    def tokens(i, c, k, *s):        # [B, S, heads' lanes]
+        row, c, k = at(i, c, k, *s)
+        return row, k, c
+
+    def groups(i, c, k, *s):        # [B, S, groups' lanes]
+        row, c, k = at(i, c, k, *s)
+        return row, k, (c * hb) // (gb * Hg)
+
+    def plane(i, c, k, lyr, *s):
+        row, c, _ = at(i, c, k, lyr, *s)
+        return lyr[0], row, c, 0, 0
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(B, nb, n_chunks),
+        in_specs=[pl.BlockSpec((1, Q, hb * P), tokens),
+                  pl.BlockSpec((1, hb, Q), at),
+                  pl.BlockSpec((1, hb, Q), at),
+                  pl.BlockSpec((1, Q, gb * N), groups),
+                  pl.BlockSpec((1, Q, gb * N), groups),
+                  pl.BlockSpec((1, hb * P), lambda i, c, k, *s: (0, at(
+                      i, c, k, *s)[1])),
+                  pl.BlockSpec((1, 1, hb, P, N), plane)],
+        out_specs=[pl.BlockSpec((1, Q, hb * P), tokens),
+                   pl.BlockSpec((1, 1, hb, P, N), plane)],
+        scratch_shapes=[pltpu.VMEM((Q, Q), jnp.float32)],
+    )
+    y, state = pl.pallas_call(
+        partial(_window_kernel, heads_a_group=Hg),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((B, S + pad, H * P), x.dtype),
+                   jax.ShapeDtypeStruct(state.shape, state.dtype)],
+        input_output_aliases={10: 1},
+        interpret=interpret,
+        name="ssd_window",
+        **({} if interpret else {"compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 3,
+            vmem_limit_bytes=_WINDOW_VMEM_BYTES)}),
+    )(lyr, order, n_live, live_chunks,
+      rows(x).reshape(B, S + pad, H * P), cum, dth,
+      rows(Bm).reshape(B, S + pad, G * N), rows(Cm).reshape(B, S + pad, G * N),
+      jnp.repeat(f32(D), P)[None], state)
+    # what no step wrote (a row's columns past its last chunk with tokens, a
+    # row that brought none) holds whatever the buffer held
+    real = jnp.arange(S)[None, :] < q_lens[:, None]
+    y = jnp.where(real[..., None], y[:, :S], 0)
+    return y.reshape(B, S, H, P), state
 
 
 def ssd_scan(x, dt, A, Bm, Cm, D, h0, chunk: int):

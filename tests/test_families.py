@@ -116,7 +116,7 @@ def test_a_kind_tests_its_obstacles_in_its_own_order():
     lists for the mesh first."""
     order = {k.name: tuple(k.refuses) for k in CACHE_KINDS}
     assert order == {"experts": (), "selecting": ("dense", "kv_quant", "mesh"),
-                     "recurrent": ("dense", "mesh", "spec"),
+                     "recurrent": ("dense", "mesh", "spec"), "recurrent_window": (),
                      "sliding": ("dense", "mesh", "spec", "kv_quant"),
                      "linear": ("dense", "mesh", "spec"),
                      "latent": ("dense", "kv_quant", "mesh", "spec"),
@@ -150,7 +150,8 @@ POOLS = {
                         ".v": ((2, 12, 16, 2, 32), _BF16), ".lengths": ((12,), _I32),
                         ".experts_read": ((), _I32),
                         ".ssm": ((2, 3, 8, 16, 32), "float32"),
-                        ".conv": ((2, 3, 3, 256), _BF16), ".ssm_rows": ((1,), _I32)},
+                        ".conv": ((2, 3, 3, 256), _BF16), ".ssm_rows": ((1,), _I32),
+                        ".ssm_window": ((3,), _I32)},
     ("latent", ""): {".lengths": ((12,), _I32), ".experts_read": ((), _I32),
                      ".lat": ((2, 12, 8, 96), _BF16), ".lat_rows": ((2,), _I32)},
     ("sliding", ""): {".k": ((3, 12, 16, 2, 32), _BF16), ".v": ((3, 12, 16, 2, 32), _BF16),
@@ -201,7 +202,7 @@ def test_the_pool_cache_is_built_beside_the_model(kind, kv_quant):
     # (a chip's share of the experts under a group limit adds its two; the
     # latent and the sliding toy hold 4 of 16 under a plain top-k router and
     # keep their lanes; two kinds on the lane lie end to end)
-    words = {"gqa": 0, "selecting": 2, "recurrent": 1, "sliding": 4,
+    words = {"gqa": 0, "selecting": 2, "recurrent": 1 + 3, "sliding": 4,
              "latent": 2, "linear": 6, "kda": 6 + 2 + 2}
     assert attention_words(cfg) == words[kind]
     assert long_prompts(cfg) == (kind != "gqa")
@@ -233,8 +234,10 @@ HEALTH = {
         "layers_linear", "layers_full", "state_bytes_per_sequence", "decode_rows_linear",
         "window_rows_linear", "chunks_scanned", "decode_rows_still", "decode_rows_full",
         "full_keys_read", "forward_passes"]),
-    # (the recurrent kind's own word: the rows its step kernel passed over)
-    "ssm": ("recurrent", [*_SSM, "decode_rows_still"]),
+    # (the recurrent kind's own words: the rows its step kernel passed over;
+    # the rows its window kernel updated and passed over, the chunks it skipped)
+    "ssm": ("recurrent", [*_SSM, "decode_rows_still", "window_rows_moved",
+                          "window_rows_still", "window_chunks_skipped"]),
 }
 
 
@@ -288,6 +291,9 @@ def test_the_sections_count_what_the_scheduler_counted():
     assert list(sl["ssm"]) == _SSM
     # the state-space layers' one word: the rows their step kernel passed over
     assert _sections("recurrent")["ssm"]["decode_rows_still"] == 10
+    # and the window kernel's three, end to end behind it on the lane
+    assert [_sections("recurrent")["ssm"][k] for k in (
+        "window_rows_moved", "window_rows_still", "window_chunks_skipped")] == [11, 12, 13]
     assert _sections("recurrent")["ssm"]["layer_passes"] == {
         "ssm": 10, "experts": 10, "attention": 10, "sliding": 0, "dense_mlp": 0,
         "linear": 0}

@@ -575,8 +575,8 @@ def test_the_ssd_step_kernel_over_every_plane_in_turn_equals_the_recurrence():
 
 def test_decode_logits_through_the_ssd_step_kernel_equal_the_jnp_steps(params, monkeypatch):
     """``forward`` at S == 1 takes ops/ssd_scan.py's kernel (interpreted here) on
-    the whole ``ssm`` leaf: after a 37-token window (``ssd_scan`` from and to a
-    sliced plane, untouched), 12 decode steps' logits and the state they leave
+    the whole ``ssm`` leaf: after a 37-token window (the window kernel, the same
+    on both sides), 12 decode steps' logits and the state they leave
     equal those of ``ssd_step`` patched in from and to a sliced plane, one row of
     the two dead from the fifth step on: its state stays bit for bit as it was,
     which ``ssd_step`` shows too, and its logits, which nothing samples, are
@@ -639,3 +639,199 @@ def test_the_ssd_step_kernels_float32_state_drifts_no_more_than_the_jnp_steps():
 
     err_kernel, err_jnp = decoded(S.ssd_step_kernel), decoded(_plane_ssd_step)
     assert err_kernel < 1.5 * err_jnp + 1e-7, (err_kernel, err_jnp)
+
+
+# ------ a Mamba-2 window as a kernel (ISSUE 54; ops/ssd_scan.py::ssd_window,
+# ------ interpreted here, against ``ssd_scan`` from and to a sliced plane)
+
+#: id -> (S, chunk, H, P, G, N, q_lens): one group and several; a window that is
+#: no multiple of its chunk; rows that end inside a chunk, on its edge and before
+#: the window's last chunks; a row that brought nothing; one chunk for the window
+WINDOWS = {
+    "several-groups-ragged": (37, 8, 4, 8, 2, 16, [37, 18, 0, 3]),
+    "one-group-ragged": (37, 8, 4, 8, 1, 16, [5, 37, 16, 0]),
+    "a-group-a-head": (24, 8, 4, 8, 4, 16, [24, 0, 9, 8]),
+    "one-chunk": (16, 16, 4, 8, 2, 16, [16, 1, 0, 7]),
+    "window-under-its-chunk": (5, 64, 4, 8, 2, 16, [5, 0, 2, 4]),
+    "sixteen-heads-two-tiles": (40, 16, 16, 8, 2, 16, [40, 0, 17, 32]),
+    "all-rows-dead": (24, 8, 4, 8, 2, 16, [0, 0, 0, 0]),
+}
+
+
+def window_inputs(name, planes=3):
+    """``scan_inputs`` in float32 with ``dt`` zeroed past each row's q_len (a
+    dead row: zeros all over), and a leaf of ``planes`` non-zero planes."""
+    S_, chunk, H, P, G, N, q = WINDOWS[name]
+    q = np.asarray(q)
+    raw = scan_inputs(len(name), len(q), S_, H=H, P=P, G=G, N=N)
+    raw["dt"] = raw["dt"] * (np.arange(S_)[None, :, None] < q[:, None, None])
+    for n in ("x", "Bm", "Cm"):
+        raw[n] = raw[n] * (q > 0).reshape((-1,) + (1,) * (raw[n].ndim - 1))
+    a = {k: jnp.asarray(v, jnp.float32) for k, v in raw.items()}
+    h0 = a.pop("h0")
+    return a, jnp.stack([h0 * (j + 1) for j in range(planes)]), q, chunk
+
+
+@pytest.mark.parametrize("given", [False, True], ids=["from-dt", "q-lens-given"])
+@pytest.mark.parametrize("name", list(WINDOWS))
+def test_the_ssd_window_kernel_equals_the_scan_from_a_state(name, given):
+    """On plane 1 (a traced index) of a three-plane leaf against ``ssd_scan``
+    from that plane: every real token's output and the state at each row's
+    ``q_len`` equal to float32 rounding, a row that brought nothing keeps its
+    state bit for bit (the kernel neither reads nor writes it), outputs past a
+    row's ``q_len`` are zeros, the other planes untouched, the leaf float32, the
+    output in x's dtype; ``q_lens`` read off ``dt`` or told."""
+    from ai_agent_kubectl_tpu.ops import ssd_scan as S
+
+    a, leaf, q, chunk = window_inputs(name)
+    want_y, want_h = ssd_scan(*a.values(), leaf[1], chunk)
+    y, out = jax.jit(S.ssd_window, static_argnums=8)(
+        *a.values(), leaf, jnp.asarray(1, jnp.int32), chunk,
+        jnp.asarray(q, jnp.int32) if given else None)
+    for b, n in enumerate(q):
+        np.testing.assert_allclose(np.asarray(y)[b, :n], np.asarray(want_y)[b, :n],
+                                   rtol=1e-5, atol=1e-5)
+        assert not np.asarray(y)[b, n:].any()
+    live = q > 0
+    np.testing.assert_allclose(np.asarray(out[1])[live], np.asarray(want_h)[live],
+                               rtol=1e-6, atol=1e-6)
+    if live.any():
+        assert np.abs(np.asarray(out[1])[live] - np.asarray(leaf[1])[live]).max() > 0.1
+    np.testing.assert_array_equal(np.asarray(out[1])[~live], np.asarray(leaf[1])[~live])
+    np.testing.assert_array_equal(np.asarray(out[::2]), np.asarray(leaf[::2]))
+    assert out.dtype == jnp.float32 and out.shape == leaf.shape
+    assert y.dtype == jnp.float32 and y.shape == a["x"].shape
+    if name == "one-chunk" and given:
+        y16, _ = S.ssd_window(a["x"].astype(jnp.bfloat16), *list(a.values())[1:], leaf, 1,
+                              chunk)
+        assert y16.dtype == jnp.bfloat16
+
+
+@pytest.mark.parametrize("moves", ["0000", "0010", "1111", "1000", "0001", "0101"],
+                         ids=["none", "one", "all", "first", "last", "alternating"])
+def test_the_ssd_window_kernel_visits_only_the_rows_that_move(moves):
+    """Whichever rows brought tokens (21 of a 24-wide window in chunks of 8):
+    their outputs and state equal the scan's, every other row's state is bit for
+    bit its input though its x, B and C are a real token's (``dt`` alone is 0),
+    and its outputs zeros: the kernel takes the moving rows in its grid's first
+    steps and gives the others no block of their own."""
+    from ai_agent_kubectl_tpu.ops import ssd_scan as S
+
+    live = np.asarray([c == "1" for c in moves])
+    raw = scan_inputs(3, 4, 24)
+    raw["dt"] = raw["dt"] * (live[:, None] & (np.arange(24) < 21)[None, :])[..., None]
+    a = {k: jnp.asarray(v, jnp.float32) for k, v in raw.items()}
+    h0 = a.pop("h0")
+    leaf = jnp.stack([h0, h0 * 2])
+    want_y, want_h = ssd_scan(*a.values(), leaf[1], 8)
+    y, out = jax.jit(S.ssd_window, static_argnums=8)(*a.values(), leaf,
+                                                     jnp.asarray(1, jnp.int32), 8)
+    np.testing.assert_allclose(np.asarray(y)[live, :21], np.asarray(want_y)[live, :21],
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(out[1])[live], np.asarray(want_h)[live],
+                               rtol=1e-6, atol=1e-6)
+    assert not np.asarray(y)[~live].any() and not np.asarray(y)[:, 21:].any()
+    np.testing.assert_array_equal(np.asarray(out[1])[~live], np.asarray(leaf[1])[~live])
+    np.testing.assert_array_equal(np.asarray(out[0]), np.asarray(leaf[0]))
+
+
+def test_the_ssd_window_kernel_over_every_plane_in_turn_equals_the_recurrence():
+    """As ``_patterned_layers`` runs it: the whole leaf carried through three
+    windows of a 40-token sequence (17, 16 and 7 tokens in windows 24 wide, chunks
+    of 8), the plane a traced index inside a scan over planes (Granite's scanned
+    period) or a Python int (Nemotron's unrolled layers); every plane ends where
+    the float64 recurrence from its own initial state does."""
+    from ai_agent_kubectl_tpu.ops import ssd_scan as S
+
+    T, W, planes, cuts = 40, 24, 3, [(0, 17), (17, 33), (33, 40)]
+    raw = scan_inputs(11, 2, T)
+    a = {k: jnp.asarray(v, jnp.float32) for k, v in raw.items()}
+    h0 = a.pop("h0")
+    leaf = jnp.stack([h0 * (j + 1) for j in range(planes)])
+
+    def piece(lo, hi):
+        pad = lambda v: jnp.pad(v[:, lo:hi], ((0, 0), (0, W - (hi - lo)))
+                                + ((0, 0),) * (v.ndim - 2))
+        return [pad(a[n]) if a[n].ndim > 1 else a[n]
+                for n in ("x", "dt", "A", "Bm", "Cm", "D")]
+
+    @jax.jit
+    def traced(leaf):
+        ys = []
+        for lo, hi in cuts:
+            def layer(leaf, j, args=piece(lo, hi)):
+                y, leaf = S.ssd_window(*args, leaf, j, 8)
+                return leaf, y[:, :hi - lo]
+            leaf, y = jax.lax.scan(layer, leaf, jnp.arange(planes, dtype=jnp.int32))
+            ys.append(y)
+        return leaf, jnp.concatenate(ys, axis=2)            # [planes, B, T, H, P]
+
+    @jax.jit
+    def unrolled(leaf):
+        ys = []
+        for lo, hi in cuts:
+            got = []
+            for j in range(planes):
+                y, leaf = S.ssd_window(*piece(lo, hi), leaf, j, 8)
+                got.append(y[:, :hi - lo])
+            ys.append(jnp.stack(got))
+        return leaf, jnp.concatenate(ys, axis=2)
+
+    out, y = traced(leaf)
+    out_u, y_u = unrolled(leaf)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(out_u), rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_u), rtol=1e-4, atol=1e-5)
+    for j in range(planes):
+        want_y, want_h = recurrence(**{**raw, "h0": raw["h0"] * (j + 1)})
+        np.testing.assert_allclose(np.asarray(y)[j], want_y, rtol=2e-4, atol=2e-4)
+        np.testing.assert_allclose(np.asarray(out[j]), want_h, rtol=2e-4, atol=2e-4)
+
+
+def test_window_logits_through_the_window_kernel_equal_the_scans(params, monkeypatch):
+    """``forward`` at S > 1 takes ops/ssd_scan.py's window kernel (interpreted
+    here) on the whole ``ssm`` leaf: two ragged windows' logits (one row sits the
+    second out: its state stays bit for bit) and the state they leave equal those
+    of ``ssd_scan`` patched in from and to a sliced plane, and the pass counts
+    what the kernel passed over (``KVCache.ssm_window``)."""
+    from ai_agent_kubectl_tpu.ops import ssd_scan as S
+
+    toks = np.random.default_rng(9).integers(3, 500, size=(2, 64), dtype=np.int32)
+
+    def plane_scan(x, dt, A, Bm, Cm, D, state, layer, chunk, q_lens=None):
+        y, h = ssd_scan(x, dt, A, Bm, Cm, D,
+                        jax.lax.dynamic_index_in_dim(state, layer, 0, False), chunk)
+        return y, jax.lax.dynamic_update_index_in_dim(state, h, layer, 0)
+
+    def windows(window_fn):
+        monkeypatch.setattr(S, "ssd_window", window_fn)
+        run = jax.jit(lambda tok, pos, cache, mask, q: forward(
+            params, CFG, tok, pos, cache, token_mask=mask, write_mask=mask, q_lens=q))
+        cache = dataclasses.replace(KVCache.zeros(CFG, 2, 64, dtype=jnp.float32),
+                                    ssm_window=jnp.zeros((3,), jnp.int32))
+        out, done = [], np.zeros(2, np.int32)
+        for q in ([24, 11], [20, 0]):
+            q = np.asarray(q, np.int32)
+            tok = np.zeros((2, 24), np.int32)
+            for b in range(2):
+                tok[b, :q[b]] = toks[b, done[b]:done[b] + q[b]]
+            pos = (done[:, None] + np.arange(24)[None, :]).astype(np.int32)
+            before = np.asarray(cache.ssm) if cache.ssm is not None else None
+            logits, cache = run(jnp.asarray(tok), jnp.asarray(pos), cache,
+                                jnp.asarray(np.arange(24)[None, :] < q[:, None]),
+                                jnp.asarray(q))
+            out += [np.asarray(logits[b, :q[b]]) for b in range(2)]
+            done += q
+        np.testing.assert_array_equal(np.asarray(cache.ssm)[:, 1], before[:, 1])
+        return np.concatenate(out), np.asarray(cache.ssm), np.asarray(cache.ssm_window)
+
+    kernel = S.ssd_window
+    want, want_state, _ = windows(plane_scan)
+    got, state, counted = windows(kernel)
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4 * want.std())
+    np.testing.assert_allclose(state, want_state, rtol=2e-4, atol=2e-5)
+    # two state-space layers: 2 + 1 rows moved, 0 + 1 sat a window out; chunks of
+    # ``ssm_chunk`` past a moving row's q_len
+    Q = S.window_chunk(24, CFG.ssm_chunk)
+    skipped = sum(-(-24 // Q) - -(-n // Q) for n in (24, 11, 20))
+    assert list(counted) == [2 * 3, 2 * 1, 2 * skipped]
+    assert list(S.window_counts(jnp.asarray([37, 16, 0, 17]), 37, 8)) == [3, 1, 0 + 3 + 2]

@@ -289,6 +289,10 @@ def test_a_session_snapshotted_evicted_and_restored_answers_as_from_token_zero()
     assert st["layer_passes"]["dense_mlp"] == st["forward_passes"] * 10
     assert st["layer_passes"]["attention"] == st["forward_passes"] * 2
     assert st["decode_rows_still"] > 0 and st["decode_rows_still"] % 8 == 0
+    # (the window kernel's three words, ISSUE 54, count a chunk program's
+    # prologue windows: under ``gather`` a prompt prefills in eager pieces)
+    assert {st[k] for k in ("window_rows_moved", "window_rows_still",
+                            "window_chunks_skipped")} == {0}
     # the CPU serves the gather regime: a head a row of the pool's lanes
     assert pool["attention_regime"] == "gather" and pool["attention_lane_heads"] == 1
     eng._state.check()

@@ -324,6 +324,12 @@ async def test_restored_snapshot_answers_as_a_prefill_from_token_zero(from_token
         steps = st3["forward_passes"] - st3["eager_prefill_passes"]
         assert 0 < st3["decode_rows_still"] <= steps * st3["live_rows"] * 2
         assert st3["decode_rows_still"] % 2 == 0
+        # and of the prologue windows, the window kernel's (ISSUE 54; under
+        # ``gather`` a prompt prefills in eager pieces, whose words no chunk
+        # brings to the host)
+        moved, still = st3["window_rows_moved"], st3["window_rows_still"]
+        assert moved % 2 == 0 and still % 2 == 0
+        assert (moved > 0 and still > 0) if force_ragged else moved == still == 0
         eng._state.check()
         spans = eng.spans_health()
         assert spans["sched/state_restore"]["count"] == st3["restores"]

@@ -800,10 +800,13 @@ def test_patterned_forward_compiles_at_published_widths_on_v5e(one_chip, B, W,
     # inside the VMEM it asks for (this one-plane leaf, 33.5 MB, the compiler
     # prefetches whole with a ``copy-start``; the loop of two planes below,
     # and the cell's of six, it does not)
+    # and in a window ops/ssd_scan.py's window kernel (ISSUE 54), the same way
     steps = [eqn for eqn in _pallas_calls(traced.jaxpr.jaxpr)
              if eqn.params["name"] == "ssd_step"]
-    assert len(steps) == (1 if W == 1 else 0)
-    assert hlo.count('custom_call_target="tpu_custom_call"') == 2 + len(steps)
+    windows = [eqn for eqn in _pallas_calls(traced.jaxpr.jaxpr)
+               if eqn.params["name"] == "ssd_window"]
+    assert (len(steps), len(windows)) == ((1, 0) if W == 1 else (0, 1))
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 3
     for eqn in steps:
         gm = eqn.params["grid_mapping"]
         assert gm.grid == (B, 2)
@@ -836,8 +839,10 @@ def test_state_space_decode_loop_holds_no_copy_of_the_state_on_v5e(one_chip,
     plane's multiplies, adds and broadcasts in their fusions and, at the
     cell's own 13 layers (six planes), two ``copy`` of the whole leaf a step
     (201 MB each: AOT, PR 49, of the parent; what the chip's trace showed at
-    PR 33). The prologue's window still slices its plane and sets it, once a
-    chunk, in place (no ``copy`` of the leaf's size anywhere)."""
+    PR 33). The prologue's window is the window kernel's two calls on the
+    same aliased leaf (ISSUE 54: it sliced its plane and set it, once a
+    chunk): nothing of the leaf's size but the four kernels' results
+    anywhere, no ``copy`` among them."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = ModelConfig(name="aot-nemotron", n_layers=4, layer_pattern="MEM*",
                       **NEMOTRON)
@@ -848,9 +853,108 @@ def test_state_space_decode_loop_holds_no_copy_of_the_state_on_v5e(one_chip,
     assert in_loop and {op for op, _ in in_loop} == {"custom-call"}, in_loop
     assert len(in_loop) == 2 and {dims for _, dims in in_loop} == {
         "2,16,64,64,128"}
-    whole = {op for op, _ in _results_of_size(hlo, {2 * plane})}
-    assert "dynamic-update-slice" in whole and not whole & {
-        "copy", "copy-start", "copy-done"}, whole
+    whole = _results_of_size(hlo, {2 * plane})
+    assert {op for op, _ in whole} == {"custom-call"} and len(whole) == 4, whole
+
+
+GRANITE = dict(vocab_size=100352, dim=2048, n_heads=32, n_kv_heads=8,
+               head_dim=64, mlp_hidden=8192, dense_mlp_hidden=8192,
+               rms_eps=1e-5, eos_ids=(2,), tie_embeddings=True,
+               mixers_per_layer=2, ssm_heads=64, ssm_head_dim=64,
+               ssm_state=128, ssm_groups=1, ssm_conv=4, ssm_chunk=256,
+               use_rope=False, embed_multiplier=12, residual_multiplier=0.22,
+               attention_multiplier=0.015625, logits_scaling=8)
+
+#: temporaries of a window program: the parent's (``ssd_scan`` from and to a
+#: sliced plane: AOT, PR 54, ``git archive aa8b46b``) and this tree's
+_WINDOW_TEMPS = {("granite", 1): (3_762_688, 3_128_832),
+                 ("granite", 16): (13_325_312, 4_129_280),
+                 ("nemotron", 1): (57_098_240, 44_987_392),
+                 ("nemotron", 16): (70_192_640, 3_096_576)}
+
+
+@pytest.mark.parametrize("B,W,packed", [(1, 512, None), (16, 64, 80)],
+                         ids=["eager-512", "prologue-64"])
+@pytest.mark.parametrize("sizes", ["granite", "nemotron"])
+def test_a_state_space_window_is_one_kernel_a_layer_on_the_aliased_leaf_on_v5e(
+        one_chip, sizes, B, W, packed, monkeypatch):
+    """A window program (an eager 512-wide piece of one sequence; a chunk
+    program's 64-wide prologue over 16 slots, its valid rows packed) at
+    granite-4.0-h-micro's widths (ONE group of 64 heads; two periods
+    ``MDMD*D`` scanned, the layer a traced ordinal) and at nemotron-3-nano-
+    30b-a3b-l13's (8 groups; ``MEM*`` unrolled): every Mamba-2 layer is ONE
+    ``ssd_window`` call (ISSUE 54) on the WHOLE aliased ``ssm`` leaf, its
+    grid (row, block of whole groups: 64 heads of one group, or 32 of eight,
+    chunk of 128 or of the window's 64), its blocks inside the VMEM it asks
+    for; under ``ssm/scan`` nothing float32 the size of a row's plane or more
+    is sliced, copied or updated (the parent sliced the plane out, set it
+    back and stacked each chunk's outputs with a ``dynamic-update-slice`` in
+    a loop of its own); the temporaries are under the parent's."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = (ModelConfig(name="aot-granite", n_layers=6,
+                       layer_pattern="MDMD*D" * 2, **GRANITE)
+           if sizes == "granite" else
+           ModelConfig(name="aot-nemotron", n_layers=4, layer_pattern="MEM*",
+                       **NEMOTRON))
+    page, n_blocks, pages = 64, 4096, 257
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda x: arg(x.shape, x.dtype),
+        jax.eval_shape(lambda k: random_params_int8(
+            k, cfg, dtype=jnp.bfloat16, quantize_embed=True),
+            jax.random.PRNGKey(0)))
+    from ai_agent_kubectl_tpu.ops.ragged_attention import lane_heads
+    cache = jax.tree_util.tree_map(
+        lambda a: arg(a.shape, a.dtype), jax.eval_shape(
+            lambda: KVCache.pool_zeros(
+                cfg, n_blocks=n_blocks, page=page, slots=B,
+                counts_experts=cfg.grouped_experts,
+                lane_heads=lane_heads(cfg.head_dim, cfg.kv_heads_paged))))
+    leaf = cache.ssm.shape
+    assert leaf == (cfg.n_of("M"), B, 64, 64, 128)
+
+    def step(params, tok, pos, cache, wmask, tables, q_lens):
+        return forward(params, cfg, tok, pos, cache, kv_limit=pages * page,
+                       attn_impl="ragged", token_mask=wmask,
+                       write_mask=wmask, block_tables=tables, q_lens=q_lens,
+                       logits_at=jnp.maximum(q_lens, 1) - 1,
+                       packed_rows=packed)
+
+    traced = jax.jit(step, donate_argnums=(3,)).trace(
+        params, arg((B, W), jnp.int32), arg((B, W), jnp.int32), cache,
+        arg((B, W), jnp.bool_), arg((B, pages), jnp.int32),
+        arg((B,), jnp.int32))
+    calls = [eqn for eqn in _pallas_calls(traced.jaxpr.jaxpr)
+             if eqn.params["name"] == "ssd_window"]
+    # (two layers unrolled, or a period's two in the scan's body, traced once)
+    assert len(calls) == 2 and cfg.n_of("M") == (4 if sizes == "granite" else 2)
+    for eqn in calls:
+        gm = eqn.params["grid_mapping"]
+        assert gm.grid == (B, 1 if sizes == "granite" else 2, W // min(W, 128))
+        assert eqn.params["input_output_aliases"] == ((10, 1),)
+        assert eqn.invars[10].aval.shape == leaf == eqn.outvars[1].aval.shape
+        # (counted at 4 B an element: the inputs come in bf16)
+        blocks = sum(math.prod(b if isinstance(b, int) else b.block_size
+                               for b in bm.block_shape) * 4
+                     for bm in gm.block_mappings)
+        limit = eqn.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
+        assert 2 * 64 * 64 * 128 * 4 // gm.grid[1] < blocks
+        assert 2 * blocks < limit
+    compiled = traced.lower().compile()
+    hlo = compiled.as_text()
+    moved = [(op, result) for result, op, line in _instructions(hlo)
+             if op in ("dynamic-update-slice", "dynamic-slice", "copy",
+                       "copy-start", "while")
+             and re.search(r'op_name="[^"]*ssm/scan', line)
+             and any(n >= 64 * 64 * 128 for n in _sizes(
+                 " ".join(re.findall(r"f32\[[\d,]+\]", result))))]
+    assert not moved, moved
+    before, pinned = _WINDOW_TEMPS[sizes, B]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= pinned * 1.05 < before, temp
 
 
 # ------------------- latent attention and a share of the experts (ISSUE 38)

@@ -468,6 +468,74 @@ def test_compiled_channel_decay_scan_matches_the_float64_recurrence(at_floor):
     np.testing.assert_array_equal(S1[6], np.asarray(a["S0"])[6])
 
 
+@pytest.mark.parametrize("G,chunk", [(8, 128), (1, 256)], ids=["nemotron", "granite"])
+def test_compiled_ssd_window_matches_the_scan_and_the_float64_recurrence(G, chunk):
+    """ops/ssd_scan.py's window kernel COMPILED (ISSUE 54) at both configurations'
+    geometry (64 heads of 64 x 128 in 8 groups or in ONE), on plane 1 of a
+    three-plane leaf under jit with the leaf donated: a 16 x 512 window whose rows
+    end across the chunks' edges (one whole, one empty between two that move, one
+    of a single token) and an eager 1 x 512 piece, from a non-zero state. The rows
+    that brought tokens equal ``ssd_scan`` at the configuration's own chunk to the
+    MXU's six-pass float32 rounding, and one row's first 40 tokens of four heads
+    the float64 recurrence; a row that brought none keeps its state bit for bit
+    (through grid steps that name another row's block again, which the interpreter
+    does not model), the other planes are untouched, outputs past ``q_len`` are
+    zeros."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ai_agent_kubectl_tpu.ops import ssd_scan as S
+
+    H, P, N = 64, 64, 128
+    r = np.random.default_rng(1)
+    f32 = lambda a: jnp.asarray(a, jnp.float32)
+    A, D = f32(-r.uniform(0.5, 16.0, H)), f32(r.normal(size=H))
+    window = jax.jit(S.ssd_window, static_argnums=8, donate_argnums=6)
+    for B, q_lens in ((16, [512, 500, 0, 131, 70, 3, 0, 64, 129, 128, 127, 480, 256, 300, 1, 0]),
+                      (16, [0] * 16), (1, [437])):
+        W = 512
+        q = np.asarray(q_lens)
+        live = q > 0
+        x = f32(r.normal(size=(B, W, H, P)))
+        dt = f32(np.where(np.arange(W)[None, :, None] < q[:, None, None],
+                          r.uniform(0.001, 0.1, (B, W, H)), 0.0))
+        Bm, Cm = f32(r.normal(size=(B, W, G, N))), f32(r.normal(size=(B, W, G, N)))
+        leaf0 = r.normal(size=(3, B, H, P, N)).astype(np.float32)
+        want_y, want_h = S.ssd_scan(x, dt, A, Bm, Cm, D, jnp.asarray(leaf0[1]), chunk)
+        for given in (jnp.asarray(q, jnp.int32), None):
+            y, out = window(x, dt, A, Bm, Cm, D, jnp.asarray(leaf0),
+                            jnp.asarray(1, jnp.int32), chunk, given)
+            y, out = np.asarray(y), np.asarray(out)
+            assert np.isfinite(y).all() and np.isfinite(out).all()
+            for b, n in enumerate(q):
+                np.testing.assert_allclose(y[b, :n], np.asarray(want_y)[b, :n],
+                                           rtol=2e-4, atol=2e-3)
+                assert not y[b, n:].any()
+            np.testing.assert_allclose(out[1][live], np.asarray(want_h)[live],
+                                       rtol=2e-4, atol=2e-4)
+            np.testing.assert_array_equal(out[1][~live], leaf0[1][~live])
+            np.testing.assert_array_equal(out[::2], leaf0[::2])
+        if live.any():
+            _record("ssd_window", groups=G, rows=B,
+                    y_against_scan=max(float(np.abs(y[b, :n] - np.asarray(want_y)[b, :n]).max())
+                                       for b, n in enumerate(q) if n),
+                    state_against_scan=float(np.abs(out[1][live] - np.asarray(want_h)[live]).max()))
+    # one row's head of the window against the recurrence in float64
+    from test_hybrid_model import recurrence
+    heads = slice(0, 64, 16)
+    groups = np.arange(64)[heads] // (64 // G)
+    a64 = [np.asarray(a, np.float64) for a in
+           (x[:1, :40, heads], dt[:1, :40, heads], A[heads], Bm[:1, :40, groups],
+            Cm[:1, :40, groups], D[heads], leaf0[1][:1, heads])]
+    want64 = np.stack([recurrence(a64[0][:, :, i:i + 1], a64[1][:, :, i:i + 1], a64[2][i:i + 1],
+                                  a64[3][:, :, i:i + 1], a64[4][:, :, i:i + 1], a64[5][i:i + 1],
+                                  a64[6][:, i:i + 1])[0][0, :, 0] for i in range(4)], axis=1)
+    y1, _ = window(x[:1], dt[:1], A, Bm[:1], Cm[:1], D, jnp.asarray(leaf0[:, :1]),
+                   jnp.asarray(1, jnp.int32), chunk, None)
+    np.testing.assert_allclose(np.asarray(y1)[0, :40, heads], want64, rtol=2e-4, atol=2e-3)
+
+
 def test_compiled_ssd_step_matches_the_jnp_step_and_skips_still_rows():
     """ops/ssd_scan.py's step kernel COMPILED (ISSUE 49), at nemotron-3-nano-30b-
     a3b's geometry (16 rows, 64 heads of 64 x 128 in 8 groups), on plane 1 of a
