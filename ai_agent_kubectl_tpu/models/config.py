@@ -65,6 +65,18 @@ test models. Family differences are expressed as data, not subclasses:
   the pool: ops/ragged_attention.py::lane_heads), a tied head, and four
   scalars: ``embed_multiplier``, ``residual_multiplier``,
   ``attention_multiplier``, ``logits_scaling``
+- Qwen3-Next-80B-A3B-Instruct (``qwen3_next``; registered by the benchmark,
+  ``toy-gdn-moe`` is its toy): the gated delta rule with FEWER KEY HEADS
+  THAN VALUE HEADS (``lin_key_heads`` 16 for ``lin_value_heads`` 32: value
+  head h reads key head h // 2; q and k are repeated, never the state or
+  ``W_in``) three layers to one of GATED attention — ``attn_gate``
+  "elementwise": ``W_q`` gives every head ``[q | gate]``, 2 x ``head_dim``
+  columns, and ``sigmoid(gate)`` multiplies the head's attention output
+  lane by lane before ``W_o`` — on 256-wide heads normed a head by the
+  ``(1 + w)`` norm (``rms_offset`` 1, on ``q_norm`` / ``k_norm`` too) and
+  rotated on a quarter of their lanes; behind each an expert layer, a
+  chip's share of 512 experts under a softmax router, its shared expert
+  under a sigmoid SCALAR gate a token (``shared_expert_gate``)
 """
 
 from __future__ import annotations
@@ -140,8 +152,11 @@ class ModelConfig:
     # layer keeps ``n_heads``, ``rope_theta`` and the YaRN fields below,
     # on the first ``rope_partial`` of the lanes, cos and sin times
     # ``rope_attention_factor`` (0 = none given: 1). ``attn_gate``
-    # "per-head": sigmoid(x W_g) [heads], from the layer's normed input,
-    # multiplies each head's attention output before W_o (both kinds).
+    # "per-head" (or any other non-empty word but the next): sigmoid(x W_g)
+    # [heads], from the layer's normed input, multiplies each head's
+    # attention output before W_o (both kinds). "elementwise": the gate is a
+    # vector as wide as the head and comes out of W_q itself, whose columns
+    # are [q | gate] a head (2 x head_dim); no W_g leaf.
     sliding_window: int = 0
     sliding_n_heads: int = 0
     sliding_rope_theta: float = 10000.0
@@ -162,8 +177,9 @@ class ModelConfig:
     # Linear attention by the gated delta rule (``L`` layers; ops/
     # gated_delta.py has the recurrence): ``lin_value_heads`` heads, each a
     # float32 state [``lin_key_dim``, ``lin_value_dim``]; q and k of
-    # ``lin_key_heads`` heads (equal to the value heads: a configuration
-    # whose counts differ is refused until the repeat is built); a causal
+    # ``lin_key_heads`` heads, value head h reading key head h // r where
+    # the value heads are r times the key heads (r whole; a decay a key
+    # channel needs r = 1); a causal
     # depthwise convolution of ``lin_conv`` taps over [q | k | v];
     # ``lin_neg_eigval``: the write strength beta runs over (0, 2), not
     # (0, 1).
@@ -188,6 +204,9 @@ class ModelConfig:
     # selection bias, the picked scores renormalised) and the factor the
     # routed sum is scaled by.
     shared_mlp_hidden: int = 0
+    # The shared expert under a gate of its own: sigmoid(x w_s), ONE scalar
+    # a token (leaf ``shared_expert_gate`` [d, 1]), times its output.
+    shared_expert_gate: bool = False
     router: str = "softmax"
     router_scale: float = 1.0
     # A group-limited choice (DeepSeek-V3's; ``sigmoid_bias`` only): the
@@ -276,6 +295,27 @@ class ModelConfig:
         return self.router_width or self.n_experts
 
     @property
+    def gate_elementwise(self) -> bool:
+        """The attention's gate is a vector a head, out of ``W_q``."""
+        return self.attn_gate == "elementwise"
+
+    @property
+    def gate_per_head(self) -> bool:
+        """The attention's gate is a scalar a head, from a leaf ``wg``."""
+        return bool(self.attn_gate) and not self.gate_elementwise
+
+    @property
+    def counts_picks(self) -> bool:
+        """A chip's share of the experts whose passes count the picks that
+        land here (``KVCache.expert_picks``, /health.moe ``picks`` and
+        ``picks_held``): a group-limited choice and a gated shared expert,
+        the shares that came with or after the count. The two shares under a
+        plain top-k router before it keep the programs they were measured
+        with (models/families.py::CACHE_KINDS ``expert_share``)."""
+        return (self.grouped_experts and self.router_width > 0
+                and (self.n_group > 1 or self.shared_expert_gate))
+
+    @property
     def gated_mlp(self) -> bool:
         """Three matrices (act(x Wg) * (x Wu), then Wd) or, for ``relu2``,
         two (relu(x Wu)^2, then Wd: no gate leaf anywhere in the tree)."""
@@ -294,11 +334,16 @@ class ModelConfig:
                 f"not name {n} mixers of kinds {', '.join(MIXER_KINDS)}")
         if "S" in kinds and self.sliding_window < 1:
             raise ValueError(f"{self.name}: an S layer needs sliding_window")
-        if "L" in kinds and self.lin_key_heads != self.lin_value_heads:
+        if "L" in kinds and (
+                self.lin_key_heads < 1
+                or self.lin_value_heads % self.lin_key_heads
+                or (self.lin_channel_decay
+                    and self.lin_value_heads != self.lin_key_heads)):
             raise ValueError(
                 f"{self.name}: lin_value_heads {self.lin_value_heads} over "
-                f"lin_key_heads {self.lin_key_heads}: a key head repeated "
-                "over several value heads is not built (ROADMAP)")
+                f"lin_key_heads {self.lin_key_heads}: a key head serves a "
+                "whole number of value heads, and one where the decay is a "
+                "key channel's; anything else is not built (ROADMAP)")
         if ("L" in kinds and self.lin_channel_decay
                 and not -5.5 <= self.lin_decay_floor < 0):
             raise ValueError(
@@ -467,8 +512,10 @@ class ModelConfig:
             H = self.heads_of(kind)
             whole = (H + self.n_kv_heads) * self.head_dim
             return (2 * d * self.head_dim * (H + self.n_kv_heads)
-                    + (d * H if self.attn_gate else 0)
-                    + (whole if self.qk_norm_whole else 0))
+                    + (d * H if self.gate_per_head else 0)
+                    + (d * H * self.head_dim if self.gate_elementwise else 0)
+                    + (whole if self.qk_norm_whole else 0)
+                    + (2 * self.head_dim if self.qk_norm else 0))
 
         attn = attn_of("*")
         if self.latent:
@@ -485,13 +532,15 @@ class ModelConfig:
                + mats * d * self.shared_mlp_hidden
                + d * self.experts_scored
                + (self.experts_scored if self.router == "sigmoid_bias"
-                  else 0))
+                  else 0)
+               + (d if self.shared_expert_gate else 0))
         ssm = (d * (2 * self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
                     + self.ssm_heads)
                + self.ssm_inner * d + (self.ssm_conv + 1) * self.ssm_conv_dim
                + 3 * self.ssm_heads + self.ssm_inner)
-        # W_q | W_k | W_v | W_g in, W_o out, W_a and W_b, the convolution,
-        # A_log and dt_bias, the gated norm's gain
+        # W_q | W_k | W_v | W_g in (q and k of the KEY heads: lin_conv_dim),
+        # W_o out, W_a and W_b, the convolution, A_log and dt_bias (a value
+        # head's each), the gated norm's gain
         vals = self.lin_value_heads * self.lin_value_dim
         linear = (d * (self.lin_conv_dim + vals) + vals * d
                   + 2 * d * self.lin_value_heads
@@ -641,6 +690,24 @@ TOY_SSM_DENSE = _register(ModelConfig(
     ssm_chunk=16, use_rope=False, embed_multiplier=6.0,
     residual_multiplier=0.35, attention_multiplier=0.03125,
     logits_scaling=4.0, max_seq_len=2048,
+))
+
+# The gated delta rule with two key heads for four value heads three layers to
+# one of gated attention (the gate a vector a head, out of W_q; a (1 + w) norm
+# a head on q and k; a quarter of the lanes rotated; groups of 2 over 2 KV
+# heads), each followed by a chip's share of the experts (the router scores 16,
+# this tree holds 4) beside a shared expert under a sigmoid scalar gate; key and
+# value dims that differ, two periods. The toy of the benchmark's
+# qwen3-next-80b-a3b-instruct-l12 configuration.
+TOY_GDN_MOE = _register(ModelConfig(
+    name="toy-gdn-moe", vocab_size=512, dim=128, n_layers=8, n_heads=4,
+    n_kv_heads=2, head_dim=32, mlp_hidden=64, n_experts=4,
+    experts_per_token=2, router_width=16, shared_mlp_hidden=64,
+    shared_expert_gate=True, layer_pattern="LELELE*E" * 2,
+    mixers_per_layer=2, lin_key_heads=2, lin_value_heads=4, lin_key_dim=24,
+    lin_value_dim=40, lin_conv=4, rms_offset=1.0, qk_norm=True,
+    rope_theta=10000000.0, rope_partial=0.25, attn_gate="elementwise",
+    max_seq_len=2048,
 ))
 
 # --- Gemma (HF: google/gemma-{2b,7b}-it) ---

@@ -167,9 +167,12 @@ def _linear_section(cfg, counts, facts) -> dict:
     before them; ``decode_rows_still``: the rows of decode passes that did
     not move (dead slots), whose state the step kernel neither read nor
     wrote (all six counted on the device, summed over the layers of the
-    kind)."""
+    kind); ``key_heads`` / ``value_heads``: a key head serves value_heads /
+    key_heads value heads."""
     rows_l, window_l, chunks, rows_f, keys_f, still = counts["dev"]
     return {"layers_linear": cfg.n_of("L"), "layers_full": cfg.n_of("*"),
+            "key_heads": cfg.lin_key_heads,
+            "value_heads": cfg.lin_value_heads,
             "state_bytes_per_sequence": cfg.state_bytes(),
             "decode_rows_linear": rows_l, "window_rows_linear": window_l,
             "chunks_scanned": chunks, "decode_rows_still": still,
@@ -339,13 +342,14 @@ CACHE_KINDS: Tuple[CacheKind, ...] = (
         # ops/ragged_attention.py::latent_query's
         kernel_heads=lambda cfg: (
             cfg.n_heads, 1, cfg.kv_lora_rank + 4 * cfg.qk_rope_head_dim)),
-    # A chip's share of the experts under a group-limited choice: how many
-    # of the picks land here (its two words the last of the lane). A share
-    # under a plain top-k router keeps the programs it had.
+    # A chip's share of the experts under a group-limited choice or beside
+    # a gated shared expert: how many of the picks land here (its two words
+    # the last of the lane). The two shares under a plain top-k router that
+    # came before the count keep the programs they had
+    # (``ModelConfig.counts_picks``).
     CacheKind(
         name="expert_share",
-        of=lambda cfg: (cfg.grouped_experts and cfg.router_width > 0
-                        and cfg.n_group > 1),
+        of=lambda cfg: cfg.counts_picks,
         count_leaf="expert_picks", count_shape=(2,), lane="sel_rows",
         health={"moe": _picks_section},
         long_prompts=False),

@@ -403,21 +403,29 @@ def _init_patterned(key: jax.Array, cfg: ModelConfig, dtype) -> Params:
     d, hd, KV = cfg.dim, cfg.head_dim, cfg.n_kv_heads
     nA, nE, nM = cfg.n_of("*"), cfg.n_of("E"), cfg.n_of("M")
     layers: Params = {}
+    # a gain of the (1 + w) norm starts at 0 (the gated head norms' gains
+    # are plain ones whatever the block's norm is)
+    gain = jnp.zeros if cfg.rms_offset else jnp.ones
 
     def attention(kind: str, n: int) -> Params:
         H = cfg.heads_of(kind)
         names = ATTENTION_LEAVES[kind]
+        # (an elementwise gate: a head's columns of W_q are [q | gate])
         leaves = dict(zip(names, (
-            jnp.ones((n, d), dtype),
-            dense(next(keys), (n, d, H * hd)),
+            gain((n, d), dtype),
+            dense(next(keys), (n, d, H * hd
+                               * (2 if cfg.gate_elementwise else 1))),
             dense(next(keys), (n, d, KV * hd)),
             dense(next(keys), (n, d, KV * hd)),
             dense(next(keys), (n, H * hd, d)))))
-        if cfg.attn_gate:
+        if cfg.gate_per_head:
             leaves[names[5]] = dense(next(keys), (n, d, H))
         if cfg.qk_norm_whole:
             leaves[names[6]] = jnp.ones((n, H * hd), dtype)
             leaves[names[7]] = jnp.ones((n, KV * hd), dtype)
+        elif cfg.qk_norm:
+            leaves[names[6]] = gain((n, hd), dtype)
+            leaves[names[7]] = gain((n, hd), dtype)
         return leaves
 
     if nA and cfg.latent:
@@ -429,7 +437,7 @@ def _init_patterned(key: jax.Array, cfg: ModelConfig, dtype) -> Params:
     if nE:
         E, F, Fs = cfg.n_experts, cfg.mlp_hidden, cfg.shared_mlp_hidden
         layers.update(
-            mlp_norm=jnp.ones((nE, d), dtype),
+            mlp_norm=gain((nE, d), dtype),
             router=dense(next(keys), (nE, d, cfg.experts_scored)),
             w_up=dense(next(keys), (nE, E, d, F)),
             w_down=dense(next(keys), (nE, E, F, d)))
@@ -444,10 +452,12 @@ def _init_patterned(key: jax.Array, cfg: ModelConfig, dtype) -> Params:
             layers["shared_down"] = dense(next(keys), (nE, Fs, d))
             if cfg.gated_mlp:
                 layers["shared_gate"] = dense(next(keys), (nE, d, Fs))
+            if cfg.shared_expert_gate:
+                layers["shared_expert_gate"] = dense(next(keys), (nE, d, 1))
     if nM:
         Hs, di, C = cfg.ssm_heads, cfg.ssm_inner, cfg.ssm_conv_dim
         layers.update(
-            ssm_in_norm=jnp.ones((nM, d), dtype),
+            ssm_in_norm=gain((nM, d), dtype),
             ssm_in=dense(next(keys), (nM, d, di + C + Hs)),
             ssm_gate_norm=jnp.ones((nM, di), dtype),
             ssm_out=dense(next(keys), (nM, di, d)))
@@ -463,7 +473,7 @@ def _init_patterned(key: jax.Array, cfg: ModelConfig, dtype) -> Params:
         # step biases are small leaves, bf16 or float32 in an int8 tree too
         Hl, dv, Cl = cfg.lin_value_heads, cfg.lin_value_dim, cfg.lin_conv_dim
         layers.update(
-            lin_norm=jnp.ones((nL, d), dtype),
+            lin_norm=gain((nL, d), dtype),
             lin_in=dense(next(keys), (nL, d, Cl + Hl * dv)),
             lin_gate_norm=jnp.ones((nL, dv), dtype),
             lin_out=dense(next(keys), (nL, Hl * dv, d)))
@@ -481,7 +491,7 @@ def _init_patterned(key: jax.Array, cfg: ModelConfig, dtype) -> Params:
     if nD := cfg.n_of("D"):
         Fd = cfg.dense_mlp_hidden
         layers.update(
-            dense_norm=jnp.ones((nD, d), dtype),
+            dense_norm=gain((nD, d), dtype),
             dense_up=dense(next(keys), (nD, d, Fd)),
             dense_down=dense(next(keys), (nD, Fd, d)))
         if cfg.gated_mlp:
@@ -492,7 +502,7 @@ def _init_patterned(key: jax.Array, cfg: ModelConfig, dtype) -> Params:
                                     jnp.float32)
                   / cfg.embed_multiplier).astype(dtype),
         "layers": layers,
-        "final_norm": jnp.ones((d,), dtype),
+        "final_norm": gain((d,), dtype),
     }
     if not cfg.tie_embeddings:
         params["lm_head"] = dense(next(keys), (d, cfg.vocab_size))
@@ -569,8 +579,9 @@ def init_params(key: jax.Array, cfg: ModelConfig, dtype=jnp.bfloat16) -> Params:
             w_ukv=_dense_init(next(keys), (L, C, H * (N + V)), C ** -0.5),
             wo=_dense_init(next(keys), (L, H * V, d), (H * V) ** -0.5))
     if cfg.qk_norm:
-        layers["q_norm"] = jnp.ones((L, hd), dtype)
-        layers["k_norm"] = jnp.ones((L, hd), dtype)
+        gain = jnp.zeros if cfg.rms_offset else jnp.ones
+        layers["q_norm"] = gain((L, hd), dtype)
+        layers["k_norm"] = gain((L, hd), dtype)
     if cfg.selects_keys:
         J, di = cfg.index_heads, cfg.index_head_dim
         layers["idx_wq"] = _dense_init(next(keys), (L, d, J * di), s_in)
@@ -590,6 +601,9 @@ def init_params(key: jax.Array, cfg: ModelConfig, dtype=jnp.bfloat16) -> Params:
             layers["shared_up"] = _dense_init(next(keys), (L, d, Fs), s_in)
             layers["shared_down"] = _dense_init(next(keys), (L, Fs, d),
                                                 Fs ** -0.5)
+            if cfg.shared_expert_gate:
+                layers["shared_expert_gate"] = _dense_init(
+                    next(keys), (L, d, 1), s_in)
     else:
         layers["w_gate"] = _dense_init(next(keys), (L, d, F), s_in)
         layers["w_up"] = _dense_init(next(keys), (L, d, F), s_in)
@@ -811,6 +825,18 @@ def _dense_mlp(cfg: ModelConfig, lp: Params, x: jnp.ndarray,
     return qmatmul(up, lp[prefix + "down"])
 
 
+def _shared_expert(cfg: ModelConfig, lp: Params, x: jnp.ndarray):
+    """The expert every token takes, whole on every chip: added as it is,
+    or (``shared_expert_gate``) under sigmoid(x w_s), one scalar a token."""
+    y = _dense_mlp(cfg, lp, x, "shared_")
+    if not cfg.shared_expert_gate:
+        return y
+    with jax.named_scope("shared_gate"):
+        gate = jax.nn.sigmoid(
+            (x @ lp["shared_expert_gate"]).astype(jnp.float32))
+        return (y.astype(jnp.float32) * gate).astype(y.dtype)
+
+
 #: The expert leaves of a layer (stacked [L, E, in, out] in the param tree).
 EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
 
@@ -880,7 +906,7 @@ def _moe_mlp(cfg: ModelConfig, lp: Params, x: jnp.ndarray,
         y, n_read, picks = grouped_moe_counted(
             cfg, lp, x, token_mask, cfg.first_expert, layer=layer)
         counted = {"experts_read": n_read}
-        if cfg.n_group > 1:     # models/families.py's ``expert_share`` kind
+        if cfg.counts_picks:    # models/families.py's ``expert_share`` kind
             counted["expert_picks"] = picks
         return y, counted
     return dense_moe(cfg, lp, x, mesh), {}
@@ -1270,8 +1296,7 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
         y, got = _moe_mlp(cfg, lp, x, mesh, mlp_mask, moe_impl, layer)
         counts.update(got)
         if cfg.shared_mlp_hidden:
-            # the expert every token takes, whole on every chip
-            y = y + _dense_mlp(cfg, lp, x, "shared_")
+            y = y + _shared_expert(cfg, lp, x)
         return y
 
     def after_attention(h):
@@ -1310,12 +1335,19 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
         return (after_attention(_shard_residual(mesh, h + _scaled(cfg, out))),
                 layer_k, layer_v, layer_ik, counts)
     with jax.named_scope("qkv_proj"):
-        q = qmatmul_heads(x, lp["wq"], H, hd)
+        gate = None
+        if cfg.gate_elementwise:
+            # a head's columns of W_q are [q | gate]: the gate's logits, as
+            # wide as the head, ride beside q to the attention's output
+            qg = qmatmul_heads(x, lp["wq"], H, 2 * hd)
+            q, gate = qg[..., :hd], qg[..., hd:]
+        else:
+            q = qmatmul_heads(x, lp["wq"], H, hd)
         k = qmatmul_heads(x, lp["wk"], KV, hd)
         v = qmatmul_heads(x, lp["wv"], KV, hd)
         if cfg.qk_norm:
-            q = rms_norm(q, lp["q_norm"], cfg.rms_eps)
-            k = rms_norm(k, lp["k_norm"], cfg.rms_eps)
+            q = rms_norm(q, lp["q_norm"], cfg.rms_eps, cfg.rms_offset)
+            k = rms_norm(k, lp["k_norm"], cfg.rms_eps, cfg.rms_offset)
         if cfg.qk_norm_whole:
             # one norm over a token's whole projection, every head's lanes
             whole = lambda a, w: rms_norm(
@@ -1328,8 +1360,7 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
             qi = qmatmul_heads(x, lp["idx_wq"], J, di)
             ki = qmatmul(x, lp["idx_wk"]).reshape(Bh, Sh, 1, di)
             wi = x @ lp["idx_ww"]
-        gate = None
-        if cfg.attn_gate:
+        if cfg.gate_per_head:
             # one scalar a head, from the layer's normed input
             gate = jax.nn.sigmoid((x @ lp["wg"]).astype(jnp.float32))
     with jax.named_scope("rope"):
@@ -1350,10 +1381,15 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
                 gate = win.unpack(gate)
 
     def gated(attn):
-        """Each head's output times its gate (float32, rounded once)."""
+        """Each head's output times its gate (float32, rounded once): a
+        scalar a head, or (elementwise) the sigmoid of a logit a lane."""
         if gate is None:
             return attn
         attn = attn[..., :H, :]
+        if cfg.gate_elementwise:
+            with jax.named_scope("gate"):
+                return (attn.astype(jnp.float32) * jax.nn.sigmoid(
+                    gate.astype(jnp.float32))).astype(attn.dtype)
         return (attn.astype(jnp.float32) * gate[..., None]).astype(attn.dtype)
 
     # A cache row may hold more KV heads than the model has (``ModelConfig.
@@ -1517,7 +1553,8 @@ def _layer(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
 
     if gate is not None:
         raise NotImplementedError(
-            f"{cfg.name}: the per-head gate is served through the pool alone")
+            f"{cfg.name}: the attention's gate is served through the pool "
+            "alone")
     # Write this chunk's K/V into the cache at its absolute positions.
     # (scatter; positions are per-slot absolute indices). Dead rows
     # (write_mask False) scatter at an out-of-bounds position, which jax
@@ -1609,7 +1646,8 @@ ATTENTION_LEAVES = {
 LATENT_LEAVES = ("attn_norm", "wq", "w_dq", "dq_norm", "w_uq", "w_dkv",
                  "dkv_norm", "w_ukv", "wo", "wg")
 EXPERT_LAYER_LEAVES = ("router", "router_bias", "w_gate", "w_up", "w_down",
-                       "shared_gate", "shared_up", "shared_down")
+                       "shared_gate", "shared_up", "shared_down",
+                       "shared_expert_gate")
 
 
 def _at(leaf, j: int):
@@ -1633,7 +1671,7 @@ def _expert_mixer(cfg: ModelConfig, layers: Params, j: int, h, mesh,
         y, got = _moe_mlp(cfg, lp, x, mesh, token_mask, moe_impl,
                           jnp.asarray(j, jnp.int32) if grouped else None)
         if cfg.shared_mlp_hidden:
-            y = y + _dense_mlp(cfg, lp, x, "shared_")
+            y = y + _shared_expert(cfg, lp, x)
     return h + _scaled(cfg, y), got
 
 
@@ -1729,7 +1767,9 @@ def _linear_mixer(cfg: ModelConfig, lp: Params, j, h, lin, lconv,
 
     B, S = valid.shape
     H, dk, dv = cfg.lin_value_heads, cfg.lin_key_dim, cfg.lin_value_dim
-    C = cfg.lin_conv_dim
+    # q and k have the KEY heads' count: value head h reads key head
+    # h // (H // Hk); ops/gated_delta.py repeats them, never the state
+    Hk, C = cfg.lin_key_heads, cfg.lin_conv_dim
     n_valid = jnp.sum(valid, axis=1, dtype=jnp.int32)
     with jax.named_scope("lin"):
         x = h
@@ -1754,10 +1794,10 @@ def _linear_mixer(cfg: ModelConfig, lp: Params, j, h, lin, lconv,
             qkv, tail = causal_conv(qkv, plane(lconv), lp["lin_conv_w"],
                                     None, n_valid)
         with jax.named_scope("scan"):
-            q = gated_delta.l2_normalize(qkv[..., :H * dk].reshape(B, S, H, dk),
-                             dk ** -0.5)
+            q = gated_delta.l2_normalize(
+                qkv[..., :Hk * dk].reshape(B, S, Hk, dk), dk ** -0.5)
             k = gated_delta.l2_normalize(
-                qkv[..., H * dk:2 * H * dk].reshape(B, S, H, dk))
+                qkv[..., Hk * dk:2 * Hk * dk].reshape(B, S, Hk, dk))
             if cfg.lin_channel_decay:
                 # a vector a head, in (floor, 0) for every key channel
                 g = cfg.lin_decay_floor * jax.nn.sigmoid(
@@ -1772,7 +1812,7 @@ def _linear_mixer(cfg: ModelConfig, lp: Params, j, h, lin, lconv,
             g = jnp.where(valid.reshape(valid.shape + (1,) * (g.ndim - 2)),
                           g, 0.0)
             beta = jnp.where(valid[..., None], beta, 0.0)
-            v = qkv[..., 2 * H * dk:].reshape(B, S, H, dv)
+            v = qkv[..., 2 * Hk * dk:].reshape(B, S, H, dv)
             if S == 1:
                 # a decode step: the kernel takes the whole leaf, in place
                 with jax.named_scope("step"):

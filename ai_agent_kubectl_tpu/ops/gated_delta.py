@@ -70,6 +70,16 @@ times the arithmetic and a second and third trip over the state.
 
 The window's scan is plain ``jax.numpy``, float32 at the highest matmul
 precision, as ops/ssd_scan.py: a kernel for it is ROADMAP's.
+
+FEWER KEY HEADS THAN VALUE HEADS (ISSUE 55: 16 for 32): value head h reads
+key head ``h // r``. ``q`` and ``k`` then come with the key heads' count and
+every function here takes both counts from its arguments' shapes. The decay,
+``beta``, the solve and the state are a value head's; ``K K^T`` and ``Q K^T``
+of a chunk depend on the key head alone, so ``gated_delta_scan`` makes them
+once a key head and repeats the [C, C] products over its r value heads (half
+the float32 products of a window at r = 2); the step repeats q and k
+themselves (a few KB a row). Never the state, never a weight. At r = 1
+nothing is repeated and every program is the one it was.
 """
 
 from __future__ import annotations
@@ -100,6 +110,14 @@ def l2_normalize(x, scale: float = 1.0):
                 * scale)
 
 
+def _over_value_heads(a, H: int):
+    """``a`` [B, S or chunks, key heads, ...] with its key heads repeated to
+    ``H`` value heads, head h taking key head h // r; ``a`` itself where the
+    counts are equal."""
+    r = H // a.shape[2]
+    return a if r == 1 else jnp.repeat(a, r, axis=2)
+
+
 def _own(H: int, dv: int):
     """[H, H x dv] float32: 1 where lane l belongs to head h."""
     return (jnp.arange(H * dv)[None, :] // dv
@@ -107,11 +125,11 @@ def _own(H: int, dv: int):
 
 
 def gated_delta_step(q, k, v, g, beta, S0):
-    """One token a row. q, k [B,1,H,dk] (normalised); v [B,1,H,dv]; g, beta
-    [B,1,H] (both 0 = the row does not move); S0 [B,dk,H*dv].
-    Returns (o [B,1,H,dv] float32, S [B,dk,H*dv] STATE_DTYPE)."""
-    B, _, H, dk = q.shape
-    dv = v.shape[-1]
+    """One token a row. q, k [B,1,Hk,dk] (normalised; Hk divides H); v
+    [B,1,H,dv]; g, beta [B,1,H] (both 0 = the row does not move); S0
+    [B,dk,H*dv]. Returns (o [B,1,H,dv] float32, S [B,dk,H*dv] STATE_DTYPE)."""
+    B, _, H, dv = v.shape
+    q, k = _over_value_heads(q, H), _over_value_heads(k, H)
     own = _own(H, dv)
     lanes = lambda a: jnp.repeat(a.astype(jnp.float32), dv, axis=-1)
     S = S0.astype(jnp.float32)
@@ -294,8 +312,10 @@ def _step_call(q, k, v, g, beta, state, layer, moves, *, block_heads: int,
     linear layers, and every program of a start, then share ONE trace of the
     kernel, and a program lowers it once (a chunk program's lowering is
     ``setup_s``, compile-cache hit or not)."""
-    B, _, H, dk = q.shape
-    dv = v.shape[-1]
+    B, _, H, dv = v.shape
+    dk = q.shape[-1]
+    # (fewer key heads than value heads: a value head's own column of kq)
+    q, k = _over_value_heads(q, H), _over_value_heads(k, H)
     L = H * dv
     hb = block_heads or _block_heads(H, dk, dv, state.dtype.itemsize)
     nb, W = H // hb, hb * dv
@@ -411,12 +431,12 @@ def _unit_lower_solve(A, rhs):
 
 
 def gated_delta_scan(q, k, v, g, beta, S0, chunk: int = 0):
-    """A window a row, in chunks of ``chunk`` tokens (0: ``CHUNK``). q, k [B,S,H,dk]
-    (normalised); v [B,S,H,dv]; g, beta [B,S,H] (0 on padding); S0
-    [B,dk,H*dv]. Returns (o [B,S,H,dv] float32, the state after the window
-    [B,dk,H*dv] STATE_DTYPE)."""
-    B, S, H, dk = q.shape
-    dv = v.shape[-1]
+    """A window a row, in chunks of ``chunk`` tokens (0: ``CHUNK``). q, k
+    [B,S,Hk,dk] (normalised; Hk divides H); v [B,S,H,dv]; g, beta [B,S,H] (0
+    on padding); S0 [B,dk,H*dv]. Returns (o [B,S,H,dv] float32, the state
+    after the window [B,dk,H*dv] STATE_DTYPE)."""
+    B, S, H, dv = v.shape
+    dk = q.shape[-1]
     if S == 1:
         return gated_delta_step(q, k, v, g, beta, S0)
     C = min(chunk or CHUNK, S)
@@ -433,14 +453,20 @@ def gated_delta_scan(q, k, v, g, beta, S0, chunk: int = 0):
     tril = jnp.tril(jnp.ones((C, C), bool))
     decay = jnp.exp(jnp.where(tril, gamma[..., :, None] - gamma[..., None, :],
                               -jnp.inf))                        # [B,n,H,Ci,Cj]
-    kk = jnp.einsum("bnhik,bnhjk->bnhij", kc, kc, precision=_HI)
+    # (the two products of keys and queries once a KEY head, repeated over
+    # the value heads that read it; then q and k themselves)
+    kk = _over_value_heads(
+        jnp.einsum("bnhik,bnhjk->bnhij", kc, kc, precision=_HI), H)
+    by_key_head = (qc, kc)
+    qc, kc = _over_value_heads(qc, H), _over_value_heads(kc, H)
     A = jnp.where(jnp.tril(jnp.ones((C, C), bool), -1),
                   bc[..., None] * kk * decay, 0.0)
     eg = jnp.exp(gamma)[..., None]
     rhs = jnp.concatenate([bc[..., None] * vc, bc[..., None] * eg * kc], -1)
     X = _unit_lower_solve(A, rhs)
     U0, Wm = X[..., :dv], X[..., dv:]           # T (beta V), T (beta e^g K)
-    qk = jnp.einsum("bnhik,bnhjk->bnhij", qc, kc, precision=_HI) * decay
+    qk = _over_value_heads(jnp.einsum(
+        "bnhik,bnhjk->bnhij", *by_key_head, precision=_HI), H) * decay
     to_end = jnp.exp(gamma[..., -1:] - gamma)[..., None] * kc   # [B,n,H,C,dk]
     end = jnp.exp(gamma[..., -1])[..., None, None]              # [B,n,H,1,1]
     return _run_chunks(U0, Wm, qk, eg, qc, to_end, end, S0, S, H, dv)

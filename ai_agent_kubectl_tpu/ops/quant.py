@@ -495,9 +495,13 @@ def _random_params_int8(key, cfg, dtype, quantize_embed: bool, int4: bool,
             # and selection bias: the values init_params gives them
             out.append(small)
         elif name in ("q_norm", "k_norm"):
-            out.append(_jnp.full(sds.shape, SEEDED_QK_NORM_GAIN, dtype))
+            # (the gain a head is ``rms_offset`` + the leaf)
+            out.append(_jnp.full(
+                sds.shape, SEEDED_QK_NORM_GAIN - cfg.rms_offset, dtype))
         elif name.endswith("norm"):
-            fill = _jnp.zeros if cfg.rms_offset else _jnp.ones
+            # (a gated head norm's gain is plain, whatever the block's norm)
+            fill = (_jnp.zeros if cfg.rms_offset and not name.endswith(
+                "gate_norm") else _jnp.ones)
             out.append(fill(sds.shape, dtype))
         elif name == "embed" and quantize_embed:
             q = jax.random.randint(k, sds.shape, -127, 128, dtype=_jnp.int8)
@@ -523,7 +527,7 @@ def _random_params_int8(key, cfg, dtype, quantize_embed: bool, int4: bool,
                 scale = sds.shape[-2] ** -0.5
             if name == "w_ukv":
                 scale = SEEDED_UKV_GAIN * sds.shape[-2] ** -0.5
-            if name in ("wg", "sw_wg"):
+            if name in ("wg", "sw_wg", "shared_expert_gate"):
                 # a head's gate logit of unit variance: gates spread over
                 # (0.1, 0.9), not all at 1/2
                 scale = sds.shape[-2] ** -0.5

@@ -73,7 +73,9 @@ _LONG_FILES = ("test_sparse_attention.py", "test_tpu_aot.py",
 #: are collected last: they then run while the other workers are finishing
 #: short files, not under a long file's compiles (three whole runs of three
 #: at PR 48 tripped the sentinel's healthy phase under load).
-_LAST_FILES = ("test_sentinel.py",)
+#: (and the spans' partitions, whose 5-10 ms sleeps have a millisecond of room:
+#: three of them overshot under PR 55's added files' compiles)
+_LAST_FILES = ("test_engine_spans.py", "test_sentinel.py")
 
 
 def pytest_configure(config):

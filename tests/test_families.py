@@ -231,7 +231,8 @@ HEALTH = {
         "decode_rows_full", "full_keys_read", "window_rows", "window_pairs_sliding",
         "window_pairs_full", "forward_passes"]),
     "linear_attention": ("linear", [
-        "layers_linear", "layers_full", "state_bytes_per_sequence", "decode_rows_linear",
+        "layers_linear", "layers_full", "key_heads", "value_heads",
+        "state_bytes_per_sequence", "decode_rows_linear",
         "window_rows_linear", "chunks_scanned", "decode_rows_still", "decode_rows_full",
         "full_keys_read", "forward_passes"]),
     # (the recurrent kind's own words: the rows its step kernel passed over;

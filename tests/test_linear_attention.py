@@ -285,6 +285,43 @@ def test_the_step_kernel_scanned_over_a_leafs_planes_equals_the_recurrence():
         np.testing.assert_allclose(np.asarray(out[j]), want_S, rtol=2e-4, atol=2e-5)
 
 
+@pytest.mark.parametrize("H", [4, 6])
+@pytest.mark.parametrize("form", ["scan", "kernel"])
+def test_two_key_heads_serve_four_and_six_value_heads(form, H):
+    """ISSUE 55: q and k come with the KEY heads' count (2) and value head h
+    reads key head h // r. The chunked scan (which makes ``K K^T`` and ``Q K^T``
+    once a key head) over chunk edges from a state with padding, and the step
+    kernel (interpreted; 128-wide values: one lane tile a head, as published)
+    on one plane of a leaf, against the token-by-token recurrence given the
+    keys and queries REPEATED; equal counts go the way they went (the same
+    function, nothing repeated)."""
+    r = H // 2
+    if form == "scan":
+        a = scan_inputs(H, 3, 150, [150, 37, 0], H=H)
+        a["q"], a["k"] = a["q"][:, :, ::r], a["k"][:, :, ::r]
+        for n in ("q", "k"):        # the recurrence saw head h // r's
+            assert a[n].shape[2] == 2
+        want_o, want_S = recurrence(**dict(
+            a, q=np.repeat(a["q"], r, axis=2), k=np.repeat(a["k"], r, axis=2)))
+        o, S1 = jax.jit(GD.gated_delta_scan, static_argnums=6)(*a.values(), 64)
+        for b, n in enumerate([150, 37, 0]):
+            np.testing.assert_allclose(np.asarray(o)[b, :n], want_o[b, :n],
+                                       rtol=2e-4, atol=2e-5)
+        np.testing.assert_allclose(np.asarray(S1), want_S, rtol=2e-4, atol=2e-5)
+        return
+    a, leaf = step_inputs(H, H, 16, 128)
+    a["q"], a["k"] = a["q"][:, :, ::r], a["k"][:, :, ::r]
+    full = dict(a, q=np.repeat(a["q"], r, axis=2), k=np.repeat(a["k"], r, axis=2))
+    want_o, want_S = recurrence(**full, S0=leaf[1])
+    o, out = jax.jit(GD.gated_delta_step_kernel, static_argnums=8)(
+        *a.values(), leaf, jnp.asarray(1, jnp.int32), None, 0)
+    o2, S2 = GD.gated_delta_step(*a.values(), leaf[1])
+    for got_o, got_S in ((o, out[1]), (o2, S2)):
+        np.testing.assert_allclose(np.asarray(got_o)[0], want_o[0], rtol=2e-4, atol=2e-5)
+        np.testing.assert_allclose(np.asarray(got_S), want_S, rtol=2e-4, atol=2e-5)
+    np.testing.assert_array_equal(np.asarray(out[::2]), np.asarray(leaf[::2]))
+
+
 def test_a_token_erases_along_its_key_before_it_writes():
     """What the delta rule has that a decayed sum has not: the same key written
     twice at beta 1 holds the SECOND value, not the sum of both."""
@@ -549,8 +586,11 @@ def test_the_configuration_says_what_it_keeps():
     n = sum(int(np.prod(a.shape)) for a in jax.tree_util.tree_leaves(
         jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), CFG))))
     assert CFG.param_count() == n
-    with pytest.raises(ValueError, match="lin_value_heads 8 over lin_key_heads 4"):
-        dataclasses.replace(CFG, lin_value_heads=8).layer_kinds
+    # a key head serves a WHOLE number of value heads (ISSUE 55); the rest is
+    # still refused by name
+    assert dataclasses.replace(CFG, lin_value_heads=8).layer_kinds.count("L") == 6
+    with pytest.raises(ValueError, match="lin_value_heads 6 over lin_key_heads 4"):
+        dataclasses.replace(CFG, lin_value_heads=6).layer_kinds
     with pytest.raises(NotImplementedError, match="post_norm"):
         uniform = dataclasses.replace(get_config("toy-8m"), post_norm=True)
         forward(init_params(jax.random.PRNGKey(0), uniform), uniform,

@@ -1429,3 +1429,110 @@ def test_kda_latent_chunk_program_and_copy_on_write_compile_on_v5e(
     mem = cow.lower(cache, scalar, scalar, scalar).compile().memory_analysis()
     assert mem.alias_size_in_bytes >= math.prod(cache.lat.shape) * 2
     assert mem.temp_size_in_bytes < 2 ** 24
+
+
+# --- the gated delta rule with fewer key heads than value heads beside gated
+# attention on 256-wide heads and a gated shared expert (ISSUE 55)
+
+QWEN3_NEXT = dict(vocab_size=151936, dim=2048, n_heads=16, n_kv_heads=2,
+                  head_dim=256, mlp_hidden=512, dense_mlp_hidden=5120,
+                  shared_mlp_hidden=512, shared_expert_gate=True,
+                  n_experts=128, router_width=512, experts_per_token=10,
+                  eos_ids=(2,), mixers_per_layer=2,
+                  layer_pattern="LELELE*E" * 3, lin_key_heads=16,
+                  lin_value_heads=32, lin_key_dim=128, lin_value_dim=128,
+                  lin_conv=4, rms_offset=1.0, qk_norm=True, rope_theta=1e7,
+                  rope_partial=0.25, attn_gate="elementwise")
+
+
+@pytest.mark.parametrize("layers,B,W,packed", [(12, 16, 1, None),
+                                               (4, 16, 512, 528)],
+                         ids=["decode-full-depth", "window-512"])
+def test_gdn_moe_forward_compiles_at_published_widths_on_v5e(
+        one_chip, layers, B, W, packed, monkeypatch):
+    """qwen3-next-80b-a3b-instruct-l12 (nine delta-rule layers of 16 key heads
+    for 32 value heads, three gated attention layers, twelve expert layers of
+    128 held of 512; every width as published, the cell's 257-page table over
+    its 6,144-block pool; the decode program at its full depth, a window's at
+    one whole period): Mosaic accepts the ragged kernel at 256-wide heads, 8
+    query heads a KV head over 2 KV heads a 512-lane row (``lane_heads`` 1),
+    and the step kernel at ONE lane tile a head ([128, 2,048] blocks of 16 of
+    the 32 value heads, a value head's own column of keys and queries: 32
+    columns a block). ``W_q`` is 8,192 columns for 4,096 of queries and there
+    is no gate leaf. A decode pass names the state leaf [9, 16, 128, 4096] in
+    its nine step kernels alone and moves no plane of it; a window writes a
+    plane in place."""
+    from ai_agent_kubectl_tpu.ops.ragged_attention import lane_heads
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = ModelConfig(name="aot-qwen3-next", n_layers=layers, **QWEN3_NEXT)
+    page, n_blocks, pages = 64, 6144, 257
+    nL, nA, nE = cfg.n_of("L"), cfg.n_of("*"), cfg.n_of("E")
+    assert (nL, nA, nE) == ((9, 3, 12) if layers == 12 else (3, 1, 4))
+    assert lane_heads(cfg.head_dim, cfg.kv_heads_paged) == 1
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda x: arg(x.shape, x.dtype),
+        jax.eval_shape(lambda k: random_params_int8(
+            k, cfg, dtype=jnp.bfloat16, quantize_embed=True),
+            jax.random.PRNGKey(0)))
+    assert params["layers"]["wq"].q.shape == (nA, 2048, 16 * 2 * 256)
+    assert params["layers"]["lin_in"].q.shape == (nL, 2048, 8192 + 4096)
+    assert params["layers"]["shared_expert_gate"].shape == (nE, 2048, 1)
+    assert "wg" not in params["layers"]
+    cache = _engine_cache(arg, cfg, n_blocks, page, B)
+    lin = cache.lin.shape
+    assert cache.k.shape == (nA, n_blocks, page, 2, 256)
+    assert lin == (nL, B, 128, 4096) and cache.lconv.shape == (nL, B, 3, 8192)
+    assert cache.expert_picks.shape == (2,)
+
+    def step(params, tok, pos, cache, wmask, tables, q_lens):
+        return forward(params, cfg, tok, pos, cache, kv_limit=pages * page,
+                       attn_impl="ragged", token_mask=wmask,
+                       write_mask=wmask, block_tables=tables, q_lens=q_lens,
+                       logits_at=jnp.maximum(q_lens, 1) - 1,
+                       packed_rows=packed)
+
+    traced = jax.jit(step, donate_argnums=(3,)).trace(
+        params, arg((B, W), jnp.int32), arg((B, W), jnp.int32), cache,
+        arg((B, W), jnp.bool_), arg((B, pages), jnp.int32),
+        arg((B,), jnp.int32))
+    compiled = traced.lower().compile()
+    hlo = compiled.as_text()
+    steps = [eqn for eqn in _pallas_calls(traced.jaxpr.jaxpr)
+             if eqn.params["name"] == "gated_delta_step"]
+    assert len(steps) == (nL if W == 1 else 0)
+    # ... beside a ragged kernel an attention layer and a grouped one an
+    # expert layer
+    assert hlo.count('custom_call_target="tpu_custom_call"') == (
+        len(steps) + nA + nE)
+    carried = {"scatter", "fusion", "while", "parameter", "tuple",
+               "get-tuple-element", "bitcast"}
+    state_ops = {op for op, _ in _results_of_size(hlo, {math.prod(lin)})}
+    if W == 1:
+        assert state_ops <= carried | {"custom-call"}, state_ops
+        # (float32: W_out's int8 [4096, 2048] has a plane's element count)
+        plane = math.prod(lin[1:])
+        assert not [line for result, op, line in _instructions(hlo)
+                    if op not in carried and plane in _sizes(
+                        " ".join(re.findall(r"f32\[[\d,]+\]", result)))]
+        for eqn in steps:
+            gm = eqn.params["grid_mapping"]
+            assert gm.grid == (B, 2)            # 16 of the 32 heads a block
+            shapes = [tuple(b if isinstance(b, int) else b.block_size
+                            for b in bm.block_shape)
+                      for bm in gm.block_mappings]
+            # a value head's key and query a column each; v, alpha and beta;
+            # the state
+            assert shapes[:3] == [(1, 1, 128, 32), (1, 3, 2048),
+                                  (1, 1, 128, 2048)], shapes
+    else:
+        assert "dynamic-update-slice" in state_ops, state_ops
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= (
+        2 * math.prod(cache.k.shape) * 2 + math.prod(lin) * 4)
+    assert mem.temp_size_in_bytes < (1.5 * 2 ** 30 if B * W > 512
+                                     else 2 ** 28), mem.temp_size_in_bytes

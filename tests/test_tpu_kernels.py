@@ -379,6 +379,58 @@ def test_compiled_window_scan_matches_the_float64_recurrence():
         np.testing.assert_allclose(np.asarray(S1), want_S, rtol=2e-4, atol=atol)
 
 
+def test_compiled_step_and_scan_take_sixteen_key_heads_for_thirty_two_value_heads():
+    """ISSUE 55's geometry COMPILED (qwen3-next-80b-a3b-instruct-l12: 16 key heads
+    for 32 value heads of 128 x 128, one lane tile a head, [128, 2,048] blocks of
+    16 heads): the step kernel on plane 1 of a leaf with still rows between moving
+    ones against the ``jnp`` step given q and k REPEATED, and the chunked scan over
+    rows of 300, 64 and no tokens (its ``K K^T`` and ``Q K^T`` made once a key
+    head) against tests/test_linear_attention.py's float64 recurrence given the
+    same."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from test_linear_attention import recurrence, scan_inputs
+
+    from ai_agent_kubectl_tpu.ops import gated_delta as GD
+
+    B, Hk, H, dk, dv = 16, 16, 32, 128, 128
+    assert GD._block_heads(H, dk, dv, 4) == 16
+    r = np.random.default_rng(0)
+    q = GD.l2_normalize(r.normal(size=(B, 1, Hk, dk)), dk ** -0.5)
+    k = GD.l2_normalize(r.normal(size=(B, 1, Hk, dk)))
+    v = jnp.asarray(r.normal(size=(B, 1, H, dv)), jnp.float32)
+    leaf0 = r.normal(size=(3, B, dk, H * dv)).astype(np.float32)
+    live = np.asarray([c == "1" for c in "0110110011111101"])
+    g = jnp.asarray(np.where(live[:, None, None], -r.uniform(1e-3, 0.7, (B, 1, H)), 0.0),
+                    jnp.float32)
+    beta = jnp.asarray(np.where(live[:, None, None], r.uniform(0.1, 1.0, (B, 1, H)), 0.0),
+                       jnp.float32)
+    want_o, want_S = GD.gated_delta_step(jnp.repeat(q, 2, axis=2), jnp.repeat(k, 2, axis=2),
+                                         v, g, beta, jnp.asarray(leaf0[1]))
+    step = jax.jit(GD.gated_delta_step_kernel, static_argnums=8, donate_argnums=5)
+    o, out = step(q, k, v, g, beta, jnp.asarray(leaf0), jnp.asarray(1, jnp.int32), None, 0)
+    o, out = np.asarray(o), np.asarray(out)
+    np.testing.assert_allclose(o[live], np.asarray(want_o)[live], rtol=2e-5, atol=2e-6)
+    np.testing.assert_allclose(out[1][live], np.asarray(want_S)[live], rtol=2e-5, atol=2e-6)
+    assert not o[~live].any()
+    np.testing.assert_array_equal(out[1][~live], leaf0[1][~live])
+    np.testing.assert_array_equal(out[::2], leaf0[::2])
+
+    a = scan_inputs(1, 3, 300, [300, 64, 0], H=8, dk=dk, dv=dv)
+    a["q"], a["k"] = a["q"][:, :, ::2], a["k"][:, :, ::2]
+    want_o, want_S = recurrence(**dict(a, q=np.repeat(a["q"], 2, axis=2),
+                                       k=np.repeat(a["k"], 2, axis=2)))
+    o, S1 = jax.jit(GD.gated_delta_scan)(*a.values())
+    worst = 0.0
+    for b, n in enumerate([300, 64, 0]):
+        np.testing.assert_allclose(np.asarray(o)[b, :n], want_o[b, :n], rtol=2e-4, atol=1.2e-4)
+        worst = max(worst, float(np.abs(np.asarray(o)[b, :n] - want_o[b, :n]).max(initial=0)))
+    np.testing.assert_allclose(np.asarray(S1), want_S, rtol=2e-4, atol=1.2e-4)
+    np.testing.assert_array_equal(np.asarray(S1)[2], np.asarray(a["S0"])[2])
+    _record("step_and_scan_16_key_heads_for_32", scan_worst_abs=worst)
+
+
 def _record(name, **readings):
     """What the chip read, beside the verdict: chiprun_out/kernel_parity.jsonl."""
     import json

@@ -3,12 +3,13 @@
 off the programs compiled for a DESCRIBED v5e (no chip; nothing runs: sizes and
 refusals, never a time).
 
-For a configuration file of ``benchmark/configs`` with state-space layers, at
+For a configuration file of ``benchmark/configs`` with state-space layers (the
+``ssm`` leaf) or, without them, linear-attention layers (the ``lin`` leaf), at
 its own depth, batch, pool and widest bucket (``server_env``), three programs
 are compiled as ``tests/test_tpu_aot.py`` compiles them: the decode ``forward``
 (one token a slot), the engine's WINDOWED chunk program (its widest bucket,
 grammar on, the valid rows packed) and ``jit_cow``. Of each: the temporaries,
-and every instruction whose result is the size of the ``ssm`` leaf or of one of
+and every instruction whose result is the size of the state leaf or of one of
 its planes (float32, as the state is) and that is not the carried buffer itself,
 by op, a fusion by its root's (``state_sized``). A leaf-sized
 ``copy`` a layer is what a window cost Nemotron's six layers before PR 49; at 36
@@ -143,9 +144,10 @@ def main() -> int:
             lambda: KVCache.pool_zeros(cfg, n_blocks=n_blocks, page=page,
                                        slots=B, ring=cfg.sliding_ring(W, page),
                                        lane_heads=n)))
-    leaf = cache.ssm.shape
+    state = "ssm" if cache.ssm is not None else "lin"
+    leaf = getattr(cache, state).shape
     print(json.dumps({"config": cfg_file["name"], "n_layers": cfg.n_layers,
-                      "ssm_leaf": leaf, "k_leaf": cache.k.shape,
+                      f"{state}_leaf": leaf, "k_leaf": cache.k.shape,
                       "lane_heads": n, "batch": B, "window": W}), flush=True)
     params = jax.tree_util.tree_map(
         lambda x: arg(x.shape, x.dtype),

@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Did a change move another configuration's programs? A hash of the OPTIMIZED
 HLO text (the CPU compiler's, here, no chip) of each toy preset's pool
-``forward``, one decode pass and one 32-wide packed window, gathered and through
+``forward``, one decode pass and one 32-wide packed window (``--widths``), gathered and through
 the interpreted ragged kernel, less what names a source line (op metadata, the
 stack-frame tables, the checkout's path). Run it on two checkouts and compare:
 
@@ -10,7 +10,7 @@ stack-frame tables, the checkout's path). Run it on two checkouts and compare:
     python tools/hlo_hash.py > b.json && diff a.json b.json
 
 One JSON object: ``{"<model>/<impl>/W<width>": [sha256's first 16, the text's
-length]}``.
+length]}``; ``{"<model>": null}`` for a preset the checkout does not have.
 """
 
 from __future__ import annotations
@@ -23,13 +23,16 @@ import sys
 from pathlib import Path
 
 MODELS = ("toy-8m", "toy-sparse-moe", "toy-hybrid-moe", "toy-mla-moe",
-          "toy-sliding-moe", "toy-linear-hybrid", "toy-kda-mla-moe")
+          "toy-sliding-moe", "toy-linear-hybrid", "toy-kda-mla-moe",
+          "toy-gdn-moe")
 
 
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--package-root", default=str(Path(__file__).resolve().parents[1]))
     ap.add_argument("--models", nargs="+", default=list(MODELS))
+    ap.add_argument("--widths", type=int, nargs="+", default=[1, 32],
+                    help="window widths (1: a decode pass; wider: packed rows)")
     args = ap.parse_args()
     root = str(Path(args.package_root).resolve())
     sys.path.insert(0, root)
@@ -44,7 +47,11 @@ def main() -> int:
     sds = jax.ShapeDtypeStruct
     out = {}
     for name in args.models:
-        cfg = get_config(name)
+        try:
+            cfg = get_config(name)
+        except KeyError:        # a checkout from before the preset
+            out[name] = None
+            continue
         params = jax.eval_shape(lambda k: init_params(k, cfg, jnp.float32),
                                 jax.random.PRNGKey(0))
         cache = jax.eval_shape(lambda: KVCache.pool_zeros(
@@ -52,7 +59,7 @@ def main() -> int:
             ring=cfg.sliding_ring(64, page), dtype=jnp.float32,
             counts_experts=cfg.grouped_experts))
         for impl in ("dense", "ragged"):
-            for W in (1, 32):
+            for W in args.widths:
                 def step(params, tok, pos, cache, wmask, tables, q_lens):
                     return forward(
                         params, cfg, tok, pos, cache, kv_limit=pages * page,
