@@ -168,7 +168,10 @@ def _linear_section(cfg, counts, facts) -> dict:
     not move (dead slots), whose state the step kernel neither read nor
     wrote (all six counted on the device, summed over the layers of the
     kind); ``key_heads`` / ``value_heads``: a key head serves value_heads /
-    key_heads value heads."""
+    key_heads value heads. Where the decay is a scalar a head,
+    ``_linear_window_section`` adds ``window_rows_moved`` /
+    ``window_rows_still`` / ``window_chunks_skipped`` /
+    ``window_rows_stepped``."""
     rows_l, window_l, chunks, rows_f, keys_f, still = counts["dev"]
     return {"layers_linear": cfg.n_of("L"), "layers_full": cfg.n_of("*"),
             "key_heads": cfg.lin_key_heads,
@@ -178,6 +181,21 @@ def _linear_section(cfg, counts, facts) -> dict:
             "chunks_scanned": chunks, "decode_rows_still": still,
             "decode_rows_full": rows_f, "full_keys_read": keys_f,
             "forward_passes": facts["forward_passes"]}
+
+
+def _linear_window_section(cfg, counts, facts) -> dict:
+    """/health.linear_attention of a configuration whose delta rule decays by
+    a scalar a head, beside ``_linear_section``'s keys: the rows of WINDOW
+    passes (a chunk program's prologue; an eager piece counts no words)
+    whose state the window kernel (ops/gated_delta_window.py) updated, those
+    it neither read nor wrote because they brought no token, the chunks it
+    passed over past a moving row's ``q_len``, and of the rows it updated
+    those that rode the window with ONE token (a live decode row) and took
+    the step's arithmetic inside it (counted on the device, summed over the
+    linear layers)."""
+    moved, still, skipped, stepped = counts["dev"]
+    return {"window_rows_moved": moved, "window_rows_still": still,
+            "window_chunks_skipped": skipped, "window_rows_stepped": stepped}
 
 
 def _state_section(cfg, counts, facts) -> Optional[dict]:
@@ -318,6 +336,14 @@ CACHE_KINDS: Tuple[CacheKind, ...] = (
         refuses=_STATE_REFUSES,
         health={"linear_attention": _linear_section,
                 "ssm": _state_section}),
+    # Where the decay is a scalar a head its window passes run a kernel, and
+    # count the rows and chunks that passed over (a leaf of its own: the
+    # per-channel family shares ``linear`` and keeps its six words).
+    CacheKind(
+        name="linear_window",
+        of=lambda cfg: cfg.has_linear and not cfg.lin_channel_decay,
+        count_leaf="lin_window", count_shape=(4,), lane="sel_rows",
+        health={"linear_attention": _linear_window_section}),
     # Its cache is ONE leaf of the block pool with no head axis.
     CacheKind(
         name="latent",

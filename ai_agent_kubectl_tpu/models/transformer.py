@@ -151,6 +151,16 @@ class KVCache:
              kernel neither read nor wrote (dead slots), summed over
              layers and passes since the chunk program last zeroed it
              (/health.linear_attention). Absent elsewhere.
+    lin_window: int32 [4], beside it where the delta rule's decay is a
+             scalar a head (no ``lin_channel_decay``): the rows of WINDOW
+             passes whose state the linear layers' window kernel updated,
+             the rows it neither read nor wrote (they brought no token),
+             the chunks it passed over past a moving row's ``q_len`` and,
+             of the rows it updated, those that rode the window with one
+             token and took the step, summed likewise
+             (/health.linear_attention.window_rows_moved,
+             .window_rows_still, .window_chunks_skipped,
+             .window_rows_stepped). Absent elsewhere.
     expert_picks: int32 [2], where the grouped expert path serves a chip's
              SHARE of the experts (``ModelConfig.router_width``): the picks
              the passes' live rows made among all the experts scored, and
@@ -177,13 +187,15 @@ class KVCache:
     lin: Any = None
     lconv: Any = None
     lin_rows: Any = None
+    lin_window: Any = None
     expert_picks: Any = None
 
     #: the leaves that hold one bounded state a batch row (axis 1)
     STATE = ("ssm", "conv", "sk", "sv", "lin", "lconv")
     #: what the passes count on the device, zeroed by the chunk program
     COUNTS = ("experts_read", "sel_rows", "ssm_rows", "ssm_window",
-              "lat_rows", "span_rows", "lin_rows", "expert_picks")
+              "lat_rows", "span_rows", "lin_rows", "lin_window",
+              "expert_picks")
 
     @classmethod
     def zeros(cls, cfg: ModelConfig, batch: int, max_seq: int,
@@ -1753,16 +1765,21 @@ def _linear_mixer(cfg: ModelConfig, lp: Params, j, h, lin, lconv,
     """A linear-attention layer (the gated delta rule, ops/gated_delta.py)
     of leaves ``lp`` onto the residual, from and to plane ``j`` of the
     state leaves (a traced scalar inside the scan over periods); returns
-    (h, lin, lconv, int32 [6]: the first three words of
-    ``KVCache.lin_rows`` and its last). ``valid`` as for ``_ssm_mixer``: a
-    padded token has ``g = 0`` and ``beta = 0`` and stays out of the
-    convolution's tail; a decode pass (S == 1) takes the step kernel on the
-    whole leaf, which passes over such a row's state altogether.
+    (h, lin, lconv, what the pass counted: the first three words of
+    ``KVCache.lin_rows`` and its last, int32 [6], and where the decay is a
+    scalar a head ``KVCache.lin_window``'s four). ``valid`` as for
+    ``_ssm_mixer``: a padded token has ``g = 0`` and ``beta = 0`` and stays
+    out of the convolution's tail; a decode pass (S == 1) takes the step
+    kernel and a window of scalar decays the window kernel
+    (ops/gated_delta_window.py), both on the whole leaf in place, and both
+    pass over the state of a row that brought no token altogether; a window
+    of per-channel decays slices its plane out and sets it back.
     With ``win`` the projections, the gated norm and the output projection
     run on the window's packed rows, the convolution and the scan on the
     unpacked [B, S]. Scopes ``lin/*`` hold, like ``ssm/*``, no keyword of
     the benchmark's trace categories: the mixer is its own device time."""
     from ..ops import gated_delta
+    from ..ops.gated_delta_window import gated_delta_window, window_counts
     from ..ops.ssd_scan import causal_conv
 
     B, S = valid.shape
@@ -1803,11 +1820,9 @@ def _linear_mixer(cfg: ModelConfig, lp: Params, j, h, lin, lconv,
                 g = cfg.lin_decay_floor * jax.nn.sigmoid(
                     jnp.exp(lp["lin_A_log"].astype(jnp.float32))[:, None]
                     * (a + lp["lin_f_bias"]))
-                scan = gated_delta.channel_decay_scan
             else:
                 g = -jnp.exp(lp["lin_A_log"].astype(jnp.float32)) \
                     * jax.nn.softplus(a + lp["lin_dt_bias"])
-                scan = gated_delta.gated_delta_scan
             beta = (2.0 if cfg.lin_neg_eigval else 1.0) * jax.nn.sigmoid(b)
             g = jnp.where(valid.reshape(valid.shape + (1,) * (g.ndim - 2)),
                           g, 0.0)
@@ -1818,9 +1833,14 @@ def _linear_mixer(cfg: ModelConfig, lp: Params, j, h, lin, lconv,
                 with jax.named_scope("step"):
                     o, lin = gated_delta.gated_delta_step_kernel(
                         q, k, v, g, beta, lin, j, valid[:, 0])
-            else:
-                o, state = scan(q, k, v, g, beta, plane(lin))
+            elif cfg.lin_channel_decay:
+                o, state = gated_delta.channel_decay_scan(q, k, v, g, beta,
+                                                          plane(lin))
                 lin = jax.lax.dynamic_update_index_in_dim(lin, state, j, 0)
+            else:
+                with jax.named_scope("window"):
+                    o, lin = gated_delta_window(q, k, v, g, beta, lin, j,
+                                                n_valid)
             lconv = jax.lax.dynamic_update_index_in_dim(lconv, tail, j, 0)
         with jax.named_scope("gate_norm"):
             if win is not None:
@@ -1845,8 +1865,13 @@ def _linear_mixer(cfg: ModelConfig, lp: Params, j, h, lin, lconv,
     # the rows of a decode pass that the step kernel passed over
     still = jnp.sum(jnp.logical_not(valid[:, 0]) if S == 1 else 0,
                     dtype=jnp.int32)
-    return h + _scaled(cfg, out), lin, lconv, jnp.concatenate(
-        [rows, jnp.zeros((2,), jnp.int32), still[None]])
+    counted = {"lin_rows": jnp.concatenate(
+        [rows, jnp.zeros((2,), jnp.int32), still[None]])}
+    if not cfg.lin_channel_decay:
+        # what the window kernel updated and passed over
+        counted["lin_window"] = (jnp.zeros((4,), jnp.int32) if S == 1
+                                 else window_counts(n_valid, S))
+    return h + _scaled(cfg, out), lin, lconv, counted
 
 
 def _patterned_layers(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
@@ -1925,7 +1950,7 @@ def _patterned_layers(cfg: ModelConfig, attn_impl: str, mesh, moe_impl: str,
                     cfg, {name: leaf(name, j) for name in layers
                           if name.startswith("lin_")},
                     j, h, lin, lconv, valid, q_lens, win)
-                count({"lin_rows": n})
+                count(n)
             elif kind == "D":
                 lp = {name: leaf(name, j) for name in layers
                       if name.startswith("dense_")}

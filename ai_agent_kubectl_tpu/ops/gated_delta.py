@@ -68,8 +68,8 @@ into heads either, so a head's ``S^T k`` is one product of ALL heads' keys
 with the row, of which the head's own block of lanes is kept (``_own``), 30
 times the arithmetic and a second and third trip over the state.
 
-The window's scan is plain ``jax.numpy``, float32 at the highest matmul
-precision, as ops/ssd_scan.py: a kernel for it is ROADMAP's.
+The window's scan here is plain ``jax.numpy``, float32 at the highest matmul
+precision; ops/gated_delta_window.py has its kernel, which tests hold to it.
 
 FEWER KEY HEADS THAN VALUE HEADS (ISSUE 55: 16 for 32): value head h reads
 key head ``h // r``. ``q`` and ``k`` then come with the key heads' count and

@@ -118,7 +118,7 @@ def test_a_kind_tests_its_obstacles_in_its_own_order():
     assert order == {"experts": (), "selecting": ("dense", "kv_quant", "mesh"),
                      "recurrent": ("dense", "mesh", "spec"), "recurrent_window": (),
                      "sliding": ("dense", "mesh", "spec", "kv_quant"),
-                     "linear": ("dense", "mesh", "spec"),
+                     "linear": ("dense", "mesh", "spec"), "linear_window": (),
                      "latent": ("dense", "kv_quant", "mesh", "spec"),
                      "expert_share": ()}
     assert all(set(k.refuses) <= set(OBSTACLES) for k in CACHE_KINDS)
@@ -161,7 +161,8 @@ POOLS = {
     ("linear", ""): {".k": ((2, 12, 16, 4, 32), _BF16), ".v": ((2, 12, 16, 4, 32), _BF16),
                      ".lengths": ((12,), _I32),
                      ".lin": ((6, 3, 24, 160), "float32"),
-                     ".lconv": ((6, 3, 3, 352), _BF16), ".lin_rows": ((6,), _I32)},
+                     ".lconv": ((6, 3, 3, 352), _BF16), ".lin_rows": ((6,), _I32),
+                     ".lin_window": ((4,), _I32)},
     # no K, no V: a plane a LATENT layer (2 of the 6) beside a plane a linear one
     ("kda", ""): {".lengths": ((12,), _I32), ".experts_read": ((), _I32),
                   ".lat": ((2, 12, 8, 96), _BF16), ".lat_rows": ((2,), _I32),
@@ -203,7 +204,7 @@ def test_the_pool_cache_is_built_beside_the_model(kind, kv_quant):
     # latent and the sliding toy hold 4 of 16 under a plain top-k router and
     # keep their lanes; two kinds on the lane lie end to end)
     words = {"gqa": 0, "selecting": 2, "recurrent": 1 + 3, "sliding": 4,
-             "latent": 2, "linear": 6, "kda": 6 + 2 + 2}
+             "latent": 2, "linear": 6 + 4, "kda": 6 + 2 + 2}
     assert attention_words(cfg) == words[kind]
     assert long_prompts(cfg) == (kind != "gqa")
 
@@ -234,7 +235,10 @@ HEALTH = {
         "layers_linear", "layers_full", "key_heads", "value_heads",
         "state_bytes_per_sequence", "decode_rows_linear",
         "window_rows_linear", "chunks_scanned", "decode_rows_still", "decode_rows_full",
-        "full_keys_read", "forward_passes"]),
+        "full_keys_read", "forward_passes",
+        # (a scalar decay a head: what its window kernel updated and passed over)
+        "window_rows_moved", "window_rows_still", "window_chunks_skipped",
+        "window_rows_stepped"]),
     # (the recurrent kind's own words: the rows its step kernel passed over;
     # the rows its window kernel updated and passed over, the chunks it skipped)
     "ssm": ("recurrent", [*_SSM, "decode_rows_still", "window_rows_moved",
@@ -303,6 +307,10 @@ def test_the_sections_count_what_the_scheduler_counted():
     assert [lin["linear_attention"][k] for k in (
         "decode_rows_linear", "window_rows_linear", "chunks_scanned", "decode_rows_full",
         "full_keys_read", "decode_rows_still")] == [10, 11, 12, 13, 14, 15]
+    # and the window kernel's four behind them (a leaf of its own)
+    assert [lin["linear_attention"][k] for k in (
+        "window_rows_moved", "window_rows_still", "window_chunks_skipped",
+        "window_rows_stepped")] == [16, 17, 18, 19]
     assert lin["ssm"]["layer_passes"]["linear"] == 5 * 6
     assert lin["ssm"]["state_bytes"] == get_config("toy-linear-hybrid").state_bytes()
     # a chip's share of the experts under a plain top-k router counts no
@@ -313,6 +321,8 @@ def test_the_sections_count_what_the_scheduler_counted():
     kda = _sections("kda")
     assert [kda["linear_attention"][k] for k in (
         "decode_rows_linear", "full_keys_read", "decode_rows_still")] == [10, 14, 15]
+    # (a decay a key channel runs no window kernel and keeps its six words)
+    assert "window_rows_moved" not in kda["linear_attention"]
     assert [kda["latent_attention"][k] for k in ("layers", "decode_rows", "latent_rows_read")] \
         == [2, 16 // 2, 17]
     assert [kda["moe"][k] for k in ("picks", "picks_held", "n_group", "topk_group")] \

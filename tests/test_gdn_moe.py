@@ -237,7 +237,11 @@ def test_the_configuration_says_what_it_keeps_and_counts():
     linear ones), the picks counted, the leaves' count by ``param_count``."""
     from ai_agent_kubectl_tpu.models import families
 
-    assert [k.name for k in families.kinds_of(CFG)] == ["experts", "linear", "expert_share"]
+    assert [k.name for k in families.kinds_of(CFG)] == [
+        "experts", "linear", "linear_window", "expert_share"]
+    # (a decay a key channel runs no window kernel: Ling's toy keeps its lane)
+    assert "linear_window" not in [
+        k.name for k in families.kinds_of(get_config("toy-kda-mla-moe"))]
     assert CFG.counts_picks and CFG.gate_elementwise and not CFG.gate_per_head
     assert not get_config("toy-sliding-moe").counts_picks      # the programs it had
     assert get_config("toy-kda-mla-moe").counts_picks

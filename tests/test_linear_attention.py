@@ -20,6 +20,7 @@ from ai_agent_kubectl_tpu.models.config import get_config
 from ai_agent_kubectl_tpu.models.transformer import (KVCache, forward,
                                                      init_params)
 from ai_agent_kubectl_tpu.ops import gated_delta as GD
+from ai_agent_kubectl_tpu.ops import gated_delta_window as GW
 from ai_agent_kubectl_tpu.ops.quant import random_params_int8
 
 ROOT = Path(__file__).resolve().parent.parent
@@ -320,6 +321,168 @@ def test_two_key_heads_serve_four_and_six_value_heads(form, H):
         np.testing.assert_allclose(np.asarray(got_o)[0], want_o[0], rtol=2e-4, atol=2e-5)
         np.testing.assert_allclose(np.asarray(got_S), want_S, rtol=2e-4, atol=2e-5)
     np.testing.assert_array_equal(np.asarray(out[::2]), np.asarray(leaf[::2]))
+
+
+# ---------------------------------------------------------- the window kernel
+
+def window_inputs(seed, S, q_lens, H, Hk, dk, dv, layers=3):
+    """``scan_inputs`` with the KEY heads' count on q and k, and a leaf of
+    ``layers`` planes in place of the one state."""
+    a = scan_inputs(seed, len(q_lens), S, q_lens, H=H, dk=dk, dv=dv)
+    r = H // Hk
+    a["q"], a["k"] = a["q"][:, :, ::r], a["k"][:, :, ::r]
+    leaf = jnp.asarray(np.random.default_rng(seed + 1).normal(
+        size=(layers,) + a.pop("S0").shape), jnp.float32)
+    return a, leaf
+
+
+#: (value heads, key heads, d_k, d_v): r = 1 on a head of a third of a lane
+#: tile (the toy's), r = 2 and r = 3 on a head that is a whole tile (as
+#: qwen3-next's), r = 1 and r = 2 on a head of a tile and a half (as
+#: olmo-hybrid-7b's 192)
+WINDOW_HEADS = {"r1-dv40": (4, 4, 24, 40), "r2-dv128": (4, 2, 16, 128),
+                "r3-dv128": (6, 2, 16, 128), "r1-dv192": (4, 4, 24, 192),
+                "r2-dv192": (4, 2, 24, 192)}
+
+
+@pytest.mark.parametrize("heads,S", [
+    ("r1-dv40", 70), ("r1-dv40", 150), ("r2-dv128", 130), ("r3-dv128", 70),
+    ("r1-dv192", 130), ("r2-dv192", 150)])
+def test_the_window_kernel_equals_the_scan_from_a_carried_state(heads, S):
+    """ops/gated_delta_window.py::gated_delta_window (interpreted here) on plane
+    1 of a three-plane leaf against ``gated_delta_scan`` on that plane, windows
+    that cross one and two chunk edges with rows of ``q_lens`` 0, 1, 33 and S
+    in one window: outputs and state equal to float32 rounding (the kernel
+    takes ``T`` out of the bracket and sums in another order) at every real
+    token, outputs past ``q_len`` zeros, the row that brought none bit for bit
+    its input (the kernel neither reads nor writes it), the other planes
+    untouched, the leaf float32."""
+    H, Hk, dk, dv = WINDOW_HEADS[heads]
+    q_lens = [33, 0, S, 1]
+    a, leaf = window_inputs(S, S, q_lens, H, Hk, dk, dv)
+    assert GW.window_chunk(S) == 64 and GW._unit_heads(H, H // Hk, dv)
+    want_o, want_S = GD.gated_delta_scan(*a.values(), leaf[1])
+    o, out = jax.jit(GW.gated_delta_window)(
+        *a.values(), leaf, jnp.asarray(1, jnp.int32), jnp.asarray(q_lens, jnp.int32))
+    for b, n in enumerate(q_lens):
+        np.testing.assert_allclose(np.asarray(o)[b, :n], np.asarray(want_o)[b, :n],
+                                   rtol=1e-5, atol=2e-6)
+        assert not np.asarray(o)[b, n:].any()
+    np.testing.assert_allclose(np.asarray(out[1]), np.asarray(want_S), rtol=1e-5, atol=2e-6)
+    np.testing.assert_array_equal(np.asarray(out[1, 1]), np.asarray(leaf[1, 1]))
+    assert np.abs(np.asarray(out[1, 3]) - np.asarray(leaf[1, 3])).max() > 1e-3
+    np.testing.assert_array_equal(np.asarray(out[::2]), np.asarray(leaf[::2]))
+    assert out.dtype == jnp.float32 and out.shape == leaf.shape
+    assert o.dtype == jnp.float32 and o.shape == (4, S, H, dv)
+
+
+@pytest.mark.parametrize("moves", ["1111", "0000", "0101", "1000"])
+def test_the_window_kernel_visits_only_the_rows_that_brought_tokens(moves):
+    """Whichever rows brought tokens, first, last, every or none, and with
+    ``q_lens`` left for the kernel to find (a row's last ``g`` or ``beta`` that
+    is not 0): their outputs and state are the scan's, every other row's state
+    is bit for bit its input and its outputs zeros."""
+    live = np.asarray([c == "1" for c in moves])
+    q_lens = np.where(live, [70, 5, 64, 33], 0)
+    a, leaf = window_inputs(5, 70, q_lens, 4, 2, 16, 128, layers=2)
+    want_o, want_S = GD.gated_delta_scan(*a.values(), leaf[1])
+    o, out = jax.jit(GW.gated_delta_window)(*a.values(), leaf, 1)
+    for b, n in enumerate(q_lens):
+        np.testing.assert_allclose(np.asarray(o)[b, :n], np.asarray(want_o)[b, :n],
+                                   rtol=1e-5, atol=2e-6)
+    np.testing.assert_allclose(np.asarray(out[1])[live], np.asarray(want_S)[live],
+                               rtol=1e-5, atol=2e-6)
+    assert not np.asarray(o)[~live].any()
+    np.testing.assert_array_equal(np.asarray(out[1])[~live], np.asarray(leaf[1])[~live])
+    np.testing.assert_array_equal(np.asarray(out[0]), np.asarray(leaf[0]))
+
+
+@pytest.mark.parametrize("S,chunk", [(16, 16), (24, 32), (40, 64)])
+def test_a_window_under_a_chunk_is_one_chunk_of_whole_solve_blocks(S, chunk):
+    """A 16-, 24- and 40-wide window (a toy's buckets) run as ONE chunk of 16,
+    32 and 64 rows (one, two and four of the substitution's blocks: none, one
+    and two merges), the rest padding; against the float64 recurrence."""
+    assert GW.window_chunk(S) == chunk
+    q_lens = [S, 3]
+    a, leaf = window_inputs(S, S, q_lens, 4, 4, 24, 40, layers=1)
+    want_o, want_S = recurrence(**a, S0=leaf[0])
+    o, out = jax.jit(GW.gated_delta_window)(*a.values(), leaf, 0,
+                                            jnp.asarray(q_lens, jnp.int32))
+    for b, n in enumerate(q_lens):
+        np.testing.assert_allclose(np.asarray(o)[b, :n], want_o[b, :n], rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(out[0]), want_S, rtol=2e-4, atol=2e-5)
+
+
+def test_the_window_kernel_scanned_over_a_leafs_planes_equals_the_recurrence():
+    """As ``_patterned_layers`` runs a scanned period: the whole leaf on a
+    scan's carry, the plane a traced ordinal, a 130-token window a plane; every
+    plane ends where the float64 recurrence from its own initial state does."""
+    S, planes, q_lens = 130, 3, [130, 70]
+    a, leaf = window_inputs(21, S, q_lens, 4, 2, 16, 128, layers=planes)
+
+    @jax.jit
+    def windows(leaf):
+        def layer(leaf, j):
+            o, leaf = GW.gated_delta_window(*a.values(), leaf, j,
+                                            jnp.asarray(q_lens, jnp.int32))
+            return leaf, o
+        return jax.lax.scan(layer, leaf, jnp.arange(planes, dtype=jnp.int32))
+
+    out, o = windows(leaf)                                  # o [planes, B, S, H, dv]
+    full = dict(a, q=np.repeat(a["q"], 2, axis=2), k=np.repeat(a["k"], 2, axis=2))
+    for j in range(planes):
+        want_o, want_S = recurrence(**full, S0=leaf[j])
+        for b, n in enumerate(q_lens):
+            np.testing.assert_allclose(np.asarray(o)[j, b, :n], want_o[b, :n],
+                                       rtol=2e-4, atol=2e-5)
+        np.testing.assert_allclose(np.asarray(out[j]), want_S, rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("seed", [0, 3])
+def test_the_window_kernel_holds_a_chunk_of_nearly_equal_keys_at_beta_2(seed):
+    """``worst_case_inputs`` through the kernel, whose ``T`` is made in VMEM
+    (substitution inside blocks of 16 in ``unit_lower_inverse``'s order, then
+    the merges): the tolerance the scan meets, against the float64 recurrence."""
+    a = worst_case_inputs(seed)
+    want_o, want_S = recurrence(**a)
+    leaf = a.pop("S0")[None]
+    o, out = jax.jit(GW.gated_delta_window)(*a.values(), leaf, 0)
+    np.testing.assert_allclose(np.asarray(o), want_o, rtol=2e-4, atol=5e-5)
+    np.testing.assert_allclose(np.asarray(out[0]), want_S, rtol=2e-4, atol=5e-5)
+
+
+def test_the_window_counts_are_the_rows_and_chunks_the_kernel_passes_over():
+    """``window_counts``: of a 150-wide window (three chunks a row) whose rows
+    brought 150, 33, 0, 1 and 64 tokens, four rows move, one stays and 0 + 2 +
+    2 + 2 chunks past a moving row's ``q_len`` are passed over and one row took
+    the step; rows and chunks sum to the slots and chunks the XLA scan went
+    over (``chunks_scanned``)."""
+    q_lens = jnp.asarray([150, 33, 0, 1, 64], jnp.int32)
+    moved, still, skipped, stepped = (int(n) for n in GW.window_counts(q_lens, 150))
+    assert (moved, still, skipped, stepped) == (4, 1, 6, 1)
+    assert moved + still == 5 and skipped + 3 + 1 + 1 + 1 == moved * 3
+    assert [int(n) for n in GW.window_counts(q_lens[2:3], 64)] == [0, 1, 0, 0]
+
+
+def test_a_chunk_that_is_no_whole_block_or_heads_that_make_no_pairs_take_the_scan(monkeypatch):
+    """tools/refcheck_power.py patches ``CHUNK`` to 1 to round a bf16 state at
+    every token, and a block of three heads has no pair for the substitution's
+    128 lanes: ``gated_delta_window`` then runs the plain scan from and to the
+    plane, bit for bit."""
+    a, leaf = window_inputs(9, 40, [40, 7], 3, 3, 24, 40, layers=2)
+    assert not GW._unit_heads(3, 1, 40) and GW._unit_heads(10, 1, 192) == 2
+    assert GW._unit_heads(16, 2, 128) == 2
+    want_o, want_S = GD.gated_delta_scan(*a.values(), leaf[1])
+    o, out = GW.gated_delta_window(*a.values(), leaf, 1)
+    np.testing.assert_array_equal(np.asarray(o), np.asarray(want_o))
+    np.testing.assert_array_equal(np.asarray(out[1]), np.asarray(want_S))
+    np.testing.assert_array_equal(np.asarray(out[0]), np.asarray(leaf[0]))
+    monkeypatch.setattr(GD, "CHUNK", 1)
+    assert GW.window_chunk(40) == 0
+    b, leaf = window_inputs(9, 8, [8, 7], 4, 4, 24, 40, layers=1)
+    want_o, want_S = GD.gated_delta_scan(*b.values(), leaf[0])
+    o, out = GW.gated_delta_window(*b.values(), leaf, 0)
+    np.testing.assert_array_equal(np.asarray(out[0]), np.asarray(want_S))
 
 
 def test_a_token_erases_along_its_key_before_it_writes():
@@ -667,6 +830,12 @@ async def test_a_session_seated_from_snapshots_answers_as_from_token_zero(from_t
         assert lin["full_keys_read"] > 100 * lin["decode_rows_full"] / 2
         if force_ragged:
             assert lin["window_rows_linear"] > 0 and lin["chunks_scanned"] > 0
+            # what the window kernel did with those windows' slots: a slot
+            # a linear layer is updated or passed over, chunk by chunk
+            slots = lin["window_rows_moved"] + lin["window_rows_still"]
+            assert lin["window_rows_moved"] > 0 and slots % (2 * 6) == 0
+            assert lin["window_rows_stepped"] <= lin["window_rows_moved"]
+            assert lin["window_chunks_skipped"] >= 0
         eng._state.check()
     finally:
         await eng.stop()

@@ -431,6 +431,59 @@ def test_compiled_step_and_scan_take_sixteen_key_heads_for_thirty_two_value_head
     _record("step_and_scan_16_key_heads_for_32", scan_worst_abs=worst)
 
 
+@pytest.mark.parametrize("sizes", ["qwen3next", "olmo"])
+def test_compiled_gated_delta_window_matches_the_scan_and_the_float64_recurrence(sizes):
+    """ops/gated_delta_window.py's kernel COMPILED (ISSUE 56) at the two
+    configurations' own head sizes (32 value heads over 16 key heads of 128 x 128,
+    a key head's two value heads a pair; 30 over 30 of 96 x 192, a 192-wide head
+    with its neighbour), on plane 1 of a three-plane leaf: rows of 300, 64, 1 and
+    no tokens in one 320-wide window (five chunks; the last keeps its state bit
+    for bit, outputs past ``q_len`` are zeros, the other planes untouched) against
+    the float64 recurrence and against the compiled ``gated_delta_scan``, and a
+    chunk of nearly equal keys written at ``beta`` 1.8-2.0 at the tolerance the
+    scan has there."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from test_linear_attention import recurrence, scan_inputs, worst_case_inputs
+
+    from ai_agent_kubectl_tpu.ops import gated_delta as GD
+    from ai_agent_kubectl_tpu.ops import gated_delta_window as GW
+
+    H, Hk, dk, dv = (32, 16, 128, 128) if sizes == "qwen3next" else (30, 30, 96, 192)
+    r, q_lens = H // Hk, [300, 64, 1, 0]
+    a = scan_inputs(2, 4, 320, q_lens, H=H, dk=dk, dv=dv)
+    a["q"], a["k"] = a["q"][:, :, ::r], a["k"][:, :, ::r]
+    leaf0 = np.random.default_rng(3).normal(size=(3,) + a.pop("S0").shape).astype(np.float32)
+    want_o, want_S = recurrence(**dict(a, q=np.repeat(a["q"], r, axis=2),
+                                       k=np.repeat(a["k"], r, axis=2)), S0=leaf0[1])
+    scan_o, scan_S = jax.jit(GD.gated_delta_scan)(*a.values(), jnp.asarray(leaf0[1]))
+    window = jax.jit(GW.gated_delta_window, donate_argnums=5)
+    o, out = window(*a.values(), jnp.asarray(leaf0), jnp.asarray(1, jnp.int32),
+                    jnp.asarray(q_lens, jnp.int32))
+    o, out = np.asarray(o), np.asarray(out)
+    worst = {"o": 0.0, "o_scan": 0.0}
+    for b, n in enumerate(q_lens):
+        np.testing.assert_allclose(o[b, :n], want_o[b, :n], rtol=2e-4, atol=1.2e-4)
+        worst["o"] = max(worst["o"], float(np.abs(o[b, :n] - want_o[b, :n]).max(initial=0)))
+        worst["o_scan"] = max(worst["o_scan"], float(
+            np.abs(np.asarray(scan_o)[b, :n] - want_o[b, :n]).max(initial=0)))
+        assert not o[b, n:].any()
+    np.testing.assert_allclose(out[1], want_S, rtol=2e-4, atol=1.2e-4)
+    np.testing.assert_array_equal(out[1, 3], leaf0[1, 3])
+    np.testing.assert_array_equal(out[::2], leaf0[::2])
+    w = worst_case_inputs(0, 2, 64, H=4, dk=dk, dv=dv)
+    hard_o, hard_S = recurrence(**w)
+    hard = w.pop("S0")[None]
+    o2, out2 = jax.jit(GW.gated_delta_window)(*w.values(), hard, 0)
+    np.testing.assert_allclose(np.asarray(o2), hard_o, rtol=2e-4, atol=1.2e-4)
+    np.testing.assert_allclose(np.asarray(out2[0]), hard_S, rtol=2e-4, atol=1.2e-4)
+    _record("gated_delta_window", sizes=sizes, worst_abs=worst["o"],
+            scan_worst_abs=worst["o_scan"],
+            state_worst_abs=float(np.abs(out[1] - want_S).max()),
+            hard_worst_abs=float(np.abs(np.asarray(o2) - hard_o).max()))
+
+
 def _record(name, **readings):
     """What the chip read, beside the verdict: chiprun_out/kernel_parity.jsonl."""
     import json
