@@ -62,7 +62,8 @@ exact float32), ``u``, ``alpha S + k u^T`` and ``o`` follow in float32, and
 the tile is written once: the state crosses HBM once in and once out, and a
 row that does not move (padding, a dead slot) not at all: the moving rows
 take the grid's first steps and the others' steps name the block before
-them again. ``gated_delta_step`` is the same step in ``jnp`` on one plane,
+them again (ops/state_leaf.py: the frame every kernel on a state leaf
+shares; this module has the body and its operands). ``gated_delta_step`` is the same step in ``jnp`` on one plane,
 kept as what the tests hold the kernel to: it never splits the lane axis
 into heads either, so a head's ``S^T k`` is one product of ALL heads' keys
 with the row, of which the head's own block of lanes is kept (``_own``), 30
@@ -89,7 +90,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from . import state_leaf
 
 #: dtype of the carried state (tools/refcheck_power.py patches it to read
 #: what the comparison makes of a bf16 state).
@@ -155,7 +157,7 @@ def gated_delta_step(q, k, v, g, beta, S0):
 #: Bytes of the state the kernel takes a grid step at the most: a lane block
 #: is the most heads in whole 128-lane tiles under it (10 of the published
 #: 30: [96, 1,920] float32, 737 KB, 3 steps a row; tools/
-#: time_gated_delta_step.py times the other widths).
+#: time_state_kernels.py times the other widths).
 _STEP_BLOCK_BYTES = 2 ** 20
 #: Scoped VMEM the kernel asks for: a block in and out, each double-buffered
 #: by the pipeline (4 x 737 KB), and a tile's temporaries; a whole 30-head
@@ -200,11 +202,11 @@ def _step_kernel(lyr_ref, order_ref, n_live_ref, kq_ref, vec_ref, s_ref,
     T = 128 if G * dv % 128 == 0 else G * dv
     n_live = n_live_ref[0]
 
-    @pl.when(pl.program_id(0) >= n_live)
+    @pl.when(state_leaf.passed_over(n_live))
     def _stays():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    @pl.when((n_live == 0) & (pl.program_id(0) + pl.program_id(1) == 0))
+    @pl.when(state_leaf.none_moves(n_live))
     def _none_moves():      # the one block every step names: as it came
         s_out_ref[...] = s_ref[...]
 
@@ -288,23 +290,6 @@ def gated_delta_step_kernel(q, k, v, g, beta, state, layer, moves=None,
                       interpret=jax.default_backend() != "tpu")
 
 
-def moving_rows_first(moves):
-    """(order int32 [B], n_live int32 [1]) of ``moves`` [B] bool: the rows
-    that move first, in their order, then the others; how many move. A step
-    kernel's grid takes the rows in this order, so that the rows it passes
-    over are its last steps (ops/ssd_scan.py's takes it too). A stable
-    argsort, as comparisons: a sort of 8 is a program of its own a layer."""
-    B = moves.shape[0]
-    m = moves.astype(jnp.int32)
-    n_live = jnp.sum(m).reshape(1)
-    before = jnp.tril(jnp.ones((B, B), jnp.int32), -1)
-    place = jnp.where(moves, before @ m, n_live + before @ (1 - m))
-    rows = jnp.arange(B, dtype=jnp.int32)
-    order = jnp.sum(jnp.where(place[None, :] == rows[:, None], rows[None, :],
-                              0), axis=1)
-    return order, n_live
-
-
 @partial(jax.jit, static_argnames=("block_heads", "interpret"))
 def _step_call(q, k, v, g, beta, state, layer, moves, *, block_heads: int,
                interpret: bool):
@@ -323,10 +308,9 @@ def _step_call(q, k, v, g, beta, state, layer, moves, *, block_heads: int,
     q, k, v, g, beta = f32(q), f32(k), f32(v), f32(g), f32(beta)
     channel = g.ndim == 3               # [B, H, dk]: a decay a key channel
     if moves is None:
-        moves = jnp.any(jnp.logical_or(
-            jnp.any(g != 0, axis=-1) if channel else g != 0, beta != 0),
-            axis=-1)                                                   # [B]
-    order, n_live = moving_rows_first(moves)
+        moves = state_leaf.any_gate(
+            jnp.any(g != 0, axis=-1) if channel else g != 0, beta != 0)  # [B]
+    order, n_live = state_leaf.moving_rows_first(moves)
     lanes = lambda a: jnp.repeat(a, dv, axis=-1)                    # [B, L]
     # a block's heads' keys then queries, each a column: [B, nb, dk, 2*hb]
     cols = lambda a: jnp.swapaxes(a.reshape(B, nb, hb, dk), 2, 3)
@@ -337,39 +321,22 @@ def _step_call(q, k, v, g, beta, state, layer, moves, *, block_heads: int,
         kq = jnp.concatenate([cols(k), cols(q)], axis=-1)
         vec = jnp.stack([v.reshape(B, L), lanes(jnp.exp(g)), lanes(beta)],
                         axis=1)                                     # [B, 3, L]
-    lyr = jnp.asarray(layer, jnp.int32).reshape(1)
-
-    def plane(i, c, lyr, order, n_live):
-        # past the rows that move: the last of them, its last block
-        last = jnp.maximum(n_live[0] - 1, 0)
-        return (lyr[0], order[jnp.minimum(i, last)], 0,
-                jnp.where(i < n_live[0], c, nb - 1))
-
     row = lambda i, c, lyr, order, n_live: (order[i], 0, c)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, nb),
+    o, state = state_leaf.visit(
+        partial(_step_kernel, dv=dv, **({"channel": True} if channel
+                                         else {})),
+        name="gated_delta_step", grid=(B, nb), layer=layer, order=order,
+        n_live=n_live, at=state_leaf.step_block(nb),
         in_specs=[pl.BlockSpec((1, 1, dk, kq.shape[-1]),
                                lambda i, c, lyr, order, n_live:
                                (order[i], c, 0, 0)),
-                  pl.BlockSpec((1, vec.shape[1], W), row),
-                  pl.BlockSpec((1, 1, dk, W), plane)],
-        out_specs=[pl.BlockSpec((1, 1, W), row),
-                   pl.BlockSpec((1, 1, dk, W), plane)],
-    )
-    o, state = pl.pallas_call(
-        partial(_step_kernel, dv=dv, **({"channel": True} if channel
-                                         else {})),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((B, 1, L), jnp.float32),
-                   jax.ShapeDtypeStruct(state.shape, state.dtype)],
-        input_output_aliases={5: 1},
-        interpret=interpret,
-        name="gated_delta_step",
-        **({} if interpret else {"compiler_params": pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
-            vmem_limit_bytes=_STEP_VMEM_BYTES)}),
-    )(lyr, order, n_live, kq, vec, state)
+                  pl.BlockSpec((1, vec.shape[1], W), row)],
+        out_specs=[pl.BlockSpec((1, 1, W), row)],
+        out_shape=[jax.ShapeDtypeStruct((B, 1, L), jnp.float32)],
+        leaf=state, block=(1, 1, dk, W),
+        plane=lambda layer, row, c: (layer, row, 0, c),
+        vmem_limit_bytes=_STEP_VMEM_BYTES, interpret=interpret,
+    )(kq, vec)
     return o.reshape(B, 1, H, dv), state
 
 
@@ -424,8 +391,7 @@ def unit_lower_inverse(A):
 
 
 def _unit_lower_solve(A, rhs):
-    """``(I + A)^-1 rhs`` (tools/time_gated_delta_window.py times the scan
-    with this call's result replaced by ``rhs``)."""
+    """``(I + A)^-1 rhs``, for both scans."""
     return jnp.einsum("...ij,...jv->...iv", unit_lower_inverse(A), rhs,
                       precision=_HI)
 

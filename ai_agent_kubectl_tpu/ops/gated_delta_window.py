@@ -15,9 +15,10 @@ period): no plane is sliced out in front of it and none set back behind it.
 Its grid is (row, block of heads, chunk of ``CHUNK`` tokens), the chunks
 innermost: a block of a row's state is fetched once, stays in VMEM across
 the row's chunks and is written once; the rows that brought tokens take the
-grid's first steps (``moving_rows_first``), a row that brought none has its
-state neither read nor written, and a chunk wholly past a row's ``q_len``
-is passed over. In ``jnp`` every slot's every column was scanned, padding
+grid's first steps, a row that brought none has its state neither read nor
+written, and a chunk wholly past a row's ``q_len`` is passed over
+(ops/state_leaf.py has that frame; this module the body and its operands).
+In ``jnp`` every slot's every column was scanned, padding
 too, every chunk's ``[64, 64]`` Gram products, decays and ``T`` went through
 HBM as ``[B, n, H, 64, 64]`` float32 arrays, and the chunks' outputs were
 stacked and turned over behind a ``lax.scan``.
@@ -85,9 +86,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import gated_delta
-from .gated_delta import (_HI, _SOLVE_BLOCK, _block_heads, gated_delta_scan,
-                          moving_rows_first)
+from . import gated_delta, state_leaf
+from .gated_delta import _HI, _SOLVE_BLOCK, _block_heads, gated_delta_scan
 
 #: Scoped VMEM the kernel asks for: a block of the state in and out (1 MiB
 #: each at 16 heads of 128 x 128), a chunk's keys, queries, values in and
@@ -175,8 +175,7 @@ def _window_kernel(lyr_ref, order_ref, n_live_ref, lens_ref, q_ref, k_ref,
     tn = lambda x, y: dot(x, y, (((0,), (0,)), ((), ())))      # x^T y
     iota = lambda shape, d: jax.lax.broadcasted_iota(jnp.int32, shape, d)
 
-    @pl.when((i < n_live) & (n == 0)
-             | (n_live == 0) & (i + pl.program_id(1) + n == 0))
+    @pl.when(state_leaf.fetched(i, n, n_live))
     def _fetched():     # (where no row moves: the one block every step names)
         s_out_ref[...] = s_ref[...]
 
@@ -462,12 +461,10 @@ def _window_call(q, k, v, g, beta, state, layer, q_lens, *, chunk: int,
     f32 = lambda a: a.astype(jnp.float32)
     g, beta = f32(g), f32(beta)
     if q_lens is None:
-        q_lens = jnp.max(jnp.where(
-            jnp.any(jnp.logical_or(g != 0, beta != 0), axis=-1),
-            jnp.arange(1, S + 1), 0), axis=1)
+        q_lens = state_leaf.tokens_brought(g != 0, beta != 0)
     q_lens = q_lens.astype(jnp.int32)
-    order, n_live = moving_rows_first(q_lens > 0)
-    rows = lambda a: jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+    order, n_live = state_leaf.moving_rows_first(q_lens > 0)
+    rows = partial(state_leaf.whole_chunks, pad=pad)
     # the key heads apart, a head's chunk [C, dk] a tile of its own
     qh, kh = (jnp.swapaxes(rows(f32(a)), 1, 2) for a in (q, k))
     # a chunk's running sum of g, then beta, a head each: a token a sublane
@@ -477,16 +474,8 @@ def _window_call(q, k, v, g, beta, state, layer, q_lens, *, chunk: int,
         rows(beta).reshape(B, n_chunks, C, H)], axis=-1)
     b = min(_SOLVE_BLOCK, C)
     P = -(-(hb // 2) // 8) * 8      # pairs a block, in whole sublane tiles
-    lyr = jnp.asarray(layer, jnp.int32).reshape(1)
-
-    def at(i, c, n, lyr, order, n_live, q_lens):
-        """(row, block, chunk) of step (i, c, n): its own while the row moves
-        and the chunk holds tokens of it, else the last that did."""
-        moving = i < n_live[0]
-        row = order[jnp.minimum(i, jnp.maximum(n_live[0] - 1, 0))]
-        last = jnp.maximum((q_lens[row] + C - 1) // C - 1, 0)
-        return (row, jnp.where(moving, c, nb - 1),
-                jnp.where(moving, jnp.minimum(n, last), last))
+    at = state_leaf.window_block(nb, lambda q_lens, row:
+                                 (q_lens[row] + C - 1) // C)
 
     def key_heads(i, c, n, *s):     # [B, Hk, S, dk]
         row, c, n = at(i, c, n, *s)
@@ -504,43 +493,25 @@ def _window_call(q, k, v, g, beta, state, layer, q_lens, *, chunk: int,
         row, _, n = at(i, c, n, *s)
         return row, n, 0
 
-    def plane(i, c, n, lyr, *s):
-        row, c, _ = at(i, c, n, lyr, *s)
-        return lyr[0], row, 0, c
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(B, nb, n_chunks),
+    o, state = state_leaf.visit(
+        partial(_window_kernel, r=r, u=u, dv=dv), name="gated_delta_window",
+        grid=(B, nb, n_chunks), layer=layer, order=order, n_live=n_live,
+        extra=q_lens, at=at,
         in_specs=[pl.BlockSpec((1, hb // r, C, dk), key_heads),
                   pl.BlockSpec((1, hb // r, C, dk), key_heads),
                   pl.BlockSpec((1, C, hb * dv), tokens),
                   pl.BlockSpec((1, 1, H, 2 * C), by_chunk),
-                  pl.BlockSpec((1, C, 2 * H), by_token),
-                  pl.BlockSpec((1, 1, dk, hb * dv), plane)],
-        out_specs=[pl.BlockSpec((1, C, hb * dv), tokens),
-                   pl.BlockSpec((1, 1, dk, hb * dv), plane)],
+                  pl.BlockSpec((1, C, 2 * H), by_token)],
+        out_specs=[pl.BlockSpec((1, C, hb * dv), tokens)],
+        out_shape=[jax.ShapeDtypeStruct((B, S + pad, H * dv), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((b * P, 128), jnp.float32),
                         pltpu.VMEM((b - 1, b * P, 128), jnp.float32),
                         pltpu.VMEM((b * P, 128), jnp.float32)]
         + [pltpu.VMEM((hb // 2, C, 2 * C), jnp.float32)] * 4,
-    )
-    o, state = pl.pallas_call(
-        partial(_window_kernel, r=r, u=u, dv=dv),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((B, S + pad, H * dv), jnp.float32),
-                   jax.ShapeDtypeStruct(state.shape, state.dtype)],
-        input_output_aliases={9: 1},
-        interpret=interpret,
-        name="gated_delta_window",
-        **({} if interpret else {"compiler_params": pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",) * 3,
-            vmem_limit_bytes=_WINDOW_VMEM_BYTES)}),
-    )(lyr, order, n_live, q_lens, qh, kh,
-      rows(v).reshape(B, S + pad, H * dv),
+        leaf=state, block=(1, 1, dk, hb * dv),
+        plane=lambda layer, row, c: (layer, row, 0, c),
+        vmem_limit_bytes=_WINDOW_VMEM_BYTES, interpret=interpret,
+    )(qh, kh, rows(v).reshape(B, S + pad, H * dv),
       jnp.swapaxes(gb, 2, 3).reshape(B, n_chunks, H, 2 * C),
-      gb.reshape(B, S + pad, 2 * H), state)
-    # what no step wrote (a row's columns past its last chunk with tokens, a
-    # row that brought none) holds whatever the buffer held
-    real = jnp.arange(S)[None, :] < q_lens[:, None]
-    o = jnp.where(real[..., None], o[:, :S], 0.0)
-    return o.reshape(B, S, H, dv), state
+      gb.reshape(B, S + pad, 2 * H))
+    return state_leaf.window_rows(o, q_lens, S).reshape(B, S, H, dv), state

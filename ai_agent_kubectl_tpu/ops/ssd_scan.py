@@ -41,8 +41,9 @@ tile's update) with its group's ``B`` (a row), written once, and ``C . h``
 is a sum along its lanes, all float32 on the VPU and XLU: the state
 crosses HBM once in and once out, and a row that does not move (padding,
 a dead slot) not at all: the moving rows take the grid's first steps and
-the others' steps name the block before them again (ops/gated_delta.py's
-step kernel is the same shape around another recurrence). ``ssd_step`` is
+the others' steps name the block before them again (ops/state_leaf.py: the
+frame every kernel on a state leaf shares; this module has the two bodies
+and their operands). ``ssd_step`` is
 the same step in ``jnp`` on one plane, kept as what the tests hold the
 kernel to.
 
@@ -81,7 +82,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .gated_delta import moving_rows_first
+from . import state_leaf
 
 #: dtype of the carried recurrent state (tools/refcheck_power.py patches
 #: it to read what the comparison makes of a bf16 state, by context length).
@@ -142,8 +143,8 @@ def ssd_step(x, dt, A, Bm, Cm, D, h0):
 
 #: Bytes of the state the step kernel takes a grid step at the most: a block
 #: is the most whole groups of heads under it (32 of the published 64:
-#: [32, 64, 128] float32, 1 MiB, 2 steps a row; tools/time_ssd_step.py times
-#: the other widths).
+#: [32, 64, 128] float32, 1 MiB, 2 steps a row; tools/time_state_kernels.py
+#: times the other widths).
 _STEP_BLOCK_BYTES = 2 ** 20
 #: Scoped VMEM the kernel asks for: a block in and out, each double-buffered
 #: by the pipeline (4 x 1 MiB), a row's inputs and a head's temporaries.
@@ -183,11 +184,11 @@ def _step_kernel(lyr_ref, order_ref, n_live_ref, x_ref, decay_ref, bc_ref,
     n_live = n_live_ref[0]
     first = pl.program_id(1) * hb
 
-    @pl.when(pl.program_id(0) >= n_live)
+    @pl.when(state_leaf.passed_over(n_live))
     def _stays():
         y_ref[...] = jnp.zeros_like(y_ref)
 
-    @pl.when((n_live == 0) & (pl.program_id(0) + pl.program_id(1) == 0))
+    @pl.when(state_leaf.none_moves(n_live))
     def _none_moves():      # the one block every step names: as it came
         s_out_ref[...] = s_ref[...]
 
@@ -250,8 +251,8 @@ def _step_call(x, dt, A, Bm, Cm, D, state, layer, moves, *, block_heads: int,
     f32 = lambda a: a.astype(jnp.float32)
     x32, dt = f32(x[:, 0]), f32(dt[:, 0])                   # [B,H,P], [B,H]
     if moves is None:
-        moves = jnp.any(dt != 0, axis=-1)
-    order, n_live = moving_rows_first(moves)
+        moves = state_leaf.any_gate(dt != 0)
+    order, n_live = state_leaf.moving_rows_first(moves)
     # ``u`` heads a tile: ``dt x`` a head a sublane, P along the lanes (whole
     # lane tiles: the kernel turns a tile over), and the decay along N lanes
     dtx = jnp.pad(dt[..., None] * x32, ((0, 0), (0, 0), (0, -P % 128)))
@@ -259,39 +260,22 @@ def _step_call(x, dt, A, Bm, Cm, D, state, layer, moves, *, block_heads: int,
     decay = jnp.broadcast_to(jnp.exp(dt * f32(A))[..., None],
                              (B, H, N)).reshape(B, H // u, u, N)
     bc = jnp.stack([f32(Bm[:, 0]), f32(Cm[:, 0])], axis=2)          # [B,G,2,N]
-    lyr = jnp.asarray(layer, jnp.int32).reshape(1)
-
-    def plane(i, c, lyr, order, n_live):
-        # past the rows that move: the last of them, its last block
-        last = jnp.maximum(n_live[0] - 1, 0)
-        return (lyr[0], order[jnp.minimum(i, last)],
-                jnp.where(i < n_live[0], c, nb - 1), 0, 0)
-
     row = lambda i, c, lyr, order, n_live: (order[i], 0, 0, 0)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, nb),
+    y, state = state_leaf.visit(
+        partial(_step_kernel, heads_a_group=H // G), name="ssd_step",
+        grid=(B, nb), layer=layer, order=order, n_live=n_live,
+        at=state_leaf.step_block(nb),
         in_specs=[pl.BlockSpec((1,) + dtx.shape[1:], row),
                   pl.BlockSpec((1,) + decay.shape[1:], row),
-                  pl.BlockSpec((1, G, 2, N), row),
-                  pl.BlockSpec((1, 1, hb, P, N), plane)],
+                  pl.BlockSpec((1, G, 2, N), row)],
         out_specs=[pl.BlockSpec((1, 1, P, hb),
                                 lambda i, c, lyr, order, n_live:
-                                (order[i], c, 0, 0)),
-                   pl.BlockSpec((1, 1, hb, P, N), plane)],
-    )
-    y, state = pl.pallas_call(
-        partial(_step_kernel, heads_a_group=H // G),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((B, nb, P, hb), jnp.float32),
-                   jax.ShapeDtypeStruct(state.shape, state.dtype)],
-        input_output_aliases={6: 1},
-        interpret=interpret,
-        name="ssd_step",
-        **({} if interpret else {"compiler_params": pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
-            vmem_limit_bytes=_STEP_VMEM_BYTES)}),
-    )(lyr, order, n_live, dtx, decay, bc, state)
+                                (order[i], c, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((B, nb, P, hb), jnp.float32)],
+        leaf=state, block=(1, 1, hb, P, N),
+        plane=lambda layer, row, c: (layer, row, c, 0, 0),
+        vmem_limit_bytes=_STEP_VMEM_BYTES, interpret=interpret,
+    )(dtx, decay, bc)
     y = jnp.swapaxes(y, 2, 3).reshape(B, H, P) + f32(D)[:, None] * x32
     y = jnp.where(moves[:, None, None], y, 0.0)[:, None]
     return y.astype(x.dtype), state
@@ -304,7 +288,7 @@ _WINDOW_VMEM_BYTES = 48 * 2 ** 20
 #: Tokens a chunk of the window kernel at the most: a chunk's decay-and-score
 #: tile is [Q, Q] a head, so its exponents and its ``M x`` product grow with
 #: Q a token while the two products with the state do not; 128 fills the
-#: MXU's rows and a lane tile (tools/time_ssd_window.py times the others).
+#: MXU's rows and a lane tile (tools/time_state_kernels.py times the others).
 _WINDOW_CHUNK = 128
 
 
@@ -374,8 +358,7 @@ def _window_kernel(lyr_ref, order_ref, n_live_ref, chunks_ref, x_ref, cum_ref,
                   preferred_element_type=jnp.float32)
     f32 = lambda a: a.astype(jnp.float32)
 
-    @pl.when((i < n_live) & (k == 0)
-             | (n_live == 0) & (i + pl.program_id(1) + k == 0))
+    @pl.when(state_leaf.fetched(i, k, n_live))
     def _fetched():     # (where no row moves: the one block every step names)
         s_out_ref[...] = s_ref[...]
 
@@ -480,28 +463,19 @@ def _window_call(x, dt, A, Bm, Cm, D, state, layer, q_lens, *, chunk: int,
     f32 = lambda a: a.astype(jnp.float32)
     dt = f32(dt)
     if q_lens is None:
-        q_lens = jnp.max(jnp.where(jnp.any(dt != 0, axis=-1),
-                                   jnp.arange(1, S + 1), 0), axis=1)
+        q_lens = state_leaf.tokens_brought(dt != 0)
     q_lens = q_lens.astype(jnp.int32)
-    order, n_live = moving_rows_first(q_lens > 0)
+    order, n_live = state_leaf.moving_rows_first(q_lens > 0)
     live_chunks = _chunks_with_tokens(q_lens, Q)
-    rows = lambda a: jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+    rows = partial(state_leaf.whole_chunks, pad=pad)
     # ``dt`` and a chunk's running sum of ``dt A``, a head a sublane, a token
     # a lane
     dth = jnp.moveaxis(rows(dt), 1, 2)                          # [B,H,S+pad]
     cum = jnp.cumsum((dth * f32(A)[:, None]).reshape(B, H, n_chunks, Q),
                      axis=-1).reshape(dth.shape)
-    lyr = jnp.asarray(layer, jnp.int32).reshape(1)
-
-    def at(i, c, k, lyr, order, n_live, live_chunks):
-        """(row, block, chunk) of step (i, c, k): its own while the row moves
-        and the chunk holds tokens of it, else the last that did (as it is,
-        the block of ``cum`` and ``dt`` [B, H, S])."""
-        moving = i < n_live[0]
-        row = order[jnp.minimum(i, jnp.maximum(n_live[0] - 1, 0))]
-        last = jnp.maximum(live_chunks[row] - 1, 0)
-        return (row, jnp.where(moving, c, nb - 1),
-                jnp.where(moving, jnp.minimum(k, last), last))
+    # (row, block, chunk) of a step: as it is, the block of ``cum`` and ``dt``
+    # [B, H, S]
+    at = state_leaf.window_block(nb, lambda live_chunks, row: live_chunks[row])
 
     def tokens(i, c, k, *s):        # [B, S, heads' lanes]
         row, c, k = at(i, c, k, *s)
@@ -511,45 +485,27 @@ def _window_call(x, dt, A, Bm, Cm, D, state, layer, q_lens, *, chunk: int,
         row, c, k = at(i, c, k, *s)
         return row, k, (c * hb) // (gb * Hg)
 
-    def plane(i, c, k, lyr, *s):
-        row, c, _ = at(i, c, k, lyr, *s)
-        return lyr[0], row, c, 0, 0
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(B, nb, n_chunks),
+    y, state = state_leaf.visit(
+        partial(_window_kernel, heads_a_group=Hg), name="ssd_window",
+        grid=(B, nb, n_chunks), layer=layer, order=order, n_live=n_live,
+        extra=live_chunks, at=at,
         in_specs=[pl.BlockSpec((1, Q, hb * P), tokens),
                   pl.BlockSpec((1, hb, Q), at),
                   pl.BlockSpec((1, hb, Q), at),
                   pl.BlockSpec((1, Q, gb * N), groups),
                   pl.BlockSpec((1, Q, gb * N), groups),
                   pl.BlockSpec((1, hb * P), lambda i, c, k, *s: (0, at(
-                      i, c, k, *s)[1])),
-                  pl.BlockSpec((1, 1, hb, P, N), plane)],
-        out_specs=[pl.BlockSpec((1, Q, hb * P), tokens),
-                   pl.BlockSpec((1, 1, hb, P, N), plane)],
+                      i, c, k, *s)[1]))],
+        out_specs=[pl.BlockSpec((1, Q, hb * P), tokens)],
+        out_shape=[jax.ShapeDtypeStruct((B, S + pad, H * P), x.dtype)],
         scratch_shapes=[pltpu.VMEM((Q, Q), jnp.float32)],
-    )
-    y, state = pl.pallas_call(
-        partial(_window_kernel, heads_a_group=Hg),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((B, S + pad, H * P), x.dtype),
-                   jax.ShapeDtypeStruct(state.shape, state.dtype)],
-        input_output_aliases={10: 1},
-        interpret=interpret,
-        name="ssd_window",
-        **({} if interpret else {"compiler_params": pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",) * 3,
-            vmem_limit_bytes=_WINDOW_VMEM_BYTES)}),
-    )(lyr, order, n_live, live_chunks,
-      rows(x).reshape(B, S + pad, H * P), cum, dth,
+        leaf=state, block=(1, 1, hb, P, N),
+        plane=lambda layer, row, c: (layer, row, c, 0, 0),
+        vmem_limit_bytes=_WINDOW_VMEM_BYTES, interpret=interpret,
+    )(rows(x).reshape(B, S + pad, H * P), cum, dth,
       rows(Bm).reshape(B, S + pad, G * N), rows(Cm).reshape(B, S + pad, G * N),
-      jnp.repeat(f32(D), P)[None], state)
-    # what no step wrote (a row's columns past its last chunk with tokens, a
-    # row that brought none) holds whatever the buffer held
-    real = jnp.arange(S)[None, :] < q_lens[:, None]
-    y = jnp.where(real[..., None], y[:, :S], 0)
-    return y.reshape(B, S, H, P), state
+      jnp.repeat(f32(D), P)[None])
+    return state_leaf.window_rows(y, q_lens, S).reshape(B, S, H, P), state
 
 
 def ssd_scan(x, dt, A, Bm, Cm, D, h0, chunk: int):

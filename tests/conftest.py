@@ -62,13 +62,19 @@ def pytest_pyfunc_call(pyfuncitem):
 #: the order collected, and these are collected first; the first six start
 #: together. (All longest first was tried at PR 48 and is slower: the
 #: compiles of the heaviest files then contend from the first second, 1,227
-#: s against 1,065.)
+#: s against 1,065.) At PR 57, by the driver's command's junit file on that
+#: tree: 549, 816, 543, 566, 277, 288, 299, 291 (``test_gdn_moe.py``, PR 55's,
+#: until then collected among the short files), 239, 336, 375, 506, 112
+#: (``test_ssm_dense_hybrid.py``; the next is 81). The whole is 6,705 s of
+#: tests over six workers, 1,118 s at best: the order is worth seconds now,
+#: the work is the bound.
 _LONG_FILES = ("test_sparse_attention.py", "test_tpu_aot.py",
                "test_kda_latent.py", "test_reference_logits_sliding.py",
                "test_sliding_attention.py", "test_latent_attention.py",
-               "test_window_staging.py", "test_packed_window.py",
-               "test_state_cache.py", "test_hybrid_model.py",
-               "test_linear_attention.py")
+               "test_window_staging.py", "test_gdn_moe.py",
+               "test_packed_window.py", "test_state_cache.py",
+               "test_hybrid_model.py", "test_linear_attention.py",
+               "test_ssm_dense_hybrid.py")
 #: Files that hold the program to a clock (a 6 ms step against a 50 ms fault)
 #: are collected last: they then run while the other workers are finishing
 #: short files, not under a long file's compiles (three whole runs of three
